@@ -16,9 +16,11 @@ test:
 # domains. Fails on any finding not grandfathered (with a justification)
 # in analysis-baseline.json. See README "Static analysis".
 lint:
-	$(PY) -m compileall -q cake_tpu tests bench.py __graft_entry__.py
+	$(PY) -m compileall -q cake_tpu tests bench.py chip_smoke.py \
+	  __graft_entry__.py
 	@if $(PY) -c 'import pyflakes' 2>/dev/null; then \
-	  $(PY) -m pyflakes cake_tpu tests bench.py __graft_entry__.py; fi
+	  $(PY) -m pyflakes cake_tpu tests bench.py chip_smoke.py \
+	  __graft_entry__.py; fi
 	$(PY) -m cake_tpu.analysis --baseline analysis-baseline.json
 
 native: native/libcakewire.so native/libcakeembed.so native/cake_host_demo
@@ -40,9 +42,27 @@ native/libcakeembed.so: native/cake_embed.cc
 native/cake_host_demo: native/cake_host_demo.c native/libcakeembed.so
 	gcc -O2 -o $@ $< -Lnative -lcakeembed -Wl,-rpath,'$$ORIGIN'
 
+# an explicit CPU smoke: its row says "platform": "cpu" and its metric
+# name ends _cpu. bench.py has no fallback: on a machine with a chip, drop
+# JAX_PLATFORMS=cpu and it runs there or exits non-zero.
 bench:
 	CAKE_BENCH_PRESET=tiny JAX_PLATFORMS=cpu $(PY) bench.py
 
+# the serving path end to end on ONE TPU chip (run it through the chip
+# tool; with no TPU it exits non-zero and prints no result). A four-chip
+# call is for `$(PY) chip_smoke.py --chips 4` and nothing else.
+chip-smoke:
+	$(PY) chip_smoke.py
+
+# the same control flow at tiny size on the CPU, kernels interpreted:
+# proves the script's paths before chip time is spent, never the chip
+chip-smoke-rehearse:
+	$(PY) chip_smoke.py --rehearse
+	$(PY) chip_smoke.py --rehearse --chips 4
+
+# the three tools below record on-chip measurements: off a TPU they
+# refuse --json-out (KERNELS_TPU.json is never overwritten by an
+# interpreted run); without --json-out the rows still print
 kernel-check:
 	$(PY) -m cake_tpu.tools.kernel_check --json-out KERNELS_TPU.json
 
@@ -57,8 +77,8 @@ int4-sweep:
 ici-probe:
 	$(PY) -m cake_tpu.tools.ici_probe --json-out ici_probe.json
 
-# 70B per-stage pricing on one chip (BASELINE configs 4/5): measured
-# stage step + prefill, projected v5e-16 tok/s (r5)
+# 70B per-stage pricing on one chip (BASELINE.json configs 4/5): measured
+# stage step + prefill, projected v5e-16 tok/s; refuses --json-out off-chip
 stage-slice:
 	$(PY) -m cake_tpu.tools.stage_slice --json-out stage_slice.json
 
@@ -249,4 +269,4 @@ clean:
 	rm -f native/*.so native/cake_host_demo
 	find . -name __pycache__ -type d -exec rm -rf {} +
 
-.PHONY: test lint native bench kernel-check flash-sweep int4-sweep ici-probe stage-slice spec-corpus watch ttft trace-smoke cluster-trace-smoke chaos-smoke serve-smoke constrain-smoke gateway-smoke kv-smoke disagg-smoke reqtrace-smoke prof-smoke fleet-smoke slo-smoke bench-diff perf-smoke deploy clean
+.PHONY: test lint native bench chip-smoke chip-smoke-rehearse kernel-check flash-sweep int4-sweep ici-probe stage-slice spec-corpus watch ttft trace-smoke cluster-trace-smoke chaos-smoke serve-smoke constrain-smoke gateway-smoke kv-smoke disagg-smoke reqtrace-smoke prof-smoke fleet-smoke slo-smoke bench-diff perf-smoke deploy clean

@@ -786,7 +786,7 @@ def run_http_serve(args) -> int:
     from cake_tpu.obs import metrics as obs_metrics
     from cake_tpu.serve.api import start_api_server
     from cake_tpu.serve.scheduler import Scheduler
-    from cake_tpu.utils.memory import memory_report
+    from cake_tpu.utils.memory import device_report, memory_report
 
     serve_port = args.serve_port if args.serve_port is not None else 8080
     serve_bind = args.serve_bind or "127.0.0.1"
@@ -962,6 +962,10 @@ def run_http_serve(args) -> int:
             "role": "serve",
             "version": __version__,
             "model": str(args.model),
+            # the backend that is actually serving, and what each device
+            # holds: a server that came up on the CPU, or put a sharded
+            # model on one chip, says so here
+            "device": device_report(),
             "scheduler": scheduler.stats(),
             "metrics": obs_metrics.registry().snapshot(),
         }
@@ -1024,8 +1028,11 @@ def run_http_serve(args) -> int:
             status_httpd.server_close()
         scheduler.close()
         obs.flush_artifacts()
-        log.info("drained; bye")
-    return 0
+        if scheduler.fault:
+            log.error("engine thread died: %s", scheduler.fault)
+        else:
+            log.info("drained; bye")
+    return 1 if scheduler.fault else 0
 
 
 def _gateway_flags(args) -> list[str]:
@@ -1548,6 +1555,9 @@ def run_master(args) -> int:
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     from cake_tpu import obs
+    from cake_tpu.utils.compile_cache import configure
+
+    configure()
 
     if args.mode != "gateway" and not args.model:
         sys.exit("error: --model is required (only --mode gateway runs "

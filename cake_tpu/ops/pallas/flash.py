@@ -34,11 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the params class was renamed TPUCompilerParams -> CompilerParams;
-# resolve once so the kernels build on either side of the rename
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
 _LANES = 128
 
@@ -215,7 +210,7 @@ def flash_attention(
         kernel,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -260,8 +255,9 @@ def _prefill_q8_kernel(
 
     The scale blocks carry the FULL kv-head axis: a (1, 1, BK) block would
     put a size-1 block over that axis, which Mosaic's sublane rule rejects
-    on real TPUs whenever KVH > 1 (caught on v5e, r4). The kernel selects
-    its head's row dynamically — the stripe is a few KB."""
+    on real TPUs whenever KVH > 1. The kernel reads its head's row with a
+    dynamic index on the ref (a ``dynamic_slice`` of the loaded value has
+    no Mosaic lowering) — the stripe is a few KB."""
     qb = pl.program_id(2)
     kb = pl.program_id(3)
     hk = pl.program_id(1) // group  # this grid cell's kv head
@@ -283,7 +279,7 @@ def _prefill_q8_kernel(
         s = jax.lax.dot_general(
             q, kq, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        ks_row = jax.lax.dynamic_slice_in_dim(ks_ref[0], hk, 1, 0)  # [1, BK]
+        ks_row = ks_ref[0, pl.ds(hk, 1), :]  # [1, BK]
         s = s * scale * ks_row  # fold key scales per column
 
         qpos = (
@@ -307,7 +303,7 @@ def _prefill_q8_kernel(
         l_ref[:] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
         m_ref[:] = m_new
         vq = vq_ref[0, 0].astype(q.dtype)
-        vs_row = jax.lax.dynamic_slice_in_dim(vs_ref[0], hk, 1, 0)  # [1, BK]
+        vs_row = vs_ref[0, pl.ds(hk, 1), :]  # [1, BK]
         pv = jax.lax.dot_general(
             (p * vs_row).astype(q.dtype), vq,
             (((1,), (0,)), ((), ())),
@@ -393,7 +389,7 @@ def flash_attention_q8(
         kernel,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -546,7 +542,7 @@ def flash_decode(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, kvh, group, d), q.dtype),
         grid_spec=grid_spec,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

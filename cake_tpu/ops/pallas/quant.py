@@ -17,11 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the params class was renamed TPUCompilerParams -> CompilerParams;
-# resolve once so the kernels build on either side of the rename
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _pick_block(n: int, preferred: int) -> int:
     b = 1
@@ -80,7 +75,7 @@ def quant_matmul_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kb: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -247,7 +242,7 @@ def quant4_matmul_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kb: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

@@ -407,7 +407,9 @@ def quant_matmul(
             # v5e (measured single-stream 8B int8: 84.7 vs 50.7 tok/s) and
             # ~40% faster at M=8 (batched decode). The crossover is ~M=16,
             # where the kernel's int8-in-VMEM streaming starts winning (522
-            # vs 505 aggregate tok/s at batch 16) — see BASELINE.md r2.
+            # vs 505 aggregate tok/s at batch 16). Those are the 07-31
+            # rows of bench_results.jsonl, taken at Llama-3-8B widths; the
+            # crossover has not been measured on the chip tool.
             m = x.size // x.shape[-1]
             impl = (
                 "pallas"
@@ -441,7 +443,7 @@ def quant4_matmul(
 
     The auto gate reuses the int8 m>=16 crossover as its prior (the kernels
     share the streaming structure); the int4 frontier is re-measured on chip
-    by tools/flash_sweep-style rows before any claim lands in BASELINE.md."""
+    by tools/int4_sweep rows before any claim is made."""
     from cake_tpu.ops import pallas as pk
 
     k2, n = qp.shape[-2], qp.shape[-1]

@@ -38,20 +38,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from cake_tpu.models.config import LlamaConfig
 
-# shard_map's public home moved from jax.experimental to the jax namespace
-# (and its replication-check knob was renamed check_rep -> check_vma on the
-# way); resolve both once here so every mesh program builder works on
-# either side of the move. Callers use the current spelling (check_vma).
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # older jax: the experimental home + the old knob name
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(f, *args, check_vma: bool | None = None, **kwargs):
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        return _shard_map_compat(f, *args, **kwargs)
-
 DP, STAGE, SP, EP, TP = "dp", "stage", "sp", "ep", "tp"
 
 

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from cake_tpu.models.config import LlamaConfig
@@ -54,7 +55,6 @@ from cake_tpu.parallel.mesh import (
     MeshPlan,
     cache_specs,
     param_specs,
-    shard_map,
 )
 
 

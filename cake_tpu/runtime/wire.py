@@ -13,6 +13,8 @@ toolchain.
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import socket
 import struct
@@ -24,6 +26,8 @@ from pathlib import Path
 
 from cake_tpu.obs import metrics as _metrics
 
+log = logging.getLogger("cake_tpu.wire")
+
 MAGIC = 0x7CA4E701
 MAX_PAYLOAD = 512 * 1024 * 1024
 _HEADER = struct.Struct("<IBI")  # magic, msg_type, payload_len
@@ -31,6 +35,7 @@ _HEADER = struct.Struct("<IBI")  # magic, msg_type, payload_len
 _REPO_ROOT = Path(__file__).resolve().parents[2]
 _SRC = _REPO_ROOT / "native" / "cake_wire.cc"
 _SO = _REPO_ROOT / "native" / "libcakewire.so"
+_STAMP = _REPO_ROOT / "native" / "libcakewire.so.stamp"
 _BUILD_LOCK = threading.Lock()
 
 _lib = None
@@ -40,45 +45,72 @@ _lib_tried = False
 _GUARDED_BY = {"_lib": "_BUILD_LOCK", "_lib_tried": "_BUILD_LOCK"}
 
 
-def _build_native() -> bool:
+def _src_stamp() -> str:
+    return hashlib.sha256(_SRC.read_bytes()).hexdigest()
+
+
+def _build_native() -> str | None:
+    """Compile the library and stamp it with its source's hash. Returns
+    None on success, else why it failed."""
+    tmp = _SO.with_name(f".{_SO.stem}.{os.getpid()}.so")
     try:
         subprocess.run(
-            ["g++", "-O2", "-shared", "-fPIC", "-o", str(_SO), str(_SRC)],
+            ["g++", "-O2", "-shared", "-fPIC", "-o", str(tmp), str(_SRC)],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        return True
-    except Exception:
-        return False
+        os.replace(tmp, _SO)  # atomic: a racing process never loads half
+        _STAMP.write_text(_src_stamp())
+        return None
+    except subprocess.CalledProcessError as e:
+        return f"g++ failed: {e.stderr.decode(errors='replace')[-400:]}"
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"{type(e).__name__}: {e}"
+    finally:
+        tmp.unlink(missing_ok=True)
+
+
+def _load_native():
+    """The library built from THIS checkout's source, or a reason string.
+    A binary counts as current only when its stamp file holds the
+    source's content hash: a copy of the tree keeps no mtimes, and a
+    stray binary from another revision must never be loaded as this
+    one's."""
+    if not _SRC.exists():
+        # a deployed bundle may ship the binary without sources
+        if not _SO.exists():
+            return f"neither {_SO.name} nor {_SRC.name} present"
+        try:
+            return ctypes.CDLL(str(_SO))
+        except OSError as e:
+            return f"prebuilt {_SO.name} unloadable: {e}"
+    current = (_SO.exists() and _STAMP.exists()
+               and _STAMP.read_text().strip() == _src_stamp())
+    if not current:
+        why = _build_native()
+        if why is not None:
+            return why
+    try:
+        return ctypes.CDLL(str(_SO))
+    except OSError as e:
+        return f"{_SO.name} unloadable: {e}"
 
 
 def native_lib():
-    """Load (building if needed) the native wire library, or None."""
+    """Load (building if needed) the native wire library, or None when
+    only the pure-Python framing is available. Which of the two this
+    process ended up with is logged once."""
     global _lib, _lib_tried
     with _BUILD_LOCK:
         if _lib is not None or _lib_tried:
             return _lib
         _lib_tried = True
-        stale = _SO.exists() and _SRC.exists() and (
-            _SO.stat().st_mtime < _SRC.stat().st_mtime
-        )
-        if not _SO.exists() or stale:
-            # (re)build only when the source is present; a prebuilt .so
-            # shipped without sources is used as-is
-            if not _SRC.exists() or not _build_native():
-                return None
-        try:
-            lib = ctypes.CDLL(str(_SO))
-        except OSError:
-            # existing binary unloadable (e.g. built for another arch):
-            # rebuild from source and retry once
-            if not _SRC.exists() or not _build_native():
-                return None
-            try:
-                lib = ctypes.CDLL(str(_SO))
-            except OSError:
-                return None
+        lib = _load_native()
+        if isinstance(lib, str):
+            log.warning("wire transport: pure-Python framing (%s)", lib)
+            return None
+        log.info("wire transport: native %s", _SO)
         lib.cw_connect.argtypes = [ctypes.c_char_p, ctypes.c_uint16, ctypes.c_int]
         lib.cw_connect.restype = ctypes.c_int
         lib.cw_listen.argtypes = [ctypes.c_char_p, ctypes.c_uint16, ctypes.c_int]

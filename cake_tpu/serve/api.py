@@ -408,6 +408,10 @@ def _make_handler(server: ApiServer):
                     # SLO burn state (--slo-ttft-ms/--slo-tpot-ms) rides
                     # the same probe body dashboards already poll
                     body["slo"] = st["slo"]
+                if st.get("fault"):
+                    # the engine thread died: the 503 says "draining",
+                    # this says why (the compiler's or allocator's words)
+                    body["fault"] = st["fault"]
                 self._json(200 if not st["draining"] else 503, body)
             elif path.startswith("/v1/batch/"):
                 # resumable batch fetch: results recorded so far (the

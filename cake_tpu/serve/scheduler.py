@@ -284,6 +284,10 @@ class Scheduler:
         # refreshes it every loop pass, so stats()/healthz never walk
         # live engine state from a foreign thread (cakelint CK-THREAD)
         self._engine_stats: dict = {}
+        # why the engine thread died, if it did (written once by that
+        # thread, read by handlers and by the CLI's exit code): a process
+        # whose engine is gone must not report a clean run
+        self.fault: str | None = None
         # observed-throughput window for the Retry-After estimate
         self._rate_tokens = 0
         self._rate_t0 = time.perf_counter()
@@ -610,6 +614,7 @@ class Scheduler:
                if self.transfer_port else {}),
             **({"slo": self.slo.snapshot()}
                if self.slo is not None else {}),
+            **({"fault": self.fault} if self.fault else {}),
             "engine": engine_stats,
         }
 
@@ -684,6 +689,7 @@ class Scheduler:
                 self._refresh_engine_stats()
             except Exception as e:  # engine fault: fail every session
                 log.exception("engine thread fault: %s", e)
+                self.fault = f"{type(e).__name__}: {e}"
                 with self._cond:
                     # flip to draining BEFORE aborting: a dead engine must
                     # refuse new work (submit -> 503, /healthz -> 503) —

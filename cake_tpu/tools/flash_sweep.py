@@ -8,6 +8,7 @@ dispatch — the same measured-crossover treatment ``quant_matmul`` got for its
 M>=16 gate (`ops/quant.py`).
 
 Usage:  python -m cake_tpu.tools.flash_sweep [--json-out PATH]
+(``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
 Prints one JSON line per shape:
   {"path": "prefill"|"decode", "t", "s", "pallas_ms", "xla_ms", "speedup"}
@@ -23,7 +24,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from cake_tpu.tools.kernel_check import _time_ms
+from cake_tpu.tools.kernel_check import _time_ms, refuse_offchip_record
 
 
 def _audit(rec: dict) -> dict:
@@ -51,9 +52,8 @@ def sweep(json_out: str | None = None) -> list:
     ks = jax.random.split(key, 3)
 
     class _Flushed(list):
-        """append() also rewrites json_out — a mid-sweep crash (r4w2:
-        flash_sweep died on a Mosaic lowering rule mid-run and the
-        committed artifact lost every landed row) keeps its evidence."""
+        """append() also rewrites json_out — a mid-sweep crash keeps
+        the rows that already landed."""
 
         def append(self, rec) -> None:
             super().append(rec)
@@ -72,9 +72,9 @@ def sweep(json_out: str | None = None) -> list:
     # frontier rows in a long window are the one regime where flash decode
     # has a structural edge — it reads KV blocks only up to the frontier
     # while XLA's fused gemv sweeps the whole buffer. The early rows are
-    # the measurement `ops/attention.py` used to claim without evidence
-    # (r3 verdict item 8); they decide whether `auto` gets a
-    # frontier-aware dispatch or the claim dies.
+    # the measurement `ops/attention.py` used to claim without evidence;
+    # they decide whether `auto` gets a frontier-aware dispatch or the
+    # claim dies.
     for s, p in ((512, 488), (1024, 1000), (2048, 2024), (4096, 4072),
                  (8192, 8168),  # late frontier (s - 24)
                  (4096, 512), (8192, 512), (8192, 2048), (16384, 1024)):
@@ -220,9 +220,13 @@ def sweep(json_out: str | None = None) -> list:
 
 
 def main() -> int:
+    from cake_tpu.utils.compile_cache import configure
+
+    configure()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json-out", default=None)
     args = ap.parse_args()
+    refuse_offchip_record(args.json_out)
     sweep(args.json_out)
     return 0
 

@@ -31,9 +31,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from cake_tpu.parallel.mesh import STAGE, make_mesh, shard_map
+from cake_tpu.parallel.mesh import STAGE, make_mesh
 
 
 def _build_ring(mesh, n: int, reps: int):
@@ -94,6 +95,9 @@ def probe(stages: int | None = None, reps: int = 64,
 
 
 def main() -> int:
+    from cake_tpu.utils.compile_cache import configure
+
+    configure()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--stages", type=int, default=None)
     ap.add_argument("--reps", type=int, default=64)

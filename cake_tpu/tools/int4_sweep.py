@@ -1,9 +1,10 @@
 """Int4 decode-gemv sweep: find why (and fix how) m=1 int4 runs under its
 roofline.
 
-The r4 on-chip record: single-stream int4 decode measured 51 tok/s against
-a 170 tok/s weights-bound roofline, while int8 (twice the bytes) hits 84.8
-— so the m=1 int4 kernel is the bottleneck, not HBM. Working hypothesis
+The 07-31 rows of bench_results.jsonl: single-stream int4 decode measured
+51 tok/s against a 170 tok/s weights-bound roofline, while int8 (twice the
+bytes) hits 84.8 — so the m=1 int4 kernel is the bottleneck, not HBM
+(none of it re-measured on the chip tool). Working hypothesis
 (ops/pallas/quant.py:_kernel4): the per-byte nibble unpack (widen + shifts
 + converts over a [BK2, BN] block) is VPU-bound and its widened
 temporaries pressure VMEM; both effects are block-size- and
@@ -14,6 +15,7 @@ reporting achieved packed-GB/s so the gap to the ~819 GB/s v5e HBM peak is
 explicit.
 
 Usage:  python -m cake_tpu.tools.int4_sweep [--json-out PATH] [--m M]
+(``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
 One JSON line per row:
   {"k", "n", "variant", "block_n", "block_k", "ms", "gbps", "speedup_vs_xla"}
@@ -33,7 +35,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from cake_tpu.tools.kernel_check import _time_ms
+from cake_tpu.tools.kernel_check import _time_ms, refuse_offchip_record
 
 
 # Llama-3-8B decode linears (in, out): the per-token weight sweep.
@@ -46,7 +48,7 @@ SHAPES_8B = [
 
 def sweep(json_out: str | None = None, m: int = 1) -> list:
     # probe the failure-prone setup BEFORE truncating the ledger: a bad
-    # pallas import or a wedged device grant must not zero out the
+    # pallas import or a failed device init must not zero out the
     # previous run's rows (the modules stay cached for _sweep)
     import jax
 
@@ -207,10 +209,14 @@ def _sweep(out_f, m: int = 1) -> list:
 
 
 def main() -> int:
+    from cake_tpu.utils.compile_cache import configure
+
+    configure()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json-out", default=None)
     ap.add_argument("--m", type=int, default=1)
     args = ap.parse_args()
+    refuse_offchip_record(args.json_out)
     sweep(args.json_out, m=args.m)
     return 0
 

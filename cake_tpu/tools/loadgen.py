@@ -141,6 +141,8 @@ def _one_request(url: str, body: dict, timeout: float,
                     t_last = now
                     out["tokens"] += 1
                     out["ids"].append(ev["token"])
+                    if "logprobs" in ev:  # requested with "logprobs": N
+                        out.setdefault("logprobs", []).append(ev["logprobs"])
                     if ev.get("text"):
                         out["text"] += ev["text"]
                     if abort_after and out["tokens"] >= abort_after:

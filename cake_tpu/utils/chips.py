@@ -1,52 +1,35 @@
-"""Public per-chip hardware specs (one copy — bench.py and the tools
-share it so a spec correction can never leave one caller's roofline
-denominator stale).
+"""Published per-chip peaks (one copy — bench.py and the tools share it so
+a correction can never leave one caller's roofline denominator stale).
 
-Sources: published TPU spec sheets. These feed roofline DENOMINATORS
-(weights-bound ideal tok/s = HBM bytes/s / model bytes; MFU = FLOPs/s /
-peak) — they are never presented as measurements.
+Source: Google Cloud documentation, "TPU v5e" (system architecture page):
+197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s per chip. The same numbers are
+quoted in ``/opt/skills/guides/on-chip-measurement`` section 4. JAX
+reports that chip as ``device_kind`` "TPU v5 lite".
+
+These feed roofline DENOMINATORS (weights-bound ideal tok/s = HBM bytes/s
+/ model bytes; MFU = FLOP/s / peak) — they are never presented as
+measurements. A device that is not in the table is an error, not a
+default: a ratio against the wrong chip's peak is worse than none. To
+run on another chip, add its row here with the page it was read from.
 """
 
 from __future__ import annotations
 
-# chip kind substring -> HBM GB/s
-HBM_GBPS = {
-    "v5 lite": 819.0,  # v5e: 16 GiB @ 819 GB/s
-    "v5e": 819.0,
-    "v4": 1228.0,
-    "v5p": 2765.0,
-    "v6e": 1640.0,
-    "cpu": 50.0,
-}
-
-# chip kind substring -> approx bf16 peak TFLOP/s
-PEAK_TFLOPS = {
-    "v5 lite": 197.0,
-    "v5e": 197.0,
-    "v4": 275.0,
-    "v5p": 459.0,
-    "v6e": 918.0,
-    "cpu": 1.0,
-}
-
-# chip kind substring -> HBM capacity GiB
-HBM_GIB = {
-    "v5 lite": 16.0,
-    "v5e": 16.0,
-    "v4": 32.0,
-    "v5p": 95.0,
-    "v6e": 32.0,
-}
+# device_kind substring (lower case) -> spec
+HBM_GBPS = {"v5 lite": 819.0, "v5e": 819.0}      # HBM bandwidth, GB/s
+PEAK_TFLOPS = {"v5 lite": 197.0, "v5e": 197.0}   # bf16 peak, TFLOP/s
+HBM_GIB = {"v5 lite": 16.0, "v5e": 16.0}         # HBM capacity
 
 
-def device_spec(device, table: dict, default: float) -> float:
-    """Look up a spec by substring match on ``device.device_kind``."""
-    kind = getattr(device, "device_kind", "cpu").lower()
+def device_spec(device, table: dict) -> float:
+    """Look up a spec by substring match on ``device.device_kind``;
+    raises ``KeyError`` for a device this file has no published row for
+    (a CPU included)."""
+    kind = device.device_kind
     for k, v in table.items():
-        if k in kind:
+        if k in kind.lower():
             return v
-    return default
-
-
-def hbm_gbps(device) -> float:
-    return device_spec(device, HBM_GBPS, 819.0)
+    raise KeyError(
+        f"no published peaks for device_kind {kind!r} in "
+        "cake_tpu/utils/chips.py (known: "
+        f"{sorted(table)}); add its row with a source, do not assume one")

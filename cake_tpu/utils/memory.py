@@ -43,7 +43,7 @@ def hbm_budget(
     the linears at 1 byte + f32 scales, ``quant='int4'`` at half a byte
     (packed) + f32 scales (ops/quant.py layouts).
 
-    This is the planning arithmetic behind BASELINE.md configs 4/5 (70B on
+    This is the planning arithmetic behind BASELINE.json configs 4/5 (70B on
     v5e-16): it makes the "int8 is load-bearing, not optional" claim of
     SURVEY.md §7 checkable.
     """
@@ -125,17 +125,34 @@ def hbm_budget(
     }
 
 
-def memory_report() -> str:
-    parts = [f"rss {human_bytes(rss_bytes())}"]
-    try:
-        import jax
+def device_report() -> dict:
+    """What this process's JAX backend is and what each local device
+    holds: ``{"platform", "kind", "count", "devices": [{"id",
+    "bytes_in_use", "peak_bytes_in_use", "bytes_limit"}, ...]}`` --
+    platform, kind and count exactly as ``jax.devices()`` reports them
+    (the serve status carries this block, so a client can tell a chip
+    from a CPU without a probe of its own). The byte fields are None on
+    backends that keep no ``memory_stats`` (the CPU)."""
+    import jax
 
-        dev = jax.devices()[0]
-        stats = dev.memory_stats()
-        if stats and "bytes_in_use" in stats:
-            parts.append(f"hbm {human_bytes(stats['bytes_in_use'])}")
-            if "bytes_limit" in stats:
-                parts.append(f"of {human_bytes(stats['bytes_limit'])}")
-    except Exception:
-        pass
+    devs = jax.devices()
+    per = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        per.append({"id": d.id, **{
+            k: stats.get(k)
+            for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")}})
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs), "devices": per}
+
+
+def memory_report() -> str:
+    """One log line: host RSS and every local device's bytes in use (all
+    of them: a sharded model that landed on the first chip only must
+    show in the line a user reads)."""
+    parts = [f"rss {human_bytes(rss_bytes())}"]
+    used = [d["bytes_in_use"] for d in device_report()["devices"]
+            if d["bytes_in_use"] is not None]
+    if used:
+        parts.append("hbm " + "/".join(human_bytes(u) for u in used))
     return ", ".join(parts)

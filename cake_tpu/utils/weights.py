@@ -466,3 +466,89 @@ def save_llama_params(params: dict, model_dir: str | Path, num_layers: int | Non
     }
     (model_dir / "model.safetensors.index.json").write_text(json.dumps(index))
     return out
+
+
+def write_random_q8_checkpoint(config, model_dir: str | Path, seed: int,
+                               workers: int = 4) -> dict:
+    """Write a seeded random-weight checkpoint in the pre-quantized int8
+    layout (tools/quantize_model: ``<hf_name>.q8`` + ``.scale``), one
+    safetensors file per layer, streaming: host RAM holds ``workers``
+    layers of int8 bytes plus a few 512-channel float32 chunks, never the
+    model. :func:`save_llama_params` materializes the whole pytree and
+    writes float32 into one file — 29 GB at Mistral-7B size; this is what
+    lets chip_smoke.py put a full-width model in front of the real loaders
+    (``load_llama_params_on_mesh``) in about a minute.
+
+    Weights follow :func:`cake_tpu.models.llama.init_params_int8`: each
+    linear is ``normal / sqrt(fan_in)`` quantized by the one convention
+    (``quantize_linear_np``, applied to 512 output channels at a time —
+    its scale is per output channel, so chunking changes nothing), norms
+    are ones. Layer ``i`` draws from ``default_rng([seed, i])``, so the
+    bytes depend only on (config, seed), not on ``workers``. Dense
+    bias-free families only. Returns ``{"bytes", "files", "tensors"}``."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from safetensors.numpy import save_file
+
+    from cake_tpu.models.llama import layer_shapes
+    from cake_tpu.ops.quant import LAYER_LINEARS, quantize_linear_np
+
+    if config.num_local_experts or config.attention_bias:
+        raise NotImplementedError(
+            "write_random_q8_checkpoint covers the dense bias-free "
+            "families (Llama, Mistral)")
+    model_dir = Path(model_dir)
+    model_dir.mkdir(parents=True, exist_ok=True)
+    h = config.hidden_size
+    # logical (in, out) sizes of the per-layer linears
+    shapes = layer_shapes(config)
+    linears = {name: shapes[name](config) for name in LAYER_LINEARS}
+
+    def q8(rng, name: str, fan_in: int, out: int) -> dict:
+        # stored in the HF [out, in] orientation
+        q = np.empty((out, fan_in), np.int8)
+        scale = np.empty((out,), np.float32)
+        inv = np.float32(1.0 / np.sqrt(fan_in))
+        for lo in range(0, out, 512):
+            hi = min(out, lo + 512)
+            w = rng.standard_normal((fan_in, hi - lo), dtype=np.float32)
+            w *= inv
+            qc, scale[lo:hi] = quantize_linear_np(w)
+            q[lo:hi] = qc.T
+        return {f"{name}.q8": q, f"{name}.scale": scale}
+
+    def write(fname: str, tensors: dict) -> tuple[str, list, int]:
+        save_file(tensors, model_dir / fname)
+        return fname, list(tensors), sum(t.nbytes for t in tensors.values())
+
+    def layer(i: int):
+        rng = np.random.default_rng([seed, i])
+        ones = np.ones((h,), np.float32)
+        tensors = {f"model.layers.{i}.{_LAYER_MAP['attn_norm'][0]}": ones,
+                   f"model.layers.{i}.{_LAYER_MAP['mlp_norm'][0]}": ones}
+        for ours, (fan_in, out) in linears.items():
+            tensors.update(q8(
+                rng, f"model.layers.{i}.{_LAYER_MAP[ours][0]}", fan_in, out))
+        return write(f"model-layer-{i:05d}.safetensors", tensors)
+
+    def ends():
+        rng = np.random.default_rng([seed, config.num_hidden_layers])
+        embed = rng.standard_normal((config.vocab_size, h), dtype=np.float32)
+        embed *= np.float32(1.0 / np.sqrt(h))
+        tensors = {"model.embed_tokens.weight": embed,
+                   "model.norm.weight": np.ones((h,), np.float32)}
+        tensors.update(q8(rng, "lm_head.weight", h, config.vocab_size))
+        return write("model-ends.safetensors", tensors)
+
+    with ThreadPoolExecutor(max_workers=workers) as pool:
+        jobs = [pool.submit(ends)] + [
+            pool.submit(layer, i) for i in range(config.num_hidden_layers)]
+        done = [j.result() for j in jobs]
+    weight_map = {name: fname for fname, names, _ in done for name in names}
+    total = sum(n for _, _, n in done)
+    (model_dir / "model.safetensors.index.json").write_text(json.dumps({
+        "metadata": {"total_size": total, "cake_quant": "int8"},
+        "weight_map": weight_map,
+    }))
+    (model_dir / "config.json").write_text(json.dumps(config.to_hf_dict()))
+    return {"bytes": total, "files": len(done), "tensors": len(weight_map)}

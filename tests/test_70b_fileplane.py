@@ -14,8 +14,8 @@ accounting, that
 - the loader reads the checkpoint once: total bytes ~= the checkpoint's
   tensor payload (no per-shard read amplification from the 16-way mesh),
 
-and times the load (the number recorded in BASELINE.md's weight-plane
-row)."""
+and times the load (a host-side number, printed by the test and recorded
+nowhere)."""
 
 import json
 import os

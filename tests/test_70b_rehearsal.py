@@ -1,4 +1,4 @@
-"""70B-on-16 dress rehearsal (BASELINE.md configs 4/5).
+"""70B-on-16 dress rehearsal (BASELINE.json configs 4/5).
 
 Three planes, no pod required:
 - divisibility: the real Llama-3-70B geometry shards onto the v5e-16 layouts
@@ -39,7 +39,7 @@ def test_70b_divisibility_on_16(stages, tp, sp):
 
 def test_70b_hbm_budget_configs_4_and_5():
     """Config 4 (bf16) vs config 5 (int8) on v5e-16 at an 8K window
-    (numbers documented in BASELINE.md).
+    (planning arithmetic of utils.memory.hbm_budget, not a measurement).
 
     bf16 per chip: 5 layers x 1.6 GiB + 2 GiB replicated embed + 2 GiB
     lm_head + KV = ~12 GiB — fits the ~14.5 GiB usable, but with only

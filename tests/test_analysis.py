@@ -268,7 +268,7 @@ class TestTracePurity:
     def test_shard_map_body_checked(self, tmp_path):
         out = lint(tmp_path, """
             import random, jax
-            from cake_tpu.parallel.mesh import shard_map
+            from jax import shard_map
             def stage(x):
                 return x * random.random()
             f = jax.jit(shard_map(stage, mesh=None))

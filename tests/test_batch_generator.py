@@ -130,8 +130,8 @@ def test_short_stream_survives_long_stream_window_exhaustion(params):
 
 def test_window_edge_stream_keeps_batch_on_block_dispatch(params):
     """Fused-block eligibility is per-row: one stream 2 tokens from its
-    window must NOT force the whole batch into single-step dispatches (r2
-    VERDICT weak #7). Dispatch count stays ~N/block_size, the edge stream
+    window must NOT force the whole batch into single-step dispatches.
+    Dispatch count stays ~N/block_size, the edge stream
     fills its window with exactly its solo tokens, and mid-window streams
     are bit-identical to their solo runs."""
     settings = SamplerSettings(**GREEDY)
@@ -1099,3 +1099,54 @@ def test_lookahead_drain_emits_inflight_tokens(params):
     assert g.stats()["decode_dispatches"] == dispatches_before  # no new work
     want = _single_stream(params, PROMPTS[0], len(got), settings)
     assert got == want[: len(got)]
+
+
+def test_slot_not_reclaimed_while_its_rows_are_undelivered(params):
+    """A server maps a row's slots to streams when step() RETURNS the row.
+    An admission's splice emits the buffered block rows early (into the
+    pending queue); if one of them is a stream's EOS, its slot is free
+    inside the engine before the caller has seen those tokens, and a
+    second arrival queued right behind would take the slot -- the old
+    stream's tail then reaches the new stream (found by chip_smoke.py's
+    rehearsal, PR 21: a request that timed out short while a later one
+    ended on an EOS it never sampled). Every token must reach the stream
+    that sampled it."""
+    import dataclasses
+
+    settings = SamplerSettings(**GREEDY)
+    p_a, p_b, p_c = [5, 9, 4, 11], [3, 1, 4, 1, 5, 9], [7, 7, 3]
+    g = BatchGenerator(dataclasses.replace(CFG, eos_token_id=-1), params,
+                       settings=settings, block_size=8)
+    g.set_prompts([p_a, [1]], stream_ids=[0, 99])
+    solo_a = g.generate(10)[0]
+    # end stream A by EOS inside the first fused block (tokens 2..9) but
+    # at least two rows into it, so the EOS row is still queued behind
+    # another when the second arrival could claim: at A's first token
+    # from the 4th on that it has not produced before
+    k = next(i for i in range(3, 9) if solo_a[i] not in solo_a[:i])
+    # the EOS ids are host-side bookkeeping (no program closes over
+    # them): the same generator, and its compiled programs, serve again
+    g._eos_ids = {solo_a[k]}
+
+    g.set_prompts([p_a, [1]], stream_ids=[0, 99])
+    g.streams[1].done = True  # a retired slot, as the scheduler primes
+    got: dict[int, list[int]] = {}
+    seen = {}  # every stream object that ever held a slot, by id
+
+    def pump():
+        for slot, tok in enumerate(g.step()):
+            seen[g.streams[slot].stream_id] = g.streams[slot]
+            if tok is not None:
+                got.setdefault(g.streams[slot].stream_id, []).append(tok.id)
+
+    pump()  # A's first token
+    pump()  # dispatches the block: 8 rows buffered, one emitted
+    g.enqueue(p_b, 7)  # splices into the free slot, draining the buffer:
+    g.enqueue(p_c, 8)  # A's EOS is now emitted but not handed out
+    for _ in range(24):
+        pump()
+    assert got[0] == solo_a[: k + 1]
+    # what each stream was handed is what the engine recorded for it
+    for sid in (0, 7, 8):
+        assert got[sid] == seen[sid].generated[: len(got[sid])], sid
+        assert len(got[sid]) >= min(6, len(seen[sid].generated))

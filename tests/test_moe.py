@@ -103,9 +103,8 @@ def test_expert_parallel_matches_single_device(ep):
     """Experts sharded over an ep mesh axis via shard_map: the psum'd
     combine must equal the unsharded op bit-for-bit in structure (same
     routing) and numerically."""
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from cake_tpu.parallel.mesh import shard_map
 
     x, rw, wg, wu, wd = _fixtures(n=4, e=4)
     devs = jax.devices()[:ep]

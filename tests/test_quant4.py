@@ -4,7 +4,8 @@ model quality, loader equivalence, and sharded execution.
 The int4 tier is a capability the TPU build adds beyond the reference's
 f16/bf16 dtype plane (`cake/mod.rs:56-62`): decode is HBM-bandwidth-bound,
 so halving the int8 bytes again roughly doubles the single-stream roofline
-(BASELINE.md). The adjacent-pair packing convention (ops/quant.py) is
+(the roofline, not the rate: the int4 07-31 rows of bench_results.jsonl
+reached 0.28-0.30 of it). The adjacent-pair packing convention (ops/quant.py) is
 load-bearing for tensor parallelism — tested explicitly here.
 """
 
@@ -631,7 +632,7 @@ def test_int4_mesh_spec_vs_perchannel_checkpoint_rejected(cfg, params,
 
 def test_hbm_budget_prices_grouped_scales():
     """Grouped int4 scale bytes scale with in_dim/group — a near-limit
-    config must see them (the planning arithmetic of BASELINE.md)."""
+    config must see them (the planning arithmetic of utils.memory)."""
     from cake_tpu.models.config import LlamaConfig
     from cake_tpu.utils.memory import hbm_budget
 

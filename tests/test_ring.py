@@ -9,10 +9,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from cake_tpu.ops import ring
-from cake_tpu.parallel.mesh import shard_map
 from cake_tpu.ops.attention import _attend_xla
 
 

@@ -470,6 +470,10 @@ def test_engine_fault_stops_accepting(params):
     assert sched.stats()["draining"]
     with pytest.raises(Draining):
         sched.submit(Session([1], max_tokens=2))
+    # and it says why: /healthz forwards this, and `--mode serve` exits
+    # non-zero on it instead of logging a clean drain
+    assert sched.fault == "RuntimeError: boom"
+    assert sched.stats()["fault"] == sched.fault
 
 
 def test_loadgen_closed_and_open_loop(tok_server):
