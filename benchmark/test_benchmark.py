@@ -1,0 +1,363 @@
+"""The benchmark's own tests: CPU only, well under half a minute.
+
+    python -m pytest benchmark/test_benchmark.py -q
+
+They live under ``benchmark/`` because the benchmark's ``paths`` may hold
+nothing outside its own directories; tier-1 (``pytest tests/``) does not
+collect them. Nothing here describes a TPU topology or starts a server
+at import time.
+"""
+
+from __future__ import annotations
+
+import json
+import re
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+HERE = Path(__file__).resolve().parent
+ROOT = HERE.parent
+sys.path.insert(0, str(HERE))
+
+import metrics  # noqa: E402
+import shapes  # noqa: E402
+import trace_reduce  # noqa: E402
+import traffic  # noqa: E402
+import weights  # noqa: E402
+
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+CELLS = [w["name"] for w in BENCH["workloads"]]
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+def _timeline():
+    return json.loads((HERE / "testdata" / "timeline.json").read_text())
+
+
+# -- metric arithmetic on a hand-made timeline --------------------------------
+
+def test_tokens_per_s_counts_only_tokens_inside_the_window():
+    t = _timeline()
+    # 3 of a's tokens, 4 of b's, c's one and 1 of d's arrive inside [10, 20)
+    assert metrics.tokens_in_window(t["records"], t["window"]) == 9
+    assert metrics.tokens_per_s(t["records"], t["window"]) == pytest.approx(0.9)
+
+
+def test_ttft_runs_from_the_due_instant_not_the_send():
+    t = _timeline()
+    b = t["records"][1]  # due 11.0, sent 11.4 (the generator ran late)
+    assert metrics.ttft_ms(b, "due") == pytest.approx(1000.0)
+    assert metrics.ttft_ms(b, "sent") == pytest.approx(600.0)
+    late = metrics.lateness_ms(t["records"])
+    assert late["worst_ms"] == pytest.approx(400.0)
+
+
+def test_tpot_is_per_request_and_the_median_over_requests():
+    t = _timeline()
+    a, b, c, d = t["records"]
+    assert metrics.tpot_ms(a) == pytest.approx(1000.0)   # 4 tokens over 3 s
+    assert metrics.tpot_ms(b) == pytest.approx(500.0)
+    assert metrics.tpot_ms(c) is None                    # one token: no gap
+    assert metrics.tpot_ms(d) == pytest.approx(700.0)
+    assert metrics.tpot_p50_ms(t["records"]) == pytest.approx(700.0)
+
+
+def test_gaps_are_pooled_and_clipped_to_the_window():
+    t = _timeline()
+    gaps = sorted(metrics.gaps_ms(t["records"], t["window"]))
+    # a's first gap ends at 10.0 (inside); d's ends at 20.5 (outside)
+    assert gaps == pytest.approx([500.0] * 3 + [1000.0] * 3)
+
+
+@pytest.mark.parametrize("xs,q,want", [
+    ([1, 2, 3, 4, 5], 50, 3.0), ([1, 2, 3, 4], 50, 2.5),
+    (list(range(101)), 90, 90.0), ([7.0], 99, 7.0), ([], 50, None)])
+def test_percentile(xs, q, want):
+    assert metrics.percentile(xs, q) == want
+
+
+def test_tail_mean_moves_with_every_sample_of_the_tail():
+    xs = [0.0] * 875 + [212.0] * 115 + [251.0] * 10
+    assert metrics.percentile(xs, 99) == pytest.approx(212.0, abs=1.0)
+    assert metrics.tail_mean(xs, 0.01) == pytest.approx(251.0)
+    assert metrics.tail_mean(xs[:-1] + [212.0], 0.01) == pytest.approx(247.1)
+    assert metrics.tail_mean([5.0], 0.01) == 5.0
+    assert metrics.tail_mean([], 0.01) is None
+
+
+@pytest.mark.parametrize("n,q,beyond", [(100, 90, 10), (100, 95, 5),
+                                        (99, 90, 9), (4000, 99, 40)])
+def test_sample_rule_counts_what_lies_beyond(n, q, beyond):
+    assert metrics.samples_beyond(n, q) == beyond
+
+
+def test_end_to_end_takes_ttft_from_the_due_instant():
+    t = _timeline()
+    e2e = metrics.end_to_end(t["records"], t["window"])
+    assert set(e2e) == {"tokens_per_s", "tpot_p50_ms", "ttft_mean_ms"}
+    due = [metrics.ttft_ms(r, "due") for r in t["records"]]
+    assert e2e["ttft_mean_ms"] == pytest.approx(sum(due) / len(due))
+    assert e2e["ttft_mean_ms"] != pytest.approx(
+        metrics.ttft_mean_ms(t["records"], "sent"))
+
+
+# -- the schedule ---------------------------------------------------------------
+
+def _mix(name):
+    return json.loads((HERE / "traffic" / f"{name}.json").read_text())
+
+
+def test_same_seed_same_requests_other_seed_same_set_other_order():
+    mix = _mix("chat-r80")
+    one = traffic.Schedule(mix, 3000000001, 45.0, 32000, 8)
+    two = traffic.Schedule(mix, 3000000001, 45.0, 32000, 8)
+    other = traffic.Schedule(mix, 7, 45.0, 32000, 8)
+    reqs = [one.request(k) for k in range(one.count)]
+    assert reqs == [two.request(k) for k in range(two.count)]
+    shape = lambda s: [(len(s.request(k)["prompt_ids"]),  # noqa: E731
+                        s.request(k)["max_tokens"]) for k in range(s.count)]
+    gaps = lambda s: [round(b - a, 9) for a, b in zip(  # noqa: E731
+        s._due, s._due[1:])]
+    # the seed makes the traffic: the same sizes and the same gaps ...
+    assert sorted(shape(one)) == sorted(shape(other))
+    assert sorted(gaps(one)) == sorted(gaps(other))
+    # ... each in an order of its own, and other token ids
+    assert shape(one) != shape(other) and gaps(one) != gaps(other)
+    assert one.count == round(mix["rate_rps"] * 45.0)
+    assert 0.0 == one._due[0] and max(one._due) < 45.0
+    assert max(other._due) == pytest.approx(max(one._due))
+
+
+def test_closed_loop_cycles_through_one_set():
+    mix = _mix("decode-full")
+    s = traffic.Schedule(mix, 5, 45.0, 32000, 8)
+    assert s.clients == 8 and s.count is None
+    n = mix["set_size"]
+    first = sorted(len(s.request(k)["prompt_ids"]) for k in range(n))
+    second = sorted(len(s.request(k)["prompt_ids"]) for k in range(n, 2 * n))
+    assert first == second
+    lens = [len(s.request(k)["prompt_ids"]) for k in range(n)]
+    other = traffic.Schedule(mix, 6, 45.0, 32000, 8)
+    assert sorted(lens) == sorted(len(other.request(k)["prompt_ids"])
+                                  for k in range(n))
+    assert lens != [len(other.request(k)["prompt_ids"]) for k in range(n)]
+    assert min(lens) >= 64 and max(lens) <= 512
+    assert s.admission_buckets(2048) == sorted(s.admission_buckets(2048))
+    assert all(64 <= n <= 512 for n in s.admission_buckets(2048))
+
+
+def test_shared_prefix_groups_share_their_opening_tokens():
+    mix = dict(_mix("decode-full"),
+               shared_prefix={"groups": 2, "len": 32, "share": 1.0})
+    s = traffic.Schedule(mix, 1, 10.0, 32000, 8)
+    opens = {tuple(s.request(k)["prompt_ids"][:32]) for k in range(32)}
+    assert len(opens) == 2
+    assert all(len(s.request(k)["prompt_ids"]) > 32 for k in range(32))
+
+
+# -- the trace reduction --------------------------------------------------------
+
+def test_trace_reduction_on_the_recorded_trace():
+    dumped = json.loads((HERE / "testdata" / "trace_dump.json").read_text())
+    want = json.loads((HERE / "testdata" / "trace_expect.json").read_text())
+    got = trace_reduce.reduce(dumped)
+    dev = got["devices"][0]
+    assert got["window_s"] == pytest.approx(want["window_s"])
+    assert dev["busy_s"] == pytest.approx(want["busy_s"])
+    assert 0 < dev["busy_s"] <= got["window_s"]
+    assert dev["ops"][0][0] == want["top_op"]
+    for name, m in want["modules"].items():
+        assert dev["modules"][name]["count"] == m["count"]
+        assert dev["modules"][name]["seconds"] == pytest.approx(m["seconds"])
+    assert len(dev["gaps"]) <= trace_reduce.TOP_GAPS
+
+
+def test_containers_carry_only_their_self_time():
+    # a 100 ns while-loop holding two 30 ns fusions, then a lone 10 ns copy
+    ev = [("while", 0, 100), ("fusion.1", 10, 40), ("fusion.2", 50, 80),
+          ("copy", 120, 130)]
+    st = {n: s for n, _, _, s in trace_reduce.self_times(ev)}
+    assert st == {"while": 40, "fusion.1": 30, "fusion.2": 30, "copy": 10}
+    dev = trace_reduce.reduce_device(
+        {"name": "d", "ops": [[n, lo, hi - lo] for n, lo, hi in ev],
+         "modules": [["jit_step(7)", 0, 130]]},
+        [{"thread": "main", "events": [["Engine.wait", 95, 30]]}], 0, 200)
+    assert dev["busy_s"] == pytest.approx(110e-9)
+    assert dev["modules"]["jit_step(7)"] == {"seconds": 130e-9, "count": 1}
+    assert dev["gaps"][0]["seconds"] == pytest.approx(70e-9)   # 130..200
+    assert dev["gaps"][1]["host"] == "Engine.wait"              # 100..120
+
+
+def test_collective_time_is_the_collectives_self_time():
+    dev = trace_reduce.reduce_device(
+        {"name": "d", "modules": [],
+         "ops": [["fusion.1", 0, 50], ["all-reduce.3", 50, 20],
+                 ["collective-permute.1", 80, 10]]}, [], 0, 100)
+    assert dev["collective_s"] == pytest.approx(30e-9)
+
+
+# -- bytes, against the program's own parameter shapes ---------------------------
+
+@pytest.mark.parametrize("config", ["mistral7b-int8", "mixtral8x7b-cut"])
+def test_weight_bytes_match_the_programs_parameter_shapes(config):
+    import jax
+
+    from cake_tpu.models.config import LlamaConfig
+    from cake_tpu.models.llama import init_params, init_params_int8
+
+    cfg = json.loads((HERE / "configs" / f"{config}.json").read_text())
+    layout = cfg["bench"]["weights"]["layout"]
+    lc = LlamaConfig.from_hf_dict(weights.hf_config(cfg), dtype="bfloat16")
+    init = init_params_int8 if layout == "q8" else init_params
+    tree = jax.eval_shape(lambda k: init(lc, k), jax.random.PRNGKey(0))
+    held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+    assert shapes.weight_bytes(cfg, layout) == pytest.approx(held, rel=0.002)
+    assert weights.checkpoint_bytes(cfg, layout) >= 0.99 * held
+
+
+def test_decode_step_reads_routed_experts_and_live_cache_only():
+    cfg = json.loads((HERE / "configs" / "mixtral8x7b-cut.json").read_text())
+    assert shapes.expected_experts(8, 2, 1) == pytest.approx(2.0)
+    assert 7.0 < shapes.expected_experts(8, 2, 8) < 7.3
+    one = shapes.decode_step_bytes(cfg, "q8", 1, 100)
+    full = shapes.decode_step_bytes(cfg, "q8", 8, 100)
+    assert one < 0.4 * full
+    assert shapes.kv_bytes(cfg, 100, 8) == 8 * 100 * 7 * 2 * 8 * 128 * 2
+
+
+# -- the checkpoint writer and the reference -------------------------------------
+
+def test_checkpoint_round_trip_and_reference(tmp_path):
+    import numpy as np
+
+    import reference
+
+    cfg = json.loads((HERE / "configs" / "mixtral8x7b-cut.json").read_text())
+    cfg = dict(cfg, **{k: v for k, v in cfg["bench"]["rehearsal"].items()
+                       if k != "bench"})
+    for layout in ("q8", "bf16"):
+        d = tmp_path / layout
+        info = weights.write_checkpoint(cfg, layout, 3, d, workers=2)
+        assert info["bytes"] == weights.checkpoint_bytes(cfg, layout)
+        again = tmp_path / (layout + "2")
+        weights.write_checkpoint(cfg, layout, 3, again, workers=1)
+        for f in sorted(p.name for p in d.iterdir()):
+            assert (d / f).read_bytes() == (again / f).read_bytes(), f
+        ck = weights.Checkpoint(d)
+        w = ck.f32("model.layers.0.self_attn.q_proj.weight")
+        assert w.shape == (128, 128) and 0.05 < w.std() * 128 ** 0.5 < 2.0
+        out, two = reference.chosen_logprobs(
+            cfg, d, [([5, 9, 11, 40], [7, 8, 9]), ([9, 5], [3, 4])])
+        alone, = reference.chosen_logprobs(cfg, d, [([9, 5], [3, 4])])
+        assert two == alone  # sequences share weights, not positions
+        assert len(out["logprob"]) == 3
+        assert all(b >= l for b, l in zip(out["best_logprob"], out["logprob"]))
+        assert np.isfinite(out["logprob"]).all()
+
+
+def test_correct_holds_the_servers_ids_to_the_references_margin(tmp_path):
+    import reference
+    import run
+
+    cfg = json.loads((HERE / "configs" / "mistral7b-int8.json").read_text())
+    cfg = run.overlay(cfg, cfg["bench"]["rehearsal"])
+    d = tmp_path / "ckpt"
+    weights.write_checkpoint(cfg, "q8", 3, d, workers=1)
+    prompt = [5, 9, 11, 40, 7]
+    # the reference's own greedy continuation, one token at a time
+    ids = []
+    for _ in range(3):
+        out, = reference.chosen_logprobs(cfg, d, [(prompt, ids + [0])])
+        ids.append(out["best"][-1])
+    good = [{"prompt": prompt, "ids": ids, "repeat_same": True}]
+    ok, worst = run.check_reference(good, cfg, "t", d, tmp_path / "c")
+    assert ok and worst == 0.0
+    wrong = [{"prompt": prompt, "ids": ids[:2] + [(ids[2] + 1) % 500]}]
+    ok, worst = run.check_reference(wrong, cfg, "t", d, tmp_path / "c")
+    assert not ok and worst > cfg["bench"]["margin_tol"]
+    twice = [dict(good[0], repeat_same=False)]
+    assert not run.check_reference(twice, cfg, "t", d, tmp_path / "c")[0]
+
+
+# -- every cell's files are found by name; names keep to the contract ------------
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_files_found_by_name(cell):
+    import run
+
+    c = run.load_cell(cell)
+    assert c["cfg"]["bench"]["chips"] == c["cell"]["chips"]
+    assert any(m["name"] == "setup_s" for m in c["end_to_end"])
+    assert len(c["end_to_end"]) >= 2 and c["per_layer"]
+    for m in c["per_layer"]:
+        assert callable(run.load_reader(m["name"])), m["name"]
+        assert m["moves"] in {e["name"] for e in c["end_to_end"]}, m["name"]
+    conf = {x["name"]: x for x in BENCH["configs"]}[c["cell"]["config"]]
+    assert sorted(conf["reduced"]) == sorted(c["cfg"]["reduced"])
+    assert conf["source"].split("#")[0].startswith(
+        c["cfg"]["source"].split("/blob/")[0])
+
+
+def test_names_and_units_keep_to_the_contract():
+    metrics_ = BENCH["end_to_end"] + BENCH["per_layer"]
+    names = [m["name"] for m in metrics_]
+    assert len(set(names)) == len(names)
+    for m in metrics_:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+    for e in BENCH["end_to_end"]:
+        assert 0 < e["bound"] <= 0.1 and e["source"] in ("host_clock",
+                                                         "device_trace")
+    for w in BENCH["workloads"]:
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
+    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) <= max(
+        1, len(BENCH["workloads"]) // 4)
+    source = (HERE / "run.py").read_text()
+    for word in CELLS + [c["name"] for c in BENCH["configs"]] + sorted(
+            {w["traffic"] for w in BENCH["workloads"]}):
+        assert f'"{word}"' not in source and f"'{word}'" not in source
+
+
+def test_unknown_device_kind_is_an_error():
+    import run
+
+    assert run.peaks_for("TPU v5 lite")["hbm_gb_per_s"] == 819.0
+    with pytest.raises(run.BenchFailure):
+        run.peaks_for("TPU v99")
+
+
+# -- one rehearsal, end to end ----------------------------------------------------
+
+def test_rehearsal_of_one_cell_end_to_end():
+    out = subprocess.run(
+        [sys.executable, str(HERE / "run.py"), "--workload",
+         "mixtral8x7b-cut.decode-full", "--rehearse", "--seed", "3000000001",
+         "--seconds", "2"], capture_output=True, text=True, timeout=240,
+        cwd=ROOT)
+    assert out.returncode == 0, out.stderr[-2000:]
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert last["rehearsal"] is True and last["metrics"] == {}
+    assert last["correct"] is True and last["failed"] == 0
+    assert last["attempted"] > 0
+    assert set(last["would_report"]) == {"tokens_per_s", "tpot_p50_ms",
+                                         "ttft_mean_ms", "setup_s"}
+
+
+def test_no_result_without_the_program(tmp_path):
+    import shutil
+
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(HERE, tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    out = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload", CELLS[0],
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        capture_output=True, text=True, timeout=60, cwd=tmp_path)
+    assert out.returncode != 0 and out.stdout.strip() == ""
