@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Run one cell of BENCHMARK.json once, on the chips this machine holds.
 
-    python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
     python benchmark/run.py --workload <cell> --sweep 1.5,2,2.5,3   (find a rate)
     python benchmark/run.py --workload <cell> --rehearse            (tiny, CPU)
 
@@ -14,8 +14,13 @@ draw, probes correctness, measures for ``--seconds``, SIGTERMs the server
 and holds it to a clean drain. Its last line of output is the result
 (``correct``, ``attempted``, ``failed``, ``metrics``, ``device``): the
 cell's end-to-end metrics with ``--trace 0``, its per-layer metrics (and
-``breakdown``) with ``--trace 1``. A run that finds no TPU, a server that
-answered from the CPU or did not drain: non-zero exit and no result.
+``breakdown``) with ``--trace 1``, both side by side with ``--trace 2``.
+A ``--trace 2`` run IS a ``--trace 0`` run up to the moment its window
+closes; only then does it ask the server (``POST /debug/trace``, the
+program's capture control) to trace a few seconds of the same mix.
+``--trace 1`` asks the same control in the middle of the window. A run
+that finds no TPU, a server that answered from the CPU or did not
+drain: non-zero exit and no result.
 README.md in this directory says how to add a cell without touching
 this file.
 """
@@ -36,6 +41,8 @@ import subprocess
 import sys
 import threading
 import time
+import urllib.error
+import urllib.request
 from pathlib import Path
 
 T_START = time.perf_counter()
@@ -52,6 +59,8 @@ import weights  # noqa: E402
 CACHE = ROOT / ".bench_cache"
 READY_TIMEOUT_S = 900.0  # a first run loads 7-15 GB and compiles
 PROBE_TOKENS = 16  # two decode blocks after the admission's token
+TAIL_LEAD_S = 3.0  # --trace 2: traffic before the capture opens, so that
+#                    slots are full again or arrivals are in flight
 
 
 class BenchFailure(Exception):
@@ -152,25 +161,24 @@ def ensure_checkpoint(cfg: dict, tag: str, cache: Path) -> tuple[Path, float]:
 
 class Server:
     def __init__(self, cfg: dict, model_dir: Path, run_dir: Path,
-                 chips: int, rehearse: bool, trace: bool):
+                 chips: int, rehearse: bool, every_step: bool):
         b = cfg["bench"]
         with socket.socket() as s:
             s.bind(("127.0.0.1", 0))
             self.port = s.getsockname()[1]
         self.url = f"http://127.0.0.1:{self.port}"
         self.log_path = run_dir / "server.log"
-        self.ctl = run_dir / "trace"
+        self.captures: list[str] = []  # directories the control named
         fill = dict(b, eos=cfg["eos_token_id"])
         args = [str(a).format(**fill) for a in b["server_args"]]
-        cmd = [sys.executable, str(HERE / "serve_child.py")]
-        if trace:
-            shutil.rmtree(self.ctl, ignore_errors=True)
-            self.ctl.mkdir(parents=True)
-            # every engine step stamps its phases in the traced run
-            cmd += ["--bench-trace-dir", str(self.ctl)]
+        if every_step:
+            # --trace 1: every engine step of the window stamps its
+            # phases (a --trace 2 run starts like --trace 0; its capture
+            # stamps every step while it is open)
             args += ["--prof-sample", "1"]
-        cmd += ["--mode", "serve", "--model", str(model_dir),
-                "--serve-port", str(self.port), *args]
+        cmd = [sys.executable, str(HERE / "serve_child.py"),
+               "--mode", "serve", "--model", str(model_dir),
+               "--serve-port", str(self.port), *args]
         env = dict(os.environ, PYTHONPATH=str(ROOT))
         # the compile cache: a fixed directory inside this checkout, and
         # every program in it, however quickly it compiled
@@ -216,6 +224,36 @@ class Server:
 
     def prof(self) -> dict:
         return client.get_json(self.url + "/debug/prof", 30.0)
+
+    def snapshot(self) -> dict:
+        """The server's counters at this instant: what a reader's
+        ``before`` and ``after`` hold."""
+        return {"status": self.status(), "prof": self.prof()}
+
+    def capture(self, action: str) -> dict:
+        """Ask the program's capture control (``POST /debug/trace``) to
+        ``start`` or ``stop``: the answer carries the directory and the
+        server's clocks at that instant (``unix_ns``, ``perf_s``)."""
+        req = urllib.request.Request(
+            self.url + "/debug/trace",
+            data=json.dumps({"action": action}).encode(),
+            headers={"Content-Type": "application/json"})
+        try:
+            with urllib.request.urlopen(req, timeout=280.0) as r:
+                out = json.loads(r.read())
+        except (OSError, ValueError) as e:
+            detail = e.read().decode(errors="replace")[:300] if isinstance(
+                e, urllib.error.HTTPError) else ""
+            raise BenchFailure(f"capture {action} failed: {e} {detail}")
+        if out["dir"] not in self.captures:
+            self.captures.append(out["dir"])
+        return out
+
+    def drop_captures(self) -> None:
+        """Delete every trace the control wrote (they are large)."""
+        for d in self.captures:
+            shutil.rmtree(d, ignore_errors=True)
+        self.captures.clear()
 
     def idle(self, timeout_s: float) -> bool:
         """Wait until nothing is queued or running."""
@@ -356,20 +394,38 @@ def check_reference(probes: list[dict], cfg: dict, tag: str, model_dir: Path,
 # ---------------------------------------------------------------------------
 
 def measure(srv: Server, schedule, mix: dict, seconds: float,
-            trace: bool) -> dict:
-    """Open the window: run the mix, poll the queue, and in a traced run
-    ask the child for a trace in the middle of it."""
-    timers = []
-    if trace:
-        span = min(mix.get("trace_s", 4.0), seconds / 2)
-        for name, at in (("start", (seconds - span) / 2),
-                         ("stop", (seconds + span) / 2)):
-            t = threading.Timer(at, (srv.ctl / name).touch)
-            t.daemon = True
-            timers.append(t)
-    before = {"status": srv.status(), "prof": srv.prof()}
-    for t in timers:
-        t.start()
+            capture: tuple[float, float] | None = None,
+            around_capture: bool = False) -> dict:
+    """Open a window: run the mix and poll the queue. With ``capture``
+    (seconds after the window opens at which to start and to stop), ask
+    the server for a trace meanwhile. ``before`` and ``after`` hold the
+    server's counters on either side of the window or, with
+    ``around_capture``, on either side of the capture (where every
+    engine step stamps its phases)."""
+    got: dict = {}
+
+    def run_capture(t0: float) -> None:
+        try:
+            time.sleep(max(0.0, t0 + capture[0] - time.perf_counter()))
+            if around_capture:
+                got["before"] = srv.snapshot()
+            t_ask = time.perf_counter()
+            got["started"] = srv.capture("start")
+            got["start_took_s"] = time.perf_counter() - t_ask
+            time.sleep(max(0.0, t0 + capture[1] - time.perf_counter()))
+            t_ask = time.perf_counter()
+            got["stopped"] = srv.capture("stop")
+            got["stop_took_s"] = time.perf_counter() - t_ask
+            if around_capture:
+                got["after"] = srv.snapshot()
+        except (BenchFailure, OSError, ValueError) as e:
+            got["error"] = e
+
+    before = srv.snapshot()
+    if capture:
+        th = threading.Thread(target=run_capture, daemon=True,
+                              args=(time.perf_counter(),))
+        th.start()
     with client.Poller(srv.url) as poll:
         if schedule.open:
             records, window = client.run_open(
@@ -378,33 +434,67 @@ def measure(srv: Server, schedule, mix: dict, seconds: float,
             records, window = client.run_closed(
                 srv.url, schedule, schedule.clients, seconds,
                 mix["drain_limit_s"])
-    srv.idle(mix["drain_limit_s"])
-    after = {"status": srv.status(), "prof": srv.prof()}
     trace_span = None
-    if trace:
-        deadline = time.monotonic() + 120
-        while not (srv.ctl / "done").exists():
-            if time.monotonic() > deadline:
-                raise BenchFailure("the trace was not written")
-            time.sleep(0.1)
-        trace_span = [json.loads((srv.ctl / n).read_text())
-                      for n in ("started", "done")]
+    if capture:
+        # the profiler collects the device's trace as it stops, which
+        # can outlast the drain: ask the server nothing meanwhile
+        th.join(timeout=300)
+        if "error" in got or "stopped" not in got:
+            raise BenchFailure("the trace was not written: "
+                               f"{got.get('error', 'the capture hangs')}")
+        trace_span = [got["started"], got["stopped"]]
+    srv.idle(mix["drain_limit_s"])
+    after = srv.snapshot()
     return {"records": records, "window": window, "poll": poll.samples,
-            "before": before, "after": after, "trace_span": trace_span}
+            "before": got.get("before", before),
+            "after": got.get("after", after), "trace_span": trace_span,
+            "capture_took_s": [got.get("start_took_s"),
+                               got.get("stop_took_s")]}
 
 
-def reduce_trace(srv: Server, run_dir: Path) -> dict | None:
+def trace_tail(srv: Server, a, mix: dict, cfg: dict, vocab: int) -> dict:
+    """``--trace 2``, after the measured window has closed and drained:
+    start and stop the profiler once and throw that trace away (the
+    cost of the first start falls into no number), then run a tail of
+    the same mix -- a fresh schedule of the same seed -- and trace
+    ``trace_s`` of it after a lead-in. Returns what ``measure`` returns
+    for a traced window, counters taken around the capture."""
+    srv.capture("start")
+    srv.capture("stop")
+    srv.drop_captures()
+    span = min(mix.get("trace_s", 4.0), a.seconds / 2)
+    lead = min(TAIL_LEAD_S, a.seconds / 2)
+    seconds = lead + span + 0.5
+    schedule = traffic.Schedule(mix, a.seed, seconds, vocab,
+                                cfg["bench"]["slots"])
+    return measure(srv, schedule, mix, seconds, (lead, lead + span),
+                   around_capture=True)
+
+
+def reduce_trace(trace_dir: str, run_dir: Path) -> dict | None:
     """After the server has exited: a child of its own reads the trace
     (it imports JAX, held to the CPU) and writes the reduction."""
     out = run_dir / "trace_reduced.json"
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     rc = subprocess.run([sys.executable, str(HERE / "trace_reduce.py"),
-                         str(srv.ctl / "profile"), str(out)],
-                        env=env, cwd=ROOT).returncode
-    shutil.rmtree(srv.ctl / "profile", ignore_errors=True)  # large
+                         trace_dir, str(out)], env=env, cwd=ROOT).returncode
     if rc != 0:
         raise BenchFailure(f"trace_reduce.py exited {rc}")
     return json.loads(out.read_text())
+
+
+def context_for(metric: dict, window: dict, tail: dict | None) -> dict:
+    """Which context a per-layer reader gets, by the metric's ``source``.
+    In a ``--trace 2`` run (``tail`` given) a ``device_trace`` or
+    ``program_span`` metric reads the traced tail (the device trace, and
+    counters taken around the capture, where every step is stamped); a
+    ``host_clock`` or ``program_counter`` metric reads the measured
+    window (all its records and counter deltas, taken with no profiler
+    open). A ``--trace 1`` run has the one context."""
+    if tail is not None and metric["source"] in ("device_trace",
+                                                 "program_span"):
+        return tail
+    return window
 
 
 def breakdown(reduced: dict, m: dict) -> dict:
@@ -444,7 +534,8 @@ def run_cell(a, cell: dict) -> dict:
     model_dir, wrote_s = ensure_checkpoint(cfg, tag, cache)
     schedule = traffic.Schedule(mix, a.seed, a.seconds, vocab,
                                 cfg["bench"]["slots"])
-    srv = Server(cfg, model_dir, run_dir, chips, a.rehearse, bool(a.trace))
+    srv = Server(cfg, model_dir, run_dir, chips, a.rehearse,
+                 every_step=a.trace == 1)
     try:
         srv.wait_ready()
         dev = srv.status()["device"]
@@ -458,12 +549,22 @@ def run_cell(a, cell: dict) -> dict:
         if a.sweep:
             return sweep(a, srv, mix, cfg, vocab)
         setup_s = time.perf_counter() - T_START
-        m = measure(srv, schedule, mix, a.seconds, bool(a.trace))
+        mid = None
+        if a.trace == 1:  # the profiler open in the middle of the window
+            span = min(mix.get("trace_s", 4.0), a.seconds / 2)
+            mid = ((a.seconds - span) / 2, (a.seconds + span) / 2)
+        m = measure(srv, schedule, mix, a.seconds, mid)
+        # --trace 2: the window is closed and every end-to-end number is
+        # taken; only now is anything of the profiler started
+        tail = trace_tail(srv, a, mix, cfg, vocab) if a.trace == 2 else None
         loaded_s = srv.loaded_s()
-        srv.stop()
+        srv.stop()  # the server has exited: the chip is free
+        traced = tail or (m if a.trace else None)
+        reduced = reduce_trace(traced["trace_span"][0]["dir"],
+                               run_dir) if traced else None
     finally:
         srv.kill()
-    reduced = reduce_trace(srv, run_dir) if a.trace else None
+        srv.drop_captures()
     ref_ok, worst = check_reference(probes, cfg, tag, model_dir, cache)
 
     records = m["records"]
@@ -489,23 +590,35 @@ def run_cell(a, cell: dict) -> dict:
                             - m["before"]["prof"]["compiles"]),
         checkpoint_written_s=wrote_s, worst_margin=worst)
 
-    ctx = dict(m, cfg=cfg, mix=mix, chips=chips, open_loop=schedule.open,
-               trace=reduced, loaded_s=loaded_s, setup_s=setup_s,
-               peaks=None if a.rehearse else peaks_for(dev["kind"]))
+    shared = dict(cfg=cfg, mix=mix, chips=chips, open_loop=schedule.open,
+                  loaded_s=loaded_s, setup_s=setup_s,
+                  peaks=None if a.rehearse else peaks_for(dev["kind"]))
+    ctx = dict(m, trace=reduced if a.trace == 1 else None, **shared)
+    tail_ctx = dict(tail, trace=reduced, **shared) if tail else None
+    if tail:
+        t0, t1 = (c["perf_s"] for c in tail["trace_span"])
+        say(phase="tail", attempted=len(tail["records"]),
+            failed=sum(not r["ok"] for r in tail["records"]),
+            traced_s=t1 - t0,
+            tokens_per_s_traced=metrics.tokens_per_s(tail["records"],
+                                                     (t0, t1)),
+            capture=tail["trace_span"][1],
+            capture_took_s=tail["capture_took_s"])
+        dev = tail["after"]["status"]["device"]  # the whole run's peak
     values = {}
+    if a.trace != 1:  # a --trace 1 window ran under the profiler
+        e2e = dict(metrics.end_to_end(records, m["window"]), setup_s=setup_s)
+        values.update((mt["name"], e2e.get(mt["name"]))
+                      for mt in cell["end_to_end"])
     if a.trace:
-        wanted = cell["per_layer"]
-        for mt in wanted:
+        for mt in cell["per_layer"]:
             read = load_reader(mt["name"])
             if read is None:
                 raise BenchFailure(f"no reader benchmark/layer_metrics/"
                                    f"{mt['name']}.py")
-            values[mt["name"]] = read(ctx)
-    else:
-        wanted = cell["end_to_end"]
-        e2e = dict(metrics.end_to_end(records, m["window"]), setup_s=setup_s)
-        values = {mt["name"]: e2e.get(mt["name"]) for mt in wanted}
-    units = {mt["name"]: mt["unit"] for mt in wanted}
+            values[mt["name"]] = read(context_for(mt, ctx, tail_ctx))
+    units = {mt["name"]: mt["unit"]
+             for mt in cell["end_to_end"] + cell["per_layer"]}
     result = {
         "correct": bool(ref_ok and whole),
         "attempted": len(records),
@@ -520,7 +633,7 @@ def run_cell(a, cell: dict) -> dict:
         busy = [d["busy_s"] for d in reduced["devices"][:chips]]
         result["device"]["busy_s"] = sum(busy) / len(busy)
         result["device"]["window_s"] = reduced["window_s"]
-        result["breakdown"] = breakdown(reduced, m)
+        result["breakdown"] = breakdown(reduced, traced)
     if a.rehearse:
         # a rehearsal proves the paths; its times are a CPU's and are
         # never printed under a metric's name
@@ -538,7 +651,7 @@ def sweep(a, srv: Server, mix: dict, cfg: dict, vocab: int) -> dict:
         m2 = dict(mix, rate_rps=rate)
         sched = traffic.Schedule(m2, a.seed, a.seconds, vocab,
                                  cfg["bench"]["slots"])
-        m = measure(srv, sched, m2, a.seconds, False)
+        m = measure(srv, sched, m2, a.seconds)
         t0, t1 = m["window"]
         third = [q for t, q, _ in m["poll"] if t0 + (t1 - t0) * 0.30 <= t
                  < t0 + (t1 - t0) * 0.40]
@@ -564,7 +677,10 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
-    ap.add_argument("--trace", type=int, choices=[0, 1], default=0)
+    ap.add_argument("--trace", type=int, choices=[0, 1, 2], default=0,
+                    help="1: per-layer metrics from a window traced in its "
+                    "middle; 2: a --trace 0 run, then a traced tail: both "
+                    "kinds of metric in one line")
     ap.add_argument("--sweep", type=lambda s: [float(x) for x in s.split(",")],
                     default=None, help="rates (requests/s) to offer one "
                     "after another, to find an open-loop cell's rate")

@@ -333,6 +333,106 @@ def test_unknown_device_kind_is_an_error():
         run.peaks_for("TPU v99")
 
 
+# -- which context a reader gets, and PR 24's readers ----------------------------
+
+@pytest.mark.parametrize("source,got", [
+    ("device_trace", "tail"), ("program_span", "tail"),
+    ("host_clock", "window"), ("program_counter", "window")])
+def test_trace2_hands_a_reader_its_context_by_the_metrics_source(source, got):
+    import run
+
+    window, tail = {"is": "window"}, {"is": "tail"}
+    assert run.context_for({"source": source}, window, tail)["is"] == got
+    # a --trace 1 run has the one context, whatever the source
+    assert run.context_for({"source": source}, window, None) is window
+
+
+def test_every_metric_names_a_source_context_for_knows():
+    for m in BENCH["per_layer"]:
+        assert m["source"] in ("device_trace", "program_span", "host_clock",
+                               "program_counter"), m["name"]
+    # what needs every step stamped, or the device trace, reads the tail
+    tail = {m["name"] for m in BENCH["per_layer"]
+            if m["source"] in ("device_trace", "program_span")}
+    assert {"engine.host_ms_per_dispatch", "step.decode_device_ms",
+            "kernel.decode_hbm_share", "device.idle_share",
+            "load.params_s"} <= tail
+    assert not {"compile.in_window", "engine.gap_tail1_ms",
+                "engine.stall_s", "sched.queue_wait_mean_ms"} & tail
+
+
+def _recorded_ctx(with_new: bool = True) -> dict:
+    """Counters of a server on either side of a window, as ``GET /`` and
+    ``GET /debug/prof`` give them (trimmed to what readers read)."""
+    def hist(count, total):
+        return {"type": "histogram", "count": count, "sum": total}
+
+    before = {"serve.ttft_ms": hist(10, 2000.0)}
+    after = {"serve.ttft_ms": hist(110, 27000.0)}
+    prof_after = {"compiles": 5, "phases": {}}
+    if with_new:
+        before.update({"serve.queue_wait_ms": hist(10, 100.0),
+                       "serve.admit_to_first_ms": hist(10, 1900.0),
+                       "prof.slow_pass_ms": {"type": "counter", "value": 0}})
+        after.update({"serve.queue_wait_ms": hist(110, 600.0),
+                      "serve.admit_to_first_ms": hist(110, 26400.0),
+                      "prof.slow_pass_ms": {"type": "counter",
+                                            "value": 2500.0}})
+        prof_after["startup"] = {"params_s": 27.5, "engine_s": 1.0,
+                                 "warm_s": 4.0, "loaded_s": 33.0}
+    return {"before": {"status": {"metrics": before},
+                       "prof": {"compiles": 5, "phases": {}}},
+            "after": {"status": {"metrics": after}, "prof": prof_after}}
+
+
+def test_pr24_readers_on_recorded_counters():
+    import run
+
+    ctx = _recorded_ctx()
+    read = lambda name: run.load_reader(name)(ctx)  # noqa: E731
+    assert read("sched.queue_wait_mean_ms") == pytest.approx(5.0)
+    assert read("engine.admit_to_first_mean_ms") == pytest.approx(245.0)
+    # the .open names share the functions
+    assert read("sched.queue_wait_mean_ms.open") == pytest.approx(5.0)
+    assert read("engine.admit_to_first_mean_ms.open") == pytest.approx(245.0)
+    # the two legs add up to the server's own time to first token
+    ttft = (27000.0 - 2000.0) / 100
+    assert read("sched.queue_wait_mean_ms") + read(
+        "engine.admit_to_first_mean_ms") == pytest.approx(ttft)
+    assert read("engine.stall_s") == pytest.approx(2.5)
+    assert read("load.params_s") == 27.5
+
+
+@pytest.mark.parametrize("name", [
+    "sched.queue_wait_mean_ms", "sched.queue_wait_mean_ms.open",
+    "engine.admit_to_first_mean_ms", "engine.admit_to_first_mean_ms.open",
+    "engine.stall_s", "load.params_s"])
+def test_pr24_readers_find_nothing_in_an_older_program(name):
+    import run
+
+    assert run.load_reader(name)(_recorded_ctx(with_new=False)) is None
+
+
+def test_a_clean_window_reads_a_stall_of_zero_not_nothing():
+    import run
+
+    ctx = _recorded_ctx()
+    ctx["after"]["status"]["metrics"]["prof.slow_pass_ms"]["value"] = 0
+    assert run.load_reader("engine.stall_s")(ctx) == 0.0
+
+
+def test_trace_in_run_is_declared_and_trace_1_still_parses():
+    import run
+
+    assert BENCH["trace_in_run"] is True
+    source = (HERE / "run.py").read_text()
+    assert "choices=[0, 1, 2]" in source
+    # the launcher opens no profiler of its own any more
+    child = (HERE / "serve_child.py").read_text()
+    assert "start_trace" not in child and "bench-trace-dir" not in child
+    assert callable(run.trace_tail) and callable(run.measure)
+
+
 # -- one rehearsal, end to end ----------------------------------------------------
 
 def test_rehearsal_of_one_cell_end_to_end():
@@ -348,6 +448,34 @@ def test_rehearsal_of_one_cell_end_to_end():
     assert last["attempted"] > 0
     assert set(last["would_report"]) == {"tokens_per_s", "tpot_p50_ms",
                                          "ttft_mean_ms", "setup_s"}
+
+
+def test_rehearsal_with_trace_2_ends_in_one_line_with_both_kinds():
+    out = subprocess.run(
+        [sys.executable, str(HERE / "run.py"), "--workload",
+         "mistral7b-int8.decode-full", "--rehearse", "--seed", "3000000007",
+         "--seconds", "2", "--trace", "2"], capture_output=True, text=True,
+        timeout=240, cwd=ROOT)
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = [json.loads(l) for l in out.stdout.strip().splitlines()]
+    last = lines[-1]
+    assert last["rehearsal"] is True and last["correct"] is True
+    assert last["failed"] == 0 and last["attempted"] > 0
+    would = set(last["would_report"])
+    # end-to-end, from the closed window ...
+    assert {"tokens_per_s", "tpot_p50_ms", "ttft_mean_ms", "setup_s"} <= would
+    # ... and per-layer: counters of the window, spans of the tail
+    assert {"compile.in_window", "engine.gap_tail1_ms",
+            "engine.host_ms_per_dispatch", "engine.tokens_per_dispatch",
+            "sched.queue_wait_mean_ms", "engine.admit_to_first_mean_ms",
+            "engine.stall_s", "load.params_s", "load.loaded_s"} <= would
+    tail = next(l for l in lines if l.get("phase") == "tail")
+    assert tail["failed"] == 0 and tail["capture"]["steps"] > 0
+    assert tail["tokens_per_s_traced"] > 0
+    order = [l.get("phase") for l in lines[:-1]]
+    assert order.index("window") < order.index("tail")
+    # the trace is deleted once it is reduced
+    assert not Path(tail["capture"]["dir"]).exists()
 
 
 def test_no_result_without_the_program(tmp_path):

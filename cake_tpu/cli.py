@@ -205,7 +205,11 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--process-id", type=int, default=None, dest="process_id")
     p.add_argument("--cpu", action="store_true", help="force CPU backend")
     p.add_argument("--profile", default=None, metavar="DIR",
-                   help="write a jax.profiler trace of generation to DIR")
+                   help="write a jax.profiler trace of generation to DIR "
+                        "(with the run's prof.* phase spans on its host "
+                        "plane and in DIR/spans.trace.json); in --mode "
+                        "serve, the directory the capture control "
+                        "(POST /debug/trace) writes into")
     # -- observability (cake_tpu/obs): spans, metrics, flight records ------
     p.add_argument("--trace", default=None, metavar="PATH",
                    help="record runtime spans (prefill, decode.step, "
@@ -236,10 +240,12 @@ def build_parser() -> argparse.ArgumentParser:
                    dest="prof_sample", metavar="N",
                    help="engine profiling plane (cake_tpu/obs/prof): stamp "
                         "a full per-phase step breakdown every Nth engine "
-                        "step (default 64 via CAKE_PROF_SAMPLE; 0 disables "
-                        "sampling entirely, 1 stamps every step). The "
-                        "report is served live at GET /debug/prof and "
-                        "folded into --trace timelines as prof.* spans")
+                        "step (default 64; 0 disables sampling entirely, "
+                        "1 stamps every step; a capture started with "
+                        "POST /debug/trace stamps every step while it is "
+                        "open). The report is served live at GET "
+                        "/debug/prof and folded into --trace timelines as "
+                        "prof.* spans")
     p.add_argument("--top", action="store_true",
                    help="master+topology runs: live ANSI cluster panel on "
                         "stderr while generating (per-worker p50/p99, RTT, "
@@ -784,6 +790,7 @@ def run_http_serve(args) -> int:
 
     from cake_tpu import __version__, obs
     from cake_tpu.obs import metrics as obs_metrics
+    from cake_tpu.obs import prof as obs_prof
     from cake_tpu.serve.api import start_api_server
     from cake_tpu.serve.scheduler import Scheduler
     from cake_tpu.utils.memory import device_report, memory_report
@@ -828,6 +835,9 @@ def run_http_serve(args) -> int:
     tokenizer = _load_tokenizer(args.model)
     settings = _settings(args)
     t0 = time.perf_counter()
+    # the parts of "model loaded in", reported as numbers at
+    # GET /debug/prof ``startup`` (the mesh path fills the first two)
+    startup: dict[str, float] = {}
 
     # topology: device-indexed drives the mesh plan, host-addressed the
     # cross-host wire path (same split as run_master)
@@ -909,7 +919,9 @@ def run_http_serve(args) -> int:
                                       ep=args.ep)
         except ValueError as e:
             sys.exit(f"error: {e}")
+        t_params = time.perf_counter()
         params = _mesh_params(args, config, plan)
+        startup["params_s"] = time.perf_counter() - t_params
         try:
             engine = BatchGenerator(
                 config, params, plan=plan, tokenizer=tokenizer,
@@ -921,6 +933,8 @@ def run_http_serve(args) -> int:
                 **_kv_layout_kwargs(args))
         except ValueError as e:
             sys.exit(f"error: {e}")
+        startup["engine_s"] = (time.perf_counter() - t_params
+                               - startup["params_s"])
         # compile the admission path outside the serving window (requests
         # of any length share the chunked program for this bucket)
         warm_len = min(64, engine.max_seq // 2)
@@ -939,8 +953,10 @@ def run_http_serve(args) -> int:
     # warm the masked (constrained-decoding) program too when requests
     # could carry response_format — i.e. whenever a tokenizer is loaded
     # (grammars compile against the vocab's decoded strings)
+    t_warm = time.perf_counter()
     scheduler.start(max_concurrent=max_concurrent, warm_prompt_len=warm_len,
                     warm_constrain=tokenizer is not None)
+    startup["warm_s"] = time.perf_counter() - t_warm
 
     # KV transfer listener (cake_tpu/disagg): a decode replica always
     # accepts imports (ephemeral port unless pinned); a mixed replica
@@ -998,9 +1014,11 @@ def run_http_serve(args) -> int:
         status_httpd, bound = statusd.start_status_server(
             serve_status, bind=args.status_bind, port=args.status_port)
         log.info("status page on http://%s:%d/", args.status_bind, bound)
+    startup["loaded_s"] = time.perf_counter() - t0
+    obs_prof.set_startup(**startup)
     log.info("model loaded in %.1fs (%s); serving on http://%s:%d/ "
              "(%d slots, queue %d, %ss deadline)",
-             time.perf_counter() - t0, memory_report(), serve_bind,
+             startup["loaded_s"], memory_report(), serve_bind,
              server.port, scheduler.max_concurrent, queue_depth,
              request_timeout)
 
@@ -1471,9 +1489,11 @@ def run_master(args) -> int:
     gen_error = None
     gen_ids: list[int] = []
     if args.profile:
-        import jax.profiler
+        from cake_tpu.obs import prof as obs_prof
 
-        jax.profiler.start_trace(args.profile)
+        # the one place that opens a profiler; this run's own ``finally``
+        # closes it, however long generation takes
+        obs_prof.capture_start(auto_stop=False)
     try:
         for i in range(args.sample_len):
             try:
@@ -1493,7 +1513,7 @@ def run_master(args) -> int:
                 break
     finally:
         if args.profile:
-            jax.profiler.stop_trace()
+            obs_prof.capture_stop()
             log.info("profiler trace written to %s", args.profile)
         if top_view is not None:
             top_view.stop()
@@ -1567,13 +1587,14 @@ def main(argv=None) -> int:
                  "no model; fetch on the --mode serve replicas instead")
     obs.setup_logging("debug" if args.verbose else args.log_level)
     if args.trace:
-        # --profile already captures an XLA trace; passing spans through as
-        # TraceAnnotations lines the two timelines up in one Perfetto view
-        obs.tracer().start(xla_annotations=bool(args.profile))
+        # a capture (--profile on the master path, POST /debug/trace on a
+        # server) passes the spans through as TraceAnnotations while it is
+        # open, which lines the two timelines up in one Perfetto view
+        obs.tracer().start()
     if args.prof_sample is not None:
-        from cake_tpu.obs import prof as _prof
-
-        _prof.profiler().set_sample(args.prof_sample)
+        obs.prof.profiler().set_sample(args.prof_sample)
+    # where captures go; without --profile, a fresh temporary directory each
+    obs.prof.capture().directory = args.profile
     if args.flight_log:
         try:
             obs.flight.recorder().enable(path=args.flight_log)

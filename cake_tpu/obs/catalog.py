@@ -128,6 +128,13 @@ SERIES: dict[str, tuple[str, str]] = {
                  "(warn; raise under CAKE_PROF_STRICT=1)"),
     "prof.sampled_steps": (
         COUNTER, "engine steps that recorded a sampled phase breakdown"),
+    "prof.slow_pass_ms": (
+        COUNTER, "total length (ms) of serve-scheduler passes that took "
+                 "longer than obs/prof.SLOW_PASS_MS: the time stalls "
+                 "cost (their parts are in /debug/prof slow_passes)"),
+    "prof.slow_passes": (
+        COUNTER, "serve-scheduler passes longer than "
+                 "obs/prof.SLOW_PASS_MS"),
     # -- speculative decoding acceptance (runtime/speculative) -----------
     "spec.accept_rate_ema": (
         GAUGE, "EMA of accepted-proposal fraction per round — the "
@@ -182,6 +189,10 @@ SERIES: dict[str, tuple[str, str]] = {
     "slo.good": (COUNTER, "requests that met their TTFT/TPOT targets"),
     # -- serving plane (HTTP API + scheduler) ----------------------------
     "serve.admit_chunk_ms": (HISTOGRAM, "admission prefill chunk dispatch"),
+    "serve.admit_to_first_ms": (
+        HISTOGRAM, "handed to the engine -> first token emitted, per "
+                   "request (admission, prefill, the block it joined); "
+                   "serve.queue_wait_ms + this = serve.ttft_ms"),
     "serve.cancelled": (COUNTER, "requests cancelled (client went away)"),
     "serve.completed": (COUNTER, "requests that got their tokens"),
     "serve.decode_dispatch_ms": (HISTOGRAM, "batched decode dispatch"),
@@ -192,6 +203,9 @@ SERIES: dict[str, tuple[str, str]] = {
         COUNTER, "batch streams spilled to host RAM so a higher-class "
                  "arrival could take the slot (SLO scheduling)"),
     "serve.queue_depth": (GAUGE, "requests waiting for admission"),
+    "serve.queue_wait_ms": (
+        HISTOGRAM, "submit -> handed to the engine, per request (the "
+                   "wait for a slot and for undelivered rows)"),
     "serve.rejected": (COUNTER, "submissions refused at the queue bound"),
     "serve.resume_ms": (
         HISTOGRAM, "preempted-stream resume time (spill take through "
@@ -253,8 +267,10 @@ DYNAMIC: dict[str, tuple[str, str]] = {
         GAUGE, "per-worker merged health/traffic fields (ClusterScraper)"),
     "prof.phase_ms.*": (
         HISTOGRAM, "per-phase wall ms inside sampled engine steps "
-                   "(admit/pages/guide/dispatch/sync/emit/idle_park and "
-                   "the spec_* phases — obs/prof.PHASES)"),
+                   "(admit/pages/guide/dispatch/sync/emit and the spec_* "
+                   "phases) and of the scheduler's pass around them "
+                   "(idle_park/sched_admit/deliver/retire) — "
+                   "obs/prof.PHASES"),
     "serve.ttft_ms.*": (
         HISTOGRAM, "per-class submit-to-first-token (serve.session "
                    "CLASSES — the SLO rows split interactive from "

@@ -9,12 +9,20 @@ pay, churn vs steady, SLO scheduling) hinges on. Three arms:
   step loops stamp each pass into named phases (``admit``, ``pages``,
   ``guide``, ``dispatch``, ``sync``, ``emit``, and the speculative
   ``spec_propose`` / ``spec_verify`` / ``spec_accept``; the scheduler
-  adds ``idle_park`` between passes). Each sampled step feeds the
+  adds ``idle_park`` between passes and times the parts of its own pass
+  around ``engine.step()``: ``sched_admit``, ``deliver``, ``retire``).
+  Each sampled step feeds the
   per-phase ``prof.phase_ms.*`` histograms and a bounded ring of recent
   step records. Sampling every Nth step (``--prof-sample``, default
   coarse) keeps the steady-state cost inside the existing <= 3% obs
   budget: an unsampled step pays one integer increment at ``step_begin``
-  and one attribute check per ``phase()`` call site. Phase stamping is
+  and two attribute checks per ``phase()`` call site. A sampled step's
+  ring record carries the step's start on both host clocks
+  (``t_unix_ns``, ``t_perf_s``) and each phase's offset from it
+  (``at_ms``), so it can be laid on a trace or a client's timeline. A
+  scheduler pass longer than :data:`SLOW_PASS_MS` leaves its parts in a
+  second ring (``slow_passes``) and in ``prof.slow_pass_ms``: what the
+  program keeps about a pause of seconds. Phase stamping is
   host-side driver code only — never inside a jitted body (cakelint
   CK-JIT), and the step/phase calls run on the engine-owner thread
   (CK-THREAD); the ring and report path are lock-guarded for handler
@@ -40,12 +48,25 @@ pay, churn vs steady, SLO scheduling) hinges on. Three arms:
   nothing), host RSS/peak from ``/proc/self/status``, and the kvpool
   page gauges stitched in so one report carries the whole memory story.
 
-:func:`report` assembles all three arms into the JSON served at
+- :func:`capture_start` / :func:`capture_stop` — the one place that
+  opens a ``jax.profiler`` trace, in the process that holds the chip:
+  ``POST /debug/trace`` on the serving port and the master path's
+  ``--profile`` both call them. A capture stamps every step (stride 1),
+  runs the span tracer with ``xla_annotations=True`` so every phase is a
+  ``prof.<phase>`` ``TraceAnnotation`` on the engine thread's line of
+  the trace's host plane (a device idle gap can then be named by engine
+  phase), and writes the program's own spans beside the profile
+  (``spans.trace.json``). One capture at a time; a capture nobody stops
+  is stopped after :data:`CAPTURE_MAX_S`. Off, it costs nothing: no
+  thread, and ``jax.profiler`` is not imported before the first start.
+
+:func:`report` assembles the arms into the JSON served at
 ``GET /debug/prof`` (serve replicas, statusd pages, and the gateway's
-fleet-merged view) and rendered by ``obs/top.py``. When the tracer is
-started (``--trace``), sampled phases additionally record ``prof.*``
-spans, so one Perfetto file shows request spans with the engine phases
-nested under them.
+fleet-merged view) and rendered by ``obs/top.py``; a serving process
+adds its ``startup`` times (:func:`set_startup`). When the tracer is
+started (``--trace`` or a capture), phases additionally record
+``prof.*`` spans — on every step, sampled or not — so one Perfetto file
+shows request spans with the engine phases nested under them.
 """
 
 from __future__ import annotations
@@ -66,6 +87,15 @@ log = logging.getLogger("cake_tpu.obs.prof")
 # banks hundreds of phase breakdowns.
 SAMPLE_DEFAULT = 64
 
+# A scheduler pass (admit, engine step, deliver, retire) longer than this
+# is a stall worth keeping: a decode block is ~0.2 s on the chip and the
+# longest admission ~0.15 s, so nothing healthy comes near it.
+SLOW_PASS_MS = 1000.0
+
+# A capture that nobody stops is stopped by the program (a forgotten
+# profiler fills memory and slows the host for as long as it runs).
+CAPTURE_MAX_S = 30.0
+
 # The declared phase vocabulary (catalog: prof.phase_ms.*). Call sites
 # may only stamp these names — a typo'd phase would silently fork a
 # series exactly the way the metric catalog exists to prevent.
@@ -77,6 +107,9 @@ PHASES = (
     "sync",          # device sync + host fetch (where compute lands)
     "emit",          # detok / Token fan-out / bookkeeping
     "idle_park",     # scheduler parked waiting for work
+    "sched_admit",   # scheduler pass: queue -> engine.enqueue (+ preempt)
+    "deliver",       # scheduler pass: emitted row -> session event queues
+    "retire",        # scheduler pass: close out ended sessions
     "spec_propose",  # speculative draft proposal (host n-gram walk)
     "spec_verify",   # speculative verify dispatch
     "spec_accept",   # accept/rollback: accept program + bank fetch
@@ -101,27 +134,30 @@ _NULL_PHASE = _NullPhase()
 
 
 class _Phase:
-    """One stamped phase inside a sampled step: accumulates wall ms into
-    the step record + the phase histogram, and (tracer started) records
-    a ``prof.<name>`` span so the phase lands on the Perfetto timeline
-    under whatever request span encloses it."""
+    """One stamped phase: accumulates wall ms into the sampled step's
+    record (inside one) + the phase histogram, and (tracer started)
+    records a ``prof.<name>`` span so the phase lands on the Perfetto
+    timeline under whatever request span encloses it. ``ms`` keeps the
+    phase's length for a caller that wants it (the scheduler's pass)."""
 
-    __slots__ = ("_prof", "_name", "_t0", "_span")
+    __slots__ = ("_prof", "_name", "_args", "_t0", "_span", "ms")
 
-    def __init__(self, prof: "StepProfiler", name: str):
+    def __init__(self, prof: "StepProfiler", name: str, args: dict):
         self._prof = prof
         self._name = name
+        self._args = args
+        self.ms = 0.0
 
     def __enter__(self):
-        self._span = obs_trace.span("prof." + self._name)
+        self._span = obs_trace.span("prof." + self._name, **self._args)
         self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        dt_ms = (time.perf_counter() - self._t0) * 1e3
+        self.ms = (time.perf_counter() - self._t0) * 1e3
         self._span.__exit__(*exc)
-        self._prof._record_phase(self._name, dt_ms)
+        self._prof._record_phase(self._name, self._t0, self.ms)
         return False
 
 
@@ -134,20 +170,17 @@ class StepProfiler:
     histograms behind :meth:`phases` are safe for handler threads.
     """
 
-    _GUARDED_BY = {"_ring": "_lock"}
+    _GUARDED_BY = {"_ring": "_lock", "_slow": "_lock"}
 
-    def __init__(self, sample_every: int | None = None, ring: int = 64):
-        if sample_every is None:
-            try:
-                sample_every = int(
-                    os.environ.get("CAKE_PROF_SAMPLE", str(SAMPLE_DEFAULT)))
-            except ValueError:
-                sample_every = SAMPLE_DEFAULT
+    def __init__(self, sample_every: int = SAMPLE_DEFAULT, ring: int = 64):
         self.sample_every = max(0, sample_every)
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=max(1, ring))
+        self._slow: deque = deque(maxlen=32)  # slow scheduler passes
         self._tl = threading.local()  # .count, .cur, .t0
         self._sampled = obs_metrics.counter("prof.sampled_steps")
+        self._slow_n = obs_metrics.counter("prof.slow_passes")
+        self._slow_ms = obs_metrics.counter("prof.slow_pass_ms")
         # phase histograms are created lazily per name; cached so the
         # sampled-step cost is a dict hit, not a registry lock
         self._hists: dict[str, object] = {}
@@ -158,7 +191,8 @@ class StepProfiler:
         return self.sample_every > 0
 
     def set_sample(self, every: int) -> None:
-        """Re-point the sampling stride (``--prof-sample``; 0 disables)."""
+        """Re-point the sampling stride (``--prof-sample`` at launch, a
+        capture at run time; 0 disables)."""
         self.sample_every = max(0, int(every))
 
     # -- engine-thread stamping ----------------------------------------------
@@ -171,15 +205,53 @@ class StepProfiler:
         tl.count = n + 1
         if not self.sample_every or n % self.sample_every:
             return
-        tl.cur = {"engine": engine, "step": n, "phases": {}}
         tl.t0 = time.perf_counter()
+        # the step's start on both host clocks, and where each phase
+        # began inside it: enough to lay the record on a device trace
+        # (whose host plane runs on perf_counter's clock) or on a
+        # client's timeline
+        tl.cur = {"engine": engine, "step": n, "t_unix_ns": time.time_ns(),
+                  "t_perf_s": tl.t0, "phases": {}, "at_ms": {}}
 
-    def phase(self, name: str):
-        """Context manager stamping one phase of the current step; the
-        shared no-op outside a sampled step (one attribute check)."""
+    def phase(self, name: str, **args):
+        """Context manager stamping one phase of the current step
+        (``args`` ride the phase's span). Outside a sampled step: a bare
+        ``prof.<name>`` span while the tracer runs, else the shared
+        no-op (two attribute checks)."""
         if getattr(self._tl, "cur", None) is None:
-            return _NULL_PHASE
-        return _Phase(self, name)
+            if not obs_trace.tracer().enabled:
+                return _NULL_PHASE
+            return obs_trace.span("prof." + name, **args)
+        return _Phase(self, name, args)
+
+    def pass_part(self, name: str) -> _Phase:
+        """Context manager timing one part of a scheduler pass
+        (``sched_admit``/``deliver``/``retire``), which lies outside any
+        engine step: always timed (the caller reads ``ms`` for its
+        slow-pass record), into the part's phase histogram, and a
+        ``prof.<name>`` span while the tracer runs."""
+        return _Phase(self, name, {})
+
+    def note_pass(self, total_ms: float, parts: dict, queued: int,
+                  running: int) -> None:
+        """One scheduler pass ended after ``total_ms`` (parked time left
+        out). A pass over :data:`SLOW_PASS_MS` adds its length to
+        ``prof.slow_pass_ms`` and leaves a record -- when, how long, in
+        which part, how much was waiting -- in the ``slow_passes`` ring.
+        ``parts`` holds ``admit_ms``, ``step_ms``, ``deliver_ms``; the
+        rest of the pass (retire, sweeps, the stats snapshot, the wait
+        for the scheduler's lock) is ``rest_ms``."""
+        if total_ms < SLOW_PASS_MS:
+            return
+        self._slow_n.inc()
+        self._slow_ms.inc(total_ms)
+        rec = {"t_unix_ns": time.time_ns() - int(total_ms * 1e6),
+               "total_ms": round(total_ms, 3)}
+        rec.update((k, round(v, 3)) for k, v in parts.items())
+        rec["rest_ms"] = round(max(0.0, total_ms - sum(parts.values())), 3)
+        rec["queued"], rec["running"] = queued, running
+        with self._lock:
+            self._slow.append(rec)
 
     def _hist(self, name: str):
         h = self._hists.get(name)
@@ -188,12 +260,15 @@ class StepProfiler:
                 f"prof.phase_ms.{name}")
         return h
 
-    def _record_phase(self, name: str, dt_ms: float) -> None:
+    def _record_phase(self, name: str, t0: float, dt_ms: float) -> None:
         cur = getattr(self._tl, "cur", None)
         if cur is not None:
             cur["phases"][name] = round(
                 cur["phases"].get(name, 0.0) + dt_ms, 4)
-        self._hist(name).observe(dt_ms)
+            # offset of the phase's first stamp in this step
+            cur["at_ms"].setdefault(
+                name, round((t0 - cur["t_perf_s"]) * 1e3, 4))
+        self.observe_ms(name, dt_ms)
 
     def step_end(self) -> None:
         tl = self._tl
@@ -208,7 +283,8 @@ class StepProfiler:
 
     def observe_ms(self, name: str, dt_ms: float) -> None:
         """Record one out-of-step phase sample (the scheduler's
-        ``idle_park`` waits happen between steps, not inside one)."""
+        ``idle_park`` waits and the parts of its pass happen between
+        steps, not inside one)."""
         if self.enabled:
             self._hist(name).observe(dt_ms)
 
@@ -216,6 +292,10 @@ class StepProfiler:
     def recent_steps(self) -> list[dict]:
         with self._lock:
             return list(self._ring)
+
+    def slow_passes(self) -> list[dict]:
+        with self._lock:
+            return list(self._slow)
 
     def phases(self) -> dict:
         """Per-phase histogram snapshots (count/mean/p50/p99), keyed by
@@ -230,9 +310,12 @@ class StepProfiler:
     def reset(self) -> None:
         with self._lock:
             self._ring.clear()
+            self._slow.clear()
         for h in self._hists.values():
             h.reset()
         self._sampled.reset()
+        self._slow_n.reset()
+        self._slow_ms.reset()
 
 
 class RetraceSentinel:
@@ -393,10 +476,157 @@ def memory_watermarks() -> dict:
     return out
 
 
+# -- the capture control ------------------------------------------------------
+
+class CaptureBusy(RuntimeError):
+    """A second ``start`` while a capture is open (HTTP: 409)."""
+
+
+class CaptureIdle(RuntimeError):
+    """A ``stop`` with no capture open (HTTP: 409)."""
+
+
+def _clocks() -> dict:
+    """Both host clocks at one instant: the parent of a traced run keeps
+    its timeline on ``perf_counter`` (system-wide on Linux), the device
+    trace counts from when it opened, and this pair ties them."""
+    return {"unix_ns": time.time_ns(), "perf_s": time.perf_counter()}
+
+
+class Capture:
+    """Start and stop tracing in the process that holds the chip: the
+    ``jax.profiler`` trace, the span tracer (as ``TraceAnnotation``s) and
+    stride-1 phase stamping, together. One capture at a time."""
+
+    def __init__(self, profiler: StepProfiler):
+        self._profiler = profiler
+        # serializes start and stop, each of which may take seconds (the
+        # profiler collects the device's trace when it stops); ``_open``
+        # is written under it and read bare by ``active``, so that a
+        # ``GET /debug/prof`` never waits for a profiler call
+        self._lock = threading.Lock()
+        self._open: dict | None = None
+        # where captures are written: ``--profile DIR`` when the process
+        # was launched with it, else a fresh temporary directory each
+        self.directory: str | None = None
+
+    @property
+    def active(self) -> bool:
+        return self._open is not None
+
+    def start(self, auto_stop: bool = True) -> dict:
+        """Open a capture; answers ``{"dir", "unix_ns", "perf_s"}``
+        stamped right after the profiler opened. ``auto_stop=False`` is
+        for a caller whose own ``finally`` stops it (``--profile``)."""
+        with self._lock:
+            if self._open is not None:
+                raise CaptureBusy("a capture is already open "
+                                  f"(into {self._open['dir']})")
+            import jax.profiler
+
+            directory = self.directory
+            if directory is None:
+                import tempfile
+
+                directory = tempfile.mkdtemp(prefix="cake-trace-")
+            os.makedirs(directory, exist_ok=True)
+            tr = obs_trace.tracer()
+            state = {"dir": directory, "stride": self._profiler.sample_every,
+                     "tracer_was_on": tr.enabled,
+                     "annotations_were_on": tr.xla_annotations,
+                     "steps0": self._profiler._sampled.value, "timer": None}
+            self._profiler.set_sample(1)
+            if tr.enabled:
+                # ``--trace`` runs the tracer for the whole process: keep
+                # its buffer, only pass its spans through to the profile
+                tr.xla_annotations = True
+            else:
+                tr.start(xla_annotations=True)
+            try:
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0  # runtime spans, no frames
+                options.host_tracer_level = 2
+                jax.profiler.start_trace(directory, profiler_options=options)
+            except Exception:
+                self._restore(state)
+                raise
+            opened = _clocks()
+            if auto_stop:
+                state["timer"] = threading.Timer(CAPTURE_MAX_S,
+                                                 self._auto_stop)
+                state["timer"].daemon = True
+                state["timer"].name = "cake-capture-autostop"
+                state["timer"].start()
+            self._open = state
+        log.info("capture opened into %s", directory)
+        return dict(opened, dir=directory)
+
+    def _restore(self, state: dict) -> None:
+        tr = obs_trace.tracer()
+        if state["tracer_was_on"]:
+            tr.xla_annotations = state["annotations_were_on"]
+        else:
+            tr.stop()
+        self._profiler.set_sample(state["stride"])
+
+    def stop(self) -> dict:
+        """Close the capture; answers ``{"dir", "unix_ns", "perf_s",
+        "steps", "spans", "dropped"}``, the clocks stamped just before
+        the profiler closed. The program's own spans of the capture are
+        written beside the profile (``spans.trace.json``; its
+        ``otherData.perf_origin_s`` is the ``perf_counter`` its ``ts``
+        count from)."""
+        with self._lock:
+            state = self._open
+            if state is None:
+                raise CaptureIdle("no capture is open")
+            if state["timer"] is not None:
+                state["timer"].cancel()
+            import jax.profiler
+
+            # the stride and the tracer go back first: the profiler can
+            # take a minute to stop (it collects the device's trace), and
+            # the program's spans should end where the clocks are stamped
+            closed = _clocks()
+            self._restore(state)
+            tr = obs_trace.tracer()
+            out = dict(closed, dir=state["dir"],
+                       steps=self._profiler._sampled.value - state["steps0"],
+                       spans=tr.event_count(), dropped=tr.dropped)
+            try:
+                jax.profiler.stop_trace()
+            finally:
+                self._open = None
+            took = time.perf_counter() - closed["perf_s"]
+            try:
+                tr.write_chrome_trace(
+                    os.path.join(state["dir"], "spans.trace.json"))
+            except OSError as e:
+                log.error("could not write the capture's spans: %s", e)
+            if not state["tracer_was_on"]:
+                tr.clear()
+        log.info("capture closed: %d steps, %d spans into %s (the "
+                 "profiler took %.1f s to stop)", out["steps"],
+                 out["spans"], out["dir"], took)
+        return out
+
+    def _auto_stop(self) -> None:
+        try:
+            self.stop()
+            log.warning("capture stopped by the program after %.0f s: "
+                        "nobody stopped it", CAPTURE_MAX_S)
+        except CaptureIdle:
+            pass  # stopped by its owner as the timer fired
+        except Exception:  # a timer thread has nobody to report to
+            log.exception("stopping an abandoned capture failed")
+
+
 # -- process singletons + report ----------------------------------------------
 
 _PROFILER = StepProfiler()
 _SENTINEL = RetraceSentinel()
+_CAPTURE = Capture(_PROFILER)
+_STARTUP: dict = {}
 
 
 def profiler() -> StepProfiler:
@@ -407,17 +637,42 @@ def sentinel() -> RetraceSentinel:
     return _SENTINEL
 
 
+def capture() -> Capture:
+    return _CAPTURE
+
+
+def capture_start(auto_stop: bool = True) -> dict:
+    return _CAPTURE.start(auto_stop=auto_stop)
+
+
+def capture_stop() -> dict:
+    return _CAPTURE.stop()
+
+
+def set_startup(**seconds: float) -> None:
+    """A serving process reports the parts of its start-up once:
+    ``params_s`` (checkpoint to device), ``engine_s`` (engine build),
+    ``warm_s`` (scheduler start: priming and warm admissions) and
+    ``loaded_s`` (main to serving, what the log's "model loaded in"
+    says). They appear as ``startup`` in :func:`report`."""
+    _STARTUP.clear()
+    _STARTUP.update((k, round(v, 3)) for k, v in seconds.items())
+
+
 def report() -> dict:
-    """The /debug/prof body: all three arms in one JSON document."""
+    """The /debug/prof body: all the arms in one JSON document."""
     p, s = _PROFILER, _SENTINEL
     return {
         "sample_every": p.sample_every,
         "sampled_steps": p._sampled.value,
         "phases": p.phases(),
         "recent_steps": p.recent_steps(),
+        "slow_passes": p.slow_passes(),
+        "capturing": _CAPTURE.active,
         "compiles": s.compiles.value,
         "retraces": s.retraces.value,
         "steady": s.steady,
         "findings": s.findings(),
         "memory": memory_watermarks(),
+        **({"startup": dict(_STARTUP)} if _STARTUP else {}),
     }

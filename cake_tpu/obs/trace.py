@@ -23,7 +23,8 @@ one attribute check per call site, nothing recorded. Enable with
 ``tracer().start()`` (the CLI's ``--trace PATH`` does this and writes the
 file on exit). ``start(xla_annotations=True)`` additionally wraps every span
 in ``jax.profiler.TraceAnnotation`` so the same names appear inside XLA
-profiles captured with ``--profile``.
+profiles: a capture (``obs/prof.capture_start``: ``POST /debug/trace`` on a
+server, ``--profile`` on the master path) runs the tracer that way.
 """
 
 from __future__ import annotations
@@ -126,6 +127,10 @@ class Tracer:
                 self._sources.append(source)
             self._events.append(ev)
 
+    def event_count(self) -> int:
+        with self._lock:
+            return len(self._events)
+
     def clear(self) -> None:
         with self._lock:
             self._events = []
@@ -178,11 +183,15 @@ class Tracer:
             if args:
                 ev["args"] = args
             out.append(ev)
-        doc = {"traceEvents": out, "displayTimeUnit": "ms"}
+        # the perf_counter instant every ``ts`` counts from: with it the
+        # spans can be rebased onto another timeline of the same host (a
+        # profiler capture, a load generator's records)
+        doc = {"traceEvents": out, "displayTimeUnit": "ms",
+               "otherData": {"perf_origin_s": self._t0}}
         if self.dropped:
             # surfaced in the file itself so a truncated timeline can
             # never be read as complete (Perfetto ignores extra keys)
-            doc["otherData"] = {"dropped_events": self.dropped}
+            doc["otherData"]["dropped_events"] = self.dropped
         return doc
 
     def write_chrome_trace(self, path: str) -> None:

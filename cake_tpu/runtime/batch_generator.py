@@ -2685,8 +2685,9 @@ class BatchGenerator:
         top-k logprob rows when enabled) return UN-fetched so the caller
         chooses when to pay the host round-trip (the lookahead path
         dispatches the next block first)."""
-        with span("decode.dispatch", steps=size, batch=len(self.streams)), \
-                self._prof.phase("dispatch"), self._sentinel.decode_phase():
+        with self._prof.phase("dispatch", steps=size,
+                              batch=len(self.streams)), \
+                self._sentinel.decode_phase():
             out = self._block_prog(size)(
                 self.params, self._last_tokens, self.cache,
                 jnp.asarray(self._pos), self._keys, self._history,
@@ -2789,36 +2790,36 @@ class BatchGenerator:
         if int(max(live)) >= self.max_seq:  # unreachable: _emit marks
             raise RuntimeError("KV cache exhausted")  # window-full streams done
         t0 = time.perf_counter()
-        with span("decode.dispatch", steps=1, batch=len(self.streams)):
-            args = (
-                self.params, self._last_tokens, self.cache,
-                jnp.asarray(self._pos), self._keys, self._history,
-                self._hist_slot, jnp.asarray(self._index),
-            )
-            with self._prof.phase("dispatch"), \
-                    self._sentinel.decode_phase():
-                if constrained:
-                    # gather-and-mask runs inside this compiled program;
-                    # the per-slot row vector is the only per-step upload
-                    out = self._decode_single_masked(
-                        *args, self._mask_table,
-                        jnp.asarray(self._mask_rows_np()),
-                        *self._paged_args(1),
-                    )
-                else:
-                    out = self._pick_decode(block=False)(
-                        *args, *self._paged_args(1))
-            if self.logprobs_k:
-                (tok, self.cache, self._history, self._hist_slot,
-                 lpv_d, lpi_d) = out
+        args = (
+            self.params, self._last_tokens, self.cache,
+            jnp.asarray(self._pos), self._keys, self._history,
+            self._hist_slot, jnp.asarray(self._index),
+        )
+        with self._prof.phase("dispatch", steps=1,
+                              batch=len(self.streams)), \
+                self._sentinel.decode_phase():
+            if constrained:
+                # gather-and-mask runs inside this compiled program;
+                # the per-slot row vector is the only per-step upload
+                out = self._decode_single_masked(
+                    *args, self._mask_table,
+                    jnp.asarray(self._mask_rows_np()),
+                    *self._paged_args(1),
+                )
             else:
-                tok, self.cache, self._history, self._hist_slot = out
-                lpv_d = lpi_d = None
-            # sync: dispatch is async, busy_s needs compute
-            with self._prof.phase("sync"):
-                row = self._host(tok)
-                lp_h = ((self._host(lpv_d), self._host(lpi_d))
-                        if lpv_d is not None else None)
+                out = self._pick_decode(block=False)(
+                    *args, *self._paged_args(1))
+        if self.logprobs_k:
+            (tok, self.cache, self._history, self._hist_slot,
+             lpv_d, lpi_d) = out
+        else:
+            tok, self.cache, self._history, self._hist_slot = out
+            lpv_d = lpi_d = None
+        # sync: dispatch is async, busy_s needs compute
+        with self._prof.phase("sync"):
+            row = self._host(tok)
+            lp_h = ((self._host(lpv_d), self._host(lpi_d))
+                    if lpv_d is not None else None)
         self._n_decode_dispatches += 1
         dt = time.perf_counter() - t0
         self._busy_s += dt

@@ -412,11 +412,13 @@ class Worker:
                             tr.record(n, ts, d, args)
                 reply_len = sum(len(p) for p in reply)
                 bytes_out += reply_len
-                conn.send(MsgType.TENSOR, reply)
-                ops_done += len(ops)
+                # counted before the reply leaves: a master that reads the
+                # status right after its answer finds this exchange in it
                 self._ops_ctr.inc(len(ops))
                 self._bytes_in_ctr.inc(len(payload))
                 self._bytes_out_ctr.inc(reply_len)
+                conn.send(MsgType.TENSOR, reply)
+                ops_done += len(ops)
                 if ops_done >= STATS_EVERY:
                     dt = time.perf_counter() - t_window
                     log.info(

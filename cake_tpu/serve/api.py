@@ -33,10 +33,15 @@ scheduler:
   uninterrupted, bit-identical stream; queued sessions re-run whole on
   the sibling. Without ``migrate_to`` this is a classic drain.
 - ``GET /v1/models`` / ``GET /healthz`` — discovery and liveness.
-- ``GET /`` + ``GET /metrics`` — the exact statusd surface
-  (``obs.statusd.status_response``), so one port serves traffic AND
-  observability and stays byte-identical with a standalone
+- ``GET /`` + ``GET /metrics`` + ``GET /debug/prof`` — the exact
+  statusd surface (``obs.statusd.status_response``), so one port serves
+  traffic AND observability and stays byte-identical with a standalone
   ``--status-port`` page.
+- ``POST /debug/trace`` — the capture control (``obs/prof``): body
+  ``{"action": "start"}`` / ``{"action": "stop"}`` opens and closes a
+  ``jax.profiler`` trace, the span tracer and stride-1 phase stamping in
+  this process (the one that holds the chip). The answers carry the
+  directory and both host clocks; a second ``start`` is ``409``.
 
 Backpressure: a full admission queue answers ``429`` with a
 ``Retry-After`` derived from observed tokens/sec; a draining server
@@ -467,6 +472,9 @@ def _make_handler(server: ApiServer):
             if path == "/v1/batch":
                 self._batch_request()
                 return
+            if path == "/debug/trace":
+                self._debug_trace()
+                return
             if path != "/v1/completions":
                 self._error(404, f"no route for POST {self.path}")
                 return
@@ -531,6 +539,35 @@ def _make_handler(server: ApiServer):
             finally:
                 if sess.finish_reason is None:
                     scheduler.cancel(sess)
+
+        def _debug_trace(self) -> None:
+            """The capture control (obs/prof): ``{"action": "start"}``
+            opens the profiler, the span tracer and stride-1 phase
+            stamping in this process, ``{"action": "stop"}`` closes them
+            and answers where the trace is and when, on both host
+            clocks. The caller never chooses a path."""
+            from cake_tpu.obs import prof as obs_prof
+
+            try:
+                length = int(self.headers.get("Content-Length", 0))
+                action = json.loads(self.rfile.read(length) or b"{}").get(
+                    "action")
+            except (ValueError, UnicodeDecodeError, AttributeError) as e:
+                self._error(400, f"bad JSON body: {e}")
+                return
+            if action not in ("start", "stop"):
+                self._error(400, 'body must be {"action": "start"} or '
+                                 '{"action": "stop"}')
+                return
+            try:
+                self._json(200, obs_prof.capture_start() if action == "start"
+                           else obs_prof.capture_stop())
+            except (obs_prof.CaptureBusy, obs_prof.CaptureIdle) as e:
+                self._error(409, str(e))
+            except Exception as e:  # the profiler's own failure, in its words
+                log.exception("capture %s failed", action)
+                self._error(500, f"capture {action} failed: "
+                                 f"{type(e).__name__}: {e}")
 
         def _abort_resume_import(self, sess) -> None:
             """A resume refused before admission will never attach: drop

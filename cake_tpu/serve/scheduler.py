@@ -647,7 +647,13 @@ class Scheduler:
         # block-size buckets legitimately compile late on some deployments.
         warm_steps = int(os.environ.get("CAKE_PROF_WARM_STEPS", "32"))
         steps = 0
+        prof = obs_prof.profiler()
         while True:
+            # every pass is timed, parked time left out: a pass of
+            # seconds (a stall) leaves its parts in /debug/prof
+            # ``slow_passes`` (obs/prof.StepProfiler.note_pass)
+            t_pass = time.perf_counter()
+            parked = 0.0
             with self._cond:
                 self._expire_queued_locked()
                 while not self._stopping and not self._has_work_locked():
@@ -655,9 +661,9 @@ class Scheduler:
                         break  # drained dry: park
                     t_park = time.perf_counter()
                     self._cond.wait(timeout=0.1)
-                    obs_prof.profiler().observe_ms(
-                        "idle_park",
-                        (time.perf_counter() - t_park) * 1e3)
+                    dt_park = time.perf_counter() - t_park
+                    parked += dt_park
+                    prof.observe_ms("idle_park", dt_park * 1e3)
                     self._expire_queued_locked()
                     # imports awaiting resume are not "work" (nothing to
                     # step), but their TTL must still tick while parked —
@@ -669,6 +675,7 @@ class Scheduler:
                 if self._stopping or (self._draining
                                       and not self._has_work_locked()):
                     break
+                queued, running = len(self._queue), len(self._by_sid)
             try:
                 self._drain_import_inbox()
                 self._sweep_imports()
@@ -677,16 +684,25 @@ class Scheduler:
                     # and let the top-of-loop drain check park/exit
                     self._refresh_engine_stats(best_effort=True)
                     continue
-                self._admit()
+                with prof.pass_part("sched_admit") as p_admit:
+                    self._admit()
+                t_step = time.perf_counter()
                 row = self.engine.step()
+                step_ms = (time.perf_counter() - t_step) * 1e3
                 steps += 1
                 if steps == warm_steps:
                     obs_prof.sentinel().mark_steady()
-                self._deliver(row)
-                self._retire()
+                with prof.pass_part("deliver") as p_deliver:
+                    self._deliver(row)
+                with prof.pass_part("retire"):
+                    self._retire()
                 self._sweep_spilled()
                 self._fail_lost_attaches()
                 self._refresh_engine_stats()
+                prof.note_pass(
+                    (time.perf_counter() - t_pass - parked) * 1e3,
+                    {"admit_ms": p_admit.ms, "step_ms": step_ms,
+                     "deliver_ms": p_deliver.ms}, queued, running)
             except Exception as e:  # engine fault: fail every session
                 log.exception("engine thread fault: %s", e)
                 self.fault = f"{type(e).__name__}: {e}"
@@ -812,6 +828,11 @@ class Scheduler:
         with self._cond:
             sid = self._next_sid
             self._next_sid += 1
+        # submit -> handed to the engine: the wait for a slot and for
+        # undelivered rows (serve.queue_wait_ms; with the session's
+        # serve.admit_to_first_ms it adds up to serve.ttft_ms)
+        sess.t_admit = time.perf_counter()
+        _session.QUEUE_WAIT_MS.observe((sess.t_admit - sess.t_submit) * 1e3)
         ctx = sess.reqtrace
         if ctx is not None:
             t_now = time.time()

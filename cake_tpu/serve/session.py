@@ -53,6 +53,11 @@ CLASSES = ("interactive", "batch")
 # API handler share these series without import-order coupling).
 TTFT_MS = obs_metrics.histogram("serve.ttft_ms")
 TPOT_MS = obs_metrics.histogram("serve.tpot_ms")
+# the two legs of serve.ttft_ms, observed where each ends: the scheduler
+# hands the session to the engine (queue wait), the session emits its
+# first token (admission, prefill, the block it joined)
+QUEUE_WAIT_MS = obs_metrics.histogram("serve.queue_wait_ms")
+ADMIT_TO_FIRST_MS = obs_metrics.histogram("serve.admit_to_first_ms")
 QUEUE_DEPTH = obs_metrics.gauge("serve.queue_depth")
 REJECTED = obs_metrics.counter("serve.rejected")
 CANCELLED = obs_metrics.counter("serve.cancelled")
@@ -133,6 +138,7 @@ class Session:
         self.events: queue.Queue = queue.Queue()
         now = time.perf_counter()
         self.t_submit = now
+        self.t_admit: float | None = None  # handed to the engine
         self.deadline = now + timeout_s if timeout_s else None
         self._t_last: float | None = None
         self.ttft_ms: float | None = None
@@ -161,6 +167,8 @@ class Session:
             TTFT_MS.observe(self.ttft_ms)
             obs_metrics.histogram(
                 f"serve.ttft_ms.{self.cls}").observe(self.ttft_ms)
+            if self.t_admit is not None:  # a resume's replay has none
+                ADMIT_TO_FIRST_MS.observe((now - self.t_admit) * 1e3)
             self._t_first_unix = time.time()
             ctx = self.reqtrace
             if ctx is not None:

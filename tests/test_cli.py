@@ -2,6 +2,7 @@
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 from pathlib import Path
@@ -279,7 +280,13 @@ def test_profile_flag_writes_trace(model_dir, tmp_path):
         "--profile", str(trace_dir),
     ])
     assert r.returncode == 0, r.stderr
-    assert trace_dir.exists() and any(trace_dir.rglob("*"))
+    assert trace_dir.exists() and any(trace_dir.rglob("*.xplane.pb"))
+    # opened through the capture control (obs/prof): the run's own spans
+    # lie beside the profile, the phases of every step among them
+    spans = json.loads((trace_dir / "spans.trace.json").read_text())
+    names = {e["name"] for e in spans["traceEvents"]}
+    assert any(n.startswith("prof.") or n.startswith("decode")
+               for n in names), sorted(names)
 
 
 def test_missing_config_errors(tmp_path):
@@ -430,6 +437,31 @@ def test_serve_mode_e2e_with_drain(model_dir):
             body = r.read()
         assert body.count(b"data: ") == 6  # 4 tokens + done + [DONE]
         assert b"[DONE]" in body
+        # the parts of "model loaded in", as numbers the program reports
+        rep = json.loads(urllib.request.urlopen(
+            f"http://127.0.0.1:{port}/debug/prof", timeout=30).read())
+        up = rep["startup"]
+        assert set(up) == {"params_s", "engine_s", "warm_s", "loaded_s"}
+        assert all(v >= 0 for v in up.values())
+        assert (up["params_s"] + up["engine_s"] + up["warm_s"]
+                <= up["loaded_s"] + 0.01)
+        # the capture control, in the process that holds the device
+        answers = []
+        for action in ("start", "stop"):
+            ctl = urllib.request.Request(
+                f"http://127.0.0.1:{port}/debug/trace",
+                data=json.dumps({"action": action}).encode(),
+                headers={"Content-Type": "application/json"})
+            with urllib.request.urlopen(ctl, timeout=120) as r:
+                answers.append(json.loads(r.read()))
+        trace_dir = Path(answers[1]["dir"])
+        try:
+            assert answers[0]["dir"] == answers[1]["dir"]
+            assert answers[1]["perf_s"] > answers[0]["perf_s"]
+            assert list(trace_dir.rglob("*.xplane.pb"))
+            assert (trace_dir / "spans.trace.json").exists()
+        finally:
+            shutil.rmtree(trace_dir, ignore_errors=True)
         proc.send_signal(signal.SIGTERM)
         assert proc.wait(timeout=60) == 0
         assert b"drained" in proc.stderr.read()

@@ -179,7 +179,7 @@ def test_throughput_scales_on_virtual_mesh():
     # bar); transient contention is exactly what best-of smooths, while a
     # real regression fails all three samples
     best = 0.0
-    for _ in range(3):
+    for _ in range(5):
         t_serial = timed(build_sharded_decode, per_row=True)
         t_il = timed(build_interleaved_decode)
         best = max(best, t_serial / t_il)
@@ -187,7 +187,7 @@ def test_throughput_scales_on_virtual_mesh():
             break
     assert best > 1.25, (
         f"interleaved {t_il * 1e3:.0f}ms/block not faster than serialized "
-        f"{t_serial * 1e3:.0f}ms/block (best ratio {best:.2f} of 3 runs)"
+        f"{t_serial * 1e3:.0f}ms/block (best ratio {best:.2f} of 5 runs)"
     )
 
 

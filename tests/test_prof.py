@@ -11,6 +11,7 @@ regressed ledger.
 """
 
 import json
+import urllib.error
 import urllib.request
 
 import jax
@@ -251,6 +252,324 @@ def test_phase_spans_nest_under_request_spans(params, prof_env):
     hi = max(e["ts"] + e.get("dur", 0) for e in req_evs)
     inside = [e for e in prof_evs if lo <= e["ts"] <= hi]
     assert inside, "no phase span inside the request window"
+
+
+# -- the capture control (POST /debug/trace) ----------------------------------
+
+def _post(url, body, timeout=120):
+    req = urllib.request.Request(
+        url, data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"})
+    try:
+        with urllib.request.urlopen(req, timeout=timeout) as r:
+            return r.status, json.loads(r.read())
+    except urllib.error.HTTPError as e:
+        return e.code, json.loads(e.read())
+
+
+@pytest.fixture
+def served(params, prof_env):
+    """A tiny serve replica: (base url, scheduler)."""
+    gen = BatchGenerator(CFG, params, tokenizer=_FakeTok(),
+                         settings=SamplerSettings(**GREEDY))
+    sched = Scheduler(gen, queue_depth=4, request_timeout_s=120)
+    sched.start(max_concurrent=2)
+    srv = start_api_server(sched)
+    try:
+        yield f"http://127.0.0.1:{srv.port}", sched
+    finally:
+        if prof.capture().active:
+            prof.capture_stop()
+        srv.close()
+        sched.close()
+
+
+@pytest.fixture
+def captured(served):
+    """One capture around one request on the live replica: the answers
+    of start and stop, and the state the control must put back."""
+    import threading
+
+    from cake_tpu.obs import trace as obs_trace
+
+    url, _ = served
+    prof.profiler().set_sample(7)
+    threads_before = {t.name for t in threading.enumerate()}
+    code0, started = _post(url + "/debug/trace", {"action": "start"})
+    during = {"stride": prof.profiler().sample_every,
+              "tracer": obs_trace.tracer().enabled,
+              "annotations": obs_trace.tracer().xla_annotations}
+    code_again, again = _post(url + "/debug/trace", {"action": "start"})
+    code_req, _ = _post(url + "/v1/completions",
+                        {"prompt": "abcd", "max_tokens": 10,
+                         "stream": False})
+    code1, stopped = _post(url + "/debug/trace", {"action": "stop"})
+    yield {"codes": (code0, code_again, code_req, code1),
+           "started": started, "again": again, "stopped": stopped,
+           "during": during, "threads_before": threads_before, "url": url}
+    import shutil
+
+    shutil.rmtree(started.get("dir", ""), ignore_errors=True)
+
+
+def test_capture_answers_carry_the_directory_and_both_clocks(captured):
+    assert captured["codes"] == (200, 409, 200, 200)
+    a, b = captured["started"], captured["stopped"]
+    assert set(a) == {"dir", "unix_ns", "perf_s"}
+    assert set(b) == {"dir", "unix_ns", "perf_s", "steps", "spans",
+                      "dropped"}
+    assert a["dir"] == b["dir"]
+    assert b["perf_s"] > a["perf_s"] and b["unix_ns"] > a["unix_ns"]
+    # the two clocks of one answer name one instant
+    skew = (b["unix_ns"] - a["unix_ns"]) / 1e9 - (b["perf_s"] - a["perf_s"])
+    assert abs(skew) < 0.05
+    assert b["steps"] > 0 and b["spans"] > 0 and b["dropped"] == 0
+    assert "already open" in captured["again"]["error"]
+
+
+def test_capture_writes_the_profile_and_the_programs_spans(captured):
+    from pathlib import Path
+
+    d = Path(captured["stopped"]["dir"])
+    assert list(d.rglob("*.xplane.pb")), "no profile written"
+    doc = json.loads((d / "spans.trace.json").read_text())
+    names = {e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"}
+    assert {"prof.dispatch", "prof.sync", "prof.deliver"} <= names
+    # the origin the spans' ts count from, on the answers' perf clock
+    origin = doc["otherData"]["perf_origin_s"]
+    assert origin <= captured["started"]["perf_s"]
+    first = min(e["ts"] for e in doc["traceEvents"] if e.get("ph") == "X")
+    assert origin + first / 1e6 < captured["stopped"]["perf_s"]
+
+
+def test_capture_restores_the_stride_and_the_tracer(captured):
+    import threading
+
+    from cake_tpu.obs import trace as obs_trace
+
+    assert captured["during"] == {"stride": 1, "tracer": True,
+                                  "annotations": True}
+    assert prof.profiler().sample_every == 7
+    tr = obs_trace.tracer()
+    assert not tr.enabled and not tr.xla_annotations
+    assert tr.event_count() == 0
+    assert not prof.capture().active
+    # off, the control keeps no thread of its own
+    left = {t.name for t in threading.enumerate()}
+    assert "cake-capture-autostop" not in left
+    rep = json.loads(urllib.request.urlopen(
+        captured["url"] + "/debug/prof", timeout=30).read())
+    assert rep["capturing"] is False and rep["sample_every"] == 7
+
+
+def test_capture_puts_the_phases_on_the_traces_host_plane(captured):
+    """The point of the control: a device idle gap can be named by the
+    engine phase the host was in, because every phase is an event of the
+    profiler's own trace."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+
+    pb = sorted(Path(captured["stopped"]["dir"]).rglob("*.xplane.pb"))[-1]
+    data = ProfileData.from_file(str(pb))
+    names = {e.name for plane in data.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("prof.")}
+    assert {"prof.dispatch", "prof.sync"} <= names, sorted(names)
+
+
+def test_stop_without_a_capture_and_bad_bodies(served):
+    url, _ = served
+    assert _post(url + "/debug/trace", {"action": "stop"})[0] == 409
+    assert _post(url + "/debug/trace", {"action": "pause"})[0] == 400
+    assert _post(url + "/debug/trace", {})[0] == 400
+
+
+def test_a_capture_nobody_stops_is_stopped_by_the_program(
+        served, monkeypatch, tmp_path):
+    import time
+
+    from cake_tpu.obs import trace as obs_trace
+
+    monkeypatch.setattr(prof, "CAPTURE_MAX_S", 0.3)
+    monkeypatch.setattr(prof.capture(), "directory", str(tmp_path))
+    prof.profiler().set_sample(5)
+    started = prof.capture_start()
+    assert started["dir"] == str(tmp_path) and prof.capture().active
+    deadline = time.monotonic() + 30
+    while prof.capture().active and time.monotonic() < deadline:
+        time.sleep(0.05)
+    assert not prof.capture().active
+    assert prof.profiler().sample_every == 5
+    assert not obs_trace.tracer().enabled
+    assert (tmp_path / "spans.trace.json").exists()
+    with pytest.raises(prof.CaptureIdle):
+        prof.capture_stop()
+
+
+def test_capture_keeps_a_tracer_that_was_already_running(
+        served, monkeypatch, tmp_path):
+    """``--trace`` runs the tracer for the whole process: a capture
+    passes its spans through and leaves it, and its buffer, alone."""
+    from cake_tpu.obs import trace as obs_trace
+
+    monkeypatch.setattr(prof.capture(), "directory", str(tmp_path))
+    tr = obs_trace.tracer()
+    tr.start()
+    try:
+        with obs_trace.span("before.capture"):
+            pass
+        prof.capture_start(auto_stop=False)
+        assert tr.xla_annotations
+        prof.capture_stop()
+        assert tr.enabled and not tr.xla_annotations
+        assert tr.event_count() >= 1
+    finally:
+        tr.stop()
+        tr.clear()
+
+
+# -- timestamps, scheduler pass parts, slow passes ----------------------------
+
+def test_recent_steps_carry_clocks_and_phase_offsets(params, prof_env):
+    import time
+
+    prof.profiler().set_sample(1)
+    t_before = time.time_ns()
+    p_before = time.perf_counter()
+    gen = BatchGenerator(CFG, params, settings=SamplerSettings(**GREEDY))
+    _collect(gen, [3, 1, 4, 1, 5, 9], sid=5, steps=12)
+    steps = prof.profiler().recent_steps()
+    assert steps
+    for rec in steps:
+        assert t_before <= rec["t_unix_ns"] <= time.time_ns()
+        assert p_before <= rec["t_perf_s"] <= time.perf_counter()
+        assert set(rec["at_ms"]) == set(rec["phases"])
+        for name, at in rec["at_ms"].items():
+            assert 0 <= at <= rec["total_ms"], (name, rec)
+    both = [r for r in steps if {"dispatch", "sync"} <= set(r["at_ms"])]
+    assert both and all(r["at_ms"]["dispatch"] <= r["at_ms"]["sync"]
+                        for r in both)
+
+
+def test_phase_spans_on_unsampled_steps_while_the_tracer_runs(
+        params, prof_env):
+    """One stamp per interval: the dispatch phase is the dispatch span
+    (it carries ``steps`` and ``batch``), on every step of a traced run,
+    sampled or not."""
+    from cake_tpu.obs import trace as obs_trace
+
+    prof.profiler().set_sample(0)
+    tr = obs_trace.tracer()
+    tr.start()
+    try:
+        gen = BatchGenerator(CFG, params,
+                             settings=SamplerSettings(**GREEDY))
+        _collect(gen, [3, 1, 4], sid=5, steps=10)
+    finally:
+        tr.stop()
+    evs = [e for e in tr.to_chrome_trace()["traceEvents"]
+           if e.get("ph") == "X"]
+    tr.clear()
+    names = {e["name"] for e in evs}
+    assert "prof.dispatch" in names and "decode.dispatch" not in names
+    d = next(e for e in evs if e["name"] == "prof.dispatch")
+    assert d["args"]["steps"] >= 1 and d["args"]["batch"] == 2
+    assert prof.profiler().recent_steps() == []  # nothing was sampled
+
+
+@pytest.mark.parametrize("name", ["sched_admit", "deliver", "retire",
+                                  "idle_park"])
+def test_scheduler_pass_parts_are_declared_phases(name):
+    from cake_tpu.obs import catalog
+
+    assert name in prof.PHASES
+    assert catalog.kind_of(f"prof.phase_ms.{name}") == catalog.HISTOGRAM
+
+
+def test_a_slow_pass_leaves_its_parts(prof_env, monkeypatch):
+    """A scheduler pass over the limit adds its length to
+    ``prof.slow_pass_ms`` and keeps which part held it."""
+    import time
+
+    from cake_tpu.runtime.generator import Token
+    from cake_tpu.serve.engine import _Slot
+    from cake_tpu.serve.session import Session
+
+    class SlowStep:
+        """One slot; every step of a live stream takes 60 ms."""
+        config = CFG
+        tokenizer = None
+        eos_ids = ()
+
+        def __init__(self):
+            self.streams = [_Slot(stream_id=-1, prompt=[], done=True)]
+
+        def enqueue(self, ids, sid):
+            self.streams[0] = _Slot(stream_id=sid, prompt=list(ids))
+
+        def pending_admissions(self):
+            return 0
+
+        def finish(self, sid):
+            self.streams[0].done = True
+
+        def step(self):
+            if self.streams[0].done:
+                return [None]
+            time.sleep(0.06)
+            return [Token(id=7, text=None, is_end_of_stream=False)]
+
+        def stats(self):
+            return {}
+
+    monkeypatch.setattr(prof, "SLOW_PASS_MS", 50.0)
+    p = prof.profiler()
+    sched = Scheduler(SlowStep(), queue_depth=4)
+    sched.start(max_concurrent=1)
+    try:
+        t0 = time.time_ns()
+        sess = Session([1, 2], max_tokens=3)
+        sched.submit(sess)
+        while True:
+            ev = sess.events.get(timeout=30)
+            if ev[0] != "token":
+                break
+        assert ev[0] == "done" and len(sess.generated) == 3
+    finally:
+        sched.close()
+    slow = p.slow_passes()
+    assert len(slow) == 3
+    for rec in slow:
+        assert set(rec) == {"t_unix_ns", "total_ms", "admit_ms", "step_ms",
+                            "deliver_ms", "rest_ms", "queued", "running"}
+        assert rec["step_ms"] >= 50.0 > rec["admit_ms"] + rec["deliver_ms"]
+        assert rec["total_ms"] == pytest.approx(
+            rec["admit_ms"] + rec["step_ms"] + rec["deliver_ms"]
+            + rec["rest_ms"], abs=0.01)
+        assert t0 <= rec["t_unix_ns"] <= time.time_ns()
+    assert slow[0]["queued"] == 1 and slow[-1]["running"] == 1
+    rep = prof.report()
+    assert rep["slow_passes"] == slow
+    assert p._slow_ms.value == pytest.approx(
+        sum(r["total_ms"] for r in slow), abs=0.01)
+    assert p._slow_n.value == len(slow)
+    # parked time is not part of a pass: an idle scheduler stalls nothing
+    assert all(r["total_ms"] < 1000 for r in slow)
+
+
+def test_startup_reports_its_four_numbers(prof_env):
+    assert "startup" not in prof.report() or prof.report()["startup"]
+    prof.set_startup(params_s=1.23456, engine_s=0.5, warm_s=2.0,
+                     loaded_s=4.0)
+    try:
+        assert prof.report()["startup"] == {
+            "params_s": 1.235, "engine_s": 0.5, "warm_s": 2.0,
+            "loaded_s": 4.0}
+    finally:
+        prof._STARTUP.clear()
+    assert "startup" not in prof.report()
 
 
 # -- benchdiff gate -----------------------------------------------------------

@@ -325,6 +325,75 @@ def test_serve_metrics_on_shared_port(tok_server):
     assert health["ok"] is True
 
 
+def _ms(name: str) -> dict:
+    return obs_metrics.registry().snapshot()[name]
+
+
+def test_queue_wait_and_admit_to_first_add_up_to_ttft(tok_server):
+    """The two legs of a request's wait for its first token, each
+    observed where it ends (the scheduler hands the session to the
+    engine; the session emits its first token), are the whole of it."""
+    names = ("serve.queue_wait_ms", "serve.admit_to_first_ms",
+             "serve.ttft_ms")
+    _post(tok_server, {"prompt": "abcd", "max_tokens": 2})  # series exist
+    before = {n: _ms(n) for n in names}
+    _post(tok_server, {"prompt": "bcde", "max_tokens": 3})
+    d = {n: {f: _ms(n)[f] - before[n][f] for f in ("count", "sum")}
+         for n in names}
+    assert [d[n]["count"] for n in names] == [1, 1, 1]
+    assert d["serve.queue_wait_ms"]["sum"] >= 0
+    assert d["serve.admit_to_first_ms"]["sum"] > 0
+    assert (d["serve.queue_wait_ms"]["sum"]
+            + d["serve.admit_to_first_ms"]["sum"]
+            == pytest.approx(d["serve.ttft_ms"]["sum"], abs=1e-6))
+
+
+def test_queue_wait_counts_the_wait_for_a_slot(params):
+    """With one slot, a second request's queue wait is the first one's
+    whole stream, and its admit-to-first is not."""
+    gen = BatchGenerator(CFG, params, tokenizer=_FakeTok(),
+                         settings=SamplerSettings(**GREEDY))
+    sched = Scheduler(gen, queue_depth=4, request_timeout_s=120)
+    sched.start(max_concurrent=1)
+    try:
+        a = serve_session.Session([1, 2, 3], max_tokens=24)
+        b = serve_session.Session([4, 5, 6], max_tokens=2)
+        sched.submit(a)
+        sched.submit(b)
+        for sess in (a, b):
+            while sess.events.get(timeout=60)[0] == "token":
+                pass
+    finally:
+        sched.close()
+    wait_a = (a.t_admit - a.t_submit) * 1e3
+    wait_b = (b.t_admit - b.t_submit) * 1e3
+    assert wait_b > wait_a and wait_b > 0.5 * b.ttft_ms
+    assert b.ttft_ms == pytest.approx(
+        wait_b + (b._t_last - b.t_admit) * 1e3, abs=50.0)
+
+
+def test_new_series_are_declared_and_pass_the_catalog_checker():
+    from pathlib import Path
+
+    from cake_tpu.analysis import core
+    from cake_tpu.analysis.metrics_catalog import MetricsCatalogChecker
+    from cake_tpu.obs import catalog
+
+    assert catalog.kind_of("serve.queue_wait_ms") == catalog.HISTOGRAM
+    assert catalog.kind_of("serve.admit_to_first_ms") == catalog.HISTOGRAM
+    assert catalog.kind_of("prof.slow_pass_ms") == catalog.COUNTER
+    assert catalog.kind_of("prof.slow_passes") == catalog.COUNTER
+    root = Path(__file__).resolve().parent.parent
+    files = [str(root / "cake_tpu" / f) for f in (
+        "serve/session.py", "serve/scheduler.py", "obs/prof.py")]
+    assert core.run_checkers([MetricsCatalogChecker()], roots=files,
+                             repo_root=root) == []
+    snap = obs_metrics.registry().snapshot()
+    for name in ("serve.queue_wait_ms", "serve.admit_to_first_ms",
+                 "prof.slow_pass_ms", "prof.slow_passes"):
+        assert snap[name]["type"] == catalog.kind_of(name), name
+
+
 def test_status_surface_byte_identical_with_statusd(tok_server):
     """The API server's / + /metrics must stay byte-identical with a
     standalone obs.statusd page over the same status_fn — both build
