@@ -248,8 +248,13 @@ def block_forward(
     sp_chunk: bool = False,
     ep_axis: str | None = None,
     ep_size: int | None = None,
+    layer_idx: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One pre-norm decoder block (transformer.rs:48-64).
+
+    ``layer_idx``: ``k_cache``/``v_cache`` are the stacked ``[L, B,
+    kv_heads, S, D]`` cache and this block is layer ``layer_idx`` of it
+    (:func:`forward_layers`); None: they are this layer's own buffers.
 
     Under tensor parallelism (inside shard_map), ``num_heads``/``num_kv_heads``
     are the per-device local counts and ``tp_axis`` names the mesh axis the
@@ -284,6 +289,7 @@ def block_forward(
         bv=layer.get("bv"),
         bo=layer.get("bo"),
         window=config.sliding_window,
+        layer=layer_idx,
     )
     x = x + attn_out
     h = rms_norm(x, layer["mlp_norm"], config.rms_norm_eps,
@@ -324,20 +330,34 @@ def forward_layers(
     This is the TPU-native `Forwarder::forward_batch` (cake/mod.rs:143-150,
     worker.rs:208-219): one call executes any number of contiguous layers with
     no per-layer dispatch.
-    """
 
+    What the loop carries and what it writes: the activation and the WHOLE
+    stacked cache ``[L', B, kv_heads, S, D]`` are the scan's carry; the
+    layer weights and a layer index are its ``xs`` (read, never written).
+    Layer ``i`` writes only the ``T`` new rows of each stream, in place, at
+    ``[i, b, :, pos_b : pos_b + T, :]`` of the carried buffers
+    (:func:`cake_tpu.ops.kvcache.update_layer`), and attention reads layer
+    ``i``'s keys and values out of the same buffers. So a donated cache
+    that enters a program is the buffer that leaves it: there is no
+    per-layer output stack to allocate, fill and copy back, which is what
+    scanning the cache as ``xs``/``ys`` cost (two cache-sized copies, a slab
+    write and a slab read per layer, in every decode step).
+    """
     def body(carry, per_layer):
-        h = carry
-        layer, kc, vc = per_layer
+        h, kc, vc = carry
+        layer, i = per_layer
         h, kc, vc = block_forward(layer, h, kc, vc, cos, sin, pos, config,
                                   num_heads=num_heads, num_kv_heads=num_kv_heads,
                                   tp_axis=tp_axis, sp_axis=sp_axis,
                                   sp_size=sp_size, write_gate=write_gate,
                                   sp_prefill=sp_prefill, sp_chunk=sp_chunk,
-                                  ep_axis=ep_axis, ep_size=ep_size)
-        return h, (kc, vc)
+                                  ep_axis=ep_axis, ep_size=ep_size,
+                                  layer_idx=i)
+        return (h, kc, vc), None
 
-    x, (k_new, v_new) = jax.lax.scan(body, x, (layers, cache.k, cache.v))
+    (x, k_new, v_new), _ = jax.lax.scan(
+        body, (x, cache.k, cache.v),
+        (layers, jnp.arange(cache.num_layers, dtype=jnp.int32)))
     return x, KVCache(k=k_new, v=v_new)
 
 

@@ -251,11 +251,19 @@ def self_attention_block(
     bv: jax.Array | None = None,
     bo: jax.Array | None = None,  # o_proj bias (HF llama-arch attention_bias)
     window: int | None = None,  # sliding-window width (Mistral family)
+    layer: jax.Array | None = None,  # index into a stacked [L, ...] cache
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One attention sublayer incl. cache update.
 
     Returns ``(attn_out [B,T,hidden], new_k_cache, new_v_cache)``.
     Mirrors `attention.rs:30-90` + `cache.process_kv` (:57).
+
+    ``layer``: ``k_cache``/``v_cache`` are the stacked ``[L, B, kv_heads, S,
+    D]`` buffers the layer loop carries, and this is layer ``layer`` of
+    them: only its ``T`` new rows are written (in place,
+    :func:`cake_tpu.ops.kvcache.update_layer`) and attention reads its keys
+    and values out of the same buffers; the buffers come back whole. None:
+    they are one layer's own ``[B, kv_heads, S, D]``.
 
     ``tp_axis``: when run inside shard_map with heads sharded over a tensor-
     parallel mesh axis (Megatron-style: column-parallel qkv, row-parallel
@@ -307,6 +315,13 @@ def self_attention_block(
     if sp_axis is not None and sp_size > 1:
         from cake_tpu.ops import ring
 
+        # the sp writes select over a shard's whole window slice, so they
+        # take this layer's buffers out of the stacked cache and put them
+        # back (no served cell runs sp; the slot-sized write is the plain
+        # branch's)
+        k_stack, v_stack = k_cache, v_cache
+        k_cache = kv.layer_view(k_stack, layer)
+        v_cache = kv.layer_view(v_stack, layer)
         quantized = isinstance(k_cache, kv.QuantizedKV)
         s_l = kv._kv_data(k_cache).shape[2]
         sp_idx = jax.lax.axis_index(sp_axis)
@@ -387,13 +402,16 @@ def self_attention_block(
                 kv.dequant_kv(v_cache, q.dtype), pos, sp_axis, shard_start,
                 window=window,
             )
+        k_cache = kv.layer_store(k_stack, k_cache, layer)
+        v_cache = kv.layer_store(v_stack, v_cache, layer)
     else:
         q = apply_rope(q, cos, sin, pos)
         k = apply_rope(k, cos, sin, pos)
         k_cache, v_cache = kv.update_layer(k_cache, v_cache, k, v, pos,
-                                           gate=write_gate)
-        quantized = isinstance(k_cache, kv.QuantizedKV)
-        if quantized:
+                                           gate=write_gate, layer=layer)
+        k_l = kv.layer_view(k_cache, layer)
+        v_l = kv.layer_view(v_cache, layer)
+        if isinstance(k_l, kv.QuantizedKV):
             # int8 KV. Long-context prefill (the measured flash regime,
             # S >= PREFILL_FLASH_MIN_S) routes to the quantization-aware
             # flash kernel, which folds the per-token scales into the
@@ -404,7 +422,7 @@ def self_attention_block(
             # operand would be a materialized bf16 KV buffer in HBM,
             # losing the bandwidth win, so plain flash is never used with
             # the quantized cache.)
-            s_len = k_cache.q.shape[2]
+            s_len = k_l.q.shape[2]
             use_q8_flash = (
                 t > 1
                 and jnp.asarray(pos).ndim == 0
@@ -412,15 +430,15 @@ def self_attention_block(
             )
             if use_q8_flash:
                 out = pk.flash_attention_q8(
-                    q, k_cache.q, k_cache.scale, v_cache.q, v_cache.scale,
+                    q, k_l.q, k_l.scale, v_l.q, v_l.scale,
                     pos, window=window,
                 )
             else:
-                out = attend(q, kv.dequant_kv(k_cache, q.dtype),
-                             kv.dequant_kv(v_cache, q.dtype), pos,
+                out = attend(q, kv.dequant_kv(k_l, q.dtype),
+                             kv.dequant_kv(v_l, q.dtype), pos,
                              impl="xla", window=window)
         else:
-            out = attend(q, k_cache, v_cache, pos, window=window)  # [B,H,T,D]
+            out = attend(q, k_l, v_l, pos, window=window)  # [B,H,T,D]
 
     out = out.transpose(0, 2, 1, 3).reshape(b, t, num_heads * d)
     out = quant.dense(out, wo)

@@ -4,9 +4,19 @@ TPU-native redesign of the reference cache (`cake-core/src/model/cache.rs`).
 The reference appends K/V per token with `Tensor::cat` along the sequence axis
 (cache.rs:106-135) — a realloc-per-step pattern that would force an XLA retrace
 on every decode step. Here the cache is a preallocated
-``[num_layers, batch, num_kv_heads, max_seq, head_dim]`` pytree updated in
-place with ``lax.dynamic_update_slice`` and donated across steps, so every
-decode step compiles once and reuses the same HBM buffers.
+``[num_layers, batch, num_kv_heads, max_seq, head_dim]`` pytree, donated
+across steps, so every decode step compiles once and reuses the same HBM
+buffers.
+
+What is carried and what is written: the whole stacked pytree is the CARRY
+of the layer loop (:func:`cake_tpu.models.llama.forward_layers`), of the
+staged pipeline loop and of the fused multi-step decode loop around it, so
+the buffer a program is given is the buffer it returns. A layer writes
+only its ``T`` new rows per stream (:func:`update_layer` with ``layer``: a
+``lax.dynamic_update_slice`` of ``[1, 1, KH, T, D]`` on the carried
+buffer, in place) and attention reads that layer's keys and values out of
+the same buffer (:func:`layer_view`, a slice the consumer fuses). No
+per-layer slab and no second cache is allocated, copied or written back.
 
 The reference's other two cache jobs are relocated where XLA wants them:
 RoPE tables (cache.rs:31-50) live in :mod:`cake_tpu.ops.rope`; causal masks
@@ -67,8 +77,9 @@ class KVCache:
     """Preallocated per-layer key/value buffers.
 
     Shapes: ``k, v: [num_layers, batch, num_kv_heads, max_seq, head_dim]``.
-    The leading layer axis makes the cache scannable alongside stacked layer
-    weights, and shardable along a pipeline-stage mesh axis.
+    The leading layer axis is indexed by the layer loop, which carries the
+    whole cache beside the scanned layer weights, and is shardable along a
+    pipeline-stage mesh axis.
 
     ``k``/``v`` may each be a plain array or a :class:`QuantizedKV` (int8
     storage + per-slot scales); every consumer goes through
@@ -129,6 +140,26 @@ def init_cache(
     return KVCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt))
 
 
+def layer_view(cache, layer):
+    """Layer ``layer``'s ``[B, KH, S(, D)]`` keys or values read out of a
+    stacked ``[L, B, KH, S(, D)]`` buffer (plain or :class:`QuantizedKV`);
+    ``layer`` None: ``cache`` already is one layer's buffer."""
+    if layer is None:
+        return cache
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        cache)
+
+
+def layer_store(cache, value, layer):
+    """Inverse of :func:`layer_view`: put one layer's whole buffer back."""
+    if layer is None:
+        return value
+    return jax.tree.map(
+        lambda a, v: jax.lax.dynamic_update_index_in_dim(a, v, layer, 0),
+        cache, value)
+
+
 def update_layer(
     k_cache: jax.Array,
     v_cache: jax.Array,
@@ -136,9 +167,18 @@ def update_layer(
     v_new: jax.Array,
     pos: jax.Array,
     gate: jax.Array | None = None,
+    layer: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Write ``k_new/v_new [batch, kv_heads, T, head_dim]`` into one layer's
-    buffers ``[batch, kv_heads, max_seq, head_dim]`` at sequence offset ``pos``.
+    ``T`` slots at sequence offset ``pos``, and nothing else.
+
+    ``layer`` None: the buffers are that layer's own ``[batch, kv_heads,
+    max_seq, head_dim]``. ``layer`` an index: they are the stacked
+    ``[L, batch, kv_heads, max_seq, head_dim]`` cache that the layer loop
+    carries (:func:`cake_tpu.models.llama.forward_layers`), and the rows go
+    to ``[layer, b, :, pos_b : pos_b + T, :]`` of it: a
+    ``dynamic_update_slice`` of ``T`` rows on the carried buffer, which XLA
+    performs in place, so no layer's slab is copied out or back.
 
     Replaces the reference's `process_kv` concat (cache.rs:106-135) — including
     *not* reproducing its axis-confused trimming bug (length checks on the
@@ -155,37 +195,42 @@ def update_layer(
     multi-stream serving path, where right-padded prompts of different
     lengths decode concurrently).
     """
-    t = k_new.shape[2]
     pos = jnp.asarray(pos, jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+    lead = () if layer is None else (jnp.asarray(layer, jnp.int32),)
 
-    def write_buf(cache, new, has_d):
-        """``has_d``: buffer carries a trailing head_dim axis (the int8
-        ``q``/plain arrays); scales are the same layout minus that axis."""
+    def put(cache, new, idx):
+        """``new`` at ``idx`` (offsets of the axes after the layer axis)."""
+        idx = lead + idx
+        new = new.reshape((1,) * len(lead) + new.shape)
+        if gate is not None:
+            new = jnp.where(gate, new,
+                            jax.lax.dynamic_slice(cache, idx, new.shape))
+        return jax.lax.dynamic_update_slice(cache, new, idx)
+
+    def write_buf(cache, new):
+        """Plain arrays and int8 ``q`` carry a trailing head_dim axis;
+        scales are the same layout minus that axis."""
+        tail = (zero,) * (new.ndim - 3)
         if pos.ndim == 0:
-            if gate is not None:
-                cur = jax.lax.dynamic_slice_in_dim(cache, pos, t, axis=2)
-                new = jnp.where(gate, new, cur)
-            zero = jnp.zeros((), jnp.int32)
-            idx = (zero, zero, pos, zero) if has_d else (zero, zero, pos)
-            return jax.lax.dynamic_update_slice(cache, new, idx)
+            return put(cache, new, (zero, zero, pos) + tail)
 
-        def one(c, n, p):  # c [KH, S(, D)], n [KH, T(, D)]
-            if gate is not None:
-                cur = jax.lax.dynamic_slice_in_dim(c, p, t, axis=1)
-                n = jnp.where(gate, n, cur)
-            zero = jnp.zeros((), jnp.int32)
-            idx = (zero, p, zero) if has_d else (zero, p)
-            return jax.lax.dynamic_update_slice(c, n, idx)
-
-        return jax.vmap(one)(cache, new, pos)
+        # One update per stream, unrolled (the batch is static and small).
+        # Not a loop of its own: a while body that holds nothing but this
+        # row write is laid out first by the TPU compiler, which then
+        # gives the whole carried cache the layout a [KH, 1, D] row likes
+        # (KH beside D) and re-lays the cache on the way into and out of
+        # every program, and each layer's keys before its scores.
+        for b in range(new.shape[0]):
+            cache = put(cache, new[b:b + 1],
+                        (jnp.asarray(b, jnp.int32), zero, pos[b]) + tail)
+        return cache
 
     def write(cache, new):
         if isinstance(cache, QuantizedKV):
             qn = quant_kv(new)  # quantize-on-write
-            return QuantizedKV(
-                q=write_buf(cache.q, qn.q, True),
-                scale=write_buf(cache.scale, qn.scale, False),
-            )
-        return write_buf(cache, new.astype(cache.dtype), True)
+            return QuantizedKV(q=write_buf(cache.q, qn.q),
+                               scale=write_buf(cache.scale, qn.scale))
+        return write_buf(cache, new.astype(cache.dtype))
 
     return write(k_cache, k_new), write(v_cache, v_new)

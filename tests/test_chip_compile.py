@@ -104,23 +104,19 @@ def test_kernel_compiles_for_v5e(topo, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _block_decode_bytes(topo, layers: int) -> tuple[int, int]:
-    """(arguments, temporaries) of the engine's fused 8-step per-row block
-    decode -- BatchGenerator's ``build_sharded_decode(steps=8,
-    per_row=True)`` -- for ``layers`` layers at Mistral-7B widths, int8
-    weights, 8 slots at a 2048 window (chip_smoke.py's sizes), compiled
-    for one described v5e from ``jax.eval_shape`` shapes."""
+def _engine_shapes(topo, layers: int, batch: int):
+    """Config, one-device plan and the placed shapes of parameters and a
+    ``batch``-row cache for ``layers`` layers at Mistral-7B widths, int8
+    weights, a 2048 window (chip_smoke.py's and the dense cell's sizes),
+    from ``jax.eval_shape``: nothing is allocated."""
     from jax.sharding import NamedSharding
 
     from cake_tpu.models.config import mistral_7b
     from cake_tpu.models.llama import init_params_int8
     from cake_tpu.ops.kvcache import init_cache
-    from cake_tpu.ops.sampling import SamplerSettings
     from cake_tpu.parallel.mesh import MeshPlan, cache_specs, param_specs
-    from cake_tpu.parallel.pipeline import build_sharded_decode
 
-    batch, window = 8, 2048
-    config = mistral_7b(max_seq_len=window, num_hidden_layers=layers)
+    config = mistral_7b(max_seq_len=WINDOW, num_hidden_layers=layers)
     plan = MeshPlan.build(config, devices=topo.devices[:1])
 
     def placed(shapes, specs):
@@ -134,53 +130,166 @@ def _block_decode_bytes(topo, layers: int) -> tuple[int, int]:
     params = placed(params, param_specs(params))
     cache = placed(
         jax.eval_shape(lambda: init_cache(config, batch=batch,
-                                          max_seq=window)),
-        cache_specs(None))
-    settings = SamplerSettings(temperature=0.0)
+                                          max_seq=WINDOW)),
+        cache_specs(None, batch_replicated=batch == 1))
     rep = NamedSharding(plan.mesh, jax.sharding.PartitionSpec())
 
     def arg(shape, dtype=I32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
 
+    return config, plan, params, cache, arg
+
+
+SLOTS, WINDOW = 8, 2048
+
+
+def _block_decode(topo, layers: int):
+    """The engine's fused 8-step per-row block decode -- BatchGenerator's
+    ``build_sharded_decode(steps=8, per_row=True)`` -- over 8 slots,
+    compiled for one described v5e."""
+    from cake_tpu.ops.sampling import SamplerSettings
+    from cake_tpu.parallel.pipeline import build_sharded_decode
+
+    config, plan, params, cache, arg = _engine_shapes(topo, layers, SLOTS)
+    settings = SamplerSettings(temperature=0.0)
     prog = build_sharded_decode(config, settings, plan, params_like=params,
                                 steps=8, per_row=True)
-    compiled = prog.lower(
-        params, arg((batch,)), cache, arg((batch,)),
-        arg((batch, 2), jnp.uint32),
-        arg((batch, settings.repeat_last_n)), arg((batch,)), arg((batch,)),
+    return prog.lower(
+        params, arg((SLOTS,)), cache, arg((SLOTS,)),
+        arg((SLOTS, 2), jnp.uint32),
+        arg((SLOTS, settings.repeat_last_n)), arg((SLOTS,)), arg((SLOTS,)),
     ).compile()
+
+
+def _admit_prefill(topo, layers: int, bucket: int):
+    """The engine's admission program -- ``build_admit_prefill`` -- one
+    ``bucket``-token chunk into the batch-1 staging cache."""
+    from cake_tpu.parallel.pipeline import build_admit_prefill
+
+    config, plan, params, cache, arg = _engine_shapes(topo, layers, 1)
+    prog = build_admit_prefill(config, plan, params_like=params)
+    return prog.lower(params, arg((1, bucket)), cache, arg(()),
+                      arg((1,))).compile()
+
+
+def _instructions(compiled):
+    """``(computation, name, shape, op, line)`` of every instruction of
+    the compiled program's text; ``shape`` without layout, ``bf16[2,8]``."""
+    import re
+
+    comp = ""
+    for line in compiled.as_text().splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        inst = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = \(?(\w+\[[\d,]*\])\S* ([\w\-]+)\(",
+            line)
+        if inst:
+            yield comp, inst.group(1), inst.group(2), inst.group(3), line
+
+
+def _cache_sized_moves(compiled, stacked: str) -> list[str]:
+    """What the program does with a value of the stacked cache's shape
+    besides updating it in place: every ``AllocateBuffer`` of that shape
+    and every ``copy`` (or asynchronous ``copy-start``) that produces
+    one, in whatever computation."""
+    return [f"{comp}: {name} ({op})"
+            for comp, name, shape, op, line in _instructions(compiled)
+            if shape == stacked and (
+                op in ("copy", "copy-start")
+                or (op == "custom-call" and "AllocateBuffer" in line))]
+
+
+def _slabs_written(compiled, slabs: tuple[str, ...]) -> list[str]:
+    """Instructions that leave one layer's whole keys or values behind as
+    a value of their own (not inside a fusion, where a slice of the
+    carried cache is just how the consumer addresses it)."""
+    return [f"{comp}: {name} ({op})"
+            for comp, name, shape, op, _ in _instructions(compiled)
+            if shape in slabs and not comp.startswith("fused_computation")
+            and op not in ("parameter", "get-tuple-element", "bitcast",
+                           "tuple")]
+
+
+def _donated_bytes(compiled) -> tuple[int, int]:
+    """(arguments, temporaries) by the compiler's own memory analysis,
+    having checked that the donated cache leaves in the buffers it came
+    in (outputs alias arguments)."""
     m = compiled.memory_analysis()
-    # the cache is donated, so outputs alias arguments
     assert m.alias_size_in_bytes >= m.output_size_in_bytes * 0.99
     return m.argument_size_in_bytes, m.temp_size_in_bytes
 
 
-def test_block_decode_program_fits_one_chip(topo, monkeypatch):
+GIB = 2**30
+LAYER_CACHE = SLOTS * KVH * WINDOW * D * 2 * 2  # B, KVH, S, D, k+v, bf16
+
+
+@pytest.fixture
+def as_on_chip(monkeypatch):
+    """Code under trace asks ``jax.default_backend()`` and would take its
+    CPU branch; steer it here, in the test, as the guide says -- never
+    through an option of the program."""
+    monkeypatch.setattr(pk, "on_tpu", lambda: True)
+
+
+def test_block_decode_program_fits_one_chip(topo, as_on_chip):
     """One whole engine program on one described device, by the
-    compiler's own memory analysis. Compiled at depth 1 and 2 (a second
-    each; the layer loop is a scan). The two-layer program fits; its
-    arguments grow exactly linearly with depth, so they are carried to
-    the 32 layers the smoke serves; and the temporaries are held to what
-    PR 21's rehearsal found: this program keeps about one KV cache of
-    temporaries beside the donated cache (1.0x at depth 2, 1.4x at depth
-    32; CHANGES.md). A change that adds another cache-sized temporary is
-    caught here before the 32-layer server meets the allocator on the
-    chip."""
+    compiler's own text and memory analysis, at depth 2, 4 and 32 (a few
+    seconds each; the layer loop is a scan).
+
+    The stacked cache is the layer loop's carry and each stream's row is
+    written into it in place (``models/llama.forward_layers``,
+    ``ops/kvcache.update_layer``; PR 26). So the program allocates no
+    second buffer of the cache's shape and copies none: in no loop, and
+    not in ENTRY either, since the compiler keeps the carried cache in
+    the parameter's own layout and nothing is re-laid on the way in or
+    out. No instruction of the layer loop leaves a layer's slab behind
+    before attention: the score and value fusions slice the carried
+    buffer themselves. Before PR 26 the cache was scanned as ``xs``/``ys``:
+    two such allocations, two copies in every decode step, a slab written
+    and a slab read per layer, and about one KV cache of temporaries
+    beside the donated one (1.07x at depth 2).
+
+    Temporaries: under a quarter of the cache at depth 4, and under 0.4 of
+    it at depth 2 and 32. What is left at depth 2 (0.33 of that cache) are
+    re-laid copies of the int8 attention weights, ``s8[L,4096,4096]`` and
+    ``s8[L,4096,1024]``, made once a dispatch in ENTRY; they have nothing
+    to do with the cache, the compiler keeps them in fast memory at depth
+    4, and at 32 layers they are 0.75 GiB of HBM, 0.375 of that cache. The
+    32-layer program itself, with a GiB for the admission staging row
+    and the allocator, fits the chip with the whole 2 GiB cache."""
     from cake_tpu.utils.chips import HBM_GIB
 
-    # code under trace asks jax.default_backend() and would take its CPU
-    # branch; steer it here, in the test, as the guide says -- never
-    # through an option of the program
-    monkeypatch.setattr(pk, "on_tpu", lambda: True)
-    gib = 2**30
-    hbm = HBM_GIB["v5 lite"] * gib
-    a1, _ = _block_decode_bytes(topo, 1)
-    a2, t2 = _block_decode_bytes(topo, 2)
-    assert a2 + t2 < hbm
-    cache_per_layer = 8 * 8 * 2048 * 128 * 2 * 2  # B, KVH, S, D, k+v, bf16
-    assert t2 <= 1.5 * 2 * cache_per_layer + 0.05 * gib, t2 / gib
-    args32 = a2 + 30 * (a2 - a1)
-    assert 8.8 * gib < args32 < 8.95 * gib, args32 / gib  # 6.87 + 2.0
-    # with 1.5 caches of temporaries and a GiB for the admission staging
-    # row and the allocator, the 32-layer server still fits
-    assert args32 + 1.5 * 32 * cache_per_layer + 1.0 * gib < hbm
+    for depth, bar in ((2, 0.4), (4, 0.25), (32, 0.4)):
+        compiled = _block_decode(topo, depth)
+        assert _cache_sized_moves(
+            compiled, f"bf16[{depth},{SLOTS},{KVH},{WINDOW},{D}]") == []
+        assert _slabs_written(compiled, (
+            f"bf16[1,{SLOTS},{KVH},{WINDOW},{D}]",
+            f"bf16[{SLOTS},{KVH},{WINDOW},{D}]")) == []
+        args, temps = _donated_bytes(compiled)
+        assert temps <= bar * depth * LAYER_CACHE, (depth, temps / GIB)
+    assert 8.8 * GIB < args < 8.95 * GIB, args / GIB  # 6.87 weights + 2.0
+    assert args + temps + 1.0 * GIB < HBM_GIB["v5 lite"] * GIB
+
+
+def test_admit_prefill_program_keeps_one_staging_cache(topo, as_on_chip):
+    """The admission program (``build_admit_prefill``: one 512-token
+    chunk into the batch-1 staging cache) shares the layer loop, so it
+    is held to the same facts at depth 2 and 4: no allocation and no copy
+    of the staging cache's shape anywhere, the donated cache aliased to
+    the result. Its temporaries are the chunk's activations and do not
+    grow with depth (9.2 MiB at depth 2, 4 and 32): the bar of 12 MiB is
+    three quarters of the 2-layer staging cache and three eighths of the
+    4-layer one; the scanned form kept 34 and 50 MiB (2.1 and 1.6 such
+    caches). What the program may still do is fetch a layer's keys and
+    values (4 MiB each) into fast memory ahead of the chunk's attention:
+    that read is the one attention needs."""
+    for depth in (2, 4):
+        compiled = _admit_prefill(topo, depth, 512)
+        assert _cache_sized_moves(
+            compiled, f"bf16[{depth},1,{KVH},{WINDOW},{D}]") == []
+        _, temps = _donated_bytes(compiled)
+        assert temps <= 12 * 2**20, (depth, temps / 2**20)

@@ -1,3 +1,5 @@
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -146,3 +148,98 @@ def test_llama2_7b_preset_real_geometry():
         32000, 4096, 11008)
     assert cfg.num_attention_heads == cfg.num_key_value_heads == 32
     assert cfg.head_dim == 128 and cfg.rope_theta == 10000.0
+
+
+@functools.lru_cache(maxsize=None)
+def _carried_loop_case(moe: bool, quant: str | None):
+    """Config, layer weights, a cache whose every row holds something, and
+    the rope tables, for the layer-loop parity cases (built once a
+    family x cache kind)."""
+    from cake_tpu.models.config import tiny_moe
+    from cake_tpu.ops.kvcache import KVCache, QuantizedKV
+
+    make = tiny_moe if moe else tiny
+    cfg = make(num_hidden_layers=3, max_seq_len=24, dtype="bfloat16")
+    layers = llama.init_params(cfg, jax.random.PRNGKey(7))["layers"]
+    assert ("router" in layers) == moe
+    shape = (3, 3, cfg.num_key_value_heads, cfg.max_seq_len, cfg.head_dim)
+    keys = iter(jax.random.split(jax.random.PRNGKey(11), 4))
+
+    def half():
+        if quant is None:
+            return jax.random.normal(next(keys), shape, jnp.bfloat16)
+        return QuantizedKV(
+            q=jax.random.randint(next(keys), shape, -127, 128, jnp.int8),
+            scale=jax.random.uniform(next(keys), shape[:-1], jnp.float32,
+                                     0.005, 0.02))
+
+    cache = KVCache(k=half(), v=half())
+    cos, sin = rope_tables(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
+    return cfg, layers, cache, cos, sin
+
+
+@pytest.mark.parametrize("family", ["dense", "router"])
+@pytest.mark.parametrize("gate", [None, True, False])
+@pytest.mark.parametrize("t", [1, 5])
+@pytest.mark.parametrize("pos_kind", ["scalar", "per_row"])
+@pytest.mark.parametrize("quant", [None, "int8"])
+def test_forward_layers_is_the_block_loop_bit_for_bit(quant, pos_kind, t,
+                                                      gate, family):
+    """``forward_layers`` carries the stacked cache through its scan and
+    writes each stream's ``T`` rows in place; a plain Python loop of
+    ``block_forward`` over each layer's own slices is what it has to
+    equal, bit for bit: the activations (so every id picked from them)
+    and every cache leaf. And the rows no stream wrote are bitwise what
+    they were (all of them when the gate is off)."""
+    cfg, layers, cache, cos, sin = _carried_loop_case(family == "router",
+                                                      quant)
+    batch = 3
+    x = jax.random.normal(jax.random.PRNGKey(3), (batch, t, cfg.hidden_size),
+                          jnp.bfloat16)
+    pos = (jnp.int32(6) if pos_kind == "scalar"
+           else jnp.asarray([0, 9, cfg.max_seq_len - t], jnp.int32))
+    write_gate = None if gate is None else jnp.asarray(gate)
+
+    @jax.jit
+    def carried(layers, x, cache):
+        return llama.forward_layers(layers, x, cache, cos, sin, pos, cfg,
+                                    write_gate=write_gate)
+
+    @jax.jit
+    def block(layer, h, kc, vc):
+        return llama.block_forward(layer, h, kc, vc, cos, sin, pos, cfg,
+                                   write_gate=write_gate)
+
+    got_x, got = carried(layers, x, cache)
+
+    h, ks, vs = x, [], []
+    for i in range(cfg.num_hidden_layers):
+        layer_i, kc, vc = jax.tree.map(lambda a: a[i],
+                                       (layers, cache.k, cache.v))
+        h, kc, vc = block(layer_i, h, kc, vc)
+        ks.append(kc)
+        vs.append(vc)
+    want = type(cache)(k=jax.tree.map(lambda *a: jnp.stack(a), *ks),
+                       v=jax.tree.map(lambda *a: jnp.stack(a), *vs))
+
+    np.testing.assert_array_equal(np.asarray(got_x, np.float32),
+                                  np.asarray(h, np.float32))
+    # which slots [B, S] of each stream were written
+    slot = np.arange(cfg.max_seq_len)
+    starts = np.broadcast_to(np.asarray(pos), (batch,))
+    written = ((slot[None] >= starts[:, None])
+               & (slot[None] < starts[:, None] + t))
+    if gate is False:
+        written[:] = False
+    for g, w, before in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                            jax.tree.leaves(cache)):
+        assert g.dtype == w.dtype == before.dtype
+        g, w, before = (np.asarray(a, np.float32) for a in (g, w, before))
+        np.testing.assert_array_equal(g, w)
+        keep = ~written[None, :, None, :]  # leaves are [L, B, KH, S(, D)]
+        keep = keep[..., None] if g.ndim == 5 else keep
+        keep = np.broadcast_to(keep, g.shape)
+        np.testing.assert_array_equal(g[keep], before[keep])
+        if gate is not False:
+            # the new rows did land: random rows never equal the old ones
+            assert (g[~keep] != before[~keep]).any()
