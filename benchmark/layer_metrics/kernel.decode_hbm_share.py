@@ -1,8 +1,8 @@
 """Share of the memory roofline a decode step reaches: the bytes a step
-must read (``shapes.decode_step_bytes``: weights as stored, the routed
-experts, the live streams' keys and values) over the chips' peak
-bandwidth (``peaks.json``), over the step's device time. Bound: memory."""
-import shapes
+must read (``ctx["arch"].decode_step_bytes``, the count of the
+configuration's architecture: weights as stored, the routed experts, the
+live streams' cached state) over the chips' peak bandwidth
+(``peaks.json``), over the step's device time. Bound: memory."""
 from counters import decode_step_ms, series_delta
 
 
@@ -18,7 +18,7 @@ def read(ctx):
     if not done:
         return None
     context = sum(r["prompt_len"] + r["asked"] / 2 for r in done) / len(done)
-    need = shapes.decode_step_bytes(ctx["cfg"], b["weights"]["layout"], rows,
-                                    context, b["serve_dtype"])
+    need = ctx["arch"].decode_step_bytes(
+        ctx["cfg"], b["weights"]["layout"], rows, context, b["serve_dtype"])
     floor_s = need / (ctx["chips"] * ctx["peaks"]["hbm_gb_per_s"] * 1e9)
     return 100.0 * floor_s / (step_ms / 1e3)

@@ -6,23 +6,24 @@
     python benchmark/run.py --workload <cell> --rehearse            (tiny, CPU)
 
 The parent (this file: standard library and numpy, never JAX) finds the
-cell's configuration, traffic mix and per-layer readers BY NAME under
-``benchmark/``, writes the configuration's seeded checkpoint once per
-checkout, starts the real server (``cake_tpu.cli --mode serve``, through
-``serve_child.py``) on the cell's chips, warms the shapes the mix can
-draw, probes correctness, measures for ``--seconds``, SIGTERMs the server
-and holds it to a clean drain. Its last line of output is the result
-(``correct``, ``attempted``, ``failed``, ``metrics``, ``device``): the
-cell's end-to-end metrics with ``--trace 0``, its per-layer metrics (and
-``breakdown``) with ``--trace 1``, both side by side with ``--trace 2``.
+cell's configuration, its architecture, traffic mix and per-layer readers
+BY NAME under ``benchmark/``, writes the configuration's seeded checkpoint
+once per checkout (the architecture's writer), starts the real server
+(``cake_tpu.cli --mode serve``, through ``serve_child.py``) on the cell's
+chips, warms the shapes the mix can draw, probes correctness, measures
+for ``--seconds``, SIGTERMs the server and holds it to a clean drain. Its
+last line of output is the result (``correct``, ``attempted``,
+``failed``, ``metrics``, ``device``): the cell's end-to-end metrics with
+``--trace 0``, its per-layer metrics (and ``breakdown``) with ``--trace
+1``, both side by side with ``--trace 2``.
 A ``--trace 2`` run IS a ``--trace 0`` run up to the moment its window
 closes; only then does it ask the server (``POST /debug/trace``, the
 program's capture control) to trace a few seconds of the same mix.
 ``--trace 1`` asks the same control in the middle of the window. A run
 that finds no TPU, a server that answered from the CPU or did not
 drain: non-zero exit and no result.
-README.md in this directory says how to add a cell without touching
-this file.
+README.md in this directory says how to add a cell, a configuration or
+a decoder this file has never seen without touching a file that is here.
 """
 
 from __future__ import annotations
@@ -105,16 +106,33 @@ def overlay(base: dict, over: dict) -> dict:
     return out
 
 
+def _load_by_path(module: str, path: Path):
+    spec = importlib.util.spec_from_file_location(module, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(name: str):
     """``layer_metrics/<metric name>.py``, loaded by path: its ``read(ctx)``
     returns the metric's value, or None for nothing to read."""
     path = HERE / "layer_metrics" / f"{name}.py"
     if not path.exists():
         return None
-    spec = importlib.util.spec_from_file_location(f"bench_layer_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_by_path(f"bench_layer_{name}", path).read
+
+
+def load_arch(name: str):
+    """``arch/<name>.py``, loaded by path: the module that knows one
+    decoder's tensors and mathematics (README.md, "Adding an
+    architecture", lists what it defines). A configuration file names it
+    under ``bench.arch``."""
+    path = HERE / "arch" / f"{name}.py"
+    if not path.exists():
+        has = sorted(p.stem for p in (HERE / "arch").glob("*.py"))
+        raise BenchFailure(f"no architecture {name!r}: benchmark/arch/ "
+                           f"has {has}")
+    return _load_by_path(f"bench_arch_{name}", path)
 
 
 def peaks_for(kind: str) -> dict:
@@ -129,17 +147,25 @@ def peaks_for(kind: str) -> dict:
 # checkpoint, once per checkout
 # ---------------------------------------------------------------------------
 
-def ensure_checkpoint(cfg: dict, tag: str, cache: Path) -> tuple[Path, float]:
-    """The configuration's seeded checkpoint: found, or written now.
-    Returns (directory, seconds spent writing)."""
+def checkpoint_key(cfg: dict, arch) -> str:
+    """What a checkpoint's directory is named by: the model's own sizes,
+    how the weights are made, and the architecture's writer."""
+    sizes = json.dumps(weights.hf_config(cfg, arch.HF_KEYS), sort_keys=True)
+    return hashlib.sha256(
+        f"{sizes}|{cfg['bench']['weights']}|{arch.WRITER_VERSION}"
+        .encode()).hexdigest()[:12]
+
+
+def ensure_checkpoint(cfg: dict, arch, tag: str,
+                      cache: Path) -> tuple[Path, float]:
+    """The configuration's seeded checkpoint: found, or written now by
+    its architecture's writer. Returns (directory, seconds spent
+    writing)."""
     w = cfg["bench"]["weights"]
-    sizes = json.dumps(weights.hf_config(cfg), sort_keys=True)
-    key = hashlib.sha256(f"{sizes}|{w}|{weights.WRITER_VERSION}"
-                         .encode()).hexdigest()[:12]
-    model_dir = cache / "ckpt" / f"{tag}-{key}"
+    model_dir = cache / "ckpt" / f"{tag}-{checkpoint_key(cfg, arch)}"
     if (model_dir / "DONE").exists():
         return model_dir, 0.0
-    need = weights.checkpoint_bytes(cfg, w["layout"])
+    need = arch.checkpoint_bytes(cfg, w["layout"])
     (cache / "ckpt").mkdir(parents=True, exist_ok=True)
     shutil.rmtree(model_dir, ignore_errors=True)
     if shutil.disk_usage(cache).free < 1.2 * need:
@@ -147,7 +173,7 @@ def ensure_checkpoint(cfg: dict, tag: str, cache: Path) -> tuple[Path, float]:
         for other in (cache / "ckpt").iterdir():
             shutil.rmtree(other, ignore_errors=True)
     t0 = time.perf_counter()
-    info = weights.write_checkpoint(cfg, w["layout"], w["seed"], model_dir)
+    info = arch.write_checkpoint(cfg, w["layout"], w["seed"], model_dir)
     (model_dir / "DONE").write_text(json.dumps(info))
     took = time.perf_counter() - t0
     say(phase="checkpoint_written", dir=str(model_dir.relative_to(ROOT)),
@@ -351,26 +377,25 @@ def probe(srv: Server, cfg: dict, vocab: int) -> list[dict]:
     return out
 
 
-def check_reference(probes: list[dict], cfg: dict, tag: str, model_dir: Path,
-                    cache: Path) -> tuple[bool, float]:
-    """Hold the tokens the server chose to the float32 reference,
-    teacher-forced on them: at every place the reference's own best
-    token may lie above the server's choice by at most
+def check_reference(probes: list[dict], cfg: dict, arch, tag: str,
+                    model_dir: Path, cache: Path) -> tuple[bool, float]:
+    """Hold the tokens the server chose to the architecture's float32
+    reference, teacher-forced on them: at every place the reference's own
+    best token may lie above the server's choice by at most
     ``bench.margin_tol`` nats (0 where they agree). The reference's
     answer is kept in the checkout, keyed by configuration, weights and
     ids: only a first run or a changed program computes it."""
-    import reference
-
     tol, worst = cfg["bench"]["margin_tol"], 0.0
     pairs = [[p["prompt"], p["ids"]] for p in probes]
-    key = hashlib.sha256(json.dumps(
-        [tag, model_dir.name, reference.VERSION, pairs]).encode()).hexdigest()
+    key = hashlib.sha256(json.dumps([
+        tag, model_dir.name, arch.REFERENCE_VERSION, pairs]).encode()
+    ).hexdigest()
     path = cache / "reference" / f"{key[:20]}.json"
     if path.exists():
         refs = json.loads(path.read_text())
     else:
         t0 = time.perf_counter()
-        refs = reference.chosen_logprobs(cfg, model_dir, pairs)
+        refs = arch.chosen_logprobs(cfg, model_dir, pairs)
         path.parent.mkdir(parents=True, exist_ok=True)
         path.write_text(json.dumps(refs))
         say(phase="reference_computed", tokens=[len(p) for p, _ in pairs],
@@ -527,11 +552,12 @@ def run_cell(a, cell: dict) -> dict:
         mix = overlay(mix, mix.get("rehearsal", {}))
     cache = CACHE / "rehearsal" if a.rehearse else CACHE
     tag = cell["cell"]["config"]
+    arch = load_arch(cfg["bench"].get("arch"))
     vocab = cfg["vocab_size"]
     run_dir = cache / "runs" / f"{cell['cell']['name']}-t{a.trace}"
     shutil.rmtree(run_dir, ignore_errors=True)
     run_dir.mkdir(parents=True)
-    model_dir, wrote_s = ensure_checkpoint(cfg, tag, cache)
+    model_dir, wrote_s = ensure_checkpoint(cfg, arch, tag, cache)
     schedule = traffic.Schedule(mix, a.seed, a.seconds, vocab,
                                 cfg["bench"]["slots"])
     srv = Server(cfg, model_dir, run_dir, chips, a.rehearse,
@@ -565,7 +591,8 @@ def run_cell(a, cell: dict) -> dict:
     finally:
         srv.kill()
         srv.drop_captures()
-    ref_ok, worst = check_reference(probes, cfg, tag, model_dir, cache)
+    ref_ok, worst = check_reference(probes, cfg, arch, tag, model_dir,
+                                    cache)
 
     records = m["records"]
     whole = all(len(r["ids"]) == r["asked"] for r in records
@@ -590,8 +617,8 @@ def run_cell(a, cell: dict) -> dict:
                             - m["before"]["prof"]["compiles"]),
         checkpoint_written_s=wrote_s, worst_margin=worst)
 
-    shared = dict(cfg=cfg, mix=mix, chips=chips, open_loop=schedule.open,
-                  loaded_s=loaded_s, setup_s=setup_s,
+    shared = dict(cfg=cfg, arch=arch, mix=mix, chips=chips,
+                  open_loop=schedule.open, loaded_s=loaded_s, setup_s=setup_s,
                   peaks=None if a.rehearse else peaks_for(dev["kind"]))
     ctx = dict(m, trace=reduced if a.trace == 1 else None, **shared)
     tail_ctx = dict(tail, trace=reduced, **shared) if tail else None

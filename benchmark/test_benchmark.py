@@ -10,8 +10,11 @@ at import time.
 
 from __future__ import annotations
 
+import hashlib
 import json
+import os
 import re
+import shutil
 import subprocess
 import sys
 from pathlib import Path
@@ -23,6 +26,7 @@ ROOT = HERE.parent
 sys.path.insert(0, str(HERE))
 
 import metrics  # noqa: E402
+import run  # noqa: E402
 import shapes  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic  # noqa: E402
@@ -30,12 +34,25 @@ import weights  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
+CONFIGS = [c["name"] for c in BENCH["configs"]]
+LAYOUTS = ("q8", "bf16")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
 def _timeline():
     return json.loads((HERE / "testdata" / "timeline.json").read_text())
+
+
+def _config(name: str, rehearsal: bool = False):
+    """(configuration file's dict, its architecture module), both found by
+    name as a run finds them; ``rehearsal``: at the tiny CPU sizes."""
+    conf = {c["name"]: c for c in BENCH["configs"]}[name]
+    cfg = json.loads((ROOT / conf["file"]).read_text())
+    arch = run.load_arch(cfg["bench"]["arch"])
+    if rehearsal:
+        cfg = run.overlay(cfg, cfg["bench"]["rehearsal"])
+    return cfg, arch
 
 
 # -- metric arithmetic on a hand-made timeline --------------------------------
@@ -202,85 +219,282 @@ def test_collective_time_is_the_collectives_self_time():
 
 # -- bytes, against the program's own parameter shapes ---------------------------
 
-@pytest.mark.parametrize("config", ["mistral7b-int8", "mixtral8x7b-cut"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_weight_bytes_match_the_programs_parameter_shapes(config):
     import jax
 
     from cake_tpu.models.config import LlamaConfig
     from cake_tpu.models.llama import init_params, init_params_int8
 
-    cfg = json.loads((HERE / "configs" / f"{config}.json").read_text())
+    cfg, arch = _config(config)
     layout = cfg["bench"]["weights"]["layout"]
-    lc = LlamaConfig.from_hf_dict(weights.hf_config(cfg), dtype="bfloat16")
+    lc = LlamaConfig.from_hf_dict(weights.hf_config(cfg, arch.HF_KEYS),
+                                  dtype="bfloat16")
     init = init_params_int8 if layout == "q8" else init_params
     tree = jax.eval_shape(lambda k: init(lc, k), jax.random.PRNGKey(0))
     held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
-    assert shapes.weight_bytes(cfg, layout) == pytest.approx(held, rel=0.002)
-    assert weights.checkpoint_bytes(cfg, layout) >= 0.99 * held
+    assert arch.weight_bytes(cfg, layout) == pytest.approx(held, rel=0.002)
+    assert arch.checkpoint_bytes(cfg, layout) >= 0.99 * held
 
 
 def test_decode_step_reads_routed_experts_and_live_cache_only():
-    cfg = json.loads((HERE / "configs" / "mixtral8x7b-cut.json").read_text())
+    cfg, arch = _config("mixtral8x7b-cut")
     assert shapes.expected_experts(8, 2, 1) == pytest.approx(2.0)
     assert 7.0 < shapes.expected_experts(8, 2, 8) < 7.3
-    one = shapes.decode_step_bytes(cfg, "q8", 1, 100)
-    full = shapes.decode_step_bytes(cfg, "q8", 8, 100)
+    one = arch.decode_step_bytes(cfg, "q8", 1, 100)
+    full = arch.decode_step_bytes(cfg, "q8", 8, 100)
     assert one < 0.4 * full
-    assert shapes.kv_bytes(cfg, 100, 8) == 8 * 100 * 7 * 2 * 8 * 128 * 2
+    assert arch.kv_bytes(cfg, 100, 8) == 8 * 100 * 7 * 2 * 8 * 128 * 2
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_a_decode_step_reads_no_more_than_the_device_holds(config):
+    cfg, arch = _config(config)
+    b = cfg["bench"]
+    layout, dtype, slots = b["weights"]["layout"], b["serve_dtype"], b["slots"]
+    held = arch.weight_bytes(cfg, layout, dtype)
+    in_step = arch.weight_bytes(cfg, layout, dtype, slots)
+    one = arch.decode_step_bytes(cfg, layout, 1, 100, dtype)
+    full = arch.decode_step_bytes(cfg, layout, slots, 100, dtype)
+    # more live streams and longer contexts read more, never less ...
+    assert 0 < one < full < arch.decode_step_bytes(
+        cfg, layout, slots, 200, dtype)
+    # ... the weights of a step are among those the device holds, and the
+    # rest of a step is the streams' cached state
+    assert in_step <= held and full > in_step
 
 
 # -- the checkpoint writer and the reference -------------------------------------
 
-def test_checkpoint_round_trip_and_reference(tmp_path):
+@pytest.mark.parametrize("config", CONFIGS)
+def test_checkpoint_round_trip_and_reference(config, tmp_path):
     import numpy as np
 
-    import reference
-
-    cfg = json.loads((HERE / "configs" / "mixtral8x7b-cut.json").read_text())
-    cfg = dict(cfg, **{k: v for k, v in cfg["bench"]["rehearsal"].items()
-                       if k != "bench"})
-    for layout in ("q8", "bf16"):
+    cfg, arch = _config(config, rehearsal=True)
+    linears = []
+    for layout in LAYOUTS:
         d = tmp_path / layout
-        info = weights.write_checkpoint(cfg, layout, 3, d, workers=2)
-        assert info["bytes"] == weights.checkpoint_bytes(cfg, layout)
+        info = arch.write_checkpoint(cfg, layout, 3, d, workers=2)
+        assert info["bytes"] == arch.checkpoint_bytes(cfg, layout)
         again = tmp_path / (layout + "2")
-        weights.write_checkpoint(cfg, layout, 3, again, workers=1)
+        arch.write_checkpoint(cfg, layout, 3, again, workers=1)
         for f in sorted(p.name for p in d.iterdir()):
             assert (d / f).read_bytes() == (again / f).read_bytes(), f
+        assert json.loads((d / "config.json").read_text()) == (
+            weights.hf_config(cfg, arch.HF_KEYS))
         ck = weights.Checkpoint(d)
-        w = ck.f32("model.layers.0.self_attn.q_proj.weight")
-        assert w.shape == (128, 128) and 0.05 < w.std() * 128 ** 0.5 < 2.0
-        out, two = reference.chosen_logprobs(
+        # the q8 layout says which tensors are linears; bf16 has the same
+        linears += [n[:-3] for n in ck.files if n.endswith(".q8")][:4]
+        assert linears
+        for name in linears:  # std ~ 1/sqrt(fan_in), whatever the layout
+            w = ck.f32(name)
+            assert w.ndim == 2 and 0.05 < w.std() * w.shape[1] ** 0.5 < 2.0
+        out, two = arch.chosen_logprobs(
             cfg, d, [([5, 9, 11, 40], [7, 8, 9]), ([9, 5], [3, 4])])
-        alone, = reference.chosen_logprobs(cfg, d, [([9, 5], [3, 4])])
+        alone, = arch.chosen_logprobs(cfg, d, [([9, 5], [3, 4])])
         assert two == alone  # sequences share weights, not positions
         assert len(out["logprob"]) == 3
         assert all(b >= l for b, l in zip(out["best_logprob"], out["logprob"]))
         assert np.isfinite(out["logprob"]).all()
 
 
-def test_correct_holds_the_servers_ids_to_the_references_margin(tmp_path):
-    import reference
-    import run
-
-    cfg = json.loads((HERE / "configs" / "mistral7b-int8.json").read_text())
-    cfg = run.overlay(cfg, cfg["bench"]["rehearsal"])
+@pytest.mark.parametrize("config", CONFIGS)
+def test_correct_holds_the_servers_ids_to_the_references_margin(config,
+                                                                tmp_path):
+    cfg, arch = _config(config, rehearsal=True)
     d = tmp_path / "ckpt"
-    weights.write_checkpoint(cfg, "q8", 3, d, workers=1)
+    arch.write_checkpoint(cfg, cfg["bench"]["weights"]["layout"], 3, d,
+                          workers=1)
     prompt = [5, 9, 11, 40, 7]
     # the reference's own greedy continuation, one token at a time
     ids = []
     for _ in range(3):
-        out, = reference.chosen_logprobs(cfg, d, [(prompt, ids + [0])])
+        out, = arch.chosen_logprobs(cfg, d, [(prompt, ids + [0])])
         ids.append(out["best"][-1])
+    check = lambda probes: run.check_reference(  # noqa: E731
+        probes, cfg, arch, "t", d, tmp_path / "c")
     good = [{"prompt": prompt, "ids": ids, "repeat_same": True}]
-    ok, worst = run.check_reference(good, cfg, "t", d, tmp_path / "c")
+    ok, worst = check(good)
     assert ok and worst == 0.0
     wrong = [{"prompt": prompt, "ids": ids[:2] + [(ids[2] + 1) % 500]}]
-    ok, worst = run.check_reference(wrong, cfg, "t", d, tmp_path / "c")
+    ok, worst = check(wrong)
     assert not ok and worst > cfg["bench"]["margin_tol"]
-    twice = [dict(good[0], repeat_same=False)]
-    assert not run.check_reference(twice, cfg, "t", d, tmp_path / "c")[0]
+    assert not check([dict(good[0], repeat_same=False)])[0]
+
+
+# -- the seam: an architecture is a module found by name --------------------------
+
+GOLDEN = json.loads((HERE / "testdata" / "arch_golden.json").read_text())
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+@pytest.mark.parametrize("config", sorted(GOLDEN["configs"]))
+def test_the_move_into_arch_changed_no_byte_and_no_float(config, layout,
+                                                         tmp_path):
+    """``testdata/arch_golden.json`` was recorded by the parent's code
+    (weights.py, reference.py, shapes.py of commit 2121709, before
+    ``arch/`` existed): the same checkpoints, the same answers, the same
+    counts, held exactly."""
+    want_all = GOLDEN["configs"][config]
+    want = want_all[layout]
+    cfg, arch = _config(config)
+    small = run.overlay(cfg, cfg["bench"]["rehearsal"])
+    info = arch.write_checkpoint(small, layout, GOLDEN["seed"], tmp_path,
+                                 workers=2)
+    assert info == want["written"]
+    assert {p.name: hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in tmp_path.iterdir()} == want["files"]
+    assert arch.chosen_logprobs(
+        small, tmp_path, GOLDEN["pairs"]) == want["chosen_logprobs"]
+    pub = want["published"]
+    assert arch.checkpoint_bytes(cfg, layout) == pub["checkpoint_bytes"]
+    assert arch.weight_bytes(cfg, layout, "bf16") == pub["weight_bytes_held"]
+    assert [arch.weight_bytes(cfg, layout, "bf16", r)
+            for r, _ in GOLDEN["points"]] == pub["weight_bytes"]
+    assert [arch.decode_step_bytes(cfg, layout, r, c, "bf16")
+            for r, c in GOLDEN["points"]] == pub["decode_step_bytes"]
+    # a run finds the checkpoint and the answers it wrote before the move
+    assert run.checkpoint_key(cfg, arch) == want_all["checkpoint_key"]
+    assert arch.REFERENCE_VERSION == want_all["reference_version"]
+
+
+def test_an_unknown_architecture_fails_with_the_list_of_those_there():
+    assert run.load_arch("gqa").HF_KEYS
+    with pytest.raises(run.BenchFailure, match=r"no architecture 'mla-x'.*"
+                       r"benchmark/arch/ has \[.*'gqa'.*\]"):
+        run.load_arch("mla-x")
+
+
+HARNESS = ["run.py", "counters.py", "serve_counters.py", "metrics.py",
+           "traffic.py", "client.py", "trace_reduce.py", "serve_child.py"]
+ARCH_API = ["write_checkpoint", "checkpoint_bytes", "chosen_logprobs",
+            "weight_bytes", "decode_step_bytes", "HF_KEYS", "WRITER_VERSION",
+            "REFERENCE_VERSION"]
+
+
+def test_the_harness_knows_no_architecture():
+    """run.py, what it shares with the readers, and every reader reach a
+    decoder's tensors and mathematics only through the module that
+    ``load_arch`` found by the configuration's ``bench.arch``."""
+    archs = [p.stem for p in (HERE / "arch").glob("*.py")]
+    keys = {k for a in archs for k in run.load_arch(a).HF_KEYS}
+    keys -= {"vocab_size", "eos_token_id"}
+    tensors = ["_proj", "layernorm", "embed_tokens", "lm_head", "self_attn",
+               "block_sparse_moe", "mlp.", "model.layers", "model.norm"]
+    files = [HERE / f for f in HARNESS] + sorted(
+        (HERE / "layer_metrics").glob("*.py"))
+    assert len(files) > len(HARNESS)
+    for path in files:
+        text = path.read_text()
+        assert not re.search(r"^\s*(import|from)\s+(reference|shapes|arch)\b",
+                             text, re.M), path.name
+        for word in archs:
+            assert not re.search(rf"arch[./]{re.escape(word)}\b|"
+                                 rf"[\"']{re.escape(word)}[\"']", text), (
+                path.name, word)
+        for word in sorted(keys) + tensors:
+            assert word not in text, (path.name, word)
+        # what an architecture defines is reached as an attribute of the
+        # loaded module and in no other way
+        for fn in ARCH_API:
+            for m in re.finditer(rf"\b{fn}\b", text):
+                assert text[:m.start()].endswith(("arch.", 'ctx["arch"].')), (
+                    path.name, fn)
+
+
+SEAM_ARCH = '''"""A decoder the harness has never seen: gqa's mathematics under
+keys of its own."""
+from arch import gqa
+
+HF_KEYS = gqa.HF_KEYS
+WRITER_VERSION = 7001
+REFERENCE_VERSION = 7002
+checkpoint_bytes = gqa.checkpoint_bytes
+weight_bytes = gqa.weight_bytes
+decode_step_bytes = gqa.decode_step_bytes
+
+
+def write_checkpoint(cfg, layout, seed, model_dir, workers=8):
+    info = gqa.write_checkpoint(cfg, layout, seed, model_dir, workers)
+    (model_dir / "WRITTEN_BY").write_text(__name__)
+    return info
+
+
+def chosen_logprobs(cfg, model_dir, pairs):
+    (model_dir / "JUDGED_BY").write_text(__name__)
+    return gqa.chosen_logprobs(cfg, model_dir, pairs)
+'''
+
+
+def _seam_tree(tmp: Path) -> str:
+    """A checkout under ``tmp`` that ADDS to the benchmark and edits none
+    of its files: the harness as it is, the program (a link), and three
+    new files: an architecture no file of the harness names, a
+    configuration of it, and a BENCHMARK.json with one cell of that."""
+    shutil.copytree(HERE, tmp / "benchmark", ignore=shutil.ignore_patterns(
+        "__pycache__", ".pytest_cache"))
+    (tmp / "cake_tpu").symlink_to(ROOT / "cake_tpu")
+    (tmp / "benchmark/arch/renamed-decoder.py").write_text(SEAM_ARCH)
+    base = BENCH["configs"][0]
+    cfg = json.loads((ROOT / base["file"]).read_text())
+    cfg["bench"]["arch"] = "renamed-decoder"
+    (tmp / "benchmark/configs/seam.json").write_text(json.dumps(cfg))
+    was = next(w for w in BENCH["workloads"] if w["config"] == base["name"])
+    cell = dict(was, name="seam.cell", config="seam")
+
+    def moved(metric: dict):
+        if "workloads" not in metric:
+            return metric
+        return dict(metric, workloads=["seam.cell"]) if was["name"] in metric[
+            "workloads"] else None
+
+    bench = dict(
+        BENCH, workloads=[cell],
+        configs=[dict(base, name="seam", file="benchmark/configs/seam.json")],
+        end_to_end=[m for m in map(moved, BENCH["end_to_end"]) if m],
+        per_layer=[m for m in map(moved, BENCH["per_layer"]) if m])
+    (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
+    return cell["name"]
+
+
+def _git_status() -> str | None:
+    if not (ROOT / ".git").exists():
+        return None  # the driver's checkout is no repository
+    return subprocess.run(["git", "status", "--porcelain"], cwd=ROOT,
+                          capture_output=True, text=True, check=True).stdout
+
+
+def test_a_new_architecture_comes_from_new_files_alone(tmp_path):
+    status = _git_status()
+    cell = _seam_tree(tmp_path)
+    out = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload", cell, "--rehearse",
+         "--seed", "3000000011", "--seconds", "2", "--trace", "2"],
+        capture_output=True, text=True, timeout=240, cwd=tmp_path,
+        env=dict(os.environ, PYTHONDONTWRITEBYTECODE="1"))
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = [json.loads(l) for l in out.stdout.strip().splitlines()]
+    last = lines[-1]
+    assert last["rehearsal"] is True and last["correct"] is True
+    assert last["failed"] == 0 and last["attempted"] > 0
+    assert {"tokens_per_s", "setup_s", "engine.gap_tail1_ms"} <= set(
+        last["would_report"])
+    # the harness went through the new module: its writer, its key, its
+    # reference, all inside the new checkout
+    wrote = next(l for l in lines if l.get("phase") == "checkpoint_written")
+    model_dir = tmp_path / wrote["dir"]
+    assert model_dir.parent == tmp_path / ".bench_cache/rehearsal/ckpt"
+    assert model_dir.name.startswith("seam-")
+    assert (model_dir / "WRITTEN_BY").read_text() == (
+        model_dir / "JUDGED_BY").read_text() == "bench_arch_renamed-decoder"
+    assert any(l.get("phase") == "reference_computed" for l in lines)
+    # ... and no file the benchmark had was edited, here or in the checkout
+    for path in HERE.rglob("*"):
+        if path.is_file() and not {"__pycache__", ".pytest_cache"} & set(
+                path.parts):
+            copy = tmp_path / "benchmark" / path.relative_to(HERE)
+            assert copy.read_bytes() == path.read_bytes(), path
+    assert _git_status() == status
 
 
 # -- every cell's files are found by name; names keep to the contract ------------

@@ -1,25 +1,24 @@
-"""Seeded random checkpoints at a configuration's sizes, streamed to disk.
+"""Seeded random checkpoints, streamed to disk: what every architecture's
+writer shares (``arch/<arch>.py`` names the tensors and their sizes).
 
 Numpy and the standard library only: the parent of a chip run calls this
 and may never import JAX. The files are plain safetensors (8-byte header
 length, JSON header, raw little-endian bytes), written by hand so that
-bf16 needs no extension type, in the names the program's loader reads:
+bf16 needs no extension type. Two layouts:
 
-- layout ``q8``: every linear as ``<hf_name>.q8`` (int8, torch ``[out,
-  in]``) + ``<hf_name>.scale`` (float32 ``[out]``), Mixtral's experts
-  under ``block_sparse_moe.experts.{e}.w1|w2|w3.weight``; the router, the
-  norms and the embedding in float32.
-- layout ``bf16``: every tensor in bfloat16, linears as ``[out, in]``.
+- ``q8``: every linear as ``<hf_name>.q8`` (int8, torch ``[out, in]``) +
+  ``<hf_name>.scale`` (float32 ``[out]``); unquantized tensors (a
+  router, the norms, the embedding) in float32.
+- ``bf16``: every tensor in bfloat16, linears as ``[out, in]``.
 
 Every value is a uniform int8 times a scale, so it costs one pass of a
 counter-based generator and no quantization, and every unquantized
-tensor (embedding, norms, router; all of the bf16 layout) is exact in
-bfloat16: the server's cast to its serving type loses nothing, and the
-float32 reference (benchmark/reference.py) reads the same numbers. A
-linear's scale differs per output channel, so a loader that mixed up
-channels would show in the reference comparison.
+tensor (all of the bf16 layout) is exact in bfloat16: the server's cast
+to its serving type loses nothing, and the float32 reference reads the
+same numbers. A linear's scale differs per output channel, so a loader
+that mixed up channels would show in the reference comparison.
 
-A sparse model's routing is made robust to rounding (``_routing_*``
+A sparse model's routing is made robust to rounding (``routing_*``
 below): with plain random weights the gap between the last expert chosen
 and the first left out is often under the error of a bfloat16 matmul, the
 server and the float32 reference then send some token to different
@@ -28,15 +27,15 @@ the sequence (measured on the chip, PR 23), which says nothing about
 either. So the first ``E`` channels of the residual stream belong to the
 router: the embedding writes ``ROUTE_MARK`` into ``top_k`` of them, chosen
 by the token's id, and zero into the others; no linear writes to them
-(those output rows of ``o_proj`` and of every expert's ``w2`` are zero);
+(``linear(..., zero_rows=E)`` for whatever writes the residual stream);
 the router's row ``e`` reads channel ``e`` alone. A token's experts are
 then a function of its id, uniform over the pairs, with a margin no
 rounding crosses; every other channel and all the arithmetic are as
 random as before, and the server does the same work.
 
-Tensor ``i`` of a file
-draws from ``SFC64([seed, file_index, i])``: the bytes depend only on
-(sizes, layout, seed), not on the number of threads.
+A writer draws tensor ``i`` of a file from ``SFC64([seed, file_index,
+i])``: the bytes depend only on (sizes, layout, seed), not on the number
+of threads.
 """
 
 from __future__ import annotations
@@ -49,37 +48,23 @@ from pathlib import Path
 
 import numpy as np
 
-WRITER_VERSION = 2
-
-# keys of a configuration file that are the model's own config.json (what
-# the server reads); everything else in the file is the benchmark's
-HF_KEYS = (
-    "architectures", "model_type", "vocab_size", "hidden_size",
-    "intermediate_size", "num_hidden_layers", "num_attention_heads",
-    "num_key_value_heads", "head_dim", "hidden_act",
-    "max_position_embeddings", "rms_norm_eps", "rope_theta",
-    "sliding_window", "tie_word_embeddings", "num_local_experts",
-    "num_experts_per_tok", "bos_token_id", "eos_token_id",
-    "attention_bias",
-)
-
 _ROWS = 2048  # output channels converted at a time (bf16 layout)
 ROUTE_MARK = 4.0  # what the embedding writes into a token's routing channels
 
 
-def hf_config(cfg: dict) -> dict:
+def hf_config(cfg: dict, keys) -> dict:
     """The config.json the server is given: the model's own keys of a
-    configuration file (see HF_KEYS)."""
-    return {k: cfg[k] for k in HF_KEYS if k in cfg and cfg[k] is not None}
+    configuration file, which its architecture lists (``HF_KEYS``)."""
+    return {k: cfg[k] for k in keys if k in cfg and cfg[k] is not None}
 
 
-def _pow2_scale(std: float) -> float:
+def pow2_scale(std: float) -> float:
     """The power of two that brings a uniform int8 (std 73.3) nearest to
     ``std``."""
     return 2.0 ** round(math.log2(std / 73.3))
 
 
-def _int8(rng: np.random.Generator, n: int) -> np.ndarray:
+def int8(rng: np.random.Generator, n: int) -> np.ndarray:
     """``n`` uniform int8 in [-127, 127] (-128 folded onto -127: the
     symmetric convention of the .q8 layout)."""
     words = rng.integers(0, 2**64, size=(n + 7) // 8, dtype=np.uint64)
@@ -88,13 +73,13 @@ def _int8(rng: np.random.Generator, n: int) -> np.ndarray:
     return q
 
 
-def _bf16_bits(x: np.ndarray) -> np.ndarray:
+def bf16_bits(x: np.ndarray) -> np.ndarray:
     """float32 values that are exact in bfloat16 -> their 16 bits."""
     return (np.ascontiguousarray(x, np.float32).view(np.uint32)
             >> 16).astype(np.uint16)
 
 
-class _File:
+class File:
     """One safetensors file, written tensor by tensor."""
 
     def __init__(self, path: Path):
@@ -120,7 +105,7 @@ class _File:
         return self.path.name, names, self.offset
 
 
-def _routing_channels(ids: np.ndarray, experts: int, top_k: int) -> np.ndarray:
+def routing_channels(ids: np.ndarray, experts: int, top_k: int) -> np.ndarray:
     """[len(ids), top_k] distinct channels (= experts) for each token id."""
     base = ids % experts
     step = 1 + (ids // experts) % (experts - 1)
@@ -130,24 +115,24 @@ def _routing_channels(ids: np.ndarray, experts: int, top_k: int) -> np.ndarray:
     return ch
 
 
-def _routing_embed(embed: np.ndarray, experts: int, top_k: int) -> None:
+def routing_embed(embed: np.ndarray, experts: int, top_k: int) -> None:
     """Give the first ``experts`` channels of the embedding to the router."""
     ids = np.arange(embed.shape[0])
     embed[:, :experts] = 0.0
-    for col in _routing_channels(ids, experts, top_k).T:
+    for col in routing_channels(ids, experts, top_k).T:
         embed[ids, col] = ROUTE_MARK
 
 
-def _linear(out_file: _File, rng, layout: str, name: str, fan_in: int,
+def linear(out_file: File, rng, layout: str, name: str, fan_in: int,
             out: int, zero_rows: int = 0) -> None:
     """One linear ``[out, in]`` with std ~ 1/sqrt(fan_in); its first
     ``zero_rows`` output channels write nothing (the router's channels)."""
-    q = _int8(rng, out * fan_in).reshape(out, fan_in)
+    q = int8(rng, out * fan_in).reshape(out, fan_in)
     q[:zero_rows] = 0
     # q8: a scale per output channel, 0.75 .. 1.25 of the base in
     # eighths. bf16: the power-of-two base alone, so that q * base is
     # exact in bfloat16
-    base = _pow2_scale(1.0 / math.sqrt(fan_in))
+    base = pow2_scale(1.0 / math.sqrt(fan_in))
     if layout == "q8":
         steps = rng.integers(6, 11, size=out).astype(np.float32)
         out_file.add(f"{name}.q8", "I8", (out, fan_in), q)
@@ -158,117 +143,49 @@ def _linear(out_file: _File, rng, layout: str, name: str, fan_in: int,
     for lo in range(0, out, _ROWS):
         w = q[lo:lo + _ROWS].astype(np.float32)
         w *= np.float32(base)
-        bits[lo:lo + _ROWS] = _bf16_bits(w)
+        bits[lo:lo + _ROWS] = bf16_bits(w)
     out_file.add(name, "BF16", (out, fan_in), bits)
 
 
-def _plain(out_file: _File, layout: str, name: str, values: np.ndarray):
+def plain(out_file: File, layout: str, name: str, values: np.ndarray):
     """A tensor kept unquantized: float32 in the q8 layout, bfloat16 in
     the bf16 layout. ``values`` are exact in bfloat16."""
     if layout == "q8":
         out_file.add(name, "F32", values.shape,
                      np.ascontiguousarray(values, np.float32))
     else:
-        out_file.add(name, "BF16", values.shape, _bf16_bits(values))
+        out_file.add(name, "BF16", values.shape, bf16_bits(values))
 
 
-def _norm(rng, h: int) -> np.ndarray:
+def norm(rng, h: int) -> np.ndarray:
     # 0.875 .. 1.25 in eighths: exact in bfloat16, not all ones, so that
     # a norm weight applied twice or not at all shows
     return rng.integers(7, 11, size=h).astype(np.float32) / np.float32(8)
 
 
-def _small(rng, shape, std: float) -> np.ndarray:
+def small(rng, shape, std: float) -> np.ndarray:
     n = int(np.prod(shape))
-    return (_int8(rng, n).astype(np.float32)
-            * np.float32(_pow2_scale(std))).reshape(shape)
+    return (int8(rng, n).astype(np.float32)
+            * np.float32(pow2_scale(std))).reshape(shape)
 
 
-def layer_linears(cfg: dict) -> dict[str, tuple[int, int]]:
-    """HF suffix -> (fan_in, out) of one layer's linears."""
-    h = cfg["hidden_size"]
-    d = cfg.get("head_dim") or h // cfg["num_attention_heads"]
-    q, kv = cfg["num_attention_heads"] * d, cfg["num_key_value_heads"] * d
-    f = cfg["intermediate_size"]
-    lin = {"self_attn.q_proj.weight": (h, q),
-           "self_attn.k_proj.weight": (h, kv),
-           "self_attn.v_proj.weight": (h, kv),
-           "self_attn.o_proj.weight": (q, h)}
-    experts = cfg.get("num_local_experts") or 0
-    if experts:
-        for e in range(experts):
-            p = f"block_sparse_moe.experts.{e}"
-            lin[f"{p}.w1.weight"] = (h, f)
-            lin[f"{p}.w3.weight"] = (h, f)
-            lin[f"{p}.w2.weight"] = (f, h)
-    else:
-        lin["mlp.gate_proj.weight"] = (h, f)
-        lin["mlp.up_proj.weight"] = (h, f)
-        lin["mlp.down_proj.weight"] = (f, h)
-    return lin
+def rngs(seed: int, file_index: int):
+    """The generators of one file's tensors, in the order they are drawn:
+    tensor ``i`` of file ``file_index`` has ``SFC64([seed, file_index,
+    i])``."""
+    i = 0
+    while True:
+        yield np.random.Generator(np.random.SFC64([seed, file_index, i]))
+        i += 1
 
 
-def checkpoint_bytes(cfg: dict, layout: str) -> int:
-    """Bytes the checkpoint will take on disk (to see that it fits)."""
-    per = 1 if layout == "q8" else 2
-    plain = 4 if layout == "q8" else 2
-    h, v = cfg["hidden_size"], cfg["vocab_size"]
-    layer = sum(i * o * per + (4 * o if layout == "q8" else 0)
-                for i, o in layer_linears(cfg).values())
-    layer += 2 * h * plain + (cfg.get("num_local_experts") or 0) * h * plain
-    head = v * h * per + (4 * v if layout == "q8" else 0)
-    return cfg["num_hidden_layers"] * layer + v * h * plain + h * plain + head
-
-
-def write_checkpoint(cfg: dict, layout: str, seed: int, model_dir: Path,
-                     workers: int = 8) -> dict:
-    """Write the checkpoint of configuration ``cfg`` (a configuration
-    file's dict) into ``model_dir``; returns {"bytes", "files"}."""
-    if layout not in ("q8", "bf16"):
-        raise ValueError(f"unknown checkpoint layout {layout!r}")
-    model_dir.mkdir(parents=True, exist_ok=True)
-    h, v = cfg["hidden_size"], cfg["vocab_size"]
-    layers = cfg["num_hidden_layers"]
-    experts = cfg.get("num_local_experts") or 0
-    linears = layer_linears(cfg)
-
-    def rngs(file_index: int):
-        i = 0
-        while True:
-            yield np.random.Generator(np.random.SFC64([seed, file_index, i]))
-            i += 1
-
-    def layer(i: int):
-        f = _File(model_dir / f"model-layer-{i:05d}.safetensors")
-        r = rngs(i)
-        p = f"model.layers.{i}."
-        _plain(f, layout, p + "input_layernorm.weight", _norm(next(r), h))
-        _plain(f, layout, p + "post_attention_layernorm.weight",
-               _norm(next(r), h))
-        if experts:  # row e reads routing channel e alone
-            _plain(f, layout, p + "block_sparse_moe.gate.weight",
-                   np.eye(experts, h, dtype=np.float32))
-        for suffix, (fan_in, out) in linears.items():
-            writes_residual = suffix.endswith(("o_proj.weight", "w2.weight"))
-            _linear(f, next(r), layout, p + suffix, fan_in, out,
-                    zero_rows=experts if writes_residual else 0)
-        return f.write()
-
-    def ends():
-        f = _File(model_dir / "model-ends.safetensors")
-        r = rngs(layers)
-        embed = _small(next(r), (v, h), 1.0 / math.sqrt(h))
-        if experts:
-            _routing_embed(embed, experts, cfg["num_experts_per_tok"])
-        _plain(f, layout, "model.embed_tokens.weight", embed)
-        _plain(f, layout, "model.norm.weight", _norm(next(r), h))
-        _linear(f, next(r), layout, "lm_head.weight", h, v)
-        return f.write()
-
+def write_files(model_dir: Path, layout: str, jobs, config: dict,
+                workers: int = 8) -> dict:
+    """Run ``jobs`` (each builds one ``File`` and returns its ``write()``)
+    on ``workers`` threads, then write the index over what they wrote and
+    ``config`` as the server's ``config.json``; returns {"bytes", "files"}."""
     with ThreadPoolExecutor(max_workers=workers) as pool:
-        jobs = [pool.submit(ends)] + [pool.submit(layer, i)
-                                      for i in range(layers)]
-        done = [j.result() for j in jobs]
+        done = [j.result() for j in [pool.submit(job) for job in jobs]]
     total = sum(n for _, _, n in done)
     meta = {"total_size": total}
     if layout == "q8":
@@ -277,12 +194,12 @@ def write_checkpoint(cfg: dict, layout: str, seed: int, model_dir: Path,
         "metadata": meta,
         "weight_map": {name: fname for fname, names, _ in done
                        for name in names}}))
-    (model_dir / "config.json").write_text(json.dumps(hf_config(cfg)))
+    (model_dir / "config.json").write_text(json.dumps(config))
     return {"bytes": total, "files": len(done)}
 
 
 class Checkpoint:
-    """Read access to a checkpoint written above (or any safetensors
+    """Read access to a checkpoint written so (or any safetensors
     directory with an index): memory-mapped, numpy only."""
 
     _DTYPES = {"I8": np.int8, "F32": np.float32, "BF16": np.uint16}
