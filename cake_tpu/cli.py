@@ -491,7 +491,8 @@ def _load_config(args):
     if config.sliding_window and getattr(args, "sp", 1) > 1:
         sys.exit("error: sliding-window attention (this checkpoint's "
                  "family) does not compose with --sp; run with --sp 1")
-    if getattr(args, "ep", 1) > 1 and not config.num_local_experts:
+    if getattr(args, "ep", 1) > 1 and not (config.num_local_experts
+                                           or config.n_routed_experts):
         sys.exit("error: --ep requires an MoE checkpoint "
                  "(num_local_experts > 0 in config.json)")
     return config

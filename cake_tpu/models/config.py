@@ -76,6 +76,37 @@ class LlamaConfig:
     # embedding output is multiplied by sqrt(hidden_size).
     rms_norm_offset: bool = False
     embed_scale: bool = False
+    # --- latent attention + shared/routed experts (DeepSeek-V3's keys; HF
+    # `model_type` "deepseek_v3" | "axk1") ---------------------------------
+    # Multi-head latent attention: kv_lora_rank > 0 selects it. The cache
+    # then holds one row of kv_lora_rank + qk_rope_head_dim values a token a
+    # layer, shared by every head (`cache_row`), and no per-head keys or
+    # values (ops/mla.py).
+    q_lora_rank: int | None = None
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The first `first_k_dense_replace` layers keep a dense SwiGLU of
+    # `intermediate_size`; every later layer routes over experts of
+    # `moe_intermediate_size` beside `n_shared_experts` shared ones.
+    first_k_dense_replace: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    # Experts HELD here (the expert stacks' leading axis). The router's
+    # width is `router_experts` (the published count; None = all are
+    # held), and the held ones are global experts `first_expert ..
+    # first_expert + n_routed_experts - 1`: a chip's share of an
+    # expert-parallel deployment, told by the configuration
+    # (config.json `expert_share`) and not by a mesh axis.
+    n_routed_experts: int = 0
+    router_experts: int | None = None
+    first_expert: int = 0
+    scoring_func: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -83,6 +114,28 @@ class LlamaConfig:
                 self, "head_dim",
                 self.hidden_size // self.num_attention_heads,
             )
+        if self.kv_lora_rank:
+            if not (self.q_lora_rank and self.qk_rope_head_dim
+                    and self.v_head_dim):
+                raise ValueError(
+                    "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+                    "qk_rope_head_dim and v_head_dim (a direct q_proj, "
+                    "q_lora_rank null, is not wired)")
+            if self.n_routed_experts and self.scoring_func != "sigmoid":
+                raise ValueError(
+                    f"scoring_func {self.scoring_func!r} is not wired for "
+                    "the shared-expert family (sigmoid, group-limited "
+                    "routing only)")
+            if self.n_routed_experts:
+                width = self.router_experts or self.n_routed_experts
+                object.__setattr__(self, "router_experts", width)
+                if width % self.n_group or not (
+                        0 <= self.first_expert
+                        <= width - self.n_routed_experts):
+                    raise ValueError(
+                        f"experts {self.first_expert}.."
+                        f"{self.first_expert + self.n_routed_experts - 1} "
+                        f"held of {width} in {self.n_group} groups")
         # validate at construction, not as a KeyError deep in a jit trace
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
@@ -105,6 +158,50 @@ class LlamaConfig:
     @property
     def jax_dtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def latent(self) -> bool:
+        """Multi-head latent attention (and the two-stack layer layout that
+        comes with it: leading dense layers, then expert layers)."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """Channels of a head that rotary embeddings cover."""
+        return self.qk_rope_head_dim if self.latent else self.head_dim
+
+    @property
+    def cache_row(self) -> tuple[int, int, int]:
+        """``(heads, k_width, v_width)`` of the cache's two buffers ``[L, B,
+        heads, S, width]``: THE place every allocation, spec and byte count
+        takes the cache's row from. Grouped-query attention keeps keys and
+        values per KV head; latent attention keeps the normed latent (in
+        ``k``) and the roped shared key part (in ``v``), once for all
+        heads."""
+        if self.latent:
+            return 1, self.kv_lora_rank, self.qk_rope_head_dim
+        return self.num_key_value_heads, self.head_dim, self.head_dim
+
+    @property
+    def cache_row_values(self) -> int:
+        """Values the cache holds for one token of one layer."""
+        heads, k_width, v_width = self.cache_row
+        return heads * (k_width + v_width)
+
+    @property
+    def attn_scale(self) -> float:
+        """Softmax scale of the attention scores: ``d^-0.5`` over the query
+        width, times YaRN's ``mscale^2`` where the rope scaling gives
+        ``mscale_all_dim`` (latent attention)."""
+        if not self.latent:
+            return self.head_dim ** -0.5
+        from cake_tpu.ops.rope import yarn_mscale
+
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        rs = self.rope_scaling or {}
+        if rs.get("mscale_all_dim"):
+            scale *= yarn_mscale(rs["factor"], rs["mscale_all_dim"]) ** 2
+        return scale
 
     def eos_ids(self) -> tuple[int, ...]:
         """Normalized EOS id set (reference checks config ids or "</s>",
@@ -168,6 +265,27 @@ class LlamaConfig:
                         f"(max_window_layers={mwl} of {layers}) is not "
                         "supported; all-or-none windowing only"
                     )
+        if d.get("model_type") in LATENT_MODEL_TYPES:
+            # DeepSeek-V3's keys. `topk_method` is read as the group-
+            # limited choice n_group/topk_group describe with no correction
+            # bias tensor ("none", "group_limited_greedy"); "noaux_tc"
+            # needs the bias and is refused rather than served without it.
+            if d.get("topk_method", "none") not in (
+                    "none", "greedy", "group_limited_greedy"):
+                raise ValueError(
+                    f"topk_method {d['topk_method']!r} (a routing "
+                    "correction bias) is not wired")
+            if d.get("moe_layer_freq", 1) != 1:
+                raise ValueError("moe_layer_freq != 1 is not wired")
+            share = d.get("expert_share")
+            if share:  # this chip's share of an ep deployment's experts
+                kwargs["router_experts"] = share["n_routed_experts"]
+                kwargs["first_expert"] = (share["rank"]
+                                          * kwargs["n_routed_experts"])
+        elif d.get("kv_lora_rank"):
+            raise ValueError(
+                f"model_type {d.get('model_type')!r} has latent-attention "
+                f"keys but is not one of {sorted(LATENT_MODEL_TYPES)}")
         kwargs.update(overrides)
         return cls(**kwargs)
 
@@ -186,7 +304,8 @@ class LlamaConfig:
             d.pop("sliding_window")
         if not d["num_local_experts"]:
             d.pop("num_local_experts")
-            d.pop("num_experts_per_tok")
+            if not self.n_routed_experts:
+                d.pop("num_experts_per_tok")
         if not d["attention_bias"]:
             d.pop("attention_bias")
         if d["hidden_act"] == "silu":
@@ -197,7 +316,26 @@ class LlamaConfig:
             d.pop("rms_norm_offset")
         if not d["embed_scale"]:
             d.pop("embed_scale")
+        width, first = d.pop("router_experts"), d.pop("first_expert")
+        if not self.latent:
+            for f in _LATENT_FIELDS:
+                d.pop(f)
+        elif width != self.n_routed_experts:
+            d["expert_share"] = {
+                "n_routed_experts": width,
+                "ep": width // self.n_routed_experts,
+                "rank": first // self.n_routed_experts}
         return d
+
+
+# HF `model_type`s served by the latent-attention, shared-expert decoder
+LATENT_MODEL_TYPES = ("deepseek_v3", "axk1")
+_LATENT_FIELDS = (
+    "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+    "v_head_dim", "first_k_dense_replace", "moe_intermediate_size",
+    "n_shared_experts", "n_routed_experts", "scoring_func", "n_group",
+    "topk_group", "norm_topk_prob", "routed_scaling_factor",
+)
 
 
 def llama3_8b(**overrides) -> LlamaConfig:
@@ -326,6 +464,51 @@ def gemma_7b(**overrides) -> LlamaConfig:
     return LlamaConfig(**base)
 
 
+def axk1_ep16(**overrides) -> LlamaConfig:
+    """A.X-K1 (https://huggingface.co/skt/A.X-K1, `model_type` "axk1",
+    DeepSeek-V3's keys) at its published widths, as ONE chip of 16 that
+    share each layer's 192 experts holds it: global experts 0-11 beside
+    the whole router, attention and shared expert. 61 layers as published;
+    a chip serves the depth of its pipeline stage (`num_hidden_layers=`)
+    and its slice of the vocabulary (`vocab_size=`)."""
+    base = dict(
+        model_type="axk1",
+        vocab_size=163840,
+        hidden_size=7168,
+        intermediate_size=18432,
+        num_hidden_layers=61,
+        num_attention_heads=64,
+        num_key_value_heads=64,
+        rms_norm_eps=1e-6,
+        rope_theta=10000.0,
+        rope_scaling={"type": "yarn", "factor": 32, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 4096},
+        max_seq_len=131072,
+        q_lora_rank=1536,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        first_k_dense_replace=1,
+        moe_intermediate_size=2048,
+        n_shared_experts=1,
+        n_routed_experts=12,
+        router_experts=192,
+        first_expert=0,
+        num_experts_per_tok=8,
+        scoring_func="sigmoid",
+        n_group=8,
+        topk_group=4,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        bos_token_id=0,
+        eos_token_id=1,
+    )
+    base.update(overrides)
+    return LlamaConfig(**base)
+
+
 def tiny(**overrides) -> LlamaConfig:
     """Tiny random-weight config for tests (SURVEY.md §4 test strategy)."""
     base = dict(
@@ -349,5 +532,39 @@ def tiny_moe(**overrides) -> LlamaConfig:
     """Tiny Mixtral-shaped fixture (4 experts, top-2)."""
     base = dict(model_type="mixtral", num_local_experts=4,
                 num_experts_per_tok=2)
+    base.update(overrides)
+    return tiny(**base)
+
+
+def tiny_mla_moe(**overrides) -> LlamaConfig:
+    """Tiny latent-attention, shared-expert fixture that keeps every ratio
+    of the published family (DeepSeek-V3's keys): rope/nope split of a
+    head, q and kv latents narrower than the heads they expand to, one
+    leading dense layer then expert layers, 16 sigmoid-scored experts in
+    4 groups of which 2 are kept, top-4 inside them, one shared expert,
+    YaRN over a short original window."""
+    base = dict(
+        model_type="deepseek_v3",
+        num_hidden_layers=3,
+        num_key_value_heads=4,
+        q_lora_rank=24,
+        kv_lora_rank=16,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        first_k_dense_replace=1,
+        moe_intermediate_size=32,
+        n_shared_experts=1,
+        n_routed_experts=16,
+        num_experts_per_tok=4,
+        scoring_func="sigmoid",
+        n_group=4,
+        topk_group=2,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        rope_scaling={"type": "yarn", "factor": 4.0, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1.0, "mscale_all_dim": 1.0,
+                      "original_max_position_embeddings": 32},
+    )
     base.update(overrides)
     return tiny(**base)

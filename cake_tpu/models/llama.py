@@ -33,10 +33,11 @@ from cake_tpu.models.config import LlamaConfig
 from cake_tpu.ops import quant
 from cake_tpu.ops.attention import self_attention_block
 from cake_tpu.ops.kvcache import KVCache
+from cake_tpu.ops.mla import latent_attention_block
 from cake_tpu.ops.mlp import swiglu
-from cake_tpu.ops.moe import moe_swiglu
+from cake_tpu.ops.moe import GroupRouting, moe_swiglu
 from cake_tpu.ops.norms import rms_norm
-from cake_tpu.ops.rope import rope_tables
+from cake_tpu.ops.rope import rope_tables_for
 
 Params = dict[str, Any]
 
@@ -72,11 +73,77 @@ _MOE_SHAPES = {
 }
 
 
+# Latent attention (ops/mla.py): the q and kv down-projections with their
+# inner norms, the up-projections, and the output projection.
+_LATENT_SHAPES = {
+    "attn_norm": lambda c: (c.hidden_size,),
+    "wq_a": lambda c: (c.hidden_size, c.q_lora_rank),
+    "q_norm": lambda c: (c.q_lora_rank,),
+    "wq_b": lambda c: (c.q_lora_rank, c.num_attention_heads
+                       * (c.qk_nope_head_dim + c.qk_rope_head_dim)),
+    "wkv_a": lambda c: (c.hidden_size, c.kv_lora_rank + c.qk_rope_head_dim),
+    "kv_norm": lambda c: (c.kv_lora_rank,),
+    "wkv_b": lambda c: (c.kv_lora_rank, c.num_attention_heads
+                        * (c.qk_nope_head_dim + c.v_head_dim)),
+    "wo": lambda c: (c.num_attention_heads * c.v_head_dim, c.hidden_size),
+    "mlp_norm": lambda c: (c.hidden_size,),
+}
+
+# An expert layer of the latent family: the router at its published width,
+# the HELD experts' stacks, and the shared experts as one SwiGLU.
+_SHARED_MOE_SHAPES = {
+    "router": lambda c: (c.hidden_size, c.router_experts),
+    "w_gate": lambda c: (c.n_routed_experts, c.hidden_size,
+                         c.moe_intermediate_size),
+    "w_up": lambda c: (c.n_routed_experts, c.hidden_size,
+                       c.moe_intermediate_size),
+    "w_down": lambda c: (c.n_routed_experts, c.moe_intermediate_size,
+                         c.hidden_size),
+    "ws_gate": lambda c: (c.hidden_size,
+                          c.n_shared_experts * c.moe_intermediate_size),
+    "ws_up": lambda c: (c.hidden_size,
+                        c.n_shared_experts * c.moe_intermediate_size),
+    "ws_down": lambda c: (c.n_shared_experts * c.moe_intermediate_size,
+                          c.hidden_size),
+}
+
+# The latent family's two stacks, in the order the layer loop runs them.
+STACKS = ("dense", "moe")
+
+
+def stack_layers(config: LlamaConfig) -> dict[str, int]:
+    """Layers in each stack of a latent-family model: the leading dense
+    ones, then the expert ones."""
+    dense = (config.first_k_dense_replace if config.n_routed_experts
+             else config.num_hidden_layers)
+    return {"dense": dense, "moe": config.num_hidden_layers - dense}
+
+
+def stack_shapes(config: LlamaConfig) -> dict[str, dict]:
+    """Stack name -> per-layer weight name -> shape builder for the latent
+    family (``params["layers"]`` is then ``{"dense": {...}, "moe": {...}}``,
+    each stacked over its own layers; an empty stack is left out)."""
+    dense = dict(_LATENT_SHAPES)
+    dense.update({k: _LAYER_SHAPES[k] for k in ("w_gate", "w_up", "w_down")})
+    moe = dict(_LATENT_SHAPES)
+    moe.update(_SHARED_MOE_SHAPES)
+    if not config.n_shared_experts:
+        for k in ("ws_gate", "ws_up", "ws_down"):
+            del moe[k]
+    count = stack_layers(config)
+    return {name: shapes for name, shapes in (("dense", dense), ("moe", moe))
+            if count[name]}
+
+
 def layer_shapes(config: LlamaConfig) -> dict:
     """Per-layer weight name -> shape (without the leading ``[L]`` axis) for
     the given model family: the Llama base, plus q/k/v biases when
     ``attention_bias`` (Qwen2), with the dense MLP replaced by router +
-    stacked expert weights when ``num_local_experts > 0`` (Mixtral)."""
+    stacked expert weights when ``num_local_experts > 0`` (Mixtral). The
+    latent family has two kinds of layer: :func:`stack_shapes`."""
+    if config.latent:
+        raise ValueError("a latent-attention model has two layer stacks: "
+                         "use stack_shapes(config)")
     shapes = dict(_LAYER_SHAPES)
     if config.attention_bias:
         shapes.update(_BIAS_SHAPES)
@@ -89,28 +156,42 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
     """Random-init params pytree (test fixtures / benchmarks; real weights
     come from :mod:`cake_tpu.utils.weights`)."""
     dt = dtype or config.jax_dtype
-    L = config.num_hidden_layers
-    shapes = layer_shapes(config)
-    keys = iter(jax.random.split(key, len(shapes) + 3))
 
     def dense(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32) / jnp.sqrt(fan_in)).astype(dt)
 
-    layers = {}
-    for name, shape_fn in shapes.items():
-        shape = shape_fn(config)
-        k = next(keys)
-        if name.endswith("norm"):
-            layers[name] = jnp.ones((L,) + shape, dt)
-        elif name.startswith("b"):
-            # biases: small random so tests exercise a nonzero bias path
-            layers[name] = (0.02 * jax.random.normal(k, (L,) + shape,
-                                                     jnp.float32)).astype(dt)
-        else:
-            # fan_in is the next-to-last axis for 3D expert stacks
-            # ([E, in, out]) and the first axis for plain [in, out] linears
-            fan_in = shape[-2] if len(shape) == 3 else shape[0]
-            layers[name] = dense(k, (L,) + shape, fan_in)
+    def stack(shapes, L, keys):
+        layers = {}
+        for name, shape_fn in shapes.items():
+            shape = shape_fn(config)
+            k = next(keys)
+            if name.endswith("norm"):
+                layers[name] = jnp.ones((L,) + shape, dt)
+            elif name.startswith("b"):
+                # biases: small random so tests exercise a nonzero bias path
+                layers[name] = (0.02 * jax.random.normal(
+                    k, (L,) + shape, jnp.float32)).astype(dt)
+            else:
+                # fan_in is the next-to-last axis for 3D expert stacks
+                # ([E, in, out]) and the first axis for plain [in, out]
+                # linears
+                fan_in = shape[-2] if len(shape) == 3 else shape[0]
+                layers[name] = dense(k, (L,) + shape, fan_in)
+        return layers
+
+    if config.latent:
+        k_dense, k_moe, key = jax.random.split(key, 3)
+        count = stack_layers(config)
+        layers = {
+            name: stack(shapes, count[name],
+                        iter(jax.random.split(k, len(shapes))))
+            for (name, shapes), k in zip(stack_shapes(config).items(),
+                                         (k_dense, k_moe))}
+        keys = iter(jax.random.split(key, 3))
+    else:
+        shapes = layer_shapes(config)
+        keys = iter(jax.random.split(key, len(shapes) + 3))
+        layers = stack(shapes, config.num_hidden_layers, keys)
     return {
         "embed": dense(next(keys), (config.vocab_size, config.hidden_size),
                        config.hidden_size),
@@ -144,6 +225,11 @@ def init_params_int4(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
 def _init_params_quantized(config, key, dtype, *, bits: int) -> Params:
     from functools import partial as _partial
 
+    if config.latent:
+        raise NotImplementedError(
+            "random-init quantized params cover the per-head-attention "
+            "families; quantize a latent-family pytree with "
+            "ops.quant.quantize_params")
     if config.num_local_experts and bits == 4:
         from cake_tpu.ops.quant import reject_int4_moe
 
@@ -249,8 +335,12 @@ def block_forward(
     ep_axis: str | None = None,
     ep_size: int | None = None,
     layer_idx: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One pre-norm decoder block (transformer.rs:48-64).
+    count_local: bool = False,
+):
+    """One pre-norm decoder block (transformer.rs:48-64). Returns ``(x,
+    k_cache, v_cache)``; with ``count_local`` (an expert layer of the
+    latent family) a fourth value, each row's routed pairs that fell on
+    the experts held here (:func:`cake_tpu.ops.moe.moe_swiglu`).
 
     ``layer_idx``: ``k_cache``/``v_cache`` are the stacked ``[L, B,
     kv_heads, S, D]`` cache and this block is layer ``layer_idx`` of it
@@ -269,10 +359,16 @@ def block_forward(
     Model-family deltas dispatch on the layer pytree itself: q/k/v bias
     arrays (``bq``/``bk``/``bv``, Qwen2) and a ``router`` + expert-stacked
     MLP (Mixtral) are used iff present; ``config.sliding_window`` (Mistral)
-    narrows the causal mask.
+    narrows the causal mask. The latent family (``wq_a`` present) attends
+    through :func:`cake_tpu.ops.mla.latent_attention_block` and its expert
+    layers add shared experts (``ws_*``) to the routed part.
     """
     h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps,
                    offset=config.rms_norm_offset)
+    if "wq_a" in layer:
+        return _latent_block(layer, x, h, k_cache, v_cache, cos, sin, pos,
+                             config, write_gate, ep_axis, ep_size,
+                             layer_idx, count_local)
     attn_out, k_cache, v_cache = self_attention_block(
         h, layer["wq"], layer["wk"], layer["wv"], layer["wo"],
         k_cache, v_cache, cos, sin, pos,
@@ -306,6 +402,44 @@ def block_forward(
     return x, k_cache, v_cache
 
 
+def _latent_block(layer, x, h, c_cache, r_cache, cos, sin, pos, config,
+                  write_gate, ep_axis, ep_size, layer_idx, count_local):
+    """The rest of :func:`block_forward` for a latent-family layer: ``h``
+    is the normed input. A dense layer (no ``router``) is a SwiGLU of
+    ``intermediate_size``; an expert layer is ``shared(h) + sum over the
+    chosen experts HELD here of w_e expert_e(h)``."""
+    with jax.named_scope("mla"):
+        attn_out, c_cache, r_cache = latent_attention_block(
+            h, layer, c_cache, r_cache, cos, sin, pos, config,
+            write_gate=write_gate, layer_idx=layer_idx)
+    x = x + attn_out
+    h = rms_norm(x, layer["mlp_norm"], config.rms_norm_eps)
+    local = jnp.zeros((x.shape[0],), jnp.int32)
+    if "router" in layer:
+        y = moe_swiglu(
+            h, layer["router"], layer["w_gate"], layer["w_up"],
+            layer["w_down"], top_k=config.num_experts_per_tok,
+            ep_axis=ep_axis, ep_size=ep_size,
+            routing=GroupRouting(config.n_group, config.topk_group,
+                                 config.norm_topk_prob,
+                                 config.routed_scaling_factor),
+            held=(config.first_expert, config.n_routed_experts),
+            count_local=count_local,
+        )
+        if count_local:
+            y, local = y
+        if "ws_gate" in layer:  # every rank alike, so added after the psum
+            with jax.named_scope("moe.shared"):
+                y = y + swiglu(h, layer["ws_gate"], layer["ws_up"],
+                               layer["ws_down"])
+        x = x + y
+    else:
+        x = x + swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"])
+    if count_local:
+        return x, c_cache, r_cache, local
+    return x, c_cache, r_cache
+
+
 def forward_layers(
     layers: Params,  # stacked [L', ...] weights (any contiguous block range)
     x: jax.Array,  # [B, T, hidden]
@@ -324,8 +458,12 @@ def forward_layers(
     sp_chunk: bool = False,
     ep_axis: str | None = None,
     ep_size: int | None = None,
-) -> tuple[jax.Array, KVCache]:
-    """Run a contiguous run of decoder blocks via ``lax.scan``.
+    count_local: bool = False,
+):
+    """Run a contiguous run of decoder blocks via ``lax.scan``. Returns
+    ``(x, cache)``; with ``count_local`` (latent family) ``(x, cache,
+    local_pairs)``, each batch row's routed pairs that fell on held
+    experts, summed over the expert layers (int32 ``[B]``).
 
     This is the TPU-native `Forwarder::forward_batch` (cake/mod.rs:143-150,
     worker.rs:208-219): one call executes any number of contiguous layers with
@@ -342,23 +480,41 @@ def forward_layers(
     per-layer output stack to allocate, fill and copy back, which is what
     scanning the cache as ``xs``/``ys`` cost (two cache-sized copies, a slab
     write and a slab read per layer, in every decode step).
+
+    The latent family's ``layers`` are two stacks (``{"dense": ..., "moe":
+    ...}``, :func:`stack_shapes`): the leading dense layers are scanned,
+    then the expert layers, over the ONE carried cache, the second scan's
+    layer indices going on from where the first stopped.
     """
     def body(carry, per_layer):
-        h, kc, vc = carry
+        h, kc, vc, *local = carry
         layer, i = per_layer
-        h, kc, vc = block_forward(layer, h, kc, vc, cos, sin, pos, config,
-                                  num_heads=num_heads, num_kv_heads=num_kv_heads,
-                                  tp_axis=tp_axis, sp_axis=sp_axis,
-                                  sp_size=sp_size, write_gate=write_gate,
-                                  sp_prefill=sp_prefill, sp_chunk=sp_chunk,
-                                  ep_axis=ep_axis, ep_size=ep_size,
-                                  layer_idx=i)
-        return (h, kc, vc), None
+        out = block_forward(layer, h, kc, vc, cos, sin, pos, config,
+                            num_heads=num_heads, num_kv_heads=num_kv_heads,
+                            tp_axis=tp_axis, sp_axis=sp_axis,
+                            sp_size=sp_size, write_gate=write_gate,
+                            sp_prefill=sp_prefill, sp_chunk=sp_chunk,
+                            ep_axis=ep_axis, ep_size=ep_size,
+                            layer_idx=i, count_local=count_local)
+        if count_local:
+            return (*out[:3], local[0] + out[3]), None
+        return out, None
 
-    (x, k_new, v_new), _ = jax.lax.scan(
-        body, (x, cache.k, cache.v),
-        (layers, jnp.arange(cache.num_layers, dtype=jnp.int32)))
-    return x, KVCache(k=k_new, v=v_new)
+    stacks = ([layers[name] for name in STACKS if name in layers]
+              if config.latent else [layers])
+    carry = (x, cache.k, cache.v)
+    if count_local:
+        carry += (jnp.zeros((x.shape[0],), jnp.int32),)
+    first = 0
+    for stack in stacks:
+        n = jax.tree.leaves(stack)[0].shape[0]
+        carry, _ = jax.lax.scan(
+            body, carry,
+            (stack, jnp.arange(first, first + n, dtype=jnp.int32)))
+        first += n
+    if count_local:
+        return carry[0], KVCache(k=carry[1], v=carry[2]), carry[3]
+    return carry[0], KVCache(k=carry[1], v=carry[2])
 
 
 def forward(
@@ -373,8 +529,7 @@ def forward(
     Returns ``(logits [B, vocab] f32, new_cache)`` — logits taken at the last
     position and upcast to f32 exactly as the reference (llama.rs:124-143).
     """
-    cos, sin = rope_tables(config.head_dim, cache.max_seq, config.rope_theta,
-                           scaling=config.rope_scaling)
+    cos, sin = rope_tables_for(config, cache.max_seq)
     x = embed_tokens(params, tokens, config)
     x, cache = forward_layers(params["layers"], x, cache, cos, sin, pos, config)
     x = rms_norm(x, params["norm_f"], config.rms_norm_eps,
@@ -394,6 +549,5 @@ def hidden_forward_layers(
 ) -> tuple[jax.Array, KVCache]:
     """Convenience wrapper that builds RoPE tables internally — the entry
     point a worker jits for its assigned block range (worker.rs:203-224)."""
-    cos, sin = rope_tables(config.head_dim, cache.max_seq, config.rope_theta,
-                           scaling=config.rope_scaling)
+    cos, sin = rope_tables_for(config, cache.max_seq)
     return forward_layers(layers, x, cache, cos, sin, pos, config)

@@ -37,6 +37,24 @@ HISTOGRAM = "histogram"
 # name -> (kind, meaning). Grouped by owning subsystem; keep each group
 # sorted so diffs stay reviewable.
 SERIES: dict[str, tuple[str, str]] = {
+    # -- the cache and the expert layers (runtime/batch_generator) ------
+    "cache.bytes": (
+        GAUGE, "bytes of the serving cache as allocated (slots x window x "
+               "layers x cache.row_bytes)"),
+    "cache.row_bytes": (
+        GAUGE, "bytes the cache holds for one token of one layer, from the "
+               "buffers allocated (cache.bytes / layers x slots x window): "
+               "per-head keys and values (with an int8 cache's scales), or "
+               "latent attention's one shared row"),
+    "moe.decode_steps": (
+        COUNTER, "decode steps whose routed pairs were counted"),
+    "moe.local_pairs": (
+        COUNTER, "(row, chosen expert) pairs of decode steps that fell on "
+                 "experts held here: counted on the device a batch row, "
+                 "added up over the rows live at dispatch"),
+    "moe.routed_pairs": (
+        COUNTER, "(row, chosen expert) pairs decode steps routed over all "
+                 "the router's experts: live rows x top-k x expert layers"),
     # -- constrained decoding (cake_tpu/constrain) -----------------------
     "constrain.dead_ends": (
         COUNTER, "constrained streams retired at a grammar dead end"),

@@ -76,7 +76,10 @@ def quant_kv(x: jax.Array) -> QuantizedKV:
 class KVCache:
     """Preallocated per-layer key/value buffers.
 
-    Shapes: ``k, v: [num_layers, batch, num_kv_heads, max_seq, head_dim]``.
+    Shapes: ``k, v: [num_layers, batch, num_kv_heads, max_seq, head_dim]``;
+    for latent attention ``k: [L, B, 1, S, kv_lora_rank]`` (the normed
+    latent) and ``v: [L, B, 1, S, qk_rope_head_dim]`` (the roped key part
+    every head shares), and nothing per head (``LlamaConfig.cache_row``).
     The leading layer axis is indexed by the layer loop, which carries the
     whole cache beside the scanned layer weights, and is shardable along a
     pipeline-stage mesh axis.
@@ -130,14 +133,24 @@ def init_cache(
     L = config.num_hidden_layers if num_layers is None else num_layers
     S = max_seq or config.max_seq_len
     dt = dtype or config.jax_dtype
-    shape = (L, batch, config.num_key_value_heads, S, config.head_dim)
+    # the row comes from the configuration alone (LlamaConfig.cache_row):
+    # per-head keys and values, or latent attention's one shared row
+    # (normed latent in ``k``, roped key part in ``v``)
+    heads, k_width, v_width = config.cache_row
     if quant == "int8":
-        def half():
+        if config.latent:
+            raise ValueError(
+                "an int8 cache is not wired for latent attention (the "
+                "latent row is already 1/35 of per-head keys and values)")
+
+        def half(width):
+            shape = (L, batch, heads, S, width)
             return QuantizedKV(q=jnp.zeros(shape, jnp.int8),
                                scale=jnp.zeros(shape[:-1], jnp.float32))
 
-        return KVCache(k=half(), v=half())
-    return KVCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt))
+        return KVCache(k=half(k_width), v=half(v_width))
+    return KVCache(k=jnp.zeros((L, batch, heads, S, k_width), dt),
+                   v=jnp.zeros((L, batch, heads, S, v_width), dt))
 
 
 def layer_view(cache, layer):

@@ -1,0 +1,160 @@
+"""Multi-head latent attention (DeepSeek-V2/V3's MLA) over a latent cache.
+
+The reference serves one attention (`cake-core/src/model/attention.rs`,
+per-head keys and values); this is the family whose cache is one row a
+token a layer, shared by every head. Per token ``x``:
+
+    c_q            = rmsnorm(x W_qa)                      [q_lora_rank]
+    [q_nope|q_pe]_h = c_q W_qb                            heads x (nope + rope)
+    [c | k_pe]     = x W_kva                              kv_lora_rank + rope
+    c              = rmsnorm(c);  q_pe, k_pe = rope(.)    interleaved pairs
+    [k_nope|v]_h   = c W_kvb                              heads x (nope + v)
+    score_h        = (q_nope_h . k_nope_h + q_pe_h . k_pe) * scale
+    out            = concat_h(softmax(score_h) v_h) W_o
+
+``scale`` is ``(nope + rope)^-0.5`` times YaRN's ``mscale^2``
+(``LlamaConfig.attn_scale``). **Cached: ``[c | k_pe]``** (after the norm,
+after rope): ``kv_lora_rank + qk_rope_head_dim`` values a token a layer in
+the two buffers of :class:`cake_tpu.ops.kvcache.KVCache` (``k`` holds ``c``,
+``v`` holds ``k_pe``, one "head"), written in place on the carried stacked
+cache exactly as per-head rows are (:func:`kvcache.update_layer`).
+
+Two forms of the same mathematics, chosen at trace time:
+
+- **absorbed** (against cached rows): the up-projection moves into the
+  query and the output, ``q'_h = q_nope_h W_kvb,k,h^T`` scores against ``c``
+  itself and ``o_h = (softmax . c) W_kvb,v,h``, so no key or value is ever
+  expanded per head for a cached token. A decode step (``T == 1``) is this
+  form over the whole buffer.
+- **expanded** (a chunk's own tokens, ``T > 1``): the chunk's ``k_nope``
+  and ``v`` are expanded from its own ``c`` and attended causally,
+  ``T x T``. What the chunk has behind it in the cache (positions below
+  ``pos``: a prefix hit, an earlier chunk) is attended in the absorbed
+  form and the two partial softmaxes are merged exactly; that part is
+  skipped at run time when there is no history (``lax.cond``), which is
+  every first chunk.
+
+Weights go through :func:`cake_tpu.ops.quant.dense` wherever they are used
+as a plain projection; the absorbed form contracts ``W_kvb`` over its
+other axis, so an int8 ``W_kvb`` is dequantized at trace level there (the
+convert and multiply fuse into the einsum's operand read).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from cake_tpu.ops import kvcache as kv
+from cake_tpu.ops import quant
+from cake_tpu.ops.attention import NEG_INF
+from cake_tpu.ops.norms import rms_norm
+from cake_tpu.ops.rope import apply_rope
+
+
+def _plain(w, dtype):
+    if isinstance(w, quant.QuantizedLinear):
+        return quant.dequantize_linear(w, dtype)
+    return w
+
+
+def latent_attention_block(
+    x: jax.Array,  # [B, T, hidden]
+    layer: dict,  # wq_a, q_norm, wq_b, wkv_a, kv_norm, wkv_b, wo
+    c_cache: jax.Array,  # [(L,) B, 1, S, kv_lora_rank]
+    r_cache: jax.Array,  # [(L,) B, 1, S, qk_rope_head_dim]
+    cos: jax.Array,
+    sin: jax.Array,
+    pos,  # scalar or [B]
+    config,
+    write_gate: jax.Array | None = None,
+    layer_idx: jax.Array | None = None,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One latent-attention sublayer incl. the cache write. Returns
+    ``(attn_out [B, T, hidden], c_cache, r_cache)``; the buffers come back
+    whole with this layer's ``T`` new rows written."""
+    b, t, _ = x.shape
+    nh = config.num_attention_heads
+    dn, dr = config.qk_nope_head_dim, config.qk_rope_head_dim
+    dv, dc = config.v_head_dim, config.kv_lora_rank
+    eps, scale = config.rms_norm_eps, config.attn_scale
+
+    c_q = rms_norm(quant.dense(x, layer["wq_a"]), layer["q_norm"], eps)
+    q = quant.dense(c_q, layer["wq_b"]).reshape(b, t, nh, dn + dr)
+    q = q.transpose(0, 2, 1, 3)  # [B, H, T, nope + rope]
+    q_nope, q_pe = q[..., :dn], q[..., dn:]
+    ckv = quant.dense(x, layer["wkv_a"])  # [B, T, dc + dr]
+    c = rms_norm(ckv[..., :dc], layer["kv_norm"], eps)[:, None]  # [B,1,T,dc]
+    q_pe = apply_rope(q_pe, cos, sin, pos, interleaved=True)
+    k_pe = apply_rope(ckv[..., dc:][:, None], cos, sin, pos,
+                      interleaved=True)  # [B, 1, T, dr], one for all heads
+
+    c_cache, r_cache = kv.update_layer(c_cache, r_cache, c, k_pe, pos,
+                                       gate=write_gate, layer=layer_idx)
+    c_all = kv.layer_view(c_cache, layer_idx)[:, 0]  # [B, S, dc]
+    r_all = kv.layer_view(r_cache, layer_idx)[:, 0]  # [B, S, dr]
+    s = c_all.shape[1]
+
+    w_kvb = _plain(layer["wkv_b"], x.dtype).reshape(dc, nh, dn + dv)
+    w_k, w_v = w_kvb[..., :dn], w_kvb[..., dn:]
+    pos = jnp.asarray(pos, jnp.int32)
+    pos_b = pos[:, None, None, None] if pos.ndim else pos  # over [B,H,T,S]
+
+    def cached(valid):
+        """Absorbed attention against the cached rows ``valid`` admits
+        (``[B|1, 1, T, S]``): row maximum, normalizer and the un-normalized
+        output ``[B, H, T, dv]``, all float32."""
+        q_c = jnp.einsum("bhtn,chn->bhtc", q_nope, w_k)
+        sc = (jnp.einsum("bhtc,bsc->bhts", q_c, c_all,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhtr,bsr->bhts", q_pe, r_all,
+                           preferred_element_type=jnp.float32)) * scale
+        sc = jnp.where(valid, sc, NEG_INF)
+        m = jnp.max(sc, axis=-1, keepdims=True)
+        p = jnp.exp(sc - m)
+        o_c = jnp.einsum("bhts,bsc->bhtc", p.astype(c_all.dtype), c_all,
+                         preferred_element_type=jnp.float32)
+        o = jnp.einsum("bhtc,chv->bhtv", o_c.astype(x.dtype), w_v,
+                       preferred_element_type=jnp.float32)
+        return m, jnp.sum(p, axis=-1, keepdims=True), o
+
+    kpos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, t, s), 3)
+    if t == 1:
+        m, l, o = cached(kpos <= pos_b)
+        out = o / l
+    else:
+        # the chunk's own tokens, expanded and causal among themselves
+        kv_own = quant.dense(c[:, 0], layer["wkv_b"]).reshape(
+            b, t, nh, dn + dv).transpose(0, 2, 1, 3)
+        k_nope, v_own = kv_own[..., :dn], kv_own[..., dn:]
+        sc = (jnp.einsum("bhtn,bhun->bhtu", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhtr,bur->bhtu", q_pe, k_pe[:, 0],
+                           preferred_element_type=jnp.float32)) * scale
+        qi = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+        ki = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+        sc = jnp.where(ki <= qi, sc, NEG_INF)
+        m_o = jnp.max(sc, axis=-1, keepdims=True)
+        p = jnp.exp(sc - m_o)
+        l_o = jnp.sum(p, axis=-1, keepdims=True)
+        o_o = jnp.einsum("bhtu,bhuv->bhtv", p.astype(x.dtype), v_own,
+                         preferred_element_type=jnp.float32)
+
+        # what lies behind the chunk in the cache, absorbed; nothing does
+        # on a first chunk, and then this sweep of the buffer is not run
+        def history(_):
+            return cached(kpos < pos_b)
+
+        def no_history(_):
+            return (jnp.full((b, nh, t, 1), NEG_INF, jnp.float32),
+                    jnp.zeros((b, nh, t, 1), jnp.float32),
+                    jnp.zeros((b, nh, t, dv), jnp.float32))
+
+        m_h, l_h, o_h = jax.lax.cond(jnp.any(pos > 0), history, no_history,
+                                     None)
+        m = jnp.maximum(m_o, m_h)
+        a_o, a_h = jnp.exp(m_o - m), jnp.exp(m_h - m)
+        out = (o_o * a_o + o_h * a_h) / (l_o * a_o + l_h * a_h)
+
+    out = out.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, t, nh * dv)
+    return quant.dense(out, layer["wo"]), c_cache, r_cache

@@ -35,6 +35,8 @@ TPU-first design:
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -44,6 +46,21 @@ from cake_tpu.ops.quant import QuantizedLinear, dequantize_linear
 # rows, so it only pays off while N*k is well under E (single-digit serving
 # batches at decode). Above it the dense path's E-batched einsum wins.
 GATHER_MAX_ROWS = 8
+
+
+class GroupRouting(NamedTuple):
+    """DeepSeek-V3's routing (config keys ``scoring_func: "sigmoid"``,
+    ``n_group``, ``topk_group``, ``norm_topk_prob``,
+    ``routed_scaling_factor``), read with no correction bias: sigmoid
+    scores over all experts; a group's score is the sum of its 2 highest;
+    the ``topk_group`` best groups stay; top-k of the scores inside them;
+    weights are those scores, normalised over the chosen (``+ 1e-20``)
+    and scaled."""
+
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk: bool = True
+    scale: float = 1.0
 
 
 def _deq(w, dt):
@@ -69,15 +86,38 @@ def router_topk(
     x2d: jax.Array,  # [N, H]
     router_w: jax.Array,  # [H, E] (global expert count)
     top_k: int,
+    routing: GroupRouting | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Top-k routing (Mixtral convention): softmax over the *selected*
-    logits, in f32. Returns ``(combine [N, E] f32, weights [N, k] f32,
-    idx [N, k] int32)`` where ``combine`` is zero off the top-k."""
+    """Top-k routing in f32. ``routing`` None is Mixtral's convention:
+    top-k of the logits, softmax over the *selected* logits. A
+    :class:`GroupRouting` is DeepSeek-V3's sigmoid, group-limited choice.
+    Ties go to the lower index (``lax.top_k``). Returns ``(combine [N, E]
+    f32, weights [N, k] f32, idx [N, k] int32)`` where ``combine`` is zero
+    off the top-k."""
     logits = jnp.einsum(
         "nh,he->ne", x2d, router_w, preferred_element_type=jnp.float32
     )
-    vals, idx = jax.lax.top_k(logits, top_k)  # [N, k]
-    w = jax.nn.softmax(vals, axis=-1)
+    if routing is None:
+        vals, idx = jax.lax.top_k(logits, top_k)  # [N, k]
+        w = jax.nn.softmax(vals, axis=-1)
+    else:
+        n, e = logits.shape
+        scores = jax.nn.sigmoid(logits)
+        choice = scores
+        if routing.n_group > 1:
+            grouped = scores.reshape(n, routing.n_group, -1)
+            group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)  # [N, G]
+            _, kept = jax.lax.top_k(group_score, routing.topk_group)
+            keep = jax.nn.one_hot(kept, routing.n_group,
+                                  dtype=jnp.bool_).any(axis=1)  # [N, G]
+            # -1 is below every sigmoid: an expert outside the kept groups
+            # is never chosen
+            choice = jnp.where(keep[..., None], grouped, -1.0).reshape(n, e)
+        _, idx = jax.lax.top_k(choice, top_k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        if routing.norm_topk and top_k > 1:
+            w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        w = w * routing.scale
     onehot = jax.nn.one_hot(idx, logits.shape[-1], dtype=w.dtype)  # [N,k,E]
     combine = jnp.einsum("nk,nke->ne", w, onehot)
     return combine, w, idx
@@ -129,8 +169,24 @@ def moe_swiglu(
     ep_axis: str | None = None,
     ep_size: int | None = None,
     tp_axis: str | None = None,
-) -> jax.Array:
-    """Routed SwiGLU MLP. Returns ``[B, T, H]`` (residual NOT added).
+    routing: GroupRouting | None = None,
+    held: tuple[int, int] | None = None,
+    count_local: bool = False,
+):
+    """Routed SwiGLU MLP. Returns ``[B, T, H]`` (residual NOT added); with
+    ``count_local`` a pair ``(out, local_pairs)``, each batch row's number
+    of (token, chosen expert) pairs that fell on the experts held here
+    (int32 ``[B]``, this rank's; the caller knows which rows are live).
+
+    ``held = (first, count)``: the expert stacks are a share of what the
+    router scores, told by the configuration: global experts ``first ..
+    first + count - 1`` of the router's ``E_global`` (a chip's share of an
+    expert-parallel deployment, served without the other chips). The layer
+    routes over all ``E_global`` and returns its own experts' part of the
+    result; what the absent experts would add is left out, and nothing
+    stands in for them or for their exchange. None: all ``E_global`` are
+    here. Under a real ``ep`` axis the same share is split once more over
+    the axis, and the parts are summed by the one ``psum``.
 
     The router always scores the **global** expert set; under ep the weight
     arrays hold this rank's contiguous expert slice (global experts
@@ -142,10 +198,13 @@ def moe_swiglu(
     """
     b, t, h = x.shape
     x2d = x.reshape(b * t, h)
-    combine, w_topk, idx = router_topk(x2d, router_w, top_k)
+    with jax.named_scope("moe.router"):
+        combine, w_topk, idx = router_topk(x2d, router_w, top_k, routing)
 
     e_local = (w_gate.q if isinstance(w_gate, QuantizedLinear)
                else w_gate).shape[0]
+    e_global = combine.shape[1]
+    first, count = held or (0, e_global)
     if ep_axis is not None and ep_size is None:
         # Static ep width from the shapes already in hand: the router
         # scores the GLOBAL expert set ([H, E_global]) while the weight
@@ -153,19 +212,32 @@ def moe_swiglu(
         # shard count is their ratio. Shape-derived rather than
         # jax.lax.axis_size so it works on jax versions without that API
         # (and it must be a Python int — it gates the strategy below).
-        ep_size = combine.shape[1] // e_local
+        ep_size = count // e_local
+    sharded = ep_axis is not None and ep_size > 1
     axes: tuple[str, ...] = ()
-    if ep_axis is not None and ep_size > 1:
-        lo = jax.lax.axis_index(ep_axis) * e_local
-        combine_local = jax.lax.dynamic_slice_in_dim(combine, lo, e_local, 1)
-        out = _moe_dense(x2d, combine_local, w_gate, w_up, w_down)
-        axes += (ep_axis,)
-    elif x2d.shape[0] * top_k <= GATHER_MAX_ROWS:
-        out = _moe_gather(x2d, w_topk, idx, w_gate, w_up, w_down)
-    else:
-        out = _moe_dense(x2d, combine, w_gate, w_up, w_down)
+    with jax.named_scope("moe.experts"):
+        if sharded or count != e_global:
+            # the stacks hold a slice of the experts the router scored
+            lo = first
+            if sharded:
+                lo = first + jax.lax.axis_index(ep_axis) * e_local
+                axes += (ep_axis,)
+            combine = jax.lax.dynamic_slice_in_dim(combine, lo, e_local, 1)
+            # every held expert over every row: no control flow in the
+            # layer body, whatever the rows (a conditional's operands are
+            # buffers, so the chip's compiler writes the scanned expert
+            # stacks out before it: 24 ms an admission, my chip run, PR 28)
+            out = _moe_dense(x2d, combine, w_gate, w_up, w_down)
+        elif x2d.shape[0] * top_k <= GATHER_MAX_ROWS:
+            out = _moe_gather(x2d, w_topk, idx, w_gate, w_up, w_down)
+        else:
+            out = _moe_dense(x2d, combine, w_gate, w_up, w_down)
     if tp_axis is not None:
         axes += (tp_axis,)
     if axes:
         out = jax.lax.psum(out, axes)
-    return out.reshape(b, t, h)
+    out = out.reshape(b, t, h)
+    if count_local:
+        hits = jnp.sum(combine > 0, axis=1, dtype=jnp.int32)
+        return out, hits.reshape(b, t).sum(axis=1)
+    return out

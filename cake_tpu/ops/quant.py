@@ -73,6 +73,10 @@ def quantize_linear_np(w) -> tuple:
 # Linear weight names eligible for quantization (norms/embed stay bf16; the
 # embedding is a gather, not a matmul, and norm scales are tiny).
 LAYER_LINEARS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# the same for a layer of the latent-attention, shared-expert family (its
+# router stays plain, like Mixtral's)
+LATENT_LINEARS = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "w_gate", "w_up",
+                  "w_down", "ws_gate", "ws_up", "ws_down")
 
 
 def reject_int4_moe() -> None:
@@ -109,7 +113,15 @@ def quantize_params(
     else:
         qfn = partial(quantize_linear4, group_size=group_size)
     out = dict(params)
-    if "layers" in params:
+    if "layers" in params and "wq" not in params["layers"]:
+        # the latent family's two stacks (models/llama.py stack_shapes)
+        if bits == 4:
+            reject_int4_moe()
+        out["layers"] = {
+            name: {k: (qfn(v) if k in LATENT_LINEARS else v)
+                   for k, v in stack.items()}
+            for name, stack in params["layers"].items()}
+    elif "layers" in params:
         out["layers"] = {
             k: (qfn(v) if k in LAYER_LINEARS else v)
             for k, v in params["layers"].items()

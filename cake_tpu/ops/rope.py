@@ -14,14 +14,54 @@ rotate ``(x1, x2) -> (x1*cos - x2*sin, x1*sin + x2*cos)``.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 
-def _scale_inv_freq(inv_freq: jnp.ndarray, scaling: dict) -> jnp.ndarray:
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``m = 0.1 * mscale * ln(factor) + 1``
+    (1 where nothing is stretched). Latent attention multiplies its softmax
+    scale by ``m(mscale_all_dim)^2`` (``LlamaConfig.attn_scale``)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_inv_freq(inv_freq: jnp.ndarray, scaling: dict,
+                   theta: float) -> jnp.ndarray:
+    """YaRN (Peng et al. 2023, as DeepSeek-V3's ``config.json`` keys name
+    it): pair ``i`` keeps ``theta^(-2i/d)`` where it turns more than
+    ``beta_fast`` times over the original window, is divided by ``factor``
+    where it turns fewer than ``beta_slow`` times, and is blended linearly
+    over the pair indices in between (the "correction dims")."""
+    half = inv_freq.shape[0]
+    dim = 2 * half
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(turns: float) -> float:
+        return (dim * math.log(orig / (turns * 2.0 * math.pi))
+                / (2.0 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(float(scaling.get("beta_fast", 32)))),
+              0)
+    high = min(math.ceil(correction_dim(float(scaling.get("beta_slow", 1)))),
+               dim - 1)
+    if low == high:
+        high += 0.001  # the published guard against a zero-width ramp
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
+
+
+def _scale_inv_freq(inv_freq: jnp.ndarray, scaling: dict,
+                    theta: float | None = None) -> jnp.ndarray:
     """Apply HF ``rope_scaling`` to the base frequencies.
 
-    Supports ``linear`` (uniform 1/factor) and Llama-3.1's ``llama3`` rule:
+    Supports ``linear`` (uniform 1/factor), ``yarn`` (:func:`_yarn_inv_freq`)
+    and Llama-3.1's ``llama3`` rule:
     wavelengths shorter than ``original_max/high_freq_factor`` keep their
     frequency, longer than ``original_max/low_freq_factor`` are divided by
     ``factor``, and the band between interpolates smoothly. (The reference
@@ -36,6 +76,8 @@ def _scale_inv_freq(inv_freq: jnp.ndarray, scaling: dict) -> jnp.ndarray:
     factor = float(scaling["factor"])
     if kind == "linear":
         return inv_freq / factor
+    if kind == "yarn":  # the one rule that needs the base itself
+        return _yarn_inv_freq(inv_freq, scaling, theta)
     if kind == "llama3":
         lo = float(scaling["low_freq_factor"])
         hi = float(scaling["high_freq_factor"])
@@ -54,11 +96,29 @@ def rope_tables(head_dim: int, max_seq: int, theta: float, dtype=jnp.float32,
     inv_freq = 1.0 / (
         theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
+    amp = 1.0
     if scaling is not None:
-        inv_freq = _scale_inv_freq(inv_freq, scaling)
+        inv_freq = _scale_inv_freq(inv_freq, scaling, theta)
+        if scaling.get("rope_type", scaling.get("type")) == "yarn":
+            # cos and sin carry m(mscale) / m(mscale_all_dim): 1 wherever
+            # the two are equal, as in every published latent-attention
+            # config (the temperature then sits in the softmax scale)
+            factor = float(scaling["factor"])
+            amp = (yarn_mscale(factor, float(scaling.get("mscale", 1.0)))
+                   / yarn_mscale(factor,
+                                 float(scaling.get("mscale_all_dim", 0.0))))
     t = jnp.arange(max_seq, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)  # [max_seq, head_dim/2]
-    return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
+    return ((jnp.cos(freqs) * amp).astype(dtype),
+            (jnp.sin(freqs) * amp).astype(dtype))
+
+
+def rope_tables_for(config, max_seq: int, dtype=jnp.float32):
+    """The tables of a model configuration: its rotary width
+    (``config.rope_dim``: the whole head, or latent attention's rope part),
+    base and scaling. What every execution path calls."""
+    return rope_tables(config.rope_dim, max_seq, config.rope_theta,
+                       dtype=dtype, scaling=config.rope_scaling)
 
 
 def apply_rope(
@@ -66,15 +126,26 @@ def apply_rope(
     cos: jax.Array,
     sin: jax.Array,
     pos: jax.Array,
+    interleaved: bool = False,
 ) -> jax.Array:
     """Rotate ``x [batch, heads, T, head_dim]`` for absolute positions
     ``pos .. pos+T`` (the reference's ``cosine/sine(index_pos, seq_len)``
     slice, cache.rs:71-78).
 
     ``pos`` may be a scalar (shared by all batch rows) or ``[batch]``
-    (per-row positions — the multi-stream serving path)."""
+    (per-row positions — the multi-stream serving path).
+
+    ``interleaved``: the pairs are ``(x[2i], x[2i+1])`` (the GPT-J / DeepSeek
+    checkpoint convention) instead of ``(x[i], x[i + d/2])``. The result is
+    left de-interleaved (pair ``i`` at ``i`` and ``i + d/2``), as the
+    published implementation leaves it: queries and keys get the same
+    permutation, so their dot products are those of the interleaved
+    rotation."""
     b, h, t, d = x.shape
     half = d // 2
+    if interleaved:
+        x = x.reshape(b, h, t, half, 2)
+        x = jnp.concatenate([x[..., 0], x[..., 1]], axis=-1)
     pos = jnp.asarray(pos, jnp.int32)
     if pos.ndim == 0:
         cos_t = jax.lax.dynamic_slice_in_dim(cos, pos, t, axis=0)

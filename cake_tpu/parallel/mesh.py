@@ -69,6 +69,12 @@ def make_mesh(
 def validate_shardable(config: LlamaConfig, num_stages: int, tp: int,
                        sp: int = 1, ep: int = 1) -> None:
     """Divisibility requirements for the (stage, sp, ep, tp) sharding."""
+    if config.latent and (num_stages > 1 or tp > 1 or sp > 1):
+        raise ValueError(
+            "a latent-attention model (two layer stacks, one cache row "
+            "for all heads) runs as one stage with tp = 1 and sp = 1; "
+            "only --ep shards it (its held experts)"
+        )
     if sp > 1 and config.max_seq_len % sp:
         raise ValueError(
             f"max_seq_len {config.max_seq_len} not divisible by sp {sp}"
@@ -79,13 +85,14 @@ def validate_shardable(config: LlamaConfig, num_stages: int, tp: int,
             f"stage count {num_stages}"
         )
     if ep > 1:
-        if not config.num_local_experts:
+        experts = config.num_local_experts or config.n_routed_experts
+        if not experts:
             raise ValueError(
                 "ep > 1 requires an MoE config (num_local_experts > 0)"
             )
-        if config.num_local_experts % ep:
+        if experts % ep:
             raise ValueError(
-                f"num_local_experts {config.num_local_experts} not "
+                f"num_local_experts {experts} not "
                 f"divisible by ep {ep}"
             )
     for name, dim in [
@@ -96,6 +103,11 @@ def validate_shardable(config: LlamaConfig, num_stages: int, tp: int,
     ]:
         if dim % tp:
             raise ValueError(f"{name} {dim} not divisible by tp {tp}")
+
+
+def _rank(leaf) -> int:
+    """Rank of a weight leaf (an int8 linear counts as its ``q``)."""
+    return getattr(leaf, "q", leaf).ndim
 
 
 def param_specs(params: dict | None = None) -> dict:
@@ -130,6 +142,18 @@ def param_specs(params: dict | None = None) -> dict:
     if params is None:
         return base
     layers = params.get("layers", {})
+    if "wq" not in layers and any(k in layers for k in ("dense", "moe")):
+        # the latent family's two stacks: one stage, tp = 1, so every
+        # tensor is replicated but the held experts, which shard over ep
+        def stack_spec(stack):
+            return {
+                k: (P(STAGE, EP, None, None)
+                    if k in ("w_gate", "w_up", "w_down") and "router" in stack
+                    else P(STAGE, *([None] * (_rank(v) - 1))))
+                for k, v in stack.items()}
+
+        base["layers"] = {name: stack_spec(stack)
+                          for name, stack in layers.items()}
     if "bq" in layers:
         base["layers"]["bq"] = P(STAGE, TP)
         base["layers"]["bk"] = P(STAGE, TP)
@@ -231,8 +255,8 @@ def init_cache_on_mesh(config, mesh: Mesh, batch: int = 1,
 
     from cake_tpu.ops.kvcache import init_cache
 
-    key = (mesh, config.num_hidden_layers, config.num_key_value_heads,
-           config.head_dim, str(config.dtype), batch,
+    key = (mesh, config.num_hidden_layers, config.cache_row,
+           str(config.dtype), batch,
            max_seq or config.max_seq_len, quant, batch_replicated)
     make = _CACHE_PROGRAMS.get(key)
     if make is None:

@@ -44,7 +44,7 @@ from cake_tpu.models import llama
 from cake_tpu.ops import quant, sampling
 from cake_tpu.ops.kvcache import KVCache
 from cake_tpu.ops.norms import rms_norm
-from cake_tpu.ops.rope import rope_tables
+from cake_tpu.ops.rope import rope_tables_for
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.parallel.mesh import (
     DP,
@@ -77,8 +77,12 @@ def _pipeline_layers(
     sp: int = 1,
     sp_prefill: bool = False,
     sp_chunk: bool = False,
+    count_local: bool = False,
 ):
-    """Run the staged pipeline loop. Returns (x_on_stage0, ck, cv).
+    """Run the staged pipeline loop. Returns (x_on_stage0, ck, cv); with
+    ``count_local`` (an expert model of the latent family, which runs as
+    one stage) a fourth value, each row's routed pairs that fell on
+    experts held here (:func:`llama.forward_layers`).
 
     SPMD-uniformity: every stage executes the layer math (and therefore every
     collective — tp psum, sp ring ppermute, sp decode psum/pmax) on every
@@ -95,19 +99,22 @@ def _pipeline_layers(
     perm = [(i, (i + 1) % num_stages) for i in range(num_stages)]
 
     def body(step, carry):
-        x, ck, cv = carry
+        x, ck, cv, *local = carry
         active = step == my_stage
-        h, new_cache = llama.forward_layers(
+        h, new_cache, *now = llama.forward_layers(
             layers, x, KVCache(k=ck, v=cv), cos, sin, pos, config,
             num_heads=heads_l, num_kv_heads=kv_heads_l, tp_axis=TP, ep_axis=EP,
             sp_axis=SP, sp_size=sp, write_gate=active, sp_prefill=sp_prefill,
-            sp_chunk=sp_chunk,
+            sp_chunk=sp_chunk, count_local=count_local,
         )
         x = jnp.where(active, h, x)
         x = jax.lax.ppermute(x, STAGE, perm)
-        return x, new_cache.k, new_cache.v
+        return (x, new_cache.k, new_cache.v,
+                *(a + b for a, b in zip(local, now)))
 
-    return jax.lax.fori_loop(0, num_stages, body, (x, ck, cv))
+    carry = (x, ck, cv) + (
+        (jnp.zeros((x.shape[0],), jnp.int32),) if count_local else ())
+    return jax.lax.fori_loop(0, num_stages, body, carry)
 
 
 def _pipelined_prefill_layers(
@@ -306,25 +313,29 @@ def build_sharded_decode(
     if paged and (plan.dp != 1 or plan.sp != 1):
         raise ValueError("paged decode requires dp == 1 and sp == 1 "
                          "(the page axis is unsharded)")
+    # the serving programs of an expert model of the latent family return
+    # one more value, last: each batch row's routed (token, expert) pairs
+    # of the dispatch that fell on experts held here, summed on the device
+    # over its steps, its expert layers and the ep axis; the engine adds
+    # up the live rows' (obs: moe.local_pairs)
+    count_local = per_row and moe_counted(config)
 
     def one_step(params, token, cache, pos, key, history, hist_slot,
                  mask=None):
         # cache.max_seq inside shard_map is the per-shard slice; RoPE tables
         # must cover global positions.
-        cos, sin = rope_tables(
-            config.head_dim, cache.max_seq * plan.sp, config.rope_theta,
-            scaling=config.rope_scaling,
-        )
+        cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, token[:, None], config)
-        x, ck, cv = _pipeline_layers(
+        x, ck, cv, *local = _pipeline_layers(
             x, params["layers"], cache.k, cache.v, cos, sin, pos, config,
             plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
-            sp_prefill=False,
+            sp_prefill=False, count_local=count_local,
         )
         x_last = _select_stage0(x[:, -1, :])
         logits = _head_logits(params, x_last, config)
         lp = sampling.topk_logprobs(logits, logprobs_k) if logprobs_k \
             else None
+        local = jax.lax.psum(local[0], EP) if count_local else None
         if per_row:
             tok = sampling.sample_tokens_keyed(logits, key, history,
                                                settings, mask=mask)
@@ -332,7 +343,7 @@ def build_sharded_decode(
             tok = sampling.sample_tokens(logits, _dp_fold(key, plan.dp),
                                          history, settings)
         history, hist_slot = sampling.push_history_batched(history, hist_slot, tok)
-        return tok, KVCache(k=ck, v=cv), history, hist_slot, lp
+        return tok, KVCache(k=ck, v=cv), history, hist_slot, lp, local
 
     def fold_key(key, index):
         if per_row:  # key [B, 2], index [B] (per-stream schedules)
@@ -356,7 +367,7 @@ def build_sharded_decode(
     ]
     if steps == 1 and not per_row:
         def step(params, token, cache, pos, key, history, hist_slot):
-            tok, cache, history, hist_slot, _ = one_step(
+            tok, cache, history, hist_slot, _, _ = one_step(
                 params, token, cache, pos, key, history, hist_slot)
             return tok, cache, history, hist_slot
     else:
@@ -388,17 +399,19 @@ def build_sharded_decode(
 
             def body(carry, i):
                 token, cache, history, hist_slot = carry
-                tok, cache, history, hist_slot, lp = one_step(
+                tok, cache, history, hist_slot, lp, local = one_step(
                     params, token, cache, pos + i, fold_key(key, index0 + i),
                     history, hist_slot, mask=row_mask,
                 )
                 ys = (tok, lp[0], lp[1]) if logprobs_k else tok
-                return (tok, cache, history, hist_slot), ys
+                return ((tok, cache, history, hist_slot),
+                        (ys, local) if count_local else (ys,))
 
-            (_, cache, history, hist_slot), ys = jax.lax.scan(
+            (_, cache, history, hist_slot), (ys, *local) = jax.lax.scan(
                 body, (token, cache, history, hist_slot),
                 jnp.arange(steps, dtype=jnp.int32),
             )
+            local = tuple(jnp.sum(a, axis=0) for a in local)
             if paged:
                 # only the pages this dispatch wrote go back to the pool
                 cache = kvpool.scatter_back(pool_in, cache, first_page,
@@ -409,9 +422,9 @@ def build_sharded_decode(
                 toks, lpv, lpi = ys, None, None
             if steps == 1:
                 out = (toks[0], cache, history, hist_slot)
-                return out + ((lpv[0], lpi[0]) if logprobs_k else ())
+                return out + ((lpv[0], lpi[0]) if logprobs_k else ()) + local
             out = (toks, cache, history, hist_slot)
-            return out + ((lpv, lpi) if logprobs_k else ())
+            return out + ((lpv, lpi) if logprobs_k else ()) + local
 
         in_specs.append(P(DP) if per_row else P())  # index0
         if masked:
@@ -434,10 +447,16 @@ def build_sharded_decode(
             kv_specs,
             P(DP, None),
             P(DP) if per_row else P(),
-        ) + lp_specs,
+        ) + lp_specs + ((P(DP),) if count_local else ()),
         check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(2,))
+
+
+def moe_counted(config: LlamaConfig) -> bool:
+    """Whether this model's serving decode programs count the routed pairs
+    that fall on held experts (an expert model told its share)."""
+    return config.latent and config.n_routed_experts > 0
 
 
 def _head_split_safe(hw, S: int) -> bool:
@@ -555,10 +574,7 @@ def build_interleaved_decode(
                 f"divisible by num_stages ({S})"
             )
         bm = b // S
-        cos, sin = rope_tables(
-            config.head_dim, cache.max_seq * plan.sp, config.rope_theta,
-            scaling=config.rope_scaling,
-        )
+        cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         my_stage = jax.lax.axis_index(STAGE)
         perm = [(i, (i + 1) % S) for i in range(S)]
         hw = params["lm_head"]
@@ -714,10 +730,7 @@ def build_admit_prefill(config: LlamaConfig, plan: MeshPlan,
     heads_l, kv_heads_l = _local_counts(config, plan.tp)
 
     def step(params, tokens, cache, pos0, last_local):
-        cos, sin = rope_tables(
-            config.head_dim, cache.max_seq * plan.sp, config.rope_theta,
-            scaling=config.rope_scaling,
-        )
+        cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, tokens, config)
         x, ck, cv = _pipeline_layers(
             x, params["layers"], cache.k, cache.v, cos, sin, pos0, config,
@@ -769,10 +782,7 @@ def build_sharded_verify(config: LlamaConfig, plan: MeshPlan,
                          "(single-stream plane)")
 
     def step(params, tokens, cache, pos):
-        cos, sin = rope_tables(
-            config.head_dim, cache.max_seq * plan.sp, config.rope_theta,
-            scaling=config.rope_scaling,
-        )
+        cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, tokens, config)
         x, ck, cv = _pipeline_layers(
             x, params["layers"], cache.k, cache.v, cos, sin, pos, config,
@@ -820,10 +830,7 @@ def build_sharded_verify_rows(config: LlamaConfig, plan: MeshPlan,
     heads_l, kv_heads_l = _local_counts(config, plan.tp)
 
     def step(params, tokens, cache, pos):
-        cos, sin = rope_tables(
-            config.head_dim, cache.max_seq * plan.sp, config.rope_theta,
-            scaling=config.rope_scaling,
-        )
+        cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, tokens, config)
         x, ck, cv = _pipeline_layers(
             x, params["layers"], cache.k, cache.v, cos, sin, pos, config,
@@ -885,10 +892,7 @@ def build_interleaved_verify_rows(config: LlamaConfig, plan: MeshPlan,
                 f"divisible by num_stages ({S})"
             )
         bm = b // S
-        cos, sin = rope_tables(
-            config.head_dim, cache.max_seq * plan.sp, config.rope_theta,
-            scaling=config.rope_scaling,
-        )
+        cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         my_stage = jax.lax.axis_index(STAGE)
         perm = [(i, (i + 1) % S) for i in range(S)]
         x_all = llama.embed_tokens(params, tokens, config)  # [B,T,H]
@@ -1020,10 +1024,7 @@ def build_sharded_prefill(config: LlamaConfig, plan: MeshPlan,
 
     def step(params, tokens, cache, last_index, *rest):
         pos0 = rest[0] if with_offset else 0
-        cos, sin = rope_tables(
-            config.head_dim, cache.max_seq * plan.sp, config.rope_theta,
-            scaling=config.rope_scaling,
-        )
+        cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, tokens, config)
         if microbatch > 1:
             b, t = tokens.shape

@@ -82,6 +82,7 @@ from cake_tpu.kvpool import (
 )
 from cake_tpu.kvpool import pool as kvpool_pool
 from cake_tpu.models.config import LlamaConfig
+from cake_tpu.models.llama import stack_layers
 from cake_tpu.obs import flight as obs_flight
 from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.obs import prof as obs_prof
@@ -97,6 +98,7 @@ from cake_tpu.parallel.pipeline import (
     build_admit_prefill,
     build_interleaved_decode,
     build_sharded_decode,
+    moe_counted,
     build_sharded_prefill,
 )
 from cake_tpu.runtime.generator import Token, _bucket, encode_prompt
@@ -128,6 +130,9 @@ _EXPORTS = obs_metrics.counter("disagg.exports")
 _IMPORTS = obs_metrics.counter("disagg.imports")
 _RESUMES = obs_metrics.counter("disagg.resumes")
 _IMPORT_ABORTS = obs_metrics.counter("disagg.import_aborts")
+_MOE_LOCAL = obs_metrics.counter("moe.local_pairs")
+_MOE_ROUTED = obs_metrics.counter("moe.routed_pairs")
+_MOE_STEPS = obs_metrics.counter("moe.decode_steps")
 
 # arrival-queue entry kinds (4th tuple field): None marks a plain prompt
 # arrival; imports ride the SAME FIFO so pool-pressure deferral stays
@@ -243,6 +248,12 @@ class BatchGenerator:
             raise ValueError(
                 f"kv_layout must be 'slot' or 'paged', got {kv_layout!r}")
         self._paged = kv_layout == "paged"
+        if self._paged and config.latent:
+            raise ValueError(
+                "kv_layout='paged' is not wired for latent attention (the "
+                "page pool, and with it the disagg snapshot and the spill "
+                "tier, hold per-head keys and values); serve this family "
+                "with the slot layout")
         self._page_size = int(kv_page_size)
         self._pool_pages_req = kv_pool_pages
         if self._paged:
@@ -515,6 +526,13 @@ class BatchGenerator:
         self._emitted_ctr = obs_metrics.Counter("serve.tokens_emitted")
         obs_metrics.registry().publish(
             self._dispatch_hist, self._admit_hist, self._emitted_ctr)
+        # an expert model's load on the experts held here: the decode
+        # programs of a model told its share return each row's routed
+        # pairs that fell on held experts as one more value
+        # (pipeline.moe_counted), fetched with the block's tokens
+        self._moe_counted = moe_counted(config)
+        # (local pairs a row, steps, live rows) of dispatches not yet fetched
+        self._moe_pending: deque = deque()
         # engine profiling plane (obs/prof): sampled step-phase stamps +
         # the runtime retrace sentinel watching this engine's dispatches
         self._prof = obs_prof.profiler()
@@ -979,6 +997,13 @@ class BatchGenerator:
                 jnp.asarray(last)
             )
 
+        # what the cache really holds (any family), read off the buffers
+        # that were allocated: all of it, and for one token of one layer
+        held = sum(x.nbytes for x in jax.tree.leaves(self.cache))
+        obs_metrics.gauge("cache.bytes").set(held)
+        obs_metrics.gauge("cache.row_bytes").set(
+            held / (self.cache.num_layers * self.cache.batch
+                    * self.cache.max_seq))
         # first token per stream: fold_in(stream_key, 0) — the same absolute
         # token-index schedule the in-program decode steps continue
         keys0 = jax.vmap(lambda k: jax.random.fold_in(k, 0))(self._keys)
@@ -2673,6 +2698,7 @@ class BatchGenerator:
             rows = self._host(toks)
             lp = ((self._host(lpv), self._host(lpi))
                   if lpv is not None else None)
+            self._record_moe_count()
             self._busy_s += time.perf_counter() - t0
             for i in range(rows.shape[0]):
                 self._pending_rows.append(self._emit(
@@ -2694,6 +2720,7 @@ class BatchGenerator:
                 self._hist_slot, jnp.asarray(self._index),
                 *self._paged_args(size),
             )
+            out = self._take_moe_count(out, size)
             if self.logprobs_k:
                 (toks, self.cache, self._history, self._hist_slot,
                  lpv, lpi) = out
@@ -2705,6 +2732,31 @@ class BatchGenerator:
         self._index = self._index + size
         self._last_tokens = toks[-1].astype(jnp.int32)
         return toks, lpv, lpi
+
+    def _take_moe_count(self, out: tuple, steps: int) -> tuple:
+        """Strip the trailing per-row routed-pair counts off a decode
+        program's outputs (present for an expert model told its share)
+        and queue them, un-fetched, beside the dispatch they belong to and
+        the rows that were live when it left (a dead slot's row still
+        goes through the program; its pairs are no load)."""
+        if not self._moe_counted:
+            return out
+        live = np.array([s.active and not s.done for s in self.streams])
+        self._moe_pending.append((out[-1], steps, live))
+        return out[:-1]
+
+    def _record_moe_count(self) -> None:
+        """Fetch the oldest queued counts (their dispatch's tokens were
+        just fetched, so they are ready) and add the live rows' into
+        ``moe.*``."""
+        if not self._moe_pending:
+            return
+        local, steps, live = self._moe_pending.popleft()
+        _MOE_LOCAL.inc(int(self._host(local)[live].sum()))
+        _MOE_ROUTED.inc(
+            steps * int(live.sum()) * self.config.num_experts_per_tok
+            * stack_layers(self.config)["moe"])
+        _MOE_STEPS.inc(steps)
 
     def _step_decode(self):
         # Buffered fused-block rows are EARLIER tokens than anything a new
@@ -2770,6 +2822,7 @@ class BatchGenerator:
                 rows = self._host(toks)  # [steps, B]
                 lp_h = ((self._host(lpv), self._host(lpi))
                         if lpv is not None else None)
+                self._record_moe_count()
             dt = time.perf_counter() - t0
             self._busy_s += dt
             # per-token ms so the series is comparable across block sizes
@@ -2809,6 +2862,7 @@ class BatchGenerator:
             else:
                 out = self._pick_decode(block=False)(
                     *args, *self._paged_args(1))
+        out = self._take_moe_count(out, 1)
         if self.logprobs_k:
             (tok, self.cache, self._history, self._hist_slot,
              lpv_d, lpi_d) = out
@@ -2820,6 +2874,7 @@ class BatchGenerator:
             row = self._host(tok)
             lp_h = ((self._host(lpv_d), self._host(lpi_d))
                     if lpv_d is not None else None)
+            self._record_moe_count()
         self._n_decode_dispatches += 1
         dt = time.perf_counter() - t0
         self._busy_s += dt

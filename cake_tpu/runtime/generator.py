@@ -39,7 +39,7 @@ from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.obs.trace import span
 from cake_tpu.ops import quant
 from cake_tpu.ops.kvcache import KVCache, init_cache
-from cake_tpu.ops.rope import rope_tables
+from cake_tpu.ops.rope import rope_tables_for
 from cake_tpu.ops import sampling
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.sampling import SamplerSettings
@@ -106,8 +106,7 @@ def _lm_head(params, x_last: jax.Array, config: LlamaConfig) -> jax.Array:
 def prefill_fn(params, tokens, cache: KVCache, last_index, config: LlamaConfig):
     """Prompt pass. ``tokens [B, T_pad]``; logits read at ``last_index``
     (the last *real* prompt position). Returns (logits [B, vocab], cache)."""
-    cos, sin = rope_tables(config.head_dim, cache.max_seq, config.rope_theta,
-                           scaling=config.rope_scaling)
+    cos, sin = rope_tables_for(config, cache.max_seq)
     x = llama.embed_tokens(params, tokens, config)
     x, cache = llama.forward_layers(params["layers"], x, cache, cos, sin, 0, config)
     x_last = jnp.take_along_axis(
@@ -135,8 +134,7 @@ def decode_step_fn(
     + one jnp.where inside the same compiled program. Calls without them
     trace the exact pre-constraint program — unconstrained streams stay
     bit-identical."""
-    cos, sin = rope_tables(config.head_dim, cache.max_seq, config.rope_theta,
-                           scaling=config.rope_scaling)
+    cos, sin = rope_tables_for(config, cache.max_seq)
     x = llama.embed_tokens(params, token[:, None], config)
     x, cache = llama.forward_layers(params["layers"], x, cache, cos, sin, pos, config)
     logits = _lm_head(params, x[:, -1, :], config)

@@ -41,7 +41,7 @@ from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.ops import quant, sampling
 from cake_tpu.ops.kvcache import KVCache
 from cake_tpu.ops.norms import rms_norm
-from cake_tpu.ops.rope import rope_tables
+from cake_tpu.ops.rope import rope_tables_for
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.runtime.generator import LlamaGenerator
 from cake_tpu.runtime.mesh_generator import MeshGenerator
@@ -114,8 +114,7 @@ def verify_fn(params, tokens, cache: KVCache, pos, config: LlamaConfig):
     KV for all T slots is written; slots past the accepted frontier hold
     rejected garbage that later steps overwrite before it becomes
     attendable (the same invariant as bucketed-prefill padding)."""
-    cos, sin = rope_tables(config.head_dim, cache.max_seq, config.rope_theta,
-                           scaling=config.rope_scaling)
+    cos, sin = rope_tables_for(config, cache.max_seq)
     return _verify_forward(params, tokens, cache, pos, cos, sin, config)
 
 
@@ -322,8 +321,7 @@ def spec_rounds_fn(
     are that round's emissions. The caller must guarantee
     ``pos + rounds*(K+1) <= max_seq`` (the scan writes K+1 KV slots per
     round unconditionally)."""
-    cos, sin = rope_tables(config.head_dim, cache.max_seq, config.rope_theta,
-                           scaling=config.rope_scaling)
+    cos, sin = rope_tables_for(config, cache.max_seq)
     greedy = settings.greedy
 
     def round_body(carry, _):
@@ -413,8 +411,7 @@ def spec_replay_fn(
 
     Returns ``(counts [rounds], pos, cache, acc)``.
     """
-    cos, sin = rope_tables(config.head_dim, cache.max_seq, config.rope_theta,
-                           scaling=config.rope_scaling)
+    cos, sin = rope_tables_for(config, cache.max_seq)
 
     def round_body(carry, _):
         pos, cache, acc = carry

@@ -62,6 +62,9 @@ def hbm_budget(
         lin_el, scale_el = el, 0
     S = max_seq or c.max_seq_len
     d = c.head_dim
+    if c.latent:
+        return _latent_budget(c, ep, S, batch, lin_el, scale_el, el,
+                              cache_bytes_per_el)
 
     # per-layer linear params (full, unsharded). MoE (Mixtral families):
     # the MLP triplet multiplies by num_local_experts and its expert axis
@@ -122,6 +125,46 @@ def hbm_budget(
         "head": int(head_bytes),
         "kv_cache": int(kv_bytes),
         "total": int(total),
+    }
+
+
+def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
+                   el: int, cache_el: int) -> dict:
+    """:func:`hbm_budget` for the latent-attention, shared-expert family,
+    from the shapes the model is built with (``models.llama.stack_shapes``):
+    every tensor replicated but the HELD experts' stacks, which divide over
+    ep; the cache is the latent row (``LlamaConfig.cache_row_values`` a
+    token a layer), not per-head keys and values. One stage, tp = sp = 1
+    (``mesh.validate_shardable``)."""
+    import math
+
+    from cake_tpu.models.llama import stack_layers, stack_shapes
+    from cake_tpu.ops.quant import LATENT_LINEARS
+
+    count = stack_layers(c)
+    layer_bytes = 0.0
+    for stack, shapes in stack_shapes(c).items():
+        per_layer = 0.0
+        for name, shape_fn in shapes.items():
+            shape = shape_fn(c)
+            if name in LATENT_LINEARS:
+                held = shape[0] / ep if len(shape) == 3 else 1
+                fan_in, out = shape[-2:]
+                per_layer += held * (fan_in * out * lin_el + out * scale_el)
+            else:  # norms and the router, in the serving type
+                per_layer += math.prod(shape) * el
+        layer_bytes += count[stack] * per_layer
+    embed_bytes = c.vocab_size * c.hidden_size * el
+    head_bytes = (c.hidden_size * c.vocab_size * lin_el
+                  + c.vocab_size * scale_el + c.hidden_size * el)
+    kv_bytes = (c.num_hidden_layers * batch * S * c.cache_row_values
+                * cache_el)
+    return {
+        "layers": int(layer_bytes),
+        "embed_replicated": int(embed_bytes),
+        "head": int(head_bytes),
+        "kv_cache": int(kv_bytes),
+        "total": int(layer_bytes + embed_bytes + head_bytes + kv_bytes),
     }
 
 

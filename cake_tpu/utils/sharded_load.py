@@ -25,6 +25,8 @@ contiguous original-row range, so the reads stay single slices.
 
 from __future__ import annotations
 
+import itertools
+
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -152,6 +154,12 @@ def load_llama_params_on_mesh(
     krows = 2 if int4 else 1  # original rows per stored quantized row
 
     reader = CheckpointReader(model_dir)
+    if config.latent:
+        try:
+            return _load_latent_on_mesh(reader, model_dir, config, mesh,
+                                        quantize, tie_word_embeddings)
+        finally:
+            reader.close()
     num_experts, attention_bias, o_bias = detect_family(reader.name_to_file)
     if not tie_word_embeddings and detect_tied_head(
             reader.name_to_file, model_dir, "cake_tpu.sharded_load"):
@@ -567,3 +575,114 @@ def load_llama_params_on_mesh(
         return params
     finally:
         reader.close()
+
+
+def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
+                         config: LlamaConfig, mesh: Mesh,
+                         quantize: str | None,
+                         tie_word_embeddings: bool) -> dict:
+    """The latent-attention, shared-expert family onto the mesh: two layer
+    stacks (``params["layers"] = {"dense": ..., "moe": ...}``), the held
+    experts read by their global ids (``config.first_expert`` on), every
+    tensor replicated but the expert stacks, whose expert axis shards over
+    ep (the family runs as one stage with tp = 1:
+    ``mesh.validate_shardable``). int8 (on load or stored ``.q8``)
+    quantizes every linear of ``quant.LATENT_LINEARS``; each layer's
+    tensor is read, and quantized, on its own, so host scratch is one
+    tensor."""
+    from cake_tpu.models.llama import stack_shapes
+    from cake_tpu.ops.quant import (LATENT_LINEARS, QuantizedLinear,
+                                    parse_quant_spec, quantize_linear_np,
+                                    reject_int4_moe)
+    from cake_tpu.utils.weights import check_prequantized, latent_stack_plan
+
+    tier, _ = parse_quant_spec(quantize)
+    if tier == "int4":
+        reject_int4_moe()
+    prequantized = check_prequantized(reader.name_to_file, quantize)
+    if not tie_word_embeddings and detect_tied_head(
+            reader.name_to_file, model_dir, "cake_tpu.sharded_load"):
+        tie_word_embeddings = True
+    dt = _np_dtype(config.dtype)
+    scales: dict[str, np.ndarray] = {}  # a linear's scale, once computed
+
+    def stored(name: str) -> bool:
+        return prequantized and f"{name}.q8" in reader.name_to_file
+
+    def quantized(name: str) -> np.ndarray:
+        """q [in, out] int8 of one linear, stored so or quantized here
+        (its scale is then kept for the scale leaf)."""
+        if stored(name):
+            return reader.read2d(f"{name}.q8", slice(None), slice(None),
+                                 True)
+        q, scales[name] = quantize_linear_np(
+            reader.read2d(name, slice(None), slice(None), True))
+        return q
+
+    def scale_of(name: str) -> np.ndarray:
+        if stored(name):
+            return reader.read1d(f"{name}.scale")
+        if name not in scales:
+            quantized(name)
+        return scales[name]
+
+    def stacked(names_of, lead: tuple[int, ...], shape: tuple, spec: P,
+                transpose: bool, quant: bool):
+        """One stacked leaf: ``names_of(i, [e])`` is the stored tensor of
+        each leading index; 1-D tensors and plain linears in the serving
+        type, quantized linears as (q, scale)."""
+        def gather(index, read):
+            grids = [range(*sl.indices(n)) for sl, n in zip(index, lead)]
+            out = np.stack([read(names_of(*ids))
+                            for ids in itertools.product(*grids)])
+            return out.reshape(tuple(len(g) for g in grids) + out.shape[1:])
+
+        lead_spec = tuple(spec)[:len(lead)]
+        if len(shape) == 1:
+            return _assemble(lead + shape, mesh, P(*lead_spec, None),
+                             lambda ix: gather(ix, lambda n: reader.read1d(
+                                 n, ix[-1])).astype(dt))
+        if not quant:
+            return _assemble(
+                lead + shape, mesh, P(*lead_spec, None, None),
+                lambda ix: gather(ix, lambda n: reader.read2d(
+                    n, ix[-2], ix[-1], transpose)).astype(dt))
+        return QuantizedLinear(
+            _assemble(lead + shape, mesh, P(*lead_spec, None, None),
+                      lambda ix: gather(
+                          ix, lambda n: quantized(n)[ix[-2], ix[-1]])),
+            _assemble(lead + shape[1:], mesh, P(*lead_spec, None),
+                      lambda ix: gather(ix, lambda n: scale_of(n)[ix[-1]])))
+
+    shapes = stack_shapes(config)
+    layers: dict = {}
+    for stack, (first, n, plain, experts) in latent_stack_plan(
+            config).items():
+        out = {}
+        for ours, (suffix, transpose) in plain.items():
+            out[ours] = stacked(
+                lambda i, s=suffix: f"model.layers.{first + i}.{s}",
+                (n,), shapes[stack][ours](config), P(STAGE), transpose,
+                tier is not None and ours in LATENT_LINEARS)
+        for ours, pattern in experts.items():
+            out[ours] = stacked(
+                lambda i, e, p=pattern: (
+                    f"model.layers.{first + i}."
+                    f"{p.format(e=config.first_expert + e)}"),
+                (n, config.n_routed_experts),
+                shapes[stack][ours](config)[1:], P(STAGE, EP), True,
+                tier is not None)
+        layers[stack] = out
+
+    h, v = config.hidden_size, config.vocab_size
+    head_name = ("model.embed_tokens.weight" if tie_word_embeddings
+                 else "lm_head.weight")
+    return {
+        "layers": layers,
+        "embed": stacked(lambda: "model.embed_tokens.weight", (), (v, h),
+                         P(), False, False),
+        "norm_f": stacked(lambda: "model.norm.weight", (), (h,), P(), False,
+                          False),
+        "lm_head": stacked(lambda: head_name, (), (h, v), P(), True,
+                           tier is not None),
+    }

@@ -60,6 +60,59 @@ _MOE_EXPERT_MAP = {
 _MOE_ROUTER = "block_sparse_moe.gate.weight"
 
 
+# The latent-attention, shared-expert family (DeepSeek-V3's tensor names,
+# which `model_type` "axk1" is assumed to share): every layer's attention,
+# then either a dense MLP (the leading `first_k_dense_replace` layers) or a
+# router, the shared experts and the routed experts BY THEIR GLOBAL IDS
+# (a cut checkpoint holds a slice of them: models/config.py first_expert).
+_LATENT_MAP = {
+    "attn_norm": ("input_layernorm.weight", False),
+    "wq_a": ("self_attn.q_a_proj.weight", True),
+    "q_norm": ("self_attn.q_a_layernorm.weight", False),
+    "wq_b": ("self_attn.q_b_proj.weight", True),
+    "wkv_a": ("self_attn.kv_a_proj_with_mqa.weight", True),
+    "kv_norm": ("self_attn.kv_a_layernorm.weight", False),
+    "wkv_b": ("self_attn.kv_b_proj.weight", True),
+    "wo": ("self_attn.o_proj.weight", True),
+    "mlp_norm": ("post_attention_layernorm.weight", False),
+}
+_LATENT_DENSE_MAP = {k: _LAYER_MAP[k] for k in ("w_gate", "w_up", "w_down")}
+_LATENT_MOE_MAP = {
+    "router": ("mlp.gate.weight", True),
+    "ws_gate": ("mlp.shared_experts.gate_proj.weight", True),
+    "ws_up": ("mlp.shared_experts.up_proj.weight", True),
+    "ws_down": ("mlp.shared_experts.down_proj.weight", True),
+}
+_LATENT_EXPERT_MAP = {
+    "w_gate": "mlp.experts.{e}.gate_proj.weight",
+    "w_up": "mlp.experts.{e}.up_proj.weight",
+    "w_down": "mlp.experts.{e}.down_proj.weight",
+}
+
+def latent_stack_plan(config) -> dict:
+    """Stack name -> ``(first layer, layers, {ours: (HF suffix,
+    transpose)}, {ours: expert pattern})`` for a latent-family model: what
+    both loaders and the writer walk (``models.llama.stack_shapes`` gives
+    the shapes)."""
+    from cake_tpu.models.llama import stack_layers, stack_shapes
+
+    count = stack_layers(config)
+    kinds = {"dense": ({**_LATENT_MAP, **_LATENT_DENSE_MAP}, {}),
+             "moe": ({**_LATENT_MAP, **_LATENT_MOE_MAP}, _LATENT_EXPERT_MAP)}
+    first = {"dense": 0, "moe": count["dense"]}
+    return {
+        stack: (first[stack], count[stack],
+                {k: v for k, v in kinds[stack][0].items() if k in shapes},
+                kinds[stack][1])
+        for stack, shapes in stack_shapes(config).items()}
+
+
+def is_latent_checkpoint(name_to_file: dict) -> bool:
+    """Whether the checkpoint stores latent-attention tensors."""
+    return any(".self_attn.kv_a_proj_with_mqa.weight" in n
+               for n in name_to_file)
+
+
 def hf_layer_map(num_experts: int = 0, attention_bias: bool = False,
                  o_bias: bool = False) -> dict:
     """The per-layer name map for a model family (the dense/bias-free base
@@ -369,6 +422,22 @@ def load_llama_params(
     from safetensors import safe_open
 
     name_to_file = load_safetensors_index(model_dir)
+    if is_latent_checkpoint(name_to_file):
+        # the latent family has one loader (two layer stacks, experts by
+        # global id): the direct-to-mesh one, on a mesh of one device
+        if layer_range is not None or not (include_embed and include_head):
+            raise NotImplementedError(
+                "a latent-attention checkpoint loads whole (no layer "
+                "ranges: the family runs as one stage)")
+        from cake_tpu.models.config import LlamaConfig
+        from cake_tpu.parallel.mesh import make_mesh
+        from cake_tpu.utils.sharded_load import load_llama_params_on_mesh
+
+        config = LlamaConfig.from_hf_json(Path(model_dir) / "config.json",
+                                          dtype=str(jnp.dtype(dtype)))
+        return load_llama_params_on_mesh(
+            model_dir, config, make_mesh(), quantize=quantize,
+            tie_word_embeddings=tie_word_embeddings)
     det_experts, det_bias, det_o = detect_family(name_to_file)
     if num_experts is None:
         num_experts = det_experts
@@ -411,13 +480,46 @@ def load_llama_params(
                 h.__exit__(None, None, None)
 
 
-def save_llama_params(params: dict, model_dir: str | Path, num_layers: int | None = None):
+def latent_hf_tensors(params: dict, config) -> dict[str, np.ndarray]:
+    """A latent-family params pytree as Hugging Face tensors (torch ``[out,
+    in]``), the held experts under their global ids: what
+    :func:`save_llama_params` writes and the plain reference
+    (``cake_tpu/testing/reference_mla_moe.py``) reads."""
+    tensors = {
+        "model.embed_tokens.weight": np.asarray(params["embed"]),
+        "model.norm.weight": np.asarray(params["norm_f"]),
+        "lm_head.weight": np.asarray(params["lm_head"]).T,
+    }
+    for stack, (first, n, plain, experts) in latent_stack_plan(
+            config).items():
+        for ours, (suffix, transpose) in plain.items():
+            stacked = np.asarray(params["layers"][stack][ours])
+            for i in range(n):
+                tensors[f"model.layers.{first + i}.{suffix}"] = (
+                    stacked[i].T if transpose else stacked[i])
+        for ours, pattern in experts.items():
+            stacked = np.asarray(params["layers"][stack][ours])
+            for i in range(n):
+                for e in range(stacked.shape[1]):
+                    name = pattern.format(e=config.first_expert + e)
+                    tensors[f"model.layers.{first + i}.{name}"] = (
+                        stacked[i, e].T)
+    return tensors
+
+
+def save_llama_params(params: dict, model_dir: str | Path,
+                      num_layers: int | None = None, config=None):
     """Write a params pytree back to HF-format safetensors (test fixtures and
-    the splitter round-trip). Inverse of :func:`load_llama_params`."""
+    the splitter round-trip). Inverse of :func:`load_llama_params`. A
+    latent-family pytree (two layer stacks) needs its ``config``."""
     from safetensors.numpy import save_file
 
     model_dir = Path(model_dir)
     model_dir.mkdir(parents=True, exist_ok=True)
+    if "wq" not in params["layers"]:
+        if config is None:
+            raise ValueError("saving a latent-family pytree needs config=")
+        return _save_tensors(latent_hf_tensors(params, config), model_dir)
     tensors: dict[str, np.ndarray] = {}
     if "embed" in params:
         tensors["model.embed_tokens.weight"] = np.asarray(params["embed"])
@@ -455,6 +557,11 @@ def save_llama_params(params: dict, model_dir: str | Path, num_layers: int | Non
                         f"model.layers.{i}.{pattern.format(e=e)}"
                     ] = np.ascontiguousarray(stacked[i, e].T)
             del stacked
+    return _save_tensors(tensors, model_dir)
+
+
+def _save_tensors(tensors: dict, model_dir: Path) -> Path:
+    from safetensors.numpy import save_file
 
     out = model_dir / "model.safetensors"
     # bf16 numpy isn't universally supported by safetensors.numpy; store f32

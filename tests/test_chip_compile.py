@@ -293,3 +293,88 @@ def test_admit_prefill_program_keeps_one_staging_cache(topo, as_on_chip):
             compiled, f"bf16[{depth},1,{KVH},{WINDOW},{D}]") == []
         _, temps = _donated_bytes(compiled)
         assert temps <= 12 * 2**20, (depth, temps / 2**20)
+
+
+def _latent_programs(topo, layers: int, slots: int, window: int, bucket: int):
+    """(config, block decode, admission) compiled for one described v5e at
+    A.X-K1's published widths (one chip's share of 16, an eighth of the
+    vocabulary), bf16, ``layers`` of its depth."""
+    from jax.sharding import NamedSharding
+
+    from cake_tpu.models.config import axk1_ep16
+    from cake_tpu.models.llama import init_params
+    from cake_tpu.ops.kvcache import init_cache
+    from cake_tpu.ops.sampling import SamplerSettings
+    from cake_tpu.parallel.mesh import MeshPlan, cache_specs, param_specs
+    from cake_tpu.parallel.pipeline import (build_admit_prefill,
+                                            build_sharded_decode)
+
+    config = axk1_ep16(num_hidden_layers=layers, vocab_size=20480,
+                       max_seq_len=window)
+    plan = MeshPlan.build(config, devices=topo.devices[:1])
+
+    def placed(shapes, specs):
+        return jax.tree.map(
+            lambda s, spec: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(plan.mesh, spec)),
+            shapes, specs)
+
+    params = jax.eval_shape(lambda k: init_params(config, k),
+                            jax.random.PRNGKey(0))
+    params = placed(params, param_specs(params))
+    rep = NamedSharding(plan.mesh, jax.sharding.PartitionSpec())
+
+    def arg(shape, dtype=I32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    def cache(batch):
+        return placed(
+            jax.eval_shape(lambda: init_cache(config, batch=batch,
+                                              max_seq=window)),
+            cache_specs(None, batch_replicated=batch == 1))
+
+    settings = SamplerSettings(temperature=0.0)
+    decode = build_sharded_decode(
+        config, settings, plan, params_like=params, steps=8, per_row=True
+    ).lower(params, arg((slots,)), cache(slots), arg((slots,)),
+            arg((slots, 2), jnp.uint32),
+            arg((slots, settings.repeat_last_n)), arg((slots,)),
+            arg((slots,))).compile()
+    admit = build_admit_prefill(config, plan, params_like=params).lower(
+        params, arg((1, bucket)), cache(1), arg(()), arg((1,))).compile()
+    return config, decode, admit
+
+
+def test_latent_programs_move_no_cache_and_no_expert_stack(topo, as_on_chip):
+    """The latent-attention, shared-expert family's two serving programs
+    at A.X-K1's published widths, 1 dense + 2 expert layers, 32 slots x
+    4096 rows (the cell ``axk1-ep16-cut.decode-full`` but for its depth):
+    the chip's compiler takes them; the latent cache (two buffers, 512 and
+    64 values a row, one "head") is carried through BOTH layer stacks and
+    written in place, so nothing of either buffer's shape is allocated or
+    copied; and no layer's expert stack ``[1, 12, 7168, 2048]`` is written
+    out of the scanned weights before use. That last one is what control
+    flow in the layer body costs (a ``lax.cond`` between two expert
+    strategies wrote the three stacks out before it: 24 ms of every
+    admission on the chip, PR 28), so a told share runs one strategy with
+    none."""
+    layers, slots, window = 3, 32, 4096
+    config, decode, admit = _latent_programs(topo, layers, slots, window, 512)
+    assert config.cache_row == (1, 512, 64)
+    for compiled, batch in ((decode, slots), (admit, 1)):
+        for width in (512, 64):
+            assert _cache_sized_moves(
+                compiled, f"bf16[{layers},{batch},1,{window},{width}]") == []
+        slabs = [f"{c}: {n} ({op})"
+                 for c, n, shape, op, _ in _instructions(compiled)
+                 if shape in ("bf16[1,12,7168,2048]", "bf16[1,12,2048,7168]",
+                              "bf16[12,7168,2048]", "bf16[12,2048,7168]")
+                 and not c.startswith("fused_computation")
+                 and op not in ("parameter", "get-tuple-element", "bitcast",
+                                "tuple")]
+        assert slabs == []
+    args, temps = _donated_bytes(decode)
+    # 2 x 1.35 GB of expert layers + 1.0 of the dense one + 0.59 of
+    # embedding and head = 4.29 GB = 4.0 GiB, + 0.42 GiB of latent cache
+    assert 4.3 * GIB < args < 4.6 * GIB, args / GIB
+    assert temps < 0.6 * GIB, temps / GIB

@@ -7,6 +7,7 @@ localhost and is held to golden-token parity with the all-local generator.
 """
 
 import threading
+import time
 
 import jax
 import numpy as np
@@ -17,6 +18,7 @@ from cake_tpu.models.config import tiny
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.parallel.topology import Topology
 from cake_tpu.runtime.master import DistributedGenerator, build_runners
+from cake_tpu.runtime.wire import WireError
 from cake_tpu.runtime.worker import Worker
 from cake_tpu.runtime.generator import LlamaGenerator
 
@@ -37,10 +39,20 @@ def _head_params(params):
 
 
 def _start_worker(name, topo, params, port=0):
-    w = Worker(
-        name, CFG, topo, _loader(params), address=f"127.0.0.1:{port}",
-        max_seq=CFG.max_seq_len,
-    )
+    # a restart on a GIVEN port waits for it: beside five other xdist
+    # workers something else on the host may hold the number for a moment
+    # (an outgoing connection's source port), and the bind then fails
+    for attempt in range(50):
+        try:
+            w = Worker(
+                name, CFG, topo, _loader(params), address=f"127.0.0.1:{port}",
+                max_seq=CFG.max_seq_len,
+            )
+            break
+        except (WireError, OSError):
+            if not port or attempt == 49:
+                raise
+            time.sleep(0.1)
     w.serve_in_background()
     return w
 
