@@ -8,10 +8,12 @@ sum, o_proj.
 
 TPU-first redesign decisions:
 
-- The cache is a fixed ``max_seq`` buffer; attention always reads the full
-  buffer and masks out positions beyond the causal frontier. This keeps every
-  decode step the same static shape (one compiled program) instead of the
-  reference's growing-concat shapes.
+- The cache is a fixed ``max_seq`` buffer, so every decode step has the
+  same static shape (one compiled program) instead of the reference's
+  growing-concat shapes. How much of the buffer a step READS is the
+  attention implementation's: the XLA path sweeps all of it and masks
+  positions beyond the causal frontier; the decode kernel fetches each
+  stream's blocks up to its frontier and nothing else.
 - GQA is computed with a grouped einsum (``[B, kv_heads, group, T, D]``)
   instead of materializing ``repeat_kv`` copies (attention.rs:84-89) — XLA
   maps the group axis onto the MXU batch dimension for free, where a
@@ -22,12 +24,12 @@ TPU-first redesign decisions:
 On TPU, :func:`attend` dispatches to the fused Pallas flash kernels
 (:mod:`cake_tpu.ops.pallas.flash`) — blockwise online softmax, causal mask in
 registers, no HBM score materialization, KV blocks past the frontier never
-fetched — at the shapes where the measured sweep says they win: prefill from
-``PREFILL_FLASH_MIN_S`` context up (tools/flash_sweep.py). Below the
-crossover, and for single-token decode, XLA's fused attention is faster and
-``auto`` picks it. The XLA path also remains the parity oracle
-(``CAKE_PALLAS=0`` forces it everywhere; ``CAKE_PALLAS=1`` forces the
-kernels everywhere).
+fetched — at the shapes where the measured sweep says they win
+(tools/flash_sweep.py): prefill from ``PREFILL_FLASH_MIN_S`` context up,
+single-token decode over a plain cache from ``DECODE_FLASH_MIN_S`` rows up.
+Below the crossovers XLA's fused attention is faster and ``auto`` picks it.
+The XLA path also remains the parity oracle (``CAKE_PALLAS=0`` forces it
+everywhere; ``CAKE_PALLAS=1`` forces the kernels everywhere).
 """
 
 from __future__ import annotations
@@ -37,6 +39,7 @@ import logging
 import jax
 import jax.numpy as jnp
 
+from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.ops import kvcache as kv
 from cake_tpu.ops import pallas as pk
 from cake_tpu.ops import quant
@@ -60,15 +63,7 @@ def _flash_ok(t: int, s: int, d: int) -> bool:
 # - prefill: flash wins from S >= 2048 (1.5x at T=512/S=2048, 2.2-2.3x at
 #   S=4096, 50x at S=8192 where XLA materializes the f32 score matrix) and
 #   loses below it (0.77x at T=512/S=1024, 0.87x at T=256/S=512).
-# - decode (T=1): XLA wins at every measured shape — 0.99x at S=512 falling
-#   to 0.82x at S=8192, and 0.72-0.90x at serving batches 8/32 — the
-#   [B, H, 1, S] score row is tiny, so XLA's fused masked gemv is already
-#   bandwidth-optimal at the frontier-near-full worst case. The one regime
-#   with a structural case for flash decode (it reads KV blocks only up to
-#   the frontier; XLA sweeps the whole buffer) is an EARLY frontier in a
-#   long window — tools/flash_sweep.py's (s, pos) decode rows measure it;
-#   until a measured win lands in KERNELS_TPU.json, auto stays XLA and
-#   CAKE_PALLAS=1 remains the only way to force the kernel.
+# - decode (T=1): see DECODE_FLASH_MIN_S below.
 PREFILL_FLASH_MIN_S = 2048
 # T floor for the flash prefill: the sweep's smallest measured chunk is
 # T=256; far below it the q-block degenerates (_pick_block of a tiny/odd T
@@ -77,6 +72,36 @@ PREFILL_FLASH_MIN_S = 2048
 # Real prefill buckets are powers of two >= 256 whenever S is in the flash
 # regime, so the floor costs nothing on the prompt path.
 PREFILL_FLASH_MIN_T = 256
+# Decode (T == 1) over a plain cache: XLA's fused masked gemv sweeps the
+# whole reserved buffer at bandwidth whatever the frontier; the kernel
+# leaves the cache in HBM and fetches, all KV heads at once, only each
+# stream's blocks of ``pk.DECODE_BLOCK_K`` rows up to its frontier,
+# straight out of the stacked cache the layer loop carries.
+# tools/flash_sweep.py --only served-decode on v5 lite (B 8, KVH 8, G 4,
+# D 128, a layer's time inside a walk over 8 layers, PR 29), kernel / XLA:
+#
+# - S 2048: 48 / 105 us at the frontiers a `decode-full` batch has (64-700),
+#   105 / 105 us with every frontier at the buffer's end (1.00x: the cost
+#   side, nothing to skip); S 4096: 43 / 200 and 196 / 197 us. In the
+#   served dense step the kernel takes 27 us a layer where XLA's two
+#   fusions took 88 (PERF.md section 5).
+# - S 1024: 44 / 63 us early, 61 / 61 at the end: still ahead. S 512 is
+#   one block, so there is nothing to skip: 46-53 us both, and XLA stays.
+# - blocks of 256 rows read a fifth fewer bytes at early frontiers (40 us)
+#   and cost 1.5x XLA at the buffer's end (157 us: a block's eight 4-row
+#   products no longer hide behind its fetch); 1024 reads 1024 rows for a
+#   300-row stream (60 us). So 512.
+# - other rows of heads (B 8, S 2048): KVH 4 x G 4, a tp=2 mesh's local
+#   heads: 46 / 63 us mixed, 64 / 63 at the end. KVH 32 x G 1 (an MHA 7B)
+#   fits VMEM only at 256 rows and pays for it on a full cache: 122 / 394
+#   mixed, 559 / 394 (1.42x) at the end, so it stays on XLA. (KVH 16 x
+#   D 256 at 256 rows: 86 / 375 and 376 / 374; not taken yet, PERF.md
+#   section 7.) One stream (B 1): 43-49 us both, at every frontier.
+#
+# The frontier is data, so a nearly full cache runs the kernel too, at
+# XLA's cost. An int8 cache stays on XLA (the dequantize fuses into its
+# dot; a kernel operand would be a written-out bf16 buffer).
+DECODE_FLASH_MIN_S = 1024
 
 
 def _flash_prefill_choice(t: int, s: int, d: int) -> str:
@@ -103,13 +128,41 @@ def _flash_prefill_choice(t: int, s: int, d: int) -> str:
     return "xla"
 
 
+def flash_decode_choice(s: int, d: int, kv_heads: int,
+                        itemsize: int = 2) -> str:
+    """``"flash"`` or ``"xla"`` for a single-token (T == 1) attention
+    over a plain ``S``-row cache of ``kv_heads`` heads of size ``d`` —
+    THE decode policy, from what a trace can see of its input (the
+    shapes; the frontier is data). :func:`attend` asks it, once for each
+    decode program traced, and publishes the answer
+    (``attn.decode_kernel``)."""
+    if not pk.kernels_enabled():
+        return "xla"
+    bk = pk.decode_block_k(s, kv_heads, d, itemsize)
+    if pk.force_kernels():
+        if pk.interpret_default() or (_flash_ok(1, s, d) and bk is not None):
+            return "flash"
+        log.warning(
+            "flash kernels forced (CAKE_PALLAS=1) but decode shape "
+            "(T=1, S=%d, D=%d, KVH=%d) is not lane-aligned (need D%%128==0 "
+            "and S%%128==0) or its KV blocks do not fit VMEM; falling back "
+            "to the XLA attention path", s, d, kv_heads,
+        )
+        return "xla"
+    # whole blocks of the measured size, which must fit VMEM: a wider row
+    # of heads would need shorter ones (the table above)
+    fits = d % 128 == 0 and bk == pk.DECODE_BLOCK_K
+    return "flash" if fits and s >= DECODE_FLASH_MIN_S else "xla"
+
+
 def attend(
     q: jax.Array,  # [B, n_heads, T, D] (already roped)
-    k_all: jax.Array,  # [B, kv_heads, S, D] (full cache buffer)
-    v_all: jax.Array,  # [B, kv_heads, S, D]
+    k_all,  # [B, kv_heads, S, D] (full cache buffer), or [L, ...] stacked
+    v_all,
     pos,  # scalar: absolute position of q[..., 0, :]
     impl: str = "auto",  # auto | xla | flash
     window: int | None = None,  # sliding-window width (Mistral); None=full
+    layer=None,  # index into a stacked [L, B, kv_heads, S, D] cache
 ) -> jax.Array:
     """Masked GQA attention over a fixed-size KV buffer. Returns [B,H,T,D].
 
@@ -117,67 +170,52 @@ def attend(
     multi-stream serving path; per-row is supported by the XLA path and the
     flash decode kernel, T>1 per-row routes to XLA).
 
+    ``k_all``/``v_all`` are plain arrays or :class:`kvcache.QuantizedKV`
+    halves; with ``layer`` they are the stacked cache the layer loop
+    carries, and this is layer ``layer`` of it. The decode kernel takes
+    the stacked buffers themselves (it fetches its blocks out of that
+    layer); every other path reads :func:`kvcache.layer_view`, a slice
+    its consumer fuses.
+
+    ``impl="auto"`` picks by what the trace can see (T, S, D, the cache's
+    kind): prefill by :func:`_flash_prefill_choice`, decode by
+    :func:`flash_decode_choice`. The int8 cache has a kernel of its own
+    for long prefill (scales folded into the score columns, int8 bytes
+    read) and dequantizes on the XLA path for everything else.
+
     ``window``: sliding-window attention — key positions more than
-    ``window`` behind the query are masked out. Both flash kernels fold
+    ``window`` behind the query are masked out. The flash kernels fold
     the window lower bound into their block sweeps (out-of-window KV
-    blocks are neither fetched nor computed). Prefill rides the kernel at
-    the measured crossover; decode under ``impl="auto"`` stays XLA until
-    a measured win lands (flash_sweep ``decode_win*`` rows), with
-    ``impl="flash"``/``CAKE_PALLAS=1`` forcing the windowed kernel.
-    Per-row prefill (T>1 with ``[B]`` pos) stays XLA — not a
-    kernel-served shape.
+    blocks are neither fetched nor computed). Per-row prefill (T>1 with
+    ``[B]`` pos) stays XLA — not a kernel-served shape.
     """
     t, d = q.shape[2], q.shape[3]
-    s = k_all.shape[2]
+    quantized = isinstance(k_all, kv.QuantizedKV)
+    data = kv._kv_data(k_all)
+    kvh, s = data.shape[-3], data.shape[-2]
     per_row = jnp.asarray(pos).ndim == 1
-    if window is not None:
-        # Windowed PREFILL rides the flash kernel at the measured
-        # crossover (the lower bound is folded into its block sweep — KV
-        # blocks outside the window are never fetched). Windowed DECODE
-        # supports the kernel too (same lower-bound skip: ~W KV bytes vs
-        # XLA's full-buffer sweep) but auto stays XLA until a measured
-        # win lands (flash_sweep decode_win4096 rows); CAKE_PALLAS=1 or
-        # impl='flash' forces it. Per-row prefill stays XLA (not a
-        # kernel-served shape, windowed or not).
-        if per_row and t > 1:
-            impl = "xla"
-        elif t == 1:
-            if impl == "auto":
-                force = pk.kernels_enabled() and pk.force_kernels()
-                ok = pk.interpret_default() or _flash_ok(t, s, d)
-                impl = "flash" if force and ok else "xla"
-        elif impl == "auto":
-            impl = _flash_prefill_choice(t, s, d)
-        if impl == "flash":
-            if t == 1:
-                return pk.flash_decode(q, k_all, v_all, pos, window=window)
-            return pk.flash_attention(q, k_all, v_all, pos, window=window)
-        return _attend_xla(q, k_all, v_all, pos, window=window)
-    if per_row and t > 1 and impl != "xla":
-        impl = "xla"  # per-row prefill: XLA only (not a served path)
-    if impl == "auto":
-        if t > 1:
-            impl = _flash_prefill_choice(t, s, d)
-        elif pk.kernels_enabled() and pk.force_kernels():
-            # decode: XLA wins at every measured shape (crossover notes
-            # above); CAKE_PALLAS=1 still forces the kernel
-            if pk.interpret_default() or _flash_ok(t, s, d):
-                impl = "flash"
-            else:
-                impl = "xla"
-                log.warning(
-                    "flash kernels forced (CAKE_PALLAS=1) but decode shape "
-                    "(T=%d, S=%d, D=%d) is not lane-aligned (need D%%128==0 "
-                    "and S%%128==0); falling back to the XLA attention path",
-                    t, s, d,
-                )
-        else:
-            impl = "xla"
+    if (per_row and t > 1) or (quantized and t == 1):
+        impl = "xla"  # not kernel-served shapes, whatever was asked
+    elif impl == "auto":
+        impl = (_flash_prefill_choice(t, s, d) if t > 1
+                else flash_decode_choice(s, d, kvh, data.dtype.itemsize))
+    if t == 1:
+        # trace time: which attention the decode program being built
+        # holds (the benchmark reads it beside the engine's block counts)
+        obs_metrics.gauge("attn.decode_kernel").set(int(impl == "flash"))
+    if impl == "flash" and t == 1:
+        return pk.flash_decode(q, k_all, v_all, pos, layer=layer,
+                               window=window)
+    k_l, v_l = kv.layer_view(k_all, layer), kv.layer_view(v_all, layer)
+    if quantized and impl == "flash":
+        return pk.flash_attention_q8(q, k_l.q, k_l.scale, v_l.q, v_l.scale,
+                                     pos, window=window)
     if impl == "flash":
-        if t == 1:
-            return pk.flash_decode(q, k_all, v_all, pos)
-        return pk.flash_attention(q, k_all, v_all, pos)
-    return _attend_xla(q, k_all, v_all, pos, window=window)
+        return pk.flash_attention(q, k_l, v_l, pos, window=window)
+    # int8 KV dequantizes at trace level, where the convert+mul fuses into
+    # the attention dot's operand read
+    return _attend_xla(q, kv.dequant_kv(k_l, q.dtype),
+                       kv.dequant_kv(v_l, q.dtype), pos, window=window)
 
 
 def _attend_xla(
@@ -409,36 +447,8 @@ def self_attention_block(
         k = apply_rope(k, cos, sin, pos)
         k_cache, v_cache = kv.update_layer(k_cache, v_cache, k, v, pos,
                                            gate=write_gate, layer=layer)
-        k_l = kv.layer_view(k_cache, layer)
-        v_l = kv.layer_view(v_cache, layer)
-        if isinstance(k_l, kv.QuantizedKV):
-            # int8 KV. Long-context prefill (the measured flash regime,
-            # S >= PREFILL_FLASH_MIN_S) routes to the quantization-aware
-            # flash kernel, which folds the per-token scales into the
-            # score columns / probabilities and reads only int8 bytes.
-            # Everything else — decode, short prefill — dequantizes at
-            # trace level on the XLA path, where the convert+mul fuses
-            # into the attention dot's operand read. (A plain-flash-kernel
-            # operand would be a materialized bf16 KV buffer in HBM,
-            # losing the bandwidth win, so plain flash is never used with
-            # the quantized cache.)
-            s_len = k_l.q.shape[2]
-            use_q8_flash = (
-                t > 1
-                and jnp.asarray(pos).ndim == 0
-                and _flash_prefill_choice(t, s_len, d) == "flash"
-            )
-            if use_q8_flash:
-                out = pk.flash_attention_q8(
-                    q, k_l.q, k_l.scale, v_l.q, v_l.scale,
-                    pos, window=window,
-                )
-            else:
-                out = attend(q, kv.dequant_kv(k_l, q.dtype),
-                             kv.dequant_kv(v_l, q.dtype), pos,
-                             impl="xla", window=window)
-        else:
-            out = attend(q, k_l, v_l, pos, window=window)  # [B,H,T,D]
+        out = attend(q, k_cache, v_cache, pos, window=window,
+                     layer=layer)  # [B,H,T,D]
 
     out = out.transpose(0, 2, 1, 3).reshape(b, t, num_heads * d)
     out = quant.dense(out, wo)

@@ -49,6 +49,10 @@ def interpret_default() -> bool:
 
 
 from cake_tpu.ops.pallas.flash import (  # noqa: E402
+    DECODE_BLOCK_K,
+    decode_block_k,
+    decode_block_range,
+    decode_blocks_read,
     flash_attention,
     flash_attention_q8,
     flash_decode,
@@ -62,6 +66,10 @@ __all__ = [
     "kernels_enabled",
     "interpret_default",
     "on_tpu",
+    "DECODE_BLOCK_K",
+    "decode_block_k",
+    "decode_block_range",
+    "decode_blocks_read",
     "flash_attention",
     "flash_attention_q8",
     "flash_decode",

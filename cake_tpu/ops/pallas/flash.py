@@ -16,8 +16,12 @@ Two kernels share the math:
   kv-block) with f32 running max / sum / accumulator scratch.
 - :func:`flash_decode` — decode (T == 1): the GQA head group is folded into
   the q-row axis (``[B, KVH, group, D]``) so the MXU sees a [group, D] x
-  [D, BK] matmul per step; grid over (batch, kv-head, kv-block). Only KV
-  blocks at or before the frontier ``pos`` are read.
+  [D, BK] matmul a head. One invocation leaves the cache in HBM (one
+  layer's ``[B, KVH, S, D]`` or the stacked ``[L, B, KVH, S, D]`` the
+  layer loop carries, the layer a scalar operand) and walks each stream's
+  live KV blocks, all KV heads of a block at once, with its own
+  double-buffered DMA: only blocks at or before a stream's frontier
+  ``pos`` (and inside its window) are read.
 
 Numerics match :func:`cake_tpu.ops.attention.attend`: f32 scores and
 accumulation regardless of model dtype (attention.rs:62-77), probabilities
@@ -31,6 +35,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -52,8 +57,8 @@ def _kv_block_bounds(pos, qb, block_q: int, block_k: int,
     frontier ``pos`` — THE one definition of the causal upper bound and
     the sliding-window lower bound, shared by the kernels' live-range
     gates and the BlockSpec index maps so fetch clamp and compute mask
-    can never desynchronize. ``qb``/``block_q`` of (0, 1) express the
-    decode case (a single query row at ``pos``)."""
+    can never desynchronize. (Decode's single row has its own,
+    :func:`decode_block_range`, which the host counts with too.)"""
     max_kb = jax.lax.div(pos + (qb + 1) * block_q - 1, block_k)
     if window is None:
         return 0, max_kb
@@ -409,90 +414,194 @@ def flash_attention_q8(
 # Decode kernel (T == 1)
 # ---------------------------------------------------------------------------
 
+# Rows of a KV block the decode kernel fetches at once (all KV heads of a
+# stream). From the v5e sweep of tools/flash_sweep.py at the served shapes
+# (the table is beside ops.attention.DECODE_FLASH_MIN_S).
+DECODE_BLOCK_K = 512
+# What the kernel's K and V blocks may take of VMEM, double-buffered:
+# 4 x KVH x rows x D x itemsize (q, o and the accumulators are small beside
+# them: B 128 compiles at KVH 8). v5e's compiler gives a kernel 16 MiB: 8
+# compile (KVH 32 / D 128 and KVH 16 / D 256 at 256 rows, test_chip_compile)
+# and 16 do not.
+DECODE_KV_VMEM = 8 << 20
+
+
+def decode_block_k(s: int, kv_heads: int, d: int, itemsize: int,
+                   block_k: int = DECODE_BLOCK_K) -> int | None:
+    """Rows of the KV block :func:`flash_decode` fetches at these shapes:
+    the largest power of two up to ``block_k`` that divides ``s``, halved
+    (down to 128 rows) until the blocks fit ``DECODE_KV_VMEM``; ``None``
+    where not even those fit, and the kernel cannot be built."""
+    bk = _pick_block(s, block_k)
+
+    def need(rows):
+        return 4 * kv_heads * rows * d * itemsize
+
+    while bk > 128 and need(bk) > DECODE_KV_VMEM:
+        bk //= 2
+    return bk if need(bk) <= DECODE_KV_VMEM else None
+
+
+def decode_block_range(pos, block_k: int, num_kv_blocks: int,
+                       window: int | None, xp=jnp):
+    """(lo, hi), inclusive: the KV blocks of ``block_k`` rows that a
+    single-token attention at frontier ``pos`` reads of a buffer of
+    ``num_kv_blocks`` — from the sliding window's lower bound up to the
+    frontier, inside the buffer (a frontier may have left it) and never
+    empty. THE one definition: the kernel walks it (``pos`` a traced
+    scalar) and the engine's ``attn.kv_blocks_*`` counters sum it
+    (``xp=np``, ``pos`` the host's array), so what is counted is what is
+    fetched."""
+    hi = xp.minimum(pos // block_k, num_kv_blocks - 1)
+    if window is None:
+        return 0 * hi, hi
+    return xp.minimum(xp.maximum(pos - window + 1, 0) // block_k, hi), hi
+
+
+def decode_blocks_read(pos, steps: int, s: int, block_k: int = DECODE_BLOCK_K,
+                       window: int | None = None) -> tuple[int, int]:
+    """(read, reserved): the KV blocks of ``block_k`` rows that
+    ``steps`` decode steps from the host-side frontiers ``pos [B]`` make
+    :func:`flash_decode` fetch of one layer (:func:`decode_block_range`
+    of every stream at every step), and the blocks the ``[B, S]``
+    reservation holds for those steps."""
+    nk = max(1, s // block_k)
+    at = np.asarray(pos, np.int64)[:, None] + np.arange(steps)  # [B, steps]
+    lo, hi = decode_block_range(at, block_k, nk, window, xp=np)
+    return int((hi - lo + 1).sum()), at.size * nk
+
 
 def _decode_kernel(
     pos_ref,  # [B] int32 (per-row causal frontier; row b reads pos_ref[b])
-    q_ref,  # [1, 1, G, D]
-    k_ref,  # [1, 1, BK, D]
-    v_ref,  # [1, 1, BK, D]
-    o_ref,  # [1, 1, G, D]
-    acc_ref,  # VMEM [G, D] f32
-    m_ref,  # VMEM [G, LANES] f32
-    l_ref,  # VMEM [G, LANES] f32
-    *,
+    *refs,  # [layer_ref ([1] int32) when ``stacked``,] then:
+    # q_ref [B, KVH, G, D] (VMEM), k_hbm / v_hbm: the whole cache, left
+    # where it is, o_ref [B, KVH, G, D], kbuf / vbuf VMEM [2, KVH, BK, D],
+    # sem DMA [2, 2], acc_ref VMEM [KVH, G, D] f32, m_ref / l_ref VMEM
+    # [KVH, G, LANES] f32
+    stacked: bool,
+    batch: int,
+    kv_heads: int,
     group: int,
     block_k: int,
     scale: float,
     num_kv_blocks: int,
     window: int | None = None,
 ):
-    kb = pl.program_id(2)
-    pos = pos_ref[pl.program_id(0)]
+    """One invocation walks every stream's LIVE KV blocks in turn, (row 0:
+    lo..hi), (row 1: lo..hi), ...: the block after the one being computed
+    is already on its way into the other buffer, across the change of
+    row too, so no fetch waits on a skipped grid step and none is paid
+    for."""
+    lead = ()
+    if stacked:
+        layer_ref, *refs = refs
+        lead = (layer_ref[0],)
+    q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc_ref, m_ref, l_ref = refs
 
-    @pl.when(kb == 0)
-    def _init():
-        m_ref[:] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
-        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+    def bounds(b):
+        return decode_block_range(pos_ref[b], block_k, num_kv_blocks, window)
 
-    # sliding window: this row attends keys in (pos-window, pos] only —
-    # at long S the block sweep is window-proportional where the XLA
-    # path sweeps and masks the whole buffer
-    min_kb, max_kb = _kv_block_bounds(pos, 0, 1, block_k, window)
-    live = (kb >= min_kb) & (kb <= max_kb)
+    def copies(b, kb, slot):
+        rows = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
+        at = lead + (b, slice(None), rows, slice(None))
+        return (pltpu.make_async_copy(k_hbm.at[at], kbuf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[at], vbuf.at[slot],
+                                      sem.at[1, slot]))
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0]  # [G, D]
-        k = k_ref[0, 0]  # [BK, D]
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        s = s * scale  # [G, BK]
+    def step(carry):
+        b, kb, slot = carry
+        pos = pos_ref[b]
+        lo, hi = bounds(b)
+        last = kb == hi
+        b_next = jnp.where(last, b + 1, b)
+        lo_next, _ = bounds(jnp.minimum(b_next, batch - 1))
+        kb_next = jnp.where(last, lo_next, kb + 1)
+
+        @pl.when(b_next < batch)
+        def _prefetch():
+            for c in copies(b_next, kb_next, 1 - slot):
+                c.start()
+
+        @pl.when(kb == lo)
+        def _init():
+            m_ref[:] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+            l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        for c in copies(b, kb, slot):
+            c.wait()
         kpos = kb * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (group, block_k), 1
         )
         mask = kpos <= pos
         if window is not None:
+            # sliding window: this row attends keys in (pos-window, pos]
             mask &= kpos > pos - window
-        s = jnp.where(mask, s, NEG_INF)
+        # one [G, D] x [D, BK] product a KV head (unrolled: the heads are
+        # independent, so the scheduler overlaps them)
+        for h in range(kv_heads):
+            q = q_ref[b, h]  # [G, D]
+            k = kbuf[slot, h]  # [BK, D]
+            v = vbuf[slot, h]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            s = jnp.where(mask, s * scale, NEG_INF)  # [G, BK]
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, :1])
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[h] = m_new
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            acc_ref[h] = acc_ref[h] * alpha[:, :1] + pv
 
-        m_prev = m_ref[:]
-        l_prev = l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
-        l_ref[:] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[:] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[:] = acc_ref[:] * alpha[:, :1] + pv
+        @pl.when(last)
+        def _finish():
+            o_ref[b] = (acc_ref[:] / l_ref[:, :, :1]).astype(o_ref.dtype)
 
-    @pl.when(kb == num_kv_blocks - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
+        return b_next, kb_next, 1 - slot
+
+    lo0, _ = bounds(0)
+    for c in copies(0, lo0, 0):
+        c.start()
+    jax.lax.while_loop(lambda c: c[0] < batch, step,
+                       (jnp.int32(0), lo0, jnp.int32(0)))
 
 
 def flash_decode(
     q: jax.Array,  # [B, H, 1, D] (already roped)
-    k_all: jax.Array,  # [B, KVH, S, D]
+    k_all: jax.Array,  # [B, KVH, S, D], or stacked [L, B, KVH, S, D]
     v_all: jax.Array,
-    pos,  # scalar int
+    pos,  # scalar int or [B]
     *,
-    block_k: int = 512,
+    layer=None,  # index into the stacked form's leading axis
+    block_k: int = DECODE_BLOCK_K,
     window: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Single-position flash attention. Returns [B, H, 1, D].
 
-    The GQA group is folded into q rows so each (batch, kv-head) grid cell is
-    one [group, D] x [D, BK] matmul; KV blocks past ``pos`` are neither read
-    nor computed. ``pos`` may be scalar (shared frontier) or ``[B]``
-    (per-row frontiers — multi-stream serving): it is broadcast to a [B]
-    prefetch and each batch grid row clamps its own KV fetch window.
+    The cache stays in HBM and the kernel fetches, for each stream, only
+    the KV blocks ``[KVH, BK, D]`` (all KV heads at once) from its
+    window's lower bound up to its frontier, double-buffered, the next
+    block in flight while this one is computed: a stream whose cache is
+    a seventh full costs a seventh of the sweep, and rows nobody wrote
+    are neither read nor computed. The GQA group is folded into q rows,
+    so a head is one [group, D] x [D, BK] matmul. ``pos`` may be scalar
+    (shared frontier) or ``[B]`` (per-row frontiers — multi-stream
+    serving).
+
+    ``layer``: ``k_all``/``v_all`` are the stacked ``[L, B, KVH, S, D]``
+    cache the layer loop carries and ``layer`` (traced) picks the layer
+    the blocks are fetched from, as a second scalar-prefetch operand: the
+    kernel reads straight out of the carried buffers and no layer's slab
+    is written out for it.
 
     ``window``: sliding-window attention — blocks below the window's lower
     bound are likewise neither fetched nor computed, so a W-window decode
@@ -500,56 +609,61 @@ def flash_decode(
     """
     b, h, t, d = q.shape
     assert t == 1, "flash_decode requires T == 1"
-    kvh, s = k_all.shape[1], k_all.shape[2]
+    stacked = layer is not None
+    assert k_all.ndim == (5 if stacked else 4), (k_all.shape, layer)
+    kvh, s = k_all.shape[-3], k_all.shape[-2]
     group = h // kvh
-    bk = _pick_block(s, block_k)
+    bk = decode_block_k(s, kvh, d, k_all.dtype.itemsize, block_k)
+    assert bk is not None, ("no KV block fits the kernel's VMEM", kvh, d)
     nk = s // bk
     if interpret is None:
         from cake_tpu.ops.pallas import interpret_default
 
         interpret = interpret_default()
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-    scale = 1.0 / math.sqrt(d)
+    prefetch = [pos_arr]
+    if stacked:
+        prefetch.append(jnp.asarray(layer, jnp.int32).reshape(1))
     qg = q.reshape(b, kvh, group, d)
 
-    def q_map(bi, khi, kb, pos_ref):
-        return (bi, khi, 0, 0)
-
-    def kv_map(bi, khi, kb, pos_ref):
-        min_kb, max_kb = _kv_block_bounds(pos_ref[bi], 0, 1, bk, window)
-        return (bi, khi, jnp.clip(kb, min_kb, max_kb), 0)
+    def whole(i, *prefetched):
+        return (0, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, kvh, nk),
+        num_scalar_prefetch=len(prefetch),
+        grid=(1,),
         in_specs=[
-            pl.BlockSpec((1, 1, group, d), q_map),
-            pl.BlockSpec((1, 1, bk, d), kv_map),
-            pl.BlockSpec((1, 1, bk, d), kv_map),
+            pl.BlockSpec((b, kvh, group, d), whole),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, d), q_map),
+        out_specs=pl.BlockSpec((b, kvh, group, d), whole),
         scratch_shapes=[
-            pltpu.VMEM((group, d), jnp.float32),
-            pltpu.VMEM((group, _LANES), jnp.float32),
-            pltpu.VMEM((group, _LANES), jnp.float32),
+            pltpu.VMEM((2, kvh, bk, d), k_all.dtype),
+            pltpu.VMEM((2, kvh, bk, d), v_all.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((kvh, group, d), jnp.float32),
+            pltpu.VMEM((kvh, group, _LANES), jnp.float32),
+            pltpu.VMEM((kvh, group, _LANES), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _decode_kernel, group=group, block_k=bk, scale=scale,
-        num_kv_blocks=nk, window=window,
+        _decode_kernel, stacked=stacked, batch=b, kv_heads=kvh, group=group,
+        block_k=bk, scale=1.0 / math.sqrt(d), num_kv_blocks=nk, window=window,
     )
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, kvh, group, d), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * h * s * d,
-            bytes_accessed=2 * k_all.size * k_all.dtype.itemsize,
+            bytes_accessed=2 * b * kvh * s * d * k_all.dtype.itemsize,
             transcendentals=b * h * s,
         ),
+        name="flash_decode",
         interpret=interpret,
-    )(pos_arr, qg, k_all, v_all)
+    )(*prefetch, qg, k_all, v_all)
     return out.reshape(b, h, 1, d)

@@ -87,6 +87,7 @@ from cake_tpu.obs import flight as obs_flight
 from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.obs import prof as obs_prof
 from cake_tpu.obs.trace import span
+from cake_tpu.ops import pallas as pk
 from cake_tpu.ops import quant, sampling
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.parallel.mesh import (
@@ -133,6 +134,8 @@ _IMPORT_ABORTS = obs_metrics.counter("disagg.import_aborts")
 _MOE_LOCAL = obs_metrics.counter("moe.local_pairs")
 _MOE_ROUTED = obs_metrics.counter("moe.routed_pairs")
 _MOE_STEPS = obs_metrics.counter("moe.decode_steps")
+_KV_BLOCKS_READ = obs_metrics.counter("attn.kv_blocks_read")
+_KV_BLOCKS_RESERVED = obs_metrics.counter("attn.kv_blocks_reserved")
 
 # arrival-queue entry kinds (4th tuple field): None marks a plain prompt
 # arrival; imports ride the SAME FIFO so pool-pressure deferral stays
@@ -2714,9 +2717,10 @@ class BatchGenerator:
         with self._prof.phase("dispatch", steps=size,
                               batch=len(self.streams)), \
                 self._sentinel.decode_phase():
+            pos = self._decode_pos()
             out = self._block_prog(size)(
                 self.params, self._last_tokens, self.cache,
-                jnp.asarray(self._pos), self._keys, self._history,
+                jnp.asarray(pos), self._keys, self._history,
                 self._hist_slot, jnp.asarray(self._index),
                 *self._paged_args(size),
             )
@@ -2728,10 +2732,31 @@ class BatchGenerator:
                 toks, self.cache, self._history, self._hist_slot = out
                 lpv = lpi = None
         self._n_decode_dispatches += 1
+        self._count_kv_blocks(pos, size)
         self._pos = self._pos + size
         self._index = self._index + size
         self._last_tokens = toks[-1].astype(jnp.int32)
         return toks, lpv, lpi
+
+    def _decode_pos(self) -> np.ndarray:
+        """The frontiers a decode dispatch goes out with: a live stream's
+        own, and row 0 for a slot without one (retired, or never filled).
+        Such a slot's row goes through the program all the same; its
+        output is discarded and its cache row is overwritten by the next
+        admission, so where it writes is free, and at row 0 its attention
+        reads one KV block instead of following a frontier that nobody
+        stops."""
+        live = [s.active and not s.done for s in self.streams]
+        return np.where(live, self._pos, 0).astype(np.int32)
+
+    def _count_kv_blocks(self, pos: np.ndarray, steps: int) -> None:
+        """Add what ``steps`` decode steps from the frontiers ``pos`` (as
+        dispatched) read of a layer's cache, in the decode kernel's
+        blocks, and what is reserved (``attn.kv_blocks_*``)."""
+        read, reserved = pk.decode_blocks_read(
+            pos, steps, self.max_seq, window=self.config.sliding_window)
+        _KV_BLOCKS_READ.inc(read)
+        _KV_BLOCKS_RESERVED.inc(reserved)
 
     def _take_moe_count(self, out: tuple, steps: int) -> tuple:
         """Strip the trailing per-row routed-pair counts off a decode
@@ -2843,9 +2868,10 @@ class BatchGenerator:
         if int(max(live)) >= self.max_seq:  # unreachable: _emit marks
             raise RuntimeError("KV cache exhausted")  # window-full streams done
         t0 = time.perf_counter()
+        pos = self._decode_pos()
         args = (
             self.params, self._last_tokens, self.cache,
-            jnp.asarray(self._pos), self._keys, self._history,
+            jnp.asarray(pos), self._keys, self._history,
             self._hist_slot, jnp.asarray(self._index),
         )
         with self._prof.phase("dispatch", steps=1,
@@ -2876,6 +2902,7 @@ class BatchGenerator:
                     if lpv_d is not None else None)
             self._record_moe_count()
         self._n_decode_dispatches += 1
+        self._count_kv_blocks(pos, 1)
         dt = time.perf_counter() - t0
         self._busy_s += dt
         self._dispatch_hist.observe(dt * 1e3)

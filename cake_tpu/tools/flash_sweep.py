@@ -7,11 +7,16 @@ crossover used by :func:`cake_tpu.ops.attention.attend`'s ``impl="auto"``
 dispatch — the same measured-crossover treatment ``quant_matmul`` got for its
 M>=16 gate (`ops/quant.py`).
 
-Usage:  python -m cake_tpu.tools.flash_sweep [--json-out PATH]
+Usage:  python -m cake_tpu.tools.flash_sweep [--json-out PATH] [--only served-decode]
 (``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
 Prints one JSON line per shape:
   {"path": "prefill"|"decode", "t", "s", "pallas_ms", "xla_ms", "speedup"}
+
+``--only served-decode`` runs just :func:`served_decode_rows`: the decode
+kernel against XLA on the stacked cache the layer loop carries, at the
+served shapes and frontiers (what decides ``DECODE_FLASH_MIN_S`` and
+``DECODE_BLOCK_K``; its table is kept in PERF.md).
 """
 
 from __future__ import annotations
@@ -29,15 +34,119 @@ from cake_tpu.tools.kernel_check import _time_ms, refuse_offchip_record
 
 def _audit(rec: dict) -> dict:
     """Annotate a sweep record with what ``impl='auto'`` dispatches at this
-    shape and the resulting speedup over always-XLA (>= 1.0 everywhere is
-    the dispatch-policy contract)."""
-    from cake_tpu.ops.attention import PREFILL_FLASH_MIN_S
+    shape (the policy functions themselves, at the sweep's D = 128) and
+    the resulting speedup over always-XLA (>= 1.0 everywhere is the
+    dispatch-policy contract)."""
+    from cake_tpu.ops.attention import (_flash_prefill_choice,
+                                        flash_decode_choice)
 
-    auto = ("flash" if rec["path"] == "prefill"
-            and rec["s"] >= PREFILL_FLASH_MIN_S else "xla")
+    auto = (_flash_prefill_choice(rec["t"], rec["s"], 128)
+            if rec["path"].startswith("prefill")
+            else flash_decode_choice(rec["s"], 128, kv_heads=8))
     rec["auto_impl"] = auto
     rec["auto_speedup"] = rec["speedup"] if auto == "flash" else 1.0
     return rec
+
+
+def _served_frontiers(batch: int, seed: int = 5):
+    """Per-row frontiers as the ``decode-full`` mix leaves them: a prompt
+    log-uniform in 64-512 and a uniform share of an answer of 64-192."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    prompt = np.exp(rng.uniform(np.log(64), np.log(512), batch))
+    answer = rng.uniform(64, 192, batch) * rng.uniform(0, 1, batch)
+    return (prompt + answer).astype(np.int32)
+
+
+# (batch, rows, q heads, KV heads, head size) of served_decode_rows: the
+# dense and sparse cells' (8 slots x 2048 and x 4096), shorter windows
+# (where the floor is), a batch of 32, one stream (the single-stream
+# generators' scalar frontier), the local heads of a tp=2 mesh, and the
+# rows of heads whose 512-row blocks overflow VMEM (an MHA 7B's 32 x 128,
+# Gemma-7B's 16 x 256: the kernel shrinks its blocks, auto leaves them on
+# XLA)
+SERVED_DECODE_SHAPES = (
+    (8, 2048, 32, 8, 128), (8, 4096, 32, 8, 128), (8, 1024, 32, 8, 128),
+    (8, 512, 32, 8, 128), (32, 2048, 32, 8, 128),
+    (1, 2048, 32, 8, 128), (1, 4096, 32, 8, 128),
+    (8, 2048, 16, 4, 128),
+    (8, 2048, 32, 32, 128), (8, 2048, 16, 16, 256),
+)
+
+
+def served_decode_rows(results: list, blocks=(128, 256, 512, 1024),
+                       layers: int = 8) -> None:
+    """Decode (T == 1) on the STACKED cache ``[L, B, KVH, S, D]`` as a
+    decode step meets it: one pass over ``layers`` layers, each attending
+    its own slice with the layer index traced (so no layer's keys stay
+    in fast memory between calls, which a loop over ONE layer's buffer
+    allows XLA and which no model does); times are a layer's. The kernel
+    at each block size that fits its VMEM (128 rows only where 512 do
+    not) against XLA's masked sweep of the layer's slice, at
+    ``SERVED_DECODE_SHAPES``, over frontiers early (64, 300, 704), mixed
+    as ``decode-full`` draws them, and at the buffer's end (the cost
+    side: nothing to skip)."""
+    import time
+
+    from cake_tpu.ops import kvcache as kv
+    from cake_tpu.ops.attention import _attend_xla, flash_decode_choice
+    from cake_tpu.ops.pallas import (DECODE_BLOCK_K, decode_block_k,
+                                     flash_decode, interpret_default)
+    from cake_tpu.tools.kernel_check import _sync
+
+    compiled = not interpret_default()
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+
+    def xla(q, k, v, pos, layer):
+        return _attend_xla(q, kv.layer_view(k, layer),
+                           kv.layer_view(v, layer), pos)
+
+    def kernel(bk):
+        def run(q, k, v, pos, layer):
+            return flash_decode(q, k, v, pos, layer=layer, block_k=bk,
+                                interpret=not compiled)
+        return run
+
+    def layer_ms(fn, q, k, v, pos, iters=10) -> float:
+        @jax.jit
+        def step(q, k, v, pos):
+            def body(q, layer):
+                out = fn(q, k, v, pos, layer)
+                return q + (out * 1e-30).astype(q.dtype), None
+
+            return jax.lax.scan(body, q,
+                                jnp.arange(layers, dtype=jnp.int32))[0]
+
+        _sync(step(q, k, v, pos))  # compile
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            q = step(q, k, v, pos)
+        _sync(q)
+        return (time.perf_counter() - t0) / (iters * layers) * 1e3
+
+    for b, s, h, kvh, d in SERVED_DECODE_SHAPES:
+        k = jax.random.normal(ks[0], (layers, b, kvh, s, d), jnp.bfloat16)
+        v = jax.random.normal(ks[1], (layers, b, kvh, s, d), jnp.bfloat16)
+        q = jax.random.normal(ks[2], (b, h, 1, d), jnp.bfloat16)
+        fit = decode_block_k(s, kvh, d, 2)
+        frontiers = {"64": 64, "300": 300, "704": 704, "end": s - 1,
+                     "mixed": _served_frontiers(b)}
+        for name, at in frontiers.items():
+            pos = jnp.minimum(jnp.broadcast_to(jnp.asarray(at, jnp.int32),
+                                               (b,)), s - 1)
+            rec = {"path": "decode_stacked", "batch": b, "s": s,
+                   "heads": h, "kv_heads": kvh, "d": d,
+                   "layers": layers, "frontier": name,
+                   "auto_impl": flash_decode_choice(s, d, kvh),
+                   "xla_ms": round(layer_ms(xla, q, k, v, pos), 4)}
+            for bk in blocks:
+                if (decode_block_k(s, kvh, d, 2, bk) == bk
+                        and (bk >= 256 or fit < DECODE_BLOCK_K)):
+                    ms = layer_ms(kernel(bk), q, k, v, pos)
+                    rec[f"pallas_bk{bk}_ms"] = round(ms, 4)
+            results.append(rec)
+            print(json.dumps(rec), flush=True)
 
 
 def sweep(json_out: str | None = None) -> list:
@@ -67,14 +176,13 @@ def sweep(json_out: str | None = None) -> list:
     fd_pal = jax.jit(partial(flash_decode, interpret=not compiled))
     f_xla = jax.jit(_attend_xla)
 
-    # Decode: T=1 against a KV buffer of S. Frontier-near-the-end rows are
-    # the worst case (XLA must sweep ~everything either way); the EARLY-
-    # frontier rows in a long window are the one regime where flash decode
-    # has a structural edge — it reads KV blocks only up to the frontier
-    # while XLA's fused gemv sweeps the whole buffer. The early rows are
-    # the measurement `ops/attention.py` used to claim without evidence;
-    # they decide whether `auto` gets a frontier-aware dispatch or the
-    # claim dies.
+    # Decode: T=1 against ONE layer's KV buffer of S, one stream.
+    # Frontier-near-the-end rows are the worst case (both read nearly
+    # everything); the early-frontier rows in a long window are where the
+    # kernel reads KV blocks only up to the frontier while XLA's fused
+    # gemv sweeps the whole buffer. (A loop over one layer's buffer lets
+    # XLA keep a small one in fast memory between calls: the served
+    # shapes are measured over a walk of layers, served_decode_rows.)
     for s, p in ((512, 488), (1024, 1000), (2048, 2024), (4096, 4072),
                  (8192, 8168),  # late frontier (s - 24)
                  (4096, 512), (8192, 512), (8192, 2048), (16384, 1024)):
@@ -89,6 +197,8 @@ def sweep(json_out: str | None = None) -> list:
                       "speedup": round(x_ms / p_ms, 3)})
         results.append(rec)
         print(json.dumps(rec), flush=True)
+
+    served_decode_rows(results)
 
     # Batched (serving) decode: per-row frontiers, the BatchGenerator shape
     for bb, s in ((8, 1024), (8, 4096), (32, 1024), (32, 4096)):
@@ -127,8 +237,7 @@ def sweep(json_out: str | None = None) -> list:
 
     # Windowed decode (Mistral sliding window): the kernel reads ~W of KV
     # bytes where XLA sweeps+masks the whole buffer — the structural case
-    # grows with S/W. auto currently stays XLA (measured-crossover rule);
-    # a winning row here is what flips it.
+    # grows with S/W.
     @jax.jit
     def fd_pal_w(q, kk_, vv_, pos):
         return flash_decode(q, kk_, vv_, pos, window=4096,
@@ -145,10 +254,9 @@ def sweep(json_out: str | None = None) -> list:
         pos = jnp.int32(s - 8)
         p_ms = _time_ms(fd_pal_w, q1, kv_k, kv_v, pos)
         x_ms = _time_ms(fd_xla_w, q1, kv_k, kv_v, pos)
-        rec = {"path": "decode_win4096", "t": 1, "s": s,
-               "pallas_ms": round(p_ms, 4), "xla_ms": round(x_ms, 4),
-               "speedup": round(x_ms / p_ms, 3),
-               "auto_impl": "xla", "auto_speedup": 1.0}
+        rec = _audit({"path": "decode_win4096", "t": 1, "s": s,
+                      "pallas_ms": round(p_ms, 4), "xla_ms": round(x_ms, 4),
+                      "speedup": round(x_ms / p_ms, 3)})
         results.append(rec)
         print(json.dumps(rec), flush=True)
 
@@ -225,9 +333,18 @@ def main() -> int:
     configure()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json-out", default=None)
+    ap.add_argument("--only", choices=["served-decode"], default=None,
+                    help="run one section instead of the whole sweep")
     args = ap.parse_args()
     refuse_offchip_record(args.json_out)
-    sweep(args.json_out)
+    if args.only == "served-decode":
+        rows: list = []
+        served_decode_rows(rows)
+        if args.json_out:
+            with open(args.json_out, "w") as f:
+                json.dump(rows, f, indent=1)
+    else:
+        sweep(args.json_out)
     return 0
 
 

@@ -1150,3 +1150,90 @@ def test_slot_not_reclaimed_while_its_rows_are_undelivered(params):
     for sid in (0, 7, 8):
         assert got[sid] == seen[sid].generated[: len(got[sid])], sid
         assert len(got[sid]) >= min(6, len(seen[sid].generated))
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+def test_decode_kernel_in_the_engine_and_its_counters(params, tp,
+                                                      monkeypatch):
+    """``CAKE_PALLAS=1``: the engine's decode programs attend through the
+    decode kernel, reading the carried stacked cache under per-row
+    frontiers (interpreted here; under ``tp`` with the local head counts
+    inside ``shard_map``), and give the streams XLA gives. Every decode
+    dispatch adds the KV blocks its steps read up to each slot's frontier
+    and the blocks reserved (``attn.kv_blocks_*``); the gauge
+    ``attn.decode_kernel`` says which attention the programs hold, from
+    where it was chosen (``ops.attention.attend``, as they were traced)."""
+    from cake_tpu.obs import metrics
+    from cake_tpu.ops.pallas import DECODE_BLOCK_K
+
+    settings = SamplerSettings(**GREEDY)
+    reg = metrics.registry()
+    names = ("attn.kv_blocks_read", "attn.kv_blocks_reserved")
+
+    def run(mode):
+        monkeypatch.setenv("CAKE_PALLAS", mode)
+        before = [reg.counter(n).value for n in names]
+        reg.gauge("attn.decode_kernel").set(-1)  # the engine sets nothing
+        g = BatchGenerator(CFG, params, settings=settings, tp=tp,
+                           block_size=4)
+        g.set_prompts(PROMPTS)
+        out = g.generate(9)
+        g.drain()
+        return (out, reg.gauge("attn.decode_kernel").value,
+                [reg.counter(n).value - b for n, b in zip(names, before)],
+                g.stats()["decode_dispatches"])
+
+    want, gauge, _, _ = run("0")
+    assert gauge == 0
+    got, gauge, (read, reserved), dispatches = run("1")
+    assert got == want and gauge == 1
+    # a 64-row window is one block of DECODE_BLOCK_K rows: every slot
+    # reads the one block it has, every step
+    assert CFG.max_seq_len <= DECODE_BLOCK_K
+    assert read == reserved > 0
+    assert reserved % (len(PROMPTS) * 4) == 0 and dispatches >= 2
+
+
+def test_a_dead_slot_decodes_at_row_zero(params, monkeypatch):
+    """A slot without a live stream (retired here by ``finish``) still
+    goes through every decode program, but at frontier 0, not at a
+    frontier that keeps advancing: its attention reads one KV block, and
+    the ``attn.kv_blocks_*`` counters are fed the frontiers as
+    dispatched. Its writes at rows 0.. touch only its own cache row: the
+    neighbour streams decode as if nothing had happened, and a stream
+    admitted into the slot afterwards as if it were alone."""
+    from cake_tpu.ops import pallas as pk
+
+    settings = SamplerSettings(**GREEDY)
+    counted = []
+    real = pk.decode_blocks_read
+    monkeypatch.setattr(
+        pk, "decode_blocks_read",
+        lambda pos, steps, s, **kw: (counted.append(list(pos)),
+                                     real(pos, steps, s, **kw))[1])
+    g = BatchGenerator(CFG, params, settings=settings, block_size=4)
+    g.set_prompts(PROMPTS)
+    got = {i: [] for i in range(len(PROMPTS))}
+
+    def steps(n):
+        for _ in range(n):
+            for slot, tok in enumerate(g.step()):
+                if tok is not None:
+                    got.setdefault(g.streams[slot].stream_id, []).append(
+                        tok.id)
+
+    steps(2)
+    assert g.finish(1) is True
+    g.drain()
+    counted.clear()
+    steps(8)
+    # dispatched: the live streams' own frontiers, the dead slot's pinned
+    assert counted and all(pos[1] == 0 for pos in counted)
+    assert all(pos[0] > len(PROMPTS[0]) and pos[2] > len(PROMPTS[2])
+               for pos in counted)
+    assert list(g._decode_pos()) == [int(g._pos[0]), 0, int(g._pos[2])]
+    g.enqueue([2, 8, 1], stream_id=5)
+    steps(10)
+    for sid, prompt in ((0, PROMPTS[0]), (2, PROMPTS[2]), (5, [2, 8, 1])):
+        want = _single_stream(params, prompt, len(got[sid]), settings)
+        assert len(got[sid]) >= 6 and got[sid] == want, sid

@@ -65,6 +65,17 @@ def _decode(b, s, window):
              ((b, KVH, s, D), BF16), ((b,), I32)])
 
 
+def _decode_stacked(layers, b, s, window, h=H, kvh=KVH, d=D):
+    """The decode kernel as the layer loop calls it: the stacked cache and
+    a traced layer index."""
+    def fn(q, k, v, pos, layer):
+        return flash_decode(q, k, v, pos, layer=layer, window=window,
+                            interpret=False)
+
+    return (fn, [((b, h, 1, d), BF16), ((layers, b, kvh, s, d), BF16),
+                 ((layers, b, kvh, s, d), BF16), ((b,), I32), ((), I32)])
+
+
 def _qmm(m, k, n):
     return (partial(quant_matmul_pallas, interpret=False),
             [((m, k), BF16), ((k, n), I8), ((n,), F32)])
@@ -85,6 +96,21 @@ KERNELS = {
     # the acceptance case: --kv-quant int8, a 512-token chunk, 4096 window
     "flash_q8_win4096_t512_s4096": _flash_q8(512, 4096, 4096),
     "flash_decode_b8_s4096_per_row": _decode(8, 4096, 4096),
+    # the served shapes: 8 slots x 2048 under Mistral's window, x 4096
+    "flash_decode_stacked_b8_s2048_win4096": _decode_stacked(2, 8, 2048, 4096),
+    "flash_decode_stacked_b8_s4096": _decode_stacked(2, 8, 4096, None),
+    # a batch of 32, and one stream
+    "flash_decode_stacked_b32_s2048": _decode_stacked(2, 32, 2048, None),
+    "flash_decode_stacked_b1_s4096": _decode_stacked(2, 1, 4096, None),
+    # wider rows of heads (an MHA 7B's 32 x 128, Gemma-7B's 16 x 256), as
+    # CAKE_PALLAS=1 forces them (auto leaves them on XLA): the KV blocks
+    # shrink to 256 rows to fit VMEM (pk.decode_block_k)
+    "flash_decode_stacked_b8_s2048_kvh32": _decode_stacked(
+        2, 8, 2048, None, h=32, kvh=32),
+    "flash_decode_stacked_b1_s4096_kvh32": _decode_stacked(
+        2, 1, 4096, None, h=32, kvh=32),
+    "flash_decode_stacked_b8_s2048_kvh16_d256": _decode_stacked(
+        2, 8, 2048, None, h=16, kvh=16, d=256),
     "qmm_m64_4096x14336": _qmm(64, HID, FFN),
     "qmm_m64_14336x4096": _qmm(64, FFN, HID),
     "qmm_m64_4096x32000": _qmm(64, HID, VOCAB),
@@ -104,19 +130,24 @@ def test_kernel_compiles_for_v5e(topo, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _engine_shapes(topo, layers: int, batch: int):
+def _engine_shapes(topo, layers: int, batch: int, sparse: bool = False):
     """Config, one-device plan and the placed shapes of parameters and a
     ``batch``-row cache for ``layers`` layers at Mistral-7B widths, int8
     weights, a 2048 window (chip_smoke.py's and the dense cell's sizes),
-    from ``jax.eval_shape``: nothing is allocated."""
+    from ``jax.eval_shape``: nothing is allocated. ``sparse``: Mixtral
+    8x7B's widths and the sparse cell's 4096 rows instead."""
     from jax.sharding import NamedSharding
 
-    from cake_tpu.models.config import mistral_7b
+    from cake_tpu.models.config import mistral_7b, mixtral_8x7b
     from cake_tpu.models.llama import init_params_int8
     from cake_tpu.ops.kvcache import init_cache
     from cake_tpu.parallel.mesh import MeshPlan, cache_specs, param_specs
 
-    config = mistral_7b(max_seq_len=WINDOW, num_hidden_layers=layers)
+    if sparse:
+        config = mixtral_8x7b(max_seq_len=SPARSE_WINDOW,
+                              num_hidden_layers=layers)
+    else:
+        config = mistral_7b(max_seq_len=WINDOW, num_hidden_layers=layers)
     plan = MeshPlan.build(config, devices=topo.devices[:1])
 
     def placed(shapes, specs):
@@ -129,8 +160,7 @@ def _engine_shapes(topo, layers: int, batch: int):
                             jax.random.PRNGKey(0))
     params = placed(params, param_specs(params))
     cache = placed(
-        jax.eval_shape(lambda: init_cache(config, batch=batch,
-                                          max_seq=WINDOW)),
+        jax.eval_shape(lambda: init_cache(config, batch=batch)),
         cache_specs(None, batch_replicated=batch == 1))
     rep = NamedSharding(plan.mesh, jax.sharding.PartitionSpec())
 
@@ -140,17 +170,18 @@ def _engine_shapes(topo, layers: int, batch: int):
     return config, plan, params, cache, arg
 
 
-SLOTS, WINDOW = 8, 2048
+SLOTS, WINDOW, SPARSE_WINDOW = 8, 2048, 4096
 
 
-def _block_decode(topo, layers: int):
+def _block_decode(topo, layers: int, sparse: bool = False):
     """The engine's fused 8-step per-row block decode -- BatchGenerator's
     ``build_sharded_decode(steps=8, per_row=True)`` -- over 8 slots,
     compiled for one described v5e."""
     from cake_tpu.ops.sampling import SamplerSettings
     from cake_tpu.parallel.pipeline import build_sharded_decode
 
-    config, plan, params, cache, arg = _engine_shapes(topo, layers, SLOTS)
+    config, plan, params, cache, arg = _engine_shapes(topo, layers, SLOTS,
+                                                      sparse)
     settings = SamplerSettings(temperature=0.0)
     prog = build_sharded_decode(config, settings, plan, params_like=params,
                                 steps=8, per_row=True)
@@ -213,6 +244,17 @@ def _slabs_written(compiled, slabs: tuple[str, ...]) -> list[str]:
                            "tuple")]
 
 
+def _decode_kernel_calls(compiled) -> list[str]:
+    """The computations that hold the decode kernel's custom call, by its
+    ``op_name``: how deep in the program's loops it sits."""
+    import re
+
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for _, _, _, op, line in _instructions(compiled)
+            if op == "custom-call" and "tpu_custom_call" in line
+            and "flash_decode" in line]
+
+
 def _donated_bytes(compiled) -> tuple[int, int]:
     """(arguments, temporaries) by the compiler's own memory analysis,
     having checked that the donated cache leaves in the buffers it came
@@ -252,6 +294,15 @@ def test_block_decode_program_fits_one_chip(topo, as_on_chip):
     and a slab read per layer, and about one KV cache of temporaries
     beside the donated one (1.07x at depth 2).
 
+    Attention is the decode kernel (PR 29): ONE custom call, inside the
+    layer loop (the decode block's scan over steps, ``one_step``, the
+    layer scan: three ``while`` bodies deep), whose key and value operands
+    are the carried buffers themselves; a Mosaic call fixes its operands'
+    layout, and a compiler that answered by re-laying the cache on the
+    way in would fail the two assertions above it. The sparse decoder's
+    widths over 4096 rows (the sparse cell's, depth 2) are held to the
+    same.
+
     Temporaries: under a quarter of the cache at depth 4, and under 0.4 of
     it at depth 2 and 32. What is left at depth 2 (0.33 of that cache) are
     re-laid copies of the int8 attention weights, ``s8[L,4096,4096]`` and
@@ -262,13 +313,19 @@ def test_block_decode_program_fits_one_chip(topo, as_on_chip):
     and the allocator, fits the chip with the whole 2 GiB cache."""
     from cake_tpu.utils.chips import HBM_GIB
 
+    def held(compiled, depth, window):
+        assert _cache_sized_moves(
+            compiled, f"bf16[{depth},{SLOTS},{KVH},{window},{D}]") == []
+        assert _slabs_written(compiled, (
+            f"bf16[1,{SLOTS},{KVH},{window},{D}]",
+            f"bf16[{SLOTS},{KVH},{window},{D}]")) == []
+        (call,) = _decode_kernel_calls(compiled)
+        assert call.count("while/body") == 3, call
+
+    held(_block_decode(topo, 2, sparse=True), 2, SPARSE_WINDOW)
     for depth, bar in ((2, 0.4), (4, 0.25), (32, 0.4)):
         compiled = _block_decode(topo, depth)
-        assert _cache_sized_moves(
-            compiled, f"bf16[{depth},{SLOTS},{KVH},{WINDOW},{D}]") == []
-        assert _slabs_written(compiled, (
-            f"bf16[1,{SLOTS},{KVH},{WINDOW},{D}]",
-            f"bf16[{SLOTS},{KVH},{WINDOW},{D}]")) == []
+        held(compiled, depth, WINDOW)
         args, temps = _donated_bytes(compiled)
         assert temps <= bar * depth * LAYER_CACHE, (depth, temps / GIB)
     assert 8.8 * GIB < args < 8.95 * GIB, args / GIB  # 6.87 weights + 2.0

@@ -236,8 +236,10 @@ def test_dispatch_policy(monkeypatch):
 
 def test_auto_dispatch_measured_crossover(monkeypatch):
     """impl='auto' follows the measured crossover (tools/flash_sweep.py on
-    v5e): prefill routes to flash only from S >= PREFILL_FLASH_MIN_S; decode
-    and short-context prefill run XLA, where the sweep says XLA wins.
+    v5e): prefill routes to flash only from S >= PREFILL_FLASH_MIN_S;
+    short-context prefill and decode at a head size the kernel does not
+    serve run XLA (what decides decode:
+    test_attend_picks_the_decode_kernel_by_what_it_sees).
     CAKE_PALLAS=1 still forces the kernels everywhere."""
     import cake_tpu.ops.attention as attn
     from cake_tpu.ops import pallas as pk
@@ -272,11 +274,163 @@ def test_auto_dispatch_measured_crossover(monkeypatch):
     calls.clear()
     run(PREFILL_FLASH_MIN_T, PREFILL_FLASH_MIN_S // 2)  # short -> XLA
     run(8, PREFILL_FLASH_MIN_S)  # tiny T (speculative verify) -> XLA
-    run(1, 4096)  # decode -> XLA at any S
+    run(1, 4096)  # decode at D = 8 -> XLA at any S
     assert calls == []
     monkeypatch.setattr(pk, "force_kernels", lambda: True)
     run(1, 512)  # forced -> flash decode regardless of the crossover
     assert calls == ["decode"]
+
+
+@pytest.mark.parametrize("group", [4, 1])
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("stacked", [True, False])
+def test_flash_decode_row_frontiers_on_carried_cache(stacked, window, group):
+    """The decode kernel (all KV heads of a stream's block at once)
+    against the XLA oracle, bf16: on the stacked ``[L, B, KVH, S, D]``
+    cache the layer loop carries, with a TRACED layer index, and on one
+    layer's own buffer; per-row frontiers at the first row, the block's
+    edges, 703 (what ``decode-full`` fills at most) and the buffer's end,
+    mixed in one batch; with and without a window shorter than the
+    buffer.
+
+    The kernel reads the blocks of ``decode_block_range`` (what the
+    engine's ``attn.kv_blocks_*`` counters sum) and no others: every
+    block outside a stream's range, and the stacked form's other layers,
+    hold NaN in the kernel's copy of the cache (a fetched NaN survives
+    the mask: 0 x NaN), and every block inside the range holds a row the
+    oracle attends."""
+    from cake_tpu.ops.attention import _attend_xla
+    from cake_tpu.ops.pallas import decode_block_range
+
+    layers, kvh, s, d, bk = 3, 2, 1024, 16, 128
+    frontiers = [0, 1, bk - 1, bk, 703, s - 1]
+    b, h = len(frontiers), kvh * group
+    pos = jnp.asarray(frontiers, jnp.int32)
+    q, k_one, v_one = _qkv(jax.random.PRNGKey(11), b, h, kvh, 1, s, d,
+                           dtype=jnp.bfloat16)
+    ref = _attend_xla(q, k_one, v_one, pos, window=window)
+    lo, hi = decode_block_range(np.asarray(frontiers), bk, s // bk, window,
+                                xp=np)
+    assert ((hi - lo + 1) >= 1).all() and hi.max() == s // bk - 1
+    block = np.arange(s) // bk
+    counted = (block >= lo[:, None]) & (block <= hi[:, None])  # [B, S]
+    counted = jnp.asarray(counted)[:, None, :, None]
+    k_one = jnp.where(counted, k_one, jnp.nan)
+    v_one = jnp.where(counted, v_one, jnp.nan)
+    if stacked:
+        k_all = jnp.full((layers,) + k_one.shape, jnp.nan, k_one.dtype)
+        v_all = jnp.full((layers,) + v_one.shape, jnp.nan, v_one.dtype)
+
+        @jax.jit
+        def run(layer):
+            return flash_decode(
+                q, k_all.at[layer].set(k_one), v_all.at[layer].set(v_one),
+                pos, layer=layer, block_k=bk, window=window, interpret=True)
+
+        out = run(jnp.int32(1))
+    else:
+        out = flash_decode(q, k_one, v_one, pos, block_k=bk, window=window,
+                           interpret=True)
+    assert out.dtype == q.dtype and out.shape == q.shape
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32),
+        rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("pos,steps,window,want", [
+    ([0, 1, 511, 512, 703, 2047], 1, None, (1 + 1 + 1 + 2 + 2 + 4, 24)),
+    ([300], 8, None, (8, 32)),  # one block a step
+    ([508], 8, None, (4 + 4 * 2, 32)),  # crosses into the second block
+    ([5000], 2, None, (8, 8)),  # a retired slot past the buffer: all of it
+    ([600], 8, 100, (16, 32)),  # window inside blocks 0-1
+    ([1300], 1, 200, (1, 4)),  # window inside block 2 alone
+    ([5000], 2, 100, (2, 8)),  # window past the buffer: the last block
+])
+def test_decode_blocks_read_counts_what_the_kernel_fetches(pos, steps,
+                                                           window, want):
+    """The engine's ``attn.kv_blocks_*`` arithmetic (host side) against
+    the kernel's own block range: per slot and step, the blocks from the
+    window's lower bound to the frontier, clamped into the buffer."""
+    from cake_tpu.ops.pallas import decode_blocks_read
+
+    assert decode_blocks_read(pos, steps, 2048, 512, window) == want
+
+
+DECODE_DISPATCH = {
+    # name: (T, per-row frontiers, S, D, KV heads, int8 cache, stacked)
+    #       -> expected
+    "served_stacked": ((1, True, 2048, 128, 8, False, True), "decode"),
+    "served_one_layer": ((1, True, 4096, 128, 8, False, False), "decode"),
+    "scalar_frontier": ((1, False, 2048, 128, 8, False, True), "decode"),
+    "at_the_floor": ((1, True, 1024, 128, 8, False, True), "decode"),
+    "local_heads_of_a_tp_mesh": ((1, True, 2048, 128, 2, False, True),
+                                 "decode"),
+    "int8_cache": ((1, True, 2048, 128, 8, True, True), "xla"),
+    "per_row_chunk": ((8, True, 2048, 128, 8, False, True), "xla"),
+    "head_64": ((1, True, 2048, 64, 8, False, True), "xla"),
+    "below_the_floor": ((1, True, 512, 128, 8, False, True), "xla"),
+    "not_whole_blocks": ((1, True, 2048 + 128, 128, 8, False, True), "xla"),
+    # rows of heads whose 512-row blocks overflow VMEM: llama2_7b's 32 x
+    # 128 (also at batch 1, a single stream) and gemma_7b's 16 x 256
+    "mha_32_heads": ((1, True, 2048, 128, 32, False, True), "xla"),
+    "mha_32_heads_one_stream": ((1, False, 4096, 128, 32, False, True),
+                                "xla"),
+    "heads_of_256": ((1, True, 2048, 256, 16, False, True), "xla"),
+}
+
+
+@pytest.mark.parametrize("case", list(DECODE_DISPATCH))
+def test_attend_picks_the_decode_kernel_by_what_it_sees(case, monkeypatch):
+    """``attend`` under ``auto`` on the chip (``pk.on_tpu`` steered here,
+    in the test): single-token attention over a plain cache with a
+    128-multiple head, whole blocks of ``DECODE_BLOCK_K`` rows that fit
+    the kernel's VMEM and at least ``DECODE_FLASH_MIN_S`` rows takes the
+    kernel, handed the stacked buffers and the layer index themselves; an
+    int8 cache, a per-row chunk (speculation verify), a 64-wide head, a
+    short cache, a ragged one and a row of heads too wide for VMEM stay
+    on XLA. Nothing but the input decides, and what was decided for the
+    program being traced is published (``attn.decode_kernel``)."""
+    import cake_tpu.ops.attention as attn
+    from cake_tpu.obs import metrics
+    from cake_tpu.ops import pallas as pk
+    from cake_tpu.ops.kvcache import QuantizedKV
+
+    (t, per_row, s, d, kvh, int8, stacked), want = DECODE_DISPATCH[case]
+    monkeypatch.delenv("CAKE_PALLAS", raising=False)
+    monkeypatch.setattr(pk, "on_tpu", lambda: True)
+    calls = []
+
+    def kernel(q, k, v, pos, layer=None, window=None):
+        calls.append(("decode", k.shape, layer is not None))
+        return q
+
+    monkeypatch.setattr(attn.pk, "flash_decode", kernel)
+    monkeypatch.setattr(
+        attn, "_attend_xla",
+        lambda q, k, v, pos, window=None: (calls.append(("xla", k.shape)),
+                                           q)[1])
+    layers, b, h = 2, 2, 2 * kvh
+    shape = ((layers,) if stacked else ()) + (b, kvh, s, d)
+    if int8:
+        buf = QuantizedKV(q=jnp.zeros(shape, jnp.int8),
+                          scale=jnp.ones(shape[:-1], jnp.float32))
+    else:
+        buf = jnp.zeros(shape, jnp.bfloat16)
+    q = jnp.zeros((b, h, t, d), jnp.bfloat16)
+    pos = jnp.zeros((b,), jnp.int32) if per_row else jnp.int32(0)
+    layer = jnp.int32(1) if stacked else None
+    gauge = metrics.registry().gauge("attn.decode_kernel")
+    gauge.set(-1)
+    jax.eval_shape(lambda: attn.attend(q, buf, buf, pos, layer=layer))
+    if want == "decode":
+        assert calls == [("decode", shape, stacked)]
+    else:  # XLA reads one layer's view, whatever came in
+        assert calls == [("xla", (b, kvh, s, d))]
+    # a decode trace says which attention it took; a chunk's says nothing
+    assert gauge.value == (-1 if t > 1 else int(want == "decode"))
+    if not int8:  # the policy for a plain cache, whatever T the caller has
+        assert attn.flash_decode_choice(s, d, kvh) == (
+            "flash" if want == "decode" or t > 1 else "xla")
 
 
 @pytest.mark.parametrize("pos", [0, 5])
@@ -342,8 +496,8 @@ def test_windowed_prefill_dispatch(monkeypatch):
     q, k_all, v_all = _qkv(jax.random.PRNGKey(4), b, h, kvh, t, s, d)
     A.attend(q, k_all, v_all, 0, window=8)
     assert calls == [8]
-    # decode with window: auto stays XLA (no prefill-kernel call) until a
-    # measured win flips it...
+    # decode with window below the decode kernel's floor: auto stays XLA
+    # (and never the prefill kernel)...
     q1 = q[:, :, :1, :]
     xla_out = A.attend(q1, k_all, v_all, 20, window=8)
     assert calls == [8]
