@@ -16,11 +16,9 @@ test:
 # domains. Fails on any finding not grandfathered (with a justification)
 # in analysis-baseline.json. See README "Static analysis".
 lint:
-	$(PY) -m compileall -q cake_tpu tests bench.py chip_smoke.py \
-	  __graft_entry__.py
+	$(PY) -m compileall -q cake_tpu tests chip_smoke.py __graft_entry__.py
 	@if $(PY) -c 'import pyflakes' 2>/dev/null; then \
-	  $(PY) -m pyflakes cake_tpu tests bench.py chip_smoke.py \
-	  __graft_entry__.py; fi
+	  $(PY) -m pyflakes cake_tpu tests chip_smoke.py __graft_entry__.py; fi
 	$(PY) -m cake_tpu.analysis --baseline analysis-baseline.json
 
 native: native/libcakewire.so native/libcakeembed.so native/cake_host_demo
@@ -42,12 +40,6 @@ native/libcakeembed.so: native/cake_embed.cc
 native/cake_host_demo: native/cake_host_demo.c native/libcakeembed.so
 	gcc -O2 -o $@ $< -Lnative -lcakeembed -Wl,-rpath,'$$ORIGIN'
 
-# an explicit CPU smoke: its row says "platform": "cpu" and its metric
-# name ends _cpu. bench.py has no fallback: on a machine with a chip, drop
-# JAX_PLATFORMS=cpu and it runs there or exits non-zero.
-bench:
-	CAKE_BENCH_PRESET=tiny JAX_PLATFORMS=cpu $(PY) bench.py
-
 # the serving path end to end on ONE TPU chip (run it through the chip
 # tool; with no TPU it exits non-zero and prints no result). A four-chip
 # call is for `$(PY) chip_smoke.py --chips 4` and nothing else.
@@ -60,12 +52,13 @@ chip-smoke-rehearse:
 	$(PY) chip_smoke.py --rehearse
 	$(PY) chip_smoke.py --rehearse --chips 4
 
-# the three tools below record on-chip measurements: off a TPU they
-# refuse --json-out (KERNELS_TPU.json is never overwritten by an
-# interpreted run); without --json-out the rows still print
+# on-chip Pallas-vs-XLA parity and timing; its rows print to stdout
 kernel-check:
-	$(PY) -m cake_tpu.tools.kernel_check --json-out KERNELS_TPU.json
+	$(PY) -m cake_tpu.tools.kernel_check
 
+# the two tools below record on-chip measurements: off a TPU they
+# refuse --json-out (a device record is never written by an interpreted
+# run); without --json-out the rows still print
 flash-sweep:
 	$(PY) -m cake_tpu.tools.flash_sweep --json-out flash_sweep.json
 
@@ -82,18 +75,9 @@ ici-probe:
 stage-slice:
 	$(PY) -m cake_tpu.tools.stage_slice --json-out stage_slice.json
 
-# speculation on REAL text: teacher-forced corpus replay (r5) —
-# acceptance + tokens/round from actual prose/code n-gram statistics
-spec-corpus:
-	CAKE_BENCH_SPEC=8 CAKE_BENCH_SPEC_CORPUS=1 CAKE_BENCH_SEQ=2048 \
-	  $(PY) bench.py
-
 # live cluster table over every worker's --status-port page (r5)
 watch:
 	$(PY) -m cake_tpu.tools.watch --topology $(TOPOLOGY) --port 8090
-
-ttft:
-	CAKE_BENCH_TTFT=1 $(PY) bench.py
 
 # observability smoke: tiny CPU-only decode with --trace/--metrics-out/
 # --flight-log into /tmp, validating every artifact parses. The same case
@@ -127,25 +111,19 @@ chaos-smoke:
 # stalling running streams, a disconnected client's slot reused, 429 +
 # Retry-After under saturation, drain finishing in-flight work, serve.*
 # series in /metrics, the tokenizer-less prompt_ids path, and the loadgen
-# driver — then the CAKE_BENCH_SERVE end-to-end HTTP tok/s + TTFT row.
+# driver.
 serve-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_serve.py -q -m 'not slow'
-	CAKE_BENCH_SERVE=1 CAKE_BENCH_PRESET=tiny CAKE_BENCH_STEPS=16 \
-	  JAX_PLATFORMS=cpu $(PY) bench.py
 
 # structured-output smoke: the grammar-constrained decoding plane
 # (cake_tpu/constrain) — regex/JSON-schema -> token-DFA round trips,
 # disk-cache hits, the no-retrace masked decode path (compile-count
 # pinned), schema-constrained serve requests returning valid JSON,
 # stop-string SSE holdback, logprobs vs a numpy softmax reference, and
-# the bit-identical-unconstrained determinism guard — then the
-# CAKE_BENCH_CONSTRAIN constrained-vs-unconstrained HTTP tok/s row
-# (loadgen --workload json; every response must json.loads-parse).
+# the bit-identical-unconstrained determinism guard.
 constrain-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_constrain.py -q \
 	  -m 'not slow'
-	CAKE_BENCH_CONSTRAIN=1 CAKE_BENCH_PRESET=tiny CAKE_BENCH_STEPS=16 \
-	  JAX_PLATFORMS=cpu $(PY) bench.py
 
 # gateway smoke: the multi-replica routing plane (cake_tpu/gateway) —
 # 3-backend loopback fleet with SSE pass-through bit-identical to a
@@ -153,25 +131,18 @@ constrain-smoke:
 # backend, prefix-affinity routing concentrating same-prefix requests on
 # one replica (its engine prefix-store hits move, round_robin's do not),
 # draining backends routed around with zero 5xx, loadgen --retry-429 and
-# --spawn-backends — then the CAKE_BENCH_GATEWAY gateway-vs-direct HTTP
-# tok/s + TTFT overhead row (design target: within 10%).
+# --spawn-backends.
 gateway-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_gateway.py -q -m 'not slow'
-	CAKE_BENCH_GATEWAY=1 CAKE_BENCH_PRESET=tiny CAKE_BENCH_STEPS=16 \
-	  JAX_PLATFORMS=cpu $(PY) bench.py
 
 # paged-KV smoke: the page-pool layout (cake_tpu/kvpool) — paged-vs-slot
 # bit-identical streams across steady batch, mid-run admission,
 # retire-and-reuse, shared-prefix fan-out (n streams sharing physical
 # prefill pages, prefix_hits >= n-1) and constrained streams; pool/
 # prefix-tree/LRU units incl. eviction under pressure and admission
-# deferral; the no-retrace compile pin — then the CAKE_BENCH_KVPOOL
-# churn row (paged vs slot vs steady, legs interleaved; design target:
-# churn within 25% of steady on the same config).
+# deferral; the no-retrace compile pin.
 kv-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_kvpool.py -q -m 'not slow'
-	CAKE_BENCH_KVPOOL=1 CAKE_BENCH_PRESET=tiny CAKE_BENCH_STEPS=16 \
-	  JAX_PLATFORMS=cpu $(PY) bench.py
 
 # disagg smoke: the disaggregated prefill/decode tiers (cake_tpu/disagg)
 # — KV-page snapshot round trips bit-identical to an uninterrupted
@@ -181,12 +152,9 @@ kv-smoke:
 # transfer-channel chaos (kill/truncate/corrupt/stall) recovered by
 # retry, and the gateway two-stage route (prefill tier -> transfer ->
 # decode resume) bit-identical end to end with transparent re-prefill
-# on a dead channel — then the CAKE_BENCH_DISAGG tiered-vs-mixed
-# decode-tier TPOT p95 row under the mixed-prefill workload.
+# on a dead channel.
 disagg-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_disagg.py -q -m 'not slow'
-	CAKE_BENCH_DISAGG=1 CAKE_BENCH_PRESET=tiny CAKE_BENCH_STEPS=16 \
-	  JAX_PLATFORMS=cpu $(PY) bench.py
 
 # request-tracing smoke: request-scoped fleet tracing + SLO accounting
 # (cake_tpu/obs/reqtrace) — traceparent honored/minted, spans connected
@@ -199,8 +167,8 @@ reqtrace-smoke:
 # profiling smoke: the engine profiling plane (cake_tpu/obs/prof) —
 # prof-on vs prof-off bit-identical streams, the retrace sentinel
 # flagging a steady-state shape change (warn + CAKE_PROF_STRICT raise),
-# /debug/prof live on a serve replica, prof.* spans nested under
-# request spans in one trace file, and the benchdiff gate semantics.
+# /debug/prof live on a serve replica, and prof.* spans nested under
+# request spans in one trace file.
 prof-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_prof.py -q -m 'not slow'
 
@@ -224,37 +192,22 @@ fleet-smoke:
 # bit-identically from the spill store, the spill chaos matrix
 # (resume-storm / spill-store-full / victim-finishes-during-spill),
 # admission deferral counted exactly once under spill pressure, the
-# /v1/batch bulk endpoint, gateway-vs-direct classed-request parity —
-# then the CAKE_BENCH_SLO interactive-TTFT-p95 row: class-aware
-# scheduling must beat the FIFO baseline under the mixed-class flood
-# or the row fails.
+# /v1/batch bulk endpoint, gateway-vs-direct classed-request parity.
 slo-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_slo.py -q -m 'not slow'
-	CAKE_BENCH_SLO=1 CAKE_BENCH_PRESET=tiny CAKE_BENCH_STEPS=16 \
-	  CAKE_BENCH_BATCH=2 JAX_PLATFORMS=cpu $(PY) bench.py
-
-# bench regression gate: newest bench_results.jsonl row per metric vs
-# the best prior run (tools/benchdiff) — nonzero exit past the
-# thresholds, so a perf regression fails CI the way a lint finding does.
-bench-diff:
-	$(PY) -m cake_tpu.tools.benchdiff
 
 # perf smoke (CPU, tier-1 `not slow` cases): the obs disabled-path
 # micro-bench and the wire-codec loopback — incl. the bf16 >=1.9x
-# bytes-per-decode-token acceptance — plus the obs on/off overhead row
-# from the bench ledger path. Chains the cluster smoke: the trailer and
-# ping planes ride the same hot path the codec numbers come from — the
-# chaos smoke: recovery machinery must keep surviving what the perf
-# work keeps touching — and the serve smoke: the network plane sits on
-# the same engine hot path. Lint runs first: an invariant violation
+# bytes-per-decode-token acceptance. Chains the cluster smoke: the
+# trailer and ping planes ride the same hot path the codec numbers come
+# from — the chaos smoke: recovery machinery must keep surviving what
+# the perf work keeps touching — and the serve smoke: the network plane
+# sits on the same engine hot path. Lint runs first: an invariant violation
 # fails faster than any smoke, and the smokes exercise exactly the
 # invariants cakelint pins (ownership, deadlines, lock discipline).
 perf-smoke: lint cluster-trace-smoke chaos-smoke serve-smoke constrain-smoke gateway-smoke kv-smoke disagg-smoke reqtrace-smoke prof-smoke fleet-smoke slo-smoke
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_perf_smoke.py \
 	  tests/test_wire_codec.py -q -m 'not slow'
-	CAKE_BENCH_OBS=1 CAKE_BENCH_PRESET=tiny CAKE_BENCH_STEPS=32 \
-	  JAX_PLATFORMS=cpu $(PY) bench.py
-	$(PY) -m cake_tpu.tools.benchdiff
 
 # Deploy plane (reference Makefile:29-39 sync targets): push code +
 # per-worker bundles to every host in TOPOLOGY and optionally start
@@ -269,4 +222,4 @@ clean:
 	rm -f native/*.so native/cake_host_demo
 	find . -name __pycache__ -type d -exec rm -rf {} +
 
-.PHONY: test lint native bench chip-smoke chip-smoke-rehearse kernel-check flash-sweep int4-sweep ici-probe stage-slice spec-corpus watch ttft trace-smoke cluster-trace-smoke chaos-smoke serve-smoke constrain-smoke gateway-smoke kv-smoke disagg-smoke reqtrace-smoke prof-smoke fleet-smoke slo-smoke bench-diff perf-smoke deploy clean
+.PHONY: test lint native chip-smoke chip-smoke-rehearse kernel-check flash-sweep int4-sweep ici-probe stage-slice watch trace-smoke cluster-trace-smoke chaos-smoke serve-smoke constrain-smoke gateway-smoke kv-smoke disagg-smoke reqtrace-smoke prof-smoke fleet-smoke slo-smoke perf-smoke deploy clean

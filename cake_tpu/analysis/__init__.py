@@ -1,7 +1,7 @@
 """cakelint: project-specific static analysis that gates CI.
 
 ``python -m cake_tpu.analysis`` runs every registered checker over the
-package, the examples, and bench.py, and exits nonzero on any finding
+package and the examples, and exits nonzero on any finding
 not grandfathered by ``analysis-baseline.json``. See ``core.py`` for
 the framework, the sibling modules for the checkers, and README
 "Static analysis" for the workflow (baseline, suppressions, adding a

@@ -26,7 +26,7 @@ def make_parser() -> argparse.ArgumentParser:
     )
     p.add_argument("paths", nargs="*",
                    help="files/dirs to scan (default: cake_tpu, examples, "
-                        "bench.py)")
+                        "__graft_entry__.py)")
     p.add_argument("--baseline", metavar="FILE",
                    help="grandfather findings listed in FILE; exit 0 "
                         "unless NEW findings exist")

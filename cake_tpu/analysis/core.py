@@ -30,10 +30,10 @@ from pathlib import Path
 
 REPO_ROOT = Path(__file__).resolve().parents[2]
 
-# Scan surface: the package, the runnable examples, and the bench driver.
+# Scan surface: the package, the runnable examples, and the graft entry.
 # Tests are deliberately out — they exercise invariant-breaking paths on
 # purpose (chaos faults, lock races, raw engine drives).
-DEFAULT_ROOTS = ("cake_tpu", "examples", "bench.py", "__graft_entry__.py")
+DEFAULT_ROOTS = ("cake_tpu", "examples", "__graft_entry__.py")
 
 _SKIP_DIRS = {"__pycache__", ".git", "native"}
 

@@ -12,8 +12,8 @@ is an engine — a variable bound from a ``BatchGenerator``/
 conventional name the scheduler and CLI use for the handle).
 
 Deliberate direct drives (the examples exist to demonstrate the raw
-engine API; bench.py times it without a serving plane) are grandfathered
-in the committed baseline with a justification each.
+engine API) are grandfathered in the committed baseline with a
+justification each.
 """
 
 from __future__ import annotations

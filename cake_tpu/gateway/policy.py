@@ -7,8 +7,8 @@ Three policies, selected by ``--route-policy``:
   the ``/healthz`` load fields). Mitzenmacher's result is that this beats
   random assignment exponentially in the max-queue sense while needing
   only two load lookups — no global scan, no coordination;
-- ``round_robin`` — strict rotation; the baseline the bench row and the
-  prefix-affinity acceptance test compare against;
+- ``round_robin`` — strict rotation; the baseline the prefix-affinity
+  acceptance test compares against;
 - ``prefix`` — prefix affinity (the SGLang observation): requests whose
   prompts open with the same ``prefix_block``-aligned tokens hash to the
   same preferred replica via rendezvous hashing, so that replica's engine

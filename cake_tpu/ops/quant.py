@@ -415,13 +415,10 @@ def quant_matmul(
             )
         else:
             # The compiled kernel needs enough rows to tile the MXU; skinny
-            # inputs run XLA's gemv path, which is ~67% faster at M=1 on
-            # v5e (measured single-stream 8B int8: 84.7 vs 50.7 tok/s) and
-            # ~40% faster at M=8 (batched decode). The crossover is ~M=16,
-            # where the kernel's int8-in-VMEM streaming starts winning (522
-            # vs 505 aggregate tok/s at batch 16). Those are the 07-31
-            # rows of bench_results.jsonl, taken at Llama-3-8B widths; the
-            # crossover has not been measured on the chip tool.
+            # inputs (single-stream and small-batch decode) run XLA's gemv
+            # path. From 16 rows the kernel's int8-in-VMEM streaming is
+            # expected to win; the crossover has not been measured on the
+            # chip tool.
             m = x.size // x.shape[-1]
             impl = (
                 "pallas"
@@ -454,8 +451,8 @@ def quant4_matmul(
     """int4 twin of :func:`quant_matmul` — same pin/auto dispatch contract.
 
     The auto gate reuses the int8 m>=16 crossover as its prior (the kernels
-    share the streaming structure); the int4 frontier is re-measured on chip
-    by tools/int4_sweep rows before any claim is made."""
+    share the streaming structure); the int4 frontier has not been measured
+    on the chip tool (tools/int4_sweep times it)."""
     from cake_tpu.ops import pallas as pk
 
     k2, n = qp.shape[-2], qp.shape[-1]
@@ -481,10 +478,9 @@ def quant4_matmul(
             # Unlike int8 (where XLA's gemv fuses the convert and wins below
             # m=16), the int4 XLA fallback cannot fuse the shift-unpack into
             # the dot: it re-materializes bf16 weights every step — 4x the
-            # packed bytes (measured 47.8 tok/s at M=1 on the 8B v5e
-            # single-stream bench, i.e. the bf16 rate). The kernel (with
-            # sublane M-padding) streams the packed bytes, so tileability
-            # is the only gate.
+            # packed bytes, i.e. the bf16 rate (not measured on the chip
+            # tool). The kernel (with sublane M-padding) streams the packed
+            # bytes, so tileability is the only gate.
             impl = (
                 "pallas"
                 if pk.kernels_enabled()

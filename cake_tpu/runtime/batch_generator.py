@@ -1966,9 +1966,7 @@ class BatchGenerator:
         TRACED: splicing with host-side ``.at[:, slot].set`` bakes the slot
         as a constant, so every distinct slot compiled a fresh cache-sized
         scatter (plus four small-state scatters) *inside the serving
-        window* — measured as the dominant churn-bench cost (busy_s 5.3 of
-        timed 14.4 s on v5e; the other ~9 s were these compiles). One
-        traced program serves every slot and is warmed by
+        window*. One traced program serves every slot and is warmed by
         ``warm_admission``."""
         if self.__splice is None:
             def splice(cache, row, keys, history, hist_slot, last, key,

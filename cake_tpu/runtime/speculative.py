@@ -10,7 +10,7 @@ decides every emitted token; proposals only decide how many land per
 dispatch.
 
 Why this is TPU-shaped: single-token decode reads every weight byte from
-HBM per token (weights-bound, ~85 tok/s for 8B int8 on v5e). Verification
+HBM per token (weights-bound). Verification
 feeds K+1 tokens through the same weights in one pass — the MXU loves the
 wider matmuls and the weight read amortizes over every accepted token, so
 acceptance rate converts directly into tok/s. On repetitive stretches

@@ -24,7 +24,7 @@ needs around them: admission, fairness, deadlines, cancellation, and
 drain.
 
 SLO-aware scheduling (ISSUE 20), ``sched_policy="slo"`` (the default;
-``"fifo"`` is the single-tenant baseline the bench A/B's against):
+``"fifo"`` is the single-tenant baseline it is compared against):
 
 - **Priority classes** — each session carries a class
   (``session.CLASSES``, highest first): interactive arrivals jump batch
@@ -65,8 +65,8 @@ log = logging.getLogger("cake_tpu.serve.scheduler")
 
 # admission policies: "slo" = class-priority + preemption + tenant
 # fairness (the production mix); "fifo" = strict arrival order, no
-# preemption (the single-tenant baseline the CAKE_BENCH_SLO row A/B's
-# class-aware scheduling against)
+# preemption (the single-tenant baseline class-aware scheduling is
+# compared against)
 SCHED_POLICIES = ("slo", "fifo")
 
 # replica roles (cake_tpu/disagg): what this scheduler DOES with a
@@ -401,9 +401,9 @@ class Scheduler:
                     and getattr(self.engine, "paged", False))
 
     def set_policy(self, policy: str) -> None:
-        """Swap the admission policy between runs (the CAKE_BENCH_SLO
-        row A/B's "fifo" against "slo" on one warmed stack). Handler-
-        safe; takes effect at the engine thread's next pass."""
+        """Swap the admission policy between runs ("fifo" against "slo"
+        on one warmed stack). Handler-safe; takes effect at the engine
+        thread's next pass."""
         if policy not in SCHED_POLICIES:
             raise ValueError(f"sched_policy must be one of "
                              f"{SCHED_POLICIES}, got {policy!r}")

@@ -32,8 +32,7 @@ import threading
 from cake_tpu.obs import metrics as obs_metrics
 
 # current occupancy (gauges, not counters: spilled streams resume and
-# leave) — the /healthz spill-pressure fields and the bench's ledger
-# both read these
+# leave) — the /healthz spill-pressure fields read these
 SPILL_BYTES = obs_metrics.gauge("serve.spill_bytes")
 SPILL_PAGES = obs_metrics.gauge("serve.spill_pages")
 

@@ -1,10 +1,9 @@
 """Int4 decode-gemv sweep: find why (and fix how) m=1 int4 runs under its
 roofline.
 
-The 07-31 rows of bench_results.jsonl: single-stream int4 decode measured
-51 tok/s against a 170 tok/s weights-bound roofline, while int8 (twice the
-bytes) hits 84.8 — so the m=1 int4 kernel is the bottleneck, not HBM
-(none of it re-measured on the chip tool). Working hypothesis
+The question: whether single-stream int4 decode reaches its weights-bound
+roofline (half of int8's bytes), or whether the m=1 int4 kernel and not
+HBM bounds it (not measured on the chip tool). Working hypothesis
 (ops/pallas/quant.py:_kernel4): the per-byte nibble unpack (widen + shifts
 + converts over a [BK2, BN] block) is VPU-bound and its widened
 temporaries pressure VMEM; both effects are block-size- and

@@ -287,9 +287,9 @@ class _FlushedResults(list):
 
 
 def refuse_offchip_record(json_out: str | None) -> None:
-    """``--json-out`` files are device records (KERNELS_TPU.json and its
-    kin). Off a TPU the kernels run interpreted, so writing one is
-    refused; stdout still carries the rows. Shared by the sweep tools."""
+    """``--json-out`` files are device records. Off a TPU the kernels
+    run interpreted, so writing one is refused; stdout still carries the
+    rows. Shared by the sweep tools."""
     platform = jax.devices()[0].platform
     if json_out and platform != "tpu":
         sys.exit(

@@ -9,13 +9,6 @@ closed loop self-throttles when the server slows down). Prompts draw from
 a ``--prompt-len`` mix of random in-vocab token ids (``prompt_ids`` path:
 no tokenizer needed on either side), or from ``--prompt`` literals.
 
-``--workload json`` (ISSUE 8) sends schema-constrained requests
-(``response_format: json_schema`` against :data:`JSON_WORKLOAD_SCHEMA`)
-and asserts every response's assembled text ``json.loads``-parses —
-the end-to-end proof that grammar-constrained decoding produced valid
-JSON through the whole HTTP plane. Needs a server-side tokenizer.
-Invalid responses land in ``json_invalid`` (nonzero exit).
-
 ``--workload churn`` (ISSUE 11) is the admission/retirement regime the
 paged KV pool (cake_tpu/kvpool) exists for: Poisson arrivals, a
 short/long prompt-length mix, and every Nth client disconnecting
@@ -32,15 +25,6 @@ a tiered fleet isolates them. The report splits TTFT p50/p95 by prompt
 bucket (``ttft_ms_by_prompt_len``) so the short-prompt tail is visible
 next to the long one.
 
-``--workload mixed-class`` (ISSUE 20) is the SLO-scheduling regime: an
-interactive trickle (every 4th request, ``"class": "interactive"``)
-under a batch flood (the rest, ``"class": "batch"``), Poisson arrivals,
-every request streaming. Under FIFO the interactive TTFT tail is
-hostage to however many batch requests queued first; the class-aware
-scheduler jumps them (and preempts batch victims to host-RAM spill when
-slots are full). The report splits TTFT p50/p95 by class
-(``ttft_ms_by_class``) — the ``CAKE_BENCH_SLO=1`` acceptance signal.
-
 ``--retry-429`` makes a 429 honor its ``Retry-After`` and resubmit
 (bounded) instead of counting a hard rejection — the realistic open-loop
 client against a saturated server or gateway. ``--spawn-backends N``
@@ -49,9 +33,8 @@ gateway (``cake_tpu/gateway``) and drives the gateway, so one command
 smokes the whole loopback fleet.
 
 Prints TTFT / TPOT / end-to-end percentiles and aggregate token
-throughput; used by ``make serve-smoke`` / ``make constrain-smoke`` /
-``make gateway-smoke`` and the ``CAKE_BENCH_SERVE=1`` /
-``CAKE_BENCH_CONSTRAIN=1`` / ``CAKE_BENCH_GATEWAY=1`` bench rows.
+throughput; the serve, gateway, kvpool, fleet and reqtrace tests drive
+their servers with it.
 
 Usage:
   python -m cake_tpu.tools.loadgen http://127.0.0.1:8080 \\
@@ -59,7 +42,7 @@ Usage:
   python -m cake_tpu.tools.loadgen http://127.0.0.1:8080 \\
       -n 64 --rate 8 --max-tokens 32        # open loop, 8 req/s Poisson
   python -m cake_tpu.tools.loadgen http://127.0.0.1:8080 \\
-      -n 16 --workload json --max-tokens 48  # constrained JSON workload
+      -n 16 --workload churn --max-tokens 48  # arrivals + disconnects
 """
 
 from __future__ import annotations
@@ -72,19 +55,6 @@ import threading
 import time
 import urllib.error
 import urllib.request
-
-
-# the --workload json constraint: small, fully bounded (the lowered
-# automaton is acyclic, so every constrained stream terminates within
-# its token budget), exercises object/integer/boolean paths
-JSON_WORKLOAD_SCHEMA = {
-    "type": "object",
-    "properties": {
-        "a": {"type": "integer"},
-        "ok": {"type": "boolean"},
-    },
-    "required": ["a", "ok"],
-}
 
 
 def _percentile(xs: list[float], q: float) -> float:
@@ -194,9 +164,7 @@ def run_load(url: str, n: int, concurrency: int = 4, max_tokens: int = 32,
              slo_ttft_ms: float | None = None,
              slo_tpot_ms: float | None = None) -> dict:
     """Run the load; returns aggregate stats (also the in-process entry
-    the bench row and tests use). ``workload="json"`` attaches the
-    schema constraint to every request and json-validates every
-    response's text. ``workload="churn"`` is the admission/retirement
+    the tests use). ``workload="churn"`` is the admission/retirement
     regime (ISSUE 11): Poisson arrivals (defaults ``rate`` to ~2x the
     concurrency when unset), a short/long prompt-length mix (defaults
     the mix to 8,64), and every ``disconnect_every``-th client walking
@@ -216,20 +184,9 @@ def run_load(url: str, n: int, concurrency: int = 4, max_tokens: int = 32,
     requests meeting BOTH set targets — next to the percentile view:
     percentiles say how slow the tail was, goodput says how many users
     got what the SLO promised."""
-    if workload not in ("text", "json", "churn", "mixed-prefill",
-                        "mixed-class"):
-        raise ValueError(f"workload must be 'text', 'json', 'churn', "
-                         f"'mixed-prefill' or 'mixed-class', "
-                         f"got {workload!r}")
-    if workload == "mixed-class":
-        # the SLO-scheduling regime (ISSUE 20): an interactive trickle
-        # under a batch flood, open loop — the per-class TTFT split is
-        # the whole point
-        if rate is None:
-            rate = max(2.0, 2.0 * concurrency)
-        if not stream:
-            raise ValueError("workload='mixed-class' measures per-class "
-                             "TTFT tails; it needs streaming responses")
+    if workload not in ("text", "churn", "mixed-prefill"):
+        raise ValueError(f"workload must be 'text', 'churn' or "
+                         f"'mixed-prefill', got {workload!r}")
     if workload == "mixed-prefill":
         # the disagg interference regime: bimodal prompt lengths under
         # Poisson arrivals (open loop — the honest view of the tail the
@@ -258,18 +215,8 @@ def run_load(url: str, n: int, concurrency: int = 4, max_tokens: int = 32,
     results: list[dict] = [None] * n  # type: ignore[list-item]
     t_start = time.perf_counter()
 
-    def _class_of(i: int) -> str:
-        # every 4th request is the interactive trickle; the rest are
-        # the batch flood it must cut through
-        return "interactive" if i % 4 == 0 else "batch"
-
     def fire(i: int) -> None:
         body = dict(frags[i], max_tokens=max_tokens, stream=stream)
-        if workload == "json":
-            body["response_format"] = {"type": "json_schema",
-                                       "schema": JSON_WORKLOAD_SCHEMA}
-        if workload == "mixed-class":
-            body["class"] = _class_of(i)
         abort_after = (2 if disconnect_every
                        and i % disconnect_every == disconnect_every - 1
                        else None)
@@ -328,14 +275,6 @@ def run_load(url: str, n: int, concurrency: int = 4, max_tokens: int = 32,
     errors = [r for r in results if r and (
         "error" in r or ("status" in r and r["status"] != 429))]
     disconnected = sum(1 for r in results if r and r.get("disconnected"))
-    json_invalid = 0
-    if workload == "json":
-        for r in done:
-            try:
-                json.loads(r.get("text") or "")
-            except ValueError:
-                json_invalid += 1
-                r["json_invalid"] = True
     ttfts = [r["ttft_s"] for r in done if r.get("ttft_s") is not None]
     gaps = [g for r in done for g in r.get("gaps_s", ())]
     total_tokens = sum(r["tokens"] for r in done)
@@ -354,20 +293,6 @@ def run_load(url: str, n: int, concurrency: int = 4, max_tokens: int = 32,
                   "p95": round(_percentile(xs, 0.95) * 1e3, 1),
                   "n": len(xs)}
         for ln, xs in sorted(by_len.items())}
-    # TTFT split by class (mixed-class): under FIFO the aggregate hides
-    # the interactive tail inside the batch flood's — the split is the
-    # CAKE_BENCH_SLO acceptance signal
-    ttft_by_class: dict[str, dict] = {}
-    if workload == "mixed-class":
-        by_cls: dict[str, list[float]] = {}
-        for i, r in enumerate(results):
-            if r and r.get("tokens") and r.get("ttft_s") is not None:
-                by_cls.setdefault(_class_of(i), []).append(r["ttft_s"])
-        ttft_by_class = {
-            cls: {"p50": round(_percentile(xs, 0.5) * 1e3, 1),
-                  "p95": round(_percentile(xs, 0.95) * 1e3, 1),
-                  "n": len(xs)}
-            for cls, xs in sorted(by_cls.items())}
     slo = None
     if slo_ttft_ms is not None or slo_tpot_ms is not None:
         good = 0
@@ -402,7 +327,6 @@ def run_load(url: str, n: int, concurrency: int = 4, max_tokens: int = 32,
                            for r in results if r),
         "errors": len(errors),
         "disconnected": disconnected,
-        "json_invalid": json_invalid,
         "wall_s": round(wall, 3),
         "tokens": total_tokens,
         "tok_s": round(total_tokens / wall, 2) if wall > 0 else 0.0,
@@ -416,7 +340,6 @@ def run_load(url: str, n: int, concurrency: int = 4, max_tokens: int = 32,
         },
         **({"ttft_ms_by_prompt_len": ttft_by_len}
            if len(ttft_by_len) > 1 else {}),
-        **({"ttft_ms_by_class": ttft_by_class} if ttft_by_class else {}),
         **({"slo": slo} if slo is not None else {}),
         "results": results,
     }
@@ -668,13 +591,11 @@ def main(argv=None) -> int:
                         "server-side tokenizer; overrides --prompt-len)")
     p.add_argument("--no-stream", action="store_true",
                    help="unary JSON responses instead of SSE")
-    p.add_argument("--workload", choices=["text", "json", "churn",
-                                          "mixed-prefill", "mixed-class"],
+    p.add_argument("--workload", choices=["text", "churn",
+                                          "mixed-prefill"],
                    default="text",
-                   help="json: schema-constrained requests "
-                        "(response_format json_schema), responses "
-                        "asserted json.loads-parseable. churn: the "
-                        "admission/retirement regime — Poisson arrivals "
+                   help="churn: the admission/retirement regime — "
+                        "Poisson arrivals "
                         "(--rate defaults to 2x concurrency), a "
                         "short/long prompt mix (--prompt-len defaults "
                         "to 8,64), every 4th client disconnecting "
@@ -682,11 +603,7 @@ def main(argv=None) -> int:
                         "mixed-prefill: the disagg interference regime "
                         "— Poisson arrivals with a bimodal prompt mix "
                         "(--prompt-len defaults to 8,512); the report "
-                        "splits TTFT by prompt bucket. mixed-class: the "
-                        "SLO-scheduling regime — an interactive trickle "
-                        "(every 4th request) under a batch flood, "
-                        "Poisson arrivals; the report splits TTFT by "
-                        "class (ttft_ms_by_class)")
+                        "splits TTFT by prompt bucket")
     p.add_argument("--disconnect-every", type=int, default=None,
                    dest="disconnect_every", metavar="N",
                    help="every Nth request walks away after 2 tokens "
@@ -805,7 +722,7 @@ def main(argv=None) -> int:
         print(f"SLO gate failed: goodput {stats['slo']['goodput']} < "
               f"{args.slo_goodput_min}", file=sys.stderr)
         return 1
-    return 0 if stats["errors"] == 0 and stats["json_invalid"] == 0 else 1
+    return 0 if stats["errors"] == 0 else 1
 
 
 if __name__ == "__main__":

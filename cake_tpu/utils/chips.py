@@ -1,23 +1,22 @@
-"""Published per-chip peaks (one copy — bench.py and the tools share it so
-a correction can never leave one caller's roofline denominator stale).
+"""Published per-chip peaks (one copy, so a correction can never leave one
+caller's roofline denominator stale).
 
 Source: Google Cloud documentation, "TPU v5e" (system architecture page):
-197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s per chip. The same numbers are
-quoted in ``/opt/skills/guides/on-chip-measurement`` section 4. JAX
-reports that chip as ``device_kind`` "TPU v5 lite".
+16 GB of HBM at 819 GB/s per chip. The same numbers are quoted in
+``/opt/skills/guides/on-chip-measurement`` section 4. JAX reports that
+chip as ``device_kind`` "TPU v5 lite".
 
 These feed roofline DENOMINATORS (weights-bound ideal tok/s = HBM bytes/s
-/ model bytes; MFU = FLOP/s / peak) — they are never presented as
-measurements. A device that is not in the table is an error, not a
-default: a ratio against the wrong chip's peak is worse than none. To
-run on another chip, add its row here with the page it was read from.
+/ model bytes) — they are never presented as measurements. A device that
+is not in the table is an error, not a default: a ratio against the wrong
+chip's peak is worse than none. To run on another chip, add its row here
+with the page it was read from.
 """
 
 from __future__ import annotations
 
 # device_kind substring (lower case) -> spec
 HBM_GBPS = {"v5 lite": 819.0, "v5e": 819.0}      # HBM bandwidth, GB/s
-PEAK_TFLOPS = {"v5 lite": 197.0, "v5e": 197.0}   # bf16 peak, TFLOP/s
 HBM_GIB = {"v5 lite": 16.0, "v5e": 16.0}         # HBM capacity
 
 

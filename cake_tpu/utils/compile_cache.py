@@ -11,7 +11,7 @@ cache's path is part of its key, so it must not move between runs:
   pid or the time), listed in ``.gitignore``.
 
 Entry points call :func:`configure` first thing, before anything
-compiles: ``cli.main``, ``bench.py``, ``chip_smoke.py``'s children and the
+compiles: ``cli.main``, ``chip_smoke.py``'s children and the
 ``tools/`` mains. Tests that AOT-compile for a described chip turn the
 cache off around those compiles (tests/test_chip_compile.py): such an
 entry is written but cannot be read back without a chip.

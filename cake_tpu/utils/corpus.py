@@ -1,9 +1,8 @@
-"""A small FIXED text corpus for realistic-acceptance speculation benches.
+"""A small FIXED text corpus for realistic-acceptance speculation replays.
 
-The r4 synthetic speculation rows ran a self-repeating token stream — the
-n-gram proposer's best case. The honest companion measurement replays real
-text (`bench.py` ``CAKE_BENCH_SPEC_CORPUS=1`` →
-:func:`cake_tpu.runtime.speculative.spec_replay_fn`): acceptance then
+A self-repeating token stream is the n-gram proposer's best case. The
+honest companion replays real text
+(:func:`cake_tpu.runtime.speculative.spec_replay_fn`): acceptance then
 reflects the repetition statistics of actual prose and code, not a
 constructed loop. The reference has no speculation plane at all
 (SURVEY.md §2) — this exists to keep OUR claimed numbers honest.
@@ -13,8 +12,7 @@ across rounds: technical prose (the register of real serving traffic)
 plus a code-flavored section (identifiers and syntax repeat the way real
 completion contexts do). Byte-level tokenization keeps the stream
 model-agnostic; byte text has the same kind of local n-gram structure a
-subword stream has, just at a finer granularity, and the row is labeled
-``corpus_bytes`` so it can never be mistaken for a subword-stream number.
+subword stream has, just at a finer granularity.
 """
 
 from __future__ import annotations

@@ -175,10 +175,10 @@ def child_kernels(a) -> int:
     if not ok or (not a.rehearse and not compiled):
         raise SmokeFailure("a kernel row is out of tolerance or interpreted")
 
-    # One warmed decode dispatch, timed two ways. bench.py syncs by host
-    # fetch because, on the machine it was written for, block_until_ready
-    # returned before the work was done. If the two agree beside the chip,
-    # that workaround has nothing left to work around.
+    # One warmed decode dispatch, timed two ways: around a host fetch and
+    # around block_until_ready. On an earlier machine block_until_ready
+    # returned before the work was done; if the two agree beside the chip,
+    # either is a fair way to time a dispatch.
     from cake_tpu.models.config import mistral_7b, tiny
     from cake_tpu.models.llama import init_params_int8
     from cake_tpu.ops.sampling import SamplerSettings

@@ -11,9 +11,8 @@ fixture starts them together when the file's first test starts, the
 tests that need none of them come first and run meanwhile, and the rest
 wait for the one they read.
 
-Beside them: the peaks table raising for a device it does not know,
-bench.py refusing a chip-sized preset off the chip, the native wire
-library's content stamp, and the seeded checkpoint writer.
+Beside them: the peaks table raising for a device it does not know, the
+native wire library's content stamp, and the seeded checkpoint writer.
 """
 
 import json
@@ -96,40 +95,6 @@ def test_chips_raises_for_unknown_device_kind():
     for kind in ("cpu", "TPU v9 imaginary"):
         with pytest.raises(KeyError, match="no published peaks"):
             device_spec(SimpleNamespace(device_kind=kind), HBM_GBPS)
-
-
-def test_bench_refuses_a_chip_sized_preset_off_the_chip(monkeypatch, capsys):
-    """With no TPU, bench.py's default preset fails: nothing re-runs it
-    smaller or on the CPU. Only the tiny preset is a CPU smoke."""
-    import bench
-
-    monkeypatch.delenv("CAKE_BENCH_PRESET", raising=False)
-    with pytest.raises(SystemExit) as e:
-        bench.main()
-    assert "'8b' preset is sized for a TPU" in str(e.value)
-    assert "'cpu'" in str(e.value)
-    assert capsys.readouterr().out == ""  # no row
-
-
-def test_bench_rows_name_the_device(monkeypatch, tmp_path, capsys):
-    """Every row says where it ran, on stdout and in the ledger file, and
-    a row from anywhere but a TPU loses its chip metric name."""
-    import jax
-
-    import bench
-
-    ledger = tmp_path / "ledger.jsonl"
-    monkeypatch.setattr(bench, "_ledger_path", lambda: str(ledger))
-    bench._emit({"metric": "decode_tokens_per_sec_llama_tiny_bf16_1chip",
-                 "value": 1.0, "unit": "tokens/s"}, jax.devices()[0],
-                **bench._no_peaks(jax.devices()[0]))
-    row = json.loads(capsys.readouterr().out)
-    assert row["metric"] == "decode_tokens_per_sec_llama_tiny_bf16_cpu"
-    assert (row["platform"], row["device_kind"], row["device_count"]) == (
-        "cpu", "cpu", len(jax.devices()))
-    assert row["vs_baseline"] is None and "no published peaks" in row["baseline"]
-    (rec,) = map(json.loads, ledger.read_text().splitlines())
-    assert rec["platform"] == "cpu" and rec["metric"] == row["metric"]
 
 
 # -- built from what git holds ------------------------------------------------

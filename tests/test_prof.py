@@ -5,9 +5,8 @@ vs prof-off streams bit-identical), a sampled step records the per-phase
 breakdown plus the recent-step ring, the retrace sentinel counts backend
 compiles and flags exactly the steady-state decode-phase ones (warn by
 default, raise under CAKE_PROF_STRICT=1), /debug/prof answers live on a
-serve replica, a --trace run nests prof.* phase spans under the request
-spans in one timeline, and the benchdiff gate exits nonzero exactly on a
-regressed ledger.
+serve replica, and a --trace run nests prof.* phase spans under the
+request spans in one timeline.
 """
 
 import json
@@ -570,75 +569,3 @@ def test_startup_reports_its_four_numbers(prof_env):
     finally:
         prof._STARTUP.clear()
     assert "startup" not in prof.report()
-
-
-# -- benchdiff gate -----------------------------------------------------------
-
-def _ledger(tmp_path, rows, name="ledger.jsonl"):
-    p = tmp_path / name
-    p.write_text("".join(json.dumps(r) + "\n" for r in rows))
-    return str(p)
-
-
-def _row(metric, value, unit, **extra):
-    return {"metric": metric, "value": value, "unit": unit,
-            "device": "cpu", "stamp": "2026-08-07T00:00:00Z", **extra}
-
-
-def test_benchdiff_passes_steady_ledger(tmp_path, capsys):
-    from cake_tpu.tools import benchdiff
-
-    led = _ledger(tmp_path, [
-        _row("decode_tok", 100.0, "tokens/s"),
-        _row("decode_tok", 104.0, "tokens/s"),
-        _row("ttft_ms", 12.0, "ms"),
-        _row("ttft_ms", 11.0, "ms"),
-        _row("obs_pct", 1.5, "%"),
-        _row("obs_pct", 2.0, "%"),
-    ])
-    rc = benchdiff.main(["--ledger", led,
-                         "--baseline", str(tmp_path / "nope.json")])
-    assert rc == 0
-    assert "REGRESSED" not in capsys.readouterr().out
-
-
-def test_benchdiff_fails_on_regression(tmp_path, capsys):
-    from cake_tpu.tools import benchdiff
-
-    led = _ledger(tmp_path, [
-        _row("decode_tok", 100.0, "tokens/s"),
-        _row("decode_tok", 10.0, "tokens/s"),  # -90%: past any gate
-    ])
-    rc = benchdiff.main(["--ledger", led,
-                         "--baseline", str(tmp_path / "nope.json")])
-    assert rc == 1
-    assert "REGRESSED" in capsys.readouterr().out
-
-
-def test_benchdiff_overhead_rows_gate_on_points(tmp_path):
-    from cake_tpu.tools import benchdiff
-
-    # a 4% overhead leg is inside the default 10-point budget — even
-    # though a lucky -4% leg sits in the history (a min-of-history gate
-    # would call this +8pp and start creeping toward red)
-    led = _ledger(tmp_path, [
-        _row("obs_pct", -4.0, "%"), _row("obs_pct", 4.0, "%"),
-    ])
-    assert benchdiff.main(["--ledger", led]) == 0
-    # ...11.5% overhead busts the budget regardless of history
-    led = _ledger(tmp_path, [
-        _row("obs_pct", -4.0, "%"), _row("obs_pct", 11.5, "%"),
-    ], name="bad.jsonl")
-    assert benchdiff.main(["--ledger", led]) == 1
-
-
-def test_benchdiff_ignores_cross_device_history(tmp_path):
-    from cake_tpu.tools import benchdiff
-
-    # a tpu row's 10x number must not gate the cpu smoke that follows
-    rows = [
-        dict(_row("decode_tok", 5000.0, "tokens/s"), device="TPU v5e"),
-        _row("decode_tok", 100.0, "tokens/s"),
-        _row("decode_tok", 95.0, "tokens/s"),
-    ]
-    assert benchdiff.main(["--ledger", _ledger(tmp_path, rows)]) == 0

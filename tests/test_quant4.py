@@ -4,9 +4,9 @@ model quality, loader equivalence, and sharded execution.
 The int4 tier is a capability the TPU build adds beyond the reference's
 f16/bf16 dtype plane (`cake/mod.rs:56-62`): decode is HBM-bandwidth-bound,
 so halving the int8 bytes again roughly doubles the single-stream roofline
-(the roofline, not the rate: the int4 07-31 rows of bench_results.jsonl
-reached 0.28-0.30 of it). The adjacent-pair packing convention (ops/quant.py) is
-load-bearing for tensor parallelism — tested explicitly here.
+(the roofline, not the rate: not measured on the chip tool). The
+adjacent-pair packing convention (ops/quant.py) is load-bearing for tensor
+parallelism — tested explicitly here.
 """
 
 import jax

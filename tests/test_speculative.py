@@ -448,7 +448,7 @@ def test_fused_ctx_invalidated_on_new_prompt(params):
 
 
 def test_spec_replay_teacher_forced_counts_match_host_reference(params):
-    """r5: the fused corpus replay (bench CAKE_BENCH_SPEC_CORPUS) must
+    """r5: the fused corpus replay (``spec_replay_fn``) must
     accept exactly the run lengths a host-side teacher-forced simulation
     of the same n-gram proposer produces on the same stream — the device
     proposer, the forced accept, and the position bookkeeping all agree;

@@ -45,8 +45,8 @@ def test_slice_config_is_70b_geometry():
                                   stage_slice])
 def test_tools_refuse_offchip_json_out(tool, tmp_path, monkeypatch):
     """Off a TPU the kernels run interpreted and a stage step is a CPU
-    step: a --json-out file (KERNELS_TPU.json and kin) would record
-    those under device names. Every tool refuses before measuring."""
+    step: a --json-out file would record those under device names.
+    Every tool refuses before measuring."""
     out = tmp_path / "record.json"
     monkeypatch.setattr(sys, "argv", ["tool", "--json-out", str(out)])
     with pytest.raises(SystemExit) as e:
