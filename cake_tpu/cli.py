@@ -150,9 +150,13 @@ def build_parser() -> argparse.ArgumentParser:
                    help="dispatch decode block N+1 from the device-side "
                         "feedback token BEFORE fetching block N's tokens to "
                         "the host — hides readback/detok/emission behind "
-                        "device compute (all-local fused-block path and "
-                        "--prompts-file serving; token streams are "
-                        "bit-identical to the non-lookahead path)")
+                        "device compute (the all-local fused-block path; "
+                        "token streams are bit-identical to the "
+                        "non-lookahead path). The batched engine "
+                        "(--prompts-file, --mode serve) gives the device "
+                        "its next block before it hands out a block's rows "
+                        "by itself: there the flag changes nothing and "
+                        "says so")
     p.add_argument("--wire-codec", choices=["none", "bf16", "int8"],
                    default=None, dest="wire_codec",
                    help="activation encoding for cross-host worker hops "
@@ -632,10 +636,7 @@ def run_serve(args) -> int:
     if args.wire_codec not in (None, "none"):
         sys.exit("error: --wire-codec applies to cross-host worker hops "
                  "(master/worker --topology runs); serving rides the mesh")
-    if args.lookahead and args.decode_block == 1:
-        sys.exit("error: --lookahead needs fused blocks to pipeline; it "
-                 "requires --decode-block > 1 (it would otherwise be "
-                 "silently ignored)")
+    _lookahead_is_the_order(args)
     if args.cluster_report or args.top:
         sys.exit("error: --cluster-report/--top aggregate across cross-host "
                  "workers (master/worker --topology runs); serving rides "
@@ -688,7 +689,6 @@ def run_serve(args) -> int:
                              block_size=(args.decode_block
                                          if args.decode_block is not None
                                          else 8),
-                             lookahead=args.lookahead,
                              kv_quant=args.kv_quant, spec_k=args.speculate,
                              **_kv_layout_kwargs(args))
     except ValueError as e:  # e.g. --max-seq not divisible by --sp
@@ -727,6 +727,17 @@ def _kv_layout_kwargs(args) -> dict:
     if args.kv_pool_pages is not None:
         kw["kv_pool_pages"] = args.kv_pool_pages
     return kw
+
+
+def _lookahead_is_the_order(args) -> None:
+    """``--lookahead`` on a batched path (``--prompts-file``, ``--mode
+    serve``): the engine has one order of work at a block boundary -- the
+    device's next block first, then the landed rows -- so the flag has
+    nothing to switch; it is taken, and says so."""
+    if args.lookahead:
+        log.warning("--lookahead changes nothing here: the batched engine "
+                    "enqueues the next decode block before it hands out a "
+                    "block's rows by itself (see MIGRATING.md)")
 
 
 def _serve_flags(args) -> list[str]:
@@ -907,9 +918,7 @@ def run_http_serve(args) -> int:
         if args.sp > 1 and args.speculate:
             sys.exit("error: --speculate requires --sp 1 on the serving "
                      "path")
-        if args.lookahead and args.decode_block == 1:
-            sys.exit("error: --lookahead needs fused blocks to pipeline; "
-                     "it requires --decode-block > 1")
+        _lookahead_is_the_order(args)
         try:
             if topo_mesh:
                 plan = MeshPlan.from_topology(config, topology, tp=args.tp,
@@ -929,7 +938,7 @@ def run_http_serve(args) -> int:
                 settings=settings, max_seq=args.max_seq,
                 block_size=(args.decode_block
                             if args.decode_block is not None else 8),
-                lookahead=args.lookahead, kv_quant=args.kv_quant,
+                kv_quant=args.kv_quant,
                 spec_k=args.speculate, logprobs=args.serve_logprobs,
                 **_kv_layout_kwargs(args))
         except ValueError as e:

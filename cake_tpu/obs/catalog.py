@@ -109,6 +109,22 @@ SERIES: dict[str, tuple[str, str]] = {
                  "rejected"),
     "disagg.transfer_ms": (
         HISTOGRAM, "export-to-ACK wall time per completed transfer"),
+    # -- the order of work at a block boundary (runtime/batch_generator) -
+    "engine.boundaries": (
+        COUNTER, "landed decode blocks after which the engine enqueued a "
+                 "next device program (a block, or an arrival's prefill)"),
+    "engine.boundaries_ahead": (
+        COUNTER, "of those, the ones whose next program was enqueued "
+                 "before any row of the landed block was handed out (all "
+                 "of them, but where a live guide, batched speculation or "
+                 "a chunked admission keeps the host between steps)"),
+    "engine.boundary_ms": (
+        HISTOGRAM, "host time from a decode block's fetch returning to "
+                   "the return of the step() call that enqueued the "
+                   "device's next program, once per landed block: the "
+                   "device has nothing to run while it lasts (next to "
+                   "nothing where an arrival's prefill was launched while "
+                   "the block still ran)"),
     # -- gateway (multi-replica routing front door) ----------------------
     "gateway.added_ms": (
         HISTOGRAM, "gateway-added latency ahead of the backend "

@@ -40,12 +40,20 @@ one remaining sp == 1 path is GPipe microbatch PREFILL (prompts at
 sp > 1 ride the ring prefill instead).
 
 Continuous batching: arrivals ``enqueue`` into a FIFO and are admitted into
-freed slots without stalling the batch — each ``step()`` advances the head
-arrival's prefill by one chunk dispatch (one replicated row into a staging
-cache, ``parallel.pipeline.build_admit_prefill``) alongside the running
-decode dispatch, then splices the finished row into its slot. ``admit()``
-is the synchronous variant. Admission timing never changes a stream's
-output (per-row positions + per-row token indices).
+freed slots without stalling the batch — the head arrival's prefill is
+*launched* the moment a slot is free (one replicated row into a staging
+cache, ``parallel.pipeline.build_admit_prefill``; a long prompt by one
+chunk dispatch per ``step()`` alongside the running decode dispatches)
+and, once the rows computed before it have been handed out, *lands*: the
+finished row is spliced into its slot. ``admit()`` is the synchronous
+variant. Admission timing never changes a stream's output (per-row
+positions + per-row token indices).
+
+One order of work at a block boundary: when a block's tokens have landed
+on the host, the device gets its next program — the next block, or a
+waiting arrival's prefill — before the landed rows are handed out, and
+delivery, retirement and bookkeeping run while it works
+(``BatchGenerator.step``, ``_enqueue_block``, ``_admission_tick``).
 
 Int8-weight determinism: ``ops.quant.quant_matmul``'s measured m>=16
 crossover would pick its backend per shape, so the SAME stream could see
@@ -72,6 +80,7 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from cake_tpu.kvpool import (
     SINK,
@@ -91,6 +100,7 @@ from cake_tpu.ops import pallas as pk
 from cake_tpu.ops import quant, sampling
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.parallel.mesh import (
+    DP,
     MeshPlan,
     init_cache_on_mesh,
     shard_params,
@@ -112,6 +122,9 @@ class _Stream:
     stream_id: int
     prompt: list[int]
     generated: list[int] = dataclasses.field(default_factory=list)
+    # how many of ``generated`` a caller has been handed; the rest are
+    # recorded rows that still wait (a landed block's)
+    handed: int = 0
     done: bool = False
     active: bool = True  # False: batch-padding dummy, never emitted
     detok: TokenOutputStream | None = None
@@ -134,6 +147,13 @@ _IMPORT_ABORTS = obs_metrics.counter("disagg.import_aborts")
 _MOE_LOCAL = obs_metrics.counter("moe.local_pairs")
 _MOE_ROUTED = obs_metrics.counter("moe.routed_pairs")
 _MOE_STEPS = obs_metrics.counter("moe.decode_steps")
+# The order of work at a block boundary (BatchGenerator._close_boundary):
+# host time from a block's fetch returning to the return of the step()
+# call that enqueued the device's next program, once per landed block,
+# and how often that program left before any of the block's rows did.
+_BOUNDARY_MS = obs_metrics.histogram("engine.boundary_ms")
+_BOUNDARIES = obs_metrics.counter("engine.boundaries")
+_BOUNDARIES_AHEAD = obs_metrics.counter("engine.boundaries_ahead")
 _KV_BLOCKS_READ = obs_metrics.counter("attn.kv_blocks_read")
 _KV_BLOCKS_RESERVED = obs_metrics.counter("attn.kv_blocks_reserved")
 
@@ -142,6 +162,30 @@ _KV_BLOCKS_RESERVED = obs_metrics.counter("attn.kv_blocks_reserved")
 # FIFO-fair between admissions and KV-page imports
 _ARR_IMPORT = "import"  # (xfer_id, None, None, _ARR_IMPORT)
 _ARR_ATTACH = "attach"  # (xfer_id, sid, None, _ARR_ATTACH)
+
+
+def _carrying(prog, steps: int, rows):
+    """A fused block program that also returns, as device values, what
+    the next block's dispatch feeds back: the last token row
+    (``toks[-1]``), the frontiers and the token indices advanced by
+    ``steps``. A steady boundary then issues ONE program call: no eager
+    slice of the un-fetched tokens (2.6 ms of host time on the chip with
+    no other thread awake, PERF.md PR 31) and no upload. Returns ``(the
+    program's outputs, (last, pos, index))``; a slot that goes out at row
+    0 (no live stream: ``BatchGenerator._decode_pos``) stays at row 0.
+    The three come back under ``rows``, the sharding an upload of a
+    per-row vector is given too, so that which of the two a dispatch
+    takes is no new program signature. The function keeps the programs'
+    name (``jit_step`` in a trace)."""
+    def step(params, token, cache, pos, keys, history, hist_slot, index,
+             *rest):
+        out = prog(params, token, cache, pos, keys, history, hist_slot,
+                   index, *rest)
+        return out, (out[0][-1].astype(jnp.int32),
+                     jnp.where(pos > 0, pos + steps, 0), index + steps)
+
+    return jax.jit(step, donate_argnums=(2,),
+                   out_shardings=(None, (rows, rows, rows)))
 
 
 class BatchGenerator:
@@ -182,7 +226,6 @@ class BatchGenerator:
         devices=None,
         block_size: int = 1,
         block_size_max: int = 0,
-        lookahead: bool = False,
         kv_quant: str | None = None,
         admit_chunk: int | None = None,
         prefix_share_min: int = 32,
@@ -320,23 +363,27 @@ class BatchGenerator:
             self.block_size_max = self.block_size
         self._adaptive = self.block_size
         self.__block_progs: dict = {}
-        # Lookahead double-buffering (r5): dispatch block N+1 from the
-        # DEVICE-side feedback token (toks[-1]) before fetching block N's
-        # rows to the host, so the device computes the next block while
-        # the host fetch for the current one is in flight. How much of a
-        # block's wall time that fetch is: not measured on the chip tool.
+        # The order of work at a block boundary: when a block's tokens have
+        # landed on the host, the device gets its next program first (the
+        # next block, dispatched from the device-side feedback token
+        # toks[-1] into ``_inflight``, or a waiting arrival's prefill) and
+        # the landed rows are handed out, one a step(), while it runs.
         # Token streams are unchanged: the feedback token is exactly the
         # one the host would have fed back, and rows computed past a
         # stream's EOS/retirement are discarded per-row like every other
-        # overrun (the admission splice drains the in-flight block's rows
-        # BEFORE a slot changes meaning — _finish_admission). Off by
-        # default; incompatible with batched speculation (the spec plane
-        # needs the host between dispatches).
-        if lookahead and spec_k:
-            raise ValueError("lookahead dispatch does not compose with "
-                             "batched speculation (spec_k)")
-        self._lookahead = bool(lookahead)
-        self._inflight: tuple | None = None  # (device toks [steps,B], size)
+        # overrun (the admission splice drains an in-flight block's rows
+        # BEFORE a slot changes meaning -- _finish_admission). Where the
+        # host must act between steps (a live guide, batched speculation,
+        # block_size 1, a chunked admission under way) nothing is
+        # enqueued ahead and the order is dispatch, fetch, hand out.
+        self._inflight: tuple | None = None  # (toks [steps,B], lpv, lpi,
+        #                                       steps, dispatched at)
+        # an open boundary: when the last block's fetch returned, whether a
+        # row of it has been handed out since, and (once the device's next
+        # program is enqueued) whether that came first -- engine.boundary_*
+        self._landed_at: float | None = None
+        self._landed_rows_out = False
+        self._next_ahead: bool | None = None
         # int8 KV roughly doubles servable batch x window on a fixed HBM
         # budget (quantize-on-write per slot, kvcache.QuantizedKV) — the
         # serving-side long-context lever
@@ -365,6 +412,8 @@ class BatchGenerator:
         self._params_int4 = _has_quant(self.params, quant.Quantized4Linear)
         self._prefill = self._pinned(build_sharded_prefill(
             config, plan, params_like=self.params, kv_quant=kv_quant))
+        # a per-row [B] vector's sharding, as the decode programs take it
+        self._rows = NamedSharding(plan.mesh, PartitionSpec(DP))
         # raw jit handle kept so tests can pin the compile count — the
         # paged layout's page-table operands are DATA, so table churn
         # (admission, retirement, page growth) must never retrace
@@ -374,16 +423,18 @@ class BatchGenerator:
             paged=self._paged,
         )
         self._decode_single = self._pinned(self._decode_single_jit)
-        self._decode_block = (
-            self._pinned(build_sharded_decode(config, self.settings, plan,
-                                              params_like=self.params,
-                                              steps=self.block_size,
-                                              per_row=True,
-                                              kv_quant=kv_quant,
-                                              logprobs_k=self.logprobs_k,
-                                              paged=self._paged))
+        # (the raw jitted callable stays reachable, as _decode_single_jit
+        # does, so that tests can pin its signatures)
+        self._decode_block_jit = (
+            _carrying(build_sharded_decode(
+                config, self.settings, plan, params_like=self.params,
+                steps=self.block_size, per_row=True, kv_quant=kv_quant,
+                logprobs_k=self.logprobs_k, paged=self._paged),
+                self.block_size, self._rows)
             if self.block_size > 1 else None
         )
+        self._decode_block = (self._pinned(self._decode_block_jit)
+                              if self.block_size > 1 else None)
         # Interleaved-microbatch schedule (pipeline.build_interleaved_decode):
         # with num_stages > 1 every stage decodes a different microbatch each
         # cycle instead of (S-1)/S of the mesh computing into a discarded
@@ -412,9 +463,10 @@ class BatchGenerator:
             if self._interleave else None
         )
         self._decode_block_il = (
-            self._pinned(build_interleaved_decode(
+            self._pinned(_carrying(build_interleaved_decode(
                 config, self.settings, plan, params_like=self.params,
-                steps=self.block_size, kv_quant=kv_quant))
+                steps=self.block_size, kv_quant=kv_quant),
+                self.block_size, self._rows))
             if self._interleave and self.block_size > 1 else None
         )
         self._base_key = jax.random.PRNGKey(self.settings.seed)
@@ -590,7 +642,6 @@ class BatchGenerator:
         if prog is None:
             from functools import partial
 
-            from jax.sharding import NamedSharding, PartitionSpec
             from cake_tpu.parallel.mesh import cache_specs
 
             out_sh = jax.tree.map(
@@ -1026,15 +1077,19 @@ class BatchGenerator:
         # stream admitted later starts its own schedule at 1)
         self._index = np.ones((b,), np.int32)
         self._emitted_first = False
-        # deque of [B] token rows: the per-step pop is O(1), not list.pop(0)
-        self._block_buf: deque[np.ndarray] = deque()
         self._spec_bank = [[] for _ in self.streams]
         self._spec_ctx = None  # fresh prompts: device ctx rows are stale
         self._spec_ctx_pos = None
-        # emission rows already recorded (admit() flushing the block buffer)
-        # but not yet handed to a step() caller
+        # rows already recorded against their streams (a landed block's,
+        # all at once; an admission's first token) but not yet handed to
+        # a step() caller, which is when a token is counted and gets its
+        # text
         self._pending_rows: list[list[Token | None]] = []
         self._inflight = None  # any prior in-flight block is stale now
+        self._landed_at = None
+        # (device value, what it holds) of the frontiers and the token
+        # indices the last block program returned: _carried
+        self._carry: list = [None, None]
         if self._paged:
             # hand the freshly prefilled contiguous cache to the pool:
             # from here on self.cache IS the page array and every decode
@@ -1637,6 +1692,7 @@ class BatchGenerator:
         s = _Stream(stream_id=sid, prompt=list(snap.prompt),
                     detok=rec["detok"])
         s.generated = list(snap.generated)
+        s.handed = len(s.generated)  # its caller replays them itself
         self.streams[slot] = s
         self._drop_guide(slot)
         if snap.guide_spec is not None:
@@ -1719,7 +1775,7 @@ class BatchGenerator:
                 self.import_abort(xid)
                 raise RuntimeError(
                     "no free slot: every stream is still live")
-            self._admission_tick()
+            self._admission_tick(wait=False)
             # a pool-deferred head — whoever owns it — can only unblock
             # via retires that never happen inside this synchronous loop
             if self._staging is None and self._admit_deferred:
@@ -1828,109 +1884,59 @@ class BatchGenerator:
         )
         jax.block_until_ready(out)
 
-    def _admission_tick(self) -> None:
-        """Advance the in-flight admission by one chunk dispatch (or start
-        the next queued arrival if a slot is free). KV-page imports
-        (cake_tpu/disagg) ride the same FIFO: a begin lands the pages in
-        the pool (deferring FIFO-fair under pool pressure exactly like a
-        prompt admission), an attach installs the resumed stream into a
-        free slot — each one tick, no prefill dispatches."""
-        if self._staging is None:
-            if not self._arrivals:
+    def _admission_due(self) -> bool:
+        """Whether step() has admission work this call (so an idle or
+        waiting batch does not flood the admit histogram with ~0 ms
+        ticks): a launched admission lands only once every recorded row
+        has been handed out."""
+        st = self._staging
+        if st is not None:
+            return "logits" not in st or not self._rows_wait()
+        if not self._arrivals:
+            return False
+        if self._arrivals[0][3] is None:  # a prompt: launched at once
+            return self._free_slot() is not None
+        return not self._pending_rows
+
+    def _rows_wait(self) -> bool:
+        """Whether rows computed before a launched admission are still to
+        be handed out (recorded ones, or a block in flight): it lands
+        after them."""
+        return bool(self._pending_rows) or self._inflight is not None
+
+    def _admission_tick(self, wait: bool = True) -> None:
+        """Advance the admission plane by one tick: *land* a launched
+        admission whose rows have all gone out, *launch* the next queued
+        arrival if a slot is free, or dispatch the in-flight admission's
+        next chunk. KV-page imports (cake_tpu/disagg) ride the same FIFO:
+        a begin lands the pages in the pool (deferring FIFO-fair under
+        pool pressure exactly like a prompt admission), an attach
+        installs the resumed stream into a free slot -- each one tick,
+        no prefill dispatches.
+
+        Launch and land are apart so that the prefill runs while rows go
+        out. Launch matches the prefix, builds the staging row and
+        dispatches the prefill; it touches no slot and no
+        ``self.streams`` entry, so it may run while ``_pending_rows`` is
+        non-empty, and while a block is in flight: the prefill then
+        follows that block on the device with no host time between them
+        (which program follows the running block is what a decision at
+        the boundary would have made it; only the enqueue is earlier).
+        Land (``_finish_admission``: fetch the logits, sample, splice,
+        install the stream) waits until every row computed before has
+        been handed out: those rows can hold a stream's EOS, which frees
+        its slot in here while the caller -- who maps a row's slots to
+        streams when it GETS the row -- has not seen those tokens yet;
+        installing then would hand the old stream's tail to the new one.
+        ``wait=False`` (the synchronous ``admit()``, whose caller is told
+        the slot) lands at once."""
+        st = self._staging
+        if st is not None and "logits" in st:
+            if wait and self._rows_wait():
                 return
-            kind = self._arrivals[0][3]
-            if kind == _ARR_IMPORT:
-                self._import_begin_tick()
-                return
-            if kind == _ARR_ATTACH:
-                if self._free_slot() is not None:
-                    self._import_attach_tick()
-                return
-            if self._free_slot() is None:
-                return
-            slot = self._free_slot()
-            if self._paged:
-                # claim point: the slot's previous stream (retired by ANY
-                # path, including a caller writing s.done directly) frees
-                # its page claims before the arrival's needs are priced
-                self._release_pages(slot)
-            ids, sid, guide, _ = self._arrivals.pop(0)
-            # Prefix reuse: an arrival whose opening tokens match a stored
-            # prefix (a staged row in the slot layout, a page chain in the
-            # paged one) starts from that content and prefills only its
-            # remainder — re-prefilling a known prefix is exactly the
-            # waste the store exists to kill. Falls back to a from-scratch
-            # prefill when the remainder's bucket would not fit above the
-            # prefix.
-            row = None
-            shared_pages: list[int] = []
-            if self._paged:
-                base = 0
-                if self._prefix_entries > 0:
-                    base, shared_pages = self._prefix_tree.match(ids)
-            else:
-                base, row = self._match_prefix(ids)
-            rem = len(ids) - base
-            chunk = self._admission_chunk_for(rem)
-            t_pad = -(-rem // chunk) * chunk
-            if base and base + t_pad > self.max_seq:
-                base, row, shared_pages = 0, None, []
-                rem = len(ids)
-                chunk = self._admission_chunk_for(rem)
-                t_pad = -(-rem // chunk) * chunk
-            if self._paged:
-                # hold the matched pages BEFORE any eviction can touch
-                # them, then price the remainder; when its pages cannot
-                # be found even by evicting warm prefixes, the arrival
-                # defers (stays FIFO head) until retirements free pages
-                for pid in shared_pages:
-                    self._pagepool.ref(pid)
-                ps = self._page_size
-                need = (len(ids) - 1) // ps + 1 - len(shared_pages)
-                if (self._pagepool.free_count < need
-                        and not self._prefix_tree.evict_until_free(need)):
-                    for pid in shared_pages:
-                        self._pagepool.unref(pid)
-                    if not self._admit_deferred:
-                        # count DEFERRED ADMISSIONS, not re-priced ticks
-                        # (the head arrival is re-tried every step while
-                        # it waits). Unreachable under the enforced pool
-                        # sizing — reachable the moment in-flight KV
-                        # transfers pin pages outside stream tables
-                        # (cake_tpu/disagg imports).
-                        self._pagepool.count_defer()
-                    self._admit_deferred = True
-                    self._arrivals.insert(0, (ids, sid, guide, None))
-                    return
-                self._admit_deferred = False
-            tokens = np.zeros((1, t_pad), np.int32)
-            tokens[0, :rem] = ids[base:]
-            if base:
-                self._prefix_hits += 1
-                if self._paged:
-                    # the staging starts as a GATHER of the shared pages
-                    # (prefix KV the remainder chunks attend), not a copy
-                    # of a stored row — the pages themselves stay shared
-                    ids_vec = np.zeros((self._ppp,), np.int32)
-                    ids_vec[: len(shared_pages)] = shared_pages
-                    cache = self._row_gather(self.cache,
-                                             jnp.asarray(ids_vec))
-                else:
-                    # copy: the admission program donates its cache
-                    # argument, and the stored row must survive for
-                    # future hits
-                    cache = jax.tree.map(lambda x: x.copy(), row)
-            else:
-                cache = init_cache_on_mesh(
-                    self.config, self.plan.mesh, batch=1,
-                    max_seq=self.max_seq, quant=self.kv_quant,
-                    batch_replicated=True,
-                )
-            self._staging = {
-                "ids": ids, "sid": sid, "slot": slot,
-                "tokens": tokens, "pos": 0, "chunk": chunk, "base": base,
-                "cache": cache, "guide": guide, "shared": shared_pages,
-            }
+            self._finish_admission()
+        if self._staging is None and not self._start_arrival(wait):
+            return
         st = self._staging
         pos, chunk, base = st["pos"], st["chunk"], st["base"]
         final = pos + chunk >= st["tokens"].shape[1]
@@ -1946,20 +1952,133 @@ class BatchGenerator:
                     jnp.int32,
                 ),
             )
-            np.asarray(logits.ravel()[:1])  # sync: busy_s must include compute
+            self._note_enqueued()
+            if not final:
+                # sync: busy_s must include compute (the last chunk's
+                # is waited for where it lands)
+                np.asarray(logits.ravel()[:1])
         self._n_admit_dispatches += 1
+        st["pos"] = pos + chunk
+        if not final:
+            self._admit_dispatched(t0, chunk, base + pos)
+            return
+        st["logits"], st["booking"] = logits, (t0, chunk, base + pos)
+        if not (wait and self._rows_wait()):
+            self._finish_admission()
+
+    def _admit_dispatched(self, t0: float, chunk: int, pos: int) -> None:
+        """Book one admission chunk whose compute has been waited for."""
         dt = time.perf_counter() - t0
         self._busy_s += dt
         self._admit_hist.observe(dt * 1e3)
         rec = obs_flight.recorder()
         if rec.enabled:
-            rec.record(
-                kind="admit", total_ms=round(dt * 1e3, 3), chunk=chunk,
-                pos=base + pos,
+            rec.record(kind="admit", total_ms=round(dt * 1e3, 3),
+                       chunk=chunk, pos=pos)
+
+    def _start_arrival(self, wait: bool = True) -> bool:
+        """Take the head of the arrival FIFO: an import or an attach runs
+        whole and leaves nothing staged (False); a prompt with a free
+        slot is *launched*: its staging row built, ``self._staging`` set
+        (True)."""
+        if not self._arrivals:
+            return False
+        kind = self._arrivals[0][3]
+        if kind in (_ARR_IMPORT, _ARR_ATTACH):
+            # these edit the pool and a slot: not under undelivered rows
+            if wait and self._pending_rows:
+                return False
+            if kind == _ARR_IMPORT:
+                self._import_begin_tick()
+            elif self._free_slot() is not None:
+                self._import_attach_tick()
+            return False
+        slot = self._free_slot()
+        if slot is None:
+            return False
+        if self._paged:
+            # claim point: the slot's previous stream (retired by ANY
+            # path, including a caller writing s.done directly) frees
+            # its page claims before the arrival's needs are priced
+            self._release_pages(slot)
+        ids, sid, guide, _ = self._arrivals.pop(0)
+        # Prefix reuse: an arrival whose opening tokens match a stored
+        # prefix (a staged row in the slot layout, a page chain in the
+        # paged one) starts from that content and prefills only its
+        # remainder — re-prefilling a known prefix is exactly the
+        # waste the store exists to kill. Falls back to a from-scratch
+        # prefill when the remainder's bucket would not fit above the
+        # prefix.
+        row = None
+        shared_pages: list[int] = []
+        if self._paged:
+            base = 0
+            if self._prefix_entries > 0:
+                base, shared_pages = self._prefix_tree.match(ids)
+        else:
+            base, row = self._match_prefix(ids)
+        rem = len(ids) - base
+        chunk = self._admission_chunk_for(rem)
+        t_pad = -(-rem // chunk) * chunk
+        if base and base + t_pad > self.max_seq:
+            base, row, shared_pages = 0, None, []
+            rem = len(ids)
+            chunk = self._admission_chunk_for(rem)
+            t_pad = -(-rem // chunk) * chunk
+        if self._paged:
+            # hold the matched pages BEFORE any eviction can touch
+            # them, then price the remainder; when its pages cannot
+            # be found even by evicting warm prefixes, the arrival
+            # defers (stays FIFO head) until retirements free pages
+            for pid in shared_pages:
+                self._pagepool.ref(pid)
+            ps = self._page_size
+            need = (len(ids) - 1) // ps + 1 - len(shared_pages)
+            if (self._pagepool.free_count < need
+                    and not self._prefix_tree.evict_until_free(need)):
+                for pid in shared_pages:
+                    self._pagepool.unref(pid)
+                if not self._admit_deferred:
+                    # count DEFERRED ADMISSIONS, not re-priced ticks
+                    # (the head arrival is re-tried every step while
+                    # it waits). Unreachable under the enforced pool
+                    # sizing — reachable the moment in-flight KV
+                    # transfers pin pages outside stream tables
+                    # (cake_tpu/disagg imports).
+                    self._pagepool.count_defer()
+                self._admit_deferred = True
+                self._arrivals.insert(0, (ids, sid, guide, None))
+                return False
+            self._admit_deferred = False
+        tokens = np.zeros((1, t_pad), np.int32)
+        tokens[0, :rem] = ids[base:]
+        if base:
+            self._prefix_hits += 1
+            if self._paged:
+                # the staging starts as a GATHER of the shared pages
+                # (prefix KV the remainder chunks attend), not a copy
+                # of a stored row — the pages themselves stay shared
+                ids_vec = np.zeros((self._ppp,), np.int32)
+                ids_vec[: len(shared_pages)] = shared_pages
+                cache = self._row_gather(self.cache,
+                                         jnp.asarray(ids_vec))
+            else:
+                # copy: the admission program donates its cache
+                # argument, and the stored row must survive for
+                # future hits
+                cache = jax.tree.map(lambda x: x.copy(), row)
+        else:
+            cache = init_cache_on_mesh(
+                self.config, self.plan.mesh, batch=1,
+                max_seq=self.max_seq, quant=self.kv_quant,
+                batch_replicated=True,
             )
-        st["pos"] = pos + chunk
-        if final:
-            self._finish_admission(logits)
+        self._staging = {
+            "ids": ids, "sid": sid, "slot": slot,
+            "tokens": tokens, "pos": 0, "chunk": chunk, "base": base,
+            "cache": cache, "guide": guide, "shared": shared_pages,
+        }
+        return True
 
     def _splice_fn(self):
         """The admission splice as ONE jitted program with the slot index
@@ -1989,20 +2108,26 @@ class BatchGenerator:
             self.__splice = jax.jit(splice)
         return self.__splice
 
-    def _finish_admission(self, logits) -> None:
-        """Splice the staged row into its slot, sample + record the first
-        token, and queue its emission row."""
+    def _finish_admission(self) -> None:
+        """Land the launched admission: sample the first token from its
+        last chunk's logits (the one wait for the device), splice the
+        staged row into its slot, record the token and queue its row."""
         st, self._staging = self._staging, None
         slot, ids, stream_id = st["slot"], st["ids"], st["sid"]
         guide = st.get("guide")
-        # Buffered block rows belong to the pre-admission state: record
-        # them before the slot's column changes meaning, so streaming
-        # step() consumers still receive every Token. An in-flight
-        # lookahead block is the same chronology, one block later — fetch
-        # and record it too (its rows are also pre-admission tokens).
+        logits = st["logits"]
+        # An in-flight block (dispatched between a chunked admission's
+        # ticks) belongs to the pre-admission state: fetch and record its
+        # rows before the slot's column changes meaning, so streaming
+        # step() consumers still receive every Token.
         self._drain_buffered_rows()
 
-        # the slot's previous stream is gone; its guide (if any) with it
+        # the slot's previous stream is gone; its guide (if any) with it,
+        # and (only under a synchronous admit(), which does not wait for
+        # the rows to go out) whatever of it was still to be handed out:
+        # it must not reach the new stream
+        for row in self._pending_rows:
+            row[slot] = None
         self._drop_guide(slot)
         if guide is not None:
             self._attach_guide(slot, guide)
@@ -2017,7 +2142,11 @@ class BatchGenerator:
             mask=jnp.asarray(guide.mask_bool()) if guide is not None
             else None,
         )
+        # the one wait for the device: the sampling above was dispatched
+        # while the prefill still ran (it was launched before the rows
+        # that have just gone out), so its host time hides behind it
         tok_id = int(tok)
+        self._admit_dispatched(*st["booking"])
         hist_row[len(tail) % n_hist] = tok_id
         lp_row = None
         if self.logprobs_k:
@@ -2095,12 +2224,8 @@ class BatchGenerator:
         if s.done:
             s.end_reason = "eos" if is_eos else "length"
         self._advance_guide(slot, s, tok_id)
-        text = (s.detok.next_token(tok_id)
-                if s.detok is not None and not is_eos else None)
-        self._n_emitted += 1
-        self._emitted_ctr.inc()
         row: list[Token | None] = [None] * len(self.streams)
-        row[slot] = Token(id=tok_id, text=text, is_end_of_stream=s.done,
+        row[slot] = Token(id=tok_id, text=None, is_end_of_stream=s.done,
                           logprobs=lp_row)
         self._pending_rows.append(row)
 
@@ -2143,18 +2268,28 @@ class BatchGenerator:
         internally. Returns False when the id is unknown (already done,
         or never admitted) — retirement races are normal for a server,
         not errors. Tokens the device already computed for the stream
-        (buffered fused-block rows, an in-flight lookahead block, banked
-        speculation runs) are discarded at emission like any other
-        past-EOS overrun."""
+        are discarded like any other past-EOS overrun: recorded rows not
+        yet handed out lose them here (and ``generated`` with them: the
+        stream ends where its caller saw it end), an in-flight block's
+        and banked speculation runs at emission."""
         self._domain_stamp.check("BatchGenerator.finish")
         for i, s in enumerate(self.streams):
-            if s.active and not s.done and s.stream_id == stream_id:
-                s.done = True
-                self._drop_guide(i)
-                # paged: retirement IS the KV free — a host-side unref
-                # loop over the slot's page list, no cache tensor touched
-                self._release_pages(i)
-                return True
+            if not s.active or s.stream_id != stream_id:
+                continue
+            undelivered = len(s.generated) - s.handed
+            if s.done and not undelivered:
+                continue  # over already, as its caller has seen
+            if undelivered:
+                for row in self._pending_rows:
+                    row[i] = None
+                del s.generated[s.handed:]
+                s.end_reason = None  # an end recorded past this point
+            s.done = True
+            self._drop_guide(i)
+            # paged: retirement IS the KV free — a host-side unref
+            # loop over the slot's page list, no cache tensor touched
+            self._release_pages(i)
+            return True
         if self._staging is not None and self._staging["sid"] == stream_id:
             if self._paged:
                 for pid in self._staging.get("shared", []):
@@ -2193,7 +2328,7 @@ class BatchGenerator:
                 self._arrivals = [a for a in self._arrivals
                                   if a[0] is not ids]
                 raise RuntimeError("no free slot: every stream is still live")
-            self._admission_tick()
+            self._admission_tick(wait=False)
             if self._staging is None and self._admit_deferred:
                 # paged pool pressure: nothing inside a synchronous
                 # admit() will retire streams and free pages, so busy-
@@ -2204,17 +2339,45 @@ class BatchGenerator:
                     "kv page pool exhausted: admission deferred (retire "
                     "streams via step()/finish(), or grow kv_pool_pages)")
         # the emission row just queued duplicates the returned Token: drop it
-        row = self._pending_rows.pop()
+        row = self._hand_out(self._pending_rows.pop())
         slot = next(i for i, t in enumerate(row) if t is not None)
         return slot, row[slot]
 
     # -- stepping ------------------------------------------------------------
     def _emit(self, row: np.ndarray, skip: list[bool] | None = None,
               lp=None) -> list[Token | None]:
+        """Record one [B] token row and hand it out at once (a step()
+        that returns the row it just fetched)."""
+        return self._hand_out(self._record(row, skip=skip, lp=lp))
+
+    def _hand_out(self, row: list[Token | None]) -> list[Token | None]:
+        """A recorded row leaves for the caller: its tokens are counted
+        as emitted and get their text now, not when they were recorded,
+        so a stream retired mid-block (``finish``) has neither counted
+        nor detokenized what its caller never saw."""
+        emitted = 0
+        with self._prof.phase("emit"):
+            for i, tok in enumerate(row):
+                if tok is None:
+                    continue
+                emitted += 1
+                s = self.streams[i]
+                s.handed += 1
+                # the EOS id is an end marker, not text: detokenizing it
+                # would append its (toy tokenizers: arbitrary) surface form
+                if s.detok is not None and tok.id not in self._eos_ids:
+                    tok.text = s.detok.next_token(tok.id)
+        self._n_emitted += emitted
+        self._emitted_ctr.inc(emitted)
+        return row
+
+    def _record(self, row: np.ndarray, skip: list[bool] | None = None,
+                lp=None) -> list[Token | None]:
         """Turn one [B] token row into per-stream Tokens (None when done or
-        dummy), updating per-stream bookkeeping. ``skip[i]`` excludes a
-        stream from this row without marking it done. ``lp`` is the
-        optional per-row top-k logprob pair ``(vals [B, K], ids [B, K])``.
+        dummy; their text comes at ``_hand_out``), updating per-stream
+        bookkeeping. ``skip[i]`` excludes a stream from this row without
+        marking it done. ``lp`` is the optional per-row top-k logprob pair
+        ``(vals [B, K], ids [B, K])``.
         Constrained streams advance their host-side DFA cursor here —
         the one host-side step per token the no-retrace design needs."""
         lpv, lpi = lp if lp is not None else (None, None)
@@ -2238,31 +2401,23 @@ class BatchGenerator:
                     # here — the slot is admissible the moment the row
                     # is emitted
                     self._release_pages(i)
-                # the EOS id is an end marker, not text: detokenizing it
-                # would append its (toy tokenizers: arbitrary) surface form
-                text = (s.detok.next_token(tok_id)
-                        if s.detok is not None and not is_eos else None)
                 lp_i = None
                 if lpv is not None:
                     lp_i = [(int(lpi[i, j]), float(lpv[i, j]))
                             for j in range(lpi.shape[1])]
-                out.append(Token(id=tok_id, text=text,
+                out.append(Token(id=tok_id, text=None,
                                  is_end_of_stream=s.done, logprobs=lp_i))
-        emitted = sum(1 for t in out if t is not None)
-        self._n_emitted += emitted
-        self._emitted_ctr.inc(emitted)
         return out
 
-    def _emit_buffered(self, entry) -> list[Token | None]:
-        """Emit one buffered fused-block row: ``(row [B], lp-or-None)``."""
-        row, lp = entry
-        return self._emit(row, lp=lp)
-
     def step(self) -> list[Token | None]:
-        """Advance every live stream one token; returns one entry per active
-        stream slot (None for finished/dummy streams). A queued arrival
-        (``enqueue``) advances by one admission-prefill chunk per call,
-        interleaved with the decode dispatches."""
+        """Hand out one row: one entry per stream slot (None for
+        finished/dummy streams), one token further for every live stream.
+        The call in which a fused block lands records all of its rows and
+        returns an all-None row; the calls after it first give the device
+        its next program (the next block, or a queued arrival's prefill:
+        ``_admission_tick``) and then hand the rows out one by one. A
+        chunked admission (``admit_chunk``) advances by one chunk per
+        call, interleaved with the decode dispatches."""
         self._domain_stamp.check("BatchGenerator.step")
         if not self.streams:
             raise RuntimeError("set_prompts first")
@@ -2280,24 +2435,47 @@ class BatchGenerator:
                     skip=[bool(s.generated) for s in self.streams],
                     lp=self._first_lp,
                 )
-            # A NEW arrival may claim a slot only once every emitted row
-            # has been handed out: rows drained early (an admission's
-            # splice emits the buffered block ahead of delivery) can hold
-            # a stream's EOS, which frees its slot in here while the
-            # caller -- who maps a row's slots to streams when it GETS
-            # the row -- has not seen those tokens yet. Claiming then
-            # would hand the old stream's tail to the new one.
-            if self._staging is not None or (
-                    self._arrivals and not self._pending_rows):
-                # stamp only real admission work, or an idle batch would
-                # flood the admit histogram with ~0 ms no-op ticks
+            if self._inflight is not None and not any(
+                    st.active and not st.done for st in self.streams):
+                # every stream the in-flight block was dispatched for has
+                # been retired since: nothing of it is anyone's, and an
+                # arrival must not wait for a fetch of it
+                self._inflight = None
+                if self._moe_pending:
+                    self._moe_pending.pop()
+            if self._admission_due():
                 with prof.phase("admit"):
                     self._admission_tick()
             if self._pending_rows:
-                return self._pending_rows.pop(0)
+                # first the device's next program, then a row
+                self._enqueue_block()
+                if self._landed_at is not None:
+                    self._landed_rows_out = True
+                return self._hand_out(self._pending_rows.pop(0))
             return self._step_decode()
         finally:
+            self._close_boundary()
             prof.step_end()
+
+    def _note_enqueued(self) -> None:
+        """A device program was just enqueued: if a landed block's
+        boundary is open, this is its next program."""
+        if self._landed_at is not None and self._next_ahead is None:
+            self._next_ahead = not self._landed_rows_out
+
+    def _close_boundary(self) -> None:
+        """At the return of a step(): if this call enqueued the next
+        program after a landed block, the boundary is over --
+        ``engine.boundary_ms`` takes the host time since the fetch
+        returned, ``engine.boundaries_ahead`` whether the program left
+        before any of the block's rows did."""
+        if self._landed_at is None or self._next_ahead is None:
+            return
+        _BOUNDARY_MS.observe((time.perf_counter() - self._landed_at) * 1e3)
+        _BOUNDARIES.inc()
+        if self._next_ahead:
+            _BOUNDARIES_AHEAD.inc()
+        self._landed_at = self._next_ahead = None
 
     def _spec_emit_or_round(self):
         """Drain the per-stream accepted-token banks one row per call;
@@ -2366,6 +2544,7 @@ class BatchGenerator:
                 self.params, jnp.asarray(fed), self.cache,
                 jnp.asarray(self._pos),
             )
+        self._note_enqueued()
         with self._prof.phase("spec_accept"), self._sentinel.decode_phase():
             if self.settings.greedy:
                 (toks, count, self._history,
@@ -2523,6 +2702,7 @@ class BatchGenerator:
                     done, last, self._keys)
                 toks_rounds.append(toks)
                 n_rounds.append(n)
+        self._note_enqueued()
         # one combined fetch — two sequential _host calls would pay a
         # second host sync, the very latency the chain amortizes
         # (cross-process dp still takes the allgather path per array)
@@ -2616,16 +2796,17 @@ class BatchGenerator:
         prog = self.__block_progs.get(key)
         if prog is None:
             if il_ok:
-                prog = self._pinned(build_interleaved_decode(
+                prog = build_interleaved_decode(
                     self.config, self.settings, self.plan,
                     params_like=self.params, steps=steps,
-                    kv_quant=self.kv_quant))
+                    kv_quant=self.kv_quant)
             else:
-                prog = self._pinned(build_sharded_decode(
+                prog = build_sharded_decode(
                     self.config, self.settings, self.plan,
                     params_like=self.params, steps=steps, per_row=True,
                     kv_quant=self.kv_quant,
-                    logprobs_k=self.logprobs_k, paged=self._paged))
+                    logprobs_k=self.logprobs_k, paged=self._paged)
+            prog = self._pinned(_carrying(prog, steps, self._rows))
             self.__block_progs[key] = prog
         return prog
 
@@ -2674,52 +2855,113 @@ class BatchGenerator:
             jax.block_until_ready(out)
 
     def drain(self) -> None:
-        """EMIT everything the device has already computed — buffered
-        block rows first, then any in-flight lookahead block — without
-        dispatching further work. The shutdown / measurement boundary:
-        tokens are recorded against their streams and counted immediately
-        (same `_emit` path as stepping); the Token rows land in the
-        pending queue for any consumer still calling step()."""
+        """RECORD everything the device has already computed -- the
+        in-flight block -- without dispatching further work. The
+        shutdown / measurement boundary: tokens are recorded against
+        their streams immediately (same `_record` path as stepping); the
+        Token rows land in the pending queue for any consumer still
+        calling step(), which is where they are counted as emitted."""
         self._domain_stamp.check("BatchGenerator.drain")
         self._drain_buffered_rows()
 
     def _drain_buffered_rows(self) -> None:
-        """Record every device-computed-but-unemitted row (buffered fused
-        -block rows, then any in-flight lookahead block) into the pending
-        queue — shared by drain(), the admission splice, the import
+        """Fetch an in-flight block and record its rows into the pending
+        queue -- shared by drain(), the admission splice, the import
         attach, and export (all points where a slot's column is about to
-        change meaning or the emitted state must be complete)."""
-        while self._block_buf:
-            self._pending_rows.append(
-                self._emit_buffered(self._block_buf.popleft()))
+        change meaning or the recorded state must be complete)."""
         if self._inflight is not None:
-            toks, lpv, lpi, _ = self._inflight
-            self._inflight = None
-            t0 = time.perf_counter()
-            rows = self._host(toks)
+            self._land_block()
+
+    def _land_block(self) -> float:
+        """Fetch the in-flight block's ``[steps, B]`` tokens (the host
+        round trip) and record all of its rows at once into the pending
+        queue: EOS and window retirements are known from here on, so the
+        next dispatch goes out with the right frontiers and a freed slot
+        can be launched into. Returns when the fetch returned."""
+        toks, lpv, lpi, size, t0 = self._inflight
+        self._inflight = None
+        with self._prof.phase("sync"):
+            rows = self._host(toks)  # [steps, B]
             lp = ((self._host(lpv), self._host(lpi))
                   if lpv is not None else None)
             self._record_moe_count()
-            self._busy_s += time.perf_counter() - t0
+        landed = time.perf_counter()
+        dt = landed - t0
+        self._busy_s += dt
+        # per-token ms so the series is comparable across block sizes
+        self._dispatch_hist.observe(dt * 1e3 / max(1, size))
+        rec = obs_flight.recorder()
+        if rec.enabled:
+            rec.record(
+                kind="decode", total_ms=round(dt * 1e3, 3), steps=size,
+                batch=len(self.streams),
+            )
+        with self._prof.phase("emit"):
             for i in range(rows.shape[0]):
-                self._pending_rows.append(self._emit(
+                self._pending_rows.append(self._record(
                     rows[i], lp=(lp[0][i], lp[1][i]) if lp else None))
+        return landed
 
-    def _dispatch_block(self, size: int):
+    def _enqueue_block(self, ahead: bool = True) -> bool:
+        """Give the device its next fused block, into ``_inflight``, and
+        say whether that happened. ``ahead`` (rows are still to be handed
+        out): not if the device has a program already (a block in
+        flight, an admission under way: its prefill is the next program,
+        and a chunked one keeps its one tick a step) and not where the
+        host must act between steps, which the engine knows from its own
+        state: batched speculation (rounds). Never under a live guide
+        (the DFA advance is host-side, so a block would sample tokens
+        2..K against a stale mask row), without a block program
+        (block_size 1) or without a live stream."""
+        if ahead and (self._inflight is not None
+                      or self._staging is not None or self._spec_k):
+            return False
+        if self._guides_live():
+            return False
+        # Capacity is per-stream: a finished stream's row keeps advancing
+        # (its clamped writes touch only its own cache row, whose output is
+        # discarded), so only LIVE streams gate block decode and exhaustion —
+        # a long stream hitting its window must not kill shorter ones.
+        live = [
+            self._pos[i]
+            for i, s in enumerate(self.streams)
+            if s.active and not s.done
+        ]
+        if not live:
+            self._landed_at = None  # nothing follows: no boundary
+            return False
+        if (self._decode_block is None
+                and self.block_size_max <= self.block_size):
+            return False
+        # Fused-block eligibility is per-row, not batch-global: a stream
+        # that fills its window inside the block only clamp-writes its OWN
+        # cache row past the frontier (per-row dynamic_update_slice), and
+        # _record marks it done at the window-filling token so the overrun
+        # outputs are discarded — one long stream near its edge must not
+        # force every stream to single-step dispatches.
+        size = self._pick_block_size(live)
+        if size <= 1:
+            return False
+        self._inflight = self._dispatch_block(size)
+        return True
+
+    def _dispatch_block(self, size: int) -> tuple:
         """Dispatch one fused decode block (async): the device-side state
         (cache / history / feedback token futures) and the host-side
         pos/index advance immediately; the ``[size, B]`` token rows (and
-        top-k logprob rows when enabled) return UN-fetched so the caller
-        chooses when to pay the host round-trip (the lookahead path
-        dispatches the next block first)."""
+        top-k logprob rows when enabled) return UN-fetched, as the
+        ``_inflight`` entry, so that rows already recorded go out while
+        the device works and ``_land_block`` pays the host round trip
+        when they have."""
+        t0 = time.perf_counter()
         with self._prof.phase("dispatch", steps=size,
                               batch=len(self.streams)), \
                 self._sentinel.decode_phase():
             pos = self._decode_pos()
-            out = self._block_prog(size)(
+            out, (last, pos_next, index_next) = self._block_prog(size)(
                 self.params, self._last_tokens, self.cache,
-                jnp.asarray(pos), self._keys, self._history,
-                self._hist_slot, jnp.asarray(self._index),
+                self._carried(0, pos), self._keys, self._history,
+                self._hist_slot, self._carried(1, self._index),
                 *self._paged_args(size),
             )
             out = self._take_moe_count(out, size)
@@ -2729,12 +2971,26 @@ class BatchGenerator:
             else:
                 toks, self.cache, self._history, self._hist_slot = out
                 lpv = lpi = None
+        self._note_enqueued()
         self._n_decode_dispatches += 1
         self._count_kv_blocks(pos, size)
         self._pos = self._pos + size
         self._index = self._index + size
-        self._last_tokens = toks[-1].astype(jnp.int32)
-        return toks, lpv, lpi
+        self._last_tokens = last
+        self._carry = [(pos_next, np.where(pos > 0, pos + size, 0)),
+                       (index_next, self._index)]
+        return toks, lpv, lpi, size, t0
+
+    def _carried(self, which: int, want: np.ndarray):
+        """The frontiers (0) or token indices (1) a block goes out with,
+        as a device value: the one the last block program returned if it
+        still holds what the host wants (nothing changed a row since: no
+        splice, retirement, ``finish()``, single step or round), else an
+        upload of ``want``."""
+        held = self._carry[which]
+        if held is not None and np.array_equal(held[1], want):
+            return held[0]
+        return jax.device_put(want, self._rows)
 
     def _decode_pos(self) -> np.ndarray:
         """The frontiers a decode dispatch goes out with: a live stream's
@@ -2782,21 +3038,27 @@ class BatchGenerator:
         _MOE_STEPS.inc(steps)
 
     def _step_decode(self):
-        # Buffered fused-block rows are EARLIER tokens than anything a new
-        # spec round would produce: drain them first, or a round that finds
-        # proposals mid-drain would emit later tokens ahead of buffered
-        # earlier ones and scramble per-stream order (r4 review repro).
-        if self._block_buf:
-            return self._emit_buffered(self._block_buf.popleft())
+        """No recorded row is left to hand out. Spec rounds, if any; else
+        the device's block: the one enqueued while the last rows went out
+        (or, where nothing is enqueued ahead, dispatched now) lands, all
+        of its rows are recorded, and the call returns an all-None row:
+        the caller's pass between "tokens landed" and "next program
+        enqueued" then carries nothing (no handler is woken, an arrival
+        that came in during the block reaches ``_arrivals``), and the
+        next step() enqueues before it hands out a row. Else one
+        single-step dispatch."""
         if self._spec_k:
             row = self._spec_emit_or_round()
             if row is not None:
                 return row
-
-        # Capacity is per-stream: a finished stream's row keeps advancing
-        # (its clamped writes touch only its own cache row, whose output is
-        # discarded), so only LIVE streams gate block decode and exhaustion —
-        # a long stream hitting its window must not kill shorter ones.
+        if self._inflight is not None or self._enqueue_block(ahead=False):
+            self._landed_at = self._land_block()
+            self._landed_rows_out = False
+            # an admission launched while the block ran is the device's
+            # next program already
+            launched = self._staging is not None and "logits" in self._staging
+            self._next_ahead = True if launched else None
+            return [None] * len(self.streams)
         live = [
             self._pos[i]
             for i, s in enumerate(self.streams)
@@ -2804,66 +3066,9 @@ class BatchGenerator:
         ]
         if not live:
             return [None] * len(self.streams)
-        # Fused-block eligibility is per-row, not batch-global: a stream
-        # that fills its window inside the block only clamp-writes its OWN
-        # cache row past the frontier (per-row dynamic_update_slice), and
-        # _emit marks it done at the window-filling token so the overrun
-        # outputs are discarded — one long stream near its edge must not
-        # force every stream to single-step dispatches.
-        #
-        # Constrained streams (attached Guides) pin the WHOLE batch to
-        # single-step masked dispatches: the DFA advance is host-side
-        # between steps, so a fused block (or a lookahead dispatch) would
-        # sample tokens 2..K against a stale mask row. The moment the last
-        # constrained stream retires, block/lookahead dispatch resumes.
         constrained = self._guides_live()
-        toks = lpv = lpi = None
-        if self._inflight is not None:
-            toks, lpv, lpi, _ = self._inflight  # consume pipelined block
-            self._inflight = None
-        elif not constrained:
-            can_block = (self._decode_block is not None
-                         or self.block_size_max > self.block_size)
-            size = self._pick_block_size(live) if can_block else 1
-            if size > 1:
-                toks, lpv, lpi = self._dispatch_block(size)
-        if toks is not None:
-            t0 = time.perf_counter()
-            size = len(toks)
-            if (self._lookahead and not self._arrivals
-                    and self._staging is None and not constrained):
-                # pipeline the NEXT block before this one's host fetch:
-                # EOS/retirement inside the fetched block only discards
-                # per-row outputs (the standard overrun invariant)
-                nsize = self._pick_block_size(
-                    [self._pos[i] for i, s in enumerate(self.streams)
-                     if s.active and not s.done]
-                )
-                if nsize > 1:
-                    self._inflight = self._dispatch_block(nsize) + (nsize,)
-            with self._prof.phase("sync"):
-                rows = self._host(toks)  # [steps, B]
-                lp_h = ((self._host(lpv), self._host(lpi))
-                        if lpv is not None else None)
-                self._record_moe_count()
-            dt = time.perf_counter() - t0
-            self._busy_s += dt
-            # per-token ms so the series is comparable across block sizes
-            self._dispatch_hist.observe(dt * 1e3 / max(1, size))
-            rec = obs_flight.recorder()
-            if rec.enabled:
-                rec.record(
-                    kind="decode", total_ms=round(dt * 1e3, 3), steps=size,
-                    batch=len(self.streams),
-                )
-            self._block_buf = deque(
-                (rows[i],
-                 (lp_h[0][i], lp_h[1][i]) if lp_h is not None else None)
-                for i in range(rows.shape[0])
-            )
-            return self._emit_buffered(self._block_buf.popleft())
 
-        if int(max(live)) >= self.max_seq:  # unreachable: _emit marks
+        if int(max(live)) >= self.max_seq:  # unreachable: _record marks
             raise RuntimeError("KV cache exhausted")  # window-full streams done
         t0 = time.perf_counter()
         pos = self._decode_pos()
@@ -2886,6 +3091,7 @@ class BatchGenerator:
             else:
                 out = self._pick_decode(block=False)(
                     *args, *self._paged_args(1))
+        self._note_enqueued()
         out = self._take_moe_count(out, 1)
         if self.logprobs_k:
             (tok, self.cache, self._history, self._hist_slot,
@@ -2972,8 +3178,9 @@ class BatchGenerator:
         live stream has this call's quota instead of a fixed step count —
         identical behavior on the plain one-token-per-step path. A stream
         admitted into a slot mid-call starts its quota from zero."""
-        start = {i: (s, len(s.generated))
-                 for i, s in enumerate(self.streams)}
+        # counted in tokens handed out by step(): a landed block's rows
+        # are recorded at once, ahead of the calls that hand them out
+        start = {i: (s, s.handed) for i, s in enumerate(self.streams)}
 
         def quota_met() -> bool:
             for i, s in enumerate(self.streams):
@@ -2981,7 +3188,7 @@ class BatchGenerator:
                     continue
                 s0, b = start.get(i, (None, 0))
                 base = b if s0 is s else 0
-                if len(s.generated) - base < max_new_tokens:
+                if s.handed - base < max_new_tokens:
                     return False
             return True
 

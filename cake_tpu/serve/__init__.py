@@ -2,8 +2,9 @@
 
 The reference is strictly single-request and in-process; the engine here
 (``runtime.batch_generator.BatchGenerator``) already out-builds it —
-continuous batching, shared-prefix reuse, adaptive decode blocks,
-lookahead dispatch, batched speculation — but an engine only becomes a
+continuous batching, shared-prefix reuse, adaptive decode blocks, the
+device's next program enqueued before a block's rows are handed out,
+batched speculation — but an engine only becomes a
 *service* with a serving front end (the Orca / vLLM lesson: request
 queueing, admission, streaming, cancellation are their own subsystem).
 That front end is this package, stdlib-only:

@@ -3,8 +3,8 @@
 Shows the serving plane the reference has no equivalent of
 (SURVEY.md §0: strictly single-request): N concurrent streams over one
 model instance, continuous admission of arrivals mid-run, the adaptive
-decode-block ladder, and lookahead double-buffered dispatch. Runs on CPU
-in a few seconds:
+decode-block ladder, and the next block enqueued before a block's rows are
+handed out. Runs on CPU in a few seconds:
 
     python examples/serve_demo.py
 
@@ -30,7 +30,6 @@ def main() -> None:
         settings=SamplerSettings(temperature=0.8, top_k=40, seed=7),
         block_size=2,        # fused decode steps per dispatch (base)
         block_size_max=8,    # ...doubling while no arrival waits
-        lookahead=True,      # dispatch block N+1 before fetching block N
         admit_chunk=32,      # admission prefill chunk per step
     )
 
@@ -46,13 +45,15 @@ def main() -> None:
     gen.enqueue([4, 4, 2, 9, 1, 3], stream_id=99)
     for _ in range(14):
         gen.step()
-    gen.drain()  # emit what the lookahead pipeline already computed
+    gen.drain()  # record what the in-flight block already computed
 
     for s in gen.streams:
         print(f"stream {s.stream_id}: prompt {s.prompt} -> "
               f"{len(s.generated)} tokens {s.generated[:10]}...")
     st = gen.stats()
-    print(f"\n{st['tokens_emitted']} tokens in {st['decode_dispatches']} "
+    recorded = sum(len(s.generated) for s in gen.streams)
+    print(f"\n{recorded} tokens recorded ({st['tokens_emitted']} of them "
+          f"handed out by step()) in {st['decode_dispatches']} "
           f"decode + {st['admit_dispatches']} admission dispatches "
           f"({st['tokens_per_dispatch']} tokens/dispatch, "
           f"busy {st['busy_s']}s of {st['wall_s']}s wall)")

@@ -156,7 +156,10 @@ def test_window_edge_stream_keeps_batch_on_block_dispatch(params):
     n = 20
     outs = g.generate(n)
     assert calls["single"] == 0
-    assert calls["block"] == -(-(n - 1) // block)  # first token from prefill
+    # first token from prefill; one more block is in flight, enqueued
+    # before the last landed rows went out
+    assert calls["block"] == -(-(n - 1) // block) + 1
+    assert g._inflight is not None
     assert len(outs[0]) == 32 - len(edge_prompt)  # edge filled its window
     solo_edge = _single_stream(params, edge_prompt, n, settings)
     # solo run raises window exhaustion at the same boundary; compare prefix
@@ -176,14 +179,15 @@ def test_admit_refills_finished_slot(params, block_size):
     long_prompt = list(range(2, 28))  # 26 tokens -> done after 6
     g = BG(cfg, params, settings=settings, dp=1, block_size=block_size)
     g.set_prompts([long_prompt, PROMPTS[1]], stream_ids=[0, 1])
-    for _ in range(6):
-        g.step()
+    for _ in range(12):  # until its last token has been handed out (a
+        if not g.streams[0].done or g._pending_rows:  # landing hands out
+            g.step()                                  # no row)
     assert g.streams[0].done and not g.streams[1].done
 
     slot, first = g.admit(PROMPTS[2], stream_id=7)
     assert slot == 0
     collected = [first.id]
-    for _ in range(7):
+    for _ in range(12):
         row = g.step()
         if row[0] is not None:
             collected.append(row[0].id)
@@ -231,11 +235,15 @@ def test_admit_flush_preserves_streamed_tokens(params):
                 sid = g.streams[i].stream_id
                 received[sid].append(t.id)
 
-    for _ in range(6):
-        collect(g.step())
+    for _ in range(12):  # until its last token has been handed out (a
+        if not g.streams[0].done or g._pending_rows:  # landing hands out
+            collect(g.step())                         # no row)
     slot, first = g.admit(PROMPTS[2], stream_id=7)
     received[7].append(first.id)
     for _ in range(8):
+        collect(g.step())
+    g.drain()
+    while g._pending_rows:
         collect(g.step())
     # every recorded token reached the streaming consumer, in order
     for s in g.streams:
@@ -578,7 +586,7 @@ def test_serving_stats_track_dispatches_and_tokens(params):
         g.step()
     g.streams[0].done = True
     g.enqueue([2, 8, 1], stream_id=5)
-    for _ in range(4):
+    for _ in range(12):  # it is launched at the next block boundary
         g.step()
     st = g.stats()
     assert st["tokens_emitted"] > 0
@@ -1044,61 +1052,404 @@ def test_block_size_max_rounds_down_to_ladder(params):
     assert g.block_size_max == 4
 
 
-def test_lookahead_dispatch_bit_identical_with_admission(params):
-    """r5: lookahead double-buffering (dispatch block N+1 before fetching
-    block N) must not change any stream's tokens — the device feedback
-    token is exactly the host's, and an admission mid-flight drains the
-    in-flight block's rows before the slot changes meaning."""
+def _boundary_counters():
+    from cake_tpu.obs import metrics
+
+    reg = metrics.registry()
+    return (reg.counter("engine.boundaries").value,
+            reg.counter("engine.boundaries_ahead").value,
+            reg.histogram("engine.boundary_ms").snapshot().get("count", 0))
+
+
+def test_one_order_bit_identical_with_admission(params):
+    """The order of work at a block boundary (the next block is dispatched
+    from the device's feedback token before the landed rows go out; an
+    arrival's prefill is launched before them and lands after them) must
+    not change any stream's tokens against single-step dispatches, chunked
+    admission included -- the device feedback token is exactly the host's,
+    and an admission drains an in-flight block's rows before the slot
+    changes meaning."""
     settings = SamplerSettings(**GREEDY)
     new_prompt = [2, 8, 1, 7, 6, 5, 4, 3]
 
-    def run(look):
-        g = BG(CFG, params, settings=settings, block_size=2,
-               block_size_max=8, lookahead=look, admit_chunk=4)
+    def run(**kw):
+        g = BG(CFG, params, settings=settings, **kw)
         g.set_prompts([list(PROMPTS[0]), list(PROMPTS[1])])
-        for _ in range(6):
+        engaged = False
+        for _ in range(8):
             g.step()
-        if look:
-            assert g._inflight is not None  # the pipeline actually engaged
+            engaged |= g._inflight is not None
         g.streams[0].done = True
         g.enqueue(list(new_prompt), stream_id=7)
-        for _ in range(16):
+        for _ in range(24):
             g.step()
-        return {s.stream_id: list(s.generated) for s in g.streams}
+        return engaged, {s.stream_id: list(s.generated) for s in g.streams}
 
-    got, want = run(True), run(False)
-    assert set(got) == set(want) == {1, 7}
-    for sid in got:
-        n = min(len(got[sid]), len(want[sid]))
-        assert n >= 4 and got[sid][:n] == want[sid][:n]
+    _, want = run(block_size=1)
+    for kw in (dict(block_size=2, block_size_max=8),
+               dict(block_size=2, block_size_max=8, admit_chunk=4),
+               dict(block_size=4)):
+        engaged, got = run(**kw)
+        assert engaged, kw  # a block was in flight while rows went out
+        assert set(got) == set(want) == {1, 7}
+        for sid in got:
+            n = min(len(got[sid]), len(want[sid]))
+            assert n >= 4 and got[sid][:n] == want[sid][:n], (kw, sid)
 
 
-def test_lookahead_rejects_speculation(params):
+@pytest.mark.parametrize("case", ["spec", "block1"])
+def test_nothing_is_enqueued_ahead_where_the_host_acts_between_steps(
+        params, case):
+    """Batched speculation runs rounds between fetches, and ``block_size``
+    1 has no block to enqueue: the engine knows both from its own state
+    and keeps the order dispatch, fetch, hand out (a live guide does the
+    same: tests/test_constrain.py). No switch says so, and none is left:
+    ``BatchGenerator(lookahead=...)`` is gone."""
     settings = SamplerSettings(**GREEDY)
-    with pytest.raises(ValueError, match="lookahead"):
-        BG(CFG, params, settings=settings, lookahead=True, spec_k=4)
+    with pytest.raises(TypeError, match="lookahead"):
+        BG(CFG, params, settings=settings, lookahead=True)
+    kw = dict(spec_k=4, block_size=4) if case == "spec" else dict(
+        block_size=1)
+    g = BG(CFG, params, settings=settings, **kw)
+    g.set_prompts([list(p) for p in PROMPTS])
+    b0, a0, _ = _boundary_counters()
+    got = {i: [] for i in range(len(PROMPTS))}
+    for _ in range(40):
+        row = g.step()
+        assert g._inflight is None  # nothing left in flight by a step()
+        for i, tok in enumerate(row):
+            if tok is not None:
+                got[i].append(tok.id)
+    b1, a1, _ = _boundary_counters()
+    assert a1 == a0  # no boundary's next program left before its rows
+    if case == "block1":
+        assert b1 == b0  # no block ever landed
+    for i, p in enumerate(PROMPTS):
+        assert len(got[i]) >= 10
+        assert got[i] == _single_stream(params, p, len(got[i]), settings)
 
 
-def test_lookahead_drain_emits_inflight_tokens(params):
+def test_drain_records_the_inflight_block(params):
     """drain() at a measurement/shutdown boundary fetches the in-flight
     block without dispatching more; its tokens continue the stream's
-    oracle sequence exactly."""
+    oracle sequence exactly, and reach a consumer that keeps stepping."""
     settings = SamplerSettings(**GREEDY)
-    g = BG(CFG, params, settings=settings, block_size=2, block_size_max=4,
-           lookahead=True)
+    g = BG(CFG, params, settings=settings, block_size=2, block_size_max=4)
     g.set_prompts([list(PROMPTS[0])])
+    handed = []
     for _ in range(4):
-        g.step()
+        handed += [t.id for t in g.step() if t is not None]
     assert g._inflight is not None
     dispatches_before = g.stats()["decode_dispatches"]
     before = len(g.streams[0].generated)
     g.drain()
-    assert g._inflight is None and not g._block_buf
+    assert g._inflight is None
     got = list(g.streams[0].generated)
     assert len(got) > before
     assert g.stats()["decode_dispatches"] == dispatches_before  # no new work
     want = _single_stream(params, PROMPTS[0], len(got), settings)
     assert got == want[: len(got)]
+    # recorded is not handed out: the rows wait for whoever steps on, and
+    # are counted as emitted when they leave
+    assert g.stats()["tokens_emitted"] == len(handed) < len(got)
+    while len(handed) < len(got):
+        handed += [t.id for t in g.step() if t is not None]
+    assert handed[: len(got)] == got
+
+
+def _recording(g, log):
+    """Wrap the two calls that enqueue a device program so that each
+    leaves its name in ``log``; step() results are logged by the caller."""
+    block, prefill = g._dispatch_block, g._admit_prefill
+
+    def dispatch_block(size):
+        log.append("block")
+        return block(size)
+
+    def admit_prefill(*args):
+        log.append("prefill")
+        return prefill(*args)
+
+    g._dispatch_block = dispatch_block
+    g._BatchGenerator__admit_prefill = admit_prefill
+    return g
+
+
+@pytest.mark.parametrize("arrival", [False, True])
+def test_the_next_program_is_enqueued_before_a_landed_row_leaves(params,
+                                                                 arrival):
+    """At a block boundary the device gets its next program first: the
+    call in which a block lands hands out nothing (so the server's pass
+    up to the enqueue carries no delivery), the next call enqueues the
+    next block -- or, when an arrival waits and a slot is free, launches
+    its prefill -- and only then returns the landed block's first row. An
+    admission lands (its stream installed, its first token queued) after
+    the block's last row has gone out, and the block that follows is
+    dispatched before that first token's row leaves."""
+    settings = SamplerSettings(**GREEDY)
+    log: list = []
+    g = BG(CFG, params, settings=settings, block_size=4)
+    g.warm_admission(8)
+    g.set_prompts([list(PROMPTS[0]), list(PROMPTS[1])], stream_ids=[0, 1])
+    if arrival:
+        g.streams[1].done = True  # a slot retired, as the scheduler does
+    _recording(g, log)
+    b0, a0, n0 = _boundary_counters()
+
+    def pump(n):
+        for _ in range(n):
+            row = g.step()
+            log.append(("row", [g.streams[i].stream_id
+                                for i, t in enumerate(row) if t is not None]))
+
+    pump(2)  # first tokens; then block 1 is dispatched, lands, all-None
+    assert log[-2:] == ["block", ("row", [])]
+    if arrival:
+        g.enqueue([2, 8, 1], stream_id=7)  # came in while block 1 ran
+    del log[:]
+    pump(1)
+    first = "prefill" if arrival else "block"
+    live = [0] if arrival else [0, 1]
+    assert log == [first, ("row", live)]  # enqueued, THEN a row left
+    pump(3)
+    assert log[2:] == [("row", live)] * 3  # rows only
+    if arrival:
+        assert g._staging is not None and g.streams[1].stream_id == 1
+        del log[:]
+        pump(1)  # rows are out: the admission lands, block 2 leaves,
+        assert log == ["block", ("row", [7])]  # then its first token
+        assert g.streams[1].stream_id == 7 and g._inflight is not None
+    del log[:]
+    pump(1)  # block 2 lands: hands out nothing
+    assert log == [("row", [])]
+    b1, a1, n1 = _boundary_counters()
+    assert b1 - b0 == a1 - a0 == n1 - n0 == 1  # one boundary, ahead
+
+
+def test_an_arrival_during_a_block_gets_its_first_token_after_that_block(
+        params):
+    """The decision "another block or an admission?" is taken at the
+    boundary, after the caller has had its turn to enqueue: an arrival
+    that came in while block N ran is launched in place of block N+1 (it
+    waits at most the running block, as before), not one block later.
+    Counted in dispatches, not seconds."""
+    settings = SamplerSettings(**GREEDY)
+    log: list = []
+    g = BG(CFG, params, settings=settings, block_size=4)
+    g.warm_admission(8)
+    g.set_prompts([list(PROMPTS[0]), [1]], stream_ids=[0, 99])
+    g.streams[1].done = True
+    _recording(g, log)
+    g.step()
+    g.step()  # block 1 runs and lands inside this call
+    assert log == ["block"]
+    g.enqueue([2, 8, 1], stream_id=7)  # the server's _admit(), next pass
+    first = None
+    for _ in range(12):
+        for slot, tok in enumerate(g.step()):
+            if tok is not None and g.streams[slot].stream_id == 7 \
+                    and first is None:
+                first = list(log)
+    # by its first token: its prefill, and the block that follows it was
+    # enqueued before that token's row left; block 2 did not run first
+    assert first == ["block", "prefill", "block"]
+    got = g.streams[1].generated
+    assert got == _single_stream(params, [2, 8, 1], len(got), settings)
+
+
+def test_an_arrival_under_a_running_block_is_launched_behind_it(params):
+    """An arrival handed over while a block is in flight (its client came
+    back during the hand-out of the block before) is launched at once:
+    its prefill follows the running block on the device with no host time
+    between them, which is what a decision at that block's boundary would
+    have chosen too. It lands after that block's rows have all gone out
+    (PR 21's gate), the landing's boundary counts as enqueued ahead, and
+    every stream gets the ids single steps give."""
+    settings = SamplerSettings(**GREEDY)
+    log: list = []
+    g = BG(CFG, params, settings=settings, block_size=4)
+    g.warm_admission(8)
+    g.set_prompts([list(PROMPTS[0]), [1]], stream_ids=[0, 99])
+    g.streams[1].done = True
+    _recording(g, log)
+    got: dict[int, list[int]] = {}
+
+    def pump(n):
+        for _ in range(n):
+            row = g.step()
+            log.append(("row", [g.streams[i].stream_id
+                                for i, t in enumerate(row) if t is not None]))
+            for i, t in enumerate(row):
+                if t is not None:
+                    got.setdefault(g.streams[i].stream_id, []).append(t.id)
+
+    pump(3)  # first tokens; block 1 lands; block 2 leaves, then row 1
+    assert log[-2:] == ["block", ("row", [0])] and g._inflight is not None
+    g.enqueue([2, 8, 1], stream_id=7)  # block 2 is running
+    b0, a0, n0 = _boundary_counters()
+    del log[:]
+    pump(1)
+    assert log == ["prefill", ("row", [0])]  # launched behind block 2
+    pump(2)  # block 1's last rows
+    pump(1)  # block 2 lands: its successor is enqueued already
+    assert log[-1] == ("row", []) and _boundary_counters() == (
+        b0 + 1, a0 + 1, n0 + 1)
+    assert g._staging is not None and g.streams[1].stream_id == 99
+    del log[:]
+    pump(4)  # block 2's rows go out under the prefill; nothing enqueued
+    assert log == [("row", [0])] * 4 and g.streams[1].stream_id == 99
+    pump(1)  # they are out: it lands, block 3 leaves, then its first token
+    assert log[-2:] == ["block", ("row", [7])]
+    pump(12)
+    for sid, prompt in ((0, PROMPTS[0]), (7, [2, 8, 1])):
+        assert len(got[sid]) >= 4
+        assert got[sid] == _single_stream(params, prompt, len(got[sid]),
+                                          settings)
+
+
+def _served(g, n_steps):
+    """Pump step() as a server does, mapping a row's slots to streams
+    when it gets the row."""
+    got: dict[int, list[int]] = {}
+    for _ in range(n_steps):
+        for slot, tok in enumerate(g.step()):
+            if tok is not None:
+                got.setdefault(g.streams[slot].stream_id, []).append(tok.id)
+    return got
+
+
+@pytest.mark.parametrize("case", ["eos", "finish", "window", "paged",
+                                  "prefix"])
+def test_the_one_order_gives_the_ids_single_steps_give(params, case):
+    """EOS inside a block, ``finish()`` mid-block, a stream at its
+    window's edge, the paged layout and the prefix store: with the next
+    program enqueued before the landed rows go out, every stream is
+    handed exactly the ids that single-step dispatches hand it."""
+    import dataclasses
+
+    settings = SamplerSettings(**GREEDY)
+    cfg, kw, prompts = CFG, {}, PROMPTS
+    arrivals = [([2, 8, 1, 7], 7)]
+    if case == "eos":
+        solo = _single_stream(params, PROMPTS[0], 12, settings)
+        k = next(i for i in range(3, 9) if solo[i] not in solo[:i])
+        cfg = dataclasses.replace(CFG, eos_token_id=solo[k])
+    elif case == "window":
+        cfg = tiny(max_seq_len=16)
+    elif case == "paged":
+        kw = dict(kv_layout="paged", kv_page_size=8)
+    elif case == "prefix":
+        shared = list(range(3, 40))
+        prompts = [shared + [5], [3, 1, 4]]
+        arrivals = [(shared + [9, 2], 7), (shared + [4], 8)]
+        kw = dict(prefix_share_min=8, prefix_block=8)
+
+    def run(block_size):
+        g = BG(cfg, params, settings=settings, block_size=block_size, **kw)
+        g.set_prompts([list(p) for p in prompts])
+        got = _served(g, 6)  # block 4: one of its rows is still to go out
+        retire = 1
+        frozen = {}
+        for ids, sid in arrivals:
+            g.finish(retire)
+            frozen[retire] = len(got.get(retire, []))
+            g.enqueue(list(ids), stream_id=sid)
+            for s, toks in _served(g, 30).items():
+                got.setdefault(s, []).extend(toks)
+            retire = sid
+        for s, n in frozen.items():  # nothing reached a retired stream
+            assert len(got.get(s, [])) == n, (s, block_size)
+        return got, {s.stream_id: (s.done, list(s.generated))
+                     for s in g.streams}
+
+    want, _ = run(1)
+    got, recorded = run(4)
+    assert set(got) == set(want)
+    for sid in want:
+        n = min(len(got[sid]), len(want[sid]))
+        assert n >= 3 and got[sid][:n] == want[sid][:n], (case, sid)
+    for sid, (_, ids) in recorded.items():
+        # what a stream was handed is what the engine recorded for it
+        assert got[sid] == ids[: len(got[sid])], sid
+    if case == "eos":  # ended inside a block, where single steps end it
+        assert got[0] == want[0] and got[0][-1] == cfg.eos_token_id
+    if case == "window":  # filled its window, and not a row more
+        for sid in (0, 2):
+            assert got[sid] == want[sid]
+            assert len(PROMPTS[sid]) + len(got[sid]) == cfg.max_seq_len
+
+
+def test_finish_mid_block_leaves_no_trace_of_what_was_not_handed_out(params):
+    """A landed block's rows are recorded at once; ``finish()`` on a stream
+    whose rows still wait takes them back: they are neither handed out,
+    nor counted as emitted, nor in ``generated``, nor in the
+    detokenizer's state (a server's ``decode_rest()`` tail must not hold
+    text of tokens past the budget)."""
+    class Tok:
+        def decode(self, ids):
+            return "".join(chr(ord("a") + i % 26) + " " for i in ids)
+
+    settings = SamplerSettings(**GREEDY)
+    g = BG(CFG, params, settings=settings, tokenizer=Tok(), block_size=8)
+    g.set_prompts([list(PROMPTS[0]), list(PROMPTS[1])], stream_ids=[0, 1])
+    handed = _served(g, 5)  # first tokens, the landing call, three rows
+    assert len(handed[0]) == 4 and len(g.streams[0].generated) == 9
+    e0 = g.stats()["tokens_emitted"]
+    assert g.finish(0) is True
+    assert g.streams[0].generated == handed[0]
+    assert g.streams[0].detok.tokens == handed[0]
+    assert g.finish(0) is False  # over, as its caller has seen
+    more = _served(g, 8)
+    assert 0 not in more and len(more[1]) >= 5
+    assert g.stats()["tokens_emitted"] == e0 + len(more[1])
+
+
+def test_carried_or_uploaded_is_no_new_program_signature(params):
+    """A steady boundary feeds the block program the frontiers and token
+    indices the last block returned (device values: no upload); after a
+    retirement or a splice they are uploaded again. Both come under one
+    sharding, so a retirement WITHOUT an admission behind it -- which a
+    warm-up need not contain -- compiles nothing in the serving window."""
+    settings = SamplerSettings(**GREEDY)
+    g = BG(CFG, params, settings=settings, block_size=4)
+    g.set_prompts([list(p) for p in PROMPTS])
+    uploads = []
+    real = jax.device_put
+    _served(g, 12)
+    assert g._carry[0] is not None
+    n0 = g._decode_block_jit._cache_size()
+    import unittest.mock as mock
+    with mock.patch.object(jax, "device_put",
+                           lambda x, *a, **k: (uploads.append(1),
+                                               real(x, *a, **k))[1]):
+        _served(g, 10)
+        assert not uploads  # steady: one program call, nothing uploaded
+        g.finish(1)
+        got = _served(g, 12)
+        assert uploads  # a row changed: its frontier goes out again
+    assert g._decode_block_jit._cache_size() == n0
+    for sid in (0, 2):
+        full = g.streams[sid].generated
+        assert full == _single_stream(params, PROMPTS[sid], len(full),
+                                      settings)
+    assert 1 not in got
+
+
+def test_plain_run_enqueues_ahead_at_every_boundary(params):
+    """``engine.boundaries_ahead == engine.boundaries`` on a plain run:
+    every landed block's successor (a block or an arrival's prefill) left
+    before any of its rows did, and ``engine.boundary_ms`` was observed
+    once a boundary."""
+    settings = SamplerSettings(**GREEDY)
+    b0, a0, n0 = _boundary_counters()
+    g = BG(CFG, params, settings=settings, block_size=4)
+    g.set_prompts([list(p) for p in PROMPTS])
+    _served(g, 12)
+    g.finish(1)
+    g.enqueue([2, 8, 1, 7], stream_id=7)
+    _served(g, 24)
+    b1, a1, n1 = _boundary_counters()
+    assert b1 - b0 == a1 - a0 == n1 - n0 >= 5
 
 
 def test_slot_not_reclaimed_while_its_rows_are_undelivered(params):
@@ -1233,7 +1584,7 @@ def test_a_dead_slot_decodes_at_row_zero(params, monkeypatch):
                for pos in counted)
     assert list(g._decode_pos()) == [int(g._pos[0]), 0, int(g._pos[2])]
     g.enqueue([2, 8, 1], stream_id=5)
-    steps(10)
+    steps(20)
     for sid, prompt in ((0, PROMPTS[0]), (2, PROMPTS[2]), (5, [2, 8, 1])):
         want = _single_stream(params, prompt, len(got[sid]), settings)
         assert len(got[sid]) >= 6 and got[sid] == want, sid

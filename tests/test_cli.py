@@ -609,10 +609,39 @@ def test_window_override(tmp_path):
     assert toks(base) == windowed
 
 
+def test_lookahead_on_a_batched_path_is_taken_and_says_so(model_dir,
+                                                          tmp_path):
+    """The batched engine has one order of work at a block boundary (the
+    next block before the landed rows), so ``--lookahead`` has nothing to
+    switch there: it is accepted -- with ``--decode-block 1`` too, which
+    the switch used to refuse -- says so, and changes no id."""
+    pf = tmp_path / "prompts.txt"
+    pf.write_text("3,5,7\n2,4\n")
+    base = ["--model", str(model_dir), "--prompts-file", str(pf),
+            "--prompts-ids", "-n", "6", "--temperature", "0",
+            "--max-seq", "32", "--cpu"]
+    env = dict(os.environ, PYTHONPATH=str(REPO), JAX_PLATFORMS="cpu")
+    runs = [subprocess.Popen(
+        [sys.executable, "-m", "cake_tpu.cli"] + base + extra,
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, env=env,
+        cwd=REPO) for extra in ([], ["--lookahead"],
+                                ["--lookahead", "--decode-block", "1"])]
+    outs = [p.communicate(timeout=240) + (p.returncode,) for p in runs]
+    ids = [[l for l in out.splitlines() if l.startswith("[")]
+           for out, _, _ in outs]
+    assert all(rc == 0 for _, _, rc in outs), [e[-400:] for _, e, _ in outs]
+    assert len(ids[0]) == 2 and ids[0] == ids[1] == ids[2]
+    assert "--lookahead changes nothing here" not in outs[0][1]
+    assert "--lookahead changes nothing here" in outs[1][1]
+    assert "--lookahead changes nothing here" in outs[2][1]
+
+
 def test_lookahead_and_wire_codec_flag_guards(model_dir):
-    """--lookahead with --decode-block 1 and a compressing --wire-codec on
-    a non-topology run are rejected loudly (not silently ignored); spelling
-    out the default --wire-codec none anywhere is a harmless no-op."""
+    """--lookahead with --decode-block 1 on the single-stream path (where
+    the flag keeps its meaning: runtime/generator.py) and a compressing
+    --wire-codec on a non-topology run are rejected loudly (not silently
+    ignored); spelling out the default --wire-codec none anywhere is a
+    harmless no-op."""
     r = _run_cli([
         "--model", str(model_dir), "--prompt-ids", "3,5", "-n", "2",
         "--temperature", "0", "--max-seq", "32", "--cpu",

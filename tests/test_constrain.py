@@ -307,6 +307,44 @@ class TestEngine:
         obj = json.loads(text)
         assert isinstance(obj["a"], int) and isinstance(obj["ok"], bool)
 
+    def test_a_live_guide_keeps_the_host_between_steps(self, params):
+        """The DFA advance is host-side between steps, so while a guide
+        is live nothing is enqueued ahead and no block lands; the engine
+        knows it from its own state (no switch), and the moment the
+        constrained stream ends fused blocks go out again, each before
+        the landed block's rows (``engine.boundaries_ahead``)."""
+        from cake_tpu.obs import metrics
+
+        reg = metrics.registry()
+
+        def counters():
+            return (reg.counter("engine.boundaries").value,
+                    reg.counter("engine.boundaries_ahead").value)
+
+        base = BatchGenerator(CFG, params, tokenizer=AsciiTok(),
+                              settings=SamplerSettings(**GREEDY))
+        base.set_prompts([[5, 6, 7], [8, 9, 10]])
+        ref = base.generate(40)[0]
+        gen = BatchGenerator(CFG, params, tokenizer=AsciiTok(),
+                             settings=SamplerSettings(**GREEDY),
+                             block_size=4)
+        gen.set_prompts([[5, 6, 7], [8, 9, 10]],
+                        guides=[None, _json_guide()])
+        b0, a0 = counters()
+        got, guided_steps = [], 0
+        for _ in range(60):
+            live = gen._guides_live()
+            row = gen.step()
+            if row[0] is not None:
+                got.append(row[0].id)
+            if live:
+                guided_steps += 1
+                assert gen._inflight is None and counters() == (b0, a0)
+        b1, a1 = counters()
+        assert guided_steps >= 8 and not gen._guides_live()
+        assert b1 - b0 == a1 - a0 >= 2  # blocks again, each ahead
+        assert len(got) >= 40 and got[:40] == ref
+
     def test_logprobs_engine_streams_bit_identical(self, params):
         base = BatchGenerator(CFG, params,
                               settings=SamplerSettings(**GREEDY))
@@ -434,7 +472,7 @@ class TestEngine:
                              settings=SamplerSettings(**GREEDY),
                              logprobs=2, block_size=2, block_size_max=8)
         gen.set_prompts([[5, 6, 7]])
-        rows = [gen.step() for _ in range(12)]
+        rows = [gen.step() for _ in range(18)]  # a landing hands out none
         toks = [r[0] for r in rows if r and r[0] is not None]
         assert len(toks) >= 12
         assert all(t.logprobs is not None for t in toks)

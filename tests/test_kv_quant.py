@@ -167,7 +167,7 @@ def test_batch_generator_int8_kv_serving_and_admit(params):
     g.streams[2].done = True
     slot, first = g.admit([2, 8, 1], stream_id=9)
     assert slot == 2
-    outs = [g.step() for _ in range(4)]
+    outs = [g.step() for _ in range(8)]
     admitted = [first.id] + [r[2].id for r in outs if r[2] is not None]
     solo = BatchGenerator(CFG, params, settings=settings, dp=1,
                           block_size=4, kv_quant="int8")

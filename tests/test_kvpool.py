@@ -192,13 +192,23 @@ class TestParity:
             assert slot.generate(8) == paged.generate(8)
 
     def test_fused_blocks_and_adaptive_ladder(self, params):
+        # every fused block goes out before the landed block's rows do
+        # (pages are then allocated a boundary earlier): the layouts stay
+        # bit-identical, against each other and against single steps
+        single = BatchGenerator(CFG, params,
+                                settings=SamplerSettings(**GREEDY))
+        single.set_prompts(PROMPTS)
+        want = single.generate(9)
         for kw in (dict(block_size=4),
                    dict(block_size=4, block_size_max=16),
-                   dict(block_size=4, lookahead=True)):
+                   dict(block_size=2, block_size_max=4)):
             slot, paged = self._pair(params, **kw)
             slot.set_prompts(PROMPTS)
             paged.set_prompts(PROMPTS)
-            assert slot.generate(9) == paged.generate(9), kw
+            assert slot.generate(9) == paged.generate(9) == want, kw
+            assert paged._inflight is not None  # enqueued ahead
+            assert (paged.stats()["decode_dispatches"]
+                    == slot.stats()["decode_dispatches"])
 
     def test_midrun_admission_and_retire_reuse(self, params):
         outs = {}
