@@ -107,6 +107,26 @@ class LlamaConfig:
     topk_group: int = 1
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    # A per-expert correction bias (``topk_method: "noaux_tc"``,
+    # ``moe_router_enable_expert_bias``): it enters the router's CHOICE
+    # (groups and top-k on ``score + bias``) and not the weights.
+    router_bias: bool = False
+    # --- delta-rule linear attention beside latent attention (HF
+    # `model_type` "bailing_hybrid") ----------------------------------------
+    # ``layer_group_size`` G > 0: layer i attends through latent attention
+    # if ``(i + 1) % G == 0`` and through KDA otherwise (a gated delta
+    # rule with a per-channel decay, ops/kda.py): heads of ``head_dim``
+    # keys and values, a causal depthwise convolution of
+    # ``short_conv_kernel_size`` taps on q, k and v, log-decays in
+    # ``(kda_lower_bound, 0)``. A KDA layer keeps no rows: its cache is a
+    # float32 state a head and the convolutions' last inputs
+    # (``cache_plan``). ``attn_gate``: the sigmoid output gate's
+    # granularity on the latent layers ("head_wise"); KDA layers gate a
+    # channel.
+    layer_group_size: int = 0
+    short_conv_kernel_size: int = 4
+    kda_lower_bound: float = -5.0
+    attn_gate: str | None = None
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -115,12 +135,14 @@ class LlamaConfig:
                 self.hidden_size // self.num_attention_heads,
             )
         if self.kv_lora_rank:
-            if not (self.q_lora_rank and self.qk_rope_head_dim
-                    and self.v_head_dim):
+            if not (self.qk_rope_head_dim and self.v_head_dim):
                 raise ValueError(
-                    "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
-                    "qk_rope_head_dim and v_head_dim (a direct q_proj, "
-                    "q_lora_rank null, is not wired)")
+                    "latent attention (kv_lora_rank > 0) needs "
+                    "qk_rope_head_dim and v_head_dim")
+            if self.attn_gate not in (None, "head_wise"):
+                raise ValueError(
+                    f"attn_gate {self.attn_gate!r} is not wired (a "
+                    "head-wise output gate only)")
             if self.n_routed_experts and self.scoring_func != "sigmoid":
                 raise ValueError(
                     f"scoring_func {self.scoring_func!r} is not wired for "
@@ -136,6 +158,10 @@ class LlamaConfig:
                         f"experts {self.first_expert}.."
                         f"{self.first_expert + self.n_routed_experts - 1} "
                         f"held of {width} in {self.n_group} groups")
+        if self.layer_group_size and not self.kv_lora_rank:
+            raise ValueError(
+                "layer_group_size > 0 (delta-rule layers beside latent "
+                "ones) needs the latent-attention keys (kv_lora_rank > 0)")
         # validate at construction, not as a KeyError deep in a jit trace
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
@@ -164,6 +190,53 @@ class LlamaConfig:
         """Multi-head latent attention (and the two-stack layer layout that
         comes with it: leading dense layers, then expert layers)."""
         return self.kv_lora_rank > 0
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether some layers hold a recurrent state in place of rows
+        (delta-rule linear attention, ops/kda.py)."""
+        return self.layer_group_size > 0
+
+    @property
+    def layer_kinds(self) -> tuple[tuple[str, str], ...]:
+        """``(mixer, feed-forward)`` of every layer, in model order: the
+        mixer is "gqa", "mla" or "kda", the feed-forward "dense" or "moe".
+        THE place the layer order comes from (models/llama.py
+        ``layer_plan`` groups it into scanned segments, the cache and the
+        loaders count it)."""
+        n = self.num_hidden_layers
+        experts = self.num_local_experts or self.n_routed_experts
+        dense = self.first_k_dense_replace if self.n_routed_experts else (
+            0 if self.num_local_experts else n)
+
+        def mixer(i):
+            if not self.latent:
+                return "gqa"
+            g = self.layer_group_size
+            return "mla" if not g or (i + 1) % g == 0 else "kda"
+
+        return tuple((mixer(i), "moe" if experts and i >= dense else "dense")
+                     for i in range(n))
+
+    @property
+    def cache_plan(self) -> dict[str, tuple[int, ...]]:
+        """What the cache holds, a kind of state each: ``rows`` ``(layers,
+        heads, k_width, v_width)`` for the layers that keep rows (every
+        layer of a model with one kind of attention), and for delta-rule
+        layers ``state`` ``(layers, heads, d_k, d_v)`` (float32) and
+        ``conv`` ``(layers, taps - 1, channels)`` (the last inputs of the
+        q, k and v convolutions). A kind with no layer is left out."""
+        mixers = [m for m, _ in self.layer_kinds]
+        plan = {}
+        rows = len(mixers) - mixers.count("kda")
+        if rows:
+            plan["rows"] = (rows,) + self.cache_row
+        if self.recurrent and mixers.count("kda"):
+            h, d = self.num_attention_heads, self.head_dim
+            plan["state"] = (mixers.count("kda"), h, d, d)
+            plan["conv"] = (mixers.count("kda"),
+                            self.short_conv_kernel_size - 1, 3 * h * d)
+        return plan
 
     @property
     def rope_dim(self) -> int:
@@ -265,7 +338,9 @@ class LlamaConfig:
                         f"(max_window_layers={mwl} of {layers}) is not "
                         "supported; all-or-none windowing only"
                     )
-        if d.get("model_type") in LATENT_MODEL_TYPES:
+        if d.get("model_type") == HYBRID_MODEL_TYPE:
+            kwargs.update(_hybrid_kwargs(d))
+        elif d.get("model_type") in LATENT_MODEL_TYPES:
             # DeepSeek-V3's keys. `topk_method` is read as the group-
             # limited choice n_group/topk_group describe with no correction
             # bias tensor ("none", "group_limited_greedy"); "noaux_tc"
@@ -325,11 +400,88 @@ class LlamaConfig:
                 "n_routed_experts": width,
                 "ep": width // self.n_routed_experts,
                 "rank": first // self.n_routed_experts}
+        if not self.recurrent:
+            for f in _HYBRID_FIELDS:
+                d.pop(f)
+        else:  # the family's own spelling of the keys it renames
+            for ours, theirs in _HYBRID_KEYS.items():
+                d[theirs] = d.pop(ours)
+            d["gated_attention_proj_granularity_type"] = d.pop("attn_gate")
+            d["moe_router_enable_expert_bias"] = d.pop("router_bias")
+            d["topk_method"] = "noaux_tc" if self.router_bias else "none"
+            d["moe_shared_expert_intermediate_size"] = (
+                self.moe_intermediate_size)
+        if not self.router_bias:
+            d.pop("router_bias", None)
         return d
 
 
 # HF `model_type`s served by the latent-attention, shared-expert decoder
 LATENT_MODEL_TYPES = ("deepseek_v3", "axk1")
+# ... and the one whose layers are delta-rule linear attention but every
+# `layer_group_size`-th (Ling-3.0's keys)
+HYBRID_MODEL_TYPE = "bailing_hybrid"
+_HYBRID_FIELDS = ("layer_group_size", "short_conv_kernel_size",
+                  "kda_lower_bound", "attn_gate")
+# ours -> the family's spelling, where they differ
+_HYBRID_KEYS = {"n_routed_experts": "num_experts",
+                "n_shared_experts": "num_shared_experts",
+                "scoring_func": "score_function"}
+# what this family's config.json may ask for that nothing here computes:
+# key -> the only value served
+_HYBRID_FIXED = {
+    "kda_safe_gate": True, "no_kda_lora": True, "use_kda_lora": False,
+    "linear_silu": True, "use_qk_norm": True, "num_kv_heads_for_linear_attn": 0,
+    "rope_interleave": True, "use_mla_nope": False, "use_nGPT": False,
+    "scale_router_input": False, "value_norm": False, "up_proj_norm": False,
+    "use_bias": False, "use_qkv_bias": False, "group_norm_size": 1,
+    "rope_scaling": None,
+}
+
+
+def _hybrid_kwargs(d: dict) -> dict:
+    """`LlamaConfig` fields from a "bailing_hybrid" config.json (its own
+    spelling of the expert keys; the readings this repo makes of it are
+    the benchmark configuration's ``assumed``). What the file asks for
+    and nothing here computes is refused, not guessed."""
+    for key, only in _HYBRID_FIXED.items():
+        if key in d and d[key] != only:
+            raise ValueError(
+                f"{HYBRID_MODEL_TYPE}: {key} = {d[key]!r} is not wired "
+                f"(only {only!r})")
+    layers = d["num_hidden_layers"]
+    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        if any(d.get(key, ())[:layers]):
+            raise ValueError(
+                f"{HYBRID_MODEL_TYPE}: a nonzero {key} entry inside the "
+                f"{layers} served layers (a clamp on the experts' SwiGLU "
+                "whose form the config does not give) is not wired")
+    held = d["num_experts"]
+    if d.get("moe_shared_expert_intermediate_size",
+             d["moe_intermediate_size"]) != d["moe_intermediate_size"]:
+        raise ValueError(
+            f"{HYBRID_MODEL_TYPE}: a shared expert of another width than "
+            "the routed ones is not wired")
+    bias = bool(d.get("moe_router_enable_expert_bias"))
+    if (d.get("topk_method", "noaux_tc") == "noaux_tc") != bias:
+        raise ValueError(
+            f"{HYBRID_MODEL_TYPE}: topk_method and "
+            "moe_router_enable_expert_bias disagree about a routing "
+            "correction bias")
+    kwargs = {
+        "n_routed_experts": held,
+        "n_shared_experts": d.get("num_shared_experts", 0),
+        "scoring_func": d.get("score_function",
+                              d.get("scoring_func", "sigmoid")),
+        "router_bias": bias,
+        "attn_gate": d.get("gated_attention_proj_granularity_type"),
+        "layer_group_size": d["layer_group_size"],
+    }
+    share = d.get("expert_share")
+    if share:  # this chip's share of an ep deployment's experts
+        kwargs["router_experts"] = share["n_routed_experts"]
+        kwargs["first_expert"] = share["rank"] * held
+    return kwargs
 _LATENT_FIELDS = (
     "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
     "v_head_dim", "first_k_dense_replace", "moe_intermediate_size",
@@ -509,6 +661,56 @@ def axk1_ep16(**overrides) -> LlamaConfig:
     return LlamaConfig(**base)
 
 
+def ling3flash_ep4(**overrides) -> LlamaConfig:
+    """Ling-3.0-flash (https://huggingface.co/inclusionAI/Ling-3.0-flash,
+    `model_type` "bailing_hybrid") at its published widths, as ONE chip of
+    the 4 that share each layer's 512 experts holds it: global experts
+    0-127 beside the whole router (and its bias), mixers and shared
+    expert. 42 layers as published (KDA but every sixth, which is latent
+    attention; two leading dense layers); a chip serves the depth of its
+    pipeline stage (`num_hidden_layers=`, `first_k_dense_replace=`) and
+    its slice of the vocabulary (`vocab_size=`)."""
+    base = dict(
+        model_type="bailing_hybrid",
+        vocab_size=157184,
+        hidden_size=2560,
+        intermediate_size=6144,
+        num_hidden_layers=42,
+        num_attention_heads=32,
+        num_key_value_heads=32,
+        head_dim=128,
+        rms_norm_eps=1e-6,
+        rope_theta=6000000.0,
+        max_seq_len=262144,
+        q_lora_rank=None,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        attn_gate="head_wise",
+        layer_group_size=6,
+        short_conv_kernel_size=4,
+        kda_lower_bound=-5.0,
+        first_k_dense_replace=2,
+        moe_intermediate_size=768,
+        n_shared_experts=1,
+        n_routed_experts=128,
+        router_experts=512,
+        first_expert=0,
+        num_experts_per_tok=8,
+        scoring_func="sigmoid",
+        router_bias=True,
+        n_group=8,
+        topk_group=4,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        bos_token_id=0,
+        eos_token_id=1,
+    )
+    base.update(overrides)
+    return LlamaConfig(**base)
+
+
 def tiny(**overrides) -> LlamaConfig:
     """Tiny random-weight config for tests (SURVEY.md §4 test strategy)."""
     base = dict(
@@ -565,6 +767,41 @@ def tiny_mla_moe(**overrides) -> LlamaConfig:
         rope_scaling={"type": "yarn", "factor": 4.0, "beta_fast": 32,
                       "beta_slow": 1, "mscale": 1.0, "mscale_all_dim": 1.0,
                       "original_max_position_embeddings": 32},
+    )
+    base.update(overrides)
+    return tiny(**base)
+
+
+def tiny_kda_hybrid(**overrides) -> LlamaConfig:
+    """Tiny fixture of the delta-rule + latent hybrid that keeps the
+    published family's ratios (Ling-3.0's keys): period 3 (K K M), one
+    leading dense layer, a direct query projection, a head-wise gate on
+    the latent layers, 16 bias-corrected sigmoid-scored experts in 4
+    groups of which 2 are kept, top-4, one shared expert."""
+    base = dict(
+        model_type="bailing_hybrid",
+        num_hidden_layers=4,
+        num_key_value_heads=4,
+        head_dim=16,
+        q_lora_rank=None,
+        kv_lora_rank=16,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        attn_gate="head_wise",
+        layer_group_size=3,
+        first_k_dense_replace=1,
+        moe_intermediate_size=32,
+        n_shared_experts=1,
+        n_routed_experts=16,
+        num_experts_per_tok=4,
+        scoring_func="sigmoid",
+        router_bias=True,
+        n_group=4,
+        topk_group=2,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        rms_norm_eps=1e-6,
     )
     base.update(overrides)
     return tiny(**base)

@@ -24,7 +24,8 @@ TPU-first design decisions:
 
 from __future__ import annotations
 
-from typing import Any
+import dataclasses
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +33,7 @@ import jax.numpy as jnp
 from cake_tpu.models.config import LlamaConfig
 from cake_tpu.ops import quant
 from cake_tpu.ops.attention import self_attention_block
+from cake_tpu.ops.kda import kda_attention_block
 from cake_tpu.ops.kvcache import KVCache
 from cake_tpu.ops.mla import latent_attention_block
 from cake_tpu.ops.mlp import swiglu
@@ -107,32 +109,167 @@ _SHARED_MOE_SHAPES = {
                           c.hidden_size),
 }
 
-# The latent family's two stacks, in the order the layer loop runs them.
-STACKS = ("dense", "moe")
+# Delta-rule linear attention (ops/kda.py): the q, k, v projections with
+# their depthwise convolutions' taps, the per-channel decay (full rank, a
+# rate a head and a bias a channel), beta a head, the output's gate a
+# channel, the norm a head's output goes through, the output projection.
+_KDA_SHAPES = {
+    "attn_norm": lambda c: (c.hidden_size,),
+    **{f"kda_{n}": (lambda c: (c.hidden_size,
+                               c.num_attention_heads * c.head_dim))
+       for n in "qkv"},
+    **{f"conv_{n}": (lambda c: (c.short_conv_kernel_size,
+                                c.num_attention_heads * c.head_dim))
+       for n in "qkv"},
+    "w_decay": lambda c: (c.hidden_size, c.num_attention_heads * c.head_dim),
+    "a_log": lambda c: (c.num_attention_heads,),
+    "dt_bias": lambda c: (c.num_attention_heads * c.head_dim,),
+    "w_beta": lambda c: (c.hidden_size, c.num_attention_heads),
+    "wg": lambda c: (c.hidden_size, c.num_attention_heads * c.head_dim),
+    "o_norm": lambda c: (c.head_dim,),
+    "wo": lambda c: (c.num_attention_heads * c.head_dim, c.hidden_size),
+    "mlp_norm": lambda c: (c.hidden_size,),
+}
+
+
+class Segment(NamedTuple):
+    """Layers of ONE kind in a row, scanned as one stack: ``name`` is the
+    stack's key in ``params["layers"]``, ``first`` the model index of its
+    first layer and ``cache_first`` that layer's index in the cache
+    buffers of its mixer's kind (rows, or state and tail), both in the
+    first repetition of the run; a further repetition is ``stride`` model
+    layers and ``cache_stride`` cached ones on."""
+
+    name: str
+    mixer: str  # "mla" | "kda"
+    ffn: str  # "dense" | "moe"
+    first: int
+    count: int
+    cache_first: int
+    cache_stride: int = 0
+
+
+class Run(NamedTuple):
+    """``segments`` in model order, ``repeats`` times over: a repeated
+    period is scanned as a period (its stacks carry a leading ``[repeats,
+    count]``), everything else is a run of one repetition (``[count]``)."""
+
+    repeats: int
+    segments: tuple[Segment, ...]
+    stride: int = 0
+
+    def layer_ids(self, seg: Segment):
+        """Model indices of ``seg``'s layers, shaped as its stacks lead:
+        ``[count]``, or ``[repeats, count]`` inside a repeated period."""
+        import numpy as np
+
+        ids = seg.first + np.arange(seg.count)
+        if self.repeats == 1:
+            return ids
+        return ids[None] + self.stride * np.arange(self.repeats)[:, None]
+
+
+def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
+    """The order the layer loop runs a latent-family model's layers in,
+    derived from ``config.layer_kinds`` alone: maximal stretches of one
+    kind are segments, a stretch of segments that repeats is one run
+    scanned over its repetitions. "Leading dense layers, then expert
+    layers" is two segments (``dense``, ``moe``); delta-rule layers but
+    every sixth is a period of two segments after the leading ones."""
+    kinds = config.layer_kinds
+    stretches = []  # [kind, first, count]
+    for i, kind in enumerate(kinds):
+        if stretches and stretches[-1][0] == kind:
+            stretches[-1][2] += 1
+        else:
+            stretches.append([kind, i, 1])
+    one_mixer = len({m for m, _ in kinds}) == 1
+    names: dict[str, int] = {}
+    cached = {"mla": 0, "kda": 0}
+
+    def segment(kind, first, count, cache_stride=0):
+        mixer, ffn = kind
+        base = ffn if one_mixer else f"{mixer}_{ffn}"
+        names[base] = names.get(base, 0) + 1
+        name = base if names[base] == 1 else f"{base}_{names[base]}"
+        return Segment(name, mixer, ffn, first, count, cached[mixer],
+                       cache_stride)
+
+    runs = []
+    at = 0
+    while at < len(stretches):
+        best = (1, 1)  # (repeats, width)
+        for width in range(1, (len(stretches) - at) // 2 + 1):
+            pattern = [(k, n) for k, _, n in stretches[at:at + width]]
+            r = 1
+            while [(k, n) for k, _, n in stretches[
+                    at + r * width:at + (r + 1) * width]] == pattern:
+                r += 1
+            if r > 1 and r * width > best[0] * best[1]:
+                best = (r, width)
+        repeats, width = best if best[0] > 1 else (1, 1)
+        period = stretches[at:at + width]
+        per_mixer = {m: sum(n for (mm, _), _, n in period if mm == m)
+                     for m in cached}
+        segs = []
+        for kind, first, count in period:
+            segs.append(segment(kind, first, count,
+                                per_mixer[kind[0]] if repeats > 1 else 0))
+            cached[kind[0]] += count
+        for m in cached:  # the further repetitions' layers
+            cached[m] += (repeats - 1) * per_mixer[m]
+        runs.append(Run(repeats, tuple(segs),
+                        sum(n for _, _, n in period)))
+        at += repeats * width
+    return tuple(runs)
+
+
+def plan_segments(config: LlamaConfig):
+    """``(run, segment)`` of every segment of the plan, in model order."""
+    return [(run, seg) for run in layer_plan(config) for seg in run.segments]
+
+
+def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
+    """Per-layer weight name -> shape builder of one segment's kind."""
+    if seg.mixer == "kda":
+        shapes = dict(_KDA_SHAPES)
+    else:
+        shapes = dict(_LATENT_SHAPES)
+        if not config.q_lora_rank:  # one direct query projection
+            for k in ("wq_a", "q_norm", "wq_b"):
+                del shapes[k]
+            shapes["wq"] = lambda c: (
+                c.hidden_size, c.num_attention_heads
+                * (c.qk_nope_head_dim + c.qk_rope_head_dim))
+        if config.attn_gate:  # a sigmoid gate a head
+            shapes["wg"] = lambda c: (c.hidden_size, c.num_attention_heads)
+    if seg.ffn == "dense":
+        shapes.update({k: _LAYER_SHAPES[k]
+                       for k in ("w_gate", "w_up", "w_down")})
+        return shapes
+    shapes.update(_SHARED_MOE_SHAPES)
+    if config.router_bias:
+        shapes["b_router"] = lambda c: (c.router_experts,)
+    if not config.n_shared_experts:
+        for k in ("ws_gate", "ws_up", "ws_down"):
+            del shapes[k]
+    return shapes
 
 
 def stack_layers(config: LlamaConfig) -> dict[str, int]:
-    """Layers in each stack of a latent-family model: the leading dense
-    ones, then the expert ones."""
-    dense = (config.first_k_dense_replace if config.n_routed_experts
-             else config.num_hidden_layers)
-    return {"dense": dense, "moe": config.num_hidden_layers - dense}
+    """Layers in each stack of a latent-family model (all repetitions of
+    a repeated period counted)."""
+    return {seg.name: run.repeats * seg.count
+            for run, seg in plan_segments(config)}
 
 
 def stack_shapes(config: LlamaConfig) -> dict[str, dict]:
     """Stack name -> per-layer weight name -> shape builder for the latent
-    family (``params["layers"]`` is then ``{"dense": {...}, "moe": {...}}``,
-    each stacked over its own layers; an empty stack is left out)."""
-    dense = dict(_LATENT_SHAPES)
-    dense.update({k: _LAYER_SHAPES[k] for k in ("w_gate", "w_up", "w_down")})
-    moe = dict(_LATENT_SHAPES)
-    moe.update(_SHARED_MOE_SHAPES)
-    if not config.n_shared_experts:
-        for k in ("ws_gate", "ws_up", "ws_down"):
-            del moe[k]
-    count = stack_layers(config)
-    return {name: shapes for name, shapes in (("dense", dense), ("moe", moe))
-            if count[name]}
+    family (``params["layers"]`` is then a dict of stacks, one a segment
+    of :func:`layer_plan`, each stacked over its own layers: ``{"dense":
+    {...}, "moe": {...}}`` for leading dense layers then expert layers)."""
+    return {seg.name: segment_shapes(config, seg)
+            for _, seg in plan_segments(config)}
 
 
 def layer_shapes(config: LlamaConfig) -> dict:
@@ -181,12 +318,17 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
 
     if config.latent:
         k_dense, k_moe, key = jax.random.split(key, 3)
-        count = stack_layers(config)
-        layers = {
-            name: stack(shapes, count[name],
-                        iter(jax.random.split(k, len(shapes))))
-            for (name, shapes), k in zip(stack_shapes(config).items(),
-                                         (k_dense, k_moe))}
+        segs = plan_segments(config)
+        seg_keys = (k_dense, k_moe) if len(segs) <= 2 else jax.random.split(
+            k_dense, len(segs))
+        layers = {}
+        for (run, seg), k in zip(segs, seg_keys):
+            shapes = segment_shapes(config, seg)
+            flat = stack(shapes, run.repeats * seg.count,
+                         iter(jax.random.split(k, len(shapes))))
+            lead = run.layer_ids(seg).shape
+            layers[seg.name] = {n: w.reshape(lead + w.shape[1:])
+                                for n, w in flat.items()}
         keys = iter(jax.random.split(key, 3))
     else:
         shapes = layer_shapes(config)
@@ -359,13 +501,13 @@ def block_forward(
     Model-family deltas dispatch on the layer pytree itself: q/k/v bias
     arrays (``bq``/``bk``/``bv``, Qwen2) and a ``router`` + expert-stacked
     MLP (Mixtral) are used iff present; ``config.sliding_window`` (Mistral)
-    narrows the causal mask. The latent family (``wq_a`` present) attends
+    narrows the causal mask. The latent family (``wkv_a`` present) attends
     through :func:`cake_tpu.ops.mla.latent_attention_block` and its expert
     layers add shared experts (``ws_*``) to the routed part.
     """
     h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps,
                    offset=config.rms_norm_offset)
-    if "wq_a" in layer:
+    if "wkv_a" in layer:
         return _latent_block(layer, x, h, k_cache, v_cache, cos, sin, pos,
                              config, write_gate, ep_axis, ep_size,
                              layer_idx, count_local)
@@ -404,15 +546,24 @@ def block_forward(
 
 def _latent_block(layer, x, h, c_cache, r_cache, cos, sin, pos, config,
                   write_gate, ep_axis, ep_size, layer_idx, count_local):
-    """The rest of :func:`block_forward` for a latent-family layer: ``h``
-    is the normed input. A dense layer (no ``router``) is a SwiGLU of
-    ``intermediate_size``; an expert layer is ``shared(h) + sum over the
-    chosen experts HELD here of w_e expert_e(h)``."""
+    """The rest of :func:`block_forward` for a latent-attention layer:
+    ``h`` is the normed input."""
     with jax.named_scope("mla"):
         attn_out, c_cache, r_cache = latent_attention_block(
             h, layer, c_cache, r_cache, cos, sin, pos, config,
             write_gate=write_gate, layer_idx=layer_idx)
-    x = x + attn_out
+    x, local = _shared_feed_forward(layer, x + attn_out, config, ep_axis,
+                                    ep_size, count_local)
+    if count_local:
+        return x, c_cache, r_cache, local
+    return x, c_cache, r_cache
+
+
+def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local):
+    """The feed-forward half of a latent-family layer, residual added. A
+    dense layer (no ``router``) is a SwiGLU of ``intermediate_size``; an
+    expert layer is ``shared(h) + sum over the chosen experts HELD here
+    of w_e expert_e(h)``. Returns ``(x, local_pairs [B])``."""
     h = rms_norm(x, layer["mlp_norm"], config.rms_norm_eps)
     local = jnp.zeros((x.shape[0],), jnp.int32)
     if "router" in layer:
@@ -422,7 +573,8 @@ def _latent_block(layer, x, h, c_cache, r_cache, cos, sin, pos, config,
             ep_axis=ep_axis, ep_size=ep_size,
             routing=GroupRouting(config.n_group, config.topk_group,
                                  config.norm_topk_prob,
-                                 config.routed_scaling_factor),
+                                 config.routed_scaling_factor,
+                                 layer.get("b_router")),
             held=(config.first_expert, config.n_routed_experts),
             count_local=count_local,
         )
@@ -435,9 +587,21 @@ def _latent_block(layer, x, h, c_cache, r_cache, cos, sin, pos, config,
         x = x + y
     else:
         x = x + swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"])
-    if count_local:
-        return x, c_cache, r_cache, local
-    return x, c_cache, r_cache
+    return x, local
+
+
+def _kda_block(layer, x, cache, config, valid, ep_axis, ep_size, layer_idx,
+               count_local):
+    """One delta-rule layer of the latent family over the carried cache's
+    recurrent buffers. Returns ``(x, cache, local_pairs)``."""
+    h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
+    with jax.named_scope("kda"):
+        out, state, conv = kda_attention_block(
+            h, layer, cache.state, cache.conv, config, valid=valid,
+            layer_idx=layer_idx)
+    x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
+                                    ep_size, count_local)
+    return x, dataclasses.replace(cache, state=state, conv=conv), local
 
 
 def forward_layers(
@@ -459,6 +623,7 @@ def forward_layers(
     ep_axis: str | None = None,
     ep_size: int | None = None,
     count_local: bool = False,
+    valid: jax.Array | None = None,
 ):
     """Run a contiguous run of decoder blocks via ``lax.scan``. Returns
     ``(x, cache)``; with ``count_local`` (latent family) ``(x, cache,
@@ -481,40 +646,74 @@ def forward_layers(
     scanning the cache as ``xs``/``ys`` cost (two cache-sized copies, a slab
     write and a slab read per layer, in every decode step).
 
-    The latent family's ``layers`` are two stacks (``{"dense": ..., "moe":
-    ...}``, :func:`stack_shapes`): the leading dense layers are scanned,
-    then the expert layers, over the ONE carried cache, the second scan's
-    layer indices going on from where the first stopped.
+    The latent family's ``layers`` are a dict of stacks, one a segment of
+    :func:`layer_plan` (``{"dense": ..., "moe": ...}`` for leading dense
+    layers then expert layers): each segment is scanned over the ONE
+    carried cache, its layers' indices into the cache buffers of their
+    kind going on from where the last segment of that kind stopped; a
+    repeated period of segments is one scan over its repetitions around
+    the segments' own. A delta-rule layer reads and writes the cache's
+    recurrent buffers in place of rows (``valid [B]``: the true tokens of
+    each row of a bucketed chunk, which alone touch that state).
     """
     def body(carry, per_layer):
-        h, kc, vc, *local = carry
+        h, c, *local = carry
         layer, i = per_layer
-        out = block_forward(layer, h, kc, vc, cos, sin, pos, config,
-                            num_heads=num_heads, num_kv_heads=num_kv_heads,
-                            tp_axis=tp_axis, sp_axis=sp_axis,
-                            sp_size=sp_size, write_gate=write_gate,
-                            sp_prefill=sp_prefill, sp_chunk=sp_chunk,
-                            ep_axis=ep_axis, ep_size=ep_size,
-                            layer_idx=i, count_local=count_local)
+        if "w_decay" in layer:
+            h, c, now = _kda_block(layer, h, c, config, valid, ep_axis,
+                                   ep_size, i, count_local)
+        else:
+            h, kc, vc, *now = block_forward(
+                layer, h, c.k, c.v, cos, sin, pos, config,
+                num_heads=num_heads, num_kv_heads=num_kv_heads,
+                tp_axis=tp_axis, sp_axis=sp_axis,
+                sp_size=sp_size, write_gate=write_gate,
+                sp_prefill=sp_prefill, sp_chunk=sp_chunk,
+                ep_axis=ep_axis, ep_size=ep_size,
+                layer_idx=i, count_local=count_local)
+            c = dataclasses.replace(c, k=kc, v=vc)
+            now = now[0] if now else None
         if count_local:
-            return (*out[:3], local[0] + out[3]), None
-        return out, None
+            return (h, c, local[0] + now), None
+        return (h, c), None
 
-    stacks = ([layers[name] for name in STACKS if name in layers]
-              if config.latent else [layers])
-    carry = (x, cache.k, cache.v)
+    def scan_segment(carry, stack, first):
+        """``stack``'s layers over the carry; ``first``: its first layer's
+        index into the cache buffers of its kind (a Python int, or traced
+        inside a repeated period)."""
+        n = jax.tree.leaves(stack)[0].shape[0]
+        index = (jnp.arange(first, first + n, dtype=jnp.int32)
+                 if isinstance(first, int)
+                 else first + jnp.arange(n, dtype=jnp.int32))
+        return jax.lax.scan(body, carry, (stack, index))[0]
+
+    def scan_period(carry, run):
+        """``run``'s segments, ``run.repeats`` times over: one scan over
+        the repetitions around the segments' own."""
+        def period(carry, xs):
+            stacks, r = xs
+            for seg in run.segments:
+                carry = scan_segment(carry, stacks[seg.name],
+                                     seg.cache_first + r * seg.cache_stride)
+            return carry, None
+
+        return jax.lax.scan(
+            period, carry,
+            ({seg.name: layers[seg.name] for seg in run.segments},
+             jnp.arange(run.repeats, dtype=jnp.int32)))[0]
+
+    carry = (x, cache)
     if count_local:
         carry += (jnp.zeros((x.shape[0],), jnp.int32),)
-    first = 0
-    for stack in stacks:
-        n = jax.tree.leaves(stack)[0].shape[0]
-        carry, _ = jax.lax.scan(
-            body, carry,
-            (stack, jnp.arange(first, first + n, dtype=jnp.int32)))
-        first += n
-    if count_local:
-        return carry[0], KVCache(k=carry[1], v=carry[2]), carry[3]
-    return carry[0], KVCache(k=carry[1], v=carry[2])
+    if not config.latent:  # one kind of layer, one bare stack
+        return scan_segment(carry, layers, 0)
+    for run in layer_plan(config):
+        if run.repeats > 1:
+            carry = scan_period(carry, run)
+            continue
+        for seg in run.segments:
+            carry = scan_segment(carry, layers[seg.name], seg.cache_first)
+    return carry
 
 
 def forward(

@@ -59,10 +59,22 @@ SERIES: dict[str, tuple[str, str]] = {
         GAUGE, "bytes of the serving cache as allocated (slots x window x "
                "layers x cache.row_bytes)"),
     "cache.row_bytes": (
-        GAUGE, "bytes the cache holds for one token of one layer, from the "
-               "buffers allocated (cache.bytes / layers x slots x window): "
-               "per-head keys and values (with an int8 cache's scales), or "
-               "latent attention's one shared row"),
+        GAUGE, "bytes the cache holds for one token of one layer that "
+               "keeps rows, from the buffers allocated (their bytes / "
+               "such layers x slots x window): per-head keys and values "
+               "(with an int8 cache's scales), or latent attention's one "
+               "shared row"),
+    "cache.state_bytes": (
+        GAUGE, "bytes of the serving cache that are recurrent state "
+               "(delta-rule layers' float32 state and convolution tails), "
+               "from the buffers allocated; 0 where no layer holds one"),
+    "cache.state_bytes_per_stream": (
+        GAUGE, "cache.state_bytes / slots: what a stream's recurrent "
+               "state costs whatever its length"),
+    "kda.state_resets": (
+        COUNTER, "admissions that started a slot's recurrent state from "
+                 "zero (a fresh staging row, spliced over what the slot's "
+                 "last stream left)"),
     "moe.decode_steps": (
         COUNTER, "decode steps whose routed pairs were counted"),
     "moe.local_pairs": (

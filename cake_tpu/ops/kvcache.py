@@ -71,7 +71,8 @@ def quant_kv(x: jax.Array) -> QuantizedKV:
     return QuantizedKV(q=q, scale=scale)
 
 
-@partial(jax.tree_util.register_dataclass, data_fields=["k", "v"], meta_fields=[])
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["k", "v", "state", "conv"], meta_fields=[])
 @dataclasses.dataclass
 class KVCache:
     """Preallocated per-layer key/value buffers.
@@ -87,10 +88,21 @@ class KVCache:
     ``k``/``v`` may each be a plain array or a :class:`QuantizedKV` (int8
     storage + per-slot scales); every consumer goes through
     :func:`dequant_kv` / :func:`update_layer`, which handle both.
+
+    A model whose layers are of two kinds holds two kinds of state
+    (``LlamaConfig.cache_plan``): ``k``/``v`` have a layer for each layer
+    that keeps rows and nothing for the others, and ``state [L_rec, B, H,
+    d_k, d_v]`` (float32) and ``conv [L_rec, B, taps - 1, channels]`` hold
+    what a delta-rule layer keeps a stream, whatever its length
+    (ops/kda.py). Both are None where no layer is recurrent. Every buffer
+    is ``[layers of its kind, batch, ...]``, so a slot's whole state is
+    index ``b`` of axis 1 of every leaf.
     """
 
     k: jax.Array | QuantizedKV
     v: jax.Array | QuantizedKV
+    state: jax.Array | None = None
+    conv: jax.Array | None = None
 
     @property
     def num_layers(self) -> int:
@@ -137,6 +149,17 @@ def init_cache(
     # per-head keys and values, or latent attention's one shared row
     # (normed latent in ``k``, roped key part in ``v``)
     heads, k_width, v_width = config.cache_row
+    plan = config.cache_plan
+    rec = {}
+    if "state" in plan:
+        if num_layers is not None:
+            raise ValueError("a model that holds a recurrent state is "
+                             "cached whole (no layer ranges)")
+        L = plan.get("rows", (0,))[0]
+        n, *shape = plan["state"]
+        rec["state"] = jnp.zeros((n, batch, *shape), jnp.float32)
+        n, *shape = plan["conv"]
+        rec["conv"] = jnp.zeros((n, batch, *shape), dt)
     if quant == "int8":
         if config.latent:
             raise ValueError(
@@ -150,7 +173,7 @@ def init_cache(
 
         return KVCache(k=half(k_width), v=half(v_width))
     return KVCache(k=jnp.zeros((L, batch, heads, S, k_width), dt),
-                   v=jnp.zeros((L, batch, heads, S, v_width), dt))
+                   v=jnp.zeros((L, batch, heads, S, v_width), dt), **rec)
 
 
 def layer_view(cache, layer):

@@ -6,11 +6,14 @@ token a layer, shared by every head. Per token ``x``:
 
     c_q            = rmsnorm(x W_qa)                      [q_lora_rank]
     [q_nope|q_pe]_h = c_q W_qb                            heads x (nope + rope)
+                     (or x W_q directly where q_lora_rank is null)
     [c | k_pe]     = x W_kva                              kv_lora_rank + rope
     c              = rmsnorm(c);  q_pe, k_pe = rope(.)    interleaved pairs
     [k_nope|v]_h   = c W_kvb                              heads x (nope + v)
     score_h        = (q_nope_h . k_nope_h + q_pe_h . k_pe) * scale
     out            = concat_h(softmax(score_h) v_h) W_o
+                     (each head times sigmoid(x W_g)_h first, where the
+                     layer has a head-wise output gate ``wg``)
 
 ``scale`` is ``(nope + rope)^-0.5`` times YaRN's ``mscale^2``
 (``LlamaConfig.attn_scale``). **Cached: ``[c | k_pe]``** (after the norm,
@@ -79,8 +82,12 @@ def latent_attention_block(
     dv, dc = config.v_head_dim, config.kv_lora_rank
     eps, scale = config.rms_norm_eps, config.attn_scale
 
-    c_q = rms_norm(quant.dense(x, layer["wq_a"]), layer["q_norm"], eps)
-    q = quant.dense(c_q, layer["wq_b"]).reshape(b, t, nh, dn + dr)
+    if "wq_a" in layer:
+        c_q = rms_norm(quant.dense(x, layer["wq_a"]), layer["q_norm"], eps)
+        q = quant.dense(c_q, layer["wq_b"])
+    else:  # q_lora_rank null: one direct projection, no bottleneck
+        q = quant.dense(x, layer["wq"])
+    q = q.reshape(b, t, nh, dn + dr)
     q = q.transpose(0, 2, 1, 3)  # [B, H, T, nope + rope]
     q_nope, q_pe = q[..., :dn], q[..., dn:]
     ckv = quant.dense(x, layer["wkv_a"])  # [B, T, dc + dr]
@@ -156,5 +163,9 @@ def latent_attention_block(
         a_o, a_h = jnp.exp(m_o - m), jnp.exp(m_h - m)
         out = (o_o * a_o + o_h * a_h) / (l_o * a_o + l_h * a_h)
 
-    out = out.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, t, nh * dv)
+    out = out.astype(x.dtype).transpose(0, 2, 1, 3)  # [B, T, H, dv]
+    if "wg" in layer:  # a sigmoid gate a head on the heads' outputs
+        gate = jax.nn.sigmoid(quant.dense(x, layer["wg"]).astype(jnp.float32))
+        out = (out * gate[..., None]).astype(x.dtype)
+    out = out.reshape(b, t, nh * dv)
     return quant.dense(out, layer["wo"]), c_cache, r_cache

@@ -51,16 +51,19 @@ GATHER_MAX_ROWS = 8
 class GroupRouting(NamedTuple):
     """DeepSeek-V3's routing (config keys ``scoring_func: "sigmoid"``,
     ``n_group``, ``topk_group``, ``norm_topk_prob``,
-    ``routed_scaling_factor``), read with no correction bias: sigmoid
-    scores over all experts; a group's score is the sum of its 2 highest;
-    the ``topk_group`` best groups stay; top-k of the scores inside them;
-    weights are those scores, normalised over the chosen (``+ 1e-20``)
-    and scaled."""
+    ``routed_scaling_factor``): sigmoid scores over all experts; a
+    group's score is the sum of its 2 highest; the ``topk_group`` best
+    groups stay; top-k of the scores inside them; weights are those
+    scores, normalised over the chosen (``+ 1e-20``) and scaled.
+    ``bias [E]`` (``topk_method: "noaux_tc"``): a per-expert correction
+    that enters the CHOICE (groups and top-k are taken on ``score +
+    bias``) and not the weights (the chosen experts' own scores)."""
 
     n_group: int = 1
     topk_group: int = 1
     norm_topk: bool = True
     scale: float = 1.0
+    bias: jax.Array | None = None
 
 
 def _deq(w, dt):
@@ -104,15 +107,19 @@ def router_topk(
         n, e = logits.shape
         scores = jax.nn.sigmoid(logits)
         choice = scores
+        if routing.bias is not None:
+            choice = scores + routing.bias.astype(jnp.float32)
         if routing.n_group > 1:
-            grouped = scores.reshape(n, routing.n_group, -1)
+            grouped = choice.reshape(n, routing.n_group, -1)
             group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)  # [N, G]
             _, kept = jax.lax.top_k(group_score, routing.topk_group)
             keep = jax.nn.one_hot(kept, routing.n_group,
                                   dtype=jnp.bool_).any(axis=1)  # [N, G]
-            # -1 is below every sigmoid: an expert outside the kept groups
-            # is never chosen
-            choice = jnp.where(keep[..., None], grouped, -1.0).reshape(n, e)
+            # below every score (and every corrected one): an expert
+            # outside the kept groups is never chosen
+            choice = jnp.where(keep[..., None], grouped,
+                               -jnp.inf if routing.bias is not None
+                               else -1.0).reshape(n, e)
         _, idx = jax.lax.top_k(choice, top_k)
         w = jnp.take_along_axis(scores, idx, axis=-1)
         if routing.norm_topk and top_k > 1:

@@ -57,6 +57,7 @@ from cake_tpu.ops.pallas.flash import (  # noqa: E402
     flash_attention_q8,
     flash_decode,
 )
+from cake_tpu.ops.pallas.kda import kda_decode  # noqa: E402
 from cake_tpu.ops.pallas.quant import (  # noqa: E402
     quant4_matmul_pallas,
     quant_matmul_pallas,
@@ -73,6 +74,7 @@ __all__ = [
     "flash_attention",
     "flash_attention_q8",
     "flash_decode",
+    "kda_decode",
     "quant_matmul_pallas",
     "quant4_matmul_pallas",
 ]

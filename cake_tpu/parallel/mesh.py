@@ -71,9 +71,10 @@ def validate_shardable(config: LlamaConfig, num_stages: int, tp: int,
     """Divisibility requirements for the (stage, sp, ep, tp) sharding."""
     if config.latent and (num_stages > 1 or tp > 1 or sp > 1):
         raise ValueError(
-            "a latent-attention model (two layer stacks, one cache row "
-            "for all heads) runs as one stage with tp = 1 and sp = 1; "
-            "only --ep shards it (its held experts)"
+            "a latent-attention model (stacks of several kinds of layer, "
+            "one cache row for all heads or a recurrent state) runs as "
+            "one stage with tp = 1 and sp = 1; only --ep shards it (its "
+            "held experts)"
         )
     if sp > 1 and config.max_seq_len % sp:
         raise ValueError(
@@ -142,15 +143,19 @@ def param_specs(params: dict | None = None) -> dict:
     if params is None:
         return base
     layers = params.get("layers", {})
-    if "wq" not in layers and any(k in layers for k in ("dense", "moe")):
-        # the latent family's two stacks: one stage, tp = 1, so every
-        # tensor is replicated but the held experts, which shard over ep
+    if layers and all(isinstance(v, dict) for v in layers.values()):
+        # the latent family's stacks (models/llama.py layer_plan): one
+        # stage, tp = 1, so every tensor is replicated but the held
+        # experts, whose expert axis (third from last; a repeated
+        # period's stacks lead with one axis more) shards over ep
         def stack_spec(stack):
-            return {
-                k: (P(STAGE, EP, None, None)
-                    if k in ("w_gate", "w_up", "w_down") and "router" in stack
-                    else P(STAGE, *([None] * (_rank(v) - 1))))
-                for k, v in stack.items()}
+            def spec(k, v):
+                axes = [STAGE] + [None] * (_rank(v) - 1)
+                if k in ("w_gate", "w_up", "w_down") and "router" in stack:
+                    axes[-3] = EP
+                return P(*axes)
+
+            return {k: spec(k, v) for k, v in stack.items()}
 
         base["layers"] = {name: stack_spec(stack)
                           for name, stack in layers.items()}
@@ -198,14 +203,19 @@ def param_specs(params: dict | None = None) -> dict:
 CACHE_SPEC = P(STAGE, DP, TP, SP, None)
 
 
-def cache_specs(kv_quant: str | None = None, batch_replicated: bool = False):
+def cache_specs(kv_quant: str | None = None, batch_replicated: bool = False,
+                recurrent: bool = False):
     """PartitionSpec pytree matching :func:`cake_tpu.ops.kvcache.init_cache`'s
     structure: plain buffers take CACHE_SPEC; int8 buffers take it for the
     q bytes and the same layout minus head_dim for the per-slot scales.
 
     ``batch_replicated``: don't shard the batch axis over dp — the layout of
     a single-row staging cache (continuous-batching admission) that must
-    exist on every dp shard."""
+    exist on every dp shard. ``recurrent``
+    (``LlamaConfig.recurrent``): the cache also holds delta-rule layers'
+    state ``[L, B, H, d_k, d_v]`` and convolution tail ``[L, B, taps - 1,
+    C]``, batch over dp and nothing else sharded (such a model runs as one
+    stage with tp = 1)."""
     from cake_tpu.ops.kvcache import KVCache, QuantizedKV
 
     bd = None if batch_replicated else DP
@@ -213,6 +223,9 @@ def cache_specs(kv_quant: str | None = None, batch_replicated: bool = False):
     if kv_quant == "int8":
         half = QuantizedKV(q=spec, scale=P(STAGE, bd, TP, SP))
         return KVCache(k=half, v=half)
+    if recurrent:
+        return KVCache(k=spec, v=spec, state=P(STAGE, bd, None, None, None),
+                       conv=P(STAGE, bd, None, None))
     return KVCache(k=spec, v=spec)
 
 
@@ -227,7 +240,8 @@ def shard_params(params: dict, mesh: Mesh) -> dict:
 def shard_cache(cache, mesh: Mesh):
     from cake_tpu.ops.kvcache import QuantizedKV
 
-    specs = cache_specs("int8" if isinstance(cache.k, QuantizedKV) else None)
+    specs = cache_specs("int8" if isinstance(cache.k, QuantizedKV) else None,
+                        recurrent=cache.state is not None)
     return jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), cache, specs
     )
@@ -255,12 +269,12 @@ def init_cache_on_mesh(config, mesh: Mesh, batch: int = 1,
 
     from cake_tpu.ops.kvcache import init_cache
 
-    key = (mesh, config.num_hidden_layers, config.cache_row,
-           str(config.dtype), batch,
+    key = (mesh, tuple(config.cache_plan.items()), str(config.dtype), batch,
            max_seq or config.max_seq_len, quant, batch_replicated)
     make = _CACHE_PROGRAMS.get(key)
     if make is None:
-        specs = cache_specs(quant, batch_replicated=batch_replicated)
+        specs = cache_specs(quant, batch_replicated=batch_replicated,
+                            recurrent=config.recurrent)
         out_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                               is_leaf=lambda x: isinstance(x, P))
 

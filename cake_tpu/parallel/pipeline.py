@@ -65,8 +65,7 @@ def _local_counts(config: LlamaConfig, tp: int) -> tuple[int, int]:
 def _pipeline_layers(
     x: jax.Array,  # [Bl, T, hidden] local activation
     layers,  # local stacked layer weights [L/S, ...]
-    ck: jax.Array,  # local cache k [L/S, Bl, KVl, S, D]
-    cv: jax.Array,
+    cache: KVCache,  # local cache, k and v [L/S, Bl, KVl, S, D]
     cos: jax.Array,
     sin: jax.Array,
     pos,
@@ -78,11 +77,14 @@ def _pipeline_layers(
     sp_prefill: bool = False,
     sp_chunk: bool = False,
     count_local: bool = False,
+    valid: jax.Array | None = None,
 ):
-    """Run the staged pipeline loop. Returns (x_on_stage0, ck, cv); with
+    """Run the staged pipeline loop. Returns (x_on_stage0, cache); with
     ``count_local`` (an expert model of the latent family, which runs as
-    one stage) a fourth value, each row's routed pairs that fell on
-    experts held here (:func:`llama.forward_layers`).
+    one stage) a third value, each row's routed pairs that fell on
+    experts held here (:func:`llama.forward_layers`). ``valid [B]``: the
+    true tokens of each row of a bucketed chunk (what alone may touch a
+    recurrent state).
 
     SPMD-uniformity: every stage executes the layer math (and therefore every
     collective — tp psum, sp ring ppermute, sp decode psum/pmax) on every
@@ -99,20 +101,19 @@ def _pipeline_layers(
     perm = [(i, (i + 1) % num_stages) for i in range(num_stages)]
 
     def body(step, carry):
-        x, ck, cv, *local = carry
+        x, cache, *local = carry
         active = step == my_stage
         h, new_cache, *now = llama.forward_layers(
-            layers, x, KVCache(k=ck, v=cv), cos, sin, pos, config,
+            layers, x, cache, cos, sin, pos, config,
             num_heads=heads_l, num_kv_heads=kv_heads_l, tp_axis=TP, ep_axis=EP,
             sp_axis=SP, sp_size=sp, write_gate=active, sp_prefill=sp_prefill,
-            sp_chunk=sp_chunk, count_local=count_local,
+            sp_chunk=sp_chunk, count_local=count_local, valid=valid,
         )
         x = jnp.where(active, h, x)
         x = jax.lax.ppermute(x, STAGE, perm)
-        return (x, new_cache.k, new_cache.v,
-                *(a + b for a, b in zip(local, now)))
+        return (x, new_cache, *(a + b for a, b in zip(local, now)))
 
-    carry = (x, ck, cv) + (
+    carry = (x, cache) + (
         (jnp.zeros((x.shape[0],), jnp.int32),) if count_local else ())
     return jax.lax.fori_loop(0, num_stages, body, carry)
 
@@ -189,6 +190,19 @@ def _pipelined_prefill_layers(
         0, m_chunks + num_stages, body, (x0, ck, cv, y0)
     )
     return y, ck, cv
+
+
+def _valid_rows(config: LlamaConfig, tokens: jax.Array,
+                last_index: jax.Array):
+    """The true tokens of each row of a bucketed chunk, for a model whose
+    layers hold a recurrent state (None otherwise: rows past a frontier
+    hide themselves): up to and with the row's last true token, the whole
+    chunk where that token lies in a later one."""
+    if not config.recurrent:
+        return None
+    t = tokens.shape[1]
+    return jnp.broadcast_to(jnp.minimum(last_index + 1, t),
+                            (tokens.shape[0],)).astype(jnp.int32)
 
 
 def _select_stage0(x: jax.Array) -> jax.Array:
@@ -326,8 +340,8 @@ def build_sharded_decode(
         # must cover global positions.
         cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, token[:, None], config)
-        x, ck, cv, *local = _pipeline_layers(
-            x, params["layers"], cache.k, cache.v, cos, sin, pos, config,
+        x, cache, *local = _pipeline_layers(
+            x, params["layers"], cache, cos, sin, pos, config,
             plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
             sp_prefill=False, count_local=count_local,
         )
@@ -343,7 +357,7 @@ def build_sharded_decode(
             tok = sampling.sample_tokens(logits, _dp_fold(key, plan.dp),
                                          history, settings)
         history, hist_slot = sampling.push_history_batched(history, hist_slot, tok)
-        return tok, KVCache(k=ck, v=cv), history, hist_slot, lp, local
+        return tok, cache, history, hist_slot, lp, local
 
     def fold_key(key, index):
         if per_row:  # key [B, 2], index [B] (per-stream schedules)
@@ -355,7 +369,7 @@ def build_sharded_decode(
 
         kv_specs = pool_specs(kv_quant)
     else:
-        kv_specs = cache_specs(kv_quant)
+        kv_specs = cache_specs(kv_quant, recurrent=config.recurrent)
     in_specs = [
         param_specs(params_like),
         P(DP),
@@ -732,17 +746,18 @@ def build_admit_prefill(config: LlamaConfig, plan: MeshPlan,
     def step(params, tokens, cache, pos0, last_local):
         cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, tokens, config)
-        x, ck, cv = _pipeline_layers(
-            x, params["layers"], cache.k, cache.v, cos, sin, pos0, config,
+        x, cache = _pipeline_layers(
+            x, params["layers"], cache, cos, sin, pos0, config,
             plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
-            sp_chunk=plan.sp > 1,
+            sp_chunk=plan.sp > 1, valid=_valid_rows(config, tokens,
+                                                    last_local),
         )
         # the chunk activations are replicated over sp (every shard computes
         # the full chunk), so the sp==1 last-index selection applies
         x_last = _select_last_sp(x, last_local, 1)
         x_last = _select_stage0(x_last)
         logits = _head_logits(params, x_last, config)
-        return logits, KVCache(k=ck, v=cv)
+        return logits, cache
 
     sharded = shard_map(
         step,
@@ -750,13 +765,15 @@ def build_admit_prefill(config: LlamaConfig, plan: MeshPlan,
         in_specs=(
             param_specs(params_like),
             P(None, None),
-            cache_specs(kv_quant, batch_replicated=True),
+            cache_specs(kv_quant, batch_replicated=True,
+                        recurrent=config.recurrent),
             P(),
             P(None),
         ),
         out_specs=(
             P(None, None),
-            cache_specs(kv_quant, batch_replicated=True),
+            cache_specs(kv_quant, batch_replicated=True,
+                        recurrent=config.recurrent),
         ),
         check_vma=False,
     )
@@ -784,14 +801,14 @@ def build_sharded_verify(config: LlamaConfig, plan: MeshPlan,
     def step(params, tokens, cache, pos):
         cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, tokens, config)
-        x, ck, cv = _pipeline_layers(
-            x, params["layers"], cache.k, cache.v, cos, sin, pos, config,
+        x, cache = _pipeline_layers(
+            x, params["layers"], cache, cos, sin, pos, config,
             plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
             sp_chunk=plan.sp > 1,
         )
         x = _select_stage0(x[0])  # [T, hidden], valid on stage 0
         logits = _head_logits(params, x, config)  # [T, vocab] f32
-        return logits, KVCache(k=ck, v=cv)
+        return logits, cache
 
     sharded = shard_map(
         step,
@@ -832,14 +849,14 @@ def build_sharded_verify_rows(config: LlamaConfig, plan: MeshPlan,
     def step(params, tokens, cache, pos):
         cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, tokens, config)
-        x, ck, cv = _pipeline_layers(
-            x, params["layers"], cache.k, cache.v, cos, sin, pos, config,
+        x, cache = _pipeline_layers(
+            x, params["layers"], cache, cos, sin, pos, config,
             plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
             sp_chunk=plan.sp > 1,
         )
         x = _select_stage0(x)  # [B, T, hidden], valid on stage 0
         logits = _head_logits(params, x, config)
-        return logits, KVCache(k=ck, v=cv)
+        return logits, cache
 
     sharded = shard_map(
         step,
@@ -1042,16 +1059,18 @@ def build_sharded_prefill(config: LlamaConfig, plan: MeshPlan,
                 x_chunks, params["layers"], cache.k, cache.v, cos, sin,
                 config, plan.num_stages, heads_l, kv_heads_l,
             )
+            cache = KVCache(k=ck, v=cv)
             # [M, B, C, H] -> [B, T, H] (valid on stage 0; selected below)
             x = y.transpose(1, 0, 2, 3).reshape(b, t, -1)
         else:
             # sp_prefill explicit: a bucketed prompt can give each shard a
             # ONE-token chunk, which the T>1 heuristic would misroute to the
             # decode branch (silently wrong logits — r2 code-review finding)
-            x, ck, cv = _pipeline_layers(
-                x, params["layers"], cache.k, cache.v, cos, sin, pos0,
+            x, cache = _pipeline_layers(
+                x, params["layers"], cache, cos, sin, pos0,
                 config, plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
                 sp_prefill=not chunk_mode, sp_chunk=chunk_mode,
+                valid=_valid_rows(config, tokens, last_index),
             )
         # slice the wanted position first so the cross-stage select moves
         # [B, hidden], not the whole [B, T, hidden] activation
@@ -1060,12 +1079,13 @@ def build_sharded_prefill(config: LlamaConfig, plan: MeshPlan,
         x_last = _select_last_sp(x, last_index, 1 if chunk_mode else plan.sp)
         x_last = _select_stage0(x_last)
         logits = _head_logits(params, x_last, config)
-        return logits, KVCache(k=ck, v=cv)
+        return logits, cache
 
+    kv_specs = cache_specs(kv_quant, recurrent=config.recurrent)
     in_specs = [
         param_specs(params_like),
         P(DP, None) if chunk_mode else P(DP, SP),
-        cache_specs(kv_quant),
+        kv_specs,
         P(DP),
     ]
     if with_offset:
@@ -1076,7 +1096,7 @@ def build_sharded_prefill(config: LlamaConfig, plan: MeshPlan,
         in_specs=tuple(in_specs),
         out_specs=(
             P(DP, None),
-            cache_specs(kv_quant),
+            kv_specs,
         ),
         check_vma=False,
     )

@@ -74,6 +74,7 @@ crossover's throughput.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time
 from collections import deque
 
@@ -91,7 +92,6 @@ from cake_tpu.kvpool import (
 )
 from cake_tpu.kvpool import pool as kvpool_pool
 from cake_tpu.models.config import LlamaConfig
-from cake_tpu.models.llama import stack_layers
 from cake_tpu.obs import flight as obs_flight
 from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.obs import prof as obs_prof
@@ -147,6 +147,7 @@ _IMPORT_ABORTS = obs_metrics.counter("disagg.import_aborts")
 _MOE_LOCAL = obs_metrics.counter("moe.local_pairs")
 _MOE_ROUTED = obs_metrics.counter("moe.routed_pairs")
 _MOE_STEPS = obs_metrics.counter("moe.decode_steps")
+_STATE_RESETS = obs_metrics.counter("kda.state_resets")
 # The order of work at a block boundary (BatchGenerator._close_boundary):
 # host time from a block's fetch returning to the return of the step()
 # call that enqueued the device's next program, once per landed block,
@@ -298,8 +299,13 @@ class BatchGenerator:
             raise ValueError(
                 "kv_layout='paged' is not wired for latent attention (the "
                 "page pool, and with it the disagg snapshot and the spill "
-                "tier, hold per-head keys and values); serve this family "
-                "with the slot layout")
+                "tier, hold per-head keys and values, and no recurrent "
+                "state); serve this family with the slot layout")
+        if config.recurrent and spec_k:
+            raise ValueError(
+                "speculation (spec_k) is not wired for a model whose "
+                "layers hold a recurrent state: a rejected proposal has "
+                "already advanced the state, and nothing rolls it back")
         self._page_size = int(kv_page_size)
         self._pool_pages_req = kv_pool_pages
         if self._paged:
@@ -532,6 +538,15 @@ class BatchGenerator:
         # hits SHARE physical pages via refcounts instead of copying a
         # staged row, and eviction is pool-pressure-driven.
         self._prefix_entries = max(0, prefix_cache_entries)
+        if config.recurrent and (self._prefix_entries or prefix_share_min):
+            # a stored row's recurrent state is the state at the END of
+            # the prompt that left it, not at the shared prefix's end: a
+            # hit would start from the wrong state. Every prompt of such
+            # a model is prefilled whole.
+            logging.getLogger("cake_tpu.batch_generator").info(
+                "prefix reuse is off: a recurrent state has no prefix to "
+                "share")
+            self._prefix_entries = self._prefix_share_min = 0
         self._prefix_store = PrefixLRU(self._prefix_entries)
         self._prefix_block = max(1, prefix_block)
         self._prefix_hits = 0
@@ -1054,10 +1069,15 @@ class BatchGenerator:
         # what the cache really holds (any family), read off the buffers
         # that were allocated: all of it, and for one token of one layer
         held = sum(x.nbytes for x in jax.tree.leaves(self.cache))
+        state = sum(x.nbytes for x in jax.tree.leaves(
+            (self.cache.state, self.cache.conv)))
         obs_metrics.gauge("cache.bytes").set(held)
         obs_metrics.gauge("cache.row_bytes").set(
-            held / (self.cache.num_layers * self.cache.batch
-                    * self.cache.max_seq))
+            (held - state) / (self.cache.num_layers * self.cache.batch
+                              * self.cache.max_seq))
+        obs_metrics.gauge("cache.state_bytes").set(state)
+        obs_metrics.gauge("cache.state_bytes_per_stream").set(
+            state / self.cache.batch)
         # first token per stream: fold_in(stream_key, 0) — the same absolute
         # token-index schedule the in-program decode steps continue
         keys0 = jax.vmap(lambda k: jax.random.fold_in(k, 0))(self._keys)
@@ -1947,8 +1967,11 @@ class BatchGenerator:
                 jnp.asarray(st["tokens"][:, pos: pos + chunk]),
                 st["cache"],
                 jnp.int32(base + pos),
+                # the in-chunk index of the prompt's last token; in an
+                # earlier chunk the chunk's own last (every token of it
+                # is true: what a recurrent state may be advanced by)
                 jnp.asarray(
-                    [len(st["ids"]) - 1 - base - pos if final else 0],
+                    [min(len(st["ids"]) - 1 - base - pos, chunk - 1)],
                     jnp.int32,
                 ),
             )
@@ -2073,6 +2096,10 @@ class BatchGenerator:
                 max_seq=self.max_seq, quant=self.kv_quant,
                 batch_replicated=True,
             )
+            if self.config.recurrent:
+                # the zeroed row IS the reset: the splice copies its
+                # state and convolution tail over the slot's
+                _STATE_RESETS.inc()
         self._staging = {
             "ids": ids, "sid": sid, "slot": slot,
             "tokens": tokens, "pos": 0, "chunk": chunk, "base": base,
@@ -3034,7 +3061,7 @@ class BatchGenerator:
         _MOE_LOCAL.inc(int(self._host(local)[live].sum()))
         _MOE_ROUTED.inc(
             steps * int(live.sum()) * self.config.num_experts_per_tok
-            * stack_layers(self.config)["moe"])
+            * sum(ffn == "moe" for _, ffn in self.config.layer_kinds))
         _MOE_STEPS.inc(steps)
 
     def _step_decode(self):

@@ -1,0 +1,115 @@
+"""The delta-rule decode step: the Pallas kernel against XLA's fusions.
+
+Times one decode step of every delta-rule layer of a model (the state
+``[L, B, H, d, d]`` float32, carried and donated as the layer loop
+carries it; a step is a scan over the ``L`` layers) in the two forms
+:func:`cake_tpu.ops.kda.kda_decode_choice` chooses between, at the served
+shape and around it. What decides whether the kernel stays: 1.10x over
+XLA at the cell's shape (B 32, 6 layers; PERF.md keeps the table).
+
+Usage:  python -m cake_tpu.tools.kda_sweep [--json-out PATH]
+(``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
+
+Prints one JSON line per shape: ``{"batch", "layers", "heads", "d",
+"head_block", "xla_us_per_layer", "kernel_us_per_layer", "speedup",
+"kernel_hbm_share"}`` (the share: one read and one write of the state and
+the step's vectors over 819 GB/s over the kernel's time).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+from cake_tpu.ops.kda import kda_step
+from cake_tpu.ops.pallas.kda import kda_decode, kda_decode_bytes
+from cake_tpu.tools.kernel_check import refuse_offchip_record
+
+SHAPES = (  # (batch, layers, heads, d): the cell's first
+    (32, 6, 32, 128), (48, 6, 32, 128), (8, 6, 32, 128), (1, 6, 32, 128),
+    (48, 6, 16, 128))
+HEAD_BLOCKS = (8, 16, 32)
+STEPS = 8  # a block's steps in one program, as the engine dispatches them
+
+
+def _step_all_layers(form, state, q, k, v, g, beta):
+    """``STEPS`` decode steps over all ``L`` layers, the state carried."""
+    def layer(carry, i):
+        state, acc = carry
+        if form == "xla":
+            o, s = kda_step(q, k, v, g, beta, state[i])
+            state = jax.lax.dynamic_update_index_in_dim(state, s, i, 0)
+        else:
+            o, state = kda_decode(q, k, v, g, beta, state, i,
+                                  head_block=form)
+        return (state, acc + o), None
+
+    def step(carry, _):
+        carry, _ = jax.lax.scan(layer, carry,
+                                jnp.arange(state.shape[0], dtype=jnp.int32))
+        return carry, None
+
+    (state, acc), _ = jax.lax.scan(step, (state, jnp.zeros_like(v)), None,
+                                   length=STEPS)
+    return state, acc
+
+
+def _time_us(form, b, n_layers, h, d, iters: int = 10) -> float:
+    """Microseconds a layer and step, the state donated between calls."""
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    q, k, v = (jax.random.normal(kk, (b, h, d), jnp.float32) * d ** -0.5
+               for kk in keys[:3])
+    g = -5.0 * jax.nn.sigmoid(jax.random.normal(keys[3], (b, h, d)) - 4.0)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, h)))
+    state = jax.random.normal(keys[5], (n_layers, b, h, d, d), jnp.float32)
+    fn = jax.jit(partial(_step_all_layers, form), donate_argnums=(0,))
+    state, acc = fn(state, q, k, v, g, beta)  # compile
+    jax.block_until_ready(acc)
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        state, acc = fn(state, q, k, v, g, beta)
+    jax.block_until_ready((state, acc))
+    return (time.perf_counter() - t0) * 1e6 / (iters * STEPS * n_layers)
+
+
+def rows():
+    for b, n_layers, h, d in SHAPES:
+        xla = _time_us("xla", b, n_layers, h, d)
+        for hb in HEAD_BLOCKS:
+            if hb > h:
+                continue
+            kernel = _time_us(hb, b, n_layers, h, d)
+            floor_us = kda_decode_bytes(b, h, d, d) / 819e9 * 1e6
+            yield {"batch": b, "layers": n_layers, "heads": h, "d": d,
+                   "head_block": hb, "xla_us_per_layer": round(xla, 2),
+                   "kernel_us_per_layer": round(kernel, 2),
+                   "speedup": round(xla / kernel, 3),
+                   "kernel_hbm_share": round(100 * floor_us / kernel, 1)}
+
+
+def main() -> int:
+    from cake_tpu.utils.compile_cache import configure
+
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--json-out")
+    a = ap.parse_args()
+    configure()
+    refuse_offchip_record(a.json_out)
+    out = []
+    for row in rows():
+        print(json.dumps(row), flush=True)
+        out.append(row)
+    if a.json_out:
+        with open(a.json_out, "w") as f:
+            json.dump(out, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

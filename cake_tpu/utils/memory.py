@@ -134,7 +134,9 @@ def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
     from the shapes the model is built with (``models.llama.stack_shapes``):
     every tensor replicated but the HELD experts' stacks, which divide over
     ep; the cache is the latent row (``LlamaConfig.cache_row_values`` a
-    token a layer), not per-head keys and values. One stage, tp = sp = 1
+    token a layer), not per-head keys and values, for the layers that
+    keep rows, and a delta-rule layer's state and convolution tail a
+    stream for the others (``LlamaConfig.cache_plan``). One stage, tp = sp = 1
     (``mesh.validate_shardable``)."""
     import math
 
@@ -157,8 +159,12 @@ def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
     embed_bytes = c.vocab_size * c.hidden_size * el
     head_bytes = (c.hidden_size * c.vocab_size * lin_el
                   + c.vocab_size * scale_el + c.hidden_size * el)
-    kv_bytes = (c.num_hidden_layers * batch * S * c.cache_row_values
+    plan = c.cache_plan  # rows for some layers, a state for the others
+    kv_bytes = (plan.get("rows", (0,))[0] * batch * S * c.cache_row_values
                 * cache_el)
+    if "state" in plan:  # float32 state, the tail in the serving type
+        kv_bytes += batch * (math.prod(plan["state"]) * 4
+                             + math.prod(plan["conv"]) * el)
     return {
         "layers": int(layer_bytes),
         "embed_replicated": int(embed_bytes),

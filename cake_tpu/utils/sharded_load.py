@@ -79,10 +79,14 @@ class CheckpointReader:
         """Logical ``[rows, cols]`` slice; ``transpose=True`` when the
         checkpoint stores the torch ``[out, in]`` layout and the logical
         layout is ``[in, out]``."""
-        if transpose:
-            out = np.asarray(self._slice(name)[cols, rows]).T
+        sl = self._slice(name)
+        if len(sl.get_shape()) == 3:
+            # a depthwise convolution's taps, torch's [C, 1, K]
+            out = np.asarray(sl[cols, :, rows])[:, 0, :].T
+        elif transpose:
+            out = np.asarray(sl[cols, rows]).T
         else:
-            out = np.asarray(self._slice(name)[rows, cols])
+            out = np.asarray(sl[rows, cols])
         self.bytes_read += out.nbytes
         return out
 
@@ -581,8 +585,11 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
                          config: LlamaConfig, mesh: Mesh,
                          quantize: str | None,
                          tie_word_embeddings: bool) -> dict:
-    """The latent-attention, shared-expert family onto the mesh: two layer
-    stacks (``params["layers"] = {"dense": ..., "moe": ...}``), the held
+    """The latent-attention, shared-expert family onto the mesh: a dict of
+    layer stacks, one a segment of ``models.llama.layer_plan``
+    (``params["layers"] = {"dense": ..., "moe": ...}`` for leading dense
+    layers then expert layers; a delta-rule hybrid's segments the same
+    way, a repeated period's stacks leading ``[repeats, layers]``), the held
     experts read by their global ids (``config.first_expert`` on), every
     tensor replicated but the expert stacks, whose expert axis shards over
     ep (the family runs as one stage with tp = 1:
@@ -656,21 +663,22 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
 
     shapes = stack_shapes(config)
     layers: dict = {}
-    for stack, (first, n, plain, experts) in latent_stack_plan(
-            config).items():
+    for stack, (ids, plain, experts) in latent_stack_plan(config).items():
         out = {}
+        lead = P(STAGE, *([None] * (ids.ndim - 1)))
         for ours, (suffix, transpose) in plain.items():
             out[ours] = stacked(
-                lambda i, s=suffix: f"model.layers.{first + i}.{s}",
-                (n,), shapes[stack][ours](config), P(STAGE), transpose,
+                lambda *at, s=suffix, ids=ids: (
+                    f"model.layers.{ids[at]}.{s}"),
+                ids.shape, shapes[stack][ours](config), lead, transpose,
                 tier is not None and ours in LATENT_LINEARS)
         for ours, pattern in experts.items():
             out[ours] = stacked(
-                lambda i, e, p=pattern: (
-                    f"model.layers.{first + i}."
-                    f"{p.format(e=config.first_expert + e)}"),
-                (n, config.n_routed_experts),
-                shapes[stack][ours](config)[1:], P(STAGE, EP), True,
+                lambda *at, p=pattern, ids=ids: (
+                    f"model.layers.{ids[at[:-1]]}."
+                    f"{p.format(e=config.first_expert + at[-1])}"),
+                ids.shape + (config.n_routed_experts,),
+                shapes[stack][ours](config)[1:], P(*lead, EP), True,
                 tier is not None)
         layers[stack] = out
 

@@ -89,22 +89,65 @@ _LATENT_EXPERT_MAP = {
     "w_down": "mlp.experts.{e}.down_proj.weight",
 }
 
-def latent_stack_plan(config) -> dict:
-    """Stack name -> ``(first layer, layers, {ours: (HF suffix,
-    transpose)}, {ours: expert pattern})`` for a latent-family model: what
-    both loaders and the writer walk (``models.llama.stack_shapes`` gives
-    the shapes)."""
-    from cake_tpu.models.llama import stack_layers, stack_shapes
+# Delta-rule layers beside latent ones (`model_type` "bailing_hybrid"; the
+# names are ASSUMED, the benchmark configuration lists them: FLA's KDA
+# module under `self_attn.`, the convolutions as torch depthwise `[C, 1,
+# K]`, DeepSeek-V3's names for what the two families share) and what the
+# family adds to a latent layer and to the router.
+_KDA_MAP = {
+    "attn_norm": ("input_layernorm.weight", False),
+    "kda_q": ("self_attn.q_proj.weight", True),
+    "kda_k": ("self_attn.k_proj.weight", True),
+    "kda_v": ("self_attn.v_proj.weight", True),
+    "conv_q": ("self_attn.q_conv1d.weight", True),
+    "conv_k": ("self_attn.k_conv1d.weight", True),
+    "conv_v": ("self_attn.v_conv1d.weight", True),
+    "w_decay": ("self_attn.f_proj.weight", True),
+    "a_log": ("self_attn.A_log", False),
+    "dt_bias": ("self_attn.dt_bias", False),
+    "w_beta": ("self_attn.b_proj.weight", True),
+    "wg": ("self_attn.g_proj.weight", True),
+    "o_norm": ("self_attn.o_norm.weight", False),
+    "wo": ("self_attn.o_proj.weight", True),
+    "mlp_norm": ("post_attention_layernorm.weight", False),
+}
+_HYBRID_EXTRA_MAP = {
+    "wq": ("self_attn.q_proj.weight", True),
+    "wg": ("self_attn.g_proj.weight", True),
+    "b_router": ("mlp.gate.expert_bias", False),
+}
 
-    count = stack_layers(config)
-    kinds = {"dense": ({**_LATENT_MAP, **_LATENT_DENSE_MAP}, {}),
-             "moe": ({**_LATENT_MAP, **_LATENT_MOE_MAP}, _LATENT_EXPERT_MAP)}
-    first = {"dense": 0, "moe": count["dense"]}
-    return {
-        stack: (first[stack], count[stack],
-                {k: v for k, v in kinds[stack][0].items() if k in shapes},
-                kinds[stack][1])
-        for stack, shapes in stack_shapes(config).items()}
+
+def latent_stack_plan(config) -> dict:
+    """Stack name -> ``(model layer ids, {ours: (HF suffix, transpose)},
+    {ours: expert pattern})`` for a latent-family model: what both
+    loaders and the writer walk. The ids are shaped as the stack leads
+    (``[layers]``, or ``[repeats, layers]`` for a repeated period:
+    ``models.llama.layer_plan``); ``models.llama.stack_shapes`` gives the
+    shapes. Tensors of layers past the model's depth (a next-token
+    prediction block) are never asked for."""
+    from cake_tpu.models.llama import plan_segments, segment_shapes
+
+    names = {**_LATENT_MAP, **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP,
+             **_HYBRID_EXTRA_MAP}
+    plan = {}
+    for run, seg in plan_segments(config):
+        shapes = segment_shapes(config, seg)
+        table = {**names, **_KDA_MAP} if seg.mixer == "kda" else names
+        plan[seg.name] = (
+            run.layer_ids(seg),
+            {k: table[k] for k in shapes if k in table and (
+                seg.ffn == "dense" or k not in _LATENT_EXPERT_MAP)},
+            _LATENT_EXPERT_MAP if seg.ffn == "moe" else {})
+    return plan
+
+
+def hf_layout(ours: str, w: np.ndarray, transpose: bool) -> np.ndarray:
+    """One layer's tensor as the checkpoint stores it: torch ``[out, in]``
+    for a linear, ``[C, 1, K]`` for a depthwise convolution's taps."""
+    if ours.startswith("conv_"):
+        return np.ascontiguousarray(w.T[:, None, :])
+    return w.T if transpose else w
 
 
 def is_latent_checkpoint(name_to_file: dict) -> bool:
@@ -490,19 +533,21 @@ def latent_hf_tensors(params: dict, config) -> dict[str, np.ndarray]:
         "model.norm.weight": np.asarray(params["norm_f"]),
         "lm_head.weight": np.asarray(params["lm_head"]).T,
     }
-    for stack, (first, n, plain, experts) in latent_stack_plan(
-            config).items():
+    for stack, (ids, plain, experts) in latent_stack_plan(config).items():
+        flat = ids.reshape(-1)
         for ours, (suffix, transpose) in plain.items():
             stacked = np.asarray(params["layers"][stack][ours])
-            for i in range(n):
-                tensors[f"model.layers.{first + i}.{suffix}"] = (
-                    stacked[i].T if transpose else stacked[i])
+            stacked = stacked.reshape((flat.size,) + stacked.shape[ids.ndim:])
+            for i, layer in enumerate(flat):
+                tensors[f"model.layers.{layer}.{suffix}"] = hf_layout(
+                    ours, stacked[i], transpose)
         for ours, pattern in experts.items():
             stacked = np.asarray(params["layers"][stack][ours])
-            for i in range(n):
+            stacked = stacked.reshape((flat.size,) + stacked.shape[ids.ndim:])
+            for i, layer in enumerate(flat):
                 for e in range(stacked.shape[1]):
                     name = pattern.format(e=config.first_expert + e)
-                    tensors[f"model.layers.{first + i}.{name}"] = (
+                    tensors[f"model.layers.{layer}.{name}"] = (
                         stacked[i, e].T)
     return tensors
 
@@ -511,7 +556,7 @@ def save_llama_params(params: dict, model_dir: str | Path,
                       num_layers: int | None = None, config=None):
     """Write a params pytree back to HF-format safetensors (test fixtures and
     the splitter round-trip). Inverse of :func:`load_llama_params`. A
-    latent-family pytree (two layer stacks) needs its ``config``."""
+    latent-family pytree (a dict of layer stacks) needs its ``config``."""
     from safetensors.numpy import save_file
 
     model_dir = Path(model_dir)

@@ -12,6 +12,7 @@ accepts it", never a result or a time.
 """
 
 import os
+import re
 from functools import partial
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -76,6 +77,20 @@ def _decode_stacked(layers, b, s, window, h=H, kvh=KVH, d=D):
                  ((layers, b, kvh, s, d), BF16), ((b,), I32), ((), I32)])
 
 
+def _kda_decode(layers, b, h=32, d=128, head_block=8):
+    """The delta-rule decode kernel as the layer loop calls it: the
+    stacked float32 state and a traced layer index."""
+    from cake_tpu.ops.pallas import kda_decode
+
+    def fn(q, k, v, g, beta, state, layer):
+        return kda_decode(q, k, v, g, beta, state, layer,
+                          head_block=head_block, interpret=False)
+
+    vec = ((b, h, d), F32)
+    return (fn, [vec, vec, vec, vec, ((b, h), F32),
+                 ((layers, b, h, d, d), F32), ((), I32)])
+
+
 def _qmm(m, k, n):
     return (partial(quant_matmul_pallas, interpret=False),
             [((m, k), BF16), ((k, n), I8), ((n,), F32)])
@@ -111,6 +126,11 @@ KERNELS = {
         2, 1, 4096, None, h=32, kvh=32),
     "flash_decode_stacked_b8_s2048_kvh16_d256": _decode_stacked(
         2, 8, 2048, None, h=16, kvh=16, d=256),
+    # Ling-3.0-flash's 32 heads of 128 x 128 at the cell's 32 slots, at
+    # 48, and one stream
+    "kda_decode_b32_h32": _kda_decode(6, 32),
+    "kda_decode_b48_h32": _kda_decode(6, 48),
+    "kda_decode_b1_h32": _kda_decode(6, 1),
     "qmm_m64_4096x14336": _qmm(64, HID, FFN),
     "qmm_m64_14336x4096": _qmm(64, FFN, HID),
     "qmm_m64_4096x32000": _qmm(64, HID, VOCAB),
@@ -352,13 +372,13 @@ def test_admit_prefill_program_keeps_one_staging_cache(topo, as_on_chip):
         assert temps <= 12 * 2**20, (depth, temps / 2**20)
 
 
-def _latent_programs(topo, layers: int, slots: int, window: int, bucket: int):
-    """(config, block decode, admission) compiled for one described v5e at
-    A.X-K1's published widths (one chip's share of 16, an eighth of the
-    vocabulary), bf16, ``layers`` of its depth."""
+def _family_programs(topo, config, slots: int, window: int, bucket: int):
+    """(block decode, admission) of a latent-family ``config`` compiled for
+    one described v5e, bf16: BatchGenerator's fused 8-step per-row block
+    decode over ``slots`` slots and one ``bucket``-token admission chunk
+    into the batch-1 staging cache."""
     from jax.sharding import NamedSharding
 
-    from cake_tpu.models.config import axk1_ep16
     from cake_tpu.models.llama import init_params
     from cake_tpu.ops.kvcache import init_cache
     from cake_tpu.ops.sampling import SamplerSettings
@@ -366,8 +386,6 @@ def _latent_programs(topo, layers: int, slots: int, window: int, bucket: int):
     from cake_tpu.parallel.pipeline import (build_admit_prefill,
                                             build_sharded_decode)
 
-    config = axk1_ep16(num_hidden_layers=layers, vocab_size=20480,
-                       max_seq_len=window)
     plan = MeshPlan.build(config, devices=topo.devices[:1])
 
     def placed(shapes, specs):
@@ -388,7 +406,8 @@ def _latent_programs(topo, layers: int, slots: int, window: int, bucket: int):
         return placed(
             jax.eval_shape(lambda: init_cache(config, batch=batch,
                                               max_seq=window)),
-            cache_specs(None, batch_replicated=batch == 1))
+            cache_specs(None, batch_replicated=batch == 1,
+                        recurrent=config.recurrent))
 
     settings = SamplerSettings(temperature=0.0)
     decode = build_sharded_decode(
@@ -399,7 +418,18 @@ def _latent_programs(topo, layers: int, slots: int, window: int, bucket: int):
             arg((slots,))).compile()
     admit = build_admit_prefill(config, plan, params_like=params).lower(
         params, arg((1, bucket)), cache(1), arg(()), arg((1,))).compile()
-    return config, decode, admit
+    return decode, admit
+
+
+def _latent_programs(topo, layers: int, slots: int, window: int, bucket: int):
+    """(config, block decode, admission) at A.X-K1's published widths (one
+    chip's share of 16, an eighth of the vocabulary), ``layers`` of its
+    depth."""
+    from cake_tpu.models.config import axk1_ep16
+
+    config = axk1_ep16(num_hidden_layers=layers, vocab_size=20480,
+                       max_seq_len=window)
+    return (config, *_family_programs(topo, config, slots, window, bucket))
 
 
 def test_latent_programs_move_no_cache_and_no_expert_stack(topo, as_on_chip):
@@ -435,3 +465,127 @@ def test_latent_programs_move_no_cache_and_no_expert_stack(topo, as_on_chip):
     # embedding and head = 4.29 GB = 4.0 GiB, + 0.42 GiB of latent cache
     assert 4.3 * GIB < args < 4.6 * GIB, args / GIB
     assert temps < 0.6 * GIB, temps / GIB
+
+
+def _hybrid_programs(topo, layers: int, slots: int, window: int, bucket: int):
+    """(config, block decode, admission) at Ling-3.0-flash's published
+    widths (one chip's share of 4, a quarter of the vocabulary), the cut's
+    ``layers`` (one leading dense)."""
+    from cake_tpu.models.config import ling3flash_ep4
+
+    config = ling3flash_ep4(num_hidden_layers=layers, first_k_dense_replace=1,
+                            vocab_size=39296, max_seq_len=window)
+    return (config, *_family_programs(topo, config, slots, window, bucket))
+
+
+def test_hybrid_programs_move_no_cache_no_state_and_no_expert_stack(
+        topo, as_on_chip):
+    """The delta-rule + latent hybrid's two serving programs at
+    Ling-3.0-flash's published widths, the cell
+    ``ling3flash-ep4-cut.decode-full`` itself: 7 layers (K | K K K K | M |
+    K: four segments, the fourth KDA stack of one layer after the latent
+    one), 32 slots x 4096 rows. The chip's compiler takes them; the cache's
+    two kinds of state (latent rows for the ONE latent layer, a float32
+    state and a convolution tail for the six delta-rule layers) are
+    carried through every segment and written in place, so nothing of any
+    of the four buffers' shapes is allocated or copied; no expert stack
+    ``[.., 128, 2560, 768]`` is written out of the scanned weights; the
+    decode step is the kernel, inside the layer loop, on the carried
+    state. Sizes: 9.75 GiB of weights + 0.53 GiB of cache in, under 0.3
+    GiB of temporaries: the cell fits the chip with the admission's
+    staging row and a second cache while the splice is undonated (and
+    would at 48 slots: 10.54 + 0.17 GiB; the slots are 32 for the spread
+    of TTFT between seeds, not for memory)."""
+    from cake_tpu.utils.chips import HBM_GIB
+
+    layers, slots, window = 7, 32, 4096
+    config, decode, admit = _hybrid_programs(topo, layers, slots, window, 512)
+    assert config.cache_plan == {"rows": (1, 1, 512, 64),
+                                 "state": (6, 32, 128, 128),
+                                 "conv": (6, 3, 12288)}
+    for compiled, batch in ((decode, slots), (admit, 1)):
+        for shape in (f"bf16[1,{batch},1,{window},512]",
+                      f"bf16[1,{batch},1,{window},64]",
+                      f"f32[6,{batch},32,128,128]",
+                      f"bf16[6,{batch},3,12288]"):
+            assert _cache_sized_moves(compiled, shape) == [], shape
+        slabs = [f"{c}: {n} ({op}) {shape}"
+                 for c, n, shape, op, _ in _instructions(compiled)
+                 if shape.endswith((",128,2560,768]", ",128,768,2560]"))
+                 and not c.startswith("fused_computation")
+                 and op not in ("parameter", "get-tuple-element", "bitcast",
+                                "tuple")]
+        assert slabs == []
+    # the kernel's result is a pair, which ``_instructions`` does not
+    # parse: read its calls off the text's lines
+    calls = [line for line in decode.as_text().splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line
+             and "kda_decode" in line]
+    assert len(calls) >= 1
+    for call in calls:
+        name = re.search(r'op_name="([^"]*)"', call).group(1)
+        assert name.count("while/body") == 3, name
+        # the state it returns is the operand it was given, in place
+        assert "output_to_operand_aliasing={{1}: (6, {})}" in call
+    args, temps = _donated_bytes(decode)
+    assert 10.15 * GIB < args < 10.4 * GIB, args / GIB  # 9.75 + 0.53
+    assert temps < 0.3 * GIB, temps / GIB
+    assert args + temps + 0.55 * GIB + 0.5 * GIB < HBM_GIB["v5 lite"] * GIB
+    m = admit.memory_analysis()
+    assert m.temp_size_in_bytes < 0.5 * GIB
+
+
+# sha256[:16] of the lowered text of each family's serving programs at tiny
+# widths, taken on PR 31's tree (commit 8273b40): the layer plan, the
+# cache's two kinds of state and the routing bias are additions that the
+# families PR 31 served do not pass through.
+PR31_TEXTS = {
+    "dense.decode": "cf6f26fdc793389e", "dense.admit": "13b469425420d448",
+    "sparse.decode": "9d4dfa59dafe21c7", "sparse.admit": "d9e1c8f0418fc104",
+    "latent.decode": "7744d2acc63cc1c6", "latent.admit": "5c206bbdc09ba295",
+}
+
+
+def test_existing_families_lower_to_the_text_they_had():
+    """The dense, sparse and latent families' block decode and admission
+    programs lower (StableHLO, CPU, tiny widths) to the text PR 31's tree
+    gave them, so the chip's compiler sees what it saw and the four
+    cells it measured stay where they are. A PR that changes one of
+    these programs on purpose replaces its hash here, and says so."""
+    import hashlib
+
+    from cake_tpu.models.config import tiny, tiny_mla_moe, tiny_moe
+    from cake_tpu.models.llama import init_params
+    from cake_tpu.ops.kvcache import init_cache
+    from cake_tpu.ops.sampling import SamplerSettings
+    from cake_tpu.parallel.mesh import MeshPlan
+    from cake_tpu.parallel.pipeline import (build_admit_prefill,
+                                            build_sharded_decode)
+
+    got = {}
+    settings = SamplerSettings(temperature=0.0)
+    for name, config in (("dense", tiny(sliding_window=32)),
+                         ("sparse", tiny_moe()), ("latent", tiny_mla_moe())):
+        plan = MeshPlan.build(config, devices=jax.devices()[:1])
+        params = jax.eval_shape(lambda k: init_params(config, k),
+                                jax.random.PRNGKey(0))
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, I32)
+
+        def cache(b):
+            return jax.eval_shape(
+                lambda: init_cache(config, batch=b, max_seq=64))
+
+        decode = build_sharded_decode(
+            config, settings, plan, params_like=params, steps=4,
+            per_row=True).lower(
+            params, i32(4), cache(4), i32(4),
+            jax.ShapeDtypeStruct((4, 2), jnp.uint32),
+            i32(4, settings.repeat_last_n), i32(4), i32(4))
+        admit = build_admit_prefill(config, plan, params_like=params).lower(
+            params, i32(1, 16), cache(1), i32(), i32(1))
+        for kind, lowered in (("decode", decode), ("admit", admit)):
+            got[f"{name}.{kind}"] = hashlib.sha256(
+                lowered.as_text().encode()).hexdigest()[:16]
+    assert got == PR31_TEXTS

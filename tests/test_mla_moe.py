@@ -149,20 +149,34 @@ def test_batch_generator_four_streams_match_reference(params):
     assert stats["tokens_emitted"] == 4 * 9
 
 
-def test_moe_counters_and_cache_gauges(params):
+@pytest.mark.parametrize("share", ["cut", "whole"])
+def test_moe_counters_and_cache_gauges(params, share):
+    """``moe.*`` count the LIVE rows' pairs. With every expert held
+    (``whole``) each routed pair is a local one, so the two counters are
+    equal to the pair, before and after a slot is retired: that is the
+    exact check. With a share held (``cut``: experts 4-11, two groups of
+    four) the local pairs are a part of the routed ones; how large a part
+    depends on which tokens the greedy streams happen to emit, and on a
+    loaded machine XLA's CPU reductions round differently and random tiny
+    weights emit other tokens (the driver's run of PR 31 met 8 steps of one
+    live row that never chose a held expert), so after the retirement only
+    ``local <= routed`` is held."""
     from cake_tpu.obs import metrics
     from cake_tpu.runtime.batch_generator import BatchGenerator
 
     reg = metrics.registry()
     before = {n: reg.counter(n).value for n in
               ("moe.local_pairs", "moe.routed_pairs", "moe.decode_steps")}
-    cfg = dataclasses.replace(CFG, eos_token_id=-1, n_routed_experts=4,
-                              router_experts=16, first_expert=4)
-    p = jax.tree.map(lambda a: a, params)
-    p["layers"] = dict(p["layers"])
-    p["layers"]["moe"] = {
-        k: (v[:, 4:8] if k in ("w_gate", "w_up", "w_down") else v)
-        for k, v in p["layers"]["moe"].items()}
+    cfg = dataclasses.replace(CFG, eos_token_id=-1)
+    p = params
+    if share == "cut":
+        cfg = dataclasses.replace(cfg, n_routed_experts=8, router_experts=16,
+                                  first_expert=4)
+        p = jax.tree.map(lambda a: a, params)
+        p["layers"] = dict(p["layers"])
+        p["layers"]["moe"] = {
+            k: (v[:, 4:12] if k in ("w_gate", "w_up", "w_down") else v)
+            for k, v in p["layers"]["moe"].items()}
     bg = BatchGenerator(cfg, p, settings=SamplerSettings(**GREEDY),
                         block_size=4, max_seq=64)
     bg.set_prompts([[5, 9, 2], [3, 1, 4, 1]])
@@ -172,12 +186,20 @@ def test_moe_counters_and_cache_gauges(params):
         before.update({n: reg.counter(n).value for n in before})
         return got
 
+    def held_part(got):
+        if share == "whole":
+            assert got["moe.local_pairs"] == got["moe.routed_pairs"]
+        else:
+            assert got["moe.local_pairs"] <= got["moe.routed_pairs"]
+
     bg.generate(9)
     got = counted()
     steps = got["moe.decode_steps"]
     assert steps >= 8
     assert got["moe.routed_pairs"] == steps * 2 * 4 * 2  # rows x k x layers
-    assert 0 < got["moe.local_pairs"] < got["moe.routed_pairs"]
+    held_part(got)
+    if share == "cut":  # 32 routings over 2 of 4 groups: some hit, some miss
+        assert 0 < got["moe.local_pairs"] < got["moe.routed_pairs"]
     # a retired slot's row still goes through the program, and is no load:
     # only the rows live at dispatch are counted
     bg.drain()
@@ -188,7 +210,7 @@ def test_moe_counters_and_cache_gauges(params):
     got = counted()
     assert got["moe.decode_steps"] >= 8
     assert got["moe.routed_pairs"] == got["moe.decode_steps"] * 1 * 4 * 2
-    assert 0 < got["moe.local_pairs"] < got["moe.routed_pairs"]
+    held_part(got)
     assert reg.gauge("cache.row_bytes").value == 4 * (16 + 8)
     assert reg.gauge("cache.bytes").value == 3 * 2 * 64 * 4 * (16 + 8)
 
