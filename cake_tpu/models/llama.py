@@ -25,6 +25,7 @@ TPU-first design decisions:
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, NamedTuple
 
 import jax
@@ -37,7 +38,7 @@ from cake_tpu.ops.kda import kda_attention_block
 from cake_tpu.ops.kvcache import KVCache
 from cake_tpu.ops.mla import latent_attention_block
 from cake_tpu.ops.mlp import swiglu
-from cake_tpu.ops.moe import GroupRouting, moe_swiglu
+from cake_tpu.ops.moe import GroupRouting, moe_swiglu, reads_whole_stacks
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.rope import rope_tables_for
 
@@ -478,6 +479,7 @@ def block_forward(
     ep_size: int | None = None,
     layer_idx: jax.Array | None = None,
     count_local: bool = False,
+    expert_idx: jax.Array | None = None,
 ):
     """One pre-norm decoder block (transformer.rs:48-64). Returns ``(x,
     k_cache, v_cache)``; with ``count_local`` (an expert layer of the
@@ -487,6 +489,8 @@ def block_forward(
     ``layer_idx``: ``k_cache``/``v_cache`` are the stacked ``[L, B,
     kv_heads, S, D]`` cache and this block is layer ``layer_idx`` of it
     (:func:`forward_layers`); None: they are this layer's own buffers.
+    ``expert_idx``: likewise ``layer``'s ``w_gate``/``w_up``/``w_down`` are
+    the whole expert stacks and this is layer ``expert_idx`` of them.
 
     Under tensor parallelism (inside shard_map), ``num_heads``/``num_kv_heads``
     are the per-device local counts and ``tp_axis`` names the mesh axis the
@@ -510,7 +514,7 @@ def block_forward(
     if "wkv_a" in layer:
         return _latent_block(layer, x, h, k_cache, v_cache, cos, sin, pos,
                              config, write_gate, ep_axis, ep_size,
-                             layer_idx, count_local)
+                             layer_idx, count_local, expert_idx)
     attn_out, k_cache, v_cache = self_attention_block(
         h, layer["wq"], layer["wk"], layer["wv"], layer["wo"],
         k_cache, v_cache, cos, sin, pos,
@@ -537,6 +541,7 @@ def block_forward(
             h, layer["router"], layer["w_gate"], layer["w_up"],
             layer["w_down"], top_k=config.num_experts_per_tok,
             ep_axis=ep_axis, ep_size=ep_size, tp_axis=tp_axis,
+            layer=expert_idx,
         )
     else:
         x = x + swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"],
@@ -545,7 +550,8 @@ def block_forward(
 
 
 def _latent_block(layer, x, h, c_cache, r_cache, cos, sin, pos, config,
-                  write_gate, ep_axis, ep_size, layer_idx, count_local):
+                  write_gate, ep_axis, ep_size, layer_idx, count_local,
+                  expert_idx):
     """The rest of :func:`block_forward` for a latent-attention layer:
     ``h`` is the normed input."""
     with jax.named_scope("mla"):
@@ -553,13 +559,14 @@ def _latent_block(layer, x, h, c_cache, r_cache, cos, sin, pos, config,
             h, layer, c_cache, r_cache, cos, sin, pos, config,
             write_gate=write_gate, layer_idx=layer_idx)
     x, local = _shared_feed_forward(layer, x + attn_out, config, ep_axis,
-                                    ep_size, count_local)
+                                    ep_size, count_local, expert_idx)
     if count_local:
         return x, c_cache, r_cache, local
     return x, c_cache, r_cache
 
 
-def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local):
+def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
+                         expert_idx):
     """The feed-forward half of a latent-family layer, residual added. A
     dense layer (no ``router``) is a SwiGLU of ``intermediate_size``; an
     expert layer is ``shared(h) + sum over the chosen experts HELD here
@@ -576,7 +583,7 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local):
                                  config.routed_scaling_factor,
                                  layer.get("b_router")),
             held=(config.first_expert, config.n_routed_experts),
-            count_local=count_local,
+            count_local=count_local, layer=expert_idx,
         )
         if count_local:
             y, local = y
@@ -591,7 +598,7 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local):
 
 
 def _kda_block(layer, x, cache, config, valid, ep_axis, ep_size, layer_idx,
-               count_local):
+               count_local, expert_idx):
     """One delta-rule layer of the latent family over the carried cache's
     recurrent buffers. Returns ``(x, cache, local_pairs)``."""
     h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
@@ -600,7 +607,7 @@ def _kda_block(layer, x, cache, config, valid, ep_axis, ep_size, layer_idx,
             h, layer, cache.state, cache.conv, config, valid=valid,
             layer_idx=layer_idx)
     x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
-                                    ep_size, count_local)
+                                    ep_size, count_local, expert_idx)
     return x, dataclasses.replace(cache, state=state, conv=conv), local
 
 
@@ -656,12 +663,27 @@ def forward_layers(
     recurrent buffers in place of rows (``valid [B]``: the true tokens of
     each row of a bucketed chunk, which alone touch that state).
     """
-    def body(carry, per_layer):
+    rows = x.shape[0] * x.shape[1]
+
+    def split(stack):
+        """``(scanned, whole)``: a stack's expert matrices taken out of
+        what the scan slices, where this call's rows take the expert
+        block's sorted form: its kernel reads a layer's matrices out of
+        the whole stacks (a scan's slice would be written out for it)."""
+        if "router" in stack and reads_whole_stacks(rows, stack["w_gate"]):
+            whole = {k: stack[k] for k in ("w_gate", "w_up", "w_down")}
+            return {k: v for k, v in stack.items() if k not in whole}, whole
+        return stack, {}
+
+    def body(carry, per_layer, whole=None):
         h, c, *local = carry
-        layer, i = per_layer
+        layer, i, *j = per_layer
+        if whole:
+            layer = {**layer, **whole}
+        j = j[0] if j else None
         if "w_decay" in layer:
             h, c, now = _kda_block(layer, h, c, config, valid, ep_axis,
-                                   ep_size, i, count_local)
+                                   ep_size, i, count_local, j)
         else:
             h, kc, vc, *now = block_forward(
                 layer, h, c.k, c.v, cos, sin, pos, config,
@@ -670,49 +692,64 @@ def forward_layers(
                 sp_size=sp_size, write_gate=write_gate,
                 sp_prefill=sp_prefill, sp_chunk=sp_chunk,
                 ep_axis=ep_axis, ep_size=ep_size,
-                layer_idx=i, count_local=count_local)
+                layer_idx=i, count_local=count_local, expert_idx=j)
             c = dataclasses.replace(c, k=kc, v=vc)
             now = now[0] if now else None
         if count_local:
             return (h, c, local[0] + now), None
         return (h, c), None
 
-    def scan_segment(carry, stack, first):
+    def scan_segment(carry, stack, first, whole, at=0):
         """``stack``'s layers over the carry; ``first``: its first layer's
         index into the cache buffers of its kind (a Python int, or traced
-        inside a repeated period)."""
+        inside a repeated period); ``whole``: the expert stacks it was
+        :func:`split` from, if any, and ``at`` its first layer's index
+        into them."""
         n = jax.tree.leaves(stack)[0].shape[0]
         index = (jnp.arange(first, first + n, dtype=jnp.int32)
                  if isinstance(first, int)
                  else first + jnp.arange(n, dtype=jnp.int32))
-        return jax.lax.scan(body, carry, (stack, index))[0]
+        if not whole:  # ONE body for every such segment: traced once
+            return jax.lax.scan(body, carry, (stack, index))[0]
+        return jax.lax.scan(
+            partial(body, whole=whole), carry,
+            (stack, index, at + jnp.arange(n, dtype=jnp.int32)))[0]
 
     def scan_period(carry, run):
         """``run``'s segments, ``run.repeats`` times over: one scan over
         the repetitions around the segments' own."""
+        stacks, wholes = {}, {}
+        for seg in run.segments:
+            stacks[seg.name], whole = split(layers[seg.name])
+            # [repeats, count, ..] -> [repeats * count, ..]: no data moves
+            wholes[seg.name] = jax.tree.map(
+                lambda w: w.reshape((-1,) + w.shape[2:]), whole)
+
         def period(carry, xs):
             stacks, r = xs
             for seg in run.segments:
                 carry = scan_segment(carry, stacks[seg.name],
-                                     seg.cache_first + r * seg.cache_stride)
+                                     seg.cache_first + r * seg.cache_stride,
+                                     wholes[seg.name], r * seg.count)
             return carry, None
 
         return jax.lax.scan(
             period, carry,
-            ({seg.name: layers[seg.name] for seg in run.segments},
-             jnp.arange(run.repeats, dtype=jnp.int32)))[0]
+            (stacks, jnp.arange(run.repeats, dtype=jnp.int32)))[0]
 
     carry = (x, cache)
     if count_local:
         carry += (jnp.zeros((x.shape[0],), jnp.int32),)
     if not config.latent:  # one kind of layer, one bare stack
-        return scan_segment(carry, layers, 0)
+        stack, whole = split(layers)
+        return scan_segment(carry, stack, 0, whole)
     for run in layer_plan(config):
         if run.repeats > 1:
             carry = scan_period(carry, run)
             continue
         for seg in run.segments:
-            carry = scan_segment(carry, layers[seg.name], seg.cache_first)
+            stack, whole = split(layers[seg.name])
+            carry = scan_segment(carry, stack, seg.cache_first, whole)
     return carry
 
 

@@ -75,6 +75,15 @@ SERIES: dict[str, tuple[str, str]] = {
         COUNTER, "admissions that started a slot's recurrent state from "
                  "zero (a fresh staging row, spliced over what the slot's "
                  "last stream left)"),
+    "moe.admit_rows": (
+        COUNTER, "prompt rows (the bucket's, padding included) that "
+                 "admission dispatches of an expert model ran through "
+                 "the expert block"),
+    "moe.admit_rows_sorted": (
+        COUNTER, "of moe.admit_rows, those of dispatches whose program "
+                 "took the sorted form (only the routed pairs on held "
+                 "experts computed), by what the expert block recorded "
+                 "when that bucket's program was traced"),
     "moe.decode_steps": (
         COUNTER, "decode steps whose routed pairs were counted"),
     "moe.local_pairs": (
@@ -84,6 +93,10 @@ SERIES: dict[str, tuple[str, str]] = {
     "moe.routed_pairs": (
         COUNTER, "(row, chosen expert) pairs decode steps routed over all "
                  "the router's experts: live rows x top-k x expert layers"),
+    "moe.sorted_from_rows": (
+        GAUGE, "the fewest rows of a traced call whose expert block took "
+               "the sorted form (ops.moe.expert_form, set at trace time; "
+               "0 while none has)"),
     # -- constrained decoding (cake_tpu/constrain) -----------------------
     "constrain.dead_ends": (
         COUNTER, "constrained streams retired at a grammar dead end"),

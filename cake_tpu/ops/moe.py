@@ -9,23 +9,42 @@ routing, softmax over the selected gate logits).
 TPU-first design:
 
 - **Static shapes only.** Routing never gathers a data-dependent *number* of
-  tokens. Two fixed-shape strategies, picked at trace time:
+  tokens. Three fixed-shape forms of one algorithm, ONE a program, picked
+  at trace time from the call's rows and the stacks' type
+  (:func:`expert_form`; which is better depends on how many rows share a
+  weight read):
 
-  * ``dense`` — every (local) expert runs over every token via batched
-    einsums (``[E, N, F]`` activations) and the per-token combine weights
-    zero out the non-selected experts. FLOPs are E/top_k× the routed
-    minimum, but every op is a large MXU matmul with no dynamic shapes; at
-    prefill the block is compute-bound and XLA keeps the expert axis as a
-    clean batch dimension.
-  * ``gather`` — decode-shaped inputs (tiny N): gather the top-k experts'
-    weight rows with ``jnp.take`` (static output shape ``[N, k, H, F]``)
-    and run only those. At N=1/k=2 this reads 2 experts' bytes instead of
-    E — the decode path is weights-bandwidth-bound, so the gather is the
-    difference between top-k and all-E HBM traffic per token.
+  * ``gather`` — a handful of pairs (tiny N, every expert here): gather
+    the top-k experts' weight rows with ``jnp.take`` (static output shape
+    ``[N, k, H, F]``) and run only those. At N=1/k=2 this reads 2 experts'
+    bytes instead of E — the decode path is weights-bandwidth-bound, so
+    the gather is the difference between top-k and all-E HBM traffic per
+    token.
+  * ``dense`` — a decode batch: every (local) expert runs over every token
+    via batched einsums (``[E, N, F]`` activations) and the per-token
+    combine weights zero out the non-selected experts. Each matrix is read
+    once and at a few rows an expert the arithmetic hides under the read.
+    At prompt rows it does not: E/top_k x the routed arithmetic (4x at
+    top-2 of 8, 64x at 128 held of 512), more than the experts' own bytes
+    from ~240 rows on in bf16 and ~120 in int8; and over a scanned int8
+    stack the chip's compiler writes each layer's stack out for the
+    dequantised batched product (6.7 ms a layer at Mixtral's widths, my
+    chip run, PR 33).
+  * ``sorted`` — prompt rows (an admission's bucket, a verify chunk, a
+    prefill): the ``N x k`` (row, chosen expert) pairs sorted by local
+    expert id, pairs on experts that are not here at the tail and never
+    computed; rows gathered once; gate, up and down each one grouped
+    matmul over the contiguous groups
+    (:func:`cake_tpu.ops.pallas.grouped_matmul`: an int8 stack streams as
+    int8 and is converted a block at a time); every row's results summed
+    under its routing weights in float32. Exact with no capacity, no
+    fallback and no control flow. Its kernel reads a layer's matrices out
+    of the WHOLE stacks the layer loop closes over (``layer=``), so no
+    layer's slice is written out for it (:func:`reads_whole_stacks`).
 
 - **Expert parallelism** shards the expert axis over the mesh's ``ep`` axis
   (:mod:`cake_tpu.parallel.mesh`): each rank holds ``E/ep`` experts' weights,
-  computes the dense path restricted to its local experts (tokens are
+  computes the dense or sorted form restricted to its local experts (tokens are
   replicated over ep — at inference scale activations are tiny next to
   expert weights), and the combine is a single ``psum`` over ``ep``. This
   composes with tensor parallelism: the expert intermediate axis shards over
@@ -40,12 +59,27 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from cake_tpu.obs import metrics as obs_metrics
+from cake_tpu.ops import pallas as pk
 from cake_tpu.ops.quant import QuantizedLinear, dequantize_linear
 
 # Decode/prefill strategy crossover: gather materializes [N*k, H, F] weight
 # rows, so it only pays off while N*k is well under E (single-digit serving
 # batches at decode). Above it the dense path's E-batched einsum wins.
 GATHER_MAX_ROWS = 8
+# Rows of a call from which the sorted form is taken, by the stacks' type:
+# where tools/moe_sweep.py measured it at 1.10x the dense form or better at
+# every cell's shape (my chip runs, PR 33; PERF.md section 6). int8: 3.0x
+# from 128 rows on (the dense form's dequantised product writes a layer's
+# stack out first) and 0.71x at 64, where that form has no slab and runs at
+# 89% of the bytes' roofline. bf16: 1.32x and 1.80x at 512 rows (12 held of
+# 192, 128 of 512), 0.85x and 1.10x at 256.
+SORTED_MIN_ROWS_INT8 = 128
+SORTED_MIN_ROWS = 512
+
+# rows of a call -> the form its trace took (what the engine's admission
+# counters ask: the form is a function of the shapes, so one entry a shape)
+_traced: dict[int, str] = {}
 
 
 class GroupRouting(NamedTuple):
@@ -166,6 +200,96 @@ def _moe_gather(
     return jnp.einsum("nk,nkh->nh", w_topk.astype(y.dtype), y)
 
 
+def _stack(w):
+    """The array that carries an expert stack's shape."""
+    return w.q if isinstance(w, QuantizedLinear) else w
+
+
+def _moe_sorted(
+    x2d: jax.Array,  # [N, H]
+    w_topk: jax.Array,  # [N, k] f32
+    idx: jax.Array,  # [N, k] int32 (global expert ids)
+    lo,  # the first global expert held here (an int, or traced under ep)
+    w_gate,  # [E_local, H, F] or, with ``layer``, the whole [L, E_local, ..]
+    w_up,
+    w_down,
+    layer,
+) -> jax.Array:
+    """Only the (row, chosen expert) pairs that fall on experts held here:
+    the ``N x k`` pairs sorted by local expert (a pair on an expert that
+    is not here sorts to the tail, which is never computed), the rows
+    gathered once, one grouped product each for gate, up and down over
+    the contiguous groups, and every row's ``k`` results summed under its
+    routing weights in float32. Exact whatever the routing: no capacity,
+    no fallback and no control flow."""
+    n, k = idx.shape
+    e_local = _stack(w_gate).shape[-3]
+    tm = pk.MOE_ROW_TILE
+    local = idx - lo
+    held = (local >= 0) & (local < e_local)  # [N, k]
+    key = jnp.where(held, local, e_local).reshape(-1)
+    m = -(-n * k // tm) * tm
+    key = jnp.pad(key, (0, m - n * k), constant_values=e_local)
+    order = jnp.argsort(key, stable=True)  # sorted place -> pair
+    place = jnp.argsort(order)[: n * k]  # pair -> sorted place
+    sizes = jnp.sum(key[:, None] == jnp.arange(e_local, dtype=key.dtype),
+                    axis=0, dtype=jnp.int32)
+    tiles = pk.group_tiles(sizes, m, tm)
+
+    def product(rows, w, out_dtype=None):
+        q, scale = (w.q, w.scale) if isinstance(w, QuantizedLinear) else (
+            w, None)
+        return pk.grouped_matmul(rows, q, tiles, layer=layer, scale=scale,
+                                 tm=tm, out_dtype=out_dtype)
+
+    xs = jnp.take(x2d, jnp.minimum(order // k, n - 1), axis=0)  # [M, H]
+    g = product(xs, w_gate)
+    u = product(xs, w_up)
+    y = product(jax.nn.silu(g) * u, w_down, jnp.float32)  # [M, H]
+    # rows past the last held pair were never written: select, not scale
+    y = jnp.where(held[..., None],
+                  jnp.take(y, place, axis=0).reshape(n, k, -1), 0.0)
+    return jnp.einsum("nk,nkh->nh", w_topk, y).astype(x2d.dtype)
+
+
+def expert_form(rows: int, top_k: int, quantized: bool, whole: bool) -> str:
+    """``"gather"``, ``"dense"`` or ``"sorted"``: THE strategy of a call,
+    from what its trace can see: its rows, the stacks' type, and whether
+    every expert the router scores is here (``whole``). One algorithm,
+    whose better form depends on how many rows share a weight read: a
+    handful of pairs gather their experts' matrices, a decode batch runs
+    every held expert over every row (each matrix is read once and the
+    arithmetic hides under the read), and from a bucket of prompt rows on
+    the dense form's arithmetic (every held expert over every row) costs
+    more than the read, so the pairs are sorted and only they are
+    computed. The sorted form's product is a Pallas kernel."""
+    if whole and rows * top_k <= GATHER_MAX_ROWS:
+        return "gather"
+    return "sorted" if _sorted_rows(rows, quantized) else "dense"
+
+
+def _sorted_rows(rows: int, quantized: bool) -> bool:
+    least = SORTED_MIN_ROWS_INT8 if quantized else SORTED_MIN_ROWS
+    return pk.kernels_enabled() and rows >= least
+
+
+def reads_whole_stacks(rows: int, w_gate) -> bool:
+    """Should the layer loop hand :func:`moe_swiglu` the whole expert
+    stacks and the layer's index, for a call of ``rows`` rows? Yes where
+    it takes the sorted form: a kernel's operand that is a scan's slice is
+    written out first (AOT for v5e: a slice of each of a layer's three
+    int8 stacks, the operation that costs 1.4 ms a stack where the dense
+    form pays it, my chip run, PR 33), the whole stack with an index is
+    read where it lies."""
+    return _sorted_rows(rows, isinstance(w_gate, QuantizedLinear))
+
+
+def form_traced(rows: int) -> str | None:
+    """The form the expert block took when a call of ``rows`` rows was
+    last traced in this process (None: no such call was)."""
+    return _traced.get(rows)
+
+
 def moe_swiglu(
     x: jax.Array,  # [B, T, H]
     router_w: jax.Array,  # [H, E_global]
@@ -179,6 +303,7 @@ def moe_swiglu(
     routing: GroupRouting | None = None,
     held: tuple[int, int] | None = None,
     count_local: bool = False,
+    layer: jax.Array | None = None,
 ):
     """Routed SwiGLU MLP. Returns ``[B, T, H]`` (residual NOT added); with
     ``count_local`` a pair ``(out, local_pairs)``, each batch row's number
@@ -202,14 +327,17 @@ def moe_swiglu(
     fused reduction when both are given). ``ep_size`` defaults to the mesh
     axis size (callers inside shard_map just pass the axis name; a size-1
     ep axis degrades to the unsharded strategies).
+
+    ``layer``: the three stacks are the layer loop's whole ``[L, E_local,
+    ..]`` stacks and this is layer ``layer`` of them (what
+    :func:`reads_whole_stacks` asks for: calls that take the sorted form).
     """
     b, t, h = x.shape
     x2d = x.reshape(b * t, h)
     with jax.named_scope("moe.router"):
         combine, w_topk, idx = router_topk(x2d, router_w, top_k, routing)
 
-    e_local = (w_gate.q if isinstance(w_gate, QuantizedLinear)
-               else w_gate).shape[0]
+    e_local = _stack(w_gate).shape[-3]
     e_global = combine.shape[1]
     first, count = held or (0, e_global)
     if ep_axis is not None and ep_size is None:
@@ -222,22 +350,31 @@ def moe_swiglu(
         ep_size = count // e_local
     sharded = ep_axis is not None and ep_size > 1
     axes: tuple[str, ...] = ()
+    # trace time: one strategy a program, from the shapes (no control flow
+    # in the layer body: a conditional's operands are buffers, so the
+    # chip's compiler writes the scanned expert stacks out before it, 24
+    # ms an admission, my chip run, PR 28)
+    form = expert_form(b * t, top_k, isinstance(w_gate, QuantizedLinear),
+                       not sharded and count == e_global)
+    assert layer is None or form == "sorted", (form, b * t)
+    _traced[b * t] = form
+    if form == "sorted":
+        gauge = obs_metrics.gauge("moe.sorted_from_rows")
+        gauge.set(min(b * t, gauge.value or b * t))
     with jax.named_scope("moe.experts"):
+        lo = first
         if sharded or count != e_global:
             # the stacks hold a slice of the experts the router scored
-            lo = first
             if sharded:
                 lo = first + jax.lax.axis_index(ep_axis) * e_local
                 axes += (ep_axis,)
             combine = jax.lax.dynamic_slice_in_dim(combine, lo, e_local, 1)
-            # every held expert over every row: no control flow in the
-            # layer body, whatever the rows (a conditional's operands are
-            # buffers, so the chip's compiler writes the scanned expert
-            # stacks out before it: 24 ms an admission, my chip run, PR 28)
-            out = _moe_dense(x2d, combine, w_gate, w_up, w_down)
-        elif x2d.shape[0] * top_k <= GATHER_MAX_ROWS:
+        if form == "sorted":
+            out = _moe_sorted(x2d, w_topk, idx, lo, w_gate, w_up, w_down,
+                              layer)
+        elif form == "gather":
             out = _moe_gather(x2d, w_topk, idx, w_gate, w_up, w_down)
-        else:
+        else:  # every held expert over every row
             out = _moe_dense(x2d, combine, w_gate, w_up, w_down)
     if tp_axis is not None:
         axes += (tp_axis,)

@@ -58,6 +58,11 @@ from cake_tpu.ops.pallas.flash import (  # noqa: E402
     flash_decode,
 )
 from cake_tpu.ops.pallas.kda import kda_decode  # noqa: E402
+from cake_tpu.ops.pallas.moe import (  # noqa: E402
+    ROW_TILE as MOE_ROW_TILE,
+    group_tiles,
+    grouped_matmul,
+)
 from cake_tpu.ops.pallas.quant import (  # noqa: E402
     quant4_matmul_pallas,
     quant_matmul_pallas,
@@ -75,6 +80,9 @@ __all__ = [
     "flash_attention_q8",
     "flash_decode",
     "kda_decode",
+    "MOE_ROW_TILE",
+    "group_tiles",
+    "grouped_matmul",
     "quant_matmul_pallas",
     "quant4_matmul_pallas",
 ]

@@ -98,6 +98,7 @@ from cake_tpu.obs import prof as obs_prof
 from cake_tpu.obs.trace import span
 from cake_tpu.ops import pallas as pk
 from cake_tpu.ops import quant, sampling
+from cake_tpu.ops.moe import form_traced as moe_form_traced
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.parallel.mesh import (
     DP,
@@ -147,6 +148,8 @@ _IMPORT_ABORTS = obs_metrics.counter("disagg.import_aborts")
 _MOE_LOCAL = obs_metrics.counter("moe.local_pairs")
 _MOE_ROUTED = obs_metrics.counter("moe.routed_pairs")
 _MOE_STEPS = obs_metrics.counter("moe.decode_steps")
+_MOE_ADMIT_ROWS = obs_metrics.counter("moe.admit_rows")
+_MOE_ADMIT_SORTED = obs_metrics.counter("moe.admit_rows_sorted")
 _STATE_RESETS = obs_metrics.counter("kda.state_resets")
 # The order of work at a block boundary (BatchGenerator._close_boundary):
 # host time from a block's fetch returning to the return of the step()
@@ -1981,6 +1984,7 @@ class BatchGenerator:
                 # is waited for where it lands)
                 np.asarray(logits.ravel()[:1])
         self._n_admit_dispatches += 1
+        self._count_admit_rows(chunk)
         st["pos"] = pos + chunk
         if not final:
             self._admit_dispatched(t0, chunk, base + pos)
@@ -1988,6 +1992,17 @@ class BatchGenerator:
         st["logits"], st["booking"] = logits, (t0, chunk, base + pos)
         if not (wait and self._rows_wait()):
             self._finish_admission()
+
+    def _count_admit_rows(self, chunk: int) -> None:
+        """An expert model's admission dispatch of ``chunk`` rows (the
+        bucket's, one staging row): add them to ``moe.admit_rows`` and,
+        where the expert block recorded the sorted form when this
+        bucket's program was traced, to ``moe.admit_rows_sorted``."""
+        if not any(ffn == "moe" for _, ffn in self.config.layer_kinds):
+            return
+        _MOE_ADMIT_ROWS.inc(chunk)
+        if moe_form_traced(chunk) == "sorted":
+            _MOE_ADMIT_SORTED.inc(chunk)
 
     def _admit_dispatched(self, t0: float, chunk: int, pos: int) -> None:
         """Book one admission chunk whose compute has been waited for."""

@@ -1,0 +1,197 @@
+"""The expert block over prompt rows: dense against sorted, a layer.
+
+Times :func:`cake_tpu.ops.moe.moe_swiglu` in the two forms
+:func:`cake_tpu.ops.moe.expert_form` chooses between for more than a
+handful of rows, as the layer loop calls it (a scan over ``L`` layers:
+the dense form on the scan's slice of the stacks, the sorted form on the
+whole stacks with the layer's index), at the three expert cells' shapes
+for 16 to 2048 rows. Where the sorted form is at least 1.10x the dense
+one is where ``SORTED_MIN_ROWS_INT8`` / ``SORTED_MIN_ROWS`` come from
+(PERF.md keeps the table). ``--forms ragged`` times the sorted form with
+``jax.lax.ragged_dot`` in the kernel's place (bf16 stacks only: it has no
+int8 operand, so a stack would be dequantised first).
+
+Usage:  python -m cake_tpu.tools.moe_sweep [--only NAME] [--rows 64,512]
+            [--forms dense,sorted,ragged] [--row-tile 128] [--json-out PATH]
+(``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
+
+Prints one JSON line per shape and row count: ``{"shape", "rows",
+"<form>_us_per_layer", "<form>_roofline", "speedup"}``: a form's share of
+max(the chosen held experts' bytes / 819 GB/s, the routed pairs'
+operations / 197 TFLOP/s) (what the block needs whichever form computes
+it; the router's weights give a near-uniform choice).
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import json
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+
+from cake_tpu.ops import moe
+from cake_tpu.ops import pallas as pk
+from cake_tpu.ops.quant import QuantizedLinear
+from cake_tpu.tools.kernel_check import refuse_offchip_record
+
+# name -> (held, scored, top_k, hidden, width, int8, group routing)
+SHAPES = {
+    "mixtral8x7b-int8": (8, 8, 2, 4096, 14336, True, None),
+    "axk1-ep16": (12, 192, 8, 7168, 2048, False, (8, 4)),
+    "ling3flash-ep4": (128, 512, 8, 2560, 768, False, (8, 4)),
+}
+ROWS = (16, 32, 64, 128, 256, 512, 1024, 2048)
+LAYERS = 3
+HBM_BYTES_S, BF16_FLOPS_S = 819e9, 197e12  # one v5e chip, published
+
+
+def _ragged_product(lhs, rhs, tiles, *, layer=None, scale=None,
+                    out_dtype=None, tm=None):
+    """``jax.lax.ragged_dot`` where the kernel stands (bf16 only)."""
+    assert scale is None
+    sizes = tiles.offsets[1:] - tiles.offsets[:-1]
+    w = rhs if layer is None else rhs[layer]
+    return jax.lax.ragged_dot(lhs, w, sizes,
+                              preferred_element_type=out_dtype or lhs.dtype)
+
+
+def _weights(key, layers, held, scored, hidden, width, int8):
+    keys = jax.random.split(key, 4)
+
+    def stack(k, fan_in, fan_out):
+        if int8:
+            q = jax.random.randint(k, (layers, held, fan_in, fan_out), -127,
+                                   128, jnp.int8)
+            scale = jnp.full((layers, held, fan_out),
+                             fan_in ** -0.5 / 64, jnp.float32)
+            return QuantizedLinear(q=q, scale=scale)
+        return (jax.random.normal(k, (layers, held, fan_in, fan_out),
+                                  jnp.bfloat16) * fan_in ** -0.5)
+
+    # logits of unit variance: sigmoid scores that do not saturate (ties
+    # would all go to the lowest expert ids) and a near-uniform choice
+    router = (jax.random.normal(keys[0], (layers, hidden, scored),
+                                jnp.float32) * hidden ** -0.5
+              ).astype(jnp.bfloat16)
+    return (router, stack(keys[1], hidden, width),
+            stack(keys[2], hidden, width), stack(keys[3], width, hidden))
+
+
+@contextlib.contextmanager
+def _steered(form: str, row_tile: int):
+    """While a form is traced: the expert block takes it whatever the
+    rows, at this row tile (``ragged``: the sorted form with
+    :func:`_ragged_product` where the kernel stands). Steering in the
+    tool: the program has no such knob."""
+    real = moe.expert_form, pk.MOE_ROW_TILE, pk.grouped_matmul
+    moe.expert_form = lambda *a: "dense" if form == "dense" else "sorted"
+    pk.MOE_ROW_TILE = row_tile
+    if form == "ragged":
+        pk.grouped_matmul = _ragged_product
+    try:
+        yield
+    finally:
+        moe.expert_form, pk.MOE_ROW_TILE, pk.grouped_matmul = real
+
+
+def _layers_fn(form, name):
+    held, scored, top_k, _, _, _, groups = SHAPES[name]
+    routing = moe.GroupRouting(*groups, True, 2.5) if groups else None
+    share = None if held == scored else (0, held)
+
+    def fn(x, router, w_gate, w_up, w_down):
+        def body(acc, per_layer):
+            if form == "dense":  # the stacks are the scan's slices
+                r, g, u, d = per_layer
+                y = moe.moe_swiglu(x, r, g, u, d, top_k, routing=routing,
+                                   held=share)
+            else:  # the whole stacks and an index
+                r, i = per_layer
+                y = moe.moe_swiglu(x, r, w_gate, w_up, w_down, top_k,
+                                   routing=routing, held=share, layer=i)
+            return acc + y, None
+
+        xs = ((router, w_gate, w_up, w_down) if form == "dense" else
+              (router, jnp.arange(router.shape[0], dtype=jnp.int32)))
+        return jax.lax.scan(body, jnp.zeros_like(x), xs)[0]
+
+    return jax.jit(fn)
+
+
+def _time_us(form, name, rows, weights, row_tile, iters: int = 5) -> float:
+    hidden = SHAPES[name][3]
+    x = jax.random.normal(jax.random.PRNGKey(rows), (1, rows, hidden),
+                          jnp.bfloat16)
+    fn = _layers_fn(form, name)
+    with _steered(form, row_tile):
+        jax.block_until_ready(fn(x, *weights))  # trace and compile
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = fn(x, *weights)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) * 1e6 / (iters * LAYERS)
+
+
+def floor_us(name: str, rows: int) -> float:
+    """The least a layer's expert block can take at uniform routing: the
+    bytes of the held experts some row chooses, once, or the arithmetic
+    of the pairs routed here (rows x top-k x held / scored)."""
+    held, scored, top_k, hidden, width, int8, _ = SHAPES[name]
+    chosen = held * (1 - (1 - top_k / scored) ** rows)
+    weight_bytes = 3 * chosen * hidden * width * (1 if int8 else 2)
+    flops = 3 * 2 * hidden * width * rows * top_k * held / scored
+    return max(weight_bytes / HBM_BYTES_S, flops / BF16_FLOPS_S) * 1e6
+
+
+def sweep(names, row_counts, forms, row_tile):
+    for name in names:
+        held, scored, _, hidden, width, int8, _ = SHAPES[name]
+        weights = _weights(jax.random.PRNGKey(0), LAYERS, held, scored,
+                           hidden, width, int8)
+        for rows in row_counts:
+            row = {"shape": name, "rows": rows, "row_tile": row_tile}
+            for form in forms:
+                if form == "ragged" and int8:
+                    continue
+                us = _time_us(form, name, rows, weights, row_tile)
+                row[f"{form}_us_per_layer"] = round(us, 1)
+                row[f"{form}_roofline"] = round(
+                    100 * floor_us(name, rows) / us, 1)
+            if "dense_us_per_layer" in row and "sorted_us_per_layer" in row:
+                row["speedup"] = round(row["dense_us_per_layer"]
+                                       / row["sorted_us_per_layer"], 3)
+            yield row
+        del weights
+
+
+def main() -> int:
+    from cake_tpu.utils.compile_cache import configure
+
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--only", choices=sorted(SHAPES))
+    ap.add_argument("--rows", type=lambda s: [int(v) for v in s.split(",")],
+                    default=list(ROWS))
+    ap.add_argument("--forms", type=lambda s: s.split(","),
+                    default=["dense", "sorted"])
+    ap.add_argument("--row-tile", type=int, default=pk.MOE_ROW_TILE)
+    ap.add_argument("--json-out")
+    a = ap.parse_args()
+    configure()
+    refuse_offchip_record(a.json_out)
+    out = []
+    for row in sweep([a.only] if a.only else list(SHAPES), a.rows, a.forms,
+                     a.row_tile):
+        print(json.dumps(row), flush=True)
+        out.append(row)
+    if a.json_out:
+        with open(a.json_out, "w") as f:
+            json.dump(out, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

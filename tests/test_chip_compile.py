@@ -91,6 +91,27 @@ def _kda_decode(layers, b, h=32, d=128, head_block=8):
                  ((layers, b, h, d, d), F32), ((), I32)])
 
 
+def _gmm(pairs, k, n, experts, layers, int8=False, out=BF16):
+    """The expert block's grouped matmul as the layer loop calls it for
+    prompt rows: ``pairs`` sorted rows (a 512-row bucket's ``rows x
+    top-k``), the whole ``[layers, experts, k, n]`` stack (int8 with a
+    scale per output channel, or bf16) and a traced layer index."""
+    from cake_tpu.ops.pallas.moe import ROW_TILE, GroupTiles
+
+    visits = pairs // ROW_TILE + experts - 1
+
+    def fn(lhs, rhs, scale, offsets, group, tile, count, layer):
+        return pk.grouped_matmul(
+            lhs, rhs, GroupTiles(offsets, group, tile, count), layer=layer,
+            scale=scale, out_dtype=out, interpret=False)
+
+    return (fn, [((pairs, k), BF16),
+                 ((layers, experts, k, n), I8 if int8 else BF16),
+                 ((layers, experts, n), F32) if int8 else None,
+                 ((experts + 1,), I32), ((visits,), I32), ((visits,), I32),
+                 ((1,), I32), ((), I32)])
+
+
 def _qmm(m, k, n):
     return (partial(quant_matmul_pallas, interpret=False),
             [((m, k), BF16), ((k, n), I8), ((n,), F32)])
@@ -131,6 +152,15 @@ KERNELS = {
     "kda_decode_b32_h32": _kda_decode(6, 32),
     "kda_decode_b48_h32": _kda_decode(6, 48),
     "kda_decode_b1_h32": _kda_decode(6, 1),
+    # the three expert cells' 512-row admission: Mixtral's 8 int8 experts
+    # (1024 pairs), A.X-K1's 12 held of 192 and Ling-3.0-flash's 128 held
+    # of 512 (4096 pairs each, whatever share of them falls here)
+    "gmm_mixtral_int8_gate": _gmm(1024, HID, FFN, 8, 7, int8=True),
+    "gmm_mixtral_int8_down": _gmm(1024, FFN, HID, 8, 7, int8=True, out=F32),
+    "gmm_axk1_gate": _gmm(4096, 7168, 2048, 12, 7),
+    "gmm_axk1_down": _gmm(4096, 2048, 7168, 12, 7, out=F32),
+    "gmm_ling_gate": _gmm(4096, 2560, 768, 128, 6),
+    "gmm_ling_down": _gmm(4096, 768, 2560, 128, 6, out=F32),
     "qmm_m64_4096x14336": _qmm(64, HID, FFN),
     "qmm_m64_14336x4096": _qmm(64, FFN, HID),
     "qmm_m64_4096x32000": _qmm(64, HID, VOCAB),
@@ -143,8 +173,8 @@ KERNELS = {
 def test_kernel_compiles_for_v5e(topo, name):
     fn, shapes = KERNELS[name]
     one_chip = SingleDeviceSharding(topo.devices[0])
-    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
-            for s, dt in shapes]
+    args = [None if spec is None else
+            jax.ShapeDtypeStruct(*spec, sharding=one_chip) for spec in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     # the kernel is in the program (not silently an XLA fallback)
     assert "tpu_custom_call" in compiled.as_text()
@@ -212,12 +242,13 @@ def _block_decode(topo, layers: int, sparse: bool = False):
     ).compile()
 
 
-def _admit_prefill(topo, layers: int, bucket: int):
+def _admit_prefill(topo, layers: int, bucket: int, sparse: bool = False):
     """The engine's admission program -- ``build_admit_prefill`` -- one
     ``bucket``-token chunk into the batch-1 staging cache."""
     from cake_tpu.parallel.pipeline import build_admit_prefill
 
-    config, plan, params, cache, arg = _engine_shapes(topo, layers, 1)
+    config, plan, params, cache, arg = _engine_shapes(topo, layers, 1,
+                                                      sparse)
     prog = build_admit_prefill(config, plan, params_like=params)
     return prog.lower(params, arg((1, bucket)), cache, arg(()),
                       arg((1,))).compile()
@@ -262,6 +293,32 @@ def _slabs_written(compiled, slabs: tuple[str, ...]) -> list[str]:
             if shape in slabs and not comp.startswith("fused_computation")
             and op not in ("parameter", "get-tuple-element", "bitcast",
                            "tuple")]
+
+
+def _expert_stack_moves(compiled, dtype: str, experts: int, k: int,
+                        n: int) -> list[str]:
+    """Instructions that leave one layer's expert stack behind as a value
+    of its own: of the shape ``[1, experts, k, n]``, its transpose, or
+    either without the leading 1, anywhere but inside a fusion (where a
+    slice of the stacked weights is how the consumer addresses them) and
+    other than parameters and bitcasts. What a conditional in the layer
+    body cost (PR 28), what the dense form's batched product over a
+    scanned int8 stack cost (6.7 ms a layer, my chip run, PR 33), and
+    what a kernel on a scan's slice would cost."""
+    slabs = {f"{dtype}[{lead}{experts},{a},{b}]"
+             for lead in ("1,", "") for a, b in ((k, n), (n, k))}
+    return [f"{comp}: {name} ({op}) {shape}"
+            for comp, name, shape, op, _ in _instructions(compiled)
+            if shape in slabs and not comp.startswith("fused_computation")
+            and op not in ("parameter", "get-tuple-element", "bitcast",
+                           "tuple")]
+
+
+def _grouped_matmul_calls(compiled) -> int:
+    """The expert block's kernel calls in the program's text."""
+    return sum("custom-call(" in line and "tpu_custom_call" in line
+               and "moe_grouped_matmul" in line
+               for line in compiled.as_text().splitlines())
 
 
 def _decode_kernel_calls(compiled) -> list[str]:
@@ -372,6 +429,31 @@ def test_admit_prefill_program_keeps_one_staging_cache(topo, as_on_chip):
         assert temps <= 12 * 2**20, (depth, temps / 2**20)
 
 
+def test_sparse_admission_reads_the_int8_stacks_where_they_lie(
+        topo, as_on_chip):
+    """Mixtral 8x7B's widths, int8, 3 layers, the sparse cell's 4096 rows:
+    the admission programs from the threshold's bucket to the 512-row one
+    take the expert block's sorted form, whose grouped matmul streams the
+    int8 stacks as they lie in the parameters: no instruction of a
+    layer's ``s8[1,8,4096,14336]`` or ``s8[1,8,14336,4096]`` (or their
+    rank-3 forms) anywhere, where the dense form's dequantised batched
+    product had two copies and two slices a layer (``copy.55``/``.56``,
+    ``constant_dynamic-slice_fusion.25``/``.26``: 6.74 ms a layer, 47 ms
+    of every admission, my chip run, PR 33), and temporaries of the
+    chunk's size, not a stack's (470 MB). The decode step's 8 rows stay
+    on the dense form: no kernel call there."""
+    from cake_tpu.ops.moe import SORTED_MIN_ROWS_INT8
+
+    for bucket in (SORTED_MIN_ROWS_INT8, 512):
+        compiled = _admit_prefill(topo, 3, bucket, sparse=True)
+        for k, n in ((HID, FFN),):
+            assert _expert_stack_moves(compiled, "s8", 8, k, n) == []
+        assert _grouped_matmul_calls(compiled) == 3
+        _, temps = _donated_bytes(compiled)
+        assert temps < 0.2 * GIB, (bucket, temps / GIB)
+    assert _grouped_matmul_calls(_block_decode(topo, 2, sparse=True)) == 0
+
+
 def _family_programs(topo, config, slots: int, window: int, bucket: int):
     """(block decode, admission) of a latent-family ``config`` compiled for
     one described v5e, bf16: BatchGenerator's fused 8-step per-row block
@@ -443,8 +525,10 @@ def test_latent_programs_move_no_cache_and_no_expert_stack(topo, as_on_chip):
     out of the scanned weights before use. That last one is what control
     flow in the layer body costs (a ``lax.cond`` between two expert
     strategies wrote the three stacks out before it: 24 ms of every
-    admission on the chip, PR 28), so a told share runs one strategy with
-    none."""
+    admission on the chip, PR 28), so a program runs one strategy, chosen
+    from its shapes when it is traced: the step every held expert over
+    every row, the 512-row admission the sorted form, whose kernel reads
+    the whole stacks the layer loop closes over (PR 33)."""
     layers, slots, window = 3, 32, 4096
     config, decode, admit = _latent_programs(topo, layers, slots, window, 512)
     assert config.cache_row == (1, 512, 64)
@@ -452,14 +536,11 @@ def test_latent_programs_move_no_cache_and_no_expert_stack(topo, as_on_chip):
         for width in (512, 64):
             assert _cache_sized_moves(
                 compiled, f"bf16[{layers},{batch},1,{window},{width}]") == []
-        slabs = [f"{c}: {n} ({op})"
-                 for c, n, shape, op, _ in _instructions(compiled)
-                 if shape in ("bf16[1,12,7168,2048]", "bf16[1,12,2048,7168]",
-                              "bf16[12,7168,2048]", "bf16[12,2048,7168]")
-                 and not c.startswith("fused_computation")
-                 and op not in ("parameter", "get-tuple-element", "bitcast",
-                                "tuple")]
-        assert slabs == []
+        assert _expert_stack_moves(compiled, "bf16", 12, 7168, 2048) == []
+    # the 512-row admission takes the sorted form: gate, up and down are
+    # the grouped matmul on the whole stacks; the 32-row step has none
+    assert _grouped_matmul_calls(admit) == 3
+    assert _grouped_matmul_calls(decode) == 0
     args, temps = _donated_bytes(decode)
     # 2 x 1.35 GB of expert layers + 1.0 of the dense one + 0.59 of
     # embedding and head = 4.29 GB = 4.0 GiB, + 0.42 GiB of latent cache
@@ -509,13 +590,10 @@ def test_hybrid_programs_move_no_cache_no_state_and_no_expert_stack(
                       f"f32[6,{batch},32,128,128]",
                       f"bf16[6,{batch},3,12288]"):
             assert _cache_sized_moves(compiled, shape) == [], shape
-        slabs = [f"{c}: {n} ({op}) {shape}"
-                 for c, n, shape, op, _ in _instructions(compiled)
-                 if shape.endswith((",128,2560,768]", ",128,768,2560]"))
-                 and not c.startswith("fused_computation")
-                 and op not in ("parameter", "get-tuple-element", "bitcast",
-                                "tuple")]
-        assert slabs == []
+        assert _expert_stack_moves(compiled, "bf16", 128, 2560, 768) == []
+    # three stacks of expert layers (K K K K | M | K), three products each
+    assert _grouped_matmul_calls(admit) == 9
+    assert _grouped_matmul_calls(decode) == 0
     # the kernel's result is a pair, which ``_instructions`` does not
     # parse: read its calls off the text's lines
     calls = [line for line in decode.as_text().splitlines()
@@ -543,18 +621,26 @@ PR31_TEXTS = {
     "dense.decode": "cf6f26fdc793389e", "dense.admit": "13b469425420d448",
     "sparse.decode": "9d4dfa59dafe21c7", "sparse.admit": "d9e1c8f0418fc104",
     "latent.decode": "7744d2acc63cc1c6", "latent.admit": "5c206bbdc09ba295",
+    # the hybrid's, taken on PR 32's tree (commit 9a9bb52): its delta-rule
+    # expert segments share ONE scan body, which a body built anew for
+    # each segment would lower once a segment (PR 33 met it)
+    "hybrid.decode": "f0140de63d5575f1", "hybrid.admit": "3c267fa227f8873b",
 }
 
 
 def test_existing_families_lower_to_the_text_they_had():
-    """The dense, sparse and latent families' block decode and admission
-    programs lower (StableHLO, CPU, tiny widths) to the text PR 31's tree
-    gave them, so the chip's compiler sees what it saw and the four
-    cells it measured stay where they are. A PR that changes one of
-    these programs on purpose replaces its hash here, and says so."""
+    """The dense, sparse, latent and hybrid families' block decode and
+    admission programs lower (StableHLO, CPU, tiny widths) to the text
+    PR 31's tree (PR 32's for the hybrid) gave them, so the chip's
+    compiler sees what it saw and the cells it measured stay where they
+    are: the 4-row step and the 16-row admission lie under every
+    threshold of the expert block's sorted form (PR 33). A PR that
+    changes one of these programs on purpose replaces its hash here, and
+    says so."""
     import hashlib
 
-    from cake_tpu.models.config import tiny, tiny_mla_moe, tiny_moe
+    from cake_tpu.models.config import (tiny, tiny_kda_hybrid, tiny_mla_moe,
+                                        tiny_moe)
     from cake_tpu.models.llama import init_params
     from cake_tpu.ops.kvcache import init_cache
     from cake_tpu.ops.sampling import SamplerSettings
@@ -565,7 +651,8 @@ def test_existing_families_lower_to_the_text_they_had():
     got = {}
     settings = SamplerSettings(temperature=0.0)
     for name, config in (("dense", tiny(sliding_window=32)),
-                         ("sparse", tiny_moe()), ("latent", tiny_mla_moe())):
+                         ("sparse", tiny_moe()), ("latent", tiny_mla_moe()),
+                         ("hybrid", tiny_kda_hybrid())):
         plan = MeshPlan.build(config, devices=jax.devices()[:1])
         params = jax.eval_shape(lambda k: init_params(config, k),
                                 jax.random.PRNGKey(0))

@@ -7,10 +7,15 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from cake_tpu.ops import moe
 from cake_tpu.ops.moe import (
     GATHER_MAX_ROWS,
+    SORTED_MIN_ROWS,
+    SORTED_MIN_ROWS_INT8,
+    GroupRouting,
     _moe_dense,
     _moe_gather,
+    expert_form,
     moe_swiglu,
     router_topk,
 )
@@ -130,6 +135,278 @@ def test_expert_parallel_matches_single_device(ep):
 
 
 # ---------------------------------------------------------------------------
+# The sorted form (prompt rows): only the routed pairs on held experts are
+# computed, by a Pallas grouped matmul (interpreted here).
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def kernels(monkeypatch):
+    """Off the chip the expert block stays dense unless kernels are
+    forced (interpreted), as every Pallas path of the repo."""
+    monkeypatch.setenv("CAKE_PALLAS", "1")
+
+
+# name -> (held, scored, top_k, routing, first held, stacks' type)
+SORTED_CASES = {
+    "mixtral-bf16": (8, 8, 2, None, 0, "bf16"),
+    "mixtral-int8": (8, 8, 2, None, 0, "int8"),
+    "12-of-192-grouped": (12, 192, 8, GroupRouting(8, 4, True, 2.5), 24,
+                          "f32"),
+    "128-of-512-bias": (128, 512, 8, GroupRouting(8, 4, True, 2.5, "bias"),
+                        128, "f32"),
+}
+
+
+def _sorted_case(name, rows, h=32, f=64, seed=0):
+    """``(x [1, rows, h], router, (gate, up, down), kwargs, plain)`` of a
+    case; ``plain``: the three stacks as float64 numpy, dequantised."""
+    from cake_tpu.ops.quant import dequantize_linear, quantize_linear
+
+    held, scored, top_k, routing, first, kind = SORTED_CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    dt = jnp.bfloat16 if kind in ("bf16", "int8") else jnp.float32
+    x = jax.random.normal(ks[0], (1, rows, h)).astype(dt)
+    rw = jax.random.normal(ks[1], (h, scored)).astype(dt)
+    stacks = [(jax.random.normal(k, shape) / d).astype(
+        jnp.float32 if kind == "int8" else dt)
+        for k, shape, d in ((ks[2], (held, h, f), 4), (ks[3], (held, h, f), 4),
+                            (ks[4], (held, f, h), 6))]
+    if kind == "int8":
+        stacks = [jax.vmap(quantize_linear)(w) for w in stacks]
+        plain = [np.asarray(dequantize_linear(w, jnp.float32), np.float64)
+                 for w in stacks]
+    else:
+        plain = [np.asarray(w, np.float64) for w in stacks]
+    if routing is not None and routing.bias is not None:
+        routing = routing._replace(bias=jax.random.normal(ks[5], (scored,)))
+    kw = dict(top_k=top_k, routing=routing,
+              held=None if held == scored else (first, held))
+    return x, rw, stacks, kw, plain
+
+
+def _pairs_oracle(x, rw, plain, kw):
+    """float64 loop over the (row, chosen expert) pairs the op's own
+    router chose (the router has tests of its own), held experts only."""
+    gate, up, down = plain
+    first = (kw["held"] or (0, 0))[0]
+    _, w, idx = router_topk(x[0], rw, kw["top_k"], kw["routing"])
+    x64 = np.asarray(x[0], np.float64)
+    out = np.zeros_like(x64)
+    for n, (ws, es) in enumerate(zip(np.asarray(w, np.float64),
+                                     np.asarray(idx) - first)):
+        for wgt, e in zip(ws, es):
+            if 0 <= e < gate.shape[0]:
+                g = x64[n] @ gate[e]
+                out[n] += wgt * ((g / (1 + np.exp(-g)) * (x64[n] @ up[e]))
+                                 @ down[e])
+    return out
+
+
+def _over_ep(fn, ep, stacks):
+    """``fn(stacks)`` with the expert axis sharded over ``ep`` devices."""
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:ep]), ("ep",))
+    stacks = jax.device_put(stacks, NamedSharding(mesh, P("ep")))
+    return shard_map(fn, mesh=mesh, in_specs=(P("ep"),), out_specs=P(),
+                     check_vma=False)(stacks)
+
+
+@pytest.mark.parametrize("ep", [1, 2])
+@pytest.mark.parametrize("rows", ["under", "threshold", 512])
+@pytest.mark.parametrize("name", list(SORTED_CASES))
+def test_sorted_form_is_the_dense_form_and_the_reference(
+        name, rows, ep, kernels):
+    """One rule on the call's rows serves every caller: under the
+    threshold of its stacks' type a call runs every held expert over
+    every row, from it on only the routed pairs on held experts, sorted
+    by expert (pairs on experts that are not here, or on the other rank's
+    under ``ep``, sort to the tail and are never computed). Both are the
+    float64 loop over the pairs, and ``count_local`` counts the same."""
+    least = (SORTED_MIN_ROWS_INT8 if SORTED_CASES[name][5] == "int8"
+             else SORTED_MIN_ROWS)
+    rows = {"under": least - 1, "threshold": least}.get(rows, rows)
+    x, rw, stacks, kw, plain = _sorted_case(name, rows)
+    tol = 3e-2 if x.dtype == jnp.bfloat16 else 3e-5
+
+    def run(stacks):
+        return moe_swiglu(x, rw, *stacks, count_local=True,
+                          ep_axis="ep" if ep > 1 else None, **kw)
+
+    def both():
+        return run(stacks) if ep == 1 else _over_ep(run, ep, stacks)
+
+    out, counted = both()
+    assert moe.form_traced(rows) == ("dense" if rows < least else "sorted")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("CAKE_PALLAS", "0")
+        dense, dense_counted = both()
+        assert moe.form_traced(rows) == "dense"
+    want = _pairs_oracle(x, rw, plain, kw)
+    scale = np.abs(want).max()
+    for got in (out, dense):
+        np.testing.assert_allclose(np.asarray(got[0], np.float64), want,
+                                   atol=tol * scale, rtol=0)
+    np.testing.assert_array_equal(np.asarray(counted),
+                                  np.asarray(dense_counted))
+    if kw["held"] is not None:  # a share: some pairs fell elsewhere
+        assert 0 < int(counted[0]) < rows * kw["top_k"]
+
+
+def test_sorted_row_with_no_held_choice_adds_exactly_zero(kernels):
+    """A row none of whose chosen experts is held here is never computed
+    and adds exactly zero (the kernel leaves rows past the last held
+    pair unwritten: they are selected away, not scaled), whatever lies in
+    them; 37 rows x 8 pairs is no whole number of row tiles."""
+    x, rw, stacks, kw, plain = _sorted_case("12-of-192-grouped", 37)
+    _, _, idx = router_topk(x[0], rw, kw["top_k"], kw["routing"])
+    first, count = kw["held"]
+    idx = np.asarray(idx)
+    away = ~((idx >= first) & (idx < first + count)).any(axis=1)
+    assert 0 < away.sum() < 37
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(moe, "SORTED_MIN_ROWS", 32)
+        out = np.asarray(moe_swiglu(x, rw, *stacks, **kw)[0])
+        assert moe.form_traced(37) == "sorted"
+    assert (out[away] == 0).all() and np.isfinite(out).all()
+    want = _pairs_oracle(x, rw, plain, kw)
+    np.testing.assert_allclose(out, want, atol=3e-5 * np.abs(want).max())
+
+
+def test_sorted_every_row_on_one_expert(kernels):
+    """The least balanced routing there is: every row chooses the same
+    two experts, so two groups hold every pair and six hold none."""
+    x, rw, stacks, kw, plain = _sorted_case("mixtral-int8", 256)
+    rw = jnp.zeros_like(rw).at[:, 5].set(1.0).at[:, 2].set(0.5)
+    x = jnp.abs(x)
+    _, _, idx = router_topk(x[0], rw, 2)
+    assert set(np.asarray(idx).ravel()) == {2, 5}
+    out = moe_swiglu(x, rw, *stacks, **kw)
+    assert moe.form_traced(256) == "sorted"
+    want = _pairs_oracle(x, rw, plain, kw)
+    np.testing.assert_allclose(np.asarray(out[0], np.float64), want,
+                               atol=3e-2 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("rows,top_k,int8,whole,form", [
+    (1, 2, False, True, "gather"), (4, 2, True, True, "gather"),
+    (8, 2, True, True, "dense"),  # the sparse cell's decode step
+    (32, 8, False, False, "dense"),  # the 32-slot cells' decode step
+    (1, 8, False, False, "dense"),  # a told share never gathers
+    (SORTED_MIN_ROWS_INT8 - 1, 2, True, True, "dense"),
+    (SORTED_MIN_ROWS_INT8, 2, True, True, "sorted"),
+    (SORTED_MIN_ROWS - 1, 8, False, False, "dense"),
+    (SORTED_MIN_ROWS, 8, False, False, "sorted"),
+    (2048, 2, False, True, "sorted"),
+])
+def test_decode_shaped_calls_keep_their_form(rows, top_k, int8, whole, form,
+                                             kernels, monkeypatch):
+    """One strategy a program, from the call's rows and the stacks' type:
+    decode calls take what they took before there was a sorted form, and
+    without kernels (the CPU's default) so does every call."""
+    assert expert_form(rows, top_k, int8, whole) == form
+    monkeypatch.setenv("CAKE_PALLAS", "0")
+    assert expert_form(rows, top_k, int8, whole) == (
+        "dense" if form == "sorted" else form)
+
+
+def _family(name):
+    from cake_tpu.models.config import tiny_kda_hybrid, tiny_mla_moe
+    from cake_tpu.ops.quant import quantize_params
+
+    cfg = {"mixtral": lambda: tiny_moe(max_seq_len=512),
+           "mixtral-int8": lambda: tiny_moe(max_seq_len=512),
+           "latent": lambda: tiny_mla_moe(max_seq_len=512),
+           # K K (M K K) x 2 M K: a repeated period's stacks lead [2, n]
+           "hybrid": lambda: tiny_kda_hybrid(num_hidden_layers=10,
+                                             max_seq_len=512)}[name]()
+    params = llama.init_params(cfg, jax.random.PRNGKey(1))
+    if name == "mixtral-int8":
+        params = quantize_params(params)
+    return cfg, params
+
+
+@pytest.mark.parametrize("name", ["mixtral", "mixtral-int8", "latent",
+                                  "hybrid"])
+def test_layer_loop_hands_the_sorted_form_whole_stacks(name, monkeypatch):
+    """A prefill of the threshold's rows through the layer loop of each
+    family (128 int8, 512 else): where the
+    expert block takes the sorted form the scan slices everything of a
+    layer but its expert matrices, which stay whole beside a layer index
+    (a repeated period's index runs over its repetitions too). Logits as
+    the dense form's."""
+    from cake_tpu.ops.kvcache import init_cache
+
+    cfg, params = _family(name)
+    rows = SORTED_MIN_ROWS_INT8 if name == "mixtral-int8" else SORTED_MIN_ROWS
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (1, rows), 0,
+                                cfg.vocab_size)
+
+    def logits(force):
+        monkeypatch.setenv("CAKE_PALLAS", force)
+        moe._traced.clear()
+        out, _ = jax.jit(lambda p, t: llama.forward(
+            p, t, init_cache(cfg, batch=1, max_seq=512), 0, cfg))(
+            params, tokens)
+        return np.asarray(out), moe.form_traced(rows)
+
+    want, form = logits("0")
+    assert form == "dense"
+    got, form = logits("1")
+    assert form == "sorted"
+    np.testing.assert_allclose(got, want, atol=2e-3 * np.abs(want).max())
+
+
+def test_engine_counts_admitted_rows_by_form(monkeypatch):
+    """Per admission dispatch the engine adds the bucket's rows to
+    ``moe.admit_rows`` and, where that bucket's program took the sorted
+    form when it was traced, to ``moe.admit_rows_sorted``; the gauge
+    ``moe.sorted_from_rows`` holds the smallest such bucket."""
+    from cake_tpu.obs import metrics
+    from cake_tpu.runtime.batch_generator import BatchGenerator
+
+    monkeypatch.setenv("CAKE_PALLAS", "1")
+    cfg = tiny_moe(max_seq_len=512, eos_token_id=-1)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    reg = metrics.registry()
+    rows, ordered = (reg.counter(f"moe.admit_rows{s}") for s in ("", "_sorted"))
+    reg.gauge("moe.sorted_from_rows").set(0)
+    before = rows.value, ordered.value
+    bg = BatchGenerator(cfg, params, settings=SamplerSettings(**GREEDY))
+    bg.set_prompts([[3, 5, 7], [2, 4]], stream_ids=[0, 1])
+    for sid, prompt in ((2, range(1, 21)),  # bucket 32: dense
+                        (3, range(1, 301))):  # bucket 512: sorted
+        assert bg.finish(sid - 2)
+        bg.admit([t % 250 + 1 for t in prompt], stream_id=sid)
+    assert rows.value - before[0] == 32 + 512
+    assert ordered.value - before[1] == 512
+    assert reg.gauge("moe.sorted_from_rows").value == 512
+    assert all(row is None or row.id >= 0 for row in bg.step())
+
+
+def test_moe_sweep_rows_at_tiny_shapes(monkeypatch, kernels):
+    """tools/moe_sweep.py's machinery on the CPU (interpreted kernel, no
+    device time): a row per shape and row count, each form timed through
+    ``moe_swiglu`` as the layer loop calls it, and the program's own
+    choice restored afterwards."""
+    from cake_tpu.tools import moe_sweep
+
+    monkeypatch.setattr(moe_sweep, "SHAPES", {
+        "tiny": (4, 4, 2, 32, 128, False, None),
+        "tiny-int8-share": (4, 16, 2, 32, 128, True, (4, 2))})
+    out = list(moe_sweep.sweep(["tiny", "tiny-int8-share"], [16, 128],
+                               ["dense", "sorted", "ragged"], 128))
+    assert [(r["shape"], r["rows"]) for r in out] == [
+        ("tiny", 16), ("tiny", 128), ("tiny-int8-share", 16),
+        ("tiny-int8-share", 128)]
+    for r in out:
+        assert r["dense_us_per_layer"] > 0 and r["sorted_us_per_layer"] > 0
+        assert ("ragged_us_per_layer" in r) == (r["shape"] == "tiny")
+    assert moe.expert_form is expert_form
+
+
+# ---------------------------------------------------------------------------
 # MoE over the mesh pipeline: the full generator surface with the expert
 # axis sharded (stage x ep x tp), token-identical to the all-local stream.
 # ---------------------------------------------------------------------------
@@ -168,6 +445,33 @@ def test_moe_mesh_greedy_parity_with_local(moe_params, axes):
     g = MeshGenerator(MOE_CFG, moe_params, settings=settings, **axes)
     g.set_prompt([5, 9, 2, 11])
     assert [g.next_token(i).id for i in range(6)] == want
+
+
+@pytest.mark.parametrize(
+    "axes", [dict(num_stages=2, ep=2), dict(ep=2, tp=2)],
+    ids=lambda a: "-".join(f"{k}{v}" for k, v in a.items()))
+def test_sorted_form_on_the_mesh_matches_local(axes, monkeypatch):
+    """A prompt of the int8 threshold's bucket through the mesh programs
+    (stages x ep, ep x tp): each rank's layer loop closes over ITS slice
+    of the expert stacks whole, the sorted form computes the pairs that
+    fall on its experts (and, under ``tp``, its slice of their width),
+    and the one ``psum`` sums the parts: the all-local dense stream."""
+    from cake_tpu.ops.quant import quantize_params
+
+    cfg = tiny_moe(max_seq_len=256)
+    params = quantize_params(llama.init_params(cfg, jax.random.PRNGKey(3)))
+    prompt = [t % 250 + 1 for t in range(SORTED_MIN_ROWS_INT8 + 2)]
+    settings = SamplerSettings(**GREEDY)
+    ref = LlamaGenerator(cfg, params, settings=settings)
+    ref.set_prompt(prompt)
+    want = [ref.next_token(i).id for i in range(4)]
+    assert moe.form_traced(2 * SORTED_MIN_ROWS_INT8) == "dense"
+
+    monkeypatch.setenv("CAKE_PALLAS", "1")
+    g = MeshGenerator(cfg, params, settings=settings, **axes)
+    g.set_prompt(prompt)
+    assert [g.next_token(i).id for i in range(4)] == want
+    assert moe.form_traced(2 * SORTED_MIN_ROWS_INT8) == "sorted"
 
 
 def test_ep_requires_moe_config():

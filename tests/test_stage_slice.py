@@ -11,7 +11,8 @@ import sys
 
 import pytest
 
-from cake_tpu.tools import flash_sweep, int4_sweep, kernel_check, stage_slice
+from cake_tpu.tools import (flash_sweep, int4_sweep, kda_sweep, kernel_check,
+                            moe_sweep, stage_slice)
 
 
 def test_stage_slice_mini_rows(capsys):
@@ -42,7 +43,7 @@ def test_slice_config_is_70b_geometry():
 
 
 @pytest.mark.parametrize("tool", [kernel_check, flash_sweep, int4_sweep,
-                                  stage_slice])
+                                  stage_slice, kda_sweep, moe_sweep])
 def test_tools_refuse_offchip_json_out(tool, tmp_path, monkeypatch):
     """Off a TPU the kernels run interpreted and a stage step is a CPU
     step: a --json-out file would record those under device names.
