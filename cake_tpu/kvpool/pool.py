@@ -96,10 +96,11 @@ def init_pool_on_mesh(config: LlamaConfig, mesh, num_pages: int,
     """Allocate a zeroed, mesh-sharded page pool (same no-host-copy
     contract as ``init_cache_on_mesh``: zeros come out of a compiled
     program with explicit output shardings)."""
-    if config.latent:
+    if config.segmented:
         raise ValueError(
-            "the page pool holds per-head keys and values; a latent-"
-            "attention model is served with the slot layout")
+            "the page pool holds per-head keys and values of every layer; "
+            "a latent-attention or state-space model is served with the "
+            "slot layout")
     key = ("init", mesh, config.num_hidden_layers,
            config.num_key_value_heads, config.head_dim, str(config.dtype),
            num_pages, page_size, quant)

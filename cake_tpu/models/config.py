@@ -127,6 +127,25 @@ class LlamaConfig:
     short_conv_kernel_size: int = 4
     kda_lower_bound: float = -5.0
     attn_gate: str | None = None
+    # --- selective state-space (Mamba-1) layers beside grouped-query
+    # attention (HF `model_type` "jamba") ------------------------------------
+    # ``attn_layer_period`` P > 0: layer i is grouped-query attention with
+    # NO rotary (or any other) position embedding if ``i % P ==
+    # attn_layer_offset`` and a Mamba mixer otherwise (ops/mamba.py):
+    # ``mamba_expand * hidden_size`` channels, each a ``mamba_d_state``-wide
+    # float32 state a stream, a causal depthwise convolution of
+    # ``mamba_d_conv`` taps (with a bias if ``mamba_conv_bias``), a step
+    # size a channel through a ``mamba_dt_rank`` bottleneck. A Mamba layer
+    # keeps no rows: its cache is the state ``[d_state, d_inner]`` and the
+    # convolution's last inputs (``cache_plan``). Every layer's
+    # feed-forward is the dense SwiGLU.
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0  # 0: ceil(hidden_size / 16), HF's "auto"
+    mamba_conv_bias: bool = True
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -162,6 +181,21 @@ class LlamaConfig:
             raise ValueError(
                 "layer_group_size > 0 (delta-rule layers beside latent "
                 "ones) needs the latent-attention keys (kv_lora_rank > 0)")
+        if self.attn_layer_period:
+            if self.kv_lora_rank or self.num_local_experts or (
+                    self.sliding_window is not None):
+                raise ValueError(
+                    "attn_layer_period > 0 (state-space layers beside "
+                    "grouped-query attention) is wired with full "
+                    "grouped-query attention and a dense feed-forward "
+                    "only: no latent keys, no experts, no sliding_window")
+            if not 0 <= self.attn_layer_offset < self.attn_layer_period:
+                raise ValueError(
+                    f"attn_layer_offset {self.attn_layer_offset} outside "
+                    f"the period of {self.attn_layer_period}")
+            if not self.mamba_dt_rank:
+                object.__setattr__(self, "mamba_dt_rank",
+                                   -(-self.hidden_size // 16))
         # validate at construction, not as a KeyError deep in a jit trace
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
@@ -192,15 +226,42 @@ class LlamaConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def state_space(self) -> bool:
+        """Mamba layers beside grouped-query attention layers that have no
+        position embedding."""
+        return self.attn_layer_period > 0
+
+    @property
+    def segmented(self) -> bool:
+        """Whether the layers are of several kinds, so that
+        ``params["layers"]`` is a dict of stacks, one a segment of
+        ``models.llama.layer_plan``, and not one bare stack."""
+        return self.latent or self.state_space
+
+    @property
+    def recurrent_mixer(self) -> str | None:
+        """The mixer of the layers that hold a recurrent state in place of
+        rows: "kda" (delta-rule linear attention, ops/kda.py), "mamba" (a
+        selective state space, ops/mamba.py), or None."""
+        if self.layer_group_size > 0:
+            return "kda"
+        return "mamba" if self.state_space else None
+
+    @property
     def recurrent(self) -> bool:
-        """Whether some layers hold a recurrent state in place of rows
-        (delta-rule linear attention, ops/kda.py)."""
-        return self.layer_group_size > 0
+        """Whether some layers hold a recurrent state in place of rows."""
+        return self.recurrent_mixer is not None
+
+    @property
+    def mamba_d_inner(self) -> int:
+        """Channels of a Mamba mixer."""
+        return self.mamba_expand * self.hidden_size
 
     @property
     def layer_kinds(self) -> tuple[tuple[str, str], ...]:
         """``(mixer, feed-forward)`` of every layer, in model order: the
-        mixer is "gqa", "mla" or "kda", the feed-forward "dense" or "moe".
+        mixer is "gqa", "mla", "kda" or "mamba", the feed-forward "dense" or
+        "moe".
         THE place the layer order comes from (models/llama.py
         ``layer_plan`` groups it into scanned segments, the cache and the
         loaders count it)."""
@@ -210,6 +271,9 @@ class LlamaConfig:
             0 if self.num_local_experts else n)
 
         def mixer(i):
+            if self.state_space:
+                return ("gqa" if i % self.attn_layer_period
+                        == self.attn_layer_offset else "mamba")
             if not self.latent:
                 return "gqa"
             g = self.layer_group_size
@@ -222,25 +286,33 @@ class LlamaConfig:
     def cache_plan(self) -> dict[str, tuple[int, ...]]:
         """What the cache holds, a kind of state each: ``rows`` ``(layers,
         heads, k_width, v_width)`` for the layers that keep rows (every
-        layer of a model with one kind of attention), and for delta-rule
-        layers ``state`` ``(layers, heads, d_k, d_v)`` (float32) and
-        ``conv`` ``(layers, taps - 1, channels)`` (the last inputs of the
-        q, k and v convolutions). A kind with no layer is left out."""
+        layer of a model with one kind of attention), and for the layers
+        that hold a recurrent state ``state`` (float32) and ``conv`` (the
+        convolutions' last inputs), shaped by their mixer: delta-rule
+        layers ``(layers, heads, d_k, d_v)`` and ``(layers, taps - 1, 3
+        heads d)`` (the q, k and v convolutions), Mamba layers ``(layers,
+        d_state, d_inner)`` (channels last, on the lanes) and ``(layers,
+        taps - 1, d_inner)``. A kind with no layer is left out."""
         mixers = [m for m, _ in self.layer_kinds]
+        held = mixers.count(self.recurrent_mixer)
         plan = {}
-        rows = len(mixers) - mixers.count("kda")
-        if rows:
-            plan["rows"] = (rows,) + self.cache_row
-        if self.recurrent and mixers.count("kda"):
+        if len(mixers) - held:
+            plan["rows"] = (len(mixers) - held,) + self.cache_row
+        if held and self.recurrent_mixer == "kda":
             h, d = self.num_attention_heads, self.head_dim
-            plan["state"] = (mixers.count("kda"), h, d, d)
-            plan["conv"] = (mixers.count("kda"),
-                            self.short_conv_kernel_size - 1, 3 * h * d)
+            plan["state"] = (held, h, d, d)
+            plan["conv"] = (held, self.short_conv_kernel_size - 1, 3 * h * d)
+        elif held:
+            plan["state"] = (held, self.mamba_d_state, self.mamba_d_inner)
+            plan["conv"] = (held, self.mamba_d_conv - 1, self.mamba_d_inner)
         return plan
 
     @property
     def rope_dim(self) -> int:
-        """Channels of a head that rotary embeddings cover."""
+        """Channels of a head that rotary embeddings cover; 0: the model
+        has no position embedding (position comes from the recurrence)."""
+        if self.state_space:
+            return 0
         return self.qk_rope_head_dim if self.latent else self.head_dim
 
     @property
@@ -338,7 +410,9 @@ class LlamaConfig:
                         f"(max_window_layers={mwl} of {layers}) is not "
                         "supported; all-or-none windowing only"
                     )
-        if d.get("model_type") == HYBRID_MODEL_TYPE:
+        if d.get("model_type") == STATE_SPACE_MODEL_TYPE:
+            kwargs.update(_state_space_kwargs(d))
+        elif d.get("model_type") == HYBRID_MODEL_TYPE:
             kwargs.update(_hybrid_kwargs(d))
         elif d.get("model_type") in LATENT_MODEL_TYPES:
             # DeepSeek-V3's keys. `topk_method` is read as the group-
@@ -400,7 +474,7 @@ class LlamaConfig:
                 "n_routed_experts": width,
                 "ep": width // self.n_routed_experts,
                 "rank": first // self.n_routed_experts}
-        if not self.recurrent:
+        if not self.layer_group_size:
             for f in _HYBRID_FIELDS:
                 d.pop(f)
         else:  # the family's own spelling of the keys it renames
@@ -413,6 +487,11 @@ class LlamaConfig:
                 self.moe_intermediate_size)
         if not self.router_bias:
             d.pop("router_bias", None)
+        if not self.state_space:
+            for f in _STATE_SPACE_FIELDS:
+                d.pop(f)
+        else:  # the family's other keys, at the only values served
+            d.update(_STATE_SPACE_FIXED)
         return d
 
 
@@ -482,6 +561,43 @@ def _hybrid_kwargs(d: dict) -> dict:
         kwargs["router_experts"] = share["n_routed_experts"]
         kwargs["first_expert"] = share["rank"] * held
     return kwargs
+
+
+# ... and the one whose layers are Mamba mixers but one grouped-query
+# attention layer in `attn_layer_period` (AI21's Jamba keys)
+STATE_SPACE_MODEL_TYPE = "jamba"
+_STATE_SPACE_FIELDS = ("attn_layer_period", "attn_layer_offset",
+                       "mamba_d_state", "mamba_d_conv", "mamba_expand",
+                       "mamba_dt_rank", "mamba_conv_bias")
+# what this family's config.json may ask for that nothing here computes:
+# key -> the only value served (experts in alternate layers, a window on
+# the attention layers, a bias on the mixer's projections)
+_STATE_SPACE_FIXED = {"num_experts": 1, "mamba_proj_bias": False}
+
+
+def _state_space_kwargs(d: dict) -> dict:
+    """`LlamaConfig` fields from a "jamba" config.json. What the file asks
+    for and nothing here computes is refused, not guessed."""
+    for key, only in _STATE_SPACE_FIXED.items():
+        if d.get(key, only) != only:
+            raise ValueError(
+                f"{STATE_SPACE_MODEL_TYPE}: {key} = {d[key]!r} is not "
+                f"wired (only {only!r})")
+    if d.get("sliding_window") is not None:
+        raise ValueError(
+            f"{STATE_SPACE_MODEL_TYPE}: sliding_window = "
+            f"{d['sliding_window']!r} is not wired (full attention only)")
+    rank = d.get("mamba_dt_rank", "auto")
+    return {
+        # one expert is the dense SwiGLU: its choice of 1 selects nothing
+        "num_experts_per_tok": LlamaConfig.num_experts_per_tok,
+        "mamba_dt_rank": 0 if rank == "auto" else rank,
+        # the family's defaults where the file leaves them out
+        "attn_layer_period": d.get("attn_layer_period", 8),
+        "attn_layer_offset": d.get("attn_layer_offset", 4),
+    }
+
+
 _LATENT_FIELDS = (
     "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
     "v_head_dim", "first_k_dense_replace", "moe_intermediate_size",
@@ -711,6 +827,37 @@ def ling3flash_ep4(**overrides) -> LlamaConfig:
     return LlamaConfig(**base)
 
 
+def jamba2_3b(**overrides) -> LlamaConfig:
+    """AI21-Jamba2-3B (https://huggingface.co/ai21labs/AI21-Jamba2-3B,
+    `model_type` "jamba") at its published sizes: 28 layers, Mamba mixers
+    (5120 channels of a 16-wide state) but layers 7 and 21, which are
+    attention of 20 query heads over one key/value head with no position
+    embedding; the dense 8192-wide SwiGLU in every layer; a tied head."""
+    base = dict(
+        model_type="jamba",
+        vocab_size=65536,
+        hidden_size=2560,
+        intermediate_size=8192,
+        num_hidden_layers=28,
+        num_attention_heads=20,
+        num_key_value_heads=1,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=True,
+        max_seq_len=262144,
+        attn_layer_period=14,
+        attn_layer_offset=7,
+        mamba_d_state=16,
+        mamba_d_conv=4,
+        mamba_expand=2,
+        mamba_dt_rank=160,
+        mamba_conv_bias=True,
+        bos_token_id=1,
+        eos_token_id=2,
+    )
+    base.update(overrides)
+    return LlamaConfig(**base)
+
+
 def tiny(**overrides) -> LlamaConfig:
     """Tiny random-weight config for tests (SURVEY.md §4 test strategy)."""
     base = dict(
@@ -801,6 +948,28 @@ def tiny_kda_hybrid(**overrides) -> LlamaConfig:
         topk_group=2,
         norm_topk_prob=True,
         routed_scaling_factor=2.5,
+        rms_norm_eps=1e-6,
+    )
+    base.update(overrides)
+    return tiny(**base)
+
+
+def tiny_jamba(**overrides) -> LlamaConfig:
+    """Tiny fixture of the state-space + attention hybrid that keeps the
+    published family's pattern (Jamba's keys): attention is one layer in
+    four (M A M M, twice: a period that starts and ends in Mamba layers,
+    as the published M7 A M6 does), one key/value head under four query
+    heads, 128 channels of an 8-wide state through a rank-8 step size, a
+    convolution bias, a tied head."""
+    base = dict(
+        model_type="jamba",
+        num_hidden_layers=8,
+        num_key_value_heads=1,
+        attn_layer_period=4,
+        attn_layer_offset=1,
+        mamba_d_state=8,
+        mamba_dt_rank=8,
+        tie_word_embeddings=True,
         rms_norm_eps=1e-6,
     )
     base.update(overrides)

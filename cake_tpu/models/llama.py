@@ -36,6 +36,7 @@ from cake_tpu.ops import quant
 from cake_tpu.ops.attention import self_attention_block
 from cake_tpu.ops.kda import kda_attention_block
 from cake_tpu.ops.kvcache import KVCache
+from cake_tpu.ops.mamba import mamba_mixer_block
 from cake_tpu.ops.mla import latent_attention_block
 from cake_tpu.ops.mlp import swiglu
 from cake_tpu.ops.moe import GroupRouting, moe_swiglu, reads_whole_stacks
@@ -132,6 +133,29 @@ _KDA_SHAPES = {
     "mlp_norm": lambda c: (c.hidden_size,),
 }
 
+# A selective state-space mixer (ops/mamba.py): the input projection to x
+# and the gate z, the depthwise convolution's taps and bias, the
+# projection to the step's rank and to B and C with their three inner
+# norms, the step size's projection and bias, ``A_log`` laid out ``[d_state,
+# d_inner]`` as the state is, the skip ``D``, the output projection.
+_MAMBA_SHAPES = {
+    "attn_norm": lambda c: (c.hidden_size,),
+    "w_in": lambda c: (c.hidden_size, 2 * c.mamba_d_inner),
+    "conv_w": lambda c: (c.mamba_d_conv, c.mamba_d_inner),
+    "conv_b": lambda c: (c.mamba_d_inner,),
+    "w_x": lambda c: (c.mamba_d_inner,
+                      c.mamba_dt_rank + 2 * c.mamba_d_state),
+    "dt_norm": lambda c: (c.mamba_dt_rank,),
+    "b_norm": lambda c: (c.mamba_d_state,),
+    "c_norm": lambda c: (c.mamba_d_state,),
+    "w_dt": lambda c: (c.mamba_dt_rank, c.mamba_d_inner),
+    "dt_bias": lambda c: (c.mamba_d_inner,),
+    "a_log": lambda c: (c.mamba_d_state, c.mamba_d_inner),
+    "d_skip": lambda c: (c.mamba_d_inner,),
+    "w_out": lambda c: (c.mamba_d_inner, c.hidden_size),
+    "mlp_norm": lambda c: (c.hidden_size,),
+}
+
 
 class Segment(NamedTuple):
     """Layers of ONE kind in a row, scanned as one stack: ``name`` is the
@@ -142,7 +166,7 @@ class Segment(NamedTuple):
     layers and ``cache_stride`` cached ones on."""
 
     name: str
-    mixer: str  # "mla" | "kda"
+    mixer: str  # "mla" | "kda" | "gqa" | "mamba"
     ffn: str  # "dense" | "moe"
     first: int
     count: int
@@ -170,23 +194,41 @@ class Run(NamedTuple):
         return ids[None] + self.stride * np.arange(self.repeats)[:, None]
 
 
+def _whole_period(kinds) -> int:
+    """The shortest period of several kinds that the WHOLE model repeats
+    (0: none). A period that starts and ends in one kind (M7 A M6, twice)
+    hides from a search over maximal stretches (M7 A M13 A M6), so
+    :func:`layer_plan` also ends a stretch where a repetition ends."""
+    n = len(kinds)
+    for width in range(2, n // 2 + 1):
+        if (n % width == 0 and len(set(kinds[:width])) > 1
+                and kinds == kinds[:width] * (n // width)):
+            return width
+    return 0
+
+
 def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
-    """The order the layer loop runs a latent-family model's layers in,
-    derived from ``config.layer_kinds`` alone: maximal stretches of one
-    kind are segments, a stretch of segments that repeats is one run
-    scanned over its repetitions. "Leading dense layers, then expert
-    layers" is two segments (``dense``, ``moe``); delta-rule layers but
-    every sixth is a period of two segments after the leading ones."""
+    """The order the layer loop runs a model's layers in where they are of
+    several kinds (``config.segmented``), derived from
+    ``config.layer_kinds`` alone: maximal stretches of one kind are
+    segments, a stretch of segments that repeats is one run scanned over
+    its repetitions. "Leading dense layers, then expert layers" is two
+    segments (``dense``, ``moe``); delta-rule layers but every sixth is a
+    period of two segments after the leading ones; state-space layers but
+    the eighth of every fourteen is a period of three (M7 A M6)."""
     kinds = config.layer_kinds
+    period = _whole_period(kinds)
     stretches = []  # [kind, first, count]
     for i, kind in enumerate(kinds):
-        if stretches and stretches[-1][0] == kind:
+        if (stretches and stretches[-1][0] == kind
+                and not (period and i % period == 0)):
             stretches[-1][2] += 1
         else:
             stretches.append([kind, i, 1])
     one_mixer = len({m for m, _ in kinds}) == 1
     names: dict[str, int] = {}
-    cached = {"mla": 0, "kda": 0}
+    # layers counted so far in the cache buffers of each mixer's kind
+    cached = {"mla": 0, "kda": 0, "gqa": 0, "mamba": 0}
 
     def segment(kind, first, count, cache_stride=0):
         mixer, ffn = kind
@@ -234,6 +276,13 @@ def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
     """Per-layer weight name -> shape builder of one segment's kind."""
     if seg.mixer == "kda":
         shapes = dict(_KDA_SHAPES)
+    elif seg.mixer == "mamba":
+        shapes = dict(_MAMBA_SHAPES)
+        if not config.mamba_conv_bias:
+            del shapes["conv_b"]
+    elif seg.mixer == "gqa":
+        shapes = {k: _LAYER_SHAPES[k] for k in (
+            "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm")}
     else:
         shapes = dict(_LATENT_SHAPES)
         if not config.q_lora_rank:  # one direct query projection
@@ -279,9 +328,10 @@ def layer_shapes(config: LlamaConfig) -> dict:
     ``attention_bias`` (Qwen2), with the dense MLP replaced by router +
     stacked expert weights when ``num_local_experts > 0`` (Mixtral). The
     latent family has two kinds of layer: :func:`stack_shapes`."""
-    if config.latent:
-        raise ValueError("a latent-attention model has two layer stacks: "
-                         "use stack_shapes(config)")
+    if config.segmented:
+        raise ValueError("a model whose layers are of several kinds "
+                         "(latent attention, a state space) has a stack a "
+                         "kind: use stack_shapes(config)")
     shapes = dict(_LAYER_SHAPES)
     if config.attention_bias:
         shapes.update(_BIAS_SHAPES)
@@ -317,7 +367,7 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
                 layers[name] = dense(k, (L,) + shape, fan_in)
         return layers
 
-    if config.latent:
+    if config.segmented:
         k_dense, k_moe, key = jax.random.split(key, 3)
         segs = plan_segments(config)
         seg_keys = (k_dense, k_moe) if len(segs) <= 2 else jax.random.split(
@@ -327,6 +377,8 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
             shapes = segment_shapes(config, seg)
             flat = stack(shapes, run.repeats * seg.count,
                          iter(jax.random.split(k, len(shapes))))
+            if seg.mixer == "mamba":
+                flat.update(_mamba_init(config, flat, k, dt))
             lead = run.layer_ids(seg).shape
             layers[seg.name] = {n: w.reshape(lead + w.shape[1:])
                                 for n, w in flat.items()}
@@ -342,6 +394,28 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
         "norm_f": jnp.ones((config.hidden_size,), dt),
         "lm_head": dense(next(keys), (config.hidden_size, config.vocab_size),
                          config.hidden_size),
+    }
+
+
+def _mamba_init(config: LlamaConfig, stack: dict, key, dt) -> dict:
+    """Mamba's own initialisation of what is no linear, for a stack of
+    layers: ``A_log = log(1..d_state)`` a channel, the step size's bias the
+    inverse softplus of a step log-uniform in 0.001-0.1 (so a token decays
+    a state by 0.2-0.999 and a state remembers tens to hundreds of tokens),
+    ``D = 1``, taps of std 0.5."""
+    n, di = config.mamba_d_state, config.mamba_d_inner
+    k_step, k_taps = jax.random.split(key)
+    step = jnp.exp(jax.random.uniform(
+        k_step, stack["dt_bias"].shape, jnp.float32,
+        jnp.log(0.001), jnp.log(0.1)))
+    return {
+        "a_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))[:, None],
+            stack["a_log"].shape).astype(dt),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+        "d_skip": jnp.ones_like(stack["d_skip"]),
+        "conv_w": (0.5 * jax.random.normal(
+            k_taps, stack["conv_w"].shape, jnp.float32)).astype(dt),
     }
 
 
@@ -368,11 +442,11 @@ def init_params_int4(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
 def _init_params_quantized(config, key, dtype, *, bits: int) -> Params:
     from functools import partial as _partial
 
-    if config.latent:
+    if config.segmented:
         raise NotImplementedError(
-            "random-init quantized params cover the per-head-attention "
-            "families; quantize a latent-family pytree with "
-            "ops.quant.quantize_params")
+            "random-init quantized params cover the one-stack "
+            "per-head-attention families; quantize a latent-family pytree "
+            "with ops.quant.quantize_params")
     if config.num_local_experts and bits == 4:
         from cake_tpu.ops.quant import reject_int4_moe
 
@@ -611,6 +685,19 @@ def _kda_block(layer, x, cache, config, valid, ep_axis, ep_size, layer_idx,
     return x, dataclasses.replace(cache, state=state, conv=conv), local
 
 
+def _mamba_block(layer, x, cache, config, valid, layer_idx):
+    """One state-space layer over the carried cache's recurrent buffers,
+    its dense feed-forward included. Returns ``(x, cache, local_pairs)``."""
+    h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
+    with jax.named_scope("mamba"):
+        out, state, conv = mamba_mixer_block(
+            h, layer, cache.state, cache.conv, config, valid=valid,
+            layer_idx=layer_idx)
+    x, local = _shared_feed_forward(layer, x + out, config, None, None,
+                                    False, None)
+    return x, dataclasses.replace(cache, state=state, conv=conv), local
+
+
 def forward_layers(
     layers: Params,  # stacked [L', ...] weights (any contiguous block range)
     x: jax.Array,  # [B, T, hidden]
@@ -659,9 +746,11 @@ def forward_layers(
     carried cache, its layers' indices into the cache buffers of their
     kind going on from where the last segment of that kind stopped; a
     repeated period of segments is one scan over its repetitions around
-    the segments' own. A delta-rule layer reads and writes the cache's
-    recurrent buffers in place of rows (``valid [B]``: the true tokens of
-    each row of a bucketed chunk, which alone touch that state).
+    the segments' own. A delta-rule or state-space layer reads and writes
+    the cache's recurrent buffers in place of rows (``valid [B]``: the true
+    tokens of each row of a bucketed chunk, which alone touch that state);
+    a state-space model's attention layers are segments of the plain
+    grouped-query block, with no rotation (``cos`` and ``sin`` None).
     """
     rows = x.shape[0] * x.shape[1]
 
@@ -684,6 +773,8 @@ def forward_layers(
         if "w_decay" in layer:
             h, c, now = _kda_block(layer, h, c, config, valid, ep_axis,
                                    ep_size, i, count_local, j)
+        elif "w_in" in layer:
+            h, c, now = _mamba_block(layer, h, c, config, valid, i)
         else:
             h, kc, vc, *now = block_forward(
                 layer, h, c.k, c.v, cos, sin, pos, config,
@@ -699,48 +790,58 @@ def forward_layers(
             return (h, c, local[0] + now), None
         return (h, c), None
 
-    def scan_segment(carry, stack, first, whole, at=0):
+    def scan_segment(carry, stack, first, whole):
         """``stack``'s layers over the carry; ``first``: its first layer's
-        index into the cache buffers of its kind (a Python int, or traced
-        inside a repeated period); ``whole``: the expert stacks it was
-        :func:`split` from, if any, and ``at`` its first layer's index
-        into them."""
+        index into the cache buffers of its kind; ``whole``: the expert
+        stacks it was :func:`split` from, if any."""
         n = jax.tree.leaves(stack)[0].shape[0]
-        index = (jnp.arange(first, first + n, dtype=jnp.int32)
-                 if isinstance(first, int)
-                 else first + jnp.arange(n, dtype=jnp.int32))
+        index = jnp.arange(first, first + n, dtype=jnp.int32)
         if not whole:  # ONE body for every such segment: traced once
             return jax.lax.scan(body, carry, (stack, index))[0]
         return jax.lax.scan(
             partial(body, whole=whole), carry,
-            (stack, index, at + jnp.arange(n, dtype=jnp.int32)))[0]
+            (stack, index, jnp.arange(n, dtype=jnp.int32)))[0]
 
     def scan_period(carry, run):
         """``run``'s segments, ``run.repeats`` times over: one scan over
-        the repetitions around the segments' own."""
-        stacks, wholes = {}, {}
-        for seg in run.segments:
-            stacks[seg.name], whole = split(layers[seg.name])
-            # [repeats, count, ..] -> [repeats * count, ..]: no data moves
-            wholes[seg.name] = jax.tree.map(
-                lambda w: w.reshape((-1,) + w.shape[2:]), whole)
+        the repetitions around the segments' own. The stacks stay WHOLE
+        outside both loops (``[repeats, count, ..]`` seen as ``[repeats *
+        count, ..]``: no data moves) and a layer's weights are indexed out
+        of them where they are used, as a scan indexes its ``xs``: a
+        repetition's slice handed to the inner loops as their ``xs`` would
+        be written out first, every repetition of every step (350 MiB a
+        projection at published widths: the chip's compiler, PR 34)."""
+        flat = {seg.name: split(jax.tree.map(
+            lambda w: w.reshape((-1,) + w.shape[2:]), layers[seg.name]))
+            for seg in run.segments}
 
-        def period(carry, xs):
-            stacks, r = xs
+        def scan_rows(carry, seg, r):
+            stack, whole = flat[seg.name]
+            at = r * seg.count  # the segment's first layer in its stacks
+            first = seg.cache_first + r * seg.cache_stride
+
+            def one(carry, j):
+                layer = jax.tree.map(
+                    lambda w: jax.lax.dynamic_index_in_dim(
+                        w, at + j, 0, keepdims=False), stack)
+                per_layer = (layer, first + j) + ((at + j,) if whole else ())
+                return body(carry, per_layer, whole=whole)
+
+            return jax.lax.scan(
+                one, carry, jnp.arange(seg.count, dtype=jnp.int32))[0]
+
+        def period(carry, r):
             for seg in run.segments:
-                carry = scan_segment(carry, stacks[seg.name],
-                                     seg.cache_first + r * seg.cache_stride,
-                                     wholes[seg.name], r * seg.count)
+                carry = scan_rows(carry, seg, r)
             return carry, None
 
         return jax.lax.scan(
-            period, carry,
-            (stacks, jnp.arange(run.repeats, dtype=jnp.int32)))[0]
+            period, carry, jnp.arange(run.repeats, dtype=jnp.int32))[0]
 
     carry = (x, cache)
     if count_local:
         carry += (jnp.zeros((x.shape[0],), jnp.int32),)
-    if not config.latent:  # one kind of layer, one bare stack
+    if not config.segmented:  # one kind of layer, one bare stack
         stack, whole = split(layers)
         return scan_segment(carry, stack, 0, whole)
     for run in layer_plan(config):

@@ -66,13 +66,24 @@ SERIES: dict[str, tuple[str, str]] = {
                "shared row"),
     "cache.state_bytes": (
         GAUGE, "bytes of the serving cache that are recurrent state "
-               "(delta-rule layers' float32 state and convolution tails), "
-               "from the buffers allocated; 0 where no layer holds one"),
+               "(delta-rule or state-space layers' float32 state and "
+               "convolution tails), from the buffers allocated; 0 where no "
+               "layer holds one"),
     "cache.state_bytes_per_stream": (
         GAUGE, "cache.state_bytes / slots: what a stream's recurrent "
                "state costs whatever its length"),
     "kda.state_resets": (
-        COUNTER, "admissions that started a slot's recurrent state from "
+        COUNTER, "admissions that started a slot's delta-rule state from "
+                 "zero (a fresh staging row, spliced over what the slot's "
+                 "last stream left)"),
+    "ssm.decode_kernel": (
+        GAUGE, "what ops.mamba.mamba_mixer_block chose for the last "
+               "single-token state-space step it traced (the decode "
+               "programs'): 1 the kernel that reads and writes each slot's "
+               "state once, in place, 0 XLA's fusions; absent where no "
+               "program holds a state-space layer"),
+    "ssm.state_resets": (
+        COUNTER, "admissions that started a slot's state-space state from "
                  "zero (a fresh staging row, spliced over what the slot's "
                  "last stream left)"),
     "moe.admit_rows": (

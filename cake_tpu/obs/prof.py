@@ -250,6 +250,9 @@ class StepProfiler:
         rec.update((k, round(v, 3)) for k, v in parts.items())
         rec["rest_ms"] = round(max(0.0, total_ms - sum(parts.values())), 3)
         rec["queued"], rec["running"] = queued, running
+        # the ring dies with the process and a benchmark run keeps only
+        # the server's log: say there which part of the pass held it
+        log.warning("slow scheduler pass: %s", rec)
         with self._lock:
             self._slow.append(rec)
 

@@ -91,10 +91,12 @@ class KVCache:
 
     A model whose layers are of two kinds holds two kinds of state
     (``LlamaConfig.cache_plan``): ``k``/``v`` have a layer for each layer
-    that keeps rows and nothing for the others, and ``state [L_rec, B, H,
-    d_k, d_v]`` (float32) and ``conv [L_rec, B, taps - 1, channels]`` hold
-    what a delta-rule layer keeps a stream, whatever its length
-    (ops/kda.py). Both are None where no layer is recurrent. Every buffer
+    that keeps rows and nothing for the others, and ``state`` (float32: a
+    delta-rule layer's ``[L_rec, B, H, d_k, d_v]``, ops/kda.py, or a
+    state-space layer's ``[L_rec, B, d_state, d_inner]``, ops/mamba.py)
+    and ``conv [L_rec, B, taps - 1, channels]`` hold what such a layer
+    keeps a stream, whatever its length. Both are None where no layer is
+    recurrent. Every buffer
     is ``[layers of its kind, batch, ...]``, so a slot's whole state is
     index ``b`` of axis 1 of every leaf.
     """
@@ -165,6 +167,11 @@ def init_cache(
             raise ValueError(
                 "an int8 cache is not wired for latent attention (the "
                 "latent row is already 1/35 of per-head keys and values)")
+        if rec:
+            raise ValueError(
+                "an int8 cache is not wired for a model whose layers hold "
+                "a recurrent state (its few layers of rows are the "
+                "smaller part of the cache)")
 
         def half(width):
             shape = (L, batch, heads, S, width)

@@ -116,7 +116,11 @@ def rope_tables(head_dim: int, max_seq: int, theta: float, dtype=jnp.float32,
 def rope_tables_for(config, max_seq: int, dtype=jnp.float32):
     """The tables of a model configuration: its rotary width
     (``config.rope_dim``: the whole head, or latent attention's rope part),
-    base and scaling. What every execution path calls."""
+    base and scaling. What every execution path calls. ``(None, None)`` for
+    a model with no position embedding (``rope_dim`` 0): no table is
+    built, and :func:`apply_rope` rotates nothing."""
+    if not config.rope_dim:
+        return None, None
     return rope_tables(config.rope_dim, max_seq, config.rope_theta,
                        dtype=dtype, scaling=config.rope_scaling)
 
@@ -141,6 +145,8 @@ def apply_rope(
     published implementation leaves it: queries and keys get the same
     permutation, so their dot products are those of the interleaved
     rotation."""
+    if cos is None:  # no position embedding (rope_tables_for)
+        return x
     b, h, t, d = x.shape
     half = d // 2
     if interleaved:

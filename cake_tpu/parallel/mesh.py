@@ -76,6 +76,14 @@ def validate_shardable(config: LlamaConfig, num_stages: int, tp: int,
             "one stage with tp = 1 and sp = 1; only --ep shards it (its "
             "held experts)"
         )
+    if config.state_space and (num_stages > 1 or tp > 1 or sp > 1
+                               or ep > 1):
+        raise ValueError(
+            "a state-space model (stacks of several kinds of layer, a "
+            "recurrent state a channel) runs as one stage with tp = 1, "
+            "sp = 1 and ep = 1: nothing shards it yet (tp over d_inner is "
+            "not wired)"
+        )
     if sp > 1 and config.max_seq_len % sp:
         raise ValueError(
             f"max_seq_len {config.max_seq_len} not divisible by sp {sp}"
@@ -212,10 +220,11 @@ def cache_specs(kv_quant: str | None = None, batch_replicated: bool = False,
     ``batch_replicated``: don't shard the batch axis over dp — the layout of
     a single-row staging cache (continuous-batching admission) that must
     exist on every dp shard. ``recurrent``
-    (``LlamaConfig.recurrent``): the cache also holds delta-rule layers'
-    state ``[L, B, H, d_k, d_v]`` and convolution tail ``[L, B, taps - 1,
-    C]``, batch over dp and nothing else sharded (such a model runs as one
-    stage with tp = 1)."""
+    (``LlamaConfig.recurrent``): the cache also holds recurrent layers'
+    state ``[L, B, ...]`` (a delta-rule layer's ``[L, B, H, d_k, d_v]``, a
+    state-space layer's ``[L, B, d_state, d_inner]``) and convolution tail
+    ``[L, B, taps - 1, C]``, batch over dp and no later axis sharded (such
+    a model runs as one stage with tp = 1)."""
     from cake_tpu.ops.kvcache import KVCache, QuantizedKV
 
     bd = None if batch_replicated else DP
@@ -224,8 +233,8 @@ def cache_specs(kv_quant: str | None = None, batch_replicated: bool = False,
         half = QuantizedKV(q=spec, scale=P(STAGE, bd, TP, SP))
         return KVCache(k=half, v=half)
     if recurrent:
-        return KVCache(k=spec, v=spec, state=P(STAGE, bd, None, None, None),
-                       conv=P(STAGE, bd, None, None))
+        return KVCache(k=spec, v=spec, state=P(STAGE, bd),
+                       conv=P(STAGE, bd))
     return KVCache(k=spec, v=spec)
 
 

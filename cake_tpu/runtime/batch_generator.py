@@ -150,7 +150,9 @@ _MOE_ROUTED = obs_metrics.counter("moe.routed_pairs")
 _MOE_STEPS = obs_metrics.counter("moe.decode_steps")
 _MOE_ADMIT_ROWS = obs_metrics.counter("moe.admit_rows")
 _MOE_ADMIT_SORTED = obs_metrics.counter("moe.admit_rows_sorted")
-_STATE_RESETS = obs_metrics.counter("kda.state_resets")
+# by the mixer that holds the state (LlamaConfig.recurrent_mixer)
+_STATE_RESETS = {"kda": obs_metrics.counter("kda.state_resets"),
+                 "mamba": obs_metrics.counter("ssm.state_resets")}
 # The order of work at a block boundary (BatchGenerator._close_boundary):
 # host time from a block's fetch returning to the return of the step()
 # call that enqueued the device's next program, once per landed block,
@@ -304,6 +306,13 @@ class BatchGenerator:
                 "page pool, and with it the disagg snapshot and the spill "
                 "tier, hold per-head keys and values, and no recurrent "
                 "state); serve this family with the slot layout")
+        if self._paged and config.state_space:
+            raise ValueError(
+                "kv_layout='paged' is not wired for a state-space model "
+                "(the page pool, and with it the disagg snapshot and the "
+                "spill tier, hold per-head keys and values of every layer, "
+                "and no recurrent state); serve this family with the slot "
+                "layout")
         if config.recurrent and spec_k:
             raise ValueError(
                 "speculation (spec_k) is not wired for a model whose "
@@ -2114,7 +2123,7 @@ class BatchGenerator:
             if self.config.recurrent:
                 # the zeroed row IS the reset: the splice copies its
                 # state and convolution tail over the slot's
-                _STATE_RESETS.inc()
+                _STATE_RESETS[self.config.recurrent_mixer].inc()
         self._staging = {
             "ids": ids, "sid": sid, "slot": slot,
             "tokens": tokens, "pos": 0, "chunk": chunk, "base": base,

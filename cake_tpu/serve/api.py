@@ -240,6 +240,16 @@ def _parse_request(body: dict, scheduler) -> Session:
                    guide=guide, cls=cls, tenant=tenant)
 
 
+class _ReplicaHTTPServer(http.server.ThreadingHTTPServer):
+    # A closed-loop client reconnects the moment its stream ends, and
+    # streams end together at a block boundary; a load generator opens
+    # every slot's connection at once. The stdlib's listen backlog of 5
+    # drops what overflows it, and the kernel offers the connection
+    # again a second later (or resets it): at 32-64 streams one burst
+    # overflows it (the gateway's front door has the same, and why).
+    request_queue_size = 128
+
+
 class ApiServer:
     """The serving front end; ``start_api_server`` is the entry point."""
 
@@ -271,7 +281,7 @@ class ApiServer:
                         "metrics": obs_metrics.registry().snapshot()}
         self.status_fn = status_fn
         handler = _make_handler(self)
-        self.httpd = http.server.ThreadingHTTPServer((bind, port), handler)
+        self.httpd = _ReplicaHTTPServer((bind, port), handler)
         self.port = self.httpd.server_address[1]
         self.bind = bind
         self._thread = threading.Thread(target=self.httpd.serve_forever,

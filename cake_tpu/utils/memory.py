@@ -62,7 +62,11 @@ def hbm_budget(
         lin_el, scale_el = el, 0
     S = max_seq or c.max_seq_len
     d = c.head_dim
-    if c.latent:
+    if c.state_space and quant:
+        raise ValueError("quantized linears are not wired for a state-space "
+                         "model (its mixer's projections have no int8 form "
+                         "yet)")
+    if c.segmented:
         return _latent_budget(c, ep, S, batch, lin_el, scale_el, el,
                               cache_bytes_per_el)
 
@@ -130,13 +134,14 @@ def hbm_budget(
 
 def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
                    el: int, cache_el: int) -> dict:
-    """:func:`hbm_budget` for the latent-attention, shared-expert family,
+    """:func:`hbm_budget` for a model whose layers are of several kinds
+    (the latent-attention, shared-expert family; a state-space hybrid),
     from the shapes the model is built with (``models.llama.stack_shapes``):
     every tensor replicated but the HELD experts' stacks, which divide over
     ep; the cache is the latent row (``LlamaConfig.cache_row_values`` a
     token a layer), not per-head keys and values, for the layers that
-    keep rows, and a delta-rule layer's state and convolution tail a
-    stream for the others (``LlamaConfig.cache_plan``). One stage, tp = sp = 1
+    keep rows, and a delta-rule or state-space layer's state and
+    convolution tail a stream for the others (``LlamaConfig.cache_plan``). One stage, tp = sp = 1
     (``mesh.validate_shardable``)."""
     import math
 

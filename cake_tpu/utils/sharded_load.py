@@ -158,7 +158,7 @@ def load_llama_params_on_mesh(
     krows = 2 if int4 else 1  # original rows per stored quantized row
 
     reader = CheckpointReader(model_dir)
-    if config.latent:
+    if config.segmented:
         try:
             return _load_latent_on_mesh(reader, model_dir, config, mesh,
                                         quantize, tie_word_embeddings)
@@ -585,7 +585,9 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
                          config: LlamaConfig, mesh: Mesh,
                          quantize: str | None,
                          tie_word_embeddings: bool) -> dict:
-    """The latent-attention, shared-expert family onto the mesh: a dict of
+    """A model whose layers are of several kinds (the latent-attention,
+    shared-expert family; a state-space hybrid, under its own tensor
+    names) onto the mesh: a dict of
     layer stacks, one a segment of ``models.llama.layer_plan``
     (``params["layers"] = {"dense": ..., "moe": ...}`` for leading dense
     layers then expert layers; a delta-rule hybrid's segments the same
@@ -601,11 +603,16 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
     from cake_tpu.ops.quant import (LATENT_LINEARS, QuantizedLinear,
                                     parse_quant_spec, quantize_linear_np,
                                     reject_int4_moe)
-    from cake_tpu.utils.weights import check_prequantized, latent_stack_plan
+    from cake_tpu.utils.weights import (check_prequantized, final_norm_name,
+                                        latent_stack_plan)
 
     tier, _ = parse_quant_spec(quantize)
     if tier == "int4":
         reject_int4_moe()
+    if tier is not None and config.state_space:
+        raise NotImplementedError(
+            "quantized linears are not wired for a state-space model (its "
+            "mixer's projections have no int8 form yet); serve it in bf16")
     prequantized = check_prequantized(reader.name_to_file, quantize)
     if not tie_word_embeddings and detect_tied_head(
             reader.name_to_file, model_dir, "cake_tpu.sharded_load"):
@@ -689,8 +696,8 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
         "layers": layers,
         "embed": stacked(lambda: "model.embed_tokens.weight", (), (v, h),
                          P(), False, False),
-        "norm_f": stacked(lambda: "model.norm.weight", (), (h,), P(), False,
-                          False),
+        "norm_f": stacked(lambda: final_norm_name(config), (), (h,), P(),
+                          False, False),
         "lm_head": stacked(lambda: head_name, (), (h, v), P(), True,
                            tier is not None),
     }

@@ -117,10 +117,47 @@ _HYBRID_EXTRA_MAP = {
     "b_router": ("mlp.gate.expert_bias", False),
 }
 
+# State-space layers beside grouped-query attention (`model_type` "jamba",
+# Hugging Face's own names, which config.json does not carry: the benchmark
+# configuration lists them): the mixer under `mamba.`, its convolution as
+# torch depthwise `[C, 1, K]` WITH a bias, `A_log` as `[d_inner, d_state]`
+# (ours is laid out as the state is, `[d_state, d_inner]`), the feed-forward
+# under `feed_forward.`, its norm `pre_ff_layernorm`, the model's last norm
+# `model.final_layernorm`.
+_MAMBA_MAP = {
+    "attn_norm": ("input_layernorm.weight", False),
+    "w_in": ("mamba.in_proj.weight", True),
+    "conv_w": ("mamba.conv1d.weight", True),
+    "conv_b": ("mamba.conv1d.bias", False),
+    "w_x": ("mamba.x_proj.weight", True),
+    "dt_norm": ("mamba.dt_layernorm.weight", False),
+    "b_norm": ("mamba.b_layernorm.weight", False),
+    "c_norm": ("mamba.c_layernorm.weight", False),
+    "w_dt": ("mamba.dt_proj.weight", True),
+    "dt_bias": ("mamba.dt_proj.bias", False),
+    "a_log": ("mamba.A_log", True),
+    "d_skip": ("mamba.D", False),
+    "w_out": ("mamba.out_proj.weight", True),
+}
+_STATE_SPACE_MAP = {
+    **{k: _LAYER_MAP[k] for k in ("attn_norm", "wq", "wk", "wv", "wo")},
+    "mlp_norm": ("pre_ff_layernorm.weight", False),
+    "w_gate": ("feed_forward.gate_proj.weight", True),
+    "w_up": ("feed_forward.up_proj.weight", True),
+    "w_down": ("feed_forward.down_proj.weight", True),
+}
+
+
+def final_norm_name(config) -> str:
+    """The stored name of the model's last norm."""
+    return ("model.final_layernorm.weight" if config.state_space
+            else "model.norm.weight")
+
 
 def latent_stack_plan(config) -> dict:
     """Stack name -> ``(model layer ids, {ours: (HF suffix, transpose)},
-    {ours: expert pattern})`` for a latent-family model: what both
+    {ours: expert pattern})`` for a model whose layers are of several
+    kinds (the latent family, a state-space hybrid): what both
     loaders and the writer walk. The ids are shaped as the stack leads
     (``[layers]``, or ``[repeats, layers]`` for a repeated period:
     ``models.llama.layer_plan``); ``models.llama.stack_shapes`` gives the
@@ -130,10 +167,16 @@ def latent_stack_plan(config) -> dict:
 
     names = {**_LATENT_MAP, **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP,
              **_HYBRID_EXTRA_MAP}
+    if config.state_space:
+        names = _STATE_SPACE_MAP
     plan = {}
     for run, seg in plan_segments(config):
         shapes = segment_shapes(config, seg)
-        table = {**names, **_KDA_MAP} if seg.mixer == "kda" else names
+        table = names
+        if seg.mixer == "kda":
+            table = {**names, **_KDA_MAP}
+        elif seg.mixer == "mamba":
+            table = {**names, **_MAMBA_MAP}
         plan[seg.name] = (
             run.layer_ids(seg),
             {k: table[k] for k in shapes if k in table and (
@@ -145,15 +188,16 @@ def latent_stack_plan(config) -> dict:
 def hf_layout(ours: str, w: np.ndarray, transpose: bool) -> np.ndarray:
     """One layer's tensor as the checkpoint stores it: torch ``[out, in]``
     for a linear, ``[C, 1, K]`` for a depthwise convolution's taps."""
-    if ours.startswith("conv_"):
+    if ours.startswith("conv_") and w.ndim == 2:
         return np.ascontiguousarray(w.T[:, None, :])
     return w.T if transpose else w
 
 
 def is_latent_checkpoint(name_to_file: dict) -> bool:
-    """Whether the checkpoint stores latent-attention tensors."""
+    """Whether the checkpoint stores latent-attention or state-space
+    tensors: a model of several layer stacks, which loads whole."""
     return any(".self_attn.kv_a_proj_with_mqa.weight" in n
-               for n in name_to_file)
+               or ".mamba.in_proj.weight" in n for n in name_to_file)
 
 
 def hf_layer_map(num_experts: int = 0, attention_bias: bool = False,
@@ -524,15 +568,17 @@ def load_llama_params(
 
 
 def latent_hf_tensors(params: dict, config) -> dict[str, np.ndarray]:
-    """A latent-family params pytree as Hugging Face tensors (torch ``[out,
-    in]``), the held experts under their global ids: what
-    :func:`save_llama_params` writes and the plain reference
-    (``cake_tpu/testing/reference_mla_moe.py``) reads."""
+    """A params pytree of several layer stacks (the latent family, a
+    state-space hybrid) as Hugging Face tensors (torch ``[out, in]``), the
+    held experts under their global ids: what :func:`save_llama_params`
+    writes and the plain references (``cake_tpu/testing/reference_*.py``)
+    read. A tied head is not stored."""
     tensors = {
         "model.embed_tokens.weight": np.asarray(params["embed"]),
-        "model.norm.weight": np.asarray(params["norm_f"]),
-        "lm_head.weight": np.asarray(params["lm_head"]).T,
+        final_norm_name(config): np.asarray(params["norm_f"]),
     }
+    if not config.tie_word_embeddings:
+        tensors["lm_head.weight"] = np.asarray(params["lm_head"]).T
     for stack, (ids, plain, experts) in latent_stack_plan(config).items():
         flat = ids.reshape(-1)
         for ours, (suffix, transpose) in plain.items():

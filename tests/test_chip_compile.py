@@ -91,6 +91,22 @@ def _kda_decode(layers, b, h=32, d=128, head_block=8):
                  ((layers, b, h, d, d), F32), ((), I32)])
 
 
+def _ssm(layers, b, t, n=16, c=5120):
+    """The state-space kernels as the layer loop calls them: the stacked
+    float32 state ``[L, B, d_state, d_inner]`` and a traced layer index;
+    ``t`` None: the decode step, else an admission chunk of ``t`` tokens."""
+    from cake_tpu.ops.pallas.mamba import ssm_decode, ssm_scan
+
+    def fn(x, delta, bm, cm, a, d, state, layer):
+        call = ssm_decode if t is None else ssm_scan
+        return call(x, delta, bm, cm, a, d, state, layer, interpret=False)
+
+    lead = (b,) if t is None else (b, t)
+    return (fn, [(lead + (c,), F32), (lead + (c,), F32), (lead + (n,), F32),
+                 (lead + (n,), F32), ((n, c), F32), ((c,), F32),
+                 ((layers, b, n, c), F32), ((), I32)])
+
+
 def _gmm(pairs, k, n, experts, layers, int8=False, out=BF16):
     """The expert block's grouped matmul as the layer loop calls it for
     prompt rows: ``pairs`` sorted rows (a 512-row bucket's ``rows x
@@ -152,6 +168,14 @@ KERNELS = {
     "kda_decode_b32_h32": _kda_decode(6, 32),
     "kda_decode_b48_h32": _kda_decode(6, 48),
     "kda_decode_b1_h32": _kda_decode(6, 1),
+    # Jamba2-3B's 5120 channels of a 16-wide state at the cell's 32 slots,
+    # at 64, one stream, and an admission chunk of a 512- and a 16-token
+    # bucket
+    "ssm_decode_b32": _ssm(26, 32, None),
+    "ssm_decode_b64": _ssm(26, 64, None),
+    "ssm_decode_b1": _ssm(26, 1, None),
+    "ssm_scan_t512": _ssm(26, 1, 512),
+    "ssm_scan_t16": _ssm(26, 1, 16),
     # the three expert cells' 512-row admission: Mixtral's 8 int8 experts
     # (1024 pairs), A.X-K1's 12 held of 192 and Ling-3.0-flash's 128 held
     # of 512 (4096 pairs each, whatever share of them falls here)
@@ -611,6 +635,72 @@ def test_hybrid_programs_move_no_cache_no_state_and_no_expert_stack(
     assert args + temps + 0.55 * GIB + 0.5 * GIB < HBM_GIB["v5 lite"] * GIB
     m = admit.memory_analysis()
     assert m.temp_size_in_bytes < 0.5 * GIB
+
+
+def test_state_space_programs_move_no_cache_and_no_state(topo, as_on_chip):
+    """The state-space + attention hybrid's two serving programs at
+    Jamba2-3B's published sizes, the cell ``jamba2-3b.decode-long`` itself:
+    all 28 layers (M7 A M6, twice: one period of three segments scanned
+    over two repetitions), 32 slots x 2048 rows. The chip's compiler takes
+    them; the cache's two kinds of state (rows for the two attention
+    layers, a float32 ``[16, 5120]`` state and a convolution tail for the
+    26 state-space layers) are carried through every segment and written
+    in place, so nothing of the state's or the rows' shapes is allocated
+    or copied; no repetition's weights are written out before use (the
+    period's stacks stay whole outside both loops: handed to the inner
+    loops as their ``xs``, a repetition's ``[7, 2560, 10240]`` and its
+    like were 1.43 GiB of temporaries a program); the decode step is
+    ``ssm_decode`` and the attention ``flash_decode`` (one key/value head
+    under twenty), the admission ``ssm_scan``, each inside the layer loops
+    on the carried buffers. Sizes: 5.96 GiB of weights (the tied matrix
+    held twice) + 0.34 GiB of cache in, ~0.1 GiB of temporaries (64 slots:
+    6.64 + 0.10 GiB, and a peak of 7.39 GiB on the chip; the slots are 32
+    for the spread of `tokens_per_s` between seeds, not for memory)."""
+    from cake_tpu.models.config import jamba2_3b
+    from cake_tpu.utils.chips import HBM_GIB
+
+    slots, window = 32, 2048
+    config = jamba2_3b(max_seq_len=window)
+    decode, admit = _family_programs(topo, config, slots, window, 512)
+    for compiled, batch in ((decode, slots), (admit, 1)):
+        for shape in (f"bf16[2,{batch},1,{window},128]",
+                      f"f32[26,{batch},16,5120]"):
+            assert _cache_sized_moves(compiled, shape) == [], shape
+        # a repetition's slice of a stack, as a value of its own
+        slabs = {f"bf16[{lead}{n},{a},{b}]" for lead in ("1,", "")
+                 for n in (7, 6) for a, b in (
+                     (2560, 10240), (5120, 2560), (2560, 8192), (8192, 2560))}
+        assert [name for _, name, shape, op, _ in _instructions(compiled)
+                if shape in slabs and op not in (
+                    "parameter", "get-tuple-element", "bitcast",
+                    "tuple")] == []
+
+    def calls(compiled, kernel):
+        return [re.search(r'op_name="([^"]*)"', line).group(1)
+                for line in compiled.as_text().splitlines()
+                if "custom-call(" in line and "tpu_custom_call" in line
+                and kernel in line]
+
+    # two state-space segments a period, each its own loop inside the
+    # period's, inside the block's steps: the kernel sits four loops deep
+    # in the step and three in the admission
+    assert [n.count("while/body") for n in calls(decode, "ssm_decode")] == [
+        4, 4]
+    assert [n.count("while/body") for n in calls(decode, "flash_decode")] == [
+        4]
+    assert [n.count("while/body") for n in calls(admit, "ssm_scan")] == [3, 3]
+    assert calls(decode, "ssm_scan") == calls(admit, "ssm_decode") == []
+    for line in decode.as_text().splitlines():
+        if "tpu_custom_call" in line and "ssm_decode" in line:
+            # the state it returns is the operand it was given, in place
+            assert "output_to_operand_aliasing={{1}: (7, {})}" in line
+    args, temps = _donated_bytes(decode)
+    assert 6.2 * GIB < args < 6.45 * GIB, args / GIB  # 5.96 + 0.34
+    assert temps < 0.3 * GIB, temps / GIB
+    # with the admission's staging row and a second cache while the
+    # splice is undonated
+    assert args + temps + 0.02 * GIB + 0.35 * GIB < HBM_GIB["v5 lite"] * GIB
+    assert admit.memory_analysis().temp_size_in_bytes < 0.3 * GIB
 
 
 # sha256[:16] of the lowered text of each family's serving programs at tiny

@@ -569,3 +569,21 @@ def test_startup_reports_its_four_numbers(prof_env):
     finally:
         prof._STARTUP.clear()
     assert "startup" not in prof.report()
+
+
+def test_a_slow_pass_says_its_parts_in_the_log(prof_env, caplog):
+    """The ``slow_passes`` ring dies with the process; the server's log
+    is what a benchmark run keeps. A pass over the limit leaves its
+    parts there, a pass under it leaves nothing."""
+    p = prof.profiler()
+    parts = {"admit_ms": 1.0, "step_ms": 2400.0, "deliver_ms": 2.0}
+    with caplog.at_level("WARNING", logger="cake_tpu.obs.prof"):
+        p.note_pass(prof.SLOW_PASS_MS - 1.0, parts, queued=0, running=1)
+        assert not caplog.records
+        p.note_pass(2410.0, parts, queued=3, running=32)
+    (rec,) = caplog.records
+    said = rec.getMessage()
+    assert said.startswith("slow scheduler pass: ")
+    for part in ("'total_ms': 2410.0", "'step_ms': 2400.0", "'rest_ms': 7.0",
+                 "'queued': 3", "'running': 32"):
+        assert part in said

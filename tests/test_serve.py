@@ -560,3 +560,33 @@ def test_loadgen_closed_and_open_loop(tok_server):
     stats = loadgen.run_load(_url(tok_server), 4, max_tokens=3, rate=50.0,
                              prompt_lens=[4], vocab=200, seed=4)
     assert stats["completed"] == 4 and stats["errors"] == 0
+
+
+def test_a_burst_of_connections_is_all_accepted(ids_server):
+    """Every client of a closed loop can connect in the same instant (a
+    load generator's start; streams that end at one block boundary). The
+    listener's backlog holds the burst: with the stdlib's 5, what
+    overflows is dropped and offered again a second later, or reset."""
+    assert ids_server.httpd.request_queue_size >= 128
+    n, took, errors = 64, [], []
+    gate = threading.Barrier(n)
+
+    def one():
+        gate.wait()
+        t0 = time.perf_counter()
+        try:
+            with urllib.request.urlopen(_url(ids_server) + "/healthz",
+                                        timeout=30) as r:
+                assert json.loads(r.read())["ok"]
+        except (OSError, AssertionError) as e:
+            errors.append(e)
+        took.append(time.perf_counter() - t0)
+
+    for _ in range(3):
+        threads = [threading.Thread(target=one) for _ in range(n)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+    assert not errors
+    assert len(took) == 3 * n
