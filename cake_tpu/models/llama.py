@@ -39,7 +39,12 @@ from cake_tpu.ops.kvcache import KVCache
 from cake_tpu.ops.mamba import mamba_mixer_block
 from cake_tpu.ops.mla import latent_attention_block
 from cake_tpu.ops.mlp import swiglu
-from cake_tpu.ops.moe import GroupRouting, moe_swiglu, reads_whole_stacks
+from cake_tpu.ops.moe import (
+    ExpertCount,
+    GroupRouting,
+    moe_swiglu,
+    reads_whole_stacks,
+)
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.rope import rope_tables_for
 
@@ -557,8 +562,8 @@ def block_forward(
 ):
     """One pre-norm decoder block (transformer.rs:48-64). Returns ``(x,
     k_cache, v_cache)``; with ``count_local`` (an expert layer of the
-    latent family) a fourth value, each row's routed pairs that fell on
-    the experts held here (:func:`cake_tpu.ops.moe.moe_swiglu`).
+    latent family) a fourth value, the :class:`ExpertCount` of the call
+    (:func:`cake_tpu.ops.moe.moe_swiglu`).
 
     ``layer_idx``: ``k_cache``/``v_cache`` are the stacked ``[L, B,
     kv_heads, S, D]`` cache and this block is layer ``layer_idx`` of it
@@ -644,9 +649,9 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
     """The feed-forward half of a latent-family layer, residual added. A
     dense layer (no ``router``) is a SwiGLU of ``intermediate_size``; an
     expert layer is ``shared(h) + sum over the chosen experts HELD here
-    of w_e expert_e(h)``. Returns ``(x, local_pairs [B])``."""
+    of w_e expert_e(h)``. Returns ``(x, ExpertCount)``."""
     h = rms_norm(x, layer["mlp_norm"], config.rms_norm_eps)
-    local = jnp.zeros((x.shape[0],), jnp.int32)
+    local = ExpertCount.zeros(x.shape[0])
     if "router" in layer:
         y = moe_swiglu(
             h, layer["router"], layer["w_gate"], layer["w_up"],
@@ -674,7 +679,7 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
 def _kda_block(layer, x, cache, config, valid, ep_axis, ep_size, layer_idx,
                count_local, expert_idx):
     """One delta-rule layer of the latent family over the carried cache's
-    recurrent buffers. Returns ``(x, cache, local_pairs)``."""
+    recurrent buffers. Returns ``(x, cache, ExpertCount)``."""
     h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
     with jax.named_scope("kda"):
         out, state, conv = kda_attention_block(
@@ -687,7 +692,7 @@ def _kda_block(layer, x, cache, config, valid, ep_axis, ep_size, layer_idx,
 
 def _mamba_block(layer, x, cache, config, valid, layer_idx):
     """One state-space layer over the carried cache's recurrent buffers,
-    its dense feed-forward included. Returns ``(x, cache, local_pairs)``."""
+    its dense feed-forward included. Returns ``(x, cache, ExpertCount)``."""
     h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
     with jax.named_scope("mamba"):
         out, state, conv = mamba_mixer_block(
@@ -721,8 +726,9 @@ def forward_layers(
 ):
     """Run a contiguous run of decoder blocks via ``lax.scan``. Returns
     ``(x, cache)``; with ``count_local`` (latent family) ``(x, cache,
-    local_pairs)``, each batch row's routed pairs that fell on held
-    experts, summed over the expert layers (int32 ``[B]``).
+    ExpertCount)``: each batch row's routed pairs that fell on held
+    experts (int32 ``[B]``) and the held experts some row chose, summed
+    over the expert layers.
 
     This is the TPU-native `Forwarder::forward_batch` (cake/mod.rs:143-150,
     worker.rs:208-219): one call executes any number of contiguous layers with
@@ -759,7 +765,9 @@ def forward_layers(
         what the scan slices, where this call's rows take the expert
         block's sorted form: its kernel reads a layer's matrices out of
         the whole stacks (a scan's slice would be written out for it)."""
-        if "router" in stack and reads_whole_stacks(rows, stack["w_gate"]):
+        if "router" in stack and reads_whole_stacks(
+                rows, config.num_experts_per_tok, stack["router"],
+                stack["w_gate"]):
             whole = {k: stack[k] for k in ("w_gate", "w_up", "w_down")}
             return {k: v for k, v in stack.items() if k not in whole}, whole
         return stack, {}
@@ -840,7 +848,7 @@ def forward_layers(
 
     carry = (x, cache)
     if count_local:
-        carry += (jnp.zeros((x.shape[0],), jnp.int32),)
+        carry += (ExpertCount.zeros(x.shape[0]),)
     if not config.segmented:  # one kind of layer, one bare stack
         stack, whole = split(layers)
         return scan_segment(carry, stack, 0, whole)

@@ -95,8 +95,18 @@ SERIES: dict[str, tuple[str, str]] = {
                  "took the sorted form (only the routed pairs on held "
                  "experts computed), by what the expert block recorded "
                  "when that bucket's program was traced"),
+    "moe.decode_sorted": (
+        GAUGE, "1 where the expert calls of the decode program (one row a "
+               "slot) took the sorted form when it was traced "
+               "(ops.moe.expert_form): a step reads the experts some row "
+               "chose and no others; 0: every held expert"),
     "moe.decode_steps": (
         COUNTER, "decode steps whose routed pairs were counted"),
+    "moe.experts_hit": (
+        COUNTER, "distinct held experts that some row of a decode step "
+                 "chose, summed over expert layers and steps: counted on "
+                 "the device over every row that goes through the "
+                 "program, a dead slot's too"),
     "moe.local_pairs": (
         COUNTER, "(row, chosen expert) pairs of decode steps that fell on "
                  "experts held here: counted on the device a batch row, "

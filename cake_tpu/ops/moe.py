@@ -10,9 +10,10 @@ TPU-first design:
 
 - **Static shapes only.** Routing never gathers a data-dependent *number* of
   tokens. Three fixed-shape forms of one algorithm, ONE a program, picked
-  at trace time from the call's rows and the stacks' type
-  (:func:`expert_form`; which is better depends on how many rows share a
-  weight read):
+  at trace time from the call's rows, ``top_k``, the router's width and
+  the stacks' type (:func:`expert_form`; which is better depends on how
+  much of the stacks a call touches and how many rows share a weight
+  read):
 
   * ``gather`` — a handful of pairs (tiny N, every expert here): gather
     the top-k experts' weight rows with ``jnp.take`` (static output shape
@@ -20,7 +21,8 @@ TPU-first design:
     bytes instead of E — the decode path is weights-bandwidth-bound, so
     the gather is the difference between top-k and all-E HBM traffic per
     token.
-  * ``dense`` — a decode batch: every (local) expert runs over every token
+  * ``dense`` — a batch whose pairs hit nearly every expert (8 rows x
+    top-2 of 8: 0.88 of them): every (local) expert runs over every token
     via batched einsums (``[E, N, F]`` activations) and the per-token
     combine weights zero out the non-selected experts. Each matrix is read
     once and at a few rows an expert the arithmetic hides under the read.
@@ -30,16 +32,19 @@ TPU-first design:
     stack the chip's compiler writes each layer's stack out for the
     dequantised batched product (6.7 ms a layer at Mixtral's widths, my
     chip run, PR 33).
-  * ``sorted`` — prompt rows (an admission's bucket, a verify chunk, a
-    prefill): the ``N x k`` (row, chosen expert) pairs sorted by local
-    expert id, pairs on experts that are not here at the tail and never
-    computed; rows gathered once; gate, up and down each one grouped
-    matmul over the contiguous groups
-    (:func:`cake_tpu.ops.pallas.grouped_matmul`: an int8 stack streams as
-    int8 and is converted a block at a time); every row's results summed
-    under its routing weights in float32. Exact with no capacity, no
-    fallback and no control flow. Its kernel reads a layer's matrices out
-    of the WHOLE stacks the layer loop closes over (``layer=``), so no
+  * ``sorted`` — a call that leaves many experts without a row (a decode
+    step's 32 rows x top-8 of 512 scored hit 0.39 of them, of 192 scored
+    0.74: :func:`hit_share`) and prompt rows (an admission's bucket, a
+    verify chunk, a prefill): the ``N x k`` (row, chosen expert) pairs
+    sorted by local expert id, pairs on experts that are not here at the
+    tail and never computed; rows gathered once; gate, up and down each
+    one grouped matmul over the contiguous groups
+    (:func:`cake_tpu.ops.pallas.grouped_matmul`: a group without a row is
+    never visited, so its matrices are never read; an int8 stack streams
+    as int8 and is converted a block at a time); every row's results
+    summed under its routing weights in float32. Exact with no capacity,
+    no fallback and no control flow. Its kernel reads a layer's matrices
+    out of the WHOLE stacks the layer loop closes over (``layer=``), so no
     layer's slice is written out for it (:func:`reads_whole_stacks`).
 
 - **Expert parallelism** shards the expert axis over the mesh's ``ep`` axis
@@ -69,17 +74,47 @@ from cake_tpu.ops.quant import QuantizedLinear, dequantize_linear
 GATHER_MAX_ROWS = 8
 # Rows of a call from which the sorted form is taken, by the stacks' type:
 # where tools/moe_sweep.py measured it at 1.10x the dense form or better at
-# every cell's shape (my chip runs, PR 33; PERF.md section 6). int8: 3.0x
-# from 128 rows on (the dense form's dequantised product writes a layer's
-# stack out first) and 0.71x at 64, where that form has no slab and runs at
-# 89% of the bytes' roofline. bf16: 1.32x and 1.80x at 512 rows (12 held of
-# 192, 128 of 512), 0.85x and 1.10x at 256.
+# every cell's shape (my chip runs, PR 33 and PR 35; PERF.md section 6).
+# int8: 3.0x from 128 rows on (the dense form's dequantised product writes a
+# layer's stack out first) and 0.72x at 64, where that form has no slab and
+# runs at 89% of the bytes' roofline. bf16: 1.32x and 1.80x at 512 rows (12
+# held of 192, 128 of 512), 0.88x and 1.10x at 256.
 SORTED_MIN_ROWS_INT8 = 128
 SORTED_MIN_ROWS = 512
+# ... and the share of the experts hit (:func:`hit_share`) up to which a
+# call of fewer rows takes it all the same: it reads the hit experts'
+# matrices alone, at 1.04-1.23x their bytes' time in bf16 and ~1.4x in int8
+# (a block's conversion), where the dense form reads every held one. The
+# same sweep, 8-256 rows (my chip runs, PR 35). bf16: 2.07x at a share of
+# 0.39 (32 rows x 8 of 512 scored), 1.47x at 0.63, 1.22x at 0.74 (32 x 8 of
+# 192), 1.06x at 0.86, 0.92x at 0.93. int8 (2-8 rows x 2 of 8): 1.51x at
+# 0.41, 1.29x at 0.66, 0.95x at 0.74, 0.82x at 0.88.
+SORTED_MAX_HIT_SHARE_INT8 = 0.7
+SORTED_MAX_HIT_SHARE = 0.8
 
 # rows of a call -> the form its trace took (what the engine's admission
 # counters ask: the form is a function of the shapes, so one entry a shape)
 _traced: dict[int, str] = {}
+
+
+class ExpertCount(NamedTuple):
+    """What ``count_local`` counts of a call (summed over expert layers
+    and steps by the callers): ``pairs [B]`` each batch row's (token,
+    chosen expert) pairs that fell on the experts held here; ``hit []``
+    the distinct held experts that some row chose (what the sorted form
+    reads of the stacks)."""
+
+    pairs: jax.Array
+    hit: jax.Array
+
+    @classmethod
+    def zeros(cls, batch: int) -> "ExpertCount":
+        return cls(jnp.zeros((batch,), jnp.int32), jnp.zeros((), jnp.int32))
+
+    def __add__(self, other: "ExpertCount") -> "ExpertCount":
+        # field by field (a tuple's own ``+`` would concatenate): what the
+        # layer loop and the step loop carry and add up
+        return ExpertCount(self.pairs + other.pairs, self.hit + other.hit)
 
 
 class GroupRouting(NamedTuple):
@@ -243,45 +278,60 @@ def _moe_sorted(
                                  tm=tm, out_dtype=out_dtype)
 
     xs = jnp.take(x2d, jnp.minimum(order // k, n - 1), axis=0)  # [M, H]
-    g = product(xs, w_gate)
-    u = product(xs, w_up)
-    y = product(jax.nn.silu(g) * u, w_down, jnp.float32)  # [M, H]
+    # gate and up leave their products in float32: ONE rounding, of the
+    # SwiGLU's result, before the down product
+    g = product(xs, w_gate, jnp.float32)
+    u = product(xs, w_up, jnp.float32)
+    y = product((jax.nn.silu(g) * u).astype(x2d.dtype), w_down,
+                jnp.float32)  # [M, H]
     # rows past the last held pair were never written: select, not scale
     y = jnp.where(held[..., None],
                   jnp.take(y, place, axis=0).reshape(n, k, -1), 0.0)
     return jnp.einsum("nk,nkh->nh", w_topk, y).astype(x2d.dtype)
 
 
-def expert_form(rows: int, top_k: int, quantized: bool, whole: bool) -> str:
+def hit_share(rows: int, top_k: int, scored: int) -> float:
+    """The share of the experts a call can be expected to hit: ``rows x
+    top_k`` pairs, each on one of the ``scored`` experts the router
+    chooses among (a held expert is hit as often as any other)."""
+    return 1.0 - (1.0 - 1.0 / scored) ** (rows * top_k)
+
+
+def expert_form(rows: int, top_k: int, quantized: bool, held: int,
+                scored: int) -> str:
     """``"gather"``, ``"dense"`` or ``"sorted"``: THE strategy of a call,
-    from what its trace can see: its rows, the stacks' type, and whether
-    every expert the router scores is here (``whole``). One algorithm,
-    whose better form depends on how many rows share a weight read: a
-    handful of pairs gather their experts' matrices, a decode batch runs
-    every held expert over every row (each matrix is read once and the
-    arithmetic hides under the read), and from a bucket of prompt rows on
-    the dense form's arithmetic (every held expert over every row) costs
-    more than the read, so the pairs are sorted and only they are
-    computed. The sorted form's product is a Pallas kernel."""
-    if whole and rows * top_k <= GATHER_MAX_ROWS:
+    from what its trace can see: its rows, ``top_k``, the stacks' type,
+    how many experts the stacks hold (``held``) and how many the router
+    scores (``scored``). One algorithm, whose better form depends on how
+    much of the stacks a call touches and on how many rows share a weight
+    read: a handful of pairs, every scored expert here, gather their
+    experts' matrices; a call whose pairs leave many of the experts
+    without a row (:func:`hit_share`) sorts them and reads the hit
+    experts alone; a batch that hits nearly all of them runs every held
+    expert over every row (each matrix is read once and the arithmetic
+    hides under the read); and from a bucket of prompt rows on that
+    arithmetic costs more than the read, so the pairs are sorted again and
+    only they are computed. The sorted form's product is a Pallas kernel."""
+    if held == scored and rows * top_k <= GATHER_MAX_ROWS:
         return "gather"
-    return "sorted" if _sorted_rows(rows, quantized) else "dense"
-
-
-def _sorted_rows(rows: int, quantized: bool) -> bool:
+    if not pk.kernels_enabled():
+        return "dense"
     least = SORTED_MIN_ROWS_INT8 if quantized else SORTED_MIN_ROWS
-    return pk.kernels_enabled() and rows >= least
+    most = SORTED_MAX_HIT_SHARE_INT8 if quantized else SORTED_MAX_HIT_SHARE
+    few = hit_share(rows, top_k, scored) <= most
+    return "sorted" if few or rows >= least else "dense"
 
 
-def reads_whole_stacks(rows: int, w_gate) -> bool:
+def reads_whole_stacks(rows: int, top_k: int, router, w_gate) -> bool:
     """Should the layer loop hand :func:`moe_swiglu` the whole expert
-    stacks and the layer's index, for a call of ``rows`` rows? Yes where
-    it takes the sorted form: a kernel's operand that is a scan's slice is
-    written out first (AOT for v5e: a slice of each of a layer's three
-    int8 stacks, the operation that costs 1.4 ms a stack where the dense
-    form pays it, my chip run, PR 33), the whole stack with an index is
-    read where it lies."""
-    return _sorted_rows(rows, isinstance(w_gate, QuantizedLinear))
+    stacks and the layer's index, for a call of ``rows`` rows under this
+    ``router [.., H, scored]``? Yes where it takes the sorted form: a
+    kernel's operand that is a scan's slice is written out first (AOT for
+    v5e: a slice of each of a layer's three int8 stacks, the operation
+    that costs 1.4 ms a stack where the dense form pays it, my chip run,
+    PR 33), the whole stack with an index is read where it lies."""
+    return expert_form(rows, top_k, isinstance(w_gate, QuantizedLinear),
+                       _stack(w_gate).shape[-3], router.shape[-1]) == "sorted"
 
 
 def form_traced(rows: int) -> str | None:
@@ -306,9 +356,10 @@ def moe_swiglu(
     layer: jax.Array | None = None,
 ):
     """Routed SwiGLU MLP. Returns ``[B, T, H]`` (residual NOT added); with
-    ``count_local`` a pair ``(out, local_pairs)``, each batch row's number
+    ``count_local`` a pair ``(out, ExpertCount)``: each batch row's number
     of (token, chosen expert) pairs that fell on the experts held here
-    (int32 ``[B]``, this rank's; the caller knows which rows are live).
+    (int32 ``[B]``, this rank's; the caller knows which rows are live),
+    and the number of held experts that some row chose.
 
     ``held = (first, count)``: the expert stacks are a share of what the
     router scores, told by the configuration: global experts ``first ..
@@ -355,7 +406,7 @@ def moe_swiglu(
     # chip's compiler writes the scanned expert stacks out before it, 24
     # ms an admission, my chip run, PR 28)
     form = expert_form(b * t, top_k, isinstance(w_gate, QuantizedLinear),
-                       not sharded and count == e_global)
+                       e_local, e_global)
     assert layer is None or form == "sorted", (form, b * t)
     _traced[b * t] = form
     if form == "sorted":
@@ -382,6 +433,9 @@ def moe_swiglu(
         out = jax.lax.psum(out, axes)
     out = out.reshape(b, t, h)
     if count_local:
-        hits = jnp.sum(combine > 0, axis=1, dtype=jnp.int32)
-        return out, hits.reshape(b, t).sum(axis=1)
+        chosen = combine > 0  # [N, E_local]
+        pairs = jnp.sum(chosen, axis=1, dtype=jnp.int32)
+        return out, ExpertCount(
+            pairs.reshape(b, t).sum(axis=1),
+            jnp.sum(chosen.any(axis=0), dtype=jnp.int32))
     return out

@@ -43,6 +43,7 @@ from cake_tpu.models.config import LlamaConfig
 from cake_tpu.models import llama
 from cake_tpu.ops import quant, sampling
 from cake_tpu.ops.kvcache import KVCache
+from cake_tpu.ops.moe import ExpertCount
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.rope import rope_tables_for
 from cake_tpu.ops.sampling import SamplerSettings
@@ -81,8 +82,8 @@ def _pipeline_layers(
 ):
     """Run the staged pipeline loop. Returns (x_on_stage0, cache); with
     ``count_local`` (an expert model of the latent family, which runs as
-    one stage) a third value, each row's routed pairs that fell on
-    experts held here (:func:`llama.forward_layers`). ``valid [B]``: the
+    one stage) a third value, the :class:`ExpertCount` of the experts
+    held here (:func:`llama.forward_layers`). ``valid [B]``: the
     true tokens of each row of a bucketed chunk (what alone may touch a
     recurrent state).
 
@@ -114,7 +115,7 @@ def _pipeline_layers(
         return (x, new_cache, *(a + b for a, b in zip(local, now)))
 
     carry = (x, cache) + (
-        (jnp.zeros((x.shape[0],), jnp.int32),) if count_local else ())
+        (ExpertCount.zeros(x.shape[0]),) if count_local else ())
     return jax.lax.fori_loop(0, num_stages, body, carry)
 
 
@@ -328,10 +329,11 @@ def build_sharded_decode(
         raise ValueError("paged decode requires dp == 1 and sp == 1 "
                          "(the page axis is unsharded)")
     # the serving programs of an expert model of the latent family return
-    # one more value, last: each batch row's routed (token, expert) pairs
-    # of the dispatch that fell on experts held here, summed on the device
-    # over its steps, its expert layers and the ep axis; the engine adds
-    # up the live rows' (obs: moe.local_pairs)
+    # one more value, last, an ExpertCount: each batch row's routed (token,
+    # expert) pairs of the dispatch that fell on experts held here, and
+    # the held experts that some row chose, summed on the device over its
+    # steps, its expert layers and the ep axis; the engine adds up the
+    # live rows' pairs (obs: moe.local_pairs, moe.experts_hit)
     count_local = per_row and moe_counted(config)
 
     def one_step(params, token, cache, pos, key, history, hist_slot,
@@ -425,7 +427,7 @@ def build_sharded_decode(
                 body, (token, cache, history, hist_slot),
                 jnp.arange(steps, dtype=jnp.int32),
             )
-            local = tuple(jnp.sum(a, axis=0) for a in local)
+            local = jax.tree.map(lambda a: jnp.sum(a, axis=0), tuple(local))
             if paged:
                 # only the pages this dispatch wrote go back to the pool
                 cache = kvpool.scatter_back(pool_in, cache, first_page,
@@ -461,7 +463,7 @@ def build_sharded_decode(
             kv_specs,
             P(DP, None),
             P(DP) if per_row else P(),
-        ) + lp_specs + ((P(DP),) if count_local else ()),
+        ) + lp_specs + ((ExpertCount(P(DP), P()),) if count_local else ()),
         check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(2,))

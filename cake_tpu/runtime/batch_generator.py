@@ -146,6 +146,8 @@ _IMPORTS = obs_metrics.counter("disagg.imports")
 _RESUMES = obs_metrics.counter("disagg.resumes")
 _IMPORT_ABORTS = obs_metrics.counter("disagg.import_aborts")
 _MOE_LOCAL = obs_metrics.counter("moe.local_pairs")
+_MOE_HIT = obs_metrics.counter("moe.experts_hit")
+_MOE_DECODE_SORTED = obs_metrics.gauge("moe.decode_sorted")
 _MOE_ROUTED = obs_metrics.counter("moe.routed_pairs")
 _MOE_STEPS = obs_metrics.counter("moe.decode_steps")
 _MOE_ADMIT_ROWS = obs_metrics.counter("moe.admit_rows")
@@ -3064,7 +3066,7 @@ class BatchGenerator:
         _KV_BLOCKS_RESERVED.inc(reserved)
 
     def _take_moe_count(self, out: tuple, steps: int) -> tuple:
-        """Strip the trailing per-row routed-pair counts off a decode
+        """Strip the trailing :class:`ExpertCount` off a decode
         program's outputs (present for an expert model told its share)
         and queue them, un-fetched, beside the dispatch they belong to and
         the rows that were live when it left (a dead slot's row still
@@ -3081,8 +3083,12 @@ class BatchGenerator:
         ``moe.*``."""
         if not self._moe_pending:
             return
-        local, steps, live = self._moe_pending.popleft()
-        _MOE_LOCAL.inc(int(self._host(local)[live].sum()))
+        count, steps, live = self._moe_pending.popleft()
+        _MOE_LOCAL.inc(int(self._host(count.pairs)[live].sum()))
+        # every row that went through the program, a dead slot's too: what
+        # the sorted form reads of the stacks
+        _MOE_HIT.inc(int(self._host(count.hit)))
+        _MOE_DECODE_SORTED.set(int(moe_form_traced(live.size) == "sorted"))
         _MOE_ROUTED.inc(
             steps * int(live.sum()) * self.config.num_experts_per_tok
             * sum(ffn == "moe" for _, ffn in self.config.layer_kinds))

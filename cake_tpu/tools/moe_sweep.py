@@ -1,23 +1,28 @@
-"""The expert block over prompt rows: dense against sorted, a layer.
+"""The expert block a layer: dense against sorted, from 8 rows to 2048.
 
 Times :func:`cake_tpu.ops.moe.moe_swiglu` in the two forms
 :func:`cake_tpu.ops.moe.expert_form` chooses between for more than a
-handful of rows, as the layer loop calls it (a scan over ``L`` layers:
+handful of pairs, as the layer loop calls it (a scan over ``L`` layers:
 the dense form on the scan's slice of the stacks, the sorted form on the
 whole stacks with the layer's index), at the three expert cells' shapes
-for 16 to 2048 rows. Where the sorted form is at least 1.10x the dense
-one is where ``SORTED_MIN_ROWS_INT8`` / ``SORTED_MIN_ROWS`` come from
-(PERF.md keeps the table). ``--forms ragged`` times the sorted form with
-``jax.lax.ragged_dot`` in the kernel's place (bf16 stacks only: it has no
-int8 operand, so a stack would be dequantised first).
+for 8 to 2048 rows: a decode step's rows (one a slot) and an admission's
+buckets. Where the sorted form is at least 1.10x the dense one is where
+the rule's constants come from: ``SORTED_MAX_HIT_SHARE*`` (the share of
+the experts a call of few rows may hit and still be sorted) and
+``SORTED_MIN_ROWS*`` (PERF.md keeps the table). ``--row-tile`` times the
+sorted form at other row tiles of the kernel than the program's.
+``--forms ragged`` times the sorted form with ``jax.lax.ragged_dot`` in
+the kernel's place (bf16 stacks only: it has no int8 operand, so a stack
+would be dequantised first).
 
 Usage:  python -m cake_tpu.tools.moe_sweep [--only NAME] [--rows 64,512]
-            [--forms dense,sorted,ragged] [--row-tile 128] [--json-out PATH]
+            [--forms dense,sorted,ragged] [--row-tile 32,128] [--json-out PATH]
 (``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
-Prints one JSON line per shape and row count: ``{"shape", "rows",
-"<form>_us_per_layer", "<form>_roofline", "speedup"}``: a form's share of
-max(the chosen held experts' bytes / 819 GB/s, the routed pairs'
+Prints one JSON line per shape, row count and row tile: ``{"shape",
+"rows", "row_tile", "<form>_us_per_layer", "<form>_roofline",
+"speedup"}``: a form's share of max(the chosen held experts' bytes / 819
+GB/s, the routed pairs'
 operations / 197 TFLOP/s) (what the block needs whichever form computes
 it; the router's weights give a near-uniform choice).
 """
@@ -44,7 +49,7 @@ SHAPES = {
     "axk1-ep16": (12, 192, 8, 7168, 2048, False, (8, 4)),
     "ling3flash-ep4": (128, 512, 8, 2560, 768, False, (8, 4)),
 }
-ROWS = (16, 32, 64, 128, 256, 512, 1024, 2048)
+ROWS = (8, 16, 32, 64, 128, 256, 512, 1024, 2048)
 LAYERS = 3
 HBM_BYTES_S, BF16_FLOPS_S = 819e9, 197e12  # one v5e chip, published
 
@@ -147,24 +152,31 @@ def floor_us(name: str, rows: int) -> float:
     return max(weight_bytes / HBM_BYTES_S, flops / BF16_FLOPS_S) * 1e6
 
 
-def sweep(names, row_counts, forms, row_tile):
+def sweep(names, row_counts, forms, row_tiles):
+    """A row per shape, row count and row tile (the dense form has no
+    tile: it is timed once a row count and stands in each tile's row)."""
     for name in names:
         held, scored, _, hidden, width, int8, _ = SHAPES[name]
         weights = _weights(jax.random.PRNGKey(0), LAYERS, held, scored,
                            hidden, width, int8)
         for rows in row_counts:
-            row = {"shape": name, "rows": rows, "row_tile": row_tile}
-            for form in forms:
-                if form == "ragged" and int8:
-                    continue
-                us = _time_us(form, name, rows, weights, row_tile)
-                row[f"{form}_us_per_layer"] = round(us, 1)
-                row[f"{form}_roofline"] = round(
-                    100 * floor_us(name, rows) / us, 1)
-            if "dense_us_per_layer" in row and "sorted_us_per_layer" in row:
-                row["speedup"] = round(row["dense_us_per_layer"]
-                                       / row["sorted_us_per_layer"], 3)
-            yield row
+            timed = {}
+            for tile in row_tiles:
+                row = {"shape": name, "rows": rows, "row_tile": tile}
+                for form in forms:
+                    if form == "ragged" and int8:
+                        continue
+                    if form != "dense" or form not in timed:
+                        timed[form] = _time_us(form, name, rows, weights,
+                                               tile)
+                    us = timed[form]
+                    row[f"{form}_us_per_layer"] = round(us, 1)
+                    row[f"{form}_roofline"] = round(
+                        100 * floor_us(name, rows) / us, 1)
+                if "dense" in timed and "sorted" in timed:
+                    row["speedup"] = round(
+                        timed["dense"] / timed["sorted"], 3)
+                yield row
         del weights
 
 
@@ -177,7 +189,8 @@ def main() -> int:
                     default=list(ROWS))
     ap.add_argument("--forms", type=lambda s: s.split(","),
                     default=["dense", "sorted"])
-    ap.add_argument("--row-tile", type=int, default=pk.MOE_ROW_TILE)
+    ap.add_argument("--row-tile", type=lambda s: [int(v) for v in s.split(",")],
+                    default=[pk.MOE_ROW_TILE])
     ap.add_argument("--json-out")
     a = ap.parse_args()
     configure()

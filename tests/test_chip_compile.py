@@ -107,11 +107,13 @@ def _ssm(layers, b, t, n=16, c=5120):
                  ((layers, b, n, c), F32), ((), I32)])
 
 
-def _gmm(pairs, k, n, experts, layers, int8=False, out=BF16):
-    """The expert block's grouped matmul as the layer loop calls it for
-    prompt rows: ``pairs`` sorted rows (a 512-row bucket's ``rows x
+def _gmm(pairs, k, n, experts, layers, int8=False):
+    """The expert block's grouped matmul as the layer loop calls it:
+    ``pairs`` sorted rows (a 512-row bucket's or a 32-row step's ``rows x
     top-k``), the whole ``[layers, experts, k, n]`` stack (int8 with a
-    scale per output channel, or bf16) and a traced layer index."""
+    scale per output channel, or bf16), a traced layer index, the product
+    left in float32 (gate and up until the SwiGLU's result is rounded,
+    down until the rows are summed)."""
     from cake_tpu.ops.pallas.moe import ROW_TILE, GroupTiles
 
     visits = pairs // ROW_TILE + experts - 1
@@ -119,7 +121,7 @@ def _gmm(pairs, k, n, experts, layers, int8=False, out=BF16):
     def fn(lhs, rhs, scale, offsets, group, tile, count, layer):
         return pk.grouped_matmul(
             lhs, rhs, GroupTiles(offsets, group, tile, count), layer=layer,
-            scale=scale, out_dtype=out, interpret=False)
+            scale=scale, out_dtype=F32, interpret=False)
 
     return (fn, [((pairs, k), BF16),
                  ((layers, experts, k, n), I8 if int8 else BF16),
@@ -180,11 +182,16 @@ KERNELS = {
     # (1024 pairs), A.X-K1's 12 held of 192 and Ling-3.0-flash's 128 held
     # of 512 (4096 pairs each, whatever share of them falls here)
     "gmm_mixtral_int8_gate": _gmm(1024, HID, FFN, 8, 7, int8=True),
-    "gmm_mixtral_int8_down": _gmm(1024, FFN, HID, 8, 7, int8=True, out=F32),
+    "gmm_mixtral_int8_down": _gmm(1024, FFN, HID, 8, 7, int8=True),
     "gmm_axk1_gate": _gmm(4096, 7168, 2048, 12, 7),
-    "gmm_axk1_down": _gmm(4096, 2048, 7168, 12, 7, out=F32),
+    "gmm_axk1_down": _gmm(4096, 2048, 7168, 12, 7),
     "gmm_ling_gate": _gmm(4096, 2560, 768, 128, 6),
-    "gmm_ling_down": _gmm(4096, 768, 2560, 128, 6, out=F32),
+    "gmm_ling_down": _gmm(4096, 768, 2560, 128, 6),
+    # the two 32-slot cells' decode step: 256 pairs, two row tiles
+    "gmm_axk1_gate_step": _gmm(256, 7168, 2048, 12, 7),
+    "gmm_axk1_down_step": _gmm(256, 2048, 7168, 12, 7),
+    "gmm_ling_gate_step": _gmm(256, 2560, 768, 128, 6),
+    "gmm_ling_down_step": _gmm(256, 768, 2560, 128, 6),
     "qmm_m64_4096x14336": _qmm(64, HID, FFN),
     "qmm_m64_14336x4096": _qmm(64, FFN, HID),
     "qmm_m64_4096x32000": _qmm(64, HID, VOCAB),
@@ -550,9 +557,12 @@ def test_latent_programs_move_no_cache_and_no_expert_stack(topo, as_on_chip):
     flow in the layer body costs (a ``lax.cond`` between two expert
     strategies wrote the three stacks out before it: 24 ms of every
     admission on the chip, PR 28), so a program runs one strategy, chosen
-    from its shapes when it is traced: the step every held expert over
-    every row, the 512-row admission the sorted form, whose kernel reads
-    the whole stacks the layer loop closes over (PR 33)."""
+    from its shapes when it is traced: the 512-row admission the sorted
+    form, whose kernel reads the whole stacks the layer loop closes over
+    (PR 33), and so the 32-row step, whose 256 pairs hit 0.74 of the 192
+    scored experts (PR 35): the stacks stay whole outside BOTH of the
+    block's loops (steps, then layers) and the kernel reads the hit
+    experts' matrices where they lie."""
     layers, slots, window = 3, 32, 4096
     config, decode, admit = _latent_programs(topo, layers, slots, window, 512)
     assert config.cache_row == (1, 512, 64)
@@ -561,10 +571,10 @@ def test_latent_programs_move_no_cache_and_no_expert_stack(topo, as_on_chip):
             assert _cache_sized_moves(
                 compiled, f"bf16[{layers},{batch},1,{window},{width}]") == []
         assert _expert_stack_moves(compiled, "bf16", 12, 7168, 2048) == []
-    # the 512-row admission takes the sorted form: gate, up and down are
-    # the grouped matmul on the whole stacks; the 32-row step has none
+    # both take the sorted form: gate, up and down are the grouped matmul
+    # on the whole stacks, three calls a scan body
     assert _grouped_matmul_calls(admit) == 3
-    assert _grouped_matmul_calls(decode) == 0
+    assert _grouped_matmul_calls(decode) == 3
     args, temps = _donated_bytes(decode)
     # 2 x 1.35 GB of expert layers + 1.0 of the dense one + 0.59 of
     # embedding and head = 4.29 GB = 4.0 GiB, + 0.42 GiB of latent cache
@@ -594,8 +604,9 @@ def test_hybrid_programs_move_no_cache_no_state_and_no_expert_stack(
     state and a convolution tail for the six delta-rule layers) are
     carried through every segment and written in place, so nothing of any
     of the four buffers' shapes is allocated or copied; no expert stack
-    ``[.., 128, 2560, 768]`` is written out of the scanned weights; the
-    decode step is the kernel, inside the layer loop, on the carried
+    ``[.., 128, 2560, 768]`` is written out of the scanned weights (both
+    programs' expert calls are the grouped matmul on the whole stacks);
+    the decode step is the kernel, inside the layer loop, on the carried
     state. Sizes: 9.75 GiB of weights + 0.53 GiB of cache in, under 0.3
     GiB of temporaries: the cell fits the chip with the admission's
     staging row and a second cache while the splice is undonated (and
@@ -615,9 +626,10 @@ def test_hybrid_programs_move_no_cache_no_state_and_no_expert_stack(
                       f"bf16[6,{batch},3,12288]"):
             assert _cache_sized_moves(compiled, shape) == [], shape
         assert _expert_stack_moves(compiled, "bf16", 128, 2560, 768) == []
-    # three stacks of expert layers (K K K K | M | K), three products each
+    # three stacks of expert layers (K K K K | M | K), three products each,
+    # in the admission and in the 32-row step (0.39 of 512 scored hit)
     assert _grouped_matmul_calls(admit) == 9
-    assert _grouped_matmul_calls(decode) == 0
+    assert _grouped_matmul_calls(decode) == 9
     # the kernel's result is a pair, which ``_instructions`` does not
     # parse: read its calls off the text's lines
     calls = [line for line in decode.as_text().splitlines()
@@ -710,11 +722,15 @@ def test_state_space_programs_move_no_cache_and_no_state(topo, as_on_chip):
 PR31_TEXTS = {
     "dense.decode": "cf6f26fdc793389e", "dense.admit": "13b469425420d448",
     "sparse.decode": "9d4dfa59dafe21c7", "sparse.admit": "d9e1c8f0418fc104",
-    "latent.decode": "7744d2acc63cc1c6", "latent.admit": "5c206bbdc09ba295",
-    # the hybrid's, taken on PR 32's tree (commit 9a9bb52): its delta-rule
-    # expert segments share ONE scan body, which a body built anew for
-    # each segment would lower once a segment (PR 33 met it)
-    "hybrid.decode": "f0140de63d5575f1", "hybrid.admit": "3c267fa227f8873b",
+    # the two families that count their held experts' load: the decode
+    # programs re-pinned by PR 35, which return one more count (the held
+    # experts some row chose: ``moe.experts_hit``); the dense and sparse
+    # families' programs, and every admission, are the text they were
+    "latent.decode": "3c2465d5b9b2c22d", "latent.admit": "5c206bbdc09ba295",
+    # the hybrid's admission, taken on PR 32's tree (commit 9a9bb52): its
+    # delta-rule expert segments share ONE scan body, which a body built
+    # anew for each segment would lower once a segment (PR 33 met it)
+    "hybrid.decode": "2717b08c49fc2b24", "hybrid.admit": "3c267fa227f8873b",
 }
 
 
@@ -723,8 +739,9 @@ def test_existing_families_lower_to_the_text_they_had():
     admission programs lower (StableHLO, CPU, tiny widths) to the text
     PR 31's tree (PR 32's for the hybrid) gave them, so the chip's
     compiler sees what it saw and the cells it measured stay where they
-    are: the 4-row step and the 16-row admission lie under every
-    threshold of the expert block's sorted form (PR 33). A PR that
+    are: without kernels (the CPU's default) every call of the expert
+    block takes the form it took before there was a sorted one (PR 33,
+    PR 35). A PR that
     changes one of these programs on purpose replaces its hash here, and
     says so."""
     import hashlib

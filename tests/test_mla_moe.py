@@ -166,17 +166,11 @@ def test_moe_counters_and_cache_gauges(params, share):
 
     reg = metrics.registry()
     before = {n: reg.counter(n).value for n in
-              ("moe.local_pairs", "moe.routed_pairs", "moe.decode_steps")}
-    cfg = dataclasses.replace(CFG, eos_token_id=-1)
-    p = params
+              ("moe.local_pairs", "moe.routed_pairs", "moe.decode_steps",
+               "moe.experts_hit")}
+    cfg, p = dataclasses.replace(CFG, eos_token_id=-1), params
     if share == "cut":
-        cfg = dataclasses.replace(cfg, n_routed_experts=8, router_experts=16,
-                                  first_expert=4)
-        p = jax.tree.map(lambda a: a, params)
-        p["layers"] = dict(p["layers"])
-        p["layers"]["moe"] = {
-            k: (v[:, 4:12] if k in ("w_gate", "w_up", "w_down") else v)
-            for k, v in p["layers"]["moe"].items()}
+        cfg, p = _cut_share(params)
     bg = BatchGenerator(cfg, p, settings=SamplerSettings(**GREEDY),
                         block_size=4, max_seq=64)
     bg.set_prompts([[5, 9, 2], [3, 1, 4, 1]])
@@ -198,6 +192,10 @@ def test_moe_counters_and_cache_gauges(params, share):
     assert steps >= 8
     assert got["moe.routed_pairs"] == steps * 2 * 4 * 2  # rows x k x layers
     held_part(got)
+    # two live rows' choices: at least the one's, at most the pairs'
+    assert (got["moe.local_pairs"] / 2 <= got["moe.experts_hit"]
+            <= got["moe.local_pairs"])
+    assert reg.gauge("moe.decode_sorted").value == 0  # no kernels here
     if share == "cut":  # 32 routings over 2 of 4 groups: some hit, some miss
         assert 0 < got["moe.local_pairs"] < got["moe.routed_pairs"]
     # a retired slot's row still goes through the program, and is no load:
@@ -211,8 +209,56 @@ def test_moe_counters_and_cache_gauges(params, share):
     assert got["moe.decode_steps"] >= 8
     assert got["moe.routed_pairs"] == got["moe.decode_steps"] * 1 * 4 * 2
     held_part(got)
+    # the dead slot's row goes through the program too, and the experts
+    # it chose are read: not fewer than the live row's distinct choices
+    assert got["moe.local_pairs"] <= got["moe.experts_hit"] <= (
+        got["moe.decode_steps"] * 2 * 4 * 2)
     assert reg.gauge("cache.row_bytes").value == 4 * (16 + 8)
     assert reg.gauge("cache.bytes").value == 3 * 2 * 64 * 4 * (16 + 8)
+
+
+def _cut_share(params):
+    """Experts 4-11 of 16 scored (two groups of four) held."""
+    cfg = dataclasses.replace(CFG, eos_token_id=-1, n_routed_experts=8,
+                              router_experts=16, first_expert=4)
+    p = dict(params, layers=dict(params["layers"]))
+    p["layers"]["moe"] = {
+        k: (v[:, 4:12] if k in ("w_gate", "w_up", "w_down") else v)
+        for k, v in p["layers"]["moe"].items()}
+    return cfg, p
+
+
+@pytest.mark.parametrize("kernels", ["1", "0"], ids=["sorted", "dense"])
+def test_engine_counts_the_held_experts_a_step_hits(params, kernels,
+                                                    monkeypatch):
+    """``moe.experts_hit`` against a host count: the one row of a
+    one-slot engine chooses ``top_k`` distinct experts, so the held
+    experts its steps hit are its pairs on held experts, layer by layer
+    and step by step: the two counters grow alike, through the same
+    fetch. The gauge ``moe.decode_sorted`` says which form the decode
+    program's expert calls took when it was traced: with kernels the one
+    row's 4 pairs over 16 scored experts (0.23 of them hit) are sorted,
+    without kernels every held expert runs."""
+    from cake_tpu.obs import metrics
+    from cake_tpu.ops import moe
+    from cake_tpu.runtime.batch_generator import BatchGenerator
+
+    monkeypatch.setenv("CAKE_PALLAS", kernels)
+    reg = metrics.registry()
+    names = ("moe.local_pairs", "moe.experts_hit", "moe.decode_steps")
+    before = {n: reg.counter(n).value for n in names}
+    cfg, p = _cut_share(params)
+    bg = BatchGenerator(cfg, p, settings=SamplerSettings(**GREEDY),
+                        block_size=4, max_seq=64)
+    bg.set_prompts([[5, 9, 2]])
+    bg.generate(9)
+    bg.drain()
+    got = {n: reg.counter(n).value - before[n] for n in names}
+    assert got["moe.decode_steps"] >= 8
+    assert 0 < got["moe.experts_hit"] == got["moe.local_pairs"]
+    assert got["moe.experts_hit"] < 8 * 2 * got["moe.decode_steps"]
+    assert moe.form_traced(1) == ("sorted" if kernels == "1" else "dense")
+    assert reg.gauge("moe.decode_sorted").value == int(kernels)
 
 
 def test_checkpoint_writer_reader_roundtrip(tmp_path, params, want):

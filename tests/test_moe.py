@@ -213,32 +213,52 @@ def _over_ep(fn, ep, stacks):
                      check_vma=False)(stacks)
 
 
+STEP_ROWS = 32  # the 32-slot cells' decode step: one row a slot
+
+
+def _sorted_wanted(name, rows):
+    """The rule, spelt out for the cases: from the threshold of the
+    stacks' type on, and at a step's few rows where the pairs leave many
+    of the router's experts without a row (a share of 512 or 192 scored
+    experts; Mixtral's 64 pairs hit all 8)."""
+    held, scored, _, _, _, kind = SORTED_CASES[name]
+    least = SORTED_MIN_ROWS_INT8 if kind == "int8" else SORTED_MIN_ROWS
+    return rows >= least or (rows == STEP_ROWS and held < scored)
+
+
 @pytest.mark.parametrize("ep", [1, 2])
-@pytest.mark.parametrize("rows", ["under", "threshold", 512])
+@pytest.mark.parametrize("rows", ["under", "threshold", 512, "step"])
 @pytest.mark.parametrize("name", list(SORTED_CASES))
 def test_sorted_form_is_the_dense_form_and_the_reference(
         name, rows, ep, kernels):
-    """One rule on the call's rows serves every caller: under the
-    threshold of its stacks' type a call runs every held expert over
-    every row, from it on only the routed pairs on held experts, sorted
-    by expert (pairs on experts that are not here, or on the other rank's
-    under ``ep``, sort to the tail and are never computed). Both are the
-    float64 loop over the pairs, and ``count_local`` counts the same."""
-    least = (SORTED_MIN_ROWS_INT8 if SORTED_CASES[name][5] == "int8"
-             else SORTED_MIN_ROWS)
-    rows = {"under": least - 1, "threshold": least}.get(rows, rows)
+    """One rule on what a call's trace sees serves every caller: a call
+    that leaves many of the router's experts without a row (a decode
+    step's 32 rows x 8 over 192 or 512 scored) and a call from the
+    threshold of its stacks' type on compute only the routed pairs on
+    held experts, sorted by expert (pairs on experts that are not here,
+    or on the other rank's under ``ep``, sort to the tail and are never
+    computed); between the two a call runs every held expert over every
+    row. Both are the float64 loop over the pairs, and ``count_local``
+    counts the same: each row's pairs on held experts, and the held
+    experts some row chose (a host count from the router's choice)."""
+    held, scored, top_k, _, first, kind = SORTED_CASES[name]
+    least = SORTED_MIN_ROWS_INT8 if kind == "int8" else SORTED_MIN_ROWS
+    rows = {"under": least - 1, "threshold": least,
+            "step": STEP_ROWS}.get(rows, rows)
     x, rw, stacks, kw, plain = _sorted_case(name, rows)
     tol = 3e-2 if x.dtype == jnp.bfloat16 else 3e-5
 
     def run(stacks):
-        return moe_swiglu(x, rw, *stacks, count_local=True,
-                          ep_axis="ep" if ep > 1 else None, **kw)
+        out, count = moe_swiglu(x, rw, *stacks, count_local=True,
+                                ep_axis="ep" if ep > 1 else None, **kw)
+        return out, jax.lax.psum(count, "ep") if ep > 1 else count
 
     def both():
         return run(stacks) if ep == 1 else _over_ep(run, ep, stacks)
 
     out, counted = both()
-    assert moe.form_traced(rows) == ("dense" if rows < least else "sorted")
+    assert moe.form_traced(rows) == (
+        "sorted" if _sorted_wanted(name, rows) else "dense")
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("CAKE_PALLAS", "0")
         dense, dense_counted = both()
@@ -248,10 +268,37 @@ def test_sorted_form_is_the_dense_form_and_the_reference(
     for got in (out, dense):
         np.testing.assert_allclose(np.asarray(got[0], np.float64), want,
                                    atol=tol * scale, rtol=0)
-    np.testing.assert_array_equal(np.asarray(counted),
-                                  np.asarray(dense_counted))
+    for a, b in zip(counted, dense_counted):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _, _, idx = router_topk(x[0], rw, top_k, kw["routing"])
+    local = np.asarray(idx) - first
+    local = local[(local >= 0) & (local < held)]
+    assert int(counted.pairs[0]) == local.size
+    assert int(counted.hit) == np.unique(local).size
     if kw["held"] is not None:  # a share: some pairs fell elsewhere
-        assert 0 < int(counted[0]) < rows * kw["top_k"]
+        assert 0 < local.size < rows * top_k
+    if rows == STEP_ROWS and kw["held"] is not None:
+        assert int(counted.hit) < held  # what the sorted form leaves unread
+
+
+def test_sorted_step_where_no_row_has_a_held_choice_adds_exactly_zero(
+        kernels):
+    """A decode step none of whose 32 rows chose an expert held here: no
+    group has a row, the kernel visits nothing, and the result is exactly
+    zero (selected, not scaled: the rows were never written), with no
+    pair and no expert counted."""
+    x, rw, stacks, kw, _ = _sorted_case("12-of-192-grouped", STEP_ROWS)
+    first, count = kw["held"]
+    # the held experts' scores are the lowest of their group: never chosen
+    x = jnp.abs(x)
+    rw = rw.at[:, first:first + count].set(-4.0)
+    _, _, idx = router_topk(x[0], rw, kw["top_k"], kw["routing"])
+    idx = np.asarray(idx)
+    assert not ((idx >= first) & (idx < first + count)).any()
+    out, counted = moe_swiglu(x, rw, *stacks, count_local=True, **kw)
+    assert moe.form_traced(STEP_ROWS) == "sorted"
+    assert (np.asarray(out) == 0).all()
+    assert int(counted.hit) == 0 and not np.asarray(counted.pairs).any()
 
 
 def test_sorted_row_with_no_held_choice_adds_exactly_zero(kernels):
@@ -265,10 +312,8 @@ def test_sorted_row_with_no_held_choice_adds_exactly_zero(kernels):
     idx = np.asarray(idx)
     away = ~((idx >= first) & (idx < first + count)).any(axis=1)
     assert 0 < away.sum() < 37
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(moe, "SORTED_MIN_ROWS", 32)
-        out = np.asarray(moe_swiglu(x, rw, *stacks, **kw)[0])
-        assert moe.form_traced(37) == "sorted"
+    out = np.asarray(moe_swiglu(x, rw, *stacks, **kw)[0])
+    assert moe.form_traced(37) == "sorted"  # 296 pairs hit 0.79 of 192
     assert (out[away] == 0).all() and np.isfinite(out).all()
     want = _pairs_oracle(x, rw, plain, kw)
     np.testing.assert_allclose(out, want, atol=3e-5 * np.abs(want).max())
@@ -289,25 +334,40 @@ def test_sorted_every_row_on_one_expert(kernels):
                                atol=3e-2 * np.abs(want).max())
 
 
-@pytest.mark.parametrize("rows,top_k,int8,whole,form", [
-    (1, 2, False, True, "gather"), (4, 2, True, True, "gather"),
-    (8, 2, True, True, "dense"),  # the sparse cell's decode step
-    (32, 8, False, False, "dense"),  # the 32-slot cells' decode step
-    (1, 8, False, False, "dense"),  # a told share never gathers
-    (SORTED_MIN_ROWS_INT8 - 1, 2, True, True, "dense"),
-    (SORTED_MIN_ROWS_INT8, 2, True, True, "sorted"),
-    (SORTED_MIN_ROWS - 1, 8, False, False, "dense"),
-    (SORTED_MIN_ROWS, 8, False, False, "sorted"),
-    (2048, 2, False, True, "sorted"),
+@pytest.mark.parametrize("rows,top_k,int8,held,scored,form", [
+    (1, 2, False, 8, 8, "gather"), (4, 2, True, 8, 8, "gather"),
+    (8, 2, True, 8, 8, "dense"),  # the sparse cell's decode step: 0.88 hit
+    (5, 2, True, 8, 8, "dense"),  # int8: 0.74 is over its 0.7 (0.95x)
+    (8, 2, False, 8, 8, "dense"), (7, 2, False, 8, 8, "dense"),
+    (6, 2, False, 8, 8, "sorted"),  # bf16: 0.7986
+    # the 32-slot cells' decode step: 0.39 of 512 scored, 0.74 of 192
+    (32, 8, False, 128, 512, "sorted"), (32, 8, False, 12, 192, "sorted"),
+    # the rule's two sides at each router's width (the share hit is under
+    # SORTED_MAX_HIT_SHARE up to 102 rows of 512 scored, 38 of 192)
+    (64, 8, False, 128, 512, "sorted"), (102, 8, False, 128, 512, "sorted"),
+    (103, 8, False, 128, 512, "dense"), (128, 8, False, 128, 512, "dense"),
+    (38, 8, False, 12, 192, "sorted"), (39, 8, False, 12, 192, "dense"),
+    (64, 8, False, 12, 192, "dense"), (256, 8, False, 12, 192, "dense"),
+    (1, 8, False, 12, 192, "sorted"),  # a told share never gathers
+    (1, 2, False, 4, 8, "sorted"),  # nor a rank's slice under ep
+    (2, 2, True, 4, 8, "sorted"), (4, 2, True, 4, 8, "sorted"),  # 0.66
+    (SORTED_MIN_ROWS_INT8 - 1, 2, True, 8, 8, "dense"),
+    (SORTED_MIN_ROWS_INT8, 2, True, 8, 8, "sorted"),
+    (SORTED_MIN_ROWS - 1, 8, False, 128, 512, "dense"),
+    (SORTED_MIN_ROWS, 8, False, 128, 512, "sorted"),
+    (2048, 2, False, 8, 8, "sorted"),
 ])
-def test_decode_shaped_calls_keep_their_form(rows, top_k, int8, whole, form,
-                                             kernels, monkeypatch):
-    """One strategy a program, from the call's rows and the stacks' type:
-    decode calls take what they took before there was a sorted form, and
-    without kernels (the CPU's default) so does every call."""
-    assert expert_form(rows, top_k, int8, whole) == form
+def test_decode_shaped_calls_keep_their_form(rows, top_k, int8, held, scored,
+                                             form, kernels, monkeypatch):
+    """One strategy a program, from the call's rows, ``top_k``, the
+    stacks' type, the experts held and the router's width: a call whose
+    pairs leave many of the scored experts without a row is sorted, one
+    that hits nearly all of them runs every held expert, and without
+    kernels (the CPU's default) every call takes what it took before
+    there was a sorted form."""
+    assert expert_form(rows, top_k, int8, held, scored) == form
     monkeypatch.setenv("CAKE_PALLAS", "0")
-    assert expert_form(rows, top_k, int8, whole) == (
+    assert expert_form(rows, top_k, int8, held, scored) == (
         "dense" if form == "sorted" else form)
 
 
@@ -327,34 +387,46 @@ def _family(name):
     return cfg, params
 
 
+@pytest.mark.parametrize("shape", ["prefill", "step"])
 @pytest.mark.parametrize("name", ["mixtral", "mixtral-int8", "latent",
                                   "hybrid"])
-def test_layer_loop_hands_the_sorted_form_whole_stacks(name, monkeypatch):
-    """A prefill of the threshold's rows through the layer loop of each
-    family (128 int8, 512 else): where the
-    expert block takes the sorted form the scan slices everything of a
-    layer but its expert matrices, which stay whole beside a layer index
-    (a repeated period's index runs over its repetitions too). Logits as
-    the dense form's."""
+def test_layer_loop_hands_the_sorted_form_whole_stacks(name, shape,
+                                                       monkeypatch):
+    """Through the layer loop of each family, a prefill of the
+    threshold's rows (128 int8, 512 else) and a step of 3 rows (one token
+    each: Mixtral's 6 pairs gather, the 12 pairs over 16 scored experts
+    hit 0.54 of them and are sorted): where the expert block takes the
+    sorted form the scan slices everything of a layer but its expert
+    matrices, which stay whole beside a layer index (a repeated period's
+    index runs over its repetitions too), and where it does not the scan
+    slices them too: the loop and the block ask ONE rule. Logits as
+    without kernels."""
     from cake_tpu.ops.kvcache import init_cache
 
     cfg, params = _family(name)
-    rows = SORTED_MIN_ROWS_INT8 if name == "mixtral-int8" else SORTED_MIN_ROWS
-    tokens = jax.random.randint(jax.random.PRNGKey(2), (1, rows), 0,
-                                cfg.vocab_size)
+    if shape == "prefill":
+        rows = (SORTED_MIN_ROWS_INT8 if name == "mixtral-int8"
+                else SORTED_MIN_ROWS)
+        batch, forms = 1, ("dense", "sorted")
+    else:
+        rows = batch = 3
+        forms = (("gather", "gather") if name.startswith("mixtral")
+                 else ("dense", "sorted"))
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (batch, rows // batch),
+                                0, cfg.vocab_size)
 
     def logits(force):
         monkeypatch.setenv("CAKE_PALLAS", force)
         moe._traced.clear()
         out, _ = jax.jit(lambda p, t: llama.forward(
-            p, t, init_cache(cfg, batch=1, max_seq=512), 0, cfg))(
+            p, t, init_cache(cfg, batch=batch, max_seq=512), 0, cfg))(
             params, tokens)
         return np.asarray(out), moe.form_traced(rows)
 
     want, form = logits("0")
-    assert form == "dense"
+    assert form == forms[0]
     got, form = logits("1")
-    assert form == "sorted"
+    assert form == forms[1]
     np.testing.assert_allclose(got, want, atol=2e-3 * np.abs(want).max())
 
 
@@ -396,7 +468,7 @@ def test_moe_sweep_rows_at_tiny_shapes(monkeypatch, kernels):
         "tiny": (4, 4, 2, 32, 128, False, None),
         "tiny-int8-share": (4, 16, 2, 32, 128, True, (4, 2))})
     out = list(moe_sweep.sweep(["tiny", "tiny-int8-share"], [16, 128],
-                               ["dense", "sorted", "ragged"], 128))
+                               ["dense", "sorted", "ragged"], [128]))
     assert [(r["shape"], r["rows"]) for r in out] == [
         ("tiny", 16), ("tiny", 128), ("tiny-int8-share", 16),
         ("tiny-int8-share", 128)]
@@ -404,6 +476,71 @@ def test_moe_sweep_rows_at_tiny_shapes(monkeypatch, kernels):
         assert r["dense_us_per_layer"] > 0 and r["sorted_us_per_layer"] > 0
         assert ("ragged_us_per_layer" in r) == (r["shape"] == "tiny")
     assert moe.expert_form is expert_form
+
+
+@pytest.mark.parametrize("form,hit,want", [
+    (0, 5000, 100.0),  # the dense form reads every held expert
+    (1, 6144, 40.0),  # 6144 of 128 held x 6 layers x 20 steps
+    (1, None, None),  # the parent's program: no such counter
+    (None, 6144, None),  # nor the gauge
+], ids=["dense", "sorted", "no-counter", "no-gauge"])
+def test_reader_of_the_experts_a_decode_step_reads(form, hit, want):
+    """``benchmark/layer_metrics/moe.decode_experts_read_share.py``, loaded
+    by path as the benchmark loads it: 100 where the gauge
+    ``moe.decode_sorted`` is 0; else the growth of ``moe.experts_hit``
+    over held experts x expert layers x the growth of
+    ``moe.decode_steps``; nothing (the line leaves the metric out, no
+    error) from a program without the counter or the gauge."""
+    import importlib.util
+    import sys
+    import types
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parent.parent / "benchmark"
+
+    def counter(value):
+        return {"type": "counter", "value": value}
+
+    before = {"moe.decode_steps": counter(100)}
+    after = {"moe.decode_steps": counter(120)}
+    if hit is not None:
+        before["moe.experts_hit"] = counter(1000)
+        after["moe.experts_hit"] = counter(1000 + hit)
+    if form is not None:
+        after["moe.decode_sorted"] = {"type": "gauge", "value": form}
+    arch = types.SimpleNamespace(held_experts=lambda cfg: range(128, 256),
+                                 expert_layers=lambda cfg: 6)
+    ctx = {"before": {"status": {"metrics": before}},
+           "after": {"status": {"metrics": after}}, "arch": arch, "cfg": {}}
+    path = list(sys.path)  # the readers import their helpers by bare name
+    sys.path.insert(0, str(bench))
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "reader_for_tests",
+            bench / "layer_metrics" / "moe.decode_experts_read_share.py")
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+        got = reader.read(ctx)
+    finally:
+        sys.path[:] = path
+        sys.modules.pop("counters", None)
+    assert got == (pytest.approx(want) if want is not None else None)
+
+
+def test_the_benchmark_declares_the_read_share_for_the_two_cells():
+    import json
+    from pathlib import Path
+
+    bench = json.loads((Path(__file__).resolve().parent.parent
+                        / "BENCHMARK.json").read_text())
+    # appended by PR 35: nothing before it moved, later PRs append after
+    assert next(m for m in bench["per_layer"]
+                if m["name"] == "moe.decode_experts_read_share") == {
+        "name": "moe.decode_experts_read_share", "unit": "%",
+        "better": "lower", "source": "program_counter", "layer": "kernels",
+        "moves": "tpot_p50_ms",
+        "workloads": ["axk1-ep16-cut.decode-full",
+                      "ling3flash-ep4-cut.decode-full"]}
 
 
 # ---------------------------------------------------------------------------
