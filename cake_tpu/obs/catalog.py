@@ -155,7 +155,40 @@ SERIES: dict[str, tuple[str, str]] = {
                  "rejected"),
     "disagg.transfer_ms": (
         HISTOGRAM, "export-to-ACK wall time per completed transfer"),
-    # -- the order of work at a block boundary (runtime/batch_generator) -
+    # -- an admission's stages, a block's period, and the order of work at
+    # -- a block boundary (runtime/batch_generator) -----------------------
+    "engine.admissions_landed": (
+        COUNTER, "prompt admissions (enqueue()) whose first token was "
+                 "fetched and whose splice was enqueued: one observation "
+                 "of each engine.admit_*_ms; imports, attaches and the "
+                 "synchronous admit() count nothing"),
+    "engine.admit_land_ms": (
+        HISTOGRAM, "per landed admission: _finish_admission entered -> its "
+                   "first token on the host (what is left of the prefill, "
+                   "the sampling program, the fetch); every live stream "
+                   "waits, the device works"),
+    "engine.admit_launch_wait_ms": (
+        HISTOGRAM, "per landed admission: enqueue() -> its first prefill "
+                   "dispatch returned (no free slot, or another admission "
+                   "staged; the prefix match and the staging row)"),
+    "engine.admit_rows_wait_ms": (
+        HISTOGRAM, "per landed admission: first prefill dispatch returned "
+                   "-> _finish_admission entered (the running block and "
+                   "its rows going out; the prefill runs on the device "
+                   "meanwhile; a chunked admission's later chunks)"),
+    "engine.admit_to_splice_ms": (
+        HISTOGRAM, "per landed admission: first token on the host -> the "
+                   "splice program enqueued (host work while the device "
+                   "has nothing to run)"),
+    "engine.block_period_clear_ms": (
+        HISTOGRAM, "engine.block_period_ms of the periods in which no "
+                   "admission landed: the block's steps and the boundary"),
+    "engine.block_period_ms": (
+        HISTOGRAM, "a decode block's landing less the previous block's "
+                   "(what a live stream waits for its next block of "
+                   "tokens), once per landed block but the first of a "
+                   "busy stretch: an idle engine's wait for a request, "
+                   "single steps and speculative rounds close no period"),
     "engine.boundaries": (
         COUNTER, "landed decode blocks after which the engine enqueued a "
                  "next device program (a block, or an arrival's prefill)"),
@@ -285,7 +318,6 @@ SERIES: dict[str, tuple[str, str]] = {
         GAUGE, "short-window (60 s) error-budget burn rate"),
     "slo.good": (COUNTER, "requests that met their TTFT/TPOT targets"),
     # -- serving plane (HTTP API + scheduler) ----------------------------
-    "serve.admit_chunk_ms": (HISTOGRAM, "admission prefill chunk dispatch"),
     "serve.admit_to_first_ms": (
         HISTOGRAM, "handed to the engine -> first token emitted, per "
                    "request (admission, prefill, the block it joined); "
@@ -364,7 +396,8 @@ DYNAMIC: dict[str, tuple[str, str]] = {
         GAUGE, "per-worker merged health/traffic fields (ClusterScraper)"),
     "prof.phase_ms.*": (
         HISTOGRAM, "per-phase wall ms inside sampled engine steps "
-                   "(admit/pages/guide/dispatch/sync/emit and the spec_* "
+                   "(admit with admit_launch/admit_land inside it, "
+                   "pages/guide/dispatch/sync/emit and the spec_* "
                    "phases) and of the scheduler's pass around them "
                    "(idle_park/sched_admit/deliver/retire) — "
                    "obs/prof.PHASES"),

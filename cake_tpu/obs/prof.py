@@ -6,7 +6,8 @@ the time goes* — the question every perf item (speculation that must
 pay, churn vs steady, SLO scheduling) hinges on. Three arms:
 
 - :class:`StepProfiler` — the ``BatchGenerator`` / ``SingleStreamEngine``
-  step loops stamp each pass into named phases (``admit``, ``pages``,
+  step loops stamp each pass into named phases (``admit`` with
+  ``admit_launch`` and ``admit_land`` inside it, ``pages``,
   ``guide``, ``dispatch``, ``sync``, ``emit``, and the speculative
   ``spec_propose`` / ``spec_verify`` / ``spec_accept``; the scheduler
   adds ``idle_park`` between passes and times the parts of its own pass
@@ -21,15 +22,18 @@ pay, churn vs steady, SLO scheduling) hinges on. Three arms:
   (``t_unix_ns``, ``t_perf_s``) and each phase's offset from it
   (``at_ms``), so it can be laid on a trace or a client's timeline. A
   scheduler pass longer than :data:`SLOW_PASS_MS` leaves its parts in a
-  second ring (``slow_passes``) and in ``prof.slow_pass_ms``: what the
-  program keeps about a pause of seconds. Phase stamping is
+  second ring (``slow_passes``, with the thread's CPU time and the
+  step's longest device fetch beside the parts) and in
+  ``prof.slow_pass_ms``: what the program keeps about a pause of
+  seconds. Phase stamping is
   host-side driver code only — never inside a jitted body (cakelint
   CK-JIT), and the step/phase calls run on the engine-owner thread
   (CK-THREAD); the ring and report path are lock-guarded for handler
   readers. ``dispatch`` prices the async dispatch call itself; the
   device compute lands in ``sync`` (the host fetch). ``pages`` nests
-  inside ``dispatch`` and ``guide`` inside ``emit`` — sub-phases
-  attribute their parents' time, they don't extend the step total.
+  inside ``dispatch``, ``guide`` inside ``emit``, ``admit_launch`` and
+  ``admit_land`` inside ``admit`` — sub-phases attribute their parents'
+  time, they don't extend the step total.
 
 - :class:`RetraceSentinel` — the runtime twin of cakelint CK-JIT, the
   way ``runtime/threadcheck`` twins CK-THREAD: a ``jax.monitoring``
@@ -101,6 +105,8 @@ CAPTURE_MAX_S = 30.0
 # series exactly the way the metric catalog exists to prevent.
 PHASES = (
     "admit",         # admission / arrival-drain tick (prefill chunk)
+    "admit_launch",  # in admit: take an arrival, dispatch a prefill chunk
+    "admit_land",    # in admit: first token fetched, sampled, spliced
     "pages",         # kvpool gather/scatter host prep (page-map upload)
     "guide",         # constrain guide/mask advance (host DFA cursor)
     "dispatch",      # device dispatch call (async: enqueue cost only)
@@ -233,14 +239,21 @@ class StepProfiler:
         return _Phase(self, name, {})
 
     def note_pass(self, total_ms: float, parts: dict, queued: int,
-                  running: int) -> None:
+                  running: int, cpu_ms: float = 0.0,
+                  fetch_ms: float = 0.0) -> None:
         """One scheduler pass ended after ``total_ms`` (parked time left
         out). A pass over :data:`SLOW_PASS_MS` adds its length to
         ``prof.slow_pass_ms`` and leaves a record -- when, how long, in
         which part, how much was waiting -- in the ``slow_passes`` ring.
         ``parts`` holds ``admit_ms``, ``step_ms``, ``deliver_ms``; the
         rest of the pass (retire, sweeps, the stats snapshot, the wait
-        for the scheduler's lock) is ``rest_ms``."""
+        for the scheduler's lock) is ``rest_ms``. Beside the parts,
+        whether the engine's thread ran or waited: ``cpu_ms`` is the CPU
+        time the thread got during the pass (``time.thread_time``),
+        ``fetch_ms`` the longest wait for the device inside the engine's
+        step. ``fetch_ms`` ~ ``total_ms``: the runtime or the device held
+        the pass; ``cpu_ms`` ~ ``total_ms``: Python ran; neither: the
+        thread was descheduled or waited for a lock."""
         if total_ms < SLOW_PASS_MS:
             return
         self._slow_n.inc()
@@ -249,6 +262,7 @@ class StepProfiler:
                "total_ms": round(total_ms, 3)}
         rec.update((k, round(v, 3)) for k, v in parts.items())
         rec["rest_ms"] = round(max(0.0, total_ms - sum(parts.values())), 3)
+        rec["cpu_ms"], rec["fetch_ms"] = round(cpu_ms, 3), round(fetch_ms, 3)
         rec["queued"], rec["running"] = queued, running
         # the ring dies with the process and a benchmark run keeps only
         # the server's log: say there which part of the pass held it

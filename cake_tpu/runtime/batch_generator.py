@@ -162,14 +162,33 @@ _STATE_RESETS = {"kda": obs_metrics.counter("kda.state_resets"),
 _BOUNDARY_MS = obs_metrics.histogram("engine.boundary_ms")
 _BOUNDARIES = obs_metrics.counter("engine.boundaries")
 _BOUNDARIES_AHEAD = obs_metrics.counter("engine.boundaries_ahead")
+# An admission's stages, stamped on perf_counter where each boundary is
+# crossed (BatchGenerator._observe_admission, once per landed prompt
+# admission), and a block's period, landing to landing (_land_block):
+# what stands between the decode step and a client's token gap.
+# The stamps, in the order they are taken: enqueued (enqueue()), launched
+# (the first prefill dispatch returned), land_begin (_finish_admission
+# entered), landed (the first token on the host), spliced (the splice
+# program enqueued). A stage runs from one stamp to the next.
+_ADMIT_STAGES = (
+    ("launch_wait", obs_metrics.histogram("engine.admit_launch_wait_ms")),
+    ("rows_wait", obs_metrics.histogram("engine.admit_rows_wait_ms")),
+    ("land", obs_metrics.histogram("engine.admit_land_ms")),
+    ("to_splice", obs_metrics.histogram("engine.admit_to_splice_ms")),
+)
+_ADMISSIONS_LANDED = obs_metrics.counter("engine.admissions_landed")
+_BLOCK_PERIOD_MS = obs_metrics.histogram("engine.block_period_ms")
+_BLOCK_PERIOD_CLEAR_MS = obs_metrics.histogram("engine.block_period_clear_ms")
 _KV_BLOCKS_READ = obs_metrics.counter("attn.kv_blocks_read")
 _KV_BLOCKS_RESERVED = obs_metrics.counter("attn.kv_blocks_reserved")
 
 # arrival-queue entry kinds (4th tuple field): None marks a plain prompt
 # arrival; imports ride the SAME FIFO so pool-pressure deferral stays
-# FIFO-fair between admissions and KV-page imports
-_ARR_IMPORT = "import"  # (xfer_id, None, None, _ARR_IMPORT)
-_ARR_ATTACH = "attach"  # (xfer_id, sid, None, _ARR_ATTACH)
+# FIFO-fair between admissions and KV-page imports. The 5th field is when
+# enqueue() took a prompt (perf_counter): the first of its admission's
+# stamps; None for what is not timed (imports, attaches, admit()).
+_ARR_IMPORT = "import"  # (xfer_id, None, None, _ARR_IMPORT, None)
+_ARR_ATTACH = "attach"  # (xfer_id, sid, None, _ARR_ATTACH, None)
 
 
 def _carrying(prog, steps: int, rows):
@@ -404,6 +423,20 @@ class BatchGenerator:
         self._landed_at: float | None = None
         self._landed_rows_out = False
         self._next_ahead: bool | None = None
+        # a block's period, landing to landing: when the previous block
+        # landed (forgotten where the engine goes idle, so the wait for
+        # the next request is no period) and whether an admission has
+        # landed since -- engine.block_period_*
+        self._period_from: float | None = None
+        self._period_admitted = False
+        # the longest wait for the device inside the current step() (a
+        # block's fetch, an admission's first token): a slow scheduler
+        # pass says with it whether the engine's thread ran or waited
+        self.step_fetch_ms = 0.0
+        # landed admissions' stages by stream id, until the scheduler
+        # takes them for the request's own timeline
+        # (take_admission_stages); the oldest go where nobody does
+        self._admit_stages: dict[int, list] = {}
         # int8 KV roughly doubles servable batch x window on a fixed HBM
         # budget (quantize-on-write per slot, kvcache.QuantizedKV) — the
         # serving-side long-context lever
@@ -528,7 +561,7 @@ class BatchGenerator:
         # the same >= prefix_share_min tokens (the system-prompt case), the
         # prefix is prefilled once instead of once per stream (0 disables).
         self._prefix_share_min = max(0, prefix_share_min)
-        self._arrivals: list[tuple[list[int], int]] = []
+        self._arrivals: list[tuple] = []  # see _ARR_IMPORT
         self._staging: dict | None = None
         self.__admit_prefill = None
         self.__prefill_offset = None
@@ -606,10 +639,9 @@ class BatchGenerator:
         # percentiles must reflect THIS generator, not samples a
         # predecessor in the same process left in a shared series
         self._dispatch_hist = obs_metrics.Histogram("serve.decode_dispatch_ms")
-        self._admit_hist = obs_metrics.Histogram("serve.admit_chunk_ms")
         self._emitted_ctr = obs_metrics.Counter("serve.tokens_emitted")
         obs_metrics.registry().publish(
-            self._dispatch_hist, self._admit_hist, self._emitted_ctr)
+            self._dispatch_hist, self._emitted_ctr)
         # an expert model's load on the experts held here: the decode
         # programs of a model told its share return each row's routed
         # pairs that fell on held experts as one more value
@@ -1120,7 +1152,7 @@ class BatchGenerator:
         # text
         self._pending_rows: list[list[Token | None]] = []
         self._inflight = None  # any prior in-flight block is stale now
-        self._landed_at = None
+        self._landed_at = self._period_from = None
         # (device value, what it holds) of the frontiers and the token
         # indices the last block program returned: _carried
         self._carry: list = [None, None]
@@ -1163,7 +1195,8 @@ class BatchGenerator:
         thread (where it would read as an engine fault)."""
         self._domain_stamp.check("BatchGenerator.enqueue")
         self._check_guide_ok(guide)
-        self._arrivals.append((self._encode(prompt), stream_id, guide, None))
+        self._arrivals.append((self._encode(prompt), stream_id, guide, None,
+                               time.perf_counter()))
 
     @property
     def paged(self) -> bool:
@@ -1584,7 +1617,7 @@ class BatchGenerator:
             "snap": snap, "pages": None, "detok": detok, "meta": meta,
             "deferred": False, "t": time.monotonic(),
         }
-        self._arrivals.append((snap.xfer_id, None, None, _ARR_IMPORT))
+        self._arrivals.append((snap.xfer_id, None, None, _ARR_IMPORT, None))
         return meta
 
     def _page_shapes(self) -> dict:
@@ -1687,10 +1720,11 @@ class BatchGenerator:
         self._require_paged("import_attach")
         if xfer_id not in self._imports:
             raise KeyError(f"unknown or expired transfer {xfer_id!r}")
-        self._arrivals.append((xfer_id, stream_id, None, _ARR_ATTACH))
+        self._arrivals.append(
+            (xfer_id, stream_id, None, _ARR_ATTACH, None))
 
     def _import_attach_tick(self) -> None:
-        xid, sid, _, _ = self._arrivals.pop(0)
+        xid, sid = self._arrivals.pop(0)[:2]
         rec = self._imports.pop(xid, None)
         if rec is None or rec["pages"] is None:
             # aborted/expired between queue and tick (rec["pages"] is
@@ -1968,41 +2002,53 @@ class BatchGenerator:
         if st is not None and "logits" in st:
             if wait and self._rows_wait():
                 return
-            self._finish_admission()
-        if self._staging is None and not self._start_arrival(wait):
-            return
-        st = self._staging
-        pos, chunk, base = st["pos"], st["chunk"], st["base"]
-        final = pos + chunk >= st["tokens"].shape[1]
-        t0 = time.perf_counter()
-        with span("admit.chunk", pos=base + pos, chunk=chunk):
-            logits, st["cache"] = self._admit_prefill(
-                self.params,
-                jnp.asarray(st["tokens"][:, pos: pos + chunk]),
-                st["cache"],
-                jnp.int32(base + pos),
-                # the in-chunk index of the prompt's last token; in an
-                # earlier chunk the chunk's own last (every token of it
-                # is true: what a recurrent state may be advanced by)
-                jnp.asarray(
-                    [min(len(st["ids"]) - 1 - base - pos, chunk - 1)],
-                    jnp.int32,
-                ),
-            )
-            self._note_enqueued()
+            with self._prof.phase("admit_land"):
+                self._finish_admission()
+        if self._staging is None and not self._arrivals:
+            return  # nothing to launch
+        with self._prof.phase("admit_launch"):
+            if self._staging is None and not self._start_arrival(wait):
+                return
+            st = self._staging
+            pos, chunk, base = st["pos"], st["chunk"], st["base"]
+            final = pos + chunk >= st["tokens"].shape[1]
+            t0 = time.perf_counter()
+            with span("admit.chunk", pos=base + pos, chunk=chunk):
+                logits, st["cache"] = self._admit_prefill(
+                    self.params,
+                    jnp.asarray(st["tokens"][:, pos: pos + chunk]),
+                    st["cache"],
+                    jnp.int32(base + pos),
+                    # the in-chunk index of the prompt's last token; in an
+                    # earlier chunk the chunk's own last (every token of
+                    # it is true: what a recurrent state may be advanced
+                    # by)
+                    jnp.asarray(
+                        [min(len(st["ids"]) - 1 - base - pos, chunk - 1)],
+                        jnp.int32,
+                    ),
+                )
+                self._note_enqueued()
+                stamps = st["stamps"]
+                if stamps is not None and len(stamps) == 1:
+                    # launched: the first dispatch has returned, from
+                    # here on the device has the prompt (a chunked
+                    # admission's later chunks go under rows_wait)
+                    stamps.append(time.perf_counter())
+                if not final:
+                    # sync: busy_s must include compute (the last chunk's
+                    # is waited for where it lands)
+                    np.asarray(logits.ravel()[:1])
+            self._n_admit_dispatches += 1
+            self._count_admit_rows(chunk)
+            st["pos"] = pos + chunk
             if not final:
-                # sync: busy_s must include compute (the last chunk's
-                # is waited for where it lands)
-                np.asarray(logits.ravel()[:1])
-        self._n_admit_dispatches += 1
-        self._count_admit_rows(chunk)
-        st["pos"] = pos + chunk
-        if not final:
-            self._admit_dispatched(t0, chunk, base + pos)
-            return
-        st["logits"], st["booking"] = logits, (t0, chunk, base + pos)
+                self._admit_dispatched(t0, chunk, base + pos)
+                return
+            st["logits"], st["booking"] = logits, (t0, chunk, base + pos)
         if not (wait and self._rows_wait()):
-            self._finish_admission()
+            with self._prof.phase("admit_land"):
+                self._finish_admission()
 
     def _count_admit_rows(self, chunk: int) -> None:
         """An expert model's admission dispatch of ``chunk`` rows (the
@@ -2019,7 +2065,6 @@ class BatchGenerator:
         """Book one admission chunk whose compute has been waited for."""
         dt = time.perf_counter() - t0
         self._busy_s += dt
-        self._admit_hist.observe(dt * 1e3)
         rec = obs_flight.recorder()
         if rec.enabled:
             rec.record(kind="admit", total_ms=round(dt * 1e3, 3),
@@ -2032,6 +2077,11 @@ class BatchGenerator:
         (True)."""
         if not self._arrivals:
             return False
+        if not any(s.active and not s.done for s in self.streams):
+            # nobody was decoding until this arrival came (an engine
+            # without work is not stepped): the next landing closes no
+            # block period
+            self._period_from = None
         kind = self._arrivals[0][3]
         if kind in (_ARR_IMPORT, _ARR_ATTACH):
             # these edit the pool and a slot: not under undelivered rows
@@ -2050,7 +2100,7 @@ class BatchGenerator:
             # path, including a caller writing s.done directly) frees
             # its page claims before the arrival's needs are priced
             self._release_pages(slot)
-        ids, sid, guide, _ = self._arrivals.pop(0)
+        ids, sid, guide, _, enqueued = self._arrivals.pop(0)
         # Prefix reuse: an arrival whose opening tokens match a stored
         # prefix (a staged row in the slot layout, a page chain in the
         # paged one) starts from that content and prefills only its
@@ -2096,7 +2146,7 @@ class BatchGenerator:
                     # (cake_tpu/disagg imports).
                     self._pagepool.count_defer()
                 self._admit_deferred = True
-                self._arrivals.insert(0, (ids, sid, guide, None))
+                self._arrivals.insert(0, (ids, sid, guide, None, enqueued))
                 return False
             self._admit_deferred = False
         tokens = np.zeros((1, t_pad), np.int32)
@@ -2130,6 +2180,9 @@ class BatchGenerator:
             "ids": ids, "sid": sid, "slot": slot,
             "tokens": tokens, "pos": 0, "chunk": chunk, "base": base,
             "cache": cache, "guide": guide, "shared": shared_pages,
+            # the admission's stamps (_ADMIT_STAGES), carried on from the
+            # arrival; None where nothing is timed (admit())
+            "stamps": None if enqueued is None else [enqueued],
         }
         return True
 
@@ -2165,6 +2218,7 @@ class BatchGenerator:
         """Land the launched admission: sample the first token from its
         last chunk's logits (the one wait for the device), splice the
         staged row into its slot, record the token and queue its row."""
+        land_begin = time.perf_counter()
         st, self._staging = self._staging, None
         slot, ids, stream_id = st["slot"], st["ids"], st["sid"]
         guide = st.get("guide")
@@ -2198,7 +2252,14 @@ class BatchGenerator:
         # the one wait for the device: the sampling above was dispatched
         # while the prefill still ran (it was launched before the rows
         # that have just gone out), so its host time hides behind it
+        t_fetch = time.perf_counter()
         tok_id = int(tok)
+        landed = time.perf_counter()
+        self.step_fetch_ms = max(self.step_fetch_ms,
+                                 (landed - t_fetch) * 1e3)
+        # every live stream's next block waits behind this admission: the
+        # period that holds it is not clear
+        self._period_admitted = True
         self._admit_dispatched(*st["booking"])
         hist_row[len(tail) % n_hist] = tok_id
         lp_row = None
@@ -2254,6 +2315,9 @@ class BatchGenerator:
                 jnp.asarray(hist_row), jnp.int32(len(tail) + 1),
                 jnp.int32(tok_id), jnp.int32(slot),
             )
+        if st["stamps"] is not None:
+            self._observe_admission(stream_id, st["stamps"] + [
+                land_begin, landed, time.perf_counter()])
         self._pos = np.asarray(self._pos).copy()
         self._pos[slot] = len(ids)
         self._index = np.asarray(self._index).copy()
@@ -2305,6 +2369,34 @@ class BatchGenerator:
             # first sampled token ended the stream: free its claims now
             # (AFTER the tree store above took its references)
             self._release_pages(slot)
+
+    def _observe_admission(self, stream_id: int, stamps: list) -> None:
+        """A prompt admission has landed and its splice is enqueued:
+        observe its four stages from its five stamps
+        (``engine.admit_*_ms``: what the arrival waited for before the
+        device had it, what it waited for behind the running block and
+        its rows while its prefill ran, what was left of the prefill,
+        the sampling and the fetch when the host came for it, and the
+        host work after which the device had its next program again)
+        and keep them for the request's own timeline."""
+        stages = []
+        for (name, hist), t0, t1 in zip(_ADMIT_STAGES, stamps, stamps[1:]):
+            hist.observe((t1 - t0) * 1e3)
+            stages.append((name, t0, (t1 - t0) * 1e3))
+        _ADMISSIONS_LANDED.inc()
+        kept = self._admit_stages
+        kept[stream_id] = stages
+        if len(kept) > 2 * len(self.streams):
+            del kept[next(iter(kept))]
+
+    def take_admission_stages(self, stream_id: int) -> list | None:
+        """``[(stage, its start on perf_counter, ms), ...]`` of the
+        admission that brought this stream in (``launch_wait``,
+        ``rows_wait``, ``land``, ``to_splice``, each starting where the
+        one before ended), once: the scheduler takes them where it
+        delivers the stream's first token. None for a stream that came
+        another way (``admit()``, an import)."""
+        return self._admit_stages.pop(stream_id, None)
 
     def finish(self, stream_id: int) -> bool:
         """Retire the stream with this ``stream_id`` at ANY point in its
@@ -2369,7 +2461,7 @@ class BatchGenerator:
         if not self.streams:
             raise RuntimeError("set_prompts first")
         ids = self._encode(prompt)
-        self._arrivals.append((ids, stream_id, None, None))
+        self._arrivals.append((ids, stream_id, None, None, None))
         # Drain until OUR arrival (tracked by list identity — FIFO order
         # admits anything queued ahead of it first) is fully admitted. If
         # the queue head cannot start because every stream is live, raise
@@ -2476,6 +2568,7 @@ class BatchGenerator:
             raise RuntimeError("set_prompts first")
         prof = self._prof
         prof.step_begin("batch")
+        self.step_fetch_ms = 0.0
         try:
             if not self._emitted_first:
                 self._emitted_first = True
@@ -2591,6 +2684,7 @@ class BatchGenerator:
         fed = np.zeros((b, k + 1), np.int32)
         fed[:, 0] = self._host(self._last_tokens)
         fed[:, 1:] = np.maximum(props, 0)  # -1 pads embed as 0; never match
+        self._period_from = None  # rounds between blocks: no period
         t0 = time.perf_counter()
         with self._prof.phase("spec_verify"), self._sentinel.decode_phase():
             logits, self.cache = self._pick_verify()(
@@ -2736,6 +2830,7 @@ class BatchGenerator:
                          if s.active else [0])
                 buf[i, : len(ctx_i)] = ctx_i
             self._spec_ctx = jnp.asarray(buf)
+        self._period_from = None  # rounds between blocks: no period
         t0 = time.perf_counter()
         ctx = self._spec_ctx
         pos = jnp.asarray(np.asarray(self._pos, np.int32))
@@ -2933,12 +3028,23 @@ class BatchGenerator:
         can be launched into. Returns when the fetch returned."""
         toks, lpv, lpi, size, t0 = self._inflight
         self._inflight = None
+        t_fetch = time.perf_counter()
         with self._prof.phase("sync"):
             rows = self._host(toks)  # [steps, B]
             lp = ((self._host(lpv), self._host(lpi))
                   if lpv is not None else None)
             self._record_moe_count()
         landed = time.perf_counter()
+        self.step_fetch_ms = max(self.step_fetch_ms,
+                                 (landed - t_fetch) * 1e3)
+        if self._period_from is not None:
+            # landing to landing: what a live stream waits for its next
+            # block of tokens; clear where no admission landed in between
+            period_ms = (landed - self._period_from) * 1e3
+            _BLOCK_PERIOD_MS.observe(period_ms)
+            if not self._period_admitted:
+                _BLOCK_PERIOD_CLEAR_MS.observe(period_ms)
+        self._period_from, self._period_admitted = landed, False
         dt = landed - t0
         self._busy_s += dt
         # per-token ms so the series is comparable across block sizes
@@ -2981,7 +3087,9 @@ class BatchGenerator:
             if s.active and not s.done
         ]
         if not live:
-            self._landed_at = None  # nothing follows: no boundary
+            # nothing follows: no boundary, and the wait for the next
+            # request is no period
+            self._landed_at = self._period_from = None
             return False
         if (self._decode_block is None
                 and self.block_size_max <= self.block_size):
@@ -3127,6 +3235,7 @@ class BatchGenerator:
 
         if int(max(live)) >= self.max_seq:  # unreachable: _record marks
             raise RuntimeError("KV cache exhausted")  # window-full streams done
+        self._period_from = None  # single steps between blocks: no period
         t0 = time.perf_counter()
         pos = self._decode_pos()
         args = (

@@ -652,7 +652,7 @@ class Scheduler:
             # every pass is timed, parked time left out: a pass of
             # seconds (a stall) leaves its parts in /debug/prof
             # ``slow_passes`` (obs/prof.StepProfiler.note_pass)
-            t_pass = time.perf_counter()
+            t_pass, cpu_pass = time.perf_counter(), time.thread_time()
             parked = 0.0
             with self._cond:
                 self._expire_queued_locked()
@@ -702,7 +702,9 @@ class Scheduler:
                 prof.note_pass(
                     (time.perf_counter() - t_pass - parked) * 1e3,
                     {"admit_ms": p_admit.ms, "step_ms": step_ms,
-                     "deliver_ms": p_deliver.ms}, queued, running)
+                     "deliver_ms": p_deliver.ms}, queued, running,
+                    cpu_ms=(time.thread_time() - cpu_pass) * 1e3,
+                    fetch_ms=getattr(self.engine, "step_fetch_ms", 0.0))
             except Exception as e:  # engine fault: fail every session
                 log.exception("engine thread fault: %s", e)
                 self.fault = f"{type(e).__name__}: {e}"
@@ -1088,8 +1090,11 @@ class Scheduler:
             if sess.handoff is not None:
                 handoffs.append((stream.stream_id, sess, tok))
                 continue
+            first = sess.ttft_ms is None
             sess.on_token(tok.id, tok.text,
                           logprobs=getattr(tok, "logprobs", None))
+            if first:
+                self._trace_admission(stream.stream_id, sess)
             self._tenants.add(sess.tenant)
             n += 1
             if tok.is_end_of_stream:
@@ -1113,6 +1118,24 @@ class Scheduler:
                     0.5 * self._tok_s + 0.5 * inst)
                 self._rate_tokens = 0
                 self._rate_t0 = time.perf_counter()
+
+    def _trace_admission(self, sid: int, sess: Session) -> None:
+        """A stream's first token has been delivered: take its
+        admission's stages from the engine (kept there until read) and
+        lay them on the request's own timeline, under its
+        ``engine.prefill`` span: which of an admission's waits THIS
+        request met. Nothing from an engine that keeps no stages, or for
+        a stream that came another way (a resume)."""
+        take = getattr(self.engine, "take_admission_stages", None)
+        stages = take(sid) if take is not None else None
+        ctx = sess.reqtrace
+        if stages is None or ctx is None:
+            return
+        # the engine stamps perf_counter, the timeline is on unix time
+        to_unix = time.time() - time.perf_counter()
+        for name, t0, ms in stages:
+            ctx.add_span(f"engine.admit.{name}", t0 + to_unix, ms,
+                         parent=sess.prefill_span, request=sess.id)
 
     def _handoff_one(self, sid: int, sess: Session, tok) -> None:
         """Export + retire a prefilled stream at its first token; the

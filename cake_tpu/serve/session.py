@@ -151,6 +151,9 @@ class Session:
         self.t_submit_unix = time.time()
         self.t_admit_unix: float | None = None
         self._t_first_unix: float | None = None
+        # the engine.prefill span's id once recorded: the parent of the
+        # admission's stages (Scheduler._trace_admission)
+        self.prefill_span: str | None = None
 
     # -- engine-thread side ---------------------------------------------------
     def on_token(self, tok_id: int, text: str | None,
@@ -175,10 +178,10 @@ class Session:
                 if self.t_admit_unix is not None:
                     # admission -> first token: the prefill (+ queued
                     # decode) leg, as one request-attributed span
-                    ctx.add_span("engine.prefill", self.t_admit_unix,
-                                 (self._t_first_unix
-                                  - self.t_admit_unix) * 1e3,
-                                 request=self.id)
+                    self.prefill_span = ctx.add_span(
+                        "engine.prefill", self.t_admit_unix,
+                        (self._t_first_unix - self.t_admit_unix) * 1e3,
+                        request=self.id)
                 ctx.event("decode.first_token", request=self.id,
                           ttft_ms=round(self.ttft_ms, 3))
         else:

@@ -334,6 +334,14 @@ def test_capture_writes_the_profile_and_the_programs_spans(captured):
     doc = json.loads((d / "spans.trace.json").read_text())
     names = {e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"}
     assert {"prof.dispatch", "prof.sync", "prof.deliver"} <= names
+    # the request's admission: its launch and its landing, inside admit
+    evs = {n: [e for e in doc["traceEvents"] if e["name"] == n]
+           for n in ("prof.admit", "prof.admit_launch", "prof.admit_land")}
+    assert all(evs.values()), {n: len(v) for n, v in evs.items()}
+    for inner in evs["prof.admit_launch"] + evs["prof.admit_land"]:
+        assert any(o["tid"] == inner["tid"] and o["ts"] <= inner["ts"]
+                   and inner["ts"] + inner["dur"] <= o["ts"] + o["dur"] + 1
+                   for o in evs["prof.admit"]), inner
     # the origin the spans' ts count from, on the answers' perf clock
     origin = doc["otherData"]["perf_origin_s"]
     assert origin <= captured["started"]["perf_s"]
@@ -375,7 +383,8 @@ def test_capture_puts_the_phases_on_the_traces_host_plane(captured):
              if plane.name.startswith("/host:")
              for line in plane.lines for e in line.events
              if e.name.startswith("prof.")}
-    assert {"prof.dispatch", "prof.sync"} <= names, sorted(names)
+    assert {"prof.dispatch", "prof.sync", "prof.admit", "prof.admit_launch",
+            "prof.admit_land"} <= names, sorted(names)
 
 
 def test_stop_without_a_capture_and_bad_bodies(served):
@@ -487,6 +496,32 @@ def test_scheduler_pass_parts_are_declared_phases(name):
     assert catalog.kind_of(f"prof.phase_ms.{name}") == catalog.HISTOGRAM
 
 
+@pytest.mark.parametrize("name", ["admit_launch", "admit_land"])
+def test_an_admissions_launch_and_landing_are_phases_inside_admit(
+        params, prof_env, name):
+    """Declared, stamped once per admission on a sampled step, and
+    attributing their parent's time: ``admit`` keeps its own histogram,
+    which holds what they hold."""
+    from cake_tpu.obs import catalog
+
+    assert name in prof.PHASES
+    assert catalog.kind_of(f"prof.phase_ms.{name}") == catalog.HISTOGRAM
+    prof.profiler().reset()
+    prof.profiler().set_sample(1)
+    gen = BatchGenerator(CFG, params, settings=SamplerSettings(**GREEDY))
+    _collect(gen, [2, 7, 1, 8], sid=1, steps=6)
+    phases = prof.report()["phases"]
+    assert phases[name]["count"] == 1
+    assert phases["admit"]["count"] >= 1
+    inner = phases["admit_launch"]["sum"] + phases["admit_land"]["sum"]
+    assert inner <= phases["admit"]["sum"] + 0.01
+    with_both = [r for r in prof.profiler().recent_steps()
+                 if {"admit", name} <= set(r["phases"])]
+    assert with_both and all(
+        r["phases"][name] <= r["phases"]["admit"] + 0.01
+        and r["at_ms"]["admit"] <= r["at_ms"][name] for r in with_both)
+
+
 def test_a_slow_pass_leaves_its_parts(prof_env, monkeypatch):
     """A scheduler pass over the limit adds its length to
     ``prof.slow_pass_ms`` and keeps which part held it."""
@@ -542,7 +577,12 @@ def test_a_slow_pass_leaves_its_parts(prof_env, monkeypatch):
     assert len(slow) == 3
     for rec in slow:
         assert set(rec) == {"t_unix_ns", "total_ms", "admit_ms", "step_ms",
-                            "deliver_ms", "rest_ms", "queued", "running"}
+                            "deliver_ms", "rest_ms", "queued", "running",
+                            "cpu_ms", "fetch_ms"}
+        # the engine's thread slept through the step: it neither ran nor
+        # waited for a device fetch (this engine keeps none)
+        assert rec["cpu_ms"] < 0.5 * rec["total_ms"]
+        assert rec["fetch_ms"] == 0.0
         assert rec["step_ms"] >= 50.0 > rec["admit_ms"] + rec["deliver_ms"]
         assert rec["total_ms"] == pytest.approx(
             rec["admit_ms"] + rec["step_ms"] + rec["deliver_ms"]
@@ -585,5 +625,65 @@ def test_a_slow_pass_says_its_parts_in_the_log(prof_env, caplog):
     said = rec.getMessage()
     assert said.startswith("slow scheduler pass: ")
     for part in ("'total_ms': 2410.0", "'step_ms': 2400.0", "'rest_ms': 7.0",
-                 "'queued': 3", "'running': 32"):
+                 "'queued': 3", "'running': 32", "'cpu_ms': 0.0",
+                 "'fetch_ms': 0.0"):
         assert part in said
+
+
+def test_a_slow_pass_says_whether_the_thread_ran_or_waited(
+        params, prof_env, monkeypatch, caplog):
+    """``cpu_ms`` and ``fetch_ms`` beside the parts, in the ring and in
+    the log: a pass the device held has ``fetch_ms`` near its
+    ``step_ms``, one that Python held has ``cpu_ms`` near it. The engine
+    keeps the longest fetch of the current ``step()``."""
+    import time
+
+    from cake_tpu.serve.session import Session
+
+    p = prof.profiler()
+    with caplog.at_level("WARNING", logger="cake_tpu.obs.prof"):
+        p.note_pass(1500.0, {"admit_ms": 1.0, "step_ms": 1490.0,
+                             "deliver_ms": 2.0}, queued=0, running=8,
+                    cpu_ms=12.3456, fetch_ms=1480.0)
+    (rec,) = p.slow_passes()
+    assert (rec["cpu_ms"], rec["fetch_ms"]) == (12.346, 1480.0)
+    assert rec["rest_ms"] == 7.0  # neither is a part of the pass
+    assert "'cpu_ms': 12.346, 'fetch_ms': 1480.0" in caplog.text
+    # the engine: a block's fetch that takes 80 ms is the step's longest
+    gen = BatchGenerator(CFG, params, settings=SamplerSettings(**GREEDY),
+                         block_size=4)
+    gen.set_prompts([[3, 1, 4], [1, 5, 9]])
+    gen.step()
+    real = gen._host
+
+    def slow_host(x):
+        time.sleep(0.08)
+        return real(x)
+
+    monkeypatch.setattr(gen, "_host", slow_host)
+    seen = []
+    for _ in range(7):
+        gen.step()
+        seen.append(gen.step_fetch_ms)
+    assert max(seen) >= 80.0 and min(seen) == 0.0  # reset every step()
+    # through the scheduler's pass: such a step is a slow pass whose
+    # fetch_ms says the device (here the sleeping fetch) held it
+    monkeypatch.setattr(prof, "SLOW_PASS_MS", 50.0)
+    p.reset()
+    sched = Scheduler(gen, queue_depth=4)
+    sched.start(max_concurrent=2)
+    try:
+        sess = Session([2, 7, 1], max_tokens=6)
+        sched.submit(sess)
+        while sess.events.get(timeout=60)[0] == "token":
+            pass
+    finally:
+        sched.close()
+    slow = p.slow_passes()
+    waited = [r for r in slow if r["fetch_ms"] >= 0.9 * r["total_ms"]]
+    assert waited and all(80.0 <= r["fetch_ms"] <= r["step_ms"]
+                          and r["cpu_ms"] < 0.2 * r["total_ms"]
+                          for r in waited)
+    # the others are the admission's compiles: no fetch held those
+    assert all(r["fetch_ms"] < 0.5 * r["total_ms"]
+               for r in slow if r not in waited), slow
