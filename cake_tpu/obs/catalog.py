@@ -162,15 +162,22 @@ SERIES: dict[str, tuple[str, str]] = {
                  "fetched and whose splice was enqueued: one observation "
                  "of each engine.admit_*_ms; imports, attaches and the "
                  "synchronous admit() count nothing"),
+    "engine.admit_launches": (
+        COUNTER, "prompt admission programs launched: one for the head of "
+                 "the arrival queue and every plain prompt that waited "
+                 "behind it with a free slot (BatchGenerator."
+                 "_start_arrival); admissions_landed / admit_launches is "
+                 "how many admissions a launch carries"),
     "engine.admit_land_ms": (
         HISTOGRAM, "per landed admission: _finish_admission entered -> its "
                    "first token on the host (what is left of the prefill, "
                    "the sampling program, the fetch); every live stream "
                    "waits, the device works"),
     "engine.admit_launch_wait_ms": (
-        HISTOGRAM, "per landed admission: enqueue() -> its first prefill "
-                   "dispatch returned (no free slot, or another admission "
-                   "staged; the prefix match and the staging row)"),
+        HISTOGRAM, "per landed admission: enqueue() -> its launch's "
+                   "first prefill dispatch returned (no free slot, or "
+                   "another launch staged; the prefix match and the "
+                   "staging rows)"),
     "engine.admit_rows_wait_ms": (
         HISTOGRAM, "per landed admission: first prefill dispatch returned "
                    "-> _finish_admission entered (the running block and "

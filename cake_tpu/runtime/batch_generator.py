@@ -41,13 +41,17 @@ sp > 1 ride the ring prefill instead).
 
 Continuous batching: arrivals ``enqueue`` into a FIFO and are admitted into
 freed slots without stalling the batch — the head arrival's prefill is
-*launched* the moment a slot is free (one replicated row into a staging
+*launched* the moment a slot is free (a replicated row into a staging
 cache, ``parallel.pipeline.build_admit_prefill``; a long prompt by one
-chunk dispatch per ``step()`` alongside the running decode dispatches)
-and, once the rows computed before it have been handed out, *lands*: the
-finished row is spliced into its slot. ``admit()`` is the synchronous
-variant. Admission timing never changes a stream's output (per-row
-positions + per-row token indices).
+chunk dispatch per ``step()`` alongside the running decode dispatches),
+together with the plain prompt that waits behind it where one compiled
+program holds both, a free slot and a staging row each (``_start_arrival``,
+``GROUP_SHAPES``: the weights are read once a launch, not once an
+arrival), and, once the rows computed before it have been handed out,
+*lands*: the finished rows are spliced into their slots. ``admit()`` is the
+synchronous variant. Admission timing never changes a stream's output
+(per-row positions + per-row token indices; for an expert model up to the
+order of summation its call's rows select: ``enqueue``).
 
 One order of work at a block boundary: when a block's tokens have landed
 on the host, the device gets its next program — the next block, or a
@@ -134,6 +138,42 @@ class _Stream:
     end_reason: str | None = None
 
 
+@dataclasses.dataclass(eq=False)
+class _Staged:
+    """One prompt arrival of a launched admission."""
+    ids: list[int]
+    sid: int
+    slot: int
+    guide: object | None
+    # the admission's stamps (_ADMIT_STAGES), carried on from the arrival;
+    # None where nothing is timed (admit())
+    stamps: list | None
+
+
+# The several-row admission programs there are, ``(rows, bucket)``: a
+# launch takes riders only into one of these, and only once it is compiled
+# (``_warm_bucket``: with the first one-row program of a bucket it holds).
+# One shape, because a program costs set-up 1.5-5 s whenever a server
+# starts, compile cache or not (its trace, its lowering, its load), and
+# this one, because it is where a rider pays most: ``tools/admit_sweep.py``
+# on the chip (PERF.md section 6, PR 37) has an expert model's admission at
+# 21 / 29 / 34 / 41 ms for 64 / 128 / 256 / 512 rows in one row and at 43 /
+# 59 for two rows of 256 / 512: below ~500 rows in all a program is the
+# read of the held weights, above it the rows' arithmetic, which a second
+# row doubles. (A dense model's admission is arithmetic from 128 rows on:
+# there a launch of two saves a landing and pays for what it pads.)
+GROUP_SHAPES = ((2, 256),)
+
+
+def _group_shape(own: list[int]) -> tuple[int, int] | None:
+    """The several-row program for members whose own buckets are ``own``:
+    the smallest of ``GROUP_SHAPES`` with a row each and a bucket that
+    holds the longest (None: there is none)."""
+    fits = [(r * c, r, c) for r, c in GROUP_SHAPES
+            if r >= len(own) and c >= max(own)]
+    return min(fits)[1:] if fits else None
+
+
 # initial device mask-table capacity (rows); grows by doubling as guides
 # attach, so the masked decode program compiles once per pow2 table shape
 _MASK_CAP0 = 64
@@ -177,6 +217,9 @@ _ADMIT_STAGES = (
     ("to_splice", obs_metrics.histogram("engine.admit_to_splice_ms")),
 )
 _ADMISSIONS_LANDED = obs_metrics.counter("engine.admissions_landed")
+# prompt admission programs launched (one for every arrival that rode):
+# landed / launches is how many admissions a launch carries
+_ADMIT_LAUNCHES = obs_metrics.counter("engine.admit_launches")
 _BLOCK_PERIOD_MS = obs_metrics.histogram("engine.block_period_ms")
 _BLOCK_PERIOD_CLEAR_MS = obs_metrics.histogram("engine.block_period_clear_ms")
 _KV_BLOCKS_READ = obs_metrics.counter("attn.kv_blocks_read")
@@ -213,6 +256,16 @@ def _carrying(prog, steps: int, rows):
 
     return jax.jit(step, donate_argnums=(2,),
                    out_shardings=(None, (rows, rows, rows)))
+
+
+def _splice_rows(bufs: tuple, vals: tuple, slot):
+    """Inside an admission's splice program: write row ``i`` of each of
+    ``vals`` (one row a staged arrival) into its buffer of ``bufs`` at
+    ``slot[i]`` (traced), along the buffers' first axis."""
+    for i in range(slot.shape[0]):
+        bufs = tuple(jax.lax.dynamic_update_index_in_dim(b, v[i], slot[i], 0)
+                     for b, v in zip(bufs, vals))
+    return bufs
 
 
 class BatchGenerator:
@@ -467,6 +520,12 @@ class BatchGenerator:
             config, plan, params_like=self.params, kv_quant=kv_quant))
         # a per-row [B] vector's sharding, as the decode programs take it
         self._rows = NamedSharding(plan.mesh, PartitionSpec(DP))
+        # ... and the sampler state's (keys, history, ring slots, feedback
+        # token), as they leave it: set_prompts and the admission splice
+        # leave it so too, so a splice warmed on a fresh batch is the
+        # program every later landing runs
+        rows2 = NamedSharding(plan.mesh, PartitionSpec(DP, None))
+        self._state_shardings = (rows2, rows2, self._rows, self._rows)
         # raw jit handle kept so tests can pin the compile count — the
         # paged layout's page-table operands are DATA, so table churn
         # (admission, retirement, page growth) must never retrace
@@ -562,11 +621,20 @@ class BatchGenerator:
         # prefix is prefilled once instead of once per stream (0 disables).
         self._prefix_share_min = max(0, prefix_share_min)
         self._arrivals: list[tuple] = []  # see _ARR_IMPORT
+        # the launched admission: ONE prefill program over the staging
+        # rows of every arrival that rode with the head (_start_arrival)
         self._staging: dict | None = None
+        # (rows, chunk) of the admission programs compiled so far, and the
+        # row counts whose landing (sampler, splice) is: _warm_bucket
+        self._warmed: set[tuple[int, int]] = set()
+        self._landing_warmed: set[int] = set()
+        self._warm_pending: list[int] = []  # chunks warmed before a batch
         self.__admit_prefill = None
         self.__prefill_offset = None
         self.__broadcast_progs: dict = {}
-        self.__splice = None  # slot-traced admission splice (one compile)
+        self.__splice = None  # slot-traced admission splice
+        self.__row_of = None  # one staged row out of a launch's cache
+        self.__first_tokens = None  # a landing's keys and first tokens
         self.__splice_small = None  # paged: sampler-state-only splice
         self._contiguous_cache = None  # set_prompts -> _pageify_batch hand-off
         # Generalized prefix store (slot layout): staged batch-1 KV rows
@@ -1138,7 +1206,10 @@ class BatchGenerator:
         self._history, self._hist_slot = sampling.push_history_batched(
             self._history, self._hist_slot, toks
         )
-        self._last_tokens = toks.astype(jnp.int32)
+        (self._keys, self._history, self._hist_slot,
+         self._last_tokens) = jax.device_put(
+            (self._keys, self._history, self._hist_slot,
+             toks.astype(jnp.int32)), self._state_shardings)
         # per-stream absolute token index of the NEXT token (per-row so a
         # stream admitted later starts its own schedule at 1)
         self._index = np.ones((b,), np.int32)
@@ -1163,17 +1234,20 @@ class BatchGenerator:
             self._contiguous_cache = self.cache
             self._pageify_batch(
                 lcp, self.streams[0].prompt[:lcp] if lcp else [])
-        if getattr(self, "_splice_warm_pending", False):
-            # warm_admission ran before this set_prompts; the splice warm
-            # needs the batch state that only now exists
-            self._splice_warm_pending = False
-            self._warm_splice()
+        # warm_admission ran before this set_prompts: its buckets' landing,
+        # and their launches of several rows, need the batch state that
+        # only now exists
+        pending, self._warm_pending = self._warm_pending, []
+        for chunk in pending:
+            self._warm_landing()
+            self._warm_bucket(chunk)
+
+    def _free_slots(self):
+        return (i for i, s in enumerate(self.streams)
+                if not s.active or s.done)
 
     def _free_slot(self) -> int | None:
-        return next(
-            (i for i, s in enumerate(self.streams) if not s.active or s.done),
-            None,
-        )
+        return next(self._free_slots(), None)
 
     def enqueue(self, prompt, stream_id: int, guide=None) -> None:
         """Queue a prompt for continuous admission. Each subsequent
@@ -1181,10 +1255,18 @@ class BatchGenerator:
         replicated row into a staging cache) alongside the running batch's
         decode dispatch — arrivals never stall the batch for a full prompt
         pass. When the prefill completes, the stream's first token is
-        emitted in that step's row and the stream joins the batch. Output
-        is bit-identical to the same (seed, stream_id, prompt) in any other
-        batch or admission timing (per-row positions + per-row token
-        indices). Composes with ``sp > 1`` (r5): the staged row's chunks
+        emitted in that step's row and the stream joins the batch.
+        Arrivals that wait together are admitted by one prefill program
+        (``_start_arrival``), a row each. For a model without routed
+        experts the output is bit-identical to the same (seed, stream_id,
+        prompt) in any other batch or admission timing (per-row positions
+        + per-row token indices; rows of a program do not see each
+        other). An expert model's block takes the form its call's rows
+        select (``ops.moe.expert_form``), across admission buckets and
+        across launches of one and of several rows alike: the same
+        product in another order of summation, so logits equal up to
+        that order and, in the tests, the same tokens under greedy.
+        Composes with ``sp > 1`` (r5): the staged row's chunks
         run replicated over sp against the sequence-sharded staging cache
         (owner-masked range writes + the chunk attend,
         pipeline.build_admit_prefill). ``guide`` (a constrain.Guide)
@@ -1206,7 +1288,8 @@ class BatchGenerator:
 
     def pending_admissions(self) -> int:
         """Arrivals not yet fully admitted (queued + in-flight)."""
-        return len(self._arrivals) + (1 if self._staging is not None else 0)
+        staged = self._staging["members"] if self._staging else ()
+        return len(self._arrivals) + len(staged)
 
     def _store_prefix(self, ids: list[int], row) -> None:
         """Slot layout: insert a staged batch-1 KV row under its token
@@ -1422,16 +1505,11 @@ class BatchGenerator:
         if self.__splice_small is None:
             def splice(keys, history, hist_slot, last, key, hist_row,
                        hist_used, tok, slot):
-                upd1 = lambda buf, v: jax.lax.dynamic_update_index_in_dim(
-                    buf, v, slot, 0)
-                return (
-                    upd1(keys, key),
-                    upd1(history, hist_row),
-                    upd1(hist_slot, hist_used),
-                    upd1(last, tok),
-                )
+                return _splice_rows((keys, history, hist_slot, last),
+                                    (key, hist_row, hist_used, tok), slot)
 
-            self.__splice_small = jax.jit(splice)
+            self.__splice_small = jax.jit(
+                splice, out_shardings=self._state_shardings)
         return self.__splice_small
 
     # -- KV-page export/import (cake_tpu/disagg) -----------------------------
@@ -1748,10 +1826,11 @@ class BatchGenerator:
         (self._keys, self._history, self._hist_slot,
          self._last_tokens) = self._splice_small_fn()(
             self._keys, self._history, self._hist_slot,
-            self._last_tokens, jnp.asarray(snap.key, jnp.uint32),
-            jnp.asarray(snap.history, jnp.int32),
-            jnp.int32(snap.hist_slot), jnp.int32(snap.last_token),
-            jnp.int32(slot),
+            self._last_tokens, jnp.asarray([snap.key], jnp.uint32),
+            jnp.asarray([snap.history], jnp.int32),
+            jnp.asarray([snap.hist_slot], jnp.int32),
+            jnp.asarray([snap.last_token], jnp.int32),
+            jnp.asarray([slot], jnp.int32),
         )
         self._pos = np.asarray(self._pos).copy()
         self._pos[slot] = snap.pos
@@ -1870,14 +1949,15 @@ class BatchGenerator:
         return min(self._admit_chunk, bucket) if self._admit_chunk else bucket
 
     def warm_admission(self, prompt_len: int) -> None:
-        """Compile the admission-prefill program (and staging-cache zeros
-        program) for prompts of this length, outside any serving-critical
+        """Compile the admission-prefill programs (and staging-cache zeros
+        programs) for prompts of this length, outside any serving-critical
         window — benchmarks/servers call this once so the first real
         ``enqueue`` does not pay XLA compilation mid-run. The compiled
-        shape depends only on the chunk for ``prompt_len``; with prefix
-        sharing active, call again with the expected REMAINDER length
-        (arrival length minus the shared prefix), since that is the shape
-        a prefix-cache hit dispatches.
+        shapes depend only on the chunk for ``prompt_len`` and on the
+        several-row programs such a prompt can ride in (``GROUP_SHAPES``);
+        with prefix sharing active, call again with the expected REMAINDER
+        length (arrival length minus the shared prefix), since that is the
+        shape a prefix-cache hit dispatches.
 
         With int8 weights, call AFTER ``set_prompts`` (or pass
         ``quant_backend=`` at construction): the warm trace is permanent
@@ -1890,46 +1970,84 @@ class BatchGenerator:
                 "decided first: call set_prompts before warming, or pass "
                 "quant_backend= at construction"
             )
-        chunk = self._admission_chunk_for(prompt_len)
-        staging = init_cache_on_mesh(
-            self.config, self.plan.mesh, batch=1, max_seq=self.max_seq,
+        self._warm_bucket(self._admission_chunk_for(prompt_len))
+
+    def _group_shapes(self) -> list[tuple[int, int]]:
+        """``GROUP_SHAPES`` as far as this engine can launch them: the
+        slot layout, a slot a row, the whole bucket in one dispatch."""
+        if self._paged:
+            return []
+        return [(r, c) for r, c in GROUP_SHAPES
+                if r <= len(self.streams) and self._admission_chunk_for(c) == c]
+
+    def _warm_bucket(self, chunk: int) -> None:
+        """Compile what a launch of ``chunk``-token prompts can dispatch
+        and has not yet: the one-row prefill program of that bucket, the
+        several-row programs such a prompt can ride in (``_group_shapes``
+        whose bucket holds it) and, a row count, the landing (the first
+        tokens' sampler, the splice). Outputs are discarded, nothing is
+        donated: the live state is untouched. Called where a bucket's
+        program compiles anyway (``warm_admission``, a bucket's first
+        admission); a launch takes riders only into a program that has
+        been compiled here (``_take_riders``), so no launch compiles
+        where its one-row program would not have.
+
+        Before set_prompts the batch state (and its B dimension) doesn't
+        exist yet, so the rest is deferred to the next set_prompts — never
+        silently dropped (the compile would otherwise land inside the
+        serving window, the exact stall _splice_fn exists to kill)."""
+        live = getattr(self, "cache", None) is not None
+        if not live and chunk not in self._warm_pending:
+            self._warm_pending.append(chunk)
+        for rows, bucket in [(1, chunk)] + [
+                shape for shape in self._group_shapes() if chunk <= shape[1]]:
+            if (rows, bucket) in self._warmed:
+                continue
+            self._warmed.add((rows, bucket))
+            staging = self._staging_cache(rows)
+            logits, staging = self._admit_prefill(
+                self.params, jnp.zeros((rows, bucket), jnp.int32), staging,
+                jnp.int32(0), jnp.zeros((rows,), jnp.int32),
+            )
+            if live:
+                self._warm_landing(logits, staging)
+            else:
+                # the sampler needs no batch state: on the program's own
+                # logits, as a landing runs it
+                self._first_tokens(logits, [0] * rows, np.full(
+                    (rows, self.settings.repeat_last_n), -1, np.int32))
+            np.asarray(logits.ravel()[:1])  # synchronize
+
+    def _staging_cache(self, rows: int):
+        """A zeroed staging cache of ``rows`` rows."""
+        return init_cache_on_mesh(
+            self.config, self.plan.mesh, batch=rows, max_seq=self.max_seq,
             quant=self.kv_quant, batch_replicated=True,
         )
-        logits, staging = self._admit_prefill(
-            self.params, jnp.zeros((1, chunk), jnp.int32), staging,
-            jnp.int32(0), jnp.zeros((1,), jnp.int32),
-        )
-        # warm the rest of the admission-completion path too: the first
-        # token's sampler and the slot-traced state splice (compiled once,
-        # outputs discarded — no donation, the live state is untouched).
-        # Before set_prompts the batch state (and its B dimension) doesn't
-        # exist yet, so the splice warm is deferred to the next set_prompts
-        # — never silently dropped (the compile would otherwise land inside
-        # the serving window, the exact stall _splice_fn exists to kill).
-        n_hist = self.settings.repeat_last_n
-        tok = sampling.sample_token(
-            logits[0], jax.random.fold_in(self._base_key, 0),
-            jnp.full((n_hist,), -1, jnp.int32), self.settings,
-        )
-        if getattr(self, "cache", None) is not None:
-            self._warm_splice(staging)
-        else:
-            self._splice_warm_pending = True
-        np.asarray(np.asarray(tok).ravel()[:1])  # synchronize
 
-    def _warm_splice(self, staging=None) -> None:
-        """Compile the admission-completion programs against the live
+    def _warm_landing(self, logits=None, staging=None) -> None:
+        """Compile the admission-completion programs of a launch of as
+        many rows as ``staging`` has (one where None) against the live
         batch state's shapes (outputs discarded; live state untouched).
-        Slot: the slot-traced cache splice. Paged: the row gather/scatter
-        page programs plus the small sampler-state splice — warmed on
-        pool/staging COPIES (both programs donate their first argument)
-        with all-sink ids, so no live page is read or written."""
+        Slot: the first tokens' sampler, the slot-traced cache splice and,
+        with a prefix store, the program that takes one row of several.
+        Paged: the row gather/scatter page programs plus the small
+        sampler-state splice — warmed on pool/staging COPIES (both
+        programs donate their first argument) with all-sink ids, so no
+        live page is read or written."""
         if staging is None:
-            staging = init_cache_on_mesh(
-                self.config, self.plan.mesh, batch=1, max_seq=self.max_seq,
-                quant=self.kv_quant, batch_replicated=True,
-            )
+            staging = self._staging_cache(1)
+        rows = jax.tree.leaves(staging)[0].shape[1]
+        if rows in self._landing_warmed:
+            return
+        self._landing_warmed.add(rows)
+        if logits is None:
+            logits = jnp.zeros((rows, self.config.vocab_size), jnp.float32)
         n_hist = self.settings.repeat_last_n
+        hist = np.full((rows, n_hist), -1, np.int32)
+        keys, toks = self._first_tokens(logits, [0] * rows, hist)
+        # (the splice takes the tokens from the host, as the landing's do)
+        vec = jnp.asarray(np.asarray(toks) * 0)
         if self._paged:
             sink = jnp.zeros((self._ppp,), jnp.int32)
             pool_copy = jax.tree.map(lambda x: x.copy(), self.cache)
@@ -1937,19 +2055,17 @@ class BatchGenerator:
             out_row = self._row_gather(self.cache, sink)
             out = self._splice_small_fn()(
                 self._keys, self._history, self._hist_slot,
-                self._last_tokens, jax.random.fold_in(self._base_key, 0),
-                jnp.full((n_hist,), -1, jnp.int32), jnp.int32(0),
-                jnp.int32(0), jnp.int32(0),
+                self._last_tokens, keys, jnp.asarray(hist), vec, vec, vec,
             )
             jax.block_until_ready((out_pool, out_row, out))
             return
         out = self._splice_fn()(
             self.cache, staging, self._keys, self._history,
-            self._hist_slot, self._last_tokens,
-            jax.random.fold_in(self._base_key, 0),
-            jnp.full((n_hist,), -1, jnp.int32), jnp.int32(0),
-            jnp.int32(0), jnp.int32(0),
+            self._hist_slot, self._last_tokens, keys, jnp.asarray(hist),
+            vec, vec, vec,
         )
+        if rows > 1 and self._prefix_entries > 0:
+            out = (out, self._row_of(staging, jnp.int32(0)))
         jax.block_until_ready(out)
 
     def _admission_due(self) -> bool:
@@ -1975,7 +2091,8 @@ class BatchGenerator:
     def _admission_tick(self, wait: bool = True) -> None:
         """Advance the admission plane by one tick: *land* a launched
         admission whose rows have all gone out, *launch* the next queued
-        arrival if a slot is free, or dispatch the in-flight admission's
+        arrival if a slot is free (and with it every arrival that can
+        ride: ``_start_arrival``), or dispatch the in-flight admission's
         next chunk. KV-page imports (cake_tpu/disagg) ride the same FIFO:
         a begin lands the pages in the pool (deferring FIFO-fair under
         pool pressure exactly like a prompt admission), an attach
@@ -2019,47 +2136,57 @@ class BatchGenerator:
                     jnp.asarray(st["tokens"][:, pos: pos + chunk]),
                     st["cache"],
                     jnp.int32(base + pos),
-                    # the in-chunk index of the prompt's last token; in an
-                    # earlier chunk the chunk's own last (every token of
-                    # it is true: what a recurrent state may be advanced
-                    # by)
-                    jnp.asarray(
-                        [min(len(st["ids"]) - 1 - base - pos, chunk - 1)],
-                        jnp.int32,
-                    ),
+                    # a row: the in-chunk index of its prompt's last
+                    # token; in an earlier chunk the chunk's own last
+                    # (every token of it is true: what a recurrent state
+                    # may be advanced by)
+                    jnp.asarray(np.asarray(
+                        [min(len(m.ids) - 1 - base - pos, chunk - 1)
+                         for m in st["rows"]], np.int32)),
                 )
                 self._note_enqueued()
-                stamps = st["stamps"]
-                if stamps is not None and len(stamps) == 1:
+                members = st["members"]
+                if members[0].stamps is not None and pos == 0:
                     # launched: the first dispatch has returned, from
-                    # here on the device has the prompt (a chunked
+                    # here on the device has the prompts (a chunked
                     # admission's later chunks go under rows_wait)
-                    stamps.append(time.perf_counter())
+                    launched = time.perf_counter()
+                    for m in members:
+                        m.stamps.append(launched)
+                    _ADMIT_LAUNCHES.inc()
                 if not final:
                     # sync: busy_s must include compute (the last chunk's
                     # is waited for where it lands)
                     np.asarray(logits.ravel()[:1])
             self._n_admit_dispatches += 1
-            self._count_admit_rows(chunk)
+            rows = len(st["rows"])
+            self._count_admit_rows(rows * chunk)
             st["pos"] = pos + chunk
             if not final:
                 self._admit_dispatched(t0, chunk, base + pos)
                 return
             st["logits"], st["booking"] = logits, (t0, chunk, base + pos)
+            if rows == 1 and st["tokens"].shape[1] == chunk:
+                # a whole prompt a dispatch: where this bucket's program
+                # has just compiled, so do the several-row programs it
+                # can ride in, behind this one on the device
+                self._warmed.add((1, chunk))
+                self._warm_bucket(chunk)
         if not (wait and self._rows_wait()):
             with self._prof.phase("admit_land"):
                 self._finish_admission()
 
-    def _count_admit_rows(self, chunk: int) -> None:
-        """An expert model's admission dispatch of ``chunk`` rows (the
-        bucket's, one staging row): add them to ``moe.admit_rows`` and,
-        where the expert block recorded the sorted form when this
-        bucket's program was traced, to ``moe.admit_rows_sorted``."""
+    def _count_admit_rows(self, rows: int) -> None:
+        """An expert model's admission dispatch of ``rows`` rows (the
+        bucket's, times the launch's staging rows): add them to
+        ``moe.admit_rows`` and, where the expert block recorded the
+        sorted form when a call of that many rows was traced, to
+        ``moe.admit_rows_sorted``."""
         if not any(ffn == "moe" for _, ffn in self.config.layer_kinds):
             return
-        _MOE_ADMIT_ROWS.inc(chunk)
-        if moe_form_traced(chunk) == "sorted":
-            _MOE_ADMIT_SORTED.inc(chunk)
+        _MOE_ADMIT_ROWS.inc(rows)
+        if moe_form_traced(rows) == "sorted":
+            _MOE_ADMIT_SORTED.inc(rows)
 
     def _admit_dispatched(self, t0: float, chunk: int, pos: int) -> None:
         """Book one admission chunk whose compute has been waited for."""
@@ -2074,7 +2201,11 @@ class BatchGenerator:
         """Take the head of the arrival FIFO: an import or an attach runs
         whole and leaves nothing staged (False); a prompt with a free
         slot is *launched*: its staging row built, ``self._staging`` set
-        (True)."""
+        (True). A plain prompt from position 0 takes with it the plain
+        prompts that wait behind it (``_take_riders``): one staging cache
+        of a row each, one prefill program over all of them, so that the
+        weights are read once for the launch and not once an arrival. A
+        launch of one is the same program over one row."""
         if not self._arrivals:
             return False
         if not any(s.active and not s.done for s in self.streams):
@@ -2092,9 +2223,10 @@ class BatchGenerator:
             elif self._free_slot() is not None:
                 self._import_attach_tick()
             return False
-        slot = self._free_slot()
-        if slot is None:
+        slots = list(self._free_slots())
+        if not slots:
             return False
+        slot = slots.pop(0)
         if self._paged:
             # claim point: the slot's previous stream (retired by ANY
             # path, including a caller writing s.done directly) frees
@@ -2149,8 +2281,21 @@ class BatchGenerator:
                 self._arrivals.insert(0, (ids, sid, guide, None, enqueued))
                 return False
             self._admit_deferred = False
-        tokens = np.zeros((1, t_pad), np.int32)
-        tokens[0, :rem] = ids[base:]
+        stamps = None if enqueued is None else [enqueued]
+        members, n_rows = [_Staged(ids, sid, slot, guide, stamps)], 1
+        if not base and t_pad == chunk and guide is None and stamps:
+            riders, shape = self._take_riders(members[0], slots)
+            if riders:
+                members += riders
+                n_rows, chunk = shape
+                t_pad = chunk
+        # the program's rows: the members, then the first again up to the
+        # program's row count (the same values into the same slot:
+        # nothing tells its rows apart)
+        rows = members + members[:1] * (n_rows - len(members))
+        tokens = np.zeros((len(rows), t_pad), np.int32)
+        for i, m in enumerate(rows):
+            tokens[i, :len(m.ids) - base] = m.ids[base:]
         if base:
             self._prefix_hits += 1
             if self._paged:
@@ -2167,93 +2312,174 @@ class BatchGenerator:
                 # future hits
                 cache = jax.tree.map(lambda x: x.copy(), row)
         else:
-            cache = init_cache_on_mesh(
-                self.config, self.plan.mesh, batch=1,
-                max_seq=self.max_seq, quant=self.kv_quant,
-                batch_replicated=True,
-            )
+            cache = self._staging_cache(len(rows))
             if self.config.recurrent:
                 # the zeroed row IS the reset: the splice copies its
                 # state and convolution tail over the slot's
-                _STATE_RESETS[self.config.recurrent_mixer].inc()
+                _STATE_RESETS[self.config.recurrent_mixer].inc(len(members))
         self._staging = {
-            "ids": ids, "sid": sid, "slot": slot,
+            # the arrivals this launch admits, in FIFO order (finish()
+            # takes a cancelled one out), and the program's rows
+            "members": members, "rows": rows,
             "tokens": tokens, "pos": 0, "chunk": chunk, "base": base,
-            "cache": cache, "guide": guide, "shared": shared_pages,
-            # the admission's stamps (_ADMIT_STAGES), carried on from the
-            # arrival; None where nothing is timed (admit())
-            "stamps": None if enqueued is None else [enqueued],
+            "cache": cache, "shared": shared_pages,
         }
         return True
 
+    def _take_riders(self, head: _Staged, slots: list[int]):
+        """Pop the arrivals that ride with ``head``'s launch, and say in
+        which program: ``(riders, (rows, bucket))``, or ``([], None)``. Of
+        the run of plain prompts right behind the head (FIFO: nobody is
+        overtaken), a free slot each, as many as a compiled several-row
+        program holds (``_group_shape``). The run ends at what has to be
+        launched alone: an import or an attach (they edit the pool, not a
+        staging row), a synchronous ``admit()`` (its caller takes the
+        landing's row for its own), a guide (its mask goes with one first
+        token), a prompt of more than one dispatch (``admit_chunk``: its
+        chunks interleave with decode), a prompt that starts from a stored
+        prefix or would from a rider's ahead of it (it prefills its
+        remainder alone: less work than riding). The paged layout prices
+        and writes pages an arrival: no riders."""
+        most = max((r for r, _ in self._group_shapes()), default=1)
+        run = [head]
+        for ids, sid, guide, kind, enqueued in self._arrivals[
+                :min(len(slots), most - 1)]:
+            if kind is not None or guide is not None or enqueued is None:
+                break
+            if len(ids) > self._admission_chunk_for(len(ids)):
+                break
+            if self._match_prefix(ids)[0] or any(
+                    self._shares_prefix(ids, m) for m in run):
+                break
+            run.append(_Staged(ids, sid, -1, None, [enqueued]))
+        own = [self._admission_chunk_for(len(m.ids)) for m in run]
+        for n in range(len(run), 1, -1):
+            shape = _group_shape(own[:n])
+            if shape in self._warmed:
+                riders = run[1:n]
+                for m in riders:
+                    self._arrivals.pop(0)
+                    m.slot = slots.pop(0)
+                return riders, shape
+        return [], None
+
+    def _stored_prefix_len(self, ids: list[int]) -> int:
+        """How much of a landed prompt the prefix store keeps: up to a
+        ``prefix_block`` boundary short of its last token (0: nothing)."""
+        base = (len(ids) - 1) // self._prefix_block * self._prefix_block
+        if self._prefix_entries <= 0 or base < max(1, self._prefix_share_min):
+            return 0
+        return base
+
+    def _shares_prefix(self, ids: list[int], ahead: _Staged) -> bool:
+        """Whether a prompt would start from what ``ahead`` leaves in the
+        prefix store once it has landed."""
+        base = self._stored_prefix_len(ahead.ids)
+        return 0 < base < len(ids) and ids[:base] == ahead.ids[:base]
+
     def _splice_fn(self):
-        """The admission splice as ONE jitted program with the slot index
-        TRACED: splicing with host-side ``.at[:, slot].set`` bakes the slot
-        as a constant, so every distinct slot compiled a fresh cache-sized
-        scatter (plus four small-state scatters) *inside the serving
-        window*. One traced program serves every slot and is warmed by
-        ``warm_admission``."""
+        """The admission splice as ONE jitted program with the slot
+        indices TRACED: splicing with host-side ``.at[:, slot].set`` bakes
+        the slot as a constant, so every distinct slot compiled a fresh
+        cache-sized scatter (plus four small-state scatters) *inside the
+        serving window*. One traced program a row count serves every slot
+        and is warmed with the bucket (``_warm_bucket``). Every staged row
+        goes to ``slot[i]``: a launch's repeated first row to the same
+        slot with the same values, a cancelled arrival's to the free slot
+        it had been given (a slot without a stream is overwritten by the
+        next admission)."""
         if self.__splice is None:
             def splice(cache, row, keys, history, hist_slot, last, key,
                        hist_row, hist_used, tok, slot):
-                upd1 = lambda buf, v: jax.lax.dynamic_update_index_in_dim(
-                    buf, v, slot, 0)
-                cache = jax.tree.map(
-                    lambda c, r: jax.lax.dynamic_update_index_in_dim(
-                        c, r[:, 0], slot, 1),
-                    cache, row,
-                )
-                return (
-                    cache,
-                    upd1(keys, key),
-                    upd1(history, hist_row),
-                    upd1(hist_slot, hist_used),
-                    upd1(last, tok),
-                )
+                for i in range(slot.shape[0]):
+                    cache = jax.tree.map(
+                        lambda c, r: jax.lax.dynamic_update_index_in_dim(
+                            c, r[:, i], slot[i], 1),
+                        cache, row,
+                    )
+                return (cache,) + _splice_rows(
+                    (keys, history, hist_slot, last),
+                    (key, hist_row, hist_used, tok), slot)
 
-            self.__splice = jax.jit(splice)
+            self.__splice = jax.jit(
+                splice, out_shardings=(None,) + self._state_shardings)
         return self.__splice
 
+    @property
+    def _row_of(self):
+        """Row ``i`` (traced) of a several-row staging cache as a cache of
+        one row: what the prefix store keeps of a launch. The program's
+        name keeps it among the admission's programs in a trace."""
+        if self.__row_of is None:
+            def splice(cache, i):
+                return jax.tree.map(
+                    lambda x: jax.lax.dynamic_slice_in_dim(x, i, 1, 1), cache)
+
+            self.__row_of = jax.jit(splice)
+        return self.__row_of
+
+    def _first_tokens(self, logits, sids: list[int], hist_rows: np.ndarray,
+                      mask=None):
+        """Each staged row's stream key and its first token, sampled from
+        the row's own logits under that key and the row's own history:
+        ``(keys [R, 2], tokens [R])``, un-fetched. One program a row count
+        (a landing's host time is one dispatch: where the prefill is
+        short, every live stream waits for what the host does here)."""
+        if self.__first_tokens is None:
+            def first(logits, sids, hist, mask):
+                keys = jax.vmap(
+                    lambda sid: jax.random.fold_in(self._base_key, sid))(sids)
+                at0 = jax.vmap(lambda key: jax.random.fold_in(key, 0))(keys)
+                return keys, sampling.sample_tokens_keyed(
+                    logits, at0, hist, self.settings, mask=mask)
+
+            self.__first_tokens = jax.jit(first)
+        return self.__first_tokens(
+            logits, np.asarray(sids, np.uint32), hist_rows, mask)
+
     def _finish_admission(self) -> None:
-        """Land the launched admission: sample the first token from its
-        last chunk's logits (the one wait for the device), splice the
-        staged row into its slot, record the token and queue its row."""
+        """Land the launched admission: sample every staged row's first
+        token from the last chunk's logits (the one wait for the device),
+        splice the staged rows into their slots with one program, record
+        the members' tokens and queue them as one row."""
         land_begin = time.perf_counter()
         st, self._staging = self._staging, None
-        slot, ids, stream_id = st["slot"], st["ids"], st["sid"]
-        guide = st.get("guide")
+        members, rows = st["members"], st["rows"]
         logits = st["logits"]
         # An in-flight block (dispatched between a chunked admission's
         # ticks) belongs to the pre-admission state: fetch and record its
-        # rows before the slot's column changes meaning, so streaming
+        # rows before the slots' columns change meaning, so streaming
         # step() consumers still receive every Token.
         self._drain_buffered_rows()
 
-        # the slot's previous stream is gone; its guide (if any) with it,
+        # a slot's previous stream is gone; its guide (if any) with it,
         # and (only under a synchronous admit(), which does not wait for
         # the rows to go out) whatever of it was still to be handed out:
         # it must not reach the new stream
-        for row in self._pending_rows:
-            row[slot] = None
-        self._drop_guide(slot)
-        if guide is not None:
-            self._attach_guide(slot, guide)
-        key = jax.random.fold_in(self._base_key, stream_id)
+        for m in members:
+            for row in self._pending_rows:
+                row[m.slot] = None
+            self._drop_guide(m.slot)
+            if m.guide is not None:
+                self._attach_guide(m.slot, m.guide)
+        guide = members[0].guide  # a guided arrival is launched alone
         n_hist = self.settings.repeat_last_n
-        hist_row = np.full((n_hist,), -1, np.int32)
-        tail = ids[-n_hist:]
-        hist_row[: len(tail)] = tail
-        tok = sampling.sample_token(
-            logits[0], jax.random.fold_in(key, 0), jnp.asarray(hist_row),
-            self.settings,
-            mask=jnp.asarray(guide.mask_bool()) if guide is not None
+        hist = np.full((len(rows), n_hist), -1, np.int32)
+        used = np.zeros((len(rows),), np.int32)
+        for i, m in enumerate(rows):
+            tail = m.ids[-n_hist:]
+            hist[i, : len(tail)] = tail
+            used[i] = len(tail)
+        keys, toks = self._first_tokens(
+            logits, [m.sid for m in rows], hist,
+            mask=jnp.asarray(guide.mask_bool())[None] if guide is not None
             else None,
         )
         # the one wait for the device: the sampling above was dispatched
         # while the prefill still ran (it was launched before the rows
         # that have just gone out), so its host time hides behind it
         t_fetch = time.perf_counter()
-        tok_id = int(tok)
+        tok_ids = self._host(toks)
         landed = time.perf_counter()
         self.step_fetch_ms = max(self.step_fetch_ms,
                                  (landed - t_fetch) * 1e3)
@@ -2261,12 +2487,15 @@ class BatchGenerator:
         # period that holds it is not clear
         self._period_admitted = True
         self._admit_dispatched(*st["booking"])
-        hist_row[len(tail) % n_hist] = tok_id
-        lp_row = None
+        hist[np.arange(len(rows)), used % n_hist] = tok_ids
+        lp_rows = None
         if self.logprobs_k:
-            lpv0, lpi0 = sampling.topk_logprobs(logits[0], self.logprobs_k)
-            lp_row = [(int(i), float(v))
-                      for v, i in zip(np.asarray(lpv0), np.asarray(lpi0))]
+            lpv0, lpi0 = sampling.topk_logprobs(logits, self.logprobs_k)
+            lp_rows = [[(int(i), float(v)) for v, i in zip(vs, ids)]
+                       for vs, ids in zip(np.asarray(lpv0), np.asarray(lpi0))]
+        vectors = (keys, jnp.asarray(hist), jnp.asarray(used + 1),
+                   jnp.asarray(tok_ids.astype(np.int32)),
+                   jnp.asarray(np.asarray([m.slot for m in rows], np.int32)))
 
         if self._paged:
             # the paged "splice": scatter the staged row's NEW pages into
@@ -2274,6 +2503,7 @@ class BatchGenerator:
             # id-vector slots stay sink, so refcounted pages are never
             # rewritten) and install the table. Only the small sampler
             # state splices as tensors; the KV hand-off is a page write.
+            ids, slot = members[0].ids, members[0].slot
             ps = self._page_size
             shared = st.get("shared", [])
             n_shared = len(shared)
@@ -2303,28 +2533,64 @@ class BatchGenerator:
             (self._keys, self._history, self._hist_slot,
              self._last_tokens) = self._splice_small_fn()(
                 self._keys, self._history, self._hist_slot,
-                self._last_tokens, key, jnp.asarray(hist_row),
-                jnp.int32(len(tail) + 1), jnp.int32(tok_id),
-                jnp.int32(slot),
+                self._last_tokens, *vectors,
             )
         else:
             (self.cache, self._keys, self._history, self._hist_slot,
              self._last_tokens) = self._splice_fn()(
                 self.cache, st["cache"], self._keys, self._history,
-                self._hist_slot, self._last_tokens, key,
-                jnp.asarray(hist_row), jnp.int32(len(tail) + 1),
-                jnp.int32(tok_id), jnp.int32(slot),
+                self._hist_slot, self._last_tokens, *vectors,
             )
-        if st["stamps"] is not None:
-            self._observe_admission(stream_id, st["stamps"] + [
-                land_begin, landed, time.perf_counter()])
+        spliced = time.perf_counter()
         self._pos = np.asarray(self._pos).copy()
-        self._pos[slot] = len(ids)
         self._index = np.asarray(self._index).copy()
-        self._index[slot] = 1
+        row: list[Token | None] = [None] * len(self.streams)
+        for m in members:
+            i = rows.index(m)
+            if m.stamps is not None:
+                self._observe_admission(
+                    m.sid, m.stamps + [land_begin, landed, spliced])
+            row[m.slot] = self._install(m, int(tok_ids[i]),
+                                        lp_rows[i] if lp_rows else None)
+        self._pending_rows.append(row)
 
+        # Feed the store: an arrival's prefix becomes reusable by future
+        # arrivals with the same opening. Paged: the stream's FULL prompt
+        # pages register in the prefix tree (zero copies — the tree just
+        # takes references; a later same-prefix arrival shares the
+        # physical pages, which is the copy-on-write fan-out). Slot: the
+        # staging row is retained under the prefix truncated to a
+        # prefix_block boundary (the splice above copied values out, so
+        # retaining it costs no extra dispatch; of a launch of several
+        # rows, the rows the store has room for are taken out: the last).
+        if self._paged:
+            ids, slot = members[0].ids, members[0].slot
+            n_full = len(ids) // self._page_size
+            if (self._prefix_entries > 0 and n_full
+                    and n_full * self._page_size
+                    >= max(1, self._prefix_share_min)):
+                self._prefix_tree.insert(ids, self._tables[slot][:n_full])
+            if self.streams[slot].done:
+                # first sampled token ended the stream: free its claims
+                # now (AFTER the tree store above took its references)
+                self._release_pages(slot)
+            return
+        kept = [(m, n) for m in members
+                if (n := self._stored_prefix_len(m.ids))]
+        for m, n in kept[-self._prefix_entries:]:
+            self._store_prefix(
+                m.ids[:n],
+                st["cache"] if len(rows) == 1 else self._row_of(
+                    st["cache"], jnp.int32(rows.index(m))))
+
+    def _install(self, m: _Staged, tok_id: int, lp_row) -> Token:
+        """A landed arrival becomes its slot's stream, one token long;
+        returns that token for the landing's row."""
+        slot, ids = m.slot, m.ids
+        self._pos[slot] = len(ids)
+        self._index[slot] = 1
         s = _Stream(
-            stream_id=stream_id, prompt=ids,
+            stream_id=m.sid, prompt=ids,
             detok=TokenOutputStream(self.tokenizer) if self.tokenizer else None,
         )
         self.streams[slot] = s
@@ -2341,34 +2607,8 @@ class BatchGenerator:
         if s.done:
             s.end_reason = "eos" if is_eos else "length"
         self._advance_guide(slot, s, tok_id)
-        row: list[Token | None] = [None] * len(self.streams)
-        row[slot] = Token(id=tok_id, text=None, is_end_of_stream=s.done,
-                          logprobs=lp_row)
-        self._pending_rows.append(row)
-
-        # Feed the store: this arrival's prefix becomes reusable by future
-        # arrivals with the same opening. Paged: the stream's FULL prompt
-        # pages register in the prefix tree (zero copies — the tree just
-        # takes references; a later same-prefix arrival shares the
-        # physical pages, which is the copy-on-write fan-out). Slot: the
-        # staging row is retained under the prefix truncated to a
-        # prefix_block boundary (the splice above copied values out, so
-        # retaining it costs no extra dispatch).
-        if self._paged:
-            n_full = len(ids) // self._page_size
-            if (self._prefix_entries > 0 and n_full
-                    and n_full * self._page_size
-                    >= max(1, self._prefix_share_min)):
-                self._prefix_tree.insert(ids, self._tables[slot][:n_full])
-        else:
-            base_new = ((len(ids) - 1) // self._prefix_block) \
-                * self._prefix_block
-            if base_new >= max(1, self._prefix_share_min):
-                self._store_prefix(ids[:base_new], st["cache"])
-        if s.done and self._paged:
-            # first sampled token ended the stream: free its claims now
-            # (AFTER the tree store above took its references)
-            self._release_pages(slot)
+        return Token(id=tok_id, text=None, is_end_of_stream=s.done,
+                     logprobs=lp_row)
 
     def _observe_admission(self, stream_id: int, stamps: list) -> None:
         """A prompt admission has landed and its splice is enqueued:
@@ -2435,11 +2675,18 @@ class BatchGenerator:
             # loop over the slot's page list, no cache tensor touched
             self._release_pages(i)
             return True
-        if self._staging is not None and self._staging["sid"] == stream_id:
-            if self._paged:
-                for pid in self._staging.get("shared", []):
-                    self._pagepool.unref(pid)
-            self._staging = None  # staged KV row is dropped with it
+        st = self._staging
+        for m in st["members"] if st is not None else ():
+            if m.sid != stream_id:
+                continue
+            # its staged row stays among the program's and is spliced
+            # into the free slot it was given; no stream comes of it
+            st["members"].remove(m)
+            if not st["members"]:
+                if self._paged:
+                    for pid in st.get("shared", []):
+                        self._pagepool.unref(pid)
+                self._staging = None  # the staged rows are dropped
             return True
         n0 = len(self._arrivals)
         # a cancelled resume drops its queued attach AND aborts the
@@ -2468,7 +2715,7 @@ class BatchGenerator:
         # instead of busy-looping on a no-op tick.
         while (any(a[0] is ids for a in self._arrivals)
                or (self._staging is not None
-                   and self._staging["ids"] is ids)):
+                   and self._staging["members"][0].ids is ids)):
             if self._staging is None and self._free_slot() is None:
                 self._arrivals = [a for a in self._arrivals
                                   if a[0] is not ids]

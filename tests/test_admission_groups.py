@@ -1,0 +1,627 @@
+"""A launch admits every arrival that waits, in one prefill program
+(PR 37): ``BatchGenerator._start_arrival`` takes the head of the FIFO and
+the plain prompts behind it, a free slot and a staging row each;
+``_finish_admission`` samples, splices and installs them together.
+
+What is held here: every member of a launch gets the tokens, the
+first-token logits and (where layers hold one) the recurrent state of its
+admission alone, in each family the benchmark serves; what may not ride
+splits the run and nobody is overtaken; the counters count members and
+launches; a launch of a bucket that has been met compiles nothing.
+
+Tolerances. Everything is float32 on the CPU. A row of a several-row
+program differs from the same row alone in the order of sums only (XLA
+blocks a ``[2, C]`` product otherwise than a ``[1, C]`` one; an expert
+block sums a row's experts in the order its call's rows select): measured
+0 to 6e-6 on logits of magnitude ~3. ``TIGHT`` is 1e-4, as in the
+families' own tests against their references.
+"""
+
+from __future__ import annotations
+
+import jax
+import numpy as np
+import pytest
+
+from cake_tpu.constrain import Guide, build_token_dfa
+from cake_tpu.models import llama
+from cake_tpu.models.config import (tiny, tiny_jamba, tiny_kda_hybrid,
+                                    tiny_mla_moe, tiny_moe)
+from cake_tpu.obs import catalog
+from cake_tpu.obs import metrics as obs_metrics
+from cake_tpu.ops.sampling import SamplerSettings
+from cake_tpu.runtime import batch_generator as bg
+from cake_tpu.runtime.batch_generator import BatchGenerator
+
+TIGHT = 1e-4
+GREEDY = dict(temperature=0.0, repeat_penalty=1.1)
+FAMILIES = {"gqa": tiny, "mixtral": tiny_moe, "mla_moe": tiny_mla_moe,
+            "kda_hybrid": tiny_kda_hybrid, "jamba": tiny_jamba}
+_RNG = np.random.default_rng(37)
+# one prompt per bucket (16, 32, 64), and a fourth of the first's
+PROMPTS = [[int(t) for t in _RNG.integers(3, 200, n)] for n in (9, 20, 40, 12)]
+STAGE_HISTS = tuple(f"engine.admit_{s}_ms" for s in
+                    ("launch_wait", "rows_wait", "land", "to_splice"))
+COUNTED = STAGE_HISTS + ("engine.admissions_landed", "engine.admit_launches",
+                         "moe.admit_rows", "prof.compiles")
+
+
+SHIPPED = bg.GROUP_SHAPES
+
+
+@pytest.fixture(autouse=True)
+def every_waiting_arrival_rides(monkeypatch):
+    """The cases of the mechanism run with a program of two rows at the
+    second bucket and one of four at the third: whoever waits rides (a
+    prompt of each bucket in one launch, three as four). Which programs
+    there are (``GROUP_SHAPES``) has its own cases at the end."""
+    monkeypatch.setattr(bg, "GROUP_SHAPES", ((2, 32), (4, 64)))
+
+
+def _counts() -> dict:
+    snap = obs_metrics.registry().snapshot()
+    return {n: snap.get(n, {}).get("count", snap.get(n, {}).get("value", 0))
+            for n in COUNTED}
+
+
+def _grown(before: dict) -> dict:
+    return {n: v - before[n] for n, v in _counts().items()}
+
+
+def _engine(cfg, params, slots=4, live=0, warm=True, **kw) -> BatchGenerator:
+    """``slots`` slots of which the first ``live`` hold a running stream
+    (ids 0..) and the others are free; ``warm``: the first bucket's
+    program and the several-row ones are compiled, as after a server's
+    warm-up."""
+    kw.setdefault("block_size", 4)
+    g = BatchGenerator(cfg, params, settings=SamplerSettings(**GREEDY), **kw)
+    g.set_prompts([[4, 4, 4 + i] for i in range(slots)])
+    if warm:
+        g.warm_admission(9)
+    g.step()
+    for s in g.streams[live:]:
+        g.finish(s.stream_id)
+    return g
+
+
+def _watch(g) -> dict:
+    """Record, a landing: each member's first-token logits and what the
+    splice left in its slot (every cache leaf's row)."""
+    seen: dict = {}
+    first_tokens, finish = g._first_tokens, g._finish_admission
+
+    def spy_first(logits, sids, hist, mask=None):
+        seen["logits"] = (np.asarray(logits), list(sids))
+        return first_tokens(logits, sids, hist, mask=mask)
+
+    def spy_finish():
+        members = list(g._staging["members"])
+        finish()
+        logits, sids = seen.pop("logits")
+        for m in members:
+            seen[m.sid] = dict(
+                slot=m.slot, logits=logits[sids.index(m.sid)],
+                cache=jax.tree.map(lambda x: np.asarray(x[:, m.slot]),
+                                   g.cache))
+
+    g._first_tokens, g._finish_admission = spy_first, spy_finish
+    return seen
+
+
+def _run(g, arrivals, together: bool, steps=10) -> dict:
+    """Admit ``[(prompt, sid), ...]`` all at once or each after the one
+    before has landed, then decode on; ``{sid: its record}`` with the
+    stream's first ``steps`` tokens."""
+    seen = _watch(g)
+    if together:
+        for prompt, sid in arrivals:
+            g.enqueue(list(prompt), sid)
+    for prompt, sid in arrivals:
+        if not together:
+            g.enqueue(list(prompt), sid)
+        while g.pending_admissions():
+            g.step()
+    while any(len(s.generated) < steps and not s.done for s in g.streams
+              if s.stream_id in seen):
+        g.step()
+    for s in g.streams:
+        if s.stream_id in seen:
+            seen[s.stream_id]["tokens"] = s.generated[:steps]
+    return seen
+
+
+@pytest.fixture(scope="module")
+def family():
+    """``family(name) -> (cfg, params, each prompt's record admitted
+    alone)``, made once a family."""
+    made: dict = {}
+
+    def get(name):
+        if name not in made:
+            cfg = FAMILIES[name](max_seq_len=128, eos_token_id=-1)
+            params = llama.init_params(cfg, jax.random.PRNGKey(3))
+            alone = _run(_engine(cfg, params),
+                         [(p, 10 + i) for i, p in enumerate(PROMPTS)], False)
+            made[name] = cfg, params, alone
+        return made[name]
+
+    return get
+
+
+# -- every member gets what its admission alone gives it ---------------------
+
+@pytest.mark.parametrize("n", [2, 3])
+@pytest.mark.parametrize("name", list(FAMILIES))
+def test_each_member_of_a_launch_equals_its_admission_alone(family, name, n):
+    cfg, params, alone = family(name)
+    g = _engine(cfg, params)
+    before = _counts()
+    got = _run(g, [(p, 10 + i) for i, p in enumerate(PROMPTS[:n])], True)
+    grown = _grown(before)
+    assert grown["engine.admit_launches"] == 1, grown
+    assert grown["engine.admissions_landed"] == n
+    rows = 2 if n == 2 else 4  # three run as four, the first row twice
+    assert (rows, 64 if n == 3 else 32) in g._warmed
+    if any(ffn == "moe" for _, ffn in cfg.layer_kinds):
+        assert grown["moe.admit_rows"] == rows * (64 if n == 3 else 32)
+    for sid in range(10, 10 + n):
+        want, have = alone[sid], got[sid]
+        assert have["tokens"] == want["tokens"], (name, sid)
+        np.testing.assert_allclose(have["logits"], want["logits"],
+                                   atol=TIGHT, rtol=TIGHT)
+        t = len(PROMPTS[sid - 10])
+        kv = lambda c: [np.asarray(x)[..., :t, :] for x in
+                        jax.tree.leaves((c.k, c.v))]
+        for a, b in zip(kv(have["cache"]), kv(want["cache"])):
+            np.testing.assert_allclose(a, b, atol=TIGHT, rtol=TIGHT)
+        if cfg.recurrent:
+            # a short member's state and convolution tail: the bucket's
+            # padding (its own 16 or 32 against the launch's 64) and the
+            # repeated row have touched nothing
+            for leaf in ("state", "conv"):
+                a = getattr(have["cache"], leaf)
+                b = getattr(want["cache"], leaf)
+                assert np.abs(b).max() > 0
+                np.testing.assert_allclose(a, b, atol=TIGHT, rtol=TIGHT)
+
+
+@pytest.fixture(scope="module")
+def dense():
+    cfg = tiny(max_seq_len=128, eos_token_id=-1)
+    return cfg, llama.init_params(cfg, jax.random.PRNGKey(3))
+
+
+def test_sampled_members_draw_under_their_own_keys(dense):
+    """Temperature 0.8: a stream's tokens depend on (seed, stream id,
+    prompt) alone, whichever launch brought it in."""
+    cfg, params = dense
+    outs = []
+    for together in (False, True):
+        g = BatchGenerator(cfg, params, block_size=4, settings=SamplerSettings(
+            temperature=0.8, top_k=40, seed=7))
+        g.set_prompts([[4, 4, 4]] * 4)
+        g.warm_admission(9)
+        g.step()
+        for s in g.streams:
+            g.finish(s.stream_id)
+        got = _run(g, [(p, 10 + i) for i, p in enumerate(PROMPTS[:3])],
+                   together)
+        outs.append({sid: got[sid]["tokens"] for sid in (10, 11, 12)})
+    assert outs[0] == outs[1]
+
+
+# -- first tokens reach their own streams ------------------------------------
+
+def test_a_member_whose_first_token_is_eos_ends_alone(dense, family):
+    _, params, alone = family("gqa")
+    eos = alone[11]["tokens"][0]
+    assert eos not in (alone[10]["tokens"][0], alone[12]["tokens"][0])
+    cfg = tiny(max_seq_len=128, eos_token_id=eos)
+    g = _engine(cfg, params)
+    seen = _watch(g)
+    for i, p in enumerate(PROMPTS[:3]):
+        g.enqueue(list(p), 10 + i)
+    rows = []
+    while g.pending_admissions():
+        rows.append(g.step())
+    landing = next(r for r in rows if any(t is not None for t in r))
+    for sid in (10, 11, 12):
+        tok = landing[seen[sid]["slot"]]
+        assert tok.id == alone[sid]["tokens"][0]
+        assert tok.is_end_of_stream == (sid == 11)
+    ended = g.streams[seen[11]["slot"]]
+    assert ended.done and ended.end_reason == "eos"
+    assert g._free_slot() is not None  # its slot is free again at once
+    for _ in range(8):
+        g.step()
+    for sid in (10, 12):
+        s = g.streams[seen[sid]["slot"]]
+        # (under the changed EOS id only the first tokens are comparable
+        # beyond doubt: the others until one of them is the EOS)
+        n = len(s.generated)
+        assert n > 1 and s.generated == alone[sid]["tokens"][:n]
+
+
+def test_finish_cancels_one_staged_member_and_the_others_land(dense, family):
+    cfg, params, alone = family("gqa")
+    g = _engine(cfg, params, slots=6, live=2)
+    for _ in range(3):
+        g.step()  # a block has landed: rows wait to be handed out
+    assert g._pending_rows
+    before = _counts()
+    for i, p in enumerate(PROMPTS[:3]):
+        g.enqueue(list(p), 10 + i)
+    g.step()  # launched behind the rows, not landed
+    st = g._staging
+    assert st is not None and "logits" in st
+    assert [m.sid for m in st["members"]] == [10, 11, 12]
+    assert g.pending_admissions() == 3
+    free = [m.slot for m in st["members"]]
+    assert g.finish(11) is True
+    assert g.pending_admissions() == 2
+    assert g.finish(11) is False  # gone
+    while g.pending_admissions():
+        g.step()
+    for _ in range(8):
+        g.step()
+    grown = _grown(before)
+    assert grown["engine.admit_launches"] == 1
+    assert grown["engine.admissions_landed"] == 2
+    for hist in STAGE_HISTS:
+        assert grown[hist] == 2
+    by_sid = {s.stream_id: (i, s) for i, s in enumerate(g.streams)}
+    assert 11 not in by_sid
+    for sid, slot in ((10, free[0]), (12, free[2])):
+        i, s = by_sid[sid]
+        assert i == slot
+        n = min(len(s.generated), 10)
+        assert n > 2 and s.generated[:n] == alone[sid]["tokens"][:n]
+    # the cancelled member's slot serves the next arrival
+    assert g._free_slot() == free[1]
+    g.enqueue(list(PROMPTS[1]), 21)
+    while g.pending_admissions():
+        g.step()
+    for _ in range(6):
+        g.step()
+    s = g.streams[free[1]]
+    assert s.stream_id == 21
+    assert s.generated[:6] == alone[11]["tokens"][:6]
+    # the two streams that were live all along never noticed
+    assert [g.streams[i].stream_id for i in (0, 1)] == [0, 1]
+
+
+def test_finish_of_every_staged_member_drops_the_launch(dense):
+    cfg, params = dense
+    g = _engine(cfg, params, slots=4, live=1)
+    for _ in range(3):
+        g.step()
+    g.enqueue(list(PROMPTS[0]), 10)
+    g.enqueue(list(PROMPTS[1]), 11)
+    g.step()
+    assert g.pending_admissions() == 2 and g._staging is not None
+    assert g.finish(10) and g.finish(11)
+    assert g._staging is None and g.pending_admissions() == 0
+    for _ in range(6):
+        g.step()
+    assert {s.stream_id for s in g.streams if not s.done} == {0}
+
+
+# -- who rides ---------------------------------------------------------------
+
+def _letters_guide():
+    vocab = [chr(i) if 32 <= i < 127 else "" for i in range(256)]
+    return Guide(build_token_dfa("[a-z]{2,4}", vocab, eos_ids=(2,)))
+
+
+SYSTEM = [(i * 7) % 100 + 3 for i in range(32)]  # a shared 32-token prefix
+
+
+def _landing_order(g) -> list:
+    """``(stream id, slot)`` in the order the landings installed them."""
+    order = []
+    install = g._install
+
+    def spy(m, tok_id, lp_row):
+        order.append((m.sid, m.slot))
+        return install(m, tok_id, lp_row)
+
+    g._install = spy
+    return order
+
+
+@pytest.mark.parametrize("case,kw,middle,launches", [
+    ("guide", dict(), dict(prompt=PROMPTS[1], guide=True), 3),
+    ("prefix-hit", dict(prefix_share_min=16, prefix_block=16),
+     dict(prompt=SYSTEM + [5, 9, 2]), 3),
+    ("chunked", dict(admit_chunk=32), dict(prompt=PROMPTS[2]), 3),
+    # (alone behind the head, it starts from the head's row; the third
+    # cannot ride with a prompt that starts from a stored prefix)
+    ("same-prefix-as-the-head", dict(prefix_share_min=16, prefix_block=16),
+     dict(prompt=None), 3),
+    ("plain", dict(), dict(prompt=PROMPTS[1]), 1),
+])
+def test_what_cannot_ride_splits_the_run_in_fifo_order(dense, case, kw,
+                                                       middle, launches):
+    cfg, params = dense
+    g = _engine(cfg, params, slots=5, **kw)
+    if case == "prefix-hit":
+        g.enqueue(SYSTEM + [8, 8, 4, 1], 9)  # leaves SYSTEM in the store
+        while g.pending_admissions():
+            g.step()
+        g.finish(9)
+    head = SYSTEM + [7, 7, 7] if case == "same-prefix-as-the-head" \
+        else PROMPTS[0]
+    prompt = middle["prompt"] or SYSTEM + [6, 1, 6, 1]
+    order = _landing_order(g)
+    before, hits = _counts(), g.stats()["prefix_hits"]
+    g.enqueue(list(head), 10)
+    g.enqueue(list(prompt), 11,
+              guide=_letters_guide() if middle.get("guide") else None)
+    g.enqueue(list(PROMPTS[3]), 12)
+    dispatches = g.stats()["admit_dispatches"]
+    while g.pending_admissions():
+        g.step()
+    grown = _grown(before)
+    assert [sid for sid, _ in order] == [10, 11, 12], case
+    assert grown["engine.admit_launches"] == launches, (case, grown)
+    assert grown["engine.admissions_landed"] == 3
+    want_hits = {"prefix-hit": 1, "same-prefix-as-the-head": 1}.get(case, 0)
+    assert g.stats()["prefix_hits"] - hits == want_hits
+    # a chunked admission's 40 tokens go in two dispatches of 32
+    want = launches + (1 if case == "chunked" else 0)
+    assert g.stats()["admit_dispatches"] - dispatches == want
+    # slots in FIFO order too: nobody was overtaken to a lower slot
+    assert [slot for _, slot in order] == [0, 1, 2]
+
+
+def test_paged_layout_and_an_import_between_two_prompts_launch_alone(dense):
+    cfg, params = dense
+    g = _engine(cfg, params, slots=4, live=1, kv_layout="paged",
+                kv_page_size=8)
+    for _ in range(5):
+        g.step()
+    snap = g.export_stream(0)
+    g.finish(0)
+    order = _landing_order(g)
+    before = _counts()
+    g.enqueue(list(PROMPTS[0]), 10)
+    meta = g.import_begin(snap)
+    g.import_attach(meta["xfer_id"], 20)
+    g.enqueue(list(PROMPTS[1]), 11)
+    g.enqueue(list(PROMPTS[3]), 12)
+    while g.pending_admissions():
+        g.step()
+    grown = _grown(before)
+    assert order == [(10, 0), (11, 2), (12, 3)]  # the attach took slot 1
+    assert grown["engine.admit_launches"] == 3
+    assert grown["engine.admissions_landed"] == 3
+    assert g.streams[1].stream_id == 20
+
+
+def test_synchronous_admit_takes_its_own_row_behind_a_launch(dense, family):
+    cfg, params, alone = family("gqa")
+    g = _engine(cfg, params, slots=4)
+    g.enqueue(list(PROMPTS[0]), 10)
+    g.enqueue(list(PROMPTS[1]), 11)
+    slot, tok = g.admit(list(PROMPTS[2]), 12)
+    assert g.streams[slot].stream_id == 12
+    assert tok.id == alone[12]["tokens"][0]
+    # the two ahead of it landed together, and their row still waits
+    (row,) = g._pending_rows
+    got = {g.streams[i].stream_id: t.id for i, t in enumerate(row)
+           if t is not None}
+    assert got == {10: alone[10]["tokens"][0], 11: alone[11]["tokens"][0]}
+
+
+@pytest.mark.parametrize("free,arrivals,want", [
+    (5, 6, [4, 1]),  # the cap, then the slot that is left; one stays queued
+    (2, 3, [2]),     # a slot each; the third stays queued
+    (3, 3, [3]),
+    (1, 2, [1]),
+])
+def test_more_arrivals_than_slots_or_than_the_cap_stay_queued(
+        dense, free, arrivals, want):
+    cfg, params = dense
+    g = _engine(cfg, params, slots=free + 1, live=1)
+    sizes = []
+    start = g._start_arrival
+
+    def spy(wait=True):
+        ok = start(wait)
+        if ok:
+            sizes.append(len(g._staging["members"]))
+            assert len(g._staging["rows"]) in (1, 2, 4)
+        return ok
+
+    g._start_arrival = spy
+    for i in range(arrivals):
+        g.enqueue(list(PROMPTS[i % 4]), 10 + i)
+    for _ in range(12):
+        g.step()
+    assert sizes == want
+    assert g.pending_admissions() == arrivals - sum(want)
+    assert [a[1] for a in g._arrivals] == list(
+        range(10 + sum(want), 10 + arrivals))
+    live = {s.stream_id for s in g.streams if not s.done}
+    assert live == {0} | set(range(10, 10 + sum(want)))
+    if arrivals > sum(want):  # admitted once a slot frees
+        g.finish(10)
+        for _ in range(6):
+            g.step()
+        assert any(s.stream_id == 10 + sum(want) for s in g.streams)
+
+
+# -- counters, compiles ------------------------------------------------------
+
+def test_counters_count_members_and_launches(dense):
+    cfg, params = dense
+    g = _engine(cfg, params, slots=5)
+    before = _counts()
+    for i in range(3):
+        g.enqueue(list(PROMPTS[i]), 10 + i)
+    while g.pending_admissions():
+        g.step()
+    g.enqueue(list(PROMPTS[3]), 13)
+    while g.pending_admissions():
+        g.step()
+    grown = _grown(before)
+    assert grown["engine.admit_launches"] == 2
+    assert grown["engine.admissions_landed"] == 4
+    for hist in STAGE_HISTS:
+        assert grown[hist] == 4, hist
+    assert grown["moe.admit_rows"] == 0  # no expert layer here
+    for i in range(4):
+        stages = g.take_admission_stages(10 + i)
+        assert [s[0] for s in stages] == ["launch_wait", "rows_wait",
+                                          "land", "to_splice"]
+    # the members of one launch share every stamp but the first
+    assert catalog.kind_of("engine.admit_launches") == catalog.COUNTER
+
+
+def test_a_launch_of_one_is_the_one_row_program(dense):
+    cfg, params = dense
+    g = _engine(cfg, params, slots=4, warm=False)
+    shapes = []
+    prefill = g._admit_prefill
+    g._BatchGenerator__admit_prefill = lambda p, tokens, *rest: (
+        shapes.append(tokens.shape), prefill(p, tokens, *rest))[1]
+    g.enqueue(list(PROMPTS[1]), 10)
+    while g.pending_admissions():
+        g.step()
+    # its own launch, then the programs it could have ridden in, compiled
+    # behind it
+    assert shapes == [(1, 32), (2, 32), (4, 64)]
+    assert g._group_shapes() == [(2, 32), (4, 64)]
+    assert g._splice_fn()._cache_size() == 3
+
+
+def test_a_landing_samples_with_one_program_a_row_count(dense):
+    """A landing's keys and first tokens are ONE jitted program a row count
+    (eager calls are a dispatch a primitive: host time that every live
+    stream waits for where the prefill is too short to hide it), compiled
+    with the landing and never again."""
+    cfg, params = dense
+    g = _engine(cfg, params, slots=5, live=1)  # warm: 1, 2 and 4 rows
+    sampler = g._BatchGenerator__first_tokens
+    assert sampler._cache_size() == 3
+    for n, base in ((1, 10), (2, 20), (3, 30), (1, 40)):
+        for i in range(n):
+            g.enqueue(list(PROMPTS[0]), base + i)
+        while g.pending_admissions():
+            g.step()
+        for i in range(n):
+            g.finish(base + i)
+    assert g._BatchGenerator__first_tokens is sampler
+    assert sampler._cache_size() == 3
+
+
+def test_a_met_buckets_launch_compiles_nothing(dense):
+    """Where a bucket's one-row program compiles (its first admission, or
+    ``warm_admission``) its other row counts and their landing do too: a
+    later launch of two, three or four of that bucket compiles nothing,
+    eager operations included."""
+    cfg, params = dense
+    g = _engine(cfg, params, slots=5, live=1)
+    g.warm_admission(30)  # bucket 32
+    g.enqueue(list(PROMPTS[0]), 9)  # bucket 16, by its first admission
+    while g.pending_admissions():
+        g.step()
+    for _ in range(6):
+        g.step()
+    g.finish(9)
+    for n, base in ((2, 20), (3, 30), (4, 40), (1, 50)):
+        before = _counts()
+        for i in range(n):
+            # lengths of both buckets: the launch takes the larger
+            g.enqueue(list(PROMPTS[1 if i == n - 1 else 0]), base + i)
+        while g.pending_admissions():
+            g.step()
+        for _ in range(5):
+            g.step()
+        grown = _grown(before)
+        assert grown["engine.admit_launches"] == 1
+        assert grown["prof.compiles"] == 0, (n, grown)
+        for i in range(n):
+            g.finish(base + i)
+
+
+# -- which programs there are, and how much padding they may carry -----------
+
+def _launch_sizes(g) -> list:
+    """How many members each launch from here on takes."""
+    sizes = []
+    start = g._start_arrival
+
+    def spy(wait=True):
+        ok = start(wait)
+        if ok:
+            sizes.append(len(g._staging["members"]))
+        return ok
+
+    g._start_arrival = spy
+    return sizes
+
+
+@pytest.mark.parametrize("lengths,want", [
+    # PROMPTS' buckets: 9 and 12 tokens 16, 20 tokens 32, 40 tokens 64;
+    # the one program of several rows: two rows of 32
+    ((20, 20), [2]),
+    ((9, 20), [2]),
+    ((9, 12), [2]),            # each padded to 32
+    ((20, 12, 20), [2, 1]),    # two rows: the third goes next
+    ((40, 20, 9), [1, 2]),     # no program holds 40 tokens a row
+    ((20, 40, 9), [1, 1, 1]),
+])
+def test_a_launch_takes_riders_into_a_program_that_holds_them(
+        dense, monkeypatch, lengths, want):
+    """Riders are taken into a several-row program that has a row each and
+    holds the longest; who is left goes in a later launch, in FIFO
+    order."""
+    monkeypatch.setattr(bg, "GROUP_SHAPES", ((2, 32),))
+    cfg, params = dense
+    g = _engine(cfg, params, slots=5)
+    sizes, order = _launch_sizes(g), _landing_order(g)
+    by_len = {len(p): p for p in PROMPTS}
+    for i, n in enumerate(lengths):
+        g.enqueue(list(by_len[n]), 10 + i)
+    while g.pending_admissions():
+        g.step()
+    assert sizes == want
+    assert [sid for sid, _ in order] == list(range(10, 10 + len(lengths)))
+
+
+def test_a_launch_takes_riders_only_into_a_compiled_program(dense):
+    """Nothing was warmed: the first arrival's launch compiles its
+    bucket's program and, behind it, the several-row ones that hold it;
+    the arrival that waited with it goes alone, the next two together."""
+    cfg, params = dense
+    g = _engine(cfg, params, slots=5, warm=False)
+    sizes = _launch_sizes(g)
+    assert not g._warmed
+    for i in range(2):
+        g.enqueue(list(PROMPTS[1]), 10 + i)
+    while g.pending_admissions():
+        g.step()
+    assert sizes == [1, 1]
+    assert g._warmed == {(1, 32), (2, 32), (4, 64)}
+    for i in range(2):
+        g.enqueue(list(PROMPTS[0]), 20 + i)  # bucket 16: (1, 16) compiles
+    while g.pending_admissions():
+        g.step()
+    assert sizes == [1, 1, 2]
+
+
+@pytest.mark.parametrize("own,want", [
+    ([256, 256], (2, 256)),
+    ([128, 256], (2, 256)),
+    ([64, 128], (2, 256)),
+    ([512, 256], None),       # a program of 512 rows is its arithmetic
+    ([256, 512], None),
+    ([256, 256, 256], None),  # two rows
+])
+def test_the_shipped_program_takes_two_prompts_of_up_to_256_tokens(
+        monkeypatch, own, want):
+    """What the sweep chose (PERF.md section 6, PR 37)."""
+    monkeypatch.setattr(bg, "GROUP_SHAPES", SHIPPED)
+    assert SHIPPED == ((2, 256),)
+    assert bg._group_shape(own) == want

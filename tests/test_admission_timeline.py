@@ -43,6 +43,8 @@ READERS = {  # metric -> (series it reads, its value on _made_up_ctx)
     "engine.block_period_ms": ("engine.block_period_ms", 130.0),
     "engine.block_period_clear_ms": ("engine.block_period_clear_ms", 75.0),
     "engine.admits_per_block": ("engine.admissions_landed", 1.9),
+    # PR 37: admissions landed over admission programs launched
+    "engine.admits_per_launch": ("engine.admit_launches", 2.5),
 }
 SYSTEM = [(i * 7) % 100 + 2 for i in range(16)]  # a shared 16-token prefix
 
@@ -388,14 +390,16 @@ def _made_up_ctx(without: str | None = None) -> dict:
               "engine.admit_to_splice_ms": hist(10, 5.0),
               "engine.block_period_ms": hist(8, 1000.0),
               "engine.block_period_clear_ms": hist(1, 70.0),
-              "engine.admissions_landed": counter(10)}
+              "engine.admissions_landed": counter(10),
+              "engine.admit_launches": counter(9)}
     after = {"engine.admit_launch_wait_ms": hist(110, 4900.0),
              "engine.admit_rows_wait_ms": hist(110, 5100.0),
              "engine.admit_land_ms": hist(110, 2050.0),
              "engine.admit_to_splice_ms": hist(110, 155.0),
              "engine.block_period_ms": hist(58, 7500.0),
              "engine.block_period_clear_ms": hist(21, 1570.0),
-             "engine.admissions_landed": counter(105)}
+             "engine.admissions_landed": counter(105),
+             "engine.admit_launches": counter(47)}
     for side in (before, after):
         side["serve.ttft_ms"] = hist(5, 500.0)
         side.pop(without, None)
@@ -420,7 +424,7 @@ def test_the_benchmark_declares_the_seven_at_the_end_of_per_layer():
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     closed = [w["name"] for w in bench["workloads"]
               if w["name"] != "mistral7b-int8.chat-r80"]
-    added = bench["per_layer"][30:]
+    added = bench["per_layer"][30:37]
     assert [m["name"] for m in added] == [
         "engine.admit_launch_wait_mean_ms", "engine.admit_rows_wait_mean_ms",
         "engine.admit_land_mean_ms", "engine.admit_to_splice_mean_ms",
@@ -434,3 +438,15 @@ def test_the_benchmark_declares_the_seven_at_the_end_of_per_layer():
         else:  # every cell reports tpot_p50_ms and lands blocks
             assert m["moves"] == "tpot_p50_ms" and "workloads" not in m
     assert {m["unit"] for m in added} == {"ms", "admissions"}
+
+
+def test_the_benchmark_declares_admits_per_launch_after_them():
+    """PR 37's one metric: the last entry, reported in every cell (each
+    reports ``tpot_p50_ms`` and launches admissions), more is better."""
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    assert bench["per_layer"][37] == {
+        "name": "engine.admits_per_launch", "unit": "admissions",
+        "better": "higher", "source": "program_counter", "layer": "engine",
+        "moves": "tpot_p50_ms"}
+    assert len(bench["per_layer"]) == 38
+    assert catalog.kind_of("engine.admit_launches") == catalog.COUNTER

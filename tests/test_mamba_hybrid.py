@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -58,7 +59,8 @@ def _params(cfg=CFG, seed=0):
 
     def jitter(path, leaf):
         name = path[-1].key
-        k = jax.random.fold_in(key, hash(jax.tree_util.keystr(path)) % 2**31)
+        k = jax.random.fold_in(  # (crc32: str hashes differ by process)
+            key, zlib.crc32(jax.tree_util.keystr(path).encode()) % 2**31)
         if name.endswith("norm") or name in ("norm_f", "d_skip"):
             return leaf * (1.0 + 0.25 * jax.random.uniform(
                 k, leaf.shape, minval=-1.0))

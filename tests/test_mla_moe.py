@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import math
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +51,8 @@ def _params(cfg=CFG, seed=0):
     def jitter(path, leaf):
         if not path[-1].key.endswith("norm") and path[-1].key != "norm_f":
             return leaf
-        k = jax.random.fold_in(key, hash(jax.tree_util.keystr(path)) % 2**31)
+        k = jax.random.fold_in(  # (crc32: str hashes differ by process)
+            key, zlib.crc32(jax.tree_util.keystr(path).encode()) % 2**31)
         return leaf * (1.0 + 0.25 * jax.random.uniform(k, leaf.shape,
                                                        minval=-1.0))
 
