@@ -146,6 +146,20 @@ class LlamaConfig:
     mamba_expand: int = 2
     mamba_dt_rank: int = 0  # 0: ceil(hidden_size / 16), HF's "auto"
     mamba_conv_bias: bool = True
+    # --- window and full grouped-query attention mixed by layer (HF
+    # `model_type` "exaone_moe") ---------------------------------------------
+    # ``layer_types``: "sliding_attention" | "full_attention" a layer. A
+    # window layer rotates q and k and sees the last ``sliding_window``
+    # positions, which it keeps as a ring of ``ring_rows`` rows a stream
+    # whatever the capacity (``cache_plan``); a full layer has NO position
+    # embedding and keeps every row. ``qk_norm``: an RMSNorm over each
+    # head of q and k (one ``[head_dim]`` weight for all heads), before
+    # the rotation. The feed-forward is the shared-expert family's
+    # (``first_k_dense_replace`` leading dense layers, then
+    # ``n_routed_experts`` held of ``router_experts`` beside
+    # ``n_shared_experts``).
+    layer_types: tuple[str, ...] | None = None
+    qk_norm: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -162,21 +176,23 @@ class LlamaConfig:
                 raise ValueError(
                     f"attn_gate {self.attn_gate!r} is not wired (a "
                     "head-wise output gate only)")
-            if self.n_routed_experts and self.scoring_func != "sigmoid":
+        if self.layer_types is not None:
+            self._check_layer_types()
+        if (self.kv_lora_rank or self.windowed) and self.n_routed_experts:
+            if self.scoring_func != "sigmoid":
                 raise ValueError(
                     f"scoring_func {self.scoring_func!r} is not wired for "
                     "the shared-expert family (sigmoid, group-limited "
                     "routing only)")
-            if self.n_routed_experts:
-                width = self.router_experts or self.n_routed_experts
-                object.__setattr__(self, "router_experts", width)
-                if width % self.n_group or not (
-                        0 <= self.first_expert
-                        <= width - self.n_routed_experts):
-                    raise ValueError(
-                        f"experts {self.first_expert}.."
-                        f"{self.first_expert + self.n_routed_experts - 1} "
-                        f"held of {width} in {self.n_group} groups")
+            width = self.router_experts or self.n_routed_experts
+            object.__setattr__(self, "router_experts", width)
+            if width % self.n_group or not (
+                    0 <= self.first_expert
+                    <= width - self.n_routed_experts):
+                raise ValueError(
+                    f"experts {self.first_expert}.."
+                    f"{self.first_expert + self.n_routed_experts - 1} "
+                    f"held of {width} in {self.n_group} groups")
         if self.layer_group_size and not self.kv_lora_rank:
             raise ValueError(
                 "layer_group_size > 0 (delta-rule layers beside latent "
@@ -210,6 +226,33 @@ class LlamaConfig:
                 "num_local_experts > 0"
             )
 
+    def _check_layer_types(self):
+        """What a per-layer ``layer_types`` may ask for and is computed."""
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        kinds = set(self.layer_types)
+        if (len(self.layer_types) != self.num_hidden_layers
+                or not kinds <= set(_WINDOW_MIXERS)):
+            raise ValueError(
+                f"layer_types needs one of {sorted(_WINDOW_MIXERS)} for "
+                f"each of the {self.num_hidden_layers} layers, got "
+                f"{len(self.layer_types)} entries of {sorted(kinds)}")
+        if "full_attention" not in kinds:
+            raise ValueError(
+                "layer_types without a full_attention layer is not wired "
+                "(the cache's capacity is the full layers')")
+        if "sliding_attention" in kinds and not (
+                self.sliding_window and self.sliding_window >= 8):
+            raise ValueError(
+                "layer_types names sliding_attention layers: they need a "
+                f"sliding_window of 8 or more, got {self.sliding_window!r}")
+        if self.kv_lora_rank or self.attn_layer_period or (
+                self.num_local_experts or self.attention_bias):
+            raise ValueError(
+                "layer_types (window and full grouped-query attention "
+                "mixed by layer) is wired with the shared-expert "
+                "feed-forward only: no latent keys, no state-space "
+                "layers, no Mixtral-style experts, no projection bias")
+
     @property
     def num_kv_groups(self) -> int:
         """Query heads per KV head (GQA group size, attention.rs:84-89)."""
@@ -232,11 +275,31 @@ class LlamaConfig:
         return self.attn_layer_period > 0
 
     @property
+    def windowed(self) -> bool:
+        """Window and full grouped-query attention layers mixed by layer
+        (``layer_types``): window layers keep a ring of ``ring_rows`` rows
+        a stream, full layers every row."""
+        return self.layer_types is not None
+
+    @property
+    def ring_rows(self) -> int:
+        """Rows ``R`` of a window layer's ring, a function of the window
+        alone: position ``p`` lives at row ``p % R``. A layer writes its
+        new row and then attends, so the ``sliding_window`` newest
+        positions (the query's own among them) are all a step reads, and
+        an admission chunk reads the rows before it and then writes its
+        own last ``R``: the window itself is enough. It is rounded up to
+        whole ``(16, 128)`` tiles of a bfloat16 buffer's last two axes,
+        which the published 128 is already (``window <= R < window +
+        16``)."""
+        return -(-self.sliding_window // 16) * 16
+
+    @property
     def segmented(self) -> bool:
         """Whether the layers are of several kinds, so that
         ``params["layers"]`` is a dict of stacks, one a segment of
         ``models.llama.layer_plan``, and not one bare stack."""
-        return self.latent or self.state_space
+        return self.latent or self.state_space or self.windowed
 
     @property
     def recurrent_mixer(self) -> str | None:
@@ -260,8 +323,9 @@ class LlamaConfig:
     @property
     def layer_kinds(self) -> tuple[tuple[str, str], ...]:
         """``(mixer, feed-forward)`` of every layer, in model order: the
-        mixer is "gqa", "mla", "kda" or "mamba", the feed-forward "dense" or
-        "moe".
+        mixer is "gqa", "swa" (grouped-query attention through a window,
+        where ``layer_types`` says so), "mla", "kda" or "mamba", the
+        feed-forward "dense" or "moe".
         THE place the layer order comes from (models/llama.py
         ``layer_plan`` groups it into scanned segments, the cache and the
         loaders count it)."""
@@ -271,6 +335,8 @@ class LlamaConfig:
             0 if self.num_local_experts else n)
 
         def mixer(i):
+            if self.windowed:
+                return _WINDOW_MIXERS[self.layer_types[i]]
             if self.state_space:
                 return ("gqa" if i % self.attn_layer_period
                         == self.attn_layer_offset else "mamba")
@@ -292,12 +358,19 @@ class LlamaConfig:
         layers ``(layers, heads, d_k, d_v)`` and ``(layers, taps - 1, 3
         heads d)`` (the q, k and v convolutions), Mamba layers ``(layers,
         d_state, d_inner)`` (channels last, on the lanes) and ``(layers,
-        taps - 1, d_inner)``. A kind with no layer is left out."""
+        taps - 1, d_inner)``. Window layers (``layer_types``) keep ``ring``
+        ``(layers, heads, R, k_width, v_width)``: ``R = ring_rows`` rows a
+        stream whatever the capacity, and ``rows`` then counts the full
+        layers alone. A kind with no layer is left out."""
         mixers = [m for m, _ in self.layer_kinds]
         held = mixers.count(self.recurrent_mixer)
+        ring = mixers.count("swa")
         plan = {}
-        if len(mixers) - held:
-            plan["rows"] = (len(mixers) - held,) + self.cache_row
+        if len(mixers) - held - ring:
+            plan["rows"] = (len(mixers) - held - ring,) + self.cache_row
+        if ring:
+            heads, *widths = self.cache_row
+            plan["ring"] = (ring, heads, self.ring_rows, *widths)
         if held and self.recurrent_mixer == "kda":
             h, d = self.num_attention_heads, self.head_dim
             plan["state"] = (held, h, d, d)
@@ -310,7 +383,10 @@ class LlamaConfig:
     @property
     def rope_dim(self) -> int:
         """Channels of a head that rotary embeddings cover; 0: the model
-        has no position embedding (position comes from the recurrence)."""
+        has no position embedding (position comes from the recurrence).
+        Where window and full layers are mixed (``layer_types``) this is
+        the window layers': a full layer rotates nothing (the layer loop
+        hands it no table)."""
         if self.state_space:
             return 0
         return self.qk_rope_head_dim if self.latent else self.head_dim
@@ -361,6 +437,11 @@ class LlamaConfig:
     def from_hf_dict(cls, d: dict, **overrides) -> "LlamaConfig":
         known = {f.name for f in dataclasses.fields(cls)}
         kwargs = {k: v for k, v in d.items() if k in known}
+        if d.get("model_type") != WINDOWED_MODEL_TYPE:
+            # Hugging Face writes a `layer_types` list for every family
+            # (all "full_attention" where nothing windows by layer); only
+            # the family that mixes them by layer reads it
+            kwargs.pop("layer_types", None)
         # HF configs carry torch_dtype, not dtype.
         td = d.get("torch_dtype")
         if td and "dtype" not in overrides:
@@ -408,9 +489,14 @@ class LlamaConfig:
                     raise ValueError(
                         f"partial-depth sliding window "
                         f"(max_window_layers={mwl} of {layers}) is not "
-                        "supported; all-or-none windowing only"
+                        "wired for this family's one bare stack; a "
+                        "window on some layers is read from a per-layer "
+                        f"layer_types list (model_type "
+                        f"{WINDOWED_MODEL_TYPE!r})"
                     )
-        if d.get("model_type") == STATE_SPACE_MODEL_TYPE:
+        if d.get("model_type") == WINDOWED_MODEL_TYPE:
+            kwargs.update(_windowed_kwargs(d))
+        elif d.get("model_type") == STATE_SPACE_MODEL_TYPE:
             kwargs.update(_state_space_kwargs(d))
         elif d.get("model_type") == HYBRID_MODEL_TYPE:
             kwargs.update(_hybrid_kwargs(d))
@@ -467,13 +553,30 @@ class LlamaConfig:
             d.pop("embed_scale")
         width, first = d.pop("router_experts"), d.pop("first_expert")
         if not self.latent:
-            for f in _LATENT_FIELDS:
+            for f in (_LATENT_ATTENTION_FIELDS if self.windowed
+                      else _LATENT_FIELDS):
                 d.pop(f)
-        elif width != self.n_routed_experts:
+        if self.n_routed_experts and width != self.n_routed_experts:
             d["expert_share"] = {
                 "n_routed_experts": width,
                 "ep": width // self.n_routed_experts,
                 "rank": first // self.n_routed_experts}
+        d.pop("qk_norm")  # the family's, not a key of any config.json
+        if not self.windowed:
+            d.pop("layer_types")
+        else:  # the family's own spelling (_windowed_kwargs reads it back)
+            d.pop("router_bias")
+            d["layer_types"] = list(self.layer_types)
+            d["sliding_windows"] = [
+                self.sliding_window if t == "sliding_attention" else 0
+                for t in self.layer_types]
+            d["mlp_layer_types"] = [
+                "sparse" if ffn == "moe" else "dense"
+                for _, ffn in self.layer_kinds]
+            d["num_experts"] = d.pop("n_routed_experts")
+            d["num_shared_experts"] = d.pop("n_shared_experts")
+            d["rope_parameters"] = {"rope_theta": d.pop("rope_theta"),
+                                    "rope_type": "default"}
         if not self.layer_group_size:
             for f in _HYBRID_FIELDS:
                 d.pop(f)
@@ -497,6 +600,75 @@ class LlamaConfig:
 
 # HF `model_type`s served by the latent-attention, shared-expert decoder
 LATENT_MODEL_TYPES = ("deepseek_v3", "axk1")
+# ... and the one whose layers are grouped-query attention through a window
+# but every fourth, which attends fully and rotates nothing, with the
+# shared-expert feed-forward (K-EXAONE's keys)
+WINDOWED_MODEL_TYPE = "exaone_moe"
+# `layer_types` entry -> the layer's mixer (`LlamaConfig.layer_kinds`)
+_WINDOW_MIXERS = {"sliding_attention": "swa", "full_attention": "gqa"}
+
+
+def _windowed_kwargs(d: dict) -> dict:
+    """`LlamaConfig` fields from an "exaone_moe" config.json (its own
+    spelling: ``num_experts``, ``num_shared_experts``, ``layer_types``,
+    ``mlp_layer_types``, ``sliding_windows``, ``rope_parameters``). What
+    the file asks for and nothing here computes is refused, not guessed;
+    ``num_nextn_predict_layers`` (a next-token prediction block, ``mtp.*``
+    tensors) is read and ignored: the block takes no part in the model's
+    own logits and the loaders skip its tensors. The readings made of the
+    file (pre-norm sublayers, a routing bias that enters the choice) are
+    the benchmark configuration's ``assumed``."""
+    name = WINDOWED_MODEL_TYPE
+    layers, window = d["num_hidden_layers"], d.get("sliding_window")
+    types = list(d["layer_types"])
+    if len(types) != layers:
+        raise ValueError(
+            f"{name}: layer_types has {len(types)} entries for "
+            f"{layers} layers")
+    want = [window if t == "sliding_attention" else 0 for t in types]
+    if [w or 0 for w in d.get("sliding_windows", want)] != want:
+        raise ValueError(
+            f"{name}: sliding_windows {d['sliding_windows']} disagrees "
+            f"with layer_types and sliding_window {window} (a window of "
+            "its own a layer is not wired)")
+    rope = d.get("rope_parameters") or {}
+    kind = rope.get("rope_type", rope.get("type", "default"))
+    if kind != "default" or d.get("rope_scaling"):
+        raise ValueError(
+            f"{name}: rope type {kind!r} is not wired (default rotation, "
+            "no scaling, on the window layers only)")
+    dense = d.get("first_k_dense_replace")
+    ffn = list(d.get("mlp_layer_types") or (
+        ["dense"] * (dense or 0) + ["sparse"] * (layers - (dense or 0))))
+    lead = ffn.count("dense")
+    if (len(ffn) != layers or ffn != ["dense"] * lead + ["sparse"]
+            * (layers - lead) or dense not in (None, lead)):
+        raise ValueError(
+            f"{name}: mlp_layer_types {ffn} with first_k_dense_replace "
+            f"{dense} is not wired (dense layers lead, sparse ones follow)")
+    groups, kept = d.get("n_group", 1), d.get("topk_group", 1)
+    if not 1 <= kept <= groups:
+        raise ValueError(
+            f"{name}: topk_group {kept} of n_group {groups} is not a "
+            "group-limited choice")
+    held = d["num_experts"] if lead < layers else 0
+    kwargs = {
+        "layer_types": tuple(types),
+        "qk_norm": True,
+        "first_k_dense_replace": lead,
+        "n_routed_experts": held,
+        "n_shared_experts": d.get("num_shared_experts", 0),
+        "router_bias": bool(held),
+        "rope_theta": float(rope.get("rope_theta",
+                                     d.get("rope_theta", 10000.0))),
+    }
+    share = d.get("expert_share")
+    if share:  # this chip's share of an ep deployment's experts
+        kwargs["router_experts"] = share["n_routed_experts"]
+        kwargs["first_expert"] = share["rank"] * held
+    return kwargs
+
+
 # ... and the one whose layers are delta-rule linear attention but every
 # `layer_group_size`-th (Ling-3.0's keys)
 HYBRID_MODEL_TYPE = "bailing_hybrid"
@@ -586,7 +758,9 @@ def _state_space_kwargs(d: dict) -> dict:
     if d.get("sliding_window") is not None:
         raise ValueError(
             f"{STATE_SPACE_MODEL_TYPE}: sliding_window = "
-            f"{d['sliding_window']!r} is not wired (full attention only)")
+            f"{d['sliding_window']!r} is not wired beside state-space "
+            "layers (their attention layers are full; a window a layer, "
+            f"by layer_types, is model_type {WINDOWED_MODEL_TYPE!r}'s)")
     rank = d.get("mamba_dt_rank", "auto")
     return {
         # one expert is the dense SwiGLU: its choice of 1 selects nothing
@@ -598,9 +772,11 @@ def _state_space_kwargs(d: dict) -> dict:
     }
 
 
-_LATENT_FIELDS = (
+_LATENT_ATTENTION_FIELDS = (
     "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
-    "v_head_dim", "first_k_dense_replace", "moe_intermediate_size",
+    "v_head_dim")
+_LATENT_FIELDS = _LATENT_ATTENTION_FIELDS + (
+    "first_k_dense_replace", "moe_intermediate_size",
     "n_shared_experts", "n_routed_experts", "scoring_func", "n_group",
     "topk_group", "norm_topk_prob", "routed_scaling_factor",
 )
@@ -858,6 +1034,59 @@ def jamba2_3b(**overrides) -> LlamaConfig:
     return LlamaConfig(**base)
 
 
+def _repeated(pattern, layers: int) -> tuple[str, ...]:
+    """``layer_types`` for ``layers`` layers from one period of the
+    pattern (or the whole list, which it then is)."""
+    return tuple(pattern[i % len(pattern)] for i in range(layers))
+
+
+def kexaone_ep8(**overrides) -> LlamaConfig:
+    """K-EXAONE-236B-A23B (https://huggingface.co/LGAI-EXAONE/
+    K-EXAONE-236B-A23B, `model_type` "exaone_moe") at its published
+    widths, as ONE chip of the 8 that share each layer's 128 experts holds
+    it: global experts 0-15 beside the whole router (and its bias),
+    attention and shared expert. 48 layers as published: a window of 128
+    on every layer but each fourth, which attends fully with no position
+    embedding; one leading dense layer. A chip serves the depth of its
+    pipeline stage (`num_hidden_layers=` with `layer_types=` cut to it)
+    and its slice of the vocabulary (`vocab_size=`)."""
+    base = dict(
+        model_type="exaone_moe",
+        vocab_size=153600,
+        hidden_size=6144,
+        intermediate_size=18432,
+        num_hidden_layers=48,
+        num_attention_heads=64,
+        num_key_value_heads=8,
+        head_dim=128,
+        rms_norm_eps=1e-5,
+        rope_theta=1000000.0,
+        max_seq_len=262144,
+        sliding_window=128,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        qk_norm=True,
+        first_k_dense_replace=1,
+        moe_intermediate_size=2048,
+        n_shared_experts=1,
+        n_routed_experts=16,
+        router_experts=128,
+        first_expert=0,
+        num_experts_per_tok=8,
+        scoring_func="sigmoid",
+        router_bias=True,
+        n_group=1,
+        topk_group=1,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        bos_token_id=0,
+        eos_token_id=1,
+    )
+    base.update(overrides)
+    base["layer_types"] = _repeated(base["layer_types"],
+                                    base["num_hidden_layers"])
+    return LlamaConfig(**base)
+
+
 def tiny(**overrides) -> LlamaConfig:
     """Tiny random-weight config for tests (SURVEY.md §4 test strategy)."""
     base = dict(
@@ -973,4 +1202,36 @@ def tiny_jamba(**overrides) -> LlamaConfig:
         rms_norm_eps=1e-6,
     )
     base.update(overrides)
+    return tiny(**base)
+
+
+def tiny_exaone_moe(**overrides) -> LlamaConfig:
+    """Tiny fixture of the window + full attention family that keeps the
+    published pattern (K-EXAONE's keys): two whole ``LLLG`` periods (a
+    window of 8 on every layer but each fourth, which attends fully and
+    rotates nothing; a ring of 16 rows), QK-normed heads, a leading dense
+    layer, 16 bias-corrected sigmoid-scored experts top-4 of which 4 are
+    held (rank 1 of 4), one shared expert."""
+    base = dict(
+        model_type="exaone_moe",
+        num_hidden_layers=8,
+        head_dim=16,
+        sliding_window=8,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        qk_norm=True,
+        first_k_dense_replace=1,
+        moe_intermediate_size=32,
+        n_shared_experts=1,
+        n_routed_experts=4,
+        router_experts=16,
+        first_expert=4,
+        num_experts_per_tok=4,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+    )
+    base.update(overrides)
+    base["layer_types"] = _repeated(base["layer_types"],
+                                    base["num_hidden_layers"])
     return tiny(**base)

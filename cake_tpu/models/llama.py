@@ -33,7 +33,10 @@ import jax.numpy as jnp
 
 from cake_tpu.models.config import LlamaConfig
 from cake_tpu.ops import quant
-from cake_tpu.ops.attention import self_attention_block
+from cake_tpu.ops.attention import (
+    self_attention_block,
+    window_attention_block,
+)
 from cake_tpu.ops.kda import kda_attention_block
 from cake_tpu.ops.kvcache import KVCache
 from cake_tpu.ops.mamba import mamba_mixer_block
@@ -171,7 +174,7 @@ class Segment(NamedTuple):
     layers and ``cache_stride`` cached ones on."""
 
     name: str
-    mixer: str  # "mla" | "kda" | "gqa" | "mamba"
+    mixer: str  # "mla" | "kda" | "gqa" | "swa" | "mamba"
     ffn: str  # "dense" | "moe"
     first: int
     count: int
@@ -220,7 +223,16 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
     its repetitions. "Leading dense layers, then expert layers" is two
     segments (``dense``, ``moe``); delta-rule layers but every sixth is a
     period of two segments after the leading ones; state-space layers but
-    the eighth of every fourteen is a period of three (M7 A M6)."""
+    the eighth of every fourteen is a period of three (M7 A M6).
+
+    Window and full attention mixed by layer (``config.windowed``) goes the
+    same way: a leading dense layer, ``W W G``, then ``W W W G`` eleven
+    times, is a dense window segment, a run of two sparse window layers, a
+    period of two segments (``G``, ``W W W``) eleven times over and a last
+    full layer. (A stack a KIND, walked by one scan with a ``lax.switch``
+    over the kinds, was tried first: the chip's compiler then copies the
+    rings in and out of every branch and re-lays the attention projections
+    it indexes inside one, PERF.md section 7, PR 40.)"""
     kinds = config.layer_kinds
     period = _whole_period(kinds)
     stretches = []  # [kind, first, count]
@@ -233,7 +245,7 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
     one_mixer = len({m for m, _ in kinds}) == 1
     names: dict[str, int] = {}
     # layers counted so far in the cache buffers of each mixer's kind
-    cached = {"mla": 0, "kda": 0, "gqa": 0, "mamba": 0}
+    cached = {"mla": 0, "kda": 0, "gqa": 0, "swa": 0, "mamba": 0}
 
     def segment(kind, first, count, cache_stride=0):
         mixer, ffn = kind
@@ -285,9 +297,11 @@ def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
         shapes = dict(_MAMBA_SHAPES)
         if not config.mamba_conv_bias:
             del shapes["conv_b"]
-    elif seg.mixer == "gqa":
+    elif seg.mixer in ("gqa", "swa"):
         shapes = {k: _LAYER_SHAPES[k] for k in (
             "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm")}
+        if config.qk_norm:  # one weight for all heads, each of q and k
+            shapes["q_norm"] = shapes["k_norm"] = lambda c: (c.head_dim,)
     else:
         shapes = dict(_LATENT_SHAPES)
         if not config.q_lora_rank:  # one direct query projection
@@ -690,6 +704,37 @@ def _kda_block(layer, x, cache, config, valid, ep_axis, ep_size, layer_idx,
     return x, dataclasses.replace(cache, state=state, conv=conv), local
 
 
+def _windowed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
+                    ep_axis, ep_size, layer_idx, count_local, expert_idx):
+    """One layer of a model that mixes window and full attention by
+    layer, its shared-expert feed-forward included: a window layer
+    (``mixer`` "swa") rotates q and k and attends over its ring of the
+    carried cache, a full one ("gqa") rotates nothing and attends over its
+    rows; both norm each head of q and k first. ``layer_idx`` counts the
+    layers of the mixer's own kind. Returns ``(x, cache, ExpertCount)``."""
+    h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
+    norm = ((layer["q_norm"], layer["k_norm"], config.rms_norm_eps)
+            if config.qk_norm else None)
+    args = (h, layer["wq"], layer["wk"], layer["wv"], layer["wo"])
+    heads = (config.num_attention_heads, config.num_key_value_heads)
+    if mixer == "swa":
+        with jax.named_scope("attn.swa"):
+            out, ring_k, ring_v = window_attention_block(
+                *args, cache.ring_k, cache.ring_v, cos, sin, pos, *heads,
+                window=config.sliding_window, layer=layer_idx,
+                qk_norm=norm, valid=valid)
+        cache = dataclasses.replace(cache, ring_k=ring_k, ring_v=ring_v)
+    else:
+        with jax.named_scope("attn.full"):
+            out, k, v = self_attention_block(
+                *args, cache.k, cache.v, None, None, pos, *heads,
+                layer=layer_idx, qk_norm=norm)
+        cache = dataclasses.replace(cache, k=k, v=v)
+    x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
+                                    ep_size, count_local, expert_idx)
+    return x, cache, local
+
+
 def _mamba_block(layer, x, cache, config, valid, layer_idx):
     """One state-space layer over the carried cache's recurrent buffers,
     its dense feed-forward included. Returns ``(x, cache, ExpertCount)``."""
@@ -757,6 +802,11 @@ def forward_layers(
     tokens of each row of a bucketed chunk, which alone touch that state);
     a state-space model's attention layers are segments of the plain
     grouped-query block, with no rotation (``cos`` and ``sin`` None).
+    Where window and full attention are mixed by layer, a window
+    segment's layers rotate and attend over the cache's rings (``valid``
+    keeps a bucket's padding out of them) and a full segment's take no
+    table and attend over its rows (:func:`_windowed_block`), each
+    indexed by the layers of its own mixer's kind.
     """
     rows = x.shape[0] * x.shape[1]
 
@@ -772,13 +822,17 @@ def forward_layers(
             return {k: v for k, v in stack.items() if k not in whole}, whole
         return stack, {}
 
-    def body(carry, per_layer, whole=None):
+    def body(carry, per_layer, whole=None, mixer=None):
         h, c, *local = carry
         layer, i, *j = per_layer
         if whole:
             layer = {**layer, **whole}
         j = j[0] if j else None
-        if "w_decay" in layer:
+        if mixer is not None:  # window and full layers mixed: told apart
+            h, c, now = _windowed_block(
+                layer, h, c, mixer, cos, sin, pos, config, valid, ep_axis,
+                ep_size, i, count_local, j)
+        elif "w_decay" in layer:
             h, c, now = _kda_block(layer, h, c, config, valid, ep_axis,
                                    ep_size, i, count_local, j)
         elif "w_in" in layer:
@@ -798,7 +852,15 @@ def forward_layers(
             return (h, c, local[0] + now), None
         return (h, c), None
 
-    def scan_segment(carry, stack, first, whole):
+    # a window layer and a full one hold the same tensors: where the two are
+    # mixed, a segment's mixer says which body runs it (one a mixer, so that
+    # every segment of a kind traces the same function)
+    bodies = {m: partial(body, mixer=m) for m in ("swa", "gqa")}
+
+    def body_of(seg):
+        return bodies[seg.mixer] if config.windowed else body
+
+    def scan_segment(carry, stack, first, whole, body=body):
         """``stack``'s layers over the carry; ``first``: its first layer's
         index into the cache buffers of its kind; ``whole``: the expert
         stacks it was :func:`split` from, if any."""
@@ -833,7 +895,7 @@ def forward_layers(
                     lambda w: jax.lax.dynamic_index_in_dim(
                         w, at + j, 0, keepdims=False), stack)
                 per_layer = (layer, first + j) + ((at + j,) if whole else ())
-                return body(carry, per_layer, whole=whole)
+                return body_of(seg)(carry, per_layer, whole=whole)
 
             return jax.lax.scan(
                 one, carry, jnp.arange(seg.count, dtype=jnp.int32))[0]
@@ -858,7 +920,8 @@ def forward_layers(
             continue
         for seg in run.segments:
             stack, whole = split(layers[seg.name])
-            carry = scan_segment(carry, stack, seg.cache_first, whole)
+            carry = scan_segment(carry, stack, seg.cache_first, whole,
+                                 body_of(seg))
     return carry
 
 

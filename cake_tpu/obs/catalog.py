@@ -60,10 +60,38 @@ SERIES: dict[str, tuple[str, str]] = {
                "layers x cache.row_bytes)"),
     "cache.row_bytes": (
         GAUGE, "bytes the cache holds for one token of one layer that "
-               "keeps rows, from the buffers allocated (their bytes / "
-               "such layers x slots x window): per-head keys and values "
-               "(with an int8 cache's scales), or latent attention's one "
-               "shared row"),
+               "keeps every row, a mean over the layers of that ONE kind, "
+               "from the buffers allocated (their bytes / such layers x "
+               "slots x window): per-head keys and values (with an int8 "
+               "cache's scales), or latent attention's one shared row; a "
+               "window layer's ring is no part of it (cache.rows_bytes)"),
+    "cache.ring_rows": (
+        GAUGE, "rows R a window layer's ring holds for a stream, whatever "
+               "the capacity (LlamaConfig.ring_rows: position p at row "
+               "p % R); absent where no layer attends through a ring"),
+    "cache.rows_bytes": (
+        GAUGE, "bytes of the serving cache's row buffers of both kinds, "
+               "from the buffers allocated: the full layers' rows at the "
+               "capacity and the window layers' rings; absent where no "
+               "layer attends through a ring"),
+    "cache.rows_bytes_full": (
+        GAUGE, "bytes the row buffers would hold were every window layer "
+               "a full one at the capacity (the full layers' bytes x all "
+               "attention layers / full layers): cache.rows_bytes over "
+               "this is what the rings leave of the cache"),
+    "attn.layers_swa": (
+        GAUGE, "layers that attend through a sliding window over a ring "
+               "(named scope attn.swa); absent where window and full "
+               "layers are not mixed by layer"),
+    "attn.layers_full": (
+        GAUGE, "layers that attend over every row, beside window layers "
+               "(named scope attn.full); attn.kv_blocks_read and "
+               "attn.kv_blocks_reserved count one of THESE"),
+    "load.tensors_skipped": (
+        COUNTER, "tensors a checkpoint stores that are no part of the "
+                 "served model and were not read (a next-token prediction "
+                 "block's mtp.*, layers past the served depth), by the "
+                 "loader of a model of several layer stacks"),
     "cache.state_bytes": (
         GAUGE, "bytes of the serving cache that are recurrent state "
                "(delta-rule or state-space layers' float32 state and "

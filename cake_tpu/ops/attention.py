@@ -43,6 +43,7 @@ from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.ops import kvcache as kv
 from cake_tpu.ops import pallas as pk
 from cake_tpu.ops import quant
+from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.rope import apply_rope
 
 log = logging.getLogger("cake_tpu.attention")
@@ -265,6 +266,33 @@ def _attend_xla(
     return out.reshape(b, n_heads, t, d).astype(q.dtype)
 
 
+def _project_heads(x, wq, wk, wv, num_heads: int, num_kv_heads: int,
+                   bq=None, bk=None, bv=None, qk_norm: tuple | None = None):
+    """``x [B, T, hidden]`` through the q, k and v projections (with their
+    biases, where a family has them), as heads ``[B, heads, T, D]``; with
+    ``qk_norm`` ``(q weight [D], k weight [D], eps)`` each head of q and k
+    RMS-normed (one weight for all heads), which comes before any
+    rotation."""
+    b, t, _ = x.shape
+    d = quant.out_features(wq) // num_heads
+    q = quant.dense(x, wq)
+    k = quant.dense(x, wk)
+    v = quant.dense(x, wv)
+    if bq is not None:
+        q = q + bq
+    if bk is not None:
+        k = k + bk
+    if bv is not None:
+        v = v + bv
+    q = q.reshape(b, t, num_heads, d).transpose(0, 2, 1, 3)
+    k = k.reshape(b, t, num_kv_heads, d).transpose(0, 2, 1, 3)
+    v = v.reshape(b, t, num_kv_heads, d).transpose(0, 2, 1, 3)
+    if qk_norm is not None:
+        q = rms_norm(q, qk_norm[0], qk_norm[2])
+        k = rms_norm(k, qk_norm[1], qk_norm[2])
+    return q, k, v
+
+
 def self_attention_block(
     x: jax.Array,  # [B, T, hidden]
     wq: jax.Array,  # [hidden, n_heads * D]
@@ -290,6 +318,7 @@ def self_attention_block(
     bo: jax.Array | None = None,  # o_proj bias (HF llama-arch attention_bias)
     window: int | None = None,  # sliding-window width (Mistral family)
     layer: jax.Array | None = None,  # index into a stacked [L, ...] cache
+    qk_norm: tuple | None = None,  # (q weight [D], k weight [D], eps)
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One attention sublayer incl. cache update.
 
@@ -333,22 +362,14 @@ def self_attention_block(
     loop every stage executes this code every step (collectives must be
     uniform across devices — a conditional ppermute/psum deadlocks); the gate
     makes the KV commit predicated so only the active stage's write lands.
+
+    ``qk_norm``: an RMSNorm over each head of q and k (one weight for all
+    heads), before the rotation.
     """
     b, t, hidden = x.shape
-    d = quant.out_features(wq) // num_heads
-
-    q = quant.dense(x, wq)
-    k = quant.dense(x, wk)
-    v = quant.dense(x, wv)
-    if bq is not None:
-        q = q + bq
-    if bk is not None:
-        k = k + bk
-    if bv is not None:
-        v = v + bv
-    q = q.reshape(b, t, num_heads, d).transpose(0, 2, 1, 3)
-    k = k.reshape(b, t, num_kv_heads, d).transpose(0, 2, 1, 3)
-    v = v.reshape(b, t, num_kv_heads, d).transpose(0, 2, 1, 3)
+    q, k, v = _project_heads(x, wq, wk, wv, num_heads, num_kv_heads,
+                             bq, bk, bv, qk_norm)
+    d = q.shape[-1]
 
     if sp_axis is not None and sp_size > 1:
         from cake_tpu.ops import ring
@@ -459,3 +480,129 @@ def self_attention_block(
         # projection, not to each rank's partial
         out = out + bo
     return out, k_cache, v_cache
+
+
+def _attend_blocks(qb: jax.Array, k_blocks: jax.Array, v_blocks: jax.Array,
+                   mask: jax.Array) -> jax.Array:
+    """Grouped-query attention a block of queries at a time: ``qb [B, KH,
+    G, N, Q, D]`` against ``k_blocks``/``v_blocks [B, KH, N, S, D]`` under
+    ``mask`` (broadcast to ``[B, KH, G, N, Q, S]``); float32 scores and
+    softmax, as :func:`_attend_xla`. Returns ``[B, KH, G, N, Q, D]``."""
+    d = qb.shape[-1]
+    scores = jnp.einsum("bkgnqd,bknsd->bkgnqs", qb, k_blocks,
+                        preferred_element_type=jnp.float32
+                        ) / jnp.sqrt(jnp.float32(d))
+    probs = jax.nn.softmax(jnp.where(mask, scores, NEG_INF), axis=-1)
+    out = jnp.einsum("bkgnqs,bknsd->bkgnqd", probs.astype(v_blocks.dtype),
+                     v_blocks, preferred_element_type=jnp.float32)
+    return out.astype(qb.dtype)
+
+
+def _attend_band(
+    q: jax.Array,  # [B, H, T, D]
+    k_buf: jax.Array,  # [B, KH, R + T, D]: row s holds position pos - R + s
+    v_buf: jax.Array,
+    pos,  # scalar: position of q[..., 0, :]
+    window: int,
+    rows: int,  # R: rows of history ahead of the chunk's own
+) -> jax.Array:
+    """Windowed attention of a chunk over a buffer of contiguous positions
+    (``rows`` rows of history, then the chunk's own keys): query ``t``
+    sees keys ``j`` with ``0 <= t - j < window`` and ``j >= 0``. Where the
+    chunk is whole blocks of ``rows`` queries (``window <= rows``), a
+    block's scores are taken against its own keys and the block before it
+    alone: ``T x 2R`` scores a head, not ``T x (R + T)``, at any chunk
+    length. Returns ``[B, H, T, D]``."""
+    b, n_heads, t, d = q.shape
+    kvh = k_buf.shape[1]
+    blk = rows if t % rows == 0 else t
+    nb = t // blk
+    qb = q.reshape(b, kvh, n_heads // kvh, nb, blk, d)
+
+    def blocks(buf):
+        """``[B, KH, nb, R + blk, D]``: block ``n``'s keys are buffer rows
+        ``n * blk .. n * blk + R + blk``."""
+        if nb == 1:
+            return buf[:, :, None]
+        parts = buf.reshape(b, kvh, nb + 1, blk, d)
+        return jnp.concatenate([parts[:, :, :-1], parts[:, :, 1:]], axis=3)
+
+    span = rows + blk
+    qi = jax.lax.broadcasted_iota(jnp.int32, (nb, blk, span), 1)
+    si = jax.lax.broadcasted_iota(jnp.int32, (nb, blk, span), 2)
+    first = jax.lax.broadcasted_iota(jnp.int32, (nb, blk, span), 0) * blk
+    behind = rows + qi - si  # query position - key position
+    kpos = jnp.asarray(pos, jnp.int32) - rows + first + si
+    mask = (behind >= 0) & (behind < window) & (kpos >= 0)
+    out = _attend_blocks(qb, blocks(k_buf), blocks(v_buf), mask)
+    return out.reshape(b, n_heads, t, d)
+
+
+def window_attention_block(
+    x: jax.Array,  # [B, T, hidden]
+    wq: jax.Array,
+    wk: jax.Array,
+    wv: jax.Array,
+    wo: jax.Array,
+    ring_k: jax.Array,  # [L, B, kv_heads, R, D]: the carried rings
+    ring_v: jax.Array,
+    cos: jax.Array,
+    sin: jax.Array,
+    pos,
+    num_heads: int,
+    num_kv_heads: int,
+    window: int,
+    layer: jax.Array,
+    qk_norm: tuple | None = None,  # (q weight [D], k weight [D], eps)
+    valid: jax.Array | None = None,  # [B]: a bucketed chunk's true tokens
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One attention sublayer through a sliding window, over a ring of
+    ``R`` rows a stream (:func:`cake_tpu.ops.kvcache.ring_write`): query
+    ``t`` sees keys ``j`` with ``0 <= t - j < window``. Returns
+    ``(attn_out [B, T, hidden], ring_k, ring_v)``, the rings whole and
+    written in place.
+
+    One token (``T == 1``; ``pos`` scalar or ``[B]``): the new row is
+    written, then the ring is read whole: each row's position follows
+    from its index and the stream's position
+    (:func:`cake_tpu.ops.kvcache.ring_positions`), and a row the stream
+    has not written, or one outside the window, is masked: a ring is never
+    zeroed. XLA's attention over ``R`` rows; no kernel (128 rows a stream
+    is a quarter of the decode kernel's one block).
+
+    A chunk (``T > 1``, ``pos`` scalar): the ring's rows are put in
+    position order ahead of the chunk's own keys and the chunk attends
+    that buffer band by band (:func:`_attend_band`); then the chunk's
+    newest ``R`` true rows are written (``valid``: a bucket's padding
+    never enters a ring)."""
+    b, t, _ = x.shape
+    rows = ring_k.shape[3]
+    q, k, v = _project_heads(x, wq, wk, wv, num_heads, num_kv_heads,
+                             qk_norm=qk_norm)
+    d = q.shape[-1]
+    q = apply_rope(q, cos, sin, pos)
+    k = apply_rope(k, cos, sin, pos)
+    pos = jnp.asarray(pos, jnp.int32)
+    if t == 1:
+        ring_k, ring_v = kv.ring_write(ring_k, ring_v, k, v, pos, layer)
+        held = kv.ring_positions(pos, rows)  # [R] or [B, R]
+        seen = (held >= 0) & (pos[..., None] - held < window)
+        out = _attend_blocks(  # one block of one query, R keys
+            q.reshape(b, num_kv_heads, -1, 1, 1, d),
+            kv.layer_view(ring_k, layer)[:, :, None],
+            kv.layer_view(ring_v, layer)[:, :, None],
+            jnp.broadcast_to(seen, (b, rows))[:, None, None, None, None, :])
+        out = out.reshape(b, num_heads, 1, d)
+    else:
+        def ahead(ring, new):
+            """The ring's rows in position order (``pos - R .. pos - 1``),
+            then the chunk's own."""
+            old = jnp.roll(kv.layer_view(ring, layer), -pos, axis=2)
+            return jnp.concatenate([old, new.astype(ring.dtype)], axis=2)
+
+        out = _attend_band(q, ahead(ring_k, k), ahead(ring_v, v), pos,
+                           window, rows)
+        ring_k, ring_v = kv.ring_write(ring_k, ring_v, k, v, pos, layer,
+                                       valid=valid)
+    out = out.transpose(0, 2, 1, 3).reshape(b, t, num_heads * d)
+    return quant.dense(out, wo), ring_k, ring_v

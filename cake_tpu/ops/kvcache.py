@@ -72,7 +72,8 @@ def quant_kv(x: jax.Array) -> QuantizedKV:
 
 
 @partial(jax.tree_util.register_dataclass,
-         data_fields=["k", "v", "state", "conv"], meta_fields=[])
+         data_fields=["k", "v", "state", "conv", "ring_k", "ring_v"],
+         meta_fields=[])
 @dataclasses.dataclass
 class KVCache:
     """Preallocated per-layer key/value buffers.
@@ -96,15 +97,23 @@ class KVCache:
     state-space layer's ``[L_rec, B, d_state, d_inner]``, ops/mamba.py)
     and ``conv [L_rec, B, taps - 1, channels]`` hold what such a layer
     keeps a stream, whatever its length. Both are None where no layer is
-    recurrent. Every buffer
-    is ``[layers of its kind, batch, ...]``, so a slot's whole state is
-    index ``b`` of axis 1 of every leaf.
+    recurrent. Where some layers attend through a window
+    (``LlamaConfig.windowed``), ``k``/``v`` have a layer for each FULL
+    layer, and ``ring_k``/``ring_v [L_window, B, KH, R, D]`` hold the
+    window layers' rows: ``R = config.ring_rows`` rows a stream whatever
+    ``max_seq``, position ``p`` at row ``p % R`` (:func:`ring_write`). A
+    ring is never zeroed: what an earlier stream or chunk left in it is
+    told from the live rows by position (:func:`ring_positions`). Every
+    buffer is ``[layers of its kind, batch, ...]``, so a slot's whole
+    state is index ``b`` of axis 1 of every leaf.
     """
 
     k: jax.Array | QuantizedKV
     v: jax.Array | QuantizedKV
     state: jax.Array | None = None
     conv: jax.Array | None = None
+    ring_k: jax.Array | None = None
+    ring_v: jax.Array | None = None
 
     @property
     def num_layers(self) -> int:
@@ -162,6 +171,19 @@ def init_cache(
         rec["state"] = jnp.zeros((n, batch, *shape), jnp.float32)
         n, *shape = plan["conv"]
         rec["conv"] = jnp.zeros((n, batch, *shape), dt)
+    if "ring" in plan:
+        if num_layers is not None:
+            raise ValueError("a model whose window layers hold a ring is "
+                             "cached whole (no layer ranges)")
+        if quant == "int8":
+            raise ValueError(
+                "an int8 cache is not wired for a model whose window "
+                "layers hold a ring (the ring is already a fraction of the "
+                "rows; its few full layers are the rest)")
+        L = plan["rows"][0]
+        n, kvh, r, kw, vw = plan["ring"]
+        rec["ring_k"] = jnp.zeros((n, batch, kvh, r, kw), dt)
+        rec["ring_v"] = jnp.zeros((n, batch, kvh, r, vw), dt)
     if quant == "int8":
         if config.latent:
             raise ValueError(
@@ -277,3 +299,67 @@ def update_layer(
         return write_buf(cache, new.astype(cache.dtype))
 
     return write(k_cache, k_new), write(v_cache, v_new)
+
+
+def ring_positions(last: jax.Array, rows: int) -> jax.Array:
+    """The position each row of a ring of ``rows`` rows holds once
+    position ``last`` (scalar or ``[B]``) has been written: the largest
+    ``p <= last`` with ``p % rows == row`` (``[rows]`` or ``[B, rows]``).
+    Negative: the stream has not written that row yet, and what it holds
+    is an earlier stream's, which the reader masks."""
+    last = jnp.asarray(last, jnp.int32)[..., None]
+    row = jnp.arange(rows, dtype=jnp.int32)
+    return last - jnp.mod(last - row, rows)
+
+
+def ring_write(
+    ring_k: jax.Array,  # [L, B, KH, R, D], the carried buffers
+    ring_v: jax.Array,
+    k_new: jax.Array,  # [B, KH, T, D]
+    v_new: jax.Array,
+    pos: jax.Array,  # scalar, or [B] where T == 1
+    layer: jax.Array,
+    valid: jax.Array | None = None,  # [B]: the chunk's true tokens
+) -> tuple[jax.Array, jax.Array]:
+    """Write the rows of positions ``pos .. pos + T - 1`` into layer
+    ``layer`` of the carried rings, each at its position modulo ``R``, in
+    place. One token (``T == 1``): a ``dynamic_update_slice`` of one row a
+    stream, as :func:`update_layer` writes it. A chunk: of its first
+    ``valid[b]`` tokens (a bucket's padding never enters a ring) the
+    newest ``R`` land, every ring row taking the one position it is due
+    (``ring_positions`` of the chunk's last true token) or keeping what it
+    held where that position lies before the chunk."""
+    pos = jnp.asarray(pos, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
+    b, _, t, _ = k_new.shape
+    rows = ring_k.shape[3]
+    zero = jnp.zeros((), jnp.int32)
+    if t == 1:
+        at = jnp.broadcast_to(jnp.mod(pos, rows), (b,))
+
+        def put(ring, new):
+            new = new.astype(ring.dtype)
+            for i in range(b):  # unrolled: see update_layer
+                ring = jax.lax.dynamic_update_slice(
+                    ring, new[None, i:i + 1],
+                    (layer, jnp.asarray(i, jnp.int32), zero, at[i], zero))
+            return ring
+
+        return put(ring_k, k_new), put(ring_v, v_new)
+    if pos.ndim:
+        raise ValueError("a chunk enters a ring from one position for "
+                         "all its rows (per-row chunk frontiers are not "
+                         "wired for window layers)")
+    count = (jnp.full((b,), t, jnp.int32) if valid is None
+             else jnp.asarray(valid, jnp.int32))
+    due = ring_positions(pos + count - 1, rows)  # [B, R]
+    src = jnp.clip(due - pos, 0, t - 1)[:, None, :, None]
+    fresh = (due >= pos)[:, None, :, None]
+
+    def put(ring, new):
+        old = jax.lax.dynamic_index_in_dim(ring, layer, 0, keepdims=False)
+        rows_new = jnp.take_along_axis(new.astype(ring.dtype), src, axis=2)
+        return jax.lax.dynamic_update_index_in_dim(
+            ring, jnp.where(fresh, rows_new, old), layer, 0)
+
+    return put(ring_k, k_new), put(ring_v, v_new)

@@ -76,6 +76,14 @@ def validate_shardable(config: LlamaConfig, num_stages: int, tp: int,
             "one stage with tp = 1 and sp = 1; only --ep shards it (its "
             "held experts)"
         )
+    if config.windowed and (num_stages > 1 or tp > 1 or sp > 1):
+        raise ValueError(
+            "a model of window and full attention layers (stacks of "
+            "several kinds of layer, a ring of rows beside the full "
+            "layers' cache) runs as one stage with tp = 1 and sp = 1; only "
+            "--ep shards it (its held experts): window layers under tp or "
+            "stages are not wired"
+        )
     if config.state_space and (num_stages > 1 or tp > 1 or sp > 1
                                or ep > 1):
         raise ValueError(
@@ -212,7 +220,7 @@ CACHE_SPEC = P(STAGE, DP, TP, SP, None)
 
 
 def cache_specs(kv_quant: str | None = None, batch_replicated: bool = False,
-                recurrent: bool = False):
+                recurrent: bool = False, ring: bool = False):
     """PartitionSpec pytree matching :func:`cake_tpu.ops.kvcache.init_cache`'s
     structure: plain buffers take CACHE_SPEC; int8 buffers take it for the
     q bytes and the same layout minus head_dim for the per-slot scales.
@@ -224,7 +232,10 @@ def cache_specs(kv_quant: str | None = None, batch_replicated: bool = False,
     state ``[L, B, ...]`` (a delta-rule layer's ``[L, B, H, d_k, d_v]``, a
     state-space layer's ``[L, B, d_state, d_inner]``) and convolution tail
     ``[L, B, taps - 1, C]``, batch over dp and no later axis sharded (such
-    a model runs as one stage with tp = 1)."""
+    a model runs as one stage with tp = 1). ``ring``
+    (``LlamaConfig.windowed``): it also holds the window layers' rings
+    ``[L, B, KH, R, D]``, batch over dp and no later axis sharded (as
+    above)."""
     from cake_tpu.ops.kvcache import KVCache, QuantizedKV
 
     bd = None if batch_replicated else DP
@@ -232,10 +243,12 @@ def cache_specs(kv_quant: str | None = None, batch_replicated: bool = False,
     if kv_quant == "int8":
         half = QuantizedKV(q=spec, scale=P(STAGE, bd, TP, SP))
         return KVCache(k=half, v=half)
+    held = {}  # what a stream holds whatever its length, by the kind
     if recurrent:
-        return KVCache(k=spec, v=spec, state=P(STAGE, bd),
-                       conv=P(STAGE, bd))
-    return KVCache(k=spec, v=spec)
+        held.update(state=P(STAGE, bd), conv=P(STAGE, bd))
+    if ring:
+        held.update(ring_k=P(STAGE, bd), ring_v=P(STAGE, bd))
+    return KVCache(k=spec, v=spec, **held)
 
 
 def shard_params(params: dict, mesh: Mesh) -> dict:
@@ -250,7 +263,8 @@ def shard_cache(cache, mesh: Mesh):
     from cake_tpu.ops.kvcache import QuantizedKV
 
     specs = cache_specs("int8" if isinstance(cache.k, QuantizedKV) else None,
-                        recurrent=cache.state is not None)
+                        recurrent=cache.state is not None,
+                        ring=cache.ring_k is not None)
     return jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), cache, specs
     )
@@ -283,7 +297,7 @@ def init_cache_on_mesh(config, mesh: Mesh, batch: int = 1,
     make = _CACHE_PROGRAMS.get(key)
     if make is None:
         specs = cache_specs(quant, batch_replicated=batch_replicated,
-                            recurrent=config.recurrent)
+                            recurrent=config.recurrent, ring=config.windowed)
         out_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                               is_leaf=lambda x: isinstance(x, P))
 

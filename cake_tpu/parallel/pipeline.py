@@ -196,10 +196,10 @@ def _pipelined_prefill_layers(
 def _valid_rows(config: LlamaConfig, tokens: jax.Array,
                 last_index: jax.Array):
     """The true tokens of each row of a bucketed chunk, for a model whose
-    layers hold a recurrent state (None otherwise: rows past a frontier
-    hide themselves): up to and with the row's last true token, the whole
-    chunk where that token lies in a later one."""
-    if not config.recurrent:
+    layers hold a recurrent state or a ring of rows (None otherwise: rows
+    past a frontier hide themselves): up to and with the row's last true
+    token, the whole chunk where that token lies in a later one."""
+    if not (config.recurrent or config.windowed):
         return None
     t = tokens.shape[1]
     return jnp.broadcast_to(jnp.minimum(last_index + 1, t),
@@ -371,7 +371,8 @@ def build_sharded_decode(
 
         kv_specs = pool_specs(kv_quant)
     else:
-        kv_specs = cache_specs(kv_quant, recurrent=config.recurrent)
+        kv_specs = cache_specs(kv_quant, recurrent=config.recurrent,
+                               ring=config.windowed)
     in_specs = [
         param_specs(params_like),
         P(DP),
@@ -472,7 +473,8 @@ def build_sharded_decode(
 def moe_counted(config: LlamaConfig) -> bool:
     """Whether this model's serving decode programs count the routed pairs
     that fall on held experts (an expert model told its share)."""
-    return config.latent and config.n_routed_experts > 0
+    return ((config.latent or config.windowed)
+            and config.n_routed_experts > 0)
 
 
 def _head_split_safe(hw, S: int) -> bool:
@@ -768,14 +770,14 @@ def build_admit_prefill(config: LlamaConfig, plan: MeshPlan,
             param_specs(params_like),
             P(None, None),
             cache_specs(kv_quant, batch_replicated=True,
-                        recurrent=config.recurrent),
+                        recurrent=config.recurrent, ring=config.windowed),
             P(),
             P(None),
         ),
         out_specs=(
             P(None, None),
             cache_specs(kv_quant, batch_replicated=True,
-                        recurrent=config.recurrent),
+                        recurrent=config.recurrent, ring=config.windowed),
         ),
         check_vma=False,
     )
@@ -1083,7 +1085,8 @@ def build_sharded_prefill(config: LlamaConfig, plan: MeshPlan,
         logits = _head_logits(params, x_last, config)
         return logits, cache
 
-    kv_specs = cache_specs(kv_quant, recurrent=config.recurrent)
+    kv_specs = cache_specs(kv_quant, recurrent=config.recurrent,
+                           ring=config.windowed)
     in_specs = [
         param_specs(params_like),
         P(DP, None) if chunk_mode else P(DP, SP),

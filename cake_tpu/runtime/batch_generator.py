@@ -392,6 +392,15 @@ class BatchGenerator:
                 "speculation (spec_k) is not wired for a model whose "
                 "layers hold a recurrent state: a rejected proposal has "
                 "already advanced the state, and nothing rolls it back")
+        if config.windowed and (self._paged or spec_k):
+            raise ValueError(
+                "kv_layout='paged' and speculation (spec_k) are not wired "
+                "for a model whose window layers hold a ring of rows: the "
+                "page pool (and with it the disagg snapshot and the spill "
+                "tier) holds rows of every layer at every position and no "
+                "ring, and a rejected proposal has already overwritten a "
+                "ring row that nothing restores; serve this family with "
+                "the slot layout and no speculation")
         self._page_size = int(kv_page_size)
         self._pool_pages_req = kv_pool_pages
         if self._paged:
@@ -653,14 +662,16 @@ class BatchGenerator:
         # hits SHARE physical pages via refcounts instead of copying a
         # staged row, and eviction is pool-pressure-driven.
         self._prefix_entries = max(0, prefix_cache_entries)
-        if config.recurrent and (self._prefix_entries or prefix_share_min):
-            # a stored row's recurrent state is the state at the END of
-            # the prompt that left it, not at the shared prefix's end: a
-            # hit would start from the wrong state. Every prompt of such
-            # a model is prefilled whole.
+        if (config.recurrent or config.windowed) and (
+                self._prefix_entries or prefix_share_min):
+            # a stored row's recurrent state (or window layers' ring) is
+            # the one at the END of the prompt that left it, not at the
+            # shared prefix's end: a hit would start from the wrong state
+            # (from a ring whose newest rows lie past the prefix). Every
+            # prompt of such a model is prefilled whole.
             logging.getLogger("cake_tpu.batch_generator").info(
-                "prefix reuse is off: a recurrent state has no prefix to "
-                "share")
+                "prefix reuse is off: a recurrent state or a ring of rows "
+                "has no prefix to share")
             self._prefix_entries = self._prefix_share_min = 0
         self._prefix_store = PrefixLRU(self._prefix_entries)
         self._prefix_block = max(1, prefix_block)
@@ -1185,10 +1196,26 @@ class BatchGenerator:
         held = sum(x.nbytes for x in jax.tree.leaves(self.cache))
         state = sum(x.nbytes for x in jax.tree.leaves(
             (self.cache.state, self.cache.conv)))
+        rings = sum(x.nbytes for x in jax.tree.leaves(
+            (self.cache.ring_k, self.cache.ring_v)))
         obs_metrics.gauge("cache.bytes").set(held)
         obs_metrics.gauge("cache.row_bytes").set(
-            (held - state) / (self.cache.num_layers * self.cache.batch
-                              * self.cache.max_seq))
+            (held - state - rings) / (self.cache.num_layers
+                                      * self.cache.batch
+                                      * self.cache.max_seq))
+        if rings:
+            # window layers beside full ones: what the row buffers of both
+            # kinds hold, and what they would were every window layer a
+            # full one at the capacity
+            n_ring = self.cache.ring_k.shape[0]
+            obs_metrics.gauge("cache.ring_rows").set(
+                self.cache.ring_k.shape[3])
+            obs_metrics.gauge("cache.rows_bytes").set(held - state)
+            obs_metrics.gauge("cache.rows_bytes_full").set(
+                (held - state - rings)
+                * (1 + n_ring / self.cache.num_layers))
+            obs_metrics.gauge("attn.layers_swa").set(n_ring)
+            obs_metrics.gauge("attn.layers_full").set(self.cache.num_layers)
         obs_metrics.gauge("cache.state_bytes").set(state)
         obs_metrics.gauge("cache.state_bytes_per_stream").set(
             state / self.cache.batch)
@@ -3414,9 +3441,12 @@ class BatchGenerator:
     def _count_kv_blocks(self, pos: np.ndarray, steps: int) -> None:
         """Add what ``steps`` decode steps from the frontiers ``pos`` (as
         dispatched) read of a layer's cache, in the decode kernel's
-        blocks, and what is reserved (``attn.kv_blocks_*``)."""
+        blocks, and what is reserved (``attn.kv_blocks_*``). Where window
+        and full layers are mixed, a full layer's: a ring is read whole."""
+        window = (None if self.config.windowed
+                  else self.config.sliding_window)
         read, reserved = pk.decode_blocks_read(
-            pos, steps, self.max_seq, window=self.config.sliding_window)
+            pos, steps, self.max_seq, window=window)
         _KV_BLOCKS_READ.inc(read)
         _KV_BLOCKS_RESERVED.inc(reserved)
 

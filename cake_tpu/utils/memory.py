@@ -66,6 +66,9 @@ def hbm_budget(
         raise ValueError("quantized linears are not wired for a state-space "
                          "model (its mixer's projections have no int8 form "
                          "yet)")
+    if c.windowed and quant:
+        raise ValueError("quantized linears are not wired for a model of "
+                         "window and full attention layers")
     if c.segmented:
         return _latent_budget(c, ep, S, batch, lin_el, scale_el, el,
                               cache_bytes_per_el)
@@ -141,7 +144,8 @@ def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
     ep; the cache is the latent row (``LlamaConfig.cache_row_values`` a
     token a layer), not per-head keys and values, for the layers that
     keep rows, and a delta-rule or state-space layer's state and
-    convolution tail a stream for the others (``LlamaConfig.cache_plan``). One stage, tp = sp = 1
+    convolution tail a stream for the others, and a ring of ``R`` rows a
+    stream for a window layer (``LlamaConfig.cache_plan``). One stage, tp = sp = 1
     (``mesh.validate_shardable``)."""
     import math
 
@@ -170,6 +174,9 @@ def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
     if "state" in plan:  # float32 state, the tail in the serving type
         kv_bytes += batch * (math.prod(plan["state"]) * 4
                              + math.prod(plan["conv"]) * el)
+    if "ring" in plan:  # window layers: R rows a stream whatever S
+        n, heads, rows, k_width, v_width = plan["ring"]
+        kv_bytes += batch * n * heads * rows * (k_width + v_width) * cache_el
     return {
         "layers": int(layer_bytes),
         "embed_replicated": int(embed_bytes),

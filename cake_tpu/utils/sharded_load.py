@@ -26,12 +26,14 @@ contiguous original-row range, so the reads stay single slices.
 from __future__ import annotations
 
 import itertools
+import logging
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from cake_tpu.models.config import LlamaConfig
+from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.parallel.mesh import EP, STAGE, TP
 from cake_tpu.utils.weights import (
     _BIAS_MAP,
@@ -613,12 +615,19 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
         raise NotImplementedError(
             "quantized linears are not wired for a state-space model (its "
             "mixer's projections have no int8 form yet); serve it in bf16")
+    if tier is not None and config.windowed:
+        raise NotImplementedError(
+            "quantized linears are not wired for a model of window and "
+            "full attention layers (its q, k and v projections are not "
+            "among the shared-expert family's int8 linears); serve it in "
+            "bf16")
     prequantized = check_prequantized(reader.name_to_file, quantize)
     if not tie_word_embeddings and detect_tied_head(
             reader.name_to_file, model_dir, "cake_tpu.sharded_load"):
         tie_word_embeddings = True
     dt = _np_dtype(config.dtype)
     scales: dict[str, np.ndarray] = {}  # a linear's scale, once computed
+    asked: set[str] = set()  # every stored tensor some leaf names
 
     def stored(name: str) -> bool:
         return prequantized and f"{name}.q8" in reader.name_to_file
@@ -645,6 +654,8 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
         """One stacked leaf: ``names_of(i, [e])`` is the stored tensor of
         each leading index; 1-D tensors and plain linears in the serving
         type, quantized linears as (q, scale)."""
+        asked.update(names_of(*at) for at in np.ndindex(*lead))
+
         def gather(index, read):
             grids = [range(*sl.indices(n)) for sl, n in zip(index, lead)]
             out = np.stack([read(names_of(*ids))
@@ -692,7 +703,7 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
     h, v = config.hidden_size, config.vocab_size
     head_name = ("model.embed_tokens.weight" if tie_word_embeddings
                  else "lm_head.weight")
-    return {
+    params = {
         "layers": layers,
         "embed": stacked(lambda: "model.embed_tokens.weight", (), (v, h),
                          P(), False, False),
@@ -701,3 +712,14 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
         "lm_head": stacked(lambda: head_name, (), (h, v), P(), True,
                            tier is not None),
     }
+    # what the checkpoint stores and no leaf names (a next-token prediction
+    # block's ``mtp.*``, layers past the served depth), a tensor once
+    # whatever forms it is stored in
+    skipped = {n.removesuffix(".q8").removesuffix(".scale")
+               for n in reader.name_to_file} - asked
+    obs_metrics.counter("load.tensors_skipped").inc(len(skipped))
+    if skipped:
+        logging.getLogger("cake_tpu.sharded_load").info(
+            "%d stored tensors are no part of the served model and were "
+            "not read (%s ...)", len(skipped), sorted(skipped)[0])
+    return params

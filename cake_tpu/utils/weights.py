@@ -117,6 +117,21 @@ _HYBRID_EXTRA_MAP = {
     "b_router": ("mlp.gate.expert_bias", False),
 }
 
+# Window and full grouped-query attention mixed by layer, with the
+# shared-expert feed-forward (`model_type` "exaone_moe"; the names are
+# ASSUMED, the benchmark configuration lists them: Llama's for the
+# attention with a `q_norm` / `k_norm` weight a head width wide,
+# DeepSeek-V3's for the experts and for the router's bias). The next-token
+# prediction block (`mtp.*`) is never asked for.
+_WINDOWED_MAP = {
+    **{k: _LAYER_MAP[k] for k in ("attn_norm", "wq", "wk", "wv", "wo",
+                                  "mlp_norm")},
+    "q_norm": ("self_attn.q_norm.weight", False),
+    "k_norm": ("self_attn.k_norm.weight", False),
+    **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP,
+    "b_router": ("mlp.gate.e_score_correction_bias", False),
+}
+
 # State-space layers beside grouped-query attention (`model_type` "jamba",
 # Hugging Face's own names, which config.json does not carry: the benchmark
 # configuration lists them): the mixer under `mamba.`, its convolution as
@@ -169,6 +184,8 @@ def latent_stack_plan(config) -> dict:
              **_HYBRID_EXTRA_MAP}
     if config.state_space:
         names = _STATE_SPACE_MAP
+    elif config.windowed:
+        names = _WINDOWED_MAP
     plan = {}
     for run, seg in plan_segments(config):
         shapes = segment_shapes(config, seg)
@@ -195,9 +212,12 @@ def hf_layout(ours: str, w: np.ndarray, transpose: bool) -> np.ndarray:
 
 def is_latent_checkpoint(name_to_file: dict) -> bool:
     """Whether the checkpoint stores latent-attention or state-space
-    tensors: a model of several layer stacks, which loads whole."""
+    tensors, or a routing bias beside per-head attention (window and full
+    layers mixed): a model of several layer stacks, which loads whole."""
     return any(".self_attn.kv_a_proj_with_mqa.weight" in n
-               or ".mamba.in_proj.weight" in n for n in name_to_file)
+               or ".mamba.in_proj.weight" in n
+               or ".mlp.gate.e_score_correction_bias" in n
+               for n in name_to_file)
 
 
 def hf_layer_map(num_experts: int = 0, attention_bias: bool = False,

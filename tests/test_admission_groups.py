@@ -25,8 +25,8 @@ import pytest
 
 from cake_tpu.constrain import Guide, build_token_dfa
 from cake_tpu.models import llama
-from cake_tpu.models.config import (tiny, tiny_jamba, tiny_kda_hybrid,
-                                    tiny_mla_moe, tiny_moe)
+from cake_tpu.models.config import (tiny, tiny_exaone_moe, tiny_jamba,
+                                    tiny_kda_hybrid, tiny_mla_moe, tiny_moe)
 from cake_tpu.obs import catalog
 from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.ops.sampling import SamplerSettings
@@ -36,7 +36,8 @@ from cake_tpu.runtime.batch_generator import BatchGenerator
 TIGHT = 1e-4
 GREEDY = dict(temperature=0.0, repeat_penalty=1.1)
 FAMILIES = {"gqa": tiny, "mixtral": tiny_moe, "mla_moe": tiny_mla_moe,
-            "kda_hybrid": tiny_kda_hybrid, "jamba": tiny_jamba}
+            "kda_hybrid": tiny_kda_hybrid, "jamba": tiny_jamba,
+            "exaone_moe": tiny_exaone_moe}
 _RNG = np.random.default_rng(37)
 # one prompt per bucket (16, 32, 64), and a fourth of the first's
 PROMPTS = [[int(t) for t in _RNG.integers(3, 200, n)] for n in (9, 20, 40, 12)]
@@ -179,6 +180,14 @@ def test_each_member_of_a_launch_equals_its_admission_alone(family, name, n):
             # padding (its own 16 or 32 against the launch's 64) and the
             # repeated row have touched nothing
             for leaf in ("state", "conv"):
+                a = getattr(have["cache"], leaf)
+                b = getattr(want["cache"], leaf)
+                assert np.abs(b).max() > 0
+                np.testing.assert_allclose(a, b, atol=TIGHT, rtol=TIGHT)
+        if cfg.windowed:
+            # a member's rings: its own newest rows and nothing of the
+            # bucket's padding or of the repeated row
+            for leaf in ("ring_k", "ring_v"):
                 a = getattr(have["cache"], leaf)
                 b = getattr(want["cache"], leaf)
                 assert np.abs(b).max() > 0

@@ -422,8 +422,12 @@ def test_a_reader_reads_its_series_and_nothing_from_an_older_program(metric):
 
 def test_the_benchmark_declares_the_seven_at_the_end_of_per_layer():
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    closed = [w["name"] for w in bench["workloads"]
-              if w["name"] != "mistral7b-int8.chat-r80"]
+    # the closed-loop cells that report a time to first token (a later
+    # cell whose first-token wait spreads too widely between seeds does
+    # not: PR 40)
+    closed = next(m for m in bench["end_to_end"]
+                  if m["name"] == "ttft_mean_ms")["workloads"]
+    assert "mistral7b-int8.chat-r80" not in closed and len(closed) >= 5
     added = bench["per_layer"][30:37]
     assert [m["name"] for m in added] == [
         "engine.admit_launch_wait_mean_ms", "engine.admit_rows_wait_mean_ms",
@@ -441,12 +445,13 @@ def test_the_benchmark_declares_the_seven_at_the_end_of_per_layer():
 
 
 def test_the_benchmark_declares_admits_per_launch_after_them():
-    """PR 37's one metric: the last entry, reported in every cell (each
-    reports ``tpot_p50_ms`` and launches admissions), more is better."""
+    """PR 37's one metric: the last entry of its day (later PRs append
+    after it), reported in every cell (each reports ``tpot_p50_ms`` and
+    launches admissions), more is better."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     assert bench["per_layer"][37] == {
         "name": "engine.admits_per_launch", "unit": "admissions",
         "better": "higher", "source": "program_counter", "layer": "engine",
         "moves": "tpot_p50_ms"}
-    assert len(bench["per_layer"]) == 38
+    assert len(bench["per_layer"]) >= 38
     assert catalog.kind_of("engine.admit_launches") == catalog.COUNTER

@@ -520,7 +520,7 @@ def _family_programs(topo, config, slots: int, window: int, bucket: int):
             jax.eval_shape(lambda: init_cache(config, batch=batch,
                                               max_seq=window)),
             cache_specs(None, batch_replicated=batch == 1,
-                        recurrent=config.recurrent))
+                        recurrent=config.recurrent, ring=config.windowed))
 
     settings = SamplerSettings(temperature=0.0)
     decode = build_sharded_decode(
@@ -713,6 +713,61 @@ def test_state_space_programs_move_no_cache_and_no_state(topo, as_on_chip):
     # splice is undonated
     assert args + temps + 0.02 * GIB + 0.35 * GIB < HBM_GIB["v5 lite"] * GIB
     assert admit.memory_analysis().temp_size_in_bytes < 0.3 * GIB
+
+
+def test_window_and_full_programs_move_no_cache_and_no_ring(topo, as_on_chip):
+    """The window + full attention family's two serving programs at
+    K-EXAONE's published widths, the cell ``kexaone-ep8-cut.decode-doc``
+    itself: layers 0-6 (a dense window layer, two sparse window layers,
+    the full layer, three sparse window layers: four scanned segments),
+    16 of 128 experts, 32 slots x 4096 rows. The chip's compiler takes
+    them; the cache's two kinds of rows (one full layer's ``[1, 32, 8,
+    4096, 128]`` and six rings ``[6, 32, 8, 128, 128]``) are carried
+    through every segment and written in place, so nothing of either
+    shape is allocated or copied (a ``lax.switch`` over the kinds inside
+    one scan, tried first, copied the rings in and out of every branch:
+    PERF.md section 7); no segment's expert stack is written out before
+    use (the 2048-row admission takes the sorted form, whose kernel reads
+    the whole stacks; the 32-row step the dense one, on a scan's slice in
+    place); the full layer's decode
+    attention is ``flash_decode`` on its rows, the window layers' XLA's
+    over 128 ring rows. Sizes: 9.73 GiB of weights + 0.59 GiB of cache in
+    (where six whole window layers would be 3.5 GiB), 0.11 GiB of
+    temporaries; the 2048-row admission 0.80 GiB beside its staging row
+    (a band of blocks: 2048 x 256 scores a head, not 2048 x 2048)."""
+    from cake_tpu.models.config import kexaone_ep8
+    from cake_tpu.utils.chips import HBM_GIB
+
+    slots, window = 32, 4096
+    config = kexaone_ep8(num_hidden_layers=7, vocab_size=19200,
+                         max_seq_len=window)
+    decode, admit = _family_programs(topo, config, slots, window, 2048)
+    for compiled, batch in ((decode, slots), (admit, 1)):
+        for shape in (f"bf16[1,{batch},8,{window},128]",
+                      f"bf16[6,{batch},8,128,128]"):
+            assert _cache_sized_moves(compiled, shape) == [], shape
+        # no layer's expert stack is left behind as a value of its own
+        assert _expert_stack_moves(compiled, "bf16", 16, 6144, 2048) == []
+
+    def calls(compiled, kernel):
+        return [line for line in compiled.as_text().splitlines()
+                if "custom-call(" in line and "tpu_custom_call" in line
+                and kernel in line]
+
+    assert len(calls(decode, "flash_decode")) == 1  # the full layer's
+    # the step's 256 pairs hit 0.87 of the 16 held experts: the dense form
+    # stays (ops/moe.py expert_form); the admission sorts, in each of the
+    # three sparse segments
+    assert _grouped_matmul_calls(decode) == 0
+    assert _grouped_matmul_calls(admit) == 9
+    args, temps = _donated_bytes(decode)
+    assert 10.25 * GIB < args < 10.4 * GIB, args / GIB  # 9.73 + 0.59
+    assert temps < 0.25 * GIB, temps / GIB
+    m = admit.memory_analysis()
+    assert m.temp_size_in_bytes < 1.0 * GIB, m.temp_size_in_bytes / GIB
+    # the admission beside the live cache and the undonated splice's copy
+    assert (args + temps + m.temp_size_in_bytes + 0.6 * GIB
+            < 13 / 16 * HBM_GIB["v5 lite"] * GIB)
 
 
 # sha256[:16] of the lowered text of each family's serving programs at tiny
