@@ -267,12 +267,14 @@ def _attend_xla(
 
 
 def _project_heads(x, wq, wk, wv, num_heads: int, num_kv_heads: int,
-                   bq=None, bk=None, bv=None, qk_norm: tuple | None = None):
+                   bq=None, bk=None, bv=None, qk_norm: tuple | None = None,
+                   rotated: bool = True):
     """``x [B, T, hidden]`` through the q, k and v projections (with their
     biases, where a family has them), as heads ``[B, heads, T, D]``; with
     ``qk_norm`` ``(q weight [D], k weight [D], eps)`` each head of q and k
     RMS-normed (one weight for all heads), which comes before any
-    rotation."""
+    rotation. ``rotated``: the caller rotates q and k behind this (its
+    ``cos`` is not None)."""
     b, t, _ = x.shape
     d = quant.out_features(wq) // num_heads
     q = quant.dense(x, wq)
@@ -284,6 +286,23 @@ def _project_heads(x, wq, wk, wv, num_heads: int, num_kv_heads: int,
         k = k + bk
     if bv is not None:
         v = v + bv
+    if rotated or qk_norm is not None:
+        # Keep each product apart from the per-head operations behind it.
+        # Fused with the reshape to heads, the norm over D and the
+        # rotation, a product takes their layout, and the chip's compiler
+        # answers by re-laying the WEIGHT instead of the activation: a
+        # layer's wq and wk sliced out of their stack, written out and
+        # copied transposed before every product (100 MB a layer and step
+        # at 6144 x 8192), an int8 stack transposed whole once a dispatch;
+        # v's slice written out in an admission. Apart, the products read
+        # the stacked parameter where it lies, as wo and the feed-forward
+        # do, at the cost of one pass over q, k and v
+        # (tests/test_chip_compile.py test_program_moves_no_projection
+        # guards it). Where only the reshape follows (no norm, no
+        # rotation: jamba2-3b's 2560 x 2560) the compiler stages a layer's
+        # slice in fast memory and the step is 0.4% faster that way than
+        # behind a barrier, so those stay fused (PERF.md section 6, PR 41).
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
     q = q.reshape(b, t, num_heads, d).transpose(0, 2, 1, 3)
     k = k.reshape(b, t, num_kv_heads, d).transpose(0, 2, 1, 3)
     v = v.reshape(b, t, num_kv_heads, d).transpose(0, 2, 1, 3)
@@ -368,7 +387,7 @@ def self_attention_block(
     """
     b, t, hidden = x.shape
     q, k, v = _project_heads(x, wq, wk, wv, num_heads, num_kv_heads,
-                             bq, bk, bv, qk_norm)
+                             bq, bk, bv, qk_norm, rotated=cos is not None)
     d = q.shape[-1]
 
     if sp_axis is not None and sp_size > 1:

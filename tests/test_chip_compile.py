@@ -345,6 +345,33 @@ def _expert_stack_moves(compiled, dtype: str, experts: int, k: int,
                            "tuple")]
 
 
+def _projection_moves(compiled, dtype: str, k: int, n: int) -> list[str]:
+    """Instructions that move a projection's weight before its product
+    reads it: outside a fusion, a result of the shape ``[k, n]``, ``[n,
+    k]`` or either under a leading axis (a stack's depth, or 1), that is
+    a ``copy`` (a re-laying), a ``fusion`` (a slice written out) or an
+    asynchronous ``copy-start`` INTO ANOTHER LAYOUT. An asynchronous copy
+    that keeps its operand's order of axes is a prefetch into fast memory,
+    the read the product needs started early, and no move; parameters,
+    tuples and bitcasts move nothing. What the q and k projections cost
+    while the compiler fused each product with the per-head operation
+    behind it (PR 41): 100 MB read and written a layer and step, then
+    transposed."""
+    shape = rf"{dtype}\[(?:\d+,)?(?:{k},{n}|{n},{k})\]"
+    moves = [f"{comp}: {name} ({op}) {got}"
+             for comp, name, got, op, _ in _instructions(compiled)
+             if op in ("copy", "fusion") and re.fullmatch(shape, got)
+             and not comp.startswith("fused_computation")]
+    # an asynchronous copy's result is (destination, source, context)
+    prefetch = re.compile(rf"\s*%?([\w.\-]+) = \(({shape})\{{([\d,]*)\S* "
+                          rf"{shape}\{{([\d,]*)\S* .*\) copy-start\(")
+    for line in compiled.as_text().splitlines():
+        m = prefetch.match(line)
+        if m and m.group(3) != m.group(4):
+            moves.append(f"{m.group(1)} (copy-start) {m.group(2)}")
+    return moves
+
+
 def _grouped_matmul_calls(compiled) -> int:
     """The expert block's kernel calls in the program's text."""
     return sum("custom-call(" in line and "tpu_custom_call" in line
@@ -373,7 +400,6 @@ def _donated_bytes(compiled) -> tuple[int, int]:
 
 
 GIB = 2**30
-LAYER_CACHE = SLOTS * KVH * WINDOW * D * 2 * 2  # B, KVH, S, D, k+v, bf16
 
 
 @pytest.fixture
@@ -384,7 +410,79 @@ def as_on_chip(monkeypatch):
     monkeypatch.setattr(pk, "on_tpu", lambda: True)
 
 
-def test_block_decode_program_fits_one_chip(topo, as_on_chip):
+def _kexaone_cell():
+    """K-EXAONE at the cell ``kexaone-ep8-cut.decode-doc``'s sizes:
+    published widths, layers 0-6, 16 of 128 experts, 4096 rows."""
+    from cake_tpu.models.config import kexaone_ep8
+
+    return kexaone_ep8(num_hidden_layers=7, vocab_size=19200,
+                       max_seq_len=4096)
+
+
+# the attention projections' type and [in, out] in each program that the
+# ``program`` fixture compiles: the dense and sparse int8 block decode by
+# depth, the cell kexaone-ep8-cut.decode-doc's block decode and 2048-row
+# admission
+DENSE_PROJECTIONS = ("s8", ((HID, H * D), (HID, KVH * D)))
+KEXAONE_PROJECTIONS = ("bf16", ((6144, 64 * 128), (6144, 8 * 128)))
+PROGRAMS = {
+    "dense.decode.depth2": DENSE_PROJECTIONS,
+    "dense.decode.depth4": DENSE_PROJECTIONS,
+    "dense.decode.depth32": DENSE_PROJECTIONS,
+    "sparse.decode.depth2": DENSE_PROJECTIONS,
+    "kexaone.decode": KEXAONE_PROJECTIONS,
+    "kexaone.admit2048": KEXAONE_PROJECTIONS,
+}
+
+
+@pytest.fixture(scope="module")
+def program(topo):
+    """``program(name)``: the program ``name`` of ``PROGRAMS`` compiled for
+    one described v5e, once for the tests of this file that share it (ask
+    under ``as_on_chip``)."""
+    compiled = {}
+
+    def get(name: str):
+        if name not in compiled:
+            family, *_, last = name.split(".")
+            if family == "kexaone":  # one call compiles both
+                compiled["kexaone.decode"], compiled["kexaone.admit2048"] = (
+                    _family_programs(topo, _kexaone_cell(), 32, 4096, 2048))
+            else:
+                compiled[name] = _block_decode(
+                    topo, int(last.removeprefix("depth")),
+                    sparse=family == "sparse")
+        return compiled[name]
+
+    return get
+
+
+@pytest.mark.parametrize("name", list(PROGRAMS))
+def test_program_moves_no_projection(program, as_on_chip, name):
+    """No program of the layer loop moves an attention projection's weight
+    before its product reads it (``_projection_moves``): the dense int8
+    block decode at depth 2, 4 and 32, the sparse one, and the cell
+    ``kexaone-ep8-cut.decode-doc``'s block decode and 2048-row admission
+    at published widths. The q and k products are followed by a per-head
+    operation (the reshape to heads, heads ahead, a norm over D, the
+    rotation); fused with it, the product took that operation's layout,
+    and the compiler answered by re-laying the weight: a layer's ``wq
+    [6144, 8192]`` sliced out of its stack, written to a buffer of its
+    own and copied transposed into fast memory, 100 MB read and written a
+    layer and step (0.34 s of 3.83 s busy in the cell, ledger, PR 40), the
+    int8 stacks transposed whole once a dispatch. ``ops/attention.py``
+    ``_project_heads`` keeps the two apart with an optimization barrier
+    wherever a norm or a rotation follows (Jamba's attention has neither
+    and keeps the program it had: ``PR31_TEXTS``); what is left are
+    prefetches of a one-layer stack in the parameter's own layout, the
+    product's read started early."""
+    compiled = program(name)
+    dtype, shapes = PROGRAMS[name]
+    for k, n in shapes:
+        assert _projection_moves(compiled, dtype, k, n) == [], (k, n)
+
+
+def test_block_decode_program_fits_one_chip(program, as_on_chip):
     """One whole engine program on one described device, by the
     compiler's own text and memory analysis, at depth 2, 4 and 32 (a few
     seconds each; the layer loop is a scan).
@@ -411,14 +509,22 @@ def test_block_decode_program_fits_one_chip(topo, as_on_chip):
     widths over 4096 rows (the sparse cell's, depth 2) are held to the
     same.
 
-    Temporaries: under a quarter of the cache at depth 4, and under 0.4 of
-    it at depth 2 and 32. What is left at depth 2 (0.33 of that cache) are
-    re-laid copies of the int8 attention weights, ``s8[L,4096,4096]`` and
-    ``s8[L,4096,1024]``, made once a dispatch in ENTRY; they have nothing
-    to do with the cache, the compiler keeps them in fast memory at depth
-    4, and at 32 layers they are 0.75 GiB of HBM, 0.375 of that cache. The
-    32-layer program itself, with a GiB for the admission staging row
-    and the allocator, fits the chip with the whole 2 GiB cache."""
+    Temporaries: under 4 MiB at every depth (1.8 MiB at depth 2, 1.1 at
+    depth 4 and 32: the step's activations). Until PR 41 there were 0.626
+    GiB at depth 32 (0.33 of the cache at depth 2): the compiler fused the
+    q and k products with the reshape to heads and the rotation behind
+    them, let that per-head operation's layout decide the product's, and
+    so re-laid the WEIGHT instead of the 64 KB activation: the whole
+    ``s8[L,4096,4096]`` and ``s8[L,4096,1024]`` stacks transposed once a
+    dispatch in ENTRY (``copy.185``/``.184``), and a layer's slice of each
+    written into fast memory before its product
+    (``constant_dynamic-slice_fusion.19``/``.21``). ``_project_heads``
+    now keeps product and per-head operation apart, and the products read
+    ``wq`` and ``wk`` out of the stack as they read ``wv`` and ``wo``:
+    ``test_program_moves_no_projection`` holds every program here to
+    that. The 32-layer program itself, with a GiB for the admission
+    staging row and the allocator, fits the chip with the whole 2 GiB
+    cache."""
     from cake_tpu.utils.chips import HBM_GIB
 
     def held(compiled, depth, window):
@@ -430,12 +536,12 @@ def test_block_decode_program_fits_one_chip(topo, as_on_chip):
         (call,) = _decode_kernel_calls(compiled)
         assert call.count("while/body") == 3, call
 
-    held(_block_decode(topo, 2, sparse=True), 2, SPARSE_WINDOW)
-    for depth, bar in ((2, 0.4), (4, 0.25), (32, 0.4)):
-        compiled = _block_decode(topo, depth)
+    held(program("sparse.decode.depth2"), 2, SPARSE_WINDOW)
+    for depth in (2, 4, 32):
+        compiled = program(f"dense.decode.depth{depth}")
         held(compiled, depth, WINDOW)
         args, temps = _donated_bytes(compiled)
-        assert temps <= bar * depth * LAYER_CACHE, (depth, temps / GIB)
+        assert temps <= 4 * 2**20, (depth, temps / 2**20)
     assert 8.8 * GIB < args < 8.95 * GIB, args / GIB  # 6.87 weights + 2.0
     assert args + temps + 1.0 * GIB < HBM_GIB["v5 lite"] * GIB
 
@@ -715,7 +821,8 @@ def test_state_space_programs_move_no_cache_and_no_state(topo, as_on_chip):
     assert admit.memory_analysis().temp_size_in_bytes < 0.3 * GIB
 
 
-def test_window_and_full_programs_move_no_cache_and_no_ring(topo, as_on_chip):
+def test_window_and_full_programs_move_no_cache_and_no_ring(program,
+                                                            as_on_chip):
     """The window + full attention family's two serving programs at
     K-EXAONE's published widths, the cell ``kexaone-ep8-cut.decode-doc``
     itself: layers 0-6 (a dense window layer, two sparse window layers,
@@ -724,30 +831,37 @@ def test_window_and_full_programs_move_no_cache_and_no_ring(topo, as_on_chip):
     them; the cache's two kinds of rows (one full layer's ``[1, 32, 8,
     4096, 128]`` and six rings ``[6, 32, 8, 128, 128]``) are carried
     through every segment and written in place, so nothing of either
-    shape is allocated or copied (a ``lax.switch`` over the kinds inside
-    one scan, tried first, copied the rings in and out of every branch:
-    PERF.md section 7); no segment's expert stack is written out before
+    shape is allocated or copied in the step, and nothing in any loop of
+    the admission (a ``lax.switch`` over the kinds inside one scan, tried
+    first, copied the rings in and out of every branch: PERF.md section
+    7); no segment's expert stack is written out before
     use (the 2048-row admission takes the sorted form, whose kernel reads
     the whole stacks; the 32-row step the dense one, on a scan's slice in
     place); the full layer's decode
     attention is ``flash_decode`` on its rows, the window layers' XLA's
     over 128 ring rows. Sizes: 9.73 GiB of weights + 0.59 GiB of cache in
-    (where six whole window layers would be 3.5 GiB), 0.11 GiB of
-    temporaries; the 2048-row admission 0.80 GiB beside its staging row
-    (a band of blocks: 2048 x 256 scores a head, not 2048 x 2048)."""
-    from cake_tpu.models.config import kexaone_ep8
+    (where six whole window layers would be 3.5 GiB), 0.006 GiB of
+    temporaries (0.111 until PR 41: a layer's ``wq`` and ``wk`` written
+    out of their stacks and transposed before each product,
+    ``test_program_moves_no_projection``); the 2048-row admission 0.79 GiB
+    beside its staging row (a band of blocks: 2048 x 256 scores a head,
+    not 2048 x 2048)."""
     from cake_tpu.utils.chips import HBM_GIB
 
     slots, window = 32, 4096
-    config = kexaone_ep8(num_hidden_layers=7, vocab_size=19200,
-                         max_seq_len=window)
-    decode, admit = _family_programs(topo, config, slots, window, 2048)
+    decode, admit = program("kexaone.decode"), program("kexaone.admit2048")
     for compiled, batch in ((decode, slots), (admit, 1)):
-        for shape in (f"bf16[1,{batch},8,{window},128]",
-                      f"bf16[6,{batch},8,128,128]"):
-            assert _cache_sized_moves(compiled, shape) == [], shape
+        assert _cache_sized_moves(
+            compiled, f"bf16[1,{batch},8,{window},128]") == []
         # no layer's expert stack is left behind as a value of its own
         assert _expert_stack_moves(compiled, "bf16", 16, 6144, 2048) == []
+    assert _cache_sized_moves(decode, f"bf16[6,{slots},8,128,128]") == []
+    # the admission's one-stream staging rings (1.5 MiB each) are re-laid
+    # rows ahead of heads on the way in and back on the way out, in ENTRY,
+    # once a program (~16 us each by the compiler's estimate); in no loop
+    staged = _cache_sized_moves(admit, "bf16[6,1,8,128,128]")
+    assert len(staged) <= 4 and all(
+        m.startswith("main") for m in staged), staged
 
     def calls(compiled, kernel):
         return [line for line in compiled.as_text().splitlines()
@@ -762,7 +876,7 @@ def test_window_and_full_programs_move_no_cache_and_no_ring(topo, as_on_chip):
     assert _grouped_matmul_calls(admit) == 9
     args, temps = _donated_bytes(decode)
     assert 10.25 * GIB < args < 10.4 * GIB, args / GIB  # 9.73 + 0.59
-    assert temps < 0.25 * GIB, temps / GIB
+    assert temps < 0.02 * GIB, temps / GIB
     m = admit.memory_analysis()
     assert m.temp_size_in_bytes < 1.0 * GIB, m.temp_size_in_bytes / GIB
     # the admission beside the live cache and the undonated splice's copy
@@ -775,24 +889,39 @@ def test_window_and_full_programs_move_no_cache_and_no_ring(topo, as_on_chip):
 # cache's two kinds of state and the routing bias are additions that the
 # families PR 31 served do not pass through.
 PR31_TEXTS = {
-    "dense.decode": "cf6f26fdc793389e", "dense.admit": "13b469425420d448",
-    "sparse.decode": "9d4dfa59dafe21c7", "sparse.admit": "d9e1c8f0418fc104",
+    # re-pinned by PR 41, on purpose: the dense and sparse families'
+    # attention is ``ops/attention.py`` ``_project_heads``, which now puts
+    # an ``optimization_barrier`` between the q, k and v products and the
+    # reshape to heads (one more operation a layer in all four texts, the
+    # mathematics unchanged: tests/test_ops.py
+    # ``test_project_heads_is_the_plain_projection``); the latent and
+    # hybrid families' attention (``ops/mla.py``, ``ops/kda.py``) does not
+    # pass through it and keeps its text
+    "dense.decode": "fbeebde18feae957", "dense.admit": "4e5df660db577dc7",
+    "sparse.decode": "158883cf34015073", "sparse.admit": "1aca5de8033d67e6",
     # the two families that count their held experts' load: the decode
     # programs re-pinned by PR 35, which return one more count (the held
-    # experts some row chose: ``moe.experts_hit``); the dense and sparse
-    # families' programs, and every admission, are the text they were
+    # experts some row chose: ``moe.experts_hit``); their admissions are
+    # the text they were
     "latent.decode": "3c2465d5b9b2c22d", "latent.admit": "5c206bbdc09ba295",
     # the hybrid's admission, taken on PR 32's tree (commit 9a9bb52): its
     # delta-rule expert segments share ONE scan body, which a body built
     # anew for each segment would lower once a segment (PR 33 met it)
     "hybrid.decode": "2717b08c49fc2b24", "hybrid.admit": "3c267fa227f8873b",
+    # the state-space family, taken on PR 40's tree (commit d2e802e): its
+    # attention layers pass through ``_project_heads`` with no norm and no
+    # rotation behind the products, where PR 41 puts no barrier (the chip's
+    # step is 0.4% faster with the products fused: PERF.md section 6)
+    "state_space.decode": "1db9244f72aa04e8",
+    "state_space.admit": "b095bb3adf962eef",
 }
 
 
 def test_existing_families_lower_to_the_text_they_had():
-    """The dense, sparse, latent and hybrid families' block decode and
-    admission programs lower (StableHLO, CPU, tiny widths) to the text
-    PR 31's tree (PR 32's for the hybrid) gave them, so the chip's
+    """The dense, sparse, latent, hybrid and state-space families' block
+    decode and admission programs lower (StableHLO, CPU, tiny widths) to
+    the text PR 31's tree (PR 32's for the hybrid, PR 40's for the
+    state-space family) gave them, so the chip's
     compiler sees what it saw and the cells it measured stay where they
     are: without kernels (the CPU's default) every call of the expert
     block takes the form it took before there was a sorted one (PR 33,
@@ -801,8 +930,8 @@ def test_existing_families_lower_to_the_text_they_had():
     says so."""
     import hashlib
 
-    from cake_tpu.models.config import (tiny, tiny_kda_hybrid, tiny_mla_moe,
-                                        tiny_moe)
+    from cake_tpu.models.config import (tiny, tiny_jamba, tiny_kda_hybrid,
+                                        tiny_mla_moe, tiny_moe)
     from cake_tpu.models.llama import init_params
     from cake_tpu.ops.kvcache import init_cache
     from cake_tpu.ops.sampling import SamplerSettings
@@ -814,7 +943,8 @@ def test_existing_families_lower_to_the_text_they_had():
     settings = SamplerSettings(temperature=0.0)
     for name, config in (("dense", tiny(sliding_window=32)),
                          ("sparse", tiny_moe()), ("latent", tiny_mla_moe()),
-                         ("hybrid", tiny_kda_hybrid())):
+                         ("hybrid", tiny_kda_hybrid()),
+                         ("state_space", tiny_jamba())):
         plan = MeshPlan.build(config, devices=jax.devices()[:1])
         params = jax.eval_shape(lambda k: init_params(config, k),
                                 jax.random.PRNGKey(0))

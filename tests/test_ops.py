@@ -206,3 +206,125 @@ def test_history_ring_buffer_wraps():
     for t in range(6):
         hist, slot = sampling.push_history(hist, slot, jnp.int32(t))
     assert sorted(np.asarray(hist).tolist()) == [2, 3, 4, 5]
+
+
+def _heads_reference(x, wq, wk, wv, num_heads, num_kv_heads, bq, bk, bv,
+                     qk_norm):
+    """The mathematics `_project_heads` stands for, written out: ``x @ w``
+    (an int8 weight widened into the product and scaled after it), the
+    bias, the reshape to heads, heads ahead, then each head of q and k
+    normed in float32."""
+    from cake_tpu.ops.quant import QuantizedLinear
+
+    def project(w, bias, heads):
+        if isinstance(w, QuantizedLinear):
+            y = jnp.dot(x, w.q.astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+            y = (y * w.scale).astype(x.dtype)
+        else:
+            y = x @ w
+        if bias is not None:
+            y = y + bias
+        b, t, _ = x.shape
+        return y.reshape(b, t, heads, -1).transpose(0, 2, 1, 3)
+
+    def normed(y, weight, eps):
+        yf = y.astype(jnp.float32)
+        yf = yf * jax.lax.rsqrt(jnp.mean(yf * yf, -1, keepdims=True) + eps)
+        return (yf * weight.astype(jnp.float32)).astype(y.dtype)
+
+    q = project(wq, bq, num_heads)
+    k = project(wk, bk, num_kv_heads)
+    v = project(wv, bv, num_kv_heads)
+    if qk_norm is not None:
+        q = normed(q, qk_norm[0], qk_norm[2])
+        k = normed(k, qk_norm[1], qk_norm[2])
+    return q, k, v
+
+
+def _first_attention_layer(layers):
+    """Layer 0's tensors out of a params tree's stacked layers (one stack,
+    or a segmented model's first stack that has a ``wq``)."""
+    if "wq" not in layers:
+        layers = next(seg for seg in jax.tree.leaves(
+            layers, is_leaf=lambda n: isinstance(n, dict) and "wq" in n)
+            if isinstance(seg, dict))
+    return jax.tree.map(lambda a: a[0], layers)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["dense", "int8", "qwen2_bias", "head_norm",
+                                  "tp2", "unrotated"])
+def test_project_heads_is_the_plain_projection(case, dtype):
+    """`ops.attention._project_heads` keeps the three products apart from
+    the per-head operations behind them (an optimization barrier, for the
+    chip's compiler: tests/test_chip_compile.py `_projection_moves`); it
+    is the same mathematics: bit for bit in float32, within one bfloat16
+    ulp in bfloat16, for a plain, an int8, a biased (Qwen2), a head-normed
+    (K-EXAONE's fixture) and a column-sharded (``tp=2``: the local heads)
+    projection, and for one that nothing rotates or norms (Jamba's: no
+    barrier)."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from cake_tpu.models.config import tiny_exaone_moe
+    from cake_tpu.models.llama import init_params
+    from cake_tpu.ops.attention import _project_heads
+    from cake_tpu.ops.quant import quantize_linear
+
+    make = tiny_exaone_moe if case == "head_norm" else tiny
+    cfg = make(dtype=dtype, attention_bias=case == "qwen2_bias")
+    layer = _first_attention_layer(
+        init_params(cfg, jax.random.PRNGKey(7))["layers"])
+    dt = cfg.jax_dtype
+    x = jax.random.normal(jax.random.PRNGKey(8), (3, 5, cfg.hidden_size),
+                          jnp.float32).astype(dt)
+    ws = [layer[n] for n in ("wq", "wk", "wv")]
+    if case == "int8":
+        ws = [quantize_linear(w) for w in ws]
+    biases = [layer.get(n) for n in ("bq", "bk", "bv")]
+    if case == "qwen2_bias":  # init_params zeroes them: make them count
+        biases = [jax.random.normal(jax.random.PRNGKey(9 + i), b.shape,
+                                    jnp.float32).astype(dt)
+                  for i, b in enumerate(biases)]
+    assert (biases[0] is not None) == (case == "qwen2_bias")
+    norm = ((layer["q_norm"] * 1.5, layer["k_norm"] * 0.75, cfg.rms_norm_eps)
+            if case == "head_norm" else None)
+    heads = (cfg.num_attention_heads, cfg.num_key_value_heads)
+    reference = jax.jit(_heads_reference, static_argnums=(4, 5))
+    if case == "tp2":  # each shard's own columns, its heads side by side
+        halves = [[w[:, i * w.shape[1] // 2:(i + 1) * w.shape[1] // 2]
+                   for w in ws] for i in (0, 1)]
+        want = [jnp.concatenate(pair, axis=1) for pair in zip(*(
+            reference(x, *half, heads[0] // 2, heads[1] // 2, *biases, norm)
+            for half in halves))]
+    else:
+        want = reference(x, *ws, *heads, *biases, norm)
+
+    if case == "tp2":
+        # Megatron-style: q/k/v column-sharded, the head counts local
+        mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+        got = jax.jit(jax.shard_map(
+            lambda x, wq, wk, wv: _project_heads(
+                x, wq, wk, wv, heads[0] // 2, heads[1] // 2),
+            mesh=mesh, in_specs=(P(), P(None, "tp"), P(None, "tp"),
+                                 P(None, "tp")),
+            out_specs=P(None, "tp")))(x, *ws)
+    else:
+        def project(x, ws, biases, norm):
+            return _project_heads(x, *ws, *heads, *biases, qk_norm=norm,
+                                  rotated=case != "unrotated")
+
+        got = jax.jit(project)(x, ws, biases, norm)
+        # the barrier stands wherever a norm or a rotation follows
+        assert ("optimization_barrier" in str(jax.make_jaxpr(project)(
+            x, ws, biases, norm))) == (case != "unrotated")
+
+    for name, g, w in zip("qkv", got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype == dt, name
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        if dtype == "float32":
+            np.testing.assert_array_equal(g, w, err_msg=name)
+        else:  # one ulp of bfloat16: 2^-7 of the value's power of two
+            ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(w), 1e-30)))
+                          - 7)
+            assert (np.abs(g - w) <= ulp).all(), name

@@ -561,9 +561,9 @@ def test_a_slow_pass_leaves_its_parts(prof_env, monkeypatch):
     monkeypatch.setattr(prof, "SLOW_PASS_MS", 50.0)
     p = prof.profiler()
     sched = Scheduler(SlowStep(), queue_depth=4)
+    t0 = time.time_ns()  # a pass is stamped when it starts: it may be waiting
     sched.start(max_concurrent=1)
     try:
-        t0 = time.time_ns()
         sess = Session([1, 2], max_tokens=3)
         sched.submit(sess)
         while True:
