@@ -6,6 +6,21 @@ TPU-native equivalent of the reference's config plane
 `rope_theta`, bos/eos ids — config.rs:13-26), plus the generation-time maximum
 sequence length (the reference hard-caps MAX_SEQ_LEN=4096, config.rs:6; here it
 is a tunable because the TPU build supports long context).
+
+Families read (``LlamaConfig.from_hf_dict`` by ``model_type``): the dense
+and Mixtral-style decoders (one bare stack), and five whose layers are of
+several kinds (``segmented``: a stack a stretch of one kind,
+``models/llama.py`` ``layer_plan``): ``deepseek_v3`` / ``axk1`` (latent
+attention, shared and routed experts), ``bailing_hybrid`` (delta-rule
+layers beside latent ones), ``jamba`` (Mamba layers beside rope-less
+attention), ``exaone_moe`` (window and full attention by ``layer_types``)
+and ``lfm2_moe`` (gated short convolutions beside roped attention by
+``layer_types``; keys ``conv_L_cache``, ``conv_bias``,
+``num_dense_layers``, ``num_experts``, ``norm_eps``, ``use_expert_bias``;
+every expert held, no shared one, a tied head; its conv layers keep a
+convolution's tail and NO state, so ``cache_plan`` has ``conv`` without
+``state``; nothing shards it and the paged layout, speculation,
+``--quantize`` and an int8 cache are refused).
 """
 
 from __future__ import annotations
@@ -160,6 +175,20 @@ class LlamaConfig:
     # ``n_shared_experts``).
     layer_types: tuple[str, ...] | None = None
     qk_norm: bool = False
+    # --- gated short-convolution layers beside grouped-query attention (HF
+    # `model_type` "lfm2_moe") -----------------------------------------------
+    # ``layer_types``: "conv" | "full_attention" a layer. A conv layer is
+    # ``[B | C | x] = u W_in; y = conv(B * x); out = (C * y) W_out`` with a
+    # causal depthwise convolution of ``conv_L_cache`` taps and no
+    # activation (ops/shortconv.py): it keeps NO rows and no state, only
+    # the convolution's last ``conv_L_cache - 1`` inputs a stream
+    # (``cache_plan``: ``conv`` and no ``state``). A full layer rotates q
+    # and k (unlike the window family's) behind the per-head ``qk_norm``.
+    # The feed-forward is the shared-expert family's with no shared expert:
+    # ``first_k_dense_replace`` leading dense layers, then ALL
+    # ``n_routed_experts`` sigmoid-scored, bias-corrected experts.
+    conv_L_cache: int = 3
+    conv_bias: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -178,7 +207,8 @@ class LlamaConfig:
                     "head-wise output gate only)")
         if self.layer_types is not None:
             self._check_layer_types()
-        if (self.kv_lora_rank or self.windowed) and self.n_routed_experts:
+        if (self.kv_lora_rank or self.layer_types is not None) and (
+                self.n_routed_experts):
             if self.scoring_func != "sigmoid":
                 raise ValueError(
                     f"scoring_func {self.scoring_func!r} is not wired for "
@@ -230,16 +260,20 @@ class LlamaConfig:
         """What a per-layer ``layer_types`` may ask for and is computed."""
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         kinds = set(self.layer_types)
+        mixers = _layer_type_mixers(self.model_type)
         if (len(self.layer_types) != self.num_hidden_layers
-                or not kinds <= set(_WINDOW_MIXERS)):
+                or not kinds <= set(mixers)):
             raise ValueError(
-                f"layer_types needs one of {sorted(_WINDOW_MIXERS)} for "
+                f"layer_types needs one of {sorted(mixers)} for "
                 f"each of the {self.num_hidden_layers} layers, got "
                 f"{len(self.layer_types)} entries of {sorted(kinds)}")
         if "full_attention" not in kinds:
             raise ValueError(
                 "layer_types without a full_attention layer is not wired "
                 "(the cache's capacity is the full layers')")
+        if self.short_conv:
+            self._check_short_conv(kinds)
+            return
         if "sliding_attention" in kinds and not (
                 self.sliding_window and self.sliding_window >= 8):
             raise ValueError(
@@ -252,6 +286,30 @@ class LlamaConfig:
                 "mixed by layer) is wired with the shared-expert "
                 "feed-forward only: no latent keys, no state-space "
                 "layers, no Mixtral-style experts, no projection bias")
+
+    def _check_short_conv(self, kinds):
+        """What a model of short-convolution and attention layers may ask
+        for and is computed."""
+        if "conv" not in kinds:
+            raise ValueError(
+                "layer_types without a conv layer is not wired for "
+                f"model_type {SHORT_CONV_MODEL_TYPE!r} (every layer a "
+                "full_attention one is a dense decoder's stack)")
+        if self.conv_L_cache < 2 or self.conv_bias:
+            raise ValueError(
+                f"conv_L_cache {self.conv_L_cache} / conv_bias "
+                f"{self.conv_bias} is not wired (a depthwise convolution "
+                "of 2 or more taps, no bias)")
+        if self.kv_lora_rank or self.attn_layer_period or (
+                self.num_local_experts or self.attention_bias
+                or self.sliding_window is not None
+                or self.n_shared_experts):
+            raise ValueError(
+                "layer_types with conv layers (gated short convolutions "
+                "beside full grouped-query attention) is wired with the "
+                "routed-expert feed-forward only: no latent keys, no "
+                "state-space layers, no Mixtral-style experts, no "
+                "projection bias, no sliding_window, no shared expert")
 
     @property
     def num_kv_groups(self) -> int:
@@ -279,7 +337,15 @@ class LlamaConfig:
         """Window and full grouped-query attention layers mixed by layer
         (``layer_types``): window layers keep a ring of ``ring_rows`` rows
         a stream, full layers every row."""
-        return self.layer_types is not None
+        return self.layer_types is not None and not self.short_conv
+
+    @property
+    def short_conv(self) -> bool:
+        """Gated short-convolution layers beside full grouped-query
+        attention layers (``layer_types`` of ``model_type`` "lfm2_moe"): a
+        conv layer keeps the convolution's last inputs and nothing else."""
+        return (self.layer_types is not None
+                and self.model_type == SHORT_CONV_MODEL_TYPE)
 
     @property
     def ring_rows(self) -> int:
@@ -299,20 +365,28 @@ class LlamaConfig:
         """Whether the layers are of several kinds, so that
         ``params["layers"]`` is a dict of stacks, one a segment of
         ``models.llama.layer_plan``, and not one bare stack."""
-        return self.latent or self.state_space or self.windowed
+        return (self.latent or self.state_space
+                or self.layer_types is not None)
 
     @property
     def recurrent_mixer(self) -> str | None:
-        """The mixer of the layers that hold a recurrent state in place of
-        rows: "kda" (delta-rule linear attention, ops/kda.py), "mamba" (a
-        selective state space, ops/mamba.py), or None."""
+        """The mixer of the layers that carry something from token to
+        token in place of rows: "kda" (delta-rule linear attention,
+        ops/kda.py), "mamba" (a selective state space, ops/mamba.py),
+        "conv" (a gated short convolution, ops/shortconv.py: a tail of
+        inputs and NO state), or None."""
         if self.layer_group_size > 0:
             return "kda"
+        if self.short_conv:
+            return "conv"
         return "mamba" if self.state_space else None
 
     @property
     def recurrent(self) -> bool:
-        """Whether some layers hold a recurrent state in place of rows."""
+        """Whether some layers carry a state or a convolution's tail from
+        token to token in place of rows (what they hold is
+        ``cache_plan``'s to say: ``state`` and ``conv``, or ``conv``
+        alone)."""
         return self.recurrent_mixer is not None
 
     @property
@@ -324,7 +398,8 @@ class LlamaConfig:
     def layer_kinds(self) -> tuple[tuple[str, str], ...]:
         """``(mixer, feed-forward)`` of every layer, in model order: the
         mixer is "gqa", "swa" (grouped-query attention through a window,
-        where ``layer_types`` says so), "mla", "kda" or "mamba", the
+        where ``layer_types`` says so), "mla", "kda", "mamba" or "conv" (a
+        gated short convolution, where ``layer_types`` says so), the
         feed-forward "dense" or "moe".
         THE place the layer order comes from (models/llama.py
         ``layer_plan`` groups it into scanned segments, the cache and the
@@ -335,8 +410,9 @@ class LlamaConfig:
             0 if self.num_local_experts else n)
 
         def mixer(i):
-            if self.windowed:
-                return _WINDOW_MIXERS[self.layer_types[i]]
+            if self.layer_types is not None:
+                return _layer_type_mixers(self.model_type)[
+                    self.layer_types[i]]
             if self.state_space:
                 return ("gqa" if i % self.attn_layer_period
                         == self.attn_layer_offset else "mamba")
@@ -361,7 +437,10 @@ class LlamaConfig:
         taps - 1, d_inner)``. Window layers (``layer_types``) keep ``ring``
         ``(layers, heads, R, k_width, v_width)``: ``R = ring_rows`` rows a
         stream whatever the capacity, and ``rows`` then counts the full
-        layers alone. A kind with no layer is left out."""
+        layers alone. Short-convolution layers keep ``conv`` ``(layers,
+        taps - 1, hidden)`` and NO ``state``: who asks whether a model
+        holds a state asks for the key. A kind with no layer is left
+        out."""
         mixers = [m for m, _ in self.layer_kinds]
         held = mixers.count(self.recurrent_mixer)
         ring = mixers.count("swa")
@@ -375,6 +454,8 @@ class LlamaConfig:
             h, d = self.num_attention_heads, self.head_dim
             plan["state"] = (held, h, d, d)
             plan["conv"] = (held, self.short_conv_kernel_size - 1, 3 * h * d)
+        elif held and self.recurrent_mixer == "conv":
+            plan["conv"] = (held, self.conv_L_cache - 1, self.hidden_size)
         elif held:
             plan["state"] = (held, self.mamba_d_state, self.mamba_d_inner)
             plan["conv"] = (held, self.mamba_d_conv - 1, self.mamba_d_inner)
@@ -384,9 +465,10 @@ class LlamaConfig:
     def rope_dim(self) -> int:
         """Channels of a head that rotary embeddings cover; 0: the model
         has no position embedding (position comes from the recurrence).
-        Where window and full layers are mixed (``layer_types``) this is
+        Where window and full layers are mixed (``windowed``) this is
         the window layers': a full layer rotates nothing (the layer loop
-        hands it no table)."""
+        hands it no table). Beside short-convolution layers
+        (``short_conv``) the full layers rotate the whole head."""
         if self.state_space:
             return 0
         return self.qk_rope_head_dim if self.latent else self.head_dim
@@ -424,6 +506,12 @@ class LlamaConfig:
             scale *= yarn_mscale(rs["factor"], rs["mscale_all_dim"]) ** 2
         return scale
 
+    @property
+    def topk_norm_eps(self) -> float:
+        """What the chosen experts' scores are normalised over, beside
+        their sum (``norm_topk_prob``): the family's own constant."""
+        return 1e-6 if self.short_conv else 1e-20
+
     def eos_ids(self) -> tuple[int, ...]:
         """Normalized EOS id set (reference checks config ids or "</s>",
         llama.rs:17,26-29,271)."""
@@ -437,10 +525,11 @@ class LlamaConfig:
     def from_hf_dict(cls, d: dict, **overrides) -> "LlamaConfig":
         known = {f.name for f in dataclasses.fields(cls)}
         kwargs = {k: v for k, v in d.items() if k in known}
-        if d.get("model_type") != WINDOWED_MODEL_TYPE:
+        if d.get("model_type") not in (WINDOWED_MODEL_TYPE,
+                                       SHORT_CONV_MODEL_TYPE):
             # Hugging Face writes a `layer_types` list for every family
             # (all "full_attention" where nothing windows by layer); only
-            # the family that mixes them by layer reads it
+            # the families that mix mixers by layer read it
             kwargs.pop("layer_types", None)
         # HF configs carry torch_dtype, not dtype.
         td = d.get("torch_dtype")
@@ -496,6 +585,8 @@ class LlamaConfig:
                     )
         if d.get("model_type") == WINDOWED_MODEL_TYPE:
             kwargs.update(_windowed_kwargs(d))
+        elif d.get("model_type") == SHORT_CONV_MODEL_TYPE:
+            kwargs.update(_short_conv_kwargs(d))
         elif d.get("model_type") == STATE_SPACE_MODEL_TYPE:
             kwargs.update(_state_space_kwargs(d))
         elif d.get("model_type") == HYBRID_MODEL_TYPE:
@@ -553,8 +644,8 @@ class LlamaConfig:
             d.pop("embed_scale")
         width, first = d.pop("router_experts"), d.pop("first_expert")
         if not self.latent:
-            for f in (_LATENT_ATTENTION_FIELDS if self.windowed
-                      else _LATENT_FIELDS):
+            for f in (_LATENT_ATTENTION_FIELDS
+                      if self.layer_types is not None else _LATENT_FIELDS):
                 d.pop(f)
         if self.n_routed_experts and width != self.n_routed_experts:
             d["expert_share"] = {
@@ -562,9 +653,20 @@ class LlamaConfig:
                 "ep": width // self.n_routed_experts,
                 "rank": first // self.n_routed_experts}
         d.pop("qk_norm")  # the family's, not a key of any config.json
-        if not self.windowed:
+        if not self.short_conv:
+            d.pop("conv_L_cache")
+            d.pop("conv_bias")
+        else:  # the family's own spelling (_short_conv_kwargs reads it back)
+            d["layer_types"] = list(self.layer_types)
+            for ours, theirs in _SHORT_CONV_KEYS.items():
+                d[theirs] = d.pop(ours)
+            for f in ("n_shared_experts", "scoring_func", "n_group",
+                      "topk_group"):
+                d.pop(f)
+        if self.layer_types is None:
             d.pop("layer_types")
-        else:  # the family's own spelling (_windowed_kwargs reads it back)
+        elif self.windowed:
+            # the family's own spelling (_windowed_kwargs reads it back)
             d.pop("router_bias")
             d["layer_types"] = list(self.layer_types)
             d["sliding_windows"] = [
@@ -606,6 +708,67 @@ LATENT_MODEL_TYPES = ("deepseek_v3", "axk1")
 WINDOWED_MODEL_TYPE = "exaone_moe"
 # `layer_types` entry -> the layer's mixer (`LlamaConfig.layer_kinds`)
 _WINDOW_MIXERS = {"sliding_attention": "swa", "full_attention": "gqa"}
+# ... and the one whose layers are gated short convolutions but one in
+# four or so, which is grouped-query attention that DOES rotate, with all
+# of its sigmoid-routed experts held and no shared one (LFM2-MoE's keys)
+SHORT_CONV_MODEL_TYPE = "lfm2_moe"
+_SHORT_CONV_MIXERS = {"conv": "conv", "full_attention": "gqa"}
+# ours -> the family's spelling, where they differ
+_SHORT_CONV_KEYS = {"first_k_dense_replace": "num_dense_layers",
+                    "n_routed_experts": "num_experts",
+                    "rms_norm_eps": "norm_eps",
+                    "router_bias": "use_expert_bias"}
+
+
+def _layer_type_mixers(model_type: str) -> dict[str, str]:
+    """What a ``layer_types`` entry may be, and the mixer it names, by the
+    family that reads the list."""
+    return (_SHORT_CONV_MIXERS if model_type == SHORT_CONV_MODEL_TYPE
+            else _WINDOW_MIXERS)
+
+
+def _short_conv_kwargs(d: dict) -> dict:
+    """`LlamaConfig` fields from an "lfm2_moe" config.json (its own
+    spelling: ``num_dense_layers``, ``num_experts``, ``norm_eps``,
+    ``use_expert_bias``, ``conv_L_cache``). What the file asks for and
+    nothing here computes is refused, not guessed. The readings made of
+    the file (the chunk order ``B | C | x``, no activation in the mixer,
+    a tied head where the file names none) are the benchmark
+    configuration's ``assumed``."""
+    name = SHORT_CONV_MODEL_TYPE
+    layers = d["num_hidden_layers"]
+    types = list(d["layer_types"])
+    if len(types) != layers:
+        raise ValueError(
+            f"{name}: layer_types has {len(types)} entries for "
+            f"{layers} layers")
+    rope = d.get("rope_parameters") or d.get("rope_scaling") or {}
+    kind = rope.get("rope_type", rope.get("type", "default"))
+    if kind != "default":
+        raise ValueError(
+            f"{name}: rope type {kind!r} is not wired (default rotation, "
+            "no scaling)")
+    for key, only in (("num_shared_experts", 0), ("n_group", 1),
+                      ("topk_group", 1), ("scoring_func", "sigmoid")):
+        if d.get(key, only) != only:
+            raise ValueError(
+                f"{name}: {key} = {d[key]!r} is not wired (only {only!r})")
+    lead = d.get("num_dense_layers", 0)
+    return {
+        "layer_types": tuple(types),
+        "qk_norm": True,
+        "rms_norm_eps": d.get("norm_eps", d.get("rms_norm_eps", 1e-5)),
+        "rope_theta": float(rope.get("rope_theta",
+                                     d.get("rope_theta", 1000000.0))),
+        "rope_scaling": None,
+        "first_k_dense_replace": lead,
+        "n_routed_experts": d["num_experts"] if lead < layers else 0,
+        "n_shared_experts": 0,
+        "scoring_func": "sigmoid",
+        "router_bias": bool(d.get("use_expert_bias", False)),
+        # Lfm2MoeConfig's default where the file names none
+        "tie_word_embeddings": bool(d.get("tie_word_embeddings", True)),
+    }
 
 
 def _windowed_kwargs(d: dict) -> dict:
@@ -1036,7 +1199,7 @@ def jamba2_3b(**overrides) -> LlamaConfig:
 
 def _repeated(pattern, layers: int) -> tuple[str, ...]:
     """``layer_types`` for ``layers`` layers from one period of the
-    pattern (or the whole list, which it then is)."""
+    pattern (or from the whole list: it then is, or is cut to, them)."""
     return tuple(pattern[i % len(pattern)] for i in range(layers))
 
 
@@ -1080,6 +1243,54 @@ def kexaone_ep8(**overrides) -> LlamaConfig:
         routed_scaling_factor=2.5,
         bos_token_id=0,
         eos_token_id=1,
+    )
+    base.update(overrides)
+    base["layer_types"] = _repeated(base["layer_types"],
+                                    base["num_hidden_layers"])
+    return LlamaConfig(**base)
+
+
+# LFM2-8B-A1B's 24 layers: c c A, then c c c A four times, c c A c c
+_LFM2_8B_LAYERS = tuple(
+    "full_attention" if i in (2, 6, 10, 14, 18, 21) else "conv"
+    for i in range(24))
+
+
+def lfm2_8b_a1b(**overrides) -> LlamaConfig:
+    """LFM2-8B-A1B (https://huggingface.co/LiquidAI/LFM2-8B-A1B,
+    `model_type` "lfm2_moe") at its published sizes: 24 layers, gated
+    short convolutions of 3 taps but layers 2, 6, 10, 14, 18 and 21, which
+    are attention of 32 roped, QK-normed query heads of 64 over 8
+    key/value heads; two leading dense layers (7168), then all 32
+    bias-corrected sigmoid-scored experts (1792) top-4 and no shared one;
+    a tied head. A chip serves the depth of its pipeline stage
+    (`num_hidden_layers=`; `layer_types` is cut to it)."""
+    base = dict(
+        model_type="lfm2_moe",
+        vocab_size=65536,
+        hidden_size=2048,
+        intermediate_size=7168,
+        num_hidden_layers=24,
+        num_attention_heads=32,
+        num_key_value_heads=8,
+        rms_norm_eps=1e-5,
+        rope_theta=1000000.0,
+        max_seq_len=128000,
+        tie_word_embeddings=True,
+        layer_types=_LFM2_8B_LAYERS,
+        qk_norm=True,
+        conv_L_cache=3,
+        conv_bias=False,
+        first_k_dense_replace=2,
+        moe_intermediate_size=1792,
+        n_routed_experts=32,
+        num_experts_per_tok=4,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+        routed_scaling_factor=1.0,
+        bos_token_id=1,
+        eos_token_id=7,
     )
     base.update(overrides)
     base["layer_types"] = _repeated(base["layer_types"],
@@ -1230,6 +1441,37 @@ def tiny_exaone_moe(**overrides) -> LlamaConfig:
         router_bias=True,
         norm_topk_prob=True,
         routed_scaling_factor=2.5,
+    )
+    base.update(overrides)
+    base["layer_types"] = _repeated(base["layer_types"],
+                                    base["num_hidden_layers"])
+    return tiny(**base)
+
+
+def tiny_lfm2_moe(**overrides) -> LlamaConfig:
+    """Tiny fixture of the short-convolution + attention family that keeps
+    the published pattern (LFM2-MoE's keys): ``c c A c c A c c A c``
+    (two leading conv layers, ``A c c`` twice, a ragged end), 3 taps,
+    QK-normed roped heads of 16, two leading dense layers, then all 8
+    bias-corrected sigmoid-scored experts top-2 and no shared one, a tied
+    head."""
+    base = dict(
+        model_type="lfm2_moe",
+        num_hidden_layers=10,
+        layer_types=tuple("full_attention" if i in (2, 5, 8) else "conv"
+                          for i in range(10)),
+        qk_norm=True,
+        conv_L_cache=3,
+        first_k_dense_replace=2,
+        moe_intermediate_size=32,
+        n_routed_experts=8,
+        num_experts_per_tok=2,
+        scoring_func="sigmoid",
+        router_bias=True,
+        norm_topk_prob=True,
+        routed_scaling_factor=1.0,
+        tie_word_embeddings=True,
+        rope_theta=1000000.0,
     )
     base.update(overrides)
     base["layer_types"] = _repeated(base["layer_types"],

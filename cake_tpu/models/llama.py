@@ -50,6 +50,7 @@ from cake_tpu.ops.moe import (
 )
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.rope import rope_tables_for
+from cake_tpu.ops.shortconv import conv_mixer_block
 
 Params = dict[str, Any]
 
@@ -165,6 +166,18 @@ _MAMBA_SHAPES = {
 }
 
 
+# A gated short convolution (ops/shortconv.py): the input projection to the
+# two gates and the convolved value (``[B | C | x]``), the depthwise taps,
+# the output projection.
+_CONV_SHAPES = {
+    "attn_norm": lambda c: (c.hidden_size,),
+    "w_in": lambda c: (c.hidden_size, 3 * c.hidden_size),
+    "conv_w": lambda c: (c.conv_L_cache, c.hidden_size),
+    "w_out": lambda c: (c.hidden_size, c.hidden_size),
+    "mlp_norm": lambda c: (c.hidden_size,),
+}
+
+
 class Segment(NamedTuple):
     """Layers of ONE kind in a row, scanned as one stack: ``name`` is the
     stack's key in ``params["layers"]``, ``first`` the model index of its
@@ -174,7 +187,7 @@ class Segment(NamedTuple):
     layers and ``cache_stride`` cached ones on."""
 
     name: str
-    mixer: str  # "mla" | "kda" | "gqa" | "swa" | "mamba"
+    mixer: str  # "mla" | "kda" | "gqa" | "swa" | "mamba" | "conv"
     ffn: str  # "dense" | "moe"
     first: int
     count: int
@@ -232,7 +245,15 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
     full layer. (A stack a KIND, walked by one scan with a ``lax.switch``
     over the kinds, was tried first: the chip's compiler then copies the
     rings in and out of every branch and re-lays the attention projections
-    it indexes inside one, PERF.md section 7, PR 40.)"""
+    it indexes inside one, PERF.md section 7, PR 40.)
+
+    Short convolutions beside attention (``config.short_conv``): two
+    leading dense conv layers, then ``A`` and ``c c c`` by turns with a
+    ragged end, each stretch a segment of its own and NO repeated period
+    where the layers hold routed experts (``periods`` below says why). A
+    segment's ``cache_first`` counts the layers of ITS mixer before it: an
+    attention layer's index into the rows, a conv layer's into the
+    tails."""
     kinds = config.layer_kinds
     period = _whole_period(kinds)
     stretches = []  # [kind, first, count]
@@ -243,9 +264,20 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
         else:
             stretches.append([kind, i, 1])
     one_mixer = len({m for m, _ in kinds}) == 1
+    # A repeated period is scanned as a period but where its layers hold
+    # routed experts beside short convolutions: every expert is held there,
+    # so a step and an admission of up to 256 rows take the expert block's
+    # dense form, and from 128 rows on the chip's compiler re-lays the
+    # stacks that form's product indexes inside a period (gate and up of
+    # every layer copied transposed in ENTRY: 5.26 GiB of temporaries an
+    # admission at 32 experts of 2048 x 1792 in 12 layers, more than a chip
+    # has left beside the weights; as the operand of a product behind a
+    # scan's ``xs``, a segment of one repetition, it stays as it lies: my
+    # AOT compiles and chip run, PR 43; PERF.md section 7)
+    periods = not (config.short_conv and config.n_routed_experts)
     names: dict[str, int] = {}
     # layers counted so far in the cache buffers of each mixer's kind
-    cached = {"mla": 0, "kda": 0, "gqa": 0, "swa": 0, "mamba": 0}
+    cached = {"mla": 0, "kda": 0, "gqa": 0, "swa": 0, "mamba": 0, "conv": 0}
 
     def segment(kind, first, count, cache_stride=0):
         mixer, ffn = kind
@@ -267,7 +299,7 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
                 r += 1
             if r > 1 and r * width > best[0] * best[1]:
                 best = (r, width)
-        repeats, width = best if best[0] > 1 else (1, 1)
+        repeats, width = best if best[0] > 1 and periods else (1, 1)
         period = stretches[at:at + width]
         per_mixer = {m: sum(n for (mm, _), _, n in period if mm == m)
                      for m in cached}
@@ -297,6 +329,8 @@ def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
         shapes = dict(_MAMBA_SHAPES)
         if not config.mamba_conv_bias:
             del shapes["conv_b"]
+    elif seg.mixer == "conv":
+        shapes = dict(_CONV_SHAPES)
     elif seg.mixer in ("gqa", "swa"):
         shapes = {k: _LAYER_SHAPES[k] for k in (
             "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm")}
@@ -674,7 +708,8 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
             routing=GroupRouting(config.n_group, config.topk_group,
                                  config.norm_topk_prob,
                                  config.routed_scaling_factor,
-                                 layer.get("b_router")),
+                                 layer.get("b_router"),
+                                 config.topk_norm_eps),
             held=(config.first_expert, config.n_routed_experts),
             count_local=count_local, layer=expert_idx,
         )
@@ -704,15 +739,24 @@ def _kda_block(layer, x, cache, config, valid, ep_axis, ep_size, layer_idx,
     return x, dataclasses.replace(cache, state=state, conv=conv), local
 
 
-def _windowed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
-                    ep_axis, ep_size, layer_idx, count_local, expert_idx):
-    """One layer of a model that mixes window and full attention by
-    layer, its shared-expert feed-forward included: a window layer
-    (``mixer`` "swa") rotates q and k and attends over its ring of the
-    carried cache, a full one ("gqa") rotates nothing and attends over its
-    rows; both norm each head of q and k first. ``layer_idx`` counts the
-    layers of the mixer's own kind. Returns ``(x, cache, ExpertCount)``."""
+def _typed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
+                 ep_axis, ep_size, layer_idx, count_local, expert_idx):
+    """One layer of a model whose ``layer_types`` name each layer's mixer,
+    its shared-expert feed-forward included. Window and full attention
+    mixed: a window layer (``mixer`` "swa") rotates q and k and attends
+    over its ring of the carried cache, a full one ("gqa") rotates nothing
+    and attends over its rows. Short convolutions beside attention: a
+    "conv" layer reads and writes its tail of the carried cache and no row,
+    a full one rotates q and k. Attention norms each head of q and k
+    first. ``layer_idx`` counts the layers of the mixer's own kind.
+    Returns ``(x, cache, ExpertCount)``."""
     h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
+    if mixer == "conv":
+        out, conv = conv_mixer_block(h, layer, cache.conv, valid=valid,
+                                     layer_idx=layer_idx)
+        x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
+                                        ep_size, count_local, expert_idx)
+        return x, dataclasses.replace(cache, conv=conv), local
     norm = ((layer["q_norm"], layer["k_norm"], config.rms_norm_eps)
             if config.qk_norm else None)
     args = (h, layer["wq"], layer["wk"], layer["wv"], layer["wo"])
@@ -725,9 +769,11 @@ def _windowed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
                 qk_norm=norm, valid=valid)
         cache = dataclasses.replace(cache, ring_k=ring_k, ring_v=ring_v)
     else:
+        if config.windowed:  # the window family's full layers: no table
+            cos = sin = None
         with jax.named_scope("attn.full"):
             out, k, v = self_attention_block(
-                *args, cache.k, cache.v, None, None, pos, *heads,
+                *args, cache.k, cache.v, cos, sin, pos, *heads,
                 layer=layer_idx, qk_norm=norm)
         cache = dataclasses.replace(cache, k=k, v=v)
     x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
@@ -805,8 +851,11 @@ def forward_layers(
     Where window and full attention are mixed by layer, a window
     segment's layers rotate and attend over the cache's rings (``valid``
     keeps a bucket's padding out of them) and a full segment's take no
-    table and attend over its rows (:func:`_windowed_block`), each
-    indexed by the layers of its own mixer's kind.
+    table and attend over its rows (:func:`_typed_block`), each
+    indexed by the layers of its own mixer's kind. Short convolutions
+    beside attention go the same way: a conv segment's layers read and
+    write the cache's tails (``valid`` as above) and a full segment's
+    rotate and attend over its rows.
     """
     rows = x.shape[0] * x.shape[1]
 
@@ -828,8 +877,8 @@ def forward_layers(
         if whole:
             layer = {**layer, **whole}
         j = j[0] if j else None
-        if mixer is not None:  # window and full layers mixed: told apart
-            h, c, now = _windowed_block(
+        if mixer is not None:  # mixers named by layer_types: told apart
+            h, c, now = _typed_block(
                 layer, h, c, mixer, cos, sin, pos, config, valid, ep_axis,
                 ep_size, i, count_local, j)
         elif "w_decay" in layer:
@@ -852,13 +901,14 @@ def forward_layers(
             return (h, c, local[0] + now), None
         return (h, c), None
 
-    # a window layer and a full one hold the same tensors: where the two are
-    # mixed, a segment's mixer says which body runs it (one a mixer, so that
-    # every segment of a kind traces the same function)
-    bodies = {m: partial(body, mixer=m) for m in ("swa", "gqa")}
+    # a window layer and a full one hold the same tensors: where
+    # ``layer_types`` names the mixers, a segment's mixer says which body
+    # runs it (one a mixer, so that every segment of a kind traces the same
+    # function)
+    bodies = {m: partial(body, mixer=m) for m in ("swa", "gqa", "conv")}
 
     def body_of(seg):
-        return bodies[seg.mixer] if config.windowed else body
+        return bodies[seg.mixer] if config.layer_types is not None else body
 
     def scan_segment(carry, stack, first, whole, body=body):
         """``stack``'s layers over the carry; ``first``: its first layer's

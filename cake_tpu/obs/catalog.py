@@ -58,6 +58,14 @@ SERIES: dict[str, tuple[str, str]] = {
     "cache.bytes": (
         GAUGE, "bytes of the serving cache as allocated (slots x window x "
                "layers x cache.row_bytes)"),
+    "cache.device_bytes": (
+        GAUGE, "bytes the serving cache's buffers occupy ON the device, "
+               "tile padding included (each buffer's "
+               "on_device_size_in_bytes, a replica counted once): "
+               "cache.bytes over this is the share of the reservation "
+               "that holds values, 1 where every row fills its tiles and "
+               "1/2 where a 64-wide bfloat16 row is padded to 128 lanes; "
+               "absent where the runtime does not say"),
     "cache.row_bytes": (
         GAUGE, "bytes the cache holds for one token of one layer that "
                "keeps every row, a mean over the layers of that ONE kind, "
@@ -95,11 +103,16 @@ SERIES: dict[str, tuple[str, str]] = {
     "cache.state_bytes": (
         GAUGE, "bytes of the serving cache that are recurrent state "
                "(delta-rule or state-space layers' float32 state and "
-               "convolution tails), from the buffers allocated; 0 where no "
-               "layer holds one"),
+               "convolution tails; a gated short convolution's tails "
+               "alone, which has no state), from the buffers allocated; 0 "
+               "where no layer holds one"),
     "cache.state_bytes_per_stream": (
         GAUGE, "cache.state_bytes / slots: what a stream's recurrent "
-               "state costs whatever its length"),
+               "state and convolution tails cost whatever its length"),
+    "conv.state_resets": (
+        COUNTER, "admissions that started a slot's short-convolution "
+                 "tails from zero (a fresh staging row, spliced over what "
+                 "the slot's last stream left; named scope mixer.conv)"),
     "kda.state_resets": (
         COUNTER, "admissions that started a slot's delta-rule state from "
                  "zero (a fresh staging row, spliced over what the slot's "

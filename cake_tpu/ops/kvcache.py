@@ -97,7 +97,10 @@ class KVCache:
     state-space layer's ``[L_rec, B, d_state, d_inner]``, ops/mamba.py)
     and ``conv [L_rec, B, taps - 1, channels]`` hold what such a layer
     keeps a stream, whatever its length. Both are None where no layer is
-    recurrent. Where some layers attend through a window
+    recurrent; a gated short convolution (ops/shortconv.py) keeps ``conv
+    [L_conv, B, taps - 1, hidden]`` and NO ``state``, so what a cache
+    holds is asked of ``LlamaConfig.cache_plan``, not of ``state``. Where
+    some layers attend through a window
     (``LlamaConfig.windowed``), ``k``/``v`` have a layer for each FULL
     layer, and ``ring_k``/``ring_v [L_window, B, KH, R, D]`` hold the
     window layers' rows: ``R = config.ring_rows`` rows a stream whatever
@@ -162,13 +165,15 @@ def init_cache(
     heads, k_width, v_width = config.cache_row
     plan = config.cache_plan
     rec = {}
-    if "state" in plan:
+    if "conv" in plan:  # layers that carry a tail, and a state or none
         if num_layers is not None:
-            raise ValueError("a model that holds a recurrent state is "
-                             "cached whole (no layer ranges)")
+            raise ValueError("a model that holds a recurrent state or a "
+                             "convolution's tail is cached whole (no "
+                             "layer ranges)")
         L = plan.get("rows", (0,))[0]
-        n, *shape = plan["state"]
-        rec["state"] = jnp.zeros((n, batch, *shape), jnp.float32)
+        if "state" in plan:
+            n, *shape = plan["state"]
+            rec["state"] = jnp.zeros((n, batch, *shape), jnp.float32)
         n, *shape = plan["conv"]
         rec["conv"] = jnp.zeros((n, batch, *shape), dt)
     if "ring" in plan:
@@ -192,8 +197,8 @@ def init_cache(
         if rec:
             raise ValueError(
                 "an int8 cache is not wired for a model whose layers hold "
-                "a recurrent state (its few layers of rows are the "
-                "smaller part of the cache)")
+                "a recurrent state or a convolution's tail (its few "
+                "layers of rows are the smaller part of the cache)")
 
         def half(width):
             shape = (L, batch, heads, S, width)

@@ -123,7 +123,7 @@ class GroupRouting(NamedTuple):
     ``routed_scaling_factor``): sigmoid scores over all experts; a
     group's score is the sum of its 2 highest; the ``topk_group`` best
     groups stay; top-k of the scores inside them; weights are those
-    scores, normalised over the chosen (``+ 1e-20``) and scaled.
+    scores, normalised over the chosen (``+ norm_eps``) and scaled.
     ``bias [E]`` (``topk_method: "noaux_tc"``): a per-expert correction
     that enters the CHOICE (groups and top-k are taken on ``score +
     bias``) and not the weights (the chosen experts' own scores)."""
@@ -133,6 +133,7 @@ class GroupRouting(NamedTuple):
     norm_topk: bool = True
     scale: float = 1.0
     bias: jax.Array | None = None
+    norm_eps: float = 1e-20
 
 
 def _deq(w, dt):
@@ -192,7 +193,7 @@ def router_topk(
         _, idx = jax.lax.top_k(choice, top_k)
         w = jnp.take_along_axis(scores, idx, axis=-1)
         if routing.norm_topk and top_k > 1:
-            w = w / (w.sum(-1, keepdims=True) + 1e-20)
+            w = w / (w.sum(-1, keepdims=True) + routing.norm_eps)
         w = w * routing.scale
     onehot = jax.nn.one_hot(idx, logits.shape[-1], dtype=w.dtype)  # [N,k,E]
     combine = jnp.einsum("nk,nke->ne", w, onehot)

@@ -84,6 +84,16 @@ def validate_shardable(config: LlamaConfig, num_stages: int, tp: int,
             "--ep shards it (its held experts): window layers under tp or "
             "stages are not wired"
         )
+    if config.short_conv and (num_stages > 1 or tp > 1 or sp > 1
+                              or ep > 1):
+        raise ValueError(
+            "a model of short-convolution and attention layers (stacks "
+            "of several kinds of layer, a convolution's tail beside the "
+            "attention layers' rows) runs as one stage with tp = 1, sp = "
+            "1 and ep = 1: nothing shards it yet (its tail under stages, "
+            "tp or sp is not wired, and every expert is held: no share "
+            "is cut over ep)"
+        )
     if config.state_space and (num_stages > 1 or tp > 1 or sp > 1
                                or ep > 1):
         raise ValueError(
@@ -220,22 +230,22 @@ CACHE_SPEC = P(STAGE, DP, TP, SP, None)
 
 
 def cache_specs(kv_quant: str | None = None, batch_replicated: bool = False,
-                recurrent: bool = False, ring: bool = False):
+                held=()):
     """PartitionSpec pytree matching :func:`cake_tpu.ops.kvcache.init_cache`'s
     structure: plain buffers take CACHE_SPEC; int8 buffers take it for the
     q bytes and the same layout minus head_dim for the per-slot scales.
 
     ``batch_replicated``: don't shard the batch axis over dp — the layout of
     a single-row staging cache (continuous-batching admission) that must
-    exist on every dp shard. ``recurrent``
-    (``LlamaConfig.recurrent``): the cache also holds recurrent layers'
-    state ``[L, B, ...]`` (a delta-rule layer's ``[L, B, H, d_k, d_v]``, a
-    state-space layer's ``[L, B, d_state, d_inner]``) and convolution tail
-    ``[L, B, taps - 1, C]``, batch over dp and no later axis sharded (such
-    a model runs as one stage with tp = 1). ``ring``
-    (``LlamaConfig.windowed``): it also holds the window layers' rings
-    ``[L, B, KH, R, D]``, batch over dp and no later axis sharded (as
-    above)."""
+    exist on every dp shard. ``held``: the kinds of ``LlamaConfig.
+    cache_plan`` the cache holds beside rows (the plan itself will do).
+    ``state``: recurrent layers' state ``[L, B, ...]`` (a delta-rule
+    layer's ``[L, B, H, d_k, d_v]``, a state-space layer's ``[L, B,
+    d_state, d_inner]``); ``conv``: a convolution's tail ``[L, B, taps - 1,
+    C]`` (beside a state, or alone: a gated short convolution's); ``ring``:
+    the window layers' rings ``[L, B, KH, R, D]``. Each has its batch over
+    dp and no later axis sharded (such a model runs as one stage with
+    tp = 1)."""
     from cake_tpu.ops.kvcache import KVCache, QuantizedKV
 
     bd = None if batch_replicated else DP
@@ -243,12 +253,11 @@ def cache_specs(kv_quant: str | None = None, batch_replicated: bool = False,
     if kv_quant == "int8":
         half = QuantizedKV(q=spec, scale=P(STAGE, bd, TP, SP))
         return KVCache(k=half, v=half)
-    held = {}  # what a stream holds whatever its length, by the kind
-    if recurrent:
-        held.update(state=P(STAGE, bd), conv=P(STAGE, bd))
-    if ring:
-        held.update(ring_k=P(STAGE, bd), ring_v=P(STAGE, bd))
-    return KVCache(k=spec, v=spec, **held)
+    # what a stream holds whatever its length: the buffers of each kind
+    buffers = {"state": ("state",), "conv": ("conv",),
+               "ring": ("ring_k", "ring_v")}
+    names = [n for kind, of in buffers.items() if kind in held for n in of]
+    return KVCache(k=spec, v=spec, **dict.fromkeys(names, P(STAGE, bd)))
 
 
 def shard_params(params: dict, mesh: Mesh) -> dict:
@@ -262,9 +271,12 @@ def shard_params(params: dict, mesh: Mesh) -> dict:
 def shard_cache(cache, mesh: Mesh):
     from cake_tpu.ops.kvcache import QuantizedKV
 
-    specs = cache_specs("int8" if isinstance(cache.k, QuantizedKV) else None,
-                        recurrent=cache.state is not None,
-                        ring=cache.ring_k is not None)
+    specs = cache_specs(
+        "int8" if isinstance(cache.k, QuantizedKV) else None,
+        held=[kind for kind, buf in (("state", cache.state),
+                                     ("conv", cache.conv),
+                                     ("ring", cache.ring_k))
+              if buf is not None])
     return jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), cache, specs
     )
@@ -297,7 +309,7 @@ def init_cache_on_mesh(config, mesh: Mesh, batch: int = 1,
     make = _CACHE_PROGRAMS.get(key)
     if make is None:
         specs = cache_specs(quant, batch_replicated=batch_replicated,
-                            recurrent=config.recurrent, ring=config.windowed)
+                            held=config.cache_plan)
         out_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                               is_leaf=lambda x: isinstance(x, P))
 

@@ -371,8 +371,7 @@ def build_sharded_decode(
 
         kv_specs = pool_specs(kv_quant)
     else:
-        kv_specs = cache_specs(kv_quant, recurrent=config.recurrent,
-                               ring=config.windowed)
+        kv_specs = cache_specs(kv_quant, held=config.cache_plan)
     in_specs = [
         param_specs(params_like),
         P(DP),
@@ -473,7 +472,7 @@ def build_sharded_decode(
 def moe_counted(config: LlamaConfig) -> bool:
     """Whether this model's serving decode programs count the routed pairs
     that fall on held experts (an expert model told its share)."""
-    return ((config.latent or config.windowed)
+    return ((config.latent or config.layer_types is not None)
             and config.n_routed_experts > 0)
 
 
@@ -770,14 +769,14 @@ def build_admit_prefill(config: LlamaConfig, plan: MeshPlan,
             param_specs(params_like),
             P(None, None),
             cache_specs(kv_quant, batch_replicated=True,
-                        recurrent=config.recurrent, ring=config.windowed),
+                        held=config.cache_plan),
             P(),
             P(None),
         ),
         out_specs=(
             P(None, None),
             cache_specs(kv_quant, batch_replicated=True,
-                        recurrent=config.recurrent, ring=config.windowed),
+                        held=config.cache_plan),
         ),
         check_vma=False,
     )
@@ -1085,8 +1084,7 @@ def build_sharded_prefill(config: LlamaConfig, plan: MeshPlan,
         logits = _head_logits(params, x_last, config)
         return logits, cache
 
-    kv_specs = cache_specs(kv_quant, recurrent=config.recurrent,
-                           ring=config.windowed)
+    kv_specs = cache_specs(kv_quant, held=config.cache_plan)
     in_specs = [
         param_specs(params_like),
         P(DP, None) if chunk_mode else P(DP, SP),

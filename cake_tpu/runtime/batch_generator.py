@@ -194,7 +194,8 @@ _MOE_ADMIT_ROWS = obs_metrics.counter("moe.admit_rows")
 _MOE_ADMIT_SORTED = obs_metrics.counter("moe.admit_rows_sorted")
 # by the mixer that holds the state (LlamaConfig.recurrent_mixer)
 _STATE_RESETS = {"kda": obs_metrics.counter("kda.state_resets"),
-                 "mamba": obs_metrics.counter("ssm.state_resets")}
+                 "mamba": obs_metrics.counter("ssm.state_resets"),
+                 "conv": obs_metrics.counter("conv.state_resets")}
 # The order of work at a block boundary (BatchGenerator._close_boundary):
 # host time from a block's fetch returning to the return of the step()
 # call that enqueued the device's next program, once per landed block,
@@ -232,6 +233,19 @@ _KV_BLOCKS_RESERVED = obs_metrics.counter("attn.kv_blocks_reserved")
 # stamps; None for what is not timed (imports, attaches, admit()).
 _ARR_IMPORT = "import"  # (xfer_id, None, None, _ARR_IMPORT, None)
 _ARR_ATTACH = "attach"  # (xfer_id, sid, None, _ARR_ATTACH, None)
+
+
+def _device_bytes(tree) -> int | None:
+    """What a pytree's buffers occupy on their devices, tile padding
+    included, a replica counted once; None where the runtime does not
+    say."""
+    try:
+        return sum(
+            shard.data.on_device_size_in_bytes()
+            for x in jax.tree.leaves(tree) for shard in x.addressable_shards
+            if shard.replica_id == 0)
+    except (AttributeError, NotImplementedError, RuntimeError):
+        return None
 
 
 def _carrying(prog, steps: int, rows):
@@ -387,11 +401,20 @@ class BatchGenerator:
                 "spill tier, hold per-head keys and values of every layer, "
                 "and no recurrent state); serve this family with the slot "
                 "layout")
+        if self._paged and config.short_conv:
+            raise ValueError(
+                "kv_layout='paged' is not wired for a model of "
+                "short-convolution and attention layers (the page pool, "
+                "and with it the disagg snapshot and the spill tier, hold "
+                "per-head keys and values of every layer, and no "
+                "convolution's tail); serve this family with the slot "
+                "layout")
         if config.recurrent and spec_k:
             raise ValueError(
                 "speculation (spec_k) is not wired for a model whose "
-                "layers hold a recurrent state: a rejected proposal has "
-                "already advanced the state, and nothing rolls it back")
+                "layers hold a recurrent state or a convolution's tail: a "
+                "rejected proposal has already advanced it, and nothing "
+                "rolls it back")
         if config.windowed and (self._paged or spec_k):
             raise ValueError(
                 "kv_layout='paged' and speculation (spec_k) are not wired "
@@ -670,8 +693,8 @@ class BatchGenerator:
             # (from a ring whose newest rows lie past the prefix). Every
             # prompt of such a model is prefilled whole.
             logging.getLogger("cake_tpu.batch_generator").info(
-                "prefix reuse is off: a recurrent state or a ring of rows "
-                "has no prefix to share")
+                "prefix reuse is off: a recurrent state, a convolution's "
+                "tail or a ring of rows has no prefix to share")
             self._prefix_entries = self._prefix_share_min = 0
         self._prefix_store = PrefixLRU(self._prefix_entries)
         self._prefix_block = max(1, prefix_block)
@@ -1199,6 +1222,9 @@ class BatchGenerator:
         rings = sum(x.nbytes for x in jax.tree.leaves(
             (self.cache.ring_k, self.cache.ring_v)))
         obs_metrics.gauge("cache.bytes").set(held)
+        on_device = _device_bytes(self.cache)
+        if on_device is not None:
+            obs_metrics.gauge("cache.device_bytes").set(on_device)
         obs_metrics.gauge("cache.row_bytes").set(
             (held - state - rings) / (self.cache.num_layers
                                       * self.cache.batch

@@ -4,7 +4,7 @@ Times :func:`cake_tpu.ops.moe.moe_swiglu` in the two forms
 :func:`cake_tpu.ops.moe.expert_form` chooses between for more than a
 handful of pairs, as the layer loop calls it (a scan over ``L`` layers:
 the dense form on the scan's slice of the stacks, the sorted form on the
-whole stacks with the layer's index), at the three expert cells' shapes
+whole stacks with the layer's index), at the four expert cells' shapes
 for 8 to 2048 rows: a decode step's rows (one a slot) and an admission's
 buckets. Where the sorted form is at least 1.10x the dense one is where
 the rule's constants come from: ``SORTED_MAX_HIT_SHARE*`` (the share of
@@ -48,6 +48,8 @@ SHAPES = {
     "mixtral8x7b-int8": (8, 8, 2, 4096, 14336, True, None),
     "axk1-ep16": (12, 192, 8, 7168, 2048, False, (8, 4)),
     "ling3flash-ep4": (128, 512, 8, 2560, 768, False, (8, 4)),
+    # every scored expert held, no groups (the identity at 1 / 1)
+    "lfm2-8b-a1b": (32, 32, 4, 2048, 1792, False, (1, 1)),
 }
 ROWS = (8, 16, 32, 64, 128, 256, 512, 1024, 2048)
 LAYERS = 3

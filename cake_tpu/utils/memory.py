@@ -69,6 +69,10 @@ def hbm_budget(
     if c.windowed and quant:
         raise ValueError("quantized linears are not wired for a model of "
                          "window and full attention layers")
+    if c.short_conv and quant:
+        raise ValueError("quantized linears are not wired for a model of "
+                         "short-convolution and attention layers (its "
+                         "mixer's projections have no int8 form yet)")
     if c.segmented:
         return _latent_budget(c, ep, S, batch, lin_el, scale_el, el,
                               cache_bytes_per_el)
@@ -171,9 +175,10 @@ def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
     plan = c.cache_plan  # rows for some layers, a state for the others
     kv_bytes = (plan.get("rows", (0,))[0] * batch * S * c.cache_row_values
                 * cache_el)
-    if "state" in plan:  # float32 state, the tail in the serving type
-        kv_bytes += batch * (math.prod(plan["state"]) * 4
-                             + math.prod(plan["conv"]) * el)
+    if "state" in plan:  # a float32 state ...
+        kv_bytes += batch * math.prod(plan["state"]) * 4
+    if "conv" in plan:  # ... and a tail in the serving type, or a tail alone
+        kv_bytes += batch * math.prod(plan["conv"]) * el
     if "ring" in plan:  # window layers: R rows a stream whatever S
         n, heads, rows, k_width, v_width = plan["ring"]
         kv_bytes += batch * n * heads * rows * (k_width + v_width) * cache_el

@@ -621,6 +621,11 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
             "full attention layers (its q, k and v projections are not "
             "among the shared-expert family's int8 linears); serve it in "
             "bf16")
+    if tier is not None and config.short_conv:
+        raise NotImplementedError(
+            "quantized linears are not wired for a model of "
+            "short-convolution and attention layers (its mixer's "
+            "projections have no int8 form yet); serve it in bf16")
     prequantized = check_prequantized(reader.name_to_file, quantize)
     if not tie_word_embeddings and detect_tied_head(
             reader.name_to_file, model_dir, "cake_tpu.sharded_load"):

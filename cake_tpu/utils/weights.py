@@ -163,8 +163,42 @@ _STATE_SPACE_MAP = {
 }
 
 
+# Gated short convolutions beside grouped-query attention, every expert
+# held (`model_type` "lfm2_moe"; the names are ASSUMED, the benchmark
+# configuration lists them: Hugging Face's Lfm2Moe modules): a layer's
+# norms `operator_norm` and `ffn_norm`, the mixer under `conv.` with its
+# taps as torch depthwise `[C, 1, K]`, the attention's output projection
+# `out_proj` and head norms `q_layernorm` / `k_layernorm`, the feed-forward
+# under `feed_forward.` in Mixtral's w1 (gate) / w3 (up) / w2 (down), the
+# router `feed_forward.gate` with its `expert_bias`, the model's last norm
+# `model.embedding_norm`.
+_SHORT_CONV_MAP = {
+    "attn_norm": ("operator_norm.weight", False),
+    "w_in": ("conv.in_proj.weight", True),
+    "conv_w": ("conv.conv.weight", True),
+    "w_out": ("conv.out_proj.weight", True),
+    **{k: _LAYER_MAP[k] for k in ("wq", "wk", "wv")},
+    "wo": ("self_attn.out_proj.weight", True),
+    "q_norm": ("self_attn.q_layernorm.weight", False),
+    "k_norm": ("self_attn.k_layernorm.weight", False),
+    "mlp_norm": ("ffn_norm.weight", False),
+    "w_gate": ("feed_forward.w1.weight", True),
+    "w_up": ("feed_forward.w3.weight", True),
+    "w_down": ("feed_forward.w2.weight", True),
+    "router": ("feed_forward.gate.weight", True),
+    "b_router": ("feed_forward.expert_bias", False),
+}
+_SHORT_CONV_EXPERT_MAP = {
+    "w_gate": "feed_forward.experts.{e}.w1.weight",
+    "w_up": "feed_forward.experts.{e}.w3.weight",
+    "w_down": "feed_forward.experts.{e}.w2.weight",
+}
+
+
 def final_norm_name(config) -> str:
     """The stored name of the model's last norm."""
+    if config.short_conv:
+        return "model.embedding_norm.weight"
     return ("model.final_layernorm.weight" if config.state_space
             else "model.norm.weight")
 
@@ -182,10 +216,13 @@ def latent_stack_plan(config) -> dict:
 
     names = {**_LATENT_MAP, **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP,
              **_HYBRID_EXTRA_MAP}
+    expert_names = _LATENT_EXPERT_MAP
     if config.state_space:
         names = _STATE_SPACE_MAP
     elif config.windowed:
         names = _WINDOWED_MAP
+    elif config.short_conv:
+        names, expert_names = _SHORT_CONV_MAP, _SHORT_CONV_EXPERT_MAP
     plan = {}
     for run, seg in plan_segments(config):
         shapes = segment_shapes(config, seg)
@@ -197,8 +234,8 @@ def latent_stack_plan(config) -> dict:
         plan[seg.name] = (
             run.layer_ids(seg),
             {k: table[k] for k in shapes if k in table and (
-                seg.ffn == "dense" or k not in _LATENT_EXPERT_MAP)},
-            _LATENT_EXPERT_MAP if seg.ffn == "moe" else {})
+                seg.ffn == "dense" or k not in expert_names)},
+            expert_names if seg.ffn == "moe" else {})
     return plan
 
 
@@ -211,11 +248,13 @@ def hf_layout(ours: str, w: np.ndarray, transpose: bool) -> np.ndarray:
 
 
 def is_latent_checkpoint(name_to_file: dict) -> bool:
-    """Whether the checkpoint stores latent-attention or state-space
-    tensors, or a routing bias beside per-head attention (window and full
-    layers mixed): a model of several layer stacks, which loads whole."""
+    """Whether the checkpoint stores latent-attention, state-space or
+    short-convolution tensors, or a routing bias beside per-head attention
+    (window and full layers mixed): a model of several layer stacks, which
+    loads whole."""
     return any(".self_attn.kv_a_proj_with_mqa.weight" in n
                or ".mamba.in_proj.weight" in n
+               or ".conv.in_proj.weight" in n
                or ".mlp.gate.e_score_correction_bias" in n
                for n in name_to_file)
 

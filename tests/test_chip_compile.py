@@ -591,11 +591,11 @@ def test_sparse_admission_reads_the_int8_stacks_where_they_lie(
     assert _grouped_matmul_calls(_block_decode(topo, 2, sparse=True)) == 0
 
 
-def _family_programs(topo, config, slots: int, window: int, bucket: int):
-    """(block decode, admission) of a latent-family ``config`` compiled for
-    one described v5e, bf16: BatchGenerator's fused 8-step per-row block
-    decode over ``slots`` slots and one ``bucket``-token admission chunk
-    into the batch-1 staging cache."""
+def _family_programs(topo, config, slots: int, window: int, *buckets: int):
+    """(block decode, an admission a bucket) of a latent-family ``config``
+    compiled for one described v5e, bf16: BatchGenerator's fused 8-step
+    per-row block decode over ``slots`` slots and one admission chunk of
+    each of ``buckets`` tokens into the batch-1 staging cache."""
     from jax.sharding import NamedSharding
 
     from cake_tpu.models.llama import init_params
@@ -626,7 +626,7 @@ def _family_programs(topo, config, slots: int, window: int, bucket: int):
             jax.eval_shape(lambda: init_cache(config, batch=batch,
                                               max_seq=window)),
             cache_specs(None, batch_replicated=batch == 1,
-                        recurrent=config.recurrent, ring=config.windowed))
+                        held=config.cache_plan))
 
     settings = SamplerSettings(temperature=0.0)
     decode = build_sharded_decode(
@@ -635,9 +635,11 @@ def _family_programs(topo, config, slots: int, window: int, bucket: int):
             arg((slots, 2), jnp.uint32),
             arg((slots, settings.repeat_last_n)), arg((slots,)),
             arg((slots,))).compile()
-    admit = build_admit_prefill(config, plan, params_like=params).lower(
-        params, arg((1, bucket)), cache(1), arg(()), arg((1,))).compile()
-    return decode, admit
+    admits = [
+        build_admit_prefill(config, plan, params_like=params).lower(
+            params, arg((1, bucket)), cache(1), arg(()), arg((1,))).compile()
+        for bucket in buckets]
+    return (decode, *admits)
 
 
 def _latent_programs(topo, layers: int, slots: int, window: int, bucket: int):
@@ -881,6 +883,84 @@ def test_window_and_full_programs_move_no_cache_and_no_ring(program,
     assert m.temp_size_in_bytes < 1.0 * GIB, m.temp_size_in_bytes / GIB
     # the admission beside the live cache and the undonated splice's copy
     assert (args + temps + m.temp_size_in_bytes + 0.6 * GIB
+            < 13 / 16 * HBM_GIB["v5 lite"] * GIB)
+
+
+def _layouts(compiled, shape: str) -> set[str]:
+    """Every layout the compiled program gives a value of ``shape``
+    (``bf16[4,32,8,2048,64]``): the text between its braces."""
+    import re
+
+    return set(re.findall(re.escape(shape) + r"\{([^}]*)\}",
+                          compiled.as_text()))
+
+
+def test_conv_and_attention_programs_fit_one_chip(topo, as_on_chip):
+    """The short-convolution + attention family's serving programs at
+    LFM2-8B-A1B's published widths, the cell ``lfm2-8b-a1b-cut.decode-full``
+    itself: layers 0-15 (two dense conv layers, then ``A`` and ``c c c``
+    by turns, ``A``, ``c``: nine scanned segments and no repeated period),
+    every one of the 32 experts, the whole vocabulary, 32 slots x 2048 rows; the block decode
+    and the 128-, 512- and 2048-row admissions. The chip's compiler takes
+    them and they fit one chip. RECORDED (my AOT compile, PR 43): 10.81
+    GiB of arguments (10.31 of weights, the tied matrix twice, + 0.50 of
+    rows + 3 MiB of tails) and 0.007 GiB of temporaries in the step;
+    0.004, 0.14 and 0.53 GiB of temporaries in the admissions. (With
+    ``A c c c`` scanned as a repeated period the 128- and 256-row
+    admissions, whose expert block takes the dense form, held 5.26 GiB of
+    temporaries: the period's gate and up stacks copied transposed in
+    ENTRY; the chip refused to load them beside the weights. So
+    ``layer_plan`` repeats no period here.)
+
+    What the 64-wide rows got: the chip's default layout of ``[.., 2048,
+    64]`` in bfloat16 puts the ROWS on the lanes and the head's 64
+    channels on the sublanes (``{3,4,2,1,0:T(8,128)(2,1)}``: minor-most is
+    the sequence axis), in the step and in both admissions alike, so no
+    row is padded to a tile and nothing re-lays the cache: no value of the
+    rows' shape is allocated or copied. The tails ``[12, 32, 2, 2048]``
+    lie as they are declared, two rows a tile (``T(2,128)``), and are
+    copied once on the way into and once out of the step (3 MiB each, in
+    ENTRY, in no loop); no expert stack is written out of the scanned
+    weights. Attention runs on XLA (``flash_decode`` wants heads of 128):
+    the step sweeps the reservation. The step's 128 pairs hit 0.98 of the
+    32 experts: the dense form, and so the 128-row admission; the 512- and
+    2048-row admissions sort, in each of the eight sparse segments."""
+    from cake_tpu.models.config import lfm2_8b_a1b
+    from cake_tpu.utils.chips import HBM_GIB
+
+    slots, window = 32, 2048
+    config = lfm2_8b_a1b(num_hidden_layers=16, max_seq_len=window)
+    decode, admit128, admit512, admit2048 = _family_programs(
+        topo, config, slots, window, 128, 512, 2048)
+    rows_on_lanes = "3,4,2,1,0:T(8,128)(2,1)"
+    for compiled, batch in ((decode, slots), (admit128, 1), (admit512, 1),
+                            (admit2048, 1)):
+        rows = f"bf16[4,{batch},8,{window},64]"
+        assert _layouts(compiled, rows) == {rows_on_lanes}, _layouts(
+            compiled, rows)
+        assert _cache_sized_moves(compiled, rows) == []
+        assert _expert_stack_moves(compiled, "bf16", 32, 2048, 1792) == []
+        tails = _cache_sized_moves(compiled, f"bf16[12,{batch},2,2048]")
+        assert len(tails) <= 2 and all(
+            m.startswith("main") for m in tails), tails
+    assert "3,2,1,0:T(2,128)(2,1)" in _layouts(decode, "bf16[12,32,2,2048]")
+    assert not [line for line in decode.as_text().splitlines()
+                if "tpu_custom_call" in line and "flash_decode" in line]
+    assert _grouped_matmul_calls(decode) == 0
+    assert _grouped_matmul_calls(admit128) == 0
+    assert _grouped_matmul_calls(admit512) == 24
+    assert _grouped_matmul_calls(admit2048) == 24
+    args, temps = _donated_bytes(decode)
+    assert 10.75 * GIB < args < 10.9 * GIB, args / GIB  # 10.31 + 0.50
+    assert temps < 0.02 * GIB, temps / GIB
+    dense, small, large = (a.memory_analysis().temp_size_in_bytes
+                           for a in (admit128, admit512, admit2048))
+    assert dense < 0.02 * GIB, dense / GIB  # no stack re-laid
+    assert small < 0.2 * GIB and large < 0.7 * GIB, (small / GIB,
+                                                     large / GIB)
+    # the admission beside the live cache, its staging row and the
+    # undonated splice's second cache
+    assert (args + temps + large + 0.6 * GIB
             < 13 / 16 * HBM_GIB["v5 lite"] * GIB)
 
 

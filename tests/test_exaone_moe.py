@@ -266,7 +266,7 @@ def test_full_layers_do_not_rotate_and_window_layers_do(params):
 
     def block(name, mixer, tables):
         layer = jax.tree.map(lambda w: w[0], params["layers"][name])
-        return llama._windowed_block(
+        return llama._typed_block(
             layer, x, cache, mixer, *tables, 0, CFG, None, None, None,
             jnp.int32(0), False, None)[0]
 
