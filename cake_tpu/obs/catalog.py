@@ -37,23 +37,28 @@ HISTOGRAM = "histogram"
 # name -> (kind, meaning). Grouped by owning subsystem; keep each group
 # sorted so diffs stay reviewable.
 SERIES: dict[str, tuple[str, str]] = {
-    # -- decode attention over the reservation (ops/attention, the engine) -
+    # -- decode attention over the reservation (ops/attention, ops/mla,
+    #    the engine) ------------------------------------------------------
     "attn.decode_kernel": (
-        GAUGE, "what ops.attention.attend chose for the last single-token "
-               "attention it traced (the decode programs'): 1 the kernel "
-               "that reads each stream's KV blocks up to its frontier, 0 "
-               "the sweep of the reservation; absent where no program "
-               "attends through it"),
+        GAUGE, "what the last single-token attention traced (the decode "
+               "programs') chose, by ops.attention.attend for per-head "
+               "keys and values and by ops.mla.latent_attention_block "
+               "for a latent cache: 1 the kernel that reads each "
+               "stream's blocks up to its frontier (flash_decode, "
+               "latent_decode), 0 the sweep of the reservation; absent "
+               "where no program attends through either"),
     "attn.kv_blocks_read": (
         COUNTER, "KV blocks (ops.pallas.DECODE_BLOCK_K rows) a layer's "
-                 "decode attention reads under the kernel's block range "
-                 "(ops.pallas.decode_block_range, the kernel's own): over "
-                 "every slot and decode step, from the window's lower bound "
-                 "up to the frontier as dispatched (a slot without a live "
-                 "stream goes out at row 0: one block)"),
+                 "decode attention reads under the kernels' block range "
+                 "(ops.pallas.decode_block_range, which flash_decode and "
+                 "the latent cache's latent_decode both walk): over "
+                 "every slot and decode step, from the window's lower "
+                 "bound up to the frontier as dispatched (a slot without "
+                 "a live stream goes out at row 0: one block)"),
     "attn.kv_blocks_reserved": (
         COUNTER, "KV blocks a layer's reservation holds for the same "
-                 "steps: slots x window / block x steps"),
+                 "steps: slots x window / block x steps (a latent "
+                 "cache's rows are blocks of the same 512)"),
     # -- the cache and the expert layers (runtime/batch_generator) ------
     "cache.bytes": (
         GAUGE, "bytes of the serving cache as allocated (slots x window x "

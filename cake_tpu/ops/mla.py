@@ -28,7 +28,10 @@ Two forms of the same mathematics, chosen at trace time:
   query and the output, ``q'_h = q_nope_h W_kvb,k,h^T`` scores against ``c``
   itself and ``o_h = (softmax . c) W_kvb,v,h``, so no key or value is ever
   expanded per head for a cached token. A decode step (``T == 1``) is this
-  form over the whole buffer.
+  form: on the chip, from ``LATENT_DECODE_MIN_S`` rows up, the kernel
+  :func:`cake_tpu.ops.pallas.latent.latent_decode` over each stream's
+  blocks up to its frontier, fetched once out of the carried buffers;
+  elsewhere XLA's einsums over the whole buffer, masked.
 - **expanded** (a chunk's own tokens, ``T > 1``): the chunk's ``k_nope``
   and ``v`` are expanded from its own ``c`` and attended causally,
   ``T x T``. What the chunk has behind it in the cache (positions below
@@ -45,14 +48,98 @@ convert and multiply fuse into the einsum's operand read).
 
 from __future__ import annotations
 
+import logging
+
 import jax
 import jax.numpy as jnp
 
+from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.ops import kvcache as kv
+from cake_tpu.ops import pallas as pk
 from cake_tpu.ops import quant
 from cake_tpu.ops.attention import NEG_INF
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.rope import apply_rope
+
+
+log = logging.getLogger("cake_tpu.mla")
+
+# A decode step (T == 1) over a latent cache: XLA's einsums sweep the whole
+# reserved buffer, twice, whatever the frontier; the kernel
+# (ops/pallas/latent.py) fetches each stream's blocks of
+# ``pk.DECODE_BLOCK_K`` rows up to its frontier, once.
+# tools/flash_sweep.py --only served-latent on v5 lite (B 32, 512 + 64
+# values a row, a layer's time inside a walk over 8 layers, PR 44; two
+# calls agree to 2 us), kernel at 512-row blocks / XLA, us a layer:
+#
+# - S 4096 (both latent cells): 64 heads 57 / 408 at the frontiers a
+#   `decode-full` batch has (64-700; every frontier at 64: 51, at 300: 50,
+#   at 704: 83), 275 / 414 with every frontier at the buffer's end (the
+#   cost side: XLA reads the 134 MB latent buffer twice, the kernel once,
+#   1.07 us a 0.66 MB block); 32 heads 51 / 402 and 261 / 402. In the
+#   served `axk1-ep16-cut` step XLA's two fusions took 359 us a layer and
+#   the call takes 39 (PERF.md section 5).
+# - S 2048: 55 / 216 and 149 / 216 (64 heads), 52 / 213 and 140 / 211
+#   (32); S 1024: 56 / 118 and 82 / 126, 50 / 117 and 78 / 117: ahead at
+#   the end too, so the floor is where skipping begins: S 512 is one
+#   block, read whole by either.
+# - blocks of 256 rows: 59 at the served frontiers and 385 at the end of
+#   4096 (a block's two products no longer hide behind its fetch); 1024
+#   rows: 71 served (1024 rows read for a 300-row stream) and 229 at the
+#   end. So 512, which is also what the engine's attn.kv_blocks_* count.
+#
+# The frontier is data, so a full cache runs the kernel too, at two
+# thirds of XLA's cost.
+LATENT_DECODE_MIN_S = 1024
+
+
+def latent_decode_choice(s: int, dc: int, dr: int) -> str:
+    """``"kernel"`` or ``"xla"`` for a single-token (T == 1) absorbed
+    attention over an ``s``-row latent cache of ``dc`` latent and ``dr``
+    rope values a row: THE latent decode policy, from what a trace can
+    see of its input (the shapes; the frontier is data).
+    :func:`latent_attention_block` asks it, once for each decode program
+    traced, and publishes the answer (``attn.decode_kernel``)."""
+    if not pk.kernels_enabled():
+        return "xla"
+    if pk.force_kernels() and pk.interpret_default():
+        return "kernel"  # interpreted: any shape
+    # whole blocks of the measured size (what the engine's
+    # attn.kv_blocks_* count), latent rows that fill their lanes, and a
+    # rope half under a lane tile, which the chip stores rows-on-lanes:
+    # the layout the kernel takes it in (ops/pallas/latent.py)
+    whole = (s % pk.DECODE_BLOCK_K == 0 and dc % 128 == 0
+             and dr % 16 == 0 and dr < 128)
+    if whole and (pk.force_kernels() or s >= LATENT_DECODE_MIN_S):
+        return "kernel"
+    if pk.force_kernels():
+        log.warning(
+            "kernels forced (CAKE_PALLAS=1) but the latent decode shape "
+            "(S=%d, kv_lora_rank=%d, rope=%d) is not one the kernel "
+            "serves (S%%%d==0, kv_lora_rank%%128==0, rope%%16==0 and "
+            "under 128); falling back to the XLA path", s, dc, dr,
+            pk.DECODE_BLOCK_K)
+    return "xla"
+
+
+def masked_sweep(q_c, q_pe, c_all, r_all, valid, scale):
+    """XLA's form of the absorbed sweep: the absorbed query ``q_c [B, H, T,
+    dc]`` and the roped ``q_pe [B, H, T, dr]`` against ALL of one layer's
+    rows ``c_all [B, S, dc]`` / ``r_all [B, S, dr]``, masked to those
+    ``valid`` (``[B|1, 1, T, S]``) admits. Returns the scaled scores' row
+    maximum, the un-normalized probabilities ``[B, H, T, S]`` (the
+    normalizer is their sum) and the un-normalized output in latent space
+    ``[B, H, T, dc]``, all float32."""
+    sc = (jnp.einsum("bhtc,bsc->bhts", q_c, c_all,
+                     preferred_element_type=jnp.float32)
+          + jnp.einsum("bhtr,bsr->bhts", q_pe, r_all,
+                       preferred_element_type=jnp.float32)) * scale
+    sc = jnp.where(valid, sc, NEG_INF)
+    m = jnp.max(sc, axis=-1, keepdims=True)
+    p = jnp.exp(sc - m)
+    o_c = jnp.einsum("bhts,bsc->bhtc", p.astype(c_all.dtype), c_all,
+                     preferred_element_type=jnp.float32)
+    return m, p, o_c
 
 
 def _plain(w, dtype):
@@ -110,24 +197,30 @@ def latent_attention_block(
     def cached(valid):
         """Absorbed attention against the cached rows ``valid`` admits
         (``[B|1, 1, T, S]``): row maximum, normalizer and the un-normalized
-        output ``[B, H, T, dv]``, all float32."""
+        output ``[B, H, T, dv]``, all float32. ``valid`` None (``T == 1``):
+        the rows up to each stream's frontier, by the kernel, which reads
+        those rows' blocks and no others, once, out of the carried
+        buffers themselves; a mask sweeps the whole buffer, twice."""
         q_c = jnp.einsum("bhtn,chn->bhtc", q_nope, w_k)
-        sc = (jnp.einsum("bhtc,bsc->bhts", q_c, c_all,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bhtr,bsr->bhts", q_pe, r_all,
-                           preferred_element_type=jnp.float32)) * scale
-        sc = jnp.where(valid, sc, NEG_INF)
-        m = jnp.max(sc, axis=-1, keepdims=True)
-        p = jnp.exp(sc - m)
-        o_c = jnp.einsum("bhts,bsc->bhtc", p.astype(c_all.dtype), c_all,
-                         preferred_element_type=jnp.float32)
+        if valid is None:
+            m, l, o_c = pk.latent_decode(q_c[:, :, 0], q_pe[:, :, 0], c_cache,
+                                         r_cache, pos, scale=scale,
+                                         layer=layer_idx)
+        else:
+            m, p, o_c = masked_sweep(q_c, q_pe, c_all, r_all, valid, scale)
         o = jnp.einsum("bhtc,chv->bhtv", o_c.astype(x.dtype), w_v,
                        preferred_element_type=jnp.float32)
-        return m, jnp.sum(p, axis=-1, keepdims=True), o
+        if valid is not None:  # summed here, where the lowered text had it
+            l = jnp.sum(p, axis=-1, keepdims=True)
+        return m, l, o
 
     kpos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, t, s), 3)
     if t == 1:
-        m, l, o = cached(kpos <= pos_b)
+        kernel = latent_decode_choice(s, dc, dr) == "kernel"
+        # trace time: which attention the decode program being built
+        # holds (read beside the engine's attn.kv_blocks_* counts)
+        obs_metrics.gauge("attn.decode_kernel").set(int(kernel))
+        m, l, o = cached(None if kernel else kpos <= pos_b)
         out = o / l
     else:
         # the chunk's own tokens, expanded and causal among themselves

@@ -58,6 +58,7 @@ from cake_tpu.ops.pallas.flash import (  # noqa: E402
     flash_decode,
 )
 from cake_tpu.ops.pallas.kda import kda_decode  # noqa: E402
+from cake_tpu.ops.pallas.latent import latent_decode  # noqa: E402
 from cake_tpu.ops.pallas.moe import (  # noqa: E402
     ROW_TILE as MOE_ROW_TILE,
     group_tiles,
@@ -80,6 +81,7 @@ __all__ = [
     "flash_attention_q8",
     "flash_decode",
     "kda_decode",
+    "latent_decode",
     "MOE_ROW_TILE",
     "group_tiles",
     "grouped_matmul",

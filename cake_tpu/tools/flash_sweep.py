@@ -7,16 +7,19 @@ crossover used by :func:`cake_tpu.ops.attention.attend`'s ``impl="auto"``
 dispatch — the same measured-crossover treatment ``quant_matmul`` got for its
 M>=16 gate (`ops/quant.py`).
 
-Usage:  python -m cake_tpu.tools.flash_sweep [--json-out PATH] [--only served-decode]
+Usage:  python -m cake_tpu.tools.flash_sweep [--json-out PATH]
+            [--only served-decode|served-latent]
 (``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
 Prints one JSON line per shape:
   {"path": "prefill"|"decode", "t", "s", "pallas_ms", "xla_ms", "speedup"}
 
-``--only served-decode`` runs just :func:`served_decode_rows`: the decode
-kernel against XLA on the stacked cache the layer loop carries, at the
-served shapes and frontiers (what decides ``DECODE_FLASH_MIN_S`` and
-``DECODE_BLOCK_K``; its table is kept in PERF.md).
+``--only served-decode`` runs just :func:`served_decode_rows` and
+:func:`served_latent_rows`: the decode kernels against XLA on the stacked
+cache the layer loop carries, at the served shapes and frontiers (what
+decides ``DECODE_FLASH_MIN_S`` and ``DECODE_BLOCK_K``, and for the latent
+cache ``ops.mla.LATENT_DECODE_MIN_S``; the tables stand beside those
+constants). ``--only served-latent`` runs the latent rows alone.
 """
 
 from __future__ import annotations
@@ -75,6 +78,30 @@ SERVED_DECODE_SHAPES = (
 )
 
 
+def _layer_ms(fn, q, pos, layers: int, *cache, iters: int = 10) -> float:
+    """A layer's milliseconds inside one pass over ``layers`` layers:
+    ``fn(q, pos, layer, *cache) -> q-shaped`` with the layer index traced,
+    so no layer's rows stay in fast memory between calls."""
+    import time
+
+    from cake_tpu.tools.kernel_check import _sync
+
+    @jax.jit
+    def step(q, pos, *cache):
+        def body(q, layer):
+            out = fn(q, pos, layer, *cache)
+            return q + (out * 1e-30).astype(q.dtype), None
+
+        return jax.lax.scan(body, q, jnp.arange(layers, dtype=jnp.int32))[0]
+
+    _sync(step(q, pos, *cache))  # compile
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        q = step(q, pos, *cache)
+    _sync(q)
+    return (time.perf_counter() - t0) / (iters * layers) * 1e3
+
+
 def served_decode_rows(results: list, blocks=(128, 256, 512, 1024),
                        layers: int = 8) -> None:
     """Decode (T == 1) on the STACKED cache ``[L, B, KVH, S, D]`` as a
@@ -87,43 +114,23 @@ def served_decode_rows(results: list, blocks=(128, 256, 512, 1024),
     ``SERVED_DECODE_SHAPES``, over frontiers early (64, 300, 704), mixed
     as ``decode-full`` draws them, and at the buffer's end (the cost
     side: nothing to skip)."""
-    import time
-
     from cake_tpu.ops import kvcache as kv
     from cake_tpu.ops.attention import _attend_xla, flash_decode_choice
     from cake_tpu.ops.pallas import (DECODE_BLOCK_K, decode_block_k,
                                      flash_decode, interpret_default)
-    from cake_tpu.tools.kernel_check import _sync
 
     compiled = not interpret_default()
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
 
-    def xla(q, k, v, pos, layer):
+    def xla(q, pos, layer, k, v):
         return _attend_xla(q, kv.layer_view(k, layer),
                            kv.layer_view(v, layer), pos)
 
     def kernel(bk):
-        def run(q, k, v, pos, layer):
+        def run(q, pos, layer, k, v):
             return flash_decode(q, k, v, pos, layer=layer, block_k=bk,
                                 interpret=not compiled)
         return run
-
-    def layer_ms(fn, q, k, v, pos, iters=10) -> float:
-        @jax.jit
-        def step(q, k, v, pos):
-            def body(q, layer):
-                out = fn(q, k, v, pos, layer)
-                return q + (out * 1e-30).astype(q.dtype), None
-
-            return jax.lax.scan(body, q,
-                                jnp.arange(layers, dtype=jnp.int32))[0]
-
-        _sync(step(q, k, v, pos))  # compile
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            q = step(q, k, v, pos)
-        _sync(q)
-        return (time.perf_counter() - t0) / (iters * layers) * 1e3
 
     for b, s, h, kvh, d in SERVED_DECODE_SHAPES:
         k = jax.random.normal(ks[0], (layers, b, kvh, s, d), jnp.bfloat16)
@@ -139,11 +146,78 @@ def served_decode_rows(results: list, blocks=(128, 256, 512, 1024),
                    "heads": h, "kv_heads": kvh, "d": d,
                    "layers": layers, "frontier": name,
                    "auto_impl": flash_decode_choice(s, d, kvh),
-                   "xla_ms": round(layer_ms(xla, q, k, v, pos), 4)}
+                   "xla_ms": round(_layer_ms(xla, q, pos, layers, k, v), 4)}
             for bk in blocks:
                 if (decode_block_k(s, kvh, d, 2, bk) == bk
                         and (bk >= 256 or fit < DECODE_BLOCK_K)):
-                    ms = layer_ms(kernel(bk), q, k, v, pos)
+                    ms = _layer_ms(kernel(bk), q, pos, layers, k, v)
+                    rec[f"pallas_bk{bk}_ms"] = round(ms, 4)
+            results.append(rec)
+            print(json.dumps(rec), flush=True)
+
+
+# (batch, rows, heads) of served_latent_rows at kv_lora_rank 512 + rope 64:
+# the cells axk1-ep16-cut (64 heads) and ling3flash-ep4-cut (32) at their
+# 32 slots x 4096 rows, and shorter windows (where the floor is)
+SERVED_LATENT_SHAPES = (
+    (32, 4096, 64), (32, 4096, 32), (32, 2048, 64), (32, 2048, 32),
+    (32, 1024, 64), (32, 1024, 32),
+)
+
+
+def served_latent_rows(results: list, blocks=(256, 512, 1024),
+                       layers: int = 8, dc: int = 512, dr: int = 64) -> None:
+    """The latent decode step's absorbed sweep on the STACKED latent cache
+    ``[L, B, 1, S, dc]`` + ``[L, B, 1, S, dr]``, as
+    :func:`served_decode_rows` times the GQA one: the kernel
+    (``ops/pallas/latent.py``) at each block size against XLA's two masked
+    einsums over the layer's slice (``ops.mla.masked_sweep``), at
+    ``SERVED_LATENT_SHAPES``, over the same frontiers; ``end`` is the cost
+    side (XLA reads the ``c`` buffer twice, the kernel once, and nothing
+    is skipped)."""
+    from cake_tpu.ops import kvcache as kv
+    from cake_tpu.ops.mla import latent_decode_choice, masked_sweep
+    from cake_tpu.ops.pallas import interpret_default, latent_decode
+
+    compiled = not interpret_default()
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    scale = (128 + dr) ** -0.5
+
+    for b, s, h in SERVED_LATENT_SHAPES:
+        c = jax.random.normal(ks[0], (layers, b, 1, s, dc), jnp.bfloat16)
+        r = jax.random.normal(ks[1], (layers, b, 1, s, dr), jnp.bfloat16)
+        q_pe = jax.random.normal(ks[2], (b, h, 1, dr), jnp.bfloat16)
+        q = jax.random.normal(ks[3], (b, h, 1, dc), jnp.bfloat16)
+
+        def xla(q_c, pos, layer, c, r):
+            valid = (jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, s), 3)
+                     <= pos[:, None, None, None])
+            m, p, o_c = masked_sweep(
+                q_c, q_pe, kv.layer_view(c, layer)[:, 0],
+                kv.layer_view(r, layer)[:, 0], valid, scale)
+            return o_c / jnp.sum(p, axis=-1, keepdims=True) + m
+
+        def kernel(bk):
+            def run(q_c, pos, layer, c, r):
+                m, l, o_c = latent_decode(
+                    q_c[:, :, 0], q_pe[:, :, 0], c, r, pos, scale=scale,
+                    layer=layer, block_k=bk, interpret=not compiled)
+                return o_c / l + m
+            return run
+
+        frontiers = {"64": 64, "300": 300, "704": 704, "end": s - 1,
+                     "mixed": _served_frontiers(b)}
+        for name, at in frontiers.items():
+            pos = jnp.minimum(jnp.broadcast_to(jnp.asarray(at, jnp.int32),
+                                               (b,)), s - 1)
+            rec = {"path": "latent_decode_stacked", "batch": b, "s": s,
+                   "heads": h, "dc": dc, "dr": dr, "layers": layers,
+                   "frontier": name,
+                   "auto_impl": latent_decode_choice(s, dc, dr),
+                   "xla_ms": round(_layer_ms(xla, q, pos, layers, c, r), 4)}
+            for bk in blocks:
+                if bk <= s:
+                    ms = _layer_ms(kernel(bk), q, pos, layers, c, r)
                     rec[f"pallas_bk{bk}_ms"] = round(ms, 4)
             results.append(rec)
             print(json.dumps(rec), flush=True)
@@ -199,6 +273,7 @@ def sweep(json_out: str | None = None) -> list:
         print(json.dumps(rec), flush=True)
 
     served_decode_rows(results)
+    served_latent_rows(results)
 
     # Batched (serving) decode: per-row frontiers, the BatchGenerator shape
     for bb, s in ((8, 1024), (8, 4096), (32, 1024), (32, 4096)):
@@ -333,13 +408,16 @@ def main() -> int:
     configure()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json-out", default=None)
-    ap.add_argument("--only", choices=["served-decode"], default=None,
+    ap.add_argument("--only", choices=["served-decode", "served-latent"],
+                    default=None,
                     help="run one section instead of the whole sweep")
     args = ap.parse_args()
     refuse_offchip_record(args.json_out)
-    if args.only == "served-decode":
+    if args.only:
         rows: list = []
-        served_decode_rows(rows)
+        if args.only == "served-decode":
+            served_decode_rows(rows)
+        served_latent_rows(rows)
         if args.json_out:
             with open(args.json_out, "w") as f:
                 json.dump(rows, f, indent=1)

@@ -77,6 +77,21 @@ def _decode_stacked(layers, b, s, window, h=H, kvh=KVH, d=D):
                  ((layers, b, kvh, s, d), BF16), ((b,), I32), ((), I32)])
 
 
+def _latent_decode(layers, b, s, h, dc=512, dr=64):
+    """The latent decode kernel as the layer loop calls it: the stacked
+    latent buffers (``dr`` = 64 is half a lane tile) and a traced layer
+    index."""
+    from cake_tpu.ops.pallas import latent_decode
+
+    def fn(q_c, q_pe, c, r, pos, layer):
+        return latent_decode(q_c, q_pe, c, r, pos, scale=0.1, layer=layer,
+                             interpret=False)
+
+    return (fn, [((b, h, dc), BF16), ((b, h, dr), BF16),
+                 ((layers, b, 1, s, dc), BF16), ((layers, b, 1, s, dr), BF16),
+                 ((b,), I32), ((), I32)])
+
+
 def _kda_decode(layers, b, h=32, d=128, head_block=8):
     """The delta-rule decode kernel as the layer loop calls it: the
     stacked float32 state and a traced layer index."""
@@ -165,6 +180,12 @@ KERNELS = {
         2, 1, 4096, None, h=32, kvh=32),
     "flash_decode_stacked_b8_s2048_kvh16_d256": _decode_stacked(
         2, 8, 2048, None, h=16, kvh=16, d=256),
+    # the latent cells' decode step: A.X-K1's 64 heads over 8 layers of 32
+    # slots x 4096 rows of 512 + 64, Ling-3.0-flash's 32 heads over its one
+    # latent layer, and the floor's 1024 rows
+    "latent_decode_axk1_b32_s4096_h64": _latent_decode(8, 32, 4096, 64),
+    "latent_decode_ling_b32_s4096_h32": _latent_decode(1, 32, 4096, 32),
+    "latent_decode_b32_s1024_h64": _latent_decode(8, 32, 1024, 64),
     # Ling-3.0-flash's 32 heads of 128 x 128 at the cell's 32 slots, at
     # 48, and one stream
     "kda_decode_b32_h32": _kda_decode(6, 32),
@@ -388,6 +409,36 @@ def _decode_kernel_calls(compiled) -> list[str]:
             for _, _, _, op, line in _instructions(compiled)
             if op == "custom-call" and "tpu_custom_call" in line
             and "flash_decode" in line]
+
+
+def _latent_kernel_held(compiled, slots: int, window: int, heads: int,
+                        calls: int) -> None:
+    """The block-decode program of a latent cell holds ``calls`` calls of
+    the latent decode kernel (one a scanned stretch of latent layers),
+    each inside the layer loop (steps, ``one_step``, layers: three
+    ``while`` bodies deep), and nothing of what XLA's sweep made: no score
+    ``[slots, heads, window]`` (with or without the token axis) and no
+    layer's slab of either latent buffer written out. (The kernel's result
+    is a triple, which ``_instructions`` does not parse: its calls are
+    read off the text's lines.)"""
+    got = [line for line in compiled.as_text().splitlines()
+           if "custom-call(" in line and "tpu_custom_call" in line
+           and "latent_decode" in line]
+    assert len(got) == calls, len(got)
+    for call in got:
+        name = re.search(r'op_name="([^"]*)"', call).group(1)
+        assert name.count("while/body") == 3, name
+    swept = {f"{t}[{slots},{heads},{one}{window}]"
+             for t in ("f32", "bf16") for one in ("", "1,")}
+    slabs = {f"bf16[{lead}{slots},{one}{window},{width}]"
+             for lead in ("", "1,") for one in ("", "1,")
+             for width in (512, 64)}
+    assert [f"{comp}: {name} {shape}"
+            for comp, name, shape, op, _ in _instructions(compiled)
+            if shape in swept or (
+                shape in slabs and not comp.startswith("fused_computation")
+                and op not in ("parameter", "get-tuple-element", "bitcast",
+                               "tuple", "dynamic-update-slice"))] == []
 
 
 def _donated_bytes(compiled) -> tuple[int, int]:
@@ -683,6 +734,18 @@ def test_latent_programs_move_no_cache_and_no_expert_stack(topo, as_on_chip):
     # on the whole stacks, three calls a scan body
     assert _grouped_matmul_calls(admit) == 3
     assert _grouped_matmul_calls(decode) == 3
+    # the step's absorbed attention is the kernel (PR 44), once in the
+    # dense stack's scan body and once in the expert stack's, on the
+    # carried buffers themselves: the rope half goes in rows-last, which
+    # is how the chip holds it (rows on the lanes), so the swap is a
+    # bitcast and nothing of its swapped shape is allocated or copied
+    # either; and it stays in HBM (left to choose, the compiler moved it
+    # into VMEM whole ahead of the loops: ``S(1)``)
+    _latent_kernel_held(decode, slots, window, 64, calls=2)
+    assert _cache_sized_moves(
+        decode, f"bf16[{layers},{slots},1,64,{window}]") == []
+    assert _layouts(decode, f"bf16[{layers},{slots},1,{window},64]") == {
+        "3,4,2,1,0:T(8,128)(2,1)"}
     args, temps = _donated_bytes(decode)
     # 2 x 1.35 GB of expert layers + 1.0 of the dense one + 0.59 of
     # embedding and head = 4.29 GB = 4.0 GiB, + 0.42 GiB of latent cache
@@ -749,6 +812,11 @@ def test_hybrid_programs_move_no_cache_no_state_and_no_expert_stack(
         assert name.count("while/body") == 3, name
         # the state it returns is the operand it was given, in place
         assert "output_to_operand_aliasing={{1}: (6, {})}" in call
+    # the ONE latent layer's absorbed attention is the kernel too (PR 44)
+    _latent_kernel_held(decode, slots, window, 32, calls=1)
+    assert _cache_sized_moves(decode, f"bf16[1,{slots},1,64,{window}]") == []
+    assert _layouts(decode, f"bf16[1,{slots},1,{window},64]") == {
+        "3,4,2,1,0:T(8,128)(2,1)"}
     args, temps = _donated_bytes(decode)
     assert 10.15 * GIB < args < 10.4 * GIB, args / GIB  # 9.75 + 0.53
     assert temps < 0.3 * GIB, temps / GIB

@@ -94,8 +94,25 @@ def test_prefill_logits_match_reference(params, want):
     np.testing.assert_allclose(logits[0], want[-1], atol=TIGHT, rtol=0)
 
 
+@pytest.fixture(params=["xla", "kernels-forced"])
+def decode_path(request, monkeypatch):
+    """The decode step's absorbed attention as the CPU takes it (XLA's
+    masked einsums) and with kernels forced (``CAKE_PALLAS=1``: the
+    interpreted ``latent_decode`` kernel over the carried latent cache,
+    and the expert block's sorted form); the gauge says which."""
+    from cake_tpu.obs import metrics
+
+    forced = request.param == "kernels-forced"
+    monkeypatch.setenv("CAKE_PALLAS", "1" if forced else "auto")
+    gauge = metrics.registry().gauge("attn.decode_kernel")
+    gauge.set(-1)
+    yield
+    assert gauge.value == int(forced)
+
+
 @pytest.mark.parametrize("chunk", [None, 4], ids=["one-chunk", "chunks-of-4"])
-def test_prefill_then_16_decode_steps_match_reference(params, want, chunk):
+def test_prefill_then_16_decode_steps_match_reference(params, want, chunk,
+                                                      decode_path):
     """Prefill (expanded on the chunk, absorbed against what is behind it)
     then 16 absorbed decode steps through the latent cache: the logits at
     every position against the reference's full forward."""
@@ -126,7 +143,7 @@ def test_cache_holds_the_latent_row_and_nothing_else(params):
     assert written[:, :len(TOKENS)].all() and not written[:, len(TOKENS):].any()
 
 
-def test_batch_generator_four_streams_match_reference(params):
+def test_batch_generator_four_streams_match_reference(params, decode_path):
     """Four streams of different lengths through BatchGenerator (slot
     cache, per-row positions, block decode, an admission into a freed
     slot): each stream's greedy tokens are the reference's own greedy

@@ -356,6 +356,146 @@ def test_decode_blocks_read_counts_what_the_kernel_fetches(pos, steps,
     assert decode_blocks_read(pos, steps, 2048, 512, window) == want
 
 
+def _latent_rows(key, b, h, s, dc, dr, dtype):
+    ks = jax.random.split(key, 4)
+    return (jax.random.normal(ks[0], (b, h, 1, dc), dtype),
+            jax.random.normal(ks[1], (b, h, 1, dr), dtype),
+            jax.random.normal(ks[2], (b, 1, s, dc), dtype),
+            jax.random.normal(ks[3], (b, 1, s, dr), dtype))
+
+
+def _masked_oracle(q_c, q_pe, c_one, r_one, pos, scale):
+    """``ops/mla.py``'s XLA form: the whole buffer, masked at ``pos [B]``:
+    row maximum, normalizer and un-normalized latent output."""
+    from cake_tpu.ops.mla import masked_sweep
+
+    s = c_one.shape[2]
+    valid = (jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, s), 3)
+             <= pos[:, None, None, None])
+    m, p, o_c = masked_sweep(q_c, q_pe, c_one[:, 0], r_one[:, 0], valid,
+                             scale)
+    return m, jnp.sum(p, axis=-1, keepdims=True), o_c
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("heads", [64, 32])
+@pytest.mark.parametrize("stacked", [True, False])
+def test_latent_decode_row_frontiers_on_carried_cache(stacked, heads, dtype):
+    """The latent decode kernel against the einsums of ``ops/mla.py``'s
+    ``cached()`` (``masked_sweep``), for A.X-K1's 64 and Ling's 32 heads a
+    row over the one shared "head" of the latent cache: on the stacked
+    ``[L, B, 1, S, dc]`` + ``[L, B, 1, S, dr]`` buffers the layer loop
+    carries, with a TRACED layer index, and on one layer's own; per-row
+    frontiers at row 0, inside the first block, on a block's edges, across
+    several blocks, at the buffer's end, and a dead slot dispatched at row
+    0, mixed in one batch. It returns what ``cached()`` hands on: row
+    maximum, normalizer and the UN-normalized float32 latent output.
+
+    The kernel reads the blocks of ``decode_block_range`` (what the
+    engine's ``attn.kv_blocks_*`` counters sum) and no others: every block
+    past a stream's frontier block, and the stacked form's other layers,
+    hold NaN in the kernel's copy of both buffers (a fetched NaN survives
+    the mask: 0 x NaN). In float32 a stream inside one block differs from
+    the einsums only in the order of a product's sums."""
+    from cake_tpu.ops.pallas import decode_block_range, latent_decode
+
+    layers, s, dc, dr, bk = 3, 1024, 128, 64, 128
+    frontiers = [0, 1, 77, bk - 1, bk, 3 * bk + 5, 703, s - 1, 0]
+    b = len(frontiers)
+    pos = jnp.asarray(frontiers, jnp.int32)
+    scale = 0.07
+    q_c, q_pe, c_one, r_one = _latent_rows(jax.random.PRNGKey(13), b, heads,
+                                           s, dc, dr, dtype)
+    m_ref, l_ref, o_ref = _masked_oracle(q_c, q_pe, c_one, r_one, pos, scale)
+    lo, hi = decode_block_range(np.asarray(frontiers), bk, s // bk, None,
+                                xp=np)
+    assert (lo == 0).all() and hi.max() == s // bk - 1 and hi.min() == 0
+    counted = jnp.asarray(np.arange(s) // bk <= hi[:, None])[:, None, :, None]
+    c_one = jnp.where(counted, c_one, jnp.nan)
+    r_one = jnp.where(counted, r_one, jnp.nan)
+    if stacked:
+        c_all = jnp.full((layers,) + c_one.shape, jnp.nan, dtype)
+        r_all = jnp.full((layers,) + r_one.shape, jnp.nan, dtype)
+
+        @jax.jit
+        def run(layer):
+            return latent_decode(
+                q_c[:, :, 0], q_pe[:, :, 0], c_all.at[layer].set(c_one),
+                r_all.at[layer].set(r_one), pos, scale=scale, layer=layer,
+                block_k=bk, interpret=True)
+
+        m, l, o_c = run(jnp.int32(1))
+    else:
+        m, l, o_c = latent_decode(q_c[:, :, 0], q_pe[:, :, 0], c_one, r_one,
+                                  pos, scale=scale, block_k=bk,
+                                  interpret=True)
+    assert m.shape == l.shape == (b, heads, 1, 1)
+    assert o_c.shape == (b, heads, 1, dc)
+    assert m.dtype == l.dtype == o_c.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(m), np.asarray(m_ref))
+    exact = dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(l), np.asarray(l_ref),
+                               rtol=1e-5 if exact else 2e-3)
+    np.testing.assert_allclose(
+        np.asarray(o_c / l), np.asarray(o_ref / l_ref),
+        rtol=0, atol=2e-5 if exact else 2e-2)
+
+
+@pytest.mark.parametrize("frontier", [0, 300, 2047])
+def test_latent_decode_scalar_frontier(frontier):
+    """A scalar frontier (the single-stream generators') is every row's,
+    at the served block of 512 rows: inside the first block, and at the
+    buffer's end."""
+    from cake_tpu.ops.pallas import latent_decode
+
+    b, heads, s, dc, dr, scale = 2, 8, 2048, 128, 64, 0.1
+    q_c, q_pe, c_one, r_one = _latent_rows(jax.random.PRNGKey(17), b, heads,
+                                           s, dc, dr, jnp.float32)
+    want = _masked_oracle(q_c, q_pe, c_one, r_one,
+                          jnp.full((b,), frontier, jnp.int32), scale)
+    got = latent_decode(q_c[:, :, 0], q_pe[:, :, 0], c_one, r_one,
+                        jnp.int32(frontier), scale=scale, interpret=True)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-5,
+                                   atol=2e-5)
+
+
+LATENT_DISPATCH = {
+    # name: (rows, kv_lora_rank, rope, CAKE_PALLAS, on the chip) -> expected
+    "axk1_and_ling_cells": ((4096, 512, 64, None, True), "kernel"),
+    "at_the_floor": ((1024, 512, 64, None, True), "kernel"),
+    "below_the_floor": ((512, 512, 64, None, True), "xla"),
+    "not_whole_blocks": ((4096 + 128, 512, 64, None, True), "xla"),
+    "latent_rows_off_the_lanes": ((4096, 192, 64, None, True), "xla"),
+    "rope_half_a_lane_tile_wide": ((4096, 512, 128, None, True), "xla"),
+    "off_the_chip": ((4096, 512, 64, None, False), "xla"),
+    "kernels_off": ((4096, 512, 64, "0", True), "xla"),
+    "forced_below_the_floor": ((512, 512, 64, "1", True), "kernel"),
+    "forced_interpreted_any_shape": ((64, 16, 8, "1", False), "kernel"),
+    "forced_unserved_on_the_chip": ((64, 16, 8, "1", True), "xla"),
+}
+
+
+@pytest.mark.parametrize("case", list(LATENT_DISPATCH))
+def test_latent_decode_choice_by_what_it_sees(case, monkeypatch):
+    """``ops.mla.latent_decode_choice``: whole 512-row blocks from
+    ``LATENT_DECODE_MIN_S`` rows up, latent rows that fill their lanes and
+    a rope half under a lane tile (what the chip stores rows-on-lanes)
+    take the kernel on the chip; nothing but the shapes and the kernels'
+    switch decides."""
+    from cake_tpu.ops import mla
+    from cake_tpu.ops import pallas as pk
+
+    (s, dc, dr, env, chip), want = LATENT_DISPATCH[case]
+    if env is None:
+        monkeypatch.delenv("CAKE_PALLAS", raising=False)
+    else:
+        monkeypatch.setenv("CAKE_PALLAS", env)
+    monkeypatch.setattr(pk, "on_tpu", lambda: chip)
+    assert mla.latent_decode_choice(s, dc, dr) == want
+
+
 DECODE_DISPATCH = {
     # name: (T, per-row frontiers, S, D, KV heads, int8 cache, stacked)
     #       -> expected
