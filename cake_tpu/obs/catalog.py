@@ -230,9 +230,11 @@ SERIES: dict[str, tuple[str, str]] = {
                    "its rows going out; the prefill runs on the device "
                    "meanwhile; a chunked admission's later chunks)"),
     "engine.admit_to_splice_ms": (
-        HISTOGRAM, "per landed admission: first token on the host -> the "
-                   "splice program enqueued (host work while the device "
-                   "has nothing to run)"),
+        HISTOGRAM, "per landed admission: max(0, the splice program "
+                   "enqueued - first token on the host): host work while "
+                   "the device has nothing to run; 0 where the splice "
+                   "left before the token was fetched (every landing but "
+                   "a guided arrival's)"),
     "engine.block_period_clear_ms": (
         HISTOGRAM, "engine.block_period_ms of the periods in which no "
                    "admission landed: the block's steps and the boundary"),
@@ -257,6 +259,13 @@ SERIES: dict[str, tuple[str, str]] = {
                    "device has nothing to run while it lasts (next to "
                    "nothing where an arrival's prefill was launched while "
                    "the block still ran)"),
+    "engine.landings_ahead": (
+        COUNTER, "landings whose splice AND the device's next program "
+                 "(the next arrival's prefill, else the next decode "
+                 "block) were enqueued before the first token was "
+                 "fetched; engine.admit_launches is its denominator "
+                 "(not a guided arrival's, not admit()'s, none under "
+                 "batched speculation or a live guide)"),
     # -- gateway (multi-replica routing front door) ----------------------
     "gateway.added_ms": (
         HISTOGRAM, "gateway-added latency ahead of the backend "

@@ -57,7 +57,11 @@ One order of work at a block boundary: when a block's tokens have landed
 on the host, the device gets its next program — the next block, or a
 waiting arrival's prefill — before the landed rows are handed out, and
 delivery, retirement and bookkeeping run while it works
-(``BatchGenerator.step``, ``_enqueue_block``, ``_admission_tick``).
+(``BatchGenerator.step``, ``_enqueue_block``, ``_admission_tick``). A
+landing is device work only: behind the prefill the first tokens'
+sampler, the splice (it takes the tokens on the device and writes the
+donated cache in place) and the next program are enqueued back to back,
+and the host reads the first token afterwards (``_finish_admission``).
 
 Int8-weight determinism: ``ops.quant.quant_matmul``'s measured m>=16
 crossover would pick its backend per shape, so the SAME stream could see
@@ -207,10 +211,12 @@ _BOUNDARIES_AHEAD = obs_metrics.counter("engine.boundaries_ahead")
 # crossed (BatchGenerator._observe_admission, once per landed prompt
 # admission), and a block's period, landing to landing (_land_block):
 # what stands between the decode step and a client's token gap.
-# The stamps, in the order they are taken: enqueued (enqueue()), launched
-# (the first prefill dispatch returned), land_begin (_finish_admission
-# entered), landed (the first token on the host), spliced (the splice
-# program enqueued). A stage runs from one stamp to the next.
+# The stamps, in stage order: enqueued (enqueue()), launched (the first
+# prefill dispatch returned), land_begin (_finish_admission entered),
+# landed (the first token on the host), spliced (the splice program
+# enqueued). A stage runs from one stamp to the next; the splice leaves
+# before the token is fetched (all but a guided arrival's), so the last
+# stage reads 0 there.
 _ADMIT_STAGES = (
     ("launch_wait", obs_metrics.histogram("engine.admit_launch_wait_ms")),
     ("rows_wait", obs_metrics.histogram("engine.admit_rows_wait_ms")),
@@ -221,6 +227,10 @@ _ADMISSIONS_LANDED = obs_metrics.counter("engine.admissions_landed")
 # prompt admission programs launched (one for every arrival that rode):
 # landed / launches is how many admissions a launch carries
 _ADMIT_LAUNCHES = obs_metrics.counter("engine.admit_launches")
+# landings whose splice AND the device's next program (the next arrival's
+# prefill, else the next block) were enqueued before the first token was
+# fetched: landings_ahead / admit_launches
+_LANDINGS_AHEAD = obs_metrics.counter("engine.landings_ahead")
 _BLOCK_PERIOD_MS = obs_metrics.histogram("engine.block_period_ms")
 _BLOCK_PERIOD_CLEAR_MS = obs_metrics.histogram("engine.block_period_clear_ms")
 _KV_BLOCKS_READ = obs_metrics.counter("attn.kv_blocks_read")
@@ -272,14 +282,57 @@ def _carrying(prog, steps: int, rows):
                    out_shardings=(None, (rows, rows, rows)))
 
 
-def _splice_rows(bufs: tuple, vals: tuple, slot):
-    """Inside an admission's splice program: write row ``i`` of each of
-    ``vals`` (one row a staged arrival) into its buffer of ``bufs`` at
-    ``slot[i]`` (traced), along the buffers' first axis."""
+def _splice_rows(bufs: tuple, key, hist_row, used, tok, slot):
+    """Inside a landing's splice program: make each staged arrival's row
+    of the sampler state and write it into ``bufs`` (keys, history, ring
+    slots, feedback tokens) at ``slot[i]`` (traced), along the buffers'
+    first axis. ``tok [R]`` is the first token as the sampler left it on
+    the device; ``hist_row [R, N]`` and ``used [R]`` are the prompt's tail
+    and its length: the token is pushed into the ring here, on the
+    device, so that no host fetch stands between sampler and splice."""
+    tok = tok.astype(jnp.int32)
+    hist_row, used = sampling.push_history_batched(hist_row, used, tok)
+    vals = (key, hist_row, used, tok)
     for i in range(slot.shape[0]):
         bufs = tuple(jax.lax.dynamic_update_index_in_dim(b, v[i], slot[i], 0)
                      for b, v in zip(bufs, vals))
     return bufs
+
+
+def build_splice(state_shardings: tuple, paged: bool = False):
+    """A landing's splice as ONE jitted program with the slot indices
+    TRACED: ``splice(cache, row, keys, history, hist_slot, last, key,
+    hist_row, used, tok, slot)`` writes every staged row of ``row`` into
+    ``cache`` at ``slot[i]`` and the rows of the sampler state beside it
+    (``_splice_rows``). The live cache and the four state arrays are
+    DONATED: the staged rows are written in place, no second cache is
+    alive while it runs, and the caller keeps the outputs. ``row`` (the
+    staging cache, which the prefix store may retain) is not. ``paged``:
+    the state alone (the KV hand-off is a page write): ``splice(keys,
+    history, hist_slot, last, key, hist_row, used, tok, slot)``. The
+    function keeps the programs' name (``jit_splice`` in a trace)."""
+    if paged:
+        def splice(keys, history, hist_slot, last, key, hist_row, used, tok,
+                   slot):
+            return _splice_rows((keys, history, hist_slot, last), key,
+                                hist_row, used, tok, slot)
+
+        return jax.jit(splice, donate_argnums=(0, 1, 2, 3),
+                       out_shardings=state_shardings)
+
+    def splice(cache, row, keys, history, hist_slot, last, key, hist_row,
+               used, tok, slot):
+        for i in range(slot.shape[0]):
+            cache = jax.tree.map(
+                lambda c, r: jax.lax.dynamic_update_index_in_dim(
+                    c, r[:, i], slot[i], 1),
+                cache, row,
+            )
+        return (cache,) + _splice_rows(
+            (keys, history, hist_slot, last), key, hist_row, used, tok, slot)
+
+    return jax.jit(splice, donate_argnums=(0, 2, 3, 4, 5),
+                   out_shardings=(None,) + state_shardings)
 
 
 class BatchGenerator:
@@ -1554,15 +1607,11 @@ class BatchGenerator:
         """The paged admission splice: only the per-stream sampler state
         (keys/history/ring slots/feedback token) splices — KV moved by
         the page write-back (``row_scatter``), never by a cache-sized
-        scatter. Slot index traced; compiles once."""
+        scatter. Slot index traced; compiles once; the four state arrays
+        are donated (``build_splice``)."""
         if self.__splice_small is None:
-            def splice(keys, history, hist_slot, last, key, hist_row,
-                       hist_used, tok, slot):
-                return _splice_rows((keys, history, hist_slot, last),
-                                    (key, hist_row, hist_used, tok), slot)
-
-            self.__splice_small = jax.jit(
-                splice, out_shardings=self._state_shardings)
+            self.__splice_small = build_splice(self._state_shardings,
+                                               paged=True)
         return self.__splice_small
 
     # -- KV-page export/import (cake_tpu/disagg) -----------------------------
@@ -1881,7 +1930,10 @@ class BatchGenerator:
             self._keys, self._history, self._hist_slot,
             self._last_tokens, jnp.asarray([snap.key], jnp.uint32),
             jnp.asarray([snap.history], jnp.int32),
-            jnp.asarray([snap.hist_slot], jnp.int32),
+            # the program pushes the token at ``used``: one back, it
+            # rewrites the ring's newest entry, which IS the last token
+            # (every program that samples one pushes it)
+            jnp.asarray([snap.hist_slot - 1], jnp.int32),
             jnp.asarray([snap.last_token], jnp.int32),
             jnp.asarray([slot], jnp.int32),
         )
@@ -2081,13 +2133,16 @@ class BatchGenerator:
     def _warm_landing(self, logits=None, staging=None) -> None:
         """Compile the admission-completion programs of a launch of as
         many rows as ``staging`` has (one where None) against the live
-        batch state's shapes (outputs discarded; live state untouched).
+        batch state, which is handed back as it was: the splice donates
+        the live cache and the sampler state, so the cache's is a splice
+        of slot 0's OWN rows into slot 0, kept, and the small state is
+        put back from a host copy taken first.
         Slot: the first tokens' sampler, the slot-traced cache splice and,
         with a prefix store, the program that takes one row of several.
         Paged: the row gather/scatter page programs plus the small
-        sampler-state splice — warmed on pool/staging COPIES (both
-        programs donate their first argument) with all-sink ids, so no
-        live page is read or written."""
+        sampler-state splice — the page programs warmed on pool/staging
+        COPIES (both donate their first argument) with all-sink ids, so
+        no live page is read or written."""
         if staging is None:
             staging = self._staging_cache(1)
         rows = jax.tree.leaves(staging)[0].shape[1]
@@ -2099,27 +2154,33 @@ class BatchGenerator:
         n_hist = self.settings.repeat_last_n
         hist = np.full((rows, n_hist), -1, np.int32)
         keys, toks = self._first_tokens(logits, [0] * rows, hist)
-        # (the splice takes the tokens from the host, as the landing's do)
-        vec = jnp.asarray(np.asarray(toks) * 0)
+        vec = jnp.asarray(np.zeros((rows,), np.int32))
+        state = (self._keys, self._history, self._hist_slot,
+                 self._last_tokens)
+        kept = [np.asarray(x) for x in state]
+        vectors = (keys, jnp.asarray(hist), vec, toks, vec)
         if self._paged:
             sink = jnp.zeros((self._ppp,), jnp.int32)
             pool_copy = jax.tree.map(lambda x: x.copy(), self.cache)
             out_pool = self._row_scatter(pool_copy, staging, sink)
             out_row = self._row_gather(self.cache, sink)
-            out = self._splice_small_fn()(
-                self._keys, self._history, self._hist_slot,
-                self._last_tokens, keys, jnp.asarray(hist), vec, vec, vec,
-            )
+            out = self._splice_small_fn()(*state, *vectors)
             jax.block_until_ready((out_pool, out_row, out))
-            return
-        out = self._splice_fn()(
-            self.cache, staging, self._keys, self._history,
-            self._hist_slot, self._last_tokens, keys, jnp.asarray(hist),
-            vec, vec, vec,
-        )
-        if rows > 1 and self._prefix_entries > 0:
-            out = (out, self._row_of(staging, jnp.int32(0)))
-        jax.block_until_ready(out)
+        else:
+            if rows > 1 and self._prefix_entries > 0:
+                jax.block_until_ready(self._row_of(staging, jnp.int32(0)))
+            # slot 0's rows, as a staging cache of this many rows
+            own = jax.jit(
+                lambda cache: jax.tree.map(
+                    lambda c: jnp.repeat(c[:, :1], rows, 1), cache),
+                out_shardings=jax.tree.map(lambda x: x.sharding, staging),
+            )(self.cache)
+            self.cache = self._splice_fn()(
+                self.cache, own, *state, *vectors)[0]
+            jax.block_until_ready(self.cache)
+        (self._keys, self._history, self._hist_slot,
+         self._last_tokens) = jax.device_put(
+            tuple(kept), self._state_shardings)
 
     def _admission_due(self) -> bool:
         """Whether step() has admission work this call (so an idle or
@@ -2160,25 +2221,35 @@ class BatchGenerator:
         follows that block on the device with no host time between them
         (which program follows the running block is what a decision at
         the boundary would have made it; only the enqueue is earlier).
-        Land (``_finish_admission``: fetch the logits, sample, splice,
-        install the stream) waits until every row computed before has
-        been handed out: those rows can hold a stream's EOS, which frees
-        its slot in here while the caller -- who maps a row's slots to
-        streams when it GETS the row -- has not seen those tokens yet;
-        installing then would hand the old stream's tail to the new one.
+        Land (``_finish_admission``: sample, splice, install the stream,
+        give the device its next program -- the next arrival's launch if
+        one can start -- and only then fetch the first token) waits until
+        every row computed before has been handed out: those rows can
+        hold a stream's EOS, which frees its slot in here while the
+        caller -- who maps a row's slots to streams when it GETS the row
+        -- has not seen those tokens yet; installing then would hand the
+        old stream's tail to the new one.
         ``wait=False`` (the synchronous ``admit()``, whose caller is told
         the slot) lands at once."""
         st = self._staging
-        if st is not None and "logits" in st:
-            if wait and self._rows_wait():
-                return
+        if st is None or "logits" not in st:
+            self._launch(wait)
+            st = self._staging
+        if (st is not None and "logits" in st
+                and not (wait and self._rows_wait())):
             with self._prof.phase("admit_land"):
-                self._finish_admission()
+                self._finish_admission(wait)
+
+    def _launch(self, wait: bool = True) -> bool:
+        """The launch half of a tick: start the next queued arrival if a
+        slot is free, and dispatch the staged admission's next chunk (the
+        last one leaves its logits staged, to land). Whether a program
+        was dispatched."""
         if self._staging is None and not self._arrivals:
-            return  # nothing to launch
+            return False  # nothing to launch
         with self._prof.phase("admit_launch"):
             if self._staging is None and not self._start_arrival(wait):
-                return
+                return False
             st = self._staging
             pos, chunk, base = st["pos"], st["chunk"], st["base"]
             final = pos + chunk >= st["tokens"].shape[1]
@@ -2217,7 +2288,7 @@ class BatchGenerator:
             st["pos"] = pos + chunk
             if not final:
                 self._admit_dispatched(t0, chunk, base + pos)
-                return
+                return True
             st["logits"], st["booking"] = logits, (t0, chunk, base + pos)
             if rows == 1 and st["tokens"].shape[1] == chunk:
                 # a whole prompt a dispatch: where this bucket's program
@@ -2225,9 +2296,7 @@ class BatchGenerator:
                 # can ride in, behind this one on the device
                 self._warmed.add((1, chunk))
                 self._warm_bucket(chunk)
-        if not (wait and self._rows_wait()):
-            with self._prof.phase("admit_land"):
-                self._finish_admission()
+        return True
 
     def _count_admit_rows(self, rows: int) -> None:
         """An expert model's admission dispatch of ``rows`` rows (the
@@ -2440,22 +2509,10 @@ class BatchGenerator:
         goes to ``slot[i]``: a launch's repeated first row to the same
         slot with the same values, a cancelled arrival's to the free slot
         it had been given (a slot without a stream is overwritten by the
-        next admission)."""
+        next admission). It donates the live cache and state
+        (``build_splice``): assign what it returns."""
         if self.__splice is None:
-            def splice(cache, row, keys, history, hist_slot, last, key,
-                       hist_row, hist_used, tok, slot):
-                for i in range(slot.shape[0]):
-                    cache = jax.tree.map(
-                        lambda c, r: jax.lax.dynamic_update_index_in_dim(
-                            c, r[:, i], slot[i], 1),
-                        cache, row,
-                    )
-                return (cache,) + _splice_rows(
-                    (keys, history, hist_slot, last),
-                    (key, hist_row, hist_used, tok), slot)
-
-            self.__splice = jax.jit(
-                splice, out_shardings=(None,) + self._state_shardings)
+            self.__splice = build_splice(self._state_shardings)
         return self.__splice
 
     @property
@@ -2490,11 +2547,23 @@ class BatchGenerator:
         return self.__first_tokens(
             logits, np.asarray(sids, np.uint32), hist_rows, mask)
 
-    def _finish_admission(self) -> None:
-        """Land the launched admission: sample every staged row's first
-        token from the last chunk's logits (the one wait for the device),
-        splice the staged rows into their slots with one program, record
-        the members' tokens and queue them as one row."""
+    def _finish_admission(self, wait: bool = True) -> None:
+        """Land the launched admission, as device work only: the first
+        tokens' sampler on the last chunk's logits, the splice of the
+        staged rows into their slots (it takes the tokens where the
+        sampler left them, on the device) and the device's next program
+        (the next arrival's launch if one can start, else the next block)
+        are dispatched back to back behind the prefill; the host reads
+        the tokens afterwards, while the device works, records them and
+        queues the members' row. A stream that its first token ends has
+        then a block dispatched with its row live, which ``_record``
+        discards like any overrun.
+
+        What the engine sees decides the order, nothing else: a guided
+        arrival's token is fetched before anything follows it (its guide
+        advances on the host, and no block goes out under a live guide);
+        the synchronous ``admit()`` (``wait=False``) hands its caller the
+        token, so nothing is enqueued for later before the fetch."""
         land_begin = time.perf_counter()
         st, self._staging = self._staging, None
         members, rows = st["members"], st["rows"]
@@ -2523,31 +2592,32 @@ class BatchGenerator:
             tail = m.ids[-n_hist:]
             hist[i, : len(tail)] = tail
             used[i] = len(tail)
+        # dispatched while the prefill still runs (it was launched before
+        # the rows that have just gone out): the host time from here to
+        # the fetch hides behind it
         keys, toks = self._first_tokens(
             logits, [m.sid for m in rows], hist,
             mask=jnp.asarray(guide.mask_bool())[None] if guide is not None
             else None,
         )
-        # the one wait for the device: the sampling above was dispatched
-        # while the prefill still ran (it was launched before the rows
-        # that have just gone out), so its host time hides behind it
-        t_fetch = time.perf_counter()
-        tok_ids = self._host(toks)
-        landed = time.perf_counter()
-        self.step_fetch_ms = max(self.step_fetch_ms,
-                                 (landed - t_fetch) * 1e3)
+        lp = (sampling.topk_logprobs(logits, self.logprobs_k)
+              if self.logprobs_k else None)
+
+        def fetch() -> tuple:
+            """The one wait for the device: ``(tokens [R] on the host,
+            when they came)``."""
+            t_fetch = time.perf_counter()
+            tok_ids = self._host(toks)
+            landed = time.perf_counter()
+            self.step_fetch_ms = max(self.step_fetch_ms,
+                                     (landed - t_fetch) * 1e3)
+            return tok_ids, landed
+
+        fetched = fetch() if guide is not None else None
         # every live stream's next block waits behind this admission: the
         # period that holds it is not clear
         self._period_admitted = True
-        self._admit_dispatched(*st["booking"])
-        hist[np.arange(len(rows)), used % n_hist] = tok_ids
-        lp_rows = None
-        if self.logprobs_k:
-            lpv0, lpi0 = sampling.topk_logprobs(logits, self.logprobs_k)
-            lp_rows = [[(int(i), float(v)) for v, i in zip(vs, ids)]
-                       for vs, ids in zip(np.asarray(lpv0), np.asarray(lpi0))]
-        vectors = (keys, jnp.asarray(hist), jnp.asarray(used + 1),
-                   jnp.asarray(tok_ids.astype(np.int32)),
+        vectors = (keys, jnp.asarray(hist), jnp.asarray(used), toks,
                    jnp.asarray(np.asarray([m.slot for m in rows], np.int32)))
 
         if self._paged:
@@ -2597,64 +2667,87 @@ class BatchGenerator:
         spliced = time.perf_counter()
         self._pos = np.asarray(self._pos).copy()
         self._index = np.asarray(self._index).copy()
-        row: list[Token | None] = [None] * len(self.streams)
         for m in members:
-            i = rows.index(m)
-            if m.stamps is not None:
-                self._observe_admission(
-                    m.sid, m.stamps + [land_begin, landed, spliced])
-            row[m.slot] = self._install(m, int(tok_ids[i]),
-                                        lp_rows[i] if lp_rows else None)
-        self._pending_rows.append(row)
-
+            self._install(m)
         # Feed the store: an arrival's prefix becomes reusable by future
-        # arrivals with the same opening. Paged: the stream's FULL prompt
-        # pages register in the prefix tree (zero copies — the tree just
-        # takes references; a later same-prefix arrival shares the
-        # physical pages, which is the copy-on-write fan-out). Slot: the
-        # staging row is retained under the prefix truncated to a
-        # prefix_block boundary (the splice above copied values out, so
-        # retaining it costs no extra dispatch; of a launch of several
-        # rows, the rows the store has room for are taken out: the last).
+        # arrivals with the same opening, the next launch among them.
+        # Paged: the stream's FULL prompt pages register in the prefix
+        # tree (zero copies — the tree just takes references; a later
+        # same-prefix arrival shares the physical pages, which is the
+        # copy-on-write fan-out). Slot: the staging row is retained under
+        # the prefix truncated to a prefix_block boundary (the splice
+        # above copied values out and does not donate it, so retaining it
+        # costs no extra dispatch; of a launch of several rows, the rows
+        # the store has room for are taken out: the last).
         if self._paged:
-            ids, slot = members[0].ids, members[0].slot
             n_full = len(ids) // self._page_size
             if (self._prefix_entries > 0 and n_full
                     and n_full * self._page_size
                     >= max(1, self._prefix_share_min)):
                 self._prefix_tree.insert(ids, self._tables[slot][:n_full])
-            if self.streams[slot].done:
+        else:
+            kept = [(m, n) for m in members
+                    if (n := self._stored_prefix_len(m.ids))]
+            for m, n in kept[-self._prefix_entries:]:
+                self._store_prefix(
+                    m.ids[:n],
+                    st["cache"] if len(rows) == 1 else self._row_of(
+                        st["cache"], jnp.int32(rows.index(m))))
+        try:
+            # the device's next program, before the host waits for this
+            # one's token: the landing is ahead if there is one
+            if (wait and fetched is None
+                    and (self._launch() or self._enqueue_block())
+                    and members[0].stamps is not None):
+                _LANDINGS_AHEAD.inc()
+        finally:
+            tok_ids, landed = fetched or fetch()
+            self._admit_dispatched(*st["booking"])
+            lp_rows = None
+            if lp is not None:
+                lp_rows = [[(int(i), float(v)) for v, i in zip(vs, top)]
+                           for vs, top in zip(*map(np.asarray, lp))]
+            row: list[Token | None] = [None] * len(self.streams)
+            for m in members:
+                i = rows.index(m)
+                if m.stamps is not None:
+                    self._observe_admission(
+                        m.sid, m.stamps + [land_begin, landed, spliced])
+                row[m.slot] = self._first_token(
+                    m.slot, int(tok_ids[i]), lp_rows[i] if lp_rows else None)
+            self._pending_rows.append(row)
+            if self._paged and self.streams[slot].done:
                 # first sampled token ended the stream: free its claims
-                # now (AFTER the tree store above took its references)
+                # (the tree store above has taken its references)
                 self._release_pages(slot)
-            return
-        kept = [(m, n) for m in members
-                if (n := self._stored_prefix_len(m.ids))]
-        for m, n in kept[-self._prefix_entries:]:
-            self._store_prefix(
-                m.ids[:n],
-                st["cache"] if len(rows) == 1 else self._row_of(
-                    st["cache"], jnp.int32(rows.index(m))))
+        if not wait or guide is not None:
+            self._launch(wait)  # the next arrival starts in this tick too
 
-    def _install(self, m: _Staged, tok_id: int, lp_row) -> Token:
-        """A landed arrival becomes its slot's stream, one token long;
-        returns that token for the landing's row."""
+    def _install(self, m: _Staged) -> None:
+        """A spliced arrival becomes its slot's stream, before its first
+        token is on the host: everything a next program's dispatch reads
+        (the frontier, the token index, a live stream in the slot)."""
         slot, ids = m.slot, m.ids
         self._pos[slot] = len(ids)
         self._index[slot] = 1
-        s = _Stream(
+        self.streams[slot] = _Stream(
             stream_id=m.sid, prompt=ids,
             detok=TokenOutputStream(self.tokenizer) if self.tokenizer else None,
         )
-        self.streams[slot] = s
         if self._spec_k:
             self._spec_bank[slot] = []  # the slot's old stream is gone
             # the device ctx row still holds the OLD stream's tokens; a
             # pos-coincidence could otherwise pass the staleness check
             self._spec_ctx = None
             self._spec_ctx_pos = None
+
+    def _first_token(self, slot: int, tok_id: int, lp_row) -> Token:
+        """An installed stream's first token has come: the stream is one
+        token long, and over where that token ends it; returns the token
+        for the landing's row."""
+        s = self.streams[slot]
         s.generated.append(tok_id)
-        window_full = len(ids) + 1 >= self.max_seq
+        window_full = len(s.prompt) + 1 >= self.max_seq
         is_eos = tok_id in self._eos_ids
         s.done = is_eos or window_full
         if s.done:
@@ -2674,8 +2767,9 @@ class BatchGenerator:
         and keep them for the request's own timeline."""
         stages = []
         for (name, hist), t0, t1 in zip(_ADMIT_STAGES, stamps, stamps[1:]):
-            hist.observe((t1 - t0) * 1e3)
-            stages.append((name, t0, (t1 - t0) * 1e3))
+            ms = max(0.0, (t1 - t0) * 1e3)  # to_splice: spliced came first
+            hist.observe(ms)
+            stages.append((name, t0, ms))
         _ADMISSIONS_LANDED.inc()
         kept = self._admit_stages
         kept[stream_id] = stages

@@ -7,8 +7,9 @@ checkpoint directory, ``--quantize``, ``--dtype``, ``--max-seq``,
 ``BatchGenerator._start_arrival`` could dispatch for a bucket of ``C``
 prompt tokens: the prefill program over ``[R, C]`` for each row count of
 ``--rows`` (the staging cache donated from call to call, as a launch
-donates it) and the splice of ``R`` staged rows into the live cache (not
-donated: it copies the cache, once a landing). Where
+donates it) and the splice of ``R`` staged rows into the live cache (the
+cache and the sampler state donated from call to call, as a landing
+donates them: the rows are written in place). Where
 ``batch_generator.GROUP_SHAPES`` comes from: a launch of ``R`` rows pays
 when ``[R, C]`` plus one splice costs less than its members' own
 ``[1, C']`` programs plus a splice each (PERF.md keeps the table).
@@ -71,12 +72,17 @@ def sweep(engine, buckets, row_counts=(1, 2, 4), iters: int = 6):
                 hist = jnp.asarray(np.full(
                     (rows, engine.settings.repeat_last_n), -1, np.int32))
                 keys = jnp.asarray(np.zeros((rows, 2), np.uint32))
-                splice_ms[rows] = _time_ms(
-                    lambda: engine._splice_fn()(
+
+                def splice():
+                    (engine.cache, engine._keys, engine._history,
+                     engine._hist_slot, engine._last_tokens
+                     ) = engine._splice_fn()(
                         engine.cache, state["cache"], engine._keys,
                         engine._history, engine._hist_slot,
-                        engine._last_tokens, keys, hist, vec, vec, vec),
-                    iters)
+                        engine._last_tokens, keys, hist, vec, vec, vec)
+                    return engine.cache
+
+                splice_ms[rows] = _time_ms(splice, iters)
             if rows == 1:
                 single = prefill_ms + splice_ms[1]
             yield {"chunk": chunk, "rows": rows,

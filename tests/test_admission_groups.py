@@ -95,9 +95,9 @@ def _watch(g) -> dict:
         seen["logits"] = (np.asarray(logits), list(sids))
         return first_tokens(logits, sids, hist, mask=mask)
 
-    def spy_finish():
+    def spy_finish(wait=True):
         members = list(g._staging["members"])
-        finish()
+        finish(wait)
         logits, sids = seen.pop("logits")
         for m in members:
             seen[m.sid] = dict(
@@ -330,9 +330,9 @@ def _landing_order(g) -> list:
     order = []
     install = g._install
 
-    def spy(m, tok_id, lp_row):
+    def spy(m):
         order.append((m.sid, m.slot))
-        return install(m, tok_id, lp_row)
+        return install(m)
 
     g._install = spy
     return order
@@ -634,3 +634,203 @@ def test_the_shipped_program_takes_two_prompts_of_up_to_256_tokens(
     monkeypatch.setattr(bg, "GROUP_SHAPES", SHIPPED)
     assert SHIPPED == ((2, 256),)
     assert bg._group_shape(own) == want
+
+
+# -- a landing is device work only (PR 45) -----------------------------------
+# The splice takes the first tokens where the sampler left them, on the
+# device, and the device's next program is enqueued before the host reads
+# them. Nothing about a stream's tokens may depend on that order: the
+# synchronous ``admit()`` (sampler, splice, fetch, nothing enqueued for
+# later: the parent's order) is the reference, arrival by arrival.
+
+def _record_events(g) -> list:
+    """The order in which the engine enqueues device programs and waits
+    for the device: ``"prefill"``, ``"splice"``, ``"block"``,
+    ``"fetch"``."""
+    events: list = []
+
+    def noting(name, fn):
+        return lambda *a, **k: (events.append(name), fn(*a, **k))[1]
+
+    g._host = noting("fetch", g._host)
+    splice = noting("splice", g._splice_small_fn() if g.paged
+                    else g._splice_fn())
+    if g.paged:
+        g._splice_small_fn = lambda: splice
+    else:
+        g._splice_fn = lambda: splice
+    g._dispatch_block = noting("block", g._dispatch_block)
+    g._BatchGenerator__admit_prefill = noting("prefill", g._admit_prefill)
+    return events
+
+
+def _take(out: dict, g, row) -> None:
+    for i, tok in enumerate(row):
+        if tok is not None:
+            out.setdefault(g.streams[i].stream_id, []).append(
+                (tok.id, tok.is_end_of_stream, tok.logprobs))
+
+
+def _served(g, arrivals, steps: int) -> dict:
+    """``{stream id: [(token, ended, logprobs), ...]}`` of arrivals that
+    are enqueued together and served by ``step()``."""
+    out: dict = {}
+    for prompt, sid, guide in arrivals:
+        g.enqueue(list(prompt), sid, guide=guide)
+    for _ in range(steps):
+        _take(out, g, g.step())
+    return {sid: out[sid] for _, sid, _ in arrivals}
+
+
+def _synchronous(g, arrivals, steps: int) -> dict:
+    """The same arrivals through ``admit()``, one after the other."""
+    out: dict = {}
+    for prompt, sid, _ in arrivals:
+        slot, tok = g.admit(list(prompt), sid)
+        out[sid] = [(tok.id, tok.is_end_of_stream, tok.logprobs)]
+    for _ in range(steps):
+        _take(out, g, g.step())
+    return {sid: out[sid] for _, sid, _ in arrivals}
+
+
+_LONG = [[int(t) for t in _RNG.integers(3, 200, n)] for n in (150, 200)]
+_FULL = [int(t) for t in _RNG.integers(3, 200, 127)]  # window 128, less one
+WRAPS = dict(temperature=0.0, repeat_penalty=1.3, repeat_last_n=8)
+LANDINGS = {
+    # case: (arrivals [(prompt, sid)], engine keywords, landings)
+    "one-row": ([(PROMPTS[1], 10)], {}, 1),
+    "pair-2x256": ([(_LONG[0], 10), (_LONG[1], 11)], {}, 1),
+    "chain-of-three": ([(PROMPTS[0], 10), (PROMPTS[1], 11),
+                        (PROMPTS[3], 12)], {}, 3),
+    # (the two ride in one launch: the one that ends, and its neighbour)
+    "first-token-is-eos": ([(PROMPTS[1], 10), (PROMPTS[0], 11)], {}, 1),
+    "fills-the-window": ([(_FULL, 10), (PROMPTS[0], 11)], {}, 2),
+    "logprobs": ([(PROMPTS[1], 10)], dict(logprobs=3), 1),
+    "history-wraps": ([(PROMPTS[2], 10)], {}, 1),
+    "paged": ([(PROMPTS[1], 10), (PROMPTS[0], 11)],
+              dict(kv_layout="paged", kv_page_size=8), 2),
+    "guided": ([(PROMPTS[1], 10)], {}, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(LANDINGS))
+def test_a_landing_ahead_of_its_token_serves_the_same_tokens(
+        dense, monkeypatch, case):
+    arrivals, kw, landings = LANDINGS[case]
+    _, params = dense
+    window = 512 if case == "pair-2x256" else 128
+    if case == "pair-2x256":
+        monkeypatch.setattr(bg, "GROUP_SHAPES", SHIPPED)
+    elif case == "chain-of-three":
+        monkeypatch.setattr(bg, "GROUP_SHAPES", ())
+    settings = WRAPS if case == "history-wraps" else GREEDY
+
+    def engine(eos=-1):
+        g = BatchGenerator(tiny(max_seq_len=window, eos_token_id=eos), params,
+                           block_size=4, settings=SamplerSettings(**settings),
+                           **kw)
+        g.set_prompts([[4, 4, 4 + i] for i in range(4)])
+        for prompt, *_ in arrivals:
+            g.warm_admission(len(prompt))
+        g.step()
+        for s in g.streams[1:]:  # stream 0 decodes on, beside the landings
+            g.finish(s.stream_id)
+        return g
+
+    if case == "guided":
+        # (admit() takes no guide: the reference is the stream as a batch's
+        # own member, which no admission brings in)
+        ref = BatchGenerator(tiny(max_seq_len=window, eos_token_id=-1), params,
+                             block_size=4, settings=SamplerSettings(**GREEDY))
+        ref.set_prompts([list(arrivals[0][0])], stream_ids=[10],
+                        guides=[_letters_guide()])
+        want: dict = {}
+        for _ in range(6):
+            _take(want, ref, ref.step())
+        arrivals = [(p, sid, _letters_guide()) for p, sid in arrivals]
+        eos = -1
+    else:
+        arrivals = [(p, sid, None) for p, sid in arrivals]
+        eos = -1
+        if case == "first-token-is-eos":
+            eos = _synchronous(engine(), arrivals[:1], 0)[10][0][0]
+        want = _synchronous(engine(eos), arrivals, 24)
+    g = engine(eos)
+    events = _record_events(g)
+    before = obs_metrics.registry().snapshot()["engine.landings_ahead"]["value"]
+    got = _served(g, arrivals, 40)
+    ahead = obs_metrics.registry().snapshot()[
+        "engine.landings_ahead"]["value"] - before
+
+    for sid, toks in want.items():
+        n = min(len(toks), len(got[sid]))
+        assert n >= (1 if toks[0][1] else 3), (case, sid)
+        assert [t[:2] for t in got[sid][:n]] == [t[:2] for t in toks[:n]], (
+            case, sid)
+        if case == "logprobs":
+            for have, ref_lp in zip(got[sid][:n], toks[:n]):
+                assert [i for i, _ in have[2]] == [i for i, _ in ref_lp[2]]
+                np.testing.assert_allclose([v for _, v in have[2]],
+                                           [v for _, v in ref_lp[2]],
+                                           atol=TIGHT)
+    if case == "first-token-is-eos":
+        assert got[10] == [(eos, True, None)]
+    if case == "fills-the-window":
+        assert len(got[10]) == 1 and got[10][0][1]
+    if case == "history-wraps":
+        assert len(got[10]) > 8  # the ring of 8 has gone round
+    if case == "guided":
+        assert all(chr(t[0]).islower() or t[0] == 2 for t in got[10])
+
+    # the order: a landing's splice, then the device's next program, then
+    # the host's wait for the token; under a guide the token first
+    splices = [i for i, e in enumerate(events) if e == "splice"]
+    assert len(splices) == landings, (case, events)
+    for i in splices:
+        if case == "guided":
+            assert events[i - 1] == "fetch", (case, events)
+        else:
+            assert events[i + 1] in ("block", "prefill"), (case, events)
+            assert events[i + 2] == "fetch", (case, events)
+    assert ahead == (0 if case == "guided" else landings), case
+    assert catalog.kind_of("engine.landings_ahead") == catalog.COUNTER
+    stages = g.take_admission_stages(10)
+    if case != "guided":
+        assert stages[-1][0] == "to_splice" and stages[-1][2] == 0.0
+
+
+def _live_state(g) -> list:
+    return [np.asarray(x) for x in jax.tree.leaves(
+        (g.cache, g._keys, g._history, g._hist_slot, g._last_tokens))]
+
+
+@pytest.mark.parametrize("kw", [dict(), dict(kv_layout="paged",
+                                             kv_page_size=8)],
+                         ids=["slot", "paged"])
+def test_warming_a_landing_mid_stream_leaves_the_live_state_as_it_was(
+        dense, kw):
+    """``_warm_bucket`` and ``_warm_landing`` run the DONATING splice
+    against the live batch: cache, keys, history, ring slots and last
+    tokens come back bit for bit, and the streams decode on as an engine
+    that was never warmed."""
+    cfg, params = dense
+    engines = []
+    for _ in range(2):
+        g = BatchGenerator(cfg, params, block_size=4,
+                           settings=SamplerSettings(**GREEDY), **kw)
+        g.set_prompts([list(p) for p in PROMPTS[:3]])
+        for _ in range(6):
+            g.step()
+        g.drain()
+        engines.append(g)
+    g, plain = engines
+    before = _live_state(g)
+    g.warm_admission(20)  # bucket 32: the one-row landing, and two rows'
+    g._landing_warmed.clear()
+    g._warm_landing()
+    assert g._landing_warmed == {1}
+    for a, b in zip(before, _live_state(g)):
+        np.testing.assert_array_equal(a, b)
+    for _ in range(12):
+        a, b = g.step(), plain.step()
+        assert [t and t.id for t in a] == [t and t.id for t in b]

@@ -174,12 +174,14 @@ def test_stamps_are_ordered_and_stages_sum_to_enqueue_to_first_token(
     stages = g.take_admission_stages(10)
     starts = [t0 for _, t0, _ in stages]
     end = starts[-1] + stages[-1][2] / 1e3
-    # enqueued < launched < land_begin < landed < spliced, whole ticks
+    # enqueued < launched < land_begin < landed, whole ticks; the splice
+    # left before the token was fetched, so the last stage is empty
     assert starts[0] == t_before + 1.0
-    assert starts == sorted(set(starts)) and end > starts[-1]
+    assert starts == sorted(set(starts)) and end == starts[-1]
+    assert stages[-1][2] == 0.0
     assert end <= clock.now
-    # the stages leave nothing out between enqueue() and the splice, after
-    # which the first token is recorded without another look at the clock
+    # the stages leave nothing out between enqueue() and the first token
+    # on the host, which is recorded without another look at the clock
     assert sum(ms for _, _, ms in stages) == pytest.approx(
         (end - starts[0]) * 1e3)
     grew = {n: v - sums[n] for n, v in _sums().items()}
