@@ -99,8 +99,7 @@ def init_pool_on_mesh(config: LlamaConfig, mesh, num_pages: int,
     if config.segmented:
         raise ValueError(
             "the page pool holds per-head keys and values of every layer; "
-            "a latent-attention or state-space model, or one whose "
-            "window layers hold a ring, is served with the slot layout")
+            "a model of several layer stacks is served with the slot layout")
     key = ("init", mesh, config.num_hidden_layers,
            config.num_key_value_heads, config.head_dim, str(config.dtype),
            num_pages, page_size, quant)

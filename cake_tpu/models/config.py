@@ -7,20 +7,11 @@ TPU-native equivalent of the reference's config plane
 sequence length (the reference hard-caps MAX_SEQ_LEN=4096, config.rs:6; here it
 is a tunable because the TPU build supports long context).
 
-Families read (``LlamaConfig.from_hf_dict`` by ``model_type``): the dense
-and Mixtral-style decoders (one bare stack), and five whose layers are of
-several kinds (``segmented``: a stack a stretch of one kind,
-``models/llama.py`` ``layer_plan``): ``deepseek_v3`` / ``axk1`` (latent
-attention, shared and routed experts), ``bailing_hybrid`` (delta-rule
-layers beside latent ones), ``jamba`` (Mamba layers beside rope-less
-attention), ``exaone_moe`` (window and full attention by ``layer_types``)
-and ``lfm2_moe`` (gated short convolutions beside roped attention by
-``layer_types``; keys ``conv_L_cache``, ``conv_bias``,
-``num_dense_layers``, ``num_experts``, ``norm_eps``, ``use_expert_bias``;
-every expert held, no shared one, a tied head; its conv layers keep a
-convolution's tail and NO state, so ``cache_plan`` has ``conv`` without
-``state``; nothing shards it and the paged layout, speculation,
-``--quantize`` and an int8 cache are refused).
+Families read: the dense and Mixtral-style decoders (one bare stack), and
+five whose layers are of several kinds (``segmented``: a stack a stretch of
+one kind, ``models/llama.py`` ``layer_plan``). A family's config.json and
+checkpoint, its checks and what is wired for it are its record in
+``models/families.py`` (``LlamaConfig.family``); fields and presets here.
 """
 
 from __future__ import annotations
@@ -31,6 +22,8 @@ from pathlib import Path
 from typing import Sequence
 
 import jax.numpy as jnp
+
+from cake_tpu.models import families
 
 # Reference default (config.rs:6). Overridable per-config here.
 DEFAULT_MAX_SEQ_LEN = 4096
@@ -196,52 +189,7 @@ class LlamaConfig:
                 self, "head_dim",
                 self.hidden_size // self.num_attention_heads,
             )
-        if self.kv_lora_rank:
-            if not (self.qk_rope_head_dim and self.v_head_dim):
-                raise ValueError(
-                    "latent attention (kv_lora_rank > 0) needs "
-                    "qk_rope_head_dim and v_head_dim")
-            if self.attn_gate not in (None, "head_wise"):
-                raise ValueError(
-                    f"attn_gate {self.attn_gate!r} is not wired (a "
-                    "head-wise output gate only)")
-        if self.layer_types is not None:
-            self._check_layer_types()
-        if (self.kv_lora_rank or self.layer_types is not None) and (
-                self.n_routed_experts):
-            if self.scoring_func != "sigmoid":
-                raise ValueError(
-                    f"scoring_func {self.scoring_func!r} is not wired for "
-                    "the shared-expert family (sigmoid, group-limited "
-                    "routing only)")
-            width = self.router_experts or self.n_routed_experts
-            object.__setattr__(self, "router_experts", width)
-            if width % self.n_group or not (
-                    0 <= self.first_expert
-                    <= width - self.n_routed_experts):
-                raise ValueError(
-                    f"experts {self.first_expert}.."
-                    f"{self.first_expert + self.n_routed_experts - 1} "
-                    f"held of {width} in {self.n_group} groups")
-        if self.layer_group_size and not self.kv_lora_rank:
-            raise ValueError(
-                "layer_group_size > 0 (delta-rule layers beside latent "
-                "ones) needs the latent-attention keys (kv_lora_rank > 0)")
-        if self.attn_layer_period:
-            if self.kv_lora_rank or self.num_local_experts or (
-                    self.sliding_window is not None):
-                raise ValueError(
-                    "attn_layer_period > 0 (state-space layers beside "
-                    "grouped-query attention) is wired with full "
-                    "grouped-query attention and a dense feed-forward "
-                    "only: no latent keys, no experts, no sliding_window")
-            if not 0 <= self.attn_layer_offset < self.attn_layer_period:
-                raise ValueError(
-                    f"attn_layer_offset {self.attn_layer_offset} outside "
-                    f"the period of {self.attn_layer_period}")
-            if not self.mamba_dt_rank:
-                object.__setattr__(self, "mamba_dt_rank",
-                                   -(-self.hidden_size // 16))
+        self.family.check(self)
         # validate at construction, not as a KeyError deep in a jit trace
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
@@ -256,60 +204,10 @@ class LlamaConfig:
                 "num_local_experts > 0"
             )
 
-    def _check_layer_types(self):
-        """What a per-layer ``layer_types`` may ask for and is computed."""
-        object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        kinds = set(self.layer_types)
-        mixers = _layer_type_mixers(self.model_type)
-        if (len(self.layer_types) != self.num_hidden_layers
-                or not kinds <= set(mixers)):
-            raise ValueError(
-                f"layer_types needs one of {sorted(mixers)} for "
-                f"each of the {self.num_hidden_layers} layers, got "
-                f"{len(self.layer_types)} entries of {sorted(kinds)}")
-        if "full_attention" not in kinds:
-            raise ValueError(
-                "layer_types without a full_attention layer is not wired "
-                "(the cache's capacity is the full layers')")
-        if self.short_conv:
-            self._check_short_conv(kinds)
-            return
-        if "sliding_attention" in kinds and not (
-                self.sliding_window and self.sliding_window >= 8):
-            raise ValueError(
-                "layer_types names sliding_attention layers: they need a "
-                f"sliding_window of 8 or more, got {self.sliding_window!r}")
-        if self.kv_lora_rank or self.attn_layer_period or (
-                self.num_local_experts or self.attention_bias):
-            raise ValueError(
-                "layer_types (window and full grouped-query attention "
-                "mixed by layer) is wired with the shared-expert "
-                "feed-forward only: no latent keys, no state-space "
-                "layers, no Mixtral-style experts, no projection bias")
-
-    def _check_short_conv(self, kinds):
-        """What a model of short-convolution and attention layers may ask
-        for and is computed."""
-        if "conv" not in kinds:
-            raise ValueError(
-                "layer_types without a conv layer is not wired for "
-                f"model_type {SHORT_CONV_MODEL_TYPE!r} (every layer a "
-                "full_attention one is a dense decoder's stack)")
-        if self.conv_L_cache < 2 or self.conv_bias:
-            raise ValueError(
-                f"conv_L_cache {self.conv_L_cache} / conv_bias "
-                f"{self.conv_bias} is not wired (a depthwise convolution "
-                "of 2 or more taps, no bias)")
-        if self.kv_lora_rank or self.attn_layer_period or (
-                self.num_local_experts or self.attention_bias
-                or self.sliding_window is not None
-                or self.n_shared_experts):
-            raise ValueError(
-                "layer_types with conv layers (gated short convolutions "
-                "beside full grouped-query attention) is wired with the "
-                "routed-expert feed-forward only: no latent keys, no "
-                "state-space layers, no Mixtral-style experts, no "
-                "projection bias, no sliding_window, no shared expert")
+    @property
+    def family(self) -> families.Family:
+        """The first record of ``models/families.py`` the fields select."""
+        return next(f for f in families.FAMILIES if f.selects(self))
 
     @property
     def num_kv_groups(self) -> int:
@@ -319,33 +217,6 @@ class LlamaConfig:
     @property
     def jax_dtype(self):
         return jnp.dtype(self.dtype)
-
-    @property
-    def latent(self) -> bool:
-        """Multi-head latent attention (and the two-stack layer layout that
-        comes with it: leading dense layers, then expert layers)."""
-        return self.kv_lora_rank > 0
-
-    @property
-    def state_space(self) -> bool:
-        """Mamba layers beside grouped-query attention layers that have no
-        position embedding."""
-        return self.attn_layer_period > 0
-
-    @property
-    def windowed(self) -> bool:
-        """Window and full grouped-query attention layers mixed by layer
-        (``layer_types``): window layers keep a ring of ``ring_rows`` rows
-        a stream, full layers every row."""
-        return self.layer_types is not None and not self.short_conv
-
-    @property
-    def short_conv(self) -> bool:
-        """Gated short-convolution layers beside full grouped-query
-        attention layers (``layer_types`` of ``model_type`` "lfm2_moe"): a
-        conv layer keeps the convolution's last inputs and nothing else."""
-        return (self.layer_types is not None
-                and self.model_type == SHORT_CONV_MODEL_TYPE)
 
     @property
     def ring_rows(self) -> int:
@@ -365,29 +236,7 @@ class LlamaConfig:
         """Whether the layers are of several kinds, so that
         ``params["layers"]`` is a dict of stacks, one a segment of
         ``models.llama.layer_plan``, and not one bare stack."""
-        return (self.latent or self.state_space
-                or self.layer_types is not None)
-
-    @property
-    def recurrent_mixer(self) -> str | None:
-        """The mixer of the layers that carry something from token to
-        token in place of rows: "kda" (delta-rule linear attention,
-        ops/kda.py), "mamba" (a selective state space, ops/mamba.py),
-        "conv" (a gated short convolution, ops/shortconv.py: a tail of
-        inputs and NO state), or None."""
-        if self.layer_group_size > 0:
-            return "kda"
-        if self.short_conv:
-            return "conv"
-        return "mamba" if self.state_space else None
-
-    @property
-    def recurrent(self) -> bool:
-        """Whether some layers carry a state or a convolution's tail from
-        token to token in place of rows (what they hold is
-        ``cache_plan``'s to say: ``state`` and ``conv``, or ``conv``
-        alone)."""
-        return self.recurrent_mixer is not None
+        return self.family is not families.GQA
 
     @property
     def mamba_d_inner(self) -> int:
@@ -408,15 +257,15 @@ class LlamaConfig:
         experts = self.num_local_experts or self.n_routed_experts
         dense = self.first_k_dense_replace if self.n_routed_experts else (
             0 if self.num_local_experts else n)
+        by_type = self.family.layer_mixers  # found once, not once a layer
 
         def mixer(i):
             if self.layer_types is not None:
-                return _layer_type_mixers(self.model_type)[
-                    self.layer_types[i]]
-            if self.state_space:
+                return by_type[self.layer_types[i]]
+            if self.attn_layer_period:
                 return ("gqa" if i % self.attn_layer_period
                         == self.attn_layer_offset else "mamba")
-            if not self.latent:
+            if not self.kv_lora_rank:
                 return "gqa"
             g = self.layer_group_size
             return "mla" if not g or (i + 1) % g == 0 else "kda"
@@ -442,7 +291,8 @@ class LlamaConfig:
         holds a state asks for the key. A kind with no layer is left
         out."""
         mixers = [m for m, _ in self.layer_kinds]
-        held = mixers.count(self.recurrent_mixer)
+        recurrent = self.family.recurrent_mixer
+        held = mixers.count(recurrent)
         ring = mixers.count("swa")
         plan = {}
         if len(mixers) - held - ring:
@@ -450,11 +300,11 @@ class LlamaConfig:
         if ring:
             heads, *widths = self.cache_row
             plan["ring"] = (ring, heads, self.ring_rows, *widths)
-        if held and self.recurrent_mixer == "kda":
+        if held and recurrent == "kda":
             h, d = self.num_attention_heads, self.head_dim
             plan["state"] = (held, h, d, d)
             plan["conv"] = (held, self.short_conv_kernel_size - 1, 3 * h * d)
-        elif held and self.recurrent_mixer == "conv":
+        elif held and recurrent == "conv":
             plan["conv"] = (held, self.conv_L_cache - 1, self.hidden_size)
         elif held:
             plan["state"] = (held, self.mamba_d_state, self.mamba_d_inner)
@@ -465,13 +315,13 @@ class LlamaConfig:
     def rope_dim(self) -> int:
         """Channels of a head that rotary embeddings cover; 0: the model
         has no position embedding (position comes from the recurrence).
-        Where window and full layers are mixed (``windowed``) this is
+        Where window and full layers are mixed this is
         the window layers': a full layer rotates nothing (the layer loop
-        hands it no table). Beside short-convolution layers
-        (``short_conv``) the full layers rotate the whole head."""
-        if self.state_space:
+        hands it no table). Beside short-convolution layers the full
+        layers rotate the whole head."""
+        if self.attn_layer_period:
             return 0
-        return self.qk_rope_head_dim if self.latent else self.head_dim
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
 
     @property
     def cache_row(self) -> tuple[int, int, int]:
@@ -481,7 +331,7 @@ class LlamaConfig:
         values per KV head; latent attention keeps the normed latent (in
         ``k``) and the roped shared key part (in ``v``), once for all
         heads."""
-        if self.latent:
+        if self.kv_lora_rank:
             return 1, self.kv_lora_rank, self.qk_rope_head_dim
         return self.num_key_value_heads, self.head_dim, self.head_dim
 
@@ -496,7 +346,7 @@ class LlamaConfig:
         """Softmax scale of the attention scores: ``d^-0.5`` over the query
         width, times YaRN's ``mscale^2`` where the rope scaling gives
         ``mscale_all_dim`` (latent attention)."""
-        if not self.latent:
+        if not self.kv_lora_rank:
             return self.head_dim ** -0.5
         from cake_tpu.ops.rope import yarn_mscale
 
@@ -505,12 +355,6 @@ class LlamaConfig:
         if rs.get("mscale_all_dim"):
             scale *= yarn_mscale(rs["factor"], rs["mscale_all_dim"]) ** 2
         return scale
-
-    @property
-    def topk_norm_eps(self) -> float:
-        """What the chosen experts' scores are normalised over, beside
-        their sum (``norm_topk_prob``): the family's own constant."""
-        return 1e-6 if self.short_conv else 1e-20
 
     def eos_ids(self) -> tuple[int, ...]:
         """Normalized EOS id set (reference checks config ids or "</s>",
@@ -525,95 +369,27 @@ class LlamaConfig:
     def from_hf_dict(cls, d: dict, **overrides) -> "LlamaConfig":
         known = {f.name for f in dataclasses.fields(cls)}
         kwargs = {k: v for k, v in d.items() if k in known}
-        if d.get("model_type") not in (WINDOWED_MODEL_TYPE,
-                                       SHORT_CONV_MODEL_TYPE):
-            # Hugging Face writes a `layer_types` list for every family
-            # (all "full_attention" where nothing windows by layer); only
-            # the families that mix mixers by layer read it
-            kwargs.pop("layer_types", None)
         # HF configs carry torch_dtype, not dtype.
         td = d.get("torch_dtype")
         if td and "dtype" not in overrides:
             kwargs["dtype"] = {"float16": "bfloat16", "bfloat16": "bfloat16",
                                "float32": "float32"}.get(td, "bfloat16")
-        # Family defaults not spelled out in the HF config dict: Qwen2's
-        # q/k/v bias is unconditional in its architecture (the HF config has
-        # no attention_bias key to read); Gemma's (1+w) RMSNorm, GeGLU, and
-        # sqrt(hidden) embedding scaling are likewise architectural.
-        if d.get("model_type") == "qwen2" and "attention_bias" not in d:
-            kwargs["attention_bias"] = True
-        if d.get("model_type") == "gemma":
-            kwargs.setdefault("rms_norm_offset", True)
-            kwargs.setdefault("embed_scale", True)
-            # HF Gemma spells the activation in `hidden_activation` (newer
-            # configs) or `hidden_act`; both default to the tanh gelu
-            act = d.get("hidden_activation") or d.get("hidden_act")
-            if act in (None, "gelu", "gelu_pytorch_tanh"):
-                kwargs["hidden_act"] = "gelu_tanh"
-            else:
-                raise ValueError(f"unsupported gemma activation {act!r}")
-        elif d.get("hidden_act") not in (None, "silu"):
+        # the file's `model_type` decides the spelling, defaults and limits
+        family = families.BY_MODEL_TYPE.get(d.get("model_type"),
+                                            families.GQA)
+        if family.layer_mixers is None:
+            # Hugging Face writes a `layer_types` list for every family
+            # (all "full_attention" where nothing windows by layer); only
+            # the families that mix mixers by layer read it
+            kwargs.pop("layer_types", None)
+        read = family.read(d)
+        if "hidden_act" not in read and d.get("hidden_act") not in (
+                None, "silu"):
             raise ValueError(
                 f"unsupported hidden_act {d['hidden_act']!r} for "
                 f"model_type {d.get('model_type')!r}"
             )
-        # Qwen2 configs ship a sliding_window VALUE with the feature gated
-        # off (`use_sliding_window: false`); honoring the value alone would
-        # force windowed masking (and forfeit the flash kernels) on a model
-        # that attends fully. When the gate is on, HF additionally windows
-        # only layers >= max_window_layers — full-depth (0) and no-depth
-        # (>= num layers) are uniform and supported; a partial depth would
-        # need per-layer masks the stacked scan doesn't carry, so it is
-        # rejected rather than silently diverging.
-        if "use_sliding_window" in d and d.get("sliding_window") is not None:
-            if not d["use_sliding_window"]:
-                kwargs["sliding_window"] = None
-            else:
-                mwl = d.get("max_window_layers", 0)
-                layers = kwargs.get("num_hidden_layers",
-                                    cls.num_hidden_layers)
-                if mwl >= layers:
-                    kwargs["sliding_window"] = None
-                elif mwl > 0:
-                    raise ValueError(
-                        f"partial-depth sliding window "
-                        f"(max_window_layers={mwl} of {layers}) is not "
-                        "wired for this family's one bare stack; a "
-                        "window on some layers is read from a per-layer "
-                        f"layer_types list (model_type "
-                        f"{WINDOWED_MODEL_TYPE!r})"
-                    )
-        if d.get("model_type") == WINDOWED_MODEL_TYPE:
-            kwargs.update(_windowed_kwargs(d))
-        elif d.get("model_type") == SHORT_CONV_MODEL_TYPE:
-            kwargs.update(_short_conv_kwargs(d))
-        elif d.get("model_type") == STATE_SPACE_MODEL_TYPE:
-            kwargs.update(_state_space_kwargs(d))
-        elif d.get("model_type") == HYBRID_MODEL_TYPE:
-            kwargs.update(_hybrid_kwargs(d))
-        elif d.get("model_type") in LATENT_MODEL_TYPES:
-            # DeepSeek-V3's keys. `topk_method` is read as the group-
-            # limited choice n_group/topk_group describe with no correction
-            # bias tensor ("none", "group_limited_greedy"); "noaux_tc"
-            # needs the bias and is refused rather than served without it.
-            if d.get("topk_method", "none") not in (
-                    "none", "greedy", "group_limited_greedy"):
-                raise ValueError(
-                    f"topk_method {d['topk_method']!r} (a routing "
-                    "correction bias) is not wired")
-            if d.get("moe_layer_freq", 1) != 1:
-                raise ValueError("moe_layer_freq != 1 is not wired")
-            share = d.get("expert_share")
-            if share:  # this chip's share of an ep deployment's experts
-                kwargs["router_experts"] = share["n_routed_experts"]
-                kwargs["first_expert"] = (share["rank"]
-                                          * kwargs["n_routed_experts"])
-        elif d.get("kv_lora_rank"):
-            raise ValueError(
-                f"model_type {d.get('model_type')!r} has latent-attention "
-                f"keys but is not one of {sorted(LATENT_MODEL_TYPES)}")
-        kwargs.update(overrides)
-        return cls(**kwargs)
+        return cls(**{**kwargs, **read, **overrides})
 
     @classmethod
     def from_hf_json(cls, path: str | Path, **overrides) -> "LlamaConfig":
@@ -624,10 +400,9 @@ class LlamaConfig:
         d = dataclasses.asdict(self)
         d.pop("max_seq_len")
         d.pop("dtype")
-        if d["rope_scaling"] is None:
-            d.pop("rope_scaling")
-        if d["sliding_window"] is None:
-            d.pop("sliding_window")
+        for key in ("rope_scaling", "sliding_window"):
+            if d[key] is None:
+                d.pop(key)
         if not d["num_local_experts"]:
             d.pop("num_local_experts")
             if not self.n_routed_experts:
@@ -638,311 +413,26 @@ class LlamaConfig:
             d.pop("hidden_act")
         else:  # HF spelling
             d["hidden_act"] = "gelu_pytorch_tanh"
-        if not d["rms_norm_offset"]:
-            d.pop("rms_norm_offset")
-        if not d["embed_scale"]:
-            d.pop("embed_scale")
+        for key in ("rms_norm_offset", "embed_scale"):
+            if not d[key]:
+                d.pop(key)
         width, first = d.pop("router_experts"), d.pop("first_expert")
-        if not self.latent:
-            for f in (_LATENT_ATTENTION_FIELDS
-                      if self.layer_types is not None else _LATENT_FIELDS):
-                d.pop(f)
         if self.n_routed_experts and width != self.n_routed_experts:
             d["expert_share"] = {
                 "n_routed_experts": width,
                 "ep": width // self.n_routed_experts,
                 "rank": first // self.n_routed_experts}
         d.pop("qk_norm")  # the family's, not a key of any config.json
-        if not self.short_conv:
-            d.pop("conv_L_cache")
-            d.pop("conv_bias")
-        else:  # the family's own spelling (_short_conv_kwargs reads it back)
-            d["layer_types"] = list(self.layer_types)
-            for ours, theirs in _SHORT_CONV_KEYS.items():
-                d[theirs] = d.pop(ours)
-            for f in ("n_shared_experts", "scoring_func", "n_group",
-                      "topk_group"):
-                d.pop(f)
-        if self.layer_types is None:
-            d.pop("layer_types")
-        elif self.windowed:
-            # the family's own spelling (_windowed_kwargs reads it back)
-            d.pop("router_bias")
-            d["layer_types"] = list(self.layer_types)
-            d["sliding_windows"] = [
-                self.sliding_window if t == "sliding_attention" else 0
-                for t in self.layer_types]
-            d["mlp_layer_types"] = [
-                "sparse" if ffn == "moe" else "dense"
-                for _, ffn in self.layer_kinds]
-            d["num_experts"] = d.pop("n_routed_experts")
-            d["num_shared_experts"] = d.pop("n_shared_experts")
-            d["rope_parameters"] = {"rope_theta": d.pop("rope_theta"),
-                                    "rope_type": "default"}
-        if not self.layer_group_size:
-            for f in _HYBRID_FIELDS:
-                d.pop(f)
-        else:  # the family's own spelling of the keys it renames
-            for ours, theirs in _HYBRID_KEYS.items():
-                d[theirs] = d.pop(ours)
-            d["gated_attention_proj_granularity_type"] = d.pop("attn_gate")
-            d["moe_router_enable_expert_bias"] = d.pop("router_bias")
-            d["topk_method"] = "noaux_tc" if self.router_bias else "none"
-            d["moe_shared_expert_intermediate_size"] = (
-                self.moe_intermediate_size)
+        # the keys another family's file carries go; this family's are
+        # written in its own spelling (its `read` reads them back)
+        family = self.family
+        for f in families.FIELDS - set(family.fields):
+            d.pop(f)
+        family.write(self, d)
         if not self.router_bias:
             d.pop("router_bias", None)
-        if not self.state_space:
-            for f in _STATE_SPACE_FIELDS:
-                d.pop(f)
-        else:  # the family's other keys, at the only values served
-            d.update(_STATE_SPACE_FIXED)
         return d
 
-
-# HF `model_type`s served by the latent-attention, shared-expert decoder
-LATENT_MODEL_TYPES = ("deepseek_v3", "axk1")
-# ... and the one whose layers are grouped-query attention through a window
-# but every fourth, which attends fully and rotates nothing, with the
-# shared-expert feed-forward (K-EXAONE's keys)
-WINDOWED_MODEL_TYPE = "exaone_moe"
-# `layer_types` entry -> the layer's mixer (`LlamaConfig.layer_kinds`)
-_WINDOW_MIXERS = {"sliding_attention": "swa", "full_attention": "gqa"}
-# ... and the one whose layers are gated short convolutions but one in
-# four or so, which is grouped-query attention that DOES rotate, with all
-# of its sigmoid-routed experts held and no shared one (LFM2-MoE's keys)
-SHORT_CONV_MODEL_TYPE = "lfm2_moe"
-_SHORT_CONV_MIXERS = {"conv": "conv", "full_attention": "gqa"}
-# ours -> the family's spelling, where they differ
-_SHORT_CONV_KEYS = {"first_k_dense_replace": "num_dense_layers",
-                    "n_routed_experts": "num_experts",
-                    "rms_norm_eps": "norm_eps",
-                    "router_bias": "use_expert_bias"}
-
-
-def _layer_type_mixers(model_type: str) -> dict[str, str]:
-    """What a ``layer_types`` entry may be, and the mixer it names, by the
-    family that reads the list."""
-    return (_SHORT_CONV_MIXERS if model_type == SHORT_CONV_MODEL_TYPE
-            else _WINDOW_MIXERS)
-
-
-def _short_conv_kwargs(d: dict) -> dict:
-    """`LlamaConfig` fields from an "lfm2_moe" config.json (its own
-    spelling: ``num_dense_layers``, ``num_experts``, ``norm_eps``,
-    ``use_expert_bias``, ``conv_L_cache``). What the file asks for and
-    nothing here computes is refused, not guessed. The readings made of
-    the file (the chunk order ``B | C | x``, no activation in the mixer,
-    a tied head where the file names none) are the benchmark
-    configuration's ``assumed``."""
-    name = SHORT_CONV_MODEL_TYPE
-    layers = d["num_hidden_layers"]
-    types = list(d["layer_types"])
-    if len(types) != layers:
-        raise ValueError(
-            f"{name}: layer_types has {len(types)} entries for "
-            f"{layers} layers")
-    rope = d.get("rope_parameters") or d.get("rope_scaling") or {}
-    kind = rope.get("rope_type", rope.get("type", "default"))
-    if kind != "default":
-        raise ValueError(
-            f"{name}: rope type {kind!r} is not wired (default rotation, "
-            "no scaling)")
-    for key, only in (("num_shared_experts", 0), ("n_group", 1),
-                      ("topk_group", 1), ("scoring_func", "sigmoid")):
-        if d.get(key, only) != only:
-            raise ValueError(
-                f"{name}: {key} = {d[key]!r} is not wired (only {only!r})")
-    lead = d.get("num_dense_layers", 0)
-    return {
-        "layer_types": tuple(types),
-        "qk_norm": True,
-        "rms_norm_eps": d.get("norm_eps", d.get("rms_norm_eps", 1e-5)),
-        "rope_theta": float(rope.get("rope_theta",
-                                     d.get("rope_theta", 1000000.0))),
-        "rope_scaling": None,
-        "first_k_dense_replace": lead,
-        "n_routed_experts": d["num_experts"] if lead < layers else 0,
-        "n_shared_experts": 0,
-        "scoring_func": "sigmoid",
-        "router_bias": bool(d.get("use_expert_bias", False)),
-        # Lfm2MoeConfig's default where the file names none
-        "tie_word_embeddings": bool(d.get("tie_word_embeddings", True)),
-    }
-
-
-def _windowed_kwargs(d: dict) -> dict:
-    """`LlamaConfig` fields from an "exaone_moe" config.json (its own
-    spelling: ``num_experts``, ``num_shared_experts``, ``layer_types``,
-    ``mlp_layer_types``, ``sliding_windows``, ``rope_parameters``). What
-    the file asks for and nothing here computes is refused, not guessed;
-    ``num_nextn_predict_layers`` (a next-token prediction block, ``mtp.*``
-    tensors) is read and ignored: the block takes no part in the model's
-    own logits and the loaders skip its tensors. The readings made of the
-    file (pre-norm sublayers, a routing bias that enters the choice) are
-    the benchmark configuration's ``assumed``."""
-    name = WINDOWED_MODEL_TYPE
-    layers, window = d["num_hidden_layers"], d.get("sliding_window")
-    types = list(d["layer_types"])
-    if len(types) != layers:
-        raise ValueError(
-            f"{name}: layer_types has {len(types)} entries for "
-            f"{layers} layers")
-    want = [window if t == "sliding_attention" else 0 for t in types]
-    if [w or 0 for w in d.get("sliding_windows", want)] != want:
-        raise ValueError(
-            f"{name}: sliding_windows {d['sliding_windows']} disagrees "
-            f"with layer_types and sliding_window {window} (a window of "
-            "its own a layer is not wired)")
-    rope = d.get("rope_parameters") or {}
-    kind = rope.get("rope_type", rope.get("type", "default"))
-    if kind != "default" or d.get("rope_scaling"):
-        raise ValueError(
-            f"{name}: rope type {kind!r} is not wired (default rotation, "
-            "no scaling, on the window layers only)")
-    dense = d.get("first_k_dense_replace")
-    ffn = list(d.get("mlp_layer_types") or (
-        ["dense"] * (dense or 0) + ["sparse"] * (layers - (dense or 0))))
-    lead = ffn.count("dense")
-    if (len(ffn) != layers or ffn != ["dense"] * lead + ["sparse"]
-            * (layers - lead) or dense not in (None, lead)):
-        raise ValueError(
-            f"{name}: mlp_layer_types {ffn} with first_k_dense_replace "
-            f"{dense} is not wired (dense layers lead, sparse ones follow)")
-    groups, kept = d.get("n_group", 1), d.get("topk_group", 1)
-    if not 1 <= kept <= groups:
-        raise ValueError(
-            f"{name}: topk_group {kept} of n_group {groups} is not a "
-            "group-limited choice")
-    held = d["num_experts"] if lead < layers else 0
-    kwargs = {
-        "layer_types": tuple(types),
-        "qk_norm": True,
-        "first_k_dense_replace": lead,
-        "n_routed_experts": held,
-        "n_shared_experts": d.get("num_shared_experts", 0),
-        "router_bias": bool(held),
-        "rope_theta": float(rope.get("rope_theta",
-                                     d.get("rope_theta", 10000.0))),
-    }
-    share = d.get("expert_share")
-    if share:  # this chip's share of an ep deployment's experts
-        kwargs["router_experts"] = share["n_routed_experts"]
-        kwargs["first_expert"] = share["rank"] * held
-    return kwargs
-
-
-# ... and the one whose layers are delta-rule linear attention but every
-# `layer_group_size`-th (Ling-3.0's keys)
-HYBRID_MODEL_TYPE = "bailing_hybrid"
-_HYBRID_FIELDS = ("layer_group_size", "short_conv_kernel_size",
-                  "kda_lower_bound", "attn_gate")
-# ours -> the family's spelling, where they differ
-_HYBRID_KEYS = {"n_routed_experts": "num_experts",
-                "n_shared_experts": "num_shared_experts",
-                "scoring_func": "score_function"}
-# what this family's config.json may ask for that nothing here computes:
-# key -> the only value served
-_HYBRID_FIXED = {
-    "kda_safe_gate": True, "no_kda_lora": True, "use_kda_lora": False,
-    "linear_silu": True, "use_qk_norm": True, "num_kv_heads_for_linear_attn": 0,
-    "rope_interleave": True, "use_mla_nope": False, "use_nGPT": False,
-    "scale_router_input": False, "value_norm": False, "up_proj_norm": False,
-    "use_bias": False, "use_qkv_bias": False, "group_norm_size": 1,
-    "rope_scaling": None,
-}
-
-
-def _hybrid_kwargs(d: dict) -> dict:
-    """`LlamaConfig` fields from a "bailing_hybrid" config.json (its own
-    spelling of the expert keys; the readings this repo makes of it are
-    the benchmark configuration's ``assumed``). What the file asks for
-    and nothing here computes is refused, not guessed."""
-    for key, only in _HYBRID_FIXED.items():
-        if key in d and d[key] != only:
-            raise ValueError(
-                f"{HYBRID_MODEL_TYPE}: {key} = {d[key]!r} is not wired "
-                f"(only {only!r})")
-    layers = d["num_hidden_layers"]
-    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
-        if any(d.get(key, ())[:layers]):
-            raise ValueError(
-                f"{HYBRID_MODEL_TYPE}: a nonzero {key} entry inside the "
-                f"{layers} served layers (a clamp on the experts' SwiGLU "
-                "whose form the config does not give) is not wired")
-    held = d["num_experts"]
-    if d.get("moe_shared_expert_intermediate_size",
-             d["moe_intermediate_size"]) != d["moe_intermediate_size"]:
-        raise ValueError(
-            f"{HYBRID_MODEL_TYPE}: a shared expert of another width than "
-            "the routed ones is not wired")
-    bias = bool(d.get("moe_router_enable_expert_bias"))
-    if (d.get("topk_method", "noaux_tc") == "noaux_tc") != bias:
-        raise ValueError(
-            f"{HYBRID_MODEL_TYPE}: topk_method and "
-            "moe_router_enable_expert_bias disagree about a routing "
-            "correction bias")
-    kwargs = {
-        "n_routed_experts": held,
-        "n_shared_experts": d.get("num_shared_experts", 0),
-        "scoring_func": d.get("score_function",
-                              d.get("scoring_func", "sigmoid")),
-        "router_bias": bias,
-        "attn_gate": d.get("gated_attention_proj_granularity_type"),
-        "layer_group_size": d["layer_group_size"],
-    }
-    share = d.get("expert_share")
-    if share:  # this chip's share of an ep deployment's experts
-        kwargs["router_experts"] = share["n_routed_experts"]
-        kwargs["first_expert"] = share["rank"] * held
-    return kwargs
-
-
-# ... and the one whose layers are Mamba mixers but one grouped-query
-# attention layer in `attn_layer_period` (AI21's Jamba keys)
-STATE_SPACE_MODEL_TYPE = "jamba"
-_STATE_SPACE_FIELDS = ("attn_layer_period", "attn_layer_offset",
-                       "mamba_d_state", "mamba_d_conv", "mamba_expand",
-                       "mamba_dt_rank", "mamba_conv_bias")
-# what this family's config.json may ask for that nothing here computes:
-# key -> the only value served (experts in alternate layers, a window on
-# the attention layers, a bias on the mixer's projections)
-_STATE_SPACE_FIXED = {"num_experts": 1, "mamba_proj_bias": False}
-
-
-def _state_space_kwargs(d: dict) -> dict:
-    """`LlamaConfig` fields from a "jamba" config.json. What the file asks
-    for and nothing here computes is refused, not guessed."""
-    for key, only in _STATE_SPACE_FIXED.items():
-        if d.get(key, only) != only:
-            raise ValueError(
-                f"{STATE_SPACE_MODEL_TYPE}: {key} = {d[key]!r} is not "
-                f"wired (only {only!r})")
-    if d.get("sliding_window") is not None:
-        raise ValueError(
-            f"{STATE_SPACE_MODEL_TYPE}: sliding_window = "
-            f"{d['sliding_window']!r} is not wired beside state-space "
-            "layers (their attention layers are full; a window a layer, "
-            f"by layer_types, is model_type {WINDOWED_MODEL_TYPE!r}'s)")
-    rank = d.get("mamba_dt_rank", "auto")
-    return {
-        # one expert is the dense SwiGLU: its choice of 1 selects nothing
-        "num_experts_per_tok": LlamaConfig.num_experts_per_tok,
-        "mamba_dt_rank": 0 if rank == "auto" else rank,
-        # the family's defaults where the file leaves them out
-        "attn_layer_period": d.get("attn_layer_period", 8),
-        "attn_layer_offset": d.get("attn_layer_offset", 4),
-    }
-
-
-_LATENT_ATTENTION_FIELDS = (
-    "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
-    "v_head_dim")
-_LATENT_FIELDS = _LATENT_ATTENTION_FIELDS + (
-    "first_k_dense_replace", "moe_intermediate_size",
-    "n_shared_experts", "n_routed_experts", "scoring_func", "n_group",
-    "topk_group", "norm_topk_prob", "routed_scaling_factor",
-)
 
 
 def llama3_8b(**overrides) -> LlamaConfig:

@@ -1,0 +1,774 @@
+"""What is wired for each family of models, declared once.
+
+A family is one :class:`Family` record; ``LlamaConfig.family`` finds it
+from a configuration's fields, ``LlamaConfig.from_hf_dict`` from a file's
+``model_type`` (``BY_MODEL_TYPE``). Consumers look the record up and
+ask it; what a cache HOLDS is asked of ``LlamaConfig.cache_plan``. The
+records are data: nothing outside ``cake_tpu/models/`` makes or edits one,
+and no flag sets one. Every reader refuses, and does not guess, what its
+file asks for and nothing here computes; the readings this repo makes of
+a file are its benchmark configuration's ``assumed``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable
+
+
+def _nothing(*_):
+    return {}
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    # HF `model_type`s whose config.json `read` reads, and whether a
+    # configuration's FIELDS say it is of this family
+    model_types: tuple[str, ...]
+    selects: Callable[[Any], bool]
+    # config.json <-> fields: the fields only this family's file carries;
+    # file -> fields to set beside the keys that are fields; (config, dict
+    # of its fields) -> the family's own spelling, in place; what a
+    # configuration may ask for (LlamaConfig.__post_init__: it fills in
+    # what the family derives)
+    fields: tuple[str, ...] = ()
+    read: Callable[[dict], dict] = _nothing
+    write: Callable[[Any, dict], Any] = _nothing
+    check: Callable[[Any], Any] = _nothing
+    # `layer_types` entry -> the layer's mixer, where the family reads one
+    layer_mixers: dict | None = None
+    # the mixer of its layers that carry something from token to token in
+    # place of rows: "kda" (ops/kda.py), "mamba" (ops/mamba.py), "conv"
+    # (ops/shortconv.py: a tail of inputs and NO state), or None
+    recurrent_mixer: str | None = None
+    # checkpoint tensors under `model.layers.{i}.`: ours -> (HF suffix,
+    # transpose?), a stack taking its own; ours -> a routed expert's
+    # pattern ({e}: its global id); the last norm; part of a name only
+    # this family's checkpoints store
+    tensor_names: dict = dataclasses.field(default_factory=dict)
+    expert_names: dict = dataclasses.field(default_factory=dict)
+    final_norm: str = "model.norm.weight"
+    probe: str | None = None
+    # what is wired, and the family's sentence on why where it is not
+    # (`what`: the family as a refusal names it): the mesh axes that may be
+    # more than 1, the quantized tiers of its linears and of its cache
+    what: str = ""
+    shard_axes: frozenset = frozenset(("stages", "tp", "sp", "ep"))
+    shard_why: str = ""
+    linear_tiers: tuple[str, ...] = ("int8", "int4")
+    linear_why: str = ""
+    cache_tiers: tuple[str, ...] = ("int8",)
+    cache_why: str = ""
+    # whether its expert layers count the routed pairs that fall on the
+    # held experts (an expert model told its share)
+    counts_held_experts: bool = False
+    # where a block's arithmetic differs: whether a full-attention layer
+    # rotates q and k; whether a repeated period of expert layers is
+    # scanned as one run (models/llama.py layer_plan says why not); what
+    # the chosen experts' scores are normalised over, beside their sum
+    full_layers_rotate: bool = True
+    expert_periods: bool = True
+    topk_norm_eps: float = 1e-20
+
+
+# --- tensor names -----------------------------------------------------------
+
+# our stacked name -> (HF suffix, transpose?)
+_LAYER_MAP = {
+    "attn_norm": ("input_layernorm.weight", False),
+    "wq": ("self_attn.q_proj.weight", True),
+    "wk": ("self_attn.k_proj.weight", True),
+    "wv": ("self_attn.v_proj.weight", True),
+    "wo": ("self_attn.o_proj.weight", True),
+    "mlp_norm": ("post_attention_layernorm.weight", False),
+    "w_gate": ("mlp.gate_proj.weight", True),
+    "w_up": ("mlp.up_proj.weight", True),
+    "w_down": ("mlp.down_proj.weight", True),
+}
+
+# q/k/v projection biases (Qwen2 family; HF llama-arch `attention_bias`)
+_BIAS_MAP = {
+    "bq": ("self_attn.q_proj.bias", False),
+    "bk": ("self_attn.k_proj.bias", False),
+    "bv": ("self_attn.v_proj.bias", False),
+}
+# o_proj bias: HF llama-arch `attention_bias: true` biases o_proj too
+# (Qwen2 does not) — tracked separately so each checkpoint loads exactly
+# the tensors it stores.
+_O_BIAS = ("bo", ("self_attn.o_proj.bias", False))
+
+# Mixtral MoE naming: w1 = gate proj, w3 = up proj, w2 = down proj; the
+# router is `block_sparse_moe.gate`. Expert tensors are stacked over a new
+# leading E axis per layer ([L, E, in, out] in the pytree).
+_MOE_EXPERT_MAP = {
+    "w_gate": "block_sparse_moe.experts.{e}.w1.weight",
+    "w_up": "block_sparse_moe.experts.{e}.w3.weight",
+    "w_down": "block_sparse_moe.experts.{e}.w2.weight",
+}
+_MOE_ROUTER = "block_sparse_moe.gate.weight"
+
+# The latent-attention, shared-expert family (DeepSeek-V3's tensor names,
+# which `model_type` "axk1" is assumed to share): every layer's attention,
+# then either a dense MLP (the leading `first_k_dense_replace` layers) or a
+# router, the shared experts and the routed experts BY THEIR GLOBAL IDS
+# (a cut checkpoint holds a slice of them: models/config.py first_expert).
+_LATENT_MAP = {
+    "attn_norm": ("input_layernorm.weight", False),
+    "wq_a": ("self_attn.q_a_proj.weight", True),
+    "q_norm": ("self_attn.q_a_layernorm.weight", False),
+    "wq_b": ("self_attn.q_b_proj.weight", True),
+    "wkv_a": ("self_attn.kv_a_proj_with_mqa.weight", True),
+    "kv_norm": ("self_attn.kv_a_layernorm.weight", False),
+    "wkv_b": ("self_attn.kv_b_proj.weight", True),
+    "wo": ("self_attn.o_proj.weight", True),
+    "mlp_norm": ("post_attention_layernorm.weight", False),
+}
+_LATENT_DENSE_MAP = {k: _LAYER_MAP[k] for k in ("w_gate", "w_up", "w_down")}
+_LATENT_MOE_MAP = {
+    "router": ("mlp.gate.weight", True),
+    "ws_gate": ("mlp.shared_experts.gate_proj.weight", True),
+    "ws_up": ("mlp.shared_experts.up_proj.weight", True),
+    "ws_down": ("mlp.shared_experts.down_proj.weight", True),
+}
+_LATENT_EXPERT_MAP = {
+    "w_gate": "mlp.experts.{e}.gate_proj.weight",
+    "w_up": "mlp.experts.{e}.up_proj.weight",
+    "w_down": "mlp.experts.{e}.down_proj.weight",
+}
+
+# Delta-rule layers beside latent ones (`model_type` "bailing_hybrid"; the
+# names are ASSUMED, the benchmark configuration lists them: FLA's KDA
+# module under `self_attn.`, the convolutions as torch depthwise `[C, 1,
+# K]`, DeepSeek-V3's names for what the two families share) and what the
+# family adds to a latent layer and to the router.
+_KDA_MAP = {
+    "kda_q": ("self_attn.q_proj.weight", True),
+    "kda_k": ("self_attn.k_proj.weight", True),
+    "kda_v": ("self_attn.v_proj.weight", True),
+    "conv_q": ("self_attn.q_conv1d.weight", True),
+    "conv_k": ("self_attn.k_conv1d.weight", True),
+    "conv_v": ("self_attn.v_conv1d.weight", True),
+    "w_decay": ("self_attn.f_proj.weight", True),
+    "a_log": ("self_attn.A_log", False),
+    "dt_bias": ("self_attn.dt_bias", False),
+    "w_beta": ("self_attn.b_proj.weight", True),
+    "o_norm": ("self_attn.o_norm.weight", False),
+}
+_HYBRID_EXTRA_MAP = {
+    "wq": ("self_attn.q_proj.weight", True),
+    "wg": ("self_attn.g_proj.weight", True),
+    "b_router": ("mlp.gate.expert_bias", False),
+}
+
+# Window and full grouped-query attention mixed by layer, with the
+# shared-expert feed-forward (`model_type` "exaone_moe"; the names are
+# ASSUMED, the benchmark configuration lists them: Llama's for the
+# attention with a `q_norm` / `k_norm` weight a head width wide,
+# DeepSeek-V3's for the experts and for the router's bias). The next-token
+# prediction block (`mtp.*`) is never asked for.
+_WINDOWED_MAP = {
+    **{k: _LAYER_MAP[k] for k in ("attn_norm", "wq", "wk", "wv", "wo",
+                                  "mlp_norm")},
+    "q_norm": ("self_attn.q_norm.weight", False),
+    "k_norm": ("self_attn.k_norm.weight", False),
+    **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP,
+    "b_router": ("mlp.gate.e_score_correction_bias", False),
+}
+
+# State-space layers beside grouped-query attention (`model_type` "jamba",
+# Hugging Face's own names, which config.json does not carry: the benchmark
+# configuration lists them): the mixer under `mamba.`, its convolution as
+# torch depthwise `[C, 1, K]` WITH a bias, `A_log` as `[d_inner, d_state]`
+# (ours is laid out as the state is, `[d_state, d_inner]`), the feed-forward
+# under `feed_forward.`, its norm `pre_ff_layernorm`, the model's last norm
+# `model.final_layernorm`.
+_MAMBA_MAP = {
+    "w_in": ("mamba.in_proj.weight", True),
+    "conv_w": ("mamba.conv1d.weight", True),
+    "conv_b": ("mamba.conv1d.bias", False),
+    "w_x": ("mamba.x_proj.weight", True),
+    "dt_norm": ("mamba.dt_layernorm.weight", False),
+    "b_norm": ("mamba.b_layernorm.weight", False),
+    "c_norm": ("mamba.c_layernorm.weight", False),
+    "w_dt": ("mamba.dt_proj.weight", True),
+    "dt_bias": ("mamba.dt_proj.bias", False),
+    "a_log": ("mamba.A_log", True),
+    "d_skip": ("mamba.D", False),
+    "w_out": ("mamba.out_proj.weight", True),
+}
+_STATE_SPACE_MAP = {
+    **{k: _LAYER_MAP[k] for k in ("attn_norm", "wq", "wk", "wv", "wo")},
+    "mlp_norm": ("pre_ff_layernorm.weight", False),
+    "w_gate": ("feed_forward.gate_proj.weight", True),
+    "w_up": ("feed_forward.up_proj.weight", True),
+    "w_down": ("feed_forward.down_proj.weight", True),
+}
+
+# Gated short convolutions beside grouped-query attention, every expert
+# held (`model_type` "lfm2_moe"; the names are ASSUMED, the benchmark
+# configuration lists them: Hugging Face's Lfm2Moe modules): a layer's
+# norms `operator_norm` and `ffn_norm`, the mixer under `conv.` with its
+# taps as torch depthwise `[C, 1, K]`, the attention's output projection
+# `out_proj` and head norms `q_layernorm` / `k_layernorm`, the feed-forward
+# under `feed_forward.` in Mixtral's w1 (gate) / w3 (up) / w2 (down), the
+# router `feed_forward.gate` with its `expert_bias`, the model's last norm
+# `model.embedding_norm`.
+_SHORT_CONV_MAP = {
+    "attn_norm": ("operator_norm.weight", False),
+    "w_in": ("conv.in_proj.weight", True),
+    "conv_w": ("conv.conv.weight", True),
+    "w_out": ("conv.out_proj.weight", True),
+    **{k: _LAYER_MAP[k] for k in ("wq", "wk", "wv")},
+    "wo": ("self_attn.out_proj.weight", True),
+    "q_norm": ("self_attn.q_layernorm.weight", False),
+    "k_norm": ("self_attn.k_layernorm.weight", False),
+    "mlp_norm": ("ffn_norm.weight", False),
+    "w_gate": ("feed_forward.w1.weight", True),
+    "w_up": ("feed_forward.w3.weight", True),
+    "w_down": ("feed_forward.w2.weight", True),
+    "router": ("feed_forward.gate.weight", True),
+    "b_router": ("feed_forward.expert_bias", False),
+}
+_SHORT_CONV_EXPERT_MAP = {
+    "w_gate": "feed_forward.experts.{e}.w1.weight",
+    "w_up": "feed_forward.experts.{e}.w3.weight",
+    "w_down": "feed_forward.experts.{e}.w2.weight",
+}
+
+
+# --- checks shared by several families --------------------------------------
+
+def _check_told_share(c):
+    """The shared-expert feed-forward's routing, and the held experts'
+    place among the router's (``router_experts`` is filled in)."""
+    if not c.n_routed_experts:
+        return
+    if c.scoring_func != "sigmoid":
+        raise ValueError(
+            f"scoring_func {c.scoring_func!r} is not wired for "
+            "the shared-expert family (sigmoid, group-limited "
+            "routing only)")
+    width = c.router_experts or c.n_routed_experts
+    object.__setattr__(c, "router_experts", width)
+    if width % c.n_group or not (
+            0 <= c.first_expert <= width - c.n_routed_experts):
+        raise ValueError(
+            f"experts {c.first_expert}.."
+            f"{c.first_expert + c.n_routed_experts - 1} "
+            f"held of {width} in {c.n_group} groups")
+
+
+def _check_layer_types(c, mixers) -> set:
+    """What a per-layer ``layer_types`` may ask for and is computed;
+    returns the kinds it names."""
+    object.__setattr__(c, "layer_types", tuple(c.layer_types))
+    kinds = set(c.layer_types)
+    if (len(c.layer_types) != c.num_hidden_layers
+            or not kinds <= set(mixers)):
+        raise ValueError(
+            f"layer_types needs one of {sorted(mixers)} for "
+            f"each of the {c.num_hidden_layers} layers, got "
+            f"{len(c.layer_types)} entries of {sorted(kinds)}")
+    if "full_attention" not in kinds:
+        raise ValueError(
+            "layer_types without a full_attention layer is not wired "
+            "(the cache's capacity is the full layers')")
+    return kinds
+
+
+def _expert_share(d: dict, held: int) -> dict:
+    """This chip's share of an ep deployment's experts (config.json
+    ``expert_share``): the router's width and the first held expert."""
+    share = d.get("expert_share")
+    if not share:
+        return {}
+    return {"router_experts": share["n_routed_experts"],
+            "first_expert": share["rank"] * held}
+
+
+def _default_rope(name: str, rope: dict, scaling=None, where="") -> dict:
+    """The file's rope parameters, which may ask for the default rotation
+    and no scaling."""
+    kind = rope.get("rope_type", rope.get("type", "default"))
+    if kind != "default" or scaling:
+        raise ValueError(
+            f"{name}: rope type {kind!r} is not wired (default rotation, "
+            f"no scaling{where})")
+    return rope
+
+
+def _only_served(name: str, d: dict, fixed: dict):
+    """What a config.json may ask for that nothing here computes: key ->
+    the only value served."""
+    for key, only in fixed.items():
+        if d.get(key, only) != only:
+            raise ValueError(
+                f"{name}: {key} = {d[key]!r} is not wired (only {only!r})")
+
+
+def _entries(name: str, d: dict) -> list:
+    types = list(d["layer_types"])
+    if len(types) != d["num_hidden_layers"]:
+        raise ValueError(
+            f"{name}: layer_types has {len(types)} entries for "
+            f"{d['num_hidden_layers']} layers")
+    return types
+
+
+_EXPERT_FIELDS = (
+    "first_k_dense_replace", "moe_intermediate_size",
+    "n_shared_experts", "n_routed_experts", "scoring_func", "n_group",
+    "topk_group", "norm_topk_prob", "routed_scaling_factor",
+)
+
+
+# --- the dense and Mixtral-style decoders: one bare stack -------------------
+
+def _gqa_read(d: dict) -> dict:
+    """Family defaults not spelled out in the HF config dict: Qwen2's q/k/v
+    bias is unconditional in its architecture (the HF config has no
+    attention_bias key to read); Gemma's (1+w) RMSNorm, GeGLU, and
+    sqrt(hidden) embedding scaling are likewise architectural."""
+    out = {}
+    if d.get("kv_lora_rank"):
+        raise ValueError(
+            f"model_type {d.get('model_type')!r} has latent-attention "
+            f"keys but is not one of {sorted(LATENT.model_types)}")
+    if d.get("model_type") == "qwen2" and "attention_bias" not in d:
+        out["attention_bias"] = True
+    if d.get("model_type") == "gemma":
+        out["rms_norm_offset"] = d.get("rms_norm_offset", True)
+        out["embed_scale"] = d.get("embed_scale", True)
+        # HF Gemma spells the activation in `hidden_activation` (newer
+        # configs) or `hidden_act`; both default to the tanh gelu
+        act = d.get("hidden_activation") or d.get("hidden_act")
+        if act not in (None, "gelu", "gelu_pytorch_tanh"):
+            raise ValueError(f"unsupported gemma activation {act!r}")
+        out["hidden_act"] = "gelu_tanh"
+    # Qwen2 configs ship a sliding_window VALUE with the feature gated
+    # off (`use_sliding_window: false`); honoring the value alone would
+    # force windowed masking (and forfeit the flash kernels) on a model
+    # that attends fully. When the gate is on, HF additionally windows
+    # only layers >= max_window_layers — full-depth (0) and no-depth
+    # (>= num layers) are uniform and supported; a partial depth would
+    # need per-layer masks the stacked scan doesn't carry, so it is
+    # rejected rather than silently diverging.
+    if "use_sliding_window" in d and d.get("sliding_window") is not None:
+        mwl = d.get("max_window_layers", 0)
+        layers = d.get("num_hidden_layers", 32)
+        if not d["use_sliding_window"] or mwl >= layers:
+            out["sliding_window"] = None
+        elif mwl > 0:
+            raise ValueError(
+                f"partial-depth sliding window "
+                f"(max_window_layers={mwl} of {layers}) is not "
+                "wired for this family's one bare stack; a "
+                "window on some layers is read from a per-layer "
+                f"layer_types list (model_type "
+                f"{WINDOWED.model_types[0]!r})")
+    return out
+
+
+GQA = Family(model_types=(), selects=lambda c: True, read=_gqa_read)
+
+
+# --- latent attention, shared and routed experts ----------------------------
+
+def _latent_read(d: dict) -> dict:
+    """DeepSeek-V3's keys. `topk_method` is read as the group-limited
+    choice n_group/topk_group describe with no correction bias tensor
+    ("none", "group_limited_greedy"); "noaux_tc" needs the bias and is
+    refused rather than served without it."""
+    if d.get("topk_method", "none") not in (
+            "none", "greedy", "group_limited_greedy"):
+        raise ValueError(
+            f"topk_method {d['topk_method']!r} (a routing "
+            "correction bias) is not wired")
+    if d.get("moe_layer_freq", 1) != 1:
+        raise ValueError("moe_layer_freq != 1 is not wired")
+    return _expert_share(d, d.get("n_routed_experts", 0))
+
+
+def _latent_check(c):
+    if not (c.qk_rope_head_dim and c.v_head_dim):
+        raise ValueError(
+            "latent attention (kv_lora_rank > 0) needs "
+            "qk_rope_head_dim and v_head_dim")
+    if c.attn_gate not in (None, "head_wise"):
+        raise ValueError(
+            f"attn_gate {c.attn_gate!r} is not wired (a "
+            "head-wise output gate only)")
+    _check_told_share(c)
+
+
+LATENT = Family(
+    model_types=("deepseek_v3", "axk1"),
+    selects=lambda c: c.kv_lora_rank > 0,
+    fields=("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+            "qk_rope_head_dim", "v_head_dim") + _EXPERT_FIELDS,
+    read=_latent_read, check=_latent_check,
+    tensor_names={**_LATENT_MAP, **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP,
+                  **_HYBRID_EXTRA_MAP, **_KDA_MAP},
+    expert_names=_LATENT_EXPERT_MAP,
+    probe=".self_attn.kv_a_proj_with_mqa.weight",
+    what="a latent-attention model", shard_axes=frozenset(("ep",)),
+    shard_why="one cache row for all heads or a recurrent state",
+    linear_tiers=("int8",),
+    cache_tiers=(),
+    cache_why=(
+        "an int8 cache is not wired for latent attention (the "
+        "latent row is already 1/35 of per-head keys and values)"),
+    counts_held_experts=True)
+
+
+# --- delta-rule linear attention beside latent attention (Ling-3.0's keys) --
+
+_HYBRID_FIELDS = ("layer_group_size", "short_conv_kernel_size",
+                  "kda_lower_bound", "attn_gate")
+# what this family's config.json may ask for that nothing here computes:
+# key -> the only value served
+_HYBRID_FIXED = {
+    "kda_safe_gate": True, "no_kda_lora": True, "use_kda_lora": False,
+    "linear_silu": True, "use_qk_norm": True, "num_kv_heads_for_linear_attn": 0,
+    "rope_interleave": True, "use_mla_nope": False, "use_nGPT": False,
+    "scale_router_input": False, "value_norm": False, "up_proj_norm": False,
+    "use_bias": False, "use_qkv_bias": False, "group_norm_size": 1,
+    "rope_scaling": None,
+}
+
+
+def _hybrid_read(d: dict) -> dict:
+    """`LlamaConfig` fields from a "bailing_hybrid" config.json (its own
+    spelling of the expert keys)."""
+    name = HYBRID.model_types[0]
+    _only_served(name, d, _HYBRID_FIXED)
+    layers = d["num_hidden_layers"]
+    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        if any(d.get(key, ())[:layers]):
+            raise ValueError(
+                f"{name}: a nonzero {key} entry inside the "
+                f"{layers} served layers (a clamp on the experts' SwiGLU "
+                "whose form the config does not give) is not wired")
+    held = d["num_experts"]
+    if d.get("moe_shared_expert_intermediate_size",
+             d["moe_intermediate_size"]) != d["moe_intermediate_size"]:
+        raise ValueError(
+            f"{name}: a shared expert of another width than "
+            "the routed ones is not wired")
+    bias = bool(d.get("moe_router_enable_expert_bias"))
+    if (d.get("topk_method", "noaux_tc") == "noaux_tc") != bias:
+        raise ValueError(
+            f"{name}: topk_method and "
+            "moe_router_enable_expert_bias disagree about a routing "
+            "correction bias")
+    return {
+        "n_routed_experts": held,
+        "n_shared_experts": d.get("num_shared_experts", 0),
+        "scoring_func": d.get("score_function",
+                              d.get("scoring_func", "sigmoid")),
+        "router_bias": bias,
+        "attn_gate": d.get("gated_attention_proj_granularity_type"),
+        "layer_group_size": d["layer_group_size"],
+        **_expert_share(d, held),
+    }
+
+
+def _hybrid_write(c, d: dict):
+    d["num_experts"] = d.pop("n_routed_experts")
+    d["num_shared_experts"] = d.pop("n_shared_experts")
+    d["score_function"] = d.pop("scoring_func")
+    d["gated_attention_proj_granularity_type"] = d.pop("attn_gate")
+    d["moe_router_enable_expert_bias"] = d.pop("router_bias")
+    d["topk_method"] = "noaux_tc" if c.router_bias else "none"
+    d["moe_shared_expert_intermediate_size"] = c.moe_intermediate_size
+
+
+def _hybrid_check(c):
+    if not c.kv_lora_rank:
+        raise ValueError(
+            "layer_group_size > 0 (delta-rule layers beside latent "
+            "ones) needs the latent-attention keys (kv_lora_rank > 0)")
+    _latent_check(c)
+
+
+HYBRID = dataclasses.replace(
+    LATENT, model_types=("bailing_hybrid",),
+    selects=lambda c: c.layer_group_size > 0,
+    fields=LATENT.fields + _HYBRID_FIELDS,
+    read=_hybrid_read, write=_hybrid_write, check=_hybrid_check,
+    recurrent_mixer="kda")
+
+
+# --- state-space (Mamba-1) layers beside attention (AI21's Jamba keys) ------
+
+_STATE_SPACE_FIELDS = ("attn_layer_period", "attn_layer_offset",
+                       "mamba_d_state", "mamba_d_conv", "mamba_expand",
+                       "mamba_dt_rank", "mamba_conv_bias")
+# what this family's config.json may ask for that nothing here computes:
+# key -> the only value served (experts in alternate layers, a window on
+# the attention layers, a bias on the mixer's projections)
+_STATE_SPACE_FIXED = {"num_experts": 1, "mamba_proj_bias": False}
+
+
+def _state_space_read(d: dict) -> dict:
+    """`LlamaConfig` fields from a "jamba" config.json."""
+    from cake_tpu.models.config import LlamaConfig
+
+    name = STATE_SPACE.model_types[0]
+    _only_served(name, d, _STATE_SPACE_FIXED)
+    if d.get("sliding_window") is not None:
+        raise ValueError(
+            f"{name}: sliding_window = "
+            f"{d['sliding_window']!r} is not wired beside state-space "
+            "layers (their attention layers are full; a window a layer, "
+            f"by layer_types, is model_type {WINDOWED.model_types[0]!r}'s)")
+    rank = d.get("mamba_dt_rank", "auto")
+    return {
+        # one expert is the dense SwiGLU: its choice of 1 selects nothing
+        "num_experts_per_tok": LlamaConfig.num_experts_per_tok,
+        "mamba_dt_rank": 0 if rank == "auto" else rank,
+        # the family's defaults where the file leaves them out
+        "attn_layer_period": d.get("attn_layer_period", 8),
+        "attn_layer_offset": d.get("attn_layer_offset", 4),
+    }
+
+
+def _state_space_check(c):
+    if c.kv_lora_rank or c.num_local_experts or (
+            c.sliding_window is not None):
+        raise ValueError(
+            "attn_layer_period > 0 (state-space layers beside "
+            "grouped-query attention) is wired with full "
+            "grouped-query attention and a dense feed-forward "
+            "only: no latent keys, no experts, no sliding_window")
+    if not 0 <= c.attn_layer_offset < c.attn_layer_period:
+        raise ValueError(
+            f"attn_layer_offset {c.attn_layer_offset} outside "
+            f"the period of {c.attn_layer_period}")
+    if not c.mamba_dt_rank:
+        object.__setattr__(c, "mamba_dt_rank", -(-c.hidden_size // 16))
+
+
+_NO_INT8_MIXER = "its mixer's projections have no int8 form yet"
+_REST_IS_SMALL = (
+    "an int8 cache is not wired for a model whose layers hold "
+    "a recurrent state or a convolution's tail (its few "
+    "layers of rows are the smaller part of the cache)")
+
+STATE_SPACE = Family(
+    model_types=("jamba",),
+    selects=lambda c: c.attn_layer_period > 0,
+    fields=_STATE_SPACE_FIELDS, read=_state_space_read,
+    # the family's other keys, at the only values served
+    write=lambda c, d: d.update(_STATE_SPACE_FIXED),
+    check=_state_space_check, recurrent_mixer="mamba",
+    tensor_names={**_STATE_SPACE_MAP, **_MAMBA_MAP},
+    final_norm="model.final_layernorm.weight",
+    probe=".mamba.in_proj.weight",
+    what="a state-space model", shard_axes=frozenset(),
+    shard_why=("a recurrent state a channel: tp over d_inner is not "
+               "wired"),
+    linear_tiers=(), linear_why=_NO_INT8_MIXER,
+    cache_tiers=(), cache_why=_REST_IS_SMALL)
+
+
+# --- window and full attention mixed by layer (K-EXAONE's keys) -------------
+
+def _windowed_read(d: dict) -> dict:
+    """`LlamaConfig` fields from an "exaone_moe" config.json (its own
+    spelling: ``num_experts``, ``num_shared_experts``, ``layer_types``,
+    ``mlp_layer_types``, ``sliding_windows``, ``rope_parameters``).
+    ``num_nextn_predict_layers`` (a next-token prediction block, ``mtp.*``
+    tensors) is read and ignored: the block takes no part in the model's
+    own logits and the loaders skip its tensors. Read into the file:
+    pre-norm sublayers, a routing bias that enters the choice."""
+    name = WINDOWED.model_types[0]
+    layers, window = d["num_hidden_layers"], d.get("sliding_window")
+    types = _entries(name, d)
+    want = [window if t == "sliding_attention" else 0 for t in types]
+    if [w or 0 for w in d.get("sliding_windows", want)] != want:
+        raise ValueError(
+            f"{name}: sliding_windows {d['sliding_windows']} disagrees "
+            f"with layer_types and sliding_window {window} (a window of "
+            "its own a layer is not wired)")
+    rope = _default_rope(name, d.get("rope_parameters") or {},
+                         d.get("rope_scaling"), ", on the window layers only")
+    dense = d.get("first_k_dense_replace")
+    ffn = list(d.get("mlp_layer_types") or (
+        ["dense"] * (dense or 0) + ["sparse"] * (layers - (dense or 0))))
+    lead = ffn.count("dense")
+    if (len(ffn) != layers or ffn != ["dense"] * lead + ["sparse"]
+            * (layers - lead) or dense not in (None, lead)):
+        raise ValueError(
+            f"{name}: mlp_layer_types {ffn} with first_k_dense_replace "
+            f"{dense} is not wired (dense layers lead, sparse ones follow)")
+    groups, kept = d.get("n_group", 1), d.get("topk_group", 1)
+    if not 1 <= kept <= groups:
+        raise ValueError(
+            f"{name}: topk_group {kept} of n_group {groups} is not a "
+            "group-limited choice")
+    held = d["num_experts"] if lead < layers else 0
+    return {
+        "layer_types": tuple(types),
+        "qk_norm": True,
+        "first_k_dense_replace": lead,
+        "n_routed_experts": held,
+        "n_shared_experts": d.get("num_shared_experts", 0),
+        "router_bias": bool(held),
+        "rope_theta": float(rope.get("rope_theta",
+                                     d.get("rope_theta", 10000.0))),
+        **_expert_share(d, held),
+    }
+
+
+def _windowed_write(c, d: dict):
+    d.pop("router_bias")
+    d["layer_types"] = list(c.layer_types)
+    d["sliding_windows"] = [
+        c.sliding_window if t == "sliding_attention" else 0
+        for t in c.layer_types]
+    d["mlp_layer_types"] = [
+        "sparse" if ffn == "moe" else "dense" for _, ffn in c.layer_kinds]
+    d["num_experts"] = d.pop("n_routed_experts")
+    d["num_shared_experts"] = d.pop("n_shared_experts")
+    d["rope_parameters"] = {"rope_theta": d.pop("rope_theta"),
+                            "rope_type": "default"}
+
+
+def _windowed_check(c):
+    kinds = _check_layer_types(c, WINDOWED.layer_mixers)
+    if "sliding_attention" in kinds and not (
+            c.sliding_window and c.sliding_window >= 8):
+        raise ValueError(
+            "layer_types names sliding_attention layers: they need a "
+            f"sliding_window of 8 or more, got {c.sliding_window!r}")
+    if c.kv_lora_rank or c.attn_layer_period or (
+            c.num_local_experts or c.attention_bias):
+        raise ValueError(
+            "layer_types (window and full grouped-query attention "
+            "mixed by layer) is wired with the shared-expert "
+            "feed-forward only: no latent keys, no state-space "
+            "layers, no Mixtral-style experts, no projection bias")
+    _check_told_share(c)
+
+
+WINDOWED = Family(
+    model_types=("exaone_moe",),
+    selects=lambda c: c.layer_types is not None,
+    fields=("layer_types",) + _EXPERT_FIELDS,
+    read=_windowed_read, write=_windowed_write, check=_windowed_check,
+    layer_mixers={"sliding_attention": "swa", "full_attention": "gqa"},
+    tensor_names=_WINDOWED_MAP, expert_names=_LATENT_EXPERT_MAP,
+    probe=".mlp.gate.e_score_correction_bias",
+    what="a model of window and full attention layers",
+    shard_axes=frozenset(("ep",)),
+    shard_why=("a ring of rows beside the full layers' cache: window "
+               "layers under tp or stages are not wired"),
+    linear_tiers=(),
+    linear_why=("its q, k and v projections are not among the "
+                "shared-expert family's int8 linears"),
+    cache_tiers=(),
+    cache_why=(
+        "an int8 cache is not wired for a model whose window "
+        "layers hold a ring (the ring is already a fraction of the "
+        "rows; its few full layers are the rest)"),
+    counts_held_experts=True, full_layers_rotate=False)
+
+
+# --- gated short convolutions beside attention (LFM2-MoE's keys) ------------
+
+def _short_conv_read(d: dict) -> dict:
+    """`LlamaConfig` fields from an "lfm2_moe" config.json (its own
+    spelling: ``num_dense_layers``, ``num_experts``, ``norm_eps``,
+    ``use_expert_bias``, ``conv_L_cache``). Read into the file: the chunk
+    order ``B | C | x``, no activation in the mixer, a tied head where the
+    file names none."""
+    name = SHORT_CONV.model_types[0]
+    layers = d["num_hidden_layers"]
+    types = _entries(name, d)
+    rope = _default_rope(
+        name, d.get("rope_parameters") or d.get("rope_scaling") or {})
+    _only_served(name, d, {"num_shared_experts": 0, "n_group": 1,
+                           "topk_group": 1, "scoring_func": "sigmoid"})
+    lead = d.get("num_dense_layers", 0)
+    return {
+        "layer_types": tuple(types),
+        "qk_norm": True,
+        "rms_norm_eps": d.get("norm_eps", d.get("rms_norm_eps", 1e-5)),
+        "rope_theta": float(rope.get("rope_theta",
+                                     d.get("rope_theta", 1000000.0))),
+        "rope_scaling": None,
+        "first_k_dense_replace": lead,
+        "n_routed_experts": d["num_experts"] if lead < layers else 0,
+        "n_shared_experts": 0,
+        "scoring_func": "sigmoid",
+        "router_bias": bool(d.get("use_expert_bias", False)),
+        # Lfm2MoeConfig's default where the file names none
+        "tie_word_embeddings": bool(d.get("tie_word_embeddings", True)),
+    }
+
+
+def _short_conv_write(c, d: dict):
+    d["layer_types"] = list(c.layer_types)
+    d["num_dense_layers"] = d.pop("first_k_dense_replace")
+    d["num_experts"] = d.pop("n_routed_experts")
+    d["norm_eps"] = d.pop("rms_norm_eps")
+    d["use_expert_bias"] = d.pop("router_bias")
+    for f in ("n_shared_experts", "scoring_func", "n_group", "topk_group"):
+        d.pop(f)
+
+
+def _short_conv_check(c):
+    kinds = _check_layer_types(c, SHORT_CONV.layer_mixers)
+    if "conv" not in kinds:
+        raise ValueError(
+            "layer_types without a conv layer is not wired for "
+            f"model_type {SHORT_CONV.model_types[0]!r} (every layer a "
+            "full_attention one is a dense decoder's stack)")
+    if c.conv_L_cache < 2 or c.conv_bias:
+        raise ValueError(
+            f"conv_L_cache {c.conv_L_cache} / conv_bias "
+            f"{c.conv_bias} is not wired (a depthwise convolution "
+            "of 2 or more taps, no bias)")
+    if c.kv_lora_rank or c.attn_layer_period or (
+            c.num_local_experts or c.attention_bias
+            or c.sliding_window is not None
+            or c.n_shared_experts):
+        raise ValueError(
+            "layer_types with conv layers (gated short convolutions "
+            "beside full grouped-query attention) is wired with the "
+            "routed-expert feed-forward only: no latent keys, no "
+            "state-space layers, no Mixtral-style experts, no "
+            "projection bias, no sliding_window, no shared expert")
+    _check_told_share(c)
+
+
+SHORT_CONV = Family(
+    model_types=("lfm2_moe",),
+    selects=lambda c: (c.layer_types is not None
+                       and c.model_type in SHORT_CONV.model_types),
+    fields=("layer_types", "conv_L_cache", "conv_bias") + _EXPERT_FIELDS,
+    read=_short_conv_read, write=_short_conv_write, check=_short_conv_check,
+    layer_mixers={"conv": "conv", "full_attention": "gqa"},
+    recurrent_mixer="conv",
+    tensor_names=_SHORT_CONV_MAP, expert_names=_SHORT_CONV_EXPERT_MAP,
+    final_norm="model.embedding_norm.weight", probe=".conv.in_proj.weight",
+    what="a model of short-convolution and attention layers",
+    shard_axes=frozenset(),
+    shard_why=("a convolution's tail beside the attention layers' rows: "
+               "its tail under stages, tp or sp is not wired, and every "
+               "expert is held: no share is cut over ep"),
+    linear_tiers=(), linear_why=_NO_INT8_MIXER,
+    cache_tiers=(), cache_why=_REST_IS_SMALL,
+    counts_held_experts=True, expert_periods=False, topk_norm_eps=1e-6)
+
+
+# The first record that selects a configuration is its family: the
+# families that read `layer_types` before the ones a single key names, the
+# bare stack last.
+FAMILIES = (SHORT_CONV, WINDOWED, STATE_SPACE, HYBRID, LATENT, GQA)
+# every field some family's config.json alone carries
+FIELDS = frozenset(f for family in FAMILIES for f in family.fields)
+# the record that reads a config.json, by its `model_type` (the bare
+# stack's reads every type no other family names)
+BY_MODEL_TYPE = {t: f for f in FAMILIES for t in f.model_types}

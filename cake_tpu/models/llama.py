@@ -238,7 +238,7 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
     period of two segments after the leading ones; state-space layers but
     the eighth of every fourteen is a period of three (M7 A M6).
 
-    Window and full attention mixed by layer (``config.windowed``) goes the
+    Window and full attention mixed by layer (``families.WINDOWED``) goes the
     same way: a leading dense layer, ``W W G``, then ``W W W G`` eleven
     times, is a dense window segment, a run of two sparse window layers, a
     period of two segments (``G``, ``W W W``) eleven times over and a last
@@ -247,7 +247,7 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
     rings in and out of every branch and re-lays the attention projections
     it indexes inside one, PERF.md section 7, PR 40.)
 
-    Short convolutions beside attention (``config.short_conv``): two
+    Short convolutions beside attention (``families.SHORT_CONV``): two
     leading dense conv layers, then ``A`` and ``c c c`` by turns with a
     ragged end, each stretch a segment of its own and NO repeated period
     where the layers hold routed experts (``periods`` below says why). A
@@ -274,7 +274,7 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
     # has left beside the weights; as the operand of a product behind a
     # scan's ``xs``, a segment of one repetition, it stays as it lies: my
     # AOT compiles and chip run, PR 43; PERF.md section 7)
-    periods = not (config.short_conv and config.n_routed_experts)
+    periods = config.family.expert_periods or not config.n_routed_experts
     names: dict[str, int] = {}
     # layers counted so far in the cache buffers of each mixer's kind
     cached = {"mla": 0, "kda": 0, "gqa": 0, "swa": 0, "mamba": 0, "conv": 0}
@@ -709,7 +709,7 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
                                  config.norm_topk_prob,
                                  config.routed_scaling_factor,
                                  layer.get("b_router"),
-                                 config.topk_norm_eps),
+                                 config.family.topk_norm_eps),
             held=(config.first_expert, config.n_routed_experts),
             count_local=count_local, layer=expert_idx,
         )
@@ -769,7 +769,7 @@ def _typed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
                 qk_norm=norm, valid=valid)
         cache = dataclasses.replace(cache, ring_k=ring_k, ring_v=ring_v)
     else:
-        if config.windowed:  # the window family's full layers: no table
+        if not config.family.full_layers_rotate:  # no table
             cos = sin = None
         with jax.named_scope("attn.full"):
             out, k, v = self_attention_block(

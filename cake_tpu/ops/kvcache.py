@@ -101,7 +101,7 @@ class KVCache:
     [L_conv, B, taps - 1, hidden]`` and NO ``state``, so what a cache
     holds is asked of ``LlamaConfig.cache_plan``, not of ``state``. Where
     some layers attend through a window
-    (``LlamaConfig.windowed``), ``k``/``v`` have a layer for each FULL
+    (a ``ring`` in ``cache_plan``), ``k``/``v`` have a layer for each FULL
     layer, and ``ring_k``/``ring_v [L_window, B, KH, R, D]`` hold the
     window layers' rows: ``R = config.ring_rows`` rows a stream whatever
     ``max_seq``, position ``p`` at row ``p % R`` (:func:`ring_write`). A
@@ -156,6 +156,8 @@ def init_cache(
     (:class:`QuantizedKV`): ~half the cache HBM, quantize-on-write."""
     if quant not in (None, "int8"):
         raise ValueError(f"unsupported kv quant={quant!r}")
+    if quant and quant not in config.family.cache_tiers:
+        raise ValueError(config.family.cache_why)
     L = config.num_hidden_layers if num_layers is None else num_layers
     S = max_seq or config.max_seq_len
     dt = dtype or config.jax_dtype
@@ -165,11 +167,11 @@ def init_cache(
     heads, k_width, v_width = config.cache_row
     plan = config.cache_plan
     rec = {}
+    if num_layers is not None and set(plan) - {"rows"}:
+        raise ValueError("a model that holds a recurrent state, a "
+                         "convolution's tail or a ring of rows is cached "
+                         "whole (no layer ranges)")
     if "conv" in plan:  # layers that carry a tail, and a state or none
-        if num_layers is not None:
-            raise ValueError("a model that holds a recurrent state or a "
-                             "convolution's tail is cached whole (no "
-                             "layer ranges)")
         L = plan.get("rows", (0,))[0]
         if "state" in plan:
             n, *shape = plan["state"]
@@ -177,29 +179,11 @@ def init_cache(
         n, *shape = plan["conv"]
         rec["conv"] = jnp.zeros((n, batch, *shape), dt)
     if "ring" in plan:
-        if num_layers is not None:
-            raise ValueError("a model whose window layers hold a ring is "
-                             "cached whole (no layer ranges)")
-        if quant == "int8":
-            raise ValueError(
-                "an int8 cache is not wired for a model whose window "
-                "layers hold a ring (the ring is already a fraction of the "
-                "rows; its few full layers are the rest)")
         L = plan["rows"][0]
         n, kvh, r, kw, vw = plan["ring"]
         rec["ring_k"] = jnp.zeros((n, batch, kvh, r, kw), dt)
         rec["ring_v"] = jnp.zeros((n, batch, kvh, r, vw), dt)
     if quant == "int8":
-        if config.latent:
-            raise ValueError(
-                "an int8 cache is not wired for latent attention (the "
-                "latent row is already 1/35 of per-head keys and values)")
-        if rec:
-            raise ValueError(
-                "an int8 cache is not wired for a model whose layers hold "
-                "a recurrent state or a convolution's tail (its few "
-                "layers of rows are the smaller part of the cache)")
-
         def half(width):
             shape = (L, batch, heads, S, width)
             return QuantizedKV(q=jnp.zeros(shape, jnp.int8),

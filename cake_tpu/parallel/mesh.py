@@ -69,39 +69,17 @@ def make_mesh(
 def validate_shardable(config: LlamaConfig, num_stages: int, tp: int,
                        sp: int = 1, ep: int = 1) -> None:
     """Divisibility requirements for the (stage, sp, ep, tp) sharding."""
-    if config.latent and (num_stages > 1 or tp > 1 or sp > 1):
+    family = config.family
+    asked = {axis for axis, n in (("stages", num_stages), ("tp", tp),
+                                  ("sp", sp), ("ep", ep)) if n > 1}
+    if asked - family.shard_axes:
+        only = ", ".join(f"--{axis}" for axis in sorted(family.shard_axes))
         raise ValueError(
-            "a latent-attention model (stacks of several kinds of layer, "
-            "one cache row for all heads or a recurrent state) runs as "
-            "one stage with tp = 1 and sp = 1; only --ep shards it (its "
-            "held experts)"
-        )
-    if config.windowed and (num_stages > 1 or tp > 1 or sp > 1):
-        raise ValueError(
-            "a model of window and full attention layers (stacks of "
-            "several kinds of layer, a ring of rows beside the full "
-            "layers' cache) runs as one stage with tp = 1 and sp = 1; only "
-            "--ep shards it (its held experts): window layers under tp or "
-            "stages are not wired"
-        )
-    if config.short_conv and (num_stages > 1 or tp > 1 or sp > 1
-                              or ep > 1):
-        raise ValueError(
-            "a model of short-convolution and attention layers (stacks "
-            "of several kinds of layer, a convolution's tail beside the "
-            "attention layers' rows) runs as one stage with tp = 1, sp = "
-            "1 and ep = 1: nothing shards it yet (its tail under stages, "
-            "tp or sp is not wired, and every expert is held: no share "
-            "is cut over ep)"
-        )
-    if config.state_space and (num_stages > 1 or tp > 1 or sp > 1
-                               or ep > 1):
-        raise ValueError(
-            "a state-space model (stacks of several kinds of layer, a "
-            "recurrent state a channel) runs as one stage with tp = 1, "
-            "sp = 1 and ep = 1: nothing shards it yet (tp over d_inner is "
-            "not wired)"
-        )
+            f"{family.what} (stacks of several kinds of layer) runs as one "
+            "stage with tp = 1, sp = 1" + (
+                f"; only {only} shards it (its held experts)" if only
+                else " and ep = 1; nothing shards it yet")
+            + f": {family.shard_why}")
     if sp > 1 and config.max_seq_len % sp:
         raise ValueError(
             f"max_seq_len {config.max_seq_len} not divisible by sp {sp}"

@@ -199,7 +199,7 @@ def _valid_rows(config: LlamaConfig, tokens: jax.Array,
     layers hold a recurrent state or a ring of rows (None otherwise: rows
     past a frontier hide themselves): up to and with the row's last true
     token, the whole chunk where that token lies in a later one."""
-    if not (config.recurrent or config.windowed):
+    if not set(config.cache_plan) - {"rows"}:
         return None
     t = tokens.shape[1]
     return jnp.broadcast_to(jnp.minimum(last_index + 1, t),
@@ -472,8 +472,7 @@ def build_sharded_decode(
 def moe_counted(config: LlamaConfig) -> bool:
     """Whether this model's serving decode programs count the routed pairs
     that fall on held experts (an expert model told its share)."""
-    return ((config.latent or config.layer_types is not None)
-            and config.n_routed_experts > 0)
+    return config.family.counts_held_experts and config.n_routed_experts > 0
 
 
 def _head_split_safe(hw, S: int) -> bool:

@@ -196,10 +196,15 @@ _MOE_ROUTED = obs_metrics.counter("moe.routed_pairs")
 _MOE_STEPS = obs_metrics.counter("moe.decode_steps")
 _MOE_ADMIT_ROWS = obs_metrics.counter("moe.admit_rows")
 _MOE_ADMIT_SORTED = obs_metrics.counter("moe.admit_rows_sorted")
-# by the mixer that holds the state (LlamaConfig.recurrent_mixer)
+# by the mixer whose layers hold the state or the tail
+# (LlamaConfig.layer_kinds)
 _STATE_RESETS = {"kda": obs_metrics.counter("kda.state_resets"),
                  "mamba": obs_metrics.counter("ssm.state_resets"),
                  "conv": obs_metrics.counter("conv.state_resets")}
+_SPEC_NGRAM = 3  # the longest n-gram a batched proposal is looked up by
+# what a cache may hold beside rows (cache_plan's keys), as a refusal says it
+_HELD = {"state": "recurrent state", "conv": "convolution's tail",
+         "ring": "ring row"}
 # The order of work at a block boundary (BatchGenerator._close_boundary):
 # host time from a block's fetch returning to the return of the step()
 # call that enqueued the device's next program, once per landed block,
@@ -381,7 +386,6 @@ class BatchGenerator:
         prefix_block: int = 64,
         quant_backend: str | None = None,
         spec_k: int = 0,
-        spec_ngram: int = 3,
         spec_rounds: int = 8,
         logprobs: int = 0,
         kv_layout: str = "slot",
@@ -441,42 +445,31 @@ class BatchGenerator:
             raise ValueError(
                 f"kv_layout must be 'slot' or 'paged', got {kv_layout!r}")
         self._paged = kv_layout == "paged"
-        if self._paged and config.latent:
+        # One rule a mechanism, asked of what the cache holds
+        # (``cache_plan``): the page pool holds per-head key and value rows
+        # of every layer and nothing else; a rejected proposal cannot undo
+        # a state, a tail or a ring.
+        held = [_HELD.get(k, k)
+                for k in sorted(set(config.cache_plan) - {"rows"})]
+        per_head = config.cache_row == (
+            config.num_key_value_heads, config.head_dim, config.head_dim)
+        if self._paged and (held or not per_head):
+            lacks = held + ([] if per_head else ["latent row"])
             raise ValueError(
-                "kv_layout='paged' is not wired for latent attention (the "
-                "page pool, and with it the disagg snapshot and the spill "
-                "tier, hold per-head keys and values, and no recurrent "
-                "state); serve this family with the slot layout")
-        if self._paged and config.state_space:
+                "kv_layout='paged' is not wired for this model: the page "
+                "pool, and with it the disagg snapshot and the spill tier, "
+                "hold per-head keys and values of every layer at every "
+                f"position, and no {', no '.join(lacks)}, which its cache "
+                "holds; serve this family with the slot layout")
+        if spec_k and held:
             raise ValueError(
-                "kv_layout='paged' is not wired for a state-space model "
-                "(the page pool, and with it the disagg snapshot and the "
-                "spill tier, hold per-head keys and values of every layer, "
-                "and no recurrent state); serve this family with the slot "
-                "layout")
-        if self._paged and config.short_conv:
-            raise ValueError(
-                "kv_layout='paged' is not wired for a model of "
-                "short-convolution and attention layers (the page pool, "
-                "and with it the disagg snapshot and the spill tier, hold "
-                "per-head keys and values of every layer, and no "
-                "convolution's tail); serve this family with the slot "
-                "layout")
-        if config.recurrent and spec_k:
-            raise ValueError(
-                "speculation (spec_k) is not wired for a model whose "
-                "layers hold a recurrent state or a convolution's tail: a "
-                "rejected proposal has already advanced it, and nothing "
-                "rolls it back")
-        if config.windowed and (self._paged or spec_k):
-            raise ValueError(
-                "kv_layout='paged' and speculation (spec_k) are not wired "
-                "for a model whose window layers hold a ring of rows: the "
-                "page pool (and with it the disagg snapshot and the spill "
-                "tier) holds rows of every layer at every position and no "
-                "ring, and a rejected proposal has already overwritten a "
-                "ring row that nothing restores; serve this family with "
-                "the slot layout and no speculation")
+                "speculation (spec_k) is not wired for this model: a "
+                "rejected proposal has already advanced or overwritten a "
+                f"{' and a '.join(held)}, which its cache holds and nothing "
+                "restores; serve this family with no speculation")
+        # what _count_kv_blocks counts through: beside a ring, a full layer
+        self._kv_window = (None if "ring" in config.cache_plan
+                           else config.sliding_window)
         self._page_size = int(kv_page_size)
         self._pool_pages_req = kv_pool_pages
         if self._paged:
@@ -738,8 +731,7 @@ class BatchGenerator:
         # hits SHARE physical pages via refcounts instead of copying a
         # staged row, and eviction is pool-pressure-driven.
         self._prefix_entries = max(0, prefix_cache_entries)
-        if (config.recurrent or config.windowed) and (
-                self._prefix_entries or prefix_share_min):
+        if held and (self._prefix_entries or prefix_share_min):
             # a stored row's recurrent state (or window layers' ring) is
             # the one at the END of the prompt that left it, not at the
             # shared prefix's end: a hit would start from the wrong state
@@ -762,7 +754,6 @@ class BatchGenerator:
         # no proposal still advances exactly one token (-1 pads never
         # match), so the batched verify subsumes a plain decode step.
         self._spec_k = max(0, int(spec_k))
-        self._spec_ngram = int(spec_ngram)
         self._spec_bank: list[list[int]] = []
         self._n_spec_dispatches = 0
         self._n_spec_chains = 0
@@ -2435,10 +2426,11 @@ class BatchGenerator:
                 cache = jax.tree.map(lambda x: x.copy(), row)
         else:
             cache = self._staging_cache(len(rows))
-            if self.config.recurrent:
-                # the zeroed row IS the reset: the splice copies its
-                # state and convolution tail over the slot's
-                _STATE_RESETS[self.config.recurrent_mixer].inc(len(members))
+            # the zeroed row IS the reset: the splice copies its state
+            # and convolution tail over the slot's
+            for mixer in set(_STATE_RESETS).intersection(
+                    m for m, _ in self.config.layer_kinds):
+                _STATE_RESETS[mixer].inc(len(members))
         self._staging = {
             # the arrivals this launch admits, in FIFO order (finish()
             # takes a cancelled one out), and the program's rows
@@ -3065,7 +3057,7 @@ class BatchGenerator:
             for i in live:
                 s = self.streams[i]
                 pr = ngram_propose(s.prompt + s.generated,
-                                   self._spec_ngram, k)
+                                   _SPEC_NGRAM, k)
                 props[i, : len(pr)] = pr
         if self.settings.greedy and (props < 0).all():
             return None
@@ -3149,7 +3141,7 @@ class BatchGenerator:
                 return props, fed
 
             self.__spec_propose = jax.jit(partial(
-                propose, n_max=self._spec_ngram, k=self._spec_k))
+                propose, n_max=_SPEC_NGRAM, k=self._spec_k))
         return self.__spec_propose
 
     @property
@@ -3563,10 +3555,8 @@ class BatchGenerator:
         dispatched) read of a layer's cache, in the decode kernel's
         blocks, and what is reserved (``attn.kv_blocks_*``). Where window
         and full layers are mixed, a full layer's: a ring is read whole."""
-        window = (None if self.config.windowed
-                  else self.config.sliding_window)
         read, reserved = pk.decode_blocks_read(
-            pos, steps, self.max_seq, window=window)
+            pos, steps, self.max_seq, window=self._kv_window)
         _KV_BLOCKS_READ.inc(read)
         _KV_BLOCKS_RESERVED.inc(reserved)
 

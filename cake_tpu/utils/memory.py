@@ -62,17 +62,9 @@ def hbm_budget(
         lin_el, scale_el = el, 0
     S = max_seq or c.max_seq_len
     d = c.head_dim
-    if c.state_space and quant:
-        raise ValueError("quantized linears are not wired for a state-space "
-                         "model (its mixer's projections have no int8 form "
-                         "yet)")
-    if c.windowed and quant:
-        raise ValueError("quantized linears are not wired for a model of "
-                         "window and full attention layers")
-    if c.short_conv and quant:
-        raise ValueError("quantized linears are not wired for a model of "
-                         "short-convolution and attention layers (its "
-                         "mixer's projections have no int8 form yet)")
+    if quant and not c.family.linear_tiers:
+        raise ValueError(f"quantized linears are not wired for "
+                         f"{c.family.what} ({c.family.linear_why})")
     if c.segmented:
         return _latent_budget(c, ep, S, batch, lin_el, scale_el, el,
                               cache_bytes_per_el)

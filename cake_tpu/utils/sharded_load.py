@@ -605,27 +605,15 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
     from cake_tpu.ops.quant import (LATENT_LINEARS, QuantizedLinear,
                                     parse_quant_spec, quantize_linear_np,
                                     reject_int4_moe)
-    from cake_tpu.utils.weights import (check_prequantized, final_norm_name,
-                                        latent_stack_plan)
+    from cake_tpu.utils.weights import check_prequantized, latent_stack_plan
 
     tier, _ = parse_quant_spec(quantize)
     if tier == "int4":
         reject_int4_moe()
-    if tier is not None and config.state_space:
+    if tier is not None and tier not in config.family.linear_tiers:
         raise NotImplementedError(
-            "quantized linears are not wired for a state-space model (its "
-            "mixer's projections have no int8 form yet); serve it in bf16")
-    if tier is not None and config.windowed:
-        raise NotImplementedError(
-            "quantized linears are not wired for a model of window and "
-            "full attention layers (its q, k and v projections are not "
-            "among the shared-expert family's int8 linears); serve it in "
-            "bf16")
-    if tier is not None and config.short_conv:
-        raise NotImplementedError(
-            "quantized linears are not wired for a model of "
-            "short-convolution and attention layers (its mixer's "
-            "projections have no int8 form yet); serve it in bf16")
+            f"quantized linears are not wired for {config.family.what} "
+            f"({config.family.linear_why}); serve it in bf16")
     prequantized = check_prequantized(reader.name_to_file, quantize)
     if not tie_word_embeddings and detect_tied_head(
             reader.name_to_file, model_dir, "cake_tpu.sharded_load"):
@@ -712,7 +700,7 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
         "layers": layers,
         "embed": stacked(lambda: "model.embed_tokens.weight", (), (v, h),
                          P(), False, False),
-        "norm_f": stacked(lambda: final_norm_name(config), (), (h,), P(),
+        "norm_f": stacked(lambda: config.family.final_norm, (), (h,), P(),
                           False, False),
         "lm_head": stacked(lambda: head_name, (), (h, v), P(), True,
                            tier is not None),

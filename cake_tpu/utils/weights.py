@@ -25,182 +25,9 @@ from typing import Callable, Iterable
 import jax.numpy as jnp
 import numpy as np
 
-# our stacked name -> (HF suffix, transpose?)
-_LAYER_MAP = {
-    "attn_norm": ("input_layernorm.weight", False),
-    "wq": ("self_attn.q_proj.weight", True),
-    "wk": ("self_attn.k_proj.weight", True),
-    "wv": ("self_attn.v_proj.weight", True),
-    "wo": ("self_attn.o_proj.weight", True),
-    "mlp_norm": ("post_attention_layernorm.weight", False),
-    "w_gate": ("mlp.gate_proj.weight", True),
-    "w_up": ("mlp.up_proj.weight", True),
-    "w_down": ("mlp.down_proj.weight", True),
-}
-
-# q/k/v projection biases (Qwen2 family; HF llama-arch `attention_bias`)
-_BIAS_MAP = {
-    "bq": ("self_attn.q_proj.bias", False),
-    "bk": ("self_attn.k_proj.bias", False),
-    "bv": ("self_attn.v_proj.bias", False),
-}
-# o_proj bias: HF llama-arch `attention_bias: true` biases o_proj too
-# (Qwen2 does not) — tracked separately so each checkpoint loads exactly
-# the tensors it stores.
-_O_BIAS = ("bo", ("self_attn.o_proj.bias", False))
-
-# Mixtral MoE naming: w1 = gate proj, w3 = up proj, w2 = down proj; the
-# router is `block_sparse_moe.gate`. Expert tensors are stacked over a new
-# leading E axis per layer ([L, E, in, out] in the pytree).
-_MOE_EXPERT_MAP = {
-    "w_gate": "block_sparse_moe.experts.{e}.w1.weight",
-    "w_up": "block_sparse_moe.experts.{e}.w3.weight",
-    "w_down": "block_sparse_moe.experts.{e}.w2.weight",
-}
-_MOE_ROUTER = "block_sparse_moe.gate.weight"
-
-
-# The latent-attention, shared-expert family (DeepSeek-V3's tensor names,
-# which `model_type` "axk1" is assumed to share): every layer's attention,
-# then either a dense MLP (the leading `first_k_dense_replace` layers) or a
-# router, the shared experts and the routed experts BY THEIR GLOBAL IDS
-# (a cut checkpoint holds a slice of them: models/config.py first_expert).
-_LATENT_MAP = {
-    "attn_norm": ("input_layernorm.weight", False),
-    "wq_a": ("self_attn.q_a_proj.weight", True),
-    "q_norm": ("self_attn.q_a_layernorm.weight", False),
-    "wq_b": ("self_attn.q_b_proj.weight", True),
-    "wkv_a": ("self_attn.kv_a_proj_with_mqa.weight", True),
-    "kv_norm": ("self_attn.kv_a_layernorm.weight", False),
-    "wkv_b": ("self_attn.kv_b_proj.weight", True),
-    "wo": ("self_attn.o_proj.weight", True),
-    "mlp_norm": ("post_attention_layernorm.weight", False),
-}
-_LATENT_DENSE_MAP = {k: _LAYER_MAP[k] for k in ("w_gate", "w_up", "w_down")}
-_LATENT_MOE_MAP = {
-    "router": ("mlp.gate.weight", True),
-    "ws_gate": ("mlp.shared_experts.gate_proj.weight", True),
-    "ws_up": ("mlp.shared_experts.up_proj.weight", True),
-    "ws_down": ("mlp.shared_experts.down_proj.weight", True),
-}
-_LATENT_EXPERT_MAP = {
-    "w_gate": "mlp.experts.{e}.gate_proj.weight",
-    "w_up": "mlp.experts.{e}.up_proj.weight",
-    "w_down": "mlp.experts.{e}.down_proj.weight",
-}
-
-# Delta-rule layers beside latent ones (`model_type` "bailing_hybrid"; the
-# names are ASSUMED, the benchmark configuration lists them: FLA's KDA
-# module under `self_attn.`, the convolutions as torch depthwise `[C, 1,
-# K]`, DeepSeek-V3's names for what the two families share) and what the
-# family adds to a latent layer and to the router.
-_KDA_MAP = {
-    "attn_norm": ("input_layernorm.weight", False),
-    "kda_q": ("self_attn.q_proj.weight", True),
-    "kda_k": ("self_attn.k_proj.weight", True),
-    "kda_v": ("self_attn.v_proj.weight", True),
-    "conv_q": ("self_attn.q_conv1d.weight", True),
-    "conv_k": ("self_attn.k_conv1d.weight", True),
-    "conv_v": ("self_attn.v_conv1d.weight", True),
-    "w_decay": ("self_attn.f_proj.weight", True),
-    "a_log": ("self_attn.A_log", False),
-    "dt_bias": ("self_attn.dt_bias", False),
-    "w_beta": ("self_attn.b_proj.weight", True),
-    "wg": ("self_attn.g_proj.weight", True),
-    "o_norm": ("self_attn.o_norm.weight", False),
-    "wo": ("self_attn.o_proj.weight", True),
-    "mlp_norm": ("post_attention_layernorm.weight", False),
-}
-_HYBRID_EXTRA_MAP = {
-    "wq": ("self_attn.q_proj.weight", True),
-    "wg": ("self_attn.g_proj.weight", True),
-    "b_router": ("mlp.gate.expert_bias", False),
-}
-
-# Window and full grouped-query attention mixed by layer, with the
-# shared-expert feed-forward (`model_type` "exaone_moe"; the names are
-# ASSUMED, the benchmark configuration lists them: Llama's for the
-# attention with a `q_norm` / `k_norm` weight a head width wide,
-# DeepSeek-V3's for the experts and for the router's bias). The next-token
-# prediction block (`mtp.*`) is never asked for.
-_WINDOWED_MAP = {
-    **{k: _LAYER_MAP[k] for k in ("attn_norm", "wq", "wk", "wv", "wo",
-                                  "mlp_norm")},
-    "q_norm": ("self_attn.q_norm.weight", False),
-    "k_norm": ("self_attn.k_norm.weight", False),
-    **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP,
-    "b_router": ("mlp.gate.e_score_correction_bias", False),
-}
-
-# State-space layers beside grouped-query attention (`model_type` "jamba",
-# Hugging Face's own names, which config.json does not carry: the benchmark
-# configuration lists them): the mixer under `mamba.`, its convolution as
-# torch depthwise `[C, 1, K]` WITH a bias, `A_log` as `[d_inner, d_state]`
-# (ours is laid out as the state is, `[d_state, d_inner]`), the feed-forward
-# under `feed_forward.`, its norm `pre_ff_layernorm`, the model's last norm
-# `model.final_layernorm`.
-_MAMBA_MAP = {
-    "attn_norm": ("input_layernorm.weight", False),
-    "w_in": ("mamba.in_proj.weight", True),
-    "conv_w": ("mamba.conv1d.weight", True),
-    "conv_b": ("mamba.conv1d.bias", False),
-    "w_x": ("mamba.x_proj.weight", True),
-    "dt_norm": ("mamba.dt_layernorm.weight", False),
-    "b_norm": ("mamba.b_layernorm.weight", False),
-    "c_norm": ("mamba.c_layernorm.weight", False),
-    "w_dt": ("mamba.dt_proj.weight", True),
-    "dt_bias": ("mamba.dt_proj.bias", False),
-    "a_log": ("mamba.A_log", True),
-    "d_skip": ("mamba.D", False),
-    "w_out": ("mamba.out_proj.weight", True),
-}
-_STATE_SPACE_MAP = {
-    **{k: _LAYER_MAP[k] for k in ("attn_norm", "wq", "wk", "wv", "wo")},
-    "mlp_norm": ("pre_ff_layernorm.weight", False),
-    "w_gate": ("feed_forward.gate_proj.weight", True),
-    "w_up": ("feed_forward.up_proj.weight", True),
-    "w_down": ("feed_forward.down_proj.weight", True),
-}
-
-
-# Gated short convolutions beside grouped-query attention, every expert
-# held (`model_type` "lfm2_moe"; the names are ASSUMED, the benchmark
-# configuration lists them: Hugging Face's Lfm2Moe modules): a layer's
-# norms `operator_norm` and `ffn_norm`, the mixer under `conv.` with its
-# taps as torch depthwise `[C, 1, K]`, the attention's output projection
-# `out_proj` and head norms `q_layernorm` / `k_layernorm`, the feed-forward
-# under `feed_forward.` in Mixtral's w1 (gate) / w3 (up) / w2 (down), the
-# router `feed_forward.gate` with its `expert_bias`, the model's last norm
-# `model.embedding_norm`.
-_SHORT_CONV_MAP = {
-    "attn_norm": ("operator_norm.weight", False),
-    "w_in": ("conv.in_proj.weight", True),
-    "conv_w": ("conv.conv.weight", True),
-    "w_out": ("conv.out_proj.weight", True),
-    **{k: _LAYER_MAP[k] for k in ("wq", "wk", "wv")},
-    "wo": ("self_attn.out_proj.weight", True),
-    "q_norm": ("self_attn.q_layernorm.weight", False),
-    "k_norm": ("self_attn.k_layernorm.weight", False),
-    "mlp_norm": ("ffn_norm.weight", False),
-    "w_gate": ("feed_forward.w1.weight", True),
-    "w_up": ("feed_forward.w3.weight", True),
-    "w_down": ("feed_forward.w2.weight", True),
-    "router": ("feed_forward.gate.weight", True),
-    "b_router": ("feed_forward.expert_bias", False),
-}
-_SHORT_CONV_EXPERT_MAP = {
-    "w_gate": "feed_forward.experts.{e}.w1.weight",
-    "w_up": "feed_forward.experts.{e}.w3.weight",
-    "w_down": "feed_forward.experts.{e}.w2.weight",
-}
-
-
-def final_norm_name(config) -> str:
-    """The stored name of the model's last norm."""
-    if config.short_conv:
-        return "model.embedding_norm.weight"
-    return ("model.final_layernorm.weight" if config.state_space
-            else "model.norm.weight")
+# tensor names live with each family's record; the bare stack's re-exported
+from cake_tpu.models.families import (  # noqa: F401
+    _BIAS_MAP, _LAYER_MAP, _MOE_EXPERT_MAP, _MOE_ROUTER, _O_BIAS, FAMILIES)
 
 
 def latent_stack_plan(config) -> dict:
@@ -214,28 +41,15 @@ def latent_stack_plan(config) -> dict:
     prediction block) are never asked for."""
     from cake_tpu.models.llama import plan_segments, segment_shapes
 
-    names = {**_LATENT_MAP, **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP,
-             **_HYBRID_EXTRA_MAP}
-    expert_names = _LATENT_EXPERT_MAP
-    if config.state_space:
-        names = _STATE_SPACE_MAP
-    elif config.windowed:
-        names = _WINDOWED_MAP
-    elif config.short_conv:
-        names, expert_names = _SHORT_CONV_MAP, _SHORT_CONV_EXPERT_MAP
+    table, experts = config.family.tensor_names, config.family.expert_names
     plan = {}
     for run, seg in plan_segments(config):
         shapes = segment_shapes(config, seg)
-        table = names
-        if seg.mixer == "kda":
-            table = {**names, **_KDA_MAP}
-        elif seg.mixer == "mamba":
-            table = {**names, **_MAMBA_MAP}
         plan[seg.name] = (
             run.layer_ids(seg),
             {k: table[k] for k in shapes if k in table and (
-                seg.ffn == "dense" or k not in expert_names)},
-            expert_names if seg.ffn == "moe" else {})
+                seg.ffn == "dense" or k not in experts)},
+            experts if seg.ffn == "moe" else {})
     return plan
 
 
@@ -245,18 +59,6 @@ def hf_layout(ours: str, w: np.ndarray, transpose: bool) -> np.ndarray:
     if ours.startswith("conv_") and w.ndim == 2:
         return np.ascontiguousarray(w.T[:, None, :])
     return w.T if transpose else w
-
-
-def is_latent_checkpoint(name_to_file: dict) -> bool:
-    """Whether the checkpoint stores latent-attention, state-space or
-    short-convolution tensors, or a routing bias beside per-head attention
-    (window and full layers mixed): a model of several layer stacks, which
-    loads whole."""
-    return any(".self_attn.kv_a_proj_with_mqa.weight" in n
-               or ".mamba.in_proj.weight" in n
-               or ".conv.in_proj.weight" in n
-               or ".mlp.gate.e_score_correction_bias" in n
-               for n in name_to_file)
 
 
 def hf_layer_map(num_experts: int = 0, attention_bias: bool = False,
@@ -568,9 +370,11 @@ def load_llama_params(
     from safetensors import safe_open
 
     name_to_file = load_safetensors_index(model_dir)
-    if is_latent_checkpoint(name_to_file):
-        # the latent family has one loader (two layer stacks, experts by
-        # global id): the direct-to-mesh one, on a mesh of one device
+    probes = [f.probe for f in FAMILIES if f.probe]
+    if any(p in n for n in name_to_file for p in probes):
+        # a family of several layer stacks (its checkpoint stores a tensor
+        # only it has) has one loader, experts by global id: the
+        # direct-to-mesh one, on a mesh of one device
         if layer_range is not None or not (include_embed and include_head):
             raise NotImplementedError(
                 "a latent-attention checkpoint loads whole (no layer "
@@ -634,7 +438,7 @@ def latent_hf_tensors(params: dict, config) -> dict[str, np.ndarray]:
     read. A tied head is not stored."""
     tensors = {
         "model.embed_tokens.weight": np.asarray(params["embed"]),
-        final_norm_name(config): np.asarray(params["norm_f"]),
+        config.family.final_norm: np.asarray(params["norm_f"]),
     }
     if not config.tie_word_embeddings:
         tensors["lm_head.weight"] = np.asarray(params["lm_head"]).T

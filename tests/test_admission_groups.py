@@ -175,7 +175,7 @@ def test_each_member_of_a_launch_equals_its_admission_alone(family, name, n):
                         jax.tree.leaves((c.k, c.v))]
         for a, b in zip(kv(have["cache"]), kv(want["cache"])):
             np.testing.assert_allclose(a, b, atol=TIGHT, rtol=TIGHT)
-        if cfg.recurrent:
+        if "state" in cfg.cache_plan:
             # a short member's state and convolution tail: the bucket's
             # padding (its own 16 or 32 against the launch's 64) and the
             # repeated row have touched nothing
@@ -184,7 +184,7 @@ def test_each_member_of_a_launch_equals_its_admission_alone(family, name, n):
                 b = getattr(want["cache"], leaf)
                 assert np.abs(b).max() > 0
                 np.testing.assert_allclose(a, b, atol=TIGHT, rtol=TIGHT)
-        if cfg.windowed:
+        if "ring" in cfg.cache_plan:
             # a member's rings: its own newest rows and nothing of the
             # bucket's padding or of the repeated row
             for leaf in ("ring_k", "ring_v"):

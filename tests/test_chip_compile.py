@@ -1135,14 +1135,33 @@ PR31_TEXTS = {
     # step is 0.4% faster with the products fused: PERF.md section 6)
     "state_space.decode": "1db9244f72aa04e8",
     "state_space.admit": "b095bb3adf962eef",
+    # the window and short-convolution families, taken on PR 45's tree
+    # (commit 545f5a9), before PR 46 moved what a family is into one record
+    "windowed.decode": "5fd81e6a4dfe8dc0",
+    "windowed.admit": "750c179d4d7e8076",
+    "short_conv.decode": "ffc2e337af0e44aa",
+    "short_conv.admit": "d9e071c8a3327ea1",
 }
 
 
-def test_existing_families_lower_to_the_text_they_had():
-    """The dense, sparse, latent, hybrid and state-space families' block
-    decode and admission programs lower (StableHLO, CPU, tiny widths) to
-    the text PR 31's tree (PR 32's for the hybrid, PR 40's for the
-    state-space family) gave them, so the chip's
+def _family_fixtures():
+    from cake_tpu.models.config import (tiny, tiny_exaone_moe, tiny_jamba,
+                                        tiny_kda_hybrid, tiny_lfm2_moe,
+                                        tiny_mla_moe, tiny_moe)
+
+    return {"dense": lambda: tiny(sliding_window=32), "sparse": tiny_moe,
+            "latent": tiny_mla_moe, "hybrid": tiny_kda_hybrid,
+            "state_space": tiny_jamba, "windowed": tiny_exaone_moe,
+            "short_conv": tiny_lfm2_moe}
+
+
+@pytest.mark.parametrize("name", ["dense", "sparse", "latent", "hybrid",
+                                  "state_space", "windowed", "short_conv"])
+def test_existing_families_lower_to_the_text_they_had(name):
+    """Each family's block decode and admission programs lower (StableHLO,
+    CPU, tiny widths) to the text PR 31's tree (PR 32's for the hybrid,
+    PR 40's for the state-space family, PR 45's for the window and
+    short-convolution families) gave them, so the chip's
     compiler sees what it saw and the cells it measured stay where they
     are: without kernels (the CPU's default) every call of the expert
     block takes the form it took before there was a sorted one (PR 33,
@@ -1151,8 +1170,6 @@ def test_existing_families_lower_to_the_text_they_had():
     says so."""
     import hashlib
 
-    from cake_tpu.models.config import (tiny, tiny_jamba, tiny_kda_hybrid,
-                                        tiny_mla_moe, tiny_moe)
     from cake_tpu.models.llama import init_params
     from cake_tpu.ops.kvcache import init_cache
     from cake_tpu.ops.sampling import SamplerSettings
@@ -1160,32 +1177,29 @@ def test_existing_families_lower_to_the_text_they_had():
     from cake_tpu.parallel.pipeline import (build_admit_prefill,
                                             build_sharded_decode)
 
-    got = {}
     settings = SamplerSettings(temperature=0.0)
-    for name, config in (("dense", tiny(sliding_window=32)),
-                         ("sparse", tiny_moe()), ("latent", tiny_mla_moe()),
-                         ("hybrid", tiny_kda_hybrid()),
-                         ("state_space", tiny_jamba())):
-        plan = MeshPlan.build(config, devices=jax.devices()[:1])
-        params = jax.eval_shape(lambda k: init_params(config, k),
-                                jax.random.PRNGKey(0))
+    config = _family_fixtures()[name]()
+    plan = MeshPlan.build(config, devices=jax.devices()[:1])
+    params = jax.eval_shape(lambda k: init_params(config, k),
+                            jax.random.PRNGKey(0))
 
-        def i32(*shape):
-            return jax.ShapeDtypeStruct(shape, I32)
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32)
 
-        def cache(b):
-            return jax.eval_shape(
-                lambda: init_cache(config, batch=b, max_seq=64))
+    def cache(b):
+        return jax.eval_shape(
+            lambda: init_cache(config, batch=b, max_seq=64))
 
-        decode = build_sharded_decode(
-            config, settings, plan, params_like=params, steps=4,
-            per_row=True).lower(
-            params, i32(4), cache(4), i32(4),
-            jax.ShapeDtypeStruct((4, 2), jnp.uint32),
-            i32(4, settings.repeat_last_n), i32(4), i32(4))
-        admit = build_admit_prefill(config, plan, params_like=params).lower(
-            params, i32(1, 16), cache(1), i32(), i32(1))
-        for kind, lowered in (("decode", decode), ("admit", admit)):
-            got[f"{name}.{kind}"] = hashlib.sha256(
-                lowered.as_text().encode()).hexdigest()[:16]
-    assert got == PR31_TEXTS
+    decode = build_sharded_decode(
+        config, settings, plan, params_like=params, steps=4,
+        per_row=True).lower(
+        params, i32(4), cache(4), i32(4),
+        jax.ShapeDtypeStruct((4, 2), jnp.uint32),
+        i32(4, settings.repeat_last_n), i32(4), i32(4))
+    admit = build_admit_prefill(config, plan, params_like=params).lower(
+        params, i32(1, 16), cache(1), i32(), i32(1))
+    got = {f"{name}.{kind}": hashlib.sha256(
+        lowered.as_text().encode()).hexdigest()[:16]
+        for kind, lowered in (("decode", decode), ("admit", admit))}
+    assert got == {k: v for k, v in PR31_TEXTS.items()
+                   if k.startswith(name + ".")}

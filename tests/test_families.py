@@ -554,3 +554,114 @@ def test_mistral_int8_kv_window_composition():
     assert wide == unwindowed  # window >= history: exact degeneration
     narrow = stream(dataclasses.replace(base, sliding_window=4))
     assert narrow != unwindowed  # the mask genuinely applies
+
+
+# -- a family is declared once (models/families.py) ----------------------------
+
+FAMILY_PREDICATES = {"latent", "state_space", "windowed", "short_conv",
+                     "recurrent", "recurrent_mixer"}
+# The only reads of ``.segmented`` outside cake_tpu/models/: the three
+# places that branch on the LAYOUT of ``params["layers"]`` (one bare stack
+# or a dict of stacks), which is ROADMAP D4's second half.
+SEGMENTED_SITES = {
+    "kvpool/pool.py": "the page pool allocates rows for every layer of one "
+                      "bare stack and refuses a dict of stacks",
+    "utils/sharded_load.py": "a dict of stacks has its own loader",
+    "utils/memory.py": "a dict of stacks has its own budget arithmetic",
+}
+
+
+def test_no_module_outside_models_asks_which_family_this_is():
+    """Outside ``cake_tpu/models/`` nothing reads a family predicate of
+    ``LlamaConfig``: what is wired is asked of ``config.family``
+    (``models/families.py``), what a cache holds of ``config.cache_plan``.
+    ``.segmented`` is read at its three layout sites and nowhere else."""
+    import ast
+    from pathlib import Path
+
+    root = Path(llama.__file__).resolve().parent.parent
+    found, segmented = [], set()
+    for path in sorted(root.rglob("*.py")):
+        rel = path.relative_to(root).as_posix()
+        if rel.startswith("models/"):
+            continue
+        for node in ast.walk(ast.parse(path.read_text())):
+            if not (isinstance(node, ast.Attribute)
+                    and isinstance(node.ctx, ast.Load)):
+                continue
+            if node.attr in FAMILY_PREDICATES:
+                found.append(f"{rel}:{node.lineno} .{node.attr}")
+            elif node.attr == "segmented":
+                segmented.add(rel)
+    assert found == []
+    assert segmented == set(SEGMENTED_SITES)
+
+
+def _family_fixture(name):
+    from cake_tpu.models import config
+
+    return {"dense": tiny, "sparse": tiny_moe, "latent": config.tiny_mla_moe,
+            "hybrid": config.tiny_kda_hybrid, "state_space": config.tiny_jamba,
+            "windowed": config.tiny_exaone_moe,
+            "short_conv": config.tiny_lfm2_moe}[name]()
+
+
+# What each family is refused and accepted at PR 45's tree (commit
+# 545f5a9), written out: the mesh axes ``validate_shardable`` refuses at
+# size 2 (the dense decoder's ``ep`` by the rule that ep needs experts),
+# whether ``hbm_budget`` prices quantized linears, whether ``init_cache``
+# allocates an int8 cache, and what the loader does with ``--quantize``
+# (int4 beside experts is ``quant.reject_int4_moe``'s, whatever the family).
+WIRED_AT_PR45 = {
+    "dense": (("ep",), True, True, {"int8": True, "int4": True}),
+    "sparse": ((), True, True, {"int8": True, "int4": False}),
+    "latent": (("stages", "tp", "sp"), True, False,
+               {"int8": True, "int4": False}),
+    "hybrid": (("stages", "tp", "sp"), True, False,
+               {"int8": True, "int4": False}),
+    "state_space": (("stages", "tp", "sp", "ep"), False, False,
+                    {"int8": False, "int4": False}),
+    "windowed": (("stages", "tp", "sp"), False, False,
+                 {"int8": False, "int4": False}),
+    "short_conv": (("stages", "tp", "sp", "ep"), False, False,
+                   {"int8": False, "int4": False}),
+}
+
+
+@pytest.mark.parametrize("name", list(WIRED_AT_PR45))
+def test_a_family_is_refused_and_accepted_what_it_was(name, tmp_path):
+    """The mesh, the budget, the cache and the loader refuse and accept
+    for each family exactly what they did before its record declared it,
+    with the exception types they had."""
+    import json
+
+    from cake_tpu.parallel.mesh import make_mesh, validate_shardable
+    from cake_tpu.utils.memory import hbm_budget
+    from cake_tpu.utils.sharded_load import load_llama_params_on_mesh
+
+    cfg = _family_fixture(name)
+    no_axes, budget, cache, tiers = WIRED_AT_PR45[name]
+
+    def accepted(call, error):
+        try:
+            call()
+        except error:
+            return False
+        return True
+
+    sizes = {"stages": (2, 1, 1, 1), "tp": (1, 2, 1, 1),
+             "sp": (1, 1, 2, 1), "ep": (1, 1, 1, 2)}
+    assert tuple(a for a, s in sizes.items() if not accepted(
+        lambda: validate_shardable(cfg, *s), ValueError)) == no_axes
+    for quant in ("int8", "int4"):
+        assert accepted(lambda: hbm_budget(cfg, quant=quant),
+                        ValueError) == budget
+    assert accepted(lambda: init_cache(cfg, batch=1, max_seq=32,
+                                       quant="int8"), ValueError) == cache
+    save_llama_params(llama.init_params(cfg, jax.random.PRNGKey(0)),
+                      tmp_path, cfg.num_hidden_layers, config=cfg)
+    (tmp_path / "config.json").write_text(json.dumps(cfg.to_hf_dict()))
+    for quant, loads in tiers.items():
+        assert accepted(lambda: load_llama_params_on_mesh(
+            tmp_path, cfg, make_mesh(), quantize=quant),
+            NotImplementedError) == loads

@@ -664,7 +664,7 @@ def test_swiglu_limit_past_the_served_depth_is_not_refused():
     cfg = LlamaConfig.from_hf_dict(_hf(expert_swiglu_limit_list=limits,
                                        share_expert_swiglu_limit_list=limits),
                                    dtype="float32")
-    assert cfg.num_hidden_layers == 4 and cfg.recurrent
+    assert cfg.num_hidden_layers == 4 and "state" in cfg.cache_plan
 
 
 # -- the decode kernel -----------------------------------------------------------

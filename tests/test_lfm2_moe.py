@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cake_tpu.models import llama
+from cake_tpu.models import families, llama
 from cake_tpu.models.config import LlamaConfig, lfm2_8b_a1b, tiny_lfm2_moe
 from cake_tpu.obs import metrics
 from cake_tpu.ops.kvcache import init_cache
@@ -388,8 +388,8 @@ def test_the_catalogs_keys_are_read_and_round_trip():
     whole = LlamaConfig.from_hf_dict(published, max_seq_len=128000,
                                      bos_token_id=1, eos_token_id=7)
     assert whole == lfm2_8b_a1b()
-    assert (whole.short_conv, whole.windowed, whole.recurrent,
-            whole.recurrent_mixer) == (True, False, True, "conv")
+    assert whole.family is families.SHORT_CONV
+    assert whole.family.recurrent_mixer == "conv"
     assert whole.layer_kinds[:4] == (
         ("conv", "dense"), ("conv", "dense"), ("gqa", "moe"),
         ("conv", "moe"))
@@ -399,7 +399,7 @@ def test_the_catalogs_keys_are_read_and_round_trip():
     assert (whole.head_dim, whole.rope_dim, whole.qk_norm, whole.router_bias,
             whole.n_routed_experts, whole.router_experts,
             whole.n_shared_experts, whole.first_k_dense_replace,
-            whole.tie_word_embeddings, whole.topk_norm_eps,
+            whole.tie_word_embeddings, whole.family.topk_norm_eps,
             whole.rms_norm_eps) == (
         64, 64, True, True, 32, 32, 0, 2, True, 1e-6, 1e-5)
     back = whole.to_hf_dict()

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cake_tpu.models import llama
+from cake_tpu.models import families, llama
 from cake_tpu.models.config import LlamaConfig, jamba2_3b, tiny_jamba
 from cake_tpu.obs import metrics
 from cake_tpu.ops import mamba
@@ -472,7 +472,7 @@ def test_preset_holds_the_catalogs_widths_and_round_trips():
                                                         True)
     assert (cfg.attn_layer_period, cfg.attn_layer_offset) == (14, 7)
     assert cfg.tie_word_embeddings and cfg.rope_dim == 0
-    assert cfg.recurrent and cfg.segmented and not cfg.latent
+    assert cfg.family is families.STATE_SPACE and cfg.segmented
     assert cfg.cache_row == (1, 128, 128)
     assert cfg.cache_plan == {"rows": (2, 1, 128, 128),
                               "state": (26, 16, 5120),
