@@ -100,12 +100,12 @@ def init_pool_on_mesh(config: LlamaConfig, mesh, num_pages: int,
         raise ValueError(
             "the page pool holds per-head keys and values of every layer; "
             "a model of several layer stacks is served with the slot layout")
-    key = ("init", mesh, config.num_hidden_layers,
+    L = config.cache_plan["rows"][0]  # the cache's depth is the plan's
+    key = ("init", mesh, L,
            config.num_key_value_heads, config.head_dim, str(config.dtype),
            num_pages, page_size, quant)
     make = _POOL_PROGRAMS.get(key)
     if make is None:
-        L = config.num_hidden_layers
         KH = config.num_key_value_heads
         D = config.head_dim
         dt = config.jax_dtype
@@ -181,7 +181,7 @@ def _builders(config: LlamaConfig, mesh, quant: str | None):
     page write instead of a batch-cache scatter), and batch_scatter
     (a whole prefilled batch cache -> per-row pages: set_prompts
     pageification)."""
-    key = ("progs", mesh, config.num_hidden_layers,
+    key = ("progs", mesh, config.cache_plan["rows"][0],
            config.num_key_value_heads, config.head_dim, str(config.dtype),
            quant)
     progs = _POOL_PROGRAMS.get(key)

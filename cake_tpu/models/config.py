@@ -7,9 +7,11 @@ TPU-native equivalent of the reference's config plane
 sequence length (the reference hard-caps MAX_SEQ_LEN=4096, config.rs:6; here it
 is a tunable because the TPU build supports long context).
 
-Families read: the dense and Mixtral-style decoders (one bare stack), and
+Families read: the dense and Mixtral-style decoders (one bare stack),
 five whose layers are of several kinds (``segmented``: a stack a stretch of
-one kind, ``models/llama.py`` ``layer_plan``). A family's config.json and
+one kind, ``models/llama.py`` ``layer_plan``), and one whose layers run
+several times a token (``total_ut_steps``: one stack, a cache plane a layer
+and a pass). A family's config.json and
 checkpoint, its checks and what is wired for it are its record in
 ``models/families.py`` (``LlamaConfig.family``); fields and presets here.
 """
@@ -182,6 +184,23 @@ class LlamaConfig:
     # ``n_routed_experts`` sigmoid-scored, bias-corrected experts.
     conv_L_cache: int = 3
     conv_bias: bool = False
+    # --- one set of layers run several times a token (HF `model_type`
+    # "ouro") ----------------------------------------------------------------
+    # ``total_ut_steps`` U > 1: ``h = E[tokens]``; U times over, the
+    # ``num_hidden_layers`` layers in order WITH THE SAME WEIGHTS, then
+    # ``h = RMS(h; model.norm)``: the last norm closes every pass, and the
+    # head reads the last pass's normed state with no further norm. A
+    # layer is sandwich-normed: the sub-layer's output goes through a
+    # second norm before the residual adds it. The keys and values a
+    # layer writes in pass ``u`` are read by that layer in pass ``u`` of
+    # later tokens alone: the cache holds a plane a (pass, layer) pair,
+    # plane ``u * num_hidden_layers + i`` (``cache_plan``).
+    # ``early_exit_threshold``: the cumulative exit probability (a
+    # sigmoid gate on each pass's output, ``params["exit_gate"]``) at
+    # which a token leaves the loop; only 1 (never early) is served, and
+    # the gate then changes no logit.
+    total_ut_steps: int = 1
+    early_exit_threshold: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -239,6 +258,13 @@ class LlamaConfig:
         return self.family is not families.GQA
 
     @property
+    def cache_token_bytes(self) -> int:
+        """Bytes the ``rows`` of ``cache_plan`` hold for one token of one
+        stream, in the serving type: every plane's keys and values."""
+        planes = self.cache_plan.get("rows", (0,))[0]
+        return planes * self.cache_row_values * self.jax_dtype.itemsize
+
+    @property
     def mamba_d_inner(self) -> int:
         """Channels of a Mamba mixer."""
         return self.mamba_expand * self.hidden_size
@@ -289,14 +315,19 @@ class LlamaConfig:
         layers alone. Short-convolution layers keep ``conv`` ``(layers,
         taps - 1, hidden)`` and NO ``state``: who asks whether a model
         holds a state asks for the key. A kind with no layer is left
-        out."""
+        out. Where the layers run ``total_ut_steps`` times a token, ``rows``
+        counts a plane a layer AND a pass (layer ``i`` in pass ``u`` is
+        plane ``u * num_hidden_layers + i``): the cache's depth is the
+        plan's, not ``num_hidden_layers``."""
         mixers = [m for m, _ in self.layer_kinds]
         recurrent = self.family.recurrent_mixer
         held = mixers.count(recurrent)
         ring = mixers.count("swa")
         plan = {}
         if len(mixers) - held - ring:
-            plan["rows"] = (len(mixers) - held - ring,) + self.cache_row
+            # a looped model keeps a plane a layer AND a pass
+            plan["rows"] = ((len(mixers) - held - ring) * self.total_ut_steps,
+                            ) + self.cache_row
         if ring:
             heads, *widths = self.cache_row
             plan["ring"] = (ring, heads, self.ring_rows, *widths)
@@ -687,6 +718,33 @@ def jamba2_3b(**overrides) -> LlamaConfig:
     return LlamaConfig(**base)
 
 
+def ouro_2_6b(**overrides) -> LlamaConfig:
+    """Ouro-2.6B (https://huggingface.co/ByteDance/Ouro-2.6B, `model_type`
+    "ouro") at its published sizes: 48 sandwich-normed layers of 16 query
+    heads over 16 key/value heads of 128 and a 5632-wide SwiGLU, run four
+    times a token over ONE set of weights with the last norm between
+    passes; an untied head; 192 cache planes (1.5 MiB a token in bf16)."""
+    base = dict(
+        model_type="ouro",
+        vocab_size=49152,
+        hidden_size=2048,
+        intermediate_size=5632,
+        num_hidden_layers=48,
+        num_attention_heads=16,
+        num_key_value_heads=16,
+        head_dim=128,
+        rms_norm_eps=1e-6,
+        rope_theta=1000000.0,
+        max_seq_len=65536,
+        total_ut_steps=4,
+        early_exit_threshold=1.0,
+        bos_token_id=1,
+        eos_token_id=2,
+    )
+    base.update(overrides)
+    return LlamaConfig(**base)
+
+
 def _repeated(pattern, layers: int) -> tuple[str, ...]:
     """``layer_types`` for ``layers`` layers from one period of the
     pattern (or from the whole list: it then is, or is cut to, them)."""
@@ -935,6 +993,24 @@ def tiny_exaone_moe(**overrides) -> LlamaConfig:
     base.update(overrides)
     base["layer_types"] = _repeated(base["layer_types"],
                                     base["num_hidden_layers"])
+    return tiny(**base)
+
+
+def tiny_ouro(**overrides) -> LlamaConfig:
+    """Tiny fixture of the looped family (Ouro's keys): three layers run
+    three times a token (nine cache planes: a count of passes that differs
+    from every other count would hide no mix-up, so both are 3 and the
+    tests tell a pass from a layer by their order), as many key/value
+    heads as query heads, an untied head."""
+    base = dict(
+        model_type="ouro",
+        num_hidden_layers=3,
+        num_key_value_heads=4,
+        total_ut_steps=3,
+        rms_norm_eps=1e-6,
+        rope_theta=1000000.0,
+    )
+    base.update(overrides)
     return tiny(**base)
 
 

@@ -8,6 +8,16 @@ records are data: nothing outside ``cake_tpu/models/`` makes or edits one,
 and no flag sets one. Every reader refuses, and does not guess, what its
 file asks for and nothing here computes; the readings this repo makes of
 a file are its benchmark configuration's ``assumed``.
+
+The records (``FAMILIES``, the first that selects a configuration wins):
+``LOOPED`` (``ouro``: ``total_ut_steps`` passes over one set of
+sandwich-normed layers, the last norm closing each pass, a cache plane a
+layer AND a pass; keys ``total_ut_steps``, ``early_exit_threshold``;
+limits: threshold 1 only, every layer full attention, no window, no rope
+scaling, slot layout, no sharding, no quantized tier), ``SHORT_CONV``
+(``lfm2_moe``), ``WINDOWED`` (``exaone_moe``), ``STATE_SPACE`` (``jamba``),
+``HYBRID`` (``bailing_hybrid``), ``LATENT`` (``deepseek_v3``, ``axk1``) and
+``GQA``, the bare stack every other ``model_type`` is read as.
 """
 
 from __future__ import annotations
@@ -49,6 +59,9 @@ class Family:
     expert_names: dict = dataclasses.field(default_factory=dict)
     final_norm: str = "model.norm.weight"
     probe: str | None = None
+    # tensors beside the layers, the embedding, the last norm and the
+    # head: ours (a key of ``params``) -> {part: (HF name, shape(config))}
+    extra_tensors: dict = dataclasses.field(default_factory=dict)
     # what is wired, and the family's sentence on why where it is not
     # (`what`: the family as a refusal names it): the mesh axes that may be
     # more than 1, the quantized tiers of its linears and of its cache
@@ -69,6 +82,10 @@ class Family:
     full_layers_rotate: bool = True
     expert_periods: bool = True
     topk_norm_eps: float = 1e-20
+    # whether the layer loop runs its plan ``total_ut_steps`` times over
+    # one set of weights with the model's last norm at the end of each
+    # pass (models/llama.py forward_layers): the head then norms nothing
+    loops: bool = False
 
 
 # --- tensor names -----------------------------------------------------------
@@ -233,6 +250,20 @@ _SHORT_CONV_EXPERT_MAP = {
     "w_gate": "feed_forward.experts.{e}.w1.weight",
     "w_up": "feed_forward.experts.{e}.w3.weight",
     "w_down": "feed_forward.experts.{e}.w2.weight",
+}
+
+# A looped decoder: one set of sandwich-normed layers run several times a
+# token (`model_type` "ouro"; the names are ASSUMED, the benchmark
+# configuration lists them: Llama's, a sub-layer's second norm under its
+# first one's name with `_2`, the exit gate a linear of one output).
+_LOOPED_MAP = {
+    **_LAYER_MAP,
+    "attn_post_norm": ("input_layernorm_2.weight", False),
+    "mlp_post_norm": ("post_attention_layernorm_2.weight", False),
+}
+_EXIT_GATE = {
+    "weight": ("model.early_exit_gate.weight", lambda c: (1, c.hidden_size)),
+    "bias": ("model.early_exit_gate.bias", lambda c: (1,)),
 }
 
 
@@ -763,10 +794,88 @@ SHORT_CONV = Family(
     counts_held_experts=True, expert_periods=False, topk_norm_eps=1e-6)
 
 
+# --- one set of layers run several times a token (Ouro's keys) --------------
+
+def _looped_read(d: dict) -> dict:
+    """`LlamaConfig` fields from an "ouro" config.json. Refused, not
+    guessed: a layer type other than ``full_attention``, a window, a rope
+    scaling, fewer than two passes, and (``_looped_check``, as for a
+    preset) an ``early_exit_threshold`` under 1: a token that leaves the
+    loop early is a step that does unequal work a row; at 1 the exit gate
+    changes no logit. ``max_window_layers`` selects nothing while
+    ``use_sliding_window`` is false."""
+    name = LOOPED.model_types[0]
+    kinds = set(_entries(name, d)) if d.get("layer_types") else set()
+    if kinds - {"full_attention"}:
+        raise ValueError(
+            f"{name}: layer_types entries {sorted(kinds - {'full_attention'})} "
+            "are not wired (every layer of the loop attends fully)")
+    _only_served(name, d, {"use_sliding_window": False,
+                           "sliding_window": None, "rope_scaling": None})
+    passes = d.get("total_ut_steps", 4)
+    if passes < 2:  # else the bare stack would take it, less its norms
+        raise ValueError(
+            f"{name}: total_ut_steps = {passes!r}: this family runs its "
+            "layers 2 or more times a token")
+    return {"total_ut_steps": passes,
+            "early_exit_threshold": float(d.get("early_exit_threshold", 1))}
+
+
+def _looped_write(c, d: dict):
+    d["layer_types"] = ["full_attention"] * c.num_hidden_layers
+    d["use_sliding_window"] = False
+    d["sliding_window"] = d["rope_scaling"] = None
+    d["max_window_layers"] = c.num_hidden_layers
+    d["hidden_act"] = "silu"
+
+
+def _looped_check(c):
+    if c.early_exit_threshold < 1:
+        raise ValueError(
+            f"early_exit_threshold = {c.early_exit_threshold} is not wired "
+            f"(only 1: every token takes all {c.total_ut_steps} passes; a "
+            "token that leaves the loop early needs a scheduler and a "
+            "block decode that follow unequal work a row)")
+    if (c.kv_lora_rank or c.attn_layer_period or c.layer_types is not None
+            or c.num_local_experts or c.n_routed_experts
+            or c.attention_bias or c.sliding_window is not None
+            or c.rope_scaling or c.tie_word_embeddings):
+        raise ValueError(
+            "total_ut_steps > 1 (one set of layers run several times a "
+            "token) is wired with full grouped-query attention and a "
+            "dense feed-forward only: no latent keys, no state-space "
+            "layers, no layer_types, no experts, no projection bias, no "
+            "sliding_window, no rope_scaling, no tied head")
+
+
+_A_PLANE_A_PASS = (
+    "a cache plane a layer AND a pass (the page pool, the snapshot and "
+    "the sharded programs count a plane a layer)")
+
+LOOPED = Family(
+    model_types=("ouro",),
+    selects=lambda c: c.total_ut_steps > 1,
+    fields=("total_ut_steps", "early_exit_threshold"),
+    read=_looped_read, write=_looped_write, check=_looped_check,
+    tensor_names=_LOOPED_MAP, extra_tensors={"exit_gate": _EXIT_GATE},
+    probe=".input_layernorm_2.weight",
+    what="a looped model", shard_axes=frozenset(),
+    shard_why=(_A_PLANE_A_PASS + ": a pass loop under stages (a pass "
+               "would go round the stage ring), tp or sp is not wired"),
+    linear_tiers=(),
+    linear_why=("an int8 linear's rounding is met once a pass and no "
+                "comparison with the reference has been made"),
+    cache_tiers=(),
+    cache_why=("an int8 cache is not wired for a looped model ("
+               + _A_PLANE_A_PASS + "; no comparison with the reference "
+               "has been made)"),
+    loops=True)
+
+
 # The first record that selects a configuration is its family: the
 # families that read `layer_types` before the ones a single key names, the
 # bare stack last.
-FAMILIES = (SHORT_CONV, WINDOWED, STATE_SPACE, HYBRID, LATENT, GQA)
+FAMILIES = (LOOPED, SHORT_CONV, WINDOWED, STATE_SPACE, HYBRID, LATENT, GQA)
 # every field some family's config.json alone carries
 FIELDS = frozenset(f for family in FAMILIES for f in family.fields)
 # the record that reads a config.json, by its `model_type` (the bare

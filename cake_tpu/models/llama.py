@@ -336,6 +336,9 @@ def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
             "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm")}
         if config.qk_norm:  # one weight for all heads, each of q and k
             shapes["q_norm"] = shapes["k_norm"] = lambda c: (c.head_dim,)
+        if config.family.loops:  # sandwich norms: each sub-layer's output
+            shapes["attn_post_norm"] = shapes["mlp_post_norm"] = (
+                _LAYER_SHAPES["attn_norm"])
     else:
         shapes = dict(_LATENT_SHAPES)
         if not config.q_lora_rank:  # one direct query projection
@@ -440,7 +443,7 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
         shapes = layer_shapes(config)
         keys = iter(jax.random.split(key, len(shapes) + 3))
         layers = stack(shapes, config.num_hidden_layers, keys)
-    return {
+    params = {
         "embed": dense(next(keys), (config.vocab_size, config.hidden_size),
                        config.hidden_size),
         "layers": layers,
@@ -448,6 +451,14 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
         "lm_head": dense(next(keys), (config.hidden_size, config.vocab_size),
                          config.hidden_size),
     }
+    # a family's tensors beside these (a looped model's exit gate)
+    for ours, parts in config.family.extra_tensors.items():
+        k = next(keys)
+        params[ours] = {
+            part: dense(jax.random.fold_in(k, n), shape_fn(config),
+                        config.hidden_size)
+            for n, (part, (_, shape_fn)) in enumerate(parts.items())}
+    return params
 
 
 def _mamba_init(config: LlamaConfig, stack: dict, key, dt) -> dict:
@@ -631,8 +642,10 @@ def block_forward(
 
     Model-family deltas dispatch on the layer pytree itself: q/k/v bias
     arrays (``bq``/``bk``/``bv``, Qwen2) and a ``router`` + expert-stacked
-    MLP (Mixtral) are used iff present; ``config.sliding_window`` (Mistral)
-    narrows the causal mask. The latent family (``wkv_a`` present) attends
+    MLP (Mixtral) are used iff present, and so are a sandwich-normed
+    layer's second norms (``attn_post_norm``/``mlp_post_norm``, the looped
+    family: each norms its sub-layer's OUTPUT before the residual adds it);
+    ``config.sliding_window`` (Mistral) narrows the causal mask. The latent family (``wkv_a`` present) attends
     through :func:`cake_tpu.ops.mla.latent_attention_block` and its expert
     layers add shared experts (``ws_*``) to the routed part.
     """
@@ -660,20 +673,26 @@ def block_forward(
         window=config.sliding_window,
         layer=layer_idx,
     )
+    if "attn_post_norm" in layer:  # a sandwich norm on the sub-layer's output
+        attn_out = rms_norm(attn_out, layer["attn_post_norm"],
+                            config.rms_norm_eps)
     x = x + attn_out
     h = rms_norm(x, layer["mlp_norm"], config.rms_norm_eps,
                    offset=config.rms_norm_offset)
     if "router" in layer:
-        x = x + moe_swiglu(
+        mlp_out = moe_swiglu(
             h, layer["router"], layer["w_gate"], layer["w_up"],
             layer["w_down"], top_k=config.num_experts_per_tok,
             ep_axis=ep_axis, ep_size=ep_size, tp_axis=tp_axis,
             layer=expert_idx,
         )
     else:
-        x = x + swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"],
-                       tp_axis=tp_axis, act=config.hidden_act)
-    return x, k_cache, v_cache
+        mlp_out = swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"],
+                         tp_axis=tp_axis, act=config.hidden_act)
+    if "mlp_post_norm" in layer:
+        mlp_out = rms_norm(mlp_out, layer["mlp_post_norm"],
+                           config.rms_norm_eps)
+    return x + mlp_out, k_cache, v_cache
 
 
 def _latent_block(layer, x, h, c_cache, r_cache, cos, sin, pos, config,
@@ -814,6 +833,7 @@ def forward_layers(
     ep_size: int | None = None,
     count_local: bool = False,
     valid: jax.Array | None = None,
+    pass_norm: jax.Array | None = None,
 ):
     """Run a contiguous run of decoder blocks via ``lax.scan``. Returns
     ``(x, cache)``; with ``count_local`` (latent family) ``(x, cache,
@@ -856,6 +876,25 @@ def forward_layers(
     beside attention go the same way: a conv segment's layers read and
     write the cache's tails (``valid`` as above) and a full segment's
     rotate and attend over its rows.
+
+    The looped family (``families.LOOPED``, ``config.total_ut_steps`` U > 1)
+    runs its plan U times over the carried ``(h, cache)`` WITH THE SAME
+    WEIGHTS, each pass under the named scope ``loop.pass``, and closes
+    every pass with ``rms_norm(h, pass_norm)`` (``loop.norm``):
+    ``pass_norm`` is the model's last norm (:func:`pass_norm`), which
+    therefore lives INSIDE this loop and which no caller applies again
+    before the head (:func:`head_norm`). Layer ``i`` of pass ``u`` reads
+    and writes cache plane ``u * L + i`` (``LlamaConfig.cache_plan``'s
+    ``rows`` counts ``U * L``): the keys and values a pass writes are read
+    by the same pass of later tokens alone. A chunk of an admission runs
+    all its passes before the next chunk, whose pass ``u`` attends over
+    this chunk's plane of pass ``u``, complete by then. The passes are a
+    ``lax.fori_loop`` around the one scan over the stack, the plane's
+    offset a loop value; ``tests/test_chip_compile.py`` holds that the
+    chip's compiler then copies neither the carried cache nor the stack
+    (U unrolled scans compile to the same facts and read 1.7% more
+    ``tpot_p50_ms`` on the chip: PERF.md sections 6 and 7). Limits: every token takes all U passes (``early_exit_threshold``
+    1); one stage, tp = sp = 1.
     """
     rows = x.shape[0] * x.shape[1]
 
@@ -915,7 +954,10 @@ def forward_layers(
         index into the cache buffers of its kind; ``whole``: the expert
         stacks it was :func:`split` from, if any."""
         n = jax.tree.leaves(stack)[0].shape[0]
-        index = jnp.arange(first, first + n, dtype=jnp.int32)
+        # (a looped model's pass loop hands ``first`` in as a loop value)
+        index = (jnp.arange(first, first + n, dtype=jnp.int32)
+                 if isinstance(first, int)
+                 else first + jnp.arange(n, dtype=jnp.int32))
         if not whole:  # ONE body for every such segment: traced once
             return jax.lax.scan(body, carry, (stack, index))[0]
         return jax.lax.scan(
@@ -964,15 +1006,54 @@ def forward_layers(
     if not config.segmented:  # one kind of layer, one bare stack
         stack, whole = split(layers)
         return scan_segment(carry, stack, 0, whole)
-    for run in layer_plan(config):
-        if run.repeats > 1:
-            carry = scan_period(carry, run)
-            continue
-        for seg in run.segments:
-            stack, whole = split(layers[seg.name])
-            carry = scan_segment(carry, stack, seg.cache_first, whole,
-                                 body_of(seg))
-    return carry
+
+    def one_pass(carry, plane=0):
+        """The plan's layers, once; ``plane``: the cache plane of the
+        pass's first layer."""
+        for run in layer_plan(config):
+            if run.repeats > 1:
+                carry = scan_period(carry, run)
+                continue
+            for seg in run.segments:
+                stack, whole = split(layers[seg.name])
+                carry = scan_segment(carry, stack, plane + seg.cache_first,
+                                     whole, body_of(seg))
+        return carry
+
+    if not config.family.loops:
+        return one_pass(carry)
+    if pass_norm is None:
+        raise ValueError(
+            "a looped model's layer loop closes each pass with the model's "
+            "last norm: call forward_layers(..., pass_norm=llama.pass_norm("
+            "params, config))")
+    per_pass = config.cache_plan["rows"][0] // config.total_ut_steps
+
+    def a_pass(u, carry):
+        with jax.named_scope("loop.pass"):
+            h, *rest = one_pass(carry, u * per_pass)
+        with jax.named_scope("loop.norm"):
+            h = rms_norm(h, pass_norm, config.rms_norm_eps)
+        return (h, *rest)
+
+    return jax.lax.fori_loop(0, config.total_ut_steps, a_pass, carry)
+
+
+def pass_norm(params: Params, config: LlamaConfig):
+    """What :func:`forward_layers` takes as ``pass_norm``: the model's last
+    norm where the layer loop applies it (a looped family, at the end of
+    each pass), None for every other family."""
+    return params["norm_f"] if config.family.loops else None
+
+
+def head_norm(params: Params, x: jax.Array, config: LlamaConfig) -> jax.Array:
+    """The model's last norm, before the head: THE place every path norms
+    what it hands the head. A looped family's layer loop has applied it
+    already (the last pass's closing norm), so its head norms nothing."""
+    if config.family.loops:
+        return x
+    return rms_norm(x, params["norm_f"], config.rms_norm_eps,
+                    offset=config.rms_norm_offset)
 
 
 def forward(
@@ -989,9 +1070,9 @@ def forward(
     """
     cos, sin = rope_tables_for(config, cache.max_seq)
     x = embed_tokens(params, tokens, config)
-    x, cache = forward_layers(params["layers"], x, cache, cos, sin, pos, config)
-    x = rms_norm(x, params["norm_f"], config.rms_norm_eps,
-                   offset=config.rms_norm_offset)
+    x, cache = forward_layers(params["layers"], x, cache, cos, sin, pos, config,
+                              pass_norm=pass_norm(params, config))
+    x = head_norm(params, x, config)
     x_last = x[:, -1, :]
     logits = quant.dense(x_last, params["lm_head"]).astype(jnp.float32)
     return logits, cache

@@ -58,7 +58,9 @@ SERIES: dict[str, tuple[str, str]] = {
     "attn.kv_blocks_reserved": (
         COUNTER, "KV blocks a layer's reservation holds for the same "
                  "steps: slots x window / block x steps (a latent "
-                 "cache's rows are blocks of the same 512)"),
+                 "cache's rows are blocks of the same 512); where the "
+                 "layers run several times a token, this and "
+                 "attn.kv_blocks_read count a layer's PLANES, one a pass"),
     # -- the cache and the expert layers (runtime/batch_generator) ------
     "cache.bytes": (
         GAUGE, "bytes of the serving cache as allocated (slots x window x "
@@ -71,9 +73,26 @@ SERIES: dict[str, tuple[str, str]] = {
                "that holds values, 1 where every row fills its tiles and "
                "1/2 where a 64-wide bfloat16 row is padded to 128 lanes; "
                "absent where the runtime does not say"),
+    "cache.token_bytes": (
+        GAUGE, "bytes the allocated row buffers hold for one token of one "
+               "stream, every plane of it (cache.bytes less state and "
+               "rings, over slots x window; cache.layer_planes x "
+               "cache.row_bytes): what an int8 cache or a plane shared by "
+               "the passes would move, and what sets how many streams fit"),
+    "cache.layer_planes": (
+        GAUGE, "planes of the serving cache's row buffers (their leading "
+               "axis): the layers that keep rows, times the passes where "
+               "the layers run several times a token (plane u x layers + "
+               "i for layer i in pass u)"),
+    "model.loop_passes": (
+        GAUGE, "times the layer loop runs its layers a token over one set "
+               "of weights (LlamaConfig.total_ut_steps; named scopes "
+               "loop.pass and loop.norm, the norm that closes a pass); 1 "
+               "where a layer runs once"),
     "cache.row_bytes": (
         GAUGE, "bytes the cache holds for one token of one layer that "
-               "keeps every row, a mean over the layers of that ONE kind, "
+               "keeps every row (of one PLANE where a layer has one a "
+               "pass), a mean over the layers of that ONE kind, "
                "from the buffers allocated (their bytes / such layers x "
                "slots x window): per-head keys and values (with an int8 "
                "cache's scales), or latent attention's one shared row; a "

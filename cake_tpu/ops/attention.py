@@ -233,6 +233,19 @@ def _attend_xla(
     group = n_heads // kv_heads
 
     qg = q.reshape(b, kv_heads, group, t, d)
+    # ONE query row a key/value head (a decode step of a multi-head model:
+    # T == 1, G == 1) goes into the products as a group of two equal rows,
+    # of which the first is kept. With a single row the chip's compiler
+    # multiplies q and K elementwise, wants the heads on the sublanes for
+    # it, and re-lays the CARRIED CACHE to ``[.., S, KVH, D]`` on the way
+    # into and out of the step: two more caches of temporaries (9 GiB at
+    # 192 planes x 8 slots x 768 rows; tests/test_chip_compile.py). Two
+    # rows take the product every grouped-query model takes, which reads
+    # the cache where it lies. The cache's bytes, not the rows, are the
+    # step's cost.
+    lone = group == 1 and t == 1
+    if lone:
+        qg = jnp.concatenate([qg, qg], axis=2)
     # f32 scores regardless of model dtype (attention.rs:62-77).
     scores = jnp.einsum(
         "bkgtd,bksd->bkgts", qg, k_all, preferred_element_type=jnp.float32
@@ -263,6 +276,8 @@ def _attend_xla(
         "bkgts,bksd->bkgtd", probs.astype(v_all.dtype), v_all,
         preferred_element_type=jnp.float32,
     )
+    if lone:
+        out = out[:, :, :1]
     return out.reshape(b, n_heads, t, d).astype(q.dtype)
 
 

@@ -158,28 +158,29 @@ def init_cache(
         raise ValueError(f"unsupported kv quant={quant!r}")
     if quant and quant not in config.family.cache_tiers:
         raise ValueError(config.family.cache_why)
-    L = config.num_hidden_layers if num_layers is None else num_layers
+    plan = config.cache_plan
+    # the depth of the row buffers is the plan's: the layers that keep
+    # rows, and for a looped model a plane a layer AND a pass
+    L = plan.get("rows", (0,))[0] if num_layers is None else num_layers
     S = max_seq or config.max_seq_len
     dt = dtype or config.jax_dtype
     # the row comes from the configuration alone (LlamaConfig.cache_row):
     # per-head keys and values, or latent attention's one shared row
     # (normed latent in ``k``, roped key part in ``v``)
     heads, k_width, v_width = config.cache_row
-    plan = config.cache_plan
     rec = {}
-    if num_layers is not None and set(plan) - {"rows"}:
+    if num_layers is not None and (set(plan) - {"rows"}
+                                   or config.family.loops):
         raise ValueError("a model that holds a recurrent state, a "
-                         "convolution's tail or a ring of rows is cached "
-                         "whole (no layer ranges)")
+                         "convolution's tail, a ring of rows or a plane a "
+                         "pass is cached whole (no layer ranges)")
     if "conv" in plan:  # layers that carry a tail, and a state or none
-        L = plan.get("rows", (0,))[0]
         if "state" in plan:
             n, *shape = plan["state"]
             rec["state"] = jnp.zeros((n, batch, *shape), jnp.float32)
         n, *shape = plan["conv"]
         rec["conv"] = jnp.zeros((n, batch, *shape), dt)
     if "ring" in plan:
-        L = plan["rows"][0]
         n, kvh, r, kw, vw = plan["ring"]
         rec["ring_k"] = jnp.zeros((n, batch, kvh, r, kw), dt)
         rec["ring_v"] = jnp.zeros((n, batch, kvh, r, vw), dt)

@@ -75,8 +75,7 @@ def validate_shardable(config: LlamaConfig, num_stages: int, tp: int,
     if asked - family.shard_axes:
         only = ", ".join(f"--{axis}" for axis in sorted(family.shard_axes))
         raise ValueError(
-            f"{family.what} (stacks of several kinds of layer) runs as one "
-            "stage with tp = 1, sp = 1" + (
+            f"{family.what} runs as one stage with tp = 1, sp = 1" + (
                 f"; only {only} shards it (its held experts)" if only
                 else " and ep = 1; nothing shards it yet")
             + f": {family.shard_why}")
@@ -175,6 +174,11 @@ def param_specs(params: dict | None = None) -> dict:
         base["layers"]["w_gate"] = P(STAGE, EP, None, TP)
         base["layers"]["w_up"] = P(STAGE, EP, None, TP)
         base["layers"]["w_down"] = P(STAGE, EP, TP, None)
+    # a family's tensors beside these (families.Family.extra_tensors: a
+    # looped model's exit gate): small, replicated
+    for name in params.keys() - base.keys():
+        base[name] = jax.tree.map(lambda v: P(*([None] * v.ndim)),
+                                  params[name])
     from cake_tpu.ops.quant import Quantized4Linear, QuantizedLinear
 
     def refine(p, s):

@@ -44,7 +44,6 @@ from cake_tpu.models import llama
 from cake_tpu.ops import quant, sampling
 from cake_tpu.ops.kvcache import KVCache
 from cake_tpu.ops.moe import ExpertCount
-from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.rope import rope_tables_for
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.parallel.mesh import (
@@ -65,7 +64,7 @@ def _local_counts(config: LlamaConfig, tp: int) -> tuple[int, int]:
 
 def _pipeline_layers(
     x: jax.Array,  # [Bl, T, hidden] local activation
-    layers,  # local stacked layer weights [L/S, ...]
+    params,  # local weights; its "layers" the stacked [L/S, ...] ones
     cache: KVCache,  # local cache, k and v [L/S, Bl, KVl, S, D]
     cos: jax.Array,
     sin: jax.Array,
@@ -105,10 +104,11 @@ def _pipeline_layers(
         x, cache, *local = carry
         active = step == my_stage
         h, new_cache, *now = llama.forward_layers(
-            layers, x, cache, cos, sin, pos, config,
+            params["layers"], x, cache, cos, sin, pos, config,
             num_heads=heads_l, num_kv_heads=kv_heads_l, tp_axis=TP, ep_axis=EP,
             sp_axis=SP, sp_size=sp, write_gate=active, sp_prefill=sp_prefill,
             sp_chunk=sp_chunk, count_local=count_local, valid=valid,
+            pass_norm=llama.pass_norm(params, config),
         )
         x = jnp.where(active, h, x)
         x = jax.lax.ppermute(x, STAGE, perm)
@@ -229,9 +229,9 @@ def _select_last_sp(x: jax.Array, last_index: jax.Array, sp: int) -> jax.Array:
 
 
 def _head_logits(params, x_last: jax.Array, config: LlamaConfig) -> jax.Array:
-    """ln_f + vocab-sharded lm_head; full logits gathered over tp."""
-    x_last = rms_norm(x_last, params["norm_f"], config.rms_norm_eps,
-                   offset=config.rms_norm_offset)
+    """ln_f (where the layer loop has not applied it: ``llama.head_norm``)
+    + vocab-sharded lm_head; full logits gathered over tp."""
+    x_last = llama.head_norm(params, x_last, config)
     logits_local = quant.dense(x_last, params["lm_head"]).astype(jnp.float32)
     return jax.lax.all_gather(logits_local, TP, axis=-1, tiled=True)
 
@@ -343,7 +343,7 @@ def build_sharded_decode(
         cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, token[:, None], config)
         x, cache, *local = _pipeline_layers(
-            x, params["layers"], cache, cos, sin, pos, config,
+            x, params, cache, cos, sin, pos, config,
             plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
             sp_prefill=False, count_local=count_local,
         )
@@ -620,8 +620,7 @@ def build_interleaved_decode(
 
             # ---- head + sample (uniform on every device) ----
             x_fin = _select_stage0(x[:, -1, :])  # [bm, H]
-            x_n = rms_norm(x_fin, params["norm_f"], config.rms_norm_eps,
-                   offset=config.rms_norm_offset)
+            x_n = llama.head_norm(params, x_fin, config)
             logits = head_logits(x_n)            # [bm, V] f32
             key_rows = jax.lax.dynamic_slice_in_dim(keys, base_fin, bm, 0)
             idx_rows = jax.lax.dynamic_slice_in_dim(index0, base_fin, bm, 0)
@@ -749,7 +748,7 @@ def build_admit_prefill(config: LlamaConfig, plan: MeshPlan,
         cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, tokens, config)
         x, cache = _pipeline_layers(
-            x, params["layers"], cache, cos, sin, pos0, config,
+            x, params, cache, cos, sin, pos0, config,
             plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
             sp_chunk=plan.sp > 1, valid=_valid_rows(config, tokens,
                                                     last_local),
@@ -804,7 +803,7 @@ def build_sharded_verify(config: LlamaConfig, plan: MeshPlan,
         cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, tokens, config)
         x, cache = _pipeline_layers(
-            x, params["layers"], cache, cos, sin, pos, config,
+            x, params, cache, cos, sin, pos, config,
             plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
             sp_chunk=plan.sp > 1,
         )
@@ -852,7 +851,7 @@ def build_sharded_verify_rows(config: LlamaConfig, plan: MeshPlan,
         cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, tokens, config)
         x, cache = _pipeline_layers(
-            x, params["layers"], cache, cos, sin, pos, config,
+            x, params, cache, cos, sin, pos, config,
             plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
             sp_chunk=plan.sp > 1,
         )
@@ -964,8 +963,7 @@ def build_interleaved_verify_rows(config: LlamaConfig, plan: MeshPlan,
         # each stage reads V/S of the lm_head instead of all of it
         y = jax.lax.psum(
             jnp.where(my_stage == S - 1, y, jnp.zeros_like(y)), STAGE)
-        y = rms_norm(y, params["norm_f"], config.rms_norm_eps,
-                   offset=config.rms_norm_offset)
+        y = llama.head_norm(params, y, config)
         hw = params["lm_head"]
         if S > 1 and _head_split_safe(hw, S):
             logits = quant.dense(y, _head_chunk(hw, my_stage, S)).astype(
@@ -1069,7 +1067,7 @@ def build_sharded_prefill(config: LlamaConfig, plan: MeshPlan,
             # ONE-token chunk, which the T>1 heuristic would misroute to the
             # decode branch (silently wrong logits — r2 code-review finding)
             x, cache = _pipeline_layers(
-                x, params["layers"], cache, cos, sin, pos0,
+                x, params, cache, cos, sin, pos0,
                 config, plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
                 sp_prefill=not chunk_mode, sp_chunk=chunk_mode,
                 valid=_valid_rows(config, tokens, last_index),

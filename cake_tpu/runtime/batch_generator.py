@@ -167,6 +167,16 @@ class _Staged:
 # row doubles. (A dense model's admission is arithmetic from 128 rows on:
 # there a launch of two saves a landing and pays for what it pads.)
 GROUP_SHAPES = ((2, 256),)
+# ... as far as its staging rows fit: a launch holds a staging row a member
+# (a whole stream's reservation each) beside the live cache, a launch ahead
+# of a landing one more beside the landing's, and the chip's compiler may
+# keep a copy of a row's keys beside each (an admission on XLA's attention
+# re-lays them on the way in and out). A dense cache's row is 0.25 GiB at
+# 2048 rows; a cache with a plane a layer AND a pass holds 1.125 GiB a row
+# at 768, and two of those with their temporaries beside 11.7 GiB of
+# weights and live cache are 15.1 of the chip's 15.75 GiB (PERF.md section
+# 6, PR 47): such a model stages one row at a time.
+GROUP_STAGING_BYTES = 2**30
 
 
 def _group_shape(own: list[int]) -> tuple[int, int] | None:
@@ -467,9 +477,33 @@ class BatchGenerator:
                 "rejected proposal has already advanced or overwritten a "
                 f"{' and a '.join(held)}, which its cache holds and nothing "
                 "restores; serve this family with no speculation")
+        # a looped model's cache holds a plane a layer AND a pass: what
+        # counts a plane a layer, or was never compared with the
+        # reference over a loop of passes, is refused
+        if config.family.loops and (self._paged or spec_k or kv_quant):
+            asked = ("kv_layout='paged'" if self._paged else
+                     "speculation (spec_k)" if spec_k else
+                     f"kv_quant={kv_quant!r}")
+            raise ValueError(
+                f"{asked} is not wired for a looped model: its cache "
+                f"holds {config.cache_plan['rows'][0]} planes, one a "
+                f"layer AND a pass of {config.total_ut_steps}, where the "
+                "page pool (and with it the disagg snapshot and the spill "
+                "tier) holds one a layer, and neither a verify round nor "
+                "an int8 plane has been compared with the reference over "
+                "a loop of passes; serve this family with the slot "
+                "layout, no speculation and a cache in the serving type")
         # what _count_kv_blocks counts through: beside a ring, a full layer
         self._kv_window = (None if "ring" in config.cache_plan
                            else config.sliding_window)
+        # ... and how many planes a layer it counts (a looped model's
+        # passes: each reads and reserves a plane of its own)
+        self._kv_planes = config.total_ut_steps
+        # how many staging rows (a stream's whole reservation each) may
+        # live at once beside the cache: a launch of several rows, and a
+        # launch ahead of the landing before it (GROUP_STAGING_BYTES)
+        self._staging_rows_fit = GROUP_STAGING_BYTES // max(
+            1, config.cache_token_bytes * self.max_seq)
         self._page_size = int(kv_page_size)
         self._pool_pages_req = kv_pool_pages
         if self._paged:
@@ -731,15 +765,20 @@ class BatchGenerator:
         # hits SHARE physical pages via refcounts instead of copying a
         # staged row, and eviction is pool-pressure-driven.
         self._prefix_entries = max(0, prefix_cache_entries)
-        if held and (self._prefix_entries or prefix_share_min):
+        if (held or config.family.loops) and (
+                self._prefix_entries or prefix_share_min):
             # a stored row's recurrent state (or window layers' ring) is
             # the one at the END of the prompt that left it, not at the
             # shared prefix's end: a hit would start from the wrong state
             # (from a ring whose newest rows lie past the prefix). Every
-            # prompt of such a model is prefilled whole.
+            # prompt of such a model is prefilled whole. A looped model's
+            # stored row is a plane a layer AND a pass (a whole stream's
+            # reservation, 1.1 GiB at 192 planes of 768 rows) and a hit
+            # over them has not been compared with the reference.
             logging.getLogger("cake_tpu.batch_generator").info(
                 "prefix reuse is off: a recurrent state, a convolution's "
-                "tail or a ring of rows has no prefix to share")
+                "tail, a ring of rows or a plane a pass has no prefix "
+                "to share")
             self._prefix_entries = self._prefix_share_min = 0
         self._prefix_store = PrefixLRU(self._prefix_entries)
         self._prefix_block = max(1, prefix_block)
@@ -1289,6 +1328,13 @@ class BatchGenerator:
         obs_metrics.gauge("cache.state_bytes").set(state)
         obs_metrics.gauge("cache.state_bytes_per_stream").set(
             state / self.cache.batch)
+        # what the row buffers hold for one token of one stream, every
+        # plane of it, and how the planes come about
+        obs_metrics.gauge("cache.token_bytes").set(
+            (held - state - rings) / (self.cache.batch * self.cache.max_seq))
+        obs_metrics.gauge("cache.layer_planes").set(self.cache.num_layers)
+        obs_metrics.gauge("model.loop_passes").set(
+            self.config.total_ut_steps)
         # first token per stream: fold_in(stream_key, 0) — the same absolute
         # token-index schedule the in-program decode steps continue
         keys0 = jax.vmap(lambda k: jax.random.fold_in(k, 0))(self._keys)
@@ -1611,7 +1657,7 @@ class BatchGenerator:
         (the import-side twin of the worker handshake's max_seq check)."""
         cfg = self.config
         return {
-            "layers": cfg.num_hidden_layers,
+            "layers": cfg.cache_plan["rows"][0],  # the cache's depth
             "kv_heads": cfg.num_key_value_heads,
             "head_dim": cfg.head_dim,
             "dtype": str(cfg.dtype),
@@ -1794,7 +1840,7 @@ class BatchGenerator:
     def _page_shapes(self) -> dict:
         """Expected (shape, dtype) per page tensor for this geometry."""
         cfg = self.config
-        L, KH, D = (cfg.num_hidden_layers, cfg.num_key_value_heads,
+        L, KH, D = (cfg.cache_plan["rows"][0], cfg.num_key_value_heads,
                     cfg.head_dim)
         ps = self._page_size
         if self.kv_quant == "int8":
@@ -1857,7 +1903,7 @@ class BatchGenerator:
         from cake_tpu.ops.kvcache import KVCache, QuantizedKV
 
         cfg = self.config
-        L, KH, D = (cfg.num_hidden_layers, cfg.num_key_value_heads,
+        L, KH, D = (cfg.cache_plan["rows"][0], cfg.num_key_value_heads,
                     cfg.head_dim)
         S, ps = self.max_seq, self._page_size
         if self.kv_quant == "int8":
@@ -2070,11 +2116,13 @@ class BatchGenerator:
 
     def _group_shapes(self) -> list[tuple[int, int]]:
         """``GROUP_SHAPES`` as far as this engine can launch them: the
-        slot layout, a slot a row, the whole bucket in one dispatch."""
+        slot layout, a slot a row, the whole bucket in one dispatch, the
+        members' staging rows within ``GROUP_STAGING_BYTES``."""
         if self._paged:
             return []
         return [(r, c) for r, c in GROUP_SHAPES
-                if r <= len(self.streams) and self._admission_chunk_for(c) == c]
+                if r <= len(self.streams) and self._admission_chunk_for(c) == c
+                and r <= self._staging_rows_fit]
 
     def _warm_bucket(self, chunk: int) -> None:
         """Compile what a launch of ``chunk``-token prompts can dispatch
@@ -2687,9 +2735,14 @@ class BatchGenerator:
                         st["cache"], jnp.int32(rows.index(m))))
         try:
             # the device's next program, before the host waits for this
-            # one's token: the landing is ahead if there is one
-            if (wait and fetched is None
-                    and (self._launch() or self._enqueue_block())
+            # one's token: the landing is ahead if there is one. Only
+            # where one more staging row fits beside this one's, which the
+            # splice still reads, and beside both prefills' temporaries (a
+            # program's memory is taken when it is enqueued): else the
+            # next program follows the fetch, this row released first
+            ahead = (wait and fetched is None
+                     and self._staging_rows_fit > len(rows))
+            if (ahead and (self._launch() or self._enqueue_block())
                     and members[0].stamps is not None):
                 _LANDINGS_AHEAD.inc()
         finally:
@@ -2714,6 +2767,9 @@ class BatchGenerator:
                 self._release_pages(slot)
         if not wait or guide is not None:
             self._launch(wait)  # the next arrival starts in this tick too
+        elif not ahead:
+            del st["cache"]  # the token is here: the splice has read it
+            _ = self._launch() or self._enqueue_block()
 
     def _install(self, m: _Staged) -> None:
         """A spliced arrival becomes its slot's stream, before its first
@@ -3554,11 +3610,13 @@ class BatchGenerator:
         """Add what ``steps`` decode steps from the frontiers ``pos`` (as
         dispatched) read of a layer's cache, in the decode kernel's
         blocks, and what is reserved (``attn.kv_blocks_*``). Where window
-        and full layers are mixed, a full layer's: a ring is read whole."""
+        and full layers are mixed, a full layer's: a ring is read whole.
+        Where the layers run several times a token, a layer's planes: one
+        a pass."""
         read, reserved = pk.decode_blocks_read(
             pos, steps, self.max_seq, window=self._kv_window)
-        _KV_BLOCKS_READ.inc(read)
-        _KV_BLOCKS_RESERVED.inc(reserved)
+        _KV_BLOCKS_READ.inc(read * self._kv_planes)
+        _KV_BLOCKS_RESERVED.inc(reserved * self._kv_planes)
 
     def _take_moe_count(self, out: tuple, steps: int) -> tuple:
         """Strip the trailing :class:`ExpertCount` off a decode

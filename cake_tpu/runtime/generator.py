@@ -41,7 +41,6 @@ from cake_tpu.ops import quant
 from cake_tpu.ops.kvcache import KVCache, init_cache
 from cake_tpu.ops.rope import rope_tables_for
 from cake_tpu.ops import sampling
-from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.utils.token_stream import TokenOutputStream
 
@@ -98,8 +97,7 @@ def _bucket(n: int, max_seq: int, floor: int = 16) -> int:
 
 
 def _lm_head(params, x_last: jax.Array, config: LlamaConfig) -> jax.Array:
-    x_last = rms_norm(x_last, params["norm_f"], config.rms_norm_eps,
-                   offset=config.rms_norm_offset)
+    x_last = llama.head_norm(params, x_last, config)
     return quant.dense(x_last, params["lm_head"]).astype(jnp.float32)
 
 
@@ -108,7 +106,9 @@ def prefill_fn(params, tokens, cache: KVCache, last_index, config: LlamaConfig):
     (the last *real* prompt position). Returns (logits [B, vocab], cache)."""
     cos, sin = rope_tables_for(config, cache.max_seq)
     x = llama.embed_tokens(params, tokens, config)
-    x, cache = llama.forward_layers(params["layers"], x, cache, cos, sin, 0, config)
+    x, cache = llama.forward_layers(
+        params["layers"], x, cache, cos, sin, 0, config,
+        pass_norm=llama.pass_norm(params, config))
     x_last = jnp.take_along_axis(
         x, last_index.reshape(-1, 1, 1).astype(jnp.int32), axis=1
     )[:, 0, :]
@@ -136,7 +136,9 @@ def decode_step_fn(
     bit-identical."""
     cos, sin = rope_tables_for(config, cache.max_seq)
     x = llama.embed_tokens(params, token[:, None], config)
-    x, cache = llama.forward_layers(params["layers"], x, cache, cos, sin, pos, config)
+    x, cache = llama.forward_layers(
+        params["layers"], x, cache, cos, sin, pos, config,
+        pass_norm=llama.pass_norm(params, config))
     logits = _lm_head(params, x[:, -1, :], config)
     mask = None
     if mask_table is not None:

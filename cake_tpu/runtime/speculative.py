@@ -40,7 +40,6 @@ from cake_tpu.models.config import LlamaConfig
 from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.ops import quant, sampling
 from cake_tpu.ops.kvcache import KVCache
-from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.rope import rope_tables_for
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.runtime.generator import LlamaGenerator
@@ -101,9 +100,9 @@ def _verify_forward(params, tokens, cache: KVCache, pos, cos, sin,
     never drift from the host-loop oracle the bit-identity tests pin."""
     x = llama.embed_tokens(params, tokens, config)
     x, cache = llama.forward_layers(params["layers"], x, cache, cos, sin,
-                                    pos, config)
-    x = rms_norm(x, params["norm_f"], config.rms_norm_eps,
-                   offset=config.rms_norm_offset)
+                                    pos, config,
+                                    pass_norm=llama.pass_norm(params, config))
+    x = llama.head_norm(params, x, config)
     logits = quant.dense(x[0], params["lm_head"]).astype(jnp.float32)
     return logits, cache
 

@@ -68,13 +68,15 @@ def _served_frontiers(batch: int, seed: int = 5):
 # generators' scalar frontier), the local heads of a tp=2 mesh, and the
 # rows of heads whose 512-row blocks overflow VMEM (an MHA 7B's 32 x 128,
 # Gemma-7B's 16 x 256: the kernel shrinks its blocks, auto leaves them on
-# XLA)
+# XLA), and the looped cell's multi-head row (KVH 16 x G 1: 512-row blocks
+# at exactly the VMEM budget) at its own 6 slots x 768 rows and at 2048
 SERVED_DECODE_SHAPES = (
     (8, 2048, 32, 8, 128), (8, 4096, 32, 8, 128), (8, 1024, 32, 8, 128),
     (8, 512, 32, 8, 128), (32, 2048, 32, 8, 128),
     (1, 2048, 32, 8, 128), (1, 4096, 32, 8, 128),
     (8, 2048, 16, 4, 128),
     (8, 2048, 32, 32, 128), (8, 2048, 16, 16, 256),
+    (6, 768, 16, 16, 128), (8, 2048, 16, 16, 128),
 )
 
 

@@ -111,14 +111,16 @@ def hbm_budget(
         + (head_scales / tp) * scale_el
         + c.hidden_size * el
     )
+    # the cache's depth is the plan's (LlamaConfig.cache_plan)
+    planes_per_chip = c.cache_plan["rows"][0] / num_stages
     kv_bytes = (
-        layers_per_chip * batch * (c.num_key_value_heads / tp)
+        planes_per_chip * batch * (c.num_key_value_heads / tp)
         * (S / sp) * d * 2 * cache_bytes_per_el
     )
     if cache_bytes_per_el == 1:
         # int8 KV (kvcache.QuantizedKV): one f32 scale per slot per head
         kv_bytes += (
-            layers_per_chip * batch * (c.num_key_value_heads / tp)
+            planes_per_chip * batch * (c.num_key_value_heads / tp)
             * (S / sp) * 2 * 4
         )
     total = layer_bytes + embed_bytes + head_bytes + kv_bytes

@@ -705,6 +705,13 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
         "lm_head": stacked(lambda: head_name, (), (h, v), P(), True,
                            tier is not None),
     }
+    # a family's tensors beside these (a looped model's exit gate): read
+    # and kept, in the shapes the checkpoint stores
+    for ours, parts in config.family.extra_tensors.items():
+        params[ours] = {
+            part: stacked(lambda n=name: n, (), shape_fn(config), P(),
+                          False, False)
+            for part, (name, shape_fn) in parts.items()}
     # what the checkpoint stores and no leaf names (a next-token prediction
     # block's ``mtp.*``, layers past the served depth), a tensor once
     # whatever forms it is stored in

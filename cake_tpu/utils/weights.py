@@ -442,6 +442,9 @@ def latent_hf_tensors(params: dict, config) -> dict[str, np.ndarray]:
     }
     if not config.tie_word_embeddings:
         tensors["lm_head.weight"] = np.asarray(params["lm_head"]).T
+    for ours, parts in config.family.extra_tensors.items():
+        for part, (name, _) in parts.items():
+            tensors[name] = np.asarray(params[ours][part])
     for stack, (ids, plain, experts) in latent_stack_plan(config).items():
         flat = ids.reshape(-1)
         for ours, (suffix, transpose) in plain.items():

@@ -1105,6 +1105,88 @@ def test_conv_and_attention_programs_fit_one_chip(topo, as_on_chip):
             < 13 / 16 * HBM_GIB["v5 lite"] * GIB)
 
 
+def test_looped_programs_fit_one_chip_and_copy_no_cache(topo, as_on_chip):
+    """The looped family's serving programs at Ouro-2.6B's published
+    widths, the cell ``ouro-2p6b.decode-full`` itself: 48 layers run 4
+    times a token over one stack, 192 cache planes, the whole vocabulary,
+    6 slots x 768 rows; the block decode and the 128- and 512-row
+    admissions. The chip's compiler takes them and they fit one chip.
+    RECORDED (my AOT compile, PR 47): the step holds 11.72 GiB of
+    arguments (4.97 of weights + 6.75 of rows; 13.97 at 8 slots) and 0.001
+    GiB of temporaries; an admission 6.09 GiB of arguments (the weights
+    and the batch-1 staging cache of 1.125 GiB) and 0.564 / 0.569 GiB of
+    temporaries.
+
+    The pass loop is a ``lax.fori_loop`` around the scan over THE stack
+    (``forward_layers``): three loops nest (a block's 8 steps, 4 passes, a
+    pass's 48 layers; the plane's offset is a loop value) around the
+    carried cache, and the compiler copies neither cache buffer (3.375 GiB
+    each at 6 slots) nor a layer stack (``bf16[48,2048,2048]``,
+    ``[48,2048,5632]``, ``[48,5632,2048]``: read where they lie, in every
+    pass), in no loop and not in ENTRY; temporaries 0.001 GiB. Four
+    unrolled passes (four layer loops, a constant offset each) compile to
+    the same facts (0.002 GiB); on the chip they read 1.7% more
+    ``tpot_p50_ms`` in both pairs of runs (PERF.md section 6), so the loop
+    is kept.
+
+    What DID copy the cache was not the loop but the row of heads: with
+    ONE query row a key/value head (KVH 16 x G 1, T == 1) the compiler
+    multiplies q and K elementwise, wants the heads on the sublanes, and
+    re-laid both carried buffers to ``{4,2,3,1,0}`` (``[.., S, KVH, D]``)
+    on the way into and out of the step: 9.0 GiB of temporaries at 8
+    slots, 22.97 GiB in all, refused (a plain 16/16-head decoder of 8
+    layers does the same; 16/8 does not). ``ops/attention.py``
+    ``_attend_xla`` hands such a row to the products as a group of two,
+    which takes the product every grouped-query model takes.
+
+    An admission re-lays the staging cache's KEYS once on the way in and
+    once out (``{3,4,2,1,0}``: the rows on the lanes, K transposed for the
+    chunk's score product), in ENTRY and in no loop, as every family's
+    admission on XLA's attention does (an 8-layer plain decoder's too,
+    16/8 heads as well, in fast memory there): one K buffer (0.5625 GiB)
+    of temporaries. It is why a chip holds 6 slots and not 8, and why two
+    arrivals do not ride one program here (``GROUP_STAGING_BYTES``); PERF.md
+    section 7 queues it."""
+    from cake_tpu.models.config import ouro_2_6b
+    from cake_tpu.utils.chips import HBM_GIB
+
+    slots, window = 6, 768
+    config = ouro_2_6b(max_seq_len=window)
+    decode, admit128, admit512 = _family_programs(
+        topo, config, slots, window, 128, 512)
+    stacks = ("bf16[48,2048,2048]", "bf16[48,2048,5632]",
+              "bf16[48,5632,2048]")
+    as_declared = "4,3,2,1,0:T(8,128)(2,1)"
+    rows = f"bf16[192,{slots},16,{window},128]"
+    assert _layouts(decode, rows) == {as_declared}, _layouts(decode, rows)
+    assert _cache_sized_moves(decode, rows) == []
+    for compiled in (decode, admit128, admit512):
+        for stack in stacks:
+            assert _cache_sized_moves(compiled, stack) == [], stack
+        text = compiled.as_text()
+        assert "loop.pass" in text and "loop.norm" in text
+        assert "flash_decode" not in text  # 768 rows: XLA's attention
+    args, temps = _donated_bytes(decode)
+    assert 11.7 * GIB < args < 11.75 * GIB, args / GIB
+    assert temps < 0.01 * GIB, temps / GIB
+    staging = f"bf16[192,1,16,{window},128]"
+    for compiled in (admit128, admit512):
+        moves = _cache_sized_moves(compiled, staging)
+        assert len(moves) <= 2 and all(
+            m.startswith("main") for m in moves), moves  # ENTRY, no loop
+        a, t = _donated_bytes(compiled)
+        assert 6.05 * GIB < a < 6.15 * GIB, a / GIB
+        assert t < 0.6 * GIB, t / GIB  # one K buffer, not both, not twice
+    # the step's arguments, an admission's staging row and temporaries
+    # beside them: under the 14.5 GiB ISSUE 47 sets (8 slots: 15.7)
+    worst = max(c.memory_analysis().temp_size_in_bytes
+                for c in (admit128, admit512))
+    staging_bytes = 2 * 192 * 16 * window * 128 * 2
+    assert args + temps + staging_bytes + worst < 14.5 * GIB
+    assert args + temps + staging_bytes + worst < HBM_GIB["v5 lite"] * GIB
+    assert (args + 2.25 * GIB) + staging_bytes + worst > 14.5 * GIB
+
+
 # sha256[:16] of the lowered text of each family's serving programs at tiny
 # widths, taken on PR 31's tree (commit 8273b40): the layer plan, the
 # cache's two kinds of state and the routing bias are additions that the
@@ -1141,27 +1223,32 @@ PR31_TEXTS = {
     "windowed.admit": "750c179d4d7e8076",
     "short_conv.decode": "ffc2e337af0e44aa",
     "short_conv.admit": "d9e071c8a3327ea1",
+    # the looped family, taken on PR 47's tree, which brought it: a loop of
+    # passes around the scan over one stack, a plane a layer and a pass
+    "looped.decode": "1790a3781f0fb307",
+    "looped.admit": "2d341182e4882929",
 }
 
 
 def _family_fixtures():
     from cake_tpu.models.config import (tiny, tiny_exaone_moe, tiny_jamba,
                                         tiny_kda_hybrid, tiny_lfm2_moe,
-                                        tiny_mla_moe, tiny_moe)
+                                        tiny_mla_moe, tiny_moe, tiny_ouro)
 
     return {"dense": lambda: tiny(sliding_window=32), "sparse": tiny_moe,
             "latent": tiny_mla_moe, "hybrid": tiny_kda_hybrid,
             "state_space": tiny_jamba, "windowed": tiny_exaone_moe,
-            "short_conv": tiny_lfm2_moe}
+            "short_conv": tiny_lfm2_moe, "looped": tiny_ouro}
 
 
 @pytest.mark.parametrize("name", ["dense", "sparse", "latent", "hybrid",
-                                  "state_space", "windowed", "short_conv"])
+                                  "state_space", "windowed", "short_conv",
+                                  "looped"])
 def test_existing_families_lower_to_the_text_they_had(name):
     """Each family's block decode and admission programs lower (StableHLO,
     CPU, tiny widths) to the text PR 31's tree (PR 32's for the hybrid,
     PR 40's for the state-space family, PR 45's for the window and
-    short-convolution families) gave them, so the chip's
+    short-convolution families, PR 47's for the looped one) gave them, so the chip's
     compiler sees what it saw and the cells it measured stay where they
     are: without kernels (the CPU's default) every call of the expert
     block takes the form it took before there was a sorted one (PR 33,
