@@ -48,7 +48,9 @@ SERIES: dict[str, tuple[str, str]] = {
                "latent_decode), 0 the sweep of the reservation; absent "
                "where no program attends through either"),
     "attn.kv_blocks_read": (
-        COUNTER, "KV blocks (ops.pallas.DECODE_BLOCK_K rows) a layer's "
+        COUNTER, "KV blocks (of the rows flash_decode fetches of this "
+                 "cache's shape, ops.pallas.decode_block_k: 512 for a "
+                 "group of query rows a KV head) a layer's "
                  "decode attention reads under the kernels' block range "
                  "(ops.pallas.decode_block_range, which flash_decode and "
                  "the latent cache's latent_decode both walk): over "

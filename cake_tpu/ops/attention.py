@@ -79,7 +79,10 @@ PREFILL_FLASH_MIN_T = 256
 # stream's blocks of ``pk.DECODE_BLOCK_K`` rows up to its frontier,
 # straight out of the stacked cache the layer loop carries.
 # tools/flash_sweep.py --only served-decode on v5 lite (B 8, KVH 8, G 4,
-# D 128, a layer's time inside a walk over 8 layers, PR 29), kernel / XLA:
+# D 128, a layer's time inside a walk over 8 layers, PR 29), kernel / XLA
+# (ONE walk a dispatch then: the call's dispatch stands as a floor of ~30
+# us under these lines' early-frontier times; since PR 50 the tool walks 16
+# times a dispatch):
 #
 # - S 2048: 48 / 105 us at the frontiers a `decode-full` batch has (64-700),
 #   105 / 105 us with every frontier at the buffer's end (1.00x: the cost
@@ -95,14 +98,52 @@ PREFILL_FLASH_MIN_T = 256
 # - other rows of heads (B 8, S 2048): KVH 4 x G 4, a tp=2 mesh's local
 #   heads: 46 / 63 us mixed, 64 / 63 at the end. KVH 32 x G 1 (an MHA 7B)
 #   fits VMEM only at 256 rows and pays for it on a full cache: 122 / 394
-#   mixed, 559 / 394 (1.42x) at the end, so it stays on XLA. (KVH 16 x
-#   D 256 at 256 rows: 86 / 375 and 376 / 374; not taken yet, PERF.md
-#   section 7.) One stream (B 1): 43-49 us both, at every frontier.
+#   mixed, 559 / 394 (1.42x) at the end (KVH 16 x D 256 at 256 rows: 86
+#   / 375 and 376 / 374): the loop over heads, on XLA until PR 50's form
+#   (below). One stream (B 1): 43-49 us both, at every frontier.
+#
+# ONE query row a KV head (a multi-head model: G == 1). The loop over heads
+# does a head's two products and its softmax as one dependent chain, and
+# sixteen such chains of single rows do not hide behind a block's fetch
+# (PR 47 left the shape on XLA for it). tools/flash_sweep.py --only one-row
+# on v5 lite (PR 50, my chip run; us a plane inside 16 walks over 8 layers a
+# dispatch, at the frontiers a `decode-full` batch has / at row 703 / at the
+# buffer's end), blocks of 128 | 256 | 384 (512 at S 2048) rows:
+#
+# - B 6, S 768, KVH 16 x D 128 (`ouro-2p6b`'s planes), XLA 56.7 / 56.7 / 56.7:
+#   * the heads' products in ONE batched call, the running maximum, sum and
+#     accumulator one [KVH, 1, ..] update a block (taken):
+#     26.9 | 28.2 | 36.8 mixed, 53.2 | 53.2 | 53.4 at row 703,
+#     53.0 | 53.3 | 53.5 at the end (0.94x XLA: the fetch alone);
+#   * the loop over heads: 42.6 | 45.0 | 45.7 mixed, 84.1 | 83.3 | 64.7 at
+#     the end (1.14-1.48x);
+#   * two forms that are not in the tree (ISSUE 50's: the same chain a head,
+#     another product). K streamed through the matrix unit against q stood
+#     in every column, the values' product elementwise on the vector unit:
+#     28.2 | 29.2 | 38.2 mixed, 55.5 | 54.0 | 55.0 at the end. Both products
+#     on the vector unit, a lane reduction a key row: 30.1 | 30.1 | 38 mixed,
+#     59.5 | 55.1 | 55.8 at the end. Neither beats the batched call at any
+#     block: what cost was the sixteen chains, not which operand the matrix
+#     unit holds.
+# - B 8, S 2048, XLA 185.7 (KVH 16 x D 128), 367.1 (KVH 32 x D 128), 363.5
+#   (KVH 16 x D 256), batched at 128 | 256 | 512 rows: KVH 16 29.5 | 36.7 |
+#   48.3 mixed, 180.6 | 180.7 | 181.2 at the end (the loop at 512 rows, taken
+#   until PR 50: 51.3 and 184.4); KVH 32 56.5 | 70.7 mixed, 358.4 | 358.9 at
+#   the end (0.98x; the loop 92.2 | 110.8 and 575 | 545: why it stood on
+#   XLA); KVH 16 x D 256 56.4 | 70.7 and 358.3 | 358.7.
+# - So 128 rows (``pk.ONE_ROW_BLOCK_K``: a block is computed inside its fetch
+#   at any size, and the shortest skips most), from 768 rows up and to the
+#   widest row of heads the sweep has (KVH x D 4096). A block of all 768
+#   rows (12 MiB double-buffered: over ``pk.DECODE_KV_VMEM``, timed in a
+#   scratch run with a raised limit and one walk a dispatch) reads 65-70 us
+#   at every frontier against XLA's 68-73 there: nothing to skip.
 #
 # The frontier is data, so a nearly full cache runs the kernel too, at
 # XLA's cost. An int8 cache stays on XLA (the dequantize fuses into its
 # dot; a kernel operand would be a written-out bf16 buffer).
 DECODE_FLASH_MIN_S = 1024
+ONE_ROW_FLASH_MIN_S = 768
+ONE_ROW_MAX_WIDTH = 4096
 
 
 def _flash_prefill_choice(t: int, s: int, d: int) -> str:
@@ -129,17 +170,17 @@ def _flash_prefill_choice(t: int, s: int, d: int) -> str:
     return "xla"
 
 
-def flash_decode_choice(s: int, d: int, kv_heads: int,
+def flash_decode_choice(s: int, d: int, kv_heads: int, group: int,
                         itemsize: int = 2) -> str:
     """``"flash"`` or ``"xla"`` for a single-token (T == 1) attention
-    over a plain ``S``-row cache of ``kv_heads`` heads of size ``d`` —
-    THE decode policy, from what a trace can see of its input (the
+    of ``group`` query rows a KV head over a plain ``S``-row cache of
+    ``kv_heads`` heads of size ``d`` — THE decode policy, from what a trace can see of its input (the
     shapes; the frontier is data). :func:`attend` asks it, once for each
     decode program traced, and publishes the answer
     (``attn.decode_kernel``)."""
     if not pk.kernels_enabled():
         return "xla"
-    bk = pk.decode_block_k(s, kv_heads, d, itemsize)
+    bk = pk.decode_block_k(s, kv_heads, d, itemsize, group)
     if pk.force_kernels():
         if pk.interpret_default() or (_flash_ok(1, s, d) and bk is not None):
             return "flash"
@@ -150,9 +191,17 @@ def flash_decode_choice(s: int, d: int, kv_heads: int,
             "to the XLA attention path", s, d, kv_heads,
         )
         return "xla"
+    if d % 128:
+        return "xla"
+    if group == 1:
+        # ONE query row a KV head: the batched form, at the rows of heads
+        # and windows the sweep has (the table above)
+        fits = (bk == pk.ONE_ROW_BLOCK_K
+                and kv_heads * d <= ONE_ROW_MAX_WIDTH)
+        return "flash" if fits and s >= ONE_ROW_FLASH_MIN_S else "xla"
     # whole blocks of the measured size, which must fit VMEM: a wider row
     # of heads would need shorter ones (the table above)
-    fits = d % 128 == 0 and bk == pk.DECODE_BLOCK_K
+    fits = bk == pk.DECODE_BLOCK_K
     return "flash" if fits and s >= DECODE_FLASH_MIN_S else "xla"
 
 
@@ -199,7 +248,8 @@ def attend(
         impl = "xla"  # not kernel-served shapes, whatever was asked
     elif impl == "auto":
         impl = (_flash_prefill_choice(t, s, d) if t > 1
-                else flash_decode_choice(s, d, kvh, data.dtype.itemsize))
+                else flash_decode_choice(s, d, kvh, q.shape[1] // kvh,
+                                         data.dtype.itemsize))
     if t == 1:
         # trace time: which attention the decode program being built
         # holds (the benchmark reads it beside the engine's block counts)

@@ -50,6 +50,7 @@ def interpret_default() -> bool:
 
 from cake_tpu.ops.pallas.flash import (  # noqa: E402
     DECODE_BLOCK_K,
+    ONE_ROW_BLOCK_K,
     decode_block_k,
     decode_block_range,
     decode_blocks_read,
@@ -74,6 +75,7 @@ __all__ = [
     "interpret_default",
     "on_tpu",
     "DECODE_BLOCK_K",
+    "ONE_ROW_BLOCK_K",
     "decode_block_k",
     "decode_block_range",
     "decode_blocks_read",

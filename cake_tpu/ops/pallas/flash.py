@@ -16,7 +16,9 @@ Two kernels share the math:
   kv-block) with f32 running max / sum / accumulator scratch.
 - :func:`flash_decode` — decode (T == 1): the GQA head group is folded into
   the q-row axis (``[B, KVH, group, D]``) so the MXU sees a [group, D] x
-  [D, BK] matmul a head. One invocation leaves the cache in HBM (one
+  [D, BK] matmul a head (the heads' matmuls one batched call where the
+  group is ONE row: a multi-head model). One invocation leaves the cache
+  in HBM (one
   layer's ``[B, KVH, S, D]`` or the stacked ``[L, B, KVH, S, D]`` the
   layer loop carries, the layer a scalar operand) and walks each stream's
   live KV blocks, all KV heads of a block at once, with its own
@@ -418,6 +420,10 @@ def flash_attention_q8(
 # stream). From the v5e sweep of tools/flash_sweep.py at the served shapes
 # (the table is beside ops.attention.DECODE_FLASH_MIN_S).
 DECODE_BLOCK_K = 512
+# ... and where a KV head has ONE query row (the kernel's batched form: a
+# block is computed inside its fetch at any size, so the shortest block,
+# which skips most, wins: ``--only one-row``, the same table)
+ONE_ROW_BLOCK_K = 128
 # What the kernel's K and V blocks may take of VMEM, double-buffered:
 # 4 x KVH x rows x D x itemsize (q, o and the accumulators are small beside
 # them: B 128 compiles at KVH 8). v5e's compiler gives a kernel 16 MiB: 8
@@ -427,18 +433,23 @@ DECODE_KV_VMEM = 8 << 20
 
 
 def decode_block_k(s: int, kv_heads: int, d: int, itemsize: int,
-                   block_k: int = DECODE_BLOCK_K) -> int | None:
+                   group: int, block_k: int | None = None) -> int | None:
     """Rows of the KV block :func:`flash_decode` fetches at these shapes:
-    the largest power of two up to ``block_k`` that divides ``s``, halved
-    (down to 128 rows) until the blocks fit ``DECODE_KV_VMEM``; ``None``
-    where not even those fit, and the kernel cannot be built."""
-    bk = _pick_block(s, block_k)
+    ``block_k`` (None: what the sweep gave this row of heads,
+    ``ONE_ROW_BLOCK_K`` for one query row a KV head and ``DECODE_BLOCK_K``
+    for a group of them) where it divides ``s``, else the largest power of
+    two up to it that does, halved (down to 128 rows) until the blocks fit
+    ``DECODE_KV_VMEM``; ``None`` where not even those fit, and the kernel
+    cannot be built."""
+    if block_k is None:
+        block_k = DECODE_BLOCK_K if group > 1 else ONE_ROW_BLOCK_K
+    bk = block_k if s % block_k == 0 else _pick_block(s, block_k)
 
     def need(rows):
         return 4 * kv_heads * rows * d * itemsize
 
     while bk > 128 and need(bk) > DECODE_KV_VMEM:
-        bk //= 2
+        bk = _pick_block(s, bk // 2)
     return bk if need(bk) <= DECODE_KV_VMEM else None
 
 
@@ -486,12 +497,22 @@ def _decode_kernel(
     scale: float,
     num_kv_blocks: int,
     window: int | None = None,
+    batched: bool = False,
 ):
     """One invocation walks every stream's LIVE KV blocks in turn, (row 0:
     lo..hi), (row 1: lo..hi), ...: the block after the one being computed
     is already on its way into the other buffer, across the change of
     row too, so no fetch waits on a skipped grid step and none is paid
-    for."""
+    for.
+
+    ``batched``: a block's products are one batched call over the heads
+    and its softmax bookkeeping one update of ``[KVH, G, ..]`` arrays.
+    The loop over heads makes a head's two products and its softmax one
+    dependent chain; with ONE query row a head (``group`` 1) sixteen such
+    chains of single rows take 1.5 times a block's fetch (84 us a plane at
+    B 6 x S 768 x KVH 16 with every frontier at the buffer's end, 53 as
+    one call: the fetch alone). A group of rows keeps the loop, which
+    hides behind 512-row fetches, and its compiled kernel."""
     lead = ()
     if stacked:
         layer_ref, *refs = refs
@@ -538,28 +559,53 @@ def _decode_kernel(
         if window is not None:
             # sliding window: this row attends keys in (pos-window, pos]
             mask &= kpos > pos - window
-        # one [G, D] x [D, BK] product a KV head (unrolled: the heads are
-        # independent, so the scheduler overlaps them)
-        for h in range(kv_heads):
-            q = q_ref[b, h]  # [G, D]
-            k = kbuf[slot, h]  # [BK, D]
-            v = vbuf[slot, h]
+        if batched:
+            # every head's product in ONE batched call and one update of
+            # the running maximum, sum and accumulator as [KVH, G, ..]
+            # arrays: nothing of a head waits for another head's softmax
+            k = kbuf[slot]  # [KVH, BK, D]
+            v = vbuf[slot]
             s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+                q_ref[b], k, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
             )
-            s = jnp.where(mask, s * scale, NEG_INF)  # [G, BK]
-            m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            s = jnp.where(mask, s * scale, NEG_INF)  # [KVH, G, BK]
+            m_prev = m_ref[:]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[:, :1])
-            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
-            m_ref[h] = m_new
+            p = jnp.exp(s - m_new[:, :, :1])
+            l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=2, keepdims=True)
+            m_ref[:] = m_new
             pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
             )
-            acc_ref[h] = acc_ref[h] * alpha[:, :1] + pv
+            acc_ref[:] = acc_ref[:] * alpha[:, :, :1] + pv
+        else:
+            # one [G, D] x [D, BK] product a KV head (unrolled: the heads
+            # are independent, so the scheduler overlaps them)
+            for h in range(kv_heads):
+                q = q_ref[b, h]  # [G, D]
+                k = kbuf[slot, h]  # [BK, D]
+                v = vbuf[slot, h]
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                s = jnp.where(mask, s * scale, NEG_INF)  # [G, BK]
+                m_prev = m_ref[h]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new[:, :1])
+                l_ref[h] = (alpha * l_ref[h]
+                            + jnp.sum(p, axis=1, keepdims=True))
+                m_ref[h] = m_new
+                pv = jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                acc_ref[h] = acc_ref[h] * alpha[:, :1] + pv
 
         @pl.when(last)
         def _finish():
@@ -581,9 +627,10 @@ def flash_decode(
     pos,  # scalar int or [B]
     *,
     layer=None,  # index into the stacked form's leading axis
-    block_k: int = DECODE_BLOCK_K,
+    block_k: int | None = None,
     window: int | None = None,
     interpret: bool | None = None,
+    batched: bool | None = None,
 ) -> jax.Array:
     """Single-position flash attention. Returns [B, H, 1, D].
 
@@ -593,7 +640,11 @@ def flash_decode(
     block in flight while this one is computed: a stream whose cache is
     a seventh full costs a seventh of the sweep, and rows nobody wrote
     are neither read nor computed. The GQA group is folded into q rows,
-    so a head is one [group, D] x [D, BK] matmul. ``pos`` may be scalar
+    so a head is one [group, D] x [D, BK] matmul; with ONE query row a KV
+    head the heads' matmuls are one batched call and the blocks shorter
+    (``batched``, None: where ``group`` is 1; ``block_k``, None:
+    :func:`decode_block_k`'s own; the sweep tool passes both to time
+    either form at any block). ``pos`` may be scalar
     (shared frontier) or ``[B]`` (per-row frontiers — multi-stream
     serving).
 
@@ -613,7 +664,7 @@ def flash_decode(
     assert k_all.ndim == (5 if stacked else 4), (k_all.shape, layer)
     kvh, s = k_all.shape[-3], k_all.shape[-2]
     group = h // kvh
-    bk = decode_block_k(s, kvh, d, k_all.dtype.itemsize, block_k)
+    bk = decode_block_k(s, kvh, d, k_all.dtype.itemsize, group, block_k)
     assert bk is not None, ("no KV block fits the kernel's VMEM", kvh, d)
     nk = s // bk
     if interpret is None:
@@ -650,6 +701,7 @@ def flash_decode(
     kernel = functools.partial(
         _decode_kernel, stacked=stacked, batch=b, kv_heads=kvh, group=group,
         block_k=bk, scale=1.0 / math.sqrt(d), num_kv_blocks=nk, window=window,
+        batched=group == 1 if batched is None else batched,
     )
     out = pl.pallas_call(
         kernel,

@@ -496,6 +496,15 @@ class BatchGenerator:
         # what _count_kv_blocks counts through: beside a ring, a full layer
         self._kv_window = (None if "ring" in config.cache_plan
                            else config.sliding_window)
+        # ... in blocks of how many rows: what the decode kernel fetches
+        # of a cache of this shape (the local heads of a tp mesh), asked
+        # of the function the kernel asks; a latent row's kernel, and a
+        # shape no kernel is built for, count in the default
+        self._kv_block = (per_head and pk.decode_block_k(
+            self.max_seq, config.num_key_value_heads // plan.tp,
+            config.head_dim, config.jax_dtype.itemsize,
+            config.num_attention_heads // config.num_key_value_heads)
+        ) or pk.DECODE_BLOCK_K
         # ... and how many planes a layer it counts (a looped model's
         # passes: each reads and reserves a plane of its own)
         self._kv_planes = config.total_ut_steps
@@ -3614,7 +3623,8 @@ class BatchGenerator:
         Where the layers run several times a token, a layer's planes: one
         a pass."""
         read, reserved = pk.decode_blocks_read(
-            pos, steps, self.max_seq, window=self._kv_window)
+            pos, steps, self.max_seq, block_k=self._kv_block,
+            window=self._kv_window)
         _KV_BLOCKS_READ.inc(read * self._kv_planes)
         _KV_BLOCKS_RESERVED.inc(reserved * self._kv_planes)
 

@@ -8,7 +8,7 @@ dispatch — the same measured-crossover treatment ``quant_matmul`` got for its
 M>=16 gate (`ops/quant.py`).
 
 Usage:  python -m cake_tpu.tools.flash_sweep [--json-out PATH]
-            [--only served-decode|served-latent]
+            [--only served-decode|served-latent|one-row]
 (``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
 Prints one JSON line per shape:
@@ -19,7 +19,11 @@ Prints one JSON line per shape:
 cache the layer loop carries, at the served shapes and frontiers (what
 decides ``DECODE_FLASH_MIN_S`` and ``DECODE_BLOCK_K``, and for the latent
 cache ``ops.mla.LATENT_DECODE_MIN_S``; the tables stand beside those
-constants). ``--only served-latent`` runs the latent rows alone.
+constants). ``--only served-latent`` runs the latent rows alone;
+``--only one-row`` the decode kernel's two forms (the heads' products a head
+at a time, or in one batched call) at 128- to 512-row blocks on the rows of
+heads with ONE query row a KV head (``ONE_ROW_SHAPES``: what decides
+``ops.pallas.ONE_ROW_BLOCK_K`` and ``ONE_ROW_FLASH_MIN_S``).
 """
 
 from __future__ import annotations
@@ -45,7 +49,7 @@ def _audit(rec: dict) -> dict:
 
     auto = (_flash_prefill_choice(rec["t"], rec["s"], 128)
             if rec["path"].startswith("prefill")
-            else flash_decode_choice(rec["s"], 128, kv_heads=8))
+            else flash_decode_choice(rec["s"], 128, kv_heads=8, group=4))
     rec["auto_impl"] = auto
     rec["auto_speedup"] = rec["speedup"] if auto == "flash" else 1.0
     return rec
@@ -80,42 +84,65 @@ SERVED_DECODE_SHAPES = (
 )
 
 
-def _layer_ms(fn, q, pos, layers: int, *cache, iters: int = 10) -> float:
-    """A layer's milliseconds inside one pass over ``layers`` layers:
-    ``fn(q, pos, layer, *cache) -> q-shaped`` with the layer index traced,
-    so no layer's rows stay in fast memory between calls."""
-    import time
+# walks over the layers in one dispatch of _layer_step: with one, a call's
+# dispatch sets a floor of ~30-40 us under every line (8 layers a call)
+WALKS = 16
 
-    from cake_tpu.tools.kernel_check import _sync
 
+def _layer_step(fn, layers: int):
+    """``WALKS`` passes over ``layers`` layers, jitted: ``fn(q, pos, layer,
+    *cache) -> q-shaped`` with the layer index traced, so no layer's rows
+    stay in fast memory between calls. The frontiers are data: one
+    compile times them all."""
     @jax.jit
     def step(q, pos, *cache):
         def body(q, layer):
             out = fn(q, pos, layer, *cache)
             return q + (out * 1e-30).astype(q.dtype), None
 
-        return jax.lax.scan(body, q, jnp.arange(layers, dtype=jnp.int32))[0]
+        order = jnp.tile(jnp.arange(layers, dtype=jnp.int32), WALKS)
+        return jax.lax.scan(body, q, order)[0]
+
+    return step
+
+
+def _layer_ms(step, q, pos, layers: int, *cache, iters: int = 10) -> float:
+    """A layer's milliseconds inside :func:`_layer_step`'s walks."""
+    import time
+
+    from cake_tpu.tools.kernel_check import _sync
 
     _sync(step(q, pos, *cache))  # compile
     t0 = time.perf_counter()
     for _ in range(iters):
         q = step(q, pos, *cache)
     _sync(q)
-    return (time.perf_counter() - t0) / (iters * layers) * 1e3
+    return (time.perf_counter() - t0) / (iters * layers * WALKS) * 1e3
+
+
+# the rows of SERVED_DECODE_SHAPES with ONE query row a KV head (G == 1):
+# ``--only one-row`` times both forms of the kernel at every block on them
+ONE_ROW_SHAPES = tuple(shape for shape in SERVED_DECODE_SHAPES
+                       if shape[2] == shape[3])
+# flash_decode's ``batched``: the heads' products a head at a time, or in
+# one batched call (None: the form the kernel takes itself)
+FORMS = {None: "", False: "loop_", True: "batched_"}
 
 
 def served_decode_rows(results: list, blocks=(128, 256, 512, 1024),
-                       layers: int = 8) -> None:
+                       layers: int = 8, shapes=SERVED_DECODE_SHAPES,
+                       forms=(None,)) -> None:
     """Decode (T == 1) on the STACKED cache ``[L, B, KVH, S, D]`` as a
     decode step meets it: one pass over ``layers`` layers, each attending
     its own slice with the layer index traced (so no layer's keys stay
     in fast memory between calls, which a loop over ONE layer's buffer
     allows XLA and which no model does); times are a layer's. The kernel
     at each block size that fits its VMEM (128 rows only where 512 do
-    not) against XLA's masked sweep of the layer's slice, at
-    ``SERVED_DECODE_SHAPES``, over frontiers early (64, 300, 704), mixed
-    as ``decode-full`` draws them, and at the buffer's end (the cost
-    side: nothing to skip)."""
+    not, or where asked by name) against XLA's masked sweep of the
+    layer's slice, at ``shapes``, over frontiers early (64, 300), at row
+    703 (what a ``decode-full`` stream fills at most), mixed as
+    ``decode-full`` draws them, and at the buffer's end (the cost side:
+    nothing to skip). ``forms``: the kernel's forms to time (``FORMS``)."""
     from cake_tpu.ops import kvcache as kv
     from cake_tpu.ops.attention import _attend_xla, flash_decode_choice
     from cake_tpu.ops.pallas import (DECODE_BLOCK_K, decode_block_k,
@@ -128,18 +155,24 @@ def served_decode_rows(results: list, blocks=(128, 256, 512, 1024),
         return _attend_xla(q, kv.layer_view(k, layer),
                            kv.layer_view(v, layer), pos)
 
-    def kernel(bk):
+    def kernel(bk, batched):
         def run(q, pos, layer, k, v):
             return flash_decode(q, k, v, pos, layer=layer, block_k=bk,
-                                interpret=not compiled)
+                                interpret=not compiled, batched=batched)
         return run
 
-    for b, s, h, kvh, d in SERVED_DECODE_SHAPES:
+    for b, s, h, kvh, d in shapes:
         k = jax.random.normal(ks[0], (layers, b, kvh, s, d), jnp.bfloat16)
         v = jax.random.normal(ks[1], (layers, b, kvh, s, d), jnp.bfloat16)
         q = jax.random.normal(ks[2], (b, h, 1, d), jnp.bfloat16)
-        fit = decode_block_k(s, kvh, d, 2)
-        frontiers = {"64": 64, "300": 300, "704": 704, "end": s - 1,
+        fit = decode_block_k(s, kvh, d, 2, h // kvh)
+        xla_step = _layer_step(xla, layers)
+        steps = {f"pallas_{FORMS[form]}bk{bk}_ms":
+                 _layer_step(kernel(bk, form), layers)
+                 for form in forms for bk in blocks
+                 if decode_block_k(s, kvh, d, 2, h // kvh, bk) == bk
+                 and (bk >= 256 or fit < DECODE_BLOCK_K)}
+        frontiers = {"64": 64, "300": 300, "703": 703, "end": s - 1,
                      "mixed": _served_frontiers(b)}
         for name, at in frontiers.items():
             pos = jnp.minimum(jnp.broadcast_to(jnp.asarray(at, jnp.int32),
@@ -147,13 +180,11 @@ def served_decode_rows(results: list, blocks=(128, 256, 512, 1024),
             rec = {"path": "decode_stacked", "batch": b, "s": s,
                    "heads": h, "kv_heads": kvh, "d": d,
                    "layers": layers, "frontier": name,
-                   "auto_impl": flash_decode_choice(s, d, kvh),
-                   "xla_ms": round(_layer_ms(xla, q, pos, layers, k, v), 4)}
-            for bk in blocks:
-                if (decode_block_k(s, kvh, d, 2, bk) == bk
-                        and (bk >= 256 or fit < DECODE_BLOCK_K)):
-                    ms = _layer_ms(kernel(bk), q, pos, layers, k, v)
-                    rec[f"pallas_bk{bk}_ms"] = round(ms, 4)
+                   "auto_impl": flash_decode_choice(s, d, kvh, h // kvh),
+                   "xla_ms": round(_layer_ms(xla_step, q, pos, layers, k, v),
+                                   4)}
+            for key, step in steps.items():
+                rec[key] = round(_layer_ms(step, q, pos, layers, k, v), 4)
             results.append(rec)
             print(json.dumps(rec), flush=True)
 
@@ -207,7 +238,10 @@ def served_latent_rows(results: list, blocks=(256, 512, 1024),
                 return o_c / l + m
             return run
 
-        frontiers = {"64": 64, "300": 300, "704": 704, "end": s - 1,
+        xla_step = _layer_step(xla, layers)
+        steps = {bk: _layer_step(kernel(bk), layers)
+                 for bk in blocks if bk <= s}
+        frontiers = {"64": 64, "300": 300, "703": 703, "end": s - 1,
                      "mixed": _served_frontiers(b)}
         for name, at in frontiers.items():
             pos = jnp.minimum(jnp.broadcast_to(jnp.asarray(at, jnp.int32),
@@ -216,11 +250,11 @@ def served_latent_rows(results: list, blocks=(256, 512, 1024),
                    "heads": h, "dc": dc, "dr": dr, "layers": layers,
                    "frontier": name,
                    "auto_impl": latent_decode_choice(s, dc, dr),
-                   "xla_ms": round(_layer_ms(xla, q, pos, layers, c, r), 4)}
-            for bk in blocks:
-                if bk <= s:
-                    ms = _layer_ms(kernel(bk), q, pos, layers, c, r)
-                    rec[f"pallas_bk{bk}_ms"] = round(ms, 4)
+                   "xla_ms": round(_layer_ms(xla_step, q, pos, layers, c, r),
+                                   4)}
+            for bk, step in steps.items():
+                ms = _layer_ms(step, q, pos, layers, c, r)
+                rec[f"pallas_bk{bk}_ms"] = round(ms, 4)
             results.append(rec)
             print(json.dumps(rec), flush=True)
 
@@ -410,16 +444,21 @@ def main() -> int:
     configure()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json-out", default=None)
-    ap.add_argument("--only", choices=["served-decode", "served-latent"],
+    ap.add_argument("--only", choices=["served-decode", "served-latent",
+                                       "one-row"],
                     default=None,
                     help="run one section instead of the whole sweep")
     args = ap.parse_args()
     refuse_offchip_record(args.json_out)
     if args.only:
         rows: list = []
-        if args.only == "served-decode":
-            served_decode_rows(rows)
-        served_latent_rows(rows)
+        if args.only == "one-row":
+            served_decode_rows(rows, blocks=(128, 256, 384, 512),
+                               shapes=ONE_ROW_SHAPES, forms=(False, True))
+        else:
+            if args.only == "served-decode":
+                served_decode_rows(rows)
+            served_latent_rows(rows)
         if args.json_out:
             with open(args.json_out, "w") as f:
                 json.dump(rows, f, indent=1)

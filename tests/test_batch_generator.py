@@ -1545,6 +1545,41 @@ def test_decode_kernel_in_the_engine_and_its_counters(params, tp,
     assert reserved % (len(PROMPTS) * 4) == 0 and dispatches >= 2
 
 
+@pytest.mark.parametrize("tp", [1, 2])
+def test_kv_blocks_are_counted_in_the_block_the_kernel_fetches(
+        params, tp, monkeypatch):
+    """``attn.kv_blocks_*`` count in the rows of the block that the decode
+    kernel fetches of THIS cache's shape: the engine asks
+    ``pk.decode_block_k`` what the kernel asks it (the window, the LOCAL
+    KV heads of a tp mesh, the head size, the cache's bytes an element and
+    the query rows a KV head) and hands the answer to
+    ``pk.decode_blocks_read``; a shape no kernel is built for counts in
+    the default block."""
+    from cake_tpu.ops import pallas as pk
+
+    asked, counted = [], []
+    answer = [16]
+    monkeypatch.setattr(pk, "decode_block_k",
+                        lambda *a: (asked.append(a), answer[0])[1])
+    real = pk.decode_blocks_read
+    monkeypatch.setattr(
+        pk, "decode_blocks_read",
+        lambda pos, steps, s, **kw: (counted.append(kw["block_k"]),
+                                     real(pos, steps, s, **kw))[1])
+    settings = SamplerSettings(**GREEDY)
+    g = BatchGenerator(CFG, params, settings=settings, tp=tp, block_size=4)
+    assert asked == [(CFG.max_seq_len, CFG.num_key_value_heads // tp,
+                      CFG.head_dim, CFG.jax_dtype.itemsize,
+                      CFG.num_attention_heads // CFG.num_key_value_heads)]
+    g.set_prompts(PROMPTS)
+    g.generate(5)
+    g.drain()
+    assert counted and set(counted) == {16}
+    answer[0] = None  # no block fits: the default's count
+    g = BatchGenerator(CFG, params, settings=settings, tp=tp, block_size=4)
+    assert g._kv_block == pk.DECODE_BLOCK_K
+
+
 def test_a_dead_slot_decodes_at_row_zero(params, monkeypatch):
     """A slot without a live stream (retired here by ``finish``) still
     goes through every decode program, but at frontier 0, not at a

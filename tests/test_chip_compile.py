@@ -171,15 +171,22 @@ KERNELS = {
     # a batch of 32, and one stream
     "flash_decode_stacked_b32_s2048": _decode_stacked(2, 32, 2048, None),
     "flash_decode_stacked_b1_s4096": _decode_stacked(2, 1, 4096, None),
-    # wider rows of heads (an MHA 7B's 32 x 128, Gemma-7B's 16 x 256), as
-    # CAKE_PALLAS=1 forces them (auto leaves them on XLA): the KV blocks
-    # shrink to 256 rows to fit VMEM (pk.decode_block_k)
+    # wider rows of heads with ONE query row a KV head (an MHA 7B's 32 x
+    # 128, Gemma-7B's 16 x 256): the batched form in 128-row blocks
+    # (pk.decode_block_k), which auto takes since PR 50
     "flash_decode_stacked_b8_s2048_kvh32": _decode_stacked(
         2, 8, 2048, None, h=32, kvh=32),
     "flash_decode_stacked_b1_s4096_kvh32": _decode_stacked(
         2, 1, 4096, None, h=32, kvh=32),
     "flash_decode_stacked_b8_s2048_kvh16_d256": _decode_stacked(
         2, 8, 2048, None, h=16, kvh=16, d=256),
+    # the looped cell's own step (192 planes of 6 slots x 768 rows, KVH 16
+    # x G 1: the batched form, the 128-row block ``pk.decode_block_k`` gives
+    # that row of heads) and the same row of heads at 8 x 2048
+    "flash_decode_stacked_ouro_b6_s768_kvh16": _decode_stacked(
+        192, 6, 768, None, h=16, kvh=16),
+    "flash_decode_stacked_b8_s2048_kvh16": _decode_stacked(
+        2, 8, 2048, None, h=16, kvh=16),
     # the latent cells' decode step: A.X-K1's 64 heads over 8 layers of 32
     # slots x 4096 rows of 512 + 64, Ling-3.0-flash's 32 heads over its one
     # latent layer, and the floor's 1024 rows
@@ -1029,11 +1036,14 @@ def test_window_and_full_programs_move_no_cache_and_no_ring(program,
 
 def _layouts(compiled, shape: str) -> set[str]:
     """Every layout the compiled program gives a value of ``shape``
-    (``bf16[4,32,8,2048,64]``): the text between its braces."""
+    (``bf16[4,32,8,2048,64]``): the text between its braces. (What a
+    kernel's call asks of its operands, ``operand_layout_constraints``,
+    names dimension orders and no value.)"""
     import re
 
-    return set(re.findall(re.escape(shape) + r"\{([^}]*)\}",
-                          compiled.as_text()))
+    text = re.sub(r"operand_layout_constraints=\{[^=]*\}, ", "",
+                  compiled.as_text())
+    return set(re.findall(re.escape(shape) + r"\{([^}]*)\}", text))
 
 
 def test_conv_and_attention_programs_fit_one_chip(topo, as_on_chip):
@@ -1139,6 +1149,14 @@ def test_looped_programs_fit_one_chip_and_copy_no_cache(topo, as_on_chip):
     ``_attend_xla`` hands such a row to the products as a group of two,
     which takes the product every grouped-query model takes.
 
+    Since PR 50 the STEP attends through the decode kernel's batched form
+    (``flash_decode``, 128-row blocks, each stream's live rows and no
+    others), handed the two carried buffers where they lie, in the layout
+    they are declared in: RECORDED (my AOT compile, PR 50) arguments 11.719
+    GiB and temporaries 0.0009 GiB as before, no cache-sized move, the
+    kernel's blocks in VMEM scratch alone. The admissions (``T > 1``) keep
+    XLA's attention, the group of two included, and their recorded sizes.
+
     An admission re-lays the staging cache's KEYS once on the way in and
     once out (``{3,4,2,1,0}``: the rows on the lanes, K transposed for the
     chunk's score product), in ENTRY and in no loop, as every family's
@@ -1165,7 +1183,15 @@ def test_looped_programs_fit_one_chip_and_copy_no_cache(topo, as_on_chip):
             assert _cache_sized_moves(compiled, stack) == [], stack
         text = compiled.as_text()
         assert "loop.pass" in text and "loop.norm" in text
-        assert "flash_decode" not in text  # 768 rows: XLA's attention
+    # the step attends through the decode kernel (ONE query row a KV head:
+    # the batched form), once in the program, inside the layer
+    # loop (steps, ``one_step``, passes, layers: four ``while`` bodies
+    # deep), handed the carried buffers themselves; an admission (T > 1)
+    # keeps XLA's attention
+    (call,) = _decode_kernel_calls(decode)
+    assert call.count("while/body") == 4 and "loop.pass" in call, call
+    for compiled in (admit128, admit512):
+        assert "flash_decode" not in compiled.as_text()
     args, temps = _donated_bytes(decode)
     assert 11.7 * GIB < args < 11.75 * GIB, args / GIB
     assert temps < 0.01 * GIB, temps / GIB

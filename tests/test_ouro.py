@@ -332,11 +332,13 @@ def test_batch_generator_streams_match_reference(params, tensors):
     assert reg.gauge("cache.token_bytes").value == CFG.cache_token_bytes
     assert reg.gauge("cache.bytes").value == 3 * 256 * 9 * row
     assert reg.gauge("cache.state_bytes").value == 0
-    # a 256-row window is one block: every step of every stream reads and
-    # reserves one block a plane, three planes a layer
-    steps = (reserved.value - v0) / 3
+    # ONE query row a KV head: the decode kernel's blocks are 128 rows
+    # (``pk.decode_block_k``), so a 256-row window is two. Every step of
+    # every stream reserves both and reads the one its frontier lies in
+    # (no stream here passes row 127), a plane: three planes a layer
+    steps = (read.value - r0) / 3
     assert steps == int(steps) and steps >= 3 * 26
-    assert read.value - r0 == reserved.value - v0
+    assert reserved.value - v0 == 2 * (read.value - r0)
 
 
 @pytest.mark.parametrize("admit_chunk", [None, 4],

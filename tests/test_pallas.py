@@ -281,17 +281,11 @@ def test_auto_dispatch_measured_crossover(monkeypatch):
     assert calls == ["decode"]
 
 
-@pytest.mark.parametrize("group", [4, 1])
-@pytest.mark.parametrize("window", [None, 200])
-@pytest.mark.parametrize("stacked", [True, False])
-def test_flash_decode_row_frontiers_on_carried_cache(stacked, window, group):
-    """The decode kernel (all KV heads of a stream's block at once)
-    against the XLA oracle, bf16: on the stacked ``[L, B, KVH, S, D]``
-    cache the layer loop carries, with a TRACED layer index, and on one
-    layer's own buffer; per-row frontiers at the first row, the block's
-    edges, 703 (what ``decode-full`` fills at most) and the buffer's end,
-    mixed in one batch; with and without a window shorter than the
-    buffer.
+def _row_frontiers_against_xla(kvh, group, s, d, bk, frontiers, window=None,
+                               stacked=True, layers=3, **kernel):
+    """The decode kernel at per-row ``frontiers`` against the XLA oracle,
+    bf16, on the stacked ``[L, B, KVH, S, D]`` cache with a TRACED layer
+    index (or one layer's own buffer).
 
     The kernel reads the blocks of ``decode_block_range`` (what the
     engine's ``attn.kv_blocks_*`` counters sum) and no others: every
@@ -302,8 +296,6 @@ def test_flash_decode_row_frontiers_on_carried_cache(stacked, window, group):
     from cake_tpu.ops.attention import _attend_xla
     from cake_tpu.ops.pallas import decode_block_range
 
-    layers, kvh, s, d, bk = 3, 2, 1024, 16, 128
-    frontiers = [0, 1, bk - 1, bk, 703, s - 1]
     b, h = len(frontiers), kvh * group
     pos = jnp.asarray(frontiers, jnp.int32)
     q, k_one, v_one = _qkv(jax.random.PRNGKey(11), b, h, kvh, 1, s, d,
@@ -311,7 +303,7 @@ def test_flash_decode_row_frontiers_on_carried_cache(stacked, window, group):
     ref = _attend_xla(q, k_one, v_one, pos, window=window)
     lo, hi = decode_block_range(np.asarray(frontiers), bk, s // bk, window,
                                 xp=np)
-    assert ((hi - lo + 1) >= 1).all() and hi.max() == s // bk - 1
+    assert ((hi - lo + 1) >= 1).all()
     block = np.arange(s) // bk
     counted = (block >= lo[:, None]) & (block <= hi[:, None])  # [B, S]
     counted = jnp.asarray(counted)[:, None, :, None]
@@ -325,16 +317,51 @@ def test_flash_decode_row_frontiers_on_carried_cache(stacked, window, group):
         def run(layer):
             return flash_decode(
                 q, k_all.at[layer].set(k_one), v_all.at[layer].set(v_one),
-                pos, layer=layer, block_k=bk, window=window, interpret=True)
+                pos, layer=layer, block_k=bk, window=window, interpret=True,
+                **kernel)
 
         out = run(jnp.int32(1))
     else:
         out = flash_decode(q, k_one, v_one, pos, block_k=bk, window=window,
-                           interpret=True)
+                           interpret=True, **kernel)
     assert out.dtype == q.dtype and out.shape == q.shape
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("group", [4, 1])
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("stacked", [True, False])
+def test_flash_decode_row_frontiers_on_carried_cache(stacked, window, group):
+    """The decode kernel (all KV heads of a stream's block at once)
+    against the XLA oracle (:func:`_row_frontiers_against_xla`): on the
+    stacked cache the layer loop carries and on one layer's own buffer;
+    per-row frontiers at the first row, the block's edges, 703 (what
+    ``decode-full`` fills at most) and the buffer's end, mixed in one
+    batch; with and without a window shorter than the buffer; a group of
+    query rows a KV head and ONE (the form whose keys stream)."""
+    s, bk = 1024, 128
+    _row_frontiers_against_xla(2, group, s, 16, bk,
+                               [0, 1, bk - 1, bk, 703, s - 1],
+                               window=window, stacked=stacked)
+
+
+@pytest.mark.parametrize("frontiers", [(0, 127, 128, 255, 256, 767),
+                                       (511, 703, 767, 0, 384, 383)])
+@pytest.mark.parametrize("bk", [None, 128, 256, 384])
+def test_flash_decode_one_query_row_at_the_looped_cells_shape(bk, frontiers):
+    """The one-query-row form at the shape ``ouro-2p6b.decode-full``
+    serves (KVH 16 x G 1, D 128, 6 slots x 768 rows, the plane's index
+    traced): every block size of the sweep and the one
+    ``decode_block_k`` gives this row of heads, frontiers at the first
+    row, at every block's two edges, at row 703 (what a stream of the
+    cell fills at most) and at the buffer's end, mixed in one batch."""
+    from cake_tpu.ops.pallas import decode_block_k
+
+    kvh, s, d = 16, 768, 128
+    bk = bk or decode_block_k(s, kvh, d, 2, 1)
+    _row_frontiers_against_xla(kvh, 1, s, d, bk, list(frontiers), layers=2)
 
 
 @pytest.mark.parametrize("pos,steps,window,want", [
@@ -354,6 +381,24 @@ def test_decode_blocks_read_counts_what_the_kernel_fetches(pos, steps,
     from cake_tpu.ops.pallas import decode_blocks_read
 
     assert decode_blocks_read(pos, steps, 2048, 512, window) == want
+
+
+@pytest.mark.parametrize("bk,pos,steps,want", [
+    # 6 slots x 768 rows, as ``ouro-2p6b.decode-full`` holds them
+    (128, [0, 127, 128, 383, 384, 703], 1, (1 + 1 + 2 + 3 + 4 + 6, 36)),
+    (256, [0, 127, 128, 383, 384, 703], 1, (1 + 1 + 1 + 2 + 2 + 3, 18)),
+    (384, [0, 127, 128, 383, 384, 703], 1, (1 + 1 + 1 + 1 + 2 + 2, 12)),
+    (384, [380], 8, (4 + 4 * 2, 16)),  # crosses into the second block
+    (512, [0, 703], 1, (2, 2)),  # the default's block: one of one, 100%
+])
+def test_decode_blocks_read_in_the_one_row_forms_blocks(bk, pos, steps,
+                                                        want):
+    """The same arithmetic in the blocks a 768-row cache is fetched in:
+    counted in 512-row blocks (which do not divide it) every frontier
+    reads one block of one, whatever the kernel fetches."""
+    from cake_tpu.ops.pallas import decode_blocks_read
+
+    assert decode_blocks_read(pos, steps, 768, block_k=bk) == want
 
 
 def _latent_rows(key, b, h, s, dc, dr, dtype):
@@ -569,8 +614,96 @@ def test_attend_picks_the_decode_kernel_by_what_it_sees(case, monkeypatch):
     # a decode trace says which attention it took; a chunk's says nothing
     assert gauge.value == (-1 if t > 1 else int(want == "decode"))
     if not int8:  # the policy for a plain cache, whatever T the caller has
-        assert attn.flash_decode_choice(s, d, kvh) == (
+        assert attn.flash_decode_choice(s, d, kvh, h // kvh) == (
             "flash" if want == "decode" or t > 1 else "xla")
+
+
+DECODE_POLICY = {
+    # the decode shape of every accepted cell that attends through
+    # ``ops/attention.py``: (rows, KV heads, group, head size) -> (choice,
+    # rows of the kernel's block). Every row but the last is what PR 47's
+    # tree answered; ``axk1-ep16-cut`` and ``ling3flash-ep4-cut`` attend
+    # through ``ops/mla.py`` / ``ops/kda.py`` and never ask.
+    "mistral7b-int8": ((2048, 8, 4, 128), ("flash", 512)),
+    "mixtral8x7b-cut": ((4096, 8, 4, 128), ("flash", 512)),
+    "jamba2-3b": ((2048, 1, 20, 128), ("flash", 512)),
+    "kexaone-ep8-cut_full_layer": ((4096, 8, 8, 128), ("flash", 512)),
+    "lfm2-8b-a1b-cut": ((2048, 8, 4, 64), ("xla", 512)),
+    # rows of heads beside the cells', as they were: a tp=2 mesh's local
+    # heads, a group at 768 rows (256-row blocks: XLA), an MHA 7B's 32 x
+    # 128 and Gemma-7B's 16 x 256 (blocks that overflow VMEM shrink: XLA)
+    "local_heads_of_a_tp_mesh": ((2048, 4, 4, 128), ("flash", 512)),
+    "a_group_at_768_rows": ((768, 8, 2, 128), ("xla", 256)),
+    # ONE query row a KV head (PR 50): the kernel's batched form in 128-row
+    # blocks, at the looped cell's 768 rows (XLA until then: 256-row blocks
+    # of the loop over heads), at 2048 (the loop at 512 rows until then),
+    # at an MHA 7B's 32 x 128 and Gemma-7B's 16 x 256 (XLA until then: the
+    # loop's blocks overflowed VMEM at 512 rows); not under 768 rows, nor
+    # at a row of heads wider than the sweep has
+    "ouro-2p6b": ((768, 16, 1, 128), ("flash", 128)),
+    "one_row_at_2048": ((2048, 16, 1, 128), ("flash", 128)),
+    "mha_32_heads": ((2048, 32, 1, 128), ("flash", 128)),
+    "heads_of_256": ((2048, 16, 1, 256), ("flash", 128)),
+    "one_row_under_768": ((640, 16, 1, 128), ("xla", 128)),
+    "one_row_wider_than_swept": ((2048, 64, 1, 128), ("xla", 128)),
+    "one_row_heads_of_64": ((2048, 16, 1, 64), ("xla", 128)),
+}
+
+
+@pytest.mark.parametrize("cell", list(DECODE_POLICY))
+def test_decode_policy_of_every_accepted_cell(cell, monkeypatch):
+    """``flash_decode_choice`` and ``decode_block_k`` on the chip
+    (``pk.on_tpu`` steered here), from the shapes and nothing else: a
+    group of query rows a KV head keeps the answer and the 512-row block
+    it had; ONE row a KV head takes the kernel in its own block, from
+    768 rows up and to the widest row of heads the sweep has."""
+    import cake_tpu.ops.attention as attn
+    from cake_tpu.ops import pallas as pk
+
+    monkeypatch.delenv("CAKE_PALLAS", raising=False)
+    monkeypatch.setattr(pk, "on_tpu", lambda: True)
+    (s, kvh, group, d), want = DECODE_POLICY[cell]
+    assert (attn.flash_decode_choice(s, d, kvh, group),
+            pk.decode_block_k(s, kvh, d, 2, group)) == want
+
+
+GROUPED_KERNELS = {
+    # sha256[:16] of the traced kernel (``jax.make_jaxpr`` of
+    # ``flash_decode`` on the stacked cache: the call, its block and its
+    # body), taken on PR 47's tree (commit f251505) at the decode shape of
+    # every accepted cell that runs it: (slots, rows, heads, KV heads,
+    # head size, window)
+    "mistral7b-int8": ((8, 2048, 32, 8, 128, 4096), "20617ef0422db597"),
+    "mixtral8x7b-cut": ((8, 4096, 32, 8, 128, None), "8eecfc325017978f"),
+    "jamba2-3b": ((32, 2048, 20, 1, 128, None), "6c02703a1ae8d65d"),
+    "kexaone-ep8-cut_full_layer": ((32, 4096, 64, 8, 128, None),
+                                   "7084585ffe2da0ef"),
+    "local_heads_of_a_tp_mesh": ((8, 2048, 16, 4, 128, None),
+                                 "f02784abd9283111"),
+}
+
+
+@pytest.mark.parametrize("cell", list(GROUPED_KERNELS))
+def test_a_group_of_query_rows_keeps_the_kernel_it_had(cell):
+    """The one-query-row form (PR 50) is a branch of the decode kernel
+    that a group of query rows a KV head never takes: where G >= 2 the
+    traced kernel, block and body, is the text PR 47's tree gave, so the
+    five cells that run it are measured on the program they had. A PR
+    that changes the grouped form on purpose replaces these, and says
+    so."""
+    import hashlib
+
+    (b, s, h, kvh, d, window), want = GROUPED_KERNELS[cell]
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    text = str(jax.make_jaxpr(
+        lambda q, k, v, pos, layer: flash_decode(
+            q, k, v, pos, layer=layer, window=window, interpret=False))(
+        shape(b, h, 1, d), shape(2, b, kvh, s, d), shape(2, b, kvh, s, d),
+        shape(b, dtype=jnp.int32), shape(dtype=jnp.int32)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
 
 
 @pytest.mark.parametrize("pos", [0, 5])
