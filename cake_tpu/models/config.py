@@ -201,6 +201,22 @@ class LlamaConfig:
     # the gate then changes no logit.
     total_ut_steps: int = 1
     early_exit_threshold: float = 1.0
+    # --- a residual stream several hidden vectors wide (manifold-
+    # constrained hyper-connections; HF `model_type` "xing4_0", beside the
+    # latent family's keys) --------------------------------------------------
+    # ``hc_mult`` n > 1: a token's state between sub-layers is ``X [n,
+    # hidden]`` (the embedding n times over at the entry, the streams' sum
+    # before the last norm). Each sub-layer reads ``u = sum_j H_pre[j]
+    # X[j]`` and leaves ``X'[i] = H_post[i] y + sum_j H_res[i, j] X[j]``,
+    # the 2n + n^2 coefficients a function of the token's own state
+    # (ops/hyper.py): ``H_res`` is ``exp`` of logits clamped to
+    # ``hc_res_clamp`` taken through ``hc_sinkhorn_iters`` rounds of row
+    # and column normalisation (``hc_eps`` in each division), so that it
+    # is doubly stochastic. 1: the plain residual every other model has.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple[float, float] = (-30.0, 30.0)
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -209,6 +225,7 @@ class LlamaConfig:
                 self.hidden_size // self.num_attention_heads,
             )
         self.family.check(self)
+        families.check_residual_path(self)
         # validate at construction, not as a KeyError deep in a jit trace
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
@@ -263,6 +280,12 @@ class LlamaConfig:
         stream, in the serving type: every plane's keys and values."""
         planes = self.cache_plan.get("rows", (0,))[0]
         return planes * self.cache_row_values * self.jax_dtype.itemsize
+
+    @property
+    def resid_token_bytes(self) -> int:
+        """Bytes one token's residual state holds between sub-layers, in
+        the serving type: ``hc_mult`` hidden vectors."""
+        return self.hc_mult * self.hidden_size * self.jax_dtype.itemsize
 
     @property
     def mamba_d_inner(self) -> int:
@@ -718,6 +741,57 @@ def jamba2_3b(**overrides) -> LlamaConfig:
     return LlamaConfig(**base)
 
 
+def xing4_29b(**overrides) -> LlamaConfig:
+    """Xing4.0-29B-A4B (https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B,
+    `model_type` "xing4_0", DeepSeek-V3's keys and the ``hc_*`` ones) at
+    its published sizes: 40 layers of latent attention (32 heads of 128 +
+    64, YaRN factor 64) under a residual stream FOUR hidden vectors wide
+    that every sub-layer mixes by a per-token doubly stochastic matrix (20
+    Sinkhorn rounds); two leading dense layers (9216), then all 64
+    bias-corrected sigmoid-scored experts (1024) top-4 beside a shared
+    one; an untied head. A chip serves the depth of its pipeline stage
+    (`num_hidden_layers=`, `first_k_dense_replace=`)."""
+    base = dict(
+        model_type="xing4_0",
+        vocab_size=131072,
+        hidden_size=3584,
+        intermediate_size=9216,
+        num_hidden_layers=40,
+        num_attention_heads=32,
+        num_key_value_heads=32,
+        rms_norm_eps=1e-6,
+        rope_theta=10000.0,
+        rope_scaling={"type": "yarn", "factor": 64, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 4096},
+        max_seq_len=262144,
+        q_lora_rank=768,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        first_k_dense_replace=2,
+        moe_intermediate_size=1024,
+        n_shared_experts=1,
+        n_routed_experts=64,
+        num_experts_per_tok=4,
+        scoring_func="sigmoid",
+        router_bias=True,
+        n_group=1,
+        topk_group=1,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.0,
+        hc_mult=4,
+        hc_sinkhorn_iters=20,
+        hc_eps=1e-6,
+        hc_res_clamp=(-30.0, 30.0),
+        bos_token_id=0,
+        eos_token_id=1,
+    )
+    base.update(overrides)
+    return LlamaConfig(**base)
+
+
 def ouro_2_6b(**overrides) -> LlamaConfig:
     """Ouro-2.6B (https://huggingface.co/ByteDance/Ouro-2.6B, `model_type`
     "ouro") at its published sizes: 48 sandwich-normed layers of 16 query
@@ -905,6 +979,25 @@ def tiny_mla_moe(**overrides) -> LlamaConfig:
     )
     base.update(overrides)
     return tiny(**base)
+
+
+def tiny_xing4(**overrides) -> LlamaConfig:
+    """Tiny fixture of the latent family under a wide residual stream
+    (Xing4.0's keys): four hidden vectors a token, 20 Sinkhorn rounds, one
+    leading dense layer then two expert layers of 16 bias-corrected
+    sigmoid-scored experts top-4 in ONE group beside a shared one, YaRN
+    over a short original window."""
+    base = dict(
+        model_type="xing4_0",
+        router_bias=True,
+        n_group=1,
+        topk_group=1,
+        routed_scaling_factor=2.0,
+        rms_norm_eps=1e-6,
+        hc_mult=4,
+    )
+    base.update(overrides)
+    return tiny_mla_moe(**base)
 
 
 def tiny_kda_hybrid(**overrides) -> LlamaConfig:

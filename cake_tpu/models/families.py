@@ -16,8 +16,12 @@ layer AND a pass; keys ``total_ut_steps``, ``early_exit_threshold``;
 limits: threshold 1 only, every layer full attention, no window, no rope
 scaling, slot layout, no sharding, no quantized tier), ``SHORT_CONV``
 (``lfm2_moe``), ``WINDOWED`` (``exaone_moe``), ``STATE_SPACE`` (``jamba``),
-``HYBRID`` (``bailing_hybrid``), ``LATENT`` (``deepseek_v3``, ``axk1``) and
-``GQA``, the bare stack every other ``model_type`` is read as.
+``HYBRID`` (``bailing_hybrid``), ``LATENT`` (``deepseek_v3``, ``axk1``,
+``xing4_0``) and ``GQA``, the bare stack every other ``model_type`` is read
+as. A residual stream several hidden vectors wide (``hc_mult`` > 1:
+manifold-constrained hyper-connections, ``ops/hyper.py``) is no mixer and
+no record of its own: ``LATENT`` reads its keys, every other family
+refuses them (``check_residual_path``).
 """
 
 from __future__ import annotations
@@ -153,6 +157,14 @@ _LATENT_EXPERT_MAP = {
     "w_down": "mlp.experts.{e}.down_proj.weight",
 }
 
+# A residual stream ``hc_mult`` hidden vectors wide (`model_type` "xing4_0";
+# the names are ASSUMED, the benchmark configuration lists them): each
+# sub-layer's projection to its ``n^2 + 2n`` mixing coefficients as a torch
+# linear ``[n^2 + 2n, n hidden]``, their biases and the three gains (pre,
+# post, res), all float32 whatever the serving type.
+_HC_MAP = {f"hc_{part}_{t}": (f"hc_{part}_{t}", t == "fn")
+           for part in ("attn", "ffn") for t in ("fn", "base", "scale")}
+
 # Delta-rule layers beside latent ones (`model_type` "bailing_hybrid"; the
 # names are ASSUMED, the benchmark configuration lists them: FLA's KDA
 # module under `self_attn.`, the convolutions as torch depthwise `[C, 1,
@@ -174,8 +186,10 @@ _KDA_MAP = {
 _HYBRID_EXTRA_MAP = {
     "wq": ("self_attn.q_proj.weight", True),
     "wg": ("self_attn.g_proj.weight", True),
-    "b_router": ("mlp.gate.expert_bias", False),
 }
+# the router's correction bias, under each family's own name
+_HYBRID_BIAS = {"b_router": ("mlp.gate.expert_bias", False)}
+_LATENT_BIAS = {"b_router": ("mlp.gate.e_score_correction_bias", False)}
 
 # Window and full grouped-query attention mixed by layer, with the
 # shared-expert feed-forward (`model_type` "exaone_moe"; the names are
@@ -188,8 +202,7 @@ _WINDOWED_MAP = {
                                   "mlp_norm")},
     "q_norm": ("self_attn.q_norm.weight", False),
     "k_norm": ("self_attn.k_norm.weight", False),
-    **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP,
-    "b_router": ("mlp.gate.e_score_correction_bias", False),
+    **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP, **_LATENT_BIAS,
 }
 
 # State-space layers beside grouped-query attention (`model_type` "jamba",
@@ -406,18 +419,38 @@ GQA = Family(model_types=(), selects=lambda c: True, read=_gqa_read)
 # --- latent attention, shared and routed experts ----------------------------
 
 def _latent_read(d: dict) -> dict:
-    """DeepSeek-V3's keys. `topk_method` is read as the group-limited
-    choice n_group/topk_group describe with no correction bias tensor
-    ("none", "group_limited_greedy"); "noaux_tc" needs the bias and is
-    refused rather than served without it."""
-    if d.get("topk_method", "none") not in (
-            "none", "greedy", "group_limited_greedy"):
-        raise ValueError(
-            f"topk_method {d['topk_method']!r} (a routing "
-            "correction bias) is not wired")
+    """DeepSeek-V3's keys. `topk_method` "noaux_tc" is the choice on
+    ``score + e_score_correction_bias`` (``router_bias``: the tensor is
+    then asked of the checkpoint); "none", "greedy" and
+    "group_limited_greedy" are the group-limited choice n_group/topk_group
+    describe with no bias tensor. ``hc_mult`` > 1 widens the residual
+    stream (``hc_sinkhorn_iters``, ``hc_eps``, ``mhc_h_res_clamp_min/max``:
+    ops/hyper.py). ``num_nextn_predict_layers`` (a next-token prediction
+    block, the layer past ``num_hidden_layers`` or ``mtp.*``) is read and
+    ignored: the block takes no part in the model's own logits and the
+    loaders skip its tensors."""
+    method = d.get("topk_method", "none")
+    if method not in ("none", "greedy", "group_limited_greedy", "noaux_tc"):
+        raise ValueError(f"topk_method {method!r} is not wired")
     if d.get("moe_layer_freq", 1) != 1:
         raise ValueError("moe_layer_freq != 1 is not wired")
-    return _expert_share(d, d.get("n_routed_experts", 0))
+    out = {"router_bias": method == "noaux_tc",
+           **_expert_share(d, d.get("n_routed_experts", 0))}
+    if d.get("hc_mult", 1) > 1:
+        out["hc_res_clamp"] = (float(d.get("mhc_h_res_clamp_min", -30.0)),
+                               float(d.get("mhc_h_res_clamp_max", 30.0)))
+    return out
+
+
+def _latent_write(c, d: dict):
+    if d.pop("router_bias"):
+        d["topk_method"] = "noaux_tc"
+    if c.hc_mult == 1:  # the plain residual: none of its keys
+        for f in _HC_FIELDS:
+            d.pop(f)
+    else:
+        (d["mhc_h_res_clamp_min"],
+         d["mhc_h_res_clamp_max"]) = d.pop("hc_res_clamp")
 
 
 def _latent_check(c):
@@ -432,14 +465,38 @@ def _latent_check(c):
     _check_told_share(c)
 
 
+def check_residual_path(c):
+    """What a residual stream ``hc_mult`` hidden vectors wide may ask for
+    (``LlamaConfig.__post_init__``, every family): the latent family's
+    two sub-layers alone are wrapped in its mixes."""
+    if c.hc_mult == 1:
+        return
+    lo, hi = c.hc_res_clamp
+    if c.hc_mult < 1 or c.hc_sinkhorn_iters < 1 or not lo < hi:
+        raise ValueError(
+            f"hc_mult {c.hc_mult} / hc_sinkhorn_iters {c.hc_sinkhorn_iters}"
+            f" / clamp ({lo}, {hi}) is no residual stream of one or more "
+            "hidden vectors mixed by one or more Sinkhorn rounds")
+    if c.family is not LATENT:
+        raise ValueError(
+            f"hc_mult = {c.hc_mult} (a residual stream several hidden "
+            "vectors wide, mixed round every sub-layer) is wired for the "
+            f"latent-attention family alone ({sorted(LATENT.model_types)}),"
+            f" not beside the layers of model_type {c.model_type!r}")
+    object.__setattr__(c, "hc_res_clamp", (float(lo), float(hi)))
+
+
+_LATENT_FIELDS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                  "qk_rope_head_dim", "v_head_dim") + _EXPERT_FIELDS
+_HC_FIELDS = ("hc_mult", "hc_sinkhorn_iters", "hc_eps", "hc_res_clamp")
+
 LATENT = Family(
-    model_types=("deepseek_v3", "axk1"),
+    model_types=("deepseek_v3", "axk1", "xing4_0"),
     selects=lambda c: c.kv_lora_rank > 0,
-    fields=("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-            "qk_rope_head_dim", "v_head_dim") + _EXPERT_FIELDS,
-    read=_latent_read, check=_latent_check,
+    fields=_LATENT_FIELDS + _HC_FIELDS,
+    read=_latent_read, write=_latent_write, check=_latent_check,
     tensor_names={**_LATENT_MAP, **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP,
-                  **_HYBRID_EXTRA_MAP, **_KDA_MAP},
+                  **_HYBRID_EXTRA_MAP, **_LATENT_BIAS, **_HC_MAP},
     expert_names=_LATENT_EXPERT_MAP,
     probe=".self_attn.kv_a_proj_with_mqa.weight",
     what="a latent-attention model", shard_axes=frozenset(("ep",)),
@@ -525,8 +582,10 @@ def _hybrid_check(c):
 HYBRID = dataclasses.replace(
     LATENT, model_types=("bailing_hybrid",),
     selects=lambda c: c.layer_group_size > 0,
-    fields=LATENT.fields + _HYBRID_FIELDS,
+    fields=_LATENT_FIELDS + _HYBRID_FIELDS,
     read=_hybrid_read, write=_hybrid_write, check=_hybrid_check,
+    tensor_names={**_LATENT_MAP, **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP,
+                  **_HYBRID_EXTRA_MAP, **_HYBRID_BIAS, **_KDA_MAP},
     recurrent_mixer="kda")
 
 

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from cake_tpu.models.config import LlamaConfig
-from cake_tpu.ops import quant
+from cake_tpu.ops import hyper, quant
 from cake_tpu.ops.attention import (
     self_attention_block,
     window_attention_block,
@@ -176,6 +176,22 @@ _CONV_SHAPES = {
     "w_out": lambda c: (c.hidden_size, c.hidden_size),
     "mlp_norm": lambda c: (c.hidden_size,),
 }
+
+
+# A residual stream ``hc_mult`` hidden vectors wide (ops/hyper.py): each of a
+# layer's two sub-layers has its own projection to the ``n^2 + 2 n`` mixing
+# coefficients, their biases and the three gains; float32 whatever the
+# serving type (``HC_TENSORS``: never cast, never quantised).
+_HC_SHAPES = {
+    f"hc_{part}_{name}": shape
+    for part in ("attn", "ffn")
+    for name, shape in (
+        ("fn", lambda c: (c.hc_mult * c.hidden_size,
+                          c.hc_mult * (c.hc_mult + 2))),
+        ("base", lambda c: (c.hc_mult * (c.hc_mult + 2),)),
+        ("scale", lambda c: (3,)))
+}
+HC_TENSORS = frozenset(_HC_SHAPES)
 
 
 class Segment(NamedTuple):
@@ -349,6 +365,8 @@ def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
                 * (c.qk_nope_head_dim + c.qk_rope_head_dim))
         if config.attn_gate:  # a sigmoid gate a head
             shapes["wg"] = lambda c: (c.hidden_size, c.num_attention_heads)
+        if config.hc_mult > 1:  # the two sub-layers' mixing coefficients
+            shapes.update(_HC_SHAPES)
     if seg.ffn == "dense":
         shapes.update({k: _LAYER_SHAPES[k]
                        for k in ("w_gate", "w_up", "w_down")})
@@ -435,6 +453,8 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
                          iter(jax.random.split(k, len(shapes))))
             if seg.mixer == "mamba":
                 flat.update(_mamba_init(config, flat, k, dt))
+            if config.hc_mult > 1:
+                flat.update(_hc_init(config, flat, k))
             lead = run.layer_ids(seg).shape
             layers[seg.name] = {n: w.reshape(lead + w.shape[1:])
                                 for n, w in flat.items()}
@@ -459,6 +479,28 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
                         config.hidden_size)
             for n, (part, (_, shape_fn)) in enumerate(parts.items())}
     return params
+
+
+def _hc_init(config: LlamaConfig, stack: dict, key) -> dict:
+    """A wide residual stream's tensors for a stack of layers, float32,
+    seeded so that the mechanism works: gains of 1, ``phi`` of std
+    ``1 / sqrt(n C)`` (a logit of std 1 on a normed stream), biases of std 1
+    with 2 more on the diagonal of ``H_res``'s, so that ``H_res`` is
+    neither the identity nor uniform and differs from token to token."""
+    n = config.hc_mult
+    diagonal = jnp.zeros((n * (n + 2),), jnp.float32).at[
+        2 * n + (n + 1) * jnp.arange(n)].set(2.0)
+    out = {}
+    for i, part in enumerate(("attn", "ffn")):
+        k_fn, k_base = jax.random.split(jax.random.fold_in(key, 51 + i))
+        fn = stack[f"hc_{part}_fn"]
+        out[f"hc_{part}_fn"] = jax.random.normal(
+            k_fn, fn.shape, jnp.float32) / jnp.sqrt(fn.shape[-2])
+        out[f"hc_{part}_base"] = diagonal + jax.random.normal(
+            k_base, stack[f"hc_{part}_base"].shape, jnp.float32)
+        out[f"hc_{part}_scale"] = jnp.ones(
+            stack[f"hc_{part}_scale"].shape, jnp.float32)
+    return out
 
 
 def _mamba_init(config: LlamaConfig, stack: dict, key, dt) -> dict:
@@ -589,11 +631,15 @@ def embed_tokens(params: Params, tokens, config: LlamaConfig) -> jax.Array:
     execution path (local, pipeline builders, admission, speculation).
     Gemma multiplies the embedding output by sqrt(hidden) (``embed_scale``),
     with the normalizer rounded to the activation dtype exactly as HF does,
-    so family deltas cannot drift between paths."""
+    so family deltas cannot drift between paths. Under a residual stream
+    ``hc_mult`` hidden vectors wide the result is ``[B, T, hc_mult,
+    hidden]``, the embedding in every stream (:func:`head_norm` sums them
+    again; what lies between takes the last position with ``x[:, -1]``
+    and asks neither for the hidden size nor for the rank)."""
     x = params["embed"][tokens].astype(config.jax_dtype)
     if config.embed_scale:
         x = x * jnp.asarray(config.hidden_size ** 0.5, config.jax_dtype)
-    return x
+    return hyper.widen(x, config.hc_mult)
 
 
 def block_forward(
@@ -649,12 +695,12 @@ def block_forward(
     through :func:`cake_tpu.ops.mla.latent_attention_block` and its expert
     layers add shared experts (``ws_*``) to the routed part.
     """
-    h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps,
-                   offset=config.rms_norm_offset)
     if "wkv_a" in layer:
-        return _latent_block(layer, x, h, k_cache, v_cache, cos, sin, pos,
+        return _latent_block(layer, x, k_cache, v_cache, cos, sin, pos,
                              config, write_gate, ep_axis, ep_size,
                              layer_idx, count_local, expert_idx)
+    h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps,
+                   offset=config.rms_norm_offset)
     attn_out, k_cache, v_cache = self_attention_block(
         h, layer["wq"], layer["wk"], layer["wv"], layer["wo"],
         k_cache, v_cache, cos, sin, pos,
@@ -695,17 +741,39 @@ def block_forward(
     return x + mlp_out, k_cache, v_cache
 
 
-def _latent_block(layer, x, h, c_cache, r_cache, cos, sin, pos, config,
+def _sub_layer(layer, x, part: str, norm: str, config, f):
+    """One sub-layer of a latent-family layer with its residual: ``x +
+    F(RMS(x))``, ``f(normed) -> (y, aux)``; returns ``(x, aux)``. Where the
+    layer holds ``hc_<part>_*`` (the pytree decides, as for ``bq`` or
+    ``router``) ``x`` is a residual stream's hidden vectors and the
+    sub-layer reads their mix and leaves its output mixed into all of them
+    (ops/hyper.py)."""
+    if f"hc_{part}_fn" not in layer:
+        y, aux = f(rms_norm(x, layer[norm], config.rms_norm_eps))
+        return x + y, aux
+    co = hyper.coefficients(
+        x, tuple(layer[f"hc_{part}_{t}"] for t in ("fn", "base", "scale")),
+        config)
+    y, aux = f(rms_norm(hyper.pre_mix(x, co), layer[norm],
+                        config.rms_norm_eps))
+    return hyper.post_mix(x, y, co), aux
+
+
+def _latent_block(layer, x, c_cache, r_cache, cos, sin, pos, config,
                   write_gate, ep_axis, ep_size, layer_idx, count_local,
                   expert_idx):
-    """The rest of :func:`block_forward` for a latent-attention layer:
-    ``h`` is the normed input."""
-    with jax.named_scope("mla"):
-        attn_out, c_cache, r_cache = latent_attention_block(
-            h, layer, c_cache, r_cache, cos, sin, pos, config,
-            write_gate=write_gate, layer_idx=layer_idx)
-    x, local = _shared_feed_forward(layer, x + attn_out, config, ep_axis,
-                                    ep_size, count_local, expert_idx)
+    """:func:`block_forward` for a latent-attention layer."""
+    def attend(h):
+        with jax.named_scope("mla"):
+            out, c, r = latent_attention_block(
+                h, layer, c_cache, r_cache, cos, sin, pos, config,
+                write_gate=write_gate, layer_idx=layer_idx)
+        return out, (c, r)
+
+    x, (c_cache, r_cache) = _sub_layer(layer, x, "attn", "attn_norm", config,
+                                       attend)
+    x, local = _shared_feed_forward(layer, x, config, ep_axis, ep_size,
+                                    count_local, expert_idx)
     if count_local:
         return x, c_cache, r_cache, local
     return x, c_cache, r_cache
@@ -713,13 +781,16 @@ def _latent_block(layer, x, h, c_cache, r_cache, cos, sin, pos, config,
 
 def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
                          expert_idx):
-    """The feed-forward half of a latent-family layer, residual added. A
-    dense layer (no ``router``) is a SwiGLU of ``intermediate_size``; an
-    expert layer is ``shared(h) + sum over the chosen experts HELD here
-    of w_e expert_e(h)``. Returns ``(x, ExpertCount)``."""
-    h = rms_norm(x, layer["mlp_norm"], config.rms_norm_eps)
-    local = ExpertCount.zeros(x.shape[0])
-    if "router" in layer:
+    """The feed-forward half of a latent-family layer, residual added
+    (:func:`_sub_layer`). A dense layer (no ``router``) is a SwiGLU of
+    ``intermediate_size``; an expert layer is ``shared(h) + sum over the
+    chosen experts HELD here of w_e expert_e(h)``. Returns ``(x,
+    ExpertCount)``."""
+    def feed(h):
+        local = ExpertCount.zeros(h.shape[0])
+        if "router" not in layer:
+            return swiglu(h, layer["w_gate"], layer["w_up"],
+                          layer["w_down"]), local
         y = moe_swiglu(
             h, layer["router"], layer["w_gate"], layer["w_up"],
             layer["w_down"], top_k=config.num_experts_per_tok,
@@ -738,10 +809,9 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
             with jax.named_scope("moe.shared"):
                 y = y + swiglu(h, layer["ws_gate"], layer["ws_up"],
                                layer["ws_down"])
-        x = x + y
-    else:
-        x = x + swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"])
-    return x, local
+        return y, local
+
+    return _sub_layer(layer, x, "ffn", "mlp_norm", config, feed)
 
 
 def _kda_block(layer, x, cache, config, valid, ep_axis, ep_size, layer_idx,
@@ -897,6 +967,10 @@ def forward_layers(
     1); one stage, tp = sp = 1.
     """
     rows = x.shape[0] * x.shape[1]
+    batch = x.shape[0]
+    wide = config.hc_mult > 1
+    if wide:  # the loops carry the stream's hidden vectors apart
+        x = hyper.split(x)
 
     def split(stack):
         """``(scanned, whole)``: a stack's expert matrices taken out of
@@ -1002,7 +1076,7 @@ def forward_layers(
 
     carry = (x, cache)
     if count_local:
-        carry += (ExpertCount.zeros(x.shape[0]),)
+        carry += (ExpertCount.zeros(batch),)
     if not config.segmented:  # one kind of layer, one bare stack
         stack, whole = split(layers)
         return scan_segment(carry, stack, 0, whole)
@@ -1021,7 +1095,8 @@ def forward_layers(
         return carry
 
     if not config.family.loops:
-        return one_pass(carry)
+        h, *rest = one_pass(carry)
+        return (hyper.join(h) if wide else h, *rest)
     if pass_norm is None:
         raise ValueError(
             "a looped model's layer loop closes each pass with the model's "
@@ -1049,9 +1124,12 @@ def pass_norm(params: Params, config: LlamaConfig):
 def head_norm(params: Params, x: jax.Array, config: LlamaConfig) -> jax.Array:
     """The model's last norm, before the head: THE place every path norms
     what it hands the head. A looped family's layer loop has applied it
-    already (the last pass's closing norm), so its head norms nothing."""
+    already (the last pass's closing norm), so its head norms nothing. A
+    residual stream several hidden vectors wide (``x [.., hc_mult,
+    hidden]``) leaves as their sum."""
     if config.family.loops:
         return x
+    x = hyper.narrow(x, config.hc_mult)
     return rms_norm(x, params["norm_f"], config.rms_norm_eps,
                     offset=config.rms_norm_offset)
 

@@ -91,6 +91,18 @@ SERIES: dict[str, tuple[str, str]] = {
                "of weights (LlamaConfig.total_ut_steps; named scopes "
                "loop.pass and loop.norm, the norm that closes a pass); 1 "
                "where a layer runs once"),
+    "model.hc_mult": (
+        GAUGE, "hidden vectors a token's residual state holds between "
+               "sub-layers (LlamaConfig.hc_mult: manifold-constrained "
+               "hyper-connections, ops/hyper.py; named scopes mhc.coeff, "
+               "the statistics, the product with phi, the sigmoids and the "
+               "Sinkhorn rounds, mhc.pre and mhc.post, the two mixes); 1 "
+               "where the residual is the plain one"),
+    "resid.token_bytes": (
+        GAUGE, "bytes one token's residual state holds between "
+               "sub-layers, in the serving type (model.hc_mult x the "
+               "hidden size x the type's bytes): what every sub-layer "
+               "reads and writes of the stream a row"),
     "cache.row_bytes": (
         GAUGE, "bytes the cache holds for one token of one layer that "
                "keeps every row (of one PLANE where a layer has one a "

@@ -215,9 +215,10 @@ def _select_stage0(x: jax.Array) -> jax.Array:
 
 def _select_last_sp(x: jax.Array, last_index: jax.Array, sp: int) -> jax.Array:
     """Pick the hidden state at per-batch global position ``last_index`` from
-    a sequence-sharded activation ``x [B, T_l, H]``; the owner shard
-    contributes, everyone else zero, reassembled by psum over sp."""
-    idx = last_index.reshape(-1, 1, 1).astype(jnp.int32)
+    a sequence-sharded activation ``x [B, T_l, H]`` (``[B, T_l, hc_mult,
+    H]`` under a wide residual stream); the owner shard contributes,
+    everyone else zero, reassembled by psum over sp."""
+    idx = last_index.reshape((-1,) + (1,) * (x.ndim - 1)).astype(jnp.int32)
     if sp == 1:
         return jnp.take_along_axis(x, idx, axis=1)[:, 0, :]
     t_l = x.shape[1]

@@ -1344,6 +1344,10 @@ class BatchGenerator:
         obs_metrics.gauge("cache.layer_planes").set(self.cache.num_layers)
         obs_metrics.gauge("model.loop_passes").set(
             self.config.total_ut_steps)
+        # what one token's residual state holds between sub-layers
+        obs_metrics.gauge("model.hc_mult").set(self.config.hc_mult)
+        obs_metrics.gauge("resid.token_bytes").set(
+            self.config.resid_token_bytes)
         # first token per stream: fold_in(stream_key, 0) — the same absolute
         # token-index schedule the in-program decode steps continue
         keys0 = jax.vmap(lambda k: jax.random.fold_in(k, 0))(self._keys)

@@ -109,9 +109,10 @@ def prefill_fn(params, tokens, cache: KVCache, last_index, config: LlamaConfig):
     x, cache = llama.forward_layers(
         params["layers"], x, cache, cos, sin, 0, config,
         pass_norm=llama.pass_norm(params, config))
+    # (rank-agnostic: a wide residual stream is [B, T, hc_mult, hidden])
     x_last = jnp.take_along_axis(
-        x, last_index.reshape(-1, 1, 1).astype(jnp.int32), axis=1
-    )[:, 0, :]
+        x, last_index.reshape((-1,) + (1,) * (x.ndim - 1)).astype(jnp.int32),
+        axis=1)[:, 0, :]
     return _lm_head(params, x_last, config), cache
 
 

@@ -32,7 +32,7 @@ from cake_tpu.ops import sampling
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.parallel.runner import BlockRunner, LocalRunner, RemoteRunner
 from cake_tpu.parallel.topology import Topology
-from cake_tpu.runtime import wire
+from cake_tpu.runtime import protocol, wire
 from cake_tpu.runtime.generator import GeneratorBase, Token, _bucket, _lm_head
 
 log = logging.getLogger("cake_tpu.master")
@@ -60,6 +60,7 @@ def build_runners(
     (``--recover-deadline``) budgets each replica's mid-stream reconnect.
     A topology node whose ``host`` is a LIST hands the whole replica set
     to its runner (failover order)."""
+    protocol.check_stream_width(config)
     runners: list[BlockRunner] = []
     for seg in topology.segments(config.num_hidden_layers):
         if seg.owner is None:

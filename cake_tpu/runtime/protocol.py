@@ -158,6 +158,21 @@ CODECS = ("none", "bf16", "int8")
 _BF16_MARK, _INT8_MARK = 0x81, 0x82
 
 
+def check_stream_width(config) -> None:
+    """Refuse a model whose residual stream is several hidden vectors wide
+    (``LlamaConfig.hc_mult`` > 1): the wire ships ``[B, T, hidden]`` between
+    a topology's layer ranges (the int8 codec scales a row of ``hidden``
+    values), and no range of such a model's layers has been compared with
+    its reference behind it. The master and the worker ask before they
+    plan a walk."""
+    if config.hc_mult > 1:
+        raise ValueError(
+            f"hc_mult = {config.hc_mult}: a residual stream several hidden "
+            "vectors wide is not wired across the wire (a topology that "
+            "splits this model's layers ships [B, T, hidden] between its "
+            "ranges); serve it on one host (--mode serve, no --topology)")
+
+
 def check_codec(codec: str) -> str:
     """Validate a codec name (shared by the encoder, RemoteRunner, and
     Worker so the accepted set and the error live in one place)."""

@@ -73,6 +73,7 @@ class Worker:
     ):
         if name not in topology:
             raise ValueError(f"worker '{name}' not present in topology")
+        protocol.check_stream_width(config)
         self.name = name
         self.config = config
         self.node = topology[name]

@@ -147,7 +147,7 @@ def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
     (``mesh.validate_shardable``)."""
     import math
 
-    from cake_tpu.models.llama import stack_layers, stack_shapes
+    from cake_tpu.models.llama import HC_TENSORS, stack_layers, stack_shapes
     from cake_tpu.ops.quant import LATENT_LINEARS
 
     count = stack_layers(c)
@@ -160,6 +160,8 @@ def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
                 held = shape[0] / ep if len(shape) == 3 else 1
                 fan_in, out = shape[-2:]
                 per_layer += held * (fan_in * out * lin_el + out * scale_el)
+            elif name in HC_TENSORS:  # a wide residual stream's: float32
+                per_layer += math.prod(shape) * 4
             else:  # norms and the router, in the serving type
                 per_layer += math.prod(shape) * el
         layer_bytes += count[stack] * per_layer

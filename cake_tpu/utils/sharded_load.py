@@ -601,7 +601,7 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
     quantizes every linear of ``quant.LATENT_LINEARS``; each layer's
     tensor is read, and quantized, on its own, so host scratch is one
     tensor."""
-    from cake_tpu.models.llama import stack_shapes
+    from cake_tpu.models.llama import HC_TENSORS, stack_shapes
     from cake_tpu.ops.quant import (LATENT_LINEARS, QuantizedLinear,
                                     parse_quant_spec, quantize_linear_np,
                                     reject_int4_moe)
@@ -643,10 +643,11 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
         return scales[name]
 
     def stacked(names_of, lead: tuple[int, ...], shape: tuple, spec: P,
-                transpose: bool, quant: bool):
+                transpose: bool, quant: bool, dt=dt):
         """One stacked leaf: ``names_of(i, [e])`` is the stored tensor of
         each leading index; 1-D tensors and plain linears in the serving
-        type, quantized linears as (q, scale)."""
+        type (``dt``: a wide residual stream's tensors stay float32),
+        quantized linears as (q, scale)."""
         asked.update(names_of(*at) for at in np.ndindex(*lead))
 
         def gather(index, read):
@@ -682,7 +683,8 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
                 lambda *at, s=suffix, ids=ids: (
                     f"model.layers.{ids[at]}.{s}"),
                 ids.shape, shapes[stack][ours](config), lead, transpose,
-                tier is not None and ours in LATENT_LINEARS)
+                tier is not None and ours in LATENT_LINEARS,
+                np.dtype(np.float32) if ours in HC_TENSORS else dt)
         for ours, pattern in experts.items():
             out[ours] = stacked(
                 lambda *at, p=pattern, ids=ids: (

@@ -833,6 +833,107 @@ def test_latent_programs_move_no_cache_and_no_expert_stack(topo, as_on_chip):
     assert temps < 0.6 * GIB, temps / GIB
 
 
+def _scoped_fusions(compiled, scope: str) -> dict[str, int]:
+    """Fusions (outside fused computations) whose ``op_name`` carries the
+    named scope ``scope``, counted a computation."""
+    import collections
+
+    found: dict[str, int] = collections.Counter()
+    for comp, _, _, op, line in _instructions(compiled):
+        if op != "fusion" or "fused" in comp:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        if name and scope in name.group(1):
+            found[comp] += 1
+    return dict(found)
+
+
+def test_wide_stream_programs_move_no_cache_no_stack_and_no_wide_stream(
+        topo, as_on_chip):
+    """The latent family under a residual stream FOUR hidden vectors wide
+    (``hc_mult`` 4, ops/hyper.py) at Xing4.0-29B-A4B's published widths, 1
+    dense + 2 expert layers with ALL 64 experts each, the whole 131,072-row
+    vocabulary, 32 slots x 4096 rows (the cell ``xing4-29b-cut.decode-full``
+    but for its depth): the chip's compiler takes both programs; nothing of
+    the latent cache's shapes and no layer's expert stack ``[1, 64, 3584,
+    1024]`` is allocated or copied.
+
+    THE FORMS (my AOT compiles, PR 51; PERF.md section 7). The layer loop
+    carries the stream as its four hidden vectors, a ``[B, T, 3584]`` array
+    each. With ONE array ``[B, T, 4, 3584]`` in the carry the compiler
+    holds it in ``(4, 128)`` tiles and, every sub-layer, writes it out
+    again as float32 with the streams apart (``copy_convert_fusion
+    f32[1,512,4,3584]``: 29 MB a 512-row admission where the stream is
+    14.7) for the product with ``phi`` and the mixes; held flat (``[B, T,
+    14336]``) the mixed streams' ``concatenate`` is a pass of its own. So
+    the wide shape appears TWICE a program and in no loop: where the
+    embedding is widened (a broadcast the split reads through: no
+    instruction of its own where the compiler fuses it) and where the
+    loop's result is joined for the head. Pinned: at most 2 instructions of
+    the wide shape a program, all in ENTRY or the step loop's body, none
+    in a layer loop's; no float32 copy of it anywhere.
+
+    A sub-layer's coefficients: ``x~ phi`` is four products over the
+    streams as they lie (``phi`` in three bfloat16 parts, 72 columns, so
+    the stream is never converted), the statistics four reductions, and
+    the Sinkhorn chain elementwise adds of the sixteen cells: no
+    reduction, no ``dot`` over an axis of 4. The compiler cuts a chain
+    where a fusion passes ~180 instructions and where several cells leave
+    it, so it is NOT one fusion: RECORDED 53 and 43 fusions under the
+    ``mhc.*`` scopes in the step's two layer bodies (two sub-layers each,
+    the products, statistics and both mixes counted in), 43 in the
+    admission's expert body; as ``sum(axis)`` rounds a chain alone is 80.
+    Pinned at those counts + 10%.
+
+    The step takes the expert block's DENSE form (32 rows x top-4 of 64
+    hit 0.87 of the experts, over ``SORTED_MAX_HIT_SHARE``: no grouped
+    matmul) and the 512-row admission the sorted one (three calls).
+    RECORDED: the step 5.19 GiB of arguments (2 x 1.49 GB of expert layers
+    + 0.26 of the dense one + 1.88 of embedding and head = 5.11 GB = 4.76
+    GiB, + 0.42 GiB of latent cache) and 0.07 GiB of temporaries, the
+    admission 4.78 + 0.31; at the cell's 1 + 6 layers 11.31 + 0.16 and
+    10.35 + 0.31 GiB (a scratch script: the test stays at three layers),
+    under ISSUE 51's 14.5."""
+    from cake_tpu.models.config import xing4_29b
+
+    layers, slots, window = 3, 32, 4096
+    config = xing4_29b(num_hidden_layers=layers, first_k_dense_replace=1,
+                       max_seq_len=window)
+    decode, admit = _family_programs(topo, config, slots, window, 512)
+    assert config.cache_row == (1, 512, 64)
+    for compiled, rows in ((decode, f"{slots},1"), (admit, "1,512")):
+        batch = int(rows.split(",")[0])
+        for width in (512, 64):
+            assert _cache_sized_moves(
+                compiled, f"bf16[{layers},{batch},1,{window},{width}]") == []
+        assert _expert_stack_moves(compiled, "bf16", 64, 3584, 1024) == []
+        wide = [(comp, op, shape[:3])
+                for comp, _, shape, op, _ in _instructions(compiled)
+                if shape in (f"bf16[{rows},4,3584]", f"f32[{rows},4,3584]")
+                and "fused" not in comp
+                and op not in ("parameter", "get-tuple-element", "bitcast",
+                               "tuple")]
+        assert len(wide) <= 2, wide
+        assert not [w for w in wide if w[1] in ("copy", "copy-start")], wide
+        assert not [w for w in wide if w[2] == "f32"], wide
+        text = compiled.as_text()
+        for scope in ("mhc.coeff", "mhc.pre", "mhc.post"):
+            assert scope in text, scope
+    assert _grouped_matmul_calls(decode) == 0
+    assert _grouped_matmul_calls(admit) == 3
+    bodies = sorted(sum(_scoped_fusions(decode, scope).get(comp, 0)
+                        for scope in ("mhc.", "btc,ck->btk"))
+                    for comp in _scoped_fusions(decode, "mhc.coeff")
+                    if "region" in comp)
+    assert len(bodies) == 2 and bodies[0] <= 48 and bodies[1] <= 58, bodies
+    args, temps = _donated_bytes(decode)
+    assert 5.1 * GIB < args < 5.3 * GIB, args / GIB
+    assert temps < 0.15 * GIB, temps / GIB
+    m = admit.memory_analysis()
+    assert 4.7 * GIB < m.argument_size_in_bytes < 4.9 * GIB
+    assert m.temp_size_in_bytes < 0.4 * GIB
+
+
 def _hybrid_programs(topo, layers: int, slots: int, window: int, bucket: int):
     """(config, block decode, admission) at Ling-3.0-flash's published
     widths (one chip's share of 4, a quarter of the vocabulary), the cut's
@@ -1253,23 +1354,30 @@ PR31_TEXTS = {
     # passes around the scan over one stack, a plane a layer and a pass
     "looped.decode": "1790a3781f0fb307",
     "looped.admit": "2d341182e4882929",
+    # the latent family under a residual stream four hidden vectors wide,
+    # taken on PR 51's tree, which brought it (``hc_mult`` 1 lowers every
+    # family above to the text it had: no hash replaced)
+    "latent_hc.decode": "84fbf8492d753fb3",
+    "latent_hc.admit": "58e32a443fed4e03",
 }
 
 
 def _family_fixtures():
     from cake_tpu.models.config import (tiny, tiny_exaone_moe, tiny_jamba,
                                         tiny_kda_hybrid, tiny_lfm2_moe,
-                                        tiny_mla_moe, tiny_moe, tiny_ouro)
+                                        tiny_mla_moe, tiny_moe, tiny_ouro,
+                                        tiny_xing4)
 
     return {"dense": lambda: tiny(sliding_window=32), "sparse": tiny_moe,
             "latent": tiny_mla_moe, "hybrid": tiny_kda_hybrid,
             "state_space": tiny_jamba, "windowed": tiny_exaone_moe,
-            "short_conv": tiny_lfm2_moe, "looped": tiny_ouro}
+            "short_conv": tiny_lfm2_moe, "looped": tiny_ouro,
+            "latent_hc": tiny_xing4}
 
 
 @pytest.mark.parametrize("name", ["dense", "sparse", "latent", "hybrid",
                                   "state_space", "windowed", "short_conv",
-                                  "looped"])
+                                  "looped", "latent_hc"])
 def test_existing_families_lower_to_the_text_they_had(name):
     """Each family's block decode and admission programs lower (StableHLO,
     CPU, tiny widths) to the text PR 31's tree (PR 32's for the hybrid,

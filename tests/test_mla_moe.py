@@ -514,9 +514,15 @@ def test_family_limits_are_refused_with_a_message(params):
                        kv_layout="paged", max_seq=64)
     with pytest.raises(ValueError, match="int8 cache"):
         init_cache(CFG, quant="int8")
+    # a routing correction bias is read since PR 51 (`noaux_tc`: the
+    # choice on score + mlp.gate.e_score_correction_bias); a method
+    # nothing here computes is still refused
+    assert LlamaConfig.from_hf_dict(dict(
+        CFG.to_hf_dict(), topk_method="noaux_tc")).router_bias
+    assert not LlamaConfig.from_hf_dict(CFG.to_hf_dict()).router_bias
     with pytest.raises(ValueError, match="topk_method"):
         LlamaConfig.from_hf_dict(dict(CFG.to_hf_dict(),
-                                      topk_method="noaux_tc"))
+                                      topk_method="seq_aux"))
     with pytest.raises(ValueError, match="latent-attention keys"):
         LlamaConfig.from_hf_dict(dict(CFG.to_hf_dict(), model_type="llama"))
 
