@@ -50,7 +50,8 @@ SERIES: dict[str, tuple[str, str]] = {
     "attn.kv_blocks_read": (
         COUNTER, "KV blocks (of the rows flash_decode fetches of this "
                  "cache's shape, ops.pallas.decode_block_k: 512 for a "
-                 "group of query rows a KV head) a layer's "
+                 "group of query rows a KV head, over heads of 128 and "
+                 "over pairs of heads of 64 alike) a layer's "
                  "decode attention reads under the kernels' block range "
                  "(ops.pallas.decode_block_range, which flash_decode and "
                  "the latent cache's latent_decode both walk): over "

@@ -26,7 +26,8 @@ On TPU, :func:`attend` dispatches to the fused Pallas flash kernels
 registers, no HBM score materialization, KV blocks past the frontier never
 fetched — at the shapes where the measured sweep says they win
 (tools/flash_sweep.py): prefill from ``PREFILL_FLASH_MIN_S`` context up,
-single-token decode over a plain cache from ``DECODE_FLASH_MIN_S`` rows up.
+single-token decode over a plain cache from ``DECODE_FLASH_MIN_S`` rows up
+(heads of 128 and wider, and heads of 64 two to a lane tile).
 Below the crossovers XLA's fused attention is faster and ``auto`` picks it.
 The XLA path also remains the parity oracle (``CAKE_PALLAS=0`` forces it
 everywhere; ``CAKE_PALLAS=1`` forces the kernels everywhere).
@@ -52,8 +53,10 @@ NEG_INF = -1e30
 
 
 def _flash_ok(t: int, s: int, d: int) -> bool:
-    """Shapes the compiled (non-interpret) kernels handle efficiently:
-    lane-aligned head_dim and a KV buffer divisible into aligned blocks."""
+    """Shapes the compiled (non-interpret) PREFILL kernels handle
+    efficiently: lane-aligned head_dim and a KV buffer divisible into
+    aligned blocks. (The decode kernel's shapes, heads of 64 among them,
+    are :func:`flash_decode_choice`'s.)"""
     return d % 128 == 0 and s % 128 == 0
 
 
@@ -138,12 +141,45 @@ PREFILL_FLASH_MIN_T = 256
 #   scratch run with a raised limit and one walk a dispatch) reads 65-70 us
 #   at every frontier against XLA's 68-73 there: nothing to skip.
 #
+# Heads HALF a lane tile wide (D == 64: LFM2, Llama-3.2-1B, TinyLlama)
+# under a group of query rows. The chip stores ``[.., S, 64]`` with the rows
+# on the lanes and the kernel is handed that buffer as it lies, ``[.., KVH /
+# 2, 128, S]``, a PAIR of heads one 128-deep contraction (``pk.flash_decode``;
+# asked for ``[KVH, BK, 64]`` blocks Mosaic refuses: it sees rows padded to
+# 128 lanes). tools/flash_sweep.py --only narrow on v5 lite (PR 52, my chip
+# run; us a layer inside 16 walks over 8 layers a dispatch, at the frontiers
+# a `decode-full` batch has / at row 703 / at the buffer's end), blocks of
+# 128 | 256 | 512 rows:
+#
+# - B 32, S 2048, KVH 8 x G 4 (`lfm2-8b-a1b-cut`), XLA 188.5 / 188.6 / 189.3:
+#   * the pairs' products in ONE batched call (taken):
+#     66.5 | 54.2 | 59.8 mixed, 127.6 | 87.5 | 98.5 at row 703,
+#     322.2 | 221.8 | 187.0 at the end (1.70 | 1.17 | 0.99x XLA);
+#   * the loop over pairs: 68.1 | 60.5 | 67.0 mixed, 327.5 | 253.2 | 208.5
+#     at the end (1.73 | 1.34 | 1.10x): a 512-row block is 1 MiB here, half
+#     the dense cell's, and four chains of eight rows no longer hide behind
+#     its fetch.
+#   So 512 rows (``pk.NARROW_BLOCK_K``), batched: 256 reads a tenth less at
+#   the served frontiers and costs 1.17x XLA on a full cache, over PR 29's
+#   bar of 1.10x; at 512 a block's 1.46 us is its fetch at 718 GB/s.
+# - the same row of heads, batched at 512 rows, kernel / XLA mixed and at the
+#   end: S 4096 61.0 / 365.2 and 367.0 / 364.4; S 1024 60.8 / 100.4 and 99.4
+#   / 100.3; S 512 (one block, nothing to skip) 52.7 / 52.3: XLA stays under
+#   1024 rows (``NARROW_FLASH_MIN_S``). B 8 x S 2048: 17.7 / 52.7 and 58.0 /
+#   52.9 (1.10x: a call's ~11 us stand over 32 blocks).
+# - other rows of heads, B 8 x S 2048, batched at 512: KVH 4 x G 8
+#   (TinyLlama) 10.9 / 30.1 and 30.8 / 30.0; KVH 2 x G 7 (Qwen2.5-0.5B: ONE
+#   pair, a block of 256 KiB) 8.5 / 20.3 and 23.2 / 20.2 (1.15x: over the
+#   bar, so XLA under ``NARROW_MIN_KV_HEADS``).
+#
 # The frontier is data, so a nearly full cache runs the kernel too, at
 # XLA's cost. An int8 cache stays on XLA (the dequantize fuses into its
 # dot; a kernel operand would be a written-out bf16 buffer).
 DECODE_FLASH_MIN_S = 1024
 ONE_ROW_FLASH_MIN_S = 768
 ONE_ROW_MAX_WIDTH = 4096
+NARROW_FLASH_MIN_S = 1024
+NARROW_MIN_KV_HEADS = 4
 
 
 def _flash_prefill_choice(t: int, s: int, d: int) -> str:
@@ -163,9 +199,10 @@ def _flash_prefill_choice(t: int, s: int, d: int) -> str:
     # Runs at trace time (once per compiled shape): a misaligned config
     # must not silently lose the kernels.
     log.warning(
-        "flash kernels enabled but shape (T=%d, S=%d, D=%d) is not "
-        "lane-aligned (need D%%128==0 and S%%128==0); falling back to the "
-        "XLA attention path", t, s, d,
+        "flash kernels enabled but prefill shape (T=%d, S=%d, D=%d) is not "
+        "lane-aligned (a chunk's kernel needs D%%128==0 and S%%128==0; "
+        "only the single-token kernel takes heads of 64); falling back to "
+        "the XLA attention path", t, s, d,
     )
     return "xla"
 
@@ -174,23 +211,35 @@ def flash_decode_choice(s: int, d: int, kv_heads: int, group: int,
                         itemsize: int = 2) -> str:
     """``"flash"`` or ``"xla"`` for a single-token (T == 1) attention
     of ``group`` query rows a KV head over a plain ``S``-row cache of
-    ``kv_heads`` heads of size ``d`` — THE decode policy, from what a trace can see of its input (the
-    shapes; the frontier is data). :func:`attend` asks it, once for each
-    decode program traced, and publishes the answer
+    ``kv_heads`` heads of size ``d`` — THE decode policy, from what a trace
+    can see of its input (the shapes; the frontier is data): heads that are
+    a multiple of a lane tile, and heads of half a tile in pairs, each in
+    the block and from the floor its own sweep gave. :func:`attend` asks
+    it, once for each decode program traced, and publishes the answer
     (``attn.decode_kernel``)."""
     if not pk.kernels_enabled():
         return "xla"
     bk = pk.decode_block_k(s, kv_heads, d, itemsize, group)
+    # heads of 64, an even number of them, go two to a lane tile
+    narrow = pk.narrow_heads(d, kv_heads)
     if pk.force_kernels():
-        if pk.interpret_default() or (_flash_ok(1, s, d) and bk is not None):
+        if pk.interpret_default() or (
+                bk is not None and s % 128 == 0 and (d % 128 == 0 or narrow)):
             return "flash"
         log.warning(
             "flash kernels forced (CAKE_PALLAS=1) but decode shape "
-            "(T=1, S=%d, D=%d, KVH=%d) is not lane-aligned (need D%%128==0 "
-            "and S%%128==0) or its KV blocks do not fit VMEM; falling back "
-            "to the XLA attention path", s, d, kv_heads,
+            "(T=1, S=%d, D=%d, KVH=%d) is not lane-aligned (need S%%128==0 "
+            "and D%%128==0, or D==64 over an even number of KV heads) or "
+            "its KV blocks do not fit VMEM; falling back to the XLA "
+            "attention path", s, d, kv_heads,
         )
         return "xla"
+    if narrow:
+        # a group of query rows over pairs of 64-wide heads (the table
+        # above); ONE row a head of 64 has no line in a sweep
+        fits = (bk == pk.NARROW_BLOCK_K and group > 1
+                and kv_heads >= NARROW_MIN_KV_HEADS)
+        return "flash" if fits and s >= NARROW_FLASH_MIN_S else "xla"
     if d % 128:
         return "xla"
     if group == 1:

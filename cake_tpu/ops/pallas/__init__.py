@@ -50,6 +50,7 @@ def interpret_default() -> bool:
 
 from cake_tpu.ops.pallas.flash import (  # noqa: E402
     DECODE_BLOCK_K,
+    NARROW_BLOCK_K,
     ONE_ROW_BLOCK_K,
     decode_block_k,
     decode_block_range,
@@ -57,6 +58,7 @@ from cake_tpu.ops.pallas.flash import (  # noqa: E402
     flash_attention,
     flash_attention_q8,
     flash_decode,
+    narrow_heads,
 )
 from cake_tpu.ops.pallas.kda import kda_decode  # noqa: E402
 from cake_tpu.ops.pallas.latent import latent_decode  # noqa: E402
@@ -75,6 +77,7 @@ __all__ = [
     "interpret_default",
     "on_tpu",
     "DECODE_BLOCK_K",
+    "NARROW_BLOCK_K",
     "ONE_ROW_BLOCK_K",
     "decode_block_k",
     "decode_block_range",
@@ -82,6 +85,7 @@ __all__ = [
     "flash_attention",
     "flash_attention_q8",
     "flash_decode",
+    "narrow_heads",
     "kda_decode",
     "latent_decode",
     "MOE_ROW_TILE",

@@ -17,7 +17,8 @@ Two kernels share the math:
 - :func:`flash_decode` — decode (T == 1): the GQA head group is folded into
   the q-row axis (``[B, KVH, group, D]``) so the MXU sees a [group, D] x
   [D, BK] matmul a head (the heads' matmuls one batched call where the
-  group is ONE row: a multi-head model). One invocation leaves the cache
+  group is ONE row, a multi-head model, and where heads of 64 go two to a
+  lane tile). One invocation leaves the cache
   in HBM (one
   layer's ``[B, KVH, S, D]`` or the stacked ``[L, B, KVH, S, D]`` the
   layer loop carries, the layer a scalar operand) and walks each stream's
@@ -424,6 +425,10 @@ DECODE_BLOCK_K = 512
 # block is computed inside its fetch at any size, so the shortest block,
 # which skips most, wins: ``--only one-row``, the same table)
 ONE_ROW_BLOCK_K = 128
+# ... and where a group of query rows sits over heads HALF a lane tile
+# wide, which the kernel takes two to the tile (``--only narrow``, the same
+# table)
+NARROW_BLOCK_K = 512
 # What the kernel's K and V blocks may take of VMEM, double-buffered:
 # 4 x KVH x rows x D x itemsize (q, o and the accumulators are small beside
 # them: B 128 compiles at KVH 8). v5e's compiler gives a kernel 16 MiB: 8
@@ -436,13 +441,16 @@ def decode_block_k(s: int, kv_heads: int, d: int, itemsize: int,
                    group: int, block_k: int | None = None) -> int | None:
     """Rows of the KV block :func:`flash_decode` fetches at these shapes:
     ``block_k`` (None: what the sweep gave this row of heads,
-    ``ONE_ROW_BLOCK_K`` for one query row a KV head and ``DECODE_BLOCK_K``
-    for a group of them) where it divides ``s``, else the largest power of
-    two up to it that does, halved (down to 128 rows) until the blocks fit
+    ``ONE_ROW_BLOCK_K`` for one query row a KV head, ``NARROW_BLOCK_K`` for
+    a group of them over pairs of 64-wide heads and ``DECODE_BLOCK_K`` for
+    a group over wider ones) where it divides ``s``, else the largest power
+    of two up to it that does, halved (down to 128 rows) until the blocks fit
     ``DECODE_KV_VMEM``; ``None`` where not even those fit, and the kernel
     cannot be built."""
     if block_k is None:
-        block_k = DECODE_BLOCK_K if group > 1 else ONE_ROW_BLOCK_K
+        block_k = (ONE_ROW_BLOCK_K if group == 1 else
+                   NARROW_BLOCK_K if narrow_heads(d, kv_heads) else
+                   DECODE_BLOCK_K)
     bk = block_k if s % block_k == 0 else _pick_block(s, block_k)
 
     def need(rows):
@@ -451,6 +459,12 @@ def decode_block_k(s: int, kv_heads: int, d: int, itemsize: int,
     while bk > 128 and need(bk) > DECODE_KV_VMEM:
         bk = _pick_block(s, bk // 2)
     return bk if need(bk) <= DECODE_KV_VMEM else None
+
+
+def narrow_heads(d: int, kv_heads: int) -> bool:
+    """Heads HALF a lane tile wide, an even number of them: what
+    :func:`flash_decode` takes two to the tile (:func:`_pair_heads`)."""
+    return 2 * d == _LANES and kv_heads % 2 == 0
 
 
 def decode_block_range(pos, block_k: int, num_kv_blocks: int,
@@ -498,6 +512,7 @@ def _decode_kernel(
     num_kv_blocks: int,
     window: int | None = None,
     batched: bool = False,
+    rows_on_lanes: bool = False,
 ):
     """One invocation walks every stream's LIVE KV blocks in turn, (row 0:
     lo..hi), (row 1: lo..hi), ...: the block after the one being computed
@@ -505,14 +520,23 @@ def _decode_kernel(
     row too, so no fetch waits on a skipped grid step and none is paid
     for.
 
+    ``rows_on_lanes``: the cache is ``[.., KVH, D, S]`` (a head's rows its
+    columns: how the chip lays out heads narrower than a lane tile, see
+    :func:`flash_decode`), a block ``[KVH, D, BK]``, and the two products
+    contract the other axis of it; nothing else differs.
+
     ``batched``: a block's products are one batched call over the heads
     and its softmax bookkeeping one update of ``[KVH, G, ..]`` arrays.
     The loop over heads makes a head's two products and its softmax one
     dependent chain; with ONE query row a head (``group`` 1) sixteen such
     chains of single rows take 1.5 times a block's fetch (84 us a plane at
     B 6 x S 768 x KVH 16 with every frontier at the buffer's end, 53 as
-    one call: the fetch alone). A group of rows keeps the loop, which
-    hides behind 512-row fetches, and its compiled kernel."""
+    one call: the fetch alone). A group of rows over heads of 128 keeps
+    the loop, which hides behind 512-row fetches, and its compiled kernel;
+    over PAIRS of 64-wide heads a 512-row block is half those bytes, and
+    the four pairs' chains no longer hide behind it (209 us a layer at B 32
+    x S 2048 with every frontier at the buffer's end, 187 as one call, XLA
+    189: the table beside ``ops.attention.DECODE_FLASH_MIN_S``)."""
     lead = ()
     if stacked:
         layer_ref, *refs = refs
@@ -522,9 +546,14 @@ def _decode_kernel(
     def bounds(b):
         return decode_block_range(pos_ref[b], block_k, num_kv_blocks, window)
 
+    # the axis of a head's K block that the scores contract (its D) and
+    # of its V block that the values' product contracts (its rows)
+    k_dim, v_dim = (0, 1) if rows_on_lanes else (1, 0)
+
     def copies(b, kb, slot):
         rows = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
-        at = lead + (b, slice(None), rows, slice(None))
+        at = lead + (b, slice(None)) + (
+            (slice(None), rows) if rows_on_lanes else (rows, slice(None)))
         return (pltpu.make_async_copy(k_hbm.at[at], kbuf.at[slot],
                                       sem.at[0, slot]),
                 pltpu.make_async_copy(v_hbm.at[at], vbuf.at[slot],
@@ -563,10 +592,10 @@ def _decode_kernel(
             # every head's product in ONE batched call and one update of
             # the running maximum, sum and accumulator as [KVH, G, ..]
             # arrays: nothing of a head waits for another head's softmax
-            k = kbuf[slot]  # [KVH, BK, D]
+            k = kbuf[slot]  # [KVH, BK, D] ([KVH, D, BK] rows on lanes)
             v = vbuf[slot]
             s = jax.lax.dot_general(
-                q_ref[b], k, (((2,), (2,)), ((0,), (0,))),
+                q_ref[b], k, (((2,), (1 + k_dim,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
             )
             s = jnp.where(mask, s * scale, NEG_INF)  # [KVH, G, BK]
@@ -577,7 +606,7 @@ def _decode_kernel(
             l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=2, keepdims=True)
             m_ref[:] = m_new
             pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                p.astype(v.dtype), v, (((2,), (1 + v_dim,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
             )
             acc_ref[:] = acc_ref[:] * alpha[:, :, :1] + pv
@@ -586,10 +615,10 @@ def _decode_kernel(
             # are independent, so the scheduler overlaps them)
             for h in range(kv_heads):
                 q = q_ref[b, h]  # [G, D]
-                k = kbuf[slot, h]  # [BK, D]
+                k = kbuf[slot, h]  # [BK, D] ([D, BK] rows on lanes)
                 v = vbuf[slot, h]
                 s = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
+                    q, k, (((1,), (k_dim,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
                 s = jnp.where(mask, s * scale, NEG_INF)  # [G, BK]
@@ -602,7 +631,7 @@ def _decode_kernel(
                             + jnp.sum(p, axis=1, keepdims=True))
                 m_ref[h] = m_new
                 pv = jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    p.astype(v.dtype), v, (((1,), (v_dim,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
                 acc_ref[h] = acc_ref[h] * alpha[:, :1] + pv
@@ -618,6 +647,40 @@ def _decode_kernel(
         c.start()
     jax.lax.while_loop(lambda c: c[0] < batch, step,
                        (jnp.int32(0), lo0, jnp.int32(0)))
+
+
+def _pair_heads(qg, k_all, v_all):
+    """The operands :func:`flash_decode` hands its kernel where a head is
+    HALF a lane tile wide (:func:`narrow_heads`: two heads of 64 fill the
+    128 lanes).
+
+    The chip lays such a cache ``[.., KVH, S, D]`` out with the ROWS on the
+    lanes and a head's ``D`` channels on the sublanes (its default layout
+    of the shape, ``{3,4,2,1,0:T(8,128)(2,1)}``: nothing is padded), and a
+    kernel that asks for ``[KVH, BK, D]`` blocks of it is refused (Mosaic
+    sees a buffer padded to 128 lanes, which XLA would have to write). So
+    the kernel is handed the view that lies as the buffer does, ``[..,
+    KVH / 2, 2 * D, S]``: a transpose and a merge of axes that are a
+    bitcast on the chip (tests/test_chip_compile.py holds the compiled
+    step to it), a pair of heads' channels one 128-deep contraction.
+
+    The query rows of a pair go block-diagonal, ``[B, KVH / 2, 2 * G, 2 *
+    D]`` with head ``i``'s ``G`` rows in columns ``i * D`` on and zeros
+    beside them: a row's scores are its own head's, its softmax its own,
+    and of the values' product ``[2 * G, 2 * D]`` the diagonal blocks are
+    the heads' results (the caller keeps them). The matrix unit's passes
+    are counted in 128 x 128 tiles of K and V whatever ``D`` is, so a pair
+    costs what ONE head of 128 costs."""
+    b, kvh, group, d = qg.shape
+    eye = jnp.eye(2, dtype=qg.dtype)[:, None, :, None]  # [2, 1, 2, 1]
+    qg = qg.reshape(b, kvh // 2, 2, group, 1, d) * eye
+    qg = qg.reshape(b, kvh // 2, 2 * group, 2 * d)
+
+    def view(x):
+        x = jnp.swapaxes(x, -1, -2)  # [.., KVH, D, S]
+        return x.reshape(x.shape[:-3] + (kvh // 2, 2 * d, x.shape[-1]))
+
+    return qg, view(k_all), view(v_all)
 
 
 def flash_decode(
@@ -641,10 +704,12 @@ def flash_decode(
     a seventh full costs a seventh of the sweep, and rows nobody wrote
     are neither read nor computed. The GQA group is folded into q rows,
     so a head is one [group, D] x [D, BK] matmul; with ONE query row a KV
-    head the heads' matmuls are one batched call and the blocks shorter
-    (``batched``, None: where ``group`` is 1; ``block_k``, None:
-    :func:`decode_block_k`'s own; the sweep tool passes both to time
-    either form at any block). ``pos`` may be scalar
+    head the heads' matmuls are one batched call and the blocks shorter;
+    heads of 64 go two to a lane tile (:func:`_pair_heads`), the pairs'
+    matmuls one batched call too (``batched``, None: where ``group`` is 1
+    or the heads are paired; ``block_k``, None: :func:`decode_block_k`'s
+    own; the sweep tool passes both to time either form at any block).
+    ``pos`` may be scalar
     (shared frontier) or ``[B]`` (per-row frontiers — multi-stream
     serving).
 
@@ -676,36 +741,44 @@ def flash_decode(
     if stacked:
         prefetch.append(jnp.asarray(layer, jnp.int32).reshape(1))
     qg = q.reshape(b, kvh, group, d)
+    # heads of half a lane tile, two to the tile (any other width goes as
+    # it is: the chip's compiler takes multiples of 128, the interpreter all)
+    paired = narrow_heads(d, kvh)
+    if paired:
+        qg, k_all, v_all = _pair_heads(qg, k_all, v_all)
+    kh, kg, kd = qg.shape[1:]  # the heads, query rows and width the kernel sees
 
     def whole(i, *prefetched):
         return (0, 0, 0, 0)
 
+    block = (2, kh, kd, bk) if paired else (2, kh, bk, kd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(1,),
         in_specs=[
-            pl.BlockSpec((b, kvh, group, d), whole),
+            pl.BlockSpec((b, kh, kg, kd), whole),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((b, kvh, group, d), whole),
+        out_specs=pl.BlockSpec((b, kh, kg, kd), whole),
         scratch_shapes=[
-            pltpu.VMEM((2, kvh, bk, d), k_all.dtype),
-            pltpu.VMEM((2, kvh, bk, d), v_all.dtype),
+            pltpu.VMEM(block, k_all.dtype),
+            pltpu.VMEM(block, v_all.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((kvh, group, d), jnp.float32),
-            pltpu.VMEM((kvh, group, _LANES), jnp.float32),
-            pltpu.VMEM((kvh, group, _LANES), jnp.float32),
+            pltpu.VMEM((kh, kg, kd), jnp.float32),
+            pltpu.VMEM((kh, kg, _LANES), jnp.float32),
+            pltpu.VMEM((kh, kg, _LANES), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _decode_kernel, stacked=stacked, batch=b, kv_heads=kvh, group=group,
+        _decode_kernel, stacked=stacked, batch=b, kv_heads=kh, group=kg,
         block_k=bk, scale=1.0 / math.sqrt(d), num_kv_blocks=nk, window=window,
-        batched=group == 1 if batched is None else batched,
+        batched=(group == 1 or paired) if batched is None else batched,
+        rows_on_lanes=paired,
     )
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, group, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kh, kg, kd), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -718,4 +791,8 @@ def flash_decode(
         name="flash_decode",
         interpret=interpret,
     )(*prefetch, qg, k_all, v_all)
+    if paired:
+        # a paired head's own rows and columns: the diagonal blocks
+        out = out.reshape(b, kh, 2, group, 2, d)
+        out = jnp.stack([out[:, :, i, :, i] for i in range(2)], axis=2)
     return out.reshape(b, h, 1, d)

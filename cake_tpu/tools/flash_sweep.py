@@ -8,7 +8,7 @@ dispatch — the same measured-crossover treatment ``quant_matmul`` got for its
 M>=16 gate (`ops/quant.py`).
 
 Usage:  python -m cake_tpu.tools.flash_sweep [--json-out PATH]
-            [--only served-decode|served-latent|one-row]
+            [--only served-decode|served-latent|one-row|narrow]
 (``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
 Prints one JSON line per shape:
@@ -23,7 +23,10 @@ constants). ``--only served-latent`` runs the latent rows alone;
 ``--only one-row`` the decode kernel's two forms (the heads' products a head
 at a time, or in one batched call) at 128- to 512-row blocks on the rows of
 heads with ONE query row a KV head (``ONE_ROW_SHAPES``: what decides
-``ops.pallas.ONE_ROW_BLOCK_K`` and ``ONE_ROW_FLASH_MIN_S``).
+``ops.pallas.ONE_ROW_BLOCK_K`` and ``ONE_ROW_FLASH_MIN_S``); ``--only
+narrow`` the same two forms at 128- to 512-row blocks over heads of 64, two
+to a lane tile (``NARROW_SHAPES``: ``ops.pallas.NARROW_BLOCK_K`` and
+``NARROW_FLASH_MIN_S``).
 """
 
 from __future__ import annotations
@@ -124,6 +127,16 @@ def _layer_ms(step, q, pos, layers: int, *cache, iters: int = 10) -> float:
 # ``--only one-row`` times both forms of the kernel at every block on them
 ONE_ROW_SHAPES = tuple(shape for shape in SERVED_DECODE_SHAPES
                        if shape[2] == shape[3])
+# heads HALF a lane tile wide under a group of query rows (``--only
+# narrow``): the cell lfm2-8b-a1b-cut's 32 slots x 2048 rows of 32 / 8 heads
+# (Llama-3.2-1B's row of heads too), shorter and longer windows (where the
+# floor is), 8 slots, and the rows of two more public decoders (TinyLlama's
+# 32 / 4, Qwen2.5-0.5B's 14 / 2)
+NARROW_SHAPES = (
+    (32, 2048, 32, 8, 64), (32, 1024, 32, 8, 64), (32, 512, 32, 8, 64),
+    (32, 4096, 32, 8, 64), (8, 2048, 32, 8, 64),
+    (8, 2048, 32, 4, 64), (8, 2048, 14, 2, 64),
+)
 # flash_decode's ``batched``: the heads' products a head at a time, or in
 # one batched call (None: the form the kernel takes itself)
 FORMS = {None: "", False: "loop_", True: "batched_"}
@@ -131,14 +144,14 @@ FORMS = {None: "", False: "loop_", True: "batched_"}
 
 def served_decode_rows(results: list, blocks=(128, 256, 512, 1024),
                        layers: int = 8, shapes=SERVED_DECODE_SHAPES,
-                       forms=(None,)) -> None:
+                       forms=(None,), every_block: bool = False) -> None:
     """Decode (T == 1) on the STACKED cache ``[L, B, KVH, S, D]`` as a
     decode step meets it: one pass over ``layers`` layers, each attending
     its own slice with the layer index traced (so no layer's keys stay
     in fast memory between calls, which a loop over ONE layer's buffer
     allows XLA and which no model does); times are a layer's. The kernel
     at each block size that fits its VMEM (128 rows only where 512 do
-    not, or where asked by name) against XLA's masked sweep of the
+    not, or with ``every_block``) against XLA's masked sweep of the
     layer's slice, at ``shapes``, over frontiers early (64, 300), at row
     703 (what a ``decode-full`` stream fills at most), mixed as
     ``decode-full`` draws them, and at the buffer's end (the cost side:
@@ -171,7 +184,7 @@ def served_decode_rows(results: list, blocks=(128, 256, 512, 1024),
                  _layer_step(kernel(bk, form), layers)
                  for form in forms for bk in blocks
                  if decode_block_k(s, kvh, d, 2, h // kvh, bk) == bk
-                 and (bk >= 256 or fit < DECODE_BLOCK_K)}
+                 and (bk >= 256 or fit < DECODE_BLOCK_K or every_block)}
         frontiers = {"64": 64, "300": 300, "703": 703, "end": s - 1,
                      "mixed": _served_frontiers(b)}
         for name, at in frontiers.items():
@@ -445,7 +458,7 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json-out", default=None)
     ap.add_argument("--only", choices=["served-decode", "served-latent",
-                                       "one-row"],
+                                       "one-row", "narrow"],
                     default=None,
                     help="run one section instead of the whole sweep")
     args = ap.parse_args()
@@ -455,6 +468,10 @@ def main() -> int:
         if args.only == "one-row":
             served_decode_rows(rows, blocks=(128, 256, 384, 512),
                                shapes=ONE_ROW_SHAPES, forms=(False, True))
+        elif args.only == "narrow":
+            served_decode_rows(rows, blocks=(128, 256, 512),
+                               shapes=NARROW_SHAPES, forms=(False, True),
+                               every_block=True)
         else:
             if args.only == "served-decode":
                 served_decode_rows(rows)

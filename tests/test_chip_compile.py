@@ -187,6 +187,15 @@ KERNELS = {
         192, 6, 768, None, h=16, kvh=16),
     "flash_decode_stacked_b8_s2048_kvh16": _decode_stacked(
         2, 8, 2048, None, h=16, kvh=16),
+    # heads of 64, two to a lane tile (PR 52): the cell lfm2-8b-a1b-cut's
+    # own step (4 attention layers of 32 slots x 2048 rows, KVH 8 x G 4),
+    # the floor's 1024 rows, and TinyLlama's row of heads (KVH 4 x G 8)
+    "flash_decode_stacked_lfm2_b32_s2048_d64": _decode_stacked(
+        4, 32, 2048, None, h=32, kvh=8, d=64),
+    "flash_decode_stacked_b32_s1024_d64": _decode_stacked(
+        4, 32, 1024, None, h=32, kvh=8, d=64),
+    "flash_decode_stacked_b8_s2048_kvh4_d64": _decode_stacked(
+        2, 8, 2048, None, h=32, kvh=4, d=64),
     # the latent cells' decode step: A.X-K1's 64 heads over 8 layers of 32
     # slots x 4096 rows of 512 + 64, Ling-3.0-flash's 32 heads over its one
     # latent layer, and the floor's 1024 rows
@@ -1173,10 +1182,24 @@ def test_conv_and_attention_programs_fit_one_chip(topo, as_on_chip):
     lie as they are declared, two rows a tile (``T(2,128)``), and are
     copied once on the way into and once out of the step (3 MiB each, in
     ENTRY, in no loop); no expert stack is written out of the scanned
-    weights. Attention runs on XLA (``flash_decode`` wants heads of 128):
-    the step sweeps the reservation. The step's 128 pairs hit 0.98 of the
-    32 experts: the dense form, and so the 128-row admission; the 512- and
-    2048-row admissions sort, in each of the eight sparse segments."""
+    weights. The step's 128 pairs hit 0.98 of the 32 experts: the dense
+    form, and so the 128-row admission; the 512- and 2048-row admissions
+    sort, in each of the eight sparse segments.
+
+    Since PR 52 the STEP attends through the decode kernel, once in each
+    of the four attention segments, inside the layer loop (steps,
+    ``one_step``, layers: three ``while`` bodies deep). Asked for ``[KVH,
+    BK, 64]`` blocks of the cache as declared Mosaic REFUSES (my AOT
+    compile, PR 52: "Slice shape along dimension 4 must be aligned to
+    tiling (128), but is 64": it sees a buffer whose rows are padded to
+    128 lanes, which XLA would have had to write, both buffers, every
+    layer). So the kernel is handed the rows as columns and the heads in
+    pairs, ``bf16[4,32,4,128,2048]`` in the order it is declared in:
+    RECORDED (my AOT compile, PR 52) a ``bitcast`` of the carried buffer
+    in each segment, no value of either shape allocated or copied,
+    arguments 10.81 GiB as before, temporaries 0.0115 GiB (0.007 before:
+    the kernel's q and o a segment). The admissions (``T > 1``) keep XLA's
+    attention and their recorded sizes."""
     from cake_tpu.models.config import lfm2_8b_a1b
     from cake_tpu.utils.chips import HBM_GIB
 
@@ -1196,8 +1219,17 @@ def test_conv_and_attention_programs_fit_one_chip(topo, as_on_chip):
         assert len(tails) <= 2 and all(
             m.startswith("main") for m in tails), tails
     assert "3,2,1,0:T(2,128)(2,1)" in _layouts(decode, "bf16[12,32,2,2048]")
-    assert not [line for line in decode.as_text().splitlines()
-                if "tpu_custom_call" in line and "flash_decode" in line]
+    # the step's kernel reads the carried rows where they lie: its operand
+    # is a bitcast of them (rows as columns, heads in pairs), nothing else
+    calls = _decode_kernel_calls(decode)
+    assert len(calls) == 4 and all(
+        c.count("while/body") == 3 and "attn.full" in c for c in calls), calls
+    view = f"bf16[4,{slots},4,128,{window}]"
+    assert _layouts(decode, view) == {"4,3,2,1,0:T(8,128)(2,1)"}
+    assert {op for _, _, shape, op, _ in _instructions(decode)
+            if shape == view} == {"bitcast"}
+    for compiled in (admit128, admit512, admit2048):
+        assert "flash_decode" not in compiled.as_text()
     assert _grouped_matmul_calls(decode) == 0
     assert _grouped_matmul_calls(admit128) == 0
     assert _grouped_matmul_calls(admit512) == 24

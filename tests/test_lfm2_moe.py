@@ -253,11 +253,11 @@ def _run(bg, events=(), steps=40):
     return out
 
 
-def _is_the_references_argmax(tensors, prompt, out):
+def _is_the_references_argmax(tensors, prompt, out, cfg=CFG):
     """Every token of ``out`` is the single-stream reference's own best
     continuation of what came before it, to ``TIGHT``."""
     full = np.array(list(prompt) + list(out))
-    logits = np.asarray(ref.logits(CFG.to_hf_dict(), tensors, full))
+    logits = np.asarray(ref.logits(cfg.to_hf_dict(), tensors, full))
     for j, tok in enumerate(out):
         at = logits[len(prompt) - 1 + j]
         assert at.max() - at[tok] <= TIGHT, (len(prompt), j)
@@ -287,6 +287,46 @@ def test_batch_generator_streams_match_reference(params, tensors):
     # the CPU pads nothing: what the buffers occupy is what they hold
     assert reg.gauge("cache.device_bytes").value == reg.gauge(
         "cache.bytes").value
+
+
+def test_streams_match_reference_through_the_decode_kernel(monkeypatch):
+    """HEADS OF 64 (the published width; four over two KV heads here):
+    with the kernels on (``CAKE_PALLAS=1``, interpreted) the engine's
+    decode programs attend through ``flash_decode``, which packs the pair
+    of heads into one lane tile and reads the carried cache's rows as
+    columns; each stream's tokens stay the reference's argmax, through
+    admissions (XLA's attention) and block decode alike. The gauge says
+    the kernel is in the program, and the block counters count the rows
+    of the block the kernel fetches of THIS shape (128 here, so that the
+    256-row window is two): a stream reads the second only once its
+    frontier has passed row 127."""
+    from cake_tpu.ops.pallas import flash
+
+    monkeypatch.setenv("CAKE_PALLAS", "1")
+    monkeypatch.setattr(flash, "NARROW_BLOCK_K", 128)
+    cfg = tiny_lfm2_moe(max_seq_len=256, eos_token_id=-1, hidden_size=256,
+                        num_attention_heads=4, num_key_value_heads=2)
+    assert cfg.head_dim == 64 and flash.narrow_heads(64, 2)
+    params = _params(cfg)
+    tensors = latent_hf_tensors(params, cfg)
+    reg = metrics.registry()
+    read, reserved = (reg.counter("attn.kv_blocks_read"),
+                      reg.counter("attn.kv_blocks_reserved"))
+    reg.gauge("attn.decode_kernel").set(-1)
+    prompts = [PROMPTS[0], PROMPTS[4], PROMPTS[3]]  # 5, 100 and 21 tokens
+    bg = _engine(params, prompts, cfg=cfg)
+    assert bg._kv_block == 128
+    r0, v0 = read.value, reserved.value
+    outs = bg.generate(40)  # the 100-token stream crosses row 127
+    assert reg.gauge("attn.decode_kernel").value == 1
+    for prompt, out in zip(prompts, outs):
+        _is_the_references_argmax(tensors, prompt, list(out)[:40], cfg)
+    got, held = read.value - r0, reserved.value - v0
+    # every step of every stream reserves both blocks; the short streams
+    # read one each, the long one two from row 128 on
+    assert held % 6 == 0 and held // 2 < got < held
+    steps = held // 6
+    assert got - 3 * steps in range(steps - 40, steps - 20)
 
 
 @pytest.mark.parametrize("admit_chunk", [None, 4],

@@ -364,6 +364,32 @@ def test_flash_decode_one_query_row_at_the_looped_cells_shape(bk, frontiers):
     _row_frontiers_against_xla(kvh, 1, s, d, bk, list(frontiers), layers=2)
 
 
+@pytest.mark.parametrize("stacked", [True, False])
+@pytest.mark.parametrize("bk, window, form", [
+    (None, None, {}), (128, None, {}), (256, 300, {}), (512, 300, {}),
+    (256, None, {"batched": True}),
+])
+def test_flash_decode_heads_of_64_two_to_a_lane_tile(bk, window, form,
+                                                     stacked):
+    """Heads HALF a lane tile wide at the shape ``lfm2-8b-a1b-cut``
+    serves (KVH 8 x G 4, D 64): the kernel is handed the cache's rows as
+    columns, ``[.., KVH / 2, 128, S]``, and the query rows of a pair of
+    heads block-diagonal, and gives what XLA's attention gives over
+    ``[.., KVH, S, 64]``; on the stacked cache and on one layer's buffer,
+    at every block of the sweep and the one ``decode_block_k`` gives the
+    shape, frontiers at row 0, a block's two edges, row 703 and the
+    buffer's last row mixed in one batch, with and without a window,
+    the heads a pair at a time and in one batched call."""
+    from cake_tpu.ops.pallas import decode_block_k
+
+    kvh, group, s, d = 8, 4, 1024, 64
+    bk = bk or decode_block_k(s, kvh, d, 2, group)
+    _row_frontiers_against_xla(kvh, group, s, d, bk,
+                               [0, 1, bk - 1, bk, 703, s - 1],
+                               window=window, stacked=stacked, layers=2,
+                               **form)
+
+
 @pytest.mark.parametrize("pos,steps,window,want", [
     ([0, 1, 511, 512, 703, 2047], 1, None, (1 + 1 + 1 + 2 + 2 + 4, 24)),
     ([300], 8, None, (8, 32)),  # one block a step
@@ -552,7 +578,13 @@ DECODE_DISPATCH = {
                                  "decode"),
     "int8_cache": ((1, True, 2048, 128, 8, True, True), "xla"),
     "per_row_chunk": ((8, True, 2048, 128, 8, False, True), "xla"),
-    "head_64": ((1, True, 2048, 64, 8, False, True), "xla"),
+    # heads of 64 go two to a lane tile (PR 52); an odd number of them,
+    # heads of 32 and a chunk over heads of 64 stay on XLA
+    "head_64": ((1, True, 2048, 64, 8, False, True), "decode"),
+    "head_64_one_layer": ((1, True, 2048, 64, 8, False, False), "decode"),
+    "head_64_odd_kv_heads": ((1, True, 2048, 64, 3, False, True), "xla"),
+    "head_64_below_the_floor": ((1, True, 512, 64, 8, False, True), "xla"),
+    "head_32": ((1, True, 2048, 32, 8, False, True), "xla"),
     "below_the_floor": ((1, True, 512, 128, 8, False, True), "xla"),
     "not_whole_blocks": ((1, True, 2048 + 128, 128, 8, False, True), "xla"),
     # rows of heads whose 512-row blocks overflow VMEM: llama2_7b's 32 x
@@ -571,9 +603,11 @@ def test_attend_picks_the_decode_kernel_by_what_it_sees(case, monkeypatch):
     128-multiple head, whole blocks of ``DECODE_BLOCK_K`` rows that fit
     the kernel's VMEM and at least ``DECODE_FLASH_MIN_S`` rows takes the
     kernel, handed the stacked buffers and the layer index themselves; an
-    int8 cache, a per-row chunk (speculation verify), a 64-wide head, a
-    short cache, a ragged one and a row of heads too wide for VMEM stay
-    on XLA. Nothing but the input decides, and what was decided for the
+    int8 cache, a per-row chunk (speculation verify), a short cache, a
+    ragged one and a row of heads too wide for VMEM stay on XLA. A head of
+    64 takes the kernel too where the KV heads pair up (two to a lane
+    tile), from its own floor; an odd number of them, and narrower heads,
+    stay on XLA. Nothing but the input decides, and what was decided for the
     program being traced is published (``attn.decode_kernel``)."""
     import cake_tpu.ops.attention as attn
     from cake_tpu.obs import metrics
@@ -618,17 +652,30 @@ def test_attend_picks_the_decode_kernel_by_what_it_sees(case, monkeypatch):
             "flash" if want == "decode" or t > 1 else "xla")
 
 
+NARROW_BLOCK, NARROW_FLOOR = 512, 1024  # PR 52's sweep, ops/attention.py
+
 DECODE_POLICY = {
     # the decode shape of every accepted cell that attends through
     # ``ops/attention.py``: (rows, KV heads, group, head size) -> (choice,
-    # rows of the kernel's block). Every row but the last is what PR 47's
-    # tree answered; ``axk1-ep16-cut`` and ``ling3flash-ep4-cut`` attend
-    # through ``ops/mla.py`` / ``ops/kda.py`` and never ask.
+    # rows of the kernel's block). Every row with a head of 128 or 256 is
+    # what PR 50's tree answered (the grouped ones PR 47's);
+    # ``axk1-ep16-cut``, ``ling3flash-ep4-cut`` and ``xing4-29b-cut``
+    # attend through ``ops/mla.py`` / ``ops/kda.py`` and never ask.
     "mistral7b-int8": ((2048, 8, 4, 128), ("flash", 512)),
     "mixtral8x7b-cut": ((4096, 8, 4, 128), ("flash", 512)),
     "jamba2-3b": ((2048, 1, 20, 128), ("flash", 512)),
     "kexaone-ep8-cut_full_layer": ((4096, 8, 8, 128), ("flash", 512)),
-    "lfm2-8b-a1b-cut": ((2048, 8, 4, 64), ("xla", 512)),
+    # heads of 64 (PR 52): XLA until then; the kernel takes them two to a
+    # lane tile, in the block and from the floor its own sweep gave
+    "lfm2-8b-a1b-cut": ((2048, 8, 4, 64), ("flash", NARROW_BLOCK)),
+    "heads_of_64_at_the_floor": ((NARROW_FLOOR, 8, 4, 64),
+                                 ("flash", NARROW_BLOCK)),
+    "heads_of_64_under_the_floor": ((NARROW_FLOOR // 2, 8, 4, 64),
+                                    ("xla", NARROW_FLOOR // 2)),
+    "heads_of_64_tinyllama": ((2048, 4, 8, 64), ("flash", NARROW_BLOCK)),
+    "heads_of_64_odd_kv_heads": ((2048, 3, 3, 64), ("xla", 512)),
+    "heads_of_64_one_pair": ((2048, 2, 7, 64), ("xla", NARROW_BLOCK)),
+    "heads_of_32": ((2048, 8, 4, 32), ("xla", 512)),
     # rows of heads beside the cells', as they were: a tp=2 mesh's local
     # heads, a group at 768 rows (256-row blocks: XLA), an MHA 7B's 32 x
     # 128 and Gemma-7B's 16 x 256 (blocks that overflow VMEM shrink: XLA)
@@ -656,7 +703,8 @@ def test_decode_policy_of_every_accepted_cell(cell, monkeypatch):
     (``pk.on_tpu`` steered here), from the shapes and nothing else: a
     group of query rows a KV head keeps the answer and the 512-row block
     it had; ONE row a KV head takes the kernel in its own block, from
-    768 rows up and to the widest row of heads the sweep has."""
+    768 rows up and to the widest row of heads the sweep has; a group
+    over an even number of 64-wide heads takes it from its own floor."""
     import cake_tpu.ops.attention as attn
     from cake_tpu.ops import pallas as pk
 
