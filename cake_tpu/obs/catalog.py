@@ -286,13 +286,42 @@ SERIES: dict[str, tuple[str, str]] = {
                  "before any row of the landed block was handed out (all "
                  "of them, but where a live guide, batched speculation or "
                  "a chunked admission keeps the host between steps)"),
+    "engine.boundary_emit_ms": (
+        HISTOGRAM, "first part of a boundary at which the device WAITED "
+                   "(a later step() than the landing one enqueued its "
+                   "next program; none where an admission launched while "
+                   "the block ran was that program already, so the mean "
+                   "is a clear boundary's and an idle landing's): the "
+                   "block's fetch returned -> the landing step() "
+                   "returned, i.e. recording the block's rows"),
+    "engine.boundary_enqueue_ms": (
+        HISTOGRAM, "third part of such a boundary: the entry of the "
+                   "step() that enqueues -> its program call returned "
+                   "(the step's preamble, an admission tick, the "
+                   "frontiers' uploads, the call); the three parts add "
+                   "up to engine.boundary_ms's observation less what "
+                   "that step() does after the enqueue"),
     "engine.boundary_ms": (
         HISTOGRAM, "host time from a decode block's fetch returning to "
                    "the return of the step() call that enqueued the "
-                   "device's next program, once per landed block: the "
+                   "device's next program (so a row's hand-out after the "
+                   "enqueue is in it), once per landed block: the "
                    "device has nothing to run while it lasts (next to "
                    "nothing where an arrival's prefill was launched while "
                    "the block still ran)"),
+    "engine.boundary_pass_ms": (
+        HISTOGRAM, "second part of such a boundary: the landing step() "
+                   "returned -> the step() that enqueues was entered: "
+                   "the caller's pass between the two (the scheduler's "
+                   "deliver, retire, pass_rest, sched_admit)"),
+    "engine.landing_counts_fetch_ms": (
+        HISTOGRAM, "an expert model's counts of landed decode blocks "
+                   "(what moe.local_pairs and moe.experts_hit add up): "
+                   "the time of their fetches, once per step() that "
+                   "fetched some. They are fetched at the return of a "
+                   "step() that leaves no boundary open, i.e. under the "
+                   "device's next program, not while it waits for one; "
+                   "nothing where no decode program counts"),
     "engine.landings_ahead": (
         COUNTER, "landings whose splice AND the device's next program "
                  "(the next arrival's prefill, else the next decode "
@@ -493,10 +522,10 @@ DYNAMIC: dict[str, tuple[str, str]] = {
     "prof.phase_ms.*": (
         HISTOGRAM, "per-phase wall ms inside sampled engine steps "
                    "(admit with admit_launch/admit_land inside it, "
-                   "pages/guide/dispatch/sync/emit and the spec_* "
-                   "phases) and of the scheduler's pass around them "
-                   "(idle_park/sched_admit/deliver/retire) — "
-                   "obs/prof.PHASES"),
+                   "pages/guide/dispatch/sync/sync_counts/emit and the "
+                   "spec_* phases) and of the scheduler's pass around "
+                   "them (idle_park/sched_admit/deliver/retire/"
+                   "pass_rest) — obs/prof.PHASES"),
     "serve.ttft_ms.*": (
         HISTOGRAM, "per-class submit-to-first-token (serve.session "
                    "CLASSES — the SLO rows split interactive from "

@@ -8,10 +8,12 @@ pay, churn vs steady, SLO scheduling) hinges on. Three arms:
 - :class:`StepProfiler` — the ``BatchGenerator`` / ``SingleStreamEngine``
   step loops stamp each pass into named phases (``admit`` with
   ``admit_launch`` and ``admit_land`` inside it, ``pages``,
-  ``guide``, ``dispatch``, ``sync``, ``emit``, and the speculative
-  ``spec_propose`` / ``spec_verify`` / ``spec_accept``; the scheduler
-  adds ``idle_park`` between passes and times the parts of its own pass
-  around ``engine.step()``: ``sched_admit``, ``deliver``, ``retire``).
+  ``guide``, ``dispatch``, ``sync``, ``sync_counts``, ``emit``, and the
+  speculative ``spec_propose`` / ``spec_verify`` / ``spec_accept``; the
+  scheduler adds ``idle_park`` between passes and times the parts of its
+  own pass around ``engine.step()``: ``sched_admit``, ``deliver``,
+  ``retire`` and, for everything else it does between two steps,
+  ``pass_rest``).
   Each sampled step feeds the
   per-phase ``prof.phase_ms.*`` histograms and a bounded ring of recent
   step records. Sampling every Nth step (``--prof-sample``, default
@@ -23,7 +25,8 @@ pay, churn vs steady, SLO scheduling) hinges on. Three arms:
   (``at_ms``), so it can be laid on a trace or a client's timeline. A
   scheduler pass longer than :data:`SLOW_PASS_MS` leaves its parts in a
   second ring (``slow_passes``, with the thread's CPU time and the
-  step's longest device fetch beside the parts) and in
+  step's longest device fetch, and which fetch that was, beside the
+  parts) and in
   ``prof.slow_pass_ms``: what the program keeps about a pause of
   seconds. Phase stamping is
   host-side driver code only — never inside a jitted body (cakelint
@@ -58,8 +61,11 @@ pay, churn vs steady, SLO scheduling) hinges on. Three arms:
   ``--profile`` both call them. A capture stamps every step (stride 1),
   runs the span tracer with ``xla_annotations=True`` so every phase is a
   ``prof.<phase>`` ``TraceAnnotation`` on the engine thread's line of
-  the trace's host plane (a device idle gap can then be named by engine
-  phase), and writes the program's own spans beside the profile
+  the trace's host plane, one open at a time: the innermost phase's, so
+  that a device idle gap is named by the LEAF of the host's work under
+  it (``obs/trace._leaf_annotation``; ``prof.admit`` there is the
+  tick's self time, not its landing's). It writes the program's own
+  spans, nested as ever, beside the profile
   (``spans.trace.json``). One capture at a time; a capture nobody stops
   is stopped after :data:`CAPTURE_MAX_S`. Off, it costs nothing: no
   thread, and ``jax.profiler`` is not imported before the first start.
@@ -111,11 +117,13 @@ PHASES = (
     "guide",         # constrain guide/mask advance (host DFA cursor)
     "dispatch",      # device dispatch call (async: enqueue cost only)
     "sync",          # device sync + host fetch (where compute lands)
+    "sync_counts",   # an expert model's per-block counts: their fetch
     "emit",          # detok / Token fan-out / bookkeeping
     "idle_park",     # scheduler parked waiting for work
     "sched_admit",   # scheduler pass: queue -> engine.enqueue (+ preempt)
     "deliver",       # scheduler pass: emitted row -> session event queues
     "retire",        # scheduler pass: close out ended sessions
+    "pass_rest",     # scheduler pass: all else between two engine steps
     "spec_propose",  # speculative draft proposal (host n-gram walk)
     "spec_verify",   # speculative verify dispatch
     "spec_accept",   # accept/rollback: accept program + bank fetch
@@ -232,7 +240,8 @@ class StepProfiler:
 
     def pass_part(self, name: str) -> _Phase:
         """Context manager timing one part of a scheduler pass
-        (``sched_admit``/``deliver``/``retire``), which lies outside any
+        (``sched_admit``/``deliver``/``retire``/``pass_rest``), which lies
+        outside any
         engine step: always timed (the caller reads ``ms`` for its
         slow-pass record), into the part's phase histogram, and a
         ``prof.<name>`` span while the tracer runs."""
@@ -240,7 +249,7 @@ class StepProfiler:
 
     def note_pass(self, total_ms: float, parts: dict, queued: int,
                   running: int, cpu_ms: float = 0.0,
-                  fetch_ms: float = 0.0) -> None:
+                  fetch_ms: float = 0.0, fetch_of: str = "") -> None:
         """One scheduler pass ended after ``total_ms`` (parked time left
         out). A pass over :data:`SLOW_PASS_MS` adds its length to
         ``prof.slow_pass_ms`` and leaves a record -- when, how long, in
@@ -251,7 +260,9 @@ class StepProfiler:
         whether the engine's thread ran or waited: ``cpu_ms`` is the CPU
         time the thread got during the pass (``time.thread_time``),
         ``fetch_ms`` the longest wait for the device inside the engine's
-        step. ``fetch_ms`` ~ ``total_ms``: the runtime or the device held
+        step and ``fetch_of`` which fetch that was (``block:<steps>``,
+        ``admit_land:<bucket>``, ``counts:<blocks>``; empty: none).
+        ``fetch_ms`` ~ ``total_ms``: the runtime or the device held
         the pass; ``cpu_ms`` ~ ``total_ms``: Python ran; neither: the
         thread was descheduled or waited for a lock."""
         if total_ms < SLOW_PASS_MS:
@@ -263,6 +274,7 @@ class StepProfiler:
         rec.update((k, round(v, 3)) for k, v in parts.items())
         rec["rest_ms"] = round(max(0.0, total_ms - sum(parts.values())), 3)
         rec["cpu_ms"], rec["fetch_ms"] = round(cpu_ms, 3), round(fetch_ms, 3)
+        rec["fetch_of"] = fetch_of
         rec["queued"], rec["running"] = queued, running
         # the ring dies with the process and a benchmark run keeps only
         # the server's log: say there which part of the pass held it

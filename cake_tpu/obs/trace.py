@@ -21,10 +21,13 @@ named track per worker next to the master's own.
 Disabled (the default), ``span()`` returns a shared no-op context manager —
 one attribute check per call site, nothing recorded. Enable with
 ``tracer().start()`` (the CLI's ``--trace PATH`` does this and writes the
-file on exit). ``start(xla_annotations=True)`` additionally wraps every span
-in ``jax.profiler.TraceAnnotation`` so the same names appear inside XLA
-profiles: a capture (``obs/prof.capture_start``: ``POST /debug/trace`` on a
-server, ``--profile`` on the master path) runs the tracer that way.
+file on exit). ``start(xla_annotations=True)`` additionally passes the spans
+through to XLA profiles as ``jax.profiler.TraceAnnotation``s, flattened: at
+most one annotation is open a thread, the innermost live span's, and a
+parent's name re-appears when its child ends (``_leaf_annotation``; the
+tracer's own records stay nested). A capture (``obs/prof.capture_start``:
+``POST /debug/trace`` on a server, ``--profile`` on the master path) runs
+the tracer that way.
 """
 
 from __future__ import annotations
@@ -220,13 +223,35 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _leaf_annotation(name: str | None) -> None:
+    """This thread's ONE open ``TraceAnnotation`` becomes ``name``'s (None:
+    closed). The profile's host plane gets the innermost live span alone,
+    as flat pieces: a reader that names a stretch by the annotation
+    covering most of it (``benchmark/trace_reduce._host_label``) then
+    names it by the leaf of the host's work, and a parent's name stands
+    for its self time only."""
+    ann = getattr(_local, "ann", None)
+    if ann is not None:
+        _local.ann = None
+        ann.__exit__(None, None, None)
+    if name is None:
+        return
+    try:
+        from jax.profiler import TraceAnnotation
+
+        ann = TraceAnnotation(name)
+        ann.__enter__()
+        _local.ann = ann
+    except Exception:
+        pass
+
+
 class _Span:
-    __slots__ = ("_name", "_args", "_t0", "_ann", "_id")
+    __slots__ = ("_name", "_args", "_t0", "_id")
 
     def __init__(self, name: str, args: dict):
         self._name = name
         self._args = args
-        self._ann = None
 
     def __enter__(self):
         stack = _stack()
@@ -235,23 +260,21 @@ class _Span:
         self._id = _TRACER.next_span_id()
         stack.append((self._name, self._id))
         if _TRACER.xla_annotations:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                self._ann = TraceAnnotation(self._name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
+            # closes the enclosing span's annotation: leaves, not parents
+            _leaf_annotation(self._name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._t0
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
         stack = _stack()
         if stack and stack[-1][1] == self._id:
             stack.pop()
+        if _TRACER.xla_annotations:
+            # the enclosing span is the leaf again, under its own name
+            _leaf_annotation(stack[-1][0] if stack else None)
+        elif getattr(_local, "ann", None) is not None:
+            _leaf_annotation(None)  # the capture closed under this span
         _TRACER.record(self._name, self._t0, dur, self._args)
         return False
 

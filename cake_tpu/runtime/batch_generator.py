@@ -222,6 +222,19 @@ _HELD = {"state": "recurrent state", "conv": "convolution's tail",
 _BOUNDARY_MS = obs_metrics.histogram("engine.boundary_ms")
 _BOUNDARIES = obs_metrics.counter("engine.boundaries")
 _BOUNDARIES_AHEAD = obs_metrics.counter("engine.boundaries_ahead")
+# Where that host time is spent, of the boundaries at which the device
+# WAITED, i.e. a later step() than the landing one enqueued the program
+# (one that an admission launched ahead closes at once and leaves nothing
+# here): the fetch's return -> the landing step()'s return (the rows'
+# recording) -> the entry of the step() that enqueues (the caller's pass)
+# -> the program call's return. They end where the device has its
+# program; engine.boundary_ms runs on to that step()'s return.
+_BOUNDARY_PARTS_MS = (obs_metrics.histogram("engine.boundary_emit_ms"),
+                      obs_metrics.histogram("engine.boundary_pass_ms"),
+                      obs_metrics.histogram("engine.boundary_enqueue_ms"))
+# an expert model's counts of the landed blocks, fetched at the return of
+# a step() that leaves no boundary open (_fetch_moe_counts): how long
+_COUNTS_FETCH_MS = obs_metrics.histogram("engine.landing_counts_fetch_ms")
 # An admission's stages, stamped on perf_counter where each boundary is
 # crossed (BatchGenerator._observe_admission, once per landed prompt
 # admission), and a block's period, landing to landing (_land_block):
@@ -597,6 +610,12 @@ class BatchGenerator:
         self._landed_at: float | None = None
         self._landed_rows_out = False
         self._next_ahead: bool | None = None
+        # an open boundary's later stamps (engine.boundary_*_ms's edges):
+        # when the landing step() returned, when the current step() was
+        # entered, when the next program's call returned inside it
+        self._returned_at: float | None = None
+        self._step_at: float | None = None
+        self._enqueued_at: float | None = None
         # a block's period, landing to landing: when the previous block
         # landed (forgotten where the engine goes idle, so the wait for
         # the next request is no period) and whether an admission has
@@ -605,8 +624,11 @@ class BatchGenerator:
         self._period_admitted = False
         # the longest wait for the device inside the current step() (a
         # block's fetch, an admission's first token): a slow scheduler
-        # pass says with it whether the engine's thread ran or waited
+        # pass says with it whether the engine's thread ran or waited,
+        # and for which fetch: "block:<steps>", "admit_land:<bucket>",
+        # "counts:<blocks>"
         self.step_fetch_ms = 0.0
+        self.step_fetch_of = ""
         # landed admissions' stages by stream id, until the scheduler
         # takes them for the request's own timeline
         # (take_admission_stages); the oldest go where nobody does
@@ -841,8 +863,12 @@ class BatchGenerator:
         # pairs that fell on held experts as one more value
         # (pipeline.moe_counted), fetched with the block's tokens
         self._moe_counted = moe_counted(config)
-        # (local pairs a row, steps, live rows) of dispatches not yet fetched
+        # (local pairs a row, steps, live rows) of dispatches not yet
+        # fetched, and how many of them (the oldest) have landed: their
+        # counts are ready, and are fetched once the device has its next
+        # program (_fetch_moe_counts), not while it waits for one
         self._moe_pending: deque = deque()
+        self._moe_landed = 0
         # engine profiling plane (obs/prof): sampled step-phase stamps +
         # the runtime retrace sentinel watching this engine's dispatches
         self._prof = obs_prof.profiler()
@@ -1379,6 +1405,8 @@ class BatchGenerator:
         # text
         self._pending_rows: list[list[Token | None]] = []
         self._inflight = None  # any prior in-flight block is stale now
+        self._fetch_moe_counts()  # a landed block's counts stay counted
+        self._moe_pending.clear()  # ... the stale block's go with it
         self._landed_at = self._period_from = None
         # (device value, what it holds) of the frontiers and the token
         # indices the last block program returned: _carried
@@ -2662,8 +2690,8 @@ class BatchGenerator:
             t_fetch = time.perf_counter()
             tok_ids = self._host(toks)
             landed = time.perf_counter()
-            self.step_fetch_ms = max(self.step_fetch_ms,
-                                     (landed - t_fetch) * 1e3)
+            self._note_fetch((landed - t_fetch) * 1e3, "admit_land",
+                             st["booking"][1])
             return tok_ids, landed
 
         fetched = fetch() if guide is not None else None
@@ -3023,7 +3051,8 @@ class BatchGenerator:
             raise RuntimeError("set_prompts first")
         prof = self._prof
         prof.step_begin("batch")
-        self.step_fetch_ms = 0.0
+        self.step_fetch_ms, self.step_fetch_of = 0.0, ""
+        self._note_step()
         try:
             if not self._emitted_first:
                 self._emitted_first = True
@@ -3056,26 +3085,61 @@ class BatchGenerator:
             return self._step_decode()
         finally:
             self._close_boundary()
+            if self._landed_at is None:  # the device waits for nothing
+                self._fetch_moe_counts()
             prof.step_end()
+
+    def _note_step(self) -> None:
+        """A step() was entered: with a boundary open it may be the one
+        that enqueues (``engine.boundary_pass_ms`` ends here)."""
+        if self._landed_at is not None:
+            self._step_at = time.perf_counter()
+
+    def _note_fetch(self, ms: float, of: str, size: int) -> None:
+        """A wait for the device inside this step() took ``ms``: keep the
+        longest, and which it was (a slow scheduler pass says both)."""
+        if ms > self.step_fetch_ms:
+            self.step_fetch_ms, self.step_fetch_of = ms, f"{of}:{size}"
 
     def _note_enqueued(self) -> None:
         """A device program was just enqueued: if a landed block's
         boundary is open, this is its next program."""
         if self._landed_at is not None and self._next_ahead is None:
             self._next_ahead = not self._landed_rows_out
+            # inside a step() entered with the boundary open (not the
+            # synchronous admit()'s launch): the third part's far edge
+            if self._step_at is not None:
+                self._enqueued_at = time.perf_counter()
 
     def _close_boundary(self) -> None:
         """At the return of a step(): if this call enqueued the next
         program after a landed block, the boundary is over --
         ``engine.boundary_ms`` takes the host time since the fetch
         returned, ``engine.boundaries_ahead`` whether the program left
-        before any of the block's rows did."""
-        if self._landed_at is None or self._next_ahead is None:
+        before any of the block's rows did. If the device WAITED for
+        that program (a later step() than the landing one enqueued it;
+        not where an admission launched while the block ran was the next
+        program already), ``engine.boundary_emit_ms``, ``_pass_ms`` and
+        ``_enqueue_ms`` take where the wait was spent: recording the
+        rows, the caller's pass between the two step() calls, and the
+        enqueuing step() up to its program call's return. Their sum is
+        what ``engine.boundary_ms`` observes less what that step() does
+        after the enqueue (a row's hand-out)."""
+        if self._landed_at is None:
+            return
+        if self._next_ahead is None:
+            if self._returned_at is None:  # the landing step() returns
+                self._returned_at, self._step_at = time.perf_counter(), None
             return
         _BOUNDARY_MS.observe((time.perf_counter() - self._landed_at) * 1e3)
         _BOUNDARIES.inc()
         if self._next_ahead:
             _BOUNDARIES_AHEAD.inc()
+        if self._returned_at is not None and self._enqueued_at is not None:
+            edges = (self._landed_at, self._returned_at, self._step_at,
+                     self._enqueued_at)
+            for hist, t0, t1 in zip(_BOUNDARY_PARTS_MS, edges, edges[1:]):
+                hist.observe((t1 - t0) * 1e3)
         self._landed_at = self._next_ahead = None
 
     def _spec_emit_or_round(self):
@@ -3463,9 +3527,12 @@ class BatchGenerator:
         shutdown / measurement boundary: tokens are recorded against
         their streams immediately (same `_record` path as stepping); the
         Token rows land in the pending queue for any consumer still
-        calling step(), which is where they are counted as emitted."""
+        calling step(), which is where they are counted as emitted. An
+        expert model's queued counts are fetched too: ``moe.*`` hold
+        every landed block."""
         self._domain_stamp.check("BatchGenerator.drain")
         self._drain_buffered_rows()
+        self._fetch_moe_counts()
 
     def _drain_buffered_rows(self) -> None:
         """Fetch an in-flight block and record its rows into the pending
@@ -3488,10 +3555,10 @@ class BatchGenerator:
             rows = self._host(toks)  # [steps, B]
             lp = ((self._host(lpv), self._host(lpi))
                   if lpv is not None else None)
-            self._record_moe_count()
         landed = time.perf_counter()
-        self.step_fetch_ms = max(self.step_fetch_ms,
-                                 (landed - t_fetch) * 1e3)
+        self._note_fetch((landed - t_fetch) * 1e3, "block", size)
+        if self._moe_counted:
+            self._moe_landed += 1  # its counts are ready: fetched later
         if self._period_from is not None:
             # landing to landing: what a live stream waits for its next
             # block of tokens; clear where no admission landed in between
@@ -3644,12 +3711,27 @@ class BatchGenerator:
         self._moe_pending.append((out[-1], steps, live))
         return out[:-1]
 
-    def _record_moe_count(self) -> None:
-        """Fetch the oldest queued counts (their dispatch's tokens were
-        just fetched, so they are ready) and add the live rows' into
-        ``moe.*``."""
-        if not self._moe_pending:
+    def _fetch_moe_counts(self) -> None:
+        """Fetch the counts of every dispatch whose tokens have landed
+        (they are ready) into ``moe.*``. Feeding two per-layer metrics is
+        all these round trips are for, so they are made where the device
+        does not wait for the host: at the return of a step() that
+        leaves no boundary open (the next program is enqueued, or
+        nothing follows), and in ``drain()``."""
+        blocks, self._moe_landed = self._moe_landed, 0
+        if not blocks:
             return
+        t0 = time.perf_counter()
+        with self._prof.phase("sync_counts"):
+            for _ in range(blocks):
+                self._record_moe_count()
+        ms = (time.perf_counter() - t0) * 1e3
+        _COUNTS_FETCH_MS.observe(ms)
+        self._note_fetch(ms, "counts", blocks)
+
+    def _record_moe_count(self) -> None:
+        """Fetch the oldest queued counts and add the rows' that were
+        live when their dispatch left into ``moe.*``."""
         count, steps, live = self._moe_pending.popleft()
         _MOE_LOCAL.inc(int(self._host(count.pairs)[live].sum()))
         # every row that went through the program, a dead slot's too: what
@@ -3678,6 +3760,7 @@ class BatchGenerator:
         if self._inflight is not None or self._enqueue_block(ahead=False):
             self._landed_at = self._land_block()
             self._landed_rows_out = False
+            self._returned_at = self._enqueued_at = None
             # an admission launched while the block ran is the device's
             # next program already
             launched = self._staging is not None and "logits" in self._staging
@@ -3729,7 +3812,8 @@ class BatchGenerator:
             row = self._host(tok)
             lp_h = ((self._host(lpv_d), self._host(lpi_d))
                     if lpv_d is not None else None)
-            self._record_moe_count()
+        if self._moe_counted:
+            self._moe_landed += 1
         self._n_decode_dispatches += 1
         self._count_kv_blocks(pos, 1)
         dt = time.perf_counter() - t0

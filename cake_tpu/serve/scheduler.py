@@ -654,16 +654,23 @@ class Scheduler:
             # ``slow_passes`` (obs/prof.StepProfiler.note_pass)
             t_pass, cpu_pass = time.perf_counter(), time.thread_time()
             parked = 0.0
-            with self._cond:
+            # whatever the pass does outside sched_admit / step / deliver /
+            # retire is ``pass_rest`` (the stretches here and the one
+            # after retire): at a block boundary the device waits through
+            # all of it. Parked time is idle_park's, not the pass's.
+            rest = prof.pass_part("pass_rest")
+            with rest, self._cond:
                 self._expire_queued_locked()
                 while not self._stopping and not self._has_work_locked():
                     if self._draining:
                         break  # drained dry: park
+                    rest.__exit__(None, None, None)
                     t_park = time.perf_counter()
                     self._cond.wait(timeout=0.1)
                     dt_park = time.perf_counter() - t_park
                     parked += dt_park
                     prof.observe_ms("idle_park", dt_park * 1e3)
+                    rest.__enter__()
                     self._expire_queued_locked()
                     # imports awaiting resume are not "work" (nothing to
                     # step), but their TTL must still tick while parked —
@@ -677,9 +684,11 @@ class Scheduler:
                     break
                 queued, running = len(self._queue), len(self._by_sid)
             try:
-                self._drain_import_inbox()
-                self._sweep_imports()
-                if self._migrate_all():
+                with rest:
+                    self._drain_import_inbox()
+                    self._sweep_imports()
+                    migrated = self._migrate_all()
+                if migrated:
                     # the slot set just went empty: skip the engine step
                     # and let the top-of-loop drain check park/exit
                     self._refresh_engine_stats(best_effort=True)
@@ -696,15 +705,17 @@ class Scheduler:
                     self._deliver(row)
                 with prof.pass_part("retire"):
                     self._retire()
-                self._sweep_spilled()
-                self._fail_lost_attaches()
-                self._refresh_engine_stats()
-                prof.note_pass(
-                    (time.perf_counter() - t_pass - parked) * 1e3,
-                    {"admit_ms": p_admit.ms, "step_ms": step_ms,
-                     "deliver_ms": p_deliver.ms}, queued, running,
-                    cpu_ms=(time.thread_time() - cpu_pass) * 1e3,
-                    fetch_ms=getattr(self.engine, "step_fetch_ms", 0.0))
+                with rest:
+                    self._sweep_spilled()
+                    self._fail_lost_attaches()
+                    self._refresh_engine_stats()
+                    prof.note_pass(
+                        (time.perf_counter() - t_pass - parked) * 1e3,
+                        {"admit_ms": p_admit.ms, "step_ms": step_ms,
+                         "deliver_ms": p_deliver.ms}, queued, running,
+                        cpu_ms=(time.thread_time() - cpu_pass) * 1e3,
+                        fetch_ms=getattr(self.engine, "step_fetch_ms", 0.0),
+                        fetch_of=getattr(self.engine, "step_fetch_of", ""))
             except Exception as e:  # engine fault: fail every session
                 log.exception("engine thread fault: %s", e)
                 self.fault = f"{type(e).__name__}: {e}"

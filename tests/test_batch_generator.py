@@ -1452,6 +1452,99 @@ def test_plain_run_enqueues_ahead_at_every_boundary(params):
     assert b1 - b0 == a1 - a0 == n1 - n0 >= 5
 
 
+class _BoundaryLog(list):
+    """What ``engine.boundary_ms`` and the three histograms of its parts
+    observed, ``[(series, ms)]``, taken after each ``step()`` from the
+    growth of their counts and sums (a step observes each at most
+    once)."""
+
+    NAMES = ("engine.boundary_ms", "engine.boundary_emit_ms",
+             "engine.boundary_pass_ms", "engine.boundary_enqueue_ms")
+
+    def __init__(self):
+        from cake_tpu.obs import metrics
+
+        self._hists = [metrics.registry().histogram(n) for n in self.NAMES]
+        self._at = [(h.count, h.sum) for h in self._hists]
+
+    def step(self, g):
+        row = g.step()
+        for k, h in enumerate(self._hists):
+            count, total = self._at[k]
+            assert h.count - count in (0, 1)
+            if h.count > count:
+                self.append((h.name, h.sum - total))
+            self._at[k] = (h.count, h.sum)
+        return row
+
+
+@pytest.fixture
+def boundary_log():
+    return _BoundaryLog()
+
+
+def test_a_boundary_a_later_step_closed_leaves_its_three_parts(
+        params, boundary_log):
+    """Where the device waited for its next program (the ``step()``
+    after the landing one enqueued it), the boundary leaves one
+    observation in each of ``engine.boundary_emit_ms``, ``_pass_ms`` and
+    ``_enqueue_ms``: recording the rows, the caller's pass between the
+    two calls, the enqueuing call up to its program. Boundary by boundary
+    they add up to no more than ``engine.boundary_ms`` took (it runs on
+    to the enqueuing step()'s return), and the caller's pass is in the
+    second part."""
+    import time
+
+    g = BG(CFG, params, settings=SamplerSettings(**GREEDY), block_size=4)
+    g.set_prompts([list(p) for p in PROMPTS])
+    for _ in range(22):
+        row = boundary_log.step(g)
+        if not any(t is not None for t in row):
+            time.sleep(0.02)  # the caller's pass after a landing
+    closed = [i for i, (name, _) in enumerate(boundary_log)
+              if name == "engine.boundary_ms"]
+    assert len(closed) >= 4 and len(boundary_log) == 4 * len(closed)
+    for i in closed:  # the whole first, then its parts
+        (_, whole), *parts = boundary_log[i:i + 4]
+        assert [n for n, _ in parts] == [
+            "engine.boundary_emit_ms", "engine.boundary_pass_ms",
+            "engine.boundary_enqueue_ms"]
+        emit, between, enqueue = (ms for _, ms in parts)
+        assert min(emit, between, enqueue) > 0.0
+        assert emit + between + enqueue <= whole
+        assert 20.0 <= between < whole
+
+
+def test_a_boundary_an_admission_was_launched_ahead_of_leaves_no_part(
+        params, boundary_log):
+    """A block that lands with an arrival's prefill launched behind it
+    closes its boundary at once: ``engine.boundary_ms`` observes it all
+    the same, the three parts nothing, so their means are those of the
+    boundaries at which the device waited."""
+    g = BG(CFG, params, settings=SamplerSettings(**GREEDY), block_size=4)
+    g.warm_admission(8)
+    g.set_prompts([list(PROMPTS[0]), [1]], stream_ids=[0, 99])
+    g.streams[1].done = True
+    for _ in range(3):  # first tokens; block 1 lands; block 2 leaves
+        boundary_log.step(g)
+    assert g._inflight is not None
+    waited = [n for n, _ in boundary_log]
+    assert waited.count("engine.boundary_ms") == 1 and len(waited) == 4
+    g.enqueue([2, 8, 1], stream_id=7)  # launched under block 2
+    del boundary_log[:]
+    for _ in range(4):  # block 1's rows go out, block 2 lands
+        boundary_log.step(g)
+    assert g._staging is not None and "logits" in g._staging
+    assert [n for n, _ in boundary_log] == ["engine.boundary_ms"]
+    for _ in range(12):  # the landing, then boundaries that wait again
+        boundary_log.step(g)
+    names = [n for n, _ in boundary_log]
+    assert names.count("engine.boundary_ms") >= 3
+    for part in ("emit", "pass", "enqueue"):
+        assert names.count(f"engine.boundary_{part}_ms") == (
+            names.count("engine.boundary_ms") - 1)
+
+
 def test_slot_not_reclaimed_while_its_rows_are_undelivered(params):
     """A server maps a row's slots to streams when step() RETURNS the row.
     An admission's splice emits the buffered block rows early (into the

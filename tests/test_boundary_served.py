@@ -30,6 +30,9 @@ CFG = tiny(max_seq_len=64, eos_token_id=-1)
 GREEDY = dict(temperature=0.0, repeat_penalty=1.1)
 SERIES = ("engine.boundaries", "engine.boundaries_ahead",
           "engine.boundary_ms")
+# where a boundary's host time is spent (PR 53): a histogram a part
+PARTS = ("engine.boundary_emit_ms", "engine.boundary_pass_ms",
+         "engine.boundary_enqueue_ms")
 # budgets that end inside a block of 4, at its edge, and after one token
 REQUESTS = [("hello", 7), ("world", 8), ("abcde", 9), ("zyx", 1),
             ("hellothere", 14), ("cake", 5)]
@@ -103,7 +106,8 @@ def served(params):
     one request at a time, by a server of single steps."""
     out = {}
     for name, kw in (("blocks", dict(block_size=4)), ("single", {})):
-        before = {n: _value(n) for n in SERIES}
+        before = {n: _value(n) for n in SERIES + PARTS}
+        sums = {n: _sum(n) for n in SERIES[2:] + PARTS}
         srv, sched = _serve(params, **kw)
         try:
             res: dict = {}
@@ -124,13 +128,18 @@ def served(params):
         finally:
             srv.close()
             sched.close()
-        out[name] = (res, {n: _value(n) - before[n] for n in SERIES})
+        out[name] = (res, {n: _value(n) - before[n] for n in SERIES + PARTS},
+                     {n: _sum(n) - v for n, v in sums.items()})
     return out
 
 
 def _value(name: str) -> float:
     snap = obs_metrics.registry().snapshot().get(name, {})
     return snap.get("value", snap.get("count", 0))
+
+
+def _sum(name: str) -> float:
+    return obs_metrics.registry().snapshot().get(name, {}).get("sum", 0.0)
 
 
 @pytest.mark.parametrize("prompt,budget", REQUESTS)
@@ -165,7 +174,25 @@ def test_every_served_boundary_enqueued_ahead(served):
     assert blocks["engine.boundaries"] >= 4
     assert (blocks["engine.boundaries"] == blocks["engine.boundaries_ahead"]
             == blocks["engine.boundary_ms"])
-    assert not any(single.values())
+    assert not any(single[n] for n in SERIES)
+
+
+def test_the_boundarys_parts_are_observed_where_the_device_waited(served):
+    """Through the scheduler's pass: ``engine.boundary_emit_ms``,
+    ``_pass_ms`` and ``_enqueue_ms`` hold one observation each a boundary
+    that the NEXT pass's ``step()`` closed, and none for one that an
+    admission launched under the running block closed at once (six
+    clients on three slots: some are), so each counts
+    ``engine.boundary_ms``'s observations less those; together they took
+    no longer than ``engine.boundary_ms`` did. Single steps land no
+    block."""
+    _, blocks, sums = served["blocks"]
+    waited = blocks[PARTS[0]]
+    assert 1 <= waited <= blocks["engine.boundary_ms"]
+    assert all(blocks[n] == waited for n in PARTS)
+    assert all(sums[n] > 0.0 for n in PARTS)
+    assert sum(sums[n] for n in PARTS) <= sums["engine.boundary_ms"]
+    assert not any(served["single"][1][n] for n in PARTS)
 
 
 def test_the_series_are_declared_and_on_metrics():
@@ -173,7 +200,7 @@ def test_the_series_are_declared_and_on_metrics():
     assert catalog.kind_of("engine.boundaries") == catalog.COUNTER
     assert catalog.kind_of("engine.boundaries_ahead") == catalog.COUNTER
     snap = obs_metrics.registry().snapshot()
-    for name in SERIES:
+    for name in SERIES + PARTS + ("engine.landing_counts_fetch_ms",):
         assert snap[name]["type"] == catalog.kind_of(name), name
 
 

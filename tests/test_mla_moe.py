@@ -236,6 +236,70 @@ def test_moe_counters_and_cache_gauges(params, share):
     assert reg.gauge("cache.bytes").value == 3 * 2 * 64 * 4 * (16 + 8)
 
 
+@pytest.mark.parametrize("end", ["drain", "idle"])
+def test_counts_fetched_after_the_enqueue_are_those_of_a_fetch_at_the_landing(
+        params, end):
+    """A landed block's counts are fetched once the device has its next
+    program (``_fetch_moe_counts`` at the return of a ``step()`` that
+    leaves no boundary open), not between the tokens' fetch and the
+    rows' recording. That moves WHEN they are fetched, not what is
+    counted: against an engine that fetches them as each block lands the
+    four ``moe.*`` counters come out equal, after ``drain()`` (the block
+    in flight lands there, and its counts with it) and after the engine
+    went idle with a landed block's counts still queued (every stream
+    retired between the landing and the next ``step()``)."""
+    from cake_tpu.obs import metrics
+    from cake_tpu.runtime.batch_generator import BatchGenerator
+
+    reg = metrics.registry()
+    names = ("moe.local_pairs", "moe.experts_hit", "moe.routed_pairs",
+             "moe.decode_steps")
+    cfg, p = _cut_share(params)
+
+    def run(at_the_landing: bool) -> dict:
+        before = {n: reg.counter(n).value for n in names}
+        fetches = reg.histogram("engine.landing_counts_fetch_ms").count
+        bg = BatchGenerator(cfg, p, settings=SamplerSettings(**GREEDY),
+                            block_size=4, max_seq=64)
+        if at_the_landing:
+            land = bg._land_block
+
+            def land_and_fetch():
+                landed = land()
+                bg._fetch_moe_counts()
+                return landed
+
+            bg._land_block = land_and_fetch
+        bg.set_prompts([[5, 9, 2], [3, 1, 4, 1]])
+        bg.generate(9)
+        bg.finish(bg.streams[1].stream_id)  # a dead slot's row is no load
+        landings = 0
+        while landings < 2:
+            landings += not any(t is not None for t in bg.step())
+        # a block has just landed: its rows are recorded, nothing follows
+        # it on the device yet, and its counts wait for that
+        assert bg._moe_landed == (0 if at_the_landing else 1)
+        if end == "drain":
+            bg.step()  # the next block leaves ...
+            assert bg._inflight is not None and bg._moe_landed == 0
+            bg.drain()  # ... and lands here, its counts with it
+        else:
+            bg.finish(bg.streams[0].stream_id)
+            bg.step()  # nothing live: no program follows, the engine idles
+            assert bg._inflight is None
+        assert bg._moe_landed == 0 and not bg._moe_pending
+        got = {n: reg.counter(n).value - v for n, v in before.items()}
+        got["fetches"] = reg.histogram(
+            "engine.landing_counts_fetch_ms").count - fetches
+        return got
+
+    deferred, at_once = run(False), run(True)
+    assert deferred == at_once
+    assert deferred["moe.decode_steps"] == deferred["fetches"] * 4 >= 16
+    assert 0 < deferred["moe.local_pairs"] < deferred["moe.routed_pairs"]
+    assert deferred["moe.experts_hit"] > 0
+
+
 def _cut_share(params):
     """Experts 4-11 of 16 scored (two groups of four) held."""
     cfg = dataclasses.replace(CFG, eos_token_id=-1, n_routed_experts=8,

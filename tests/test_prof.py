@@ -488,7 +488,7 @@ def test_phase_spans_on_unsampled_steps_while_the_tracer_runs(
 
 
 @pytest.mark.parametrize("name", ["sched_admit", "deliver", "retire",
-                                  "idle_park"])
+                                  "idle_park", "pass_rest", "sync_counts"])
 def test_scheduler_pass_parts_are_declared_phases(name):
     from cake_tpu.obs import catalog
 
@@ -578,11 +578,11 @@ def test_a_slow_pass_leaves_its_parts(prof_env, monkeypatch):
     for rec in slow:
         assert set(rec) == {"t_unix_ns", "total_ms", "admit_ms", "step_ms",
                             "deliver_ms", "rest_ms", "queued", "running",
-                            "cpu_ms", "fetch_ms"}
+                            "cpu_ms", "fetch_ms", "fetch_of"}
         # the engine's thread slept through the step: it neither ran nor
         # waited for a device fetch (this engine keeps none)
         assert rec["cpu_ms"] < 0.5 * rec["total_ms"]
-        assert rec["fetch_ms"] == 0.0
+        assert rec["fetch_ms"] == 0.0 and rec["fetch_of"] == ""
         assert rec["step_ms"] >= 50.0 > rec["admit_ms"] + rec["deliver_ms"]
         assert rec["total_ms"] == pytest.approx(
             rec["admit_ms"] + rec["step_ms"] + rec["deliver_ms"]
@@ -626,7 +626,7 @@ def test_a_slow_pass_says_its_parts_in_the_log(prof_env, caplog):
     assert said.startswith("slow scheduler pass: ")
     for part in ("'total_ms': 2410.0", "'step_ms': 2400.0", "'rest_ms': 7.0",
                  "'queued': 3", "'running': 32", "'cpu_ms': 0.0",
-                 "'fetch_ms': 0.0"):
+                 "'fetch_ms': 0.0", "'fetch_of': ''"):
         assert part in said
 
 
@@ -687,3 +687,226 @@ def test_a_slow_pass_says_whether_the_thread_ran_or_waited(
     # the others are the admission's compiles: no fetch held those
     assert all(r["fetch_ms"] < 0.5 * r["total_ms"]
                for r in slow if r not in waited), slow
+
+
+def test_a_slow_pass_says_which_fetch_held_it(params, prof_env, monkeypatch,
+                                              caplog):
+    """``fetch_of`` beside ``fetch_ms``, in the ring and in the log: the
+    longest wait for the device inside the engine's step was a block's
+    fetch (``block:<steps>``) or an admission's first token
+    (``admit_land:<bucket>``). The engine keeps it with
+    ``step_fetch_ms``, reset every ``step()``, and the scheduler's pass
+    hands both to ``note_pass``."""
+    import time
+
+    from cake_tpu.serve.session import Session
+
+    gen = BatchGenerator(CFG, params, settings=SamplerSettings(**GREEDY),
+                         block_size=4)
+    gen.warm_admission(16)
+    gen.set_prompts([[3, 1, 4], [1, 5, 9]])
+    gen.streams[1].done = True
+    gen.step()
+    real = gen._host
+
+    def slow_host(x):
+        time.sleep(0.06)
+        return real(x)
+
+    monkeypatch.setattr(gen, "_host", slow_host)
+    gen.enqueue([2, 7, 1], 7)
+    seen = []
+    for _ in range(12):
+        gen.step()
+        seen.append((gen.step_fetch_of, gen.step_fetch_ms))
+    of = {o for o, _ in seen}
+    assert {"", "block:4", "admit_land:16"} <= of, seen
+    assert all((o == "") == (ms == 0.0) for o, ms in seen)
+    assert all(ms >= 60.0 for o, ms in seen if o)
+    # through the scheduler: the slow pass's record and log line say it
+    monkeypatch.setattr(prof, "SLOW_PASS_MS", 50.0)
+    p = prof.profiler()
+    p.reset()
+    sched = Scheduler(gen, queue_depth=4)
+    sched.start(max_concurrent=2)
+    try:
+        with caplog.at_level("WARNING", logger="cake_tpu.obs.prof"):
+            sess = Session([2, 7, 1], max_tokens=6)
+            sched.submit(sess)
+            while sess.events.get(timeout=60)[0] == "token":
+                pass
+    finally:
+        sched.close()
+    held = {r["fetch_of"] for r in p.slow_passes()
+            if r["fetch_ms"] >= 0.9 * r["total_ms"]}
+    assert "block:4" in held and held <= {"block:4", "admit_land:16"}, held
+    assert "'fetch_of': 'block:4'" in caplog.text
+
+
+# -- leaves, not parents, on the profile's host plane --------------------------
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: every enter and
+    exit, by thread, in order."""
+
+    def __init__(self):
+        import threading
+
+        self.log: list = []  # (thread, "+" | "-", name)
+        outer = self
+
+        class Annotation:
+            def __init__(self, name, **kw):
+                self.name = name
+
+            def __enter__(self):
+                outer.log.append((threading.get_ident(), "+", self.name))
+                return self
+
+            def __exit__(self, *exc):
+                outer.log.append((threading.get_ident(), "-", self.name))
+                return False
+
+        self.cls = Annotation
+
+    def flat(self) -> list[str]:
+        """The names in the order they were open, after checking that a
+        thread never had two open and that every enter has its exit."""
+        open_by_thread: dict = {}
+        names = []
+        for tid, what, name in self.log:
+            if what == "+":
+                assert tid not in open_by_thread, (
+                    f"{name} opened under {open_by_thread[tid]}")
+                open_by_thread[tid] = name
+                names.append(name)
+            else:
+                assert open_by_thread.pop(tid) == name
+        assert not open_by_thread, f"left open: {open_by_thread}"
+        return names
+
+
+@pytest.fixture
+def annotated(prof_env, monkeypatch):
+    """The span tracer as a capture runs it, the profiler's annotation
+    replaced by a recorder."""
+    import jax.profiler
+
+    from cake_tpu.obs import trace as obs_trace
+
+    rec = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec.cls)
+    prof.profiler().set_sample(1)
+    tr = obs_trace.tracer()
+    tr.start(xla_annotations=True)
+    yield rec, tr
+    tr.stop()
+    tr.clear()
+
+
+_NESTINGS = {
+    "admit>admit_land>dispatch": (
+        ("admit", "admit_land", "dispatch"),
+        ["prof.admit", "prof.admit_land", "prof.dispatch",
+         "prof.admit_land", "prof.admit"]),
+    "dispatch>pages": (
+        ("dispatch", "pages"),
+        ["prof.dispatch", "prof.pages", "prof.dispatch"]),
+    "emit>guide": (
+        ("emit", "guide"), ["prof.emit", "prof.guide", "prof.emit"]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_NESTINGS))
+def test_the_profile_gets_the_leaf_and_the_parent_again_after_it(
+        annotated, case):
+    """While a capture is open at most ONE annotation is open a thread,
+    the innermost phase's: a phase closes the annotation of the one it
+    nests in and that one's name re-appears when it ends, so a reader
+    that names a stretch by the annotation covering most of it names the
+    leaf. The tracer's own records stay nested (``parent``)."""
+    rec, tr = annotated
+    nest, want = _NESTINGS[case]
+    p = prof.profiler()
+    p.step_begin("batch")
+    try:
+        def enter(names):
+            if names:
+                with p.phase(names[0]):
+                    enter(names[1:])
+
+        enter(nest)
+    finally:
+        p.step_end()
+    assert rec.flat() == want
+    evs = {e["name"]: e for e in tr.to_chrome_trace()["traceEvents"]
+           if e.get("ph") == "X"}
+    for outer, inner in zip(nest, nest[1:]):
+        assert evs[f"prof.{inner}"]["args"]["parent"] == f"prof.{outer}"
+        o, i = evs[f"prof.{outer}"], evs[f"prof.{inner}"]
+        assert o["ts"] <= i["ts"] and i["ts"] + i["dur"] <= o["ts"] + o["dur"]
+
+
+def test_a_request_span_around_a_step_is_closed_under_its_phases(annotated):
+    """A request span around a step is a parent like any other: the
+    profile shows it only where no phase is open under it, on this
+    thread; another thread's annotations are its own."""
+    import threading
+
+    from cake_tpu.obs import trace as obs_trace
+
+    rec, _ = annotated
+    p = prof.profiler()
+    other = threading.Thread(target=lambda: obs_trace.span(
+        "elsewhere").__enter__().__exit__(None, None, None))
+    with obs_trace.span("engine.prefill", sid=3):
+        p.step_begin("batch")
+        with p.phase("admit"):
+            other.start()
+            other.join()
+            with p.phase("admit_launch"):
+                pass
+        p.step_end()
+        with p.pass_part("deliver"):
+            pass
+    assert rec.flat() == [
+        "engine.prefill", "prof.admit", "elsewhere", "prof.admit_launch",
+        "prof.admit", "engine.prefill", "prof.deliver", "engine.prefill"]
+
+
+def test_a_phase_that_raises_leaves_no_annotation_open(annotated):
+    """Every enter has its exit when a phase raises, at any depth, and
+    the thread's next span opens with nothing over it."""
+    rec, _ = annotated
+    p = prof.profiler()
+    p.step_begin("batch")
+    with pytest.raises(RuntimeError, match="boom"):
+        try:
+            with p.phase("admit"):
+                with p.phase("admit_land"):
+                    with p.phase("dispatch"):
+                        raise RuntimeError("boom")
+        finally:
+            p.step_end()
+    with p.pass_part("retire"):
+        pass
+    assert rec.flat() == [
+        "prof.admit", "prof.admit_land", "prof.dispatch", "prof.admit_land",
+        "prof.admit", "prof.retire"]
+
+
+def test_a_capture_that_closes_under_a_span_leaves_no_annotation_open(
+        annotated):
+    """The capture's stop turns the pass-through off while the engine's
+    thread is inside a phase: that phase's exit still closes what is
+    open, and nothing is opened after it."""
+    rec, tr = annotated
+    p = prof.profiler()
+    with p.pass_part("sched_admit"):
+        with p.pass_part("deliver"):
+            tr.xla_annotations = False
+    with p.pass_part("retire"):
+        pass
+    assert rec.flat() == ["prof.sched_admit", "prof.deliver"]
+    assert len([e for e in tr.to_chrome_trace()["traceEvents"]
+                if e.get("ph") == "X"]) == 3
