@@ -329,6 +329,15 @@ SERIES: dict[str, tuple[str, str]] = {
                  "fetched; engine.admit_launches is its denominator "
                  "(not a guided arrival's, not admit()'s, none under "
                  "batched speculation or a live guide)"),
+    "engine.landings_before_rows": (
+        COUNTER, "landings whose device half (the first tokens' sampler, "
+                 "the splice, the device's next program) was enqueued "
+                 "while rows recorded before it were still to be handed "
+                 "out: the stream's install and its first token followed "
+                 "those rows; engine.admit_launches is its denominator "
+                 "(not a guided arrival's, not admit()'s, none under "
+                 "batched speculation, in the paged layout, or where no "
+                 "staging row fits beside the landing's)"),
     # -- gateway (multi-replica routing front door) ----------------------
     "gateway.added_ms": (
         HISTOGRAM, "gateway-added latency ahead of the backend "

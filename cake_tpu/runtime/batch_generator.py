@@ -47,11 +47,13 @@ chunk dispatch per ``step()`` alongside the running decode dispatches),
 together with the plain prompt that waits behind it where one compiled
 program holds both, a free slot and a staging row each (``_start_arrival``,
 ``GROUP_SHAPES``: the weights are read once a launch, not once an
-arrival), and, once the rows computed before it have been handed out,
-*lands*: the finished rows are spliced into their slots. ``admit()`` is the
-synchronous variant. Admission timing never changes a stream's output
-(per-row positions + per-row token indices; for an expert model up to the
-order of summation its call's rows select: ``enqueue``).
+arrival), and *lands* once the block that was running has landed: the
+finished rows are spliced into their slots and the device goes on, while
+the streams are installed once the rows recorded before have been handed
+out. ``admit()`` is the synchronous variant. Admission timing never
+changes a stream's output (per-row positions + per-row token indices; for
+an expert model up to the order of summation its call's rows select:
+``enqueue``).
 
 One order of work at a block boundary: when a block's tokens have landed
 on the host, the device gets its next program — the next block, or a
@@ -61,7 +63,10 @@ delivery, retirement and bookkeeping run while it works
 landing is device work only: behind the prefill the first tokens'
 sampler, the splice (it takes the tokens on the device and writes the
 donated cache in place) and the next program are enqueued back to back,
-and the host reads the first token afterwards (``_finish_admission``).
+and the host reads the first token afterwards (``_finish_admission``),
+after the rows recorded before the landing have gone out
+(``_land_host``): those rows are the host's to deliver, not the device's
+to wait for.
 
 Int8-weight determinism: ``ops.quant.quant_matmul``'s measured m>=16
 crossover would pick its backend per shape, so the SAME stream could see
@@ -154,6 +159,23 @@ class _Staged:
     stamps: list | None
 
 
+@dataclasses.dataclass(eq=False)
+class _Landed:
+    """A landing between its halves: the device has the sampler, the
+    splice and its next program; the members' streams are not installed
+    and their first tokens not fetched (``_finish_admission``)."""
+    members: list[_Staged]  # finish() takes a cancelled one out
+    rows: list[_Staged]  # the program's rows: which token is whose
+    toks: object  # the first tokens [R], on the device
+    lp: tuple | None  # their top-k logprobs, on the device
+    booking: tuple  # the last prefill chunk's (_admit_dispatched)
+    land_begin: float
+    spliced: float
+    # the count of rows handed out (``_rows_out``) from which the rows
+    # recorded before the device half are all with the caller
+    due: int
+
+
 # The several-row admission programs there are, ``(rows, bucket)``: a
 # launch takes riders only into one of these, and only once it is compiled
 # (``_warm_bucket``: with the first one-row program of a bucket it holds).
@@ -240,11 +262,12 @@ _COUNTS_FETCH_MS = obs_metrics.histogram("engine.landing_counts_fetch_ms")
 # admission), and a block's period, landing to landing (_land_block):
 # what stands between the decode step and a client's token gap.
 # The stamps, in stage order: enqueued (enqueue()), launched (the first
-# prefill dispatch returned), land_begin (_finish_admission entered),
-# landed (the first token on the host), spliced (the splice program
-# enqueued). A stage runs from one stamp to the next; the splice leaves
-# before the token is fetched (all but a guided arrival's), so the last
-# stage reads 0 there.
+# prefill dispatch returned), land_begin (_finish_admission entered: the
+# landing's device half), landed (the first token on the host: its host
+# half, after the rows recorded before the landing), spliced (the splice
+# program enqueued). A stage runs from one stamp to the next; the splice
+# leaves before the token is fetched (all but a guided arrival's), so the
+# last stage reads 0 there.
 _ADMIT_STAGES = (
     ("launch_wait", obs_metrics.histogram("engine.admit_launch_wait_ms")),
     ("rows_wait", obs_metrics.histogram("engine.admit_rows_wait_ms")),
@@ -259,6 +282,10 @@ _ADMIT_LAUNCHES = obs_metrics.counter("engine.admit_launches")
 # prefill, else the next block) were enqueued before the first token was
 # fetched: landings_ahead / admit_launches
 _LANDINGS_AHEAD = obs_metrics.counter("engine.landings_ahead")
+# landings whose device half (sampler, splice, next program) was enqueued
+# while rows recorded before it were still to be handed out:
+# landings_before_rows / admit_launches
+_LANDINGS_BEFORE_ROWS = obs_metrics.counter("engine.landings_before_rows")
 _BLOCK_PERIOD_MS = obs_metrics.histogram("engine.block_period_ms")
 _BLOCK_PERIOD_CLEAR_MS = obs_metrics.histogram("engine.block_period_clear_ms")
 _KV_BLOCKS_READ = obs_metrics.counter("attn.kv_blocks_read")
@@ -767,6 +794,11 @@ class BatchGenerator:
         # the launched admission: ONE prefill program over the staging
         # rows of every arrival that rode with the head (_start_arrival)
         self._staging: dict | None = None
+        # landings between their halves, in order: spliced, their slots
+        # served by the device's next program, their streams installed
+        # once the rows recorded before them are out (_land_host)
+        self._landed: list[_Landed] = []
+        self._rows_out = 0  # recorded rows handed out by step(), ever
         # (rows, chunk) of the admission programs compiled so far, and the
         # row counts whose landing (sampler, splice) is: _warm_bucket
         self._warmed: set[tuple[int, int]] = set()
@@ -1404,6 +1436,7 @@ class BatchGenerator:
         # a step() caller, which is when a token is counted and gets its
         # text
         self._pending_rows: list[list[Token | None]] = []
+        self._landed = []  # ... and so is a landing that waited for them
         self._inflight = None  # any prior in-flight block is stale now
         self._fetch_moe_counts()  # a landed block's counts stay counted
         self._moe_pending.clear()  # ... the stale block's go with it
@@ -1426,9 +1459,22 @@ class BatchGenerator:
             self._warm_landing()
             self._warm_bucket(chunk)
 
+    def _live(self) -> list[bool]:
+        """Which slots the device's next program serves: a slot whose
+        stream runs, and a slot a landing has spliced an arrival into
+        whose stream is not installed yet (``_landed``). Between a
+        landing's halves ``self.streams[slot]`` is still the stream whose
+        recorded rows are going out: it says whose token a row holds,
+        this says what a dispatch reads (the frontiers, the block size,
+        the counts) and which slots are taken."""
+        live = [s.active and not s.done for s in self.streams]
+        for ld in self._landed:
+            for m in ld.members:
+                live[m.slot] = True
+        return live
+
     def _free_slots(self):
-        return (i for i, s in enumerate(self.streams)
-                if not s.active or s.done)
+        return (i for i, live in enumerate(self._live()) if not live)
 
     def _free_slot(self) -> int | None:
         return next(self._free_slots(), None)
@@ -1471,9 +1517,11 @@ class BatchGenerator:
         return self._paged
 
     def pending_admissions(self) -> int:
-        """Arrivals not yet fully admitted (queued + in-flight)."""
+        """Arrivals not yet fully admitted (queued + in-flight + spliced
+        with their stream still to be installed)."""
         staged = self._staging["members"] if self._staging else ()
-        return len(self._arrivals) + len(staged)
+        return (len(self._arrivals) + len(staged)
+                + sum(len(ld.members) for ld in self._landed))
 
     def _store_prefix(self, ids: list[int], row) -> None:
         """Slot layout: insert a staged batch-1 KV row under its token
@@ -1553,8 +1601,8 @@ class BatchGenerator:
         steady-state decode path (a handful of list appends per page
         boundary crossed; no device work)."""
         ps = self._page_size
-        for i, s in enumerate(self.streams):
-            if not s.active or s.done:
+        for i, live in enumerate(self._live()):
+            if not live:
                 continue
             t = self._tables[i]
             last = min(int(self._pos[i]) + size - 1, self.max_seq - 1) // ps
@@ -1580,8 +1628,8 @@ class BatchGenerator:
         ps = self._page_size
         w = kvpool_pool.writeback_width(size, ps, self._ppp)
         ids = np.full((len(self.streams), w), SINK, np.int32)
-        for i, s in enumerate(self.streams):
-            if not s.active or s.done:
+        for i, live in enumerate(self._live()):
+            if not live:
                 continue
             t = self._tables[i]
             pos = int(self._pos[i])
@@ -2265,33 +2313,79 @@ class BatchGenerator:
     def _admission_due(self) -> bool:
         """Whether step() has admission work this call (so an idle or
         waiting batch does not flood the admit histogram with ~0 ms
-        ticks): a launched admission lands only once every recorded row
-        has been handed out."""
+        ticks): a landing's host half that the rows have made room for,
+        a launched admission that can land (``_can_land``), a chunk to
+        dispatch, an arrival to start."""
+        if self._host_half_due():
+            return True
         st = self._staging
         if st is not None:
-            return "logits" not in st or not self._rows_wait()
+            return "logits" not in st or self._can_land(st)
         if not self._arrivals:
             return False
         if self._arrivals[0][3] is None:  # a prompt: launched at once
             return self._free_slot() is not None
         return not self._pending_rows
 
+    def _host_half_due(self) -> bool:
+        """Whether the rows recorded before the oldest landing's device
+        half are all with the caller: its host half may run."""
+        return bool(self._landed) and self._landed[0].due <= self._rows_out
+
     def _rows_wait(self) -> bool:
         """Whether rows computed before a launched admission are still to
-        be handed out (recorded ones, or a block in flight): it lands
-        after them."""
+        be handed out (recorded ones, or a block in flight): a landing
+        that installs its stream at once lands after them."""
         return bool(self._pending_rows) or self._inflight is not None
 
+    def _lands_before_rows(self, st: dict) -> bool:
+        """Whether the staged launch's device half may leave while rows
+        recorded before it are still going out, from what the engine
+        sees: not where the host acts between the halves' programs (a
+        guided arrival's token is fetched before anything follows;
+        batched speculation runs rounds), not in the paged layout (the
+        landing edits the slot's page table and the pool, which are the
+        old stream's until its rows are out), and only where the staging
+        rows of every landing whose token has not been fetched, this
+        launch's and one more fit side by side (a program's memory is
+        taken when it is enqueued)."""
+        held = len(st["rows"]) + sum(len(ld.rows) for ld in self._landed)
+        return (st["members"][0].guide is None and not self._paged
+                and not self._spec_k and self._staging_rows_fit > held)
+
+    def _landing_runs(self) -> bool:
+        """Whether the device still has the prefill or the sampler of a
+        landing between its halves to run (the newest one's tokens are
+        not there yet). The next BLOCK waits for that while the host is
+        back in a step() every row: enqueued at once it would hold the
+        device for its eight steps, and an arrival that comes meanwhile
+        (a client whose answer ended in the rows that are going out)
+        would wait behind it, where launched in the block's place its
+        prefill follows the landing's own, as it did when a landing
+        waited for the rows. The block leaves once the tokens are there
+        (``step``) or, at the latest, before the host waits for them
+        (``_admission_tick``)."""
+        return bool(self._landed) and not self._landed[-1].toks.is_ready()
+
+    def _can_land(self, st: dict) -> bool:
+        """Whether the launched admission's landing may start in this
+        step(): no block is in flight (the landed block's rows are
+        recorded, so every retirement it holds is known) and, where both
+        halves go together, every recorded row has been handed out."""
+        if self._lands_before_rows(st):
+            return self._inflight is None
+        return not self._rows_wait()
+
     def _admission_tick(self, wait: bool = True) -> None:
-        """Advance the admission plane by one tick: *land* a launched
-        admission whose rows have all gone out, *launch* the next queued
-        arrival if a slot is free (and with it every arrival that can
-        ride: ``_start_arrival``), or dispatch the in-flight admission's
-        next chunk. KV-page imports (cake_tpu/disagg) ride the same FIFO:
-        a begin lands the pages in the pool (deferring FIFO-fair under
-        pool pressure exactly like a prompt admission), an attach
-        installs the resumed stream into a free slot -- each one tick,
-        no prefill dispatches.
+        """Advance the admission plane by one tick: finish the landings
+        whose rows have gone out (``_land_host``), *land* a launched
+        admission, *launch* the next queued arrival if a slot is free
+        (and with it every arrival that can ride: ``_start_arrival``),
+        or dispatch the in-flight admission's next chunk. KV-page imports
+        (cake_tpu/disagg) ride the same FIFO: a begin lands the pages in
+        the pool (deferring FIFO-fair under pool pressure exactly like a
+        prompt admission), an attach installs the resumed stream into a
+        free slot -- each one tick, no prefill dispatches.
 
         Launch and land are apart so that the prefill runs while rows go
         out. Launch matches the prefix, builds the staging row and
@@ -2301,22 +2395,50 @@ class BatchGenerator:
         follows that block on the device with no host time between them
         (which program follows the running block is what a decision at
         the boundary would have made it; only the enqueue is earlier).
-        Land (``_finish_admission``: sample, splice, install the stream,
-        give the device its next program -- the next arrival's launch if
-        one can start -- and only then fetch the first token) waits until
-        every row computed before has been handed out: those rows can
-        hold a stream's EOS, which frees its slot in here while the
-        caller -- who maps a row's slots to streams when it GETS the row
-        -- has not seen those tokens yet; installing then would hand the
-        old stream's tail to the new one.
+
+        A landing (``_finish_admission``) has two halves. The DEVICE
+        half -- sample, splice, count the slot live, give the device its
+        next program (the next arrival's launch if one can start, else
+        the next block) -- needs nothing of the rows that are still going
+        out, so it leaves in the first step() in which the logits are
+        staged and no block is in flight: at the boundary itself where
+        the launch happens there, at the block's landing where the launch
+        went out behind the running block. The HOST half -- fetch the
+        first token, install the stream, queue its row -- waits until
+        every row recorded before the device half has been handed out:
+        those rows can hold a stream's EOS, which frees its slot in here
+        while the caller -- who maps a row's slots to streams through
+        ``self.streams`` when it GETS the row, as ``_hand_out`` does for
+        the text -- has not seen those tokens yet; installing then would
+        hand the old stream's tail to the new one. Where the halves may
+        not be apart (``_lands_before_rows``) the whole landing waits for
+        the rows, and for a block in flight. Of the device half's next
+        program the next BLOCK alone is held back while the prefill
+        still runs (``_landing_runs``): an arrival that comes meanwhile
+        is launched in its place, and the block leaves when the tokens
+        are there or, at the latest, before the host half of the newest
+        landing waits for them.
         ``wait=False`` (the synchronous ``admit()``, whose caller is told
-        the slot) lands at once."""
+        the slot) lands at once, behind the landings before it."""
+        # one host half a tick: its row goes out in this step(), and a
+        # later landing's token, whose prefill may still run, is waited
+        # for when the rows that are here have left (admit(): all now)
+        due = int(self._host_half_due()) if wait else len(self._landed)
+        started = False
+        if due:
+            with self._prof.phase("admit_land"):
+                if wait and self._staging is None and len(self._landed) == 1:
+                    # the host comes for the newest landing's token: the
+                    # device's next program first, if it was held back
+                    started = self._launch() or self._enqueue_block()
+                for _ in range(due):
+                    self._land_next(drop_rows=not wait)
         st = self._staging
-        if st is None or "logits" not in st:
+        if not started and (st is None or "logits" not in st):
             self._launch(wait)
             st = self._staging
         if (st is not None and "logits" in st
-                and not (wait and self._rows_wait())):
+                and (not wait or self._can_land(st))):
             with self._prof.phase("admit_land"):
                 self._finish_admission(wait)
 
@@ -2410,7 +2532,7 @@ class BatchGenerator:
         launch of one is the same program over one row."""
         if not self._arrivals:
             return False
-        if not any(s.active and not s.done for s in self.streams):
+        if not any(self._live()):
             # nobody was decoding until this arrival came (an engine
             # without work is not stepped): the next landing closes no
             # block period
@@ -2629,16 +2751,27 @@ class BatchGenerator:
             logits, np.asarray(sids, np.uint32), hist_rows, mask)
 
     def _finish_admission(self, wait: bool = True) -> None:
-        """Land the launched admission, as device work only: the first
+        """Land the launched admission. The DEVICE half, here: the first
         tokens' sampler on the last chunk's logits, the splice of the
         staged rows into their slots (it takes the tokens where the
-        sampler left them, on the device) and the device's next program
-        (the next arrival's launch if one can start, else the next block)
-        are dispatched back to back behind the prefill; the host reads
-        the tokens afterwards, while the device works, records them and
+        sampler left them, on the device), what the next dispatch reads
+        of the new streams (their frontiers and token indices, their
+        slots counted live: ``_live``), the prefix store's feed, and the
+        device's next program (the next arrival's launch if one can
+        start, else the next block, which waits for the prefill's end
+        while rows go out: ``_landing_runs``), dispatched back to back
+        behind the prefill. The HOST half (``_land_host``) reads the tokens
+        afterwards, while the device works, installs the streams and
         queues the members' row. A stream that its first token ends has
         then a block dispatched with its row live, which ``_record``
         discards like any overrun.
+
+        The host half follows at once where no recorded row is still to
+        be handed out. Where some are (``_can_land`` has seen that the
+        halves may be apart) it waits for them in ``_landed``: until then
+        ``self.streams[slot]`` is the stream those rows belong to, for
+        ``_hand_out`` and for the caller, and ``_live`` alone knows the
+        slot is taken and served.
 
         What the engine sees decides the order, nothing else: a guided
         arrival's token is fetched before anything follows it (its guide
@@ -2649,6 +2782,9 @@ class BatchGenerator:
         st, self._staging = self._staging, None
         members, rows = st["members"], st["rows"]
         logits = st["logits"]
+        # rows still to go out: the streams they belong to stay where
+        # they are until the host half
+        before_rows = wait and bool(self._pending_rows)
         # An in-flight block (dispatched between a chunked admission's
         # ticks) belongs to the pre-admission state: fetch and record its
         # rows before the slots' columns change meaning, so streaming
@@ -2660,8 +2796,8 @@ class BatchGenerator:
         # the rows to go out) whatever of it was still to be handed out:
         # it must not reach the new stream
         for m in members:
-            for row in self._pending_rows:
-                row[m.slot] = None
+            if not wait:
+                self._drop_rows(m.slot)
             self._drop_guide(m.slot)
             if m.guide is not None:
                 self._attach_guide(m.slot, m.guide)
@@ -2683,18 +2819,9 @@ class BatchGenerator:
         )
         lp = (sampling.topk_logprobs(logits, self.logprobs_k)
               if self.logprobs_k else None)
-
-        def fetch() -> tuple:
-            """The one wait for the device: ``(tokens [R] on the host,
-            when they came)``."""
-            t_fetch = time.perf_counter()
-            tok_ids = self._host(toks)
-            landed = time.perf_counter()
-            self._note_fetch((landed - t_fetch) * 1e3, "admit_land",
-                             st["booking"][1])
-            return tok_ids, landed
-
-        fetched = fetch() if guide is not None else None
+        booking = st["booking"]
+        fetched = (self._fetch_first(toks, booking[1]) if guide is not None
+                   else None)
         # every live stream's next block waits behind this admission: the
         # period that holds it is not clear
         self._period_admitted = True
@@ -2745,11 +2872,17 @@ class BatchGenerator:
                 self.cache, st["cache"], self._keys, self._history,
                 self._hist_slot, self._last_tokens, *vectors,
             )
-        spliced = time.perf_counter()
+        ld = _Landed(members, rows, toks, lp, booking, land_begin,
+                     spliced=time.perf_counter(),
+                     due=self._rows_out + len(self._pending_rows))
+        # everything a next program's dispatch reads of the arrivals: the
+        # frontier, the token index, a live slot
         self._pos = np.asarray(self._pos).copy()
         self._index = np.asarray(self._index).copy()
         for m in members:
-            self._install(m)
+            self._pos[m.slot] = len(m.ids)
+            self._index[m.slot] = 1
+        self._landed.append(ld)
         # Feed the store: an arrival's prefix becomes reusable by future
         # arrivals with the same opening, the next launch among them.
         # Paged: the stream's FULL prompt pages register in the prefix
@@ -2783,48 +2916,92 @@ class BatchGenerator:
             # next program follows the fetch, this row released first
             ahead = (wait and fetched is None
                      and self._staging_rows_fit > len(rows))
-            if (ahead and (self._launch() or self._enqueue_block())
-                    and members[0].stamps is not None):
-                _LANDINGS_AHEAD.inc()
+            if ahead:
+                # (while rows go out the next block waits for the
+                # prefill to have run: _landing_runs)
+                _ = self._launch() or (
+                    not before_rows and self._enqueue_block())
+            if before_rows and members[0].stamps is not None:
+                _LANDINGS_BEFORE_ROWS.inc()
         finally:
-            tok_ids, landed = fetched or fetch()
-            self._admit_dispatched(*st["booking"])
-            lp_rows = None
-            if lp is not None:
-                lp_rows = [[(int(i), float(v)) for v, i in zip(vs, top)]
-                           for vs, top in zip(*map(np.asarray, lp))]
-            row: list[Token | None] = [None] * len(self.streams)
-            for m in members:
-                i = rows.index(m)
-                if m.stamps is not None:
-                    self._observe_admission(
-                        m.sid, m.stamps + [land_begin, landed, spliced])
-                row[m.slot] = self._first_token(
-                    m.slot, int(tok_ids[i]), lp_rows[i] if lp_rows else None)
-            self._pending_rows.append(row)
-            if self._paged and self.streams[slot].done:
-                # first sampled token ended the stream: free its claims
-                # (the tree store above has taken its references)
-                self._release_pages(slot)
+            if not before_rows:
+                self._landed.remove(ld)
+                self._land_host(ld, fetched)
         if not wait or guide is not None:
             self._launch(wait)  # the next arrival starts in this tick too
         elif not ahead:
             del st["cache"]  # the token is here: the splice has read it
             _ = self._launch() or self._enqueue_block()
 
+    def _drop_rows(self, slot: int) -> None:
+        """A slot's recorded tokens that nobody has been handed go with
+        its stream."""
+        for row in self._pending_rows:
+            row[slot] = None
+
+    def _fetch_first(self, toks, bucket: int) -> tuple:
+        """A landing's one wait for the device: ``(first tokens [R] on
+        the host, when they came)``."""
+        t_fetch = time.perf_counter()
+        tok_ids = self._host(toks)
+        landed = time.perf_counter()
+        self._note_fetch((landed - t_fetch) * 1e3, "admit_land", bucket)
+        return tok_ids, landed
+
+    def _land_next(self, drop_rows: bool = False) -> None:
+        """The oldest landing's host half. ``drop_rows`` (under the
+        synchronous ``admit()``, which waits for no row): what is left of
+        the rows before it in its slots goes, as it does for ``admit()``'s
+        own slot."""
+        ld = self._landed.pop(0)
+        if drop_rows:
+            for m in ld.members:
+                self._drop_rows(m.slot)
+        self._land_host(ld)
+
+    def _land_host(self, ld: _Landed, fetched: tuple | None = None) -> None:
+        """A landing's host half: the first tokens come to the host
+        (long ready where the rows before them took their time), the
+        members become their slots' streams, one token long, and their
+        row is queued behind the rows recorded before. From here on
+        ``self.streams`` says what ``_live`` said of these slots."""
+        if (fetched is None and ld.members[0].stamps is not None
+                and (self._inflight is not None or self._staging is not None
+                     or self._landed)):
+            # the device has its next program (a block, a prefill, or
+            # the programs of the landings behind this one)
+            _LANDINGS_AHEAD.inc()
+        tok_ids, landed = fetched or self._fetch_first(ld.toks,
+                                                       ld.booking[1])
+        self._admit_dispatched(*ld.booking)
+        lp_rows = None
+        if ld.lp is not None:
+            lp_rows = [[(int(i), float(v)) for v, i in zip(vs, top)]
+                       for vs, top in zip(*map(np.asarray, ld.lp))]
+        row: list[Token | None] = [None] * len(self.streams)
+        for m in ld.members:
+            i = ld.rows.index(m)
+            self._install(m)
+            if m.stamps is not None:
+                self._observe_admission(
+                    m.sid, m.stamps + [ld.land_begin, landed, ld.spliced])
+            row[m.slot] = self._first_token(
+                m.slot, int(tok_ids[i]), lp_rows[i] if lp_rows else None)
+            if self._paged and self.streams[m.slot].done:
+                # first sampled token ended the stream: free its claims
+                # (the tree store has taken its references)
+                self._release_pages(m.slot)
+        self._pending_rows.append(row)
+
     def _install(self, m: _Staged) -> None:
-        """A spliced arrival becomes its slot's stream, before its first
-        token is on the host: everything a next program's dispatch reads
-        (the frontier, the token index, a live stream in the slot)."""
-        slot, ids = m.slot, m.ids
-        self._pos[slot] = len(ids)
-        self._index[slot] = 1
-        self.streams[slot] = _Stream(
-            stream_id=m.sid, prompt=ids,
+        """A spliced arrival becomes its slot's stream, for the rows
+        that are recorded from here on and for whoever is handed them."""
+        self.streams[m.slot] = _Stream(
+            stream_id=m.sid, prompt=m.ids,
             detok=TokenOutputStream(self.tokenizer) if self.tokenizer else None,
         )
         if self._spec_k:
-            self._spec_bank[slot] = []  # the slot's old stream is gone
+            self._spec_bank[m.slot] = []  # the slot's old stream is gone
             # the device ctx row still holds the OLD stream's tokens; a
             # pos-coincidence could otherwise pass the staleness check
             self._spec_ctx = None
@@ -2911,6 +3088,17 @@ class BatchGenerator:
             # loop over the slot's page list, no cache tensor touched
             self._release_pages(i)
             return True
+        for ld in self._landed:
+            for m in ld.members:
+                if m.sid != stream_id:
+                    continue
+                # spliced, not installed: no stream comes of it, its slot
+                # is free again, and what a block computed for its row is
+                # discarded like any overrun (the slot's stream is done)
+                ld.members.remove(m)
+                if not ld.members:
+                    self._landed.remove(ld)
+                return True
         st = self._staging
         for m in st["members"] if st is not None else ():
             if m.sid != stream_id:
@@ -3065,8 +3253,7 @@ class BatchGenerator:
                     skip=[bool(s.generated) for s in self.streams],
                     lp=self._first_lp,
                 )
-            if self._inflight is not None and not any(
-                    st.active and not st.done for st in self.streams):
+            if self._inflight is not None and not any(self._live()):
                 # every stream the in-flight block was dispatched for has
                 # been retired since: nothing of it is anyone's, and an
                 # arrival must not wait for a fetch of it
@@ -3078,9 +3265,11 @@ class BatchGenerator:
                     self._admission_tick()
             if self._pending_rows:
                 # first the device's next program, then a row
-                self._enqueue_block()
+                if not self._landing_runs():
+                    self._enqueue_block()
                 if self._landed_at is not None:
                     self._landed_rows_out = True
+                self._rows_out += 1
                 return self._hand_out(self._pending_rows.pop(0))
             return self._step_decode()
         finally:
@@ -3529,8 +3718,12 @@ class BatchGenerator:
         Token rows land in the pending queue for any consumer still
         calling step(), which is where they are counted as emitted. An
         expert model's queued counts are fetched too: ``moe.*`` hold
-        every landed block."""
+        every landed block. A block that left behind a landing whose
+        rows are still going out stays in flight
+        (``_drain_buffered_rows``)."""
         self._domain_stamp.check("BatchGenerator.drain")
+        while self._host_half_due():
+            self._land_next()
         self._drain_buffered_rows()
         self._fetch_moe_counts()
 
@@ -3538,8 +3731,12 @@ class BatchGenerator:
         """Fetch an in-flight block and record its rows into the pending
         queue -- shared by drain(), the admission splice, the import
         attach, and export (all points where a slot's column is about to
-        change meaning or the recorded state must be complete)."""
-        if self._inflight is not None:
+        change meaning or the recorded state must be complete). Not a
+        block that left behind a landing whose stream is not installed
+        yet (``_landed``): its rows are that stream's too, and
+        ``self.streams`` is the one's whose rows are still going out; the
+        step() that follows those rows records it."""
+        if self._inflight is not None and not self._landed:
             self._land_block()
 
     def _land_block(self) -> float:
@@ -3603,11 +3800,7 @@ class BatchGenerator:
         # (its clamped writes touch only its own cache row, whose output is
         # discarded), so only LIVE streams gate block decode and exhaustion —
         # a long stream hitting its window must not kill shorter ones.
-        live = [
-            self._pos[i]
-            for i, s in enumerate(self.streams)
-            if s.active and not s.done
-        ]
+        live = [self._pos[i] for i, on in enumerate(self._live()) if on]
         if not live:
             # nothing follows: no boundary, and the wait for the next
             # request is no period
@@ -3683,8 +3876,7 @@ class BatchGenerator:
         admission, so where it writes is free, and at row 0 its attention
         reads one KV block instead of following a frontier that nobody
         stops."""
-        live = [s.active and not s.done for s in self.streams]
-        return np.where(live, self._pos, 0).astype(np.int32)
+        return np.where(self._live(), self._pos, 0).astype(np.int32)
 
     def _count_kv_blocks(self, pos: np.ndarray, steps: int) -> None:
         """Add what ``steps`` decode steps from the frontiers ``pos`` (as
@@ -3707,8 +3899,7 @@ class BatchGenerator:
         goes through the program; its pairs are no load)."""
         if not self._moe_counted:
             return out
-        live = np.array([s.active and not s.done for s in self.streams])
-        self._moe_pending.append((out[-1], steps, live))
+        self._moe_pending.append((out[-1], steps, np.array(self._live())))
         return out[:-1]
 
     def _fetch_moe_counts(self) -> None:
@@ -3766,11 +3957,7 @@ class BatchGenerator:
             launched = self._staging is not None and "logits" in self._staging
             self._next_ahead = True if launched else None
             return [None] * len(self.streams)
-        live = [
-            self._pos[i]
-            for i, s in enumerate(self.streams)
-            if s.active and not s.done
-        ]
+        live = [self._pos[i] for i, on in enumerate(self._live()) if on]
         if not live:
             return [None] * len(self.streams)
         constrained = self._guides_live()

@@ -45,6 +45,9 @@ READERS = {  # metric -> (series it reads, its value on _made_up_ctx)
     "engine.admits_per_block": ("engine.admissions_landed", 1.9),
     # PR 37: admissions landed over admission programs launched
     "engine.admits_per_launch": ("engine.admit_launches", 2.5),
+    # PR 54: device halves that left before the rows, over launches
+    "engine.landings_before_rows_share": ("engine.landings_before_rows",
+                                          50.0),
 }
 SYSTEM = [(i * 7) % 100 + 2 for i in range(16)]  # a shared 16-token prefix
 
@@ -187,6 +190,44 @@ def test_stamps_are_ordered_and_stages_sum_to_enqueue_to_first_token(
     grew = {n: v - sums[n] for n, v in _sums().items()}
     for (stage, _, ms), series in zip(stages, STAGE_HISTS):
         assert grew[series] == pytest.approx(ms), stage
+
+
+def test_a_landing_before_its_rows_keeps_the_stamps_in_stage_order(
+        params, monkeypatch):
+    """The device half leaves while the landed block's rows go out (PR
+    54): ``land_begin`` is its entry and ``landed`` the first token on
+    the host, after those rows, so the rows' time is the ``land`` stage's
+    and no longer ``rows_wait``'s; the stages still leave nothing out."""
+    g = _engine(params, block_size=4)
+    while not g._pending_rows:  # a block has landed: 4 rows to go out
+        g.step()
+    clock = _Ticks()
+    monkeypatch.setattr(bg, "time", clock)
+    before = obs_metrics.registry().snapshot()[
+        "engine.landings_before_rows"]["value"]
+    g.finish(0)
+    t_before = clock.now
+    g.enqueue([2, 8, 1, 7, 6, 5, 4, 3, 9], stream_id=10)
+    g.step()  # launch and device half at the boundary; a row goes out
+    assert g._landed and len(g._pending_rows) == 3
+    t_device_half = clock.now
+    while not any(s.stream_id == 10 and s.generated for s in g.streams):
+        g.step()
+    assert obs_metrics.registry().snapshot()[
+        "engine.landings_before_rows"]["value"] == before + 1
+    stages = g.take_admission_stages(10)
+    assert [s[0] for s in stages] == list(STAGES)
+    starts = [t0 for _, t0, _ in stages]
+    end = starts[-1] + stages[-1][2] / 1e3
+    assert starts[0] == t_before + 1.0
+    assert starts == sorted(set(starts)) and stages[-1][2] == 0.0
+    # launched and land_begin within the step() of the device half, the
+    # first token on the host after the three rows that followed it
+    assert starts[2] < t_device_half < starts[3]
+    assert sum(ms for _, _, ms in stages) == pytest.approx(
+        (end - starts[0]) * 1e3)
+    for (_, t0, ms), (_, t1, _) in zip(stages, stages[1:]):
+        assert t1 == pytest.approx(t0 + ms / 1e3, abs=1e-6)
 
 
 # -- a block's period --------------------------------------------------------
@@ -393,7 +434,8 @@ def _made_up_ctx(without: str | None = None) -> dict:
               "engine.block_period_ms": hist(8, 1000.0),
               "engine.block_period_clear_ms": hist(1, 70.0),
               "engine.admissions_landed": counter(10),
-              "engine.admit_launches": counter(9)}
+              "engine.admit_launches": counter(9),
+              "engine.landings_before_rows": counter(4)}
     after = {"engine.admit_launch_wait_ms": hist(110, 4900.0),
              "engine.admit_rows_wait_ms": hist(110, 5100.0),
              "engine.admit_land_ms": hist(110, 2050.0),
@@ -401,7 +443,8 @@ def _made_up_ctx(without: str | None = None) -> dict:
              "engine.block_period_ms": hist(58, 7500.0),
              "engine.block_period_clear_ms": hist(21, 1570.0),
              "engine.admissions_landed": counter(105),
-             "engine.admit_launches": counter(47)}
+             "engine.admit_launches": counter(47),
+             "engine.landings_before_rows": counter(23)}
     for side in (before, after):
         side["serve.ttft_ms"] = hist(5, 500.0)
         side.pop(without, None)
@@ -457,3 +500,18 @@ def test_the_benchmark_declares_admits_per_launch_after_them():
         "moves": "tpot_p50_ms"}
     assert len(bench["per_layer"]) >= 38
     assert catalog.kind_of("engine.admit_launches") == catalog.COUNTER
+
+
+def test_the_benchmark_declares_the_share_of_landings_before_rows_last():
+    """PR 54's one metric, appended: reported in every cell (each reports
+    ``tpot_p50_ms`` and launches admissions), more is better."""
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("engine.landings_before_rows_share")
+    assert at >= 47 and names[46] == "engine.landing_counts_fetch_ms"
+    assert bench["per_layer"][at] == {
+        "name": "engine.landings_before_rows_share", "unit": "%",
+        "better": "higher", "source": "program_counter", "layer": "engine",
+        "moves": "tpot_p50_ms"}
+    assert (ROOT / "benchmark" / "layer_metrics"
+            / "engine.landings_before_rows_share.py").is_file()

@@ -1183,9 +1183,10 @@ def test_the_next_program_is_enqueued_before_a_landed_row_leaves(params,
     up to the enqueue carries no delivery), the next call enqueues the
     next block -- or, when an arrival waits and a slot is free, launches
     its prefill -- and only then returns the landed block's first row. An
-    admission lands (its stream installed, its first token queued) after
-    the block's last row has gone out, and the block that follows is
-    dispatched before that first token's row leaves."""
+    admission's device half (sampler, splice, the block that follows)
+    leaves behind its prefill in that same call, before any of those rows
+    (PR 54); its stream is installed and its first token queued after the
+    block's last row has gone out."""
     settings = SamplerSettings(**GREEDY)
     log: list = []
     g = BG(CFG, params, settings=settings, block_size=4)
@@ -1194,6 +1195,7 @@ def test_the_next_program_is_enqueued_before_a_landed_row_leaves(params,
     if arrival:
         g.streams[1].done = True  # a slot retired, as the scheduler does
     _recording(g, log)
+    g._landing_runs = lambda: False  # (the prefill has run: no block held)
     b0, a0, n0 = _boundary_counters()
 
     def pump(n):
@@ -1208,16 +1210,19 @@ def test_the_next_program_is_enqueued_before_a_landed_row_leaves(params,
         g.enqueue([2, 8, 1], stream_id=7)  # came in while block 1 ran
     del log[:]
     pump(1)
-    first = "prefill" if arrival else "block"
+    first = ["prefill", "block"] if arrival else ["block"]
     live = [0] if arrival else [0, 1]
-    assert log == [first, ("row", live)]  # enqueued, THEN a row left
+    assert log == [*first, ("row", live)]  # enqueued, THEN a row left
+    del log[:]
     pump(3)
-    assert log[2:] == [("row", live)] * 3  # rows only
+    assert log == [("row", live)] * 3  # rows only
     if arrival:
-        assert g._staging is not None and g.streams[1].stream_id == 1
+        # spliced and served, not installed: the old stream's rows go out
+        assert g._staging is None and g._landed and g._inflight is not None
+        assert g.streams[1].stream_id == 1 and g._live()[1]
         del log[:]
-        pump(1)  # rows are out: the admission lands, block 2 leaves,
-        assert log == ["block", ("row", [7])]  # then its first token
+        pump(1)  # rows are out: the stream is installed, its first token
+        assert log == [("row", [7])]  # leaves; block 2 left long before
         assert g.streams[1].stream_id == 7 and g._inflight is not None
     del log[:]
     pump(1)  # block 2 lands: hands out nothing
@@ -1262,9 +1267,11 @@ def test_an_arrival_under_a_running_block_is_launched_behind_it(params):
     back during the hand-out of the block before) is launched at once:
     its prefill follows the running block on the device with no host time
     between them, which is what a decision at that block's boundary would
-    have chosen too. It lands after that block's rows have all gone out
-    (PR 21's gate), the landing's boundary counts as enqueued ahead, and
-    every stream gets the ids single steps give."""
+    have chosen too. Its device half (splice, the next block) leaves in
+    the first step() after that block has landed, before the block's rows
+    (PR 54); its stream is installed after they have all gone out (PR
+    21's gate, on the host half alone), the landing's boundary counts as
+    enqueued ahead, and every stream gets the ids single steps give."""
     settings = SamplerSettings(**GREEDY)
     log: list = []
     g = BG(CFG, params, settings=settings, block_size=4)
@@ -1272,6 +1279,7 @@ def test_an_arrival_under_a_running_block_is_launched_behind_it(params):
     g.set_prompts([list(PROMPTS[0]), [1]], stream_ids=[0, 99])
     g.streams[1].done = True
     _recording(g, log)
+    g._landing_runs = lambda: False  # (the prefill has run: no block held)
     got: dict[int, list[int]] = {}
 
     def pump(n):
@@ -1296,10 +1304,11 @@ def test_an_arrival_under_a_running_block_is_launched_behind_it(params):
         b0 + 1, a0 + 1, n0 + 1)
     assert g._staging is not None and g.streams[1].stream_id == 99
     del log[:]
-    pump(4)  # block 2's rows go out under the prefill; nothing enqueued
-    assert log == [("row", [0])] * 4 and g.streams[1].stream_id == 99
-    pump(1)  # they are out: it lands, block 3 leaves, then its first token
-    assert log[-2:] == ["block", ("row", [7])]
+    pump(4)  # splice and block 3 leave behind the prefill, then the rows
+    assert log == ["block"] + [("row", [0])] * 4
+    assert g._landed and g.streams[1].stream_id == 99
+    pump(1)  # they are out: its stream is installed, its first token leaves
+    assert log[-1] == ("row", [7]) and "block" not in log[1:]
     pump(12)
     for sid, prompt in ((0, PROMPTS[0]), (7, [2, 8, 1])):
         assert len(got[sid]) >= 4
