@@ -157,18 +157,25 @@ class LlamaConfig:
     mamba_dt_rank: int = 0  # 0: ceil(hidden_size / 16), HF's "auto"
     mamba_conv_bias: bool = True
     # --- window and full grouped-query attention mixed by layer (HF
-    # `model_type` "exaone_moe") ---------------------------------------------
+    # `model_type` "exaone_moe" | "mellum") ----------------------------------
     # ``layer_types``: "sliding_attention" | "full_attention" a layer. A
-    # window layer rotates q and k and sees the last ``sliding_window``
-    # positions, which it keeps as a ring of ``ring_rows`` rows a stream
-    # whatever the capacity (``cache_plan``); a full layer has NO position
-    # embedding and keeps every row. ``qk_norm``: an RMSNorm over each
+    # window layer sees the last ``sliding_window`` positions, which it
+    # keeps as a ring of ``ring_rows`` rows a stream whatever the capacity
+    # (``cache_plan``); a full layer keeps every row. ``layer_rope``: the
+    # rotation of q and k a layer KIND, read from the file (``rotation``
+    # gives a kind's rope parameters, or None for a kind that carries NO
+    # position embedding): K-EXAONE rotates its window layers and nothing
+    # on its full ones; Mellum rotates its window layers plainly and its
+    # full ones under YaRN, two tables in one program
+    # (``ops.rope.rope_tables_for``). ``qk_norm``: an RMSNorm over each
     # head of q and k (one ``[head_dim]`` weight for all heads), before
     # the rotation. The feed-forward is the shared-expert family's
     # (``first_k_dense_replace`` leading dense layers, then
     # ``n_routed_experts`` held of ``router_experts`` beside
-    # ``n_shared_experts``).
+    # ``n_shared_experts``, which may be none), sigmoid-scored with a
+    # bias or softmax-scored over all experts (``scoring_func``).
     layer_types: tuple[str, ...] | None = None
+    layer_rope: tuple | None = None
     qk_norm: bool = False
     # --- gated short-convolution layers beside grouped-query attention (HF
     # `model_type` "lfm2_moe") -----------------------------------------------
@@ -263,9 +270,17 @@ class LlamaConfig:
         an admission chunk reads the rows before it and then writes its
         own last ``R``: the window itself is enough. It is rounded up to
         whole ``(16, 128)`` tiles of a bfloat16 buffer's last two axes,
-        which the published 128 is already (``window <= R < window +
-        16``)."""
+        which the published windows (128, 1024) are already (``window <=
+        R < window + 16``)."""
         return -(-self.sliding_window // 16) * 16
+
+    def rotation(self, layer_type: str) -> dict | None:
+        """The rope parameters of the layers of one ``layer_types`` kind
+        (``rope_type``, ``rope_theta`` and, under YaRN, its keys), or None
+        where that kind rotates nothing or the model has no such layer:
+        ``layer_rope`` as a dict."""
+        rope = dict(self.layer_rope).get(layer_type)
+        return None if rope is None else dict(rope)
 
     @property
     def segmented(self) -> bool:
@@ -369,10 +384,10 @@ class LlamaConfig:
     def rope_dim(self) -> int:
         """Channels of a head that rotary embeddings cover; 0: the model
         has no position embedding (position comes from the recurrence).
-        Where window and full layers are mixed this is
-        the window layers': a full layer rotates nothing (the layer loop
-        hands it no table). Beside short-convolution layers the full
-        layers rotate the whole head."""
+        Where window and full layers are mixed this is the width of
+        whichever kinds rotate (``layer_rope``: the layer loop hands each
+        kind its own table, or none). Beside short-convolution layers the
+        full layers rotate the whole head."""
         if self.attn_layer_period:
             return 0
         return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
@@ -825,6 +840,14 @@ def _repeated(pattern, layers: int) -> tuple[str, ...]:
     return tuple(pattern[i % len(pattern)] for i in range(layers))
 
 
+def _window_layers_rotate(theta: float) -> dict:
+    """K-EXAONE's ``layer_rope``: the window layers rotate plainly, the
+    full ones carry no position embedding."""
+    return {"sliding_attention": {"rope_type": "default",
+                                  "rope_theta": float(theta)},
+            "full_attention": None}
+
+
 def kexaone_ep8(**overrides) -> LlamaConfig:
     """K-EXAONE-236B-A23B (https://huggingface.co/LGAI-EXAONE/
     K-EXAONE-236B-A23B, `model_type` "exaone_moe") at its published
@@ -863,6 +886,54 @@ def kexaone_ep8(**overrides) -> LlamaConfig:
         topk_group=1,
         norm_topk_prob=True,
         routed_scaling_factor=2.5,
+        bos_token_id=0,
+        eos_token_id=1,
+    )
+    base.update(overrides)
+    base["layer_types"] = _repeated(base["layer_types"],
+                                    base["num_hidden_layers"])
+    base.setdefault("layer_rope", _window_layers_rotate(base["rope_theta"]))
+    return LlamaConfig(**base)
+
+
+def mellum2_12b(**overrides) -> LlamaConfig:
+    """Mellum2-12B-A2.5B-Instruct (https://huggingface.co/JetBrains/
+    Mellum2-12B-A2.5B-Instruct, `model_type` "mellum") at its published
+    sizes: 28 layers, a window of 1024 on every layer but each fourth; the
+    window layers rotate q and k plainly (theta 5e5) and the full ones
+    under YaRN (factor 16 over an original 8192, ``attention_factor``
+    1.2772588722239782 on cos and sin): two rotations in one model, by
+    layer kind. 32 QK-normed query heads over 4 key/value heads of 128;
+    every layer routes over all 64 softmax-scored experts (896 wide) top-8
+    with the chosen shares renormalised, no shared expert, no bias; an
+    untied head. A chip serves the depth of its pipeline stage
+    (`num_hidden_layers=` with `layer_types=` cut to it)."""
+    base = dict(
+        model_type="mellum",
+        vocab_size=98304,
+        hidden_size=2304,
+        intermediate_size=7168,
+        num_hidden_layers=28,
+        num_attention_heads=32,
+        num_key_value_heads=4,
+        head_dim=128,
+        rms_norm_eps=1e-6,
+        max_seq_len=131072,
+        sliding_window=1024,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        layer_rope={
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 500000},
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                "original_max_position_embeddings": 8192, "beta_fast": 32,
+                "beta_slow": 1, "attention_factor": 1.2772588722239782}},
+        qk_norm=True,
+        moe_intermediate_size=896,
+        n_routed_experts=64,
+        num_experts_per_tok=8,
+        scoring_func="softmax",
+        norm_topk_prob=True,
         bos_token_id=0,
         eos_token_id=1,
     )
@@ -1082,6 +1153,43 @@ def tiny_exaone_moe(**overrides) -> LlamaConfig:
         router_bias=True,
         norm_topk_prob=True,
         routed_scaling_factor=2.5,
+    )
+    base.update(overrides)
+    base["layer_types"] = _repeated(base["layer_types"],
+                                    base["num_hidden_layers"])
+    base.setdefault("layer_rope", _window_layers_rotate(
+        base.get("rope_theta", 10000.0)))
+    return tiny(**base)
+
+
+def tiny_mellum(**overrides) -> LlamaConfig:
+    """Tiny fixture of the window + full attention family under Mellum's
+    keys, which keeps the published pattern: two whole ``LLLG`` periods (a
+    window of 8 over a ring of 16 rows), the window layers rotated plainly
+    and the full ones under YaRN (factor 4 over an original 16 positions,
+    an explicit ``attention_factor``), QK-normed heads, every layer
+    sparse: all 16 softmax-scored experts top-4, the chosen shares
+    renormalised, no shared expert, no bias, an untied head."""
+    base = dict(
+        model_type="mellum",
+        num_hidden_layers=8,
+        head_dim=16,
+        sliding_window=8,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        layer_rope={
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 10000.0},
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 10000.0, "factor": 4.0,
+                "original_max_position_embeddings": 16, "beta_fast": 32,
+                "beta_slow": 1, "attention_factor": 1.1386294361119891}},
+        qk_norm=True,
+        moe_intermediate_size=32,
+        n_routed_experts=16,
+        num_experts_per_tok=4,
+        scoring_func="softmax",
+        norm_topk_prob=True,
+        rms_norm_eps=1e-6,
     )
     base.update(overrides)
     base["layer_types"] = _repeated(base["layer_types"],

@@ -15,7 +15,9 @@ sandwich-normed layers, the last norm closing each pass, a cache plane a
 layer AND a pass; keys ``total_ut_steps``, ``early_exit_threshold``;
 limits: threshold 1 only, every layer full attention, no window, no rope
 scaling, slot layout, no sharding, no quantized tier), ``SHORT_CONV``
-(``lfm2_moe``), ``WINDOWED`` (``exaone_moe``), ``STATE_SPACE`` (``jamba``),
+(``lfm2_moe``), ``WINDOWED`` (``exaone_moe``, and ``mellum``: the rotation
+a layer KIND is data read from the file, ``LlamaConfig.layer_rope``),
+``STATE_SPACE`` (``jamba``),
 ``HYBRID`` (``bailing_hybrid``), ``LATENT`` (``deepseek_v3``, ``axk1``,
 ``xing4_0``) and ``GQA``, the bare stack every other ``model_type`` is read
 as. A residual stream several hidden vectors wide (``hc_mult`` > 1:
@@ -79,11 +81,11 @@ class Family:
     # whether its expert layers count the routed pairs that fall on the
     # held experts (an expert model told its share)
     counts_held_experts: bool = False
-    # where a block's arithmetic differs: whether a full-attention layer
-    # rotates q and k; whether a repeated period of expert layers is
-    # scanned as one run (models/llama.py layer_plan says why not); what
-    # the chosen experts' scores are normalised over, beside their sum
-    full_layers_rotate: bool = True
+    # where a block's arithmetic differs: whether a repeated period of
+    # expert layers is scanned as one run (models/llama.py layer_plan says
+    # why not); what the chosen experts' scores are normalised over,
+    # beside their sum. (Which layers rotate q and k, and how, is no
+    # family's: a configuration's ``layer_rope`` says it a layer kind.)
     expert_periods: bool = True
     topk_norm_eps: float = 1e-20
     # whether the layer loop runs its plan ``total_ut_steps`` times over
@@ -192,11 +194,14 @@ _HYBRID_BIAS = {"b_router": ("mlp.gate.expert_bias", False)}
 _LATENT_BIAS = {"b_router": ("mlp.gate.e_score_correction_bias", False)}
 
 # Window and full grouped-query attention mixed by layer, with the
-# shared-expert feed-forward (`model_type` "exaone_moe"; the names are
-# ASSUMED, the benchmark configuration lists them: Llama's for the
+# shared-expert feed-forward (`model_type` "exaone_moe" and "mellum"; the
+# names are ASSUMED, the benchmark configurations list them: Llama's for the
 # attention with a `q_norm` / `k_norm` weight a head width wide,
-# DeepSeek-V3's for the experts and for the router's bias). The next-token
-# prediction block (`mtp.*`) is never asked for.
+# DeepSeek-V3's for the experts and for the router's bias, which are
+# Qwen3-MoE's too: `mlp.gate.weight`, `mlp.experts.{e}.*`). A layer is
+# asked for the tensors its shapes name (a Mellum layer for no shared
+# expert and no bias). The next-token prediction block (`mtp.*`) is never
+# asked for.
 _WINDOWED_MAP = {
     **{k: _LAYER_MAP[k] for k in ("attn_norm", "wq", "wk", "wv", "wo",
                                   "mlp_norm")},
@@ -282,16 +287,33 @@ _EXIT_GATE = {
 
 # --- checks shared by several families --------------------------------------
 
-def _check_told_share(c):
+def _check_told_share(c, scorings=("sigmoid",)):
     """The shared-expert feed-forward's routing, and the held experts'
-    place among the router's (``router_experts`` is filled in)."""
+    place among the router's (``router_experts`` is filled in).
+    ``scorings``: the scoring functions the family's layers compute.
+    ``"softmax"`` is wired in ONE form: over all the router's experts, the
+    chosen ones' shares renormalised over their sum and nothing else, which
+    IS softmax over the chosen logits (``ops/moe.py`` ``router_topk``'s
+    ``routing=None`` form; ``testing/reference_mellum.py`` computes the
+    long form and the tests hold the two together). A bias, groups, an
+    unnormalised share or a scaling factor would break the identity."""
     if not c.n_routed_experts:
         return
-    if c.scoring_func != "sigmoid":
+    if c.scoring_func not in scorings:
         raise ValueError(
             f"scoring_func {c.scoring_func!r} is not wired for "
-            "the shared-expert family (sigmoid, group-limited "
-            "routing only)")
+            f"{c.family.what or 'the shared-expert family'} (only "
+            f"{', '.join(scorings)}; sigmoid is the group-limited routing)")
+    if c.scoring_func == "softmax" and not (
+            c.norm_topk_prob and c.routed_scaling_factor == 1.0
+            and not c.router_bias and c.n_group == c.topk_group == 1):
+        raise ValueError(
+            "scoring_func 'softmax' is wired as softmax over all experts, "
+            "the chosen ones renormalised (norm_topk_prob true, "
+            "routed_scaling_factor 1, no routing bias, one group): got "
+            f"norm_topk_prob {c.norm_topk_prob}, routed_scaling_factor "
+            f"{c.routed_scaling_factor}, a bias {c.router_bias}, "
+            f"{c.topk_group} of {c.n_group} groups")
     width = c.router_experts or c.n_routed_experts
     object.__setattr__(c, "router_experts", width)
     if width % c.n_group or not (
@@ -662,17 +684,42 @@ STATE_SPACE = Family(
     cache_tiers=(), cache_why=_REST_IS_SMALL)
 
 
-# --- window and full attention mixed by layer (K-EXAONE's keys) -------------
+# --- window and full attention mixed by layer (K-EXAONE's, Mellum's keys) ---
 
-def _windowed_read(d: dict) -> dict:
-    """`LlamaConfig` fields from an "exaone_moe" config.json (its own
-    spelling: ``num_experts``, ``num_shared_experts``, ``layer_types``,
-    ``mlp_layer_types``, ``sliding_windows``, ``rope_parameters``).
-    ``num_nextn_predict_layers`` (a next-token prediction block, ``mtp.*``
-    tensors) is read and ignored: the block takes no part in the model's
-    own logits and the loaders skip its tensors. Read into the file:
-    pre-norm sublayers, a routing bias that enters the choice."""
-    name = WINDOWED.model_types[0]
+# the rope types a layer kind's rotation may ask for (ops/rope.py)
+_LAYER_ROPE_TYPES = ("default", "yarn")
+
+
+def _freeze_layer_rope(by_kind) -> tuple:
+    """``{layer_types entry: rope parameters, or None for a kind that
+    rotates nothing}`` as the hashable value ``LlamaConfig.layer_rope``
+    holds: ``((kind, ((key, value), ..) or None), ..)``, both levels
+    sorted (``LlamaConfig.rotation`` gives a kind's dict back)."""
+    items = by_kind.items() if isinstance(by_kind, dict) else by_kind
+    return tuple(sorted(
+        (kind, None if rope is None else tuple(sorted(dict(rope).items())))
+        for kind, rope in items))
+
+
+def _kind_rope(name: str, kind: str, rope: dict) -> dict:
+    """One layer kind's ``rope_parameters`` entry, as far as it is
+    computed: the default rotation, or YaRN with the keys ops/rope.py
+    reads (``attention_factor`` where the file gives one)."""
+    rope_type = rope.get("rope_type", rope.get("type", "default"))
+    if rope_type not in _LAYER_ROPE_TYPES:
+        raise ValueError(
+            f"{name}: rope type {rope_type!r} on {kind} layers is not wired "
+            f"(one of {', '.join(_LAYER_ROPE_TYPES)})")
+    if "rope_theta" not in rope:
+        raise ValueError(f"{name}: rope_parameters of {kind} layers name no "
+                         "rope_theta")
+    return dict(rope, rope_type=rope_type)
+
+
+def _windowed_entries(name: str, d: dict) -> tuple[list, list]:
+    """``(layer_types, mlp_layer_types)`` of a file of this family, the
+    per-layer windows held to them: dense layers lead, sparse ones
+    follow."""
     layers, window = d["num_hidden_layers"], d.get("sliding_window")
     types = _entries(name, d)
     want = [window if t == "sliding_attention" else 0 for t in types]
@@ -681,8 +728,6 @@ def _windowed_read(d: dict) -> dict:
             f"{name}: sliding_windows {d['sliding_windows']} disagrees "
             f"with layer_types and sliding_window {window} (a window of "
             "its own a layer is not wired)")
-    rope = _default_rope(name, d.get("rope_parameters") or {},
-                         d.get("rope_scaling"), ", on the window layers only")
     dense = d.get("first_k_dense_replace")
     ffn = list(d.get("mlp_layer_types") or (
         ["dense"] * (dense or 0) + ["sparse"] * (layers - (dense or 0))))
@@ -692,37 +737,118 @@ def _windowed_read(d: dict) -> dict:
         raise ValueError(
             f"{name}: mlp_layer_types {ffn} with first_k_dense_replace "
             f"{dense} is not wired (dense layers lead, sparse ones follow)")
+    return types, ffn
+
+
+def _exaone_read(d: dict) -> dict:
+    """`LlamaConfig` fields from an "exaone_moe" config.json (its own
+    spelling: ``num_experts``, ``num_shared_experts``, ``layer_types``,
+    ``mlp_layer_types``, ``sliding_windows``, ``rope_parameters``). ONE
+    flat ``rope_parameters`` (the default rotation, no scaling), which is
+    the WINDOW layers': a full layer of this model carries no position
+    embedding (``layer_rope``: None for ``full_attention``).
+    ``num_nextn_predict_layers`` (a next-token prediction block, ``mtp.*``
+    tensors) is read and ignored: the block takes no part in the model's
+    own logits and the loaders skip its tensors. Read into the file:
+    pre-norm sublayers, a routing bias that enters the choice."""
+    name = "exaone_moe"
+    types, ffn = _windowed_entries(name, d)
+    rope = _default_rope(name, d.get("rope_parameters") or {},
+                         d.get("rope_scaling"), ", on the window layers only")
+    _only_served(name, d, {"scoring_func": "sigmoid"})
     groups, kept = d.get("n_group", 1), d.get("topk_group", 1)
     if not 1 <= kept <= groups:
         raise ValueError(
             f"{name}: topk_group {kept} of n_group {groups} is not a "
             "group-limited choice")
-    held = d["num_experts"] if lead < layers else 0
+    held = d["num_experts"] if "sparse" in ffn else 0
+    theta = float(rope.get("rope_theta", d.get("rope_theta", 10000.0)))
     return {
         "layer_types": tuple(types),
+        "layer_rope": _freeze_layer_rope({
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": theta},
+            "full_attention": None}),
         "qk_norm": True,
-        "first_k_dense_replace": lead,
+        "first_k_dense_replace": ffn.count("dense"),
         "n_routed_experts": held,
         "n_shared_experts": d.get("num_shared_experts", 0),
+        "scoring_func": "sigmoid",
         "router_bias": bool(held),
-        "rope_theta": float(rope.get("rope_theta",
-                                     d.get("rope_theta", 10000.0))),
+        "rope_theta": theta,
         **_expert_share(d, held),
     }
+
+
+def _mellum_read(d: dict) -> dict:
+    """`LlamaConfig` fields from a "mellum" config.json (Qwen3-MoE's key
+    set: ``num_experts``, ``moe_intermediate_size``, ``norm_topk_prob``,
+    ``max_window_layers``, ``use_sliding_window``, beside ``layer_types``,
+    ``mlp_layer_types`` and a ``rope_parameters`` KEYED BY LAYER KIND: a
+    rotation of its own for ``sliding_attention`` and for
+    ``full_attention`` layers, ``layer_rope``). No shared expert, no
+    routing bias, no ``first_k_dense_replace``, no group limit, no scaling
+    factor. Read into the file, by that key set's convention: a per-head
+    RMSNorm on q and k (``qk_norm``), softmax scoring over all experts
+    with the chosen shares renormalised (``norm_topk_prob`` true is the
+    one form wired), pre-norm sublayers. ``max_window_layers`` selects
+    nothing: ``layer_types`` names each layer's kind."""
+    name = "mellum"
+    _only_served(name, d, {
+        "use_sliding_window": True, "attention_bias": False,
+        "num_shared_experts": 0, "scoring_func": "softmax", "n_group": 1, "topk_group": 1,
+        "routed_scaling_factor": 1.0, "rope_scaling": None})
+    types, ffn = _windowed_entries(name, d)
+    by_kind = d.get("rope_parameters") or {}
+    if set(by_kind) != set(types):
+        raise ValueError(
+            f"{name}: rope_parameters is keyed by layer kind and names "
+            f"{sorted(by_kind)} where layer_types has {sorted(set(types))}")
+    held = d["num_experts"] if "sparse" in ffn else 0
+    return {
+        "layer_types": tuple(types),
+        "layer_rope": _freeze_layer_rope(
+            {kind: _kind_rope(name, kind, rope)
+             for kind, rope in by_kind.items()}),
+        "qk_norm": True,
+        "first_k_dense_replace": ffn.count("dense"),
+        "n_routed_experts": held,
+        "n_shared_experts": 0,
+        "scoring_func": "softmax",
+        "router_bias": False,
+        **_expert_share(d, held),
+    }
+
+
+_WINDOWED_READS = {"exaone_moe": _exaone_read, "mellum": _mellum_read}
+
+
+def _windowed_read(d: dict) -> dict:
+    return _WINDOWED_READS[d["model_type"]](d)
 
 
 def _windowed_write(c, d: dict):
     d.pop("router_bias")
     d["layer_types"] = list(c.layer_types)
-    d["sliding_windows"] = [
-        c.sliding_window if t == "sliding_attention" else 0
-        for t in c.layer_types]
     d["mlp_layer_types"] = [
         "sparse" if ffn == "moe" else "dense" for _, ffn in c.layer_kinds]
     d["num_experts"] = d.pop("n_routed_experts")
+    by_kind = {kind: c.rotation(kind) for kind in dict(d.pop("layer_rope"))}
+    if c.model_type == "mellum":  # Qwen3-MoE's key set
+        d["rope_parameters"] = by_kind
+        d["use_sliding_window"] = True
+        d["max_window_layers"] = 0
+        for f in ("rope_theta", "n_shared_experts", "first_k_dense_replace",
+                  "scoring_func", "n_group", "topk_group",
+                  "routed_scaling_factor"):
+            d.pop(f)
+        return
+    d["sliding_windows"] = [
+        c.sliding_window if t == "sliding_attention" else 0
+        for t in c.layer_types]
     d["num_shared_experts"] = d.pop("n_shared_experts")
-    d["rope_parameters"] = {"rope_theta": d.pop("rope_theta"),
-                            "rope_type": "default"}
+    d.pop("rope_theta")
+    d["rope_parameters"] = by_kind["sliding_attention"]
 
 
 def _windowed_check(c):
@@ -739,17 +865,35 @@ def _windowed_check(c):
             "mixed by layer) is wired with the shared-expert "
             "feed-forward only: no latent keys, no state-space "
             "layers, no Mixtral-style experts, no projection bias")
-    _check_told_share(c)
+    # the rotation a layer KIND reads from its file; a configuration that
+    # names none rotates every kind by its flat rope_theta / rope_scaling
+    flat = {"rope_type": "default", "rope_theta": c.rope_theta,
+            **(c.rope_scaling or {})}
+    by_kind = dict(c.layer_rope or {k: flat for k in kinds})
+    if set(by_kind) != kinds:
+        raise ValueError(
+            f"layer_rope names {sorted(by_kind)} where layer_types has "
+            f"{sorted(kinds)}: a rotation, or None, for each layer kind")
+    object.__setattr__(c, "layer_rope", _freeze_layer_rope(
+        {kind: None if rope is None else _kind_rope(c.model_type, kind,
+                                                    dict(rope))
+         for kind, rope in by_kind.items()}))
+    # the flat field says what the kinds' own parameters say (so that a
+    # file read back is the configuration that wrote it)
+    object.__setattr__(c, "rope_theta", next(
+        (float(dict(rope)["rope_theta"]) for _, rope in c.layer_rope if rope),
+        c.rope_theta))
+    _check_told_share(c, ("sigmoid", "softmax"))
 
 
 WINDOWED = Family(
-    model_types=("exaone_moe",),
+    model_types=("exaone_moe", "mellum"),
     selects=lambda c: c.layer_types is not None,
-    fields=("layer_types",) + _EXPERT_FIELDS,
+    fields=("layer_types", "layer_rope") + _EXPERT_FIELDS,
     read=_windowed_read, write=_windowed_write, check=_windowed_check,
     layer_mixers={"sliding_attention": "swa", "full_attention": "gqa"},
     tensor_names=_WINDOWED_MAP, expert_names=_LATENT_EXPERT_MAP,
-    probe=".mlp.gate.e_score_correction_bias",
+    probe=".self_attn.q_norm.weight",
     what="a model of window and full attention layers",
     shard_axes=frozenset(("ep",)),
     shard_why=("a ring of rows beside the full layers' cache: window "
@@ -762,7 +906,7 @@ WINDOWED = Family(
         "an int8 cache is not wired for a model whose window "
         "layers hold a ring (the ring is already a fraction of the "
         "rows; its few full layers are the rest)"),
-    counts_held_experts=True, full_layers_rotate=False)
+    counts_held_experts=True, expert_periods=False)
 
 
 # --- gated short convolutions beside attention (LFM2-MoE's keys) ------------

@@ -254,14 +254,16 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
     period of two segments after the leading ones; state-space layers but
     the eighth of every fourteen is a period of three (M7 A M6).
 
-    Window and full attention mixed by layer (``families.WINDOWED``) goes the
-    same way: a leading dense layer, ``W W G``, then ``W W W G`` eleven
-    times, is a dense window segment, a run of two sparse window layers, a
-    period of two segments (``G``, ``W W W``) eleven times over and a last
-    full layer. (A stack a KIND, walked by one scan with a ``lax.switch``
-    over the kinds, was tried first: the chip's compiler then copies the
-    rings in and out of every branch and re-lays the attention projections
-    it indexes inside one, PERF.md section 7, PR 40.)
+    Window and full attention mixed by layer (``families.WINDOWED``): a
+    leading dense layer, ``W W G``, then ``W W W G`` eleven times, is a
+    dense window segment, a run of two sparse window layers, then ``G`` and
+    ``W W W`` by turns, each stretch a segment of its own and, as beside
+    short convolutions, NO repeated period where the layers hold routed
+    experts (``periods`` below says why). (A stack a KIND, walked by one
+    scan with a ``lax.switch`` over the kinds, was tried first: the chip's
+    compiler then copies the rings in and out of every branch and re-lays
+    the attention projections it indexes inside one, PERF.md section 7,
+    PR 40.)
 
     Short convolutions beside attention (``families.SHORT_CONV``): two
     leading dense conv layers, then ``A`` and ``c c c`` by turns with a
@@ -281,15 +283,18 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
             stretches.append([kind, i, 1])
     one_mixer = len({m for m, _ in kinds}) == 1
     # A repeated period is scanned as a period but where its layers hold
-    # routed experts beside short convolutions: every expert is held there,
-    # so a step and an admission of up to 256 rows take the expert block's
-    # dense form, and from 128 rows on the chip's compiler re-lays the
-    # stacks that form's product indexes inside a period (gate and up of
-    # every layer copied transposed in ENTRY: 5.26 GiB of temporaries an
+    # routed experts beside short convolutions or beside window layers: an
+    # admission of 128-256 rows takes the expert block's dense form (its
+    # pairs hit nearly every held expert), and the chip's compiler re-lays
+    # the stacks that form's product indexes inside a period (gate and up
+    # of every layer copied transposed in ENTRY: 5.26 GiB of temporaries an
     # admission at 32 experts of 2048 x 1792 in 12 layers, more than a chip
-    # has left beside the weights; as the operand of a product behind a
-    # scan's ``xs``, a segment of one repetition, it stays as it lies: my
-    # AOT compiles and chip run, PR 43; PERF.md section 7)
+    # has left beside the weights, my AOT compiles and chip run, PR 43;
+    # 3.95 and 4.22 GiB at 64 experts of 2304 x 896 in two periods of four
+    # layers, against 0.05-0.10 GiB for the sorted form's 512- and 1024-row
+    # admissions of the same program, my AOT compiles, PR 55). As the
+    # operand of a product behind a scan's ``xs``, a segment of one
+    # repetition, a stack stays as it lies (PERF.md section 7)
     periods = config.family.expert_periods or not config.n_routed_experts
     names: dict[str, int] = {}
     # layers counted so far in the cache buffers of each mixer's kind
@@ -784,22 +789,27 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
     """The feed-forward half of a latent-family layer, residual added
     (:func:`_sub_layer`). A dense layer (no ``router``) is a SwiGLU of
     ``intermediate_size``; an expert layer is ``shared(h) + sum over the
-    chosen experts HELD here of w_e expert_e(h)``. Returns ``(x,
+    chosen experts HELD here of w_e expert_e(h)`` (the shared expert where
+    the layer holds one; the weights sigmoid scores under
+    :class:`GroupRouting`, or softmax shares). Returns ``(x,
     ExpertCount)``."""
     def feed(h):
         local = ExpertCount.zeros(h.shape[0])
         if "router" not in layer:
             return swiglu(h, layer["w_gate"], layer["w_up"],
                           layer["w_down"]), local
+        # softmax over ALL the router's experts with the chosen shares
+        # renormalised is softmax over the chosen logits: router_topk's
+        # ``routing=None`` form (families._check_told_share admits no
+        # other softmax)
+        routing = None if config.scoring_func == "softmax" else GroupRouting(
+            config.n_group, config.topk_group, config.norm_topk_prob,
+            config.routed_scaling_factor, layer.get("b_router"),
+            config.family.topk_norm_eps)
         y = moe_swiglu(
             h, layer["router"], layer["w_gate"], layer["w_up"],
             layer["w_down"], top_k=config.num_experts_per_tok,
-            ep_axis=ep_axis, ep_size=ep_size,
-            routing=GroupRouting(config.n_group, config.topk_group,
-                                 config.norm_topk_prob,
-                                 config.routed_scaling_factor,
-                                 layer.get("b_router"),
-                                 config.family.topk_norm_eps),
+            ep_axis=ep_axis, ep_size=ep_size, routing=routing,
             held=(config.first_expert, config.n_routed_experts),
             count_local=count_local, layer=expert_idx,
         )
@@ -832,11 +842,14 @@ def _typed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
                  ep_axis, ep_size, layer_idx, count_local, expert_idx):
     """One layer of a model whose ``layer_types`` name each layer's mixer,
     its shared-expert feed-forward included. Window and full attention
-    mixed: a window layer (``mixer`` "swa") rotates q and k and attends
-    over its ring of the carried cache, a full one ("gqa") rotates nothing
-    and attends over its rows. Short convolutions beside attention: a
-    "conv" layer reads and writes its tail of the carried cache and no row,
-    a full one rotates q and k. Attention norms each head of q and k
+    mixed: a window layer (``mixer`` "swa") attends over its ring of the
+    carried cache, a full one ("gqa") over its rows, each rotating q and k
+    by ITS KIND's table (``cos``/``sin`` a dict by mixer,
+    ``ops.rope.rope_tables_for``: K-EXAONE's full layers get None and
+    rotate nothing, Mellum's get YaRN's beside the window layers' plain
+    one). Short convolutions beside attention: a "conv" layer reads and
+    writes its tail of the carried cache and no row, a full one rotates q
+    and k by the model's one table. Attention norms each head of q and k
     first. ``layer_idx`` counts the layers of the mixer's own kind.
     Returns ``(x, cache, ExpertCount)``."""
     h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
@@ -846,6 +859,8 @@ def _typed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
         x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
                                         ep_size, count_local, expert_idx)
         return x, dataclasses.replace(cache, conv=conv), local
+    if isinstance(cos, dict):  # a rotation a layer kind: this kind's
+        cos, sin = cos[mixer], sin[mixer]
     norm = ((layer["q_norm"], layer["k_norm"], config.rms_norm_eps)
             if config.qk_norm else None)
     args = (h, layer["wq"], layer["wk"], layer["wv"], layer["wo"])
@@ -858,8 +873,6 @@ def _typed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
                 qk_norm=norm, valid=valid)
         cache = dataclasses.replace(cache, ring_k=ring_k, ring_v=ring_v)
     else:
-        if not config.family.full_layers_rotate:  # no table
-            cos = sin = None
         with jax.named_scope("attn.full"):
             out, k, v = self_attention_block(
                 *args, cache.k, cache.v, cos, sin, pos, *heads,
@@ -939,10 +952,11 @@ def forward_layers(
     a state-space model's attention layers are segments of the plain
     grouped-query block, with no rotation (``cos`` and ``sin`` None).
     Where window and full attention are mixed by layer, a window
-    segment's layers rotate and attend over the cache's rings (``valid``
-    keeps a bucket's padding out of them) and a full segment's take no
-    table and attend over its rows (:func:`_typed_block`), each
-    indexed by the layers of its own mixer's kind. Short convolutions
+    segment's layers attend over the cache's rings (``valid`` keeps a
+    bucket's padding out of them) and a full segment's over its rows, each
+    under its kind's rotation or none (``cos``/``sin`` a dict by mixer,
+    :func:`_typed_block`) and indexed by the layers of its own mixer's
+    kind. Short convolutions
     beside attention go the same way: a conv segment's layers read and
     write the cache's tails (``valid`` as above) and a full segment's
     rotate and attend over its rows.

@@ -134,6 +134,26 @@ SERIES: dict[str, tuple[str, str]] = {
         GAUGE, "layers that attend over every row, beside window layers "
                "(named scope attn.full); attn.kv_blocks_read and "
                "attn.kv_blocks_reserved count one of THESE"),
+    "attn.ring_rows_live": (
+        COUNTER, "rows of the window layers' rings that hold a key the "
+                 "step's query may see, over every slot, decode step and "
+                 "window layer: min(position + 1, sliding_window) a row "
+                 "of the batch as dispatched (a slot without a live "
+                 "stream goes out at row 0: one row)"),
+    "attn.ring_rows_swept": (
+        COUNTER, "rows of the window layers' rings the same steps' "
+                 "attention reads: the ring whole, cache.ring_rows a slot, "
+                 "step and window layer (ops.attention."
+                 "window_attention_block sweeps it in XLA whatever the "
+                 "stream holds); live over swept is what a step that read "
+                 "live rows alone would save"),
+    "rope.tables": (
+        GAUGE, "pairs of rotary tables (cos, sin) the last program traced "
+               "carries (ops.rope.rope_tables_for): 0 a model with no "
+               "position embedding, 1 one rotation for every layer that "
+               "rotates, 2 a rotation a layer KIND (LlamaConfig.layer_rope: "
+               "window layers rotated plainly beside full layers under "
+               "YaRN)"),
     "load.tensors_skipped": (
         COUNTER, "tensors a checkpoint stores that are no part of the "
                  "served model and were not read (a next-token prediction "

@@ -671,6 +671,40 @@ def _attend_band(
     return out.reshape(b, n_heads, t, d)
 
 
+def _attend_ring_flash(
+    q: jax.Array,  # [B, H, T, D]
+    ring_k: jax.Array,  # [B, KH, R, D]: one layer's ring, position p at p % R
+    ring_v: jax.Array,
+    k: jax.Array,  # [B, KH, T, D]: the chunk's own keys
+    v: jax.Array,
+    pos,  # scalar: position of q[..., 0, :]
+    window: int,
+) -> jax.Array:
+    """Windowed attention of a chunk over its ring and its own keys
+    through the flash prefill kernel (:func:`pk.flash_attention` with
+    ``window``: the bare stack's, Mistral's path), with no score in HBM.
+    The kernel wants a buffer whose row ``s`` holds position ``base + s``
+    and no row of another stream before the queries: ``base = max(pos - R,
+    0)``, the ring rolled so that its row of position ``base`` comes first
+    (its ``R`` rows are then positions ``base .. base + R - 1``, those
+    the stream has written among them), the chunk's keys written over it
+    from row ``pos - base = min(pos, R)`` on. What lies behind the chunk
+    (stale rows, where ``pos < R``) is later than every query and masked
+    causally. Returns ``[B, H, T, D]``."""
+    rows = ring_k.shape[2]
+    pos = jnp.asarray(pos, jnp.int32)
+    at = jnp.minimum(pos, rows)  # the first query's row of the buffer
+
+    def buffer(ring, new):
+        new = new.astype(ring.dtype)
+        buf = jnp.concatenate([jnp.roll(ring, -(pos - at), axis=2), new],
+                              axis=2)
+        return jax.lax.dynamic_update_slice_in_dim(buf, new, at, axis=2)
+
+    return pk.flash_attention(q, buffer(ring_k, k), buffer(ring_v, v), at,
+                              window=window)
+
+
 def window_attention_block(
     x: jax.Array,  # [B, T, hidden]
     wq: jax.Array,
@@ -700,14 +734,24 @@ def window_attention_block(
     from its index and the stream's position
     (:func:`cake_tpu.ops.kvcache.ring_positions`), and a row the stream
     has not written, or one outside the window, is masked: a ring is never
-    zeroed. XLA's attention over ``R`` rows; no kernel (128 rows a stream
-    is a quarter of the decode kernel's one block).
+    zeroed. XLA's attention over ``R`` rows and no kernel: a ring of 128
+    rows a stream (K-EXAONE) is a quarter of the decode kernel's one
+    block; one of 1024 (Mellum) is two of them, swept whole whatever the
+    stream holds (``attn.ring_rows_swept`` against ``attn.ring_rows_live``
+    say what a step that followed the live rows would save: a later
+    kernel's, PERF.md section 7).
 
     A chunk (``T > 1``, ``pos`` scalar): the ring's rows are put in
     position order ahead of the chunk's own keys and the chunk attends
-    that buffer band by band (:func:`_attend_band`); then the chunk's
-    newest ``R`` true rows are written (``valid``: a bucket's padding
-    never enters a ring)."""
+    that buffer: band by band in XLA (:func:`_attend_band`, ``T x 2R``
+    float32 scores a head) or, where the prefill policy takes the kernels
+    at a band's shape (:func:`_flash_prefill_choice` of ``R`` queries over
+    ``2R`` keys: rings of 1024 rows from chunks of 1024 on, where the
+    band's scores would be 2 GB a layer at 8192 rows), through the flash
+    prefill kernel with the window's lower bound folded into its sweep
+    (:func:`_attend_ring_flash`: no score leaves the chip's fast memory).
+    Then the chunk's newest ``R`` true rows are written (``valid``: a
+    bucket's padding never enters a ring)."""
     b, t, _ = x.shape
     rows = ring_k.shape[3]
     q, k, v = _project_heads(x, wq, wk, wv, num_heads, num_kv_heads,
@@ -727,14 +771,20 @@ def window_attention_block(
             jnp.broadcast_to(seen, (b, rows))[:, None, None, None, None, :])
         out = out.reshape(b, num_heads, 1, d)
     else:
-        def ahead(ring, new):
-            """The ring's rows in position order (``pos - R .. pos - 1``),
-            then the chunk's own."""
-            old = jnp.roll(kv.layer_view(ring, layer), -pos, axis=2)
-            return jnp.concatenate([old, new.astype(ring.dtype)], axis=2)
+        blk = rows if t % rows == 0 else t  # a band's queries
+        if _flash_prefill_choice(blk, rows + blk, d) == "flash":
+            out = _attend_ring_flash(
+                q, kv.layer_view(ring_k, layer), kv.layer_view(ring_v, layer),
+                k, v, pos, window)
+        else:
+            def ahead(ring, new):
+                """The ring's rows in position order (``pos - R .. pos -
+                1``), then the chunk's own."""
+                old = jnp.roll(kv.layer_view(ring, layer), -pos, axis=2)
+                return jnp.concatenate([old, new.astype(ring.dtype)], axis=2)
 
-        out = _attend_band(q, ahead(ring_k, k), ahead(ring_v, v), pos,
-                           window, rows)
+            out = _attend_band(q, ahead(ring_k, k), ahead(ring_v, v), pos,
+                               window, rows)
         ring_k, ring_v = kv.ring_write(ring_k, ring_v, k, v, pos, layer,
                                        valid=valid)
     out = out.transpose(0, 2, 1, 3).reshape(b, t, num_heads * d)

@@ -19,6 +19,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from cake_tpu.obs import metrics as obs_metrics
+
 
 def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
     """YaRN's attention temperature ``m = 0.1 * mscale * ln(factor) + 1``
@@ -100,13 +102,19 @@ def rope_tables(head_dim: int, max_seq: int, theta: float, dtype=jnp.float32,
     if scaling is not None:
         inv_freq = _scale_inv_freq(inv_freq, scaling, theta)
         if scaling.get("rope_type", scaling.get("type")) == "yarn":
-            # cos and sin carry m(mscale) / m(mscale_all_dim): 1 wherever
-            # the two are equal, as in every published latent-attention
-            # config (the temperature then sits in the softmax scale)
+            # cos and sin carry the file's explicit ``attention_factor``
+            # at every position (a grouped-query head under YaRN, as
+            # Hugging Face's ``yarn`` applies it) or, where it gives none,
+            # m(mscale) / m(mscale_all_dim): 1 wherever the two are equal,
+            # as in every published latent-attention config (the
+            # temperature then sits in the softmax scale)
             factor = float(scaling["factor"])
-            amp = (yarn_mscale(factor, float(scaling.get("mscale", 1.0)))
-                   / yarn_mscale(factor,
-                                 float(scaling.get("mscale_all_dim", 0.0))))
+            amp = scaling.get("attention_factor")
+            if amp is None:
+                amp = (yarn_mscale(factor, float(scaling.get("mscale", 1.0)))
+                       / yarn_mscale(factor, float(
+                           scaling.get("mscale_all_dim", 0.0))))
+            amp = float(amp)
     t = jnp.arange(max_seq, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)  # [max_seq, head_dim/2]
     return ((jnp.cos(freqs) * amp).astype(dtype),
@@ -118,7 +126,28 @@ def rope_tables_for(config, max_seq: int, dtype=jnp.float32):
     (``config.rope_dim``: the whole head, or latent attention's rope part),
     base and scaling. What every execution path calls. ``(None, None)`` for
     a model with no position embedding (``rope_dim`` 0): no table is
-    built, and :func:`apply_rope` rotates nothing."""
+    built, and :func:`apply_rope` rotates nothing.
+
+    Where the file gives a rotation a layer KIND (``config.layer_rope``:
+    window and full attention mixed by layer) ``cos`` and ``sin`` are each
+    a dict by the kind's mixer (``"swa"``, ``"gqa"``), a table or None (a
+    kind that rotates nothing): ONE program carries as many pairs of
+    tables as kinds rotate, and the layer loop hands each layer its
+    kind's (``models.llama._typed_block``). The gauge ``rope.tables``
+    says how many the program being traced carries."""
+    count = obs_metrics.gauge("rope.tables")
+    if config.layer_rope:
+        cos, sin = {}, {}
+        for kind, mixer in config.family.layer_mixers.items():
+            rope = config.rotation(kind)
+            cos[mixer], sin[mixer] = (None, None) if rope is None else (
+                rope_tables(config.head_dim, max_seq,
+                            float(rope["rope_theta"]), dtype=dtype,
+                            scaling=None if rope["rope_type"] == "default"
+                            else rope))
+        count.set(sum(t is not None for t in cos.values()))
+        return cos, sin
+    count.set(int(bool(config.rope_dim)))
     if not config.rope_dim:
         return None, None
     return rope_tables(config.rope_dim, max_seq, config.rope_theta,

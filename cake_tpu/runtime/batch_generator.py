@@ -289,6 +289,8 @@ _LANDINGS_BEFORE_ROWS = obs_metrics.counter("engine.landings_before_rows")
 _BLOCK_PERIOD_MS = obs_metrics.histogram("engine.block_period_ms")
 _BLOCK_PERIOD_CLEAR_MS = obs_metrics.histogram("engine.block_period_clear_ms")
 _KV_BLOCKS_READ = obs_metrics.counter("attn.kv_blocks_read")
+_RING_ROWS_LIVE = obs_metrics.counter("attn.ring_rows_live")
+_RING_ROWS_SWEPT = obs_metrics.counter("attn.ring_rows_swept")
 _KV_BLOCKS_RESERVED = obs_metrics.counter("attn.kv_blocks_reserved")
 
 # arrival-queue entry kinds (4th tuple field): None marks a plain prompt
@@ -548,6 +550,11 @@ class BatchGenerator:
         # ... and how many planes a layer it counts (a looped model's
         # passes: each reads and reserves a plane of its own)
         self._kv_planes = config.total_ut_steps
+        # the window layers' rings, if any: (layers, rows R, window)
+        self._rings = (
+            (config.cache_plan["ring"][0], config.ring_rows,
+             config.sliding_window)
+            if "ring" in config.cache_plan else None)
         # how many staging rows (a stream's whole reservation each) may
         # live at once beside the cache: a launch of several rows, and a
         # launch ahead of the landing before it (GROUP_STAGING_BYTES)
@@ -3882,7 +3889,9 @@ class BatchGenerator:
         """Add what ``steps`` decode steps from the frontiers ``pos`` (as
         dispatched) read of a layer's cache, in the decode kernel's
         blocks, and what is reserved (``attn.kv_blocks_*``). Where window
-        and full layers are mixed, a full layer's: a ring is read whole.
+        and full layers are mixed, a full layer's: a ring is read whole,
+        and ``attn.ring_rows_live`` / ``_swept`` say how much of it held a
+        key the step's query could see.
         Where the layers run several times a token, a layer's planes: one
         a pass."""
         read, reserved = pk.decode_blocks_read(
@@ -3890,6 +3899,15 @@ class BatchGenerator:
             window=self._kv_window)
         _KV_BLOCKS_READ.inc(read * self._kv_planes)
         _KV_BLOCKS_RESERVED.inc(reserved * self._kv_planes)
+        if self._rings:
+            # a window layer's step reads its ring whole; the rows that
+            # hold a key its query may see are the window's, or fewer
+            # while the stream is shorter than the window
+            layers, rows, window = self._rings
+            seen = np.minimum(
+                pos[:, None] + np.arange(1, steps + 1)[None, :], window)
+            _RING_ROWS_LIVE.inc(layers * int(seen.sum()))
+            _RING_ROWS_SWEPT.inc(layers * rows * pos.size * steps)
 
     def _take_moe_count(self, out: tuple, steps: int) -> tuple:
         """Strip the trailing :class:`ExpertCount` off a decode

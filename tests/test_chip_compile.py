@@ -1144,6 +1144,60 @@ def test_window_and_full_programs_move_no_cache_and_no_ring(program,
             < 13 / 16 * HBM_GIB["v5 lite"] * GIB)
 
 
+def test_two_rotation_programs_band_through_the_kernel_and_fit(topo,
+                                                              as_on_chip):
+    """The window + full attention family under Mellum2's keys at the cell
+    ``mellum2-12b-cut.code-mixed``'s sizes: published widths, layers 0-7
+    (three window layers and a full one, twice: four scanned segments and
+    no repeated period), all 64 experts, the whole vocabulary, 32 slots x
+    8192 rows, rings of 1024 rows; the block decode and the 256- and
+    8192-row admissions. The chip's compiler takes them and they fit one
+    chip. RECORDED (my AOT compiles, PR 55): 8.44 GiB of arguments (7.07
+    of weights + 1.375 of rows and rings) and 0.006 GiB of temporaries in
+    the step; 0.014 GiB in the 256-row admission, whose expert block takes
+    the dense form (3.95 GiB with ``W W W G`` scanned as a repeated
+    period: the period's gate and up stacks copied transposed in ENTRY,
+    so ``layer_plan`` repeats no period here); 1.18 GiB in the 8192-row
+    admission, whose window layers attend through the flash prefill
+    kernel over the ring-then-chunk buffer (the XLA band's float32 scores
+    alone would be 2.15 GB a window layer: ``1 x 32 x 8 x 1024 x 2048``):
+    two attention kernels (one a kind of layer) and three grouped products
+    a sparse segment. The step's full layers read their rows through
+    ``flash_decode``, its window layers sweep their rings in XLA. Neither
+    kind of row buffer is copied in the step."""
+    from cake_tpu.models.config import mellum2_12b
+    from cake_tpu.utils.chips import HBM_GIB
+
+    slots, window = 32, 8192
+    cfg = mellum2_12b(num_hidden_layers=8, max_seq_len=window)
+    decode, admit256, admit8192 = _family_programs(topo, cfg, slots, window,
+                                                   256, 8192)
+    assert _cache_sized_moves(decode, f"bf16[2,{slots},4,{window},128]") == []
+    assert _cache_sized_moves(decode, f"bf16[6,{slots},4,1024,128]") == []
+    for compiled in (decode, admit256, admit8192):
+        assert _expert_stack_moves(compiled, "bf16", 64, 2304, 896) == []
+
+    def kernels(compiled):
+        return sum("custom-call(" in line and "tpu_custom_call" in line
+                   for line in compiled.as_text().splitlines())
+
+    # the step: a decode kernel a full segment, the dense expert form
+    assert kernels(decode) == 2 and _grouped_matmul_calls(decode) == 0
+    assert _grouped_matmul_calls(admit8192) == 12  # 3 a sparse segment
+    assert kernels(admit8192) == 12 + 4  # ... and an attention kernel each
+    # 256 rows: the dense expert form and XLA's band (a band's shape is
+    # under the prefill policy's floor), the full layers' flash prefill
+    assert _grouped_matmul_calls(admit256) == 0 and kernels(admit256) == 2
+    args, temps = _donated_bytes(decode)
+    assert 8.4 * GIB < args < 8.5 * GIB, args / GIB
+    assert temps < 0.02 * GIB, temps / GIB
+    small, large = (a.memory_analysis().temp_size_in_bytes
+                    for a in (admit256, admit8192))
+    assert small < 0.1 * GIB, small / GIB
+    assert large < 1.5 * GIB, large / GIB
+    assert args + temps + large + 0.1 * GIB < 11 / 16 * HBM_GIB["v5 lite"] * GIB
+
+
 def _layouts(compiled, shape: str) -> set[str]:
     """Every layout the compiled program gives a value of ``shape``
     (``bf16[4,32,8,2048,64]``): the text between its braces. (What a

@@ -29,7 +29,7 @@ import pytest
 
 from cake_tpu.models import llama
 from cake_tpu.models.config import (LlamaConfig, kexaone_ep8,
-                                    tiny_exaone_moe)
+                                    tiny_exaone_moe, tiny_mellum)
 from cake_tpu.obs import metrics
 from cake_tpu.ops import attention, kvcache, moe
 from cake_tpu.ops.kvcache import init_cache
@@ -178,7 +178,7 @@ def _whole_rows_logits(params, cfg, tokens):
             swa = mixer == "swa"
             out, k, v = attention.self_attention_block(
                 h, layer["wq"], layer["wk"], layer["wv"], layer["wo"],
-                *caches[i], cos if swa else None, sin if swa else None, pos,
+                *caches[i], cos[mixer], sin[mixer], pos,
                 4, 2, window=WINDOW if swa else None,
                 qk_norm=(layer["q_norm"], layer["k_norm"], eps))
             new.append((k, v))
@@ -252,34 +252,59 @@ def test_ring_positions_and_chunk_writes():
                                   [[-1, -1, 7, -1], [-1, 7, -1, -1]])
 
 
-def test_full_layers_do_not_rotate_and_window_layers_do(params):
-    """A full layer takes no table: whatever positions the tables hold
-    (shifted by a constant, or doubled) its output is the same to the
-    bit. A window layer rotates: doubled positions move its output, and a
+@pytest.mark.parametrize("make, full_rotates", [
+    (tiny_exaone_moe, False), (tiny_mellum, True)],
+    ids=["exaone_moe", "mellum"])
+def test_full_layers_do_not_rotate_and_window_layers_do(make, full_rotates):
+    """The rotation is the layer KIND's, read from the file
+    (``LlamaConfig.layer_rope``). K-EXAONE: a full layer takes no table:
+    whatever positions the tables hold (shifted by a constant, or doubled)
+    its output is the same to the bit. Mellum: a full layer rotates, by a
+    table of its own (YaRN's, another than the window layers'). A window
+    layer rotates in both: doubled positions move its output, and a
     constant shift does not (rotation is relative). The heads' norms come
     BEFORE the rotation: a weight that differs between a pair's two
     channels does not commute with it, and the reference agrees
     (``test_prefill_then_decode...``) with such weights."""
-    cos, sin = rope_tables_for(CFG, 256)
-    x = jax.random.normal(jax.random.PRNGKey(5), (1, 16, CFG.hidden_size))
-    cache = init_cache(CFG, batch=1, max_seq=256)
+    cfg = make(max_seq_len=256, eos_token_id=-1)
+    params = _params(cfg)
+    cos, sin = rope_tables_for(cfg, 256)
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 16, cfg.hidden_size))
+    cache = init_cache(cfg, batch=1, max_seq=256)
 
-    def block(name, mixer, tables):
-        layer = jax.tree.map(lambda w: w[0], params["layers"][name])
+    def block(name, mixer, change=lambda t: t):
+        """The first layer of stack ``name`` under the tables with this
+        kind's changed (None stays None)."""
+        run, seg = next((r, s) for r, s in llama.plan_segments(cfg)
+                        if s.name == name)
+        lead = run.layer_ids(seg).ndim  # 2 inside a repeated period
+        layer = jax.tree.map(lambda w: w[(0,) * lead], params["layers"][name])
+        tables = [dict(t, **{mixer: t[mixer] if t[mixer] is None
+                             else change(t[mixer])}) for t in (cos, sin)]
         return llama._typed_block(
-            layer, x, cache, mixer, *tables, 0, CFG, None, None, None,
+            layer, x, cache, mixer, *tables, 0, cfg, None, None, None,
             jnp.int32(0), False, None)[0]
 
-    shifted = (cos[7:], sin[7:])
-    doubled = (cos[::2], sin[::2])
-    for tables in (shifted, doubled):
-        np.testing.assert_array_equal(block("gqa_moe", "gqa", tables),
-                                      block("gqa_moe", "gqa", (cos, sin)))
-    base = block("swa_moe", "swa", (cos, sin))
-    np.testing.assert_allclose(block("swa_moe", "swa", shifted), base,
-                               atol=2e-5, rtol=0)
-    assert np.abs(block("swa_moe", "swa", doubled) - base).max() > 1e-2
-    q = params["layers"]["swa_moe"]["q_norm"][0]
+    def shifted(t):
+        return t[7:]
+
+    def doubled(t):
+        return t[::2]
+
+    assert (cos["gqa"] is not None) == full_rotates
+    for seg_name, mixer in (("gqa_moe", "gqa"), ("swa_moe", "swa")):
+        base = block(seg_name, mixer)
+        if mixer == "gqa" and not full_rotates:
+            for change in (shifted, doubled):
+                np.testing.assert_array_equal(
+                    block(seg_name, mixer, change), base)
+            continue
+        np.testing.assert_allclose(block(seg_name, mixer, shifted), base,
+                                   atol=2e-5, rtol=0)
+        assert np.abs(block(seg_name, mixer, doubled) - base).max() > 1e-2
+    if full_rotates:  # two tables in one program, and they differ
+        assert np.abs(cos["gqa"] - cos["swa"]).max() > 0.1
+    q = params["layers"]["swa_moe"]["q_norm"].reshape(-1, cfg.head_dim)[0]
     assert np.abs(q[:8] - q[8:]).max() > 0.05  # the pairs' weights differ
 
 
@@ -526,21 +551,23 @@ def test_the_catalogs_keys_are_read_and_round_trip():
 def test_layer_plan_of_the_published_layers():
     """The published 48 layers (a leading dense layer, ``W W G``, then ``W
     W W G`` eleven times) are a dense window layer, a run of two sparse
-    window layers, a period of (one full layer, three window layers)
-    scanned eleven times over, and a last full layer; every window segment
-    runs the one window body and every full segment the one full body.
-    The cache indices count the layers of a segment's own mixer's kind."""
+    window layers, then a full layer and three window layers by turns, each
+    stretch a scanned segment of its own: no period of expert layers is
+    repeated (an admission of 128-256 rows takes the expert block's dense
+    form, whose stacks the chip's compiler re-lays whole inside a period:
+    ``models/llama.py`` ``layer_plan``). Every window segment runs the one
+    window body and every full segment the one full body. The cache
+    indices count the layers of a segment's own mixer's kind."""
     full = llama.layer_plan(kexaone_ep8())
-    assert [(r.repeats, [(s.name, s.mixer, s.count, s.cache_first,
-                          s.cache_stride) for s in r.segments])
-            for r in full] == [
-        (1, [("swa_dense", "swa", 1, 0, 0)]),
-        (1, [("swa_moe", "swa", 2, 1, 0)]),
-        (11, [("gqa_moe", "gqa", 1, 0, 1), ("swa_moe_2", "swa", 3, 3, 3)]),
-        (1, [("gqa_moe_2", "gqa", 1, 11, 0)])]
-    ids = full[2].layer_ids(full[2].segments[1])
-    assert ids.shape == (11, 3) and list(ids[0]) == [4, 5, 6]
-    assert list(ids[-1]) == [44, 45, 46]
+    assert {r.repeats for r in full} == {1}
+    segs = [(s.name, s.mixer, s.first, s.count, s.cache_first)
+            for r in full for s in r.segments]
+    assert segs[:4] == [
+        ("swa_dense", "swa", 0, 1, 0), ("swa_moe", "swa", 1, 2, 1),
+        ("gqa_moe", "gqa", 3, 1, 0), ("swa_moe_2", "swa", 4, 3, 3)]
+    assert segs[-2:] == [("swa_moe_12", "swa", 44, 3, 33),
+                         ("gqa_moe_12", "gqa", 47, 1, 11)]
+    assert len(segs) == 25
     assert sum(llama.stack_layers(kexaone_ep8()).values()) == 48
     shapes = llama.stack_shapes(kexaone_ep8())
     assert shapes["gqa_moe"]["q_norm"](kexaone_ep8()) == (128,)
