@@ -214,6 +214,16 @@ SERIES: dict[str, tuple[str, str]] = {
     "moe.routed_pairs": (
         COUNTER, "(row, chosen expert) pairs decode steps routed over all "
                  "the router's experts: live rows x top-k x expert layers"),
+    "moe.sorted_pair_rows": (
+        COUNTER, "(row, chosen expert) pair rows handed to expert calls "
+                 "that took the sorted form: rows x top-k a call and "
+                 "expert layer, of decode steps and admission dispatches, "
+                 "counted on the device"),
+    "moe.sorted_pair_rows_live": (
+        COUNTER, "of moe.sorted_pair_rows, the rows of the row tiles "
+                 "those calls touched (the tiles that hold a pair on a "
+                 "held expert: live tiles x the row tile; the others are "
+                 "neither read nor written), counted on the device"),
     "moe.sorted_from_rows": (
         GAUGE, "the fewest rows of a traced call whose expert block took "
                "the sorted form (ops.moe.expert_form, set at trace time; "

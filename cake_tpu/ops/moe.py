@@ -37,15 +37,20 @@ TPU-first design:
     0.74: :func:`hit_share`) and prompt rows (an admission's bucket, a
     verify chunk, a prefill): the ``N x k`` (row, chosen expert) pairs
     sorted by local expert id, pairs on experts that are not here at the
-    tail and never computed; rows gathered once; gate, up and down each
-    one grouped matmul over the contiguous groups
-    (:func:`cake_tpu.ops.pallas.grouped_matmul`: a group without a row is
+    tail; from there on only the LIVE row tiles (those that hold a pair
+    on a held expert) are read or written: their rows gathered; gate, up
+    and the SwiGLU one grouped call and down another over the contiguous
+    groups (:func:`cake_tpu.ops.pallas.grouped_swiglu`,
+    :func:`~cake_tpu.ops.pallas.grouped_matmul`: a group without a row is
     never visited, so its matrices are never read; an int8 stack streams
     as int8 and is converted a block at a time); every row's results
-    summed under its routing weights in float32. Exact with no capacity,
-    no fallback and no control flow. Its kernel reads a layer's matrices
-    out of the WHOLE stacks the layer loop closes over (``layer=``), so no
-    layer's slice is written out for it (:func:`reads_whole_stacks`).
+    summed under its routing weights in float32 over the live tiles' rows
+    (:func:`compacts`: by kernels where a share of the scored experts is
+    held, by XLA where all are and every tile is live). Exact with no
+    capacity, no fallback and no control flow. Its kernels read a layer's
+    matrices out of the WHOLE stacks the layer loop closes over
+    (``layer=``), so no layer's slice is written out for them
+    (:func:`reads_whole_stacks`).
 
 - **Expert parallelism** shards the expert axis over the mesh's ``ep`` axis
   (:mod:`cake_tpu.parallel.mesh`): each rank holds ``E/ep`` experts' weights,
@@ -74,21 +79,25 @@ from cake_tpu.ops.quant import QuantizedLinear, dequantize_linear
 GATHER_MAX_ROWS = 8
 # Rows of a call from which the sorted form is taken, by the stacks' type:
 # where tools/moe_sweep.py measured it at 1.10x the dense form or better at
-# every cell's shape (my chip runs, PR 33 and PR 35; PERF.md section 6).
-# int8: 3.0x from 128 rows on (the dense form's dequantised product writes a
-# layer's stack out first) and 0.72x at 64, where that form has no slab and
-# runs at 89% of the bytes' roofline. bf16: 1.32x and 1.80x at 512 rows (12
-# held of 192, 128 of 512), 0.88x and 1.10x at 256.
+# EVERY cell's shape (my chip runs, PR 33 and PR 35, and PR 56's over the
+# live-tile form at all seven shapes, which moved neither constant;
+# PERF.md section 6 keeps the table). int8: 2.99x from 128 rows on (the
+# dense form's dequantised product writes a layer's stack out first) and
+# 0.71x at 64, where that form has no slab and runs at 89% of the bytes'
+# roofline. bf16 at 512 rows: 1.44x (64 held of 64, 2304 wide) to 1.98x
+# (128 of 512), 1.75x at 12 held of 192; at 256 rows 0.97x-1.14x (1.06x
+# and 1.14x at those two): under the bar at five shapes of six.
 SORTED_MIN_ROWS_INT8 = 128
 SORTED_MIN_ROWS = 512
 # ... and the share of the experts hit (:func:`hit_share`) up to which a
 # call of fewer rows takes it all the same: it reads the hit experts'
 # matrices alone, at 1.04-1.23x their bytes' time in bf16 and ~1.4x in int8
 # (a block's conversion), where the dense form reads every held one. The
-# same sweep, 8-256 rows (my chip runs, PR 35). bf16: 2.07x at a share of
-# 0.39 (32 rows x 8 of 512 scored), 1.47x at 0.63, 1.22x at 0.74 (32 x 8 of
-# 192), 1.06x at 0.86, 0.92x at 0.93. int8 (2-8 rows x 2 of 8): 1.51x at
-# 0.41, 1.29x at 0.66, 0.95x at 0.74, 0.82x at 0.88.
+# same sweeps, 8-256 rows. bf16 (PR 56's table, 32-128 rows): 2.10x at a
+# share of 0.39 (32 rows x 8 of 512 scored), 1.47x at 0.63, 1.24x at 0.74
+# (32 x 8 of 192), 1.06-1.10x and 1.08x at 0.87 and 0.86 (32 x 8 of 128;
+# 128 x 8 of 512), 0.96x at 0.93. int8 (2-8 rows x 2 of 8, PR 35): 1.51x
+# at 0.41, 1.29x at 0.66, 0.95x at 0.74, 0.82x at 0.88.
 SORTED_MAX_HIT_SHARE_INT8 = 0.7
 SORTED_MAX_HIT_SHARE = 0.8
 
@@ -102,19 +111,26 @@ class ExpertCount(NamedTuple):
     and steps by the callers): ``pairs [B]`` each batch row's (token,
     chosen expert) pairs that fell on the experts held here; ``hit []``
     the distinct held experts that some row chose (what the sorted form
-    reads of the stacks)."""
+    reads of the stacks); ``sorted_rows []`` the pair rows (``rows x
+    top_k``) of a call that took the sorted form, and ``live_rows []``
+    those of them that lie in a row tile the call touched (the sorted
+    form moves the tiles that hold a held pair and no others); both 0 of
+    a call in another form."""
 
     pairs: jax.Array
     hit: jax.Array
+    sorted_rows: jax.Array
+    live_rows: jax.Array
 
     @classmethod
     def zeros(cls, batch: int) -> "ExpertCount":
-        return cls(jnp.zeros((batch,), jnp.int32), jnp.zeros((), jnp.int32))
+        zero = jnp.zeros((), jnp.int32)
+        return cls(jnp.zeros((batch,), jnp.int32), zero, zero, zero)
 
     def __add__(self, other: "ExpertCount") -> "ExpertCount":
         # field by field (a tuple's own ``+`` would concatenate): what the
         # layer loop and the step loop carry and add up
-        return ExpertCount(self.pairs + other.pairs, self.hit + other.hit)
+        return ExpertCount(*(a + b for a, b in zip(self, other)))
 
 
 class GroupRouting(NamedTuple):
@@ -250,14 +266,19 @@ def _moe_sorted(
     w_up,
     w_down,
     layer,
-) -> jax.Array:
+    scored: int,  # the experts the router chose among
+) -> tuple[jax.Array, jax.Array]:
     """Only the (row, chosen expert) pairs that fall on experts held here:
     the ``N x k`` pairs sorted by local expert (a pair on an expert that
-    is not here sorts to the tail, which is never computed), the rows
-    gathered once, one grouped product each for gate, up and down over
-    the contiguous groups, and every row's ``k`` results summed under its
-    routing weights in float32. Exact whatever the routing: no capacity,
-    no fallback and no control flow."""
+    is not here sorts to the tail), and from there on only the LIVE row
+    tiles, those that hold a held pair, are read or written: their rows
+    gathered, gate, up and the SwiGLU one grouped call, down another, and
+    every row's results summed under its routing weights in float32 over
+    the live tiles' rows. Where every scored expert is held every tile is
+    live, and the rows are gathered and summed by XLA (:func:`compacts`).
+    Exact whatever the routing: no capacity, no fallback and no control
+    flow. Returns the block's result and the rows of the live tiles
+    (int32 ``[]``: what of ``N x k`` was touched)."""
     n, k = idx.shape
     e_local = _stack(w_gate).shape[-3]
     tm = pk.MOE_ROW_TILE
@@ -265,30 +286,56 @@ def _moe_sorted(
     held = (local >= 0) & (local < e_local)  # [N, k]
     key = jnp.where(held, local, e_local).reshape(-1)
     m = -(-n * k // tm) * tm
-    key = jnp.pad(key, (0, m - n * k), constant_values=e_local)
-    order = jnp.argsort(key, stable=True)  # sorted place -> pair
-    place = jnp.argsort(order)[: n * k]  # pair -> sorted place
+    pad = (0, m - n * k)
+    key = jnp.pad(key, pad, constant_values=e_local)
     sizes = jnp.sum(key[:, None] == jnp.arange(e_local, dtype=key.dtype),
                     axis=0, dtype=jnp.int32)
     tiles = pk.group_tiles(sizes, m, tm)
+    compact = compacts(e_local, scored)
+    # ONE sort carries a pair's place and, for the kernel that sums, its
+    # routing weight along (a gather of 4096 scalars costs the chip more
+    # than the sort does): sorted place -> pair, and each sorted row's
+    # weight ([M] each: the pairs' own width, as the router's); a padding
+    # pair names the last token
+    carried = (jnp.pad(w_topk.reshape(-1), pad),) if compact else ()
+    _, order, *weight = jax.lax.sort(
+        (key, jnp.arange(m, dtype=jnp.int32), *carried), num_keys=1,
+        is_stable=True)
+    token = jnp.minimum(order, n * k - 1) // k
 
-    def product(rows, w, out_dtype=None):
-        q, scale = (w.q, w.scale) if isinstance(w, QuantizedLinear) else (
-            w, None)
-        return pk.grouped_matmul(rows, q, tiles, layer=layer, scale=scale,
-                                 tm=tm, out_dtype=out_dtype)
+    def split(w):
+        return (w.q, w.scale) if isinstance(w, QuantizedLinear) else (w, None)
 
-    xs = jnp.take(x2d, jnp.minimum(order // k, n - 1), axis=0)  # [M, H]
-    # gate and up leave their products in float32: ONE rounding, of the
-    # SwiGLU's result, before the down product
-    g = product(xs, w_gate, jnp.float32)
-    u = product(xs, w_up, jnp.float32)
-    y = product((jax.nn.silu(g) * u).astype(x2d.dtype), w_down,
-                jnp.float32)  # [M, H]
-    # rows past the last held pair were never written: select, not scale
-    y = jnp.where(held[..., None],
-                  jnp.take(y, place, axis=0).reshape(n, k, -1), 0.0)
-    return jnp.einsum("nk,nkh->nh", w_topk, y).astype(x2d.dtype)
+    (gate, gate_scale), (up, up_scale) = split(w_gate), split(w_up)
+    down, down_scale = split(w_down)
+    if compact:
+        xs = pk.gather_rows(x2d, token, tiles, tm=tm)  # [M, H], live tiles
+    else:
+        xs = jnp.take(x2d, token, axis=0)
+    act = pk.grouped_swiglu(xs, gate, up, tiles, layer=layer,
+                            gate_scale=gate_scale, up_scale=up_scale, tm=tm)
+    y = pk.grouped_matmul(act, down, tiles, layer=layer, scale=down_scale,
+                          tm=tm, out_dtype=jnp.float32)  # [M, H]
+    if compact:
+        out = pk.combine_rows(y, token, weight[0], tiles, n,
+                              out_dtype=x2d.dtype, tm=tm)
+    else:
+        place = jnp.argsort(order)[: n * k]  # pair -> sorted place
+        y = jnp.take(y, place, axis=0).reshape(n, k, -1)
+        out = jnp.einsum("nk,nkh->nh", w_topk, y).astype(x2d.dtype)
+    return out, tiles.live[0] * tm
+
+
+def compacts(held: int, scored: int) -> bool:
+    """Does the sorted form gather its rows and sum its results with the
+    kernels that run over the live row tiles alone? Yes where the stacks
+    hold a share of the scored experts: most pairs then lie on experts
+    that are elsewhere, and moving all ``N x k`` rows is moving mostly
+    nothing. Where every scored expert is held every pair is live: XLA's
+    gather and sum then move exactly the live rows, at the memory's rate,
+    which a row at a time in a kernel does not reach
+    (``tools/moe_sweep.py --forms compact``; PERF.md section 6, PR 56)."""
+    return held < scored
 
 
 def hit_share(rows: int, top_k: int, scored: int) -> float:
@@ -421,9 +468,10 @@ def moe_swiglu(
                 lo = first + jax.lax.axis_index(ep_axis) * e_local
                 axes += (ep_axis,)
             combine = jax.lax.dynamic_slice_in_dim(combine, lo, e_local, 1)
+        live_rows = jnp.zeros((), jnp.int32)
         if form == "sorted":
-            out = _moe_sorted(x2d, w_topk, idx, lo, w_gate, w_up, w_down,
-                              layer)
+            out, live_rows = _moe_sorted(x2d, w_topk, idx, lo, w_gate, w_up,
+                                         w_down, layer, e_global)
         elif form == "gather":
             out = _moe_gather(x2d, w_topk, idx, w_gate, w_up, w_down)
         else:  # every held expert over every row
@@ -438,5 +486,6 @@ def moe_swiglu(
         pairs = jnp.sum(chosen, axis=1, dtype=jnp.int32)
         return out, ExpertCount(
             pairs.reshape(b, t).sum(axis=1),
-            jnp.sum(chosen.any(axis=0), dtype=jnp.int32))
+            jnp.sum(chosen.any(axis=0), dtype=jnp.int32),
+            jnp.int32(b * t * top_k if form == "sorted" else 0), live_rows)
     return out

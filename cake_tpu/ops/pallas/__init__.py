@@ -64,8 +64,11 @@ from cake_tpu.ops.pallas.kda import kda_decode  # noqa: E402
 from cake_tpu.ops.pallas.latent import latent_decode  # noqa: E402
 from cake_tpu.ops.pallas.moe import (  # noqa: E402
     ROW_TILE as MOE_ROW_TILE,
+    combine_rows,
+    gather_rows,
     group_tiles,
     grouped_matmul,
+    grouped_swiglu,
 )
 from cake_tpu.ops.pallas.quant import (  # noqa: E402
     quant4_matmul_pallas,
@@ -89,8 +92,11 @@ __all__ = [
     "kda_decode",
     "latent_decode",
     "MOE_ROW_TILE",
+    "combine_rows",
+    "gather_rows",
     "group_tiles",
     "grouped_matmul",
+    "grouped_swiglu",
     "quant_matmul_pallas",
     "quant4_matmul_pallas",
 ]

@@ -464,7 +464,8 @@ def build_sharded_decode(
             kv_specs,
             P(DP, None),
             P(DP) if per_row else P(),
-        ) + lp_specs + ((ExpertCount(P(DP), P()),) if count_local else ()),
+        ) + lp_specs + (
+            (ExpertCount(P(DP), P(), P(), P()),) if count_local else ()),
         check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(2,))
@@ -742,24 +743,36 @@ def build_admit_prefill(config: LlamaConfig, plan: MeshPlan,
     write (``ring.sp_range_cache_write``) plus the T>1 distributed-flash
     chunk attend, so continuous admission composes with the
     sequence-sharded serving window.
+
+    An expert model that counts its held experts (:func:`moe_counted`)
+    returns two more values, int32 ``[]`` each: the pair rows its
+    sorted-form expert calls were handed and those that lay in a row tile
+    the calls touched (:class:`ExpertCount` ``sorted_rows``,
+    ``live_rows``), summed over its expert layers and the ep axis.
     """
     heads_l, kv_heads_l = _local_counts(config, plan.tp)
+    count_local = moe_counted(config)
 
     def step(params, tokens, cache, pos0, last_local):
         cos, sin = rope_tables_for(config, cache.max_seq * plan.sp)
         x = llama.embed_tokens(params, tokens, config)
-        x, cache = _pipeline_layers(
+        x, cache, *local = _pipeline_layers(
             x, params, cache, cos, sin, pos0, config,
             plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
             sp_chunk=plan.sp > 1, valid=_valid_rows(config, tokens,
                                                     last_local),
+            count_local=count_local,
         )
         # the chunk activations are replicated over sp (every shard computes
         # the full chunk), so the sp==1 last-index selection applies
         x_last = _select_last_sp(x, last_local, 1)
         x_last = _select_stage0(x_last)
         logits = _head_logits(params, x_last, config)
-        return logits, cache
+        if not count_local:
+            return logits, cache
+        rows = jax.lax.psum(
+            (local[0].sorted_rows, local[0].live_rows), EP)
+        return (logits, cache) + rows
 
     sharded = shard_map(
         step,
@@ -776,7 +789,7 @@ def build_admit_prefill(config: LlamaConfig, plan: MeshPlan,
             P(None, None),
             cache_specs(kv_quant, batch_replicated=True,
                         held=config.cache_plan),
-        ),
+        ) + ((P(), P()) if count_local else ()),
         check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(2,))

@@ -228,6 +228,8 @@ _MOE_ROUTED = obs_metrics.counter("moe.routed_pairs")
 _MOE_STEPS = obs_metrics.counter("moe.decode_steps")
 _MOE_ADMIT_ROWS = obs_metrics.counter("moe.admit_rows")
 _MOE_ADMIT_SORTED = obs_metrics.counter("moe.admit_rows_sorted")
+_MOE_SORTED_ROWS = obs_metrics.counter("moe.sorted_pair_rows")
+_MOE_SORTED_LIVE = obs_metrics.counter("moe.sorted_pair_rows_live")
 # by the mixer whose layers hold the state or the tail
 # (LlamaConfig.layer_kinds)
 _STATE_RESETS = {"kda": obs_metrics.counter("kda.state_resets"),
@@ -907,6 +909,8 @@ class BatchGenerator:
         # counts are ready, and are fetched once the device has its next
         # program (_fetch_moe_counts), not while it waits for one
         self._moe_pending: deque = deque()
+        # admission dispatches' (sorted pair rows, live ones), un-fetched
+        self._moe_admitted: deque = deque()
         self._moe_landed = 0
         # engine profiling plane (obs/prof): sampled step-phase stamps +
         # the runtime retrace sentinel watching this engine's dispatches
@@ -987,11 +991,24 @@ class BatchGenerator:
         """Admission-prefill program, compiled on first use (callers that
         never admit mid-run pay nothing)."""
         if self.__admit_prefill is None:
-            self.__admit_prefill = self._pinned(build_admit_prefill(
+            prog = self._pinned(build_admit_prefill(
                 self.config, self.plan, params_like=self.params,
                 kv_quant=self.kv_quant,
             ))
+            if self._moe_counted:
+                prog = self._admissions_counted(prog)
+            self.__admit_prefill = prog
         return self.__admit_prefill
+
+    def _admissions_counted(self, prog):
+        """An expert model's admission program returns two more values,
+        the pair rows its sorted-form calls were handed and touched: kept
+        un-fetched until the program has run (``_fetch_moe_counts``)."""
+        def counted(*args):
+            logits, cache, handed, live = prog(*args)
+            self._moe_admitted.append((handed, live))
+            return logits, cache
+        return counted
 
     @property
     def _verify_rows(self):
@@ -3928,12 +3945,19 @@ class BatchGenerator:
         leaves no boundary open (the next program is enqueued, or
         nothing follows), and in ``drain()``."""
         blocks, self._moe_landed = self._moe_landed, 0
-        if not blocks:
+        # ... and of every admission dispatch that has run (in order)
+        admitted = 0
+        while (admitted < len(self._moe_admitted)
+               and self._moe_admitted[admitted][1].is_ready()):
+            admitted += 1
+        if not blocks and not admitted:
             return
         t0 = time.perf_counter()
         with self._prof.phase("sync_counts"):
             for _ in range(blocks):
                 self._record_moe_count()
+            for _ in range(admitted):
+                self._record_sorted_rows(*self._moe_admitted.popleft())
         ms = (time.perf_counter() - t0) * 1e3
         _COUNTS_FETCH_MS.observe(ms)
         self._note_fetch(ms, "counts", blocks)
@@ -3942,15 +3966,24 @@ class BatchGenerator:
         """Fetch the oldest queued counts and add the rows' that were
         live when their dispatch left into ``moe.*``."""
         count, steps, live = self._moe_pending.popleft()
+        for leaf in count:  # one wait for the four, not four
+            leaf.copy_to_host_async()
         _MOE_LOCAL.inc(int(self._host(count.pairs)[live].sum()))
         # every row that went through the program, a dead slot's too: what
         # the sorted form reads of the stacks
         _MOE_HIT.inc(int(self._host(count.hit)))
+        self._record_sorted_rows(count.sorted_rows, count.live_rows)
         _MOE_DECODE_SORTED.set(int(moe_form_traced(live.size) == "sorted"))
         _MOE_ROUTED.inc(
             steps * int(live.sum()) * self.config.num_experts_per_tok
             * sum(ffn == "moe" for _, ffn in self.config.layer_kinds))
         _MOE_STEPS.inc(steps)
+
+    def _record_sorted_rows(self, handed, live) -> None:
+        """One dispatch's pair rows ``handed`` to sorted-form calls and
+        those in a row tile the calls touched, fetched, into ``moe.*``."""
+        _MOE_SORTED_ROWS.inc(int(self._host(handed)))
+        _MOE_SORTED_LIVE.inc(int(self._host(live)))
 
     def _step_decode(self):
         """No recorded row is left to hand out. Spec rounds, if any; else

@@ -4,27 +4,26 @@ Times :func:`cake_tpu.ops.moe.moe_swiglu` in the two forms
 :func:`cake_tpu.ops.moe.expert_form` chooses between for more than a
 handful of pairs, as the layer loop calls it (a scan over ``L`` layers:
 the dense form on the scan's slice of the stacks, the sorted form on the
-whole stacks with the layer's index), at the four expert cells' shapes
+whole stacks with the layer's index), at the seven expert cells' shapes
 for 8 to 2048 rows: a decode step's rows (one a slot) and an admission's
 buckets. Where the sorted form is at least 1.10x the dense one is where
 the rule's constants come from: ``SORTED_MAX_HIT_SHARE*`` (the share of
 the experts a call of few rows may hit and still be sorted) and
 ``SORTED_MIN_ROWS*`` (PERF.md keeps the table). ``--row-tile`` times the
 sorted form at other row tiles of the kernel than the program's.
-``--forms ragged`` times the sorted form with ``jax.lax.ragged_dot`` in
-the kernel's place (bf16 stacks only: it has no int8 operand, so a stack
-would be dequantised first).
 
 Usage:  python -m cake_tpu.tools.moe_sweep [--only NAME] [--rows 64,512]
-            [--forms dense,sorted,ragged] [--row-tile 32,128] [--json-out PATH]
+            [--forms dense,sorted,compact] [--row-tile 32,128] [--json-out PATH]
 (``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
 Prints one JSON line per shape, row count and row tile: ``{"shape",
 "rows", "row_tile", "<form>_us_per_layer", "<form>_roofline",
-"speedup"}``: a form's share of max(the chosen held experts' bytes / 819
-GB/s, the routed pairs'
-operations / 197 TFLOP/s) (what the block needs whichever form computes
-it; the router's weights give a near-uniform choice).
+"<form>_moved_mb", "speedup"}``: a form's share of max(the chosen held
+experts' bytes / 819 GB/s, the routed pairs' operations / 197 TFLOP/s)
+(what the block needs whichever form computes it; the router's weights
+give a near-uniform choice), and the bytes a layer's block moves BESIDE
+the weights by its shapes (:func:`moved_bytes`: rows gathered, what lies
+between the products, the results combined).
 """
 
 from __future__ import annotations
@@ -50,20 +49,14 @@ SHAPES = {
     "ling3flash-ep4": (128, 512, 8, 2560, 768, False, (8, 4)),
     # every scored expert held, no groups (the identity at 1 / 1)
     "lfm2-8b-a1b": (32, 32, 4, 2048, 1792, False, (1, 1)),
+    "kexaone-ep8": (16, 128, 8, 6144, 2048, False, (1, 1)),
+    "xing4-29b": (64, 64, 4, 3584, 1024, False, (1, 1)),
+    # softmax over the chosen logits (Mixtral's convention)
+    "mellum2-12b": (64, 64, 8, 2304, 896, False, None),
 }
 ROWS = (8, 16, 32, 64, 128, 256, 512, 1024, 2048)
 LAYERS = 3
 HBM_BYTES_S, BF16_FLOPS_S = 819e9, 197e12  # one v5e chip, published
-
-
-def _ragged_product(lhs, rhs, tiles, *, layer=None, scale=None,
-                    out_dtype=None, tm=None):
-    """``jax.lax.ragged_dot`` where the kernel stands (bf16 only)."""
-    assert scale is None
-    sizes = tiles.offsets[1:] - tiles.offsets[:-1]
-    w = rhs if layer is None else rhs[layer]
-    return jax.lax.ragged_dot(lhs, w, sizes,
-                              preferred_element_type=out_dtype or lhs.dtype)
 
 
 def _weights(key, layers, held, scored, hidden, width, int8):
@@ -91,18 +84,18 @@ def _weights(key, layers, held, scored, hidden, width, int8):
 @contextlib.contextmanager
 def _steered(form: str, row_tile: int):
     """While a form is traced: the expert block takes it whatever the
-    rows, at this row tile (``ragged``: the sorted form with
-    :func:`_ragged_product` where the kernel stands). Steering in the
-    tool: the program has no such knob."""
-    real = moe.expert_form, pk.MOE_ROW_TILE, pk.grouped_matmul
+    rows, at this row tile (``compact``: the sorted form with the live
+    tiles' gather and sum kernels whatever share of the experts is held).
+    Steering in the tool: the program has no such knob."""
+    real = moe.expert_form, moe.compacts, pk.MOE_ROW_TILE
     moe.expert_form = lambda *a: "dense" if form == "dense" else "sorted"
+    if form == "compact":
+        moe.compacts = lambda *a: True
     pk.MOE_ROW_TILE = row_tile
-    if form == "ragged":
-        pk.grouped_matmul = _ragged_product
     try:
         yield
     finally:
-        moe.expert_form, pk.MOE_ROW_TILE, pk.grouped_matmul = real
+        moe.expert_form, moe.compacts, pk.MOE_ROW_TILE = real
 
 
 def _layers_fn(form, name):
@@ -154,6 +147,38 @@ def floor_us(name: str, rows: int) -> float:
     return max(weight_bytes / HBM_BYTES_S, flops / BF16_FLOPS_S) * 1e6
 
 
+def moved_bytes(form: str, name: str, rows: int,
+                row_tile: int = pk.MOE_ROW_TILE) -> int:
+    """Bytes a layer's expert block moves beside the weights, by its
+    shapes at uniform routing, each operand read once and each result
+    written once. ``dense``: every held expert's gate, up, SwiGLU and down
+    results over every row, and their weighted sum. ``sorted``: the LIVE
+    row tiles alone (the pairs on held experts, rounded up to whole
+    tiles): rows gathered from ``[rows, H]``, the SwiGLU's result in the
+    rows' type (the row tile re-read a block of output columns), the down
+    product in float32, and the sum into ``[rows, H]`` (where XLA sums,
+    :func:`cake_tpu.ops.moe.compacts`, over a gathered copy of the
+    product)."""
+    held, scored, top_k, hidden, width, int8, _ = SHAPES[name]
+    act = 2  # bfloat16 rows
+    if form == "dense":
+        between = held * rows * (3 * width + hidden) * act  # g, u, h, y
+        return 2 * (rows * hidden * act + between)
+    pairs = rows * top_k * held / scored
+    live = min(-(-pairs // row_tile) * row_tile,
+               -(-rows * top_k // row_tile) * row_tile)
+    itemsize = 1 if int8 else 2
+    gate_blocks = width // pk.moe._block_n(hidden, width, itemsize)
+    down_blocks = hidden // pk.moe._block_n(width, hidden, itemsize)
+    kernels = form == "compact" or moe.compacts(held, scored)
+    return int(
+        rows * hidden * act + live * hidden * act  # gathered
+        + gate_blocks * live * hidden * act + live * width * act  # SwiGLU
+        + down_blocks * live * width * act + live * hidden * 4  # down
+        + (0 if kernels else 2 * live * hidden * 4)  # XLA's gathered copy
+        + live * hidden * 4 + rows * hidden * act)  # combined
+
+
 def sweep(names, row_counts, forms, row_tiles):
     """A row per shape, row count and row tile (the dense form has no
     tile: it is timed once a row count and stands in each tile's row)."""
@@ -166,8 +191,6 @@ def sweep(names, row_counts, forms, row_tiles):
             for tile in row_tiles:
                 row = {"shape": name, "rows": rows, "row_tile": tile}
                 for form in forms:
-                    if form == "ragged" and int8:
-                        continue
                     if form != "dense" or form not in timed:
                         timed[form] = _time_us(form, name, rows, weights,
                                                tile)
@@ -175,6 +198,8 @@ def sweep(names, row_counts, forms, row_tiles):
                     row[f"{form}_us_per_layer"] = round(us, 1)
                     row[f"{form}_roofline"] = round(
                         100 * floor_us(name, rows) / us, 1)
+                    row[f"{form}_moved_mb"] = round(
+                        moved_bytes(form, name, rows, tile) / 1e6, 2)
                 if "dense" in timed and "sorted" in timed:
                     row["speedup"] = round(
                         timed["dense"] / timed["sorted"], 3)

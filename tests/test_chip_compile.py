@@ -122,27 +122,64 @@ def _ssm(layers, b, t, n=16, c=5120):
                  ((layers, b, n, c), F32), ((), I32)])
 
 
+def _tiles(pairs, experts):
+    """A :class:`GroupTiles`' shapes over ``pairs`` sorted rows."""
+    from cake_tpu.ops.pallas.moe import ROW_TILE
+
+    visits = pairs // ROW_TILE + experts - 1
+    return [((experts + 1,), I32), ((visits,), I32), ((visits,), I32),
+            ((1,), I32), ((1,), I32)]
+
+
 def _gmm(pairs, k, n, experts, layers, int8=False):
-    """The expert block's grouped matmul as the layer loop calls it:
+    """The expert block's down product as the layer loop calls it:
     ``pairs`` sorted rows (a 512-row bucket's or a 32-row step's ``rows x
     top-k``), the whole ``[layers, experts, k, n]`` stack (int8 with a
     scale per output channel, or bf16), a traced layer index, the product
-    left in float32 (gate and up until the SwiGLU's result is rounded,
-    down until the rows are summed)."""
-    from cake_tpu.ops.pallas.moe import ROW_TILE, GroupTiles
+    left in float32 until the rows are summed."""
+    from cake_tpu.ops.pallas.moe import GroupTiles
 
-    visits = pairs // ROW_TILE + experts - 1
-
-    def fn(lhs, rhs, scale, offsets, group, tile, count, layer):
-        return pk.grouped_matmul(
-            lhs, rhs, GroupTiles(offsets, group, tile, count), layer=layer,
-            scale=scale, out_dtype=F32, interpret=False)
+    def fn(lhs, rhs, scale, *rest):
+        *tiles, layer = rest
+        return pk.grouped_matmul(lhs, rhs, GroupTiles(*tiles), layer=layer,
+                                 scale=scale, out_dtype=F32, interpret=False)
 
     return (fn, [((pairs, k), BF16),
                  ((layers, experts, k, n), I8 if int8 else BF16),
                  ((layers, experts, n), F32) if int8 else None,
-                 ((experts + 1,), I32), ((visits,), I32), ((visits,), I32),
-                 ((1,), I32), ((), I32)])
+                 *_tiles(pairs, experts), ((), I32)])
+
+
+def _gswiglu(pairs, k, n, experts, layers, int8=False):
+    """Gate, up and the SwiGLU in one call over the same operands: both
+    whole stacks, a converted block each where they are int8."""
+    from cake_tpu.ops.pallas.moe import GroupTiles
+
+    def fn(lhs, gate, up, gate_scale, up_scale, *rest):
+        *tiles, layer = rest
+        return pk.grouped_swiglu(
+            lhs, gate, up, GroupTiles(*tiles), layer=layer,
+            gate_scale=gate_scale, up_scale=up_scale, interpret=False)
+
+    stack = ((layers, experts, k, n), I8 if int8 else BF16)
+    scale = ((layers, experts, n), F32) if int8 else None
+    return (fn, [((pairs, k), BF16), stack, stack, scale, scale,
+                 *_tiles(pairs, experts), ((), I32)])
+
+
+def _live_rows(rows, pairs, h, experts):
+    """The live tiles' gather and sum as a sorted call of ``rows`` rows
+    makes them: ``[rows, h]`` in, ``pairs`` sorted rows between."""
+    from cake_tpu.ops.pallas.moe import GroupTiles
+
+    def fn(x, y, token, weight, *tiles):
+        tiles = GroupTiles(*tiles)
+        return (pk.gather_rows(x, token, tiles, interpret=False),
+                pk.combine_rows(y, token, weight, tiles, rows,
+                                out_dtype=BF16, interpret=False))
+
+    return (fn, [((rows, h), BF16), ((pairs, h), F32), ((pairs,), I32),
+                 ((pairs,), F32), *_tiles(pairs, experts)])
 
 
 def _qmm(m, k, n):
@@ -218,17 +255,23 @@ KERNELS = {
     # the three expert cells' 512-row admission: Mixtral's 8 int8 experts
     # (1024 pairs), A.X-K1's 12 held of 192 and Ling-3.0-flash's 128 held
     # of 512 (4096 pairs each, whatever share of them falls here)
-    "gmm_mixtral_int8_gate": _gmm(1024, HID, FFN, 8, 7, int8=True),
+    "gmm_mixtral_int8_gate": _gswiglu(1024, HID, FFN, 8, 7, int8=True),
     "gmm_mixtral_int8_down": _gmm(1024, FFN, HID, 8, 7, int8=True),
-    "gmm_axk1_gate": _gmm(4096, 7168, 2048, 12, 7),
+    "gmm_axk1_gate": _gswiglu(4096, 7168, 2048, 12, 7),
     "gmm_axk1_down": _gmm(4096, 2048, 7168, 12, 7),
-    "gmm_ling_gate": _gmm(4096, 2560, 768, 128, 6),
+    "gmm_ling_gate": _gswiglu(4096, 2560, 768, 128, 6),
     "gmm_ling_down": _gmm(4096, 768, 2560, 128, 6),
     # the two 32-slot cells' decode step: 256 pairs, two row tiles
-    "gmm_axk1_gate_step": _gmm(256, 7168, 2048, 12, 7),
+    "gmm_axk1_gate_step": _gswiglu(256, 7168, 2048, 12, 7),
     "gmm_axk1_down_step": _gmm(256, 2048, 7168, 12, 7),
-    "gmm_ling_gate_step": _gmm(256, 2560, 768, 128, 6),
+    "gmm_ling_gate_step": _gswiglu(256, 2560, 768, 128, 6),
     "gmm_ling_down_step": _gmm(256, 768, 2560, 128, 6),
+    # the live tiles' gather and sum where a share of the experts is held:
+    # the two cells' 512-row admission and step, K-EXAONE's 2048-row prompt
+    "live_rows_axk1": _live_rows(512, 4096, 7168, 12),
+    "live_rows_axk1_step": _live_rows(32, 256, 7168, 12),
+    "live_rows_ling": _live_rows(512, 4096, 2560, 128),
+    "live_rows_kexaone_t2048": _live_rows(2048, 16384, 6144, 16),
     "qmm_m64_4096x14336": _qmm(64, HID, FFN),
     "qmm_m64_14336x4096": _qmm(64, FFN, HID),
     "qmm_m64_4096x32000": _qmm(64, HID, VOCAB),
@@ -409,11 +452,28 @@ def _projection_moves(compiled, dtype: str, k: int, n: int) -> list[str]:
     return moves
 
 
-def _grouped_matmul_calls(compiled) -> int:
-    """The expert block's kernel calls in the program's text."""
+def _moe_calls(compiled, name: str) -> int:
+    """The kernel calls whose own name (the result's, left of ``=``)
+    holds ``name``: a call's operands carry other kernels' names."""
     return sum("custom-call(" in line and "tpu_custom_call" in line
-               and "moe_grouped_matmul" in line
+               and name in line.split("=")[0]
                for line in compiled.as_text().splitlines())
+
+
+def _grouped_matmul_calls(compiled) -> int:
+    """The expert block's grouped products in the program's text: two a
+    sorted call (gate, up and the SwiGLU one, ``moe_grouped_swiglu``; down
+    the other, ``moe_grouped_matmul``)."""
+    return _moe_calls(compiled, "moe_grouped_")
+
+
+def _live_tile_calls(compiled) -> int:
+    """The kernels that gather the live row tiles' rows and sum their
+    results (``moe_gather_rows``, ``moe_combine_rows``): two a sorted call
+    where the stacks hold a share of the scored experts, none where every
+    one is held (``ops.moe.compacts``)."""
+    return (_moe_calls(compiled, "moe_gather_rows")
+            + _moe_calls(compiled, "moe_combine_rows"))
 
 
 def _decode_kernel_calls(compiled) -> list[str]:
@@ -725,7 +785,8 @@ def test_sparse_admission_reads_the_int8_stacks_where_they_lie(
         compiled = _admit_prefill(topo, 3, bucket, sparse=True)
         for k, n in ((HID, FFN),):
             assert _expert_stack_moves(compiled, "s8", 8, k, n) == []
-        assert _grouped_matmul_calls(compiled) == 3
+        assert _grouped_matmul_calls(compiled) == 2
+        assert _live_tile_calls(compiled) == 0  # all 8 experts held
         _, temps = _donated_bytes(compiled)
         assert temps < 0.2 * GIB, (bucket, temps / GIB)
     assert _grouped_matmul_calls(_block_decode(topo, 2, sparse=True)) == 0
@@ -819,10 +880,12 @@ def test_latent_programs_move_no_cache_and_no_expert_stack(topo, as_on_chip):
             assert _cache_sized_moves(
                 compiled, f"bf16[{layers},{batch},1,{window},{width}]") == []
         assert _expert_stack_moves(compiled, "bf16", 12, 7168, 2048) == []
-    # both take the sorted form: gate, up and down are the grouped matmul
-    # on the whole stacks, three calls a scan body
-    assert _grouped_matmul_calls(admit) == 3
-    assert _grouped_matmul_calls(decode) == 3
+    # both take the sorted form: gate, up and the SwiGLU one grouped call
+    # on the whole stacks and down another, a scan body, between the live
+    # tiles' gather and sum
+    assert _grouped_matmul_calls(admit) == 2
+    assert _grouped_matmul_calls(decode) == 2
+    assert _live_tile_calls(admit) == _live_tile_calls(decode) == 2
     # the step's absorbed attention is the kernel (PR 44), once in the
     # dense stack's scan body and once in the expert stack's, on the
     # carried buffers themselves: the rope half goes in rows-last, which
@@ -929,7 +992,7 @@ def test_wide_stream_programs_move_no_cache_no_stack_and_no_wide_stream(
         for scope in ("mhc.coeff", "mhc.pre", "mhc.post"):
             assert scope in text, scope
     assert _grouped_matmul_calls(decode) == 0
-    assert _grouped_matmul_calls(admit) == 3
+    assert _grouped_matmul_calls(admit) == 2
     bodies = sorted(sum(_scoped_fusions(decode, scope).get(comp, 0)
                         for scope in ("mhc.", "btc,ck->btk"))
                     for comp in _scoped_fusions(decode, "mhc.coeff")
@@ -989,8 +1052,9 @@ def test_hybrid_programs_move_no_cache_no_state_and_no_expert_stack(
         assert _expert_stack_moves(compiled, "bf16", 128, 2560, 768) == []
     # three stacks of expert layers (K K K K | M | K), three products each,
     # in the admission and in the 32-row step (0.39 of 512 scored hit)
-    assert _grouped_matmul_calls(admit) == 9
-    assert _grouped_matmul_calls(decode) == 9
+    assert _grouped_matmul_calls(admit) == 6
+    assert _grouped_matmul_calls(decode) == 6
+    assert _live_tile_calls(admit) == _live_tile_calls(decode) == 6
     # the kernel's result is a pair, which ``_instructions`` does not
     # parse: read its calls off the text's lines
     calls = [line for line in decode.as_text().splitlines()
@@ -1133,7 +1197,7 @@ def test_window_and_full_programs_move_no_cache_and_no_ring(program,
     # stays (ops/moe.py expert_form); the admission sorts, in each of the
     # three sparse segments
     assert _grouped_matmul_calls(decode) == 0
-    assert _grouped_matmul_calls(admit) == 9
+    assert _grouped_matmul_calls(admit) == 6
     args, temps = _donated_bytes(decode)
     assert 10.25 * GIB < args < 10.4 * GIB, args / GIB  # 9.73 + 0.59
     assert temps < 0.02 * GIB, temps / GIB
@@ -1183,8 +1247,9 @@ def test_two_rotation_programs_band_through_the_kernel_and_fit(topo,
 
     # the step: a decode kernel a full segment, the dense expert form
     assert kernels(decode) == 2 and _grouped_matmul_calls(decode) == 0
-    assert _grouped_matmul_calls(admit8192) == 12  # 3 a sparse segment
-    assert kernels(admit8192) == 12 + 4  # ... and an attention kernel each
+    assert _grouped_matmul_calls(admit8192) == 8  # 2 a sparse segment
+    assert kernels(admit8192) == 8 + 4  # ... and an attention kernel each
+    assert _live_tile_calls(admit8192) == 0  # all 64 experts held
     # 256 rows: the dense expert form and XLA's band (a band's shape is
     # under the prefill policy's floor), the full layers' flash prefill
     assert _grouped_matmul_calls(admit256) == 0 and kernels(admit256) == 2
@@ -1286,8 +1351,8 @@ def test_conv_and_attention_programs_fit_one_chip(topo, as_on_chip):
         assert "flash_decode" not in compiled.as_text()
     assert _grouped_matmul_calls(decode) == 0
     assert _grouped_matmul_calls(admit128) == 0
-    assert _grouped_matmul_calls(admit512) == 24
-    assert _grouped_matmul_calls(admit2048) == 24
+    assert _grouped_matmul_calls(admit512) == 16
+    assert _grouped_matmul_calls(admit2048) == 16
     args, temps = _donated_bytes(decode)
     assert 10.75 * GIB < args < 10.9 * GIB, args / GIB  # 10.31 + 0.50
     assert temps < 0.02 * GIB, temps / GIB
@@ -1405,6 +1470,14 @@ def test_looped_programs_fit_one_chip_and_copy_no_cache(topo, as_on_chip):
 # cache's two kinds of state and the routing bias are additions that the
 # families PR 31 served do not pass through.
 PR31_TEXTS = {
+    # PR 56 re-pinned the five families that count their held experts
+    # (latent, hybrid, windowed, short_conv, latent_hc), both programs, on
+    # purpose: their expert block returns two more counts (the pair rows
+    # handed to the sorted form and those of the row tiles it touched:
+    # ``moe.sorted_pair_rows*``), which the decode programs carry beside
+    # the two they had and the admission programs now return. The dense,
+    # sparse, state-space and looped families keep every hash: no cell
+    # without a counted expert block runs a changed program.
     # re-pinned by PR 41, on purpose: the dense and sparse families'
     # attention is ``ops/attention.py`` ``_project_heads``, which now puts
     # an ``optimization_barrier`` between the q, k and v products and the
@@ -1419,11 +1492,11 @@ PR31_TEXTS = {
     # programs re-pinned by PR 35, which return one more count (the held
     # experts some row chose: ``moe.experts_hit``); their admissions are
     # the text they were
-    "latent.decode": "3c2465d5b9b2c22d", "latent.admit": "5c206bbdc09ba295",
+    "latent.decode": "1409e4ef740439b3", "latent.admit": "247a7617458f8410",
     # the hybrid's admission, taken on PR 32's tree (commit 9a9bb52): its
     # delta-rule expert segments share ONE scan body, which a body built
     # anew for each segment would lower once a segment (PR 33 met it)
-    "hybrid.decode": "2717b08c49fc2b24", "hybrid.admit": "3c267fa227f8873b",
+    "hybrid.decode": "dd65de518af3a6cb", "hybrid.admit": "75386c5159e2e903",
     # the state-space family, taken on PR 40's tree (commit d2e802e): its
     # attention layers pass through ``_project_heads`` with no norm and no
     # rotation behind the products, where PR 41 puts no barrier (the chip's
@@ -1432,10 +1505,10 @@ PR31_TEXTS = {
     "state_space.admit": "b095bb3adf962eef",
     # the window and short-convolution families, taken on PR 45's tree
     # (commit 545f5a9), before PR 46 moved what a family is into one record
-    "windowed.decode": "5fd81e6a4dfe8dc0",
-    "windowed.admit": "750c179d4d7e8076",
-    "short_conv.decode": "ffc2e337af0e44aa",
-    "short_conv.admit": "d9e071c8a3327ea1",
+    "windowed.decode": "ef316bfadefe7e91",
+    "windowed.admit": "bbea7a8b932e2425",
+    "short_conv.decode": "21e8ec7733b740d7",
+    "short_conv.admit": "903657caf6523926",
     # the looped family, taken on PR 47's tree, which brought it: a loop of
     # passes around the scan over one stack, a plane a layer and a pass
     "looped.decode": "1790a3781f0fb307",
@@ -1443,8 +1516,8 @@ PR31_TEXTS = {
     # the latent family under a residual stream four hidden vectors wide,
     # taken on PR 51's tree, which brought it (``hc_mult`` 1 lowers every
     # family above to the text it had: no hash replaced)
-    "latent_hc.decode": "84fbf8492d753fb3",
-    "latent_hc.admit": "58e32a443fed4e03",
+    "latent_hc.decode": "64178e4935b87604",
+    "latent_hc.admit": "17ac986347105f98",
 }
 
 

@@ -15,6 +15,7 @@ from cake_tpu.ops.moe import (
     GroupRouting,
     _moe_dense,
     _moe_gather,
+    compacts,
     expert_form,
     moe_swiglu,
     router_topk,
@@ -268,8 +269,9 @@ def test_sorted_form_is_the_dense_form_and_the_reference(
     for got in (out, dense):
         np.testing.assert_allclose(np.asarray(got[0], np.float64), want,
                                    atol=tol * scale, rtol=0)
-    for a, b in zip(counted, dense_counted):
+    for a, b in zip(counted[:2], dense_counted[:2]):  # pairs, hit
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert not int(dense_counted.sorted_rows) + int(dense_counted.live_rows)
     _, _, idx = router_topk(x[0], rw, top_k, kw["routing"])
     local = np.asarray(idx) - first
     local = local[(local >= 0) & (local < held)]
@@ -332,6 +334,54 @@ def test_sorted_every_row_on_one_expert(kernels):
     want = _pairs_oracle(x, rw, plain, kw)
     np.testing.assert_allclose(np.asarray(out[0], np.float64), want,
                                atol=3e-2 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("case", ["boundary", "long-straddle"])
+def test_sorted_form_under_a_traced_first_expert(case, kernels):
+    """The sorted form where the first held expert is a traced value (an
+    ``ep`` rank's), on choices made by hand: the held pairs fill exactly
+    one row tile of 128 (nothing of the second tile is touched), or one
+    expert's 160 rows span two tiles and share the second with the next
+    expert's. Both are the dense form over the held experts and the
+    float64 loop over the pairs; the rows of the live tiles are what the
+    count says; a token with no held choice gets exactly zero."""
+    from cake_tpu.ops.moe import _moe_sorted
+
+    first, held, scored, k, h, f = 4, 4, 16, 4, 32, 64
+    if case == "boundary":  # 32 tokens x 4 held choices = 128 pairs
+        n, live = 64, 128
+        idx = np.where(np.arange(n)[:, None] < 32, [[4, 5, 6, 7]],
+                       [[0, 1, 2, 3]])
+    else:  # expert 4: 160 rows; expert 5: 80 rows from row 160 on
+        n, live = 160, 256
+        idx = np.tile([[4, 12, 13, 14]], (n, 1))
+        idx[::2, 1] = 5
+    ks = jax.random.split(jax.random.PRNGKey(9), 5)
+    x = jax.random.normal(ks[0], (n, h))
+    w = jax.random.uniform(ks[1], (n, k), minval=0.1)
+    stacks = [jax.random.normal(key, shape) / 4 for key, shape in (
+        (ks[2], (held, h, f)), (ks[3], (held, h, f)), (ks[4], (held, f, h)))]
+    idx = jnp.asarray(idx, jnp.int32)
+    got, live_rows = jax.jit(lambda lo: _moe_sorted(
+        x, w, idx, lo, *stacks, None, scored))(jnp.int32(first))
+    assert int(live_rows) == live
+    combine = jnp.einsum("nk,nke->ne", w, jax.nn.one_hot(idx, scored))
+    dense = _moe_dense(x, combine[:, first:first + held], *stacks)
+    want = np.zeros((n, h))
+    x64, plain = np.asarray(x, np.float64), [np.asarray(a, np.float64)
+                                             for a in stacks]
+    for t in range(n):
+        for wgt, e in zip(np.asarray(w[t], np.float64),
+                          np.asarray(idx[t]) - first):
+            if 0 <= e < held:
+                g = x64[t] @ plain[0][e]
+                want[t] += wgt * ((g / (1 + np.exp(-g))
+                                   * (x64[t] @ plain[1][e])) @ plain[2][e])
+    for out in (got, dense):
+        np.testing.assert_allclose(np.asarray(out, np.float64), want,
+                                   atol=3e-5 * np.abs(want).max(), rtol=0)
+    if case == "boundary":
+        assert (np.asarray(got)[32:] == 0).all()
 
 
 @pytest.mark.parametrize("rows,top_k,int8,held,scored,form", [
@@ -457,25 +507,77 @@ def test_engine_counts_admitted_rows_by_form(monkeypatch):
     assert all(row is None or row.id >= 0 for row in bg.step())
 
 
+def test_engine_counts_the_pair_rows_the_sorted_form_touches(monkeypatch):
+    """An expert model told its share (4 held of 16 scored, top-4) counts
+    on the device, a sorted-form call and expert layer, the pair rows the
+    call was handed (``rows x top_k``) and those of the row tiles it
+    touched; the engine brings both home with the counts it already
+    fetches: an admission's once its program has run, a decode step's
+    with its block. A 512-row program (the bucket's, and the two-row
+    ones the engine warms behind it) hands 2048 pair rows a layer to the
+    sorted form, of which a quarter or so are held: 6 tiles of 16 at
+    most."""
+    from cake_tpu.models.config import tiny_mla_moe
+    from cake_tpu.obs import metrics
+    from cake_tpu.runtime.batch_generator import BatchGenerator
+
+    monkeypatch.setenv("CAKE_PALLAS", "1")
+    cfg = tiny_mla_moe(max_seq_len=512, eos_token_id=-1, n_routed_experts=4,
+                       router_experts=16, first_expert=4)
+    layers = sum(ffn == "moe" for _, ffn in cfg.layer_kinds)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    reg = metrics.registry()
+    handed, live = (reg.counter(f"moe.sorted_pair_rows{s}")
+                    for s in ("", "_live"))
+    bg = BatchGenerator(cfg, params, settings=SamplerSettings(**GREEDY))
+    bg.set_prompts([[3, 5, 7], [2, 4]], stream_ids=[0, 1])
+    bg.drain()
+    before = handed.value, live.value
+    assert bg.finish(1)
+    bg.admit([t % 250 + 1 for t in range(1, 301)], stream_id=3)
+    bg.drain()
+    assert moe.form_traced(512) == "sorted"
+    programs = (handed.value - before[0]) / (512 * 4 * layers)
+    assert programs >= 1 and programs == int(programs)
+    touched = live.value - before[1]
+    assert touched % 128 == 0
+    assert 128 <= touched / (programs * layers) <= 6 * 128
+    # a step of 2 rows x 4 of 16 scored hits 0.4 of them: sorted, one
+    # tile of 128 for its 8 pair rows where a pair is held, else none
+    before = handed.value, live.value
+    for _ in range(4):
+        bg.step()
+    bg.drain()
+    steps = (handed.value - before[0]) / (2 * 4 * layers)
+    assert steps >= 1 and steps == int(steps)
+    assert 0 <= live.value - before[1] <= steps * layers * 128
+
+
 def test_moe_sweep_rows_at_tiny_shapes(monkeypatch, kernels):
     """tools/moe_sweep.py's machinery on the CPU (interpreted kernel, no
     device time): a row per shape and row count, each form timed through
-    ``moe_swiglu`` as the layer loop calls it, and the program's own
-    choice restored afterwards."""
+    ``moe_swiglu`` as the layer loop calls it (``compact``: the live
+    tiles' gather and sum kernels where every expert is held too), the
+    bytes each moves beside the weights by its shapes, and the program's
+    own choices restored afterwards."""
     from cake_tpu.tools import moe_sweep
 
     monkeypatch.setattr(moe_sweep, "SHAPES", {
         "tiny": (4, 4, 2, 32, 128, False, None),
         "tiny-int8-share": (4, 16, 2, 32, 128, True, (4, 2))})
     out = list(moe_sweep.sweep(["tiny", "tiny-int8-share"], [16, 128],
-                               ["dense", "sorted", "ragged"], [128]))
+                               ["dense", "sorted", "compact"], [128]))
     assert [(r["shape"], r["rows"]) for r in out] == [
         ("tiny", 16), ("tiny", 128), ("tiny-int8-share", 16),
         ("tiny-int8-share", 128)]
     for r in out:
         assert r["dense_us_per_layer"] > 0 and r["sorted_us_per_layer"] > 0
-        assert ("ragged_us_per_layer" in r) == (r["shape"] == "tiny")
-    assert moe.expert_form is expert_form
+        assert r["compact_us_per_layer"] > 0
+        # a quarter of the pairs are held: the sorted form moves less
+        assert (r["sorted_moved_mb"] == r["compact_moved_mb"]) == (
+            r["shape"] == "tiny-int8-share")
+    assert out[3]["sorted_moved_mb"] < out[3]["dense_moved_mb"]
+    assert moe.expert_form is expert_form and moe.compacts is compacts
 
 
 @pytest.mark.parametrize("form,hit,want", [

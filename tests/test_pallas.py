@@ -885,3 +885,123 @@ def test_flash_prefill_q8_windowed_matches_dequant_oracle(window):
                              interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The expert block's sorted form (ops/pallas/moe.py): the fused gate, up
+# and SwiGLU call, and the live row tiles' gather and sum.
+# ---------------------------------------------------------------------------
+
+MOE_TM = 8  # a small row tile: groups straddle and span tiles at few rows
+
+# group sizes over 4 experts -> what the case is there for
+MOE_GROUPS = {
+    "straddle": (5, 6, 0, 9),  # groups 1 and 3 lie across a tile's edge
+    "long": (20, 0, 3, 1),  # group 0 spans three tiles, group 1 has no row
+    "boundary": (4, 4, 6, 2),  # the grouped rows end exactly on a tile's edge
+    "none": (0, 0, 0, 0),  # no pair on a held expert at all
+    "all": (8, 8, 8, 8),  # every row grouped: every tile live
+}
+
+
+def _moe_tiles(sizes, rows=32):
+    from cake_tpu.ops.pallas import group_tiles
+
+    tiles = group_tiles(jnp.asarray(sizes, jnp.int32), rows, MOE_TM)
+    assert int(tiles.live[0]) == -(-sum(sizes) // MOE_TM)
+    return tiles, np.repeat(np.arange(len(sizes)), sizes)
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+@pytest.mark.parametrize("groups", ["straddle", "long", "boundary"])
+def test_grouped_swiglu_is_both_products_and_the_swiglu(groups, kind):
+    """Gate, up and the SwiGLU in ONE call: every grouped row is
+    ``silu(x @ gate[g]) * (x @ up[g])`` of its group's matrices, both
+    products float32 and the result rounded once (to the rows' type),
+    whichever tiles a group's rows lie in and however many it spans; the
+    whole ``[L, E, ..]`` stacks with a layer's index give what that
+    layer's own stacks give. Against float64, and against the two
+    separate grouped products it replaces."""
+    from cake_tpu.ops.pallas import grouped_matmul, grouped_swiglu
+    from cake_tpu.ops.quant import dequantize_linear, quantize_linear
+
+    sizes = MOE_GROUPS[groups]
+    tiles, group_of = _moe_tiles(sizes)
+    k, n, e = 32, 256, len(sizes)
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    x = jax.random.normal(ks[0], (32, k)).astype(jnp.bfloat16)
+    stacks = [jax.random.normal(key, (2, e, k, n)) / 4 for key in ks[1:]]
+    if kind == "int8":
+        q = [jax.vmap(jax.vmap(quantize_linear))(w) for w in stacks]
+        plain = [np.asarray(dequantize_linear(w, jnp.float32), np.float64)
+                 for w in q]
+        gate, up = (w.q for w in q)
+        scales = dict(gate_scale=q[0].scale, up_scale=q[1].scale)
+    else:
+        gate, up = (w.astype(jnp.bfloat16) for w in stacks)
+        plain = [np.asarray(w, np.float64) for w in (gate, up)]
+        scales = {}
+    got = grouped_swiglu(x, gate, up, tiles, layer=jnp.int32(1), tm=MOE_TM,
+                         block_n=128, interpret=True, **scales)
+    assert got.dtype == jnp.bfloat16
+    x64 = np.asarray(x, np.float64)
+    rows = len(group_of)
+    g = np.einsum("rk,rkn->rn", x64[:rows], plain[0][1][group_of])
+    u = np.einsum("rk,rkn->rn", x64[:rows], plain[1][1][group_of])
+    want = g / (1 + np.exp(-g)) * u
+    np.testing.assert_allclose(np.asarray(got, np.float64)[:rows], want,
+                               atol=2 ** -7 * np.abs(want).max(), rtol=0)
+    # one layer's own stacks: the same call, bit for bit
+    alone = grouped_swiglu(
+        x, gate[1], up[1], tiles, tm=MOE_TM, block_n=128, interpret=True,
+        **{name: s[1] for name, s in scales.items()})
+    np.testing.assert_array_equal(np.asarray(alone)[:rows],
+                                  np.asarray(got)[:rows])
+    # ... and the two float32 products it replaces, the SwiGLU between
+    parts = [grouped_matmul(x, w, tiles, layer=jnp.int32(1), tm=MOE_TM,
+                            scale=scales.get(name), out_dtype=jnp.float32,
+                            block_n=128, interpret=True)
+             for w, name in ((gate, "gate_scale"), (up, "up_scale"))]
+    two = (jax.nn.silu(parts[0]) * parts[1]).astype(jnp.bfloat16)
+    np.testing.assert_allclose(
+        np.asarray(two, np.float64)[:rows], np.asarray(got, np.float64)[:rows],
+        atol=2 ** -7 * np.abs(want).max(), rtol=0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("groups", list(MOE_GROUPS))
+def test_live_tiles_are_gathered_and_summed_alone(groups, dtype):
+    """The rows of the live row tiles are ``x[token]`` exactly, and the sum
+    adds each grouped row under its weight into its token's row in
+    float32: a token no grouped row names gets exactly zero, and nothing
+    past the grouped rows is read (NaN lies there, in the live tile's tail
+    and in every tile behind it): with no pair held at all, with every
+    pair held, with the grouped rows ending on a tile's edge."""
+    from cake_tpu.ops.pallas import combine_rows, gather_rows
+
+    sizes = MOE_GROUPS[groups]
+    tiles, group_of = _moe_tiles(sizes)
+    total, n, h = len(group_of), 11, 256
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    x = jax.random.normal(ks[0], (n, h)).astype(dtype)
+    token = jax.random.randint(ks[1], (32,), 0, n - 1)  # row n - 1: unnamed
+    weight = jax.random.uniform(ks[2], (32,))
+    picked = gather_rows(x, token, tiles, tm=MOE_TM, interpret=True)
+    live = int(tiles.live[0]) * MOE_TM
+    np.testing.assert_array_equal(np.asarray(picked)[:live],
+                                  np.asarray(x)[np.asarray(token)[:live]])
+    y = jax.random.normal(ks[3], (32, h))
+    y = y.at[total:].set(jnp.nan)  # never written: never read
+    got = combine_rows(y, token, weight, tiles, n, out_dtype=dtype,
+                       tm=MOE_TM, block_h=128, interpret=True)
+    assert got.dtype == dtype and got.shape == (n, h)
+    want = np.zeros((n, h))
+    for r in range(total):
+        want[int(token[r])] += float(weight[r]) * np.asarray(
+            y[r], np.float64)
+    tol = 2 ** -7 if dtype == jnp.bfloat16 else 1e-6
+    np.testing.assert_allclose(np.asarray(got, np.float64), want,
+                               atol=tol * max(np.abs(want).max(), 1), rtol=0)
+    named = np.zeros(n, bool)
+    named[np.asarray(token)[:total]] = True
+    assert (np.asarray(got)[~named] == 0).all()
