@@ -8,7 +8,7 @@ sequence length (the reference hard-caps MAX_SEQ_LEN=4096, config.rs:6; here it
 is a tunable because the TPU build supports long context).
 
 Families read: the dense and Mixtral-style decoders (one bare stack),
-five whose layers are of several kinds (``segmented``: a stack a stretch of
+six whose layers are of several kinds (``segmented``: a stack a stretch of
 one kind, ``models/llama.py`` ``layer_plan``), and one whose layers run
 several times a token (``total_ut_steps``: one stack, a cache plane a layer
 and a pass). A family's config.json and
@@ -21,7 +21,7 @@ from __future__ import annotations
 import dataclasses
 import json
 from pathlib import Path
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
 import jax.numpy as jnp
 
@@ -29,6 +29,16 @@ from cake_tpu.models import families
 
 # Reference default (config.rs:6). Overridable per-config here.
 DEFAULT_MAX_SEQ_LEN = 4096
+
+
+class DeltaRule(NamedTuple):
+    """The sizes of a model's delta-rule layers (ops/kda.py)."""
+
+    key_heads: int
+    value_heads: int
+    d_k: int
+    d_v: int
+    taps: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -224,6 +234,32 @@ class LlamaConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: tuple[float, float] = (-30.0, 30.0)
+    # --- scalar-gated delta-rule layers beside gated grouped-query
+    # attention (HF `model_type` "qwen3_next") -------------------------------
+    # ``layer_types``: "linear_attention" | "full_attention" a layer (the
+    # file gives ``full_attention_interval`` P: full where ``(i + 1) % P ==
+    # 0``). A linear layer is a gated delta rule (ops/kda.py) of
+    # ``linear_num_value_heads`` heads of ``linear_key_head_dim`` x
+    # ``linear_value_head_dim`` state over ``linear_num_key_heads`` key
+    # heads (value head ``h`` reads key head ``h // (Hv / Hk)``), a log-decay
+    # a HEAD (``-exp(A_log) softplus(a + dt_bias)``, unbounded below), a
+    # causal depthwise convolution of ``linear_conv_kernel_dim`` taps over
+    # ``[q | k | v]``, the output normed a head and gated by ``silu(z)``. A
+    # full layer is grouped-query attention whose q projection carries a
+    # gate a channel beside each head (``attn_gate`` "elementwise": ``out *
+    # sigmoid(gate)`` before ``wo``), whose q and k heads are normed
+    # (``qk_norm``) and of whose ``head_dim`` channels the first
+    # ``rope_fraction`` rotate (the file's ``partial_rotary_factor``). Every
+    # layer routes over softmax-scored experts beside ONE shared expert
+    # weighted by ``sigmoid(x w_sg)`` (``shared_expert_gate``). The file's
+    # norms are stored as ``w - 1``; the loaders add the one.
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    rope_fraction: float = 1.0
+    shared_expert_gate: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -233,6 +269,7 @@ class LlamaConfig:
             )
         self.family.check(self)
         families.check_residual_path(self)
+        families.check_gated_keys(self)
         # validate at construction, not as a KeyError deep in a jit trace
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
@@ -311,9 +348,10 @@ class LlamaConfig:
     def layer_kinds(self) -> tuple[tuple[str, str], ...]:
         """``(mixer, feed-forward)`` of every layer, in model order: the
         mixer is "gqa", "swa" (grouped-query attention through a window,
-        where ``layer_types`` says so), "mla", "kda", "mamba" or "conv" (a
-        gated short convolution, where ``layer_types`` says so), the
-        feed-forward "dense" or "moe".
+        where ``layer_types`` says so), "mla", "kda", "gdn" (the
+        scalar-gated delta rule, where ``layer_types`` says so), "mamba" or
+        "conv" (a gated short convolution, where ``layer_types`` says so),
+        the feed-forward "dense" or "moe".
         THE place the layer order comes from (models/llama.py
         ``layer_plan`` groups it into scanned segments, the cache and the
         loaders count it)."""
@@ -338,14 +376,37 @@ class LlamaConfig:
                      for i in range(n))
 
     @property
+    def delta_rule(self) -> DeltaRule:
+        """``(key heads, value heads, d_k, d_v, taps)`` of the model's
+        delta-rule layers (ops/kda.py), from the MIXER's own keys: KDA's are
+        the attention's (as many key heads as value heads of ``head_dim``,
+        ``short_conv_kernel_size`` taps), the scalar-gated rule's are the
+        ``linear_*`` ones."""
+        if self.family.recurrent_mixer == "gdn":
+            return DeltaRule(
+                self.linear_num_key_heads, self.linear_num_value_heads,
+                self.linear_key_head_dim, self.linear_value_head_dim,
+                self.linear_conv_kernel_dim)
+        h, d = self.num_attention_heads, self.head_dim
+        return DeltaRule(h, h, d, d, self.short_conv_kernel_size)
+
+    @property
+    def delta_conv_width(self) -> int:
+        """Channels of a delta-rule layer's convolution: ``[q | k | v]``."""
+        hk, hv, dk, dv, _ = self.delta_rule
+        return 2 * hk * dk + hv * dv
+
+    @property
     def cache_plan(self) -> dict[str, tuple[int, ...]]:
         """What the cache holds, a kind of state each: ``rows`` ``(layers,
         heads, k_width, v_width)`` for the layers that keep rows (every
         layer of a model with one kind of attention), and for the layers
         that hold a recurrent state ``state`` (float32) and ``conv`` (the
         convolutions' last inputs), shaped by their mixer: delta-rule
-        layers ``(layers, heads, d_k, d_v)`` and ``(layers, taps - 1, 3
-        heads d)`` (the q, k and v convolutions), Mamba layers ``(layers,
+        layers ``(layers, value heads, d_k, d_v)`` and ``(layers, taps - 1,
+        2 key heads d_k + value heads d_v)`` (the q, k and v convolutions;
+        ``delta_rule``: the mixer's own heads, not the attention's), Mamba
+        layers ``(layers,
         d_state, d_inner)`` (channels last, on the lanes) and ``(layers,
         taps - 1, d_inner)``. Window layers (``layer_types``) keep ``ring``
         ``(layers, heads, R, k_width, v_width)``: ``R = ring_rows`` rows a
@@ -369,10 +430,10 @@ class LlamaConfig:
         if ring:
             heads, *widths = self.cache_row
             plan["ring"] = (ring, heads, self.ring_rows, *widths)
-        if held and recurrent == "kda":
-            h, d = self.num_attention_heads, self.head_dim
-            plan["state"] = (held, h, d, d)
-            plan["conv"] = (held, self.short_conv_kernel_size - 1, 3 * h * d)
+        if held and recurrent in ("kda", "gdn"):
+            _, hv, dk, dv, taps = self.delta_rule
+            plan["state"] = (held, hv, dk, dv)
+            plan["conv"] = (held, taps - 1, self.delta_conv_width)
         elif held and recurrent == "conv":
             plan["conv"] = (held, self.conv_L_cache - 1, self.hidden_size)
         elif held:
@@ -387,10 +448,14 @@ class LlamaConfig:
         Where window and full layers are mixed this is the width of
         whichever kinds rotate (``layer_rope``: the layer loop hands each
         kind its own table, or none). Beside short-convolution layers the
-        full layers rotate the whole head."""
+        full layers rotate the whole head; beside scalar-gated delta-rule
+        layers they rotate the head's first ``rope_fraction`` channels
+        (``ops.rope.apply_rope`` leaves the rest as they are)."""
         if self.attn_layer_period:
             return 0
-        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
+        if self.kv_lora_rank:
+            return self.qk_rope_head_dim
+        return int(self.head_dim * self.rope_fraction)
 
     @property
     def cache_row(self) -> tuple[int, int, int]:
@@ -943,6 +1008,58 @@ def mellum2_12b(**overrides) -> LlamaConfig:
     return LlamaConfig(**base)
 
 
+def qwen3next_ep4(**overrides) -> LlamaConfig:
+    """Qwen3-Next-80B-A3B-Instruct (https://huggingface.co/Qwen/
+    Qwen3-Next-80B-A3B-Instruct, `model_type` "qwen3_next") at its
+    published widths, as ONE chip of the 4 that share each layer's 512
+    experts holds it: global experts 0-127 beside the whole router, mixers
+    and gated shared expert. 48 layers as published, every one sparse: a
+    scalar-gated delta rule (16 key heads under 32 value heads of 128 x
+    128 state, 4 taps) but each fourth, which is gated grouped-query
+    attention of 16 QK-normed query heads over 2 key/value heads of 256,
+    the first 64 channels rotated; top-10 of 512 softmax-scored experts
+    (512 wide). A chip serves the depth of its pipeline stage
+    (`num_hidden_layers=`; `layer_types` is cut to it) and its slice of
+    the vocabulary (`vocab_size=`)."""
+    base = dict(
+        model_type="qwen3_next",
+        vocab_size=151936,
+        hidden_size=2048,
+        intermediate_size=5120,
+        num_hidden_layers=48,
+        num_attention_heads=16,
+        num_key_value_heads=2,
+        head_dim=256,
+        rms_norm_eps=1e-6,
+        rope_theta=10000000.0,
+        rope_fraction=0.25,
+        max_seq_len=262144,
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        linear_num_key_heads=16,
+        linear_num_value_heads=32,
+        linear_key_head_dim=128,
+        linear_value_head_dim=128,
+        linear_conv_kernel_dim=4,
+        qk_norm=True,
+        attn_gate="elementwise",
+        moe_intermediate_size=512,
+        n_shared_experts=1,
+        shared_expert_gate=True,
+        n_routed_experts=128,
+        router_experts=512,
+        first_expert=0,
+        num_experts_per_tok=10,
+        scoring_func="softmax",
+        norm_topk_prob=True,
+        bos_token_id=0,
+        eos_token_id=1,
+    )
+    base.update(overrides)
+    base["layer_types"] = _repeated(base["layer_types"],
+                                    base["num_hidden_layers"])
+    return LlamaConfig(**base)
+
+
 # LFM2-8B-A1B's 24 layers: c c A, then c c c A four times, c c A c c
 _LFM2_8B_LAYERS = tuple(
     "full_attention" if i in (2, 6, 10, 14, 18, 21) else "conv"
@@ -1190,6 +1307,45 @@ def tiny_mellum(**overrides) -> LlamaConfig:
         scoring_func="softmax",
         norm_topk_prob=True,
         rms_norm_eps=1e-6,
+    )
+    base.update(overrides)
+    base["layer_types"] = _repeated(base["layer_types"],
+                                    base["num_hidden_layers"])
+    return tiny(**base)
+
+
+def tiny_qwen3_next(**overrides) -> LlamaConfig:
+    """Tiny fixture of the scalar-gated delta-rule + gated attention family
+    that keeps the published ratios (Qwen3-Next's keys): two whole ``D D D
+    A`` periods, 2 key heads under 4 value heads of 16 x 8 state (a key
+    width that differs from the value width hides no mix-up), 4 taps; 4
+    gated, QK-normed query heads over 2 key/value heads of 16 whose first
+    8 channels rotate; every layer sparse: 16 softmax-scored experts top-4
+    of which 4 are held (rank 1 of 4) beside ONE gated shared expert."""
+    base = dict(
+        model_type="qwen3_next",
+        num_hidden_layers=8,
+        head_dim=16,
+        rope_fraction=0.5,
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        linear_num_key_heads=2,
+        linear_num_value_heads=4,
+        linear_key_head_dim=16,
+        linear_value_head_dim=8,
+        linear_conv_kernel_dim=4,
+        qk_norm=True,
+        attn_gate="elementwise",
+        moe_intermediate_size=32,
+        n_shared_experts=1,
+        shared_expert_gate=True,
+        n_routed_experts=4,
+        router_experts=16,
+        first_expert=4,
+        num_experts_per_tok=4,
+        scoring_func="softmax",
+        norm_topk_prob=True,
+        rms_norm_eps=1e-6,
+        rope_theta=10000000.0,
     )
     base.update(overrides)
     base["layer_types"] = _repeated(base["layer_types"],

@@ -15,7 +15,12 @@ sandwich-normed layers, the last norm closing each pass, a cache plane a
 layer AND a pass; keys ``total_ut_steps``, ``early_exit_threshold``;
 limits: threshold 1 only, every layer full attention, no window, no rope
 scaling, slot layout, no sharding, no quantized tier), ``SHORT_CONV``
-(``lfm2_moe``), ``WINDOWED`` (``exaone_moe``, and ``mellum``: the rotation
+(``lfm2_moe``), ``GATED_DELTA`` (``qwen3_next``: scalar-gated delta-rule
+layers, whose state is shaped by the MIXER's own heads, beside gated,
+part-rotated grouped-query attention, softmax-scored experts beside a
+gated shared one; norm weights stored as ``w - 1`` and fused projections
+stored a key head's group at a time, both folded where the tensors are
+read: ``Fold``), ``WINDOWED`` (``exaone_moe``, and ``mellum``: the rotation
 a layer KIND is data read from the file, ``LlamaConfig.layer_rope``),
 ``STATE_SPACE`` (``jamba``),
 ``HYBRID`` (``bailing_hybrid``), ``LATENT`` (``deepseek_v3``, ``axk1``,
@@ -29,7 +34,7 @@ refuses them (``check_residual_path``).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 
 def _nothing(*_):
@@ -58,12 +63,14 @@ class Family:
     # (ops/shortconv.py: a tail of inputs and NO state), or None
     recurrent_mixer: str | None = None
     # checkpoint tensors under `model.layers.{i}.`: ours -> (HF suffix,
-    # transpose?), a stack taking its own; ours -> a routed expert's
-    # pattern ({e}: its global id); the last norm; part of a name only
-    # this family's checkpoints store
+    # transpose?[, Fold: how it is stored where that is not how the program
+    # holds it]), a stack taking its own; ours -> a routed expert's
+    # pattern ({e}: its global id); the last norm (and its Fold); part of
+    # a name only this family's checkpoints store
     tensor_names: dict = dataclasses.field(default_factory=dict)
     expert_names: dict = dataclasses.field(default_factory=dict)
     final_norm: str = "model.norm.weight"
+    final_norm_fold: Any = None
     probe: str | None = None
     # tensors beside the layers, the embedding, the last norm and the
     # head: ours (a key of ``params``) -> {part: (HF name, shape(config))}
@@ -282,6 +289,92 @@ _LOOPED_MAP = {
 _EXIT_GATE = {
     "weight": ("model.early_exit_gate.weight", lambda c: (1, c.hidden_size)),
     "bias": ("model.early_exit_gate.bias", lambda c: (1,)),
+}
+
+
+class Fold(NamedTuple):
+    """How a checkpoint stores a tensor where that is not how the program
+    holds it, as the third element of a ``tensor_names`` entry: ``load(
+    config, stored)`` gives ours from the stored tensor in its logical
+    layout (a linear ``[in, out]``, float32), ``save`` the inverse. Both
+    loaders and the writer apply it to the whole tensor
+    (``utils/sharded_load.py``, ``utils/weights.py``)."""
+
+    load: Callable
+    save: Callable
+
+
+# a norm's weight stored as an offset from one (``x * rsqrt(..) * (1 +
+# w)``): folded on load, so that the program's ``rms_norm`` stays one
+# function
+_ONE_PLUS = Fold(lambda c, w: 1.0 + w, lambda c, w: w - 1.0)
+
+
+def _grouped_columns(c, widths) -> "np.ndarray":
+    """Stored column of each of our columns for a projection fused a KEY
+    HEAD'S GROUP at a time: the checkpoint stores, for key head 0, then 1,
+    .., ``widths`` channels of each part side by side (``[q_0 | k_0 | v_0 |
+    z_0 | q_1 | ..]``); ours is part by part (``[q | k | v | z]``, heads in
+    order inside each)."""
+    import numpy as np
+
+    hk = c.delta_rule.key_heads
+    starts = np.concatenate([[0], np.cumsum(widths)])
+    base = np.arange(hk)[:, None] * starts[-1]
+    return np.concatenate([
+        (base + starts[i] + np.arange(w)).reshape(-1)
+        for i, w in enumerate(widths)])
+
+
+def _group_fold(widths_of) -> Fold:
+    def load(c, w):
+        return w[..., _grouped_columns(c, widths_of(c))]
+
+    def save(c, w):
+        import numpy as np
+
+        out = np.empty_like(w)
+        out[..., _grouped_columns(c, widths_of(c))] = w
+        return out
+
+    return Fold(load, save)
+
+
+def _qkvz_widths(c):
+    hk, hv, dk, dv, _ = c.delta_rule
+    return (dk, dk, hv // hk * dv, hv // hk * dv)
+
+
+# Scalar-gated delta-rule layers beside gated attention (`model_type`
+# "qwen3_next"; Hugging Face's Qwen3Next modules, which config.json does not
+# carry: the benchmark configuration lists them): the mixer under
+# `linear_attn.`, its projections fused a key head's group at a time
+# (`in_proj_qkvz`: `[q | k | v | z]` of key head 0, then of 1, ..;
+# `in_proj_ba`: `[b | a]` likewise), ONE convolution over `[q | k | v]` as
+# torch depthwise `[C, 1, K]`, `norm` a PLAIN weight; the attention's
+# `q_proj` a head's `[q | gate]` side by side; every other norm stored as
+# `w - 1`; the shared expert under `mlp.shared_expert.` (singular) with its
+# gate `mlp.shared_expert_gate` a linear of one output.
+_GATED_DELTA_MAP = {
+    "attn_norm": ("input_layernorm.weight", False, _ONE_PLUS),
+    "mlp_norm": ("post_attention_layernorm.weight", False, _ONE_PLUS),
+    "w_qkvz": ("linear_attn.in_proj_qkvz.weight", True,
+               _group_fold(_qkvz_widths)),
+    "w_ba": ("linear_attn.in_proj_ba.weight", True, _group_fold(
+        lambda c: (c.delta_rule.value_heads // c.delta_rule.key_heads,) * 2)),
+    "conv_qkv": ("linear_attn.conv1d.weight", True),
+    "a_log": ("linear_attn.A_log", False),
+    "dt_bias": ("linear_attn.dt_bias", False),
+    "o_norm": ("linear_attn.norm.weight", False),
+    "w_out": ("linear_attn.out_proj.weight", True),
+    **{k: _LAYER_MAP[k] for k in ("wq", "wk", "wv", "wo")},
+    "q_norm": ("self_attn.q_norm.weight", False, _ONE_PLUS),
+    "k_norm": ("self_attn.k_norm.weight", False, _ONE_PLUS),
+    "router": ("mlp.gate.weight", True),
+    "ws_gate": ("mlp.shared_expert.gate_proj.weight", True),
+    "ws_up": ("mlp.shared_expert.up_proj.weight", True),
+    "ws_down": ("mlp.shared_expert.down_proj.weight", True),
+    "ws_share": ("mlp.shared_expert_gate.weight", True),
 }
 
 
@@ -997,6 +1090,171 @@ SHORT_CONV = Family(
     counts_held_experts=True, expert_periods=False, topk_norm_eps=1e-6)
 
 
+# --- scalar-gated delta-rule layers beside gated attention (Qwen3-Next) -----
+
+_GATED_DELTA_FIELDS = (
+    "layer_types", "linear_num_key_heads", "linear_num_value_heads",
+    "linear_key_head_dim", "linear_value_head_dim", "linear_conv_kernel_dim",
+    "rope_fraction", "attn_gate", "shared_expert_gate")
+# what this family's config.json may ask for that nothing here computes:
+# key -> the only value served
+_GATED_DELTA_FIXED = {
+    "decoder_sparse_step": 1, "mlp_only_layers": [], "rope_scaling": None,
+    "attention_bias": False, "use_sliding_window": False,
+    "norm_topk_prob": True,
+}
+
+
+def _interval_types(layers: int, interval: int) -> list:
+    """Hugging Face's rule where the file gives no ``layer_types``: layer
+    ``i`` attends fully where ``(i + 1) % full_attention_interval == 0``."""
+    return ["full_attention" if (i + 1) % interval == 0
+            else "linear_attention" for i in range(layers)]
+
+
+def _gated_delta_read(d: dict) -> dict:
+    """`LlamaConfig` fields from a "qwen3_next" config.json (its own
+    spelling: ``num_experts``, ``shared_expert_intermediate_size``,
+    ``full_attention_interval``, ``partial_rotary_factor``, the
+    ``linear_*`` keys). Read into the file, by its modules' convention:
+    every layer sparse (``decoder_sparse_step`` 1, no ``mlp_only_layers``:
+    ``intermediate_size`` names no tensor), q and k heads normed, the gate
+    a channel in ``q_proj``, ONE shared expert weighted by a sigmoid gate,
+    softmax scoring over all experts with the chosen shares renormalised.
+    The multi-token-prediction block (``mtp.*``) is never asked for."""
+    name = GATED_DELTA.model_types[0]
+    _only_served(name, d, _GATED_DELTA_FIXED)
+    layers = d["num_hidden_layers"]
+    types = _interval_types(layers, d.get("full_attention_interval", 4))
+    if d.get("layer_types") and _entries(name, d) != types:
+        if "full_attention_interval" in d:
+            raise ValueError(
+                f"{name}: layer_types disagrees with full_attention_interval "
+                f"{d['full_attention_interval']}")
+        types = _entries(name, d)
+    width = d["moe_intermediate_size"]
+    if d.get("shared_expert_intermediate_size", width) != width:
+        raise ValueError(
+            f"{name}: a shared expert of another width "
+            f"({d['shared_expert_intermediate_size']}) than the routed ones "
+            f"({width}) is not wired")
+    held = d["num_experts"]
+    return {
+        "layer_types": tuple(types),
+        "qk_norm": True,
+        "attn_gate": "elementwise",
+        "rope_fraction": float(d.get("partial_rotary_factor", 1.0)),
+        "first_k_dense_replace": 0,
+        "n_routed_experts": held,
+        "n_shared_experts": 1,
+        "shared_expert_gate": True,
+        "scoring_func": "softmax",
+        "router_bias": False,
+        **_expert_share(d, held),
+    }
+
+
+def _gated_delta_write(c, d: dict):
+    d.pop("router_bias")
+    d.pop("attn_gate")
+    d.pop("shared_expert_gate")
+    d.pop("layer_types")
+    interval = c.layer_types.index("full_attention") + 1
+    if list(c.layer_types) == _interval_types(c.num_hidden_layers, interval):
+        d["full_attention_interval"] = interval
+    else:
+        d["layer_types"] = list(c.layer_types)
+    d["partial_rotary_factor"] = d.pop("rope_fraction")
+    d["num_experts"] = d.pop("n_routed_experts")
+    d["shared_expert_intermediate_size"] = c.moe_intermediate_size
+    d.update(_GATED_DELTA_FIXED)
+    for f in ("n_shared_experts", "first_k_dense_replace", "scoring_func",
+              "n_group", "topk_group", "routed_scaling_factor"):
+        d.pop(f)
+
+
+def _gated_delta_check(c):
+    kinds = _check_layer_types(c, GATED_DELTA.layer_mixers)
+    if "linear_attention" not in kinds:
+        raise ValueError(
+            "layer_types without a linear_attention layer is not wired for "
+            f"model_type {GATED_DELTA.model_types[0]!r}")
+    hk, hv, dk, dv, taps = c.delta_rule
+    if not (hk and hv and dk and dv) or hv % hk or taps < 2:
+        raise ValueError(
+            f"linear_num_key_heads {hk} / linear_num_value_heads {hv} / "
+            f"linear_key_head_dim {dk} / linear_value_head_dim {dv} / "
+            f"linear_conv_kernel_dim {taps} is no delta rule of whole "
+            "groups of value heads a key head under a convolution of 2 or "
+            "more taps")
+    rotated = c.head_dim * c.rope_fraction
+    if not 0 < c.rope_fraction <= 1 or rotated != int(rotated) or (
+            int(rotated) % 2):
+        raise ValueError(
+            f"partial_rotary_factor {c.rope_fraction} of head_dim "
+            f"{c.head_dim} is no even number of leading channels to rotate")
+    if (c.attn_gate != "elementwise" or not c.qk_norm
+            or not c.shared_expert_gate or c.n_shared_experts != 1
+            or c.first_k_dense_replace or c.router_bias):
+        raise ValueError(
+            "linear_attention layers are wired beside gated, QK-normed "
+            "attention (attn_gate 'elementwise', qk_norm) and ONE shared "
+            "expert under a sigmoid gate (n_shared_experts 1, "
+            "shared_expert_gate), every layer sparse, no routing bias")
+    if c.kv_lora_rank or c.attn_layer_period or (
+            c.num_local_experts or c.attention_bias
+            or c.sliding_window is not None or c.rope_scaling):
+        raise ValueError(
+            "layer_types with linear_attention layers (a scalar-gated "
+            "delta rule beside full grouped-query attention) is wired with "
+            "the shared-expert feed-forward only: no latent keys, no "
+            "state-space layers, no Mixtral-style experts, no projection "
+            "bias, no sliding_window, no rope_scaling")
+    _check_told_share(c, ("softmax",))
+
+
+def check_gated_keys(c):
+    """What only ``GATED_DELTA`` computes (``LlamaConfig.__post_init__``,
+    every family): a rotation over part of a grouped-query head, a gate a
+    channel between attention and ``wo``, a weighted shared expert."""
+    if c.family is GATED_DELTA:
+        return
+    asked = [f"{key} = {getattr(c, key)!r}" for key, plain in (
+        ("rope_fraction", 1.0), ("shared_expert_gate", False))
+        if getattr(c, key) != plain]
+    if c.attn_gate == "elementwise":
+        asked.append("attn_gate = 'elementwise'")
+    if asked:
+        raise ValueError(
+            f"{', '.join(asked)} is wired for model_type "
+            f"{GATED_DELTA.model_types[0]!r} alone, not beside the layers "
+            f"of model_type {c.model_type!r}")
+
+
+GATED_DELTA = Family(
+    model_types=("qwen3_next",),
+    selects=lambda c: (c.layer_types is not None
+                       and c.model_type in GATED_DELTA.model_types),
+    fields=_GATED_DELTA_FIELDS + _EXPERT_FIELDS,
+    read=_gated_delta_read, write=_gated_delta_write,
+    check=_gated_delta_check,
+    layer_mixers={"linear_attention": "gdn", "full_attention": "gqa"},
+    recurrent_mixer="gdn",
+    tensor_names=_GATED_DELTA_MAP, expert_names=_LATENT_EXPERT_MAP,
+    final_norm_fold=_ONE_PLUS,
+    probe=".linear_attn.in_proj_qkvz.weight",
+    what="a model of gated delta-rule and gated attention layers",
+    shard_axes=frozenset(("ep",)),
+    shard_why=("a recurrent state a value head beside the attention "
+               "layers' rows: heads of a delta-rule mixer under tp, its "
+               "state under stages or sp are not wired"),
+    linear_tiers=(), linear_why=(
+        "its fused projections (q, k, v and the gate in one, b and a in "
+        "one) have no int8 form yet"),
+    cache_tiers=(), cache_why=_REST_IS_SMALL,
+    counts_held_experts=True, expert_periods=False)
+
+
 # --- one set of layers run several times a token (Ouro's keys) --------------
 
 def _looped_read(d: dict) -> dict:
@@ -1078,7 +1336,8 @@ LOOPED = Family(
 # The first record that selects a configuration is its family: the
 # families that read `layer_types` before the ones a single key names, the
 # bare stack last.
-FAMILIES = (LOOPED, SHORT_CONV, WINDOWED, STATE_SPACE, HYBRID, LATENT, GQA)
+FAMILIES = (LOOPED, SHORT_CONV, GATED_DELTA, WINDOWED, STATE_SPACE, HYBRID,
+            LATENT, GQA)
 # every field some family's config.json alone carries
 FIELDS = frozenset(f for family in FAMILIES for f in family.fields)
 # the record that reads a config.json, by its `model_type` (the bare

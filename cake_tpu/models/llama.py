@@ -37,7 +37,7 @@ from cake_tpu.ops.attention import (
     self_attention_block,
     window_attention_block,
 )
-from cake_tpu.ops.kda import kda_attention_block
+from cake_tpu.ops.kda import gdn_attention_block, kda_attention_block
 from cake_tpu.ops.kvcache import KVCache
 from cake_tpu.ops.mamba import mamba_mixer_block
 from cake_tpu.ops.mla import latent_attention_block
@@ -142,6 +142,25 @@ _KDA_SHAPES = {
     "mlp_norm": lambda c: (c.hidden_size,),
 }
 
+# The scalar-gated delta rule (ops/kda.py): ONE projection to ``[q | k | v
+# | z]`` (key heads' q and k, value heads' v and output gate), one to ``[b |
+# a]`` (beta and the decay's input, a value head each), the taps of the one
+# convolution over ``[q | k | v]``, a rate and a bias a value head, the norm
+# a head's output goes through (a plain weight), the output projection.
+_GDN_SHAPES = {
+    "attn_norm": lambda c: (c.hidden_size,),
+    "w_qkvz": lambda c: (c.hidden_size, c.delta_conv_width
+                         + c.delta_rule.value_heads * c.delta_rule.d_v),
+    "w_ba": lambda c: (c.hidden_size, 2 * c.delta_rule.value_heads),
+    "conv_qkv": lambda c: (c.delta_rule.taps, c.delta_conv_width),
+    "a_log": lambda c: (c.delta_rule.value_heads,),
+    "dt_bias": lambda c: (c.delta_rule.value_heads,),
+    "o_norm": lambda c: (c.delta_rule.d_v,),
+    "w_out": lambda c: (c.delta_rule.value_heads * c.delta_rule.d_v,
+                        c.hidden_size),
+    "mlp_norm": lambda c: (c.hidden_size,),
+}
+
 # A selective state-space mixer (ops/mamba.py): the input projection to x
 # and the gate z, the depthwise convolution's taps and bias, the
 # projection to the step's rank and to B and C with their three inner
@@ -203,7 +222,7 @@ class Segment(NamedTuple):
     layers and ``cache_stride`` cached ones on."""
 
     name: str
-    mixer: str  # "mla" | "kda" | "gqa" | "swa" | "mamba" | "conv"
+    mixer: str  # "mla" | "kda" | "gdn" | "gqa" | "swa" | "mamba" | "conv"
     ffn: str  # "dense" | "moe"
     first: int
     count: int
@@ -265,6 +284,11 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
     the attention projections it indexes inside one, PERF.md section 7,
     PR 40.)
 
+    Scalar-gated delta-rule layers beside gated attention
+    (``families.GATED_DELTA``): ``D D D A`` all the way, every layer sparse,
+    is ``D D D`` and ``A`` by turns, each stretch a segment of its own and
+    NO repeated period, for the same reason.
+
     Short convolutions beside attention (``families.SHORT_CONV``): two
     leading dense conv layers, then ``A`` and ``c c c`` by turns with a
     ragged end, each stretch a segment of its own and NO repeated period
@@ -292,13 +316,18 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
     # has left beside the weights, my AOT compiles and chip run, PR 43;
     # 3.95 and 4.22 GiB at 64 experts of 2304 x 896 in two periods of four
     # layers, against 0.05-0.10 GiB for the sorted form's 512- and 1024-row
-    # admissions of the same program, my AOT compiles, PR 55). As the
+    # admissions of the same program, my AOT compiles, PR 55; 4.10 GiB at
+    # 128 held experts of 2048 x 512 in two ``D D D A`` periods of
+    # scalar-gated delta-rule and gated attention layers, a 128-row
+    # admission, against 0.09 GiB a segment a stretch, my AOT compiles, PR
+    # 57). As the
     # operand of a product behind a scan's ``xs``, a segment of one
     # repetition, a stack stays as it lies (PERF.md section 7)
     periods = config.family.expert_periods or not config.n_routed_experts
     names: dict[str, int] = {}
     # layers counted so far in the cache buffers of each mixer's kind
-    cached = {"mla": 0, "kda": 0, "gqa": 0, "swa": 0, "mamba": 0, "conv": 0}
+    cached = {"mla": 0, "kda": 0, "gdn": 0, "gqa": 0, "swa": 0, "mamba": 0,
+              "conv": 0}
 
     def segment(kind, first, count, cache_stride=0):
         mixer, ffn = kind
@@ -346,6 +375,8 @@ def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
     """Per-layer weight name -> shape builder of one segment's kind."""
     if seg.mixer == "kda":
         shapes = dict(_KDA_SHAPES)
+    elif seg.mixer == "gdn":
+        shapes = dict(_GDN_SHAPES)
     elif seg.mixer == "mamba":
         shapes = dict(_MAMBA_SHAPES)
         if not config.mamba_conv_bias:
@@ -357,6 +388,9 @@ def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
             "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm")}
         if config.qk_norm:  # one weight for all heads, each of q and k
             shapes["q_norm"] = shapes["k_norm"] = lambda c: (c.head_dim,)
+        if config.attn_gate == "elementwise":  # a head's [q | gate]
+            shapes["wq"] = lambda c: (
+                c.hidden_size, 2 * c.num_attention_heads * c.head_dim)
         if config.family.loops:  # sandwich norms: each sub-layer's output
             shapes["attn_post_norm"] = shapes["mlp_post_norm"] = (
                 _LAYER_SHAPES["attn_norm"])
@@ -382,6 +416,8 @@ def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
     if not config.n_shared_experts:
         for k in ("ws_gate", "ws_up", "ws_down"):
             del shapes[k]
+    if config.shared_expert_gate:  # the shared expert's own weight a token
+        shapes["ws_share"] = lambda c: (c.hidden_size, 1)
     return shapes
 
 
@@ -458,6 +494,8 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
                          iter(jax.random.split(k, len(shapes))))
             if seg.mixer == "mamba":
                 flat.update(_mamba_init(config, flat, k, dt))
+            if seg.mixer == "gdn":
+                flat.update(_gdn_init(flat, k, dt))
             if config.hc_mult > 1:
                 flat.update(_hc_init(config, flat, k))
             lead = run.layer_ids(seg).shape
@@ -506,6 +544,25 @@ def _hc_init(config: LlamaConfig, stack: dict, key) -> dict:
         out[f"hc_{part}_scale"] = jnp.ones(
             stack[f"hc_{part}_scale"].shape, jnp.float32)
     return out
+
+
+def _gdn_init(stack: dict, key, dt) -> dict:
+    """Gated DeltaNet's own initialisation of what is no linear, for a
+    stack of layers: ``A`` uniform in 1-16 (``A_log`` its logarithm), the
+    decay's bias the inverse softplus of a step log-uniform in 0.001-0.1
+    (so a token decays a state by 0.2-0.999 and a state remembers a few to
+    hundreds of tokens), taps of std 0.5."""
+    k_rate, k_step, k_taps = jax.random.split(key, 3)
+    step = jnp.exp(jax.random.uniform(
+        k_step, stack["dt_bias"].shape, jnp.float32,
+        jnp.log(0.001), jnp.log(0.1)))
+    return {
+        "a_log": jnp.log(jax.random.uniform(
+            k_rate, stack["a_log"].shape, jnp.float32, 1.0, 16.0)).astype(dt),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+        "conv_qkv": (0.5 * jax.random.normal(
+            k_taps, stack["conv_qkv"].shape, jnp.float32)).astype(dt),
+    }
 
 
 def _mamba_init(config: LlamaConfig, stack: dict, key, dt) -> dict:
@@ -790,9 +847,9 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
     (:func:`_sub_layer`). A dense layer (no ``router``) is a SwiGLU of
     ``intermediate_size``; an expert layer is ``shared(h) + sum over the
     chosen experts HELD here of w_e expert_e(h)`` (the shared expert where
-    the layer holds one; the weights sigmoid scores under
-    :class:`GroupRouting`, or softmax shares). Returns ``(x,
-    ExpertCount)``."""
+    the layer holds one, times ``sigmoid(h w_share)`` where the layer holds
+    ``ws_share``; the weights sigmoid scores under :class:`GroupRouting`,
+    or softmax shares). Returns ``(x, ExpertCount)``."""
     def feed(h):
         local = ExpertCount.zeros(h.shape[0])
         if "router" not in layer:
@@ -817,8 +874,14 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
             y, local = y
         if "ws_gate" in layer:  # every rank alike, so added after the psum
             with jax.named_scope("moe.shared"):
-                y = y + swiglu(h, layer["ws_gate"], layer["ws_up"],
-                               layer["ws_down"])
+                shared = swiglu(h, layer["ws_gate"], layer["ws_up"],
+                                layer["ws_down"])
+            if "ws_share" in layer:  # weighted by the token's own gate
+                with jax.named_scope("moe.shared_gate"):
+                    shared = shared * jax.nn.sigmoid(quant.dense(
+                        h, layer["ws_share"]).astype(jnp.float32)
+                    ).astype(shared.dtype)
+            y = y + shared
         return y, local
 
     return _sub_layer(layer, x, "ffn", "mlp_norm", config, feed)
@@ -859,6 +922,14 @@ def _typed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
         x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
                                         ep_size, count_local, expert_idx)
         return x, dataclasses.replace(cache, conv=conv), local
+    if mixer == "gdn":
+        with jax.named_scope("gdn"):
+            out, state, conv = gdn_attention_block(
+                h, layer, cache.state, cache.conv, config, valid=valid,
+                layer_idx=layer_idx)
+        x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
+                                        ep_size, count_local, expert_idx)
+        return x, dataclasses.replace(cache, state=state, conv=conv), local
     if isinstance(cos, dict):  # a rotation a layer kind: this kind's
         cos, sin = cos[mixer], sin[mixer]
     norm = ((layer["q_norm"], layer["k_norm"], config.rms_norm_eps)
@@ -876,7 +947,8 @@ def _typed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
         with jax.named_scope("attn.full"):
             out, k, v = self_attention_block(
                 *args, cache.k, cache.v, cos, sin, pos, *heads,
-                layer=layer_idx, qk_norm=norm)
+                layer=layer_idx, qk_norm=norm,
+                gated=config.attn_gate == "elementwise")
         cache = dataclasses.replace(cache, k=k, v=v)
     x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
                                     ep_size, count_local, expert_idx)
@@ -1032,7 +1104,8 @@ def forward_layers(
     # ``layer_types`` names the mixers, a segment's mixer says which body
     # runs it (one a mixer, so that every segment of a kind traces the same
     # function)
-    bodies = {m: partial(body, mixer=m) for m in ("swa", "gqa", "conv")}
+    bodies = {m: partial(body, mixer=m)
+              for m in ("swa", "gqa", "conv", "gdn")}
 
     def body_of(seg):
         return bodies[seg.mixer] if config.layer_types is not None else body

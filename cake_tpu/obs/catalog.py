@@ -173,9 +173,19 @@ SERIES: dict[str, tuple[str, str]] = {
                  "tails from zero (a fresh staging row, spliced over what "
                  "the slot's last stream left; named scope mixer.conv)"),
     "kda.state_resets": (
-        COUNTER, "admissions that started a slot's delta-rule state from "
-                 "zero (a fresh staging row, spliced over what the slot's "
-                 "last stream left)"),
+        COUNTER, "admissions that started a slot's delta-rule state "
+                 "(either rule of ops/kda.py: KDA's or the scalar-gated "
+                 "one) from zero (a fresh staging row, spliced over what "
+                 "the slot's last stream left)"),
+    "delta.chunks_swept": (
+        COUNTER, "chunks of ops.kda.CHUNK tokens that the delta-rule "
+                 "layers' admission scans ran through: delta-rule layers x "
+                 "a launch's rows x ceil(bucket / 64), a dispatch (the "
+                 "scan is serial, so a bucket's padding costs its chunks)"),
+    "delta.chunks_live": (
+        COUNTER, "of delta.chunks_swept, the chunks that held a true "
+                 "token: the same with each row's own length in place of "
+                 "the bucket's"),
     "ssm.decode_kernel": (
         GAUGE, "what ops.mamba.mamba_mixer_block chose for the last "
                "single-token state-space step it traced (the decode "

@@ -382,15 +382,17 @@ def _attend_xla(
 
 def _project_heads(x, wq, wk, wv, num_heads: int, num_kv_heads: int,
                    bq=None, bk=None, bv=None, qk_norm: tuple | None = None,
-                   rotated: bool = True):
+                   rotated: bool = True, gated: bool = False):
     """``x [B, T, hidden]`` through the q, k and v projections (with their
     biases, where a family has them), as heads ``[B, heads, T, D]``; with
     ``qk_norm`` ``(q weight [D], k weight [D], eps)`` each head of q and k
     RMS-normed (one weight for all heads), which comes before any
     rotation. ``rotated``: the caller rotates q and k behind this (its
-    ``cos`` is not None)."""
+    ``cos`` is not None). ``gated``: ``wq`` gives a head's ``[q | gate]``
+    side by side; a fourth value comes back, the gate ``[B, T, heads *
+    D]`` as the output lies before ``wo`` (None where not gated)."""
     b, t, _ = x.shape
-    d = quant.out_features(wq) // num_heads
+    d = quant.out_features(wq) // num_heads // (2 if gated else 1)
     q = quant.dense(x, wq)
     k = quant.dense(x, wk)
     v = quant.dense(x, wv)
@@ -417,13 +419,17 @@ def _project_heads(x, wq, wk, wv, num_heads: int, num_kv_heads: int,
         # slice in fast memory and the step is 0.4% faster that way than
         # behind a barrier, so those stay fused (PERF.md section 6, PR 41).
         q, k, v = jax.lax.optimization_barrier((q, k, v))
+    gate = None
+    if gated:
+        q, gate = (a.reshape(b, t, num_heads * d) for a in jnp.split(
+            q.reshape(b, t, num_heads, 2 * d), 2, axis=-1))
     q = q.reshape(b, t, num_heads, d).transpose(0, 2, 1, 3)
     k = k.reshape(b, t, num_kv_heads, d).transpose(0, 2, 1, 3)
     v = v.reshape(b, t, num_kv_heads, d).transpose(0, 2, 1, 3)
     if qk_norm is not None:
         q = rms_norm(q, qk_norm[0], qk_norm[2])
         k = rms_norm(k, qk_norm[1], qk_norm[2])
-    return q, k, v
+    return q, k, v, gate
 
 
 def self_attention_block(
@@ -452,6 +458,7 @@ def self_attention_block(
     window: int | None = None,  # sliding-window width (Mistral family)
     layer: jax.Array | None = None,  # index into a stacked [L, ...] cache
     qk_norm: tuple | None = None,  # (q weight [D], k weight [D], eps)
+    gated: bool = False,  # wq gives a head's [q | gate]
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One attention sublayer incl. cache update.
 
@@ -497,11 +504,14 @@ def self_attention_block(
     makes the KV commit predicated so only the active stage's write lands.
 
     ``qk_norm``: an RMSNorm over each head of q and k (one weight for all
-    heads), before the rotation.
+    heads), before the rotation. ``gated``: ``wq`` gives each head a gate a
+    channel beside its query, and the heads' output is multiplied by its
+    sigmoid before ``wo`` (named scope ``attn.gate``).
     """
     b, t, hidden = x.shape
-    q, k, v = _project_heads(x, wq, wk, wv, num_heads, num_kv_heads,
-                             bq, bk, bv, qk_norm, rotated=cos is not None)
+    q, k, v, gate = _project_heads(
+        x, wq, wk, wv, num_heads, num_kv_heads, bq, bk, bv, qk_norm,
+        rotated=cos is not None, gated=gated)
     d = q.shape[-1]
 
     if sp_axis is not None and sp_size > 1:
@@ -605,6 +615,10 @@ def self_attention_block(
                      layer=layer)  # [B,H,T,D]
 
     out = out.transpose(0, 2, 1, 3).reshape(b, t, num_heads * d)
+    if gate is not None:
+        with jax.named_scope("attn.gate"):
+            out = (out * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(
+                out.dtype)
     out = quant.dense(out, wo)
     if tp_axis is not None:
         out = jax.lax.psum(out, tp_axis)
@@ -754,8 +768,8 @@ def window_attention_block(
     bucket's padding never enters a ring)."""
     b, t, _ = x.shape
     rows = ring_k.shape[3]
-    q, k, v = _project_heads(x, wq, wk, wv, num_heads, num_kv_heads,
-                             qk_norm=qk_norm)
+    q, k, v, _ = _project_heads(x, wq, wk, wv, num_heads, num_kv_heads,
+                                qk_norm=qk_norm)
     d = q.shape[-1]
     q = apply_rope(q, cos, sin, pos)
     k = apply_rope(k, cos, sin, pos)

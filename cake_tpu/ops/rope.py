@@ -173,9 +173,19 @@ def apply_rope(
     left de-interleaved (pair ``i`` at ``i`` and ``i + d/2``), as the
     published implementation leaves it: queries and keys get the same
     permutation, so their dot products are those of the interleaved
-    rotation."""
+    rotation.
+
+    Tables narrower than the head (``cos [S, r / 2]`` with ``r < D``: a
+    rotation over PART of a head, ``LlamaConfig.rope_fraction``) rotate
+    its first ``r`` channels, pairs ``(c, c + r / 2)``, and leave the rest
+    as they are."""
     if cos is None:  # no position embedding (rope_tables_for)
         return x
+    if 2 * cos.shape[-1] < x.shape[-1]:
+        r = 2 * cos.shape[-1]
+        return jnp.concatenate(
+            [apply_rope(x[..., :r], cos, sin, pos, interleaved), x[..., r:]],
+            axis=-1)
     b, h, t, d = x.shape
     half = d // 2
     if interleaved:

@@ -111,6 +111,7 @@ from cake_tpu.obs import prof as obs_prof
 from cake_tpu.obs.trace import span
 from cake_tpu.ops import pallas as pk
 from cake_tpu.ops import quant, sampling
+from cake_tpu.ops.kda import CHUNK
 from cake_tpu.ops.moe import form_traced as moe_form_traced
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.parallel.mesh import (
@@ -232,9 +233,17 @@ _MOE_SORTED_ROWS = obs_metrics.counter("moe.sorted_pair_rows")
 _MOE_SORTED_LIVE = obs_metrics.counter("moe.sorted_pair_rows_live")
 # by the mixer whose layers hold the state or the tail
 # (LlamaConfig.layer_kinds)
+# (both rules of ops/kda.py count as one: a delta-rule state)
 _STATE_RESETS = {"kda": obs_metrics.counter("kda.state_resets"),
+                 "gdn": obs_metrics.counter("kda.state_resets"),
                  "mamba": obs_metrics.counter("ssm.state_resets"),
                  "conv": obs_metrics.counter("conv.state_resets")}
+# A delta-rule layer's admission is a serial scan of ops.kda.CHUNK-token
+# chunks: what a launch's rows cost at the bucket's length, and what they
+# would at each row's own (a bucket's padding costs chunks where attention
+# would skip blocks)
+_DELTA_CHUNKS_SWEPT = obs_metrics.counter("delta.chunks_swept")
+_DELTA_CHUNKS_LIVE = obs_metrics.counter("delta.chunks_live")
 _SPEC_NGRAM = 3  # the longest n-gram a batched proposal is looked up by
 # what a cache may hold beside rows (cache_plan's keys), as a refusal says it
 _HELD = {"state": "recurrent state", "conv": "convolution's tail",
@@ -552,6 +561,9 @@ class BatchGenerator:
         # ... and how many planes a layer it counts (a looped model's
         # passes: each reads and reserves a plane of its own)
         self._kv_planes = config.total_ut_steps
+        # the layers whose admission is a scan of chunks (_count_delta_chunks)
+        self._delta_layers = sum(
+            m in ("kda", "gdn") for m, _ in config.layer_kinds)
         # the window layers' rings, if any: (layers, rows R, window)
         self._rings = (
             (config.cache_plan["ring"][0], config.ring_rows,
@@ -2511,6 +2523,8 @@ class BatchGenerator:
             self._n_admit_dispatches += 1
             rows = len(st["rows"])
             self._count_admit_rows(rows * chunk)
+            self._count_delta_chunks(
+                chunk, [len(m.ids) - base - pos for m in st["rows"]])
             st["pos"] = pos + chunk
             if not final:
                 self._admit_dispatched(t0, chunk, base + pos)
@@ -2535,6 +2549,19 @@ class BatchGenerator:
         _MOE_ADMIT_ROWS.inc(rows)
         if moe_form_traced(rows) == "sorted":
             _MOE_ADMIT_SORTED.inc(rows)
+
+    def _count_delta_chunks(self, chunk: int, left: list[int]) -> None:
+        """An admission dispatch of ``chunk`` tokens a row over a model
+        with delta-rule layers: add the chunks their scans sweep (the
+        bucket's, every row) to ``delta.chunks_swept`` and those that hold
+        a true token (``left``: each row's prompt tokens from this
+        dispatch's first on) to ``delta.chunks_live``."""
+        if not self._delta_layers:
+            return
+        _DELTA_CHUNKS_SWEPT.inc(
+            self._delta_layers * len(left) * -(-chunk // CHUNK))
+        _DELTA_CHUNKS_LIVE.inc(self._delta_layers * sum(
+            -(-min(max(n, 0), chunk) // CHUNK) for n in left))
 
     def _admit_dispatched(self, t0: float, chunk: int, pos: int) -> None:
         """Book one admission chunk whose compute has been waited for."""

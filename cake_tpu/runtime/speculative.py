@@ -457,6 +457,15 @@ class SpeculativeMixin:
             raise ValueError("spec_k must be >= 1")
         if self.spec_rounds < 1:
             raise ValueError("spec_rounds must be >= 1")
+        held = sorted(set(self.config.cache_plan) - {"rows"})
+        if held:
+            # the serving engine's rule (runtime/batch_generator.py): a
+            # rejected proposal cannot be undone where it is no row
+            raise ValueError(
+                "speculation is not wired for this model: a rejected "
+                "proposal has already advanced or overwritten what its "
+                f"cache holds beside rows ({', '.join(held)}) and nothing "
+                "restores it; run it with no speculation")
         eos = sorted(self._eos_ids) or [-1]
         self._eos_arr = jnp.asarray(eos, jnp.int32)
         # greedy: exact match accept (bit-identical streams); sampled:

@@ -643,11 +643,15 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
         return scales[name]
 
     def stacked(names_of, lead: tuple[int, ...], shape: tuple, spec: P,
-                transpose: bool, quant: bool, dt=dt):
+                transpose: bool, quant: bool, dt=dt, fold=None):
         """One stacked leaf: ``names_of(i, [e])`` is the stored tensor of
         each leading index; 1-D tensors and plain linears in the serving
         type (``dt``: a wide residual stream's tensors stay float32),
-        quantized linears as (q, scale)."""
+        quantized linears as (q, scale). ``fold``
+        (``models.families.Fold``): the tensor is stored otherwise than
+        the program holds it (a norm as ``w - 1``, a fused projection a key
+        head's group at a time): read whole, folded in float32, then
+        sliced."""
         asked.update(names_of(*at) for at in np.ndindex(*lead))
 
         def gather(index, read):
@@ -657,6 +661,16 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
             return out.reshape(tuple(len(g) for g in grids) + out.shape[1:])
 
         lead_spec = tuple(spec)[:len(lead)]
+        if fold is not None:
+            def whole(n):
+                w = (reader.read1d(n) if len(shape) == 1 else reader.read2d(
+                    n, slice(None), slice(None), transpose))
+                return fold.load(config, w.astype(np.float32))
+
+            return _assemble(
+                lead + shape, mesh, P(*lead_spec, *([None] * len(shape))),
+                lambda ix: gather(ix, lambda n: whole(n)[
+                    tuple(ix[len(lead):])]).astype(dt))
         if len(shape) == 1:
             return _assemble(lead + shape, mesh, P(*lead_spec, None),
                              lambda ix: gather(ix, lambda n: reader.read1d(
@@ -678,13 +692,14 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
     for stack, (ids, plain, experts) in latent_stack_plan(config).items():
         out = {}
         lead = P(STAGE, *([None] * (ids.ndim - 1)))
-        for ours, (suffix, transpose) in plain.items():
+        for ours, (suffix, transpose, *fold) in plain.items():
             out[ours] = stacked(
                 lambda *at, s=suffix, ids=ids: (
                     f"model.layers.{ids[at]}.{s}"),
                 ids.shape, shapes[stack][ours](config), lead, transpose,
                 tier is not None and ours in LATENT_LINEARS,
-                np.dtype(np.float32) if ours in HC_TENSORS else dt)
+                np.dtype(np.float32) if ours in HC_TENSORS else dt,
+                *fold)
         for ours, pattern in experts.items():
             out[ours] = stacked(
                 lambda *at, p=pattern, ids=ids: (
@@ -703,7 +718,7 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
         "embed": stacked(lambda: "model.embed_tokens.weight", (), (v, h),
                          P(), False, False),
         "norm_f": stacked(lambda: config.family.final_norm, (), (h,), P(),
-                          False, False),
+                          False, False, fold=config.family.final_norm_fold),
         "lm_head": stacked(lambda: head_name, (), (h, v), P(), True,
                            tier is not None),
     }

@@ -436,9 +436,16 @@ def latent_hf_tensors(params: dict, config) -> dict[str, np.ndarray]:
     held experts under their global ids: what :func:`save_llama_params`
     writes and the plain references (``cake_tpu/testing/reference_*.py``)
     read. A tied head is not stored."""
+    def stored(w, fold=None):
+        """``w`` as the checkpoint stores it (``families.Fold``)."""
+        if fold is None:
+            return w
+        return fold.save(config, w.astype(np.float32)).astype(w.dtype)
+
     tensors = {
         "model.embed_tokens.weight": np.asarray(params["embed"]),
-        config.family.final_norm: np.asarray(params["norm_f"]),
+        config.family.final_norm: stored(np.asarray(params["norm_f"]),
+                                         config.family.final_norm_fold),
     }
     if not config.tie_word_embeddings:
         tensors["lm_head.weight"] = np.asarray(params["lm_head"]).T
@@ -447,12 +454,12 @@ def latent_hf_tensors(params: dict, config) -> dict[str, np.ndarray]:
             tensors[name] = np.asarray(params[ours][part])
     for stack, (ids, plain, experts) in latent_stack_plan(config).items():
         flat = ids.reshape(-1)
-        for ours, (suffix, transpose) in plain.items():
+        for ours, (suffix, transpose, *fold) in plain.items():
             stacked = np.asarray(params["layers"][stack][ours])
             stacked = stacked.reshape((flat.size,) + stacked.shape[ids.ndim:])
             for i, layer in enumerate(flat):
                 tensors[f"model.layers.{layer}.{suffix}"] = hf_layout(
-                    ours, stacked[i], transpose)
+                    ours, stored(stacked[i], *fold), transpose)
         for ours, pattern in experts.items():
             stacked = np.asarray(params["layers"][stack][ours])
             stacked = stacked.reshape((flat.size,) + stacked.shape[ids.ndim:])

@@ -92,18 +92,21 @@ def _latent_decode(layers, b, s, h, dc=512, dr=64):
                  ((b,), I32), ((), I32)])
 
 
-def _kda_decode(layers, b, h=32, d=128, head_block=8):
+def _kda_decode(layers, b, h=32, d=128, head_block=8, key_heads=None,
+                scalar=False):
     """The delta-rule decode kernel as the layer loop calls it: the
-    stacked float32 state and a traced layer index."""
+    stacked float32 state and a traced layer index. ``key_heads`` under
+    ``h`` value heads and ``scalar`` (a decay a head, in scalar memory):
+    the scalar-gated rule's case."""
     from cake_tpu.ops.pallas import kda_decode
 
     def fn(q, k, v, g, beta, state, layer):
         return kda_decode(q, k, v, g, beta, state, layer,
                           head_block=head_block, interpret=False)
 
-    vec = ((b, h, d), F32)
-    return (fn, [vec, vec, vec, vec, ((b, h), F32),
-                 ((layers, b, h, d, d), F32), ((), I32)])
+    vec, keys = ((b, h, d), F32), ((b, key_heads or h, d), F32)
+    return (fn, [keys, keys, vec, ((b, h), F32) if scalar else vec,
+                 ((b, h), F32), ((layers, b, h, d, d), F32), ((), I32)])
 
 
 def _ssm(layers, b, t, n=16, c=5120):
@@ -244,6 +247,12 @@ KERNELS = {
     "kda_decode_b32_h32": _kda_decode(6, 32),
     "kda_decode_b48_h32": _kda_decode(6, 48),
     "kda_decode_b1_h32": _kda_decode(6, 1),
+    # Qwen3-Next's 16 key heads under 32 value heads of 128 x 128, a decay
+    # a head, at the cell's 32 slots (the kernel's default block) and one
+    "kda_decode_scalar_b32_h16_32": _kda_decode(6, 32, head_block=16,
+                                                key_heads=16, scalar=True),
+    "kda_decode_scalar_b1_h16_32": _kda_decode(6, 1, head_block=16,
+                                               key_heads=16, scalar=True),
     # Jamba2-3B's 5120 channels of a 16-wide state at the cell's 32 slots,
     # at 64, one stream, and an admission chunk of a 512- and a 16-token
     # bucket
@@ -1263,6 +1272,53 @@ def test_two_rotation_programs_band_through_the_kernel_and_fit(topo,
     assert args + temps + large + 0.1 * GIB < 11 / 16 * HBM_GIB["v5 lite"] * GIB
 
 
+def test_gated_delta_programs_fit_and_repeat_no_period(topo, as_on_chip):
+    """The scalar-gated delta-rule + gated attention family at the cell
+    ``qwen3next-ep4-cut.code-mixed``'s sizes: published widths, layers 0-7
+    (``D D D`` and ``A`` by turns: four scanned segments and no repeated
+    period), 128 of 512 experts, a quarter of the vocabulary, 32 slots x
+    8192 rows; the block decode and the 128-row admission. The chip's
+    compiler takes them. RECORDED (my AOT compiles, PR 57): 8.21 GiB of
+    arguments (6.83 of weights + 1.0 of rows + 0.38 of state and tails) and
+    0.006 GiB of temporaries in the step; 0.09 GiB in the 128-row
+    admission, whose expert block takes the dense form (4.10 GiB with ``D D
+    D A`` scanned as a repeated period: the period's gate and up stacks
+    copied transposed, so ``layer_plan`` repeats no period here); 0.02 /
+    0.08 / 0.13 / 0.95 GiB at 256 / 512 / 1024 / 8192 rows. The step's
+    delta-rule layers go through ``kda_decode`` (its scalar case) in place
+    on the carried state and its full layers' 256-wide heads through
+    ``flash_decode``; neither the rows, the state nor the tails are
+    copied."""
+    from cake_tpu.models.config import qwen3next_ep4
+    from cake_tpu.utils.chips import HBM_GIB
+
+    slots, window = 32, 8192
+    cfg = qwen3next_ep4(num_hidden_layers=8, vocab_size=37984,
+                        max_seq_len=window)
+    assert cfg.cache_plan == {"rows": (2, 2, 256, 256),
+                              "state": (6, 32, 128, 128),
+                              "conv": (6, 3, 8192)}
+    decode, admit = _family_programs(topo, cfg, slots, window, 128)
+    for shape in (f"bf16[2,{slots},2,{window},256]",
+                  f"f32[6,{slots},32,128,128]", f"bf16[6,{slots},3,8192]"):
+        assert _cache_sized_moves(decode, shape) == [], shape
+    for compiled in (decode, admit):
+        assert _expert_stack_moves(compiled, "bf16", 128, 2048, 512) == []
+    calls = [line for line in decode.as_text().splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert sum("kda_decode" in c for c in calls) == 2  # one a D D D segment
+    assert sum("flash_decode" in c for c in calls) == 2  # one an A segment
+    for call in calls:
+        if "kda_decode" in call:  # the state it returns is its operand
+            assert "output_to_operand_aliasing={{1}: (6, {})}" in call
+    args, temps = _donated_bytes(decode)
+    assert 8.15 * GIB < args < 8.3 * GIB, args / GIB
+    assert temps < 0.02 * GIB, temps / GIB
+    small = admit.memory_analysis().temp_size_in_bytes
+    assert small < 0.3 * GIB, small / GIB
+    assert args + temps + 1.0 * GIB + 0.4 * GIB < HBM_GIB["v5 lite"] * GIB
+
+
 def _layouts(compiled, shape: str) -> set[str]:
     """Every layout the compiled program gives a value of ``shape``
     (``bf16[4,32,8,2048,64]``): the text between its braces. (What a
@@ -1495,8 +1551,15 @@ PR31_TEXTS = {
     "latent.decode": "1409e4ef740439b3", "latent.admit": "247a7617458f8410",
     # the hybrid's admission, taken on PR 32's tree (commit 9a9bb52): its
     # delta-rule expert segments share ONE scan body, which a body built
-    # anew for each segment would lower once a segment (PR 33 met it)
-    "hybrid.decode": "dd65de518af3a6cb", "hybrid.admit": "75386c5159e2e903",
+    # anew for each segment would lower once a segment (PR 33 met it).
+    # The ADMISSION re-pinned by PR 57, on purpose: ``ops/kda.py``
+    # ``kda_chunk`` is ONE chunk form for this family's decay a channel and
+    # for the scalar-gated rule's decay a head under grouped key heads, so
+    # its operands carry a group axis of one here (``[B, G, R = 1, C, ..]``:
+    # reshapes, the same sums, products and triangular solve; tests/
+    # test_qwen3_next.py ``test_chunk_form_is_the_recurrence[kda]``); the
+    # decode step's text is what it was
+    "hybrid.decode": "dd65de518af3a6cb", "hybrid.admit": "51a58d15fe83b66a",
     # the state-space family, taken on PR 40's tree (commit d2e802e): its
     # attention layers pass through ``_project_heads`` with no norm and no
     # rotation behind the products, where PR 41 puts no barrier (the chip's

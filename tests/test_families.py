@@ -603,7 +603,8 @@ def _family_fixture(name):
     return {"dense": tiny, "sparse": tiny_moe, "latent": config.tiny_mla_moe,
             "hybrid": config.tiny_kda_hybrid, "state_space": config.tiny_jamba,
             "windowed": config.tiny_exaone_moe,
-            "short_conv": config.tiny_lfm2_moe}[name]()
+            "short_conv": config.tiny_lfm2_moe,
+            "gated_delta": config.tiny_qwen3_next}[name]()
 
 
 # What each family is refused and accepted at PR 45's tree (commit
@@ -625,6 +626,9 @@ WIRED_AT_PR45 = {
                  {"int8": False, "int4": False}),
     "short_conv": (("stages", "tp", "sp", "ep"), False, False,
                    {"int8": False, "int4": False}),
+    # (PR 57's family, at the tree that brought it)
+    "gated_delta": (("stages", "tp", "sp"), False, False,
+                    {"int8": False, "int4": False}),
 }
 
 
