@@ -59,13 +59,50 @@ Two forms of the recurrence, chosen at trace time by ``T``:
 - :func:`kda_chunk` (``T > 1``, an admission chunk): chunks of 64 tokens in
   the WY form. With ``G_t`` the log-decay summed from the chunk's start,
   ``u_t = beta_t (v_t - (k_t e^{G_t})^T S_0 - sum_{s<t} A_ts u_s)`` where
-  ``A_ts = sum_c k_tc k_sc e^{G_tc - G_sc}``: one unit-triangular solve a
-  chunk, then ``o_t = (q_t e^{G_t})^T S_0 + sum_{s<=t} A^q_ts u_s`` and
-  ``S_C = e^{G_C} S_0 + sum_s (k_s e^{G_C - G_s}) u_s^T``. Every exponent is
-  a difference ``G_t - G_s`` with ``s <= t``, so nothing overflows however
-  fast the decays are; the form is exact against the recurrence
-  (float32, matmuls at the highest precision), enters through the slot's
-  state and leaves through it, so a chunked admission is exact too.
+  ``A_ts = sum_c k_tc k_sc e^{G_tc - G_sc}``, i.e. ``(I + N) u = beta (v -
+  (k e^G)^T S_0)`` with ``N = beta tril(A, -1)``; then ``o_t = (q_t
+  e^{G_t})^T S_0 + sum_{s<=t} A^q_ts u_s`` and ``S_C = e^{G_C} S_0 + sum_s
+  (k_s e^{G_C - G_s}) u_s^T``. Every exponent is a difference ``G_t - G_s``
+  with ``s <= t``, so nothing overflows however fast the decays are; the
+  form is exact against the recurrence (float32, matmuls at the highest
+  precision), enters through the slot's state and leaves through it, so a
+  chunked admission is exact too.
+
+  **What is made ahead of the scan.** ``N`` is made of the chunk's keys,
+  decays and ``beta`` alone; only the right-hand side reads the carried
+  state. So ``T = (I + N)^-1`` (:func:`_unit_lower_inverse`) and the halves
+  of ``u`` that do not read the state, ``u_hat = T (beta v)`` and ``w = T
+  (beta k e^G)``, are made for ALL chunks of a bucket at once, batched over
+  ``[n, B, G, R]``, and the serial scan over the chunks holds products on
+  the state alone: ``u = u_hat - w S_0``, ``o = (q e^G) S_0 + A^q u``, ``S_C
+  = e^{G_C} S_0 + (k e^{G_C - G})^T u``. Where the decay is a channel's a
+  chunk's ``[C, C, d_k]`` tensor is as large as all of a long bucket's
+  ``[C, C]`` masks together (67 MB at 32 heads of 128): held for ``n``
+  chunks it would not fit, so past ``HOIST_BYTES`` the same two functions
+  run a chunk at a time inside the scan. One algorithm, placed by the
+  shapes a trace sees.
+
+  **How ``T`` is made** (PR 58). Until then a unit-triangular solve a chunk
+  and layer inside the scan: on the chip a custom call of 161 us for 32
+  systems of 64 x 64, the MXU idle under it, 36.5% of the admission
+  programs' time where prompts are long (PERF.md section 6).
+  :func:`_unit_lower_inverse` inverts the diagonal blocks of
+  ``INVERSE_BLOCK`` rows by forward substitution and merges neighbours by
+  products, ``T21 = -(T22 N21 T11)``. The Neumann product ``(I - N)(I +
+  N^2)(I + N^4)..(I + N^32)`` would be products alone and was REFUSED:
+  where keys repeat and the decay is near one (a prompt that repeats a
+  token) ``N``'s powers grow before they vanish and cancel. Worst error
+  against float64 (numpy float32 on the host, the test's and the model's
+  widths, 20 draws a case, some with strongly correlated keys, ``beta``
+  within 1e-3 of 1; ISSUE 58):
+
+  ==========================================  ========  ===================
+  form                                        no decay  random scalar decay
+  ==========================================  ========  ===================
+  forward substitution (what the solve does)  2.2e-6    4.0e-7
+  blocks of 16, then two merges               1.6e-6    4.0e-7
+  the Neumann product ("doubling")            1.4e11    4.0e-7
+  ==========================================  ========  ===================
 
 ``valid [B]``: the true tokens of each row of a bucketed chunk. A padded
 token gets ``beta = 0`` and ``g = 0`` (it neither writes nor decays the
@@ -77,6 +114,8 @@ serial all the same: a bucket's padding costs its chunks
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
@@ -86,6 +125,12 @@ from cake_tpu.ops import quant
 from cake_tpu.ops.norms import rms_norm
 
 CHUNK = 64
+# rows of a diagonal block of a chunk's inverse (tools/kda_sweep --chunk)
+INVERSE_BLOCK = 16
+# What the chunks may hold ahead of the scan, counted on the widest thing
+# each makes: an 8192-row bucket's [C, C] a head at 32 heads; ONE chunk's
+# decay at the same widths where it is a channel's ([C, C, d_k])
+HOIST_BYTES = 1 << 26
 L2_EPS = 1e-6
 
 
@@ -135,6 +180,60 @@ def kda_recurrence(q, k, v, g, beta, state):
     return jnp.moveaxis(o, 0, 1), state
 
 
+@partial(jax.jit, static_argnums=1)
+@jax.default_matmul_precision("highest")
+def _unit_lower_inverse(n_mat, block: int):
+    """``(I + N)^-1`` for ``N [.., C, C]`` strictly lower triangular, by
+    products (module docstring): the diagonal blocks of ``block`` rows by
+    forward substitution, unrolled, every step one multiply-add over all
+    blocks of all matrices at once (the batch lies minor: whole lanes
+    whatever ``block`` is); then ``log2(C / block)`` levels that merge
+    neighbours, ``T21 = -(T22 N21 T11)``. Float32, as exact as the solve
+    it replaced. A ``C`` that is no ``block * 2^m`` is completed by the
+    identity. A function of its own to JAX, and the substitution's step
+    written once: an admission program's layers and a server's buckets
+    trace it once a shape, where sixteen steps of Python a call cost a
+    warm start 2 s a program (PERF.md section 6, PR 58)."""
+    c = n_mat.shape[-1]
+    block = min(block, c)
+    size = block
+    while size < c:
+        size *= 2
+    if size > c:
+        n_mat = jnp.pad(
+            n_mat, ((0, 0),) * (n_mat.ndim - 2) + ((0, size - c),) * 2)
+
+    def blocks(s, below=0):
+        """The ``s``-row blocks on the diagonal (``below`` 1: under it, of
+        every second) ``[.., size / (s << below), s, s]``"""
+        return jnp.stack(
+            [n_mat[..., i + below * s:i + (below + 1) * s, i:i + s]
+             for i in range(0, size, s << below)], axis=-3)
+
+    low = jnp.moveaxis(blocks(block), (-2, -1), (0, 1))  # [i, j, .., nb]
+    eye = jnp.eye(block, dtype=n_mat.dtype).reshape(
+        (block, block) + (1,) * (low.ndim - 2))
+
+    def row(i, t):  # row i of T: e_i - sum_{j<i} N_ij T_j (N_ij = 0, j >= i)
+        return jax.lax.dynamic_update_index_in_dim(
+            t, eye[i] - jnp.sum(low[i][:, None] * t, axis=0), i, 0)
+
+    t = jax.lax.fori_loop(1, block, row, jnp.broadcast_to(eye, low.shape),
+                          unroll=True)
+    t = jnp.moveaxis(t, (0, 1), (-2, -1))  # [.., nb, s, s]
+    s = block
+    while s < size:
+        t11, t22 = t[..., 0::2, :, :], t[..., 1::2, :, :]
+        t21 = -(t22 @ blocks(s, 1) @ t11)
+        t = jnp.concatenate(
+            [jnp.concatenate([t11, jnp.zeros_like(t11)], axis=-1),
+             jnp.concatenate([t21, t22], axis=-1)], axis=-2)
+        s *= 2
+    return t[..., 0, :c, :c]
+
+
+@partial(jax.jit, static_argnames="chunk")
+@jax.default_matmul_precision("highest")
 def kda_chunk(q, k, v, g, beta, state, chunk: int = CHUNK):
     """``T`` tokens in chunks of ``chunk`` (module docstring). ``q, k [B, T,
     Hk, d_k]``, ``v [B, T, Hv, d_v]``, ``g [B, T, Hv, d_k]`` (a decay a
@@ -142,7 +241,9 @@ def kda_chunk(q, k, v, g, beta, state, chunk: int = CHUNK):
     Hv, d_k, d_v]``, all float32. Returns ``(o [B, T, Hv, d_v], state)``.
     Inside, the value heads lie ``[G, R]``: ``G = Hk`` key heads, each
     under its ``R`` value heads, so that what only q and k make (``K K^T``,
-    ``Q K^T`` in the scalar case) is made once a KEY head."""
+    ``Q K^T`` in the scalar case) is made once a KEY head. A function of
+    its own to JAX: a program's stacks of delta-rule layers trace it
+    once."""
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2:]
     r = hv // hk
@@ -165,9 +266,10 @@ def kda_chunk(q, k, v, g, beta, state, chunk: int = CHUNK):
 
     tri = jnp.tril(jnp.ones((c, c), jnp.bool_))
 
-    @jax.default_matmul_precision("highest")
-    def body(s0, xs):
-        qc, kc, vc, gc, bc = xs  # qc, kc [B, G, C, dk]; the rest [B, G, R, C, .]
+    def ahead(xs):
+        """What a chunk makes of its own tokens alone, the state unseen:
+        ``qc, kc [B, G, C, dk]``, the rest ``[B, G, R, C, .]``."""
+        qc, kc, vc, gc, bc = xs
         cum = jnp.cumsum(gc, axis=3)  # G_t, inclusive
         if scalar:
             # e^{G_t - G_s} for s <= t, ONE [C, C] mask a head (exponents
@@ -179,12 +281,6 @@ def kda_chunk(q, k, v, g, beta, state, chunk: int = CHUNK):
             into = jnp.exp(cum)[..., None]  # e^{G_t} [B, G, R, C, 1]
             out = jnp.exp(cum[..., -1:] - cum)[..., None]  # e^{G_C - G_s}
             carried = jnp.exp(cum[..., -1])[..., None, None]
-
-            def from_state(a):  # (a_t e^{G_t})^T S_0
-                return jnp.einsum("bgck,bgrkv->bgrcv", a, s0) * into
-
-            def to_state(u):  # sum_s (k_s e^{G_C - G_s}) u_s^T
-                return jnp.einsum("bgsk,bgrsv->bgrkv", kc, u * out)
         else:
             # ... a channel: a [C, C, dk] tensor a head
             decay = jnp.exp(jnp.where(
@@ -196,26 +292,31 @@ def kda_chunk(q, k, v, g, beta, state, chunk: int = CHUNK):
             into = jnp.exp(cum)  # [B, G, R, C, dk]
             out = jnp.exp(cum[:, :, :, -1:] - cum)
             carried = into[:, :, :, -1, :, None]
+        # u = T (beta (v - (k e^G)^T S_0)), T = (I + beta tril(K K^T, -1))^-1:
+        # the halves of the product on either side of the state
+        inv = _unit_lower_inverse(bc[..., None] * jnp.tril(kk, -1),
+                                  INVERSE_BLOCK)
+        kc = kc[:, :, None]
+        return (qc[:, :, None] * into, kc * out, carried, qk,
+                inv @ (bc[..., None] * vc),
+                inv @ (bc[..., None] * (kc * into)))
 
-            def from_state(a):
-                return jnp.einsum("bgrck,bgrkv->bgrcv",
-                                  a[:, :, None] * into, s0)
+    def advance(s0, xs):
+        q_in, k_out, carried, qk, u_hat, w = xs
+        u = u_hat - w @ s0
+        o = q_in @ s0 + qk @ u
+        return s0 * carried + jnp.einsum("bgrsk,bgrsv->bgrkv", k_out, u), o
 
-            def to_state(u):
-                return jnp.einsum("bgrsk,bgrsv->bgrkv",
-                                  kc[:, :, None] * out, u)
-
-        rhs = bc[..., None] * (vc - from_state(kc))
-        m = jnp.eye(c, dtype=kk.dtype) + bc[..., None] * jnp.tril(kk, -1)
-        u = jax.scipy.linalg.solve_triangular(
-            m, rhs, lower=True, unit_diagonal=True)
-        o = from_state(qc) + jnp.einsum("bgrcs,bgrsv->bgrcv", qk, u)
-        return s0 * carried + to_state(u), o
-
-    state, o = jax.lax.scan(
-        body, state.reshape(b, hk, r, dk, dv),
-        (chunks(q, False), chunks(k, False), chunks(v), chunks(g),
-         chunks(beta)))
+    xs = (chunks(q, False), chunks(k, False), chunks(v), chunks(g),
+          chunks(beta))
+    s0 = state.reshape(b, hk, r, dk, dv)
+    # the widest thing a chunk makes ahead of the state: [C, C] a value
+    # head, times d_k where the decay is a channel's
+    widest = 4 * n * b * hv * c * c * (1 if scalar else dk)
+    if widest <= HOIST_BYTES:  # every chunk at once, off the serial path
+        state, o = jax.lax.scan(advance, s0, jax.vmap(ahead)(xs))
+    else:  # a chunk at a time, where it is used
+        state, o = jax.lax.scan(lambda s, x: advance(s, ahead(x)), s0, xs)
     # [n, B, G, R, C, dv] -> [B, T, Hv, dv]
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 4, 2).reshape(b, n * c, hv, dv)
     return o[:, :t], state.reshape(b, hv, dk, dv)

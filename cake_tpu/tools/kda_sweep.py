@@ -1,4 +1,5 @@
-"""The delta-rule decode step: the Pallas kernel against XLA's fusions.
+"""The delta rule's two forms, timed: the decode step (the Pallas kernel
+against XLA's fusions) and, with ``--chunk``, an admission's chunk form.
 
 Times one decode step of every delta-rule layer of a model (the state
 ``[L, B, H, d, d]`` float32, carried and donated as the layer loop
@@ -7,13 +8,23 @@ carries it; a step is a scan over the ``L`` layers) in the two forms
 shape and around it. What decides whether the kernel stays: 1.10x over
 XLA at the cell's shape (B 32, 6 layers; PERF.md keeps the table).
 
-Usage:  python -m cake_tpu.tools.kda_sweep [--json-out PATH]
+``--chunk`` times :func:`cake_tpu.ops.kda.kda_chunk` a layer (a scan over
+``CHUNK_LAYERS`` layers' states a dispatch, as an admission walks them) at
+the two delta-rule cells' shapes, for each size of the diagonal blocks its
+inverse starts from (``--blocks``; 0 leaves ``ops.kda.INVERSE_BLOCK`` as
+the checkout has it, which is how a tree without the constant is timed):
+where that constant comes from (PERF.md keeps the table).
+
+Usage:  python -m cake_tpu.tools.kda_sweep [--chunk [--blocks 8,16,32]]
+                                           [--json-out PATH]
 (``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
 Prints one JSON line per shape: ``{"batch", "layers", "heads", "d",
 "head_block", "xla_us_per_layer", "kernel_us_per_layer", "speedup",
 "kernel_hbm_share"}`` (the share: one read and one write of the state and
-the step's vectors over 819 GB/s over the kernel's time).
+the step's vectors over 819 GB/s over the kernel's time); with ``--chunk``
+``{"decay", "batch", "tokens", "key_heads", "heads", "d", "block",
+"us_per_layer", "us_per_chunk"}``.
 """
 
 from __future__ import annotations
@@ -27,6 +38,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from cake_tpu.ops import kda
 from cake_tpu.ops.kda import kda_step
 from cake_tpu.ops.pallas.kda import kda_decode, kda_decode_bytes
 from cake_tpu.tools.kernel_check import refuse_offchip_record
@@ -36,6 +48,12 @@ SHAPES = (  # (batch, layers, heads, d): the cell's first
     (48, 6, 16, 128))
 HEAD_BLOCKS = (8, 16, 32)
 STEPS = 8  # a block's steps in one program, as the engine dispatches them
+# (decay, batch, tokens, key heads, value heads, d): qwen3next-ep4-cut's
+# admission buckets, then ling3flash-ep4-cut's one- and two-row launches
+CHUNK_SHAPES = tuple(("scalar", 1, t, 16, 32, 128)
+                     for t in (256, 1024, 4096, 8192)) + tuple(
+    ("channel", b, t, 32, 32, 128) for b in (1, 2) for t in (128, 256, 512))
+CHUNK_LAYERS = 6  # delta-rule layers of either cell
 
 
 def _step_all_layers(form, state, q, k, v, g, beta):
@@ -60,6 +78,18 @@ def _step_all_layers(form, state, q, k, v, g, beta):
     return state, acc
 
 
+def _call_us(fn, state, args, iters: int) -> float:
+    """Microseconds a call of ``fn(state, *args) -> (state, acc)`` once it
+    has compiled, the state donated from call to call."""
+    state, acc = fn(state, *args)  # compile
+    jax.block_until_ready(acc)
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        state, acc = fn(state, *args)
+    jax.block_until_ready((state, acc))
+    return (time.perf_counter() - t0) * 1e6 / iters
+
+
 def _time_us(form, b, n_layers, h, d, iters: int = 10) -> float:
     """Microseconds a layer and step, the state donated between calls."""
     keys = jax.random.split(jax.random.PRNGKey(0), 6)
@@ -69,13 +99,7 @@ def _time_us(form, b, n_layers, h, d, iters: int = 10) -> float:
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, h)))
     state = jax.random.normal(keys[5], (n_layers, b, h, d, d), jnp.float32)
     fn = jax.jit(partial(_step_all_layers, form), donate_argnums=(0,))
-    state, acc = fn(state, q, k, v, g, beta)  # compile
-    jax.block_until_ready(acc)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        state, acc = fn(state, q, k, v, g, beta)
-    jax.block_until_ready((state, acc))
-    return (time.perf_counter() - t0) * 1e6 / (iters * STEPS * n_layers)
+    return _call_us(fn, state, (q, k, v, g, beta), iters) / (STEPS * n_layers)
 
 
 def rows():
@@ -93,16 +117,59 @@ def rows():
                    "kernel_hbm_share": round(100 * floor_us / kernel, 1)}
 
 
+def _chunk_us(decay, b, t, hk, hv, d, iters: int = 5) -> float:
+    """Microseconds a layer of ``kda_chunk`` over ``t`` tokens, the layers'
+    states carried and donated as an admission carries them."""
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    q, k = (kda._l2norm(jax.random.normal(kk, (b, t, hk, d), jnp.float32))
+            * scale for kk, scale in zip(keys[:2], (d ** -0.5, 1.0)))
+    v = jax.random.normal(keys[2], (b, t, hv, d), jnp.float32)
+    g = -jax.nn.softplus(jax.random.normal(
+        keys[3], (b, t, hv) + ((d,) if decay == "channel" else ())) - 2.0)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, t, hv)))
+    state = jax.random.normal(keys[5], (CHUNK_LAYERS, b, hv, d, d),
+                              jnp.float32)
+
+    def layers(state, q, k, v, g, beta):
+        def layer(acc, s0):
+            o, s1 = kda.kda_chunk(q, k, v, g, beta, s0)
+            return acc + o, s1
+
+        acc, state = jax.lax.scan(layer, jnp.zeros_like(v), state)
+        return state, acc
+
+    fn = jax.jit(layers, donate_argnums=(0,))
+    return _call_us(fn, state, (q, k, v, g, beta), iters) / CHUNK_LAYERS
+
+
+def chunk_rows(blocks):
+    for block in blocks:
+        if block:
+            kda.INVERSE_BLOCK = block  # read when ``kda_chunk`` is traced,
+            jax.clear_caches()  # which a trace kept for these shapes is not
+        for decay, b, t, hk, hv, d in CHUNK_SHAPES:
+            us = _chunk_us(decay, b, t, hk, hv, d)
+            yield {"decay": decay, "batch": b, "tokens": t, "key_heads": hk,
+                   "heads": hv, "d": d, "block": block or None,
+                   "us_per_layer": round(us, 1),
+                   "us_per_chunk": round(us / -(-t // kda.CHUNK), 2)}
+
+
 def main() -> int:
     from cake_tpu.utils.compile_cache import configure
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--chunk", action="store_true",
+                    help="time the admission's chunk form, not the step")
+    ap.add_argument("--blocks", default="8,16,32",
+                    help="--chunk: diagonal block sizes (0: the checkout's)")
     ap.add_argument("--json-out")
     a = ap.parse_args()
     configure()
     refuse_offchip_record(a.json_out)
     out = []
-    for row in rows():
+    for row in (chunk_rows([int(x) for x in a.blocks.split(",")])
+                if a.chunk else rows()):
         print(json.dumps(row), flush=True)
         out.append(row)
     if a.json_out:

@@ -1044,7 +1044,14 @@ def test_hybrid_programs_move_no_cache_no_state_and_no_expert_stack(
     GiB of temporaries: the cell fits the chip with the admission's
     staging row and a second cache while the splice is undonated (and
     would at 48 slots: 10.54 + 0.17 GiB; the slots are 32 for the spread
-    of TTFT between seeds, not for memory)."""
+    of TTFT between seeds, not for memory). The admission's chunk form
+    holds no triangular solve (PR 58: the unit-triangular block's inverse
+    is ``ops/kda.py`` ``_unit_lower_inverse``, products; XLA's solve was
+    the custom call ``InvertDiagBlocksLowerTriangular``, 161 us a chunk
+    and layer), and the channel case makes the inverse inside the scan, a
+    chunk at a time (a ``[C, C, d_k]`` decay is 67 MB a chunk here).
+    RECORDED (my AOT compiles, PR 58): the 512-row admission, the cell's
+    largest bucket, 0.3100 GiB of temporaries (0.3095 with the solve)."""
     from cake_tpu.utils.chips import HBM_GIB
 
     layers, slots, window = 7, 32, 4096
@@ -1084,8 +1091,9 @@ def test_hybrid_programs_move_no_cache_no_state_and_no_expert_stack(
     assert 10.15 * GIB < args < 10.4 * GIB, args / GIB  # 9.75 + 0.53
     assert temps < 0.3 * GIB, temps / GIB
     assert args + temps + 0.55 * GIB + 0.5 * GIB < HBM_GIB["v5 lite"] * GIB
+    assert "triangular" not in admit.as_text().lower()
     m = admit.memory_analysis()
-    assert m.temp_size_in_bytes < 0.5 * GIB
+    assert m.temp_size_in_bytes < 0.35 * GIB, m.temp_size_in_bytes / GIB
 
 
 def test_state_space_programs_move_no_cache_and_no_state(topo, as_on_chip):
@@ -1288,7 +1296,13 @@ def test_gated_delta_programs_fit_and_repeat_no_period(topo, as_on_chip):
     delta-rule layers go through ``kda_decode`` (its scalar case) in place
     on the carried state and its full layers' 256-wide heads through
     ``flash_decode``; neither the rows, the state nor the tails are
-    copied."""
+    copied. Neither admission holds a triangular solve (PR 58: XLA's was
+    the custom call ``InvertDiagBlocksLowerTriangular``, one a 64-token
+    chunk and layer inside the scan; ``ops/kda.py``
+    ``_unit_lower_inverse`` makes every chunk's inverse by products ahead
+    of it). RECORDED (my AOT compiles, PR 58): what the 8192-row bucket's
+    128 chunks hold ahead of the scan lifts its temporaries from 0.947 to
+    1.403 GiB (0.091 -> 0.068 at 128 rows, 0.132 -> 0.120 at 1024)."""
     from cake_tpu.models.config import qwen3next_ep4
     from cake_tpu.utils.chips import HBM_GIB
 
@@ -1298,7 +1312,8 @@ def test_gated_delta_programs_fit_and_repeat_no_period(topo, as_on_chip):
     assert cfg.cache_plan == {"rows": (2, 2, 256, 256),
                               "state": (6, 32, 128, 128),
                               "conv": (6, 3, 8192)}
-    decode, admit = _family_programs(topo, cfg, slots, window, 128)
+    decode, admit, widest = _family_programs(topo, cfg, slots, window, 128,
+                                             8192)
     for shape in (f"bf16[2,{slots},2,{window},256]",
                   f"f32[6,{slots},32,128,128]", f"bf16[6,{slots},3,8192]"):
         assert _cache_sized_moves(decode, shape) == [], shape
@@ -1314,9 +1329,13 @@ def test_gated_delta_programs_fit_and_repeat_no_period(topo, as_on_chip):
     args, temps = _donated_bytes(decode)
     assert 8.15 * GIB < args < 8.3 * GIB, args / GIB
     assert temps < 0.02 * GIB, temps / GIB
-    small = admit.memory_analysis().temp_size_in_bytes
+    for compiled in (admit, widest):
+        assert "triangular" not in compiled.as_text().lower()
+    small, large = (a.memory_analysis().temp_size_in_bytes
+                    for a in (admit, widest))
     assert small < 0.3 * GIB, small / GIB
-    assert args + temps + 1.0 * GIB + 0.4 * GIB < HBM_GIB["v5 lite"] * GIB
+    assert large < 1.5 * GIB, large / GIB
+    assert args + temps + large + 0.4 * GIB < HBM_GIB["v5 lite"] * GIB
 
 
 def _layouts(compiled, shape: str) -> set[str]:
@@ -1557,9 +1576,15 @@ PR31_TEXTS = {
     # for the scalar-gated rule's decay a head under grouped key heads, so
     # its operands carry a group axis of one here (``[B, G, R = 1, C, ..]``:
     # reshapes, the same sums, products and triangular solve; tests/
-    # test_qwen3_next.py ``test_chunk_form_is_the_recurrence[kda]``); the
+    # test_qwen3_next.py ``test_chunk_form_is_the_recurrence[kda]``), and
+    # again by PR 58, on purpose: the triangular solve is gone from
+    # ``kda_chunk`` (the unit-triangular block's inverse by products,
+    # ``_unit_lower_inverse``, and the halves of the chunk's update that do
+    # not read the state made ahead of the scan over the chunks where they
+    # fit, as they do at this fixture's widths; the same tests, and
+    # tests/test_kda_hybrid.py ``test_kda_chunk_is_the_recurrence``); the
     # decode step's text is what it was
-    "hybrid.decode": "dd65de518af3a6cb", "hybrid.admit": "51a58d15fe83b66a",
+    "hybrid.decode": "dd65de518af3a6cb", "hybrid.admit": "0754319899298397",
     # the state-space family, taken on PR 40's tree (commit d2e802e): its
     # attention layers pass through ``_project_heads`` with no norm and no
     # rotation behind the products, where PR 41 puts no barrier (the chip's

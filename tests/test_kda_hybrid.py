@@ -161,31 +161,53 @@ def test_state_in_bfloat16_fails_the_tolerance(tensors, want):
     assert np.abs(low - want).max() > 30 * TIGHT
 
 
-@pytest.mark.parametrize("case", ["random", "decay-floor", "no-decay"])
+@pytest.mark.parametrize("case", ["random", "decay-floor", "no-decay",
+                                  "repeated-keys", "padded-tail"])
 def test_kda_chunk_is_the_recurrence(case):
     """The WY form against the recurrence it rewrites, over 150 tokens
     (chunk boundaries inside) from a nonzero state: at random gates, at
     ``g`` within 1e-3 of the lower bound -5 with ``beta`` within 1e-3 of 1
-    (64 steps of decay are e^-320: nothing may be divided by that), and
-    with no decay at all."""
+    (64 steps of decay are e^-320: nothing may be divided by that), with
+    no decay at all, with no decay and ONE key a head at every token
+    (``beta tril(K K^T, -1)`` all ones under the diagonal: its powers grow
+    before they vanish, and an inverse summed from them cancels to
+    nothing), and over 192 tokens (three whole chunks) of which the rows
+    hold 130 and 64 true ones (``_advance`` with ``valid``: padded tokens
+    write nothing and decay nothing)."""
     rs = np.random.default_rng(0)
-    b, t, h, d = 2, 150, 3, 16
+    b, t, h, d = 2, 192 if case == "padded-tail" else 150, 3, 16
 
     def unit(x):
         return x / np.sqrt((x * x).sum(-1, keepdims=True) + 1e-6)
 
     q = unit(rs.normal(size=(b, t, h, d))) * d ** -0.5
     k, v = unit(rs.normal(size=(b, t, h, d))), rs.normal(size=(b, t, h, d))
+    if case == "repeated-keys":
+        k = np.broadcast_to(k[:, :1], k.shape)
+    random = (-5 / (1 + np.exp(-rs.normal(size=(b, t, h, d)))),
+              1 / (1 + np.exp(-rs.normal(size=(b, t, h)))))
+    no_decay = (-1e-3 * rs.random((b, t, h, d)),
+                1 - 1e-3 * rs.random((b, t, h)))
     g, beta = {
-        "random": (-5 / (1 + np.exp(-rs.normal(size=(b, t, h, d)))),
-                   1 / (1 + np.exp(-rs.normal(size=(b, t, h))))),
+        "random": random, "padded-tail": random,
         "decay-floor": (-5 + 1e-3 * rs.random((b, t, h, d)),
                         1 - 1e-3 * rs.random((b, t, h))),
-        "no-decay": (-1e-3 * rs.random((b, t, h, d)),
-                     1 - 1e-3 * rs.random((b, t, h))),
+        "no-decay": no_decay, "repeated-keys": no_decay,
     }[case]
     args = [jnp.asarray(a, jnp.float32)
             for a in (q, k, v, g, beta, rs.normal(size=(b, h, d, d)))]
+    if case == "padded-tail":
+        valid = np.array([130, 64], np.int32)
+        o_got, s_got = kda._advance(*args, jnp.asarray(valid), None, "kda")
+        for row, n in enumerate(valid):
+            o_want, s_want = kda.kda_recurrence(
+                *(a[row:row + 1, :n] for a in args[:5]),
+                args[5][row:row + 1])
+            np.testing.assert_allclose(o_got[row:row + 1, :n], o_want,
+                                       atol=1e-5, rtol=0)
+            np.testing.assert_allclose(s_got[row:row + 1], s_want,
+                                       atol=1e-5, rtol=0)
+        return
     o_want, s_want = kda.kda_recurrence(*args)
     o_got, s_got = kda.kda_chunk(*args)
     assert np.isfinite(np.asarray(o_got)).all()
