@@ -17,6 +17,7 @@ native wire library's content stamp, and the seeded checkpoint writer.
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 import tempfile
@@ -76,6 +77,7 @@ def runs():
         if proc.poll() is None:  # its tests never ran
             proc.terminate()  # chip_smoke.py stops its children on SIGTERM
             proc.wait()
+    shutil.rmtree(given, ignore_errors=True)
 
 
 def _phases(rows, phase):

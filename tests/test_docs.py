@@ -6,7 +6,11 @@ second, stale account of the system. Every backticked name that ends in
 `.py`, `.json`, `.jsonl` or `.md` must resolve: as a path from the root,
 from `cake_tpu/` or from `benchmark/`, or, for a bare basename, as the
 basename of some tracked file. A name is exempt only in a paragraph or
-list item that itself says it was deleted."""
+list item that itself says it was deleted.
+
+`PERF.md` is read whole by every session before it plans or builds, so its
+size is held too, in bytes and in the length of a line: a limit on the
+number of lines alone was met for twenty PRs by not breaking them."""
 
 import functools
 import os
@@ -108,3 +112,24 @@ def test_document_names_files_that_exist(doc):
                     for name in _names(line)
                     if not _resolves(name, basenames)]
     assert not missing, "\n".join(missing)
+
+
+# PERF.md's room: the ledger keeps the numbers and CHANGES.md each PR's
+# full account; what outgrows this is merged into what it taught
+_PERF_MAX_BYTES = 100_000
+_PERF_MAX_LINE = 2_000
+
+
+def test_perf_md_is_under_its_size():
+    size = len((_ROOT / "PERF.md").read_bytes())
+    assert size < _PERF_MAX_BYTES, (
+        f"PERF.md is {size} bytes: merge its oldest findings into what "
+        f"they taught (under {_PERF_MAX_BYTES})")
+
+
+def test_perf_md_has_no_line_over_its_length():
+    long = [f"PERF.md:{n}: {len(line)} characters"
+            for n, line in enumerate(
+                (_ROOT / "PERF.md").read_text().splitlines(), 1)
+            if len(line) > _PERF_MAX_LINE]
+    assert not long, "\n".join(long)

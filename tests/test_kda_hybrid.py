@@ -19,13 +19,15 @@ one logit of 38,400 over 150 tokens. ``TIGHT`` is 1e-3, three times the
 worst, and thirty times under what computing in bfloat16 costs (checked
 below), so a lowered precision fails. The recurrence itself is held to
 1e-5 (``test_kda_chunk_is_the_recurrence``).
+
+The engine's section is ``tests/test_kda_hybrid_engine.py`` since PR 59;
+what the two files share is ``tests/kda_hybrid_kit.py``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import json
-import zlib
 
 import jax
 import jax.numpy as jnp
@@ -33,92 +35,22 @@ import numpy as np
 import pytest
 
 from cake_tpu.models import llama
-from cake_tpu.models.config import (LlamaConfig, ling3flash_ep4,
-                                    tiny_kda_hybrid, tiny_mla_moe)
-from cake_tpu.obs import metrics
+from cake_tpu.models.config import (
+    LlamaConfig, ling3flash_ep4, tiny_kda_hybrid, tiny_mla_moe,
+)
 from cake_tpu.ops import kda, moe
 from cake_tpu.ops.kvcache import init_cache
-from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.rope import rope_tables_for
-from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.parallel.mesh import validate_shardable
 from cake_tpu.testing import reference_kda_mla_moe as ref
-from cake_tpu.utils.weights import (latent_hf_tensors, load_llama_params,
-                                    save_llama_params)
+from cake_tpu.utils.weights import (
+    latent_hf_tensors, load_llama_params, save_llama_params,
+)
 
-TIGHT = 1e-3
-CFG = tiny_kda_hybrid(max_seq_len=256, eos_token_id=-1)
-TOKENS = np.array([3, 5, 7, 9, 11, 200, 100, 50, 25, 12, 6, 1, 99, 42, 17, 8,
-                   33, 64, 128, 255, 2, 4, 77, 31], np.int32)
-GREEDY = dict(temperature=0.0, repeat_penalty=1.0)
-
-
-def _params(cfg=CFG, seed=0):
-    """Seeded weights whose norm scales are not all ones, whose decay
-    rates differ by head and whose routing bias is large enough to change
-    choices: what is applied twice, not at all or to the wrong thing
-    shows."""
-    params = llama.init_params(cfg, jax.random.PRNGKey(seed))
-    key = jax.random.PRNGKey(seed + 1)
-
-    def jitter(path, leaf):
-        name = path[-1].key
-        k = jax.random.fold_in(  # (crc32: str hashes differ by process)
-            key, zlib.crc32(jax.tree_util.keystr(path).encode()) % 2**31)
-        if name.endswith("norm") or name == "norm_f":
-            return leaf * (1.0 + 0.25 * jax.random.uniform(
-                k, leaf.shape, minval=-1.0))
-        if name == "b_router":
-            return 0.3 * jax.random.normal(k, leaf.shape)
-        if name in ("a_log", "dt_bias"):
-            return jax.random.normal(k, leaf.shape)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(jitter, params)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return _params()
-
-
-@pytest.fixture(scope="module")
-def tensors(params):
-    return latent_hf_tensors(params, CFG)
-
-
-@pytest.fixture(scope="module")
-def want(tensors):
-    """The reference's logits at every position of TOKENS."""
-    return np.asarray(ref.logits(CFG.to_hf_dict(), tensors, TOKENS))
-
-
-def _decode_all(params, cfg, tokens, prefill: int, chunk: int | None = None):
-    """Logits at positions ``prefill - 1 ..`` through the cache: a prefill
-    of ``prefill`` tokens (in chunks of ``chunk``), then one step a token."""
-    cache = init_cache(cfg, batch=1, max_seq=64)
-    step = jax.jit(lambda p, t, c, pos: llama.forward(p, t, c, pos, cfg))
-    chunk = chunk or prefill
-    for lo in range(0, prefill, chunk):
-        logits, cache = step(params, jnp.asarray(tokens[None, lo:lo + chunk]),
-                             cache, lo)
-    out = [logits[0]]
-    for i in range(prefill, len(tokens)):
-        logits, cache = step(params, jnp.asarray(tokens[None, i:i + 1]),
-                             cache, i)
-        out.append(logits[0])
-    return np.stack(out), cache
-
-
-def _all_logits(params, cfg, tokens, max_seq=256, valid=None):
-    """Logits at every position of one prefill, and the cache it leaves."""
-    cos, sin = rope_tables_for(cfg, max_seq)
-    x = llama.embed_tokens(params, jnp.asarray(tokens)[None], cfg)
-    x, cache = llama.forward_layers(
-        params["layers"], x, init_cache(cfg, 1, max_seq), cos, sin, 0, cfg,
-        valid=valid)
-    x = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
-    return np.asarray(x[0] @ params["lm_head"]), cache
+from kda_hybrid_kit import (  # noqa: F401
+    CFG, TIGHT, TOKENS, _all_logits, _decode_all, _engine, _params, params,
+    tensors, want,
+)
 
 
 # -- against the reference -------------------------------------------------------
@@ -305,136 +237,6 @@ def test_hbm_budget_counts_state_and_rows_of_the_preset():
     half = hbm_budget(cfg, batch=32, max_seq=4096, ep=2)
     experts = 6 * 128 * 3 * 2560 * 768 * 2
     assert b["layers"] - half["layers"] == experts // 2
-
-
-# -- the engine --------------------------------------------------------------------
-
-def _engine(params, prompts, ids=None, cfg=CFG, **kw):
-    from cake_tpu.runtime.batch_generator import BatchGenerator
-
-    kw.setdefault("block_size", 4)
-    bg = BatchGenerator(cfg, params, settings=SamplerSettings(**GREEDY),
-                        max_seq=64, **kw)
-    bg.set_prompts(prompts, stream_ids=ids)
-    return bg
-
-
-def _run(bg, events=(), steps=40):
-    """Step the engine; ``events``: ``{step: callable(bg)}``. Returns every
-    stream's generated ids by stream id."""
-    events = dict(events)
-    out: dict[int, list[int]] = {}
-    for i in range(steps):
-        if i in events:
-            events[i](bg)
-        bg.step()
-        for s in bg.streams:
-            if s.active and s.stream_id >= 0:
-                out[s.stream_id] = list(s.generated)
-    return out
-
-
-def _alone(params, prompt, n, **kw):
-    bg = _engine(params, [prompt], **kw)
-    return bg.generate(n)[0]
-
-
-PROMPTS = [[5, 9, 2, 11], [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5], [7, 7, 2],
-           [8, 6, 7, 5, 3, 0, 9]]
-
-
-def test_batch_generator_streams_match_reference(params, tensors):
-    """Four streams of different lengths through BatchGenerator (a bucketed
-    batch prefill whose padding may not touch a state, per-row positions,
-    block decode): each stream's greedy tokens are the reference's own
-    greedy continuation, by its logits' argmax with a margin check."""
-    bg = _engine(params, PROMPTS)
-    outs = bg.generate(9)
-    for prompt, out in zip(PROMPTS, outs):
-        full = np.array(prompt + list(out))
-        logits = np.asarray(ref.logits(CFG.to_hf_dict(), tensors, full))
-        for j, tok in enumerate(out):
-            at = logits[len(prompt) - 1 + j]
-            assert at.max() - at[tok] <= TIGHT, (prompt, j)
-    assert bg.stats()["tokens_emitted"] == 4 * 9
-
-
-@pytest.mark.parametrize("admit_chunk", [None, 4],
-                         ids=["one-chunk", "chunks-of-4"])
-def test_slot_reuse_starts_from_a_fresh_state(params, admit_chunk):
-    """SLOT REUSE: a short stream admitted into the slot a long one left
-    gives the tokens a fresh engine gives it (the slot's state and tail
-    have no frontier that would hide the old stream's), whether its
-    admission is one chunk or chunks of 4 that carry state and tail
-    between them; ``kda.state_resets`` counts the admission."""
-    long, short = PROMPTS[1] * 3, [4, 8, 15, 16, 23, 42, 10]
-    resets = metrics.registry().counter("kda.state_resets")
-    before = resets.value
-    bg = _engine(params, [long, PROMPTS[0]], ids=[1, 2],
-                 admit_chunk=admit_chunk)
-    got = _run(bg, {6: lambda e: (e.finish(1), e.enqueue(short, 3))},
-               steps=30)
-    assert resets.value - before == 1
-    assert len(got[3]) >= 8
-    assert got[3][:8] == _alone(params, short, 8)
-    # the neighbour never noticed
-    assert got[2][:12] == _alone(params, PROMPTS[0], 12)
-
-
-def test_four_streams_with_admissions_mid_flight_equal_each_alone(params):
-    events = {
-        3: lambda e: e.enqueue(PROMPTS[2], 12),
-        5: lambda e: e.finish(10),
-        9: lambda e: (e.finish(11), e.enqueue(PROMPTS[3], 13)),
-    }
-    bg = _engine(params, PROMPTS[:2], ids=[10, 11], admit_chunk=4)
-    got = _run(bg, events, steps=36)
-    for sid, prompt in ((12, PROMPTS[2]), (13, PROMPTS[3])):
-        assert len(got[sid]) >= 8
-        assert got[sid][:8] == _alone(params, prompt, 8), sid
-    assert got[10] == _alone(params, PROMPTS[0], 9)[:len(got[10])]
-    assert got[11] == _alone(params, PROMPTS[1], 24)[:len(got[11])]
-
-
-def test_state_gauges_and_moe_counters(params):
-    """The new family's counters go through the same path as the latent
-    family's: pairs of live rows only, and the cache's gauges read off the
-    allocated buffers (rows over the latent layer alone)."""
-    reg = metrics.registry()
-    names = ("moe.local_pairs", "moe.routed_pairs", "moe.decode_steps")
-    cfg = dataclasses.replace(CFG, n_routed_experts=4, router_experts=16,
-                              first_expert=4)
-    p = dict(params, layers={
-        name: {k: (v[:, 4:8] if k in ("w_gate", "w_up", "w_down")
-                   and "router" in stack else v) for k, v in stack.items()}
-        for name, stack in params["layers"].items()})
-    bg = _engine(p, [[5, 9, 2], [3, 1, 4, 1]], cfg=cfg)
-    before = {n: reg.counter(n).value for n in names}
-    bg.generate(9)
-    bg.drain()
-    got = {n: reg.counter(n).value - before[n] for n in names}
-    steps = got["moe.decode_steps"]
-    assert steps >= 8
-    assert got["moe.routed_pairs"] == steps * 2 * 4 * 3  # rows x k x layers
-    assert 0 < got["moe.local_pairs"] < got["moe.routed_pairs"]
-    h, d = cfg.num_attention_heads, cfg.head_dim
-    per_stream = 3 * (h * d * d * 4 + 3 * 3 * h * d * 4)
-    assert reg.gauge("cache.state_bytes_per_stream").value == per_stream
-    assert reg.gauge("cache.state_bytes").value == 2 * per_stream
-    assert reg.gauge("cache.row_bytes").value == 4 * (16 + 8)
-    assert reg.gauge("cache.bytes").value == (
-        2 * per_stream + 1 * 2 * 64 * 4 * (16 + 8))
-
-
-def test_ep_axis_splits_the_told_share(params):
-    """Under a real ep axis the same entry point takes the split from the
-    axis: the mesh stream is the single-device stream."""
-    prompts = [[5, 9, 2, 11], [3, 1, 4, 1, 5]]
-    outs = []
-    for ep in (1, 2):
-        bg = _engine(params, prompts, block_size=2, ep=ep)
-        outs.append(bg.generate(6))
-    assert outs[0] == outs[1]
 
 
 # -- the expert layer ----------------------------------------------------------

@@ -14,6 +14,10 @@ sizes, CPU, float32.
 - the configuration (the catalog's keys, the round trip, every refusal),
   the loader (float32 ``hc`` tensors, a written prediction block skipped
   and counted), the engine's gauges, the benchmark's copy of the reference.
+
+Sections (c), the engine, and the latent family's extras are
+``tests/test_xing4_engine.py`` and ``tests/test_xing4_latent.py`` since
+PR 59; what the three share is ``tests/xing4_kit.py``.
 """
 
 from __future__ import annotations
@@ -21,10 +25,7 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
-import os
 import sys
-import zlib
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -32,91 +33,20 @@ import numpy as np
 import pytest
 
 from cake_tpu.models import families, llama
-from cake_tpu.models.config import (LlamaConfig, tiny, tiny_jamba,
-                                    tiny_kda_hybrid, tiny_xing4, xing4_29b)
+from cake_tpu.models.config import (
+    LlamaConfig, tiny, tiny_jamba, tiny_kda_hybrid, tiny_xing4, xing4_29b,
+)
 from cake_tpu.obs import metrics
 from cake_tpu.ops import hyper
 from cake_tpu.ops.kvcache import init_cache
-from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.parallel.mesh import validate_shardable
 from cake_tpu.testing import reference_mhc_mla_moe as ref
-from cake_tpu.utils.weights import (latent_hf_tensors, load_llama_params,
-                                    save_llama_params)
+from cake_tpu.utils.weights import load_llama_params, save_llama_params
 
-TIGHT = 1e-4
-# three rounds here (XLA's CPU backend takes 36 s to compile ONE program
-# of two stacks at 20 rounds, 2 s at 3; the reference takes the rounds
-# from the file too); the 20 published rounds in the tests of ops/hyper.py
-CFG = tiny_xing4(max_seq_len=256, eos_token_id=-1, hc_sinkhorn_iters=3)
-CFG20 = dataclasses.replace(CFG, hc_sinkhorn_iters=20)
-TOKENS = np.random.default_rng(51).integers(3, 250, 48).astype(np.int32)
-GREEDY = dict(temperature=0.0, repeat_penalty=1.0)
-ROOT = Path(__file__).resolve().parent.parent
-
-
-def _params(cfg=CFG, seed=0):
-    """Seeded weights whose norm scales are not all ones (a norm applied
-    twice or not at all shows) and whose three ``hc`` gains differ from 1
-    and from each other (a gain on the wrong columns shows)."""
-    params = llama.init_params(cfg, jax.random.PRNGKey(seed))
-    key = jax.random.PRNGKey(seed + 1)
-
-    def jitter(path, leaf):
-        name = path[-1].key
-        k = jax.random.fold_in(  # (crc32: str hashes differ by process)
-            key, zlib.crc32(jax.tree_util.keystr(path).encode()) % 2**31)
-        if name.endswith("norm") or name == "norm_f":
-            return leaf * (1.0 + 0.25 * jax.random.uniform(
-                k, leaf.shape, minval=-1.0))
-        if name.endswith("_scale") and name.startswith("hc_"):
-            return leaf * jnp.asarray([0.8, 1.25, 1.1], leaf.dtype)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(jitter, params)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return _params()
-
-
-@pytest.fixture(scope="module")
-def tensors(params):
-    return latent_hf_tensors(params, CFG)
-
-
-@pytest.fixture(scope="module")
-def want(tensors):
-    """The reference's logits at every position of TOKENS."""
-    return np.asarray(ref.logits(CFG.to_hf_dict(), tensors, TOKENS))
-
-
-_STEPS: dict = {}  # (LlamaConfig holds a dict: no static argument)
-
-
-def _STEP(params, tokens, cache, pos, cfg):
-    """``llama.forward`` jitted, one function a configuration."""
-    key = repr(cfg), os.environ.get("CAKE_PALLAS")  # what a trace asks
-    if key not in _STEPS:
-        _STEPS[key] = jax.jit(
-            lambda p, t, c, at: llama.forward(p, t, c, at, cfg))
-    return _STEPS[key](params, jnp.asarray(tokens), cache, pos)
-
-
-def _decode_all(params, cfg, tokens, prefill: int, chunk: int | None = None):
-    """Logits at positions ``prefill - 1 ..`` through the cache: a prefill
-    of ``prefill`` tokens (in chunks of ``chunk``), then one step a token."""
-    cache = init_cache(cfg, batch=1, max_seq=64)
-    chunk = chunk or prefill
-    for lo in range(0, prefill, chunk):
-        logits, cache = _STEP(params, jnp.asarray(tokens[None, lo:lo + chunk]),
-                              cache, lo, cfg)
-    out = [logits[0]]
-    for i in range(prefill, len(tokens)):
-        logits, cache = _STEP(params, jnp.asarray(tokens[None, i:i + 1]),
-                              cache, i, cfg)
-        out.append(logits[0])
-    return np.stack(out), cache
+from xing4_kit import (  # noqa: F401
+    CFG, CFG20, ROOT, TIGHT, TOKENS, _STEP, _STEPS, _decode_all, _engine,
+    _is_the_references_argmax, _run, params, tensors, want,
+)
 
 
 # -- (a) ops/hyper.py --------------------------------------------------------------
@@ -332,192 +262,6 @@ def test_stream_zero_as_the_plain_residual_is_the_plain_latent_model(params):
     # and with the seeded coefficients it is another model
     other, _ = _decode_all(params, CFG, TOKENS[:16], prefill=8)
     assert np.abs(other - want).max() > 100 * TIGHT
-
-
-# -- (c) the engine ------------------------------------------------------------------
-
-def _engine(params, prompts, ids=None, cfg=CFG, **kw):
-    from cake_tpu.runtime.batch_generator import BatchGenerator
-
-    kw.setdefault("block_size", 4)
-    bg = BatchGenerator(cfg, params, settings=SamplerSettings(**GREEDY),
-                        max_seq=256, **kw)
-    bg.set_prompts(prompts, stream_ids=ids)
-    return bg
-
-
-def _run(bg, events=(), steps=40):
-    """Step the engine; ``events``: ``{step: callable(bg)}``. Returns every
-    stream's generated ids by stream id."""
-    events = dict(events)
-    out: dict[int, list[int]] = {}
-    for i in range(steps):
-        if i in events:
-            events[i](bg)
-        bg.step()
-        for s in bg.streams:
-            if s.active and s.stream_id >= 0:
-                out[s.stream_id] = list(s.generated)
-    return out
-
-
-def _is_the_references_argmax(tensors, prompt, out, cfg=CFG):
-    """Every token of ``out`` is the single-stream reference's own best
-    continuation of what came before it, to ``TIGHT``."""
-    full = np.array(list(prompt) + list(out))
-    logits = np.asarray(ref.logits(cfg.to_hf_dict(), tensors, full))
-    for j, tok in enumerate(out):
-        at = logits[len(prompt) - 1 + j]
-        assert at.max() - at[tok] <= TIGHT, (len(prompt), j)
-
-
-_RNG = np.random.default_rng(7)
-PROMPTS = [[int(t) for t in _RNG.integers(3, 250, n)]
-           for n in (5, 37, 70, 21, 100, 12)]
-
-
-def test_batch_generator_streams_match_reference(params, tensors):
-    """Three streams of different lengths through BatchGenerator: a
-    bucketed batch prefill, per-row positions, block decode; each stream's
-    tokens are the reference's argmax. The gauges say what a token holds:
-    four hidden vectors between sub-layers, the latent row in the cache."""
-    reg = metrics.registry()
-    bg = _engine(params, PROMPTS[:3])
-    outs = bg.generate(13)
-    for prompt, out in zip(PROMPTS[:3], outs):
-        _is_the_references_argmax(tensors, prompt, list(out)[:13])
-    assert reg.gauge("model.hc_mult").value == 4
-    assert reg.gauge("resid.token_bytes").value == 4 * 64 * 4
-    assert CFG.resid_token_bytes == 4 * 64 * 4
-    assert reg.gauge("cache.row_bytes").value == 4 * (16 + 8)
-    assert reg.gauge("cache.layer_planes").value == 3
-    assert reg.gauge("model.loop_passes").value == 1
-    assert bg.stats()["tokens_emitted"] == 3 * 13
-
-
-def test_every_other_model_reads_one_hidden_vector(params):
-    from cake_tpu.models.config import tiny_mla_moe
-
-    cfg = tiny_mla_moe(max_seq_len=256, eos_token_id=-1)
-    bg = _engine(llama.init_params(cfg, jax.random.PRNGKey(0)), [[5, 9, 2]],
-                 cfg=cfg)
-    bg.generate(2)
-    reg = metrics.registry()
-    assert reg.gauge("model.hc_mult").value == 1
-    assert reg.gauge("resid.token_bytes").value == 64 * 4
-
-
-@pytest.mark.parametrize("admit_chunk", [None, 4],
-                         ids=["one-bucket", "bands-of-4"])
-def test_a_reused_slot_gives_the_references_tokens(params, tensors,
-                                                   admit_chunk):
-    """SLOT REUSE in the engine: a short stream admitted into the slot a
-    long one left gives the reference's tokens, whether its admission is
-    one bucket or bands of 4 rows (a prompt longer than a chunk: the wide
-    stream of a band is carried by nothing but the cache); the neighbour
-    never notices."""
-    long, short = PROMPTS[4], PROMPTS[5]
-    bg = _engine(params, [long, PROMPTS[3]], ids=[1, 2],
-                 admit_chunk=admit_chunk)
-    got = _run(bg, {6: lambda e: (e.finish(1), e.enqueue(short, 3))},
-               steps=30)
-    assert len(got[3]) >= 10
-    _is_the_references_argmax(tensors, short, got[3][:10])
-    _is_the_references_argmax(tensors, PROMPTS[3], got[2][:12])
-
-
-def test_admissions_among_live_streams_ride_one_program(params, tensors,
-                                                        monkeypatch):
-    """An admission among live streams, then two arrivals that wait
-    together and ride ONE prefill program of two rows: each stream's
-    tokens are the single-stream reference's."""
-    from cake_tpu.runtime import batch_generator as engine
-
-    monkeypatch.setattr(engine, "GROUP_SHAPES", ((2, 64),))
-    launches = metrics.registry().counter("engine.admit_launches")
-    bg = _engine(params, [PROMPTS[1], PROMPTS[0], [4, 4, 4], [4, 4, 5]],
-                 ids=[10, 11, 90, 91])
-    bg.warm_admission(40)
-    before = launches.value
-    events = {
-        2: lambda e: (e.finish(90), e.enqueue(PROMPTS[3], 12)),
-        8: lambda e: (e.finish(91), e.finish(11),
-                      e.enqueue(PROMPTS[2][:40], 13),
-                      e.enqueue(PROMPTS[5], 14)),
-    }
-    got = _run(bg, events, steps=36)
-    assert launches.value - before == 2  # 12 alone, 13 and 14 together
-    for sid, prompt in ((10, PROMPTS[1]), (12, PROMPTS[3]),
-                        (13, PROMPTS[2][:40]), (14, PROMPTS[5])):
-        assert len(got[sid]) >= 10, sid
-        _is_the_references_argmax(tensors, prompt, got[sid][:10])
-
-
-# -- what the latent family has beyond the slot layout, under the wide stream ------
-
-def test_the_single_stream_generators_match_reference(params, tensors):
-    """``LlamaGenerator`` (bucketed prefill, block decode) and
-    ``SpeculativeGenerator`` (n-gram proposals verified in one forward
-    pass, ``head_norm`` over every position's streams) give the
-    reference's argmax."""
-    from cake_tpu.runtime.generator import LlamaGenerator
-    from cake_tpu.runtime.speculative import SpeculativeGenerator
-
-    prompt = PROMPTS[3]
-    for make in (
-            lambda: LlamaGenerator(CFG, params, settings=SamplerSettings(
-                **GREEDY), max_seq=256, block_size=4),
-            lambda: SpeculativeGenerator(CFG, params, settings=SamplerSettings(
-                **GREEDY), max_seq=256, spec_k=3)):
-        gen = make()
-        gen.set_prompt(prompt)
-        out = [gen.next_token(i).id for i in range(12)]
-        _is_the_references_argmax(tensors, prompt, out)
-
-
-def test_speculation_in_the_engine_matches_reference(params, tensors):
-    """``spec_k`` in the engine: the per-row verify program takes the
-    streams' sum at every fed position."""
-    bg = _engine(params, [PROMPTS[0], PROMPTS[3]], spec_k=2)
-    outs = bg.generate(10)
-    for prompt, out in zip((PROMPTS[0], PROMPTS[3]), outs):
-        _is_the_references_argmax(tensors, prompt, list(out)[:10])
-
-
-def test_ep_axis_splits_the_held_experts(params):
-    """Under a real ep axis the expert block's psum sits inside the
-    sub-layer the mixes wrap: the mesh stream is the single-device one."""
-    prompts = [[5, 9, 2, 11], [3, 1, 4, 1, 5]]
-    outs = []
-    for ep in (1, 2):
-        bg = _engine(params, prompts, block_size=2, ep=ep)
-        outs.append(bg.generate(6))
-    assert outs[0] == outs[1]
-
-
-def test_int8_linears_leave_the_hc_tensors_float32(params):
-    """``--quantize int8`` at the small size: every latent linear through
-    ``quant.dense``, the ``hc`` tensors as they are (float32, never
-    quantised), against the reference over the explicitly dequantized
-    weights."""
-    from cake_tpu.ops.quant import (QuantizedLinear, dequantize_linear,
-                                    quantize_params)
-
-    q = quantize_params(params, bits=8)
-    stack = q["layers"]["moe"]
-    assert isinstance(stack["wkv_b"], QuantizedLinear)
-    for name in llama.HC_TENSORS:
-        assert stack[name].dtype == jnp.float32, name
-        np.testing.assert_array_equal(stack[name],
-                                      params["layers"]["moe"][name])
-    deq = jax.tree.map(
-        lambda a: dequantize_linear(a, jnp.float32)
-        if isinstance(a, QuantizedLinear) else a, q,
-        is_leaf=lambda a: isinstance(a, QuantizedLinear))
-    got, _ = _decode_all(q, CFG, TOKENS[:12], prefill=8)
-    want = np.asarray(ref.logits(CFG.to_hf_dict(),
-                                 latent_hf_tensors(deq, CFG), TOKENS[:12]))
-    np.testing.assert_allclose(got, want[7:], atol=TIGHT, rtol=0)
 
 
 # -- (e) the configuration -----------------------------------------------------------
