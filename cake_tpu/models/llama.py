@@ -726,6 +726,7 @@ def block_forward(
     layer_idx: jax.Array | None = None,
     count_local: bool = False,
     expert_idx: jax.Array | None = None,
+    valid: jax.Array | None = None,
 ):
     """One pre-norm decoder block (transformer.rs:48-64). Returns ``(x,
     k_cache, v_cache)``; with ``count_local`` (an expert layer of the
@@ -737,6 +738,9 @@ def block_forward(
     (:func:`forward_layers`); None: they are this layer's own buffers.
     ``expert_idx``: likewise ``layer``'s ``w_gate``/``w_up``/``w_down`` are
     the whole expert stacks and this is layer ``expert_idx`` of them.
+    ``valid [B]``: the true tokens of each row of a bucketed chunk, for the
+    expert block alone (:func:`cake_tpu.ops.moe.moe_swiglu`): rows past a
+    frontier hide themselves from attention.
 
     Under tensor parallelism (inside shard_map), ``num_heads``/``num_kv_heads``
     are the per-device local counts and ``tp_axis`` names the mesh axis the
@@ -760,7 +764,7 @@ def block_forward(
     if "wkv_a" in layer:
         return _latent_block(layer, x, k_cache, v_cache, cos, sin, pos,
                              config, write_gate, ep_axis, ep_size,
-                             layer_idx, count_local, expert_idx)
+                             layer_idx, count_local, expert_idx, valid)
     h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps,
                    offset=config.rms_norm_offset)
     attn_out, k_cache, v_cache = self_attention_block(
@@ -792,7 +796,7 @@ def block_forward(
             h, layer["router"], layer["w_gate"], layer["w_up"],
             layer["w_down"], top_k=config.num_experts_per_tok,
             ep_axis=ep_axis, ep_size=ep_size, tp_axis=tp_axis,
-            layer=expert_idx,
+            layer=expert_idx, valid=valid,
         )
     else:
         mlp_out = swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"],
@@ -823,7 +827,7 @@ def _sub_layer(layer, x, part: str, norm: str, config, f):
 
 def _latent_block(layer, x, c_cache, r_cache, cos, sin, pos, config,
                   write_gate, ep_axis, ep_size, layer_idx, count_local,
-                  expert_idx):
+                  expert_idx, valid=None):
     """:func:`block_forward` for a latent-attention layer."""
     def attend(h):
         with jax.named_scope("mla"):
@@ -835,21 +839,24 @@ def _latent_block(layer, x, c_cache, r_cache, cos, sin, pos, config,
     x, (c_cache, r_cache) = _sub_layer(layer, x, "attn", "attn_norm", config,
                                        attend)
     x, local = _shared_feed_forward(layer, x, config, ep_axis, ep_size,
-                                    count_local, expert_idx)
+                                    count_local, expert_idx, valid)
     if count_local:
         return x, c_cache, r_cache, local
     return x, c_cache, r_cache
 
 
 def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
-                         expert_idx):
+                         expert_idx, valid=None):
     """The feed-forward half of a latent-family layer, residual added
     (:func:`_sub_layer`). A dense layer (no ``router``) is a SwiGLU of
     ``intermediate_size``; an expert layer is ``shared(h) + sum over the
     chosen experts HELD here of w_e expert_e(h)`` (the shared expert where
     the layer holds one, times ``sigmoid(h w_share)`` where the layer holds
     ``ws_share``; the weights sigmoid scores under :class:`GroupRouting`,
-    or softmax shares). Returns ``(x, ExpertCount)``."""
+    or softmax shares). ``valid [B]``: the true tokens of each row of a
+    bucketed chunk, which the routed part alone is told (a padding row's
+    routed result is zero; its shared expert's is nobody's to read).
+    Returns ``(x, ExpertCount)``."""
     def feed(h):
         local = ExpertCount.zeros(h.shape[0])
         if "router" not in layer:
@@ -868,7 +875,7 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
             layer["w_down"], top_k=config.num_experts_per_tok,
             ep_axis=ep_axis, ep_size=ep_size, routing=routing,
             held=(config.first_expert, config.n_routed_experts),
-            count_local=count_local, layer=expert_idx,
+            count_local=count_local, layer=expert_idx, valid=valid,
         )
         if count_local:
             y, local = y
@@ -897,7 +904,7 @@ def _kda_block(layer, x, cache, config, valid, ep_axis, ep_size, layer_idx,
             h, layer, cache.state, cache.conv, config, valid=valid,
             layer_idx=layer_idx)
     x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
-                                    ep_size, count_local, expert_idx)
+                                    ep_size, count_local, expert_idx, valid)
     return x, dataclasses.replace(cache, state=state, conv=conv), local
 
 
@@ -920,7 +927,8 @@ def _typed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
         out, conv = conv_mixer_block(h, layer, cache.conv, valid=valid,
                                      layer_idx=layer_idx)
         x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
-                                        ep_size, count_local, expert_idx)
+                                        ep_size, count_local, expert_idx,
+                                        valid)
         return x, dataclasses.replace(cache, conv=conv), local
     if mixer == "gdn":
         with jax.named_scope("gdn"):
@@ -928,7 +936,8 @@ def _typed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
                 h, layer, cache.state, cache.conv, config, valid=valid,
                 layer_idx=layer_idx)
         x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
-                                        ep_size, count_local, expert_idx)
+                                        ep_size, count_local, expert_idx,
+                                        valid)
         return x, dataclasses.replace(cache, state=state, conv=conv), local
     if isinstance(cos, dict):  # a rotation a layer kind: this kind's
         cos, sin = cos[mixer], sin[mixer]
@@ -951,7 +960,7 @@ def _typed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
                 gated=config.attn_gate == "elementwise")
         cache = dataclasses.replace(cache, k=k, v=v)
     x, local = _shared_feed_forward(layer, x + out, config, ep_axis,
-                                    ep_size, count_local, expert_idx)
+                                    ep_size, count_local, expert_idx, valid)
     return x, cache, local
 
 
@@ -989,6 +998,7 @@ def forward_layers(
     count_local: bool = False,
     valid: jax.Array | None = None,
     pass_norm: jax.Array | None = None,
+    expert_valid: jax.Array | None = None,
 ):
     """Run a contiguous run of decoder blocks via ``lax.scan``. Returns
     ``(x, cache)``; with ``count_local`` (latent family) ``(x, cache,
@@ -1031,7 +1041,11 @@ def forward_layers(
     kind. Short convolutions
     beside attention go the same way: a conv segment's layers read and
     write the cache's tails (``valid`` as above) and a full segment's
-    rotate and attend over its rows.
+    rotate and attend over its rows. An expert layer's routed part is
+    told the same lengths and counts a bucket's padding as nothing of its
+    own (:func:`cake_tpu.ops.moe.moe_swiglu`); ``expert_valid [B]`` tells
+    it where the cache holds rows alone and ``valid`` is None (rows past
+    a frontier hide themselves from attention, not from an expert block).
 
     The looped family (``families.LOOPED``, ``config.total_ut_steps`` U > 1)
     runs its plan U times over the carried ``(h, cache)`` WITH THE SAME
@@ -1054,6 +1068,8 @@ def forward_layers(
     """
     rows = x.shape[0] * x.shape[1]
     batch = x.shape[0]
+    if expert_valid is None:
+        expert_valid = valid
     wide = config.hc_mult > 1
     if wide:  # the loops carry the stream's hidden vectors apart
         x = hyper.split(x)
@@ -1093,7 +1109,8 @@ def forward_layers(
                 sp_size=sp_size, write_gate=write_gate,
                 sp_prefill=sp_prefill, sp_chunk=sp_chunk,
                 ep_axis=ep_axis, ep_size=ep_size,
-                layer_idx=i, count_local=count_local, expert_idx=j)
+                layer_idx=i, count_local=count_local, expert_idx=j,
+                valid=expert_valid)
             c = dataclasses.replace(c, k=kc, v=vc)
             now = now[0] if now else None
         if count_local:
@@ -1199,6 +1216,27 @@ def forward_layers(
         return (h, *rest)
 
     return jax.lax.fori_loop(0, config.total_ut_steps, a_pass, carry)
+
+
+def true_rows(config: LlamaConfig, shape: tuple[int, int], last_index):
+    """``(valid, expert_valid)`` of :func:`forward_layers` for a bucketed
+    chunk of ``shape = (B, T)`` whose rows' last true tokens lie at
+    ``last_index [B]`` (or one index for every row): the true tokens of
+    each row, int32 ``[B]``, up to and with its last true token, the whole
+    chunk where that token lies in a later one. ``valid`` for a model
+    whose layers hold a recurrent state, a tail or a ring of rows (None
+    otherwise: rows past a frontier hide themselves), ``expert_valid``
+    for a model with expert layers, whatever its cache holds (None
+    otherwise): a frontier hides a padding row from attention, and an
+    expert block would route and compute it all the same."""
+    stateful = bool(set(config.cache_plan) - {"rows"})
+    sparse = any(ffn == "moe" for _, ffn in config.layer_kinds)
+    if not (stateful or sparse):  # such a program is told no length
+        return None, None
+    b, t = shape
+    true = jnp.broadcast_to(jnp.minimum(last_index + 1, t),
+                            (b,)).astype(jnp.int32)
+    return true if stateful else None, true if sparse else None
 
 
 def pass_norm(params: Params, config: LlamaConfig):

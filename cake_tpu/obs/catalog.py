@@ -180,12 +180,14 @@ SERIES: dict[str, tuple[str, str]] = {
     "delta.chunks_swept": (
         COUNTER, "chunks of ops.kda.CHUNK tokens that the delta-rule "
                  "layers' admission scans ran through: delta-rule layers x "
-                 "a launch's rows x ceil(bucket / 64), a dispatch (the "
-                 "scan is serial, so a bucket's padding costs its chunks)"),
+                 "a launch's rows x ceil(its longest row's true tokens / "
+                 "64), a dispatch (the scan is serial over a launch's "
+                 "rows and stops at the last chunk that holds a true "
+                 "token of any of them)"),
     "delta.chunks_live": (
         COUNTER, "of delta.chunks_swept, the chunks that held a true "
                  "token: the same with each row's own length in place of "
-                 "the bucket's"),
+                 "the longest's"),
     "ssm.decode_kernel": (
         GAUGE, "what ops.mamba.mamba_mixer_block chose for the last "
                "single-token state-space step it traced (the decode "
@@ -227,13 +229,15 @@ SERIES: dict[str, tuple[str, str]] = {
     "moe.sorted_pair_rows": (
         COUNTER, "(row, chosen expert) pair rows handed to expert calls "
                  "that took the sorted form: rows x top-k a call and "
-                 "expert layer, of decode steps and admission dispatches, "
+                 "expert layer (an admission bucket's padding rows "
+                 "included), of decode steps and admission dispatches, "
                  "counted on the device"),
     "moe.sorted_pair_rows_live": (
         COUNTER, "of moe.sorted_pair_rows, the rows of the row tiles "
-                 "those calls touched (the tiles that hold a pair on a "
-                 "held expert: live tiles x the row tile; the others are "
-                 "neither read nor written), counted on the device"),
+                 "those calls touched (the tiles that hold a true token's "
+                 "pair on a held expert: live tiles x the row tile; the "
+                 "others, a bucket's padding among them, are neither "
+                 "read nor written), counted on the device"),
     "moe.sorted_from_rows": (
         GAUGE, "the fewest rows of a traced call whose expert block took "
                "the sorted form (ops.moe.expert_form, set at trace time; "

@@ -106,10 +106,16 @@ Two forms of the recurrence, chosen at trace time by ``T``:
 
 ``valid [B]``: the true tokens of each row of a bucketed chunk. A padded
 token gets ``beta = 0`` and ``g = 0`` (it neither writes nor decays the
-state) and the convolutions' tail is taken at the true length. The scan is
-serial all the same: a bucket's padding costs its chunks
-(``delta.chunks_swept`` against ``delta.chunks_live``,
-``runtime/batch_generator.py``).
+state) and the convolutions' tail is taken at the true length. A chunk of
+such tokens is the identity on the state (``u_hat = w = 0``, the carried
+decay 1), so the serial loop stops at the last chunk that holds a true
+token of SOME row of the launch (:func:`_advance` counts them, a traced
+bound: one program a bucket whatever the prompts' lengths) and a bucket's
+padding costs no chunk of it; ``o`` past that chunk is zero, and is
+padding's. What is left is a launch's shorter rows, which ride to the
+longest's end (``delta.chunks_swept`` against ``delta.chunks_live``,
+``runtime/batch_generator.py``). What the chunks make ahead of the loop
+is made for the whole bucket all the same: batched, off the serial path.
 """
 
 from __future__ import annotations
@@ -234,11 +240,16 @@ def _unit_lower_inverse(n_mat, block: int):
 
 @partial(jax.jit, static_argnames="chunk")
 @jax.default_matmul_precision("highest")
-def kda_chunk(q, k, v, g, beta, state, chunk: int = CHUNK):
+def kda_chunk(q, k, v, g, beta, state, live=None, chunk: int = CHUNK):
     """``T`` tokens in chunks of ``chunk`` (module docstring). ``q, k [B, T,
     Hk, d_k]``, ``v [B, T, Hv, d_v]``, ``g [B, T, Hv, d_k]`` (a decay a
     channel) or ``[B, T, Hv]`` (a head), ``beta [B, T, Hv]``, ``state [B,
     Hv, d_k, d_v]``, all float32. Returns ``(o [B, T, Hv, d_v], state)``.
+    ``live`` (int32 ``[]``, traced; None: all): the leading chunks that
+    hold a token which writes or decays the state (``beta`` and ``g`` are
+    0 from there on). The serial loop stops there: the state is what all
+    ``T`` tokens leave (a chunk past ``live`` is the identity on it),
+    ``o`` is zero past ``live x chunk``.
     Inside, the value heads lie ``[G, R]``: ``G = Hk`` key heads, each
     under its ``R`` value heads, so that what only q and k make (``K K^T``,
     ``Q K^T`` in the scalar case) is made once a KEY head. A function of
@@ -314,9 +325,34 @@ def kda_chunk(q, k, v, g, beta, state, chunk: int = CHUNK):
     # head, times d_k where the decay is a channel's
     widest = 4 * n * b * hv * c * c * (1 if scalar else dk)
     if widest <= HOIST_BYTES:  # every chunk at once, off the serial path
-        state, o = jax.lax.scan(advance, s0, jax.vmap(ahead)(xs))
+        step, xs = advance, jax.vmap(ahead)(xs)
     else:  # a chunk at a time, where it is used
-        state, o = jax.lax.scan(lambda s, x: advance(s, ahead(x)), s0, xs)
+        def step(s, x):
+            return advance(s, ahead(x))
+    if live is None:
+        state, o = jax.lax.scan(step, s0, xs)
+    else:
+        # the same steps, as many as hold a token: the bound is data. The
+        # loop writes ``o`` chunk by chunk into a buffer it carries, and a
+        # second one zeroes the chunks past ``live``: each chunk of ``o``
+        # is written once (a buffer of zeros filled first is 134 MB of an
+        # 8192-row bucket, 3.6% of a full one's time; zeros selected in
+        # afterwards are a pass over all of it, 7%: my chip runs, PR 60)
+        live = jnp.clip(live, 0, n)
+
+        def some(i, carry):
+            s, o = carry
+            s, here = step(s, jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False), xs))
+            return s, jax.lax.dynamic_update_index_in_dim(o, here, i, 0)
+
+        state, o = jax.lax.fori_loop(0, live, some, (
+            s0, jax.lax.empty((n, b, hk, r, c, dv), v.dtype)))
+        none = jnp.zeros(o.shape[1:], o.dtype)
+        o = jax.lax.fori_loop(
+            live, n,
+            lambda i, o: jax.lax.dynamic_update_index_in_dim(o, none, i, 0),
+            o)
     # [n, B, G, R, C, dv] -> [B, T, Hv, dv]
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 4, 2).reshape(b, n * c, hv, dv)
     return o[:, :t], state.reshape(b, hv, dk, dv)
@@ -395,10 +431,13 @@ def _advance(q, k, v, g, beta, state, valid, layer_idx, scope: str):
     tokens (``valid``) touch nothing. Returns ``(o [B, T, Hv, d_v],
     state)``, the buffer whole."""
     t = q.shape[1]
+    chunks = None
     if valid is not None:
         live = jnp.arange(t, dtype=jnp.int32)[None] < valid[:, None]
         g = jnp.where(live.reshape(live.shape + (1,) * (g.ndim - 2)), g, 0.0)
         beta = jnp.where(live[..., None], beta, 0.0)
+        # the chunks that hold a true token of some row of the launch
+        chunks = (jnp.max(valid) + CHUNK - 1) // CHUNK
     if t == 1 and layer_idx is not None and kda_decode_choice(
             *state.shape[-2:]) == "kernel":
         from cake_tpu.ops.pallas.kda import kda_decode
@@ -415,7 +454,7 @@ def _advance(q, k, v, g, beta, state, valid, layer_idx, scope: str):
             o = o[:, None]
     else:
         with jax.named_scope(f"{scope}.chunk"):
-            o, s1 = kda_chunk(q, k, v, g, beta, s0)
+            o, s1 = kda_chunk(q, k, v, g, beta, s0, chunks)
     return o, kv.layer_store(state, s1, layer_idx)
 
 

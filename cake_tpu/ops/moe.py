@@ -37,19 +37,21 @@ TPU-first design:
     0.74: :func:`hit_share`) and prompt rows (an admission's bucket, a
     verify chunk, a prefill): the ``N x k`` (row, chosen expert) pairs
     sorted by local expert id, pairs on experts that are not here at the
-    tail; from there on only the LIVE row tiles (those that hold a pair
-    on a held expert) are read or written: their rows gathered; gate, up
-    and the SwiGLU one grouped call and down another over the contiguous
-    groups (:func:`cake_tpu.ops.pallas.grouped_swiglu`,
+    tail and with them the pairs of a bucket's padding (``valid``: the
+    rows' true lengths, where the caller knows them); from there on only
+    the LIVE row tiles (those that hold a true pair on a held expert) are
+    read or written: their rows gathered; gate, up and the SwiGLU one
+    grouped call and down another over the contiguous groups
+    (:func:`cake_tpu.ops.pallas.grouped_swiglu`,
     :func:`~cake_tpu.ops.pallas.grouped_matmul`: a group without a row is
     never visited, so its matrices are never read; an int8 stack streams
     as int8 and is converted a block at a time); every row's results
     summed under its routing weights in float32 over the live tiles' rows
     (:func:`compacts`: by kernels where a share of the scored experts is
-    held, by XLA where all are and every tile is live). Exact with no
-    capacity, no fallback and no control flow. Its kernels read a layer's
-    matrices out of the WHOLE stacks the layer loop closes over
-    (``layer=``), so no layer's slice is written out for them
+    held, by XLA where all are and every tile but padding's is live).
+    Exact with no capacity, no fallback and no control flow. Its kernels
+    read a layer's matrices out of the WHOLE stacks the layer loop closes
+    over (``layer=``), so no layer's slice is written out for them
     (:func:`reads_whole_stacks`).
 
 - **Expert parallelism** shards the expert axis over the mesh's ``ep`` axis
@@ -112,10 +114,11 @@ class ExpertCount(NamedTuple):
     chosen expert) pairs that fell on the experts held here; ``hit []``
     the distinct held experts that some row chose (what the sorted form
     reads of the stacks); ``sorted_rows []`` the pair rows (``rows x
-    top_k``) of a call that took the sorted form, and ``live_rows []``
-    those of them that lie in a row tile the call touched (the sorted
-    form moves the tiles that hold a held pair and no others); both 0 of
-    a call in another form."""
+    top_k``, a bucket's padding included) of a call that took the sorted
+    form, and ``live_rows []`` those of them that lie in a row tile the
+    call touched (the sorted form moves the tiles that hold a true
+    token's pair on a held expert and no others: ``ceil(such pairs / row
+    tile)`` tiles); both 0 of a call in another form."""
 
     pairs: jax.Array
     hit: jax.Array
@@ -267,23 +270,29 @@ def _moe_sorted(
     w_down,
     layer,
     scored: int,  # the experts the router chose among
+    true: jax.Array | None = None,  # [N] bool: the row is no padding
 ) -> tuple[jax.Array, jax.Array]:
     """Only the (row, chosen expert) pairs that fall on experts held here:
     the ``N x k`` pairs sorted by local expert (a pair on an expert that
-    is not here sorts to the tail), and from there on only the LIVE row
-    tiles, those that hold a held pair, are read or written: their rows
-    gathered, gate, up and the SwiGLU one grouped call, down another, and
-    every row's results summed under its routing weights in float32 over
-    the live tiles' rows. Where every scored expert is held every tile is
-    live, and the rows are gathered and summed by XLA (:func:`compacts`).
-    Exact whatever the routing: no capacity, no fallback and no control
-    flow. Returns the block's result and the rows of the live tiles
+    is not here sorts to the tail, and so does a pair of a bucket's
+    padding: a row that ``true`` does not name), and from there on only
+    the LIVE row tiles, those that hold a true pair on a held expert, are
+    read or written: their rows gathered, gate, up and the SwiGLU one
+    grouped call, down another, and every row's results summed under its
+    routing weights in float32 over the live tiles' rows. Where every
+    scored expert is held every tile is live but padding's, and the rows
+    are gathered and summed by XLA (:func:`compacts`). Exact whatever the
+    routing: no capacity, no fallback and no control flow. A padding
+    row's result is exactly zero whatever lies in the tiles no kernel
+    wrote. Returns the block's result and the rows of the live tiles
     (int32 ``[]``: what of ``N x k`` was touched)."""
     n, k = idx.shape
     e_local = _stack(w_gate).shape[-3]
     tm = pk.MOE_ROW_TILE
     local = idx - lo
     held = (local >= 0) & (local < e_local)  # [N, k]
+    if true is not None:
+        held &= true[:, None]
     key = jnp.where(held, local, e_local).reshape(-1)
     m = -(-n * k // tm) * tm
     pad = (0, m - n * k)
@@ -322,6 +331,11 @@ def _moe_sorted(
     else:
         place = jnp.argsort(order)[: n * k]  # pair -> sorted place
         y = jnp.take(y, place, axis=0).reshape(n, k, -1)
+        if true is not None:
+            # a padding pair's place is past the groups' rows, which no
+            # kernel wrote: selected away, not multiplied (0 x NaN)
+            y = jnp.where(true[:, None, None], y, 0.0)
+            w_topk = jnp.where(true[:, None], w_topk, 0.0)
         out = jnp.einsum("nk,nkh->nh", w_topk, y).astype(x2d.dtype)
     return out, tiles.live[0] * tm
 
@@ -402,6 +416,7 @@ def moe_swiglu(
     held: tuple[int, int] | None = None,
     count_local: bool = False,
     layer: jax.Array | None = None,
+    valid: jax.Array | None = None,
 ):
     """Routed SwiGLU MLP. Returns ``[B, T, H]`` (residual NOT added); with
     ``count_local`` a pair ``(out, ExpertCount)``: each batch row's number
@@ -430,6 +445,13 @@ def moe_swiglu(
     ``layer``: the three stacks are the layer loop's whole ``[L, E_local,
     ..]`` stacks and this is layer ``layer`` of them (what
     :func:`reads_whole_stacks` asks for: calls that take the sorted form).
+
+    ``valid [B]``: the true tokens of each row of a bucketed chunk (None:
+    all ``T``). The sorted form counts a token at or past its row's
+    ``valid`` as it counts a pair on an absent expert: nothing of its own,
+    no tile visited for it, and its result exactly zero (every true row's
+    is what it is without ``valid``, bit for bit). The dense and gather
+    forms have no tile to skip and compute a padding row like any other.
     """
     b, t, h = x.shape
     x2d = x.reshape(b * t, h)
@@ -470,8 +492,10 @@ def moe_swiglu(
             combine = jax.lax.dynamic_slice_in_dim(combine, lo, e_local, 1)
         live_rows = jnp.zeros((), jnp.int32)
         if form == "sorted":
+            true = None if valid is None else (
+                jnp.arange(t, dtype=jnp.int32) < valid[:, None]).reshape(-1)
             out, live_rows = _moe_sorted(x2d, w_topk, idx, lo, w_gate, w_up,
-                                         w_down, layer, e_global)
+                                         w_down, layer, e_global, true)
         elif form == "gather":
             out = _moe_gather(x2d, w_topk, idx, w_gate, w_up, w_down)
         else:  # every held expert over every row

@@ -78,13 +78,15 @@ def _pipeline_layers(
     sp_chunk: bool = False,
     count_local: bool = False,
     valid: jax.Array | None = None,
+    expert_valid: jax.Array | None = None,
 ):
     """Run the staged pipeline loop. Returns (x_on_stage0, cache); with
     ``count_local`` (an expert model of the latent family, which runs as
     one stage) a third value, the :class:`ExpertCount` of the experts
     held here (:func:`llama.forward_layers`). ``valid [B]``: the
     true tokens of each row of a bucketed chunk (what alone may touch a
-    recurrent state).
+    recurrent state); ``expert_valid [B]``: the same for an expert block,
+    whatever the cache holds (:func:`llama.true_rows`).
 
     SPMD-uniformity: every stage executes the layer math (and therefore every
     collective — tp psum, sp ring ppermute, sp decode psum/pmax) on every
@@ -109,6 +111,7 @@ def _pipeline_layers(
             sp_axis=SP, sp_size=sp, write_gate=active, sp_prefill=sp_prefill,
             sp_chunk=sp_chunk, count_local=count_local, valid=valid,
             pass_norm=llama.pass_norm(params, config),
+            expert_valid=expert_valid,
         )
         x = jnp.where(active, h, x)
         x = jax.lax.ppermute(x, STAGE, perm)
@@ -193,17 +196,15 @@ def _pipelined_prefill_layers(
     return y, ck, cv
 
 
-def _valid_rows(config: LlamaConfig, tokens: jax.Array,
-                last_index: jax.Array):
-    """The true tokens of each row of a bucketed chunk, for a model whose
-    layers hold a recurrent state or a ring of rows (None otherwise: rows
-    past a frontier hide themselves): up to and with the row's last true
-    token, the whole chunk where that token lies in a later one."""
-    if not set(config.cache_plan) - {"rows"}:
-        return None
-    t = tokens.shape[1]
-    return jnp.broadcast_to(jnp.minimum(last_index + 1, t),
-                            (tokens.shape[0],)).astype(jnp.int32)
+def _true_rows(config: LlamaConfig, tokens: jax.Array,
+               last_index: jax.Array, whole: bool = True) -> dict:
+    """``valid`` and ``expert_valid`` of :func:`_pipeline_layers` for a
+    bucketed chunk (:func:`llama.true_rows`). ``whole`` False: ``tokens``
+    are a shard of each row (ring prefill), whose places are not the
+    bucket's: no length for the expert block."""
+    valid, expert_valid = llama.true_rows(config, tokens.shape, last_index)
+    return {"valid": valid,
+            "expert_valid": expert_valid if whole else None}
 
 
 def _select_stage0(x: jax.Array) -> jax.Array:
@@ -759,9 +760,8 @@ def build_admit_prefill(config: LlamaConfig, plan: MeshPlan,
         x, cache, *local = _pipeline_layers(
             x, params, cache, cos, sin, pos0, config,
             plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
-            sp_chunk=plan.sp > 1, valid=_valid_rows(config, tokens,
-                                                    last_local),
-            count_local=count_local,
+            sp_chunk=plan.sp > 1, count_local=count_local,
+            **_true_rows(config, tokens, last_local),
         )
         # the chunk activations are replicated over sp (every shard computes
         # the full chunk), so the sp==1 last-index selection applies
@@ -1084,7 +1084,8 @@ def build_sharded_prefill(config: LlamaConfig, plan: MeshPlan,
                 x, params, cache, cos, sin, pos0,
                 config, plan.num_stages, heads_l, kv_heads_l, sp=plan.sp,
                 sp_prefill=not chunk_mode, sp_chunk=chunk_mode,
-                valid=_valid_rows(config, tokens, last_index),
+                **_true_rows(config, tokens, last_index,
+                             whole=chunk_mode or plan.sp == 1),
             )
         # slice the wanted position first so the cross-stage select moves
         # [B, hidden], not the whole [B, T, hidden] activation

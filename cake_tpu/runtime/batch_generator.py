@@ -238,10 +238,11 @@ _STATE_RESETS = {"kda": obs_metrics.counter("kda.state_resets"),
                  "gdn": obs_metrics.counter("kda.state_resets"),
                  "mamba": obs_metrics.counter("ssm.state_resets"),
                  "conv": obs_metrics.counter("conv.state_resets")}
-# A delta-rule layer's admission is a serial scan of ops.kda.CHUNK-token
-# chunks: what a launch's rows cost at the bucket's length, and what they
-# would at each row's own (a bucket's padding costs chunks where attention
-# would skip blocks)
+# A delta-rule layer's admission is a serial loop over ops.kda.CHUNK-token
+# chunks that stops at the launch's last live one: what a launch's rows
+# cost at its longest row's length, and what they would at each row's own
+# (a bucket's padding costs no chunk; a shorter row rides to the longest's
+# end)
 _DELTA_CHUNKS_SWEPT = obs_metrics.counter("delta.chunks_swept")
 _DELTA_CHUNKS_LIVE = obs_metrics.counter("delta.chunks_live")
 _SPEC_NGRAM = 3  # the longest n-gram a batched proposal is looked up by
@@ -2553,15 +2554,16 @@ class BatchGenerator:
     def _count_delta_chunks(self, chunk: int, left: list[int]) -> None:
         """An admission dispatch of ``chunk`` tokens a row over a model
         with delta-rule layers: add the chunks their scans sweep (the
-        bucket's, every row) to ``delta.chunks_swept`` and those that hold
-        a true token (``left``: each row's prompt tokens from this
-        dispatch's first on) to ``delta.chunks_live``."""
+        launch's longest row's, every row: the scan stops at the last
+        chunk that holds a true token of some row, ``ops.kda._advance``)
+        to ``delta.chunks_swept`` and those that hold a true token
+        (``left``: each row's prompt tokens from this dispatch's first
+        on) to ``delta.chunks_live``."""
         if not self._delta_layers:
             return
-        _DELTA_CHUNKS_SWEPT.inc(
-            self._delta_layers * len(left) * -(-chunk // CHUNK))
-        _DELTA_CHUNKS_LIVE.inc(self._delta_layers * sum(
-            -(-min(max(n, 0), chunk) // CHUNK) for n in left))
+        live = [-(-min(max(n, 0), chunk) // CHUNK) for n in left]
+        _DELTA_CHUNKS_SWEPT.inc(self._delta_layers * len(left) * max(live))
+        _DELTA_CHUNKS_LIVE.inc(self._delta_layers * sum(live))
 
     def _admit_dispatched(self, t0: float, chunk: int, pos: int) -> None:
         """Book one admission chunk whose compute has been waited for."""

@@ -106,18 +106,18 @@ def prefill_fn(params, tokens, cache: KVCache, last_index, config: LlamaConfig):
     (the last *real* prompt position). Returns (logits [B, vocab], cache)."""
     cos, sin = rope_tables_for(config, cache.max_seq)
     x = llama.embed_tokens(params, tokens, config)
-    valid = None
-    if set(config.cache_plan) - {"rows"}:
+    valid, expert_valid = llama.true_rows(config, tokens.shape, last_index)
+    if valid is not None:
         # a recurrent state, a tail or a ring has no frontier that hides
-        # what is not the prompt's: the bucket's padding must not touch it,
-        # and what the last prompt left goes
-        valid = jnp.minimum(last_index + 1, tokens.shape[1]).astype(jnp.int32)
+        # what is not the prompt's: the bucket's padding must not touch it
+        # (``valid``), and what the last prompt left goes
         cache = dataclasses.replace(cache, **{
             name: jnp.zeros_like(getattr(cache, name))
             for name in ("state", "conv") if getattr(cache, name) is not None})
     x, cache = llama.forward_layers(
         params["layers"], x, cache, cos, sin, 0, config,
-        pass_norm=llama.pass_norm(params, config), valid=valid)
+        pass_norm=llama.pass_norm(params, config), valid=valid,
+        expert_valid=expert_valid)
     # (rank-agnostic: a wide residual stream is [B, T, hc_mult, hidden])
     x_last = jnp.take_along_axis(
         x, last_index.reshape((-1,) + (1,) * (x.ndim - 1)).astype(jnp.int32),

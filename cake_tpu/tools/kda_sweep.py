@@ -13,9 +13,15 @@ XLA at the cell's shape (B 32, 6 layers; PERF.md keeps the table).
 the two delta-rule cells' shapes, for each size of the diagonal blocks its
 inverse starts from (``--blocks``; 0 leaves ``ops.kda.INVERSE_BLOCK`` as
 the checkout has it, which is how a tree without the constant is timed):
-where that constant comes from (PERF.md keeps the table).
+where that constant comes from (PERF.md keeps the table). The chunk form
+is entered as a layer enters it (``ops.kda._advance`` with each row's
+true length): ``--live-share`` makes that length a share of the tokens,
+the rest a bucket's padding (1.0: a bucket that is full, told so), one
+pass a share: what a scan that stops at the last live chunk saves, and
+what its bound costs a bucket that has no padding.
 
-Usage:  python -m cake_tpu.tools.kda_sweep [--chunk [--blocks 8,16,32]]
+Usage:  python -m cake_tpu.tools.kda_sweep [--chunk [--blocks 8,16,32]
+                                           [--live-share 1.0,0.67]]
                                            [--json-out PATH]
 (``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
@@ -23,8 +29,9 @@ Prints one JSON line per shape: ``{"batch", "layers", "heads", "d",
 "head_block", "xla_us_per_layer", "kernel_us_per_layer", "speedup",
 "kernel_hbm_share"}`` (the share: one read and one write of the state and
 the step's vectors over 819 GB/s over the kernel's time); with ``--chunk``
-``{"decay", "batch", "tokens", "key_heads", "heads", "d", "block",
-"us_per_layer", "us_per_chunk"}``.
+``{"decay", "batch", "tokens", "live_share", "key_heads", "heads", "d",
+"block", "us_per_layer", "us_per_chunk"}`` (a chunk: one of the bucket's,
+live or not).
 """
 
 from __future__ import annotations
@@ -117,9 +124,11 @@ def rows():
                    "kernel_hbm_share": round(100 * floor_us / kernel, 1)}
 
 
-def _chunk_us(decay, b, t, hk, hv, d, iters: int = 5) -> float:
-    """Microseconds a layer of ``kda_chunk`` over ``t`` tokens, the layers'
-    states carried and donated as an admission carries them."""
+def _chunk_us(decay, b, t, hk, hv, d, live_share: float = 1.0,
+              iters: int = 5) -> float:
+    """Microseconds a layer of ``kda_chunk`` over ``t`` tokens of which
+    each row's leading ``live_share`` are true, the layers' states carried
+    and donated as an admission carries them."""
     keys = jax.random.split(jax.random.PRNGKey(0), 6)
     q, k = (kda._l2norm(jax.random.normal(kk, (b, t, hk, d), jnp.float32))
             * scale for kk, scale in zip(keys[:2], (d ** -0.5, 1.0)))
@@ -129,30 +138,34 @@ def _chunk_us(decay, b, t, hk, hv, d, iters: int = 5) -> float:
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, t, hv)))
     state = jax.random.normal(keys[5], (CHUNK_LAYERS, b, hv, d, d),
                               jnp.float32)
+    valid = jnp.full((b,), round(t * live_share), jnp.int32)
 
-    def layers(state, q, k, v, g, beta):
+    def layers(state, q, k, v, g, beta, valid):
         def layer(acc, s0):
-            o, s1 = kda.kda_chunk(q, k, v, g, beta, s0)
+            o, s1 = kda._advance(q, k, v, g, beta, s0, valid, None, "kda")
             return acc + o, s1
 
         acc, state = jax.lax.scan(layer, jnp.zeros_like(v), state)
         return state, acc
 
     fn = jax.jit(layers, donate_argnums=(0,))
-    return _call_us(fn, state, (q, k, v, g, beta), iters) / CHUNK_LAYERS
+    return _call_us(fn, state, (q, k, v, g, beta, valid),
+                    iters) / CHUNK_LAYERS
 
 
-def chunk_rows(blocks):
+def chunk_rows(blocks, live_shares=(1.0,)):
     for block in blocks:
         if block:
             kda.INVERSE_BLOCK = block  # read when ``kda_chunk`` is traced,
             jax.clear_caches()  # which a trace kept for these shapes is not
         for decay, b, t, hk, hv, d in CHUNK_SHAPES:
-            us = _chunk_us(decay, b, t, hk, hv, d)
-            yield {"decay": decay, "batch": b, "tokens": t, "key_heads": hk,
-                   "heads": hv, "d": d, "block": block or None,
-                   "us_per_layer": round(us, 1),
-                   "us_per_chunk": round(us / -(-t // kda.CHUNK), 2)}
+            for share in live_shares:
+                us = _chunk_us(decay, b, t, hk, hv, d, share)
+                yield {"decay": decay, "batch": b, "tokens": t,
+                       "live_share": share, "key_heads": hk, "heads": hv,
+                       "d": d, "block": block or None,
+                       "us_per_layer": round(us, 1),
+                       "us_per_chunk": round(us / -(-t // kda.CHUNK), 2)}
 
 
 def main() -> int:
@@ -163,12 +176,16 @@ def main() -> int:
                     help="time the admission's chunk form, not the step")
     ap.add_argument("--blocks", default="8,16,32",
                     help="--chunk: diagonal block sizes (0: the checkout's)")
+    ap.add_argument("--live-share", default="1.0",
+                    help="--chunk: the share of each row's tokens that "
+                         "are true, the rest a bucket's padding")
     ap.add_argument("--json-out")
     a = ap.parse_args()
     configure()
     refuse_offchip_record(a.json_out)
     out = []
-    for row in (chunk_rows([int(x) for x in a.blocks.split(",")])
+    for row in (chunk_rows([int(x) for x in a.blocks.split(",")],
+                           [float(x) for x in a.live_share.split(",")])
                 if a.chunk else rows()):
         print(json.dumps(row), flush=True)
         out.append(row)

@@ -4,20 +4,27 @@ Times :func:`cake_tpu.ops.moe.moe_swiglu` in the two forms
 :func:`cake_tpu.ops.moe.expert_form` chooses between for more than a
 handful of pairs, as the layer loop calls it (a scan over ``L`` layers:
 the dense form on the scan's slice of the stacks, the sorted form on the
-whole stacks with the layer's index), at the seven expert cells' shapes
+whole stacks with the layer's index), at the eight expert cells' shapes
 for 8 to 2048 rows: a decode step's rows (one a slot) and an admission's
 buckets. Where the sorted form is at least 1.10x the dense one is where
 the rule's constants come from: ``SORTED_MAX_HIT_SHARE*`` (the share of
 the experts a call of few rows may hit and still be sorted) and
 ``SORTED_MIN_ROWS*`` (PERF.md keeps the table). ``--row-tile`` times the
 sorted form at other row tiles of the kernel than the program's.
+``--valid-share`` tells the block that this share of the rows, the
+leading ones, are true tokens and the rest a bucket's padding
+(``moe_swiglu``'s ``valid``; 1.0: a bucket that is full, told so), one
+pass a share: what the sorted form saves of a bucket's padding, and what
+being told costs a bucket that has none.
 
 Usage:  python -m cake_tpu.tools.moe_sweep [--only NAME] [--rows 64,512]
-            [--forms dense,sorted,compact] [--row-tile 32,128] [--json-out PATH]
+            [--forms dense,sorted,compact] [--row-tile 32,128]
+            [--valid-share 1.0,0.67] [--json-out PATH]
 (``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
-Prints one JSON line per shape, row count and row tile: ``{"shape",
-"rows", "row_tile", "<form>_us_per_layer", "<form>_roofline",
+Prints one JSON line per shape, row count, row tile and share:
+``{"shape", "rows", "row_tile", "valid_share", "<form>_us_per_layer",
+"<form>_roofline",
 "<form>_moved_mb", "speedup"}``: a form's share of max(the chosen held
 experts' bytes / 819 GB/s, the routed pairs' operations / 197 TFLOP/s)
 (what the block needs whichever form computes it; the router's weights
@@ -30,6 +37,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import itertools
 import json
 import sys
 import time
@@ -53,6 +61,7 @@ SHAPES = {
     "xing4-29b": (64, 64, 4, 3584, 1024, False, (1, 1)),
     # softmax over the chosen logits (Mixtral's convention)
     "mellum2-12b": (64, 64, 8, 2304, 896, False, None),
+    "qwen3next-ep4": (128, 512, 10, 2048, 512, False, None),
 }
 ROWS = (8, 16, 32, 64, 128, 256, 512, 1024, 2048)
 LAYERS = 3
@@ -103,16 +112,17 @@ def _layers_fn(form, name):
     routing = moe.GroupRouting(*groups, True, 2.5) if groups else None
     share = None if held == scored else (0, held)
 
-    def fn(x, router, w_gate, w_up, w_down):
+    def fn(x, valid, router, w_gate, w_up, w_down):
         def body(acc, per_layer):
             if form == "dense":  # the stacks are the scan's slices
                 r, g, u, d = per_layer
                 y = moe.moe_swiglu(x, r, g, u, d, top_k, routing=routing,
-                                   held=share)
+                                   held=share, valid=valid)
             else:  # the whole stacks and an index
                 r, i = per_layer
                 y = moe.moe_swiglu(x, r, w_gate, w_up, w_down, top_k,
-                                   routing=routing, held=share, layer=i)
+                                   routing=routing, held=share, layer=i,
+                                   valid=valid)
             return acc + y, None
 
         xs = ((router, w_gate, w_up, w_down) if form == "dense" else
@@ -122,16 +132,18 @@ def _layers_fn(form, name):
     return jax.jit(fn)
 
 
-def _time_us(form, name, rows, weights, row_tile, iters: int = 5) -> float:
+def _time_us(form, name, rows, weights, row_tile, valid_share: float = 1.0,
+             iters: int = 5) -> float:
     hidden = SHAPES[name][3]
     x = jax.random.normal(jax.random.PRNGKey(rows), (1, rows, hidden),
                           jnp.bfloat16)
+    valid = jnp.asarray([round(rows * valid_share)], jnp.int32)
     fn = _layers_fn(form, name)
     with _steered(form, row_tile):
-        jax.block_until_ready(fn(x, *weights))  # trace and compile
+        jax.block_until_ready(fn(x, valid, *weights))  # trace and compile
     t0 = time.perf_counter()
     for _ in range(iters):
-        out = fn(x, *weights)
+        out = fn(x, valid, *weights)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) * 1e6 / (iters * LAYERS)
 
@@ -179,21 +191,24 @@ def moved_bytes(form: str, name: str, rows: int,
         + live * hidden * 4 + rows * hidden * act)  # combined
 
 
-def sweep(names, row_counts, forms, row_tiles):
-    """A row per shape, row count and row tile (the dense form has no
-    tile: it is timed once a row count and stands in each tile's row)."""
+def sweep(names, row_counts, forms, row_tiles, valid_shares=(1.0,)):
+    """A row per shape, row count, row tile and share of true rows (the
+    dense form has no tile and skips no padding: it is timed once a row
+    count and stands in each tile's and share's row; the floor and the
+    bytes moved are the whole bucket's at every share)."""
     for name in names:
         held, scored, _, hidden, width, int8, _ = SHAPES[name]
         weights = _weights(jax.random.PRNGKey(0), LAYERS, held, scored,
                            hidden, width, int8)
         for rows in row_counts:
             timed = {}
-            for tile in row_tiles:
-                row = {"shape": name, "rows": rows, "row_tile": tile}
+            for tile, share in itertools.product(row_tiles, valid_shares):
+                row = {"shape": name, "rows": rows, "row_tile": tile,
+                       "valid_share": share}
                 for form in forms:
                     if form != "dense" or form not in timed:
                         timed[form] = _time_us(form, name, rows, weights,
-                                               tile)
+                                               tile, share)
                     us = timed[form]
                     row[f"{form}_us_per_layer"] = round(us, 1)
                     row[f"{form}_roofline"] = round(
@@ -218,13 +233,16 @@ def main() -> int:
                     default=["dense", "sorted"])
     ap.add_argument("--row-tile", type=lambda s: [int(v) for v in s.split(",")],
                     default=[pk.MOE_ROW_TILE])
+    ap.add_argument("--valid-share", default=[1.0],
+                    type=lambda s: [float(v) for v in s.split(",")],
+                    help="the share of a call's rows that are true tokens")
     ap.add_argument("--json-out")
     a = ap.parse_args()
     configure()
     refuse_offchip_record(a.json_out)
     out = []
     for row in sweep([a.only] if a.only else list(SHAPES), a.rows, a.forms,
-                     a.row_tile):
+                     a.row_tile, a.valid_share):
         print(json.dumps(row), flush=True)
         out.append(row)
     if a.json_out:

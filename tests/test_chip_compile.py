@@ -330,8 +330,13 @@ PR31_TEXTS = {
     # not read the state made ahead of the scan over the chunks where they
     # fit, as they do at this fixture's widths; the same tests, and
     # tests/test_kda_hybrid.py ``test_kda_chunk_is_the_recurrence``); the
-    # decode step's text is what it was
-    "hybrid.decode": "dd65de518af3a6cb", "hybrid.admit": "0754319899298397",
+    # decode step's text is what it was; and by PR 60, on purpose: the
+    # serial loop over the chunks runs to the launch's last live chunk
+    # (``_advance`` counts them from ``valid``; a ``fori_loop`` whose
+    # bound is data where the scan was, over the same ``advance``: tests/
+    # test_qwen3_next.py
+    # ``test_scan_that_stops_at_the_last_live_chunk_is_the_whole_scan``)
+    "hybrid.decode": "dd65de518af3a6cb", "hybrid.admit": "4f52b8ee0ff2bed8",
     # the state-space family, taken on PR 40's tree (commit d2e802e): its
     # attention layers pass through ``_project_heads`` with no norm and no
     # rotation behind the products, where PR 41 puts no barrier (the chip's
@@ -353,6 +358,11 @@ PR31_TEXTS = {
     # family above to the text it had: no hash replaced)
     "latent_hc.decode": "64178e4935b87604",
     "latent_hc.admit": "17ac986347105f98",
+    # PR 60 (an admission tells its expert blocks and its chunked delta
+    # rule the rows' true lengths) replaced the hybrid's admission alone:
+    # a decode program is told no length, and at these 16 rows, without
+    # kernels, no expert block takes the sorted form, the other place
+    # that reads one
 }
 
 

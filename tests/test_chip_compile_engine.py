@@ -349,7 +349,12 @@ def test_two_rotation_programs_band_through_the_kernel_and_fit(topo,
     two attention kernels (one a kind of layer) and three grouped products
     a sparse segment. The step's full layers read their rows through
     ``flash_decode``, its window layers sweep their rings in XLA. Neither
-    kind of row buffer is copied in the step."""
+    kind of row buffer is copied in the step. Since PR 60 the admissions
+    tell their expert blocks the rows' true lengths (``moe_swiglu``'s
+    ``valid``: one select on what XLA gathers, where every expert is
+    held). RECORDED (my AOT compiles, PR 60): 1.1810 GiB of temporaries
+    in the 8192-row admission against the parent's 1.1807, 0.0137 at 256
+    rows on both."""
     from cake_tpu.models.config import mellum2_12b
     from cake_tpu.utils.chips import HBM_GIB
 
@@ -380,7 +385,7 @@ def test_two_rotation_programs_band_through_the_kernel_and_fit(topo,
     small, large = (a.memory_analysis().temp_size_in_bytes
                     for a in (admit256, admit8192))
     assert small < 0.1 * GIB, small / GIB
-    assert large < 1.5 * GIB, large / GIB
+    assert large < 1.19 * GIB, large / GIB
     assert args + temps + large + 0.1 * GIB < 11 / 16 * HBM_GIB["v5 lite"] * GIB
 
 

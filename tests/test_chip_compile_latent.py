@@ -199,7 +199,13 @@ def test_hybrid_programs_move_no_cache_no_state_and_no_expert_stack(
     and layer), and the channel case makes the inverse inside the scan, a
     chunk at a time (a ``[C, C, d_k]`` decay is 67 MB a chunk here).
     RECORDED (my AOT compiles, PR 58): the 512-row admission, the cell's
-    largest bucket, 0.3100 GiB of temporaries (0.3095 with the solve)."""
+    largest bucket, 0.3100 GiB of temporaries (0.3095 with the solve).
+    Since PR 60 the loop over the chunks runs to the launch's last live
+    chunk, a bound that is data, inside the scanned layer body (it takes
+    no layer weight and carries none of the four cache buffers: the
+    assertions below hold of the admission as they did), and the expert
+    block is told the rows' true lengths. RECORDED (my AOT compiles, PR
+    60): 0.3102 GiB against the parent's 0.3101."""
     from cake_tpu.utils.chips import HBM_GIB
 
     layers, slots, window = 7, 32, 4096
@@ -241,4 +247,4 @@ def test_hybrid_programs_move_no_cache_no_state_and_no_expert_stack(
     assert args + temps + 0.55 * GIB + 0.5 * GIB < HBM_GIB["v5 lite"] * GIB
     assert "triangular" not in admit.as_text().lower()
     m = admit.memory_analysis()
-    assert m.temp_size_in_bytes < 0.35 * GIB, m.temp_size_in_bytes / GIB
+    assert m.temp_size_in_bytes < 0.315 * GIB, m.temp_size_in_bytes / GIB

@@ -102,7 +102,16 @@ def test_gated_delta_programs_fit_and_repeat_no_period(topo, as_on_chip):
     ``_unit_lower_inverse`` makes every chunk's inverse by products ahead
     of it). RECORDED (my AOT compiles, PR 58): what the 8192-row bucket's
     128 chunks hold ahead of the scan lifts its temporaries from 0.947 to
-    1.403 GiB (0.091 -> 0.068 at 128 rows, 0.132 -> 0.120 at 1024)."""
+    1.403 GiB (0.091 -> 0.068 at 128 rows, 0.132 -> 0.120 at 1024).
+    Since PR 60 the loop over the chunks runs to a bound that is data (the
+    launch's last live chunk) inside the scanned layer body, and the
+    expert block is told the rows' true lengths: the loop takes no layer
+    weight and carries no cache buffer (no expert stack, state or tail is
+    written out or copied in the 8192-row admission either). RECORDED (my
+    AOT compiles, PR 60): 1.3332 GiB of temporaries at 8192 rows against
+    the parent's 1.3327 (the loop's ``o`` is written in place into the one
+    ``[n, ..]`` buffer it carries, which nothing fills first), 0.068 at
+    128 on both."""
     from cake_tpu.models.config import qwen3next_ep4
     from cake_tpu.utils.chips import HBM_GIB
 
@@ -117,8 +126,11 @@ def test_gated_delta_programs_fit_and_repeat_no_period(topo, as_on_chip):
     for shape in (f"bf16[2,{slots},2,{window},256]",
                   f"f32[6,{slots},32,128,128]", f"bf16[6,{slots},3,8192]"):
         assert _cache_sized_moves(decode, shape) == [], shape
-    for compiled in (decode, admit):
+    for compiled in (decode, admit, widest):
         assert _expert_stack_moves(compiled, "bf16", 128, 2048, 512) == []
+    for shape in ("f32[6,1,32,128,128]", "bf16[6,1,3,8192]",
+                  f"bf16[2,1,2,{window},256]"):
+        assert _cache_sized_moves(widest, shape) == [], shape
     calls = [line for line in decode.as_text().splitlines()
              if "custom-call(" in line and "tpu_custom_call" in line]
     assert sum("kda_decode" in c for c in calls) == 2  # one a D D D segment
@@ -134,7 +146,7 @@ def test_gated_delta_programs_fit_and_repeat_no_period(topo, as_on_chip):
     small, large = (a.memory_analysis().temp_size_in_bytes
                     for a in (admit, widest))
     assert small < 0.3 * GIB, small / GIB
-    assert large < 1.5 * GIB, large / GIB
+    assert large < 1.34 * GIB, large / GIB  # the parent's: 1.3327
     assert args + temps + large + 0.4 * GIB < HBM_GIB["v5 lite"] * GIB
 
 

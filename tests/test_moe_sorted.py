@@ -297,3 +297,71 @@ def test_decode_shaped_calls_keep_their_form(rows, top_k, int8, held, scored,
     monkeypatch.setenv("CAKE_PALLAS", "0")
     assert expert_form(rows, top_k, int8, held, scored) == (
         "dense" if form == "sorted" else form)
+
+
+# name -> (held, scored, top_k, first held): every scored expert here (XLA
+# gathers and sums, :func:`cake_tpu.ops.moe.compacts` False) or a share of
+# them (the live tiles' kernels do)
+VALID_SHAPES = {"all-held": (8, 8, 2, 0), "compact": (4, 16, 4, 4)}
+
+
+@pytest.mark.parametrize("launch", ["one-row", "two-rows"])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+@pytest.mark.parametrize("shape", list(VALID_SHAPES))
+def test_a_buckets_padding_is_nothing_of_the_sorted_forms(
+        shape, kind, launch, kernels):
+    """``valid [B]``: a token at or past its row's true length counts as a
+    pair on an absent expert does. Every true row's result is what it is
+    without ``valid``, bit for bit; every padding row's is exactly zero,
+    and finite where the padding rows of ``x`` are NaN (their routing
+    weights are NaN then, and what the all-held form reads for their
+    pairs was never written: selected away, not multiplied); the live row
+    tiles hold the true pairs on held experts alone; and a bucket that is
+    all true (``valid == T``) is the call without ``valid``."""
+    from cake_tpu.ops.quant import quantize_linear
+
+    held, scored, top_k, first = VALID_SHAPES[shape]
+    b, t, valid = {"one-row": (1, 512, [300]),
+                   "two-rows": (2, 256, [70, 201])}[launch]
+    h, f = 32, 64
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    x = jax.random.normal(ks[0], (b, t, h)).astype(jnp.bfloat16)
+    rw = jax.random.normal(ks[1], (h, scored)).astype(jnp.bfloat16)
+    stacks = [jax.random.normal(k, shp) / d for k, shp, d in (
+        (ks[2], (held, h, f), 4), (ks[3], (held, h, f), 4),
+        (ks[4], (held, f, h), 6))]
+    stacks = [jax.vmap(quantize_linear)(w) if kind == "int8"
+              else w.astype(jnp.bfloat16) for w in stacks]
+    kw = dict(top_k=top_k, count_local=True,
+              held=None if held == scored else (first, held))
+
+    def run(x, valid):
+        out, count = moe_swiglu(
+            x, rw, *stacks, **kw,
+            valid=None if valid is None else jnp.asarray(valid, jnp.int32))
+        assert moe.form_traced(b * t) == "sorted"
+        return np.asarray(out, np.float32), int(count.live_rows)
+
+    whole, whole_live = run(x, None)
+    got, live = run(x, valid)
+    true = np.arange(t)[None] < np.asarray(valid)[:, None]  # [B, T]
+    np.testing.assert_array_equal(got[true], whole[true])
+    assert (got[~true] == 0).all()
+    _, _, idx = router_topk(x.reshape(b * t, h), rw, top_k)
+    local = np.asarray(idx).reshape(b, t, top_k) - first
+    on_held = (local >= 0) & (local < held)
+    assert whole_live == -(-on_held.sum() // 128) * 128
+    assert live == -(-on_held[true].sum() // 128) * 128 < whole_live
+    # NaN where nobody reads
+    poisoned, poisoned_live = run(
+        jnp.where(jnp.asarray(true)[..., None], x, jnp.nan), valid)
+    assert poisoned_live == live
+    assert (poisoned[~true] == 0).all()
+    if not moe.compacts(held, scored):
+        # (the kernel that gathers the live tiles' rows picks them by a
+        # one-hot product over all of ``x``: 0 x NaN)
+        np.testing.assert_array_equal(poisoned[true], whole[true])
+    # a bucket with no padding: the call it was
+    full, full_live = run(x, [t] * b)
+    np.testing.assert_array_equal(full, whole)
+    assert full_live == whole_live

@@ -153,6 +153,64 @@ def test_engine_counts_the_pair_rows_the_sorted_form_touches(monkeypatch):
     assert 0 <= live.value - before[1] <= steps * layers * 128
 
 
+@pytest.mark.parametrize("tokens", [300, 513])
+@pytest.mark.parametrize("name", ["all-held", "share"])
+def test_an_admission_told_its_length_starts_as_it_did(name, tokens,
+                                                       monkeypatch):
+    """A prompt of 300 tokens in its 512-row bucket and one of 513 in its
+    1024-row one, through the admission program of a model that holds
+    every scored expert (the cache holds rows alone: the expert block is
+    told the length all the same) and of one told its share (4 of 16: the
+    live tiles' kernels): the logits at the prompt's last token are, bit
+    for bit, those of the program that tells its expert blocks nothing
+    (the parent's: ``llama.true_rows`` gives them no length), while the
+    sorted form touches fewer pair rows; and the engine's first token is
+    their argmax."""
+    import jax.numpy as jnp
+
+    from cake_tpu.models.config import tiny_mla_moe
+    from cake_tpu.ops.kvcache import init_cache
+    from cake_tpu.parallel.mesh import MeshPlan
+    from cake_tpu.parallel.pipeline import build_admit_prefill
+    from cake_tpu.runtime.batch_generator import BatchGenerator
+
+    monkeypatch.setenv("CAKE_PALLAS", "1")
+    cfg = (tiny_moe(max_seq_len=1024, eos_token_id=-1) if name == "all-held"
+           else tiny_mla_moe(max_seq_len=1024, eos_token_id=-1,
+                             n_routed_experts=4, router_experts=16,
+                             first_expert=4))
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    prompt = [t * 7 % 250 + 1 for t in range(tokens)]
+    bucket = 512 if tokens <= 512 else 1024
+    # (a bucket's padding is whatever lies there: ids the router spreads)
+    padded = jnp.asarray([prompt + [t * 11 % 250 + 1 for t in range(
+        bucket - tokens)]], jnp.int32)
+    plan = MeshPlan.build(cfg, devices=jax.devices()[:1])
+
+    def admitted():
+        logits, _, *rows = build_admit_prefill(cfg, plan, params_like=params)(
+            params, padded, init_cache(cfg, batch=1, max_seq=1024),
+            jnp.int32(0), jnp.asarray([tokens - 1], jnp.int32))
+        assert moe.form_traced(bucket) == "sorted"
+        return np.asarray(logits), [int(r) for r in rows]
+
+    told, told_rows = admitted()
+    real = llama.true_rows
+    with monkeypatch.context() as mp:
+        mp.setattr(llama, "true_rows", lambda *a: (real(*a)[0], None))
+        untold, untold_rows = admitted()
+    np.testing.assert_array_equal(told, untold)
+    if name == "share":  # it counts: as many handed, fewer touched
+        assert told_rows[0] == untold_rows[0]
+        assert told_rows[1] < untold_rows[1]
+    bg = BatchGenerator(cfg, params, max_seq=1024, settings=SamplerSettings(
+        temperature=0.0, repeat_penalty=1.0))
+    bg.set_prompts([[3, 5, 7], [2, 4]], stream_ids=[0, 1])
+    assert bg.finish(1)
+    _, first = bg.admit(prompt, stream_id=2)
+    assert first.id == int(untold[0].argmax())
+
+
 def test_moe_sweep_rows_at_tiny_shapes(monkeypatch, kernels):
     """tools/moe_sweep.py's machinery on the CPU (interpreted kernel, no
     device time): a row per shape and row count, each form timed through
@@ -177,7 +235,32 @@ def test_moe_sweep_rows_at_tiny_shapes(monkeypatch, kernels):
         assert (r["sorted_moved_mb"] == r["compact_moved_mb"]) == (
             r["shape"] == "tiny-int8-share")
     assert out[3]["sorted_moved_mb"] < out[3]["dense_moved_mb"]
+    assert {r["valid_share"] for r in out} == {1.0}
+    # ``--valid-share``: a row a share, the block told that the leading
+    # share of its rows is true (the dense form, which skips nothing, is
+    # timed once and stands in both)
+    out = list(moe_sweep.sweep(["tiny-int8-share"], [128],
+                               ["dense", "sorted"], [128], [1.0, 0.5]))
+    assert [r["valid_share"] for r in out] == [1.0, 0.5]
+    assert out[0]["dense_us_per_layer"] == out[1]["dense_us_per_layer"]
+    assert all(r["sorted_us_per_layer"] > 0 for r in out)
     assert moe.expert_form is expert_form and moe.compacts is compacts
+
+
+def test_kda_sweep_chunk_rows_at_tiny_shapes(monkeypatch):
+    """tools/kda_sweep.py ``--chunk`` on the CPU (no device time): a row a
+    shape and share of true tokens, the chunk form entered through
+    ``_advance`` with each row's length as a layer enters it."""
+    from cake_tpu.tools import kda_sweep
+
+    monkeypatch.setattr(kda_sweep, "CHUNK_SHAPES", (
+        ("scalar", 1, 160, 2, 4, 16), ("channel", 2, 96, 2, 2, 16)))
+    monkeypatch.setattr(kda_sweep, "CHUNK_LAYERS", 2)
+    out = list(kda_sweep.chunk_rows([0], [1.0, 0.4]))
+    assert [(r["decay"], r["tokens"], r["live_share"]) for r in out] == [
+        ("scalar", 160, 1.0), ("scalar", 160, 0.4), ("channel", 96, 1.0),
+        ("channel", 96, 0.4)]
+    assert all(r["us_per_layer"] > 0 and r["block"] is None for r in out)
 
 
 @pytest.mark.parametrize("form,hit,want", [

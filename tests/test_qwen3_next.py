@@ -279,6 +279,48 @@ def test_chunk_form_is_the_recurrence(hk, hv, scalar, case):
     np.testing.assert_allclose(s, s_want, atol=5e-6, rtol=0)
 
 
+@pytest.mark.parametrize("valid", [(130, 40), (0, 0), (290, 7)],
+                         ids=["uneven", "no-token", "whole"])
+@pytest.mark.parametrize("ahead", ["hoisted", "in-the-scan"])
+@pytest.mark.parametrize("hk, hv, scalar", RULES[:2], ids=RULE_IDS[:2])
+def test_scan_that_stops_at_the_last_live_chunk_is_the_whole_scan(
+        hk, hv, scalar, ahead, valid, monkeypatch):
+    """``_advance`` hands ``kda_chunk`` the chunks that hold a true token
+    of some row of the launch, and the serial loop runs that many of the
+    300 (290) tokens' five (the last a padded one): three where the rows
+    hold 130 and 40 tokens, none where they hold none, all five where one
+    row fills the bucket. Against the scan over all five on the same masked
+    inputs, for both decays and both placements of what a chunk makes
+    ahead of the state (every chunk at once, or a chunk at a time inside
+    the loop: ``HOIST_BYTES``): the state it leaves and ``o`` on every
+    true row are EQUAL, and ``o`` past the last live chunk is zero."""
+    # (a length of its own a placement: JAX keeps a trace by its shapes,
+    # and ``HOIST_BYTES`` is read when ``kda_chunk`` is traced)
+    t = 300 if ahead == "hoisted" else 290
+    q, k, v, g, beta, s0 = _rule_inputs(hk, hv, scalar, t=t)
+    lengths = jnp.asarray(valid, jnp.int32)
+    true = np.arange(t)[None] < np.asarray(valid)[:, None]  # [B, T]
+    mask = jnp.asarray(true)
+    masked = (jnp.where(mask.reshape(mask.shape + (1,) * (g.ndim - 2)), g, 0),
+              jnp.where(mask[..., None], beta, 0))
+    if ahead == "in-the-scan":
+        monkeypatch.setattr(kda, "HOIST_BYTES", 0)
+    o_want, s_want = kda.kda_chunk(q, k, v, *masked, s0)
+    o, s = kda._advance(q, k, v, g, beta, s0, lengths, None, "gdn")
+    text = str(jax.make_jaxpr(kda.kda_chunk)(
+        q, k, v, *masked, s0, jnp.int32(1)))
+    # a loop whose bound is data (and a second that zeroes ``o`` past it),
+    # the chunks' inverse called ahead of them or in the first one's body
+    assert text.count("while[") == 2
+    assert (text.index("name=_unit_lower_inverse") < text.index("while[")
+            ) == (ahead == "hoisted")
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(s_want))
+    np.testing.assert_array_equal(np.asarray(o)[true], np.asarray(o_want)[true])
+    swept = -(-max(valid) // kda.CHUNK) * kda.CHUNK
+    assert not np.asarray(o)[:, swept:].any()
+    assert np.isfinite(np.asarray(o)).all()
+
+
 @pytest.mark.parametrize("rows, block", [(64, 8), (64, 16), (64, 32),
                                          (40, 16), (5, 16)])
 @pytest.mark.parametrize("keys", ["random", "near", "same"])

@@ -113,11 +113,13 @@ def test_batch_generator_streams_and_a_reused_slot_match_reference(params,
     assert resets.value - before[2] == 1
 
 
-def test_a_buckets_padding_costs_chunks_of_the_scan(params):
+def test_a_launchs_longest_row_sets_the_chunks_of_the_scan(params):
     """An admission of 70 tokens in ONE dispatch of its 128-row bucket
     sweeps two chunks a layer and both are live; one of 12 tokens beside it
     in a two-row program sweeps two a row where one of the short row's
-    holds a token."""
+    holds a token (the scan is serial over the launch's rows: it stops at
+    the longest's last live chunk); and the same two rows in a 256-row
+    bucket sweep no more: a bucket's padding costs no chunk."""
     from cake_tpu.runtime import batch_generator as engine
 
     reg = metrics.registry()
@@ -129,11 +131,17 @@ def test_a_buckets_padding_costs_chunks_of_the_scan(params):
     assert (swept.value - before[0], live.value - before[1]) == (12, 12)
     bg._count_delta_chunks(128, [70, 12])
     assert (swept.value - before[0], live.value - before[1]) == (36, 30)
+    bg._count_delta_chunks(256, [70, 12])
+    assert (swept.value - before[0], live.value - before[1]) == (60, 48)
+    # a later chunk of a chunked admission: a row that ended before it
+    # holds nothing of it, the other's 200 left fill its four chunks
+    bg._count_delta_chunks(256, [200, -30])
+    assert (swept.value - before[0], live.value - before[1]) == (108, 72)
     assert engine._DELTA_CHUNKS_SWEPT is swept
     # a model without delta-rule layers counts nothing
     bg._delta_layers = 0
     bg._count_delta_chunks(128, [70])
-    assert swept.value - before[0] == 36
+    assert swept.value - before[0] == 108
 
 
 # -- the configuration, the plan, the budget, the loaders -----------------------
