@@ -260,6 +260,23 @@ class LlamaConfig:
     linear_conv_kernel_dim: int = 4
     rope_fraction: float = 1.0
     shared_expert_gate: bool = False
+    # --- a learned sparse attention over the latent cache (DeepSeek sparse
+    # attention; HF `model_type` "glm_moe_dsa", beside the latent family's
+    # keys) ------------------------------------------------------------------
+    # ``index_topk`` K > 0: every latent layer holds an indexer
+    # (ops/dsa.py): ``index_n_heads`` queries of ``index_head_dim`` from the
+    # query latent, ONE key of ``index_head_dim`` a token (a LayerNorm with
+    # a bias behind its projection), the first ``qk_rope_head_dim`` channels
+    # of each rotated by the latent attention's tables, and a weight a head
+    # from the hidden state. A query's index score of row ``s`` is ``sum_j
+    # w_j relu(q_j . k_s)`` in float32; it attends the K rows of highest
+    # score among those at or before it (all of them up to K rows; a tie
+    # goes to the lower row). The cache keeps the key beside the latent
+    # row: a third kind of row (``cache_plan``'s ``index``). 0: plain
+    # latent attention.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -270,6 +287,7 @@ class LlamaConfig:
         self.family.check(self)
         families.check_residual_path(self)
         families.check_gated_keys(self)
+        families.check_indexer(self)
         # validate at construction, not as a KeyError deep in a jit trace
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
@@ -329,9 +347,13 @@ class LlamaConfig:
     @property
     def cache_token_bytes(self) -> int:
         """Bytes the ``rows`` of ``cache_plan`` hold for one token of one
-        stream, in the serving type: every plane's keys and values."""
-        planes = self.cache_plan.get("rows", (0,))[0]
-        return planes * self.cache_row_values * self.jax_dtype.itemsize
+        stream, in the serving type: every plane's keys and values, and a
+        sparse attention's index key beside each (``index``)."""
+        plan = self.cache_plan
+        planes = plan.get("rows", (0,))[0]
+        layers, heads, width = plan.get("index", (0, 0, 0))
+        return (planes * self.cache_row_values
+                + layers * heads * width) * self.jax_dtype.itemsize
 
     @property
     def resid_token_bytes(self) -> int:
@@ -417,7 +439,10 @@ class LlamaConfig:
         out. Where the layers run ``total_ut_steps`` times a token, ``rows``
         counts a plane a layer AND a pass (layer ``i`` in pass ``u`` is
         plane ``u * num_hidden_layers + i``): the cache's depth is the
-        plan's, not ``num_hidden_layers``."""
+        plan's, not ``num_hidden_layers``. Under a learned sparse attention
+        (``index_topk`` > 0) every latent layer keeps ``index`` ``(layers,
+        1, index_head_dim)`` beside its rows: the indexer's one key a
+        token, normed and rotated, in the serving type."""
         mixers = [m for m, _ in self.layer_kinds]
         recurrent = self.family.recurrent_mixer
         held = mixers.count(recurrent)
@@ -427,6 +452,8 @@ class LlamaConfig:
             # a looped model keeps a plane a layer AND a pass
             plan["rows"] = ((len(mixers) - held - ring) * self.total_ut_steps,
                             ) + self.cache_row
+        if self.index_topk:
+            plan["index"] = (mixers.count("mla"), 1, self.index_head_dim)
         if ring:
             heads, *widths = self.cache_row
             plan["ring"] = (ring, heads, self.ring_rows, *widths)
@@ -464,7 +491,18 @@ class LlamaConfig:
         takes the cache's row from. Grouped-query attention keeps keys and
         values per KV head; latent attention keeps the normed latent (in
         ``k``) and the roped shared key part (in ``v``), once for all
-        heads."""
+        heads, or both in one row of ``k`` under a learned sparse attention
+        (``index_topk`` > 0)."""
+        if self.kv_lora_rank and self.index_topk:
+            # under a learned sparse attention a step GATHERS its chosen
+            # rows, and a gather costs a row whatever its width: the latent
+            # and the roped part lie in ONE row of the first buffer (the
+            # second holds nothing), so that a chosen row is fetched once;
+            # padded to whole lane tiles of 128 (576 -> 640), which the
+            # chip otherwise lays out rows-on-lanes and re-lays for every
+            # program that gathers from it (PERF.md section 7)
+            width = self.kv_lora_rank + self.qk_rope_head_dim
+            return 1, -(-width // 128) * 128, 0
         if self.kv_lora_rank:
             return 1, self.kv_lora_rank, self.qk_rope_head_dim
         return self.num_key_value_heads, self.head_dim, self.head_dim
@@ -872,6 +910,59 @@ def xing4_29b(**overrides) -> LlamaConfig:
     return LlamaConfig(**base)
 
 
+def glm5_ep16(**overrides) -> LlamaConfig:
+    """GLM-5 (https://huggingface.co/zai-org/GLM-5, `model_type`
+    "glm_moe_dsa": DeepSeek-V3's keys under a learned sparse attention) at
+    its published widths, as ONE chip of 16 that share each layer's 256
+    experts holds it: global experts 0-15 beside the whole router,
+    attention, indexer and shared expert. 78 layers of latent attention
+    (64 heads of 192 + 64 query/key and 256 value channels, plain rope)
+    whose queries attend the 2048 rows a 32-head indexer of 128 scores
+    highest; three leading dense layers (12288), then bias-corrected
+    sigmoid-scored experts (2048) top-8 in one group beside a shared one;
+    an untied head. A chip serves the depth of its pipeline stage
+    (`num_hidden_layers=`, `first_k_dense_replace=`) and its slice of the
+    vocabulary (`vocab_size=`)."""
+    base = dict(
+        model_type="glm_moe_dsa",
+        vocab_size=154880,
+        hidden_size=6144,
+        intermediate_size=12288,
+        num_hidden_layers=78,
+        num_attention_heads=64,
+        num_key_value_heads=64,
+        head_dim=64,
+        rms_norm_eps=1e-5,
+        rope_theta=1000000.0,
+        max_seq_len=202752,
+        q_lora_rank=2048,
+        kv_lora_rank=512,
+        qk_nope_head_dim=192,
+        qk_rope_head_dim=64,
+        v_head_dim=256,
+        index_n_heads=32,
+        index_head_dim=128,
+        index_topk=2048,
+        first_k_dense_replace=3,
+        moe_intermediate_size=2048,
+        n_shared_experts=1,
+        n_routed_experts=16,
+        router_experts=256,
+        first_expert=0,
+        num_experts_per_tok=8,
+        scoring_func="sigmoid",
+        router_bias=True,
+        n_group=1,
+        topk_group=1,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        bos_token_id=0,
+        eos_token_id=1,
+    )
+    base.update(overrides)
+    return LlamaConfig(**base)
+
+
 def ouro_2_6b(**overrides) -> LlamaConfig:
     """Ouro-2.6B (https://huggingface.co/ByteDance/Ouro-2.6B, `model_type`
     "ouro") at its published sizes: 48 sandwich-normed layers of 16 query
@@ -1183,6 +1274,30 @@ def tiny_xing4(**overrides) -> LlamaConfig:
         routed_scaling_factor=2.0,
         rms_norm_eps=1e-6,
         hc_mult=4,
+    )
+    base.update(overrides)
+    return tiny_mla_moe(**base)
+
+
+def tiny_glm_dsa(**overrides) -> LlamaConfig:
+    """Tiny fixture of the latent family under a learned sparse attention
+    (GLM-5's keys): plain rope, heads as wide for keys as for values (16 +
+    8 = 24, as 192 + 64 = 256), an indexer of 4 heads of 16 that chooses
+    ``index_topk`` 8 rows, fewer than the test contexts hold, one leading
+    dense layer then two expert layers of 16 bias-corrected
+    sigmoid-scored experts top-4 in ONE group beside a shared one."""
+    base = dict(
+        model_type="glm_moe_dsa",
+        rope_scaling=None,
+        rope_theta=10000.0,
+        v_head_dim=24,
+        index_n_heads=4,
+        index_head_dim=16,
+        index_topk=8,
+        router_bias=True,
+        n_group=1,
+        topk_group=1,
+        rms_norm_eps=1e-5,
     )
     base.update(overrides)
     return tiny_mla_moe(**base)

@@ -24,11 +24,17 @@ read: ``Fold``), ``WINDOWED`` (``exaone_moe``, and ``mellum``: the rotation
 a layer KIND is data read from the file, ``LlamaConfig.layer_rope``),
 ``STATE_SPACE`` (``jamba``),
 ``HYBRID`` (``bailing_hybrid``), ``LATENT`` (``deepseek_v3``, ``axk1``,
-``xing4_0``) and ``GQA``, the bare stack every other ``model_type`` is read
-as. A residual stream several hidden vectors wide (``hc_mult`` > 1:
+``xing4_0``, ``glm_moe_dsa``) and ``GQA``, the bare stack every other
+``model_type`` is read as. A residual stream several hidden vectors wide (``hc_mult`` > 1:
 manifold-constrained hyper-connections, ``ops/hyper.py``) is no mixer and
 no record of its own: ``LATENT`` reads its keys, every other family
-refuses them (``check_residual_path``).
+refuses them (``check_residual_path``). A learned sparse attention
+(``index_topk`` > 0: an indexer of ``index_n_heads`` heads of
+``index_head_dim`` scores every cached row and a query attends the
+``index_topk`` best, ``ops/dsa.py``) is the same: ``LATENT`` reads its keys
+(``glm_moe_dsa``) and keeps one index key a token a layer beside the
+latent row (``cache_plan``'s ``index``), every other family refuses them
+(``check_indexer``).
 """
 
 from __future__ import annotations
@@ -173,6 +179,18 @@ _LATENT_EXPERT_MAP = {
 # post, res), all float32 whatever the serving type.
 _HC_MAP = {f"hc_{part}_{t}": (f"hc_{part}_{t}", t == "fn")
            for part in ("attn", "ffn") for t in ("fn", "base", "scale")}
+
+# A learned sparse attention's indexer (`model_type` "glm_moe_dsa";
+# DeepSeek-V3.2's names, which the benchmark configuration lists as
+# ASSUMED for GLM-5): its queries from the query latent, one key a token
+# behind a LayerNorm with a bias, a weight a head from the hidden state.
+_INDEXER_MAP = {
+    "idx_wq_b": ("self_attn.indexer.wq_b.weight", True),
+    "idx_wk": ("self_attn.indexer.wk.weight", True),
+    "idx_k_norm": ("self_attn.indexer.k_norm.weight", False),
+    "idx_k_bias": ("self_attn.indexer.k_norm.bias", False),
+    "idx_w": ("self_attn.indexer.weights_proj.weight", True),
+}
 
 # Delta-rule layers beside latent ones (`model_type` "bailing_hybrid"; the
 # names are ASSUMED, the benchmark configuration lists them: FLA's KDA
@@ -543,7 +561,12 @@ def _latent_read(d: dict) -> dict:
     ops/hyper.py). ``num_nextn_predict_layers`` (a next-token prediction
     block, the layer past ``num_hidden_layers`` or ``mtp.*``) is read and
     ignored: the block takes no part in the model's own logits and the
-    loaders skip its tensors."""
+    loaders skip its tensors. ``index_topk`` > 0 (`model_type`
+    "glm_moe_dsa") is a learned sparse attention over the latent cache
+    (ops/dsa.py): such a file nests ``rope_theta`` in ``rope_parameters``
+    (the default rotation alone) and says how the indexer's rotated slice
+    pairs up (``indexer_rope_interleave``: only interleaved pairs, the
+    latent tables' own, are computed)."""
     method = d.get("topk_method", "none")
     if method not in ("none", "greedy", "group_limited_greedy", "noaux_tc"):
         raise ValueError(f"topk_method {method!r} is not wired")
@@ -554,12 +577,38 @@ def _latent_read(d: dict) -> dict:
     if d.get("hc_mult", 1) > 1:
         out["hc_res_clamp"] = (float(d.get("mhc_h_res_clamp_min", -30.0)),
                                float(d.get("mhc_h_res_clamp_max", 30.0)))
+    name = f"model_type {d.get('model_type')!r}"
+    if "rope_parameters" in d:  # where the file nests its rope_theta
+        rope = _default_rope(name, d["rope_parameters"] or {},
+                             d.get("rope_scaling"))
+        if "rope_theta" in rope:
+            out["rope_theta"] = float(rope["rope_theta"])
+    if "index_topk" in d:
+        if not d.get("indexer_rope_interleave", True):
+            raise ValueError(
+                f"{name}: indexer_rope_interleave false (an indexer that "
+                "rotates half against half) is not wired: the sparse "
+                "attention's indexer rotates interleaved pairs, with the "
+                "latent attention's own tables")
+        limit = d.get("max_position_embeddings")
+        if d["index_topk"] < 1 or (limit and d["index_topk"] > limit):
+            raise ValueError(
+                f"{name}: index_topk {d['index_topk']} is no count of rows "
+                "a sparse attention's indexer can choose (1 to "
+                f"max_position_embeddings {limit})")
     return out
 
 
 def _latent_write(c, d: dict):
     if d.pop("router_bias"):
         d["topk_method"] = "noaux_tc"
+    if c.index_topk:  # the sparse family's spelling
+        d["rope_parameters"] = {"rope_theta": d.pop("rope_theta"),
+                                "rope_type": "default"}
+        d["indexer_rope_interleave"] = True
+    else:
+        for f in _INDEXER_FIELDS:
+            d.pop(f)
     if c.hc_mult == 1:  # the plain residual: none of its keys
         for f in _HC_FIELDS:
             d.pop(f)
@@ -578,6 +627,43 @@ def _latent_check(c):
             f"attn_gate {c.attn_gate!r} is not wired (a "
             "head-wise output gate only)")
     _check_told_share(c)
+
+
+def check_indexer(c):
+    """What a sparse attention's indexer may ask for, and who may carry
+    one (``LlamaConfig.__post_init__``, every family): ``LATENT`` alone,
+    whose cache then keeps an index key beside the latent row."""
+    if not (c.index_topk or c.index_n_heads or c.index_head_dim):
+        if c.model_type == "glm_moe_dsa":
+            raise ValueError(
+                "model_type 'glm_moe_dsa' is latent attention UNDER a "
+                "learned sparse attention: index_topk 0 names no indexer")
+        return
+    if c.family is not LATENT:
+        raise ValueError(
+            f"index_topk {c.index_topk} / index_n_heads {c.index_n_heads} "
+            f"/ index_head_dim {c.index_head_dim} (a learned sparse "
+            "attention's indexer over the cache) is wired for the "
+            f"latent-attention family alone ({sorted(LATENT.model_types)}),"
+            f" not for the layers of model_type {c.model_type!r}")
+    if not (c.index_topk > 0 and c.index_n_heads > 0
+            and c.index_head_dim > 0 and c.q_lora_rank):
+        raise ValueError(
+            f"a sparse attention's indexer (index_topk {c.index_topk}) "
+            f"needs index_n_heads ({c.index_n_heads}) heads of "
+            f"index_head_dim ({c.index_head_dim}) over a query latent "
+            f"(q_lora_rank {c.q_lora_rank})")
+    if c.index_head_dim < c.qk_rope_head_dim:
+        raise ValueError(
+            f"index_head_dim {c.index_head_dim} is narrower than the "
+            f"{c.qk_rope_head_dim} channels the indexer rotates "
+            "(qk_rope_head_dim: the first channels of each index head, "
+            "under the latent attention's tables)")
+    if c.hc_mult > 1 or c.attn_gate:
+        raise ValueError(
+            "a sparse attention's indexer (index_topk > 0) is wired over "
+            "plain latent attention alone, not beside a wide residual "
+            "stream (hc_mult) or an output gate (attn_gate)")
 
 
 def check_residual_path(c):
@@ -604,14 +690,16 @@ def check_residual_path(c):
 _LATENT_FIELDS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                   "qk_rope_head_dim", "v_head_dim") + _EXPERT_FIELDS
 _HC_FIELDS = ("hc_mult", "hc_sinkhorn_iters", "hc_eps", "hc_res_clamp")
+_INDEXER_FIELDS = ("index_n_heads", "index_head_dim", "index_topk")
 
 LATENT = Family(
-    model_types=("deepseek_v3", "axk1", "xing4_0"),
+    model_types=("deepseek_v3", "axk1", "xing4_0", "glm_moe_dsa"),
     selects=lambda c: c.kv_lora_rank > 0,
-    fields=_LATENT_FIELDS + _HC_FIELDS,
+    fields=_LATENT_FIELDS + _HC_FIELDS + _INDEXER_FIELDS,
     read=_latent_read, write=_latent_write, check=_latent_check,
     tensor_names={**_LATENT_MAP, **_LATENT_DENSE_MAP, **_LATENT_MOE_MAP,
-                  **_HYBRID_EXTRA_MAP, **_LATENT_BIAS, **_HC_MAP},
+                  **_HYBRID_EXTRA_MAP, **_LATENT_BIAS, **_HC_MAP,
+                  **_INDEXER_MAP},
     expert_names=_LATENT_EXPERT_MAP,
     probe=".self_attn.kv_a_proj_with_mqa.weight",
     what="a latent-attention model", shard_axes=frozenset(("ep",)),

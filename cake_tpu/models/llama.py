@@ -102,6 +102,17 @@ _LATENT_SHAPES = {
     "mlp_norm": lambda c: (c.hidden_size,),
 }
 
+# A learned sparse attention's indexer beside them (ops/dsa.py): its
+# queries from the query latent, one key a token behind a LayerNorm with a
+# bias, a weight a head from the hidden state.
+_INDEXER_SHAPES = {
+    "idx_wq_b": lambda c: (c.q_lora_rank, c.index_n_heads * c.index_head_dim),
+    "idx_wk": lambda c: (c.hidden_size, c.index_head_dim),
+    "idx_k_norm": lambda c: (c.index_head_dim,),
+    "idx_k_bias": lambda c: (c.index_head_dim,),
+    "idx_w": lambda c: (c.hidden_size, c.index_n_heads),
+}
+
 # An expert layer of the latent family: the router at its published width,
 # the HELD experts' stacks, and the shared experts as one SwiGLU.
 _SHARED_MOE_SHAPES = {
@@ -406,6 +417,8 @@ def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
             shapes["wg"] = lambda c: (c.hidden_size, c.num_attention_heads)
         if config.hc_mult > 1:  # the two sub-layers' mixing coefficients
             shapes.update(_HC_SHAPES)
+        if config.index_topk:  # a sparse attention's indexer
+            shapes.update(_INDEXER_SHAPES)
     if seg.ffn == "dense":
         shapes.update({k: _LAYER_SHAPES[k]
                        for k in ("w_gate", "w_up", "w_down")})
@@ -727,11 +740,14 @@ def block_forward(
     count_local: bool = False,
     expert_idx: jax.Array | None = None,
     valid: jax.Array | None = None,
+    index_cache: jax.Array | None = None,
 ):
     """One pre-norm decoder block (transformer.rs:48-64). Returns ``(x,
     k_cache, v_cache)``; with ``count_local`` (an expert layer of the
     latent family) a fourth value, the :class:`ExpertCount` of the call
-    (:func:`cake_tpu.ops.moe.moe_swiglu`).
+    (:func:`cake_tpu.ops.moe.moe_swiglu`). ``index_cache``: the index keys
+    of a latent layer under a sparse attention (``KVCache.index``), which
+    then come back right after ``v_cache``.
 
     ``layer_idx``: ``k_cache``/``v_cache`` are the stacked ``[L, B,
     kv_heads, S, D]`` cache and this block is layer ``layer_idx`` of it
@@ -764,7 +780,8 @@ def block_forward(
     if "wkv_a" in layer:
         return _latent_block(layer, x, k_cache, v_cache, cos, sin, pos,
                              config, write_gate, ep_axis, ep_size,
-                             layer_idx, count_local, expert_idx, valid)
+                             layer_idx, count_local, expert_idx, valid,
+                             index_cache)
     h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps,
                    offset=config.rms_norm_offset)
     attn_out, k_cache, v_cache = self_attention_block(
@@ -827,22 +844,21 @@ def _sub_layer(layer, x, part: str, norm: str, config, f):
 
 def _latent_block(layer, x, c_cache, r_cache, cos, sin, pos, config,
                   write_gate, ep_axis, ep_size, layer_idx, count_local,
-                  expert_idx, valid=None):
+                  expert_idx, valid=None, i_cache=None):
     """:func:`block_forward` for a latent-attention layer."""
     def attend(h):
         with jax.named_scope("mla"):
-            out, c, r = latent_attention_block(
+            out, *caches = latent_attention_block(
                 h, layer, c_cache, r_cache, cos, sin, pos, config,
-                write_gate=write_gate, layer_idx=layer_idx)
-        return out, (c, r)
+                write_gate=write_gate, layer_idx=layer_idx, i_cache=i_cache)
+        return out, tuple(caches)
 
-    x, (c_cache, r_cache) = _sub_layer(layer, x, "attn", "attn_norm", config,
-                                       attend)
+    x, caches = _sub_layer(layer, x, "attn", "attn_norm", config, attend)
     x, local = _shared_feed_forward(layer, x, config, ep_axis, ep_size,
                                     count_local, expert_idx, valid)
     if count_local:
-        return x, c_cache, r_cache, local
-    return x, c_cache, r_cache
+        return (x, *caches, local)
+    return (x, *caches)
 
 
 def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
@@ -1110,8 +1126,10 @@ def forward_layers(
                 sp_prefill=sp_prefill, sp_chunk=sp_chunk,
                 ep_axis=ep_axis, ep_size=ep_size,
                 layer_idx=i, count_local=count_local, expert_idx=j,
-                valid=expert_valid)
+                valid=expert_valid, index_cache=c.index)
             c = dataclasses.replace(c, k=kc, v=vc)
+            if c.index is not None:  # a sparse attention's index keys
+                c = dataclasses.replace(c, index=now.pop(0))
             now = now[0] if now else None
         if count_local:
             return (h, c, local[0] + now), None
@@ -1229,7 +1247,8 @@ def true_rows(config: LlamaConfig, shape: tuple[int, int], last_index):
     for a model with expert layers, whatever its cache holds (None
     otherwise): a frontier hides a padding row from attention, and an
     expert block would route and compute it all the same."""
-    stateful = bool(set(config.cache_plan) - {"rows"})
+    # (an index key lies behind its stream's frontier, as a row does)
+    stateful = bool(set(config.cache_plan) - {"rows", "index"})
     sparse = any(ffn == "moe" for _, ffn in config.layer_kinds)
     if not (stateful or sparse):  # such a program is told no length
         return None, None

@@ -177,6 +177,62 @@ SERIES: dict[str, tuple[str, str]] = {
                  "(either rule of ops/kda.py: KDA's or the scalar-gated "
                  "one) from zero (a fresh staging row, spliced over what "
                  "the slot's last stream left)"),
+    # -- a learned sparse attention over the latent cache (ops/dsa.py, the
+    #    engine; named scopes dsa.index, dsa.select, dsa.attend in both
+    #    programs; the trace's operations dsa_index, dsa_attend (decode:
+    #    the choice between them is XLA's sort, the gather XLA's) and
+    #    dsa_prefill_select, dsa_prefill_attend (admission)) ---------------
+    "cache.index_row_bytes": (
+        GAUGE, "bytes the index buffer holds for one token of one layer "
+               "(a sparse attention's one index key, normed and rotated, "
+               "in the serving type; KVCache.index): counted in "
+               "cache.token_bytes, not in cache.row_bytes"),
+    "dsa.admit_calls": (
+        COUNTER, "(layer, dispatch) calls of an admission launch's sparse "
+                 "attention path: the layers under it, a dispatch (what "
+                 "the dsa.admit_* counts are a mean over)"),
+    "dsa.admit_pairs_attended": (
+        COUNTER, "of dsa.admit_pairs_scored, the pairs a query row attends: "
+                 "min(t + 1, index_topk) a row, a layer"),
+    "dsa.admit_pairs_scored": (
+        COUNTER, "(query row, row at or before it) pairs the indexers of an "
+                 "admission launch score at the rows' TRUE lengths: n (n + "
+                 "1) / 2 a prompt of n tokens, a layer under the sparse "
+                 "attention (the kernels compute a bucket's whole lower "
+                 "triangle)"),
+    "dsa.admit_rows": (
+        COUNTER, "rows an admission launch's programs were handed under a "
+                 "learned sparse attention: the bucket's, times the "
+                 "launch's staging rows, a dispatch"),
+    "dsa.admit_rows_true": (
+        COUNTER, "of dsa.admit_rows, the rows that were prompt tokens (the "
+                 "rest a bucket's padding, which lies past every frontier "
+                 "and is never chosen)"),
+    "dsa.decode_calls": (
+        COUNTER, "(layer, step) calls of the decode step's sparse attention "
+                 "path: the layers under it x the steps, a dispatch (what "
+                 "dsa.rows_live and dsa.rows_selected are a mean over: a "
+                 "capture's trace counts the calls it holds)"),
+    "dsa.index_kernel": (
+        GAUGE, "what ops.dsa.decode_index_scores chose for the last "
+               "single-token index scoring it traced (the decode "
+               "programs'): 1 the kernel that reads each stream's index "
+               "keys up to its frontier (dsa_index), 0 XLA's product over "
+               "the whole buffer, masked"),
+    "dsa.index_topk": (
+        GAUGE, "rows a query attends at most under the model's learned "
+               "sparse attention (LlamaConfig.index_topk); absent where the "
+               "model has none"),
+    "dsa.rows_live": (
+        COUNTER, "rows a decode step's indexers score: over every slot, "
+                 "decode step and layer under the sparse attention, the "
+                 "stream's rows up to its frontier as dispatched (a slot "
+                 "without a live stream goes out at row 0: one row)"),
+    "dsa.rows_selected": (
+        COUNTER, "of dsa.rows_live, the rows a step attends: min(frontier + "
+                 "1, index_topk) a stream, step and layer: the latent rows "
+                 "a step gathers out of the cache, where a full sweep "
+                 "would read dsa.rows_live"),
     "delta.chunks_swept": (
         COUNTER, "chunks of ops.kda.CHUNK tokens that the delta-rule "
                  "layers' admission scans ran through: delta-rule layers x "

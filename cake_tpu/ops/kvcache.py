@@ -72,7 +72,8 @@ def quant_kv(x: jax.Array) -> QuantizedKV:
 
 
 @partial(jax.tree_util.register_dataclass,
-         data_fields=["k", "v", "state", "conv", "ring_k", "ring_v"],
+         data_fields=["k", "v", "state", "conv", "ring_k", "ring_v",
+                      "index"],
          meta_fields=[])
 @dataclasses.dataclass
 class KVCache:
@@ -106,7 +107,16 @@ class KVCache:
     window layers' rows: ``R = config.ring_rows`` rows a stream whatever
     ``max_seq``, position ``p`` at row ``p % R`` (:func:`ring_write`). A
     ring is never zeroed: what an earlier stream or chunk left in it is
-    told from the live rows by position (:func:`ring_positions`). Every
+    told from the live rows by position (:func:`ring_positions`). Under a
+    learned sparse attention (an ``index`` in ``cache_plan``, ops/dsa.py)
+    ``index [L, B, 1, S, index_head_dim]`` holds the indexer's one key a
+    token a layer, normed and rotated, in the serving type, row for row
+    beside ``k``/``v`` and written as they are (:func:`update_layer`);
+    such a model keeps ``[c | k_pe]`` in ONE row of ``k``, padded to whole
+    lane tiles, and an empty ``v`` (``LlamaConfig.cache_row``: a step
+    gathers its chosen rows, and a gather costs a row whatever its width);
+    a row past a stream's frontier is an earlier stream's or a bucket's
+    padding and is never scored. Every
     buffer is ``[layers of its kind, batch, ...]``, so a slot's whole
     state is index ``b`` of axis 1 of every leaf.
     """
@@ -117,6 +127,7 @@ class KVCache:
     conv: jax.Array | None = None
     ring_k: jax.Array | None = None
     ring_v: jax.Array | None = None
+    index: jax.Array | None = None
 
     @property
     def num_layers(self) -> int:
@@ -172,8 +183,9 @@ def init_cache(
     if num_layers is not None and (set(plan) - {"rows"}
                                    or config.family.loops):
         raise ValueError("a model that holds a recurrent state, a "
-                         "convolution's tail, a ring of rows or a plane a "
-                         "pass is cached whole (no layer ranges)")
+                         "convolution's tail, a ring of rows, an index key "
+                         "or a plane a pass is cached whole (no layer "
+                         "ranges)")
     if "conv" in plan:  # layers that carry a tail, and a state or none
         if "state" in plan:
             n, *shape = plan["state"]
@@ -184,6 +196,9 @@ def init_cache(
         n, kvh, r, kw, vw = plan["ring"]
         rec["ring_k"] = jnp.zeros((n, batch, kvh, r, kw), dt)
         rec["ring_v"] = jnp.zeros((n, batch, kvh, r, vw), dt)
+    if "index" in plan:  # a sparse attention's key a token, beside the rows
+        n, heads_i, width = plan["index"]
+        rec["index"] = jnp.zeros((n, batch, heads_i, S, width), dt)
     if quant == "int8":
         def half(width):
             shape = (L, batch, heads, S, width)
@@ -223,9 +238,12 @@ def update_layer(
     pos: jax.Array,
     gate: jax.Array | None = None,
     layer: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array]:
+    index: tuple[jax.Array, jax.Array] | None = None,
+) -> tuple[jax.Array, ...]:
     """Write ``k_new/v_new [batch, kv_heads, T, head_dim]`` into one layer's
-    ``T`` slots at sequence offset ``pos``, and nothing else.
+    ``T`` slots at sequence offset ``pos``, and nothing else. ``index``
+    ``(cache, new)``: a sparse attention's index keys, written the same
+    way into their own buffer, which then comes back third.
 
     ``layer`` None: the buffers are that layer's own ``[batch, kv_heads,
     max_seq, head_dim]``. ``layer`` an index: they are the stacked
@@ -288,7 +306,8 @@ def update_layer(
                                scale=write_buf(cache.scale, qn.scale))
         return write_buf(cache, new.astype(cache.dtype))
 
-    return write(k_cache, k_new), write(v_cache, v_new)
+    out = write(k_cache, k_new), write(v_cache, v_new)
+    return out if index is None else out + (write(*index),)
 
 
 def ring_positions(last: jax.Array, rows: int) -> jax.Array:

@@ -40,6 +40,17 @@ Two forms of the same mathematics, chosen at trace time:
   skipped at run time when there is no history (``lax.cond``), which is
   every first chunk.
 
+Where the layer holds an indexer (``idx_*``: a learned sparse attention,
+:mod:`cake_tpu.ops.dsa`) both forms attend a CHOICE of the rows: a decode
+step the ``index_topk`` rows of highest index score, gathered out of the
+carried buffers (the absorbed form over them alone), a chunk from
+position 0 under each row's mask (the expanded form, blocked by query
+rows); the indexer's key of each token is written into a third buffer
+beside the latent row, and ``[c | k_pe]`` lie in ONE row of the first
+buffer, padded to whole lane tiles (``LlamaConfig.cache_row``: a gather
+costs a row whatever its width, so a chosen row is fetched once; the
+second buffer is empty).
+
 Weights go through :func:`cake_tpu.ops.quant.dense` wherever they are used
 as a plain projection; the absorbed form contracts ``W_kvb`` over its
 other axis, so an int8 ``W_kvb`` is dequantized at trace level there (the
@@ -54,6 +65,7 @@ import jax
 import jax.numpy as jnp
 
 from cake_tpu.obs import metrics as obs_metrics
+from cake_tpu.ops import dsa
 from cake_tpu.ops import kvcache as kv
 from cake_tpu.ops import pallas as pk
 from cake_tpu.ops import quant
@@ -159,10 +171,13 @@ def latent_attention_block(
     config,
     write_gate: jax.Array | None = None,
     layer_idx: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+    i_cache: jax.Array | None = None,  # [(L,) B, 1, S, index_head_dim]
+) -> tuple[jax.Array, ...]:
     """One latent-attention sublayer incl. the cache write. Returns
     ``(attn_out [B, T, hidden], c_cache, r_cache)``; the buffers come back
-    whole with this layer's ``T`` new rows written."""
+    whole with this layer's ``T`` new rows written. A layer that holds an
+    indexer takes ``i_cache`` too and returns it fourth, its index keys
+    written."""
     b, t, _ = x.shape
     nh = config.num_attention_heads
     dn, dr = config.qk_nope_head_dim, config.qk_rope_head_dim
@@ -172,6 +187,10 @@ def latent_attention_block(
     if "wq_a" in layer:
         c_q = rms_norm(quant.dense(x, layer["wq_a"]), layer["q_norm"], eps)
         q = quant.dense(c_q, layer["wq_b"])
+        if i_cache is not None:
+            with jax.named_scope("dsa.index"):
+                q_i, k_i, w_i = dsa.index_projections(
+                    x, c_q, layer, cos, sin, pos, config)
     else:  # q_lora_rank null: one direct projection, no bottleneck
         q = quant.dense(x, layer["wq"])
     q = q.reshape(b, t, nh, dn + dr)
@@ -183,8 +202,15 @@ def latent_attention_block(
     k_pe = apply_rope(ckv[..., dc:][:, None], cos, sin, pos,
                       interleaved=True)  # [B, 1, T, dr], one for all heads
 
-    c_cache, r_cache = kv.update_layer(c_cache, r_cache, c, k_pe, pos,
-                                       gate=write_gate, layer=layer_idx)
+    if i_cache is None:
+        c_cache, r_cache = kv.update_layer(c_cache, r_cache, c, k_pe, pos,
+                                           gate=write_gate, layer=layer_idx)
+    else:  # [c | k_pe | 0..] in ONE row of the first buffer, the second empty
+        pad = jnp.zeros(c.shape[:-1] + (c_cache.shape[-1] - dc - dr,), c.dtype)
+        c_cache, r_cache, i_cache = kv.update_layer(
+            c_cache, r_cache, jnp.concatenate([c, k_pe, pad], -1),
+            k_pe[..., :0], pos, gate=write_gate, layer=layer_idx,
+            index=(i_cache, k_i))
     c_all = kv.layer_view(c_cache, layer_idx)[:, 0]  # [B, S, dc]
     r_all = kv.layer_view(r_cache, layer_idx)[:, 0]  # [B, S, dr]
     s = c_all.shape[1]
@@ -215,7 +241,30 @@ def latent_attention_block(
         return m, l, o
 
     kpos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, t, s), 3)
-    if t == 1:
+    if i_cache is not None and t == 1:
+        # the rows the indexer chooses, and no others, out of the buffer
+        q_c = jnp.einsum("bhtn,chn->bhtc", q_nope, w_k)
+        m, l, o_c = dsa.decode_attend(
+            q_c, q_pe, q_i, w_i, c_cache, i_cache, pos, layer_idx,
+            scale=scale, topk=config.index_topk)
+        out = jnp.einsum("bhtc,chv->bhtv", o_c.astype(x.dtype), w_v,
+                         preferred_element_type=jnp.float32) / l
+    elif i_cache is not None:
+        # a chunk from position 0 (the engine admits such a model a whole
+        # bucket at a time): the chunk's own keys and values expanded,
+        # each row under its own choice
+        kv_own = quant.dense(c[:, 0], layer["wkv_b"]).reshape(
+            b, t, nh, dn + dv).transpose(0, 2, 1, 3)
+        k_own = jnp.concatenate(
+            [kv_own[..., :dn], jnp.broadcast_to(k_pe, (b, nh, t, dr))], -1)
+        kernel = dsa.prefill_kernel_choice(
+            t, dn + dr, dv, config.index_head_dim) == "kernel"
+        mask = dsa.prefill_mask(q_i, w_i, k_i[:, 0], config.index_topk,
+                                kernel=kernel)
+        out = dsa.prefill_attend(
+            jnp.concatenate([q_nope, q_pe], -1), k_own, kv_own[..., dn:],
+            mask, scale=scale, kernel=kernel)
+    elif t == 1:
         kernel = latent_decode_choice(s, dc, dr) == "kernel"
         # trace time: which attention the decode program being built
         # holds (read beside the engine's attn.kv_blocks_* counts)
@@ -261,4 +310,7 @@ def latent_attention_block(
         gate = jax.nn.sigmoid(quant.dense(x, layer["wg"]).astype(jnp.float32))
         out = (out * gate[..., None]).astype(x.dtype)
     out = out.reshape(b, t, nh * dv)
-    return quant.dense(out, layer["wo"]), c_cache, r_cache
+    out = quant.dense(out, layer["wo"])
+    if i_cache is None:
+        return out, c_cache, r_cache
+    return out, c_cache, r_cache, i_cache

@@ -60,6 +60,12 @@ from cake_tpu.ops.pallas.flash import (  # noqa: E402
     flash_decode,
     narrow_heads,
 )
+from cake_tpu.ops.pallas.dsa import (  # noqa: E402
+    dsa_attend,
+    dsa_index,
+    dsa_prefill_attend,
+    dsa_prefill_select,
+)
 from cake_tpu.ops.pallas.kda import kda_decode  # noqa: E402
 from cake_tpu.ops.pallas.latent import latent_decode  # noqa: E402
 from cake_tpu.ops.pallas.moe import (  # noqa: E402
@@ -89,6 +95,10 @@ __all__ = [
     "flash_attention_q8",
     "flash_decode",
     "narrow_heads",
+    "dsa_attend",
+    "dsa_index",
+    "dsa_prefill_attend",
+    "dsa_prefill_select",
     "kda_decode",
     "latent_decode",
     "MOE_ROW_TILE",

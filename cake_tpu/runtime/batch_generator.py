@@ -248,7 +248,7 @@ _DELTA_CHUNKS_LIVE = obs_metrics.counter("delta.chunks_live")
 _SPEC_NGRAM = 3  # the longest n-gram a batched proposal is looked up by
 # what a cache may hold beside rows (cache_plan's keys), as a refusal says it
 _HELD = {"state": "recurrent state", "conv": "convolution's tail",
-         "ring": "ring row"}
+         "ring": "ring row", "index": "sparse attention's index key"}
 # The order of work at a block boundary (BatchGenerator._close_boundary):
 # host time from a block's fetch returning to the return of the step()
 # call that enqueued the device's next program, once per landed block,
@@ -304,6 +304,23 @@ _KV_BLOCKS_READ = obs_metrics.counter("attn.kv_blocks_read")
 _RING_ROWS_LIVE = obs_metrics.counter("attn.ring_rows_live")
 _RING_ROWS_SWEPT = obs_metrics.counter("attn.ring_rows_swept")
 _KV_BLOCKS_RESERVED = obs_metrics.counter("attn.kv_blocks_reserved")
+# A learned sparse attention (ops/dsa.py), from the positions as
+# dispatched: the rows a decode step's indexer scores (each stream's, to
+# its frontier) and those it attends (at most index_topk of them), a layer
+# a step, and how many (layer, step) calls that was; the rows an admission
+# launch's programs were handed (buckets), those that were prompt tokens,
+# and how many (layer, dispatch) calls that was
+_DSA_DECODE_CALLS = obs_metrics.counter("dsa.decode_calls")
+_DSA_ADMIT_CALLS = obs_metrics.counter("dsa.admit_calls")
+_DSA_ROWS_LIVE = obs_metrics.counter("dsa.rows_live")
+_DSA_ROWS_SELECTED = obs_metrics.counter("dsa.rows_selected")
+_DSA_ADMIT_ROWS = obs_metrics.counter("dsa.admit_rows")
+_DSA_ADMIT_ROWS_TRUE = obs_metrics.counter("dsa.admit_rows_true")
+# ... and, at the rows' true lengths, the (query row, row at or before it)
+# pairs a launch's indexers score and those its queries attend
+# (min(t + 1, index_topk) a row), a layer
+_DSA_PAIRS_SCORED = obs_metrics.counter("dsa.admit_pairs_scored")
+_DSA_PAIRS_ATTENDED = obs_metrics.counter("dsa.admit_pairs_attended")
 
 # arrival-queue entry kinds (4th tuple field): None marks a plain prompt
 # arrival; imports ride the SAME FIFO so pool-pressure deferral stays
@@ -562,6 +579,10 @@ class BatchGenerator:
         # ... and how many planes a layer it counts (a looped model's
         # passes: each reads and reserves a plane of its own)
         self._kv_planes = config.total_ut_steps
+        # the layers under a learned sparse attention, and how many rows a
+        # query of theirs attends at most (_count_kv_blocks, _launch)
+        self._dsa = ((config.cache_plan["index"][0], config.index_topk)
+                     if "index" in config.cache_plan else None)
         # the layers whose admission is a scan of chunks (_count_delta_chunks)
         self._delta_layers = sum(
             m in ("kda", "gdn") for m, _ in config.layer_kinds)
@@ -807,6 +828,13 @@ class BatchGenerator:
                 f"max_seq {self.max_seq} (a chunk round-up past the window "
                 "would clamp-overwrite committed KV)"
             )
+        if admit_chunk is not None and "index" in config.cache_plan:
+            raise ValueError(
+                "admit_chunk is not wired for a model under a learned "
+                "sparse attention (index_topk > 0): a chunk that has "
+                "history behind it would choose among cached rows and its "
+                "own under one mask, which no program here computes; such "
+                "a prompt is admitted a whole bucket at a time")
         self._admit_chunk = admit_chunk
         # Shared-prefix serving: when every prompt in a batch opens with
         # the same >= prefix_share_min tokens (the system-prompt case), the
@@ -1412,8 +1440,11 @@ class BatchGenerator:
         on_device = _device_bytes(self.cache)
         if on_device is not None:
             obs_metrics.gauge("cache.device_bytes").set(on_device)
+        # (a sparse attention's index keys lie beside the rows: counted in
+        # cache.token_bytes and in cache.index_row_bytes, not here)
+        index = 0 if self.cache.index is None else self.cache.index.nbytes
         obs_metrics.gauge("cache.row_bytes").set(
-            (held - state - rings) / (self.cache.num_layers
+            (held - state - rings - index) / (self.cache.num_layers
                                       * self.cache.batch
                                       * self.cache.max_seq))
         if rings:
@@ -1429,6 +1460,11 @@ class BatchGenerator:
                 * (1 + n_ring / self.cache.num_layers))
             obs_metrics.gauge("attn.layers_swa").set(n_ring)
             obs_metrics.gauge("attn.layers_full").set(self.cache.num_layers)
+        if index:
+            obs_metrics.gauge("cache.index_row_bytes").set(
+                index / (self.cache.index.shape[0] * self.cache.batch
+                         * self.cache.max_seq))
+            obs_metrics.gauge("dsa.index_topk").set(self.config.index_topk)
         obs_metrics.gauge("cache.state_bytes").set(state)
         obs_metrics.gauge("cache.state_bytes_per_stream").set(
             state / self.cache.batch)
@@ -2524,6 +2560,10 @@ class BatchGenerator:
             self._n_admit_dispatches += 1
             rows = len(st["rows"])
             self._count_admit_rows(rows * chunk)
+            if self._dsa:
+                self._count_dsa_admission(
+                    chunk, [min(max(len(m.ids) - base - pos, 0), chunk)
+                            for m in st["rows"]])
             self._count_delta_chunks(
                 chunk, [len(m.ids) - base - pos for m in st["rows"]])
             st["pos"] = pos + chunk
@@ -2550,6 +2590,21 @@ class BatchGenerator:
         _MOE_ADMIT_ROWS.inc(rows)
         if moe_form_traced(rows) == "sorted":
             _MOE_ADMIT_SORTED.inc(rows)
+
+    def _count_dsa_admission(self, chunk: int, true: list[int]) -> None:
+        """An admission dispatch of ``chunk`` rows a member, ``true`` of
+        them each member's prompt tokens, over a model under a learned
+        sparse attention: the rows handed and true, and the pairs its
+        indexers score and its queries attend at the true lengths."""
+        layers, topk = self._dsa
+        _DSA_ADMIT_CALLS.inc(layers)
+        _DSA_ADMIT_ROWS.inc(len(true) * chunk)
+        _DSA_ADMIT_ROWS_TRUE.inc(sum(true))
+        for n in true:
+            k = min(n, topk)
+            _DSA_PAIRS_SCORED.inc(layers * (n * (n + 1) // 2))
+            _DSA_PAIRS_ATTENDED.inc(
+                layers * (k * (k + 1) // 2 + (n - k) * topk))
 
     def _count_delta_chunks(self, chunk: int, left: list[int]) -> None:
         """An admission dispatch of ``chunk`` tokens a row over a model
@@ -3945,6 +4000,14 @@ class BatchGenerator:
             window=self._kv_window)
         _KV_BLOCKS_READ.inc(read * self._kv_planes)
         _KV_BLOCKS_RESERVED.inc(reserved * self._kv_planes)
+        if self._dsa:
+            # step j of the dispatch scores the rows 0..pos + j of a stream
+            # and attends index_topk of them, or all while it has fewer
+            layers, topk = self._dsa
+            live = pos[:, None] + np.arange(1, steps + 1)[None, :]
+            _DSA_DECODE_CALLS.inc(layers * steps)
+            _DSA_ROWS_LIVE.inc(layers * int(live.sum()))
+            _DSA_ROWS_SELECTED.inc(layers * int(np.minimum(live, topk).sum()))
         if self._rings:
             # a window layer's step reads its ring whole; the rows that
             # hold a key its query may see are the window's, or fewer

@@ -143,7 +143,8 @@ def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
     token a layer), not per-head keys and values, for the layers that
     keep rows, and a delta-rule or state-space layer's state and
     convolution tail a stream for the others, and a ring of ``R`` rows a
-    stream for a window layer (``LlamaConfig.cache_plan``). One stage, tp = sp = 1
+    stream for a window layer, and a sparse attention's index key a token
+    beside the latent row (``LlamaConfig.cache_plan``). One stage, tp = sp = 1
     (``mesh.validate_shardable``)."""
     import math
 
@@ -178,6 +179,8 @@ def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
     if "ring" in plan:  # window layers: R rows a stream whatever S
         n, heads, rows, k_width, v_width = plan["ring"]
         kv_bytes += batch * n * heads * rows * (k_width + v_width) * cache_el
+    if "index" in plan:  # a sparse attention's key a token beside the rows
+        kv_bytes += batch * S * math.prod(plan["index"]) * el
     return {
         "layers": int(layer_bytes),
         "embed_replicated": int(embed_bytes),

@@ -652,7 +652,17 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
         the program holds it (a norm as ``w - 1``, a fused projection a key
         head's group at a time): read whole, folded in float32, then
         sliced."""
-        asked.update(names_of(*at) for at in np.ndindex(*lead))
+        names = [names_of(*at) for at in np.ndindex(*lead)]
+        asked.update(names)
+        lacks = [n for n in names if ".indexer." in n and not (
+            n in reader.name_to_file or stored(n))]
+        if lacks:
+            raise ValueError(
+                f"the checkpoint stores no {lacks[0]}: a model under a "
+                f"learned sparse attention (index_topk {config.index_topk})"
+                " needs its indexer's five tensors a layer "
+                "(self_attn.indexer.wq_b, wk, k_norm.weight, k_norm.bias, "
+                "weights_proj)")
 
         def gather(index, read):
             grids = [range(*sl.indices(n)) for sl, n in zip(index, lead)]

@@ -358,6 +358,11 @@ PR31_TEXTS = {
     # family above to the text it had: no hash replaced)
     "latent_hc.decode": "64178e4935b87604",
     "latent_hc.admit": "17ac986347105f98",
+    # the latent family under a learned sparse attention, taken on PR 61's
+    # tree, which brought it (``index_topk`` 0 lowers every family above
+    # to the text it had: no hash replaced)
+    "sparse_latent.decode": "9b75c3cd53ca71ed",
+    "sparse_latent.admit": "9fb811b1982e9704",
     # PR 60 (an admission tells its expert blocks and its chunked delta
     # rule the rows' true lengths) replaced the hybrid's admission alone:
     # a decode program is told no length, and at these 16 rows, without
@@ -367,21 +372,21 @@ PR31_TEXTS = {
 
 
 def _family_fixtures():
-    from cake_tpu.models.config import (tiny, tiny_exaone_moe, tiny_jamba,
-                                        tiny_kda_hybrid, tiny_lfm2_moe,
-                                        tiny_mla_moe, tiny_moe, tiny_ouro,
-                                        tiny_xing4)
+    from cake_tpu.models.config import (tiny, tiny_exaone_moe, tiny_glm_dsa,
+                                        tiny_jamba, tiny_kda_hybrid,
+                                        tiny_lfm2_moe, tiny_mla_moe, tiny_moe,
+                                        tiny_ouro, tiny_xing4)
 
     return {"dense": lambda: tiny(sliding_window=32), "sparse": tiny_moe,
             "latent": tiny_mla_moe, "hybrid": tiny_kda_hybrid,
             "state_space": tiny_jamba, "windowed": tiny_exaone_moe,
             "short_conv": tiny_lfm2_moe, "looped": tiny_ouro,
-            "latent_hc": tiny_xing4}
+            "latent_hc": tiny_xing4, "sparse_latent": tiny_glm_dsa}
 
 
 @pytest.mark.parametrize("name", ["dense", "sparse", "latent", "hybrid",
                                   "state_space", "windowed", "short_conv",
-                                  "looped", "latent_hc"])
+                                  "looped", "latent_hc", "sparse_latent"])
 def test_existing_families_lower_to_the_text_they_had(name):
     """Each family's block decode and admission programs lower (StableHLO,
     CPU, tiny widths) to the text PR 31's tree (PR 32's for the hybrid,

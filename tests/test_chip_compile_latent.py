@@ -248,3 +248,89 @@ def test_hybrid_programs_move_no_cache_no_state_and_no_expert_stack(
     assert "triangular" not in admit.as_text().lower()
     m = admit.memory_analysis()
     assert m.temp_size_in_bytes < 0.315 * GIB, m.temp_size_in_bytes / GIB
+
+
+def _kernel_calls(compiled, name: str) -> list[str]:
+    """The lines of ``compiled``'s text that call the kernel ``name``."""
+    return [line for line in compiled.as_text().splitlines()
+            if "custom-call(" in line and "tpu_custom_call" in line
+            and name in line.split("=")[0]]
+
+
+def test_sparse_latent_programs_read_the_chosen_rows_and_write_no_scores(
+        topo, as_on_chip):
+    """The latent family under a learned sparse attention at GLM-5's
+    published widths, the cell ``glm5-ep16-cut.agent-long`` itself: 1
+    dense + 4 expert layers, 16 slots x 16,384 rows, and its largest
+    admission, 16,384 rows. The chip's compiler takes them. The cache's
+    three kinds of row (the latent, the roped key part, the index key) are
+    carried through both stacks and written in place: in the decode
+    program nothing of the latent or the index buffer's shape is allocated
+    or copied; the roped
+    part, which the chip holds rows-on-lanes, is re-laid ONCE a dispatch
+    at the program's entry for the gather of the chosen rows (one copy in
+    ENTRY, 168 MB a block of 8 steps: PERF.md section 7 says what would
+    take it away), never a layer. A decode step's index scores are the
+    kernel on the carried index buffer (``dsa_index``), its choice XLA's
+    sort of ``[16, 16384]`` scores, its attention the kernel over the
+    gathered ``[16, 2048, 640]`` rows (``dsa_attend``), each once a
+    stack inside the layer loop: no score ``[16, 64, 16384]`` of a full
+    sweep exists, and the plain latent kernel is not called. The admission
+    holds the choice and the masked sweep as kernels (``dsa_prefill_
+    select``, ``dsa_prefill_attend``) and writes out neither the heads'
+    index products ``[32, T, T]``, nor the index scores ``[T, T]`` in
+    float32, nor the attention's ``[64, T, T]``: a row's mask ``[T, T]``
+    int8 is what passes between them (268 MB). RECORDED (my AOT compiles,
+    PR 61): block decode 9.16 GiB of arguments + 0.57 of temporaries; the
+    16,384-row admission 7.40 + 3.95 GiB beside the 1.875 GiB live cache
+    (8192 rows: 1.89; 4096: 0.95): well under the 6 GiB the cut leaves."""
+    from cake_tpu.models.config import glm5_ep16
+    from cake_tpu.utils.chips import HBM_GIB
+
+    layers, slots, window, bucket = 5, 16, 16384, 16384
+    config = glm5_ep16(num_hidden_layers=layers, first_k_dense_replace=1,
+                       vocab_size=19360, max_seq_len=window)
+    decode, admit = _family_programs(topo, config, slots, window, bucket)
+    assert config.cache_plan == {"rows": (5, 1, 640, 0),
+                                 "index": (5, 1, 128)}
+    for width in (640, 128):
+        assert _cache_sized_moves(
+            decode, f"bf16[{layers},{slots},1,{window},{width}]") == []
+    # the admission's one-row staging cache (0.12 GiB) may be re-laid at
+    # the program's entry, never inside a loop
+    for width in (640, 128):
+        assert all(m.startswith("main") for m in _cache_sized_moves(
+            admit, f"bf16[{layers},1,1,{window},{width}]"))
+    for compiled in (decode, admit):
+        assert _expert_stack_moves(compiled, "bf16", 16, 6144, 2048) == []
+    for name in ("dsa_index", "dsa_attend"):
+        calls = _kernel_calls(decode, name)
+        assert len(calls) == 2, (name, len(calls))  # a stack each
+        for call in calls:
+            scope = re.search(r'op_name="([^"]*)"', call).group(1)
+            assert scope.count("while/body") == 3, scope
+    assert _kernel_calls(decode, "latent_decode") == []
+    # (a sort's result is a tuple, which ``_instructions`` does not parse)
+    def sorted_shapes(compiled):
+        return re.findall(r"= \((\w+\[[\d,]*\])[^=]* sort\(",
+                          compiled.as_text())
+
+    assert sorted_shapes(decode).count(f"f32[{slots},{window}]") == 2
+    swept = {f"{t}[{slots},64,{one}{window}]"
+             for t in ("f32", "bf16") for one in ("", "1,")}
+    assert [s for _, _, s, _, _ in _instructions(decode) if s in swept] == []
+    assert len(_kernel_calls(admit, "dsa_prefill_select")) == 2
+    assert len(_kernel_calls(admit, "dsa_prefill_attend")) == 2
+    whole = {f"f32[{lead}{heads}{bucket},{bucket}]"
+             for lead in ("", "1,") for heads in ("", "32,", "64,")}
+    assert [s for _, _, s, _, _ in _instructions(admit) if s in whole] == []
+    # (no row's scores are sorted: the router's [T, 256] alone is)
+    assert not [s for s in sorted_shapes(admit) if s.endswith(f",{bucket}]")]
+    args, temps = _donated_bytes(decode)
+    assert 9.05 * GIB < args < 9.25 * GIB, args / GIB  # 7.28 + 1.875
+    assert temps < 0.65 * GIB, temps / GIB
+    m = admit.memory_analysis()
+    assert m.temp_size_in_bytes < 4.1 * GIB, m.temp_size_in_bytes / GIB
+    # beside the live cache and a second staging row
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes + 1.875 * GIB
+            + 0.12 * GIB) < HBM_GIB["v5 lite"] * GIB

@@ -604,7 +604,8 @@ def _family_fixture(name):
             "hybrid": config.tiny_kda_hybrid, "state_space": config.tiny_jamba,
             "windowed": config.tiny_exaone_moe,
             "short_conv": config.tiny_lfm2_moe,
-            "gated_delta": config.tiny_qwen3_next}[name]()
+            "gated_delta": config.tiny_qwen3_next,
+            "sparse_latent": config.tiny_glm_dsa}[name]()
 
 
 # What each family is refused and accepted at PR 45's tree (commit
@@ -629,6 +630,10 @@ WIRED_AT_PR45 = {
     # (PR 57's family, at the tree that brought it)
     "gated_delta": (("stages", "tp", "sp"), False, False,
                     {"int8": False, "int4": False}),
+    # (PR 61: the latent family under a learned sparse attention is
+    # refused and accepted what the latent family is)
+    "sparse_latent": (("stages", "tp", "sp"), True, False,
+                      {"int8": True, "int4": False}),
 }
 
 
@@ -669,3 +674,62 @@ def test_a_family_is_refused_and_accepted_what_it_was(name, tmp_path):
         assert accepted(lambda: load_llama_params_on_mesh(
             tmp_path, cfg, make_mesh(), quantize=quant),
             NotImplementedError) == loads
+
+
+# -- a learned sparse attention's indexer (PR 61) --------------------------------------
+
+def _glm_file(**changes) -> dict:
+    from cake_tpu.models.config import tiny_glm_dsa
+
+    return {**tiny_glm_dsa().to_hf_dict(), "max_position_embeddings": 128,
+            **changes}
+
+
+@pytest.mark.parametrize("make,says", [
+    (lambda: tiny(index_topk=8, index_n_heads=4, index_head_dim=16),
+     "indexer over the cache.*latent-attention family alone"),
+    (lambda: LlamaConfig.from_hf_dict(
+        {**tiny().to_hf_dict(), "index_topk": 8, "index_n_heads": 4,
+         "index_head_dim": 16}),
+     "indexer over the cache.*latent-attention family alone"),
+    (lambda: __import__("cake_tpu.models.config", fromlist=["x"])
+     .tiny_kda_hybrid(index_topk=8, index_n_heads=4, index_head_dim=16),
+     "indexer over the cache.*not for the layers of model_type "
+     "'bailing_hybrid'"),
+    (lambda: LlamaConfig.from_hf_dict(_glm_file(index_topk=0)),
+     "index_topk 0 is no count of rows a sparse attention's indexer"),
+    (lambda: LlamaConfig.from_hf_dict(_glm_file(index_topk=129)),
+     "index_topk 129 is no count of rows.*max_position_embeddings 128"),
+    (lambda: LlamaConfig.from_hf_dict(
+        _glm_file(indexer_rope_interleave=False)),
+     "indexer_rope_interleave false.*is not wired"),
+    (lambda: LlamaConfig.from_hf_dict(_glm_file(
+        rope_parameters={"rope_theta": 1e6, "rope_type": "yarn"})),
+     "rope type 'yarn' is not wired"),
+    (lambda: LlamaConfig(**{**_glm_fields(), "index_topk": 0,
+                            "index_n_heads": 0, "index_head_dim": 0}),
+     "index_topk 0 names no indexer"),
+    (lambda: LlamaConfig(**{**_glm_fields(), "index_head_dim": 4}),
+     "narrower than the 8 channels the indexer rotates"),
+    (lambda: LlamaConfig(**{**_glm_fields(), "q_lora_rank": None}),
+     "indexer .index_topk 8. needs index_n_heads.*over a query latent"),
+    (lambda: LlamaConfig(**{**_glm_fields(), "hc_mult": 4}),
+     "indexer .index_topk > 0. is wired over plain latent attention alone"),
+], ids=["bare-stack-fields", "bare-stack-file", "another-family",
+        "topk-zero", "topk-past-the-window", "half-rotation", "rope-scaling",
+        "no-indexer", "narrow-head", "no-query-latent", "wide-stream"])
+def test_an_indexer_is_refused_where_nothing_computes_it(make, says):
+    """A sparse attention's keys in any other family, an ``index_topk`` of
+    0 or above ``max_position_embeddings``, a rotation or a width nothing
+    here computes: each fails where the configuration is made, with a
+    message that names the mechanism."""
+    with pytest.raises(ValueError, match=says):
+        make()
+
+
+def _glm_fields() -> dict:
+    import dataclasses
+
+    from cake_tpu.models.config import tiny_glm_dsa
+
+    return dataclasses.asdict(tiny_glm_dsa())
