@@ -179,9 +179,11 @@ SERIES: dict[str, tuple[str, str]] = {
                  "the slot's last stream left)"),
     # -- a learned sparse attention over the latent cache (ops/dsa.py, the
     #    engine; named scopes dsa.index, dsa.select, dsa.attend in both
-    #    programs; the trace's operations dsa_index, dsa_attend (decode:
-    #    the choice between them is XLA's sort, the gather XLA's) and
-    #    dsa_prefill_select, dsa_prefill_attend (admission)) ---------------
+    #    programs; the trace's operations dsa_index, then a decode
+    #    program's one of two forms (ops.dsa.attend_form_choice): the
+    #    sweep's dsa_select and dsa_attend over the carried buffer, or the
+    #    gather's sort and gather, which are XLA's, and dsa_attend over the
+    #    copies; dsa_prefill_select, dsa_prefill_attend (admission)) -------
     "cache.index_row_bytes": (
         GAUGE, "bytes the index buffer holds for one token of one layer "
                "(a sparse attention's one index key, normed and rotated, "
@@ -208,6 +210,14 @@ SERIES: dict[str, tuple[str, str]] = {
         COUNTER, "of dsa.admit_rows, the rows that were prompt tokens (the "
                  "rest a bucket's padding, which lies past every frontier "
                  "and is never chosen)"),
+    "dsa.attend_sweep": (
+        GAUGE, "what ops.dsa.attend_form_choice chose for the last decode "
+               "step's sparse attention traced (the decode programs'; by "
+               "the buffer's rows): 1 the sweep (the choice a threshold, "
+               "dsa_select, and dsa_attend over the carried buffer to each "
+               "frontier under the mask: no sort, no gather), 0 the gather "
+               "(lax.top_k, the chosen rows gathered, dsa_attend over the "
+               "copies)"),
     "dsa.decode_calls": (
         COUNTER, "(layer, step) calls of the decode step's sparse attention "
                  "path: the layers under it x the steps, a dispatch (what "
@@ -228,11 +238,21 @@ SERIES: dict[str, tuple[str, str]] = {
                  "decode step and layer under the sparse attention, the "
                  "stream's rows up to its frontier as dispatched (a slot "
                  "without a live stream goes out at row 0: one row)"),
+    "dsa.rows_read": (
+        COUNTER, "latent rows a decode step's attention fetches, over every "
+                 "slot, step and layer under the sparse attention, from the "
+                 "positions as dispatched: under the sweep the whole blocks "
+                 "to the frontier ((frontier // block + 1) x block, the "
+                 "unchosen rows among them read and masked), under the "
+                 "gather the chosen rows (dsa.rows_selected). Over "
+                 "dsa.rows_selected: what the sweep pays in bytes for "
+                 "fetching blocks and not rows"),
     "dsa.rows_selected": (
         COUNTER, "of dsa.rows_live, the rows a step attends: min(frontier + "
                  "1, index_topk) a stream, step and layer: the latent rows "
-                 "a step gathers out of the cache, where a full sweep "
-                 "would read dsa.rows_live"),
+                 "a step's softmax runs over (the gather fetches these "
+                 "alone; the sweep fetches dsa.rows_read and masks the "
+                 "rest), where a full sweep would attend dsa.rows_live"),
     "delta.chunks_swept": (
         COUNTER, "chunks of ops.kda.CHUNK tokens that the delta-rule "
                  "layers' admission scans ran through: delta-rule layers x "

@@ -24,12 +24,28 @@ Two programs, as for MLA:
 
 - a **decode step** (``T == 1``, :func:`decode_attend`): index scores of
   each stream's rows up to its frontier (``dsa.index``), the choice
-  (``dsa.select``: ``lax.top_k``, which keeps the lower row of a tie), and
-  the absorbed attention over the chosen rows, gathered out of the
-  carried buffers (``dsa.attend``): the latent rows a step did not choose
-  are not read. A stream under ``index_topk`` rows chooses all its rows
-  (the surplus choices are rows past its frontier, scored ``-inf`` and
-  masked): the same code, no second program.
+  (``dsa.select``) and the absorbed attention over the chosen rows
+  (``dsa.attend``), in one of two forms a program, picked by the
+  buffer's rows (:func:`attend_form_choice`; the same set of rows and the
+  same softmax either way):
+
+  - **the sweep** (buffers up to ``SWEEP_MAX_ROWS`` rows): the choice is
+    a threshold, each stream's ``index_topk``-th largest score by
+    bisection on the scores' bits (:func:`chosen_mask` is the definition:
+    a tie to the lower row), and the attention sweeps the carried buffer's
+    blocks to each frontier under that mask: nothing is sorted, no row is
+    copied, and the rows a step did not choose are read and masked (a
+    block is a DMA's unit, a row is none: the chosen rows cost more to
+    fetch one at a time than every row costs to stream);
+  - **the gather** (longer buffers): ``lax.top_k``, which keeps the lower
+    row of a tie, the chosen rows gathered out of the carried buffer, and
+    the attention over the copies: the latent rows a step did not choose
+    are not read. It costs ``index_topk`` fetches a stream whatever the
+    frontier, and a sort of the buffer's rows.
+
+  A stream under ``index_topk`` rows chooses all its rows (under the
+  gather the surplus choices are rows past its frontier, scored ``-inf``
+  and masked): the same code, no second program.
 - an **admission** (``T > 1`` from position 0, :func:`prefill_attend`):
   the chunk's own index keys, the scores of a block of query rows at a
   time (never ``[heads, T, T]``, nor ``[T, T]``), each row's threshold (its
@@ -47,6 +63,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.ops import pallas as pk
@@ -145,11 +162,23 @@ def decode_attend(q_c, q_pe, q_i, w, row_cache, i_cache, pos, layer, *,
     or a scalar: each stream's frontier (its new row's position). Returns
     ``(m [B, H, 1, 1], l [B, H, 1, 1], o_c [B, H, 1, dc])`` float32 over
     the chosen rows: the scaled scores' maximum, the normalizer and the
-    un-normalized output in latent space."""
-    b = q_c.shape[0]
+    un-normalized output in latent space. The chosen rows reach the
+    attention as a mask over the buffer or as gathered copies
+    (:func:`attend_form_choice`): the same rows, the same softmax."""
+    b, s = q_c.shape[0], row_cache.shape[-2]
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    sweep = attend_form_choice(s, topk) == "sweep"
+    # trace time: which form the decode program being built holds (read
+    # beside the engine's dsa.rows_read / dsa.rows_selected)
+    obs_metrics.gauge("dsa.attend_sweep").set(int(sweep))
     with jax.named_scope("dsa.index"):
         scores = decode_index_scores(q_i, w, i_cache, pos_b, layer)
+    if sweep:
+        with jax.named_scope("dsa.select"):
+            kept = keep_chosen(scores, pos_b, topk)  # [B, S]
+        with jax.named_scope("dsa.attend"):
+            return attend_kept(q_c, q_pe, row_cache, kept, pos_b, layer,
+                               scale)
     with jax.named_scope("dsa.select"):
         values, rows = choose(scores, topk)  # [B, K]
     with jax.named_scope("dsa.attend"):
@@ -161,20 +190,46 @@ def decode_attend(q_c, q_pe, q_i, w, row_cache, i_cache, pos, layer, *,
         return attend_chosen(q_c, q_pe, row_cache[at], values, scale)
 
 
+def keep_chosen(scores, pos_b, topk: int):
+    """The sweep's choice: ``scores [B, S]`` (``-inf`` past a frontier)
+    with every row that is not among its stream's ``topk`` made ``-inf``
+    (:func:`chosen_mask`'s rows): the kernel's bisection where the shapes
+    allow it, else the ``jnp`` form."""
+    if select_kernel_choice(scores.shape[-1]) == "kernel":
+        return pk.dsa_select(scores, pos_b, topk)
+    return jnp.where(chosen_mask(scores, topk), scores, -jnp.inf)
+
+
+def attend_kept(q_c, q_pe, row_cache, kept, pos_b, layer, scale: float):
+    """The sweep's absorbed attention over the rows of the carried buffer
+    that ``kept [B, S]`` keeps: the kernel, which reads each stream's
+    blocks to its frontier, or XLA's form over the whole layer."""
+    from cake_tpu.ops import kvcache as kv
+
+    if attend_kernel_choice(row_cache.shape[-2], q_c.shape[-1]) == "kernel":
+        return pk.dsa_attend(q_c[:, :, 0], q_pe[:, :, 0], row_cache, kept,
+                             pos_b, scale=scale, layer=layer)
+    return masked_attend(q_c, q_pe, kv.layer_view(row_cache, layer)[:, 0],
+                         kept, scale)
+
+
 def attend_chosen(q_c, q_pe, chosen, values, scale: float):
-    """The absorbed attention over the gathered rows ``chosen [B, K, row
-    width]``: the kernel where the shapes allow it, else XLA's form."""
+    """The gather's absorbed attention over the gathered rows ``chosen [B,
+    K, row width]``: the kernel where the shapes allow it, else XLA's
+    form."""
     if attend_kernel_choice(chosen.shape[1], q_c.shape[-1]) == "kernel":
-        return pk.dsa_attend(q_c[:, :, 0], q_pe[:, :, 0], chosen, values,
-                             scale=scale)
+        return pk.dsa_attend_gathered(q_c[:, :, 0], q_pe[:, :, 0], chosen,
+                                      values, scale=scale)
     return masked_attend(q_c, q_pe, chosen, values, scale)
 
 
 def masked_attend(q_c, q_pe, chosen, values, scale: float):
-    """XLA's form of the absorbed attention over the chosen rows ``chosen
-    [B, K, >= dc + dr]`` (``[c | k_pe | padding]`` each; ``values [B, K]``:
-    their index scores, ``-inf`` where a choice is no row): what
-    :func:`cake_tpu.ops.pallas.dsa.dsa_attend` returns."""
+    """XLA's form of the absorbed attention over the rows ``chosen [B, K,
+    >= dc + dr]`` (``[c | k_pe | padding]`` each; ``values [B, K]``: their
+    index scores, ``-inf`` where one is not attended): the gathered rows,
+    or a whole layer of the buffer under the kept scores. What
+    :func:`cake_tpu.ops.pallas.dsa.dsa_attend` and ``dsa_attend_gathered``
+    return."""
     from cake_tpu.ops.mla import masked_sweep
 
     dc, dr = q_c.shape[-1], q_pe.shape[-1]
@@ -217,9 +272,60 @@ def index_kernel_choice(s: int, dim: int) -> str:
     return "xla"
 
 
+# The buffer's rows ``S`` up to which a decode step sweeps the carried rows
+# under a mask, and past which it gathers the chosen ones: the LARGEST
+# buffer measured, not a crossing: the sweep won at every one
+# (``tools/dsa_sweep.py --rows ..`` on v5 lite, PR 62, GLM-5's widths, the
+# streams that hold 262,144 rows in all, every stream at the frontier; us
+# a layer, index scores + choice + attention, the gather form -> the sweep):
+#   S 16,384 (16 streams): frontier 2048 1320 -> 505; 8192 1298 -> 698;
+#     16,000 1312 -> 989
+#   S 32,768 (8): 8192 1004 -> 549; 16,000 1044 -> 709; 32,000 1079 -> 990
+#   S 65,536 (4): 16,000 1099 -> 575; 32,000 1135 -> 741; 65,000 1163 -> 1098
+#   S 131,072 (2): 16,000 1527 -> 506; 65,000 1544 -> 706; 131,000 1618 -> 1044
+# (the gather's sort grows with S: 290 / 311 / 550 / 1037 us; the swept
+# attention 36 us a thousand rows of frontier over 16 streams)
+SWEEP_MAX_ROWS = 131072
+
+
+def attend_form_choice(s: int, topk: int) -> str:
+    """``"sweep"`` or ``"gather"``: how a decode step's chosen rows reach
+    its attention over a buffer of ``s`` rows, from the shapes a trace
+    sees (the frontier is data, and a branch on it in the scanned layer
+    body is what the chip's compiler answers with copies of the carried
+    buffers). ``"sweep"``: a threshold (no sort), then the buffer's blocks
+    to each frontier under the mask. ``"gather"``: ``lax.top_k``, the
+    gather of ``topk`` rows a stream, the attention over the copies."""
+    return "sweep" if s <= max(topk, SWEEP_MAX_ROWS) else "gather"
+
+
+def rows_fetched(live, s: int, topk: int):
+    """The latent rows a decode step's attention fetches for a stream that
+    holds ``live`` rows (a numpy array of them) of a buffer of ``s``: the
+    sweep's whole blocks to the frontier, or the gather's chosen rows
+    (the engine's ``dsa.rows_read``, from the positions as dispatched)."""
+    live = np.asarray(live)
+    if attend_form_choice(s, topk) == "gather":
+        return np.minimum(live, topk)
+    block = pk.dsa_attend_block(s)
+    return ((live - 1) // block + 1) * block
+
+
+def select_kernel_choice(s: int) -> str:
+    """``"kernel"`` or ``"xla"`` for the sweep's choice among ``s``
+    scores a stream."""
+    if not pk.kernels_enabled():
+        return "xla"
+    if pk.force_kernels() and pk.interpret_default():
+        return "kernel"
+    # whole lane tiles, and whole stretches of the kernel's 2048 columns
+    return "kernel" if s % 128 == 0 and s % min(2048, s) == 0 else "xla"
+
+
 def attend_kernel_choice(k: int, dc: int) -> str:
     """``"kernel"`` or ``"xla"`` for a decode step's absorbed attention
-    over ``k`` chosen rows of ``dc`` latent values."""
+    over ``k`` rows (the chosen ones, gathered, or the buffer's) of ``dc``
+    latent values."""
     if not pk.kernels_enabled():
         return "xla"
     if pk.force_kernels() and pk.interpret_default():

@@ -62,9 +62,12 @@ from cake_tpu.ops.pallas.flash import (  # noqa: E402
 )
 from cake_tpu.ops.pallas.dsa import (  # noqa: E402
     dsa_attend,
+    dsa_attend_block,
+    dsa_attend_gathered,
     dsa_index,
     dsa_prefill_attend,
     dsa_prefill_select,
+    dsa_select,
 )
 from cake_tpu.ops.pallas.kda import kda_decode  # noqa: E402
 from cake_tpu.ops.pallas.latent import latent_decode  # noqa: E402
@@ -96,9 +99,12 @@ __all__ = [
     "flash_decode",
     "narrow_heads",
     "dsa_attend",
+    "dsa_attend_block",
+    "dsa_attend_gathered",
     "dsa_index",
     "dsa_prefill_attend",
     "dsa_prefill_select",
+    "dsa_select",
     "kda_decode",
     "latent_decode",
     "MOE_ROW_TILE",

@@ -110,7 +110,7 @@ from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.obs import prof as obs_prof
 from cake_tpu.obs.trace import span
 from cake_tpu.ops import pallas as pk
-from cake_tpu.ops import quant, sampling
+from cake_tpu.ops import dsa, quant, sampling
 from cake_tpu.ops.kda import CHUNK
 from cake_tpu.ops.moe import form_traced as moe_form_traced
 from cake_tpu.ops.sampling import SamplerSettings
@@ -306,14 +306,17 @@ _RING_ROWS_SWEPT = obs_metrics.counter("attn.ring_rows_swept")
 _KV_BLOCKS_RESERVED = obs_metrics.counter("attn.kv_blocks_reserved")
 # A learned sparse attention (ops/dsa.py), from the positions as
 # dispatched: the rows a decode step's indexer scores (each stream's, to
-# its frontier) and those it attends (at most index_topk of them), a layer
-# a step, and how many (layer, step) calls that was; the rows an admission
+# its frontier), those it attends (at most index_topk of them) and those
+# its attention fetches (the sweep's blocks to the frontier, or the
+# gather's chosen rows), a layer a step, and how many (layer, step) calls
+# that was; the rows an admission
 # launch's programs were handed (buckets), those that were prompt tokens,
 # and how many (layer, dispatch) calls that was
 _DSA_DECODE_CALLS = obs_metrics.counter("dsa.decode_calls")
 _DSA_ADMIT_CALLS = obs_metrics.counter("dsa.admit_calls")
 _DSA_ROWS_LIVE = obs_metrics.counter("dsa.rows_live")
 _DSA_ROWS_SELECTED = obs_metrics.counter("dsa.rows_selected")
+_DSA_ROWS_READ = obs_metrics.counter("dsa.rows_read")
 _DSA_ADMIT_ROWS = obs_metrics.counter("dsa.admit_rows")
 _DSA_ADMIT_ROWS_TRUE = obs_metrics.counter("dsa.admit_rows_true")
 # ... and, at the rows' true lengths, the (query row, row at or before it)
@@ -4008,6 +4011,8 @@ class BatchGenerator:
             _DSA_DECODE_CALLS.inc(layers * steps)
             _DSA_ROWS_LIVE.inc(layers * int(live.sum()))
             _DSA_ROWS_SELECTED.inc(layers * int(np.minimum(live, topk).sum()))
+            _DSA_ROWS_READ.inc(layers * int(dsa.rows_fetched(
+                live, self.max_seq, topk).sum()))
         if self._rings:
             # a window layer's step reads its ring whole; the rows that
             # hold a key its query may see are the window's, or fewer

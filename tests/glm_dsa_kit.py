@@ -28,6 +28,7 @@ import pytest
 
 from cake_tpu.models import llama
 from cake_tpu.models.config import tiny_glm_dsa
+from cake_tpu.ops import dsa
 from cake_tpu.ops.kvcache import init_cache
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.testing import reference_glm_dsa as ref
@@ -82,12 +83,25 @@ def want(tensors):
     return np.asarray(ref.logits(CFG.to_hf_dict(), tensors, TOKENS))
 
 
+@pytest.fixture(params=["sweep", "gather"])
+def form(request, monkeypatch):
+    """Both forms of a decode step's choice and attention
+    (``ops.dsa.attend_form_choice``), by the shape the choice reads: the
+    tests' 64- and 128-row buffers lie under ``SWEEP_MAX_ROWS`` as it is
+    and over one of a row."""
+    if request.param == "gather":
+        monkeypatch.setattr(dsa, "SWEEP_MAX_ROWS", 1)
+    assert dsa.attend_form_choice(64, TOPK) == request.param
+    return request.param
+
+
 _STEPS: dict = {}  # (LlamaConfig holds a dict: no static argument)
 
 
 def _STEP(params, tokens, cache, pos, cfg):
     """``llama.forward`` jitted, one function a configuration."""
-    key = repr(cfg), os.environ.get("CAKE_PALLAS")  # what a trace asks
+    # what a trace asks
+    key = repr(cfg), os.environ.get("CAKE_PALLAS"), dsa.SWEEP_MAX_ROWS
     if key not in _STEPS:
         _STEPS[key] = jax.jit(
             lambda p, t, c, at: llama.forward(p, t, c, at, cfg))

@@ -360,8 +360,12 @@ PR31_TEXTS = {
     "latent_hc.admit": "17ac986347105f98",
     # the latent family under a learned sparse attention, taken on PR 61's
     # tree, which brought it (``index_topk`` 0 lowers every family above
-    # to the text it had: no hash replaced)
-    "sparse_latent.decode": "9b75c3cd53ca71ed",
+    # to the text it had: no hash replaced); the decode step's retaken on
+    # PR 62's tree: off the chip too a buffer of 64 rows takes the sweep's
+    # ``jnp`` form (``chosen_mask`` and the masked sweep of the layer) where
+    # it took ``lax.top_k`` and a gather (``ops.dsa.attend_form_choice``,
+    # by the rows); the admission's, as every family's above, is PR 61's
+    "sparse_latent.decode": "4f84a34ca6d0ea0c",
     "sparse_latent.admit": "9fb811b1982e9704",
     # PR 60 (an admission tells its expert blocks and its chunked delta
     # rule the rows' true lengths) replaced the hybrid's admission alone:

@@ -257,39 +257,60 @@ def _kernel_calls(compiled, name: str) -> list[str]:
             and name in line.split("=")[0]]
 
 
+def _sorted_shapes(compiled) -> list[str]:
+    """The first result of every sort in ``compiled`` (a sort's result is
+    a tuple, which ``_instructions`` does not parse)."""
+    return re.findall(r"= \((\w+\[[\d,]*\])[^=]* sort\(", compiled.as_text())
+
+
+def _glm5(layers: int, window: int):
+    from cake_tpu.models.config import glm5_ep16
+
+    return glm5_ep16(num_hidden_layers=layers, first_k_dense_replace=1,
+                     vocab_size=19360, max_seq_len=window)
+
+
+def _in_the_layer_loop(compiled, name: str, calls: int) -> None:
+    """The kernel ``name`` is called ``calls`` times in ``compiled``, each
+    inside the block's loops (steps, then layers)."""
+    found = _kernel_calls(compiled, name)
+    assert len(found) == calls, (name, len(found))
+    for call in found:
+        scope = re.search(r'op_name="([^"]*)"', call).group(1)
+        assert scope.count("while/body") == 3, scope
+
+
 def test_sparse_latent_programs_read_the_chosen_rows_and_write_no_scores(
         topo, as_on_chip):
     """The latent family under a learned sparse attention at GLM-5's
     published widths, the cell ``glm5-ep16-cut.agent-long`` itself: 1
     dense + 4 expert layers, 16 slots x 16,384 rows, and its largest
     admission, 16,384 rows. The chip's compiler takes them. The cache's
-    three kinds of row (the latent, the roped key part, the index key) are
-    carried through both stacks and written in place: in the decode
-    program nothing of the latent or the index buffer's shape is allocated
-    or copied; the roped
-    part, which the chip holds rows-on-lanes, is re-laid ONCE a dispatch
-    at the program's entry for the gather of the chosen rows (one copy in
-    ENTRY, 168 MB a block of 8 steps: PERF.md section 7 says what would
-    take it away), never a layer. A decode step's index scores are the
-    kernel on the carried index buffer (``dsa_index``), its choice XLA's
-    sort of ``[16, 16384]`` scores, its attention the kernel over the
-    gathered ``[16, 2048, 640]`` rows (``dsa_attend``), each once a
-    stack inside the layer loop: no score ``[16, 64, 16384]`` of a full
-    sweep exists, and the plain latent kernel is not called. The admission
-    holds the choice and the masked sweep as kernels (``dsa_prefill_
-    select``, ``dsa_prefill_attend``) and writes out neither the heads'
-    index products ``[32, T, T]``, nor the index scores ``[T, T]`` in
-    float32, nor the attention's ``[64, T, T]``: a row's mask ``[T, T]``
-    int8 is what passes between them (268 MB). RECORDED (my AOT compiles,
-    PR 61): block decode 9.16 GiB of arguments + 0.57 of temporaries; the
+    two kinds of row (``[c | k_pe | padding]`` in ONE row of 640, the
+    second buffer empty: ``cache_plan`` ``rows (5, 1, 640, 0)``; the index
+    key) are carried through both stacks and written in place: in the
+    decode program nothing of the latent or the index buffer's shape is
+    allocated or copied. A decode step runs the SWEEP (16,384 rows lie
+    under ``ops.dsa.SWEEP_MAX_ROWS``; PR 62): its index scores are the
+    kernel on the carried index buffer (``dsa_index``), its choice a
+    threshold (``dsa_select``: no ``sort`` of ``[16, 16384]`` scores), its
+    attention the kernel over the carried row buffer itself under the kept
+    scores (``dsa_attend``: no gathered ``[16, 2048, 640]`` or ``[32768,
+    640]`` rows exist), each once a stack inside the layer loop: no score
+    ``[16, 64, 16384]`` of a full sweep exists, and the plain latent
+    kernel is not called. The admission holds the choice and the masked
+    sweep as kernels (``dsa_prefill_select``, ``dsa_prefill_attend``) and
+    writes out neither the heads' index products ``[32, T, T]``, nor the
+    index scores ``[T, T]`` in float32, nor the attention's ``[64, T,
+    T]``: a row's mask ``[T, T]`` int8 is what passes between them (268
+    MB). RECORDED (my AOT compiles, PR 62): block decode 9.16 GiB of
+    arguments + 0.49 of temporaries (PR 61, the gather: 9.16 + 0.57); the
     16,384-row admission 7.40 + 3.95 GiB beside the 1.875 GiB live cache
     (8192 rows: 1.89; 4096: 0.95): well under the 6 GiB the cut leaves."""
-    from cake_tpu.models.config import glm5_ep16
     from cake_tpu.utils.chips import HBM_GIB
 
     layers, slots, window, bucket = 5, 16, 16384, 16384
-    config = glm5_ep16(num_hidden_layers=layers, first_k_dense_replace=1,
-                       vocab_size=19360, max_seq_len=window)
+    config = _glm5(layers, window)
     decode, admit = _family_programs(topo, config, slots, window, bucket)
     assert config.cache_plan == {"rows": (5, 1, 640, 0),
                                  "index": (5, 1, 128)}
@@ -303,34 +324,60 @@ def test_sparse_latent_programs_read_the_chosen_rows_and_write_no_scores(
             admit, f"bf16[{layers},1,1,{window},{width}]"))
     for compiled in (decode, admit):
         assert _expert_stack_moves(compiled, "bf16", 16, 6144, 2048) == []
-    for name in ("dsa_index", "dsa_attend"):
-        calls = _kernel_calls(decode, name)
-        assert len(calls) == 2, (name, len(calls))  # a stack each
-        for call in calls:
-            scope = re.search(r'op_name="([^"]*)"', call).group(1)
-            assert scope.count("while/body") == 3, scope
+    for name in ("dsa_index", "dsa_select", "dsa_attend"):
+        _in_the_layer_loop(decode, name, calls=2)  # a stack each
     assert _kernel_calls(decode, "latent_decode") == []
-    # (a sort's result is a tuple, which ``_instructions`` does not parse)
-    def sorted_shapes(compiled):
-        return re.findall(r"= \((\w+\[[\d,]*\])[^=]* sort\(",
-                          compiled.as_text())
-
-    assert sorted_shapes(decode).count(f"f32[{slots},{window}]") == 2
+    assert f"f32[{slots},{window}]" not in _sorted_shapes(decode)
+    gathered = {f"bf16[{slots},{config.index_topk},640]",
+                f"bf16[{slots * config.index_topk},640]"}
     swept = {f"{t}[{slots},64,{one}{window}]"
              for t in ("f32", "bf16") for one in ("", "1,")}
-    assert [s for _, _, s, _, _ in _instructions(decode) if s in swept] == []
+    assert [s for _, _, s, _, _ in _instructions(decode)
+            if s in gathered | swept] == []
     assert len(_kernel_calls(admit, "dsa_prefill_select")) == 2
     assert len(_kernel_calls(admit, "dsa_prefill_attend")) == 2
     whole = {f"f32[{lead}{heads}{bucket},{bucket}]"
              for lead in ("", "1,") for heads in ("", "32,", "64,")}
     assert [s for _, _, s, _, _ in _instructions(admit) if s in whole] == []
     # (no row's scores are sorted: the router's [T, 256] alone is)
-    assert not [s for s in sorted_shapes(admit) if s.endswith(f",{bucket}]")]
+    assert not [s for s in _sorted_shapes(admit) if s.endswith(f",{bucket}]")]
     args, temps = _donated_bytes(decode)
     assert 9.05 * GIB < args < 9.25 * GIB, args / GIB  # 7.28 + 1.875
-    assert temps < 0.65 * GIB, temps / GIB
+    assert temps < 0.55 * GIB, temps / GIB
     m = admit.memory_analysis()
     assert m.temp_size_in_bytes < 4.1 * GIB, m.temp_size_in_bytes / GIB
     # beside the live cache and a second staging row
     assert (m.argument_size_in_bytes + m.temp_size_in_bytes + 1.875 * GIB
             + 0.12 * GIB) < HBM_GIB["v5 lite"] * GIB
+
+
+def test_sparse_latent_decode_gathers_the_chosen_rows_of_a_long_buffer(
+        topo, as_on_chip):
+    """The same model over a buffer PAST ``ops.dsa.SWEEP_MAX_ROWS``, 2
+    slots x 202,752 rows (the published positions; 3.1 GB of cache): the
+    chip's compiler takes the decode program in the GATHER form as PR 61
+    left it: the choice ``lax.top_k`` of ``[2, 202752]`` scores, the chosen
+    ``[2, 2048, 640]`` rows gathered out of the carried buffer and
+    attended by the kernel over the copies (trace name ``dsa_attend``),
+    each once a stack inside the layer loop; no ``dsa_select``, no
+    ``latent_decode``, and still no copy of a cache-sized buffer."""
+    from cake_tpu.ops import dsa
+
+    layers, slots, window = 5, 2, 202752
+    config = _glm5(layers, window)
+    assert dsa.attend_form_choice(window, config.index_topk) == "gather"
+    assert dsa.attend_form_choice(16384, config.index_topk) == "sweep"
+    decode, = _family_programs(topo, config, slots, window)
+    for width in (640, 128):
+        assert _cache_sized_moves(
+            decode, f"bf16[{layers},{slots},1,{window},{width}]") == []
+    for name in ("dsa_index", "dsa_attend"):
+        _in_the_layer_loop(decode, name, calls=2)
+    assert _kernel_calls(decode, "dsa_select") == []
+    assert _kernel_calls(decode, "latent_decode") == []
+    # (the choice is ``lax.top_k``: a sort of the scores at 65,536 rows,
+    # the compiler's ``TopK`` call at these)
+    assert 'dsa.select/top_k"' in decode.as_text()
+    gathered = {f"bf16[{slots},{config.index_topk},640]",
+                f"bf16[{slots * config.index_topk},640]"}
+    assert [s for _, _, s, _, _ in _instructions(decode) if s in gathered]

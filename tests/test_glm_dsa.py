@@ -43,7 +43,7 @@ from cake_tpu.testing import reference_glm_dsa as ref
 from cake_tpu.utils.weights import load_llama_params
 
 from glm_dsa_kit import (  # noqa: F401
-    CFG, ROOT, TIGHT, TOKENS, TOPK, WIDE, _STEP, _decode_all, params,
+    CFG, ROOT, TIGHT, TOKENS, TOPK, WIDE, _STEP, _decode_all, form, params,
     tensors, want,
 )
 
@@ -63,11 +63,14 @@ def test_a_prompts_logits_are_the_references(params, want, n):
 
 @pytest.mark.parametrize("prefill", [3, TOPK, 24],
                          ids=["under", "at", "three-times"])
-def test_decode_through_the_cache_is_the_reference(params, want, prefill):
+def test_decode_through_the_cache_is_the_reference(params, want, prefill,
+                                                   form):
     """A prefill, then a step a token to five times ``index_topk``: every
-    step scores the cached index keys, chooses, gathers and attends (the
-    decode's form), and gives the reference's logits; a stream that
-    starts under ``index_topk`` rows crosses it on the way."""
+    step scores the cached index keys, chooses and attends (the decode's
+    two forms: a threshold and the sweep of the buffer under the mask, or
+    a sort, the gather and the copies), and gives the reference's logits;
+    a stream that starts under ``index_topk`` rows crosses it on the
+    way."""
     got, cache = _decode_all(params, CFG, TOKENS, prefill)
     np.testing.assert_allclose(got, want[prefill - 1:], atol=TIGHT, rtol=0)
     # a third kind of row: one index key a token a layer, written beside
@@ -96,9 +99,21 @@ def _layer0(params, tokens):
     return dsa.index_projections(x, c_q, layer, cos, sin, 0, CFG)
 
 
-def test_the_chosen_sets_are_the_references(params, tensors):
+def _decode_choice(scores, pos, k, form):
+    """The rows (ascending) a decode step of ``form`` attends, a stream:
+    what the sweep keeps, or what the gather's ``top_k`` names (a choice
+    scored ``-inf`` is no row)."""
+    if form == "sweep":
+        kept = np.asarray(dsa.keep_chosen(scores, jnp.asarray(pos), k))
+        return [np.flatnonzero(row > -np.inf).tolist() for row in kept]
+    values, rows = (np.asarray(a) for a in dsa.choose(scores, k))
+    return [sorted(r[v > -np.inf].tolist()) for v, r in zip(values, rows)]
+
+
+def test_the_chosen_sets_are_the_references(params, tensors, form):
     """Layer 0 of the 40 tokens: the admission's mask row by row, and the
-    decode's choice for the last row, are the reference's ``S_t``."""
+    decode's choice for the last row (by either form), are the
+    reference's ``S_t``."""
     chosen: list = []
     ref.logits(CFG.to_hf_dict(), tensors, TOKENS, chosen=chosen)
     q_i, k_i, w = _layer0(params, TOKENS)
@@ -107,21 +122,22 @@ def test_the_chosen_sets_are_the_references(params, tensors):
         assert len(rows) == min(t + 1, TOPK)
         assert np.flatnonzero(mask[t]).tolist() == rows, t
     scores = dsa.index_scores(q_i[:, :, -1:], w[:, -1:], k_i[:, 0])[:, 0]
-    _, picked = dsa.choose(scores, TOPK)
-    assert sorted(np.asarray(picked)[0].tolist()) == chosen[0][-1]
+    assert _decode_choice(scores, [len(TOKENS) - 1], TOPK, form) == [
+        chosen[0][-1]]
 
 
-def test_a_tie_goes_to_the_lower_row():
-    """Equal scores: the mask and the decode's choice keep the LOWER rows,
-    as the reference's stable sort does; rows of ``-inf`` (past a
-    frontier, above the diagonal) are never chosen, however few are
-    left."""
+def test_a_tie_goes_to_the_lower_row(form):
+    """Equal scores: the mask and the decode's choice (by either form)
+    keep the LOWER rows, as the reference's stable sort does; rows of
+    ``-inf`` (past a frontier, above the diagonal) are never chosen,
+    however few are left."""
     scores = jnp.asarray([[1.0, 3.0, 1.0, 3.0, 1.0, 0.5, 1.0, -jnp.inf],
                           [2.0, -jnp.inf, -jnp.inf, -jnp.inf, -jnp.inf,
                            -jnp.inf, -jnp.inf, -jnp.inf]])
     mask = np.asarray(dsa.chosen_mask(scores, 4))
     assert np.flatnonzero(mask[0]).tolist() == [0, 1, 2, 3]
     assert np.flatnonzero(mask[1]).tolist() == [0]
+    assert _decode_choice(scores, [6, 0], 4, form) == [[0, 1, 2, 3], [0]]
     values, rows = dsa.choose(scores, 4)
     assert np.asarray(rows)[0].tolist() == [1, 3, 0, 2]
     assert np.asarray(values)[1].tolist() == [2.0] + [-np.inf] * 3
@@ -148,11 +164,12 @@ def test_every_control_fails_by_a_wide_factor(params, tensors, control):
 
 # -- (d) the tie to the shared code ----------------------------------------------------
 
-def test_a_stream_under_topk_rows_is_the_plain_latent_path(params):
+def test_a_stream_under_topk_rows_is_the_plain_latent_path(params, form):
     """The same weights without the indexer, served as a plain latent
     model (``index_topk`` 0: ``ops/mla.py``'s own forms), give the sparse
-    model's logits while a stream holds no more than ``index_topk`` rows,
-    one chunk or step by step, and other logits from the next row on."""
+    model's logits (by either form of its decode step) while a stream
+    holds no more than ``index_topk`` rows, one chunk or step by step, and
+    other logits from the next row on."""
     plain_cfg = dataclasses.replace(
         CFG, model_type="deepseek_v3", index_topk=0, index_n_heads=0,
         index_head_dim=0)
@@ -181,15 +198,7 @@ def _close(got, want, tol):
                                atol=tol, rtol=0)
 
 
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
-                                       (jnp.bfloat16, 2e-2)],
-                         ids=["float32", "bfloat16"])
-def test_the_kernels_are_the_jnp_forms(dtype, tol):
-    """``ops/pallas/dsa.py``'s four kernels in interpret mode against the
-    ``jnp`` forms of ``ops/dsa.py`` (same operands, float32 products: the
-    tolerance is a sum's order; bfloat16 operands round the probabilities
-    once more). The admission's choice, a bisection on the scores' bits
-    where the ``jnp`` form sorts, is the same mask cell for cell."""
+def _index_case(dtype, tol):
     b, j, d, s, layers = 2, 4, 128, 1024, 2
     q_i, w = _rand(0, (b, j, 1, d), dtype), _rand(1, (b, 1, j))
     i_cache = _rand(2, (layers, b, 1, s, d), dtype)
@@ -199,24 +208,31 @@ def test_the_kernels_are_the_jnp_forms(dtype, tol):
            jnp.where(jnp.arange(s)[None] <= pos[:, None],
                      dsa.index_scores(q_i, w, i_cache[1, :, 0])[:, 0],
                      -jnp.inf), tol)
-    # the chosen rows' absorbed attention, some choices no row at all
-    h, dc, dr, k = 8, 128, 64, 1024
+
+
+def _attend_gathered_case(dtype, tol):
+    """The chosen rows' absorbed attention, some choices no row at all."""
+    from cake_tpu.ops.mla import masked_sweep
+
+    b, h, dc, dr, k = 2, 8, 128, 64, 1024
     q_c, q_pe = _rand(3, (b, h, 1, dc), dtype), _rand(4, (b, h, 1, dr), dtype)
     c, r = _rand(5, (b, k, dc), dtype), _rand(6, (b, k, dr), dtype)
     values = jnp.where(jnp.arange(k)[None] < jnp.asarray([[300], [k]]), 1.0,
                        -jnp.inf)
-    from cake_tpu.ops.mla import masked_sweep
-
     m, p, o = masked_sweep(q_c, q_pe, c, r,
                            (values > -jnp.inf)[:, None, None], 0.1)
-    m2, l2, o2 = pk.dsa_attend(q_c[:, :, 0], q_pe[:, :, 0],
-                               jnp.concatenate([c, r], -1), values,
-                               scale=0.1, interpret=True)
+    m2, l2, o2 = pk.dsa_attend_gathered(
+        q_c[:, :, 0], q_pe[:, :, 0], jnp.concatenate([c, r], -1), values,
+        scale=0.1, interpret=True)
     _close(m2, m, tol)
     _close(o2 / l2, o / p.sum(-1, keepdims=True), tol)
-    # an admission's choice: every row's mask, ties among them (rows 64-127
-    # hold the keys of rows 192-255, so a later query scores them alike)
-    t = 1024
+
+
+def _prefill_select_case(dtype, tol):
+    """An admission's choice: every row's mask, ties among them (rows
+    64-127 hold the keys of rows 192-255, so a later query scores them
+    alike)."""
+    b, j, d, t = 2, 4, 128, 1024
     q_t, w_t = _rand(7, (b, j, t, d), dtype), _rand(8, (b, t, j))
     k_i = _rand(9, (b, t, d), dtype)
     k_i = k_i.at[:, 64:128].set(k_i[:, 192:256])
@@ -226,7 +242,11 @@ def test_the_kernels_are_the_jnp_forms(dtype, tol):
             np.asarray(got), np.asarray(dsa.prefill_mask(q_t, w_t, k_i, topk)))
         assert (np.asarray(got).sum(-1)
                 == np.minimum(np.arange(t) + 1, topk)).all()
-    # the flash sweep under each row's mask (a row may see ONE key)
+
+
+def _prefill_attend_case(dtype, tol):
+    """The flash sweep under each row's mask (a row may see ONE key)."""
+    b, h, d, t = 2, 8, 128, 1024
     q, kk, v = (_rand(n, (b, h, t, d), dtype) for n in (10, 11, 12))
     causal = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
     mask = dsa.chosen_mask(
@@ -238,15 +258,107 @@ def test_the_kernels_are_the_jnp_forms(dtype, tol):
            dsa.prefill_attend(q, kk, v, mask, scale=d ** -0.5), tol)
 
 
+def _select_case(dtype, tol):
+    """The sweep's choice against ``chosen_mask``, cell for cell: nine
+    streams over 4096 scores (two stretches of columns) rounded to
+    quarters, so that dozens tie at every threshold (``room`` several),
+    and one stream built so that ONE of three at the threshold fits
+    (``room`` 1); frontiers under, at and past ``topk`` rows, inside a
+    stretch and at the buffer's end; what lies past a frontier is FINITE
+    here (stale scores) and never kept, nor is a live row of ``-inf``."""
+    s, topk = 4096, 256
+    scores = jnp.round(_rand(14, (9, s), dtype).astype(jnp.float32) * 4) / 4
+    pos = jnp.asarray([0, 5, topk - 2, topk - 1, topk, 2100, s - 1, 999,
+                       s - 1])
+    scores = scores.at[5, 17].set(-jnp.inf)
+    # stream 8: 200 rows of 1.0, then zeros of BOTH signs by turns: -0.0
+    # ties with 0.0 (``==``), so the first 56 of them are kept, whatever
+    # their sign
+    zeros = jnp.where(jnp.arange(s) % 2 == 0, -0.0, 0.0)
+    scores = scores.at[8].set(jnp.where(jnp.arange(s) < 200, 1.0, zeros))
+    # stream 7: 255 rows above 1.0, then three AT it: rows 400, 600, 800
+    one = jnp.where(jnp.arange(s) < 255, 2.0 + jnp.arange(s) % 7, 0.0)
+    scores = scores.at[7].set(one.at[jnp.asarray([400, 600, 800])].set(1.0))
+    live = jnp.arange(s)[None] <= pos[:, None]
+    want = np.asarray(dsa.chosen_mask(jnp.where(live, scores, -jnp.inf),
+                                      topk))
+    got = np.asarray(pk.dsa_select(scores, pos, topk, interpret=True))
+    np.testing.assert_array_equal(got > -np.inf, want)
+    np.testing.assert_array_equal(got[want], np.asarray(scores)[want])
+    assert want.sum(-1).tolist() == [1, 6, 255, 256, 256, 256, 256, 256, 256]
+    assert want[7, 400] and not want[7, 600] and not want[5, 17]
+    assert np.flatnonzero(want[8]).tolist() == list(range(256))
+    # a threshold shared by dozens: the lower rows of them, no more
+    theta = np.where(want[6], np.asarray(scores[6]), np.inf).min()
+    ties = np.flatnonzero(np.asarray(scores[6]) == theta)
+    kept = int(want[6, ties].sum())
+    assert 1 < kept < len(ties) and want[6, ties[:kept]].all()
+    # topk at or past the buffer: every live row (a tiny model's case)
+    short = pk.dsa_select(scores[:, :128], jnp.minimum(pos, 127), 128,
+                          interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(short) > -np.inf, np.asarray(
+            (jnp.arange(128)[None] <= jnp.minimum(pos, 127)[:, None])
+            & (scores[:, :128] > -jnp.inf)))
+
+
+def _attend_swept_case(dtype, tol):
+    """The sweep's attention over the carried buffer against
+    ``masked_attend`` over the same rows: frontiers that end in different
+    blocks (a stream of ONE row, one inside a block, one at the buffer's
+    end), a stream whose first two blocks hold no kept row, the buffer
+    stacked (``layer`` a traced value) and not."""
+    b, h, dc, dr, s, layers = 4, 8, 128, 64, 2048, 2
+    q_c, q_pe = _rand(15, (b, h, 1, dc), dtype), _rand(16, (b, h, 1, dr),
+                                                      dtype)
+    rows_all = _rand(17, (layers, b, 1, s, 256), dtype)
+    pos = jnp.asarray([0, 700, s - 1, 1500])
+    scores = _rand(18, (b, s)).at[3, :1024].set(-1e3)
+    kept = dsa.keep_chosen(
+        jnp.where(jnp.arange(s)[None] <= pos[:, None], scores, -jnp.inf),
+        pos, 256)
+    assert (np.asarray(kept > -jnp.inf).sum(-1) == [1, 256, 256, 256]).all()
+    assert not np.asarray(kept[3, :1024] > -jnp.inf).any()
+    for layer, buffer in ((1, rows_all), (None, rows_all[0])):
+        view = buffer[layer, :, 0] if layer is not None else buffer[:, 0]
+        m, l, o = dsa.masked_attend(q_c, q_pe, view, kept, 0.1)
+        m2, l2, o2 = jax.jit(lambda layer: pk.dsa_attend(
+            q_c[:, :, 0], q_pe[:, :, 0], buffer, kept, pos, scale=0.1,
+            layer=layer, block_k=512, interpret=True))(layer)
+        _close(m2, m, tol)
+        _close(o2 / l2, o / l, tol)
+
+
+@pytest.mark.parametrize("kernel", [
+    "index", "attend-gathered", "prefill-select", "prefill-attend", "select",
+    "attend-swept"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+def test_the_kernels_are_the_jnp_forms(dtype, tol, kernel):
+    """``ops/pallas/dsa.py``'s six kernels in interpret mode against the
+    ``jnp`` forms of ``ops/dsa.py`` (same operands, float32 products: the
+    tolerance is a sum's order; bfloat16 operands round the probabilities
+    once more). A choice, a bisection on the scores' bits where the
+    ``jnp`` form sorts, is the same mask cell for cell: an admission's
+    (``dsa_prefill_select``) and a decode step's (``dsa_select``)."""
+    {"index": _index_case, "attend-gathered": _attend_gathered_case,
+     "prefill-select": _prefill_select_case,
+     "prefill-attend": _prefill_attend_case, "select": _select_case,
+     "attend-swept": _attend_swept_case}[kernel](dtype, tol)
+
+
 def test_the_program_under_interpreted_kernels_is_the_reference(
-        params, want, monkeypatch):
+        params, want, monkeypatch, form):
     """``CAKE_PALLAS=1`` on the CPU: the admission's strips and masked
-    sweep and the decode's index scores and attention run through the
-    interpreted kernels, and the logits are still the reference's."""
+    sweep and the decode's index scores, choice and attention (by either
+    form) run through the interpreted kernels, and the logits are still
+    the reference's."""
     monkeypatch.setenv("CAKE_PALLAS", "1")
     assert dsa.index_kernel_choice(64, CFG.index_head_dim) == "kernel"
     assert dsa.prefill_kernel_choice(24, 24, 24, 16) == "kernel"
     assert dsa.attend_kernel_choice(8, 16) == "kernel"
+    assert dsa.select_kernel_choice(64) == "kernel"
     got, _ = _decode_all(params, CFG, TOKENS[:30], 24)
     np.testing.assert_allclose(got, want[23:30], atol=TIGHT, rtol=0)
 

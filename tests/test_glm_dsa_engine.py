@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from cake_tpu.obs import metrics
+from cake_tpu.ops import dsa
 from cake_tpu.ops.kvcache import init_cache
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.utils.weights import save_llama_params
@@ -44,7 +45,8 @@ def test_batch_generator_streams_match_reference(params, tensors):
     step scored and attended."""
     reg = metrics.registry()
     live, chosen = _count("dsa.rows_live"), _count("dsa.rows_selected")
-    before = live.value, chosen.value
+    read = _count("dsa.rows_read")
+    before = live.value, chosen.value, read.value
     bg = _engine(params, PROMPTS[:3])
     outs = bg.generate(13)
     for prompt, out in zip(PROMPTS[:3], outs):
@@ -68,6 +70,15 @@ def test_batch_generator_streams_match_reference(params, tensors):
                  if rows(j, lambda r: r) == live.value - before[0])
     assert chosen.value - before[1] == rows(
         steps, lambda r: np.minimum(r, TOPK))
+    # the program traced the sweep (128 rows a stream lie under
+    # SWEEP_MAX_ROWS), whose attention fetches whole blocks to a frontier:
+    # here the one block a 128-row buffer is
+    assert reg.gauge("dsa.attend_sweep").value == 1
+    assert read.value - before[2] == rows(steps, lambda r: 0 * r + 128)
+    assert dsa.rows_fetched(np.array([1, 1024, 1025]), 16384, 2048).tolist() \
+        == [1024, 1024, 2048]
+    assert dsa.rows_fetched(np.array([1, 5000]), 2 * dsa.SWEEP_MAX_ROWS,
+                            2048).tolist() == [1, 2048]
     assert bg.stats()["tokens_emitted"] == 3 * 13
 
 
@@ -285,11 +296,12 @@ def test_dsa_sweep_rows_at_tiny_shapes(monkeypatch, capsys):
 
     monkeypatch.setattr(dsa_sweep, "REPEATS", 1)
     monkeypatch.setattr(dsa_sweep, "LAYERS", 2)
-    assert dsa_sweep.main(["--tiny", "--frontier", "300", "--buckets",
-                           "512"]) == 0
+    assert dsa_sweep.main(["--tiny", "--frontier", "300", "--attend-block",
+                           "128,512", "--buckets", "512"]) == 0
     rows = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
     assert [r["part"] for r in rows] == [
         "index", "select", "gather", "gather_in_row_order", "attend",
-        "full_sweep",
+        "select_threshold", "full_sweep", "attend_swept[128]",
+        "attend_swept[512]", "gather_path", "sweep_path",
         "prefill_select", "prefill_attend", "sorted_strips"]
     assert all(r["us_per_layer"] > 0 for r in rows)
