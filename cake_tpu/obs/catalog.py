@@ -314,6 +314,15 @@ SERIES: dict[str, tuple[str, str]] = {
                  "pair on a held expert: live tiles x the row tile; the "
                  "others, a bucket's padding among them, are neither "
                  "read nor written), counted on the device"),
+    "moe.gather_rows_fetched": (
+        COUNTER, "of moe.sorted_pair_rows_live, the rows of admission "
+                 "dispatches whose sorted calls gathered them by address "
+                 "(ops.moe.gather_form: a long bucket's; a short one's "
+                 "and a decode step's are picked by a one-hot product)"),
+    "moe.gather_fetch_min_rows": (
+        GAUGE, "the fewest rows of a traced sorted call that gathered its "
+               "live tiles' rows by address (ops.moe.gather_form, set at "
+               "trace time; 0 while none has)"),
     "moe.sorted_from_rows": (
         GAUGE, "the fewest rows of a traced call whose expert block took "
                "the sorted form (ops.moe.expert_form, set at trace time; "

@@ -40,8 +40,10 @@ TPU-first design:
     tail and with them the pairs of a bucket's padding (``valid``: the
     rows' true lengths, where the caller knows them); from there on only
     the LIVE row tiles (those that hold a true pair on a held expert) are
-    read or written: their rows gathered; gate, up and the SwiGLU one
-    grouped call and down another over the contiguous groups
+    read or written: their rows gathered (:func:`gather_form`: picked by
+    a one-hot product, or fetched by address out of a long bucket); gate,
+    up and the SwiGLU one grouped call and down another over the
+    contiguous groups
     (:func:`cake_tpu.ops.pallas.grouped_swiglu`,
     :func:`~cake_tpu.ops.pallas.grouped_matmul`: a group without a row is
     never visited, so its matrices are never read; an int8 stack streams
@@ -83,7 +85,10 @@ GATHER_MAX_ROWS = 8
 # where tools/moe_sweep.py measured it at 1.10x the dense form or better at
 # EVERY cell's shape (my chip runs, PR 33 and PR 35, and PR 56's over the
 # live-tile form at all seven shapes, which moved neither constant;
-# PERF.md section 6 keeps the table). int8: 2.99x from 128 rows on (the
+# PERF.md section 6 keeps the table; at these rows the live tiles' gather
+# is the one-hot product, which PR 63's fetch by address replaces from
+# GATHER_FETCH_MIN_ROWS rows on: its sweep left the points here where
+# they were). int8: 2.99x from 128 rows on (the
 # dense form's dequantised product writes a layer's stack out first) and
 # 0.71x at 64, where that form has no slab and runs at 89% of the bytes'
 # roofline. bf16 at 512 rows: 1.44x (64 held of 64, 2304 wide) to 1.98x
@@ -102,10 +107,25 @@ SORTED_MIN_ROWS = 512
 # at 0.41, 1.29x at 0.66, 0.95x at 0.74, 0.82x at 0.88.
 SORTED_MAX_HIT_SHARE_INT8 = 0.7
 SORTED_MAX_HIT_SHARE = 0.8
+# Rows of a compacting sorted call from which its live tiles' rows are
+# fetched by address and not picked by a one-hot product
+# (:func:`gather_form`): where tools/moe_sweep.py --forms onehot,fetch
+# measured the block 1.10x the one-hot form's or better at every shape
+# whose row is whole tiles of words (my chip runs, PR 63; us a layer,
+# one-hot -> fetch; PERF.md section 6 keeps the table). qwen3next-ep4
+# (H 2048): 512 rows 1315 -> 1325, 1024 1526 -> 1500, 2048 2111 -> 1964
+# (1.075x), 4096 3723 -> 2951 (1.26x), 8192 9102 -> 5586 (1.63x); glm5-ep16
+# (H 6144): 2048 2955 -> 2834 (1.04x), 4096 4514 -> 3996 (1.13x), 8192
+# 8552 -> 6422, 16,384 22,269 -> 13,479 (1.65x); kexaone-ep8 (H 6144): 512
+# 2089 -> 2093, 2048 3562 -> 3353 (1.06x). Under the bar at 2048 rows at
+# all three, over it from 4096 on.
+GATHER_FETCH_MIN_ROWS = 4096
 
 # rows of a call -> the form its trace took (what the engine's admission
 # counters ask: the form is a function of the shapes, so one entry a shape)
 _traced: dict[int, str] = {}
+# ... and the rows of the sorted calls whose gather was traced as a fetch
+_fetched: set[int] = set()
 
 
 class ExpertCount(NamedTuple):
@@ -277,7 +297,10 @@ def _moe_sorted(
     is not here sorts to the tail, and so does a pair of a bucket's
     padding: a row that ``true`` does not name), and from there on only
     the LIVE row tiles, those that hold a true pair on a held expert, are
-    read or written: their rows gathered, gate, up and the SwiGLU one
+    read or written: their rows gathered (picked out of the bucket by a
+    one-hot product at a step's rows and a short bucket's, fetched by
+    address from a long bucket on, where the product would cost more than
+    the experts' own: :func:`gather_form`), gate, up and the SwiGLU one
     grouped call, down another, and every row's results summed under its
     routing weights in float32 over the live tiles' rows. Where every
     scored expert is held every tile is live but padding's, and the rows
@@ -317,8 +340,13 @@ def _moe_sorted(
 
     (gate, gate_scale), (up, up_scale) = split(w_gate), split(w_up)
     down, down_scale = split(w_down)
-    if compact:
-        xs = pk.gather_rows(x2d, token, tiles, tm=tm)  # [M, H], live tiles
+    if compact:  # [M, H], the live tiles': by address from a long bucket on
+        fetch = gather_form(n, x2d.shape[1], x2d.dtype) == "fetch"
+        if fetch:
+            _fetched.add(n)
+            gauge = obs_metrics.gauge("moe.gather_fetch_min_rows")
+            gauge.set(min(n, gauge.value or n))
+        xs = pk.gather_rows(x2d, token, tiles, fetch=fetch, tm=tm)
     else:
         xs = jnp.take(x2d, token, axis=0)
     act = pk.grouped_swiglu(xs, gate, up, tiles, layer=layer,
@@ -345,11 +373,30 @@ def compacts(held: int, scored: int) -> bool:
     kernels that run over the live row tiles alone? Yes where the stacks
     hold a share of the scored experts: most pairs then lie on experts
     that are elsewhere, and moving all ``N x k`` rows is moving mostly
-    nothing. Where every scored expert is held every pair is live: XLA's
+    nothing (the gather by a one-hot product or, from a long bucket on,
+    by address, :func:`gather_form`; the sum a row at a time into a
+    float32 block held in VMEM). Where every scored expert is held every
+    pair is live: XLA's
     gather and sum then move exactly the live rows, at the memory's rate,
     which a row at a time in a kernel does not reach
     (``tools/moe_sweep.py --forms compact``; PERF.md section 6, PR 56)."""
     return held < scored
+
+
+def gather_form(rows: int, hidden: int, dtype) -> str:
+    """``"fetch"`` or ``"onehot"``: how a compacting sorted call of
+    ``rows`` rows gathers its live tiles' rows
+    (:func:`cake_tpu.ops.pallas.gather_rows`), from what its trace sees.
+    One gather, whose cheaper form depends on the rows: the one-hot
+    product costs ``live rows x rows x hidden`` operations, all but free
+    at a step's rows and a short bucket's and the larger part of the
+    block at 8192; a fetch by address costs the live rows' alone and one
+    pass over the bucket to re-lay it. A width whose row is no whole
+    number of the chip's tiles (``rows_fetchable``: 7168 and 2560, whose
+    cells' buckets end at 512 rows anyway) keeps the one-hot product."""
+    if rows >= GATHER_FETCH_MIN_ROWS and pk.rows_fetchable(hidden, dtype):
+        return "fetch"
+    return "onehot"
 
 
 def hit_share(rows: int, top_k: int, scored: int) -> float:
@@ -400,6 +447,12 @@ def form_traced(rows: int) -> str | None:
     """The form the expert block took when a call of ``rows`` rows was
     last traced in this process (None: no such call was)."""
     return _traced.get(rows)
+
+
+def fetch_traced(rows: int) -> bool:
+    """Did a sorted call of ``rows`` rows traced in this process gather
+    its rows by address (:func:`gather_form`)?"""
+    return rows in _fetched
 
 
 def moe_swiglu(

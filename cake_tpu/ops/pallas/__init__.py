@@ -78,6 +78,7 @@ from cake_tpu.ops.pallas.moe import (  # noqa: E402
     group_tiles,
     grouped_matmul,
     grouped_swiglu,
+    rows_fetchable,
 )
 from cake_tpu.ops.pallas.quant import (  # noqa: E402
     quant4_matmul_pallas,
@@ -113,6 +114,7 @@ __all__ = [
     "group_tiles",
     "grouped_matmul",
     "grouped_swiglu",
+    "rows_fetchable",
     "quant_matmul_pallas",
     "quant4_matmul_pallas",
 ]

@@ -16,7 +16,8 @@ row tile past the LIVE ones (the grouped rows are the leading ones, so
 the live tiles are ``0 .. GroupTiles.live - 1``: on a chip that holds one
 share in sixteen of the scored experts, a sixteenth of the pairs):
 
-- :func:`gather_rows`: the live tiles' rows out of ``[N, H]``;
+- :func:`gather_rows`: the live tiles' rows out of ``[N, H]``, by a
+  one-hot product where ``N`` is small and by address where it is not;
 - :func:`grouped_swiglu`: gate, up and the SwiGLU in one call (one fetch
   of a row tile serves both products; nothing is rounded between the
   float32 products and the SwiGLU; no ``[N*k, F]`` float32 leaves it);
@@ -67,6 +68,10 @@ VMEM_LIMIT = 96 * 2**20
 # a block of a 32-bit vector held in scalar memory (the chip lays such an
 # operand out in tiles of 1024)
 SMEM_BLOCK = 1024
+# copies of a fetched row started (and waited for) a loop step
+FETCH_UNROLL = 8
+# rows of the bucket a step of the pass that re-lays it as words
+FETCH_PACK_ROWS = 256
 
 
 class GroupTiles(NamedTuple):
@@ -327,17 +332,164 @@ def _gather_kernel(live_ref, token_ref, x_ref, out_ref):
     ).astype(out_ref.dtype)
 
 
-def gather_rows(x, token, tiles: GroupTiles, *, tm: int = ROW_TILE,
-                interpret: bool | None = None):
+def rows_fetchable(h: int, dtype) -> bool:
+    """Can a row of ``[N, h]`` be fetched by address (:func:`gather_rows`'s
+    ``fetch`` form)? Where it is a whole number of the chip's ``(8, 128)``
+    tiles of 32-bit words (a copy out of a tiled array takes aligned
+    slices alone): ``h`` a multiple of 2048 in bfloat16 (2048, 6144; not
+    2560's 10 rows of words nor 7168's 28) and of 1024 in float32."""
+    itemsize = jnp.dtype(dtype).itemsize
+    return itemsize in (2, 4) and not h * itemsize % (8 * 128 * 4)
+
+
+def _row_span(x) -> int:
+    """Rows of 128 32-bit words a row of ``x [N, H]`` makes."""
+    return x.shape[1] * x.dtype.itemsize // (128 * 4)
+
+
+def _words_kernel(x_ref, out_ref, *, span: int):
+    rows, h = x_ref.shape
+    for s in range(span):
+        at = pl.ds(s * 128, 128)
+        if x_ref.dtype.itemsize == 4:
+            words = jax.lax.bitcast_convert_type(x_ref[:, at], jnp.uint32)
+        else:
+            low = jax.lax.bitcast_convert_type(x_ref[:, at], jnp.uint16)
+            high = jax.lax.bitcast_convert_type(
+                x_ref[:, pl.ds(h // 2 + s * 128, 128)], jnp.uint16)
+            words = (low.astype(jnp.uint32)
+                     | (high.astype(jnp.uint32) << 16))
+        out_ref[pl.ds(s, rows, stride=span), :] = words
+
+
+def _row_words(x, interpret: bool):
+    """``x [N, H]`` as 32-bit words, a token's row ``span = H * itemsize /
+    512`` consecutive rows of 128 (whole tiles: what a copy by address
+    takes): the bits themselves in float32; in bfloat16 element ``j`` in a
+    word's low half and ``j + H/2`` in its high half, so that 128
+    consecutive elements of a row lie in each half of a row of words. One
+    pass over ``x`` at the memory's rate (XLA's own convert, shift and
+    reshape took five times as long: PERF.md section 6, PR 63)."""
+    n, h = x.shape
+    span = _row_span(x)
+    rows = min(n, FETCH_PACK_ROWS)
+    return pl.pallas_call(
+        functools.partial(_words_kernel, span=span),
+        out_shape=jax.ShapeDtypeStruct((n * span, 128), jnp.uint32),
+        grid=(pl.cdiv(n, rows),),
+        in_specs=[pl.BlockSpec((rows, h), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((rows * span, 128), lambda i: (i, 0)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=0, bytes_accessed=2 * n * h * x.dtype.itemsize,
+            transcendentals=0),
+        name="moe_row_words",
+        interpret=interpret,
+    )(x)
+
+
+def _fetch_kernel(live_ref, token_ref, words_hbm, out_ref, buf, sem, *,
+                  tm: int, span: int):
+    """Grid step ``t`` writes live tile ``t``: its ``tm`` rows, ``span``
+    rows of words each, come by one copy a row out of ``words_hbm`` (left
+    where it is) into half ``t % 2`` of ``buf``; the next tile's copies are
+    started before this tile's are waited for."""
+    t = pl.program_id(0)
+    slot = t % 2
+
+    def copies(tile, slot, act):
+        def some(i, carry):
+            for j in range(FETCH_UNROLL):
+                r = i * FETCH_UNROLL + j
+                at = pl.multiple_of(token_ref[tile * tm + r] * span, 8)
+                act(pltpu.make_async_copy(
+                    words_hbm.at[pl.ds(at, span)],
+                    buf.at[slot, pl.ds(pl.multiple_of(r * span, 8), span)],
+                    sem.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, tm // FETCH_UNROLL, some, 0)
+
+    @pl.when(t == 0)
+    def _first():
+        copies(0, 0, lambda copy: copy.start())
+
+    @pl.when(t + 1 < live_ref[0])
+    def _ahead():
+        copies(t + 1, 1 - slot, lambda copy: copy.start())
+
+    copies(t, slot, lambda copy: copy.wait())
+    half = out_ref.shape[1] // 2
+    for s in range(span):
+        # row ``s`` of every token's span: 128 words a token
+        words = buf[slot, pl.ds(s, tm, stride=span), :]
+        cols = pl.ds(s * 128, 128)
+        if out_ref.dtype.itemsize == 4:
+            out_ref[:, cols] = jax.lax.bitcast_convert_type(
+                words, out_ref.dtype)
+        else:  # integer halves, so exact whatever the bits are
+            out_ref[:, cols] = jax.lax.bitcast_convert_type(
+                (words & 0xFFFF).astype(jnp.uint16), out_ref.dtype)
+            out_ref[:, pl.ds(half + s * 128, 128)] = (
+                jax.lax.bitcast_convert_type(
+                    (words >> 16).astype(jnp.uint16), out_ref.dtype))
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def _fetch_rows(x, token, tiles: GroupTiles, tm: int, interpret: bool):
+    """:func:`gather_rows`'s fetch form; jitted, so that a program whose
+    layer segments each call it traces the two kernels once a shape."""
+    n, h = x.shape
+    (m,) = token.shape
+    assert tm % FETCH_UNROLL == 0, tm
+    span = _row_span(x)
+    moved = m * h * x.dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(_fetch_kernel, tm=tm, span=span),
+        out_shape=jax.ShapeDtypeStruct((m, h), x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(tiles.live[0],),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tm, h), lambda t, live, token: (t, 0)),
+            scratch_shapes=[pltpu.VMEM((2, tm * span, 128), jnp.uint32),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=0, bytes_accessed=2 * moved, transcendentals=0),
+        name="moe_fetch_rows",
+        interpret=interpret,
+    )(tiles.live, token, _row_words(x, interpret))
+
+
+def gather_rows(x, token, tiles: GroupTiles, *, fetch: bool = False,
+                tm: int = ROW_TILE, interpret: bool | None = None):
     """``out[r] = x[token[r]]`` for the rows of the LIVE row tiles alone
-    (``tiles.live``, a dynamic grid bound): a block of ``x [N, H]``'s
-    columns stays in VMEM while the live tiles pass, and a tile's rows are
-    picked by a one-hot product (exact). ``token [M]`` int32, every entry
-    a row of ``x``. Returns ``[M, H]``; the tiles past the live ones are
-    NOT written."""
+    (``tiles.live``, a dynamic grid bound). ``token [M]`` int32, every
+    entry a row of ``x [N, H]``. Returns ``[M, H]``; the tiles past the
+    live ones are NOT written. Two forms of the one gather, both exact,
+    the caller's to choose by ``N`` (:func:`cake_tpu.ops.moe.gather_form`):
+
+    - the one-hot product: a block of ``x``'s columns stays in VMEM while
+      the live tiles pass, and a tile's rows are picked by a ``[tm, N]``
+      one-hot on the MXU: ``2 x live rows x N x H`` operations, nothing
+      at a step's or a short bucket's ``N``, 4 ms a layer at 8192 rows;
+    - ``fetch``: a row comes by its address, one copy a row out of ``x``
+      re-laid as 32-bit words a whole tile a row (:func:`_row_words`, one
+      pass over ``x``), a tile's copies in flight behind the tile being
+      written: the live rows' bytes and no more. A width whose row is no
+      whole number of tiles (:func:`rows_fetchable`) takes the one-hot
+      product all the same."""
     n, h = x.shape
     (m,) = token.shape
     assert m % tm == 0, (m, tm)
+    if fetch and rows_fetchable(h, x.dtype):
+        return _fetch_rows(x, token, tiles, tm, _interpret(interpret))
     hb = _block_n(n, h, x.dtype.itemsize)
     return pl.pallas_call(
         _gather_kernel,

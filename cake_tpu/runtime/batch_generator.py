@@ -112,6 +112,7 @@ from cake_tpu.obs.trace import span
 from cake_tpu.ops import pallas as pk
 from cake_tpu.ops import dsa, quant, sampling
 from cake_tpu.ops.kda import CHUNK
+from cake_tpu.ops.moe import fetch_traced as moe_fetch_traced
 from cake_tpu.ops.moe import form_traced as moe_form_traced
 from cake_tpu.ops.sampling import SamplerSettings
 from cake_tpu.parallel.mesh import (
@@ -231,6 +232,7 @@ _MOE_ADMIT_ROWS = obs_metrics.counter("moe.admit_rows")
 _MOE_ADMIT_SORTED = obs_metrics.counter("moe.admit_rows_sorted")
 _MOE_SORTED_ROWS = obs_metrics.counter("moe.sorted_pair_rows")
 _MOE_SORTED_LIVE = obs_metrics.counter("moe.sorted_pair_rows_live")
+_MOE_GATHER_FETCHED = obs_metrics.counter("moe.gather_rows_fetched")
 # by the mixer whose layers hold the state or the tail
 # (LlamaConfig.layer_kinds)
 # (both rules of ops/kda.py count as one: a delta-rule state)
@@ -1048,9 +1050,11 @@ class BatchGenerator:
         """An expert model's admission program returns two more values,
         the pair rows its sorted-form calls were handed and touched: kept
         un-fetched until the program has run (``_fetch_moe_counts``)."""
-        def counted(*args):
-            logits, cache, handed, live = prog(*args)
-            self._moe_admitted.append((handed, live))
+        def counted(params, tokens, *args):
+            logits, cache, handed, live = prog(params, tokens, *args)
+            # the bucket says how its live rows were gathered
+            self._moe_admitted.append(
+                (handed, live, moe_fetch_traced(tokens.size)))
             return logits, cache
         return counted
 
@@ -4076,11 +4080,15 @@ class BatchGenerator:
             * sum(ffn == "moe" for _, ffn in self.config.layer_kinds))
         _MOE_STEPS.inc(steps)
 
-    def _record_sorted_rows(self, handed, live) -> None:
+    def _record_sorted_rows(self, handed, live, fetched=False) -> None:
         """One dispatch's pair rows ``handed`` to sorted-form calls and
-        those in a row tile the calls touched, fetched, into ``moe.*``."""
+        those in a row tile the calls touched, fetched, into ``moe.*``
+        (``fetched``: its calls gathered those rows by address)."""
         _MOE_SORTED_ROWS.inc(int(self._host(handed)))
-        _MOE_SORTED_LIVE.inc(int(self._host(live)))
+        live = int(self._host(live))
+        _MOE_SORTED_LIVE.inc(live)
+        if fetched:
+            _MOE_GATHER_FETCHED.inc(live)
 
     def _step_decode(self):
         """No recorded row is left to hand out. Spec rounds, if any; else

@@ -1,16 +1,23 @@
-"""The expert block a layer: dense against sorted, from 8 rows to 2048.
+"""The expert block a layer: dense against sorted, from 8 rows to 16,384.
 
 Times :func:`cake_tpu.ops.moe.moe_swiglu` in the two forms
 :func:`cake_tpu.ops.moe.expert_form` chooses between for more than a
 handful of pairs, as the layer loop calls it (a scan over ``L`` layers:
 the dense form on the scan's slice of the stacks, the sorted form on the
 whole stacks with the layer's index), at the eight expert cells' shapes
-for 8 to 2048 rows: a decode step's rows (one a slot) and an admission's
-buckets. Where the sorted form is at least 1.10x the dense one is where
-the rule's constants come from: ``SORTED_MAX_HIT_SHARE*`` (the share of
-the experts a call of few rows may hit and still be sorted) and
+for 8 to 2048 rows (``--rows 4096,8192,16384`` for the long buckets of
+the cells that have them): a decode step's rows (one a slot) and an
+admission's buckets. Where the sorted form is at least 1.10x the dense
+one is where the rule's constants come from: ``SORTED_MAX_HIT_SHARE*``
+(the share of the experts a call of few rows may hit and still be
+sorted) and
 ``SORTED_MIN_ROWS*`` (PERF.md keeps the table). ``--row-tile`` times the
-sorted form at other row tiles of the kernel than the program's.
+sorted form at other row tiles of the kernel than the program's. The
+sorted form's gather has forms of its own, side by side under ``--forms
+onehot,fetch,take``: the live tiles' rows picked by a one-hot product, or
+fetched by address (``ops.pallas.gather_rows``; ``sorted`` is whichever
+``ops.moe.gather_form`` takes at the rows: where ``GATHER_FETCH_MIN_ROWS``
+comes from), and XLA's ``take`` over ALL the pair rows as the control.
 ``--valid-share`` tells the block that this share of the rows, the
 leading ones, are true tokens and the rest a bucket's padding
 (``moe_swiglu``'s ``valid``; 1.0: a bucket that is full, told so), one
@@ -18,7 +25,8 @@ pass a share: what the sorted form saves of a bucket's padding, and what
 being told costs a bucket that has none.
 
 Usage:  python -m cake_tpu.tools.moe_sweep [--only NAME] [--rows 64,512]
-            [--forms dense,sorted,compact] [--row-tile 32,128]
+            [--forms dense,sorted,compact,onehot,fetch,take]
+            [--row-tile 32,128]
             [--valid-share 1.0,0.67] [--json-out PATH]
 (``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
@@ -62,6 +70,7 @@ SHAPES = {
     # softmax over the chosen logits (Mixtral's convention)
     "mellum2-12b": (64, 64, 8, 2304, 896, False, None),
     "qwen3next-ep4": (128, 512, 10, 2048, 512, False, None),
+    "glm5-ep16": (16, 256, 8, 6144, 2048, False, (1, 1)),
 }
 ROWS = (8, 16, 32, 64, 128, 256, 512, 1024, 2048)
 LAYERS = 3
@@ -94,17 +103,27 @@ def _weights(key, layers, held, scored, hidden, width, int8):
 def _steered(form: str, row_tile: int):
     """While a form is traced: the expert block takes it whatever the
     rows, at this row tile (``compact``: the sorted form with the live
-    tiles' gather and sum kernels whatever share of the experts is held).
-    Steering in the tool: the program has no such knob."""
-    real = moe.expert_form, moe.compacts, pk.MOE_ROW_TILE
+    tiles' gather and sum kernels whatever share of the experts is held;
+    ``onehot`` / ``fetch``: the sorted form with that gather whatever the
+    rows; ``take``: with XLA's ``take`` over all the pair rows in the
+    gather kernel's place). Steering in the tool: the program has no such
+    knob."""
+    real = (moe.expert_form, moe.compacts, moe.gather_form, pk.gather_rows,
+            pk.MOE_ROW_TILE)
     moe.expert_form = lambda *a: "dense" if form == "dense" else "sorted"
     if form == "compact":
         moe.compacts = lambda *a: True
+    if form in ("onehot", "fetch"):
+        moe.gather_form = lambda *a: form
+    if form == "take":
+        pk.gather_rows = lambda x, token, tiles, **kw: jnp.take(
+            x, token, axis=0)
     pk.MOE_ROW_TILE = row_tile
     try:
         yield
     finally:
-        moe.expert_form, moe.compacts, pk.MOE_ROW_TILE = real
+        (moe.expert_form, moe.compacts, moe.gather_form, pk.gather_rows,
+         pk.MOE_ROW_TILE) = real
 
 
 def _layers_fn(form, name):
@@ -166,8 +185,12 @@ def moved_bytes(form: str, name: str, rows: int,
     written once. ``dense``: every held expert's gate, up, SwiGLU and down
     results over every row, and their weighted sum. ``sorted``: the LIVE
     row tiles alone (the pairs on held experts, rounded up to whole
-    tiles): rows gathered from ``[rows, H]``, the SwiGLU's result in the
-    rows' type (the row tile re-read a block of output columns), the down
+    tiles): rows gathered from ``[rows, H]`` (``onehot``: the bucket read
+    and the live rows written; ``fetch``: the bucket read and written as
+    words, the live rows read and written; ``take``: every pair row read
+    and written; ``sorted``: as :func:`cake_tpu.ops.moe.gather_form` has
+    it at these rows), the SwiGLU's result in the rows' type (the row
+    tile re-read a block of output columns), the down
     product in float32, and the sum into ``[rows, H]`` (where XLA sums,
     :func:`cake_tpu.ops.moe.compacts`, over a gathered copy of the
     product)."""
@@ -183,8 +206,17 @@ def moved_bytes(form: str, name: str, rows: int,
     gate_blocks = width // pk.moe._block_n(hidden, width, itemsize)
     down_blocks = hidden // pk.moe._block_n(width, hidden, itemsize)
     kernels = form == "compact" or moe.compacts(held, scored)
+    gather = (form if form in ("onehot", "fetch", "take") else
+              moe.gather_form(rows, hidden, jnp.bfloat16))
+    if gather == "fetch" and not pk.rows_fetchable(hidden, jnp.bfloat16):
+        gather = "onehot"  # what gather_rows falls to
+    if not kernels:  # XLA's take of tiles that are all live moves as much
+        gather = "onehot"
+    gathered = {"onehot": rows * hidden * act + live * hidden * act,
+                "fetch": 2 * rows * hidden * act + 2 * live * hidden * act,
+                "take": 2 * rows * top_k * hidden * act}[gather]
     return int(
-        rows * hidden * act + live * hidden * act  # gathered
+        gathered
         + gate_blocks * live * hidden * act + live * width * act  # SwiGLU
         + down_blocks * live * width * act + live * hidden * 4  # down
         + (0 if kernels else 2 * live * hidden * 4)  # XLA's gathered copy

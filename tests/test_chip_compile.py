@@ -159,12 +159,19 @@ def _gswiglu(pairs, k, n, experts, layers, int8=False):
 
 def _live_rows(rows, pairs, h, experts):
     """The live tiles' gather and sum as a sorted call of ``rows`` rows
-    makes them: ``[rows, h]`` in, ``pairs`` sorted rows between."""
+    makes them: ``[rows, h]`` in, ``pairs`` sorted rows between; the
+    gather in the form the call takes at those rows (by address from
+    ``ops.moe.GATHER_FETCH_MIN_ROWS`` on: the pass that re-lays the
+    bucket as words and the kernel that copies a row an address)."""
+    from cake_tpu.ops.moe import gather_form
     from cake_tpu.ops.pallas.moe import GroupTiles
+
+    fetch = gather_form(rows, h, BF16) == "fetch"
 
     def fn(x, y, token, weight, *tiles):
         tiles = GroupTiles(*tiles)
-        return (pk.gather_rows(x, token, tiles, interpret=False),
+        return (pk.gather_rows(x, token, tiles, fetch=fetch,
+                               interpret=False),
                 pk.combine_rows(y, token, weight, tiles, rows,
                                 out_dtype=BF16, interpret=False))
 
@@ -268,6 +275,10 @@ KERNELS = {
     "live_rows_axk1_step": _live_rows(32, 256, 7168, 12),
     "live_rows_ling": _live_rows(512, 4096, 2560, 128),
     "live_rows_kexaone_t2048": _live_rows(2048, 16384, 6144, 16),
+    # ... fetched by address: Qwen3-Next's 8192-row bucket (top-10, 128
+    # held of 512) and GLM-5's 16,384-row one (top-8, 16 of 256)
+    "live_rows_qwen3next_t8192": _live_rows(8192, 81920, 2048, 128),
+    "live_rows_glm5_t16384": _live_rows(16384, 131072, 6144, 16),
     "qmm_m64_4096x14336": _qmm(64, HID, FFN),
     "qmm_m64_14336x4096": _qmm(64, FFN, HID),
     "qmm_m64_4096x32000": _qmm(64, HID, VOCAB),
@@ -284,7 +295,13 @@ def test_kernel_compiles_for_v5e(topo, name):
             jax.ShapeDtypeStruct(*spec, sharding=one_chip) for spec in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     # the kernel is in the program (not silently an XLA fallback)
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # ... and a long bucket's gather is the fetch, a short one's the product
+    if name.startswith("live_rows"):
+        long = name in ("live_rows_qwen3next_t8192", "live_rows_glm5_t16384")
+        assert ("moe_fetch_rows" in text) == long
+        assert ("moe_gather_rows" in text) != long
 
 
 # sha256[:16] of the lowered text of each family's serving programs at tiny

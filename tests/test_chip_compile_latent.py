@@ -334,6 +334,13 @@ def test_sparse_latent_programs_read_the_chosen_rows_and_write_no_scores(
              for t in ("f32", "bf16") for one in ("", "1,")}
     assert [s for _, _, s, _, _ in _instructions(decode)
             if s in gathered | swept] == []
+    # the 16,384-row bucket's expert blocks fetch their live rows by
+    # address (PR 63); a step's 16 rows are picked by the one-hot product
+    for name, (step, bucketed) in {"moe_fetch_rows": (0, 1),
+                                   "moe_row_words": (0, 1),
+                                   "moe_gather_rows": (1, 0)}.items():
+        assert len(_kernel_calls(decode, name)) == step, name
+        assert len(_kernel_calls(admit, name)) == bucketed, name
     assert len(_kernel_calls(admit, "dsa_prefill_select")) == 2
     assert len(_kernel_calls(admit, "dsa_prefill_attend")) == 2
     whole = {f"f32[{lead}{heads}{bucket},{bucket}]"

@@ -143,6 +143,16 @@ def test_gated_delta_programs_fit_and_repeat_no_period(topo, as_on_chip):
     assert temps < 0.02 * GIB, temps / GIB
     for compiled in (admit, widest):
         assert "triangular" not in compiled.as_text().lower()
+    # the 8192-row bucket's expert blocks fetch their live rows by address
+    # (PR 63: the pass that re-lays the bucket as words, then a copy a
+    # row); a step's 32 rows are picked by the one-hot product, and the
+    # 128-row bucket's block is dense
+    assert "moe_fetch_rows" in widest.as_text()
+    assert "moe_row_words" in widest.as_text()
+    assert "moe_gather_rows" not in widest.as_text()
+    for compiled in (decode, admit):
+        assert "moe_fetch_rows" not in compiled.as_text()
+    assert "moe_gather_rows" in decode.as_text()
     small, large = (a.memory_analysis().temp_size_in_bytes
                     for a in (admit, widest))
     assert small < 0.3 * GIB, small / GIB

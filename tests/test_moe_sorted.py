@@ -365,3 +365,82 @@ def test_a_buckets_padding_is_nothing_of_the_sorted_forms(
     full, full_live = run(x, [t] * b)
     np.testing.assert_array_equal(full, whole)
     assert full_live == whole_live
+
+
+# the compacting cells' widths (``tools/moe_sweep.py`` ``SHAPES``): hidden
+GATHER_WIDTHS = {"axk1-ep16": 7168, "ling3flash-ep4": 2560,
+                 "kexaone-ep8": 6144, "qwen3next-ep4": 2048,
+                 "glm5-ep16": 6144}
+
+
+@pytest.mark.parametrize("name,rows,form", [
+    # a decode step's rows and the 64- to 2048-row buckets: the one-hot
+    # product, at every compacting cell's width
+    *[(name, rows, "onehot") for name in GATHER_WIDTHS
+      for rows in (16, 32, 64, 512, 2048)],
+    # the rule's two sides where a row is whole tiles of words
+    ("qwen3next-ep4", moe.GATHER_FETCH_MIN_ROWS - 64, "onehot"),
+    ("qwen3next-ep4", moe.GATHER_FETCH_MIN_ROWS, "fetch"),
+    ("qwen3next-ep4", 8192, "fetch"), ("glm5-ep16", 8192, "fetch"),
+    ("glm5-ep16", 16384, "fetch"), ("kexaone-ep8", 8192, "fetch"),
+    # 28 and 10 rows of words a token: no whole number of tiles
+    ("axk1-ep16", 8192, "onehot"), ("ling3flash-ep4", 16384, "onehot"),
+])
+def test_gather_form_by_the_rows_and_the_width(name, rows, form):
+    """ONE function of what a trace sees picks how a compacting sorted
+    call gathers its live rows: by a one-hot product under
+    ``GATHER_FETCH_MIN_ROWS`` rows (every step, and every bucket of
+    ``axk1-ep16-cut``, Ling and ``kexaone-ep8-cut``), by address from
+    there on (``qwen3next-ep4-cut``'s 4096- and 8192-row buckets,
+    ``glm5-ep16-cut``'s to 16,384) where a row is a whole number of the
+    chip's tiles, and no model's name enters it."""
+    from cake_tpu.tools.moe_sweep import SHAPES
+
+    hidden = GATHER_WIDTHS[name]
+    assert SHAPES[name][3] == hidden and SHAPES[name][0] < SHAPES[name][1]
+    assert moe.gather_form(rows, hidden, jnp.bfloat16) == form
+    assert 2048 < moe.GATHER_FETCH_MIN_ROWS <= 8192
+
+
+@pytest.mark.parametrize("fill", [1.0, 0.67])
+def test_sorted_form_by_fetch_is_the_one_hot_forms_bit_for_bit(
+        fill, kernels, monkeypatch):
+    """The expert block over rows gathered by address is the block over
+    rows picked by the one-hot product, bit for bit: a gather is exact
+    either way, and nothing else of the call differs. A bucket told that
+    0.67 of its rows are true included, the live rows counted alike; and
+    where its padding rows are NaN the fetch never reads them (the
+    one-hot product multiplies them by zero, into NaN); the trace records
+    which bucket fetched (``fetch_traced``, ``moe.gather_fetch_min_rows``)."""
+    from cake_tpu.obs import metrics as obs_metrics
+
+    held, scored, top_k, first, rows, h, f = 4, 16, 4, 4, 512, 2048, 128
+    ks = jax.random.split(jax.random.PRNGKey(13), 5)
+    x = jax.random.normal(ks[0], (1, rows, h)).astype(jnp.bfloat16)
+    true = round(rows * fill)
+    rw = jax.random.normal(ks[1], (h, scored)).astype(jnp.bfloat16) / 16
+    stacks = [(jax.random.normal(k, shp) / d).astype(jnp.bfloat16)
+              for k, shp, d in ((ks[2], (held, h, f), 32),
+                                (ks[3], (held, h, f), 32),
+                                (ks[4], (held, f, h), 8))]
+
+    def run(x=x):
+        out, count = moe_swiglu(
+            x, rw, *stacks, top_k=top_k, held=(first, held),
+            count_local=True, valid=jnp.asarray([true], jnp.int32))
+        assert moe.form_traced(rows) == "sorted"
+        return np.asarray(out, np.float32), int(count.live_rows)
+
+    monkeypatch.setattr(moe, "_fetched", set())
+    gauge = obs_metrics.gauge("moe.gather_fetch_min_rows")
+    gauge.set(0)
+    picked, picked_live = run()
+    assert not moe.fetch_traced(rows) and not gauge.value
+    monkeypatch.setattr(moe, "GATHER_FETCH_MIN_ROWS", rows)
+    fetched, fetched_live = run()
+    assert moe.fetch_traced(rows) and gauge.value == rows
+    np.testing.assert_array_equal(fetched, picked)
+    assert fetched_live == picked_live > 0
+    assert (fetched[0, true:] == 0).all() and np.abs(fetched).max() > 0
+    poisoned, _ = run(x.at[:, true:].set(jnp.nan))
+    np.testing.assert_array_equal(poisoned, fetched)

@@ -153,6 +153,49 @@ def test_engine_counts_the_pair_rows_the_sorted_form_touches(monkeypatch):
     assert 0 <= live.value - before[1] <= steps * layers * 128
 
 
+@pytest.mark.parametrize("hidden", [1024, 64])
+def test_engine_counts_the_live_rows_an_admission_fetched(hidden,
+                                                          monkeypatch):
+    """``moe.gather_rows_fetched``: the live rows of the admissions whose
+    bucket gathered them by address, known from the bucket alone (the
+    trace recorded its form; no new device output). With the rule's row
+    count brought down to the tiny model's 512-row bucket: a model whose
+    float32 row of 1024 is a whole tile of words counts every live row of
+    its admission and nothing of its decode steps (a step's rows are
+    picked by the one-hot product); one whose row of 64 is no tile counts
+    nothing at all."""
+    from cake_tpu.models.config import tiny_mla_moe
+    from cake_tpu.obs import metrics
+    from cake_tpu.runtime.batch_generator import BatchGenerator
+
+    monkeypatch.setenv("CAKE_PALLAS", "1")
+    monkeypatch.setattr(moe, "GATHER_FETCH_MIN_ROWS", 512)
+    monkeypatch.setattr(moe, "_fetched", set())
+    cfg = tiny_mla_moe(max_seq_len=512, eos_token_id=-1, n_routed_experts=4,
+                       router_experts=16, first_expert=4, hidden_size=hidden)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    reg = metrics.registry()
+    live, fetched = (reg.counter(f"moe.{name}") for name in (
+        "sorted_pair_rows_live", "gather_rows_fetched"))
+    bg = BatchGenerator(cfg, params, settings=SamplerSettings(**GREEDY))
+    bg.set_prompts([[3, 5, 7], [2, 4]], stream_ids=[0, 1])
+    bg.drain()
+    before = live.value, fetched.value
+    assert bg.finish(1)
+    bg.admit([t % 250 + 1 for t in range(1, 301)], stream_id=3)
+    bg.drain()
+    assert moe.form_traced(512) == "sorted"
+    assert moe.fetch_traced(512) == (hidden == 1024)
+    touched = live.value - before[0]
+    assert touched >= 128
+    assert fetched.value - before[1] == (touched if hidden == 1024 else 0)
+    before = live.value, fetched.value
+    for _ in range(4):
+        bg.step()
+    bg.drain()
+    assert not moe.fetch_traced(2) and fetched.value == before[1]
+
+
 @pytest.mark.parametrize("tokens", [300, 513])
 @pytest.mark.parametrize("name", ["all-held", "share"])
 def test_an_admission_told_its_length_starts_as_it_did(name, tokens,

@@ -1005,3 +1005,55 @@ def test_live_tiles_are_gathered_and_summed_alone(groups, dtype):
     named = np.zeros(n, bool)
     named[np.asarray(token)[:total]] = True
     assert (np.asarray(got)[~named] == 0).all()
+
+
+# dtype, width -> is a row a whole number of the chip's tiles of words?
+FETCH_WIDTHS = {"bf16": (jnp.bfloat16, 2048, True),
+                "f32": (jnp.float32, 1024, True),
+                "bf16_2560": (jnp.bfloat16, 2560, False),  # 10 rows of words
+                "f32_256": (jnp.float32, 256, False)}
+
+
+@pytest.mark.parametrize("width", list(FETCH_WIDTHS))
+@pytest.mark.parametrize("groups", list(MOE_GROUPS))
+def test_live_tiles_are_fetched_by_address(groups, width):
+    """The fetch form of the gather is ``x[token]`` on every live tile, BIT
+    for bit whatever the bits are (a denormal, an infinity and a NaN lie
+    in a fetched row: the one-hot product would smear them over the
+    tile), in bfloat16 (two halves of a row packed a word) and float32:
+    with no pair held at all (``live`` 0: nothing written), with every
+    pair held, with a group across a tile's edge and the grouped rows
+    ending on one. Nothing past the live tiles is read: their tokens
+    name no row of ``x`` at all. A width whose row is no whole number of
+    tiles takes the one-hot product all the same."""
+    from cake_tpu.ops.pallas import gather_rows, rows_fetchable
+    from cake_tpu.ops.pallas import moe as pm
+
+    dtype, h, fetchable = FETCH_WIDTHS[width]
+    assert rows_fetchable(h, dtype) == fetchable
+    tiles, _ = _moe_tiles(MOE_GROUPS[groups])
+    live, n = int(tiles.live[0]) * MOE_TM, 11
+    bits_t = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+    x = jax.random.normal(jax.random.PRNGKey(5), (n, h)).astype(dtype)
+    if fetchable:
+        odd = {np.uint16: [0x0001, 0x8001, 0x7F80, 0xFF80, 0x7FC0, 0x8000],
+               np.uint32: [1, 0x80000001, 0x7F800000, 0xFF800000,
+                           0x7FC00000, 0x80000000]}[bits_t]
+        raw = np.asarray(x).view(bits_t).copy()
+        raw[0, : len(odd)] = raw[0, h - len(odd):] = odd
+        x = jnp.asarray(raw.view(np.asarray(x).dtype))
+    token = jax.random.randint(jax.random.PRNGKey(6), (32,), 0, n)
+    token = token.at[0].set(0).at[live:].set(2 ** 30)  # never fetched
+    calls = []
+    real = pm._fetch_rows
+    pm._fetch_rows = lambda *a: calls.append(1) or real(*a)
+    try:
+        picked = gather_rows(x, token, tiles, fetch=True, tm=MOE_TM,
+                             interpret=True)
+    finally:
+        pm._fetch_rows = real
+    assert len(calls) == fetchable
+    assert picked.shape == (32, h) and picked.dtype == dtype
+    np.testing.assert_array_equal(
+        np.asarray(picked)[:live].view(bits_t),
+        np.asarray(x)[np.asarray(token)[:live]].view(bits_t))
