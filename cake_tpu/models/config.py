@@ -9,9 +9,10 @@ is a tunable because the TPU build supports long context).
 
 Families read: the dense and Mixtral-style decoders (one bare stack),
 six whose layers are of several kinds (``segmented``: a stack a stretch of
-one kind, ``models/llama.py`` ``layer_plan``), and one whose layers run
+one kind, ``models/llama.py`` ``layer_plan``), one whose layers run
 several times a token (``total_ut_steps``: one stack, a cache plane a layer
-and a pass). A family's config.json and
+and a pass), and one whose every layer is a double layer (two latent
+attentions, two cache planes: ``zero_expert_num`` and the fields beside it). A family's config.json and
 checkpoint, its checks and what is wired for it are its record in
 ``models/families.py`` (``LlamaConfig.family``); fields and presets here.
 """
@@ -277,6 +278,30 @@ class LlamaConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # --- a shortcut-connected double layer with zero-compute experts
+    # (LongCat-Flash's keys; HF `model_type` "longcat_flash", beside the
+    # latent attention's) ------------------------------------------------------
+    # One published layer is TWO latent attentions and TWO dense SwiGLUs of
+    # ``intermediate_size`` around ONE expert block that reads the first
+    # sub-layer's normed input and whose result is added a sub-layer late
+    # (models/llama.py ``_double_block``): ``a0 = x + MLA_0(RMS(x)); h =
+    # RMS(a0); s = MoE(h); b0 = a0 + FFN_0(h); a1 = b0 + MLA_1(RMS(b0)); out
+    # = a1 + FFN_1(RMS(a1)) + s``. A layer keeps TWO cache planes (layer
+    # ``l``'s attention ``j`` is plane ``2 l + j``, ``cache_plan``). The
+    # router scores ``router_experts + zero_expert_num`` outputs by softmax
+    # over all of them, chooses on ``p + bias``, and weighs the chosen by
+    # ``routed_scaling_factor p`` with no renormalisation; the last
+    # ``zero_expert_num`` outputs are no experts: one that is chosen returns
+    # its input (``zero_expert_type`` "identity", the only kind computed).
+    # ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: the query (behind its
+    # up-projection) times ``(hidden / q_lora_rank)^0.5`` and the normed key
+    # latent times ``(hidden / kv_lora_rank)^0.5``; both are constants on a
+    # norm's output ahead of a linear and fold into that norm's weight
+    # where the tensors are read (``families.Fold``).
+    zero_expert_num: int = 0
+    zero_expert_type: str = "identity"
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -288,6 +313,7 @@ class LlamaConfig:
         families.check_residual_path(self)
         families.check_gated_keys(self)
         families.check_indexer(self)
+        families.check_zero_experts(self)
         # validate at construction, not as a KeyError deep in a jit trace
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
@@ -373,7 +399,9 @@ class LlamaConfig:
         where ``layer_types`` says so), "mla", "kda", "gdn" (the
         scalar-gated delta rule, where ``layer_types`` says so), "mamba" or
         "conv" (a gated short convolution, where ``layer_types`` says so),
-        the feed-forward "dense" or "moe".
+        or "mla2" (a shortcut-connected double layer: two latent attentions,
+        two dense feed-forwards and the expert block between them, where the
+        family's layers are such), the feed-forward "dense" or "moe".
         THE place the layer order comes from (models/llama.py
         ``layer_plan`` groups it into scanned segments, the cache and the
         loaders count it)."""
@@ -391,11 +419,21 @@ class LlamaConfig:
                         == self.attn_layer_offset else "mamba")
             if not self.kv_lora_rank:
                 return "gqa"
+            if self.family.planes_a_layer == 2:
+                return "mla2"
             g = self.layer_group_size
             return "mla" if not g or (i + 1) % g == 0 else "kda"
 
         return tuple((mixer(i), "moe" if experts and i >= dense else "dense")
                      for i in range(n))
+
+    @property
+    def router_outputs(self) -> int:
+        """Outputs of an expert layer's router: the published experts
+        (``router_experts``, of which ``n_routed_experts`` are held here)
+        and, behind them, the ``zero_expert_num`` zero-compute ones."""
+        return ((self.router_experts or self.n_routed_experts)
+                + self.zero_expert_num)
 
     @property
     def delta_rule(self) -> DeltaRule:
@@ -439,7 +477,9 @@ class LlamaConfig:
         out. Where the layers run ``total_ut_steps`` times a token, ``rows``
         counts a plane a layer AND a pass (layer ``i`` in pass ``u`` is
         plane ``u * num_hidden_layers + i``): the cache's depth is the
-        plan's, not ``num_hidden_layers``. Under a learned sparse attention
+        plan's, not ``num_hidden_layers``. A shortcut-connected double layer
+        keeps TWO planes (its attention ``j`` is plane ``2 l + j``). Under a
+        learned sparse attention
         (``index_topk`` > 0) every latent layer keeps ``index`` ``(layers,
         1, index_head_dim)`` beside its rows: the indexer's one key a
         token, normed and rotated, in the serving type."""
@@ -449,9 +489,10 @@ class LlamaConfig:
         ring = mixers.count("swa")
         plan = {}
         if len(mixers) - held - ring:
-            # a looped model keeps a plane a layer AND a pass
-            plan["rows"] = ((len(mixers) - held - ring) * self.total_ut_steps,
-                            ) + self.cache_row
+            # a looped model keeps a plane a layer AND a pass, a double
+            # layer one for each of its two attentions
+            plan["rows"] = ((len(mixers) - held - ring) * self.total_ut_steps
+                            * self.family.planes_a_layer,) + self.cache_row
         if self.index_topk:
             plan["index"] = (mixers.count("mla"), 1, self.index_head_dim)
         if ring:
@@ -963,6 +1004,55 @@ def glm5_ep16(**overrides) -> LlamaConfig:
     return LlamaConfig(**base)
 
 
+def longcat_flash_ep32(**overrides) -> LlamaConfig:
+    """LongCat-Flash (the language model of https://huggingface.co/
+    meituan-longcat/LongCat-Flash-Omni, `model_type` "longcat_flash") at
+    its published widths, as ONE chip of the 32 that share each layer's
+    512 experts holds it: global experts 0-15 beside the whole router (768
+    outputs: the 512 experts and 256 zero-compute identities, top-12 on
+    softmax share + bias, the chosen shares times 6 and not renormalised),
+    both latent attentions (64 heads of 128 + 64 over a 1536-wide query
+    and a 512-wide key latent, the two `mla_scale_*` factors) and both
+    dense 12288-wide feed-forwards of every double layer. 28 double layers
+    as published (56 cache planes); a chip serves the depth of its
+    pipeline stage (`num_hidden_layers=`) and its slice of the vocabulary
+    (`vocab_size=`)."""
+    base = dict(
+        model_type="longcat_flash",
+        vocab_size=131072,
+        hidden_size=6144,
+        intermediate_size=12288,
+        num_hidden_layers=28,
+        num_attention_heads=64,
+        num_key_value_heads=64,
+        rms_norm_eps=1e-5,
+        rope_theta=10000000.0,
+        max_seq_len=131072,
+        q_lora_rank=1536,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        mla_scale_q_lora=True,
+        mla_scale_kv_lora=True,
+        moe_intermediate_size=2048,
+        n_routed_experts=16,
+        router_experts=512,
+        first_expert=0,
+        zero_expert_num=256,
+        zero_expert_type="identity",
+        num_experts_per_tok=12,
+        scoring_func="softmax",
+        router_bias=True,
+        norm_topk_prob=False,
+        routed_scaling_factor=6.0,
+        bos_token_id=0,
+        eos_token_id=1,
+    )
+    base.update(overrides)
+    return LlamaConfig(**base)
+
+
 def ouro_2_6b(**overrides) -> LlamaConfig:
     """Ouro-2.6B (https://huggingface.co/ByteDance/Ouro-2.6B, `model_type`
     "ouro") at its published sizes: 48 sandwich-normed layers of 16 query
@@ -1301,6 +1391,39 @@ def tiny_glm_dsa(**overrides) -> LlamaConfig:
     )
     base.update(overrides)
     return tiny_mla_moe(**base)
+
+
+def tiny_longcat_flash(**overrides) -> LlamaConfig:
+    """Tiny fixture of the shortcut-connected double layer (LongCat-Flash's
+    keys) that keeps the published ratios: three double layers (six cache
+    planes), latents narrower than the heads they expand to with both
+    `mla_scale_*` factors on (1.63 and 2), a router of 24 outputs, 16
+    experts (all held) and 8 zero-compute identities, a third of them as
+    published, top-4 on softmax share + bias, the chosen shares times 6
+    and not renormalised, no shared expert, plain rope."""
+    base = dict(
+        model_type="longcat_flash",
+        num_hidden_layers=3,
+        num_key_value_heads=4,
+        q_lora_rank=24,
+        kv_lora_rank=16,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        mla_scale_q_lora=True,
+        mla_scale_kv_lora=True,
+        moe_intermediate_size=32,
+        n_routed_experts=16,
+        zero_expert_num=8,
+        num_experts_per_tok=4,
+        scoring_func="softmax",
+        router_bias=True,
+        norm_topk_prob=False,
+        routed_scaling_factor=6.0,
+        rope_theta=10000000.0,
+    )
+    base.update(overrides)
+    return tiny(**base)
 
 
 def tiny_kda_hybrid(**overrides) -> LlamaConfig:

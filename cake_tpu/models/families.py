@@ -22,7 +22,14 @@ gated shared one; norm weights stored as ``w - 1`` and fused projections
 stored a key head's group at a time, both folded where the tensors are
 read: ``Fold``), ``WINDOWED`` (``exaone_moe``, and ``mellum``: the rotation
 a layer KIND is data read from the file, ``LlamaConfig.layer_rope``),
-``STATE_SPACE`` (``jamba``),
+``STATE_SPACE`` (``jamba``), ``SHORTCUT`` (``longcat_flash``: a
+shortcut-connected double layer, two latent attentions and two dense
+feed-forwards around one expert block whose result lands a sub-layer
+late, two cache planes a layer; a router that scores zero-compute
+outputs behind its experts, by softmax over all of them; the two
+``mla_scale_*`` factors folded into the latents' norm weights where the
+tensors are read: ``Fold``; limits: identity zero experts only, slot
+layout, ``ep`` alone, no quantized tier),
 ``HYBRID`` (``bailing_hybrid``), ``LATENT`` (``deepseek_v3``, ``axk1``,
 ``xing4_0``, ``glm_moe_dsa``) and ``GQA``, the bare stack every other
 ``model_type`` is read as. A residual stream several hidden vectors wide (``hc_mult`` > 1:
@@ -105,6 +112,11 @@ class Family:
     # one set of weights with the model's last norm at the end of each
     # pass (models/llama.py forward_layers): the head then norms nothing
     loops: bool = False
+    # cache planes a layer keeps: 2 where one published layer is a
+    # shortcut-connected double layer (two latent attentions; models/llama.py
+    # ``_double_block``: its attention ``j`` of layer ``l`` is plane ``2 l +
+    # j`` and ``LlamaConfig.layer_kinds`` names its mixer "mla2")
+    planes_a_layer: int = 1
 
 
 # --- tensor names -----------------------------------------------------------
@@ -398,16 +410,21 @@ _GATED_DELTA_MAP = {
 
 # --- checks shared by several families --------------------------------------
 
-def _check_told_share(c, scorings=("sigmoid",)):
+def _check_told_share(c, scorings=("sigmoid",), unnormalised=False):
     """The shared-expert feed-forward's routing, and the held experts'
     place among the router's (``router_experts`` is filled in).
     ``scorings``: the scoring functions the family's layers compute.
-    ``"softmax"`` is wired in ONE form: over all the router's experts, the
-    chosen ones' shares renormalised over their sum and nothing else, which
-    IS softmax over the chosen logits (``ops/moe.py`` ``router_topk``'s
-    ``routing=None`` form; ``testing/reference_mellum.py`` computes the
-    long form and the tests hold the two together). A bias, groups, an
-    unnormalised share or a scaling factor would break the identity."""
+    ``"softmax"`` is wired in TWO forms, both over ALL the router's
+    outputs and in one group: the chosen shares renormalised over their
+    sum and nothing else, which IS softmax over the chosen logits
+    (``ops/moe.py`` ``router_topk``'s ``routing=None`` form;
+    ``testing/reference_mellum.py`` computes the long form and the tests
+    hold the two together); and the chosen shares as they are (NOT
+    renormalised), times a scaling factor, the choice on ``share + bias``
+    where the model has one (``GroupRouting(scoring="softmax")``;
+    ``testing/reference_longcat_flash.py``; ``unnormalised``: the family
+    whose layers were held to that reference admits it). Groups, or a
+    renormalised share beside a bias or a scaling factor, are neither."""
     if not c.n_routed_experts:
         return
     if c.scoring_func not in scorings:
@@ -415,13 +432,18 @@ def _check_told_share(c, scorings=("sigmoid",)):
             f"scoring_func {c.scoring_func!r} is not wired for "
             f"{c.family.what or 'the shared-expert family'} (only "
             f"{', '.join(scorings)}; sigmoid is the group-limited routing)")
-    if c.scoring_func == "softmax" and not (
-            c.norm_topk_prob and c.routed_scaling_factor == 1.0
-            and not c.router_bias and c.n_group == c.topk_group == 1):
+    if c.scoring_func == "softmax" and (
+            c.n_group != 1 or c.topk_group != 1 or (c.norm_topk_prob and (
+                c.router_bias or c.routed_scaling_factor != 1.0))
+            or not (c.norm_topk_prob or unnormalised)):
         raise ValueError(
-            "scoring_func 'softmax' is wired as softmax over all experts, "
-            "the chosen ones renormalised (norm_topk_prob true, "
-            "routed_scaling_factor 1, no routing bias, one group): got "
+            "scoring_func 'softmax' is wired as softmax over all the "
+            "router's outputs in one group, the chosen shares either "
+            "renormalised (norm_topk_prob true, routed_scaling_factor 1, no "
+            "routing bias) or, beside a shortcut-connected double layer's "
+            "latent attention alone, left as they are (norm_topk_prob "
+            "false: a scaling factor and a routing bias are then "
+            "computed): got "
             f"norm_topk_prob {c.norm_topk_prob}, routed_scaling_factor "
             f"{c.routed_scaling_factor}, a bias {c.router_bias}, "
             f"{c.topk_group} of {c.n_group} groups")
@@ -1343,6 +1365,170 @@ GATED_DELTA = Family(
     counts_held_experts=True, expert_periods=False)
 
 
+# --- a shortcut-connected double layer, zero-compute experts (LongCat-Flash) --
+
+# `model_type` "longcat_flash" (that the omni release's own `model_type`
+# names the same decoder, and the names below, are ASSUMED: the benchmark
+# configuration lists them): a layer's two attentions under
+# `self_attn.{0,1}.` with DeepSeek-V3's names inside, its two dense
+# feed-forwards `mlps.{0,1}.`, its four norms `input_layernorm.{0,1}` and
+# `post_attention_layernorm.{0,1}`, the router `mlp.router.classifier`
+# with its bias, the experts `mlp.experts.{e}.*` by global id. Ours: a
+# sub-layer's tensors under the latent family's names behind `s0_` / `s1_`
+# (`models/llama.py` ``sub_layer`` strips it), the expert block's under the
+# shared-expert family's.
+
+
+def _lora_scale(flag: str, rank: str) -> Fold:
+    """``(hidden / rank)^0.5`` on a latent's norm weight where the file's
+    ``flag`` is true (``mla_scale_q_lora`` / ``mla_scale_kv_lora``: a
+    constant on the norm's output ahead of a linear), nothing where it is
+    false: the program's latent attention multiplies by no factor of its
+    own, and the cached row is the scaled latent."""
+    def factor(c):
+        return ((c.hidden_size / getattr(c, rank)) ** 0.5
+                if getattr(c, flag) else 1.0)
+
+    return Fold(lambda c, w: w * factor(c), lambda c, w: w / factor(c))
+
+
+_LORA_SCALES = {"q_norm": _lora_scale("mla_scale_q_lora", "q_lora_rank"),
+                "kv_norm": _lora_scale("mla_scale_kv_lora", "kv_lora_rank")}
+
+
+def _sub_layer_names(j: int) -> dict:
+    names = {}
+    for ours, (suffix, transpose) in _LATENT_MAP.items():
+        head, _, rest = suffix.partition(".")  # a module, then its tensor
+        stored = (f"{head}.{j}.{rest}" if head == "self_attn"
+                  else f"{head}.{j}.weight")
+        names[f"s{j}_{ours}"] = (stored, transpose) + (
+            (_LORA_SCALES[ours],) if ours in _LORA_SCALES else ())
+    for ours, (suffix, transpose) in _LATENT_DENSE_MAP.items():
+        names[f"s{j}_{ours}"] = (
+            suffix.replace("mlp.", f"mlps.{j}.", 1), transpose)
+    return names
+
+
+_SHORTCUT_MAP = {
+    **_sub_layer_names(0), **_sub_layer_names(1),
+    "router": ("mlp.router.classifier.weight", True),
+    "b_router": ("mlp.router.e_score_correction_bias", False),
+}
+_SHORTCUT_FIELDS = ("zero_expert_num", "zero_expert_type",
+                    "mla_scale_q_lora", "mla_scale_kv_lora")
+
+
+def _shortcut_read(d: dict) -> dict:
+    """`LlamaConfig` fields from a "longcat_flash" config.json (its own
+    spelling: ``num_layers``, ``ffn_hidden_size``,
+    ``expert_ffn_hidden_size``, ``moe_topk``, ``zero_expert_num``,
+    ``zero_expert_type``, ``mla_scale_q_lora``, ``mla_scale_kv_lora``,
+    ``attention_method``). Read into the file, by the family's published
+    modelling code: softmax over ALL the router's outputs (the experts and
+    the zero-compute ones), the choice on ``share +
+    e_score_correction_bias``, the chosen shares NOT renormalised (the file
+    has no ``norm_topk_prob``; the code's default is false) times
+    ``routed_scaling_factor``; no shared expert, no leading dense layer, no
+    groups; interleaved rope pairs, no scaling."""
+    name = SHORTCUT.model_types[0]
+    _only_served(name, d, {"attention_method": "MLA", "rope_scaling": None,
+                           "attention_bias": False, "norm_topk_prob": False})
+    held = d.get("n_routed_experts", 0)
+    return {
+        "num_hidden_layers": d["num_layers"],
+        "intermediate_size": d["ffn_hidden_size"],
+        "moe_intermediate_size": d["expert_ffn_hidden_size"],
+        "num_experts_per_tok": d["moe_topk"],
+        "num_key_value_heads": d["num_attention_heads"],
+        "first_k_dense_replace": 0,
+        "n_shared_experts": 0,
+        "scoring_func": "softmax",
+        "norm_topk_prob": False,
+        "router_bias": True,
+        "n_group": 1,
+        "topk_group": 1,
+        **_expert_share(d, held),
+    }
+
+
+def _shortcut_write(c, d: dict):
+    d["num_layers"] = d.pop("num_hidden_layers")
+    d["ffn_hidden_size"] = d.pop("intermediate_size")
+    d["expert_ffn_hidden_size"] = d.pop("moe_intermediate_size")
+    d["moe_topk"] = d.pop("num_experts_per_tok")
+    d["attention_method"] = "MLA"
+    for f in ("router_bias", "scoring_func", "norm_topk_prob", "n_group",
+              "topk_group", "first_k_dense_replace", "n_shared_experts",
+              "num_key_value_heads", "head_dim"):
+        d.pop(f)
+
+
+def _shortcut_check(c):
+    if c.zero_expert_type != "identity":
+        raise ValueError(
+            f"zero_expert_type {c.zero_expert_type!r} is not wired: a "
+            "zero-compute expert returns its input (only 'identity')")
+    if not (c.kv_lora_rank and c.q_lora_rank and c.qk_rope_head_dim
+            and c.v_head_dim):
+        raise ValueError(
+            "model_type 'longcat_flash' is two latent attentions a layer: "
+            "it needs q_lora_rank, kv_lora_rank, qk_rope_head_dim and "
+            "v_head_dim")
+    if (c.zero_expert_num < 0 or not c.n_routed_experts or c.attn_gate
+            or c.first_k_dense_replace or c.n_shared_experts
+            or not c.router_bias or c.rope_scaling or c.layer_group_size
+            or c.num_local_experts or c.attention_bias):
+        raise ValueError(
+            "a shortcut-connected double layer (model_type 'longcat_flash')"
+            " is wired with routed experts and zero-compute ones chosen on "
+            "share + bias in every layer and plain latent attention: no "
+            "leading dense layer, no shared expert, no output gate, no rope "
+            "scaling, no projection bias, no delta-rule layers")
+    _check_told_share(c, ("softmax",), unnormalised=True)
+
+
+def check_zero_experts(c):
+    """What only ``SHORTCUT`` computes (``LlamaConfig.__post_init__``,
+    every family): router outputs that are no experts, and the latent
+    attention's two ``mla_scale_*`` factors."""
+    if c.family is SHORTCUT:
+        return
+    asked = [f"{key} = {getattr(c, key)!r}" for key in (
+        "zero_expert_num", "mla_scale_q_lora", "mla_scale_kv_lora")
+        if getattr(c, key)]
+    if asked:
+        raise ValueError(
+            f"{', '.join(asked)} is wired for model_type "
+            f"{SHORTCUT.model_types[0]!r} alone, not beside the layers of "
+            f"model_type {c.model_type!r}")
+
+
+_TWO_PLANES = (
+    "two cache planes a layer (the page pool, the snapshot and the sharded "
+    "programs count a plane a layer)")
+
+SHORTCUT = Family(
+    model_types=("longcat_flash",),
+    selects=lambda c: c.model_type in SHORTCUT.model_types,
+    fields=_LATENT_FIELDS + _SHORTCUT_FIELDS,
+    read=_shortcut_read, write=_shortcut_write, check=_shortcut_check,
+    tensor_names=_SHORTCUT_MAP, expert_names=_LATENT_EXPERT_MAP,
+    probe=".mlp.router.classifier.weight",
+    what="a shortcut-connected double-layer model",
+    shard_axes=frozenset(("ep",)),
+    shard_why=("one cache row for all heads, " + _TWO_PLANES + ": a double "
+               "layer under stages, tp or sp is not wired"),
+    linear_tiers=(),
+    linear_why=("no comparison with the reference has been made of an "
+                "int8 linear behind a folded mla_scale factor"),
+    cache_tiers=(),
+    cache_why=(
+        "an int8 cache is not wired for latent attention (the "
+        "latent row is already 1/35 of per-head keys and values)"),
+    counts_held_experts=True, planes_a_layer=2)
+
+
 # --- one set of layers run several times a token (Ouro's keys) --------------
 
 def _looped_read(d: dict) -> dict:
@@ -1424,8 +1610,8 @@ LOOPED = Family(
 # The first record that selects a configuration is its family: the
 # families that read `layer_types` before the ones a single key names, the
 # bare stack last.
-FAMILIES = (LOOPED, SHORT_CONV, GATED_DELTA, WINDOWED, STATE_SPACE, HYBRID,
-            LATENT, GQA)
+FAMILIES = (LOOPED, SHORT_CONV, GATED_DELTA, WINDOWED, STATE_SPACE, SHORTCUT,
+            HYBRID, LATENT, GQA)
 # every field some family's config.json alone carries
 FIELDS = frozenset(f for family in FAMILIES for f in family.fields)
 # the record that reads a config.json, by its `model_type` (the bare

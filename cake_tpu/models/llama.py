@@ -116,7 +116,7 @@ _INDEXER_SHAPES = {
 # An expert layer of the latent family: the router at its published width,
 # the HELD experts' stacks, and the shared experts as one SwiGLU.
 _SHARED_MOE_SHAPES = {
-    "router": lambda c: (c.hidden_size, c.router_experts),
+    "router": lambda c: (c.hidden_size, c.router_outputs),
     "w_gate": lambda c: (c.n_routed_experts, c.hidden_size,
                          c.moe_intermediate_size),
     "w_up": lambda c: (c.n_routed_experts, c.hidden_size,
@@ -233,7 +233,8 @@ class Segment(NamedTuple):
     layers and ``cache_stride`` cached ones on."""
 
     name: str
-    mixer: str  # "mla" | "kda" | "gdn" | "gqa" | "swa" | "mamba" | "conv"
+    # "mla" | "mla2" | "kda" | "gdn" | "gqa" | "swa" | "mamba" | "conv"
+    mixer: str
     ffn: str  # "dense" | "moe"
     first: int
     count: int
@@ -337,8 +338,8 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
     periods = config.family.expert_periods or not config.n_routed_experts
     names: dict[str, int] = {}
     # layers counted so far in the cache buffers of each mixer's kind
-    cached = {"mla": 0, "kda": 0, "gdn": 0, "gqa": 0, "swa": 0, "mamba": 0,
-              "conv": 0}
+    cached = {"mla": 0, "mla2": 0, "kda": 0, "gdn": 0, "gqa": 0, "swa": 0,
+              "mamba": 0, "conv": 0}
 
     def segment(kind, first, count, cache_stride=0):
         mixer, ffn = kind
@@ -394,6 +395,15 @@ def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
             del shapes["conv_b"]
     elif seg.mixer == "conv":
         shapes = dict(_CONV_SHAPES)
+    elif seg.mixer == "mla2":
+        # a double layer: each sub-layer's latent attention, norms and
+        # dense feed-forward behind ``s0_`` / ``s1_`` (:func:`sub_layer`),
+        # the one expert block's tensors under their own names
+        shapes = {
+            f"s{j}_{k}": shape for j in (0, 1) for k, shape in (
+                *_LATENT_SHAPES.items(),
+                *((k, _LAYER_SHAPES[k]) for k in ("w_gate", "w_up",
+                                                  "w_down")))}
     elif seg.mixer in ("gqa", "swa"):
         shapes = {k: _LAYER_SHAPES[k] for k in (
             "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm")}
@@ -425,7 +435,7 @@ def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
         return shapes
     shapes.update(_SHARED_MOE_SHAPES)
     if config.router_bias:
-        shapes["b_router"] = lambda c: (c.router_experts,)
+        shapes["b_router"] = lambda c: (c.router_outputs,)
     if not config.n_shared_experts:
         for k in ("ws_gate", "ws_up", "ws_down"):
             del shapes[k]
@@ -878,21 +888,8 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
         if "router" not in layer:
             return swiglu(h, layer["w_gate"], layer["w_up"],
                           layer["w_down"]), local
-        # softmax over ALL the router's experts with the chosen shares
-        # renormalised is softmax over the chosen logits: router_topk's
-        # ``routing=None`` form (families._check_told_share admits no
-        # other softmax)
-        routing = None if config.scoring_func == "softmax" else GroupRouting(
-            config.n_group, config.topk_group, config.norm_topk_prob,
-            config.routed_scaling_factor, layer.get("b_router"),
-            config.family.topk_norm_eps)
-        y = moe_swiglu(
-            h, layer["router"], layer["w_gate"], layer["w_up"],
-            layer["w_down"], top_k=config.num_experts_per_tok,
-            ep_axis=ep_axis, ep_size=ep_size, routing=routing,
-            held=(config.first_expert, config.n_routed_experts),
-            count_local=count_local, layer=expert_idx, valid=valid,
-        )
+        y = _routed(layer, h, config, ep_axis, ep_size, count_local,
+                    expert_idx, valid)
         if count_local:
             y, local = y
         if "ws_gate" in layer:  # every rank alike, so added after the psum
@@ -908,6 +905,82 @@ def _shared_feed_forward(layer, x, config, ep_axis, ep_size, count_local,
         return y, local
 
     return _sub_layer(layer, x, "ffn", "mlp_norm", config, feed)
+
+
+def _routed(layer, h, config, ep_axis, ep_size, count_local, expert_idx,
+            valid):
+    """An expert layer's routed part of ``h`` (:func:`moe_swiglu`: the held
+    experts' share and, where the router scores zero-compute outputs, the
+    identity part), under the configuration's routing."""
+    # softmax over ALL the router's experts with the chosen shares
+    # renormalised is softmax over the chosen logits: router_topk's
+    # ``routing=None`` form (families._check_told_share admits that softmax
+    # and the unnormalised one, which is a GroupRouting of softmax shares)
+    plain = config.scoring_func == "softmax" and config.norm_topk_prob
+    routing = None if plain else GroupRouting(
+        config.n_group, config.topk_group, config.norm_topk_prob,
+        config.routed_scaling_factor, layer.get("b_router"),
+        config.family.topk_norm_eps, config.scoring_func)
+    return moe_swiglu(
+        h, layer["router"], layer["w_gate"], layer["w_up"],
+        layer["w_down"], top_k=config.num_experts_per_tok,
+        ep_axis=ep_axis, ep_size=ep_size, routing=routing,
+        held=(config.first_expert, config.n_routed_experts),
+        count_local=count_local, layer=expert_idx, valid=valid,
+        zero_experts=config.zero_expert_num,
+    )
+
+
+def sub_layer(layer: Params, j: int) -> Params:
+    """Sub-layer ``j``'s tensors of a double layer, under the names the
+    latent family's one-attention layer holds them by (``attn_norm``,
+    ``wq_a`` .. ``wo``, ``mlp_norm``, ``w_gate``, ``w_up``, ``w_down``)."""
+    prefix = f"s{j}_"
+    return {k[len(prefix):]: v for k, v in layer.items()
+            if k.startswith(prefix)}
+
+
+def _double_block(layer, x, cache, cos, sin, pos, config, write_gate,
+                  ep_axis, ep_size, layer_idx, count_local, expert_idx,
+                  valid):
+    """One shortcut-connected double layer over the carried cache's rows
+    (planes ``2 layer_idx`` and ``2 layer_idx + 1``)::
+
+        a0 = x  + MLA_0(RMS(x))          h = RMS(a0)
+        s  = MoE(h)                      # computed here, NOT added here
+        b0 = a0 + FFN_0(h)
+        a1 = b0 + MLA_1(RMS(b0))
+        out = a1 + FFN_1(RMS(a1)) + s
+
+    ``s`` lives inside the block: the layer loop's carry is what every
+    other family's is. Returns ``(x, cache, ExpertCount)``."""
+    eps = config.rms_norm_eps
+
+    def attend(sub, x, cache, plane):
+        with jax.named_scope("mla"):
+            out, c, r = latent_attention_block(
+                rms_norm(x, sub["attn_norm"], eps), sub, cache.k, cache.v,
+                cos, sin, pos, config, write_gate=write_gate,
+                layer_idx=plane)
+        return x + out, dataclasses.replace(cache, k=c, v=r)
+
+    def feed(sub, h):
+        return swiglu(h, sub["w_gate"], sub["w_up"], sub["w_down"])
+
+    first, second = sub_layer(layer, 0), sub_layer(layer, 1)
+    local = ExpertCount.zeros(x.shape[0], zero=True)
+    with jax.named_scope("scmoe.first"):
+        x, cache = attend(first, x, cache, 2 * layer_idx)
+        h = rms_norm(x, first["mlp_norm"], eps)
+        late = _routed(layer, h, config, ep_axis, ep_size, count_local,
+                       expert_idx, valid)
+        if count_local:
+            late, local = late
+        x = x + feed(first, h)
+    with jax.named_scope("scmoe.second"):
+        x, cache = attend(second, x, cache, 2 * layer_idx + 1)
+        x = x + feed(second, rms_norm(x, second["mlp_norm"], eps)) + late
+    return x, cache, local
 
 
 def _kda_block(layer, x, cache, config, valid, ep_axis, ep_size, layer_idx,
@@ -1097,7 +1170,7 @@ def forward_layers(
         the whole stacks (a scan's slice would be written out for it)."""
         if "router" in stack and reads_whole_stacks(
                 rows, config.num_experts_per_tok, stack["router"],
-                stack["w_gate"]):
+                stack["w_gate"], config.zero_expert_num):
             whole = {k: stack[k] for k in ("w_gate", "w_up", "w_down")}
             return {k: v for k, v in stack.items() if k not in whole}, whole
         return stack, {}
@@ -1117,6 +1190,10 @@ def forward_layers(
                                    ep_size, i, count_local, j)
         elif "w_in" in layer:
             h, c, now = _mamba_block(layer, h, c, config, valid, i)
+        elif "s0_wkv_a" in layer:  # a double layer: planes 2 i and 2 i + 1
+            h, c, now = _double_block(
+                layer, h, c, cos, sin, pos, config, write_gate, ep_axis,
+                ep_size, i, count_local, j, expert_valid)
         else:
             h, kc, vc, *now = block_forward(
                 layer, h, c.k, c.v, cos, sin, pos, config,
@@ -1198,7 +1275,7 @@ def forward_layers(
 
     carry = (x, cache)
     if count_local:
-        carry += (ExpertCount.zeros(batch),)
+        carry += (ExpertCount.zeros(batch, config.zero_expert_num > 0),)
     if not config.segmented:  # one kind of layer, one bare stack
         stack, whole = split(layers)
         return scan_segment(carry, stack, 0, whole)

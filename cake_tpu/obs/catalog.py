@@ -47,6 +47,45 @@ SERIES: dict[str, tuple[str, str]] = {
                "stream's blocks up to its frontier (flash_decode, "
                "latent_decode), 0 the sweep of the reservation; absent "
                "where no program attends through either"),
+    "attn.admit_blocked": (
+        GAUGE, "what the last admission chunk traced of a latent layer with "
+               "no indexer chose (ops.mla.latent_admit_choice, from the "
+               "chunk's rows): 1 blocked by query rows (named scope "
+               "mla.admit_blocked: a first chunk's own tokens by the flash "
+               "prefill kernel over the expanded keys, the trace's "
+               "operation latent_prefill, or a strip of query rows at a "
+               "time where the chunk has history or no kernel runs), 0 the "
+               "chunk's own tokens in one piece (float32 scores [B, H, T, "
+               "T]); absent where no program admits through it"),
+    "attn.admit_blocked_min_rows": (
+        GAUGE, "the fewest rows a chunk a latent admission traced in this "
+               "process took the blocked form at (ops.mla."
+               "LATENT_ADMIT_BLOCK_MIN_T is the floor); absent where none "
+               "did"),
+    "attn.latent_decode_calls": (
+        COUNTER, "(plane, decode step) calls of a plain latent attention's "
+                 "single-token sweep (ops.mla; the trace's operation "
+                 "latent_decode where the kernel runs): planes of latent "
+                 "rows x steps, what attn.latent_rows_live is a mean over"),
+    "attn.latent_rows_live": (
+        COUNTER, "latent rows a decode step's sweep must read: over every "
+                 "slot, decode step and plane of plain latent rows, the "
+                 "stream's rows up to its frontier as dispatched (a slot "
+                 "without a live stream goes out at row 0: one row)"),
+    "attn.latent_admit_calls": (
+        COUNTER, "(plane, dispatch) calls of the blocked latent admission's "
+                 "own-chunk kernel (the trace's operation latent_prefill): "
+                 "first chunks whose program took the flash form "
+                 "(ops.mla.latent_admit_choice), a plane each"),
+    "attn.latent_admit_pairs": (
+        COUNTER, "causal (query row, row at or before it) pairs of those "
+                 "calls at the rows' TRUE lengths (a bucket's padding not "
+                 "counted), a plane each"),
+    "attn.latent_admit_pairs_handed": (
+        COUNTER, "causal pairs of those calls at the BUCKET's length (what "
+                 "the kernel was handed, padding rows included), a plane "
+                 "each: attn.latent_admit_pairs over it is the share of the "
+                 "handed work that was a true token's"),
     "attn.kv_blocks_read": (
         COUNTER, "KV blocks (of the rows flash_decode fetches of this "
                  "cache's shape, ops.pallas.decode_block_k: 512 for a "
@@ -302,6 +341,23 @@ SERIES: dict[str, tuple[str, str]] = {
     "moe.routed_pairs": (
         COUNTER, "(row, chosen expert) pairs decode steps routed over all "
                  "the router's experts: live rows x top-k x expert layers"),
+    "moe.zero_pairs": (
+        COUNTER, "(row, chosen output) pairs decode steps routed to the "
+                 "router's zero-compute outputs (LlamaConfig."
+                 "zero_expert_num: each returns its input; named scope "
+                 "moe.zero, the identity part added once behind the "
+                 "experts' sum), counted on the device a batch row and "
+                 "added up over the rows live at dispatch: of "
+                 "moe.routed_pairs, the pairs that cost no expert; 0 where "
+                 "the router scores experts alone"),
+    "model.planes_a_layer": (
+        GAUGE, "cache planes a layer keeps (Family.planes_a_layer): 2 where "
+               "a layer is a shortcut-connected double layer (named scopes "
+               "scmoe.first: the first attention, the expert block whose "
+               "result is held back, the first dense feed-forward; "
+               "scmoe.second: the second attention and feed-forward and "
+               "the late add), and attn.kv_blocks_* then count both; 1 "
+               "elsewhere"),
     "moe.sorted_pair_rows": (
         COUNTER, "(row, chosen expert) pair rows handed to expert calls "
                  "that took the sorted form: rows x top-k a call and "

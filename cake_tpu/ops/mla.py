@@ -38,7 +38,16 @@ Two forms of the same mathematics, chosen at trace time:
   ``pos``: a prefix hit, an earlier chunk) is attended in the absorbed
   form and the two partial softmaxes are merged exactly; that part is
   skipped at run time when there is no history (``lax.cond``), which is
-  every first chunk.
+  every first chunk. From ``LATENT_ADMIT_BLOCK_MIN_T`` rows on the chunk
+  is **blocked by query rows** (:func:`latent_admit_choice`), so that no
+  ``[B, H, T, T]`` array is built (float32: 1.07 GB at 2048 rows, 17 GB at
+  8192): a first chunk's own tokens by the flash prefill kernel over the
+  expanded keys, their 192 channels zero-padded to 256 (a zero channel
+  adds nothing to a product; the values stay 128 wide); a chunk with
+  history behind it, and every such chunk where no kernel runs, a strip
+  of query rows at a time (:func:`_strips`: float32 ``[B, H, strip, T]``
+  scores of its own tokens and ``[B, H, strip, S]`` of the history, the
+  two merged exactly as above).
 
 Where the layer holds an indexer (``idx_*``: a learned sparse attention,
 :mod:`cake_tpu.ops.dsa`) both forms attend a CHOICE of the rows: a decode
@@ -104,6 +113,34 @@ log = logging.getLogger("cake_tpu.mla")
 # thirds of XLA's cost.
 LATENT_DECODE_MIN_S = 1024
 
+# An admission chunk (T > 1) of a layer with no indexer: under this many
+# rows the chunk's own tokens are attended in ONE piece (float32 scores
+# ``[B, H, T, T]``: the form every plain-latent cell's 16-512-row buckets
+# compile), from it on blocked by query rows.
+# tools/flash_sweep.py --only latent-admit on v5 lite (B 1, 64 heads of
+# 128 + 64 keys and 128 values, bf16, one set of operands, ms a call; my
+# chip run, PR 64; PERF.md section 6), whole / strip / flash:
+#
+# - T 512: 0.310 / 0.176 / 0.209; T 1024: 2.087 / 0.572 / **0.547**; T
+#   2048: 8.089 / 5.135 / **2.139**; T 4096: 31.73 / 35.26 / **5.586**; T
+#   8192: - (17 GB of scores) / 150.1 / **17.74**. The three agree to one
+#   bfloat16 step of the output (0.002).
+# - the whole form grows with T^2 in HBM traffic (the float32 scores are
+#   written and read twice) and so do the strips (150 ms at 8192: 0.8 GB a
+#   strip of 128 rows); the kernel keeps a block's scores in VMEM and skips
+#   the blocks above the diagonal: 62 TFLOP/s of the TRUE causal products
+#   at 8192 rows (32% of the chip's peak; it also computes 64 zero
+#   channels a key and the diagonal blocks whole).
+# - both blocked forms are ahead at 512 rows too (1.5-1.8x); the floor
+#   stands at 1024 all the same: 512 rows is where the cells of the three
+#   older plain-latent configurations end, and their programs stay the
+#   ones they were measured with.
+LATENT_ADMIT_BLOCK_MIN_T = 1024
+# float32 score bytes a strip of query rows may take (its own tokens' and
+# its history's, each)
+ADMIT_STRIP_BYTES = 256 << 20
+ADMIT_STRIP = 128  # query rows a strip holds at most
+
 
 def latent_decode_choice(s: int, dc: int, dr: int) -> str:
     """``"kernel"`` or ``"xla"`` for a single-token (T == 1) absorbed
@@ -132,6 +169,122 @@ def latent_decode_choice(s: int, dc: int, dr: int) -> str:
             "under 128); falling back to the XLA path", s, dc, dr,
             pk.DECODE_BLOCK_K)
     return "xla"
+
+
+def latent_admit_choice(t: int, d_qk: int) -> str:
+    """``"whole"``, ``"flash"`` or ``"strip"`` for an admission chunk of
+    ``t`` rows a stream, keys ``d_qk`` wide, of a latent layer with no
+    indexer: THE plain latent admission's policy, from what a trace can see of its input (the
+    shapes; whether a chunk has history behind it is data, and a blocked
+    chunk that has is swept in strips at run time whatever this says).
+    :func:`latent_attention_block` asks it, once for each admission
+    program traced, and publishes the answer (``attn.admit_blocked``,
+    ``attn.admit_blocked_min_rows``)."""
+    if t < LATENT_ADMIT_BLOCK_MIN_T:
+        return "whole"
+    if not pk.kernels_enabled():
+        return "strip"
+    if pk.force_kernels() and pk.interpret_default():
+        return "flash"
+    # whole blocks of query rows; any key width pads to whole lane tiles
+    return "flash" if t % 128 == 0 and d_qk <= 256 else "strip"
+
+
+def _strip_rows(b: int, h: int, t: int, s: int) -> int:
+    """Query rows a strip holds: the largest power of two up to
+    ``ADMIT_STRIP`` that divides ``t`` and keeps a strip's float32 scores
+    against ``s`` rows within ``ADMIT_STRIP_BYTES``."""
+    strip = ADMIT_STRIP
+    while strip > 1 and (t % strip or b * h * strip * s * 4
+                         > ADMIT_STRIP_BYTES):
+        strip //= 2
+    return strip
+
+
+def chunk_whole(q_nope, q_pe, k_nope, k_pe, v_own, scale):
+    """A chunk's own tokens, expanded and causal among themselves, in one
+    piece: ``q_nope [B, H, T, dn]``, ``q_pe [B, H, T, dr]``, ``k_nope [B,
+    H, T, dn]``, ``k_pe [B, 1, T, dr]`` (one for all heads), ``v_own [B, H,
+    T, dv]``. Returns the scaled scores' row maximum, the normalizer and
+    the un-normalized output, float32."""
+    t = q_nope.shape[2]
+
+    def causal():
+        qi = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+        ki = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+        return ki <= qi
+
+    return _own_part(q_nope, q_pe, k_nope, k_pe, v_own, scale, causal)
+
+
+def _own_part(q_nope, q_pe, k_nope, k_pe, v_own, scale, keep):
+    """Query rows ``q_nope`` / ``q_pe`` (all of a chunk's, or a strip of
+    them) against the chunk's expanded keys and values under the mask
+    ``keep()`` (``[T', T]``, made once the scores are): row maximum,
+    normalizer, un-normalized output, float32."""
+    sc = (jnp.einsum("bhtn,bhun->bhtu", q_nope, k_nope,
+                     preferred_element_type=jnp.float32)
+          + jnp.einsum("bhtr,bur->bhtu", q_pe, k_pe[:, 0],
+                       preferred_element_type=jnp.float32)) * scale
+    sc = jnp.where(keep(), sc, NEG_INF)
+    m_o = jnp.max(sc, axis=-1, keepdims=True)
+    p = jnp.exp(sc - m_o)
+    l_o = jnp.sum(p, axis=-1, keepdims=True)
+    o_o = jnp.einsum("bhtu,bhuv->bhtv", p.astype(v_own.dtype), v_own,
+                     preferred_element_type=jnp.float32)
+    return m_o, l_o, o_o
+
+
+def chunk_flash(q_nope, q_pe, k_nope, k_pe, v_own, scale):
+    """A first chunk's own tokens by the flash prefill kernel over the
+    expanded keys ``[k_nope | k_pe]``, queries and keys zero-padded to
+    whole lane tiles (192 -> 256; the scale stays the true width's).
+    Returns the normalized output ``[B, H, T, dv]`` in the inputs' type:
+    the kernel keeps its running maximum and normalizer to itself, so a
+    chunk with history takes :func:`_strips`."""
+    b, h, t, dn = q_nope.shape
+    dr = q_pe.shape[-1]
+    pad = -(dn + dr) % 128
+    zeros = jnp.zeros((b, h, t, pad), q_nope.dtype)
+    q = jnp.concatenate([q_nope, q_pe, zeros], -1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe, (b, h, t, dr)), zeros], -1)
+    return pk.flash_attention(q, k, v_own, 0, scale=scale,
+                              name="latent_prefill")
+
+
+def _strips(q_nope, q_pe, k_nope, k_pe, v_own, scale, history=None,
+            behind: int = 0):
+    """A chunk's attention a strip of query rows at a time: each strip's
+    own-chunk part as :func:`chunk_whole` computes it (float32 ``[B, H,
+    strip, T]``; the arguments are its) and, with ``history(q_nope_s,
+    q_pe_s) -> (m, l, o)`` (the absorbed sweep of the ``behind`` rows the
+    buffer holds), that part merged in. Returns the normalized output ``[B, H, T, dv]``, float32."""
+    b, h, t, dn = q_nope.shape
+    dr, dv = q_pe.shape[-1], v_own.shape[-1]
+    strip = _strip_rows(b, h, t, max(t, behind))
+    n = t // strip
+    col = jnp.arange(t, dtype=jnp.int32)
+
+    def one(args):
+        qn_s, qp_s, first = args  # [B, H, strip, dn], [.., dr], []
+        row = first + jnp.arange(strip, dtype=jnp.int32)
+        m_o, l_o, o_o = _own_part(
+            qn_s, qp_s, k_nope, k_pe, v_own, scale,
+            lambda: col[None, :] <= row[:, None])
+        if history is None:
+            return o_o / l_o
+        m_h, l_h, o_h = history(qn_s, qp_s)
+        m = jnp.maximum(m_o, m_h)
+        a_o, a_h = jnp.exp(m_o - m), jnp.exp(m_h - m)
+        return (o_o * a_o + o_h * a_h) / (l_o * a_o + l_h * a_h)
+
+    def strips(x):
+        return x.reshape(b, h, n, strip, x.shape[-1]).transpose(2, 0, 1, 3, 4)
+
+    out = jax.lax.map(one, (strips(q_nope), strips(q_pe),
+                            jnp.arange(n, dtype=jnp.int32) * strip))
+    return out.transpose(1, 2, 0, 3, 4).reshape(b, h, t, dv)
 
 
 def masked_sweep(q_c, q_pe, c_all, r_all, valid, scale):
@@ -220,13 +373,15 @@ def latent_attention_block(
     pos = jnp.asarray(pos, jnp.int32)
     pos_b = pos[:, None, None, None] if pos.ndim else pos  # over [B,H,T,S]
 
-    def cached(valid):
+    def cached(valid, q_nope=q_nope, q_pe=q_pe):
         """Absorbed attention against the cached rows ``valid`` admits
         (``[B|1, 1, T, S]``): row maximum, normalizer and the un-normalized
         output ``[B, H, T, dv]``, all float32. ``valid`` None (``T == 1``):
         the rows up to each stream's frontier, by the kernel, which reads
         those rows' blocks and no others, once, out of the carried
-        buffers themselves; a mask sweeps the whole buffer, twice."""
+        buffers themselves; a mask sweeps the whole buffer, twice.
+        ``q_nope`` / ``q_pe``: a strip of the chunk's query rows in place
+        of all of them."""
         q_c = jnp.einsum("bhtn,chn->bhtc", q_nope, w_k)
         if valid is None:
             m, l, o_c = pk.latent_decode(q_c[:, :, 0], q_pe[:, :, 0], c_cache,
@@ -275,35 +430,47 @@ def latent_attention_block(
         # the chunk's own tokens, expanded and causal among themselves
         kv_own = quant.dense(c[:, 0], layer["wkv_b"]).reshape(
             b, t, nh, dn + dv).transpose(0, 2, 1, 3)
-        k_nope, v_own = kv_own[..., :dn], kv_own[..., dn:]
-        sc = (jnp.einsum("bhtn,bhun->bhtu", q_nope, k_nope,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bhtr,bur->bhtu", q_pe, k_pe[:, 0],
-                           preferred_element_type=jnp.float32)) * scale
-        qi = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
-        ki = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
-        sc = jnp.where(ki <= qi, sc, NEG_INF)
-        m_o = jnp.max(sc, axis=-1, keepdims=True)
-        p = jnp.exp(sc - m_o)
-        l_o = jnp.sum(p, axis=-1, keepdims=True)
-        o_o = jnp.einsum("bhtu,bhuv->bhtv", p.astype(x.dtype), v_own,
-                         preferred_element_type=jnp.float32)
+        own = (q_nope, q_pe, kv_own[..., :dn], k_pe, kv_own[..., dn:], scale)
+        form = latent_admit_choice(t, dn + dr)
+        # trace time: which admission the program being built holds
+        obs_metrics.gauge("attn.admit_blocked").set(int(form != "whole"))
+        if form == "whole":
+            m_o, l_o, o_o = chunk_whole(*own)
 
-        # what lies behind the chunk in the cache, absorbed; nothing does
-        # on a first chunk, and then this sweep of the buffer is not run
-        def history(_):
-            return cached(kpos < pos_b)
+            # what lies behind the chunk in the cache, absorbed; nothing
+            # does on a first chunk, and then this sweep of the buffer is
+            # not run
+            def history(_):
+                return cached(kpos < pos_b)
 
-        def no_history(_):
-            return (jnp.full((b, nh, t, 1), NEG_INF, jnp.float32),
-                    jnp.zeros((b, nh, t, 1), jnp.float32),
-                    jnp.zeros((b, nh, t, dv), jnp.float32))
+            def no_history(_):
+                return (jnp.full((b, nh, t, 1), NEG_INF, jnp.float32),
+                        jnp.zeros((b, nh, t, 1), jnp.float32),
+                        jnp.zeros((b, nh, t, dv), jnp.float32))
 
-        m_h, l_h, o_h = jax.lax.cond(jnp.any(pos > 0), history, no_history,
-                                     None)
-        m = jnp.maximum(m_o, m_h)
-        a_o, a_h = jnp.exp(m_o - m), jnp.exp(m_h - m)
-        out = (o_o * a_o + o_h * a_h) / (l_o * a_o + l_h * a_h)
+            m_h, l_h, o_h = jax.lax.cond(jnp.any(pos > 0), history,
+                                         no_history, None)
+            m = jnp.maximum(m_o, m_h)
+            a_o, a_h = jnp.exp(m_o - m), jnp.exp(m_h - m)
+            out = (o_o * a_o + o_h * a_h) / (l_o * a_o + l_h * a_h)
+        else:
+            # ... and from how many rows the blocked one was taken
+            gauge = obs_metrics.gauge("attn.admit_blocked_min_rows")
+            gauge.set(min(t, gauge.value or t))
+            with jax.named_scope("mla.admit_blocked"):
+                behind = kpos[:, :, :1] < pos_b  # [B|1, 1, 1, S]: any row's
+
+                def with_history(_):
+                    return _strips(*own, history=lambda qn, qp: cached(
+                        behind, qn, qp), behind=s)
+
+                def first_chunk(_):
+                    if form == "flash":
+                        return chunk_flash(*own).astype(jnp.float32)
+                    return _strips(*own)
+
+                out = jax.lax.cond(jnp.any(pos > 0), with_history,
+                                   first_chunk, None)
 
     out = out.astype(x.dtype).transpose(0, 2, 1, 3)  # [B, T, H, dv]
     if "wg" in layer:  # a sigmoid gate a head on the heads' outputs

@@ -64,6 +64,17 @@ TPU-first design:
   composes with tensor parallelism: the expert intermediate axis shards over
   ``tp`` exactly like the dense MLP, and the down-projection partial sums
   reduce over ``(ep, tp)`` in one fused psum.
+
+- **A router wider than the experts** (``zero_experts``: the router's last
+  outputs are zero-compute experts, each of which returns its input). A
+  pair on such an output is, to the sorted form, a pair on an expert that
+  is not here: the tail of the sort, no tile, no read. To the dense form it
+  is a zero weight (the combine's columns stop at the experts). The gather
+  form never indexes a stack by such an id (the id is clamped and its
+  weight zeroed). :func:`expert_form` and :func:`hit_share` reckon with
+  every output the router scores. The identity part ``z(h) h`` (``z`` the
+  sum of the chosen zero outputs' weights) is every rank's alike and is
+  added ONCE, after the ``psum``.
 """
 
 from __future__ import annotations
@@ -138,22 +149,29 @@ class ExpertCount(NamedTuple):
     form, and ``live_rows []`` those of them that lie in a row tile the
     call touched (the sorted form moves the tiles that hold a true
     token's pair on a held expert and no others: ``ceil(such pairs / row
-    tile)`` tiles); both 0 of a call in another form."""
+    tile)`` tiles); both 0 of a call in another form. ``zero [B]`` each
+    batch row's pairs that fell on zero-compute outputs of the router,
+    or None (no leaf: the programs of a model whose router scores experts
+    alone return what they returned before there was such a count)."""
 
     pairs: jax.Array
     hit: jax.Array
     sorted_rows: jax.Array
     live_rows: jax.Array
+    zero: jax.Array | None = None
 
     @classmethod
-    def zeros(cls, batch: int) -> "ExpertCount":
-        zero = jnp.zeros((), jnp.int32)
-        return cls(jnp.zeros((batch,), jnp.int32), zero, zero, zero)
+    def zeros(cls, batch: int, zero: bool = False) -> "ExpertCount":
+        """``zero``: the router scores zero-compute outputs too."""
+        nothing = jnp.zeros((), jnp.int32)
+        rows = jnp.zeros((batch,), jnp.int32)
+        return cls(rows, nothing, nothing, nothing, rows if zero else None)
 
     def __add__(self, other: "ExpertCount") -> "ExpertCount":
         # field by field (a tuple's own ``+`` would concatenate): what the
         # layer loop and the step loop carry and add up
-        return ExpertCount(*(a + b for a, b in zip(self, other)))
+        return ExpertCount(*(None if a is None else a + b
+                             for a, b in zip(self, other)))
 
 
 class GroupRouting(NamedTuple):
@@ -165,7 +183,11 @@ class GroupRouting(NamedTuple):
     scores, normalised over the chosen (``+ norm_eps``) and scaled.
     ``bias [E]`` (``topk_method: "noaux_tc"``): a per-expert correction
     that enters the CHOICE (groups and top-k are taken on ``score +
-    bias``) and not the weights (the chosen experts' own scores)."""
+    bias``) and not the weights (the chosen experts' own scores).
+    ``scoring`` "softmax" (LongCat-Flash's routing): the scores are the
+    softmax shares over ALL the router's outputs in place of sigmoids,
+    everything else as above (that model: one group, a bias, the chosen
+    shares not renormalised, a scale of 6)."""
 
     n_group: int = 1
     topk_group: int = 1
@@ -173,6 +195,7 @@ class GroupRouting(NamedTuple):
     scale: float = 1.0
     bias: jax.Array | None = None
     norm_eps: float = 1e-20
+    scoring: str = "sigmoid"
 
 
 def _deq(w, dt):
@@ -202,7 +225,9 @@ def router_topk(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Top-k routing in f32. ``routing`` None is Mixtral's convention:
     top-k of the logits, softmax over the *selected* logits. A
-    :class:`GroupRouting` is DeepSeek-V3's sigmoid, group-limited choice.
+    :class:`GroupRouting` is DeepSeek-V3's sigmoid, group-limited choice,
+    or (``scoring`` "softmax") the same choice on softmax shares over all
+    the outputs.
     Ties go to the lower index (``lax.top_k``). Returns ``(combine [N, E]
     f32, weights [N, k] f32, idx [N, k] int32)`` where ``combine`` is zero
     off the top-k."""
@@ -214,7 +239,9 @@ def router_topk(
         w = jax.nn.softmax(vals, axis=-1)
     else:
         n, e = logits.shape
-        scores = jax.nn.sigmoid(logits)
+        scores = (jax.nn.softmax(logits, axis=-1)
+                  if routing.scoring == "softmax"
+                  else jax.nn.sigmoid(logits))
         choice = scores
         if routing.bias is not None:
             choice = scores + routing.bias.astype(jnp.float32)
@@ -260,9 +287,16 @@ def _moe_gather(
     w_gate,  # [E, H, F] array or int8 QuantizedLinear
     w_up,
     w_down,  # [E, F, H]
+    clamp: bool = False,  # some ids name zero-compute outputs, no expert
 ) -> jax.Array:
     n, k = idx.shape
     dt = x2d.dtype
+    if clamp:
+        # a pair on a zero-compute output names no row of the stacks: its
+        # id is clamped to the last expert's and its weight is zero
+        experts = _stack(w_gate).shape[-3]
+        w_topk = jnp.where(idx < experts, w_topk, 0.0)
+        idx = jnp.minimum(idx, experts - 1)
     flat = idx.reshape(-1)
     gg = _deq(_take(w_gate, flat), dt)  # [N*k, H, F]
     gu = _deq(_take(w_up, flat), dt)
@@ -407,11 +441,12 @@ def hit_share(rows: int, top_k: int, scored: int) -> float:
 
 
 def expert_form(rows: int, top_k: int, quantized: bool, held: int,
-                scored: int) -> str:
+                scored: int, zero: int = 0) -> str:
     """``"gather"``, ``"dense"`` or ``"sorted"``: THE strategy of a call,
     from what its trace can see: its rows, ``top_k``, the stacks' type,
-    how many experts the stacks hold (``held``) and how many the router
-    scores (``scored``). One algorithm, whose better form depends on how
+    how many experts the stacks hold (``held``) and how many outputs the
+    router scores (``scored``: the experts and, behind them, ``zero``
+    zero-compute outputs, on which a pair is a pair on no expert here). One algorithm, whose better form depends on how
     much of the stacks a call touches and on how many rows share a weight
     read: a handful of pairs, every scored expert here, gather their
     experts' matrices; a call whose pairs leave many of the experts
@@ -421,7 +456,7 @@ def expert_form(rows: int, top_k: int, quantized: bool, held: int,
     hides under the read); and from a bucket of prompt rows on that
     arithmetic costs more than the read, so the pairs are sorted again and
     only they are computed. The sorted form's product is a Pallas kernel."""
-    if held == scored and rows * top_k <= GATHER_MAX_ROWS:
+    if held + zero == scored and rows * top_k <= GATHER_MAX_ROWS:
         return "gather"
     if not pk.kernels_enabled():
         return "dense"
@@ -431,16 +466,18 @@ def expert_form(rows: int, top_k: int, quantized: bool, held: int,
     return "sorted" if few or rows >= least else "dense"
 
 
-def reads_whole_stacks(rows: int, top_k: int, router, w_gate) -> bool:
+def reads_whole_stacks(rows: int, top_k: int, router, w_gate,
+                       zero: int = 0) -> bool:
     """Should the layer loop hand :func:`moe_swiglu` the whole expert
     stacks and the layer's index, for a call of ``rows`` rows under this
-    ``router [.., H, scored]``? Yes where it takes the sorted form: a
+    ``router [.., H, scored]`` (``zero`` of its outputs zero-compute)? Yes where it takes the sorted form: a
     kernel's operand that is a scan's slice is written out first (AOT for
     v5e: a slice of each of a layer's three int8 stacks, the operation
     that costs 1.4 ms a stack where the dense form pays it, my chip run,
     PR 33), the whole stack with an index is read where it lies."""
     return expert_form(rows, top_k, isinstance(w_gate, QuantizedLinear),
-                       _stack(w_gate).shape[-3], router.shape[-1]) == "sorted"
+                       _stack(w_gate).shape[-3], router.shape[-1],
+                       zero) == "sorted"
 
 
 def form_traced(rows: int) -> str | None:
@@ -470,6 +507,7 @@ def moe_swiglu(
     count_local: bool = False,
     layer: jax.Array | None = None,
     valid: jax.Array | None = None,
+    zero_experts: int = 0,
 ):
     """Routed SwiGLU MLP. Returns ``[B, T, H]`` (residual NOT added); with
     ``count_local`` a pair ``(out, ExpertCount)``: each batch row's number
@@ -505,6 +543,13 @@ def moe_swiglu(
     no tile visited for it, and its result exactly zero (every true row's
     is what it is without ``valid``, bit for bit). The dense and gather
     forms have no tile to skip and compute a padding row like any other.
+
+    ``zero_experts``: the router's LAST that many outputs are zero-compute
+    experts (each returns its input): ``E_global`` counts them, ``held``
+    and the stacks count experts alone. The result then carries the
+    identity part ``z h`` (``z`` the sum of a token's chosen zero outputs'
+    weights), every rank's alike and added once, after the ``psum``
+    (named scope ``moe.zero``); a padding row's is nobody's to read.
     """
     b, t, h = x.shape
     x2d = x.reshape(b * t, h)
@@ -513,7 +558,8 @@ def moe_swiglu(
 
     e_local = _stack(w_gate).shape[-3]
     e_global = combine.shape[1]
-    first, count = held or (0, e_global)
+    real = e_global - zero_experts  # the router's outputs that are experts
+    first, count = held or (0, real)
     if ep_axis is not None and ep_size is None:
         # Static ep width from the shapes already in hand: the router
         # scores the GLOBAL expert set ([H, E_global]) while the weight
@@ -529,7 +575,7 @@ def moe_swiglu(
     # chip's compiler writes the scanned expert stacks out before it, 24
     # ms an admission, my chip run, PR 28)
     form = expert_form(b * t, top_k, isinstance(w_gate, QuantizedLinear),
-                       e_local, e_global)
+                       e_local, e_global, zero_experts)
     assert layer is None or form == "sorted", (form, b * t)
     _traced[b * t] = form
     if form == "sorted":
@@ -550,19 +596,36 @@ def moe_swiglu(
             out, live_rows = _moe_sorted(x2d, w_topk, idx, lo, w_gate, w_up,
                                          w_down, layer, e_global, true)
         elif form == "gather":
-            out = _moe_gather(x2d, w_topk, idx, w_gate, w_up, w_down)
+            out = _moe_gather(x2d, w_topk, idx, w_gate, w_up, w_down,
+                              clamp=zero_experts > 0)
         else:  # every held expert over every row
             out = _moe_dense(x2d, combine, w_gate, w_up, w_down)
     if tp_axis is not None:
         axes += (tp_axis,)
     if axes:
         out = jax.lax.psum(out, axes)
+    if zero_experts:
+        on_zero = idx >= real  # [N, k]: pairs on zero-compute outputs
+        with jax.named_scope("moe.zero"):
+            z = jnp.sum(jnp.where(on_zero, w_topk, 0.0), axis=1,
+                        keepdims=True)
+            out = (out.astype(jnp.float32)
+                   + z * x2d.astype(jnp.float32)).astype(x2d.dtype)
     out = out.reshape(b, t, h)
     if count_local:
         chosen = combine > 0  # [N, E_local]
         pairs = jnp.sum(chosen, axis=1, dtype=jnp.int32)
+        zero_pairs = None
+        if zero_experts:
+            zero_pairs = jnp.sum(on_zero, axis=1, dtype=jnp.int32)
+            if sharded:  # every rank's alike: counted by the first (the
+                # callers sum the counts over ep)
+                zero_pairs = jnp.where(jax.lax.axis_index(ep_axis) == 0,
+                                       zero_pairs, 0)
+            zero_pairs = zero_pairs.reshape(b, t).sum(axis=1)
         return out, ExpertCount(
             pairs.reshape(b, t).sum(axis=1),
             jnp.sum(chosen.any(axis=0), dtype=jnp.int32),
-            jnp.int32(b * t * top_k if form == "sorted" else 0), live_rows)
+            jnp.int32(b * t * top_k if form == "sorted" else 0), live_rows,
+            zero_pairs)
     return out

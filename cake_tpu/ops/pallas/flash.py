@@ -157,8 +157,15 @@ def flash_attention(
     block_k: int | None = None,
     window: int | None = None,
     interpret: bool | None = None,
+    scale: float | None = None,
+    name: str | None = None,
 ) -> jax.Array:
-    """Causal flash attention over a fixed KV buffer. Returns [B, H, T, D].
+    """Causal flash attention over a fixed KV buffer. Returns [B, H, T, Dv]
+    (``Dv``: the values' width, which may differ from the keys' ``D``).
+    ``scale``: the scores' factor where it is not ``D^-0.5`` (heads padded
+    with zero channels to whole lane tiles keep their own); ``name``: the
+    call's name in a device trace where a reader must tell it from the
+    grouped-query prefill's.
 
     Default blocks from a v5e sweep (8B geometry, D=128): bq=512
     throughout; bk=1024 once the KV buffer is long enough to amortize the
@@ -172,6 +179,7 @@ def flash_attention(
     """
     b, h, t, d = q.shape
     kvh, s = k_all.shape[1], k_all.shape[2]
+    dv = v_all.shape[-1]
     group = h // kvh
     if block_k is None:
         block_k = 1024 if s >= 4096 else 512
@@ -183,7 +191,8 @@ def flash_attention(
 
         interpret = interpret_default()
     pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
 
     def q_map(bi, hi, qb, kb, pos_ref):
         return (bi, hi, qb, 0)
@@ -201,11 +210,11 @@ def flash_attention(
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), q_map),
             pl.BlockSpec((1, 1, bk, d), kv_map),
-            pl.BlockSpec((1, 1, bk, d), kv_map),
+            pl.BlockSpec((1, 1, bk, dv), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, d), q_map),
+        out_specs=pl.BlockSpec((1, 1, bq, dv), q_map),
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
@@ -216,17 +225,19 @@ def flash_attention(
     )
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * h * t * s * d,
-            bytes_accessed=(q.size + 2 * k_all.size + q.size) * q.dtype.itemsize,
+            flops=2 * b * h * t * s * (d + dv),
+            bytes_accessed=(q.size + k_all.size + v_all.size
+                            + b * h * t * dv) * q.dtype.itemsize,
             transcendentals=b * h * t * s,
         ),
         interpret=interpret,
+        **({"name": name} if name else {}),
     )(pos_arr, q, k_all, v_all)
 
 

@@ -118,7 +118,8 @@ def _pipeline_layers(
         return (x, new_cache, *(a + b for a, b in zip(local, now)))
 
     carry = (x, cache) + (
-        (ExpertCount.zeros(x.shape[0]),) if count_local else ())
+        (ExpertCount.zeros(x.shape[0], config.zero_expert_num > 0),)
+        if count_local else ())
     return jax.lax.fori_loop(0, num_stages, body, carry)
 
 
@@ -466,7 +467,9 @@ def build_sharded_decode(
             P(DP, None),
             P(DP) if per_row else P(),
         ) + lp_specs + (
-            (ExpertCount(P(DP), P(), P(), P()),) if count_local else ()),
+            (ExpertCount(P(DP), P(), P(), P(),
+                         P(DP) if config.zero_expert_num else None),)
+            if count_local else ()),
         check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(2,))

@@ -112,6 +112,7 @@ from cake_tpu.obs.trace import span
 from cake_tpu.ops import pallas as pk
 from cake_tpu.ops import dsa, quant, sampling
 from cake_tpu.ops.kda import CHUNK
+from cake_tpu.ops.mla import latent_admit_choice
 from cake_tpu.ops.moe import fetch_traced as moe_fetch_traced
 from cake_tpu.ops.moe import form_traced as moe_form_traced
 from cake_tpu.ops.sampling import SamplerSettings
@@ -227,6 +228,7 @@ _MOE_LOCAL = obs_metrics.counter("moe.local_pairs")
 _MOE_HIT = obs_metrics.counter("moe.experts_hit")
 _MOE_DECODE_SORTED = obs_metrics.gauge("moe.decode_sorted")
 _MOE_ROUTED = obs_metrics.counter("moe.routed_pairs")
+_MOE_ZERO = obs_metrics.counter("moe.zero_pairs")
 _MOE_STEPS = obs_metrics.counter("moe.decode_steps")
 _MOE_ADMIT_ROWS = obs_metrics.counter("moe.admit_rows")
 _MOE_ADMIT_SORTED = obs_metrics.counter("moe.admit_rows_sorted")
@@ -326,6 +328,19 @@ _DSA_ADMIT_ROWS_TRUE = obs_metrics.counter("dsa.admit_rows_true")
 # (min(t + 1, index_topk) a row), a layer
 _DSA_PAIRS_SCORED = obs_metrics.counter("dsa.admit_pairs_scored")
 _DSA_PAIRS_ATTENDED = obs_metrics.counter("dsa.admit_pairs_attended")
+# Plain latent attention (ops/mla.py), from the positions as dispatched:
+# the rows a decode step's sweep must read (each stream's, to its
+# frontier), a plane a step, and how many (plane, step) calls that was;
+# the causal pairs, at the rows' true lengths and at the bucket's (what the
+# kernel was handed), of the first chunks whose own tokens the blocked
+# admission's kernel attended, a plane, and how many (plane, dispatch)
+# calls that was
+_LATENT_DECODE_CALLS = obs_metrics.counter("attn.latent_decode_calls")
+_LATENT_ROWS_LIVE = obs_metrics.counter("attn.latent_rows_live")
+_LATENT_ADMIT_CALLS = obs_metrics.counter("attn.latent_admit_calls")
+_LATENT_ADMIT_PAIRS = obs_metrics.counter("attn.latent_admit_pairs")
+_LATENT_ADMIT_PAIRS_HANDED = obs_metrics.counter(
+    "attn.latent_admit_pairs_handed")
 
 # arrival-queue entry kinds (4th tuple field): None marks a plain prompt
 # arrival; imports ride the SAME FIFO so pool-pressure deferral stays
@@ -582,12 +597,20 @@ class BatchGenerator:
             config.num_attention_heads // config.num_key_value_heads)
         ) or pk.DECODE_BLOCK_K
         # ... and how many planes a layer it counts (a looped model's
-        # passes: each reads and reserves a plane of its own)
-        self._kv_planes = config.total_ut_steps
+        # passes, a double layer's two attentions: each reads and reserves
+        # a plane of its own)
+        self._kv_planes = config.total_ut_steps * config.family.planes_a_layer
+        obs_metrics.gauge("model.planes_a_layer").set(
+            config.family.planes_a_layer)
         # the layers under a learned sparse attention, and how many rows a
         # query of theirs attends at most (_count_kv_blocks, _launch)
         self._dsa = ((config.cache_plan["index"][0], config.index_topk)
                      if "index" in config.cache_plan else None)
+        # the planes of plain latent rows (no indexer over them), and the
+        # widths its admission's choice reads (_count_kv_blocks, _launch)
+        self._latent_planes = (
+            config.cache_plan["rows"][0]
+            if config.kv_lora_rank and self._dsa is None else 0)
         # the layers whose admission is a scan of chunks (_count_delta_chunks)
         self._delta_layers = sum(
             m in ("kda", "gdn") for m, _ in config.layer_kinds)
@@ -2571,6 +2594,9 @@ class BatchGenerator:
                 self._count_dsa_admission(
                     chunk, [min(max(len(m.ids) - base - pos, 0), chunk)
                             for m in st["rows"]])
+            if self._latent_planes and base + pos == 0:
+                self._count_latent_admission(
+                    chunk, [min(len(m.ids), chunk) for m in st["rows"]])
             self._count_delta_chunks(
                 chunk, [len(m.ids) - base - pos for m in st["rows"]])
             st["pos"] = pos + chunk
@@ -2612,6 +2638,22 @@ class BatchGenerator:
             _DSA_PAIRS_SCORED.inc(layers * (n * (n + 1) // 2))
             _DSA_PAIRS_ATTENDED.inc(
                 layers * (k * (k + 1) // 2 + (n - k) * topk))
+
+    def _count_latent_admission(self, chunk: int, true: list[int]) -> None:
+        """A first admission dispatch of ``chunk`` rows a member, ``true`` of
+        them each member's prompt tokens, over plain latent planes: where
+        the program's own-chunk attention is the blocked form's kernel
+        (``ops.mla.latent_admit_choice``, from the shapes), the calls and
+        the causal pairs at the true lengths, a plane."""
+        c = self.config
+        if latent_admit_choice(
+                chunk, c.qk_nope_head_dim + c.qk_rope_head_dim) != "flash":
+            return
+        _LATENT_ADMIT_CALLS.inc(self._latent_planes)
+        _LATENT_ADMIT_PAIRS.inc(
+            self._latent_planes * sum(n * (n + 1) // 2 for n in true))
+        _LATENT_ADMIT_PAIRS_HANDED.inc(
+            self._latent_planes * len(true) * (chunk * (chunk + 1) // 2))
 
     def _count_delta_chunks(self, chunk: int, left: list[int]) -> None:
         """An admission dispatch of ``chunk`` tokens a row over a model
@@ -4007,6 +4049,11 @@ class BatchGenerator:
             window=self._kv_window)
         _KV_BLOCKS_READ.inc(read * self._kv_planes)
         _KV_BLOCKS_RESERVED.inc(reserved * self._kv_planes)
+        if self._latent_planes:
+            # step j of the dispatch sweeps the rows 0..pos + j of a stream
+            live = pos[:, None] + np.arange(1, steps + 1)[None, :]
+            _LATENT_DECODE_CALLS.inc(self._latent_planes * steps)
+            _LATENT_ROWS_LIVE.inc(self._latent_planes * int(live.sum()))
         if self._dsa:
             # step j of the dispatch scores the rows 0..pos + j of a stream
             # and attends index_topk of them, or all while it has fewer
@@ -4067,9 +4114,11 @@ class BatchGenerator:
         """Fetch the oldest queued counts and add the rows' that were
         live when their dispatch left into ``moe.*``."""
         count, steps, live = self._moe_pending.popleft()
-        for leaf in count:  # one wait for the four, not four
+        for leaf in jax.tree.leaves(count):  # one wait for all, not one each
             leaf.copy_to_host_async()
         _MOE_LOCAL.inc(int(self._host(count.pairs)[live].sum()))
+        if count.zero is not None:  # the router scores zero-compute outputs
+            _MOE_ZERO.inc(int(self._host(count.zero)[live].sum()))
         # every row that went through the program, a dead slot's too: what
         # the sorted form reads of the stacks
         _MOE_HIT.inc(int(self._host(count.hit)))

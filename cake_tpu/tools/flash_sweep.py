@@ -8,7 +8,7 @@ dispatch — the same measured-crossover treatment ``quant_matmul`` got for its
 M>=16 gate (`ops/quant.py`).
 
 Usage:  python -m cake_tpu.tools.flash_sweep [--json-out PATH]
-            [--only served-decode|served-latent|one-row|narrow]
+            [--only served-decode|served-latent|latent-admit|one-row|narrow]
 (``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
 Prints one JSON line per shape:
@@ -20,6 +20,9 @@ cache the layer loop carries, at the served shapes and frontiers (what
 decides ``DECODE_FLASH_MIN_S`` and ``DECODE_BLOCK_K``, and for the latent
 cache ``ops.mla.LATENT_DECODE_MIN_S``; the tables stand beside those
 constants). ``--only served-latent`` runs the latent rows alone;
+``--only latent-admit`` the plain latent ADMISSION's own-chunk attention in
+its three forms (:func:`latent_admit_rows`: what decides
+``ops.mla.LATENT_ADMIT_BLOCK_MIN_T``);
 ``--only one-row`` the decode kernel's two forms (the heads' products a head
 at a time, or in one batched call) at 128- to 512-row blocks on the rows of
 heads with ONE query row a KV head (``ONE_ROW_SHAPES``: what decides
@@ -272,6 +275,75 @@ def served_latent_rows(results: list, blocks=(256, 512, 1024),
             print(json.dumps(rec), flush=True)
 
 
+# (rows a chunk, heads) of latent_admit_rows at nope 128 + rope 64 keys and
+# 128-wide values: LongCat-Flash's and A.X-K1's heads, a first chunk of one
+# stream (the engine admits a prompt a bucket at a time)
+LATENT_ADMIT_SHAPES = ((512, 64), (1024, 64), (2048, 64), (4096, 64),
+                       (8192, 64))
+# the whole form's float32 scores [H, T, T] past this do not fit beside the
+# rest of a 16 GiB chip (17 GB at 8192 rows): not timed
+_WHOLE_MAX_T = 4096
+
+
+def latent_admit_rows(results: list, shapes=LATENT_ADMIT_SHAPES,
+                      dn: int = 128, dr: int = 64, dv: int = 128) -> None:
+    """A plain latent admission's own-chunk attention (``ops/mla.py``, a
+    first chunk of ``T`` rows, ``B`` 1, bf16) in its three forms on the
+    SAME expanded operands: ``whole`` (float32 scores ``[H, T, T]`` in one
+    piece: the form under ``LATENT_ADMIT_BLOCK_MIN_T``), ``strip`` (a
+    strip of query rows at a time), ``flash`` (the flash prefill kernel
+    over the expanded keys, zero-padded 192 -> 256, values 128 wide). Each
+    normalised to ``[1, H, T, dv]``; ms a call."""
+    from cake_tpu.ops import mla
+    from cake_tpu.ops.pallas import interpret_default
+
+    compiled = not interpret_default()
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    scale = (dn + dr) ** -0.5
+
+    def whole(*own):
+        m, l, o = mla.chunk_whole(*own, scale)
+        return (o / l).astype(jnp.bfloat16)
+
+    def strip(*own):
+        return mla._strips(*own, scale).astype(jnp.bfloat16)
+
+    def flash(*own):
+        return mla.chunk_flash(*own, scale)
+
+    for t, h in shapes:
+        own = (jax.random.normal(ks[0], (1, h, t, dn), jnp.bfloat16),
+               jax.random.normal(ks[1], (1, h, t, dr), jnp.bfloat16),
+               jax.random.normal(ks[2], (1, h, t, dn), jnp.bfloat16),
+               jax.random.normal(ks[3], (1, 1, t, dr), jnp.bfloat16),
+               jax.random.normal(ks[4], (1, h, t, dv), jnp.bfloat16))
+        rec = {"path": "latent_admit", "t": t, "heads": h,
+               "d_qk": dn + dr, "d_v": dv,
+               "auto_impl": mla.latent_admit_choice(t, dn + dr)}
+        outs = {}
+        for name, fn in (("whole", whole), ("strip", strip),
+                         ("flash", flash)):
+            if name == "whole" and t > _WHOLE_MAX_T and compiled:
+                continue
+            try:
+                f = jax.jit(fn)
+                outs[name] = f(*own)
+                # (the timing loop keeps every call's output: few a
+                # dispatch at thousands of rows)
+                rec[f"{name}_ms"] = round(_time_ms(
+                    f, *own, iters=5, inner=4 if t >= 2048 else 16), 4)
+            except Exception as e:  # a form the chip's compiler refuses
+                rec[f"{name}_error"] = f"{type(e).__name__}: {e}"[:300]
+        base = outs.get("strip")
+        for name, out in outs.items():
+            if base is not None and name != "strip":
+                rec[f"{name}_max_abs_diff_from_strip"] = float(jnp.max(
+                    jnp.abs(out.astype(jnp.float32)
+                            - base.astype(jnp.float32))))
+        results.append(rec)
+        print(json.dumps(rec), flush=True)
+
+
 def sweep(json_out: str | None = None) -> list:
     from cake_tpu.ops.attention import _attend_xla
     from cake_tpu.ops.pallas import flash_attention, flash_decode, interpret_default
@@ -458,7 +530,7 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json-out", default=None)
     ap.add_argument("--only", choices=["served-decode", "served-latent",
-                                       "one-row", "narrow"],
+                                       "latent-admit", "one-row", "narrow"],
                     default=None,
                     help="run one section instead of the whole sweep")
     args = ap.parse_args()
@@ -472,6 +544,8 @@ def main() -> int:
             served_decode_rows(rows, blocks=(128, 256, 512),
                                shapes=NARROW_SHAPES, forms=(False, True),
                                every_block=True)
+        elif args.only == "latent-admit":
+            latent_admit_rows(rows)
         else:
             if args.only == "served-decode":
                 served_decode_rows(rows)
