@@ -303,6 +303,19 @@ SERIES: dict[str, tuple[str, str]] = {
         COUNTER, "of delta.chunks_swept, the chunks that held a true "
                  "token: the same with each row's own length in place of "
                  "the longest's"),
+    "delta.chunks_kernel": (
+        COUNTER, "of delta.chunks_swept, the chunks of the dispatches whose "
+                 "program runs a layer's serial scan as ONE Pallas call "
+                 "(kda_chunk_scan: a block of heads' state in VMEM from "
+                 "chunk to chunk) where the others run XLA's loop "
+                 "(ops.kda.kda_chunk_choice, by the bucket's tokens and "
+                 "the rule)"),
+    "delta.chunk_kernel": (
+        GAUGE, "1 once an admission program that holds the scan kernel has "
+               "been traced (ops.kda.kda_chunk_choice said so for its "
+               "bucket; set at trace time), 0 while every traced bucket's "
+               "scan is XLA's loop; absent where no program holds a "
+               "delta-rule layer"),
     "ssm.decode_kernel": (
         GAUGE, "what ops.mamba.mamba_mixer_block chose for the last "
                "single-token state-space step it traced (the decode "

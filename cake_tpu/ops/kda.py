@@ -1,5 +1,6 @@
 """Delta-rule linear attention over a recurrent state: ONE step, ONE chunk
-form and ONE decode kernel for the two rules this repo serves.
+form, ONE decode kernel for the two rules this repo serves, and a kernel
+for the chunk form's serial scan where the decay is a head's.
 
 The reference serves one attention, over cached keys and values
 (`cake-core/src/model/attention.rs`); this is the layer that keeps none.
@@ -75,7 +76,28 @@ Two forms of the recurrence, chosen at trace time by ``T``:
   (beta k e^G)``, are made for ALL chunks of a bucket at once, batched over
   ``[n, B, G, R]``, and the serial scan over the chunks holds products on
   the state alone: ``u = u_hat - w S_0``, ``o = (q e^G) S_0 + A^q u``, ``S_C
-  = e^{G_C} S_0 + (k e^{G_C - G})^T u``. Where the decay is a channel's a
+  = e^{G_C} S_0 + (k e^{G_C - G})^T u``. **Who runs that scan** (PR 65;
+  :func:`kda_chunk_choice`, from what a trace sees: the rule, the state's
+  tiles, the bucket's tokens, no knob): the ``jnp`` loop below, a
+  ``fori_loop`` whose every turn reads all heads' state from HBM, slices
+  its six operands out of bucket-sized arrays and launches a handful of
+  small fusions (44 us a chunk of 32 heads on the chip where the MXU's own
+  time is ~14); or, for the scalar-gated rule on the chip from
+  ``KDA_SCAN_MIN_T`` tokens on, ONE call a layer of the Pallas kernel
+  :func:`cake_tpu.ops.pallas.kda.kda_chunk_scan`, in which a block of
+  heads' state stays in VMEM from the first live chunk to the last, the
+  next chunk's operands are fetched while this one's products run, and
+  ``q e^G``, ``k e^{G_C - G}`` and the masked ``Q K^T`` are made there from
+  ``q``, ``k``, ``Q K^T`` a KEY head and ``G``: three bucket-sized arrays
+  a value head that the loop's form writes and reads back are never made.
+  The same arithmetic (float32 operands, every product at the highest
+  precision); the loop is the other branch and the oracle, and the decay
+  a channel's only form. The kernel also ends the layer: given the gate
+  it writes ``rmsnorm_head(o) * silu(z)`` as the output projection reads
+  it (:func:`_gated` is the same in ``jnp``, for every other path),
+  because whatever layout it returned ``o`` in, XLA copied ``o`` and ``z``
+  into another tiling before gating them, which cost more than the scan
+  saved (``ops/pallas/kda.py``). Where the decay is a channel's a
   chunk's ``[C, C, d_k]`` tensor is as large as all of a long bucket's
   ``[C, C]`` masks together (67 MB at 32 heads of 128): held for ``n``
   chunks it would not fit, so past ``HOIST_BYTES`` the same two functions
@@ -125,6 +147,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.ops import kvcache as kv
 from cake_tpu.ops import pallas as pk
 from cake_tpu.ops import quant
@@ -172,6 +195,37 @@ def kda_decode_choice(d_k: int, d_v: int) -> str:
     if pk.interpret_default():
         return "kernel" if pk.force_kernels() else "xla"
     return "kernel" if d_k % 128 == 0 and d_v % 128 == 0 else "xla"
+
+
+# fewest tokens of a bucket whose scan takes the kernel: a layer 1.11x
+# XLA's loop there, 1.24-1.26x at 2048, 1.49-1.51x at 4096 and 8192, and
+# 1.04-1.09x at 256 and 512, under the 1.10x a second form must show
+# (tools/kda_sweep --chunk --forms xla,kernel at live shares 1.0 and 0.67,
+# my chip runs, PR 65; PERF.md section 6)
+KDA_SCAN_MIN_T = 1024
+# what each traced bucket's scan took, by its tokens (chunk_form_traced)
+_traced: dict[int, str] = {}
+
+
+def kda_chunk_choice(t: int, d_k: int, d_v: int, scalar: bool) -> str:
+    """``"kernel"`` or ``"xla"`` for the serial scan of an admission's
+    ``t`` tokens: THE policy, from what a trace can see. The kernel
+    (:func:`cake_tpu.ops.pallas.kda.kda_chunk_scan`) is the scalar-gated
+    rule's (a decay a head), wants whole ``(8, 128)`` tiles of a head's
+    state and a bucket long enough to pay for its launch; off the chip it
+    runs interpreted, and only when kernels are forced (tests)."""
+    if not (scalar and pk.kernels_enabled()):
+        return "xla"
+    if pk.interpret_default():
+        return "kernel" if pk.force_kernels() else "xla"
+    return "kernel" if (d_k % 128 == 0 and d_v % 128 == 0
+                        and t >= KDA_SCAN_MIN_T) else "xla"
+
+
+def chunk_form_traced(t: int) -> str | None:
+    """What the scan of the last ``t``-token admission traced took
+    (``"kernel"`` | ``"xla"``; None: none was traced)."""
+    return _traced.get(t)
 
 
 def kda_recurrence(q, k, v, g, beta, state):
@@ -238,9 +292,10 @@ def _unit_lower_inverse(n_mat, block: int):
     return t[..., 0, :c, :c]
 
 
-@partial(jax.jit, static_argnames="chunk")
+@partial(jax.jit, static_argnames=("chunk", "form", "eps"))
 @jax.default_matmul_precision("highest")
-def kda_chunk(q, k, v, g, beta, state, live=None, chunk: int = CHUNK):
+def kda_chunk(q, k, v, g, beta, state, live=None, chunk: int = CHUNK,
+              form: str = "xla", gate=None, eps: float = 0.0):
     """``T`` tokens in chunks of ``chunk`` (module docstring). ``q, k [B, T,
     Hk, d_k]``, ``v [B, T, Hv, d_v]``, ``g [B, T, Hv, d_k]`` (a decay a
     channel) or ``[B, T, Hv]`` (a head), ``beta [B, T, Hv]``, ``state [B,
@@ -249,7 +304,11 @@ def kda_chunk(q, k, v, g, beta, state, live=None, chunk: int = CHUNK):
     hold a token which writes or decays the state (``beta`` and ``g`` are
     0 from there on). The serial loop stops there: the state is what all
     ``T`` tokens leave (a chunk past ``live`` is the identity on it),
-    ``o`` is zero past ``live x chunk``.
+    ``o`` is zero past ``live x chunk``. ``form``: who runs the serial
+    scan, the ``jnp`` loop below (``"xla"``) or, for a decay a head, the
+    Pallas kernel (``"kernel"``; :func:`kda_chunk_choice` for a layer),
+    which with ``gate`` ``(z [B, T, Hv d_v], norm [d_v])`` and ``eps``
+    returns the layer's output :func:`_gated` in ``o``'s place.
     Inside, the value heads lie ``[G, R]``: ``G = Hk`` key heads, each
     under its ``R`` value heads, so that what only q and k make (``K K^T``,
     ``Q K^T`` in the scalar case) is made once a KEY head. A function of
@@ -261,10 +320,13 @@ def kda_chunk(q, k, v, g, beta, state, live=None, chunk: int = CHUNK):
     scalar = g.ndim == beta.ndim
     c = min(chunk, t)
     pad = -t % c
+    assert gate is None or form == "kernel", form
     if pad:  # tokens that neither write nor decay the state
         q, k, v, g, beta = (
             jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
             for a in (q, k, v, g, beta))
+        if gate is not None:
+            gate = (jnp.pad(gate[0], ((0, 0), (0, pad), (0, 0))), gate[1])
     n = (t + pad) // c
 
     def chunks(a, grouped=True):
@@ -288,7 +350,9 @@ def kda_chunk(q, k, v, g, beta, state, live=None, chunk: int = CHUNK):
             mask = jnp.exp(jnp.where(
                 tri, cum[..., :, None] - cum[..., None, :], -jnp.inf))
             kk = jnp.einsum("bgck,bgsk->bgcs", kc, kc)[:, :, None] * mask
-            qk = jnp.einsum("bgck,bgsk->bgcs", qc, kc)[:, :, None] * mask
+            qk = jnp.einsum("bgck,bgsk->bgcs", qc, kc)  # a KEY head
+            if form != "kernel":  # which decays it where it is used
+                qk = qk[:, :, None] * mask
             into = jnp.exp(cum)[..., None]  # e^{G_t} [B, G, R, C, 1]
             out = jnp.exp(cum[..., -1:] - cum)[..., None]  # e^{G_C - G_s}
             carried = jnp.exp(cum[..., -1])[..., None, None]
@@ -308,9 +372,14 @@ def kda_chunk(q, k, v, g, beta, state, live=None, chunk: int = CHUNK):
         inv = _unit_lower_inverse(bc[..., None] * jnp.tril(kk, -1),
                                   INVERSE_BLOCK)
         kc = kc[:, :, None]
-        return (qc[:, :, None] * into, kc * out, carried, qk,
-                inv @ (bc[..., None] * vc),
-                inv @ (bc[..., None] * (kc * into)))
+
+        def halves():
+            return (inv @ (bc[..., None] * vc),
+                    inv @ (bc[..., None] * (kc * into)))
+
+        if form == "kernel":  # which takes q and k themselves
+            return qk, cum, *halves()
+        return (qc[:, :, None] * into, kc * out, carried, qk, *halves())
 
     def advance(s0, xs):
         q_in, k_out, carried, qk, u_hat, w = xs
@@ -320,6 +389,27 @@ def kda_chunk(q, k, v, g, beta, state, live=None, chunk: int = CHUNK):
 
     xs = (chunks(q, False), chunks(k, False), chunks(v), chunks(g),
           chunks(beta))
+    if form == "kernel":
+        assert scalar, "the scan kernel is the scalar-gated rule's"
+        from cake_tpu.ops.pallas.kda import kda_chunk_scan
+
+        # as many chunks a call as may be held ahead of it (all of a
+        # bucket the engine launches: HOIST_BYTES is an 8192-row one's)
+        per = max(1, HOIST_BYTES // (4 * b * hv * c * c))
+        live = n if live is None else live
+        outs = []
+        for at in range(0, n, per):
+            qk, cum, u_hat, w = jax.vmap(ahead)(
+                tuple(a[at:at + per] for a in xs))
+            m = cum.shape[0]
+            o, state = kda_chunk_scan(
+                xs[0][at:at + m], xs[1][at:at + m], qk,
+                cum.reshape(m, b, hv, c), u_hat.reshape(m, b, hv, c, dv),
+                w.reshape(m, b, hv, c, dk), state, live - at,
+                gate=gate and (gate[0][:, at * c:(at + m) * c], gate[1], eps))
+            outs.append(o)
+        o = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+        return o[:, :t], state
     s0 = state.reshape(b, hk, r, dk, dv)
     # the widest thing a chunk makes ahead of the state: [C, C] a value
     # head, times d_k where the decay is a channel's
@@ -424,12 +514,25 @@ def kda_attention_block(
     return quant.dense(o, layer["wo"]), state, conv
 
 
-def _advance(q, k, v, g, beta, state, valid, layer_idx, scope: str):
+def _gated(o, z, norm, eps: float):
+    """The scalar-gated rule's output gate: ``rmsnorm_head(o; norm) *
+    silu(z)`` for ``o [B, T, Hv, d_v]`` float32 and ``z [B, T, Hv d_v]``,
+    as ``z`` lies and in its type."""
+    o = rms_norm(o, norm.astype(jnp.float32), eps)
+    gate = jax.nn.silu(z.astype(jnp.float32).reshape(o.shape))
+    return (o * gate).astype(z.dtype).reshape(z.shape)
+
+
+def _advance(q, k, v, g, beta, state, valid, layer_idx, scope: str,
+             gate=None):
     """The recurrence over a chunk's ``T`` tokens from layer ``layer_idx``
     of the carried ``state``, by the form ``T`` chooses (module docstring),
     under the named scope ``<scope>.step`` or ``<scope>.chunk``; padded
     tokens (``valid``) touch nothing. Returns ``(o [B, T, Hv, d_v],
-    state)``, the buffer whole."""
+    state)``, the buffer whole; with ``gate`` ``(z, norm, eps)`` the
+    layer's output :func:`_gated` ``[B, T, Hv d_v]`` in ``o``'s place
+    (the scan kernel makes it where it has ``o`` in hand: what it returns
+    for XLA to gate, XLA first copies into another tiling)."""
     t = q.shape[1]
     chunks = None
     if valid is not None:
@@ -445,7 +548,8 @@ def _advance(q, k, v, g, beta, state, valid, layer_idx, scope: str):
         with jax.named_scope(f"{scope}.step"):
             o, state = kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                                   beta[:, 0], state, layer_idx)
-        return o[:, None], state
+        o = o[:, None]
+        return (o if gate is None else _gated(o, *gate)), state
     s0 = kv.layer_view(state, layer_idx)
     if t == 1:
         with jax.named_scope(f"{scope}.step"):
@@ -453,8 +557,20 @@ def _advance(q, k, v, g, beta, state, valid, layer_idx, scope: str):
                              s0)
             o = o[:, None]
     else:
+        # trace time: which scan the admission program being built holds
+        form = kda_chunk_choice(t, *state.shape[-2:], g.ndim == beta.ndim)
+        _traced[t] = form
+        gauge = obs_metrics.gauge("delta.chunk_kernel")
+        gauge.set(max(gauge.value or 0, int(form == "kernel")))
         with jax.named_scope(f"{scope}.chunk"):
-            o, s1 = kda_chunk(q, k, v, g, beta, s0, chunks)
+            if gate is not None and form == "kernel":  # whose epilogue it is
+                o, s1 = kda_chunk(q, k, v, g, beta, s0, chunks, form=form,
+                                  gate=gate[:2], eps=gate[2])
+                gate = None
+            else:
+                o, s1 = kda_chunk(q, k, v, g, beta, s0, chunks, form=form)
+    if gate is not None:
+        o = _gated(o, *gate)
     return o, kv.layer_store(state, s1, layer_idx)
 
 
@@ -480,7 +596,6 @@ def gdn_attention_block(
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = -jnp.exp(layer["a_log"].astype(f32)) * jax.nn.softplus(
             ba[..., hv:] + layer["dt_bias"].astype(f32))  # [B, T, Hv]
-        z = qkvz[..., width:].astype(f32).reshape(b, t, hv, dv)
     with jax.named_scope("gdn.conv"):
         y, tail = causal_conv(qkvz[..., :width],
                               kv.layer_view(conv, layer_idx),
@@ -489,8 +604,8 @@ def gdn_attention_block(
         q = _l2norm(y[..., :hk * dk].reshape(b, t, hk, dk)) * dk ** -0.5
         k = _l2norm(y[..., hk * dk:2 * hk * dk].reshape(b, t, hk, dk))
         v = y[..., 2 * hk * dk:].reshape(b, t, hv, dv)
-    o, state = _advance(q, k, v, g, beta, state, valid, layer_idx, "gdn")
+    o, state = _advance(
+        q, k, v, g, beta, state, valid, layer_idx, "gdn",
+        gate=(qkvz[..., width:], layer["o_norm"], config.rms_norm_eps))
     conv = kv.layer_store(conv, tail.astype(conv.dtype), layer_idx)
-    o = rms_norm(o, layer["o_norm"].astype(f32), config.rms_norm_eps)
-    o = (o * jax.nn.silu(z)).astype(x.dtype).reshape(b, t, hv * dv)
     return quant.dense(o, layer["w_out"]), state, conv

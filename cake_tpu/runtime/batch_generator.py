@@ -112,6 +112,7 @@ from cake_tpu.obs.trace import span
 from cake_tpu.ops import pallas as pk
 from cake_tpu.ops import dsa, quant, sampling
 from cake_tpu.ops.kda import CHUNK
+from cake_tpu.ops.kda import chunk_form_traced as delta_form_traced
 from cake_tpu.ops.mla import latent_admit_choice
 from cake_tpu.ops.moe import fetch_traced as moe_fetch_traced
 from cake_tpu.ops.moe import form_traced as moe_form_traced
@@ -246,9 +247,11 @@ _STATE_RESETS = {"kda": obs_metrics.counter("kda.state_resets"),
 # chunks that stops at the launch's last live one: what a launch's rows
 # cost at its longest row's length, and what they would at each row's own
 # (a bucket's padding costs no chunk; a shorter row rides to the longest's
-# end)
+# end); and the swept chunks of the dispatches whose program runs the loop
+# as ONE kernel call a layer (ops.kda.kda_chunk_choice, by the bucket)
 _DELTA_CHUNKS_SWEPT = obs_metrics.counter("delta.chunks_swept")
 _DELTA_CHUNKS_LIVE = obs_metrics.counter("delta.chunks_live")
+_DELTA_CHUNKS_KERNEL = obs_metrics.counter("delta.chunks_kernel")
 _SPEC_NGRAM = 3  # the longest n-gram a batched proposal is looked up by
 # what a cache may hold beside rows (cache_plan's keys), as a refusal says it
 _HELD = {"state": "recurrent state", "conv": "convolution's tail",
@@ -2662,12 +2665,17 @@ class BatchGenerator:
         chunk that holds a true token of some row, ``ops.kda._advance``)
         to ``delta.chunks_swept`` and those that hold a true token
         (``left``: each row's prompt tokens from this dispatch's first
-        on) to ``delta.chunks_live``."""
+        on) to ``delta.chunks_live``; the swept ones to
+        ``delta.chunks_kernel`` too where the scan recorded the kernel
+        when a layer of that many tokens was traced."""
         if not self._delta_layers:
             return
         live = [-(-min(max(n, 0), chunk) // CHUNK) for n in left]
-        _DELTA_CHUNKS_SWEPT.inc(self._delta_layers * len(left) * max(live))
+        swept = self._delta_layers * len(left) * max(live)
+        _DELTA_CHUNKS_SWEPT.inc(swept)
         _DELTA_CHUNKS_LIVE.inc(self._delta_layers * sum(live))
+        if delta_form_traced(chunk) == "kernel":
+            _DELTA_CHUNKS_KERNEL.inc(swept)
 
     def _admit_dispatched(self, t0: float, chunk: int, pos: int) -> None:
         """Book one admission chunk whose compute has been waited for."""

@@ -18,10 +18,18 @@ is entered as a layer enters it (``ops.kda._advance`` with each row's
 true length): ``--live-share`` makes that length a share of the tokens,
 the rest a bucket's padding (1.0: a bucket that is full, told so), one
 pass a share: what a scan that stops at the last live chunk saves, and
-what its bound costs a bucket that has no padding.
+what its bound costs a bucket that has no padding. ``--forms`` times
+the serial scan as the ``jnp`` loop (``xla``) and as the Pallas kernel
+(``kernel``: a decay a head alone, once a head block of ``--head-block``),
+whatever :func:`cake_tpu.ops.kda.kda_chunk_choice` would choose: where
+``ops.kda.KDA_SCAN_MIN_T`` and ``ops.pallas.kda.SCAN_HEAD_BLOCK`` come
+from. (Both forms return ``o`` here, as the rule leaves it; the kernel's
+epilogue, a layer's norm and gate, is the served cell's to judge.)
 
 Usage:  python -m cake_tpu.tools.kda_sweep [--chunk [--blocks 8,16,32]
-                                           [--live-share 1.0,0.67]]
+                                           [--live-share 1.0,0.67]
+                                           [--forms xla,kernel
+                                            [--head-block 8,16]]]
                                            [--json-out PATH]
 (``--json-out`` is refused off a TPU: interpreted kernels, no device times.)
 
@@ -30,8 +38,9 @@ Prints one JSON line per shape: ``{"batch", "layers", "heads", "d",
 "kernel_hbm_share"}`` (the share: one read and one write of the state and
 the step's vectors over 819 GB/s over the kernel's time); with ``--chunk``
 ``{"decay", "batch", "tokens", "live_share", "key_heads", "heads", "d",
-"block", "us_per_layer", "us_per_chunk"}`` (a chunk: one of the bucket's,
-live or not).
+"block", "form", "head_block", "us_per_layer", "us_per_chunk"}`` (a chunk:
+one of the bucket's, live or not; ``head_block``: the kernel's, else
+null).
 """
 
 from __future__ import annotations
@@ -47,6 +56,7 @@ import jax.numpy as jnp
 
 from cake_tpu.ops import kda
 from cake_tpu.ops.kda import kda_step
+from cake_tpu.ops.pallas import kda as pallas_kda
 from cake_tpu.ops.pallas.kda import kda_decode, kda_decode_bytes
 from cake_tpu.tools.kernel_check import refuse_offchip_record
 
@@ -58,7 +68,7 @@ STEPS = 8  # a block's steps in one program, as the engine dispatches them
 # (decay, batch, tokens, key heads, value heads, d): qwen3next-ep4-cut's
 # admission buckets, then ling3flash-ep4-cut's one- and two-row launches
 CHUNK_SHAPES = tuple(("scalar", 1, t, 16, 32, 128)
-                     for t in (256, 1024, 4096, 8192)) + tuple(
+                     for t in (256, 512, 1024, 2048, 4096, 8192)) + tuple(
     ("channel", b, t, 32, 32, 128) for b in (1, 2) for t in (128, 256, 512))
 CHUNK_LAYERS = 6  # delta-rule layers of either cell
 
@@ -87,14 +97,18 @@ def _step_all_layers(form, state, q, k, v, g, beta):
 
 def _call_us(fn, state, args, iters: int) -> float:
     """Microseconds a call of ``fn(state, *args) -> (state, acc)`` once it
-    has compiled, the state donated from call to call."""
+    has compiled, the state donated from call to call (``iters`` calls
+    timed together, three times)."""
     state, acc = fn(state, *args)  # compile
     jax.block_until_ready(acc)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        state, acc = fn(state, *args)
-    jax.block_until_ready((state, acc))
-    return (time.perf_counter() - t0) * 1e6 / iters
+    best = float("inf")
+    for _ in range(3):  # the best of three: one stall is not the form's
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            state, acc = fn(state, *args)
+        jax.block_until_ready((state, acc))
+        best = min(best, time.perf_counter() - t0)
+    return best * 1e6 / iters
 
 
 def _time_us(form, b, n_layers, h, d, iters: int = 10) -> float:
@@ -153,19 +167,42 @@ def _chunk_us(decay, b, t, hk, hv, d, live_share: float = 1.0,
                     iters) / CHUNK_LAYERS
 
 
-def chunk_rows(blocks, live_shares=(1.0,)):
-    for block in blocks:
-        if block:
-            kda.INVERSE_BLOCK = block  # read when ``kda_chunk`` is traced,
-            jax.clear_caches()  # which a trace kept for these shapes is not
-        for decay, b, t, hk, hv, d in CHUNK_SHAPES:
-            for share in live_shares:
-                us = _chunk_us(decay, b, t, hk, hv, d, share)
-                yield {"decay": decay, "batch": b, "tokens": t,
-                       "live_share": share, "key_heads": hk, "heads": hv,
-                       "d": d, "block": block or None,
-                       "us_per_layer": round(us, 1),
-                       "us_per_chunk": round(us / -(-t // kda.CHUNK), 2)}
+def chunk_rows(blocks, live_shares=(1.0,), forms=("xla",),
+               head_blocks=(0,)):
+    """``forms``: who runs a scalar shape's serial scan (a decay a channel
+    has the ``jnp`` loop alone); ``head_blocks``: the kernel's value heads
+    a grid step (0: the checkout's)."""
+    choice, head_block = kda.kda_chunk_choice, pallas_kda.SCAN_HEAD_BLOCK
+    settings = [(block, form, hb) for block in blocks for form in forms
+                for hb in (head_blocks if form == "kernel" else (0,))]
+    try:
+        for block, form, hb in settings:
+            # the three are read when a layer is traced (the choice
+            # whatever the backend is), and a trace kept for these shapes
+            # holds the old ones
+            if block:
+                kda.INVERSE_BLOCK = block
+            kda.kda_chunk_choice = (
+                lambda t, dk, dv, scalar, form=form:
+                form if scalar else "xla")
+            pallas_kda.SCAN_HEAD_BLOCK = hb or head_block
+            jax.clear_caches()
+            for decay, b, t, hk, hv, d in CHUNK_SHAPES:
+                if form == "kernel" and decay != "scalar":
+                    continue
+                for share in live_shares:
+                    us = _chunk_us(decay, b, t, hk, hv, d, share)
+                    yield {"decay": decay, "batch": b, "tokens": t,
+                           "live_share": share, "key_heads": hk,
+                           "heads": hv, "d": d, "block": block or None,
+                           "form": form,
+                           "head_block": (min(hv, hb or head_block)
+                                          if form == "kernel" else None),
+                           "us_per_layer": round(us, 1),
+                           "us_per_chunk": round(us / -(-t // kda.CHUNK), 2)}
+    finally:
+        kda.kda_chunk_choice = choice
+        pallas_kda.SCAN_HEAD_BLOCK = head_block
 
 
 def main() -> int:
@@ -179,13 +216,20 @@ def main() -> int:
     ap.add_argument("--live-share", default="1.0",
                     help="--chunk: the share of each row's tokens that "
                          "are true, the rest a bucket's padding")
+    ap.add_argument("--forms", default="xla,kernel",
+                    help="--chunk: who runs the serial scan")
+    ap.add_argument("--head-block", default="0",
+                    help="--chunk: the kernel's value heads a grid step "
+                         "(0: the checkout's)")
     ap.add_argument("--json-out")
     a = ap.parse_args()
     configure()
     refuse_offchip_record(a.json_out)
     out = []
     for row in (chunk_rows([int(x) for x in a.blocks.split(",")],
-                           [float(x) for x in a.live_share.split(",")])
+                           [float(x) for x in a.live_share.split(",")],
+                           a.forms.split(","),
+                           [int(x) for x in a.head_block.split(",")])
                 if a.chunk else rows()):
         print(json.dumps(row), flush=True)
         out.append(row)

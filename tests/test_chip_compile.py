@@ -96,6 +96,28 @@ def _kda_decode(layers, b, h=32, d=128, head_block=8, key_heads=None,
                  ((b, h), F32), ((layers, b, h, d, d), F32), ((), I32)])
 
 
+def _kda_chunk_scan(t, gated=True, b=1, hk=16, hv=32, d=128, c=64):
+    """The scalar-gated rule's scan kernel as ``kda_chunk`` calls it: a
+    bucket of ``t`` tokens' keys and queries and what its ``t / c`` chunks
+    made ahead, the staging row's float32 state, the live chunks (traced)
+    and, ``gated`` (as a layer calls it), the bfloat16 gate ``z`` and the
+    head norm's weight of its epilogue."""
+    from cake_tpu.ops.pallas.kda import kda_chunk_scan
+
+    n = t // c
+
+    def fn(q, k, qk, cum, u_hat, w, state, live, *gate):
+        return kda_chunk_scan(q, k, qk, cum, u_hat, w, state, live,
+                              gate=(*gate, 1e-6) if gate else None,
+                              interpret=False)
+
+    return (fn, [((n, b, hk, c, d), F32), ((n, b, hk, c, d), F32),
+                 ((n, b, hk, c, c), F32), ((n, b, hv, c), F32),
+                 ((n, b, hv, c, d), F32), ((n, b, hv, c, d), F32),
+                 ((b, hv, d, d), F32), ((), I32)]
+            + ([((b, t, hv * d), BF16), ((d,), F32)] if gated else []))
+
+
 def _ssm(layers, b, t, n=16, c=5120):
     """The state-space kernels as the layer loop calls them: the stacked
     float32 state ``[L, B, d_state, d_inner]`` and a traced layer index;
@@ -247,6 +269,11 @@ KERNELS = {
                                                 key_heads=16, scalar=True),
     "kda_decode_scalar_b1_h16_32": _kda_decode(6, 1, head_block=16,
                                                key_heads=16, scalar=True),
+    # the scan of qwen3next-ep4-cut's long buckets: B 1, 16 key heads under
+    # 32 value heads of 128
+    "kda_chunk_scan_t4096": _kda_chunk_scan(4096),
+    "kda_chunk_scan_t8192": _kda_chunk_scan(8192),
+    "kda_chunk_scan_ungated_t8192": _kda_chunk_scan(8192, gated=False),
     # Jamba2-3B's 5120 channels of a 16-wide state at the cell's 32 slots,
     # at 64, one stream, and an admission chunk of a 512- and a 16-token
     # bucket

@@ -293,7 +293,12 @@ def test_moe_sweep_rows_at_tiny_shapes(monkeypatch, kernels):
 def test_kda_sweep_chunk_rows_at_tiny_shapes(monkeypatch):
     """tools/kda_sweep.py ``--chunk`` on the CPU (no device time): a row a
     shape and share of true tokens, the chunk form entered through
-    ``_advance`` with each row's length as a layer enters it."""
+    ``_advance`` with each row's length as a layer enters it; with
+    ``--forms`` a row a form too, the kernel (interpreted) for the decay a
+    head alone and once a head block, and the choice and the block are the
+    checkout's again afterwards."""
+    from cake_tpu.ops import kda
+    from cake_tpu.ops.pallas import kda as pallas_kda
     from cake_tpu.tools import kda_sweep
 
     monkeypatch.setattr(kda_sweep, "CHUNK_SHAPES", (
@@ -303,7 +308,17 @@ def test_kda_sweep_chunk_rows_at_tiny_shapes(monkeypatch):
     assert [(r["decay"], r["tokens"], r["live_share"]) for r in out] == [
         ("scalar", 160, 1.0), ("scalar", 160, 0.4), ("channel", 96, 1.0),
         ("channel", 96, 0.4)]
-    assert all(r["us_per_layer"] > 0 and r["block"] is None for r in out)
+    assert all(r["us_per_layer"] > 0 and r["block"] is None
+               and r["form"] == "xla" and r["head_block"] is None
+               for r in out)
+    choice, block = kda.kda_chunk_choice, pallas_kda.SCAN_HEAD_BLOCK
+    out = list(kda_sweep.chunk_rows([0], [0.4], ("xla", "kernel"), (2, 4)))
+    assert [(r["decay"], r["form"], r["head_block"]) for r in out] == [
+        ("scalar", "xla", None), ("channel", "xla", None),
+        ("scalar", "kernel", 2), ("scalar", "kernel", 4)]
+    assert all(r["us_per_layer"] > 0 for r in out)
+    assert kda.kda_chunk_choice is choice
+    assert pallas_kda.SCAN_HEAD_BLOCK == block
 
 
 @pytest.mark.parametrize("form,hit,want", [

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import json
 import zlib
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -237,14 +238,25 @@ RULE_IDS = ["kda", "scalar-grouped", "scalar", "channel-grouped"]
 CHUNK_CASES = [(*rule, "random") for rule in RULES] + [
     (2, 4, True, "repeated-keys"), (2, 4, True, "padded-tail")]
 CHUNK_IDS = RULE_IDS + ["scalar-repeated-keys", "scalar-padded-tail"]
+# ... and the scan as the Pallas kernel (interpreted here, forced), which
+# is the scalar-gated rule's alone
+CHUNK_FORMS = [("xla", *case) for case in CHUNK_CASES] + [
+    ("kernel", *case) for case in CHUNK_CASES if case[2]]
+CHUNK_FORM_IDS = CHUNK_IDS + [
+    f"kernel-{name}" for name, case in zip(CHUNK_IDS, CHUNK_CASES) if case[2]]
 
 
-@pytest.mark.parametrize("hk, hv, scalar, case", CHUNK_CASES, ids=CHUNK_IDS)
-def test_chunk_form_is_the_recurrence(hk, hv, scalar, case):
+@pytest.mark.parametrize("form, hk, hv, scalar, case", CHUNK_FORMS,
+                         ids=CHUNK_FORM_IDS)
+def test_chunk_form_is_the_recurrence(form, hk, hv, scalar, case,
+                                      monkeypatch):
     """``kda_chunk`` (150 tokens: two whole chunks and a padded one)
     against ``kda_recurrence`` for both rules, with as many key heads as
-    value heads and with groups: outputs and the state it leaves. The
-    recurrence is given the key heads repeated; the chunk form groups.
+    value heads and with groups: outputs and the state it leaves, the
+    serial scan as the ``jnp`` loop and, for a decay a head, as the kernel
+    ``kda_chunk_scan`` (``Hk < Hv`` and ``Hk == Hv``; two value heads a
+    grid step, so that a block's key heads are fetched by the index maps).
+    The recurrence is given the key heads repeated; the chunk form groups.
     ``repeated-keys``: one key a head and row at every token, no decay and
     ``beta`` within 1e-3 of 1, so that ``N = beta tril(K K^T, -1)`` is all
     ones under its diagonal, its powers grow to 5e17 before they vanish,
@@ -255,6 +267,12 @@ def test_chunk_form_is_the_recurrence(hk, hv, scalar, case):
     state is the recurrence's over the true tokens alone."""
     t = 192 if case == "padded-tail" else 150
     q, k, v, g, beta, s0 = _rule_inputs(hk, hv, scalar, t=t)
+    if form == "kernel":
+        from cake_tpu.ops.pallas import kda as pallas_kda
+
+        monkeypatch.setenv("CAKE_PALLAS", "1")  # what ``_advance`` asks
+        monkeypatch.setattr(pallas_kda, "SCAN_HEAD_BLOCK", 2)
+        assert kda.kda_chunk_choice(t, 16, 8, scalar) == "kernel"
     if case == "repeated-keys":
         k = jnp.broadcast_to(k[:, :1], k.shape)
         g, beta = 0 * g, 1 - 1e-3 * beta
@@ -274,17 +292,104 @@ def test_chunk_form_is_the_recurrence(hk, hv, scalar, case):
                                        rtol=0)
         return
     o_want, s_want = kda.kda_recurrence(*wide, s0)
-    o, s = jax.jit(kda.kda_chunk)(q, k, v, g, beta, s0)
+    o, s = kda.kda_chunk(q, k, v, g, beta, s0, form=form)
     np.testing.assert_allclose(o, o_want, atol=5e-6, rtol=0)
     np.testing.assert_allclose(s, s_want, atol=5e-6, rtol=0)
+
+
+@pytest.mark.parametrize("live", [None, 2], ids=["whole", "two-chunks"])
+def test_scan_kernel_gates_its_output_as_the_layer_does(live):
+    """``kda_chunk(form="kernel", gate=)``: the kernel's epilogue,
+    ``rmsnorm_head(o) * silu(z)`` written a head's lanes of a token, is
+    ``_gated`` of the ``jnp`` loop's ``o`` (float32 ``z``: no rounding on
+    the way out), zero past the live chunks as ``o`` is, and the state
+    the same; in ``z``'s type where that is bfloat16."""
+    from cake_tpu.ops.pallas import kda as pallas_kda
+
+    q, k, v, g, beta, s0 = _rule_inputs(2, 4, True, t=152)
+    rs = np.random.default_rng(11)
+    z = jnp.asarray(rs.normal(size=(2, 152, 4 * 8)), jnp.float32)
+    norm = jnp.asarray(1 + 0.1 * rs.normal(size=8), jnp.float32)
+    live = live if live is None else jnp.int32(live)
+    o, s_want = kda.kda_chunk(q, k, v, g, beta, s0, live)
+    want = kda._gated(o, z, norm, 1e-6)
+    got, s = kda.kda_chunk(q, k, v, g, beta, s0, live, form="kernel",
+                           gate=(z, norm), eps=1e-6)
+    assert got.shape == (2, 152, 32) and got.dtype == jnp.float32
+    # (the norm divides by the rms of eight values: errors of 5e-6 in o
+    # stand over an rms of ~0.1)
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=0)
+    np.testing.assert_allclose(s, s_want, atol=5e-6, rtol=0)
+    if live is not None:
+        assert not np.asarray(got)[:, 128:].any()
+    half, _ = kda.kda_chunk(q, k, v, g, beta, s0, live, form="kernel",
+                            gate=(z.astype(jnp.bfloat16), norm), eps=1e-6)
+    assert half.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        half.astype(jnp.float32),
+        kda._gated(o, z.astype(jnp.bfloat16), norm, 1e-6).astype(
+            jnp.float32), atol=2e-2, rtol=0)
+
+
+def test_a_bfloat16_operand_in_the_scan_kernel_fails_the_tolerance(
+        monkeypatch):
+    """The control of the kernel's precision: ``kda_chunk_scan`` handed ONE
+    operand (``u_hat``) rounded to bfloat16 leaves a state and outputs that
+    miss ``kda_recurrence`` by far more than the 5e-6 the chunk forms are
+    held to (as the reference with a bfloat16 state misses ``TIGHT``)."""
+    from cake_tpu.ops.pallas import kda as pallas_kda
+
+    scan = pallas_kda.kda_chunk_scan
+
+    def rounded(q, k, qk, cum, u_hat, w, *rest, **kw):
+        return scan(q, k, qk, cum,
+                    u_hat.astype(jnp.bfloat16).astype(jnp.float32), w, *rest,
+                    **kw)
+
+    monkeypatch.setattr(pallas_kda, "kda_chunk_scan", rounded)
+    # (a length of its own: JAX keeps ``kda_chunk``'s trace by its shapes)
+    q, k, v, g, beta, s0 = _rule_inputs(2, 4, True, t=151)
+    wide = (jnp.repeat(q, 2, axis=2), jnp.repeat(k, 2, axis=2), v, g, beta)
+    o_want, s_want = kda.kda_recurrence(*wide, s0)
+    o, s = kda.kda_chunk(q, k, v, g, beta, s0, form="kernel")
+    assert np.abs(np.asarray(o - o_want)).max() > 100 * 5e-6
+    assert np.abs(np.asarray(s - s_want)).max() > 100 * 5e-6
+
+
+@pytest.mark.parametrize("on_chip, mode, t, dk, dv, scalar, want", [
+    (True, "auto", 8192, 128, 128, True, "kernel"),
+    (True, "auto", kda.KDA_SCAN_MIN_T, 128, 128, True, "kernel"),
+    (True, "auto", kda.KDA_SCAN_MIN_T - 64, 128, 128, True, "xla"),
+    (True, "auto", 512, 128, 128, False, "xla"),  # Ling: a decay a channel
+    (True, "auto", 8192, 64, 128, True, "xla"),  # no whole tile of a state
+    (True, "auto", 8192, 128, 96, True, "xla"),
+    (True, "0", 8192, 128, 128, True, "xla"),  # kernels off
+    (False, "auto", 8192, 128, 128, True, "xla"),  # off the chip
+    (False, "1", 150, 16, 8, True, "kernel"),  # ... forced: interpreted
+    (False, "1", 150, 16, 8, False, "xla"),
+], ids=["the-cell", "the-floor", "under-the-floor", "channel", "narrow-keys",
+        "narrow-values", "kernels-off", "off-the-chip", "forced",
+        "forced-channel"])
+def test_scan_kernel_is_chosen_by_the_shapes(on_chip, mode, t, dk, dv,
+                                             scalar, want, monkeypatch):
+    """``kda_chunk_choice``: the kernel for a decay a head over whole
+    ``(8, 128)`` tiles of a head's state from ``KDA_SCAN_MIN_T`` tokens
+    on, on the chip; elsewhere XLA's loop, but for forced kernels."""
+    from cake_tpu.ops import pallas as pk
+
+    monkeypatch.setattr(pk, "on_tpu", lambda: on_chip)
+    monkeypatch.setenv("CAKE_PALLAS", mode)
+    assert kda.kda_chunk_choice(t, dk, dv, scalar) == want
 
 
 @pytest.mark.parametrize("valid", [(130, 40), (0, 0), (290, 7)],
                          ids=["uneven", "no-token", "whole"])
 @pytest.mark.parametrize("ahead", ["hoisted", "in-the-scan"])
-@pytest.mark.parametrize("hk, hv, scalar", RULES[:2], ids=RULE_IDS[:2])
+@pytest.mark.parametrize("form, hk, hv, scalar", [
+    ("xla", *RULES[0]), ("xla", *RULES[1]), ("kernel", *RULES[1])],
+    ids=RULE_IDS[:2] + ["kernel-scalar-grouped"])
 def test_scan_that_stops_at_the_last_live_chunk_is_the_whole_scan(
-        hk, hv, scalar, ahead, valid, monkeypatch):
+        form, hk, hv, scalar, ahead, valid, monkeypatch):
     """``_advance`` hands ``kda_chunk`` the chunks that hold a true token
     of some row of the launch, and the serial loop runs that many of the
     300 (290) tokens' five (the last a padded one): three where the rows
@@ -293,10 +398,16 @@ def test_scan_that_stops_at_the_last_live_chunk_is_the_whole_scan(
     inputs, for both decays and both placements of what a chunk makes
     ahead of the state (every chunk at once, or a chunk at a time inside
     the loop: ``HOIST_BYTES``): the state it leaves and ``o`` on every
-    true row are EQUAL, and ``o`` past the last live chunk is zero."""
-    # (a length of its own a placement: JAX keeps a trace by its shapes,
-    # and ``HOIST_BYTES`` is read when ``kda_chunk`` is traced)
-    t = 300 if ahead == "hoisted" else 290
+    true row are EQUAL, and ``o`` past the last live chunk is zero. The
+    same of the scan as the kernel (a two-row launch's uneven rows,
+    ``live`` 0 and ``live == n``): a grid step past ``live`` does no
+    product and writes zeros; what cannot be held ahead of ONE call goes
+    into as many calls as it takes, the state carried between them."""
+    # (a length of its own a placement and form: JAX keeps a trace by its
+    # shapes, and ``HOIST_BYTES`` is read when ``kda_chunk`` is traced)
+    t = (300 if ahead == "hoisted" else 290) + (form == "kernel")
+    if form == "kernel":
+        monkeypatch.setenv("CAKE_PALLAS", "1")
     q, k, v, g, beta, s0 = _rule_inputs(hk, hv, scalar, t=t)
     lengths = jnp.asarray(valid, jnp.int32)
     true = np.arange(t)[None] < np.asarray(valid)[:, None]  # [B, T]
@@ -305,15 +416,25 @@ def test_scan_that_stops_at_the_last_live_chunk_is_the_whole_scan(
               jnp.where(mask[..., None], beta, 0))
     if ahead == "in-the-scan":
         monkeypatch.setattr(kda, "HOIST_BYTES", 0)
-    o_want, s_want = kda.kda_chunk(q, k, v, *masked, s0)
+    o_want, s_want = kda.kda_chunk(q, k, v, *masked, s0, form=form)
     o, s = kda._advance(q, k, v, g, beta, s0, lengths, None, "gdn")
-    text = str(jax.make_jaxpr(kda.kda_chunk)(
+    assert kda.chunk_form_traced(t) == form
+    text = str(jax.make_jaxpr(partial(kda.kda_chunk, form=form))(
         q, k, v, *masked, s0, jnp.int32(1)))
-    # a loop whose bound is data (and a second that zeroes ``o`` past it),
-    # the chunks' inverse called ahead of them or in the first one's body
-    assert text.count("while[") == 2
-    assert (text.index("name=_unit_lower_inverse") < text.index("while[")
-            ) == (ahead == "hoisted")
+    if form == "kernel":
+        # no loop of XLA's: one call over all five chunks, or one a chunk
+        assert "while[" not in text
+        assert text.count("pallas_call[") == (1 if ahead == "hoisted" else 5)
+        jnp_o, jnp_s = kda.kda_chunk(q, k, v, *masked, s0)
+        np.testing.assert_allclose(o_want, jnp_o, atol=5e-6, rtol=0)
+        np.testing.assert_allclose(s_want, jnp_s, atol=5e-6, rtol=0)
+    else:
+        # a loop whose bound is data (and a second that zeroes ``o`` past
+        # it), the chunks' inverse called ahead of them or in the first
+        # one's body
+        assert text.count("while[") == 2
+        assert (text.index("name=_unit_lower_inverse")
+                < text.index("while[")) == (ahead == "hoisted")
     np.testing.assert_array_equal(np.asarray(s), np.asarray(s_want))
     np.testing.assert_array_equal(np.asarray(o)[true], np.asarray(o_want)[true])
     swept = -(-max(valid) // kda.CHUNK) * kda.CHUNK
@@ -384,10 +505,13 @@ def test_decode_through_the_kernel_matches_reference(params, want,
                                                      monkeypatch):
     """With kernels forced (``CAKE_PALLAS=1``: interpreted off the chip)
     the decode steps of the layer loop go through ``kda_decode`` on the
-    carried state (the scalar case, key heads under value heads), and the
+    carried state (the scalar case, key heads under value heads), the
+    16-token prefill before them through ``kda_chunk_scan`` and its
+    epilogue (the layer's norm and gate made in the kernel), and the
     logits are still the reference's."""
     monkeypatch.setenv("CAKE_PALLAS", "1")
     assert kda.kda_decode_choice(16, 8) == "kernel"
+    assert kda.kda_chunk_choice(16, 16, 8, True) == "kernel"
     step = jax.jit(_logits, static_argnums=(1,))  # traced with kernels on
     got, _ = _through_the_cache(params, TOKENS[:24], 16, 16, step=step)
     np.testing.assert_allclose(got, want[:24], atol=TIGHT, rtol=0)

@@ -144,6 +144,43 @@ def test_a_launchs_longest_row_sets_the_chunks_of_the_scan(params):
     assert swept.value - before[0] == 108
 
 
+def test_engine_counts_the_chunks_that_ran_in_the_scan_kernel(params,
+                                                              monkeypatch):
+    """``delta.chunks_kernel`` is ``delta.chunks_swept`` over the
+    dispatches whose bucket's program holds the scan kernel and stays
+    where it was over the others. An arrival of 100 tokens is admitted in
+    chunks of 32 (a bucket no other test of this process traces) with the
+    scan chosen as the kernel for a decay a head (interpreted; the choice
+    is ``ops.kda``'s, steered here as a chip would answer it): the gauge
+    says a traced admission holds the kernel, the counter takes every
+    swept chunk, and the stream's tokens are those of the same engine
+    under XLA's loop, whose dispatches the counter leaves alone."""
+    from cake_tpu.ops import kda
+
+    reg = metrics.registry()
+    swept, inside = (reg.counter(n) for n in ("delta.chunks_swept",
+                                              "delta.chunks_kernel"))
+
+    def served(chunk):
+        bg = _engine(params, [PROMPTS[0], PROMPTS[3]], ids=[1, 2],
+                     admit_chunk=chunk)
+        before = swept.value, inside.value
+        got = _run(bg, {1: lambda e: (e.finish(1), e.enqueue(PROMPTS[4], 7))},
+                   steps=14)
+        return got[7][:8], swept.value - before[0], inside.value - before[1]
+
+    plain = served(64)
+    assert plain[1:] == (6 * 2, 0)  # two dispatches of a chunk, six layers
+    monkeypatch.setattr(
+        kda, "kda_chunk_choice",
+        lambda t, dk, dv, scalar: "kernel" if scalar and t == 32 else "xla")
+    # four dispatches of one (half) chunk each in six layers
+    assert served(32) == (plain[0], 6 * 4, 6 * 4)
+    assert kda.chunk_form_traced(32) == "kernel"
+    assert kda.chunk_form_traced(64) == "xla"
+    assert reg.gauge("delta.chunk_kernel").value == 1
+
+
 # -- the configuration, the plan, the budget, the loaders -----------------------
 
 def _catalog() -> dict:
