@@ -302,6 +302,27 @@ class LlamaConfig:
     zero_expert_type: str = "identity"
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    # --- EVA attention: an exact window and a summary of what left it
+    # (EvaByte's keys; HF `model_type` "evabyte") ------------------------------
+    # ``attention_class`` "eva": every layer is multi-head attention whose
+    # softmax runs over TWO sets of rows at once (ops/eva.py). With ``W =
+    # window_size`` and ``C = chunk_size``, a query at position ``n`` sees
+    # exactly the keys of its own window (``m // W == n // W``, ``m <= n``:
+    # the window does not slide, it RESETS every ``W`` positions) and, for
+    # every chunk of ``C`` positions of every window COMPLETED before its
+    # own, one learned summary row ``(k~, v~)``: ``v~`` the chunk's values
+    # under a softmax of ``phi_h . k_m`` (a learned vector a head), ``k~``
+    # the chunk's mean key plus a learned ``mu_h``. No layer holds a row a
+    # position: the cache is a ring of ``W`` rows and a plane of one summary
+    # row for every ``C`` positions (``cache_plan``'s ``ring`` and
+    # ``summary``). ``num_pred_heads``: the stored head holds that many
+    # blocks of ``vocab_size`` rows; the model's own next-token logits are
+    # block 0's, the only one the served path loads (the others propose
+    # further tokens for the release's self-speculative decoding).
+    attention_class: str | None = None
+    window_size: int = 0
+    chunk_size: int = 0
+    num_pred_heads: int = 1
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -314,6 +335,7 @@ class LlamaConfig:
         families.check_gated_keys(self)
         families.check_indexer(self)
         families.check_zero_experts(self)
+        families.check_attention_class(self)
         # validate at construction, not as a KeyError deep in a jit trace
         if self.hidden_act not in ("silu", "gelu_tanh"):
             raise ValueError(
@@ -353,6 +375,8 @@ class LlamaConfig:
         whole ``(16, 128)`` tiles of a bfloat16 buffer's last two axes,
         which the published windows (128, 1024) are already (``window <=
         R < window + 16``)."""
+        if self.attention_class == "eva":  # the window itself: it resets
+            return self.window_size
         return -(-self.sliding_window // 16) * 16
 
     def rotation(self, layer_type: str) -> dict | None:
@@ -378,8 +402,22 @@ class LlamaConfig:
         plan = self.cache_plan
         planes = plan.get("rows", (0,))[0]
         layers, heads, width = plan.get("index", (0, 0, 0))
-        return (planes * self.cache_row_values
-                + layers * heads * width) * self.jax_dtype.itemsize
+        values = planes * self.cache_row_values + layers * heads * width
+        if "summary" in plan:  # one row for every ``chunk`` positions
+            n, heads, chunk, k_width, v_width = plan["summary"]
+            values += n * heads * (k_width + v_width) // chunk
+        return values * self.jax_dtype.itemsize
+
+    def stream_bytes(self, capacity: int) -> int:
+        """Bytes one stream's whole reservation holds at ``capacity``
+        positions, in the serving type: what grows with the capacity
+        (``cache_token_bytes`` a position) and a ring's rows, which do
+        not (a staging row of an admission is this large)."""
+        ring = self.cache_plan.get("ring")
+        fixed = 0 if ring is None else (
+            ring[0] * ring[1] * ring[2] * (ring[3] + ring[4])
+            * self.jax_dtype.itemsize)
+        return self.cache_token_bytes * capacity + fixed
 
     @property
     def resid_token_bytes(self) -> int:
@@ -401,7 +439,9 @@ class LlamaConfig:
         "conv" (a gated short convolution, where ``layer_types`` says so),
         or "mla2" (a shortcut-connected double layer: two latent attentions,
         two dense feed-forwards and the expert block between them, where the
-        family's layers are such), the feed-forward "dense" or "moe".
+        family's layers are such), or "eva" (attention over a window that
+        resets and the summaries of the windows before it, where
+        ``attention_class`` says so), the feed-forward "dense" or "moe".
         THE place the layer order comes from (models/llama.py
         ``layer_plan`` groups it into scanned segments, the cache and the
         loaders count it)."""
@@ -417,6 +457,8 @@ class LlamaConfig:
             if self.attn_layer_period:
                 return ("gqa" if i % self.attn_layer_period
                         == self.attn_layer_offset else "mamba")
+            if self.attention_class == "eva":
+                return "eva"
             if not self.kv_lora_rank:
                 return "gqa"
             if self.family.planes_a_layer == 2:
@@ -482,12 +524,25 @@ class LlamaConfig:
         learned sparse attention
         (``index_topk`` > 0) every latent layer keeps ``index`` ``(layers,
         1, index_head_dim)`` beside its rows: the indexer's one key a
-        token, normed and rotated, in the serving type."""
+        token, normed and rotated, in the serving type. EVA layers
+        (``attention_class``) keep NO ``rows``: a ``ring`` of ``window_size``
+        rows whose live rows are those of the query's own window (row ``p %
+        W``; the window resets, it does not slide) and ``summary``
+        ``(layers, heads, chunk_size, k_width, v_width)``: ONE row for every
+        ``chunk_size`` positions, ``capacity // chunk_size`` rows a stream
+        (the plan names the positions a row stands for; the capacity is the
+        allocation's: ``ops.kvcache.init_cache``)."""
         mixers = [m for m, _ in self.layer_kinds]
         recurrent = self.family.recurrent_mixer
         held = mixers.count(recurrent)
         ring = mixers.count("swa")
         plan = {}
+        eva = mixers.count("eva")
+        if eva:
+            heads, *widths = self.cache_row
+            plan["ring"] = (eva, heads, self.ring_rows, *widths)
+            plan["summary"] = (eva, heads, self.chunk_size, *widths)
+            return plan
         if len(mixers) - held - ring:
             # a looped model keeps a plane a layer AND a pass, a double
             # layer one for each of its two attentions
@@ -1080,6 +1135,36 @@ def ouro_2_6b(**overrides) -> LlamaConfig:
     return LlamaConfig(**base)
 
 
+def evabyte_6p5b(**overrides) -> LlamaConfig:
+    """EvaByte 6.5B (https://huggingface.co/EvaByte/EvaByte, `model_type`
+    "evabyte") at its published sizes: 32 pre-norm layers of EVA attention
+    (32 heads of 128, an exact window of 2048 positions that resets, one
+    summary row for every 16 positions of the windows before it) and an
+    11008-wide SwiGLU over a byte vocabulary of 320 (256 bytes + 64
+    special ids) with eight prediction heads, of which head 0 is the
+    model's own next byte; norm weights stored as ``w - 1``."""
+    base = dict(
+        model_type="evabyte",
+        attention_class="eva",
+        vocab_size=320,
+        hidden_size=4096,
+        intermediate_size=11008,
+        num_hidden_layers=32,
+        num_attention_heads=32,
+        num_key_value_heads=32,
+        rms_norm_eps=1e-5,
+        rope_theta=100000.0,
+        max_seq_len=32768,
+        window_size=2048,
+        chunk_size=16,
+        num_pred_heads=8,
+        bos_token_id=1,
+        eos_token_id=2,
+    )
+    base.update(overrides)
+    return LlamaConfig(**base)
+
+
 def _repeated(pattern, layers: int) -> tuple[str, ...]:
     """``layer_types`` for ``layers`` layers from one period of the
     pattern (or from the whole list: it then is, or is cut to, them)."""
@@ -1604,6 +1689,27 @@ def tiny_ouro(**overrides) -> LlamaConfig:
         total_ut_steps=3,
         rms_norm_eps=1e-6,
         rope_theta=1000000.0,
+    )
+    base.update(overrides)
+    return tiny(**base)
+
+
+def tiny_evabyte(**overrides) -> LlamaConfig:
+    """Tiny fixture of the EVA family (EvaByte's keys): three layers of
+    four heads over a window of 32 positions in chunks of 4 (eight summary
+    rows a completed window), two prediction heads, 128 positions: a
+    prompt and an answer cross chunk ends and a window reset within a
+    few dozen tokens."""
+    base = dict(
+        model_type="evabyte",
+        attention_class="eva",
+        num_hidden_layers=3,
+        num_key_value_heads=4,
+        window_size=32,
+        chunk_size=4,
+        num_pred_heads=2,
+        rms_norm_eps=1e-5,
+        rope_theta=100000.0,
     )
     base.update(overrides)
     return tiny(**base)

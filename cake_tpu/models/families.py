@@ -30,6 +30,14 @@ outputs behind its experts, by softmax over all of them; the two
 ``mla_scale_*`` factors folded into the latents' norm weights where the
 tensors are read: ``Fold``; limits: identity zero experts only, slot
 layout, ``ep`` alone, no quantized tier),
+``EVA`` (``evabyte``: multi-head attention over an exact window that
+RESETS every ``window_size`` positions and one learned summary row for
+every ``chunk_size`` positions of the windows completed before it, one
+softmax over both, ops/eva.py; no layer holds a row a position: the cache
+is a ring and a plane of summaries, ``cache_plan``'s ``summary``; norm
+weights stored as ``w - 1``, a head of ``num_pred_heads`` blocks of which
+block 0 is loaded; limits: as many key/value heads as query heads, slot
+layout, no sharding, no quantized tier, whole-prompt admission),
 ``HYBRID`` (``bailing_hybrid``), ``LATENT`` (``deepseek_v3``, ``axk1``,
 ``xing4_0``, ``glm_moe_dsa``) and ``GQA``, the bare stack every other
 ``model_type`` is read as. A residual stream several hidden vectors wide (``hc_mult`` > 1:
@@ -468,11 +476,29 @@ def _check_layer_types(c, mixers) -> set:
             f"layer_types needs one of {sorted(mixers)} for "
             f"each of the {c.num_hidden_layers} layers, got "
             f"{len(c.layer_types)} entries of {sorted(kinds)}")
-    if "full_attention" not in kinds:
+    check_capacity(c)
+    return kinds
+
+
+# the kinds of ``LlamaConfig.cache_plan`` whose buffers grow with the
+# capacity: a row a position, or a summary row for every few
+_CAPACITY_KINDS = ("rows", "summary")
+
+
+def check_capacity(c):
+    """Whether the cache the fields describe can say how many positions
+    it holds: asked of ``cache_plan``, which answers with ``rows`` (a row
+    a position of the full layers) or ``summary`` (a row for every
+    ``chunk_size`` positions). A model of window, state or tail layers
+    alone has neither: nothing here allocates or retires by a capacity no
+    buffer has."""
+    plan = c.cache_plan
+    if not set(plan) & set(_CAPACITY_KINDS):
         raise ValueError(
             "layer_types without a full_attention layer is not wired "
-            "(the cache's capacity is the full layers')")
-    return kinds
+            f"(the cache would hold {sorted(plan)} and nothing that grows "
+            "with the capacity: the capacity is asked of cache_plan's "
+            f"{' or '.join(_CAPACITY_KINDS)})")
 
 
 def _expert_share(d: dict, held: int) -> dict:
@@ -1607,11 +1633,136 @@ LOOPED = Family(
     loops=True)
 
 
+# --- EVA attention: an exact window and the summaries of those before it
+# (EvaByte's keys) -------------------------------------------------------------
+
+# a head's learned vector stored ``[1, heads, 1, 1, head_dim]``, held flat
+_PER_HEAD = Fold(
+    lambda c, w: w.reshape(-1),
+    lambda c, w: w.reshape(1, c.num_attention_heads, 1, 1, c.head_dim))
+
+# Llama's names, every norm stored as ``w - 1`` (``norm_add_unit_offset``),
+# and the two learned vectors a head (the names are ASSUMED: the benchmark
+# configuration lists them)
+_EVA_MAP = {
+    **{k: _LAYER_MAP[k] for k in ("wq", "wk", "wv", "wo", "w_gate", "w_up",
+                                  "w_down")},
+    "attn_norm": (*_LAYER_MAP["attn_norm"], _ONE_PLUS),
+    "mlp_norm": (*_LAYER_MAP["mlp_norm"], _ONE_PLUS),
+    "eva_phi": ("self_attn.adaptive_phi", False, _PER_HEAD),
+    "eva_mu": ("self_attn.adaptive_mu_k", False, _PER_HEAD),
+}
+
+# keys of an "evabyte" config.json that only one value of is computed
+_EVA_FIXED = {"attention_class": "eva", "num_chunks": None,
+              "rope_scaling": None, "attention_bias": False,
+              "norm_add_unit_offset": True, "tie_word_embeddings": False}
+
+
+def _eva_read(d: dict) -> dict:
+    """`LlamaConfig` fields from an "evabyte" config.json
+    (``attention_class``, ``window_size``, ``chunk_size``,
+    ``num_pred_heads`` are fields as they stand). Refused, not guessed:
+    another ``attention_class``, a ``num_chunks`` (a fixed number of
+    summaries in place of a chunk length), a rope scaling, a projection
+    bias, norms stored plainly, a tied head, fewer key/value heads than
+    query heads (``_eva_check``, as for a preset). Read and computed as
+    the serving type computes everything else, a departure the benchmark
+    configuration's ``assumed`` names: ``fp32_skip_add`` (the published
+    residual additions are float32; the program's residual stream is the
+    serving type's), ``fp32_ln`` and ``mixedp_attn`` (norm and softmax
+    statistics are float32 here in any case), ``fp32_logits`` (the head's
+    product is the serving type's with float32 accumulation, returned as
+    float32)."""
+    _only_served(EVA.model_types[0], d, _EVA_FIXED)
+    return {}
+
+
+def _eva_write(c, d: dict):
+    d.update(num_chunks=None, rope_scaling=None, norm_add_unit_offset=True,
+             fp32_logits=True, hidden_act="silu",
+             max_position_embeddings=c.max_seq_len)
+
+
+def _eva_check(c):
+    w, chunk = c.window_size, c.chunk_size
+    if chunk < 1 or w < chunk or w % chunk:
+        raise ValueError(
+            f"attention_class 'eva' needs a window_size that is a whole "
+            f"number of chunks of chunk_size >= 1, got window_size {w} and "
+            f"chunk_size {chunk}")
+    if c.num_key_value_heads != c.num_attention_heads:
+        raise ValueError(
+            "attention_class 'eva' is wired for as many key/value heads as "
+            f"query heads (a learned phi and mu a head), got "
+            f"{c.num_key_value_heads} under {c.num_attention_heads}: "
+            "grouped-query heads beside EVA are not wired")
+    if c.num_pred_heads < 1:
+        raise ValueError(
+            f"num_pred_heads = {c.num_pred_heads}: the head holds one or "
+            "more blocks of vocab_size rows")
+    if (c.kv_lora_rank or c.attn_layer_period or c.layer_types is not None
+            or c.num_local_experts or c.n_routed_experts or c.attention_bias
+            or c.sliding_window is not None or c.rope_scaling
+            or c.tie_word_embeddings or c.total_ut_steps > 1):
+        raise ValueError(
+            "attention_class 'eva' is wired with a dense feed-forward and "
+            "the default rotation only: no latent keys, no state-space "
+            "layers, no layer_types, no experts, no projection bias, no "
+            "sliding_window (its window resets: window_size), no "
+            "rope_scaling, no tied head, no loop of passes")
+    check_capacity(c)
+
+
+def check_attention_class(c):
+    """EVA's keys on a configuration of another family, or a class of
+    attention nothing here computes."""
+    if c.attention_class not in (None, "eva"):
+        raise ValueError(
+            f"attention_class = {c.attention_class!r} is not wired (only "
+            "'eva': an exact window and the summaries of those before it)")
+    if c.attention_class == "eva" and c.family is not EVA:
+        raise ValueError(
+            "attention_class 'eva' beside the keys of "
+            f"{c.family.what or 'another family'} is not wired")
+    if c.attention_class is None and (
+            c.window_size or c.chunk_size or c.num_pred_heads != 1):
+        raise ValueError(
+            "window_size, chunk_size and num_pred_heads are the keys of "
+            "attention_class 'eva' (model_type "
+            f"{EVA.model_types[0]!r}); this configuration names no "
+            "attention_class")
+
+
+_NO_ROW_A_POSITION = (
+    "a ring that resets and a plane of one summary row for every "
+    "chunk_size positions, and no row a position")
+
+EVA = Family(
+    model_types=("evabyte",),
+    selects=lambda c: c.attention_class == "eva",
+    fields=("attention_class", "window_size", "chunk_size",
+            "num_pred_heads"),
+    read=_eva_read, write=_eva_write, check=_eva_check,
+    tensor_names=_EVA_MAP, final_norm_fold=_ONE_PLUS,
+    probe=".self_attn.adaptive_phi",
+    what="a model of EVA attention layers", shard_axes=frozenset(),
+    shard_why=("its cache is " + _NO_ROW_A_POSITION + ": the summaries "
+               "under tp, sp or stages are not wired"),
+    linear_tiers=(),
+    linear_why=("no comparison with the reference has been made over "
+                "int8 linears ahead of a learned summary"),
+    cache_tiers=(),
+    cache_why=("an int8 cache is not wired for EVA attention (its cache "
+               "is " + _NO_ROW_A_POSITION + "; an int8 window or summary "
+               "has not been compared with the reference)"))
+
+
 # The first record that selects a configuration is its family: the
 # families that read `layer_types` before the ones a single key names, the
 # bare stack last.
 FAMILIES = (LOOPED, SHORT_CONV, GATED_DELTA, WINDOWED, STATE_SPACE, SHORTCUT,
-            HYBRID, LATENT, GQA)
+            HYBRID, LATENT, EVA, GQA)
 # every field some family's config.json alone carries
 FIELDS = frozenset(f for family in FAMILIES for f in family.fields)
 # the record that reads a config.json, by its `model_type` (the bare

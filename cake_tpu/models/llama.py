@@ -37,6 +37,7 @@ from cake_tpu.ops.attention import (
     self_attention_block,
     window_attention_block,
 )
+from cake_tpu.ops.eva import eva_attention_block
 from cake_tpu.ops.kda import gdn_attention_block, kda_attention_block
 from cake_tpu.ops.kvcache import KVCache
 from cake_tpu.ops.mamba import mamba_mixer_block
@@ -233,7 +234,8 @@ class Segment(NamedTuple):
     layers and ``cache_stride`` cached ones on."""
 
     name: str
-    # "mla" | "mla2" | "kda" | "gdn" | "gqa" | "swa" | "mamba" | "conv"
+    # "mla" | "mla2" | "kda" | "gdn" | "gqa" | "swa" | "mamba" | "conv" |
+    # "eva"
     mixer: str
     ffn: str  # "dense" | "moe"
     first: int
@@ -339,7 +341,7 @@ def layer_plan(config: LlamaConfig) -> tuple[Run, ...]:
     names: dict[str, int] = {}
     # layers counted so far in the cache buffers of each mixer's kind
     cached = {"mla": 0, "mla2": 0, "kda": 0, "gdn": 0, "gqa": 0, "swa": 0,
-              "mamba": 0, "conv": 0}
+              "mamba": 0, "conv": 0, "eva": 0}
 
     def segment(kind, first, count, cache_stride=0):
         mixer, ffn = kind
@@ -404,6 +406,12 @@ def segment_shapes(config: LlamaConfig, seg: Segment) -> dict:
                 *_LATENT_SHAPES.items(),
                 *((k, _LAYER_SHAPES[k]) for k in ("w_gate", "w_up",
                                                   "w_down")))}
+    elif seg.mixer == "eva":
+        # multi-head attention's tensors and two learned vectors a head
+        shapes = {k: _LAYER_SHAPES[k] for k in (
+            "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm")}
+        shapes["eva_phi"] = shapes["eva_mu"] = lambda c: (
+            c.num_attention_heads * c.head_dim,)
     elif seg.mixer in ("gqa", "swa"):
         shapes = {k: _LAYER_SHAPES[k] for k in (
             "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm")}
@@ -519,6 +527,8 @@ def init_params(config: LlamaConfig, key: jax.Array, dtype=None) -> Params:
                 flat.update(_mamba_init(config, flat, k, dt))
             if seg.mixer == "gdn":
                 flat.update(_gdn_init(flat, k, dt))
+            if seg.mixer == "eva":
+                flat.update(_eva_init(flat, k, dt))
             if config.hc_mult > 1:
                 flat.update(_hc_init(config, flat, k))
             lead = run.layer_ids(seg).shape
@@ -567,6 +577,18 @@ def _hc_init(config: LlamaConfig, stack: dict, key) -> dict:
         out[f"hc_{part}_scale"] = jnp.ones(
             stack[f"hc_{part}_scale"].shape, jnp.float32)
     return out
+
+
+def _eva_init(stack: dict, key, dt) -> dict:
+    """An EVA layer's two learned vectors a head, seeded so that the
+    mechanism works: ``phi`` of std 2 (a chunk's ``head_dim^-0.5 phi . k``
+    over normed keys is then a logit of std ~2: the summary's softmax
+    leans on a few of its positions) and ``mu`` of std 0.5 (a summary's
+    key stands apart from its chunk's mean)."""
+    k_phi, k_mu = jax.random.split(jax.random.fold_in(key, 7))
+    shape = stack["eva_phi"].shape
+    return {"eva_phi": (2.0 * jax.random.normal(k_phi, shape)).astype(dt),
+            "eva_mu": (0.5 * jax.random.normal(k_mu, shape)).astype(dt)}
 
 
 def _gdn_init(stack: dict, key, dt) -> dict:
@@ -1053,6 +1075,23 @@ def _typed_block(layer, x, cache, mixer, cos, sin, pos, config, valid,
     return x, cache, local
 
 
+def _eva_block(layer, x, cache, cos, sin, pos, config, valid, layer_idx):
+    """One EVA layer over the carried cache's ring and summary plane
+    (ops/eva.py), its dense feed-forward included; the norms' ``1 + w`` is
+    folded where the tensors are read. Returns ``(x, cache,
+    ExpertCount)``."""
+    h = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
+    with jax.named_scope("attn.eva"):
+        out, ring_k, ring_v, sum_k, sum_v = eva_attention_block(
+            h, layer, cache, cos, sin, pos, config.num_attention_heads,
+            config.window_size, config.chunk_size, layer_idx, valid=valid)
+    cache = dataclasses.replace(cache, ring_k=ring_k, ring_v=ring_v,
+                                sum_k=sum_k, sum_v=sum_v)
+    x, local = _shared_feed_forward(layer, x + out, config, None, None,
+                                    False, None)
+    return x, cache, local
+
+
 def _mamba_block(layer, x, cache, config, valid, layer_idx):
     """One state-space layer over the carried cache's recurrent buffers,
     its dense feed-forward included. Returns ``(x, cache, ExpertCount)``."""
@@ -1190,6 +1229,9 @@ def forward_layers(
                                    ep_size, i, count_local, j)
         elif "w_in" in layer:
             h, c, now = _mamba_block(layer, h, c, config, valid, i)
+        elif "eva_phi" in layer:
+            h, c, now = _eva_block(layer, h, c, cos, sin, pos, config,
+                                   valid, i)
         elif "s0_wkv_a" in layer:  # a double layer: planes 2 i and 2 i + 1
             h, c, now = _double_block(
                 layer, h, c, cos, sin, pos, config, write_gate, ep_axis,
@@ -1324,7 +1366,8 @@ def true_rows(config: LlamaConfig, shape: tuple[int, int], last_index):
     for a model with expert layers, whatever its cache holds (None
     otherwise): a frontier hides a padding row from attention, and an
     expert block would route and compute it all the same."""
-    # (an index key lies behind its stream's frontier, as a row does)
+    # (an index key lies behind its stream's frontier, as a row does; a
+    # summary row does not: a bucket's padding may not enter one)
     stateful = bool(set(config.cache_plan) - {"rows", "index"})
     sparse = any(ffn == "moe" for _, ffn in config.layer_kinds)
     if not (stateful or sparse):  # such a program is told no length

@@ -292,6 +292,61 @@ SERIES: dict[str, tuple[str, str]] = {
                  "a step's softmax runs over (the gather fetches these "
                  "alone; the sweep fetches dsa.rows_read and masks the "
                  "rest), where a full sweep would attend dsa.rows_live"),
+    # -- EVA attention: a window that resets and the summaries of those
+    #    before it (ops/eva.py, ops/pallas/eva.py, the engine; named scopes
+    #    eva_decode, eva_summarise, eva_prefill, attn.eva) ------------------
+    "attn.eva_decode_calls": (
+        COUNTER, "(layer, decode step) calls of EVA attention's single-token "
+                 "form (the trace's operation eva_decode where the kernel "
+                 "runs): EVA layers x steps, what attn.eva_window_rows_live, "
+                 "attn.eva_summary_rows_visible and attn.eva_rows_read are "
+                 "means over"),
+    "attn.eva_decode_kernel": (
+        GAUGE, "what the last EVA decode program traced chose "
+               "(ops.eva.eva_decode_choice, from the buffers' shapes): 1 the "
+               "kernel that reads the ring and the summary plane each to "
+               "its own frontier, 0 two masked products over both buffers "
+               "whole, merged by their statistics; absent where no program "
+               "attends through it"),
+    "attn.eva_rows_read": (
+        COUNTER, "rows (ring and summary together) a decode step's EVA "
+                 "attention fetches, over every slot, step and layer, from "
+                 "the positions as dispatched: under the kernel the whole "
+                 "blocks to each frontier (ops.pallas.eva.eva_block_counts), "
+                 "else both buffers whole"),
+    "attn.eva_summary_rows_visible": (
+        COUNTER, "summary rows a decode step's query attends: over every "
+                 "slot, step and EVA layer, (position // window_size) x "
+                 "(window_size // chunk_size): every chunk of every window "
+                 "completed before the query's own (a slot without a live "
+                 "stream goes out at row 0: none)"),
+    "attn.eva_window_rows_live": (
+        COUNTER, "ring rows a decode step's query attends: over every slot, "
+                 "step and EVA layer, position % window_size + 1: the rows "
+                 "of the query's own window at or before it (1 right after "
+                 "a reset)"),
+    "cache.eva_summary_rows": (
+        GAUGE, "summary rows a stream holds a layer under EVA attention: "
+               "capacity // chunk_size (one row for every chunk_size "
+               "positions); absent for every other model"),
+    "cache.eva_window_rows": (
+        GAUGE, "rows of an EVA layer's ring a stream: window_size, whatever "
+               "the capacity; absent for every other model"),
+    "eva.chunks_summarised.admit": (
+        COUNTER, "chunks an admission dispatch summarises: EVA layers x the "
+                 "launch's rows x bucket // chunk_size (every chunk of the "
+                 "bucket: a bucket's padding takes no part in a summary but "
+                 "its chunks are computed)"),
+    "eva.chunks_summarised.step": (
+        COUNTER, "chunks the decode steps summarise: EVA layers x slots x "
+                 "steps (each step refreshes the current chunk's row from "
+                 "the ring: no branch in the layer body)"),
+    "eva.window_resets": (
+        COUNTER, "windows that reset under a decode step: over every slot, "
+                 "step and EVA layer, the steps whose position is a "
+                 "multiple of window_size (their query sees ONE ring row "
+                 "and window_size // chunk_size more summaries than the "
+                 "step before)"),
     "delta.chunks_swept": (
         COUNTER, "chunks of ops.kda.CHUNK tokens that the delta-rule "
                  "layers' admission scans ran through: delta-rule layers x "

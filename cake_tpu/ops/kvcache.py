@@ -73,7 +73,7 @@ def quant_kv(x: jax.Array) -> QuantizedKV:
 
 @partial(jax.tree_util.register_dataclass,
          data_fields=["k", "v", "state", "conv", "ring_k", "ring_v",
-                      "index"],
+                      "index", "sum_k", "sum_v"],
          meta_fields=[])
 @dataclasses.dataclass
 class KVCache:
@@ -116,7 +116,16 @@ class KVCache:
     lane tiles, and an empty ``v`` (``LlamaConfig.cache_row``: a step
     gathers its chosen rows, and a gather costs a row whatever its width);
     a row past a stream's frontier is an earlier stream's or a bucket's
-    padding and is never scored. Every
+    padding and is never scored. Under EVA attention (a ``summary``
+    in ``cache_plan``, ops/eva.py) NO layer keeps a row a position:
+    ``k``/``v`` have no layer (``[0, B, KH, S, D]``: the capacity ``S`` is
+    still theirs to say, ``max_seq``), ``ring_k``/``ring_v [L, B, KH, W,
+    D]`` hold the rows of the stream's CURRENT window (position ``p`` at
+    row ``p % W``; the window resets, so the live rows are ``0 .. p % W``)
+    and ``sum_k``/``sum_v [L, B, KH, S // C, D]`` one summary row for every
+    ``C`` positions, chunk ``c`` at row ``c``, written when the chunk is
+    (:func:`cake_tpu.ops.eva.eva_attention_block`) and read once its
+    window is complete. Every
     buffer is ``[layers of its kind, batch, ...]``, so a slot's whole
     state is index ``b`` of axis 1 of every leaf.
     """
@@ -128,6 +137,8 @@ class KVCache:
     ring_k: jax.Array | None = None
     ring_v: jax.Array | None = None
     index: jax.Array | None = None
+    sum_k: jax.Array | None = None
+    sum_v: jax.Array | None = None
 
     @property
     def num_layers(self) -> int:
@@ -183,9 +194,9 @@ def init_cache(
     if num_layers is not None and (set(plan) - {"rows"}
                                    or config.family.loops):
         raise ValueError("a model that holds a recurrent state, a "
-                         "convolution's tail, a ring of rows, an index key "
-                         "or a plane a pass is cached whole (no layer "
-                         "ranges)")
+                         "convolution's tail, a ring of rows, an index key, "
+                         "a summary row or a plane a pass is cached whole "
+                         "(no layer ranges)")
     if "conv" in plan:  # layers that carry a tail, and a state or none
         if "state" in plan:
             n, *shape = plan["state"]
@@ -199,6 +210,16 @@ def init_cache(
     if "index" in plan:  # a sparse attention's key a token, beside the rows
         n, heads_i, width = plan["index"]
         rec["index"] = jnp.zeros((n, batch, heads_i, S, width), dt)
+    if "summary" in plan:  # one row for every ``chunk`` positions
+        n, kvh, chunk, kw, vw = plan["summary"]
+        if S % chunk or S % plan["ring"][2]:
+            raise ValueError(
+                f"a capacity of {S} positions is not a whole number of "
+                f"windows of {plan['ring'][2]} (chunks of {chunk}): a "
+                "summary row stands for a whole chunk and a window "
+                "becomes visible whole")
+        rec["sum_k"] = jnp.zeros((n, batch, kvh, S // chunk, kw), dt)
+        rec["sum_v"] = jnp.zeros((n, batch, kvh, S // chunk, vw), dt)
     if quant == "int8":
         def half(width):
             shape = (L, batch, heads, S, width)

@@ -226,7 +226,8 @@ def cache_specs(kv_quant: str | None = None, batch_replicated: bool = False,
     d_state, d_inner]``); ``conv``: a convolution's tail ``[L, B, taps - 1,
     C]`` (beside a state, or alone: a gated short convolution's); ``ring``:
     the window layers' rings ``[L, B, KH, R, D]``; ``index``: a sparse
-    attention's index keys ``[L, B, 1, S, D]``. Each has its batch over
+    attention's index keys ``[L, B, 1, S, D]``; ``summary``: EVA
+    attention's summary rows ``[L, B, KH, S // C, D]``. Each has its batch over
     dp and no later axis sharded (such a model runs as one stage with
     tp = 1)."""
     from cake_tpu.ops.kvcache import KVCache, QuantizedKV
@@ -238,7 +239,8 @@ def cache_specs(kv_quant: str | None = None, batch_replicated: bool = False,
         return KVCache(k=half, v=half)
     # what a stream holds whatever its length: the buffers of each kind
     buffers = {"state": ("state",), "conv": ("conv",),
-               "ring": ("ring_k", "ring_v"), "index": ("index",)}
+               "ring": ("ring_k", "ring_v"), "index": ("index",),
+               "summary": ("sum_k", "sum_v")}
     names = [n for kind, of in buffers.items() if kind in held for n in of]
     return KVCache(k=spec, v=spec, **dict.fromkeys(names, P(STAGE, bd)))
 
@@ -259,7 +261,8 @@ def shard_cache(cache, mesh: Mesh):
         held=[kind for kind, buf in (("state", cache.state),
                                      ("conv", cache.conv),
                                      ("ring", cache.ring_k),
-                                     ("index", cache.index))
+                                     ("index", cache.index),
+                                     ("summary", cache.sum_k))
               if buf is not None])
     return jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), cache, specs

@@ -110,7 +110,7 @@ from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.obs import prof as obs_prof
 from cake_tpu.obs.trace import span
 from cake_tpu.ops import pallas as pk
-from cake_tpu.ops import dsa, quant, sampling
+from cake_tpu.ops import dsa, eva, quant, sampling
 from cake_tpu.ops.kda import CHUNK
 from cake_tpu.ops.kda import chunk_form_traced as delta_form_traced
 from cake_tpu.ops.mla import latent_admit_choice
@@ -255,7 +255,8 @@ _DELTA_CHUNKS_KERNEL = obs_metrics.counter("delta.chunks_kernel")
 _SPEC_NGRAM = 3  # the longest n-gram a batched proposal is looked up by
 # what a cache may hold beside rows (cache_plan's keys), as a refusal says it
 _HELD = {"state": "recurrent state", "conv": "convolution's tail",
-         "ring": "ring row", "index": "sparse attention's index key"}
+         "ring": "ring row", "index": "sparse attention's index key",
+         "summary": "summary row"}
 # The order of work at a block boundary (BatchGenerator._close_boundary):
 # host time from a block's fetch returning to the return of the step()
 # call that enqueued the device's next program, once per landed block,
@@ -311,6 +312,22 @@ _KV_BLOCKS_READ = obs_metrics.counter("attn.kv_blocks_read")
 _RING_ROWS_LIVE = obs_metrics.counter("attn.ring_rows_live")
 _RING_ROWS_SWEPT = obs_metrics.counter("attn.ring_rows_swept")
 _KV_BLOCKS_RESERVED = obs_metrics.counter("attn.kv_blocks_reserved")
+# EVA attention (ops/eva.py), from the positions as dispatched, a layer a
+# step: the ring rows of a stream's own window (0 .. p % W) and the summary
+# rows it sees (the windows completed before: (p // W) (W // C)), what the
+# step's attention FETCHES of both (the kernel's blocks to each frontier,
+# or both buffers whole), how many (layer, step) calls that was, the
+# windows that reset; and the chunks summarised, a step's (one a stream and
+# layer: the current chunk's row refreshed) and an admission's (every chunk
+# of the bucket) apart
+_EVA_WINDOW_ROWS_LIVE = obs_metrics.counter("attn.eva_window_rows_live")
+_EVA_SUMMARY_ROWS_VISIBLE = obs_metrics.counter(
+    "attn.eva_summary_rows_visible")
+_EVA_ROWS_READ = obs_metrics.counter("attn.eva_rows_read")
+_EVA_DECODE_CALLS = obs_metrics.counter("attn.eva_decode_calls")
+_EVA_WINDOW_RESETS = obs_metrics.counter("eva.window_resets")
+_EVA_CHUNKS_STEP = obs_metrics.counter("eva.chunks_summarised.step")
+_EVA_CHUNKS_ADMIT = obs_metrics.counter("eva.chunks_summarised.admit")
 # A learned sparse attention (ops/dsa.py), from the positions as
 # dispatched: the rows a decode step's indexer scores (each stream's, to
 # its frontier), those it attends (at most index_topk of them) and those
@@ -621,12 +638,25 @@ class BatchGenerator:
         self._rings = (
             (config.cache_plan["ring"][0], config.ring_rows,
              config.sliding_window)
-            if "ring" in config.cache_plan else None)
+            if "ring" in config.cache_plan
+            and "summary" not in config.cache_plan else None)
+        # EVA layers, if any: (layers, window W, chunk C); their ring
+        # resets, and what a step reads of it and of the summary plane is
+        # counted apart (_count_kv_blocks)
+        self._eva = (
+            (config.cache_plan["summary"][0], config.window_size,
+             config.chunk_size)
+            if "summary" in config.cache_plan else None)
+        if self._eva and self.max_seq % config.window_size:
+            raise ValueError(
+                f"max_seq {self.max_seq} is not a whole number of windows "
+                f"of {config.window_size}: EVA attention's summaries become "
+                "visible a window at a time")
         # how many staging rows (a stream's whole reservation each) may
         # live at once beside the cache: a launch of several rows, and a
         # launch ahead of the landing before it (GROUP_STAGING_BYTES)
         self._staging_rows_fit = GROUP_STAGING_BYTES // max(
-            1, config.cache_token_bytes * self.max_seq)
+            1, config.stream_bytes(self.max_seq))
         self._page_size = int(kv_page_size)
         self._pool_pages_req = kv_pool_pages
         if self._paged:
@@ -859,6 +889,12 @@ class BatchGenerator:
                 f"max_seq {self.max_seq} (a chunk round-up past the window "
                 "would clamp-overwrite committed KV)"
             )
+        if admit_chunk is not None and self._eva:
+            raise ValueError(
+                "admit_chunk is not wired for EVA attention: a chunk that "
+                "has history behind it would attend the cached ring and "
+                "summaries beside its own, which no program here computes; "
+                "such a prompt is admitted a whole bucket at a time")
         if admit_chunk is not None and "index" in config.cache_plan:
             raise ValueError(
                 "admit_chunk is not wired for a model under a learned "
@@ -1476,11 +1512,22 @@ class BatchGenerator:
         # (a sparse attention's index keys lie beside the rows: counted in
         # cache.token_bytes and in cache.index_row_bytes, not here)
         index = 0 if self.cache.index is None else self.cache.index.nbytes
+        summaries = sum(x.nbytes for x in jax.tree.leaves(
+            (self.cache.sum_k, self.cache.sum_v)))
+        # (a model none of whose layers holds a row a position has no
+        # layer of rows: 0)
         obs_metrics.gauge("cache.row_bytes").set(
-            (held - state - rings - index) / (self.cache.num_layers
-                                      * self.cache.batch
-                                      * self.cache.max_seq))
-        if rings:
+            (held - state - rings - index - summaries)
+            / max(1, self.cache.num_layers * self.cache.batch
+                  * self.cache.max_seq))
+        if summaries:
+            # EVA layers: a window's rows and the summary rows a stream
+            # holds, a layer
+            obs_metrics.gauge("cache.eva_window_rows").set(
+                self.cache.ring_k.shape[3])
+            obs_metrics.gauge("cache.eva_summary_rows").set(
+                self.cache.sum_k.shape[3])
+        elif rings:
             # window layers beside full ones: what the row buffers of both
             # kinds hold, and what they would were every window layer a
             # full one at the capacity
@@ -2602,6 +2649,9 @@ class BatchGenerator:
                     chunk, [min(len(m.ids), chunk) for m in st["rows"]])
             self._count_delta_chunks(
                 chunk, [len(m.ids) - base - pos for m in st["rows"]])
+            if self._eva:  # every chunk of the bucket, a row and layer
+                _EVA_CHUNKS_ADMIT.inc(
+                    self._eva[0] * rows * (chunk // self._eva[2]))
             st["pos"] = pos + chunk
             if not final:
                 self._admit_dispatched(t0, chunk, base + pos)
@@ -4051,12 +4101,15 @@ class BatchGenerator:
         and ``attn.ring_rows_live`` / ``_swept`` say how much of it held a
         key the step's query could see.
         Where the layers run several times a token, a layer's planes: one
-        a pass."""
-        read, reserved = pk.decode_blocks_read(
-            pos, steps, self.max_seq, block_k=self._kv_block,
-            window=self._kv_window)
-        _KV_BLOCKS_READ.inc(read * self._kv_planes)
-        _KV_BLOCKS_RESERVED.inc(reserved * self._kv_planes)
+        a pass. Under EVA attention no layer holds such rows: the ring's
+        live rows, the summaries a query sees and what the step fetches
+        of both are counted in their place (``attn.eva_*``)."""
+        if "rows" in self.config.cache_plan:  # some layer holds a row a
+            read, reserved = pk.decode_blocks_read(  # position
+                pos, steps, self.max_seq, block_k=self._kv_block,
+                window=self._kv_window)
+            _KV_BLOCKS_READ.inc(read * self._kv_planes)
+            _KV_BLOCKS_RESERVED.inc(reserved * self._kv_planes)
         if self._latent_planes:
             # step j of the dispatch sweeps the rows 0..pos + j of a stream
             live = pos[:, None] + np.arange(1, steps + 1)[None, :]
@@ -4072,6 +4125,23 @@ class BatchGenerator:
             _DSA_ROWS_SELECTED.inc(layers * int(np.minimum(live, topk).sum()))
             _DSA_ROWS_READ.inc(layers * int(dsa.rows_fetched(
                 live, self.max_seq, topk).sum()))
+        if self._eva:
+            # step j of the dispatch writes position pos + j at ring row
+            # (pos + j) % W and attends that row's window and the
+            # summaries of the windows before it
+            layers, window, chunk = self._eva
+            at = pos[:, None] + np.arange(steps)[None, :]
+            live, visible = at % window + 1, at // window * (window // chunk)
+            _EVA_DECODE_CALLS.inc(layers * steps)
+            _EVA_WINDOW_ROWS_LIVE.inc(layers * int(live.sum()))
+            _EVA_SUMMARY_ROWS_VISIBLE.inc(layers * int(visible.sum()))
+            _EVA_ROWS_READ.inc(layers * int(eva.rows_fetched(
+                live - 1, visible, window, self.max_seq // chunk,
+                self.config.head_dim).sum()))
+            # (position 0 is a stream's start, or a slot without one)
+            _EVA_WINDOW_RESETS.inc(
+                layers * int(((at % window == 0) & (at > 0)).sum()))
+            _EVA_CHUNKS_STEP.inc(layers * at.size)
         if self._rings:
             # a window layer's step reads its ring whole; the rows that
             # hold a key its query may see are the window's, or fewer

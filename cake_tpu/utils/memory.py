@@ -144,7 +144,9 @@ def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
     keep rows, and a delta-rule or state-space layer's state and
     convolution tail a stream for the others, and a ring of ``R`` rows a
     stream for a window layer, and a sparse attention's index key a token
-    beside the latent row (``LlamaConfig.cache_plan``). One stage, tp = sp = 1
+    beside the latent row, and one summary row for every ``chunk_size``
+    positions beside an EVA layer's ring (``LlamaConfig.cache_plan``: the
+    capacity is ``S``, whichever kind of the plan grows with it). One stage, tp = sp = 1
     (``mesh.validate_shardable``)."""
     import math
 
@@ -181,6 +183,10 @@ def _latent_budget(c, ep: int, S: int, batch: int, lin_el, scale_el,
         kv_bytes += batch * n * heads * rows * (k_width + v_width) * cache_el
     if "index" in plan:  # a sparse attention's key a token beside the rows
         kv_bytes += batch * S * math.prod(plan["index"]) * el
+    if "summary" in plan:  # EVA: a row for every ``chunk`` positions
+        n, heads, chunk, k_width, v_width = plan["summary"]
+        kv_bytes += (batch * n * heads * (S // chunk) * (k_width + v_width)
+                     * cache_el)
     return {
         "layers": int(layer_bytes),
         "embed_replicated": int(embed_bytes),

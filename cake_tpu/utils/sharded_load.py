@@ -686,10 +686,17 @@ def _load_latent_on_mesh(reader: CheckpointReader, model_dir,
                              lambda ix: gather(ix, lambda n: reader.read1d(
                                  n, ix[-1])).astype(dt))
         if not quant:
+            def bounded(sl, n):
+                """``sl`` of the LEAF's axis of ``n``: a stored tensor may
+                hold more than the program does (a head of several
+                prediction blocks, of which block 0 is read)."""
+                return slice(*sl.indices(n))
+
             return _assemble(
                 lead + shape, mesh, P(*lead_spec, None, None),
                 lambda ix: gather(ix, lambda n: reader.read2d(
-                    n, ix[-2], ix[-1], transpose)).astype(dt))
+                    n, bounded(ix[-2], shape[0]), bounded(ix[-1], shape[1]),
+                    transpose)).astype(dt))
         return QuantizedLinear(
             _assemble(lead + shape, mesh, P(*lead_spec, None, None),
                       lambda ix: gather(

@@ -448,7 +448,12 @@ def latent_hf_tensors(params: dict, config) -> dict[str, np.ndarray]:
                                          config.family.final_norm_fold),
     }
     if not config.tie_word_embeddings:
-        tensors["lm_head.weight"] = np.asarray(params["lm_head"]).T
+        head = np.asarray(params["lm_head"]).T
+        # a head of several prediction blocks (EvaByte's ``num_pred_heads``):
+        # the program holds block 0, the model's own next token; the file
+        # keeps the stored shape, the other blocks zero
+        tensors["lm_head.weight"] = np.concatenate(
+            [head] + [np.zeros_like(head)] * (config.num_pred_heads - 1))
     for ours, parts in config.family.extra_tensors.items():
         for part, (name, _) in parts.items():
             tensors[name] = np.asarray(params[ours][part])
